@@ -9,7 +9,7 @@ from __future__ import annotations
 
 from typing import Any, List, Optional, Sequence, Union
 
-from ray_tpu._private import api_internal
+from ray_tpu._private import api_internal, device_env
 from ray_tpu._private.config import Config
 from ray_tpu._private.object_ref import ObjectRef
 from ray_tpu._private.runtime import Runtime
@@ -25,8 +25,8 @@ def init(num_cpus: Optional[int] = None, num_tpus: Optional[int] = None,
     or — with ``address`` — ATTACH to a running cluster in client mode
     (reference: Ray Client, ray.init("ray://...")).
 
-    ``num_tpus`` defaults to the number of locally attached TPU chips if jax
-    is importable and sees TPU devices; pass 0 to disable.
+    ``num_tpus`` defaults to the number of TPU chips this host has (its
+    device nodes are counted; JAX is not imported); pass 0 to disable.
     """
     import os as _os
 
@@ -58,31 +58,13 @@ def init(num_cpus: Optional[int] = None, num_tpus: Optional[int] = None,
             raise RuntimeError("ray_tpu.init() called twice "
                                "(pass ignore_reinit_error=True to allow).")
     if num_tpus is None:
-        num_tpus = _detect_tpu_chips()
+        num_tpus = device_env.detect_tpu_chips()
+    device_env.check_node_chips(int(num_tpus))
     config = Config.from_env(_system_config)
     rt = Runtime(config, num_cpus=num_cpus, num_tpus=num_tpus,
                  resources=resources, job_name=namespace)
     api_internal.set_global_runtime(rt)
     return rt
-
-
-def _detect_tpu_chips() -> int:
-    """Count local TPU chips without initializing the TPU runtime in the
-    driver (the chips belong to workers; reference analog: GPU autodetect in
-    python/ray/_private/resource_spec.py)."""
-    import glob
-    import os
-
-    if os.environ.get("RAY_TPU_FORCE_NUM_TPUS"):
-        return int(os.environ["RAY_TPU_FORCE_NUM_TPUS"])
-    # vfio devices (TPU VM) or accel nodes
-    accel = glob.glob("/dev/accel*")
-    if accel:
-        return len(accel)
-    vfio = glob.glob("/dev/vfio/[0-9]*")
-    if vfio:
-        return len(vfio)
-    return 0
 
 
 def shutdown():

@@ -21,6 +21,7 @@ RAY_TPU_AUTHKEY / RAY_TPU_AGENT_* env vars (see cluster_utils.Cluster).
 
 Wire contract: the agent-plane verbs (``agent_ready``/``agent_ack``,
 ``spawn_worker``/``kill_worker``/``kill_worker_hard``,
+``reap_worker``/``worker_reaped``,
 ``read_segment``/``segment``, ``unlink_segment``, ``oom_pressure``,
 ``worker_logs``, ``shutdown``, and the elastic-drain pair
 ``preempt_notice``/``drain_node`` — caps family ``drain_caps``,
@@ -41,7 +42,8 @@ import time
 from multiprocessing.connection import Listener
 from typing import Dict
 
-from ray_tpu._private import object_transfer, protocol, recovery
+from ray_tpu._private import device_env, object_transfer, protocol, \
+    recovery
 from ray_tpu._private.shm_store import ShmStore
 
 
@@ -354,6 +356,11 @@ class NodeAgent:
                 self._spawn_worker(msg[1], msg[2])
             elif tag == "kill_worker":
                 self._kill_worker(msg[1])
+            elif tag == "reap_worker":
+                # A retired TPU worker (already told to exit): its chips
+                # are granted again only after the process is gone.
+                threading.Thread(target=self._reap_worker,
+                                 args=(msg[1],), daemon=True).start()
             elif tag == "kill_worker_hard":
                 # SIGKILL, no graceful terminate: the chaos harness's
                 # worker-crash injection (a terminate lets atexit/finally
@@ -454,13 +461,14 @@ class NodeAgent:
         """terminate -> wait -> kill, as in shutdown(): a TPU worker
         mid-computation takes seconds to die, and new workers must not
         race it for the chips."""
-        for proc in self.workers.values():
+        procs = list(self.workers.values())  # reap threads pop too
+        for proc in procs:
             try:
                 proc.terminate()
             except Exception:
                 pass
         deadline = time.time() + 3.0
-        for proc in self.workers.values():
+        for proc in procs:
             try:
                 proc.wait(timeout=max(0.1, deadline - time.time()))
             except Exception:
@@ -486,7 +494,13 @@ class NodeAgent:
 
     def _spawn_worker(self, worker_id_hex: str, env_overrides: Dict[str, str]):
         env = dict(os.environ)
+        env.pop("TPU_VISIBLE_CHIPS", None)
         env.update(env_overrides)
+        # The head sends the grant; the rest of the device environment
+        # is built here, on the host whose chips they are.
+        env.update(device_env.worker_device_env(
+            [int(c) for c in env.get("TPU_VISIBLE_CHIPS", "").split(",")
+             if c]))
         env["RAY_TPU_SHM_DIR_OVERRIDE"] = self.shm_dir
         env["RAY_TPU_STORE_ID"] = self.store_id
         # THIS node's store policy wins over head defaults (see
@@ -517,15 +531,28 @@ class NodeAgent:
             env=env, cwd=pkg_root, stdout=log_f,
             stderr=subprocess.STDOUT)
         log_f.close()
+        # A child stays in the table until it has EXITED (a killed one
+        # may still be asked about by reap_worker); the exited are
+        # forgotten here.
+        for wid, p in list(self.workers.items()):
+            if p.poll() is not None:
+                self.workers.pop(wid, None)
         self.workers[worker_id_hex] = proc
 
     def _kill_worker(self, worker_id_hex: str, hard: bool = False):
-        proc = self.workers.pop(worker_id_hex, None)
+        proc = self.workers.get(worker_id_hex)
         if proc is not None:
             try:
                 proc.kill() if hard else proc.terminate()
             except Exception:
                 pass
+
+    def _reap_worker(self, worker_id_hex: str):
+        proc = self.workers.get(worker_id_hex)
+        if proc is not None:
+            device_env.reap(proc)
+            self.workers.pop(worker_id_hex, None)
+        self._send(("worker_reaped", worker_id_hex))
 
     def _read_segment(self, rid, name: str):
         try:
@@ -565,11 +592,13 @@ def main():
         from ray_tpu import chaos as chaos_mod
 
         chaos_mod.maybe_arm_env_net_chaos("agent")
+    resources = json.loads(os.environ.get("RAY_TPU_AGENT_RESOURCES",
+                                          '{"CPU": 1.0}'))
+    device_env.check_node_chips(int(resources.get("TPU", 0)))
     agent = NodeAgent(
         head_address=os.environ["RAY_TPU_HEAD_ADDRESS"],
         authkey=bytes.fromhex(os.environ["RAY_TPU_AUTHKEY"]),
-        resources=json.loads(os.environ.get("RAY_TPU_AGENT_RESOURCES",
-                                            '{"CPU": 1.0}')),
+        resources=resources,
         shm_dir=os.environ.get("RAY_TPU_AGENT_SHM_DIR",
                                f"/tmp/ray_tpu_node_{os.getpid()}"),
         labels=json.loads(os.environ.get("RAY_TPU_AGENT_LABELS", "{}")),

@@ -321,6 +321,12 @@ VERBS = {
                         "terminate a worker process"),
     "kill_worker_hard": Verb(("head",), ("agent",), (2, 2),
                              "SIGKILL a worker (chaos/OOM paths)"),
+    "reap_worker": Verb(("head",), ("agent",), (2, 2),
+                        "wait for a retired TPU worker's process to "
+                        "exit, then answer worker_reaped"),
+    "worker_reaped": Verb(("agent",), ("head",), (2, 2),
+                          "reply to reap_worker: the process is gone, "
+                          "its chips may be granted again"),
     "read_segment": Verb(("head",), ("agent",), (3, 3),
                          "relay-read a segment from the agent's store"),
     "unlink_segment": Verb(("head",), ("agent",), (3, 3),

@@ -406,16 +406,21 @@ class _WorkerRuntime:
         # Push-shuffle counters, only if a shuffle actually ran in this
         # process (lazy module lookup: importing the data layer from
         # every worker just to read zeros would be waste).
-        shuffle_mod = sys.modules.get("ray_tpu.data.shuffle")
-        if shuffle_mod is not None:
-            cur.update(shuffle_mod.shuffle_stats())
+        # (getattr: another thread may be half-way through importing the
+        # module — it is in sys.modules before its body has run.)
+        shuffle_stats = getattr(sys.modules.get("ray_tpu.data.shuffle"),
+                                "shuffle_stats", None)
+        if shuffle_stats is not None:
+            cur.update(shuffle_stats())
         # Distributed-training counters, same lazy-lookup contract:
         # present only in workers hosting a pipeline stage actor or an
         # IMPALA learner (stage restores count here too — the restored
         # actor's fresh process imports the module in __ray_restore__).
-        train_mod = sys.modules.get("ray_tpu.train.pipeline_actors")
-        if train_mod is not None:
-            cur.update(train_mod.train_stats())
+        train_stats = getattr(
+            sys.modules.get("ray_tpu.train.pipeline_actors"),
+            "train_stats", None)
+        if train_stats is not None:
+            cur.update(train_stats())
         with self._xfer_lock:
             delta = {}
             for k, v in cur.items():
@@ -1827,6 +1832,13 @@ def worker_entry(conn, worker_id_hex: str, session: str, shm_dir: str,
     def handle(msg):
         tag = msg[0]
         if tag in ("exec", "create_actor", "kill"):
+            if tag == "kill":
+                # The head closes this conn right after a kill: the EOF
+                # that follows is our retirement, not a head failover.
+                # Never re-dial — a retired worker that re-registered
+                # while its exec thread had not yet popped the kill was
+                # handed the next task and died with it.
+                rt._shutting_down = True
             with tq_cv:
                 queued_behind = bool(tasks) or rt._executing > 0
                 tasks.append(msg)

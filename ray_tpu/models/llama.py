@@ -181,12 +181,14 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh]):
         return mha_reference(q, k, v, causal=True)
     # flash under a mesh: pallas has no SPMD partitioning rule, so run the
     # kernel per-shard: batch over (dp,fsdp), heads over tp, seq replicated.
+    # Manual over EVERY mesh axis — the TPU lowering refuses a Mosaic
+    # kernel in a region that leaves any axis to the partitioner.
     from ray_tpu.parallel.sharding import manual_shard_map
     k, v = repeat_kv_heads(q, k, v)
     spec = P((AXIS_DP, AXIS_FSDP), None, AXIS_TP, None)
     fn = manual_shard_map(
         lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True),
-        {AXIS_DP, AXIS_FSDP, AXIS_TP}, in_specs=(spec, spec, spec),
+        set(mesh.axis_names), in_specs=(spec, spec, spec),
         out_specs=spec, mesh=mesh)
     return fn(q, k, v)
 
@@ -239,7 +241,8 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig, *,
 def _make_cst(mesh, rules):
     if mesh is None:
         return lambda x, ax: x
-    return lambda x, ax: with_logical_constraint(x, ax, rules=rules)
+    return lambda x, ax: with_logical_constraint(x, ax, mesh=mesh,
+                                                 rules=rules)
 
 
 def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False):

@@ -3,7 +3,10 @@
 Reference design: vLLM's PagedAttention (SOSP'23) mapped onto the TPU
 grid model, next to the contiguous flash kernel in ``attention.py``.
 The KV cache is not one contiguous ``(B, S, h, d)`` tensor but a pool
-of fixed-size blocks ``(num_blocks, block_size, h, d)``; each sequence
+of fixed-size blocks ``(num_blocks, h, block_size, d)`` — head-major
+pages, so both in-kernel matmuls batch over a LEADING head dimension
+(the form the TPU compiler accepts) and each page's trailing
+``(block_size, d)`` dims land on the (sublane, lane) tiling; each sequence
 owns a *block table* — the list of physical block ids holding its
 context in order.  A decode step computes attention of ONE query token
 per sequence against that sequence's gathered context:
@@ -55,7 +58,7 @@ def paged_attention_reference(q: jax.Array, k_cache: jax.Array,
                               window: int = 0) -> jax.Array:
     """Pure-XLA oracle: gather each sequence's context contiguously via
     its block table, then plain softmax attention.  q: ``(B, h, d)``;
-    caches ``(num_blocks, block_size, h, d)``; returns ``(B, h, d)``."""
+    caches ``(num_blocks, h, block_size, d)``; returns ``(B, h, d)``."""
     import numpy as np
 
     if sm_scale is None:
@@ -65,20 +68,20 @@ def paged_attention_reference(q: jax.Array, k_cache: jax.Array,
     vc = np.asarray(v_cache, np.float32)
     bt = np.asarray(block_tables)
     cl = np.asarray(context_lens)
-    bs = kc.shape[1]
+    bs = kc.shape[2]
     out = np.zeros_like(qh)
     for b in range(qh.shape[0]):
         n = int(cl[b])
         pages = bt[b, : -(-n // bs)]
-        k = kc[pages].reshape(-1, *kc.shape[2:])[:n]   # (n, h, d)
-        v = vc[pages].reshape(-1, *vc.shape[2:])[:n]
+        k = np.concatenate(list(kc[pages]), axis=1)[:, :n]  # (h, n, d)
+        v = np.concatenate(list(vc[pages]), axis=1)[:, :n]
         lo = max(0, n - window) if window else 0
-        k, v = k[lo:], v[lo:]
-        s = np.einsum("hd,khd->hk", qh[b], k) * sm_scale
+        k, v = k[:, lo:], v[:, lo:]
+        s = np.einsum("hd,hkd->hk", qh[b], k) * sm_scale
         s -= s.max(-1, keepdims=True)
         p = np.exp(s)
         p /= p.sum(-1, keepdims=True)
-        out[b] = np.einsum("hk,khd->hd", p, v)
+        out[b] = np.einsum("hk,hkd->hd", p, v)
     return jnp.asarray(out)
 
 
@@ -100,25 +103,33 @@ def _paged_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
     # context hold arbitrary padding table entries and are skipped.
     live = (page_lo < ctx) & (page_lo + block_size > start)
 
+    # A float32 cache gets float32 arithmetic: the MXU's default rounds
+    # float32 operands to bfloat16, which would break the window=1
+    # bitwise-gather identity on the chip.
+    precision = (jax.lax.Precision.HIGHEST
+                 if k_ref.dtype == jnp.float32 else None)
+
     @pl.when(live)
     def _compute():
-        q = q_ref[0]                                   # (h, d), pre-scaled
-        k = k_ref[0]                                   # (bs, h, d)
+        q = q_ref[0]                                   # (h, 1, d), pre-scaled
+        k = k_ref[0]                                   # (h, bs, d)
         v = v_ref[0]
-        s = jax.lax.dot_general(                       # (h, bs)
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        pos = page_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # Head-batched matmuls with the batch dimension LEADING on both
+        # operands and a (unit) non-contracting query dimension: the
+        # only dot_general form Mosaic's TPU lowering accepts.
+        s = jnp.einsum("hqd,hkd->hqk", q, k, precision=precision,
+                       preferred_element_type=jnp.float32)  # (h, 1, bs)
+        pos = page_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where((pos >= start) & (pos < ctx), s, NEG_INF)
-        m_prev = m_scr[...]                            # (h, 1)
+        m_prev = m_scr[...]                            # (h, 1, 1)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_next = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp2(m_prev - m_next)
-        p = jnp.exp2(s - m_next)                       # (h, bs)
+        p = jnp.exp2(s - m_next)                       # (h, 1, bs)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)        # (h, d)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.einsum(
+            "hqk,hkd->hqd", p.astype(v.dtype), v, precision=precision,
+            preferred_element_type=jnp.float32)        # (h, 1, d)
         m_scr[...] = m_next
 
     @pl.when(i == npages - 1)
@@ -133,7 +144,7 @@ def paged_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     """Decode attention over a paged KV cache.
 
     q: ``(B, h, d)`` — one query token per sequence.
-    k_cache/v_cache: ``(num_blocks, block_size, h, d)`` physical pool.
+    k_cache/v_cache: ``(num_blocks, h, block_size, d)`` physical pool.
     block_tables: ``(B, max_pages)`` int32 — per-sequence physical block
     ids in context order; entries past ``ceil(context_len/block_size)``
     may be arbitrary valid indices (padding).
@@ -146,36 +157,37 @@ def paged_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     if interpret is None:
         interpret = _interpret_default()
     B, h, d = q.shape
-    bs = k_cache.shape[1]
+    bs = k_cache.shape[2]
     max_pages = block_tables.shape[1]
     # Pre-scale into the log2 domain like the flash kernel: the hot loop
     # then uses exp2 directly and the per-tile scale multiply vanishes.
-    qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)
+    qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)[:, :, None, :]
     bt = jnp.asarray(block_tables, jnp.int32)
     cl = jnp.asarray(context_lens, jnp.int32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda b, i, bt_, cl_: (b, 0, 0)),
-            pl.BlockSpec((1, bs, h, d),
+            pl.BlockSpec((1, h, 1, d), lambda b, i, bt_, cl_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, h, bs, d),
                          lambda b, i, bt_, cl_: (bt_[b, i], 0, 0, 0)),
-            pl.BlockSpec((1, bs, h, d),
+            pl.BlockSpec((1, h, bs, d),
                          lambda b, i, bt_, cl_: (bt_[b, i], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, d), lambda b, i, bt_, cl_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, 1, d),
+                               lambda b, i, bt_, cl_: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, 1, 1), jnp.float32),
+            pltpu.VMEM((h, 1, 1), jnp.float32),
+            pltpu.VMEM((h, 1, d), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, block_size=bs, window=window)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, h, 1, d), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(bt, cl, qs, k_cache, v_cache)
+    )(bt, cl, qs, k_cache, v_cache)[:, :, 0, :]

@@ -15,30 +15,16 @@ the framework's first-class layer:
 ``ray_tpu.util.collective``; in-mesh collectives are ``jax.lax.p*``.)
 """
 
-from ray_tpu.parallel.mesh import (
-    AXIS_DP,
-    AXIS_EP,
-    AXIS_FSDP,
-    AXIS_PP,
-    AXIS_SP,
-    AXIS_TP,
-    MESH_AXES,
-    MeshConfig,
-    make_mesh,
-    use_mesh,
-)
-from ray_tpu.parallel.sharding import (
-    LogicalAxisRules,
-    DEFAULT_RULES,
-    logical_to_mesh_axes,
-    named_sharding,
-    shard_pytree,
-    with_logical_constraint,
-)
+# Names resolve on first use (PEP 562): a driver that only needs
+# ``MeshConfig`` for a ScalingConfig must not import JAX — the chip
+# belongs to the workers.
+from ray_tpu._private.lazy import lazy_exports
 
-__all__ = [
-    "AXIS_DP", "AXIS_FSDP", "AXIS_EP", "AXIS_PP", "AXIS_SP", "AXIS_TP",
-    "MESH_AXES", "MeshConfig", "make_mesh", "use_mesh",
-    "LogicalAxisRules", "DEFAULT_RULES", "logical_to_mesh_axes",
-    "named_sharding", "shard_pytree", "with_logical_constraint",
-]
+__all__, __getattr__ = lazy_exports(__name__, {
+    "ray_tpu.parallel.mesh": (
+        "AXIS_DP", "AXIS_FSDP", "AXIS_EP", "AXIS_PP", "AXIS_SP", "AXIS_TP",
+        "MESH_AXES", "MeshConfig", "make_mesh", "use_mesh"),
+    "ray_tpu.parallel.sharding": (
+        "LogicalAxisRules", "DEFAULT_RULES", "logical_to_mesh_axes",
+        "named_sharding", "shard_pytree", "with_logical_constraint"),
+})

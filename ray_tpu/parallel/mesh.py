@@ -23,10 +23,10 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Optional, Sequence, Tuple
 
-import jax
-import numpy as np
+if TYPE_CHECKING:  # MeshConfig is driver-side data: no JAX at import
+    import jax
 
 AXIS_DP = "dp"
 AXIS_FSDP = "fsdp"
@@ -99,38 +99,18 @@ def make_mesh(config: Optional[MeshConfig] = None,
     ``jax.devices()`` row-major, which preserves axis semantics.
 
     Axes are ``Auto`` (GSPMD propagation): model code steers the partitioner
-    with ``with_sharding_constraint`` rather than jax 0.9's explicit
+    with ``with_sharding_constraint`` rather than explicit
     sharding-in-types mode, which would demand out_shardings on every
-    ambiguous op (gathers, einsums) throughout model code.  On jax
-    releases predating ``jax.sharding.AxisType`` (<= 0.4.x) every axis is
-    implicitly Auto, so the kwarg is simply omitted — feature-detected,
-    since passing it would raise (AttributeError here, TypeError inside
-    ``jax.make_mesh``).
+    ambiguous op (gathers, einsums) throughout model code.
     """
+    import jax
+
     devices = list(devices if devices is not None else jax.devices())
     config = config or MeshConfig()
     sizes = config.sizes(len(devices))
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = ({"axis_types": (axis_type.Auto,) * len(MESH_AXES)}
-              if axis_type is not None else {})
-    try:
-        try:
-            return jax.make_mesh(sizes, MESH_AXES, devices=devices,
-                                 **kwargs)
-        except TypeError:
-            if not kwargs:
-                raise
-            # jax.make_mesh exists but predates the axis_types kwarg.
-            kwargs = {}
-            return jax.make_mesh(sizes, MESH_AXES, devices=devices)
-    except (ValueError, NotImplementedError):
-        # jax.make_mesh's contiguous-remapping can reject exotic topologies;
-        # fall back to a plain row-major reshape.
-        arr = np.asarray(devices).reshape(sizes)
-        try:
-            return jax.sharding.Mesh(arr, MESH_AXES, **kwargs)
-        except TypeError:
-            return jax.sharding.Mesh(arr, MESH_AXES)
+    return jax.make_mesh(
+        sizes, MESH_AXES, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(MESH_AXES))
 
 
 def mesh_axis_size(mesh: jax.sharding.Mesh, axis: str) -> int:
@@ -138,13 +118,7 @@ def mesh_axis_size(mesh: jax.sharding.Mesh, axis: str) -> int:
 
 
 def use_mesh(mesh: jax.sharding.Mesh):
-    """Activate ``mesh`` as the ambient mesh, as a context manager.
+    """Activate ``mesh`` as the ambient mesh, as a context manager."""
+    import jax
 
-    On current jax this is ``jax.set_mesh``; releases predating it
-    (<= 0.4.x) get the classic ``Mesh`` context manager, which sets the
-    thread-resource physical mesh that pjit/shard_map resolve against —
-    the same role."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+    return jax.set_mesh(mesh)

@@ -84,26 +84,12 @@ def named_sharding(mesh: Mesh, *logical_axes: Optional[str],
 def with_logical_constraint(x: jax.Array, logical_axes: Sequence[Optional[str]],
                             mesh: Optional[Mesh] = None,
                             rules: Optional[LogicalAxisRules] = None) -> jax.Array:
-    """``lax.with_sharding_constraint`` by logical names.  Inside jit under a
-    mesh context the PartitionSpec alone suffices (jax>=0.4.30 semantics)."""
+    """``lax.with_sharding_constraint`` by logical names.  Under a mesh
+    context the PartitionSpec alone suffices (and is the only form valid
+    inside a manual region); ``mesh`` is bound explicitly only when no
+    context mesh exists — same rule as ``manual_shard_map``."""
     spec = logical_to_mesh_axes(logical_axes, rules)
-    if getattr(jax, "shard_map", None) is None and spec:
-        # Legacy-jax path: manual_shard_map regions are FULL manual
-        # there, and a constraint naming a manually-bound mesh axis is
-        # rejected at lowering (too late for a try/except here).
-        # Constraints are propagation hints, not semantics — drop any
-        # that touch a bound axis.
-        get_bound = getattr(jax.core,
-                            "unsafe_get_axis_names_DO_NOT_USE", None)
-        bound = set(get_bound()) if get_bound is not None else set()
-        if bound:
-            named = set()
-            for a in spec:
-                if a is not None:
-                    named.update(a if isinstance(a, tuple) else (a,))
-            if named & bound:
-                return x
-    if mesh is not None:
+    if mesh is not None and jax.sharding.get_abstract_mesh().empty:
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
     return jax.lax.with_sharding_constraint(x, spec)
 
@@ -123,82 +109,24 @@ def manual_shard_map(f, axis_names, in_specs, out_specs,
     """shard_map manual over only ``axis_names`` (other mesh axes stay under
     GSPMD auto-propagation), resolved against the *context* mesh so ops that
     wrap themselves in shard_map (ring attention over 'sp', pipeline over
-    'pp') nest inside each other and inside jit.  ``mesh`` is only used to
-    establish a context when none exists (eager/standalone calls).
-
-    Two jax API generations are supported, feature-detected once:
-    ``jax.shard_map`` (axis_names/check_vma, context-mesh resolution) on
-    current releases, and the 0.4.x ``jax.experimental.shard_map`` — an
-    explicit-mesh API where partial-manual is spelled as the complement
-    ``auto=`` set and the context mesh comes from the classic Mesh
-    context (``thread_resources``)."""
-    import contextlib
-
-    if getattr(jax, "shard_map", None) is None:
-        return _manual_shard_map_04(f, axis_names, in_specs, out_specs,
-                                    mesh)
-
-    mapped = jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                           axis_names=set(axis_names), check_vma=False)
+    'pp') nest inside each other and inside jit.  ``mesh`` is bound
+    explicitly only when no context mesh exists (eager/standalone calls,
+    or a jitted step built with ``mesh=`` and called outside ``use_mesh``
+    — ``jax.set_mesh`` cannot be opened under a trace)."""
+    kw = dict(in_specs=in_specs, out_specs=out_specs,
+              axis_names=set(axis_names), check_vma=False)
+    # Always under jit: partial-manual shard_map only lowers correctly
+    # there (eager evaluation — a bare call or one under an un-jitted
+    # grad trace — tries to complete out_specs with every mesh axis);
+    # nested inside an outer jit it is inlined, so it is free.
+    in_ctx = jax.jit(jax.shard_map(f, **kw))
+    bound = (jax.jit(jax.shard_map(f, mesh=mesh, **kw))
+             if mesh is not None else None)
 
     def call(*args):
-        # Mesh-context check happens at call time, inside the with: a
-        # jax.set_mesh constructed eagerly at wrap time would mutate the
-        # global mesh immediately and be single-use.
-        ctx = jax.sharding.get_abstract_mesh()
-        need_ctx = (ctx is None or ctx.empty) and mesh is not None
-        cm = jax.set_mesh(mesh) if need_ctx else contextlib.nullcontext()
-        with cm:
-            from jax._src import core as _core
-            if _core.trace_state_clean():
-                # Partial-manual shard_map only lowers correctly under jit
-                # (eager evaluation tries to complete out_specs with every
-                # mesh axis); jit here is semantically free.
-                return jax.jit(mapped)(*args)
-            return mapped(*args)
-
-    return call
-
-
-def _manual_shard_map_04(f, axis_names, in_specs, out_specs,
-                         mesh: Optional[Mesh]):
-    """manual_shard_map for jax 0.4.x (see above)."""
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # One (mapped, jitted) pair per resolved mesh: rebuilding them per
-    # call would defeat jax's trace/compile cache (keyed on callable
-    # identity) and recompile the region on every eager invocation.
-    cache: Dict[Any, tuple] = {}
-
-    def call(*args):
-        # Context mesh wins (new-API semantics); ``mesh`` covers
-        # standalone/eager calls.  Resolved per call: the wrapping mesh
-        # context is only live at trace time.
-        from jax._src import core as _core
-        from jax._src import mesh as _mesh_lib
-
-        ctx = _mesh_lib.thread_resources.env.physical_mesh
-        use = ctx if ctx is not None and not ctx.empty else mesh
-        if use is None or use.empty:
-            raise ValueError(
-                "manual_shard_map needs an active mesh context (use_mesh) "
-                "or an explicit mesh argument")
-        ent = cache.get(use)
-        if ent is None:
-            # FULL manual (not ``auto=`` partial): 0.4.x's partitioner
-            # hits a manual-subgroup CHECK (spmd_partitioner.cc:512)
-            # resharding in and out of partial-manual regions.  Axes the
-            # specs don't mention are replicated across the region
-            # instead of auto-propagated — numerically identical, at
-            # worst extra gathers on those axes for this legacy path.
-            mapped = _shard_map(f, use, in_specs=in_specs,
-                                out_specs=out_specs, check_rep=False)
-            ent = cache[use] = (mapped, jax.jit(mapped))
-        mapped, jitted = ent
-        with use:
-            if _core.trace_state_clean():
-                return jitted(*args)
-            return mapped(*args)
+        if bound is not None and jax.sharding.get_abstract_mesh().empty:
+            return bound(*args)
+        return in_ctx(*args)
 
     return call
 

@@ -346,7 +346,8 @@ class ServeController:
         actor = remote_cls.options(**opts).remote(
             d["cls_or_fn"], d.get("init_args", ()),
             d.get("init_kwargs", {}), d.get("role"))
-        return {"actor": actor, "version": version}
+        return {"actor": actor, "version": version, "ready": False,
+                "spawned": time.monotonic()}
 
     def record_pool_metric(self, name: str, key: str, value: float):
         """One pool-saturation sample ((value, ts) into the (name, key)
@@ -497,6 +498,8 @@ class ServeController:
             return self._reconcile_once()  # noqa: RTL505 -- the reconcile serializer is strictly OUTER to the controller lock; no path under _lock takes _reconcile_lock
 
     DRAIN_S = 3.0
+    # How long a replica may take to answer its FIRST health check.
+    START_GRACE_S = 300.0
 
     def _retire(self, rep):
         with self._lock:
@@ -530,7 +533,18 @@ class ServeController:
             for r in reps:
                 try:
                     ray.get(r["actor"].health_check.remote(), timeout=5)
+                    r["ready"] = True
                     alive.append(r)
+                except ray.exceptions.GetTimeoutError:
+                    # No answer yet.  A replica that has never answered
+                    # is still CONSTRUCTING (a TPU replica spends ~10 s
+                    # bringing up its device before its weights load) —
+                    # replacing it would only queue a successor behind
+                    # the chip it holds, forever.  Past the grace, or
+                    # once it had been ready, silence is unhealthy.
+                    if not r["ready"] and time.monotonic() \
+                            < r["spawned"] + self.START_GRACE_S:
+                        alive.append(r)
                 except Exception:
                     pass  # dead or unhealthy: dropped, replaced below
             if d.get("role") and d.get("autoscaling_config"):

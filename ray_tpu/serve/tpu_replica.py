@@ -189,11 +189,12 @@ class MeshShardedDecoder:
             # The batching decorator picks this attribute up and wires
             # block-gated admission into the continuous batcher.
             self.serve_kv_engine = self._kv_engine
-            # Device-resident paged value cache: one (block_size, 1,
-            # embed) page per block, replicated over the mesh (read by
-            # position — gather-heavy, like the embedding table).
+            # Device-resident paged value cache: one head-major (1,
+            # block_size, embed) page per block (ops.paged_attention's
+            # layout), replicated over the mesh (read by position —
+            # gather-heavy, like the embedding table).
             self._kv_cache = jax.device_put(
-                np.zeros((kv_blocks, kv_block_size, 1, embed),
+                np.zeros((kv_blocks, 1, kv_block_size, embed),
                          np.float32), self._in_sharding)
             # Draft model: a perturbed integer copy of the projection —
             # mostly agrees with the target (that is the whole game of
@@ -254,8 +255,8 @@ class MeshShardedDecoder:
                 self._kv_cache[olds])
         if blocks:
             self._kv_cache = self._kv_cache.at[
-                jnp.asarray(blocks, jnp.int32),
-                jnp.asarray(offs, jnp.int32), 0].set(
+                jnp.asarray(blocks, jnp.int32), 0,
+                jnp.asarray(offs, jnp.int32)].set(
                     jnp.asarray(np.stack(vals)))
 
     def _read_last(self, live):
@@ -322,7 +323,7 @@ class MeshShardedDecoder:
                             writes, range(lo, len(kvp.prompt))):
                         wb.append(blk)
                         wo.append(off)
-                        wv.append(pages[p // sbs, p % sbs, 0])
+                        wv.append(pages[p // sbs, 0, p % sbs])
                     eng.note_chain_imported()
                 else:
                     for (blk, off), tok in zip(writes, kvp.prompt[lo:]):
@@ -674,6 +675,13 @@ class MeshShardedDecoder:
         descr, _sampler = handoff
         imp = self._open_chain(descr)
         return self._decode({**(body or {}), "_import": imp})
+
+    def device_info(self) -> Dict[str, Any]:
+        """The devices THIS replica's process runs on, as JAX reports
+        them — what a measurement must name beside its numbers."""
+        devs = self._jax.devices()
+        return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                "count": len(devs)}
 
     # -- host-side reference (tests pin numerics against this) -------------
     def reference_decode(self, prompt, tokens: int) -> List[int]:

@@ -8,25 +8,20 @@ train step; gradient traffic is XLA collectives over ICI, never an external
 library.
 """
 
-from ray_tpu.train.core import (
-    TrainState,
-    default_optimizer,
-    init_train_state,
-    make_train_step,
-)
-from ray_tpu.train.backend import Backend, JaxConfig
-from ray_tpu.train.backend_executor import BackendExecutor, TrainingFailedError
-from ray_tpu.train.trainer import (
-    BaseTrainer,
-    DataParallelTrainer,
-    JaxTrainer,
-)
-from ray_tpu.train.worker_group import WorkerGroup
-from ray_tpu.train.pipeline_actors import PipelineStage, PipelineTrainer
+# Names resolve on first use (PEP 562): the driver side (trainers,
+# executor, worker group) must not import JAX — only ``core`` needs it,
+# and that runs in the workers that own the chips.
+from ray_tpu._private.lazy import lazy_exports
 
-__all__ = [
-    "TrainState", "init_train_state", "make_train_step", "default_optimizer",
-    "Backend", "JaxConfig", "BackendExecutor", "TrainingFailedError",
-    "BaseTrainer", "DataParallelTrainer", "JaxTrainer", "WorkerGroup",
-    "PipelineStage", "PipelineTrainer",
-]
+__all__, __getattr__ = lazy_exports(__name__, {
+    "ray_tpu.train.core": (
+        "TrainState", "init_train_state", "make_train_step",
+        "default_optimizer"),
+    "ray_tpu.train.backend": ("Backend", "JaxConfig"),
+    "ray_tpu.train.backend_executor": (
+        "BackendExecutor", "TrainingFailedError"),
+    "ray_tpu.train.trainer": (
+        "BaseTrainer", "DataParallelTrainer", "JaxTrainer"),
+    "ray_tpu.train.worker_group": ("WorkerGroup",),
+    "ray_tpu.train.pipeline_actors": ("PipelineStage", "PipelineTrainer"),
+})

@@ -16,13 +16,14 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.llama import (
     LlamaConfig, forward_pipelined, init_params, loss_fn, param_logical_axes,
 )
 from ray_tpu.parallel.mesh import AXIS_DP, AXIS_FSDP, AXIS_PP
 from ray_tpu.parallel.sharding import (
-    LogicalAxisRules, named_sharding, shard_pytree,
+    LogicalAxisRules, named_sharding, sharding_tree,
 )
 
 
@@ -34,22 +35,55 @@ class TrainState:
     opt_state: Any
 
 
+def _fresh_state(key: jax.Array, cfg: LlamaConfig,
+                 optimizer: optax.GradientTransformation) -> TrainState:
+    params = init_params(key, cfg)
+    return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                      opt_state=optimizer.init(params))
+
+
+def train_state_shardings(cfg: LlamaConfig,
+                          optimizer: optax.GradientTransformation, mesh,
+                          rules: Optional[LogicalAxisRules] = None
+                          ) -> TrainState:
+    """Where every leaf of the train state lives on ``mesh``: parameters
+    by their logical axes; an optimizer-state leaf whose tree path ends
+    in a parameter's path (adam's mu/nu mirror the parameter tree) like
+    that parameter; everything else (step, counts) replicated."""
+    params = sharding_tree(param_logical_axes(cfg), mesh, rules)
+    replicated = NamedSharding(mesh, P())
+    by_path = dict(jax.tree_util.tree_leaves_with_path(params))
+
+    def mirror(path, _):
+        for n in range(len(path)):  # longest suffix first
+            if path[n:] in by_path:
+                return by_path[path[n:]]
+        return replicated
+
+    shapes = jax.eval_shape(lambda k: _fresh_state(k, cfg, optimizer),
+                            jax.random.PRNGKey(0))
+    return TrainState(
+        step=replicated, params=params,
+        opt_state=jax.tree_util.tree_map_with_path(mirror,
+                                                   shapes.opt_state))
+
+
 def init_train_state(key: jax.Array, cfg: LlamaConfig,
                      optimizer: optax.GradientTransformation,
                      mesh=None,
                      rules: Optional[LogicalAxisRules] = None) -> TrainState:
-    """Init params (host) and optimizer state, sharded onto ``mesh``.
+    """Init params and optimizer state, sharded onto ``mesh``.
 
-    Optimizer state leaves mirror param leaves (adam mu/nu), so they inherit
-    the matching param sharding; scalar leaves replicate.
+    One jitted program with explicit ``out_shardings``: every leaf is
+    BORN where it lives.  Left to itself the whole model is built on the
+    default device before resharding, and ``jit(optimizer.init)`` puts
+    the (constant) adam moments on that one device too — on a four-chip
+    host the first chip then holds four times its share.
     """
-    params = init_params(key, cfg)
-    if mesh is not None:
-        params = shard_pytree(params, param_logical_axes(cfg), mesh, rules)
-    opt_state = jax.jit(optimizer.init)(params) if mesh is not None \
-        else optimizer.init(params)
-    return TrainState(step=jnp.zeros((), jnp.int32), params=params,
-                      opt_state=opt_state)
+    shardings = None if mesh is None else train_state_shardings(
+        cfg, optimizer, mesh, rules)
+    return jax.jit(lambda k: _fresh_state(k, cfg, optimizer),
+                   out_shardings=shardings)(key)
 
 
 def make_train_step(cfg: LlamaConfig,

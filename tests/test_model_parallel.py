@@ -80,15 +80,12 @@ def test_train_step_decreases_loss_single_device():
     assert float(metrics["loss"]) < float(m0["loss"])
 
 
-def test_train_step_sharded_matches_single_device():
-    cfg = LlamaConfig.tiny()
-    opt = optax.adam(1e-2)
-    batch = _batch(cfg, b=8)
-
+def _one_step_both_ways(cfg, opt, batch):
+    """One step from the same initial state on one device and on the
+    dp=2 x fsdp=2 x tp=2 mesh: (initial params, single-device state and
+    metrics, sharded state and metrics)."""
     state = init_train_state(KEY, cfg, opt)
-    step = make_train_step(cfg, opt, donate=False)
-    s1, m1 = step(state, batch)
-
+    s1, m1 = make_train_step(cfg, opt, donate=False)(state, batch)
     mesh = make_mesh(MeshConfig(dp=2, fsdp=2, tp=2))
     with use_mesh(mesh):
         state_sh = init_train_state(KEY, cfg, opt, mesh=mesh)
@@ -96,11 +93,57 @@ def test_train_step_sharded_matches_single_device():
         toks = jax.device_put(
             batch["tokens"], NamedSharding(mesh, P(("dp", "fsdp"), None)))
         s2, m2 = step_sh(state_sh, {"tokens": toks})
-        assert abs(float(m1["loss"]) - float(m2["loss"])) < 1e-4
-        # params after one step agree
-        d = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))),
-                         s1.params, s2.params)
+        s2 = jax.device_get(s2)
+    return state.params, s1, m1, s2, m2
+
+
+def test_train_step_sharded_matches_single_device():
+    cfg = LlamaConfig.tiny()
+    batch = _batch(cfg, b=8)
+
+    # A plain SGD step of lr 1 IS the gradient: every element of every
+    # leaf agrees to 1e-6 (measured: 1.2e-7).
+    p0, g1, n1, g2, n2 = _one_step_both_ways(cfg, optax.sgd(1.0), batch)
+    assert abs(float(n1["loss"]) - float(n2["loss"])) < 1e-4
+    d = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))),
+                     g1.params, g2.params)
+    assert max(jax.tree.leaves(d)) < 1e-6
+
+    # Params after one Adam step agree to 1e-4.  Adam's first step is
+    # lr * g / (|g| + 1e-8): where |g| is within two decades of that
+    # epsilon the last ulp of a reduction order decides the update (the
+    # two differ by 1.8e-4 at an element of w_gate whose gradient is
+    # 7e-9), so those elements — under 1% of any leaf — are held to the
+    # gradient bound above instead, which is what a sharding fault
+    # would move.  (Exactly-zero gradients, the embedding rows of unused
+    # tokens, stay in the comparison.)
+    _, s1, m1, s2, m2 = _one_step_both_ways(cfg, optax.adam(1e-2), batch)
+    assert abs(float(m1["loss"]) - float(m2["loss"])) < 1e-4
+    tiny = jax.tree.map(
+        lambda p, g: (p != g) & (jnp.abs(p - g) <= 1e-6), p0, g1.params)
+    assert max(float(jnp.mean(m)) for m in jax.tree.leaves(tiny)) < 0.01
+    d = jax.tree.map(
+        lambda a, b, m: float(jnp.max(jnp.where(m, 0.0, jnp.abs(a - b)))),
+        s1.params, s2.params, tiny)
     assert max(jax.tree.leaves(d)) < 1e-4
+
+
+def test_sharded_flash_step_needs_no_mesh_context():
+    """``make_train_step(cfg, opt, mesh=mesh)`` is complete on its own:
+    called OUTSIDE any ``use_mesh`` block (how a trainer loop calls it)
+    the flash shard_map and the logical constraints bind the explicit
+    mesh — ``jax.set_mesh`` cannot be opened under the step's trace."""
+    cfg = LlamaConfig.tiny(attn_impl="flash")
+    opt = optax.adam(1e-2)
+    batch = _batch(cfg, b=8)
+    _, m1 = make_train_step(cfg, opt, donate=False)(
+        init_train_state(KEY, cfg, opt), batch)
+
+    mesh = make_mesh(MeshConfig(dp=2, fsdp=2, tp=2))
+    state = init_train_state(KEY, cfg, opt, mesh=mesh)
+    assert jax.sharding.get_abstract_mesh().empty
+    _, m2 = make_train_step(cfg, opt, mesh=mesh)(state, batch)
+    assert abs(float(m1["loss"]) - float(m2["loss"])) < 1e-4
 
 
 @pytest.mark.slow  # ~38s of multichip mesh dryruns (the single biggest

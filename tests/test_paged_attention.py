@@ -23,8 +23,8 @@ def _random_paged(rng, B, h, d, bs, num_blocks, max_ctx):
     """Random cache + per-seq block tables (shuffled physical ids,
     ragged lengths, arbitrary padding entries past the last page)."""
     q = jnp.asarray(rng.normal(size=(B, h, d)), jnp.float32)
-    kc = jnp.asarray(rng.normal(size=(num_blocks, bs, h, d)), jnp.float32)
-    vc = jnp.asarray(rng.normal(size=(num_blocks, bs, h, d)), jnp.float32)
+    kc = jnp.asarray(rng.normal(size=(num_blocks, h, bs, d)), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=(num_blocks, h, bs, d)), jnp.float32)
     cls = rng.integers(1, max_ctx + 1, size=B).astype(np.int32)
     width = -(-int(cls.max()) // bs)
     perm = rng.permutation(num_blocks)
@@ -41,9 +41,11 @@ def _random_paged(rng, B, h, d, bs, num_blocks, max_ctx):
 def _gathered(kc, vc, bt, cls, b, bs):
     n = int(cls[b])
     pages = bt[b, : -(-n // bs)]
-    k = np.asarray(kc)[pages].reshape(-1, *kc.shape[2:])[:n]
-    v = np.asarray(vc)[pages].reshape(-1, *vc.shape[2:])[:n]
-    return jnp.asarray(k), jnp.asarray(v), n
+    # pages are head-major (h, bs, d): lay the context out (n, h, d).
+    k = np.concatenate(list(np.asarray(kc)[pages]), axis=1)[:, :n]
+    v = np.concatenate(list(np.asarray(vc)[pages]), axis=1)[:, :n]
+    return (jnp.asarray(k.transpose(1, 0, 2)),
+            jnp.asarray(v.transpose(1, 0, 2)), n)
 
 
 @pytest.mark.parametrize("h,d,bs", [(1, 32, 8), (4, 32, 8), (2, 64, 16)])
@@ -89,7 +91,7 @@ def test_paged_attention_window1_is_bitwise_gather():
     for b in range(out.shape[0]):
         n = int(cls[b])
         blk = int(bt[b, (n - 1) // 8])
-        last = np.asarray(vc)[blk, (n - 1) % 8]
+        last = np.asarray(vc)[blk, :, (n - 1) % 8]
         assert (out[b] == last).all(), b
 
 
@@ -97,14 +99,14 @@ def test_paged_attention_ragged_single_token_context():
     """context_len=1 with a one-entry table: the smallest legal shape
     (a request admitted with a single prompt token)."""
     rng = np.random.default_rng(11)
-    kc = jnp.asarray(rng.normal(size=(4, 8, 1, 16)), jnp.float32)
-    vc = jnp.asarray(rng.normal(size=(4, 8, 1, 16)), jnp.float32)
+    kc = jnp.asarray(rng.normal(size=(4, 1, 8, 16)), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=(4, 1, 8, 16)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(1, 1, 16)), jnp.float32)
     bt = np.asarray([[2]], np.int32)
     cls = np.asarray([1], np.int32)
     out = paged_attention(q, kc, vc, bt, cls, interpret=True)
     # Softmax over one position: exactly the first row of block 2.
-    assert (np.asarray(out)[0] == np.asarray(vc)[2, 0]).all()
+    assert (np.asarray(out)[0] == np.asarray(vc)[2, :, 0]).all()
 
 
 def test_paged_attention_interpret_default_off_tpu():

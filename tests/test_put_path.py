@@ -255,7 +255,10 @@ def test_reservation_aborted_when_pusher_dies(shm_store):
     finally:
         conn.close()  # pusher "dies" between reserve and commit
     deadline = time.monotonic() + 10
-    while os.path.exists(path) and time.monotonic() < deadline:
+    # (The server unlinks first and restores the accounting right
+    # after: wait for both before judging either.)
+    while (os.path.exists(path) or shm_store._used != used0) \
+            and time.monotonic() < deadline:
         time.sleep(0.02)
     assert not os.path.exists(path), "reservation segment leaked"
     assert shm_store._used == used0, "store accounting not restored"

@@ -86,6 +86,33 @@ def test_dead_replica_replacement(ray8):
     assert counts["d"] == 2
 
 
+def test_slow_starting_replica_is_not_replaced(ray8, tmp_path):
+    """A replica whose constructor outlasts the 5 s health-check timeout
+    (on a chip, bringing the device up alone takes ~10 s) is STARTING,
+    not dead: the controller must wait for it, not drop it and queue a
+    successor behind the resources it holds — seen on the v5e as an
+    endless replace loop that never answered."""
+    marks = str(tmp_path)
+
+    @serve.deployment(num_replicas=1)
+    class Slow:
+        def __init__(self):
+            import os
+            import time
+
+            open(os.path.join(marks, str(os.getpid())), "w").close()
+            time.sleep(7.0)
+
+        def __call__(self, body):
+            return "up"
+
+    handle = serve.run(Slow.bind())
+    assert ray.get(handle.remote({}), timeout=60) == "up"
+    import os
+
+    assert len(os.listdir(marks)) == 1  # constructed exactly once
+
+
 def test_http_proxy_end_to_end(ray8):
     import requests
 

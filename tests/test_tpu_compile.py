@@ -1,0 +1,175 @@
+"""Compile the main path for a described (not attached) TPU v5e 2x2.
+
+Interpret-mode tests cannot see what the chip's compiler refuses (a dot
+form Mosaic cannot parse, a block off the (8, 128) tiling, a step that
+does not fit the device).  The TPU compiler is installed here and
+compiles for a topology that is only described, so every kernel and
+jitted step ``chip_smoke.py`` runs is compiled at its real widths —
+a compile, not a run: nothing here says a step executes or what it costs.
+
+The topology and everything built from it live in module-scoped
+fixtures of THIS file: only the xdist worker that is handed this file
+loads the TPU library (one process at a time may), and it compiles in
+its own process.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.paged_attention import paged_attention
+from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+from ray_tpu.train.core import (
+    TrainState, default_optimizer, init_train_state, make_train_step,
+    train_state_shardings)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it off around these.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return make_mesh(MeshConfig(dp=1, fsdp=2, tp=2), devices=topo.devices)
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# -- kernels -----------------------------------------------------------------
+
+@pytest.mark.parametrize("window", [0, 1])
+@pytest.mark.parametrize("B,h,d,bs,blocks,dtype", [
+    (8, 1, 32, 8, 32, jnp.float32),          # the engine's default
+    (64, 1, 1024, 16, 4096, jnp.float32),    # the smoke's serve size
+    (8, 32, 128, 16, 256, jnp.bfloat16),     # real widths
+    (8, 8, 128, 32, 256, jnp.bfloat16),
+])
+def test_paged_attention_compiles(one_chip, B, h, d, bs, blocks, dtype,
+                                  window):
+    cache = _shape((blocks, h, bs, d), dtype, one_chip)
+    compiled = jax.jit(
+        lambda q, k, v, bt, cl: paged_attention(
+            q, k, v, bt, cl, window=window, interpret=False)
+    ).lower(_shape((B, h, d), dtype, one_chip), cache, cache,
+            _shape((B, 16), jnp.int32, one_chip),
+            _shape((B,), jnp.int32, one_chip)).compile()
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_attention_compiles(one_chip, grad):
+    x = _shape((8, 2048, 32, 128), jnp.bfloat16, one_chip)
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    fn = jax.grad(f, argnums=(0, 1, 2)) if grad else f
+    assert _has_kernel(jax.jit(fn).lower(x, x, x).compile())
+
+
+# -- whole train steps -------------------------------------------------------
+
+@pytest.fixture
+def as_on_chip(monkeypatch):
+    """The model picks interpret mode from ``jax.default_backend()``,
+    which is the CPU here: steer it to the branch a chip worker takes."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_interpret_default", lambda: False)
+
+
+def _state_shapes(cfg, opt, shardings):
+    """TrainState of ShapeDtypeStructs carrying ``shardings`` — a
+    TrainState of them, or one sharding for every leaf (there is no
+    device to hold an array, so nothing is initialised)."""
+    shapes = jax.eval_shape(
+        lambda k: init_train_state(k, cfg, opt), jax.random.PRNGKey(0))
+    if not isinstance(shardings, TrainState):
+        shardings = jax.tree.map(lambda _: shardings, shapes)
+    return jax.tree.map(
+        lambda s, sh: _shape(s.shape, s.dtype, sh), shapes, shardings)
+
+
+def _smoke_cfg(**kw):
+    import chip_smoke
+
+    model = dict(chip_smoke.TRAIN_MODEL, **kw)
+    model.pop("preset")
+    model["param_dtype"] = jnp.dtype(model["param_dtype"])
+    return LlamaConfig.llama2_7b(**model)
+
+
+def _batch(sharding):
+    import chip_smoke
+
+    return {"tokens": _shape(
+        (chip_smoke.TRAIN_BATCH, chip_smoke.TRAIN_SEQ + 1), jnp.int32,
+        sharding)}
+
+
+def test_one_chip_train_step_compiles(one_chip, as_on_chip):
+    """The smoke's config (7B widths, batch 8 x 2048), depth cut to 1
+    layer to keep the compile short — the scanned layer body is the
+    same program at any depth."""
+    cfg, opt = _smoke_cfg(num_layers=1), default_optimizer()
+    compiled = make_train_step(cfg, opt).lower(
+        _state_shapes(cfg, opt, one_chip), _batch(one_chip)).compile()
+    assert _has_kernel(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_fsdp2_tp2_train_step_compiles(mesh4, as_on_chip):
+    """The --chips 4 step, built with ``mesh=`` and lowered with NO mesh
+    context (how the smoke's loop calls it)."""
+    cfg, opt = _smoke_cfg(num_layers=1), default_optimizer()
+    shardings = train_state_shardings(cfg, opt, mesh4)
+    # adam's moments must live where their parameter lives, not on chip 0
+    mu = shardings.opt_state[1][0].mu
+    assert mu["layers"]["wq"] == shardings.params["layers"]["wq"]
+    assert mu["lm_head"].spec == P("fsdp", "tp")
+    compiled = make_train_step(cfg, opt, mesh=mesh4).lower(
+        _state_shapes(cfg, opt, shardings),
+        _batch(NamedSharding(mesh4, P(("dp", "fsdp"))))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # fsdp gathers parameters and scatters gradients; tp reduces partial
+    # matmul sums: a step without them was not partitioned.
+    assert "all-gather" in text and "all-reduce" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
