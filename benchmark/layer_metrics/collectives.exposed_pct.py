@@ -1,0 +1,11 @@
+"""Time in collective operations on a chip's op stream — where no
+compute runs beside them — as a share of the traced steps' device time;
+the chip where it is largest."""
+
+
+def read(run):
+    trace = run["worker"]["trace"]
+    if not trace:
+        return None
+    return 100.0 * max(d["collective_s"] / sum(d["step_s"])
+                       for d in trace["devices"])

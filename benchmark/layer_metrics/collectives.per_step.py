@@ -1,0 +1,9 @@
+"""Collective operations executed per step on one chip (an async pair
+counts once), from the trace."""
+
+
+def read(run):
+    trace = run["worker"]["trace"]
+    if not trace:
+        return None
+    return max(d["collectives_per_step"] for d in trace["devices"])

@@ -1,0 +1,294 @@
+"""The ``train`` loop: what a cell of kind ``"loop": "train"`` runs.
+
+``loop(config)`` is the ``train_loop_per_worker`` that ``run.py`` hands to
+``JaxTrainer.fit()``.  It runs in the worker that owns the cell's chips —
+the only process of the run that imports JAX — and is written the way a
+user's loop is: build the model's config, ``init_train_state`` from the
+seed (made on the device, born sharded on a mesh), compile the one step
+program, then step on a NEW batch every time (numpy on the host, then
+``device_put``, as a loader hands it over), calling ``session.report``
+once a step.  The loss is fetched one step late, so the fetch never
+drains the device.
+
+Shape copied from ``chip_smoke.py``'s ``_train_loop`` (PR 21), not
+imported: the yardstick may not move when the program does.  From the
+program it takes only the system under test (``ray_tpu.models.llama``,
+``ray_tpu.train.core``, ``ray_tpu.parallel``, ``ray_tpu.air.session``).
+"""
+
+from __future__ import annotations
+
+import glob
+import math
+import os
+import shutil
+import tempfile
+import time
+from typing import Any, Dict, List
+
+ANNOTATIONS = ("make_batch", "device_put", "step", "report")
+
+
+def require_chips(devs, chips: int, peaks: Dict) -> None:
+    """Nothing falls back to the CPU: the worker holds exactly the
+    cell's chips, of a kind the peaks table knows, or the run fails."""
+    kind = devs[0].device_kind
+    if devs[0].platform != "tpu" or len(devs) != chips:
+        raise RuntimeError(
+            f"the cell needs {chips} TPU chip(s); this worker sees "
+            f"{len(devs)} x {devs[0].platform} ({kind})")
+    if kind not in peaks:
+        raise KeyError(f"device kind {kind!r} is not in benchmark/peaks.json")
+
+
+def program_config(conf: Dict):
+    """The program's ``LlamaConfig`` for a configuration file, through
+    the file's own ``llama_config`` map (field -> published key, or
+    ``a/b`` of two published keys)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig
+
+    def value(expr: str):
+        if "/" in expr:
+            a, b = expr.split("/")
+            return conf[a] // conf[b]
+        return conf[expr]
+
+    fields = {k: value(v) for k, v in conf["llama_config"].items()}
+    for k in ("dtype", "param_dtype"):
+        fields[k] = jnp.dtype(conf["assumed"][k]["value"])
+    return LlamaConfig(**fields)
+
+
+def measure(config: Dict[str, Any], devs, marks: Dict[str, float]
+            ) -> Dict[str, Any]:
+    """Set up, check against the reference, warm up, run the window and
+    (traced run) trace a few steps.  Returns plain data for ``run.py``."""
+    import jax
+    import numpy as np
+    from jax import monitoring
+    from jax.profiler import TraceAnnotation
+
+    from benchmark.reference import decoder
+    from ray_tpu.air import session
+    from ray_tpu.models.llama import loss_fn
+    from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+    from ray_tpu.parallel.sharding import named_sharding
+    from ray_tpu.train.core import (
+        default_optimizer, init_train_state, make_train_step)
+
+    events = {"hits": 0, "misses": 0, "compiles": 0}
+
+    def on_event(event, **_):
+        if event.endswith("/cache_hits"):
+            events["hits"] += 1
+        elif event.endswith("/cache_misses"):
+            events["misses"] += 1
+
+    def on_duration(event, duration, **_):
+        # Fires once per program handed to the backend, from the
+        # persistent cache or compiled.
+        if event.endswith("/backend_compile_duration"):
+            events["compiles"] += 1
+
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
+
+    conf, job, seed = config["conf"], config["job"], config["seed"]
+    cfg = program_config(conf)
+    rows, seq = job["rows"], job["seq"]
+    mesh = make_mesh(MeshConfig(**job["mesh"])) if job["mesh"] else None
+    batch_sharding = (devs[0] if mesh is None
+                      else named_sharding(mesh, "batch", None))
+    opt = default_optimizer()
+    marks["imports"] = time.time()
+    state = jax.block_until_ready(
+        init_train_state(jax.random.PRNGKey(seed), cfg, opt, mesh=mesh))
+    marks["state_init"] = time.time()
+
+    # (a) step-0 loss of the program against the plain reference, on a
+    # seeded sample of the cell's own sequence length; its arrays are
+    # gone before the step program's temporaries are needed.
+    sample = jax.device_put(
+        np.random.default_rng([seed, 1]).integers(
+            0, cfg.vocab_size, (job["check_rows"], seq + 1), dtype=np.int32),
+        batch_sharding)
+    program_loss = float(jax.jit(
+        lambda p, t: loss_fn(p, {"tokens": t}, cfg, mesh=mesh)[0])(
+            state.params, sample))
+    reference_loss = float(decoder.loss(state.params, sample, conf))
+    del sample
+    marks["reference_check"] = time.time()
+
+    rng = np.random.default_rng([seed, 0])
+
+    def new_batch():
+        with TraceAnnotation("make_batch"):
+            tokens = rng.integers(0, cfg.vocab_size, (rows, seq + 1),
+                                  dtype=np.int32)
+        with TraceAnnotation("device_put"):
+            return {"tokens": jax.device_put(tokens, batch_sharding)}
+
+    step_fn = make_train_step(cfg, opt, mesh=mesh)
+    t0 = time.perf_counter()
+    compiled = step_fn.lower(state, new_batch()).compile()
+    step_load_s = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    marks["step_load"] = time.time()
+
+    losses: List[float] = []
+    pending = None
+
+    def fetch_pending():
+        nonlocal pending
+        if pending is not None:
+            losses.append(float(pending["loss"]))
+            session.report({"step": len(losses), "loss": losses[-1]})
+            pending = None
+
+    def one_step():
+        """What the window repeats: batch, hand-over, dispatch, then the
+        PREVIOUS step's loss (the device is already busy with this one)."""
+        nonlocal state, pending
+        batch = new_batch()
+        with TraceAnnotation("step"):
+            state, metrics = compiled(state, batch)
+        with TraceAnnotation("report"):
+            fetch_pending()
+        pending = metrics
+
+    def run_steps(n):
+        for _ in range(n):
+            one_step()
+        fetch_pending()
+        jax.block_until_ready(state)
+
+    run_steps(job["warmup_steps"])
+
+    # The measured window: whole steps until --seconds have passed.
+    compiles_before = events["compiles"]
+    n_warm = len(losses)
+    attempted = failed = 0
+    error = None
+    window_start = time.time()
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < config["seconds"]:
+        attempted += 1
+        try:
+            one_step()
+        except Exception as e:  # noqa: BLE001 — counted, reported, fatal
+            failed, error = failed + 1, repr(e)
+            break
+    if error is None:
+        fetch_pending()
+        jax.block_until_ready(state)
+    elapsed = time.perf_counter() - t0
+    window_losses = losses[n_warm:]
+    failed += sum(1 for x in window_losses if not np.isfinite(x))
+    compiles_in_window = events["compiles"] - compiles_before
+
+    trace = None
+    if config["trace"] and error is None:
+        trace = _traced_steps(config, run_steps, f"jit_{step_fn.__name__}")
+
+    return {
+        "device": {"platform": devs[0].platform,
+                   "kind": devs[0].device_kind, "count": len(devs)},
+        "loop_start": marks["loop_start"],
+        "setup_marks": marks,
+        "window_start": window_start,
+        "check": {"program_loss": program_loss,
+                  "reference_loss": reference_loss,
+                  "rtol": decoder.LOSS_RTOL},
+        "window": {"attempted": attempted, "failed": failed,
+                   "steps": len(window_losses),
+                   "tokens": len(window_losses) * rows * seq,
+                   "elapsed_s": elapsed, "error": error,
+                   "first_loss": window_losses[0] if window_losses else None,
+                   "last_loss": window_losses[-1] if window_losses else None,
+                   "compiles": compiles_in_window},
+        "compile": {"step_load_s": step_load_s,
+                    "cache_hits": events["hits"],
+                    "cache_misses": events["misses"],
+                    "cache_dir": jax.config.jax_compilation_cache_dir,
+                    "argument_bytes": mem.argument_size_in_bytes,
+                    "temp_bytes": mem.temp_size_in_bytes},
+        "peak_bytes_in_use": [
+            (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+            for d in devs],
+        "trace": trace,
+    }
+
+
+def _traced_steps(config, run_steps, step_module):
+    """After the window: one lead-in step and ``traced_steps`` more under
+    the profiler, reduced here — only this process can trace its chips."""
+    import jax
+
+    from benchmark import trace_reduce
+
+    keep = config.get("trace_dir")
+    trace_dir = keep or tempfile.mkdtemp(prefix="benchmark-trace-")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # the loop's own annotations suffice
+    try:
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            run_steps(config["job"]["traced_steps"] + 1)
+        finally:
+            jax.profiler.stop_trace()
+        files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                          recursive=True)
+        if not files:
+            return None
+        return trace_reduce.reduce_file(max(files, key=os.path.getmtime),
+                                        step_module=step_module,
+                                        annotations=ANNOTATIONS)
+    finally:
+        if not keep:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+
+
+def correct(run: Dict[str, Any]) -> bool:
+    """Driver side.  (a) the program's step-0 loss equals the plain
+    reference's within its stated tolerance; (b) every loss of the window
+    finite and no step raised; (c) on several chips, per-chip peaks
+    within 20 %; (d) nothing compiled or loaded inside the window."""
+    w = run["worker"]
+    check, window, peaks = w["check"], w["window"], w["peak_bytes_in_use"]
+    return bool(
+        math.isfinite(check["program_loss"])
+        and abs(check["program_loss"] - check["reference_loss"])
+        <= check["rtol"] * abs(check["reference_loss"])
+        and window["steps"] > 0 and window["failed"] == 0
+        and window["error"] is None
+        and min(peaks) > 0 and max(peaks) <= 1.2 * min(peaks)
+        and window["compiles"] == 0)
+
+
+def end_to_end(run: Dict[str, Any]) -> Dict[str, float]:
+    """Driver side.  Tokens of all steps completed in the window over the
+    time from the first measured step's start to the last one's
+    ``block_until_ready`` (worker's host clock; for the cell as a whole,
+    not per chip), and process start to the first measured step."""
+    w = run["worker"]
+    return {
+        "train_tokens_per_s": w["window"]["tokens"] / w["window"]["elapsed_s"],
+        "setup_s": w["window_start"] - run["process_start"],
+    }
+
+
+def loop(config: Dict[str, Any]) -> None:
+    # Wall-clock marks that split set-up, from before JAX is imported
+    # and brings the chips up.
+    marks = {"loop_start": time.time()}
+    import jax
+
+    from ray_tpu.air import session
+
+    marks["import_jax"] = time.time()
+    devs = jax.devices()
+    marks["devices"] = time.time()
+    require_chips(devs, config["chips"], config["peaks"])
+    session.report(measure(config, devs, marks))
