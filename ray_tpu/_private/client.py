@@ -345,6 +345,7 @@ def client_connect(address: str, authkey: bytes,
             _t.sleep(0.25)
             try:
                 rt.flush_decrefs()
+                rt.flush_spans()  # a client driver's own spans
                 # Lease-plane counter deltas (leased_submits/spillbacks):
                 # a client drives direct pushes too and its counters feed
                 # the same head-side transfer_stats aggregation.

@@ -693,6 +693,7 @@ class DirectCaller:
             "kwargs": {k: subst(v)
                        for k, v in (spec.get("kwargs") or {}).items()},
             "resources": spec.get("resources") or {},
+            "span": spec.get("span"),
         }
         if "actor_id" in spec:
             task["actor_id"] = spec["actor_id"]

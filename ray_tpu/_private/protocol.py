@@ -160,7 +160,8 @@ VERBS = {
     "result_batch": Verb(("worker",), ("head",), (2, 2),
                          "coalesced results (one pickle+write)"),
     "spans": Verb(("worker",), ("head",), (2, 2),
-                  "task execution spans (ray timeline)"),
+                  "util.tracing spans: task roots, what opens inside "
+                  "them (ray timeline)"),
     "event": Verb(("worker",), ("head",), (3, 3),
                   "generic worker->driver pubsub (train streaming)"),
     "xfer_stats": Verb(("worker",), ("head",), (2, 2),

@@ -51,6 +51,7 @@ from ray_tpu._private.ids import (
 )
 from ray_tpu._private.shm_store import ShmStore
 from ray_tpu import exceptions as exc
+from ray_tpu.util import tracing
 
 PENDING, READY, ERRORED = 0, 1, 2
 
@@ -189,7 +190,7 @@ class WorkerHandle:
         "pending_force_kill", "direct_addr", "client_lease",
         "oom_killed", "last_dispatch_ts", "lease_expiry",
         "lease_offer_ts", "lease_caps", "last_seen", "hc_suspect",
-        "hc_misses", "hc_probe_ts",
+        "hc_misses", "hc_probe_ts", "spawn_span",
     )
 
     def __init__(self, worker_id, conn, proc, node, env_key, tpu_chips):
@@ -213,6 +214,9 @@ class WorkerHandle:
         self.outbox: List[tuple] = []
         self.outbuf: List[tuple] = []  # conflation-sender batch buffer
         self.spawned_at = time.monotonic()
+        # (launch wall time, creation spec) while the process of an actor
+        # boots; closed as the span ``worker.spawn`` by its ``ready``.
+        self.spawn_span: Optional[tuple] = None
         # Lease state: while leased, the worker holds lease_req resources on
         # its node (or lease_pg's bundle) and serves one scheduling class.
         self.lease_key: Optional[tuple] = None
@@ -643,6 +647,9 @@ class Runtime:
         self.microbatch_pushes = 0
         self.stage_restarts = 0
         self.learner_queue_stalls = 0
+        # The driver-process share is a process-wide cumulative registry:
+        # what an EARLIER runtime of this process counted is not ours.
+        self._train_stats_base = self._process_train_stats()
         # Drain rendezvous: aid -> Event set when the forced
         # ("checkpoint_now", aid) round-trips as an actor_checkpoint;
         # node_id -> [done_event, outcome, deadline_abs] for that
@@ -803,9 +810,11 @@ class Runtime:
         # count conservatively high).
         self._actor_tokens: Dict[bytes, bytes] = {}
         self._actor_tokens_consumed: set = set()
-        # Task execution spans (worker "spans" batches) + per-message-
-        # handler latency stats (reference: task events + event_stats.h).
+        # Spans (worker "spans" batches, the driver's and the head's own
+        # through record_span) + per-message-handler latency stats
+        # (reference: task events + event_stats.h).
         self.task_spans: deque = deque(maxlen=200_000)
+        self._span_lock = threading.Lock()  # lock-order: leaf
         self._handler_stats: Dict[str, list] = {}
         self._handler_stats_lock = threading.Lock()
         self._sender = threading.Thread(
@@ -2660,7 +2669,10 @@ class Runtime:
         if idle:
             w = idle.pop()
             return w
-        return self._spawn_worker(node, env_key, rec, tpu_chips)
+        w = self._spawn_worker(node, env_key, rec, tpu_chips)
+        if rec.is_actor_creation:
+            w.spawn_span = (time.time(), rec.spec)
+        return w
 
     def _worker_config_env(self) -> Dict[str, str]:
         """Config knobs that follow _system_config overrides into workers
@@ -3023,6 +3035,9 @@ class Runtime:
                 self._on_worker_death(w)
                 continue
             w.ready.set()
+            if w.spawn_span is not None:
+                (launched, spec), w.spawn_span = w.spawn_span, None
+                self._record_creation_span("worker.spawn", spec, launched)
             # One reader thread per connection (replaces the old select
             # loop): recv/unpickle for different workers runs in parallel,
             # and a burst from one worker is drained back-to-back instead
@@ -3644,6 +3659,7 @@ class Runtime:
             "num_returns": spec["num_returns"],
             "name": spec.get("name", "task"),
             "resources": rec.requirements,
+            "span": spec.get("span"),
         }
         if "actor_id" in spec:
             msg_task["actor_id"] = spec["actor_id"]
@@ -3655,6 +3671,7 @@ class Runtime:
             worker.queue_msg(("func", func_id, self.functions[func_id]))
             sent.add(func_id)
         if rec.is_actor_creation:
+            self._record_creation_span("sched.wait", spec)
             actor = self.actors[rec.actor_id]
             # Restartable-actor checkpointing: the worker arms the
             # __ray_save__ hook only when recovery is on AND the actor
@@ -4642,6 +4659,30 @@ class Runtime:
                 if dt > s[2]:
                     s[2] = dt
 
+    def _store_spans(self, records, worker_id: str, node_id: str):
+        with self._span_lock:
+            for rec in records:
+                self.task_spans.append(
+                    tracing.span_record(rec, worker_id, node_id))
+
+    def record_span(self, rec: tuple):
+        """A span of this process (util.tracing.span in the driver, or
+        the head's own): straight into the store, on the driver's lane."""
+        self._store_spans([rec], "driver", self.head_node.node_id.hex())
+
+    def _record_creation_span(self, name: str, spec: dict,
+                              start: Optional[float] = None):
+        """The head's share of an actor's start, caused by the span
+        that created the actor: ``sched.wait`` (submitted -> dispatched;
+        holds the wait for resources and for a retiring worker's chips)
+        and ``worker.spawn`` (process launched -> ``ready``)."""
+        parent, submitted = spec.get("span") or (None, None)
+        if submitted is None:
+            return  # a restart the head made up itself: nobody waited
+        self.record_span((
+            spec["task_id"], name, submitted if start is None else start,
+            time.time(), "head", tracing.new_id(), parent, None, None))
+
     def _handle_worker_msg_inner(self, worker: WorkerHandle, msg: tuple):
         tag = msg[0]
         if tag == "ready":
@@ -4657,15 +4698,9 @@ class Runtime:
         elif tag == "spans":
             # Task execution spans from a worker (task events; feeds
             # `ray_tpu.timeline()` — scripts.py:1840 `ray timeline`).
-            wid = worker.worker_id.hex()
-            nid = (worker.node.node_id.hex()
-                   if worker.node is not None else "")
-            with self.lock:
-                for tid_bin, name, start, end, kind in msg[1]:
-                    self.task_spans.append({
-                        "task_id": tid_bin.hex(), "name": name,
-                        "start": start, "end": end, "kind": kind,
-                        "worker_id": wid, "node_id": nid})
+            self._store_spans(
+                msg[1], worker.worker_id.hex(),
+                worker.node.node_id.hex() if worker.node is not None else "")
         elif tag == "event":
             # Generic worker->driver pubsub (reference: src/ray/pubsub/
             # long-poll channels) — used by train session streaming and
@@ -6487,7 +6522,12 @@ class Runtime:
                     "removed": pg.removed,
                 } for pg in self.placement_groups.values()][:limit]
         if kind == "spans":
-            with self.lock:
+            parents = filters.get("parents")
+            with self._span_lock:
+                if parents is not None:
+                    parents = set(parents)
+                    return [s for s in self.task_spans
+                            if s["parent"] in parents][-limit:]
                 n = len(self.task_spans)
                 return list(itertools.islice(self.task_spans,
                                              max(0, n - limit), None))
@@ -6515,6 +6555,12 @@ class Runtime:
                                        key=lambda kv: -kv[1][1])][:limit]
         raise ValueError(f"unknown state query kind {kind!r}")
 
+    @staticmethod
+    def _process_train_stats() -> Dict[str, int]:
+        # Lazy module lookup: never imported means all-zero.
+        return getattr(sys.modules.get("ray_tpu.train.pipeline_actors"),
+                       "train_stats", dict)()
+
     def transfer_stats(self) -> Dict[str, int]:
         """Data-plane + locality counters in one snapshot: the scheduler's
         locality accounting plus the aggregated worker-side prefetch/
@@ -6536,9 +6582,9 @@ class Runtime:
         # driver and IMPALA's learner-side loader usually ARE this head
         # process, so their counters live in the train module's
         # process-local registry, not in any worker delta.
-        head_train = getattr(
-            sys.modules.get("ray_tpu.train.pipeline_actors"),
-            "train_stats", dict)()
+        head_train = {
+            k: v - self._train_stats_base.get(k, 0)
+            for k, v in self._process_train_stats().items()}
         with self.lock:
             return {
                 "shuffle_pushed_bytes":

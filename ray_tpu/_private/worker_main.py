@@ -41,6 +41,7 @@ from ray_tpu._private import object_ref as object_ref_mod
 from ray_tpu._private.object_ref import ObjectRef
 from ray_tpu._private.shm_store import ShmStore
 from ray_tpu import exceptions as exc
+from ray_tpu.util import tracing
 
 
 class _WorkerRuntime:
@@ -133,7 +134,8 @@ class _WorkerRuntime:
         # (reference: batched reply streams; kills per-task head wakeups).
         self._result_buf: list = []
         self._result_lock = threading.Lock()
-        # Task execution spans, shipped to the head in periodic batches
+        # Spans (util.tracing.span: every task's root span and whatever
+        # opens inside it), shipped to the head in periodic batches
         # (reference: task events / tracing_helper.py span injection —
         # every task records submit->run->finish wall times; the head
         # aggregates them for `ray timeline`).
@@ -367,10 +369,10 @@ class _WorkerRuntime:
         else:
             self._send(("result_batch", buf))
 
-    def record_span(self, task_id_bin: bytes, name: str, start: float,
-                    end: float, kind: str):
+    def record_span(self, rec: tuple):
+        """``rec``: util.tracing.span_record's tuple."""
         with self._result_lock:
-            self._span_buf.append((task_id_bin, name, start, end, kind))
+            self._span_buf.append(rec)
 
     def flush_spans(self):
         with self._result_lock:
@@ -1518,74 +1520,72 @@ def _execute(rt: _WorkerRuntime, fns: _FunctionCache, task: dict,
 
     Reference: _raylet.pyx:702 execute_task — deserialize args, invoke,
     store returns (small inline to owner, large to plasma/shm)."""
-    import time as _time
-
     recovery.syncpoint("exec_start")
     task_id = TaskID(task["task_id"])
     dreply = task.pop("_dreply", None)
     rt.current_task_id = task_id
     num_returns = task["num_returns"]
     name = task.get("name", "task")
-    span_start = _time.time()
-    with rt._exec_lock:
-        rt._executing += 1
-        # Tracked for the failover re-register payload: a head restart
-        # mid-execution must learn this task is still producing results
-        # here (direct-pushed tasks are owned by their caller, not the
-        # head, and are excluded at snapshot time).
-        rt._executing_tasks.append((task, dreply is not None))
-    try:
-        args, kwargs = _load_args(rt, task)
-        if "actor_id" in task:
-            actor = actors[task["actor_id"]]
-            rt.current_actor_id = ActorID(task["actor_id"])
-            method = getattr(actor, task["method"])
-            result = method(*args, **kwargs)
-            if asyncio.iscoroutine(result):
-                result = _run_coroutine(result)
-        else:
-            fn = fns.get(task["func_id"])
-            result = fn(*args, **kwargs)
-            if asyncio.iscoroutine(result):
-                result = _run_coroutine(result)
-        returns, nested = _pack_returns(rt, task_id, result, num_returns)
-        if dreply is not None:
-            # Direct-pushed task: the reply goes straight to the owning
-            # caller on its connection, never through the head.  Nested
-            # ref bins ride in meta; this worker addrefs them at the head
-            # ON THE CALLER'S BEHALF (the caller's owned entry decrefs on
-            # free) so an LRU eviction here cannot free a returned ref
-            # before the caller materializes it.
-            meta = {}
-            if any(nested):
-                rt._send(("addref_batch",
-                          [b for lst in nested for b in lst]))
-                meta = {"nested": nested}
-            dreply[0].reply(dreply[1], True, returns, meta)
-        else:
-            rt.send_result((task["task_id"], True, returns, {}))
-        if "actor_id" in task:
-            # After the reply (off the caller's latency path): persist
-            # __ray_save__ state for restartable actors.
-            rt.maybe_checkpoint_actor(task["actor_id"], actor)
-    except Exception as e:  # noqa: BLE001 — task errors become objects
-        err = exc.TaskError.from_exception(name, e)
-        payload = _pickle_error(err)
-        returns = [(protocol.ERROR, payload)] * max(1, num_returns)
-        if dreply is not None:
-            dreply[0].reply(dreply[1], False, returns, {})
-        else:
-            rt.send_result((task["task_id"], False, returns, {}))
-    finally:
+    # The task's root span: whatever opens inside it has it as parent,
+    # and in a process that has JAX it is on the device trace's clock.
+    with tracing.task_span(task):
         with rt._exec_lock:
-            rt._executing -= 1
-            rt._executing_tasks = [
-                (t, d) for t, d in rt._executing_tasks
-                if t is not task]
-        rt.current_task_id = None
-        rt.current_actor_id = None
-        rt.record_span(task["task_id"], name, span_start, _time.time(),
-                       "actor_method" if "actor_id" in task else "task")
+            rt._executing += 1
+            # Tracked for the failover re-register payload: a head restart
+            # mid-execution must learn this task is still producing results
+            # here (direct-pushed tasks are owned by their caller, not the
+            # head, and are excluded at snapshot time).
+            rt._executing_tasks.append((task, dreply is not None))
+        try:
+            args, kwargs = _load_args(rt, task)
+            if "actor_id" in task:
+                actor = actors[task["actor_id"]]
+                rt.current_actor_id = ActorID(task["actor_id"])
+                method = getattr(actor, task["method"])
+                result = method(*args, **kwargs)
+                if asyncio.iscoroutine(result):
+                    result = _run_coroutine(result)
+            else:
+                fn = fns.get(task["func_id"])
+                result = fn(*args, **kwargs)
+                if asyncio.iscoroutine(result):
+                    result = _run_coroutine(result)
+            returns, nested = _pack_returns(rt, task_id, result, num_returns)
+            if dreply is not None:
+                # Direct-pushed task: the reply goes straight to the owning
+                # caller on its connection, never through the head.  Nested
+                # ref bins ride in meta; this worker addrefs them at the head
+                # ON THE CALLER'S BEHALF (the caller's owned entry decrefs on
+                # free) so an LRU eviction here cannot free a returned ref
+                # before the caller materializes it.
+                meta = {}
+                if any(nested):
+                    rt._send(("addref_batch",
+                              [b for lst in nested for b in lst]))
+                    meta = {"nested": nested}
+                dreply[0].reply(dreply[1], True, returns, meta)
+            else:
+                rt.send_result((task["task_id"], True, returns, {}))
+            if "actor_id" in task:
+                # After the reply (off the caller's latency path): persist
+                # __ray_save__ state for restartable actors.
+                rt.maybe_checkpoint_actor(task["actor_id"], actor)
+        except Exception as e:  # noqa: BLE001 — task errors become objects
+            err = exc.TaskError.from_exception(name, e)
+            payload = _pickle_error(err)
+            returns = [(protocol.ERROR, payload)] * max(1, num_returns)
+            if dreply is not None:
+                dreply[0].reply(dreply[1], False, returns, {})
+            else:
+                rt.send_result((task["task_id"], False, returns, {}))
+        finally:
+            with rt._exec_lock:
+                rt._executing -= 1
+                rt._executing_tasks = [
+                    (t, d) for t, d in rt._executing_tasks
+                    if t is not task]
+            rt.current_task_id = None
+            rt.current_actor_id = None
 
 
 def _pickle_error(err):

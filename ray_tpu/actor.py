@@ -22,6 +22,7 @@ from ray_tpu._private import serialization
 from ray_tpu._private.api_internal import require_runtime
 from ray_tpu._private.ids import ActorID, new_task_id
 from ray_tpu._private.object_ref import ObjectRef
+from ray_tpu.util import tracing
 from ray_tpu.remote_function import (
     _normalize_resources,
     _strategy_tuple,
@@ -113,6 +114,7 @@ class ActorHandle:
             "name": f"actor.{method_name}",
             "func_id": None,
         }
+        tracing.stamp(spec)
         serialize_args(rt, args, kwargs, spec)
         return spec
 
@@ -245,6 +247,7 @@ class ActorClass:
             "scheduling_strategy": _strategy_tuple(
                 opts.get("scheduling_strategy")),
         }
+        tracing.stamp(spec)
         serialize_args(rt, args, kwargs, spec)
         creation_opts = {
             "max_restarts": opts.get("max_restarts", 0),

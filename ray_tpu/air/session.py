@@ -19,6 +19,7 @@ import threading
 from typing import Any, Dict, List, Optional
 
 from ray_tpu.air.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 _local = threading.local()
 
@@ -40,6 +41,11 @@ class _TrainSession:
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None):
+        with tracing.span("session.report"):
+            self._report(metrics, checkpoint)
+
+    def _report(self, metrics: Dict[str, Any],
+                checkpoint: Optional[Checkpoint]):
         entry = dict(metrics)
         entry["_training_iteration"] = len(self.reports)
         self.reports.append(entry)

@@ -172,6 +172,7 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh]):
         # Ring/ulysses degenerate to plain attention on one device.
         if impl == "flash":
             return flash_attention(q, k, v, causal=True)
+        k, v = repeat_kv_heads(q, k, v)
         return mha_reference(q, k, v, causal=True)
     if impl == "ring":
         return ring_attention(q, k, v, causal=True, mesh=mesh)
@@ -217,25 +218,31 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig, *,
     ``parallel.pipeline.forward_pipelined`` — manual SPMD.)
     """
     cst = _make_cst(mesh, rules)
-    b, s = tokens.shape
-    if mesh is not None:
-        # One-hot matmul instead of gather: with a ('vocab','embed')-sharded
-        # table this lowers to a local matmul + psum over 'tp' — the gather
-        # form makes the SPMD partitioner fully rematerialize the table.
-        onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.dtype)
-        x = onehot @ params["embed"].astype(cfg.dtype)
-    else:
-        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
-    x = cst(x, ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        if mesh is not None:
+            # One-hot matmul instead of gather: with a ('vocab','embed')-
+            # sharded table this lowers to a local matmul + psum over 'tp'
+            # — the gather form makes the SPMD partitioner fully
+            # rematerialize the table.
+            onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.dtype)
+            x = onehot @ params["embed"].astype(cfg.dtype)
+        else:
+            x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+        x = cst(x, ("batch", "seq", "embed"))
     layer_fn = _make_layer_fn(cfg, mesh, rules)
     if cfg.remat:
         layer_fn = jax.checkpoint(layer_fn)
     (x, aux), _ = jax.lax.scan(layer_fn, (x, jnp.zeros((), jnp.float32)),
                                params["layers"])
-    x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
-    logits = cst(logits, ("batch", "seq", "vocab"))
-    return logits, aux / cfg.num_layers
+    return _lm_head(params, x, cfg, cst), aux / cfg.num_layers
+
+
+def _lm_head(params, x, cfg: LlamaConfig, cst):
+    """Final norm and head product -> f32 logits (scope ``lm_head``)."""
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"])
+        logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+        return cst(logits, ("batch", "seq", "vocab"))
 
 
 def _make_cst(mesh, rules):
@@ -260,41 +267,47 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False):
     def layer_fn(carry, lp):
         x, aux = carry
         b, s = x.shape[0], x.shape[1]
-        offset = 0
-        if sp_manual:
-            offset = jax.lax.axis_index(AXIS_SP) * s
-        cos, sin = rope(s, cfg.head_dim, cfg.rope_theta, offset=offset)
-        h = rms_norm(x, lp["attn_norm"])
-        q = (h @ lp["wq"].astype(cfg.dtype)).reshape(
-            b, s, cfg.num_heads, cfg.head_dim)
-        k = (h @ lp["wk"].astype(cfg.dtype)).reshape(
-            b, s, cfg.num_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"].astype(cfg.dtype)).reshape(
-            b, s, cfg.num_kv_heads, cfg.head_dim)
-        q = cst(apply_rope(q, cos, sin), ("batch", "seq", "heads", "head_dim"))
-        k = cst(apply_rope(k, cos, sin),
-                ("batch", "seq", "kv_heads", "head_dim"))
-        if sp_manual:
-            o = _attention_sp_manual(q, k, v, cfg)
-        else:
-            o = _attention(q, k, v, cfg, mesh)
-        o = o.reshape(b, s, cfg.qkv_dim)
-        x = x + cst(o @ lp["wo"].astype(cfg.dtype), ("batch", "seq", "embed"))
+        with jax.named_scope("attn_qkv"):
+            offset = 0
+            if sp_manual:
+                offset = jax.lax.axis_index(AXIS_SP) * s
+            cos, sin = rope(s, cfg.head_dim, cfg.rope_theta, offset=offset)
+            h = rms_norm(x, lp["attn_norm"])
+            q = (h @ lp["wq"].astype(cfg.dtype)).reshape(
+                b, s, cfg.num_heads, cfg.head_dim)
+            k = (h @ lp["wk"].astype(cfg.dtype)).reshape(
+                b, s, cfg.num_kv_heads, cfg.head_dim)
+            v = (h @ lp["wv"].astype(cfg.dtype)).reshape(
+                b, s, cfg.num_kv_heads, cfg.head_dim)
+            q = cst(apply_rope(q, cos, sin),
+                    ("batch", "seq", "heads", "head_dim"))
+            k = cst(apply_rope(k, cos, sin),
+                    ("batch", "seq", "kv_heads", "head_dim"))
+        with jax.named_scope("attention"):
+            if sp_manual:
+                o = _attention_sp_manual(q, k, v, cfg)
+            else:
+                o = _attention(q, k, v, cfg, mesh)
+        with jax.named_scope("attn_out"):
+            o = o.reshape(b, s, cfg.qkv_dim)
+            x = x + cst(o @ lp["wo"].astype(cfg.dtype),
+                        ("batch", "seq", "embed"))
 
-        h = rms_norm(x, lp["mlp_norm"])
-        if cfg.num_experts:
-            flat = h.reshape(b * s, cfg.embed_dim)
-            moe = moe_ffn(flat, lp["router"], lp["w_gate"], lp["w_up"],
-                          lp["w_down"], num_selected=cfg.num_selected,
-                          capacity_factor=cfg.capacity_factor,
-                          constrain=cst if mesh is not None else None)
-            ff = moe.out.reshape(b, s, cfg.embed_dim)
-            aux = aux + moe.aux_loss
-        else:
-            gate = h @ lp["w_gate"].astype(cfg.dtype)
-            up = h @ lp["w_up"].astype(cfg.dtype)
-            ff = swiglu(gate, up) @ lp["w_down"].astype(cfg.dtype)
-        x = x + cst(ff, ("batch", "seq", "embed"))
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, lp["mlp_norm"])
+            if cfg.num_experts:
+                flat = h.reshape(b * s, cfg.embed_dim)
+                moe = moe_ffn(flat, lp["router"], lp["w_gate"], lp["w_up"],
+                              lp["w_down"], num_selected=cfg.num_selected,
+                              capacity_factor=cfg.capacity_factor,
+                              constrain=cst if mesh is not None else None)
+                ff = moe.out.reshape(b, s, cfg.embed_dim)
+                aux = aux + moe.aux_loss
+            else:
+                gate = h @ lp["w_gate"].astype(cfg.dtype)
+                up = h @ lp["w_up"].astype(cfg.dtype)
+                ff = swiglu(gate, up) @ lp["w_down"].astype(cfg.dtype)
+            x = x + cst(ff, ("batch", "seq", "embed"))
         return (x, aux), None
 
     return layer_fn
@@ -320,10 +333,10 @@ def forward_pipelined(params: Dict[str, Any], tokens: jax.Array,
     from ray_tpu.parallel.mesh import AXIS_PP
 
     cst = _make_cst(mesh, rules)
-    b, s = tokens.shape
-    onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.dtype)
-    x = cst(onehot @ params["embed"].astype(cfg.dtype),
-            ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.dtype)
+        x = cst(onehot @ params["embed"].astype(cfg.dtype),
+                ("batch", "seq", "embed"))
 
     sp_manual = cfg.attn_impl in ("ring", "ulysses") and \
         mesh.shape[AXIS_SP] > 1
@@ -351,9 +364,7 @@ def forward_pipelined(params: Dict[str, Any], tokens: jax.Array,
     x = pipeline_apply(stage_fn, stages, x, mesh=mesh,
                        num_microbatches=num_microbatches,
                        manual_axes=manual_axes, x_spec=x_spec)
-    x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
-    return cst(logits, ("batch", "seq", "vocab")), jnp.zeros((), jnp.float32)
+    return _lm_head(params, x, cfg, cst), jnp.zeros((), jnp.float32)
 
 
 def pipeline_stage_params(params: Dict[str, Any],
@@ -395,12 +406,12 @@ def make_pipeline_stage_fn(cfg: LlamaConfig):
         if cfg.remat:
             layer_fn = jax.checkpoint(layer_fn)
         if "embed" in sp:
-            x = jnp.take(sp["embed"], x, axis=0).astype(cfg.dtype)
+            with jax.named_scope("embed"):
+                x = jnp.take(sp["embed"], x, axis=0).astype(cfg.dtype)
         (x, _), _ = jax.lax.scan(
             layer_fn, (x, jnp.zeros((), jnp.float32)), sp["layers"])
         if "lm_head" in sp:
-            x = rms_norm(x, sp["final_norm"])
-            x = (x @ sp["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+            x = _lm_head(sp, x, cfg, _make_cst(None, None))
         return x
 
     return stage_fn
@@ -412,9 +423,8 @@ def make_pipeline_loss_fn(cfg: LlamaConfig):
     pair for the actor pipeline's loss stage."""
 
     def pipeline_loss(logits, targets):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(nll)
+        with jax.named_scope("loss"):
+            return _mean_nll(logits, targets)
 
     return pipeline_loss
 
@@ -437,9 +447,14 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
         logits, aux = forward(params, inputs, cfg, mesh=mesh, rules=rules)
     else:
         logits, aux = forward_fn(params, inputs)
+    with jax.named_scope("loss"):
+        loss = _mean_nll(logits, targets)
+        total = loss + cfg.aux_loss_coef * aux
+        return total, {"loss": loss, "aux_loss": aux,
+                       "perplexity": jnp.exp(loss)}
+
+
+def _mean_nll(logits, targets):
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    loss = jnp.mean(nll)
-    total = loss + cfg.aux_loss_coef * aux
-    return total, {"loss": loss, "aux_loss": aux,
-                   "perplexity": jnp.exp(loss)}
+    return jnp.mean(nll)

@@ -172,6 +172,7 @@ def _fwd_call(qt, kt, vt, causal, block_q, block_k, interpret):
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return o, lse
 
@@ -294,6 +295,7 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, block_q, block_k,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name="flash_dkv",
     )(qt, kt, vt, dot, lse, delta)
 
     dq = pl.pallas_call(
@@ -306,6 +308,7 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name="flash_dq",
     )(qt, kt, vt, dot, lse, delta)
     return dq, dk, dv
 

@@ -17,6 +17,7 @@ from ray_tpu._private import serialization
 from ray_tpu._private.api_internal import require_runtime
 from ray_tpu._private.ids import new_task_id
 from ray_tpu._private.object_ref import ObjectRef
+from ray_tpu.util import tracing
 
 _VALID_OPTIONS = {
     "num_cpus", "num_tpus", "num_gpus", "resources", "num_returns",
@@ -235,6 +236,7 @@ class RemoteFunction:
                     "retry_exceptions must be True/False, an exception "
                     f"type, or a list of exception types; got {rexc!r}")
             spec["retry_exceptions"] = rexc
+        tracing.stamp(spec)
         serialize_args(rt, args, kwargs, spec)
         if payload is not None and rt.is_worker():
             spec["func_payload"] = payload

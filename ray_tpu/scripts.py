@@ -181,6 +181,20 @@ def _cmd_timeline(args):
         rt.disconnect()
 
 
+def _cmd_step_breakdown(args):
+    """Device time of a profiler trace by step scope and phase
+    (``util.tracing.step_breakdown``); needs no cluster."""
+    from ray_tpu.util.tracing import format_breakdown, step_breakdown
+
+    b = step_breakdown(args.xplane, args.step_module)
+    if b is None:
+        sys.exit(f"no two executions of {args.step_module} in {args.xplane}")
+    print(format_breakdown(b))
+    if args.json:
+        with open(args.json, "w", encoding="utf-8") as f:
+            json.dump(b, f, indent=1)
+
+
 def _cmd_handler_stats(args):
     rt = _client(args)
     try:
@@ -275,6 +289,15 @@ def main(argv=None):
         "handler-stats", help="head per-message-handler latency stats")
     common(hs)
     hs.set_defaults(fn=_cmd_handler_stats)
+
+    bd = sub.add_parser(
+        "step-breakdown",
+        help="device time of a profiler trace (.xplane.pb) by step scope "
+             "and phase")
+    bd.add_argument("xplane")
+    bd.add_argument("--step-module", default="jit_step")
+    bd.add_argument("--json", default=None, help="also write it as JSON")
+    bd.set_defaults(fn=_cmd_step_breakdown)
 
     args = p.parse_args(argv)
     args.fn(args)

@@ -18,6 +18,7 @@ from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.air.config import ScalingConfig
 from ray_tpu.train.backend import Backend, JaxConfig
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import tracing
 from ray_tpu.util.placement_group import placement_group, remove_placement_group
 
 
@@ -38,22 +39,29 @@ class BackendExecutor:
 
     def start(self):
         sc = self._scaling
-        bundles = [sc.worker_resources() for _ in range(sc.num_workers)]
-        self._pg = placement_group(bundles, strategy=sc.placement_strategy)
-        ray.get(self._pg.ready(), timeout=60)
-        self._worker_group = WorkerGroup(
-            sc.num_workers, sc.worker_resources(), placement_group=self._pg)
-        # rank/world env (reference: backend_executor.py:255)
-        futs = []
-        for rank, w in enumerate(self._worker_group.workers):
-            futs.append(w.set_env.remote({
-                "RANK": str(rank),
-                "WORLD_RANK": str(rank),
-                "WORLD_SIZE": str(sc.num_workers),
-                "LOCAL_RANK": "0",
-            }))
-        ray.get(futs)
-        self._backend.on_start(self._worker_group, self._backend_config)
+        with tracing.span("train.placement_group"):
+            bundles = [sc.worker_resources() for _ in range(sc.num_workers)]
+            self._pg = placement_group(bundles,
+                                       strategy=sc.placement_strategy)
+            ray.get(self._pg.ready(), timeout=60)
+        # Actor creation to the first reply of every worker; the head's
+        # ``sched.wait`` and ``worker.spawn`` of each fall inside it.
+        with tracing.span("train.start_workers", workers=sc.num_workers):
+            self._worker_group = WorkerGroup(
+                sc.num_workers, sc.worker_resources(),
+                placement_group=self._pg)
+            # rank/world env (reference: backend_executor.py:255)
+            futs = []
+            for rank, w in enumerate(self._worker_group.workers):
+                futs.append(w.set_env.remote({
+                    "RANK": str(rank),
+                    "WORLD_RANK": str(rank),
+                    "WORLD_SIZE": str(sc.num_workers),
+                    "LOCAL_RANK": "0",
+                }))
+            ray.get(futs)
+        with tracing.span("train.backend_start"):
+            self._backend.on_start(self._worker_group, self._backend_config)
 
     @property
     def worker_group(self) -> WorkerGroup:
@@ -77,30 +85,32 @@ class BackendExecutor:
         topic = f"train-{uuid.uuid4().hex[:12]}"
         self._topic = topic
         ckpt = checkpoint.to_bytes() if checkpoint is not None else None
-        futs = []
-        for rank, w in enumerate(wg.workers):
-            session_kwargs = {
-                "world_rank": rank,
-                "world_size": wg.num_workers,
-                "local_rank": 0,
-                "checkpoint": Checkpoint.from_bytes(ckpt) if ckpt else None,
-                "stream_topic": topic,
-            }
-            futs.append(w.run_train_fn.remote(train_fn, config,
-                                              session_kwargs))
         from ray_tpu._private.api_internal import require_runtime
         rt = require_runtime()
-        pending = list(futs)
-        try:
-            while pending:
-                _, pending = ray.wait(pending, num_returns=len(pending),
-                                      timeout=0.25)
+        with tracing.span("train.run"):
+            futs = []
+            for rank, w in enumerate(wg.workers):
+                session_kwargs = {
+                    "world_rank": rank,
+                    "world_size": wg.num_workers,
+                    "local_rank": 0,
+                    "checkpoint": (Checkpoint.from_bytes(ckpt)
+                                   if ckpt else None),
+                    "stream_topic": topic,
+                }
+                futs.append(w.run_train_fn.remote(train_fn, config,
+                                                  session_kwargs))
+            pending = list(futs)
+            try:
+                while pending:
+                    _, pending = ray.wait(pending, num_returns=len(pending),
+                                          timeout=0.25)
+                    self._drain_stream(rt, topic, pickle)
                 self._drain_stream(rt, topic, pickle)
-            self._drain_stream(rt, topic, pickle)
-            return ray.get(futs)
-        except Exception as e:
-            self._drain_stream(rt, topic, pickle)
-            raise TrainingFailedError(str(e)) from e
+                return ray.get(futs)
+            except Exception as e:
+                self._drain_stream(rt, topic, pickle)
+                raise TrainingFailedError(str(e)) from e
 
     def _drain_stream(self, rt, topic: str, pickle):
         for raw in rt.poll_events(topic):
@@ -114,6 +124,10 @@ class BackendExecutor:
                     ev["checkpoint"])
 
     def shutdown(self):
+        with tracing.span("train.shutdown"):
+            self._shutdown()
+
+    def _shutdown(self):
         if self._worker_group is not None:
             try:
                 self._backend.on_shutdown(self._worker_group,

@@ -27,6 +27,16 @@ from ray_tpu.parallel.sharding import (
 )
 
 
+# The ``jax.named_scope`` names that between them cover the step program,
+# with no overlap: ``models/llama.py`` opens all but the last, ``step``
+# below opens ``optimizer``.  A device op's ``op_name`` carries exactly one
+# of them, wrapped by JAX in the phase: bare or ``jvp(..)`` is the forward
+# pass, under ``rematted_computation`` the rematerialised forward,
+# ``transpose(jvp(..))`` the backward pass (``util.tracing.step_breakdown``).
+STEP_SCOPES = ("embed", "attn_qkv", "attention", "attn_out", "ffn",
+               "lm_head", "loss", "optimizer")
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class TrainState:
@@ -108,13 +118,15 @@ def make_train_step(cfg: LlamaConfig,
     def step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
         (_, metrics), grads = jax.value_and_grad(
             compute_loss, has_aux=True)(state.params, batch)
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        params = optax.apply_updates(state.params, updates)
-        metrics = dict(metrics,
-                       grad_norm=optax.global_norm(grads).astype(jnp.float32))
-        return TrainState(step=state.step + 1, params=params,
-                          opt_state=opt_state), metrics
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = optax.apply_updates(state.params, updates)
+            metrics = dict(
+                metrics,
+                grad_norm=optax.global_norm(grads).astype(jnp.float32))
+            return TrainState(step=state.step + 1, params=params,
+                              opt_state=opt_state), metrics
 
     donate_argnums = (0,) if donate else ()
     return jax.jit(step, donate_argnums=donate_argnums)

@@ -27,6 +27,7 @@ from ray_tpu.air.config import FailureConfig, RunConfig, ScalingConfig
 from ray_tpu.air.result import Result
 from ray_tpu.train.backend import JaxConfig
 from ray_tpu.train.backend_executor import BackendExecutor, TrainingFailedError
+from ray_tpu.util import tracing
 
 
 class BaseTrainer:
@@ -74,6 +75,20 @@ class DataParallelTrainer(BaseTrainer):
         self._datasets = datasets or {}
 
     def fit(self) -> Result:
+        """Run the loop on the gang.  ``Result.metrics["_spans"]`` says
+        where the time of this call went: per span name (``train.*`` of
+        the driver, the head's ``sched.wait`` / ``worker.spawn`` of the
+        workers, rank 0's ``train.session_start``, ``train.loop`` and
+        ``session.report``)
+        ``count``, ``total_s``, ``max_s``, ``first_start``, ``last_end``."""
+        with tracing.collect() as got, tracing.span("train.fit"):
+            result = self._fit()
+        got.merge(result.metrics.get("_spans"))
+        got.add_caused()
+        result.metrics["_spans"] = got.summary
+        return result
+
+    def _fit(self) -> Result:
         failure = self.run_config.failure_config or FailureConfig()
         retries = failure.max_failures
         checkpoint = self.resume_from_checkpoint
@@ -119,7 +134,10 @@ def _payloads_to_result(payloads) -> Result:
     ckpt = None
     if rank0["checkpoints"]:
         ckpt = Checkpoint.from_bytes(rank0["checkpoints"][-1])
-    metrics = reports[-1] if reports else {}
+    # A copy: ``_spans`` is filled in once, here and by ``fit()`` — it is
+    # no part of the last report in ``metrics_history``.
+    metrics = dict(reports[-1]) if reports else {}
+    metrics["_spans"] = rank0.get("spans")
     return Result(metrics=metrics, checkpoint=ckpt,
                   metrics_history=reports)
 

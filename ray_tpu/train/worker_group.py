@@ -47,15 +47,22 @@ class TrainWorker:
                      session_kwargs: Dict[str, Any]):
         """Run the user loop under an active air session; return the
         session's reports + checkpoints (driver-side aggregation)."""
-        from ray_tpu.air.session import _TrainSession, _set_session
-        sess = _TrainSession(**session_kwargs)
-        _set_session(sess)
-        try:
-            train_fn(config)
-        finally:
-            _set_session(None)
+        from ray_tpu.util import tracing
+        with tracing.collect() as got:
+            # The first import of ray_tpu.air in this process brings
+            # numpy (0.3 s on the v5e host, PERF.md PR 23).
+            with tracing.span("train.session_start"):
+                from ray_tpu.air.session import _TrainSession, _set_session
+                sess = _TrainSession(**session_kwargs)
+                _set_session(sess)
+            try:
+                with tracing.span("train.loop"):
+                    train_fn(config)
+            finally:
+                _set_session(None)
         ckpt_blobs = [c.to_bytes() for c in sess.checkpoints]
-        return {"reports": sess.reports, "checkpoints": ckpt_blobs}
+        return {"reports": sess.reports, "checkpoints": ckpt_blobs,
+                "spans": got.summary}
 
 
 class WorkerGroup:

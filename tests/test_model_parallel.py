@@ -146,6 +146,83 @@ def test_sharded_flash_step_needs_no_mesh_context():
     assert abs(float(m1["loss"]) - float(m2["loss"])) < 1e-4
 
 
+def test_reference_attention_repeats_kv_heads_without_a_mesh():
+    """GQA through ``attn_impl="reference"`` on one device: 4 query
+    heads over 2 KV heads give the loss the flash path gives."""
+    kw = dict(num_heads=4, num_kv_heads=2)
+    ref_cfg = LlamaConfig.tiny(attn_impl="reference", **kw)
+    params = init_params(KEY, ref_cfg)
+    batch = _batch(ref_cfg)
+    got, _ = loss_fn(params, batch, ref_cfg)
+    want, _ = loss_fn(params, batch, LlamaConfig.tiny(attn_impl="flash",
+                                                      **kw))
+    assert abs(float(got) - float(want)) < 1e-4
+
+
+def _op_names(hlo_text, opcodes):
+    """(opcode, op_name) of every instruction of these opcodes."""
+    import re
+
+    out = []
+    for line in hlo_text.splitlines():
+        m = re.search(r"[\]})] ([a-z][a-z0-9-]*)\(", line)
+        if m and m.group(1).startswith(opcodes):
+            name = re.search(r'op_name="([^"]*)"', line)
+            out.append((m.group(1), name.group(1) if name else ""))
+    return out
+
+
+@pytest.mark.parametrize("mesh_kw", [None, dict(fsdp=2, tp=2)],
+                         ids=["one_device", "fsdp2_tp2"])
+def test_step_program_is_named_by_scope(mesh_kw):
+    """What a device trace can tell: the three kernels by name, and on
+    every matmul, kernel call and collective exactly one of STEP_SCOPES
+    (``util.tracing.step_breakdown`` reads them off the profiler's
+    ``op_name``)."""
+    import re
+
+    from ray_tpu.train.core import STEP_SCOPES
+    from ray_tpu.util.tracing import scope_and_phase
+
+    cfg = LlamaConfig.tiny(attn_impl="flash", remat=True, num_kv_heads=2)
+    opt = optax.adam(1e-2)
+    mesh = None
+    if mesh_kw:
+        mesh = make_mesh(MeshConfig(**mesh_kw), devices=jax.devices()[:4])
+    state = init_train_state(KEY, cfg, opt, mesh=mesh)
+    step = make_train_step(cfg, opt, mesh=mesh, donate=False)
+    batch = _batch(cfg)
+
+    jaxpr = str(jax.make_jaxpr(step)(state, batch))
+    for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert re.search(rf"\bname={kernel}\b", jaxpr), kernel
+
+    # As compiled (CPU): every matmul, the partitioner's collectives,
+    # and the interpret-mode kernels' own dots under attention/<kernel>.
+    named = _op_names(step.lower(state, batch).compile().as_text(), (
+        "dot", "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+        "collective-permute"))
+    assert sum(op == "dot" for op, _ in named) >= 20
+    if mesh is not None:
+        assert any(op.startswith("all-") for op, _ in named)
+    seen = set()
+    for op, name in named:
+        tokens = re.findall(r"[^/()]+", name)
+        scopes = [t for t in tokens if t in STEP_SCOPES]
+        assert len(scopes) == 1, (op, name)
+        seen.add(scope_and_phase(name, STEP_SCOPES))
+        kernels = [t for t in tokens if t.startswith("flash_")]
+        assert not kernels or scopes == ["attention"], name
+    # Every scope has a matmul or a collective of its own but the loss
+    # (elementwise and reductions) — and each phase is told apart.
+    want = set(STEP_SCOPES) - ({"loss"} if mesh is None else set())
+    want -= {"embed"} if mesh is None else set()  # a gather, no matmul
+    want -= {"optimizer"} if mesh is None else set()
+    assert want <= {s for s, _ in seen}, seen
+    assert {("ffn", "forward"), ("ffn", "remat"), ("ffn", "backward"),
+            ("attention", "remat"), ("lm_head", "backward")} <= seen
+
+
 @pytest.mark.slow  # ~38s of multichip mesh dryruns (the single biggest
 # tier-1 sink); sharding coverage keeps its tier-1 representatives via
 # test_train_step_sharded_matches_single_device and the

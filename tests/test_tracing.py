@@ -80,3 +80,277 @@ def test_spans_visible_from_worker(init2):
     ray.get([f.remote() for _ in range(10)])
     time.sleep(0.6)
     assert ray.get(probe.remote()) >= 1
+
+
+# ------------------------------------------------ the span primitive --
+
+def _wait_spans(want, timeout=8.0):
+    """Spans flush with the workers' 0.25 s flusher: poll until every
+    name in ``want`` is at the head."""
+    deadline = time.time() + timeout
+    while True:
+        spans = get_task_spans()
+        names = {s["name"] for s in spans}
+        if set(want) <= names or time.time() > deadline:
+            assert set(want) <= names, sorted(names)
+            return spans
+        time.sleep(0.1)
+
+
+def test_span_parent_is_enclosing_or_submitting_span(init2):
+    from ray_tpu.util import tracing
+
+    @ray.remote
+    def leaf():
+        return 1
+
+    @ray.remote
+    def outer():
+        from ray_tpu.util import tracing as t
+        with t.span("inner.work", rows=3):
+            time.sleep(0.002)
+        return ray.get(leaf.remote())  # a task submitted by a task
+
+    @ray.remote
+    class A:
+        def m(self):
+            return 1
+
+    a = A.remote()
+    with tracing.span("driver.batch") as batch:
+        assert ray.get(outer.remote()) == 1
+        assert ray.get(a.m.remote()) == 1
+    spans = _wait_spans(
+        ["driver.batch", "outer", "inner.work", "leaf", "actor.m"])
+    by_name = {s["name"]: s for s in spans}
+    outer_s, inner = by_name["outer"], by_name["inner.work"]
+    # Enclosing span in the same thread; the submitter's span across
+    # processes, for a task from the driver and for one from a task.
+    assert inner["parent"] == outer_s["span_id"]
+    assert inner["task_id"] == outer_s["task_id"]
+    assert inner["args"] == {"rows": 3}
+    assert outer_s["parent"] == batch.id
+    assert by_name["actor.m"]["parent"] == batch.id
+    assert by_name["leaf"]["parent"] == outer_s["span_id"]
+    assert by_name["driver.batch"]["worker_id"] == "driver"
+    assert by_name["driver.batch"]["parent"] is None
+    ids = [s["span_id"] for s in spans]
+    assert len(set(ids)) == len(ids)
+    for name in ("outer", "leaf", "actor.m"):
+        s = by_name[name]
+        assert s["submitted"] <= s["start"] <= s["end"], s
+    assert "submitted" not in inner
+    # The driver has a lane of its own in the timeline.
+    lanes = [e["args"]["name"] for e in chrome_trace(spans)
+             if e.get("name") == "thread_name"]
+    assert "driver" in lanes
+
+
+def test_span_never_imports_jax():
+    """The benchmark's driver must stay off the chip: a span in a
+    process without JAX leaves it without JAX."""
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from ray_tpu.util import tracing\n"
+            "with tracing.span('outer'):\n"
+            "    with tracing.span('inner') as s:\n"
+            "        pass\n"
+            "assert s.parent is not None and s.start > 0\n"
+            "assert 'jax' not in sys.modules, 'span() imported jax'\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
+
+
+def test_span_is_on_the_profilers_clock(tmp_path):
+    """With JAX in the process a span is a TraceAnnotation: an event of
+    the /host:CPU plane.  Clock rule: ``start_ns`` counts from the stat
+    ``profile_start_time`` of the plane ``Task Environment``, which is
+    ``time.time()`` in ns (CLOCK_REALTIME)."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from ray_tpu.util import tracing
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        recorded = []
+        for _ in range(3):
+            with tracing.span("probe.span") as s:
+                jnp.ones((8, 8)).sum().block_until_ready()
+                time.sleep(0.005)
+            recorded.append((s.start, time.time()))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    planes = {p.name: p for p in ProfileData.from_file(path).planes}
+    t0 = dict(planes["Task Environment"].stats)["profile_start_time"]
+    events = sorted((e.start_ns, e.duration_ns)
+                    for line in planes["/host:CPU"].lines
+                    for e in line.events if e.name == "probe.span")
+    assert len(events) == 3
+    for (start_ns, dur_ns), (start, end) in zip(events, recorded):
+        assert abs(t0 + start_ns - start * 1e9) < 2e6, (t0, start_ns, start)
+        assert 5e6 <= dur_ns <= (end - start) * 1e9 + 2e6
+
+
+def test_fit_returns_its_spans():
+    from ray_tpu.air import session
+    from ray_tpu.air.config import ScalingConfig
+    from ray_tpu.train import JaxTrainer
+
+    def loop(config):
+        for i in range(config["reports"]):
+            session.report({"i": i})
+
+    ray.init(num_cpus=2, ignore_reinit_error=True)
+    try:
+        result = JaxTrainer(
+            loop, train_loop_config={"reports": 3},
+            scaling_config=ScalingConfig(num_workers=1)).fit()
+    finally:
+        ray.shutdown()
+    assert result.error is None
+    spans = result.metrics["_spans"]
+    assert set(spans) == {
+        "train.fit", "train.placement_group", "train.start_workers",
+        "train.backend_start", "train.run", "train.shutdown",
+        "sched.wait", "worker.spawn", "train.session_start", "train.loop",
+        "session.report"}
+    assert spans["session.report"]["count"] == 3 == len(
+        result.metrics_history)
+    assert result.metrics["i"] == 2
+    assert "_spans" not in result.metrics_history[-1]
+    for name, s in spans.items():
+        assert set(s) == {"count", "total_s", "max_s", "first_start",
+                          "last_end"}, name
+        assert 0 <= s["max_s"] <= s["total_s"], name
+        assert s["first_start"] <= s["last_end"], name
+    fit = spans["train.fit"]
+    for name, s in spans.items():  # one clock, one machine
+        assert fit["first_start"] <= s["first_start"], name
+        assert s["last_end"] <= fit["last_end"], name
+    inside = spans["train.start_workers"]
+    assert inside["first_start"] <= spans["sched.wait"]["first_start"]
+    assert spans["worker.spawn"]["last_end"] <= inside["last_end"]
+    assert spans["train.loop"]["total_s"] <= spans["train.run"]["total_s"]
+    import pickle
+    assert len(pickle.dumps(spans)) < 1024
+
+
+# ------------------------------------- device trace -> scope and phase --
+
+def _xspace(ops, modules):
+    """A one-chip trace as the profiler writes it (text proto ->
+    bytes): ``ops`` are (instruction text, op_name, start_ns, dur_ns)."""
+    from jax.profiler import ProfileData
+
+    meta, events = [], []
+    for i, (text, op_name, start, dur) in enumerate(ops, start=1):
+        stat = (f'stats {{ metadata_id: 1 str_value: "{op_name}:" }}'
+                if op_name else "")
+        meta.append(f'event_metadata {{ key: {i} value {{ id: {i} '
+                    f'name: "{text}" {stat} }} }}')
+        events.append(f"events {{ metadata_id: {i} offset_ps: "
+                      f"{start * 1000} duration_ps: {dur * 1000} }}")
+    base = len(ops)
+    mod_events = []
+    for j, (start, dur) in enumerate(modules, start=1):
+        meta.append(f'event_metadata {{ key: {base + j} value {{ '
+                    f'id: {base + j} name: "jit_step(7)" }} }}')
+        mod_events.append(f"events {{ metadata_id: {base + j} offset_ps: "
+                          f"{start * 1000} duration_ps: {dur * 1000} }}")
+    text = ('planes { id: 1 name: "/device:TPU:0" '
+            'lines { id: 1 name: "XLA Modules" ' + " ".join(mod_events)
+            + ' } lines { id: 2 name: "XLA Ops" ' + " ".join(events) + " } "
+            + " ".join(meta)
+            + ' stat_metadata { key: 1 value { id: 1 name: "tf_op" } } }')
+    return ProfileData.text_proto_to_serialized_xspace(text)
+
+
+def test_step_breakdown_on_a_synthetic_trace(tmp_path):
+    from ray_tpu.train.core import STEP_SCOPES
+    from ray_tpu.util.tracing import (
+        format_breakdown, scope_and_phase, step_breakdown)
+
+    fwd = "jit(step)/jvp()/while/body/closed_call/"
+    bwd = "jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+    kernel = ', custom_call_target=\\"tpu_custom_call\\"'
+    # One step of 1000 ns from t=2000 (the first execution is a lead-in):
+    # a forward ``while`` [2000, 2400) over three ops, its own 100 ns left;
+    # then a backward while [2500, 2900) holding a remat op, a nested
+    # while, two more ops and 50 ns of its own.
+    ops = [
+        ("%lead = f32[] add()", "jit(step)/optimizer/add", 500, 100),
+        ("%while.1 = () while()", "jit(step)/jvp()/while", 2000, 400),
+        ("%qkv.1 = f32[] fusion()", fwd + "attn_qkv/dot_general", 2000, 100),
+        ("%flash_fwd.1 = f32[] custom-call()" + kernel,
+         fwd + "attention/flash_fwd/pallas_call", 2100, 100),
+        ("%ffn.1 = f32[] fusion()", fwd + "ffn/dot_general", 2200, 100),
+        ("%head.1 = f32[] fusion()", "jit(step)/jvp(lm_head)/dot_general",
+         2400, 50),
+        ("%loss.1 = f32[] fusion()",
+         "jit(step)/jvp(loss)/jit(log_softmax)/sub", 2450, 50),
+        ("%while.2 = () while()", "jit(step)/transpose(jvp())/while",
+         2500, 400),
+        ("%flash_fwd.2 = f32[] custom-call()" + kernel,
+         bwd + "rematted_computation/attention/flash_fwd/pallas_call",
+         2500, 50),
+        ("%while.3 = () while()", bwd + "attention/flash_dkv/while",
+         2550, 200),
+        ("%flash_dkv.1 = f32[] custom-call()" + kernel,
+         bwd + "attention/flash_dkv/pallas_call", 2550, 120),
+        ("%out.1 = f32[] fusion()", bwd + "attn_out/transpose", 2750, 50),
+        ("%slice.1 = f32[] fusion()",
+         "jit(step)/transpose(jvp())/while/body/squeeze", 2800, 50),
+        ("%adam.1 = f32[] fusion()", "jit(step)/optimizer/mul", 2900, 50),
+        ("%copy.9 = f32[] copy()", "", 2950, 30),
+        ("%embed.1 = f32[] gather()", "jit(step)/jvp(embed)/gather",
+         2980, 20),
+    ]
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace(ops, modules=[(0, 1000), (2000, 1000)]))
+    b = step_breakdown(str(path), "jit_step")
+    assert b["steps"] == 1 and b["device"] == 0
+    ns = lambda s: round(s * 1e9)  # noqa: E731
+    got = {scope: {p: ns(t) for p, t in row.items()}
+           for scope, row in b["scopes"].items()}
+    assert got == {
+        "attn_qkv": {"forward": 100},
+        "attention": {"forward": 100, "remat": 50, "backward": 200},
+        "ffn": {"forward": 100},
+        "lm_head": {"forward": 50},
+        "loss": {"forward": 50},
+        "attn_out": {"backward": 50},
+        "embed": {"forward": 20},
+        "optimizer": {"optimizer": 50},
+        # The scan's own: 100 ns of while.1, 50 of while.2, the slice.
+        "scan": {"forward": 100, "backward": 100},
+    }
+    assert ns(b["unscoped_s"]) == 30
+    assert b["unscoped_ops"] == [["copy.9", pytest.approx(30e-9)]]
+    assert {k: ns(t) for k, t in b["kernels"].items()} == {
+        "flash_fwd": 100, "flash_fwd.remat": 50, "flash_dkv": 120}
+    assert ns(b["step_s"]) == 1000
+    total = sum(t for row in got.values() for t in row.values()) + 30
+    assert total == ns(b["busy_s"]) == 1000  # the parts sum to the busy time
+    assert set(got) - {"scan"} <= set(STEP_SCOPES)
+    assert "attention" in format_breakdown(b)
+    # No second execution of the module: nothing to read.
+    path.write_bytes(_xspace(ops, modules=[(2000, 1000)]))
+    assert step_breakdown(str(path), "jit_step") is None
+    # The phase rule, once.
+    assert scope_and_phase("jit(step)/jvp(lm_head)/dot_general",
+                           STEP_SCOPES) == ("lm_head", "forward")
+    assert scope_and_phase("jit(step)/transpose(jvp(lm_head))/dot_general",
+                           STEP_SCOPES) == ("lm_head", "backward")
+    assert scope_and_phase(bwd + "rematted_computation/ffn/dot_general",
+                           STEP_SCOPES) == ("ffn", "remat")
+    assert scope_and_phase("jit(step)/optimizer/add",
+                           STEP_SCOPES) == ("optimizer", "optimizer")
+    assert scope_and_phase("", STEP_SCOPES) == (None, "forward")
