@@ -19,8 +19,13 @@ Design (standard memory-efficient attention, mapped to the TPU grid model):
   grid ``(b, h, kv_blocks, q_blocks)``, one accumulates ``dq`` with grid
   ``(b, h, q_blocks, kv_blocks)``; both recompute ``p = exp(s - lse)`` from
   the saved per-row logsumexp instead of materializing the S x S matrix.
-- Causal blocks that are fully masked are skipped with ``pl.when`` so the
-  kernel does ~half the FLOPs at long sequence.
+- Causal schedule: a grid step FETCHES a large tile and the kernel walks
+  it in COMPUTE sub-tiles, each dead (no code runs), interior (no mask) or
+  diagonal (masked); grid steps whose whole tile is dead name the block
+  their neighbour holds, so nothing is copied for them.  With the sizes
+  ``choose_tiles`` picks the kernels compute 1.06 times the causal pairs at
+  s=4096 and 1.25 times at s=512 (``causal_tile_counts``), where whole
+  512 x 1024 tiles computed 1.25 and 2.0 times.
 - Accumulation is f32 regardless of input dtype (bf16 inputs hit the MXU).
 
 The reference framework has no counterpart (Ray core has no tensor ops —
@@ -32,6 +37,7 @@ pallas interpret mode, so the same code path is tested on CPU.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -48,11 +54,17 @@ _LANES = 128     # TPU lane width; stats are lane-replicated
 _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
 
-# Tuned on TPU v5e: large blocks amortize grid overhead (the d=64
-# contraction underfills the MXU, so throughput comes from big output
-# tiles); _fit_block shrinks them for short sequences.
-DEFAULT_BLOCK_Q = 512
-DEFAULT_BLOCK_K = 1024
+# Fetch tile (what one grid step copies into VMEM) and compute sub-tile,
+# measured on TPU v5e at d=128 bf16 (PERF.md §6, PR 24).  Under the causal
+# mask the largest tile wins: fewer ~0.35 us grid steps, fewer running-
+# softmax updates, longer contractions, and the sub-tile walk keeps what is
+# computed above the diagonal small whatever the tile.  Without a mask the
+# tile stays what the kernels always used.
+MAX_BLOCK = 2048                   # rows of a causal fetch tile, q and kv
+UNMASKED_BLOCK = (512, 1024)       # (q, kv) rows of a non-causal one
+_BLOCK_BYTES = 2 * 1024 * 1024     # one operand's block: bounds rows by d
+SUB_TILES = (256, 128)             # compute sub-tile widths, widest first
+MAX_EXECUTED = 1.25                # executed / causal pairs a width may cost
 
 
 def _interpret_default() -> bool:
@@ -89,16 +101,143 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
-def _causal_mask(s, qi, ki, block_q, block_k):
-    rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(rows >= cols, s, NEG_INF)
+# ------------------------------------------------------ causal tile schedule
+#
+# A FETCH tile ``(block_q, block_k)`` is what one grid step copies into
+# VMEM; the kernels walk it in COMPUTE sub-tiles ``(sub_q, sub_k)``.
+# With ``off`` = (first kv column) - (first q row) of a sub-tile:
+#   dead      off >= sub_q      its last q row is before its first column
+#   interior  off <= 1 - sub_k  its first q row sees its last column
+#   diagonal  otherwise         the only kind that needs the mask
+# Causal positions are top-left aligned (row r sees column c iff r >= c),
+# as ``mha_reference`` has them with zero offsets.
+
+def _tile_kind(off, sub_q, sub_k):
+    """(interior, diagonal) of a sub-tile: Python bools for a Python int
+    ``off``, traced scalars otherwise.  Neither holds for a dead one."""
+    return off <= 1 - sub_k, (off > 1 - sub_k) & (off < sub_q)
+
+
+def _last_live_k(i, block_q, block_k):
+    """Last kv tile that q tile ``i`` sees any column of."""
+    return (i * block_q + block_q - 1) // block_k
+
+
+def _first_live_q(i, block_q, block_k):
+    """First q tile that holds a row seeing kv tile ``i``."""
+    return (i * block_k) // block_q
+
+
+def causal_tile_counts(sq: int, sk: int, block_q: int, block_k: int,
+                       sub_q: int, sub_k: int, causal: bool = True) -> dict:
+    """What the schedule executes for one (batch, head), from shapes
+    alone: the number of compute sub-tiles of each kind, the (q, k) pairs
+    the live ones compute, and the pairs the mask leaves (``sq * sk``
+    without a mask).  Sub-tiles never span fetch tiles, so the fetch tile
+    only matters where it cuts a sub-tile short."""
+    sub_q, sub_k = min(sub_q, block_q), min(sub_k, block_k)
+    counts = {"dead": 0, "interior": 0, "diagonal": 0}
+    for q0 in range(0, sq, sub_q):
+        for k0 in range(0, sk, sub_k):
+            interior, diagonal = (True, False) if not causal else (
+                _tile_kind(k0 - q0, sub_q, sub_k))
+            counts["interior" if interior else
+                   "diagonal" if diagonal else "dead"] += 1
+    live = counts["interior"] + counts["diagonal"]
+    counts["executed_pairs"] = live * sub_q * sub_k
+    rows = min(sq, sk)   # row r sees min(r + 1, sk) columns
+    counts["causal_pairs"] = (rows * (rows + 1) // 2 + (sq - rows) * sk
+                              if causal else sq * sk)
+    return counts
+
+
+def _walk_tile(causal, off, tiles, body, strips):
+    """Run ``body(q_slice, k_slice, mask)`` over the live part of the
+    fetched tile whose first column minus first row is ``off``.
+
+    A fetched tile is dead (nothing runs), interior as a whole (one call
+    over all of it, no mask) or straddles the diagonal.  ``off`` is a
+    multiple of gcd(block_q, block_k), so the straddling offsets are few
+    and known when the kernel is traced: each gets ONE branch of
+    straight-line code, which the scheduler overlaps where per-sub-tile
+    branches would serialise it.  In it the tile is cut in strips of
+    sub-tiles along ``strips`` ("q": one per ``sub_q`` rows, for kernels
+    that accumulate per q row; "k": one per ``sub_k`` columns).  A strip's
+    live sub-tiles are adjacent and run as ONE call; its dead ones run no
+    code; its diagonal ones lie at the end nearest the diagonal, and
+    ``mask`` = (axis, n, sub_off) says that those ``n`` trailing columns
+    (axis 1, "q" strips) or leading rows (axis 0, "k" strips), whose own
+    offset is ``sub_off``, need the mask (None: every sub-tile of the
+    strip is interior)."""
+    block_q, block_k, sub_q, sub_k = tiles
+    whole = functools.partial(body, pl.ds(0, block_q), pl.ds(0, block_k), None)
+    if not causal:
+        return whole()
+    by_q = strips == "q"
+    # (length, step) across the strips and along one
+    across, along = (((block_q, sub_q), (block_k, sub_k)) if by_q else
+                     ((block_k, sub_k), (block_q, sub_q)))
+
+    def walk(off):
+        for s0 in range(0, *across):
+            kinds = {}      # start of each live sub-tile -> is it diagonal
+            for x0 in range(0, *along):
+                a, t = (s0, x0) if by_q else (x0, s0)
+                interior, diagonal = _tile_kind(off + t - a, sub_q, sub_k)
+                if interior or diagonal:
+                    kinds[x0] = diagonal
+            if not kinds:
+                continue
+            first, n_diag = min(kinds), sum(kinds.values()) * along[1]
+            extent = max(kinds) + along[1] - first
+            if by_q:
+                qs, ks = pl.ds(s0, sub_q), pl.ds(first, extent)
+                mask = (1, n_diag, off + first + extent - n_diag - s0)
+            else:
+                qs, ks = pl.ds(first, extent), pl.ds(s0, sub_k)
+                mask = (0, n_diag, off + s0 - first)
+            body(qs, ks, mask if n_diag else None)
+
+    if isinstance(off, int):     # a one-tile grid: decided while tracing
+        return walk(off)
+    step = math.gcd(block_q, block_k)
+    pl.when(off <= -block_k)(whole)     # the next multiple of step straddles
+    for straddling in range(step - block_k, block_q, step):
+        pl.when(off == straddling)(functools.partial(walk, straddling))
+
+
+def _scores(q, k, mask):
+    """f32 ``q @ k^T`` of one strip.  ``mask`` = (axis, n, off): the first
+    ``n`` rows (axis 0) or the last ``n`` columns (axis 1) are masked, row
+    r of that part seeing its column c iff r - c >= off."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if mask is None:
+        return s
+    axis, n, off = mask
+    cut = n if axis == 0 else s.shape[1] - n
+    part = s[:cut] if axis == 0 else s[:, cut:]
+    diff = (jax.lax.broadcasted_iota(jnp.int32, part.shape, 0)
+            - jax.lax.broadcasted_iota(jnp.int32, part.shape, 1))
+    part = jnp.where(diff >= off, part, NEG_INF)
+    if part.shape == s.shape:
+        return part
+    return jnp.concatenate(
+        [part, s[cut:]] if axis == 0 else [s[:, :cut], part], axis)
+
+
+def _tile_offset(causal, qi, ki, tiles, grid_qk):
+    """First column minus first row of grid tile (qi, ki): a Python 0 on
+    a one-tile grid, and unused without a mask."""
+    if not causal or grid_qk == (1, 1):
+        return 0
+    return ki * tiles[1] - qi * tiles[0]
 
 
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, causal, block_q, block_k):
+                m_scr, l_scr, acc_scr, *, causal, tiles, grid_qk):
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -108,29 +247,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: block is live iff its last q row can see its first kv column.
-    live = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0]                                      # (bq, d)
-        k = k_ref[0, 0]                                      # (bk, d)
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        m_prev = m_scr[...]                          # (bq, LANES) replicated
-        m_cur = jnp.max(s, axis=-1, keepdims=True)   # (bq, 1)
+    def update(qs, ks, mask):
+        s = _scores(q_ref[0, 0, qs], k_ref[0, 0, ks], mask)   # f32
+        v = v_ref[0, 0, ks]
+        m_prev = m_scr[qs]                           # (sq, LANES) replicated
+        m_cur = jnp.max(s, axis=-1, keepdims=True)   # (sq, 1)
         m_next = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
         alpha = jnp.exp2(m_prev - m_next)
         p = jnp.exp2(s - m_next[:, :1])
-        l_scr[...] = l_scr[...] * alpha + jnp.broadcast_to(
+        l_scr[qs] = l_scr[qs] * alpha + jnp.broadcast_to(
             jnp.sum(p, axis=-1, keepdims=True), m_prev.shape)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
+        acc_scr[qs] = acc_scr[qs] * alpha[:, :1] + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[...] = m_next
+        m_scr[qs] = m_next
+
+    _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
+               update, strips="q")
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -139,28 +272,52 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = m_scr[...] + jnp.log2(l)   # log2-domain lse
 
 
-def _fwd_call(qt, kt, vt, causal, block_q, block_k, interpret):
+def _grid_and_specs(qt, kt, causal, tiles):
+    """``(nq, nk)`` tiles of the call and the BlockSpecs of the q-side and
+    kv-side operands for both grid orders: ``q_i, k_j, row_i`` for grids
+    ``(b, h, q, kv)`` and ``q_j, k_i, row_j`` for ``(b, h, kv, q)``.
+    Under the mask a dead grid step names the block its nearest live step
+    holds, which Pallas does not copy again."""
+    block_q, block_k = tiles[:2]
+    d = qt.shape[3]
+    nq, nk = qt.shape[2] // block_q, kt.shape[2] // block_k
+    if causal:
+        def inner_k(i, j):
+            return jnp.minimum(j, _last_live_k(i, block_q, block_k))
+
+        def inner_q(i, j):
+            return jnp.minimum(
+                jnp.maximum(j, _first_live_q(i, block_q, block_k)), nq - 1)
+    else:
+        inner_k = inner_q = lambda i, j: j
+
+    def spec(block, width, index):
+        return pl.BlockSpec((1, 1, block, width),
+                            lambda b_, h_, i, j: (b_, h_, index(i, j), 0))
+
+    outer = lambda i, j: i
+    return (nq, nk), {
+        "q_i": spec(block_q, d, outer), "row_i": spec(block_q, _LANES, outer),
+        "k_j": spec(block_k, d, inner_k),
+        "k_i": spec(block_k, d, outer),
+        "q_j": spec(block_q, d, inner_q),
+        "row_j": spec(block_q, _LANES, inner_q),
+    }
+
+
+def _fwd_call(qt, kt, vt, causal, tiles, interpret):
     """qt/kt/vt: (b, h, s, d); qt PRE-SCALED by sm_scale*log2e.  Returns
     (o_t, lse) with o_t (b, h, sq, d) and lse (b, h, sq, LANES)
     lane-replicated f32 in the log2 domain."""
     b, h, sq, d = qt.shape
-    sk = kt.shape[2]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    block_q = tiles[0]
+    (nq, nk), specs = _grid_and_specs(qt, kt, causal, tiles)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(b, h, pl.cdiv(sq, block_q), pl.cdiv(sk, block_k)),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, _LANES),
-                         lambda b_, h_, i, j: (b_, h_, i, 0)),
-        ],
+        functools.partial(_fwd_kernel, causal=causal, tiles=tiles,
+                          grid_qk=(nq, nk)),
+        grid=(b, h, nq, nk),
+        in_specs=[specs["q_i"], specs["k_j"], specs["k_j"]],
+        out_specs=[specs["q_i"], specs["row_i"]],
         out_shape=[
             jax.ShapeDtypeStruct(qt.shape, qt.dtype),
             jax.ShapeDtypeStruct((b, h, sq, _LANES), jnp.float32),
@@ -179,9 +336,17 @@ def _fwd_call(qt, kt, vt, causal, block_q, block_k, interpret):
 
 # ---------------------------------------------------------------- backward
 
+def _p_and_ds(q, k, v, do, lse, delta, mask):
+    """Recomputed probabilities and score gradients of one sub-tile, both
+    f32 ``(sq, sk)``: ``p = exp2(s - lse)``, ``ds = p * (dp - delta)``."""
+    p = jnp.exp2(_scores(q, k, mask) - lse[:, :1])
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta[:, :1])
+
+
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_ref, dv_ref, dk_scr, dv_scr,
-                 *, causal, block_q, block_k):
+                 dk_ref, dv_ref, dk_scr, dv_scr, *, causal, tiles, grid_qk):
     ki, qi = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
 
@@ -190,32 +355,21 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                                   # (bq, LANES)
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        p = jnp.exp2(s - lse[:, :1])                          # (bq, bk)
+    def update(qs, ks, mask):
+        q, do = q_ref[0, 0, qs], do_ref[0, 0, qs]
+        p, ds = _p_and_ds(q, k_ref[0, 0, ks], v_ref[0, 0, ks], do,
+                          lse_ref[0, 0, qs], delta_ref[0, 0, qs], mask)
         # Grad matmuls in the INPUT dtype (bf16 on TPU): the MXU runs
-        # bf16 natively; the old f32 operands forced multi-pass matmuls.
-        dv_scr[...] += jax.lax.dot_general(
+        # bf16 natively; f32 operands would force multi-pass matmuls.
+        dv_scr[ks] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bk, d)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, :1])).astype(q.dtype)
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (sk, d)
+        dk_scr[ks] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
+               update, strips="k")
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -226,7 +380,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *, sm_scale, causal, block_q, block_k):
+               dq_ref, dq_scr, *, sm_scale, causal, tiles, grid_qk):
     # sm_scale is applied once at finalize: dL/dq_orig = sm_scale * ds@k.
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -235,58 +389,39 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    live = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        p = jnp.exp2(s - lse[:, :1])
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, :1])).astype(k.dtype)
-        dq_scr[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+    def update(qs, ks, mask):
+        k = k_ref[0, 0, ks]
+        _, ds = _p_and_ds(q_ref[0, 0, qs], k, v_ref[0, 0, ks],
+                          do_ref[0, 0, qs], lse_ref[0, 0, qs],
+                          delta_ref[0, 0, qs], mask)
+        dq_scr[qs] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
+               update, strips="q")
 
     @pl.when(ki == nk - 1)
     def _finalize():
         dq_ref[0, 0] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, block_q, block_k,
-              interpret):
+def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret):
     """All tensors (b, h, s, d); lse (b, h, sq, LANES).  Returns transposed
     grads (dqt, dkt, dvt)."""
     b, h, sq, d = qt.shape
-    sk = kt.shape[2]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
+    block_q, block_k = tiles[:2]
     delta = jnp.sum(ot.astype(jnp.float32) * dot.astype(jnp.float32),
                     axis=-1, keepdims=True)                  # (b, h, sq, 1)
     delta = jnp.broadcast_to(delta, (b, h, sq, _LANES))
-
-    q_i = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    q_j = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, j, 0))
-    k_i = pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    k_j = pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_, j, 0))
-    row_i = pl.BlockSpec((1, 1, block_q, _LANES),
-                         lambda b_, h_, i, j: (b_, h_, i, 0))
-    row_j = pl.BlockSpec((1, 1, block_q, _LANES),
-                         lambda b_, h_, i, j: (b_, h_, j, 0))
+    (nq, nk), specs = _grid_and_specs(qt, kt, causal, tiles)
+    q_i, k_j, row_i = specs["q_i"], specs["k_j"], specs["row_i"]
+    q_j, k_i, row_j = specs["q_j"], specs["k_i"], specs["row_j"]
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkdv_kernel, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(b, h, pl.cdiv(sk, block_k), pl.cdiv(sq, block_q)),
+        functools.partial(_dkdv_kernel, causal=causal, tiles=tiles,
+                          grid_qk=(nq, nk)),
+        grid=(b, h, nk, nq),
         in_specs=[q_j, k_i, k_i, q_j, row_j, row_j],
         out_specs=[k_i, k_i],
         out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
@@ -300,8 +435,8 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, block_q, block_k,
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(b, h, pl.cdiv(sq, block_q), pl.cdiv(sk, block_k)),
+                          tiles=tiles, grid_qk=(nq, nk)),
+        grid=(b, h, nq, nk),
         in_specs=[q_i, k_j, k_j, q_i, row_i, row_i],
         out_specs=q_i,
         out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
@@ -319,25 +454,27 @@ def _to_bhsd(x):
     return jnp.transpose(x, (0, 2, 1, 3))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, sm_scale, causal, tiles, interpret):
+    """``tiles`` = (block_q, block_k, sub_q, sub_k): each sub divides its
+    block, each block its sequence."""
     qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)
     o, _ = _fwd_call(_to_bhsd(qs), _to_bhsd(k), _to_bhsd(v), causal,
-                     block_q, block_k, interpret)
+                     tiles, interpret)
     return _to_bhsd(o)
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret):
     qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)
     qt, kt, vt = _to_bhsd(qs), _to_bhsd(k), _to_bhsd(v)
-    ot, lse = _fwd_call(qt, kt, vt, causal, block_q, block_k, interpret)
+    ot, lse = _fwd_call(qt, kt, vt, causal, tiles, interpret)
     return _to_bhsd(ot), (qt, kt, vt, ot, lse)
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
+def _flash_bwd(sm_scale, causal, tiles, interpret, res, do):
     qt, kt, vt, ot, lse = res
     dqt, dkt, dvt = _bwd_call(qt, kt, vt, ot, lse, _to_bhsd(do), sm_scale,
-                              causal, block_q, block_k, interpret)
+                              causal, tiles, interpret)
     return _to_bhsd(dqt), _to_bhsd(dkt), _to_bhsd(dvt)
 
 
@@ -346,13 +483,14 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, sm_scale: Optional[float] = None,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: int = MAX_BLOCK, block_k: int = MAX_BLOCK,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Memory-efficient MHA.  q: (b, sq, h, d); k/v: (b, sk, h, d).
 
     Supports grouped-query attention: if k/v have fewer heads than q and
     ``h % h_kv == 0``, kv heads are repeated (XLA fuses the broadcast).
+    ``block_q``/``block_k`` are upper bounds of the fetch tile; the tile
+    and the compute sub-tile follow the call's shapes (``choose_tiles``).
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -360,20 +498,49 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         interpret = _interpret_default()
     from ray_tpu.ops.layers import repeat_kv_heads
     k, v = repeat_kv_heads(q, k, v)
-    # The kernels have no partial-block masking: blocks must tile the
-    # sequence exactly.  Shrink to a fitting power-of-two block; if none
-    # >= 8 exists, use the XLA reference (correct, O(S^2) memory).
-    block_q = _fit_block(block_q, q.shape[1])
-    block_k = _fit_block(block_k, k.shape[1])
-    if block_q is None or block_k is None:
+    tiles = choose_tiles(q.shape[1], k.shape[1], causal, q.shape[-1],
+                         q.dtype, block_q, block_k)
+    if tiles is None:
+        # No block >= 8 tiles the sequence exactly: the XLA reference is
+        # correct, at O(S^2) memory.
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-    return _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+    return _flash(q, k, v, sm_scale, causal, tiles, interpret)
 
 
 def _fit_block(block: int, seq: int) -> Optional[int]:
+    """Largest ``block / 2**n`` >= 8 that divides ``seq``: the kernels
+    have no partial-block masking, so blocks tile the sequence exactly."""
     block = min(block, seq)
     while block >= 8:
         if seq % block == 0:
             return block
         block //= 2
     return None
+
+
+def choose_tiles(sq: int, sk: int, causal: bool, d: int, dtype,
+                 block_q: int = MAX_BLOCK, block_k: int = MAX_BLOCK):
+    """(block_q, block_k, sub_q, sub_k) for a call from what it can see,
+    or None where no block tiles a sequence.  The fetch tile is the
+    largest under the caps (the caller's, the mode's, and ``_BLOCK_BYTES``
+    for one operand's block).  Without a mask every sub-tile is interior,
+    so the sub-tile is the tile.  Under it the sub-tile is the widest of
+    ``SUB_TILES`` that computes at most ``MAX_EXECUTED`` times the causal
+    pairs (``causal_tile_counts``), else the narrowest: wide strips feed
+    the MXU longer products, narrow ones compute less above the diagonal."""
+    rows = _BLOCK_BYTES // (d * jnp.dtype(dtype).itemsize)
+    caps = (MAX_BLOCK, MAX_BLOCK) if causal else UNMASKED_BLOCK
+    block_q = _fit_block(min(block_q, caps[0], rows), sq)
+    block_k = _fit_block(min(block_k, caps[1], rows), sk)
+    if block_q is None or block_k is None:
+        return None
+    if not causal:
+        return block_q, block_k, block_q, block_k
+
+    for sub in SUB_TILES:
+        tiles = (block_q, block_k,
+                 _fit_block(sub, block_q), _fit_block(sub, block_k))
+        n = causal_tile_counts(sq, sk, *tiles)
+        if n["executed_pairs"] <= MAX_EXECUTED * n["causal_pairs"]:
+            break
+    return tiles

@@ -389,6 +389,34 @@ def op_names(xplane: bytes) -> Dict[str, Dict[str, str]]:
     return out
 
 
+# The q and k operands of a flash kernel's custom call, as the HLO text
+# of its trace event gives them: ``dtype[b,h,sq,d]..., dtype[b,h,sk,d]``.
+_FLASH_OPERANDS = re.compile(
+    r"operand_layout_constraints=\{(\w+)\[\d+,\d+,(\d+),(\d+)\]\{[^}]*\}, "
+    r"\w+\[\d+,\d+,(\d+),\d+\]")
+_HLO_DTYPES = {"bf16": "bfloat16", "f16": "float16", "f32": "float32"}
+
+
+def flash_executed_over_causal(text: str) -> Optional[float]:
+    """(q, k) pairs a flash kernel computes over the pairs the causal
+    mask leaves, for the kernel whose custom call has the HLO ``text``:
+    ``ops.attention.causal_tile_counts`` at the tile sizes this tree
+    picks for the operands' shapes (the step's attention is causal).
+    Static per shape: nothing is counted at run time.  None where the
+    text names no such operands."""
+    m = _FLASH_OPERANDS.search(text)
+    if m is None or m.group(1) not in _HLO_DTYPES:
+        return None
+    from ray_tpu.ops.attention import causal_tile_counts, choose_tiles
+
+    sq, d, sk = int(m.group(2)), int(m.group(3)), int(m.group(4))
+    tiles = choose_tiles(sq, sk, True, d, _HLO_DTYPES[m.group(1)])
+    if tiles is None:
+        return None
+    n = causal_tile_counts(sq, sk, *tiles)
+    return n["executed_pairs"] / n["causal_pairs"]
+
+
 def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
                      step_module: str, scopes: Sequence[str]
                      ) -> Optional[Dict[str, Any]]:
@@ -410,6 +438,7 @@ def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
         op_name = names.get(plane_name, {})
         by_scope: Dict[str, Dict[str, float]] = {}
         kernels: Dict[str, float] = {}
+        kernel_pairs: Dict[str, float] = {}
         unscoped: Dict[str, float] = {}
         busy = 0
         ops = sorted(lines.get("XLA Ops", ()), key=lambda e: (e[1], -e[2]))
@@ -434,6 +463,10 @@ def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
                                if t.startswith("flash_")), "unnamed")
                 key = kernel + (".remat" if phase == "remat" else "")
                 kernels[key] = kernels.get(key, 0) + self_ns
+                if key not in kernel_pairs:
+                    ratio = flash_executed_over_causal(text)
+                    if ratio is not None:
+                        kernel_pairs[key] = ratio
 
         for text, a, b in ops:
             if not any(s[1] <= a < s[2] for s in steps):
@@ -458,6 +491,7 @@ def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
                 ([k, t / n / 1e9] for k, t in unscoped.items()),
                 key=lambda kv: -kv[1])[:10],
             "kernels": {k: t / n / 1e9 for k, t in kernels.items()},
+            "kernel_pairs": kernel_pairs,
         }
         if best is None or result["busy_s"] > best["busy_s"]:
             best = result
@@ -469,11 +503,12 @@ def step_breakdown(xplane_path: str, step_module: str = "jit_step",
                    ) -> Optional[Dict[str, Any]]:
     """Device seconds per step of a profiler trace (``.xplane.pb``) by step
     scope and phase: ``{"scopes": {scope: {phase: s}}, "unscoped_s",
-    "unscoped_ops", "kernels": {name: s}, "step_s", "busy_s", "steps",
-    "device"}``.  SELF times (a ``while`` covers its body), over the
-    executions of ``step_module`` after the first; the Mosaic kernels by
-    the ``name=`` of their ``pallas_call``.  None when the trace holds no
-    two executions of the module.  Command line::
+    "unscoped_ops", "kernels": {name: s}, "kernel_pairs": {name: executed
+    / causal pairs}, "step_s", "busy_s", "steps", "device"}``.  SELF times
+    (a ``while`` covers its body), over the executions of ``step_module``
+    after the first; the Mosaic kernels by the ``name=`` of their
+    ``pallas_call``.  None when the trace holds no two executions of the
+    module.  Command line::
 
         python -m ray_tpu.scripts step-breakdown <file.xplane.pb>
     """
@@ -513,5 +548,7 @@ def format_breakdown(b: Dict[str, Any]) -> str:
     for name, t in b["unscoped_ops"]:
         out.append(f"  unscoped {t * 1e3:9.3f} ms  {name}")
     for name, t in sorted(b["kernels"].items()):
-        out.append(f"  kernel   {t * 1e3:9.3f} ms  {name}")
+        pairs = b.get("kernel_pairs", {}).get(name)
+        out.append(f"  kernel   {t * 1e3:9.3f} ms  {name}" + (
+            "" if pairs is None else f"  executed/causal {pairs:.4f}"))
     return "\n".join(out)

@@ -14,6 +14,7 @@ from ray_tpu.ops import (
     flash_attention, mha_reference, ring_attention, ulysses_attention,
     rms_norm, rope, apply_rope,
 )
+from ray_tpu.ops.attention import causal_tile_counts, choose_tiles
 from ray_tpu.ops.moe import moe_ffn
 from ray_tpu.parallel import MeshConfig, make_mesh, use_mesh
 
@@ -27,35 +28,145 @@ def qkv():
                  for k in jax.random.split(key, 3))
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_fwd(qkv, causal):
+def _qkv(b, sq, sk, h, h_kv, d, dtype):
+    keys = jax.random.split(jax.random.PRNGKey(sq + sk + h_kv + d), 3)
+    return (jax.random.normal(keys[0], (b, sq, h, d), jnp.float32).astype(dtype),
+            jax.random.normal(keys[1], (b, sk, h_kv, d), jnp.float32).astype(dtype),
+            jax.random.normal(keys[2], (b, sk, h_kv, d), jnp.float32).astype(dtype))
+
+
+# (b, sq, sk, h, h_kv, d, dtype, causal, block caps) -> the tiles
+# ``choose_tiles`` picks and the kinds of tile they produce.
+FLASH_CASES = {
+    # the module's (2, 128, 4, 32) shape under caps of 64, as always
+    # tested: sub-tile = tile, a 2 x 2 grid of interior, diagonal and dead
+    "fwd-noncausal": (B, S, S, H, H, D, jnp.float32, False, 64),
+    "fwd-causal": (B, S, S, H, H, D, jnp.float32, True, 64),
+    "gqa-2to1": (B, S, S, H, 2, D, jnp.float32, True, None),
+    # one 512 tile walked in 128-wide sub-tiles, all three kinds, the
+    # walk decided while tracing (a one-tile grid)
+    "one-tile-of-sub-tiles": (1, 512, 512, 2, 2, D, jnp.float32, True, None),
+    # fetch tile 256 > sub-tile 128 on a 2 x 2 grid: dead tiles fetch
+    # nothing, the interior tile runs whole, the diagonal ones in strips
+    "tile-larger-than-sub-tile": (1, 512, 512, 1, 1, D, jnp.float32, True,
+                                  256),
+    "sq-shorter": (1, 128, 256, 2, 2, D, jnp.float32, True, 64),
+    "sq-longer": (1, 256, 128, 2, 2, D, jnp.float32, True, 64),
+    "sq-longer-rect-tile": (1, 256, 128, 1, 1, D, jnp.float32, True, 128),
+    "gqa-4to1": (1, 128, 128, 8, 2, D, jnp.float32, True, 64),
+    "bf16-d128": (1, 256, 256, 2, 2, 128, jnp.bfloat16, True, None),
+    "noncausal-rect": (1, 128, 256, 2, 2, D, jnp.float32, False, 64),
+    # no block >= 8 tiles 100 rows: the XLA reference answers
+    "untileable": (1, 100, 100, 2, 2, D, jnp.float32, True, None),
+}
+
+
+@pytest.mark.parametrize("grads", [False, True], ids=["value", "grads"])
+@pytest.mark.parametrize("case", FLASH_CASES)
+def test_flash_attention(case, grads):
+    """Value and the three gradients against ``mha_reference``."""
+    b, sq, sk, h, h_kv, d, dtype, causal, cap = FLASH_CASES[case]
+    q, k, v = _qkv(b, sq, sk, h, h_kv, d, dtype)
+    caps = {} if cap is None else {"block_q": cap, "block_k": cap}
+    rep = h // h_kv
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal, **caps)
+    ref = lambda q, k, v: mha_reference(
+        q, jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2), causal=causal)
+    f32 = lambda x: x.astype(jnp.float32)
+    # bf16 carries 8 bits: one ulp of an O(1) output is 2**-8
+    tol = 1e-4 if dtype == jnp.float32 else 2e-2
+    if not grads:
+        err = jnp.max(jnp.abs(f32(flash(q, k, v)) - f32(ref(q, k, v))))
+        assert err < tol
+        return
+    loss = lambda fn: lambda *a: (f32(fn(*a)) ** 2).sum()
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), (0, 1, 2))(q, k, v)
+    for a, w in zip(got, want):
+        scale = max(1.0, float(jnp.max(jnp.abs(f32(w)))))
+        assert jnp.max(jnp.abs(f32(a) - f32(w))) < 10 * tol * scale
+
+
+@pytest.mark.parametrize("tiles", [
+    (64, 64, 32, 32), (128, 128, 32, 32), (32, 128, 32, 64),
+    (128, 32, 64, 16), (64, 128, 16, 64), (128, 64, 64, 16)],
+    ids=lambda t: "x".join(map(str, t)))
+def test_flash_tile_schedules(qkv, tiles):
+    """Any fetch tile and sub-tile give the reference's numbers: square
+    and rectangular, sub-tile wider than tall and the reverse, tiles that
+    straddle the diagonal at several offsets."""
+    from ray_tpu.ops.attention import _flash
+
     q, k, v = qkv
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
-    ref = mha_reference(q, k, v, causal=causal)
-    assert jnp.max(jnp.abs(out - ref)) < 1e-4
+    f = lambda *a: (_flash(*a, D ** -0.5, True, tiles, True) ** 2).sum()
+    g = lambda *a: (mha_reference(*a, causal=True) ** 2).sum()
+    out = _flash(q, k, v, D ** -0.5, True, tiles, True)
+    assert jnp.max(jnp.abs(out - mha_reference(q, k, v, causal=True))) < 1e-4
+    for a, w in zip(jax.grad(f, (0, 1, 2))(q, k, v),
+                    jax.grad(g, (0, 1, 2))(q, k, v)):
+        assert jnp.max(jnp.abs(a - w)) < 1e-3
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_bwd(qkv, causal):
-    q, k, v = qkv
-    f = lambda *a: (flash_attention(*a, causal=causal, block_q=64,
-                                    block_k=64) ** 2).sum()
-    g = lambda *a: (mha_reference(*a, causal=causal) ** 2).sum()
-    got = jax.grad(f, (0, 1, 2))(q, k, v)
-    want = jax.grad(g, (0, 1, 2))(q, k, v)
-    for a, b in zip(got, want):
-        assert jnp.max(jnp.abs(a - b)) < 1e-3
+def _ratio(sq, sk, tiles):
+    n = causal_tile_counts(sq, sk, *tiles)
+    return n["executed_pairs"] / n["causal_pairs"], n
 
 
-def test_flash_attention_gqa(qkv):
-    q, _, _ = qkv
-    key = jax.random.PRNGKey(7)
-    k2, v2 = (jax.random.normal(k, (B, S, 2, D), jnp.float32)
-              for k in jax.random.split(key, 2))
-    out = flash_attention(q, k2, v2, causal=True)
-    ref = mha_reference(q, jnp.repeat(k2, 2, 2), jnp.repeat(v2, 2, 2),
-                        causal=True)
-    assert jnp.max(jnp.abs(out - ref)) < 1e-4
+@pytest.mark.parametrize("seq,most,masked", [
+    (4096, 1.13, 0.25),    # mistral7b-train-s4096, the mesh cell's chips
+    (512, 1.5, 1.0),       # mistral7b-train-s512
+    (2048, 1.25, 0.25), (1024, 1.25, 1.0), (32768, 1.02, 0.02)])
+def test_causal_tile_counts_at_the_cells_shapes(seq, most, masked):
+    """What the kernels compute over what the mask leaves, at the sizes
+    ``choose_tiles`` picks for d=128 bf16."""
+    tiles = choose_tiles(seq, seq, True, 128, jnp.bfloat16)
+    ratio, n = _ratio(seq, seq, tiles)
+    assert 1.0 <= ratio <= most
+    assert n["diagonal"] <= masked * (n["interior"] + n["diagonal"])
+    assert n["causal_pairs"] == seq * (seq + 1) // 2
+    # Parent schedule, for the record: whole 512 x 1024 tiles.
+    old = _ratio(seq, seq, (min(seq, 512), min(seq, 1024)) * 2)[0]
+    assert ratio < old
+
+
+def test_causal_tile_counts_without_a_mask():
+    tiles = choose_tiles(4096, 4096, False, 128, jnp.bfloat16)
+    assert tiles == (512, 1024, 512, 1024)      # what the parent compiled
+    n = causal_tile_counts(4096, 4096, *tiles, causal=False)
+    assert (n["dead"], n["diagonal"], n["interior"]) == (0, 0, 32)
+    assert n["executed_pairs"] == n["causal_pairs"] == 4096 * 4096
+
+
+@pytest.mark.parametrize("sq,sk", [(32, 32), (48, 16), (16, 48), (40, 24)])
+def test_no_visible_pair_in_a_dead_tile(sq, sk):
+    """Exhaustive on small sizes, every sub-tile shape that divides: a
+    dead sub-tile holds no pair with q >= k, an interior one no pair with
+    q < k, and the counter agrees with the classification."""
+    from ray_tpu.ops.attention import _tile_kind
+
+    for sub_q in (1, 2, 4, 8, 16):
+        for sub_k in (1, 2, 4, 8, 16):
+            if sq % sub_q or sk % sub_k:
+                continue
+            kinds = {"dead": 0, "interior": 0, "diagonal": 0}
+            for q0 in range(0, sq, sub_q):
+                for k0 in range(0, sk, sub_k):
+                    interior, diagonal = _tile_kind(k0 - q0, sub_q, sub_k)
+                    assert not (interior and diagonal)
+                    seen = [q >= k for q in range(q0, q0 + sub_q)
+                            for k in range(k0, k0 + sub_k)]
+                    if interior:
+                        assert all(seen)
+                    elif diagonal:
+                        assert any(seen) and not all(seen)
+                    else:
+                        assert not any(seen)
+                    kinds["interior" if interior else
+                          "diagonal" if diagonal else "dead"] += 1
+            n = causal_tile_counts(sq, sk, sq, sk, sub_q, sub_k)
+            assert {k: n[k] for k in kinds} == kinds
+            assert n["executed_pairs"] >= n["causal_pairs"] == sum(
+                min(q + 1, sk) for q in range(sq))
 
 
 @pytest.mark.parametrize("impl", ["ring", "ulysses"])

@@ -91,8 +91,16 @@ def test_paged_attention_compiles(one_chip, B, h, d, bs, blocks, dtype,
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
-def test_flash_attention_compiles(one_chip, grad):
-    x = _shape((8, 2048, 32, 128), jnp.bfloat16, one_chip)
+@pytest.mark.parametrize("shape", [
+    (8, 2048, 32, 128),     # the smoke's: one fetch tile, walked in sub-tiles
+    (4, 4096, 32, 128),     # mistral7b-train-s4096: 2 x 2 tiles, one dead
+    (32, 512, 32, 128),     # mistral7b-train-s512: narrow sub-tiles
+    (2, 4096, 16, 128),     # a chip of deepseek7b-train-s4096-x4
+], ids=lambda s: "x".join(map(str, s)))
+def test_flash_attention_compiles(one_chip, shape, grad):
+    """Sub-tile slices, the masked part's concatenation and the clamped
+    index maps are what Mosaic could refuse."""
+    x = _shape(shape, jnp.bfloat16, one_chip)
 
     def f(q, k, v):
         return flash_attention(q, k, v, causal=True,

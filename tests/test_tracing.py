@@ -280,7 +280,9 @@ def test_step_breakdown_on_a_synthetic_trace(tmp_path):
 
     fwd = "jit(step)/jvp()/while/body/closed_call/"
     bwd = "jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
-    kernel = ', custom_call_target=\\"tpu_custom_call\\"'
+    kernel = (', custom_call_target=\\"tpu_custom_call\\", '
+              'operand_layout_constraints={bf16[4,32,4096,128]{3,2,1,0}, '
+              'bf16[4,32,4096,128]{3,2,1,0}, bf16[4,32,4096,128]{3,2,1,0}}')
     # One step of 1000 ns from t=2000 (the first execution is a lead-in):
     # a forward ``while`` [2000, 2400) over three ops, its own 100 ns left;
     # then a backward while [2500, 2900) holding a remat op, a nested
@@ -336,6 +338,11 @@ def test_step_breakdown_on_a_synthetic_trace(tmp_path):
     assert b["unscoped_ops"] == [["copy.9", pytest.approx(30e-9)]]
     assert {k: ns(t) for k, t in b["kernels"].items()} == {
         "flash_fwd": 100, "flash_fwd.remat": 50, "flash_dkv": 120}
+    # Beside each kernel row, what its schedule computes over what the
+    # causal mask leaves at the operands' shapes: static, from the text.
+    assert b["kernel_pairs"] == {k: pytest.approx(1.0622, abs=1e-4)
+                                 for k in b["kernels"]}
+    assert "flash_dkv  executed/causal 1.0622" in format_breakdown(b)
     assert ns(b["step_s"]) == 1000
     total = sum(t for row in got.values() for t in row.values()) + 30
     assert total == ns(b["busy_s"]) == 1000  # the parts sum to the busy time
