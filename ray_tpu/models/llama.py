@@ -15,8 +15,10 @@ TPU-first choices:
 - attention is pluggable: pallas flash (ops/attention.py), ring over 'sp'
   (ops/ring_attention.py), Ulysses all-to-all, or the XLA reference — all
   numerically interchangeable (tested).
-- MoE layers use the dense-dispatch router (ops/moe.py); expert tensors are
-  sharded over 'ep' so XLA lowers dispatch/combine to ICI all-to-alls.
+- MoE layers are dropless (ops/moe.py): every token reaches all of its
+  ``num_selected`` experts through a grouped matmul over the sorted
+  assignments; expert tensors are sharded over 'ep', each rank computes its
+  own experts' rows inside a shard_map and the partial outputs are summed.
 
 Reference counterpart: none in Ray core (no tensor ops); RLlib's model zoo
 (``rllib/models/catalog.py``) plays the "models shipped with the framework"
@@ -27,6 +29,7 @@ for parity, not design.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -39,8 +42,10 @@ from ray_tpu.ops.ulysses import ulysses_attention
 from ray_tpu.ops.layers import (
     rms_norm, rope, apply_rope, swiglu, repeat_kv_heads,
 )
-from ray_tpu.ops.moe import moe_ffn
-from ray_tpu.parallel.mesh import AXIS_DP, AXIS_FSDP, AXIS_SP, AXIS_TP
+from ray_tpu.ops.moe import moe_block
+from ray_tpu.parallel.mesh import (
+    AXIS_DP, AXIS_EP, AXIS_FSDP, AXIS_SP, AXIS_TP,
+)
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES, LogicalAxisRules, with_logical_constraint,
 )
@@ -61,9 +66,12 @@ class LlamaConfig:
     param_dtype: Any = jnp.float32
     attn_impl: str = "flash"          # flash | ring | ulysses | reference
     num_experts: int = 0              # 0 = dense FFN
-    num_selected: int = 2
-    capacity_factor: float = 1.25
-    aux_loss_coef: float = 0.01
+    num_selected: int = 2             # experts a token goes to (all of them)
+    norm_topk_prob: bool = False      # renormalise the selected gates
+    aux_loss_coef: float = 0.01       # load-balancing loss
+    z_loss_coef: float = 0.0          # router z-loss
+    norm_eps: float = 1e-6            # every RMSNorm
+    qk_norm: bool = False             # RMSNorm over the q and k projections
     remat: bool = True
 
     @property
@@ -106,6 +114,9 @@ def _dense_layer_shapes(cfg: LlamaConfig) -> Dict[str, Tuple[Tuple[int, ...],
         "wo": ((h, d), ("layer", "heads", "kernel_in")),
         "mlp_norm": ((d,), ("layer", "embed")),
     }
+    if cfg.qk_norm:  # over the whole projection, before heads and RoPE
+        shapes.update({"q_norm": ((h,), ("layer", "heads")),
+                       "k_norm": ((kvd,), ("layer", "kv_heads"))})
     if cfg.num_experts:
         e = cfg.num_experts
         shapes.update({
@@ -232,15 +243,76 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig, *,
     layer_fn = _make_layer_fn(cfg, mesh, rules)
     if cfg.remat:
         layer_fn = jax.checkpoint(layer_fn)
-    (x, aux), _ = jax.lax.scan(layer_fn, (x, jnp.zeros((), jnp.float32)),
+    (x, aux), _ = jax.lax.scan(layer_fn, (x, _zero_aux(cfg)),
                                params["layers"])
-    return _lm_head(params, x, cfg, cst), aux / cfg.num_layers
+    return _lm_head(params, x, cfg, cst), _mean_aux(aux, cfg)
+
+
+def _zero_aux(cfg: LlamaConfig):
+    """What the layer scan carries beside the activations: a dense model's
+    auxiliary loss (0), or the expert layers' float32 scalars."""
+    zero = jnp.zeros((), jnp.float32)
+    if not cfg.num_experts:
+        return zero
+    return {"aux_loss": zero, "z_loss": zero, "load_max_over_mean": zero,
+            "dropped": zero}
+
+
+def _mean_aux(aux, cfg: LlamaConfig):
+    if not cfg.num_experts:
+        return aux / cfg.num_layers
+    return dict(aux, aux_loss=aux["aux_loss"] / cfg.num_layers,
+                z_loss=aux["z_loss"] / cfg.num_layers)
+
+
+def _moe(x, lp, cfg: LlamaConfig, mesh: Optional[Mesh], cst):
+    """The expert layer (``ops.moe.moe_block``) on the residual stream.
+    Under a mesh it runs per shard, as the flash kernel does: tokens over
+    (dp, fsdp) x sp, experts over ep, their width over tp, partial outputs
+    summed over ep x tp.  Inside a region that is already manual (the
+    pipeline) it is called as it is and the partitioner splits it, which
+    the TPU lowering refuses for a Mosaic kernel."""
+    block = functools.partial(
+        moe_block, num_selected=cfg.num_selected, norm_eps=cfg.norm_eps,
+        norm_topk_prob=cfg.norm_topk_prob)
+    args = (x, lp["mlp_norm"], lp["router"], lp["w_gate"], lp["w_up"],
+            lp["w_down"])
+    if mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return block(*args)
+    from ray_tpu.parallel.sharding import manual_shard_map
+    # The parameters as the region takes them, laid out under the scope
+    # that uses them (pinned first as they are stored, or the partitioner
+    # moves the change of layout up to the scan's slice): the fsdp gathers
+    # and their gradients' scatters then carry a step scope like every
+    # other collective.
+    axes = param_logical_axes(cfg)["layers"]
+
+    def laid_out(name, *gathered):
+        return cst(cst(lp[name], axes[name][1:]), gathered)
+
+    with jax.named_scope("moe_route"):
+        small = (laid_out("mlp_norm", None), laid_out("router", None, None))
+    with jax.named_scope("moe_experts"):
+        args = (x,) + small + (
+            laid_out("w_gate", "expert", None, "mlp"),
+            laid_out("w_up", "expert", None, "mlp"),
+            laid_out("w_down", "expert", "mlp", None))
+    x_spec = P((AXIS_DP, AXIS_FSDP), AXIS_SP, None)
+    up_spec = P(AXIS_EP, None, AXIS_TP)
+    fn = manual_shard_map(
+        functools.partial(block, token_axes=(AXIS_DP, AXIS_FSDP, AXIS_SP),
+                          expert_axis=AXIS_EP, sum_axes=(AXIS_EP, AXIS_TP)),
+        set(mesh.axis_names),
+        in_specs=(x_spec, P(), P(), up_spec, up_spec,
+                  P(AXIS_EP, AXIS_TP, None)),
+        out_specs=(x_spec, P()), mesh=mesh)
+    return fn(*args)
 
 
 def _lm_head(params, x, cfg: LlamaConfig, cst):
     """Final norm and head product -> f32 logits (scope ``lm_head``)."""
     with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
         return cst(logits, ("batch", "seq", "vocab"))
 
@@ -272,11 +344,14 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False):
             if sp_manual:
                 offset = jax.lax.axis_index(AXIS_SP) * s
             cos, sin = rope(s, cfg.head_dim, cfg.rope_theta, offset=offset)
-            h = rms_norm(x, lp["attn_norm"])
-            q = (h @ lp["wq"].astype(cfg.dtype)).reshape(
-                b, s, cfg.num_heads, cfg.head_dim)
-            k = (h @ lp["wk"].astype(cfg.dtype)).reshape(
-                b, s, cfg.num_kv_heads, cfg.head_dim)
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q = h @ lp["wq"].astype(cfg.dtype)
+            k = h @ lp["wk"].astype(cfg.dtype)
+            if cfg.qk_norm:
+                q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+                k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+            q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
+            k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
             v = (h @ lp["wv"].astype(cfg.dtype)).reshape(
                 b, s, cfg.num_kv_heads, cfg.head_dim)
             q = cst(apply_rope(q, cos, sin),
@@ -293,20 +368,17 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False):
             x = x + cst(o @ lp["wo"].astype(cfg.dtype),
                         ("batch", "seq", "embed"))
 
+        if cfg.num_experts:  # opens its own four scopes in place of ffn
+            x, stats = _moe(x, lp, cfg, mesh, cst)
+            x = cst(x, ("batch", "seq", "embed"))
+            aux = {k: (jnp.maximum if k == "load_max_over_mean"
+                       else jnp.add)(v, stats[k]) for k, v in aux.items()}
+            return (x, aux), None
         with jax.named_scope("ffn"):
-            h = rms_norm(x, lp["mlp_norm"])
-            if cfg.num_experts:
-                flat = h.reshape(b * s, cfg.embed_dim)
-                moe = moe_ffn(flat, lp["router"], lp["w_gate"], lp["w_up"],
-                              lp["w_down"], num_selected=cfg.num_selected,
-                              capacity_factor=cfg.capacity_factor,
-                              constrain=cst if mesh is not None else None)
-                ff = moe.out.reshape(b, s, cfg.embed_dim)
-                aux = aux + moe.aux_loss
-            else:
-                gate = h @ lp["w_gate"].astype(cfg.dtype)
-                up = h @ lp["w_up"].astype(cfg.dtype)
-                ff = swiglu(gate, up) @ lp["w_down"].astype(cfg.dtype)
+            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            gate = h @ lp["w_gate"].astype(cfg.dtype)
+            up = h @ lp["w_up"].astype(cfg.dtype)
+            ff = swiglu(gate, up) @ lp["w_down"].astype(cfg.dtype)
             x = x + cst(ff, ("batch", "seq", "embed"))
         return (x, aux), None
 
@@ -326,8 +398,9 @@ def forward_pipelined(params: Dict[str, Any], tokens: jax.Array,
     regions) — activations enter seq-sharded, RoPE offsets come from the
     'sp' rank, and attention runs inline over the bound axis.
 
-    MoE aux loss inside pipeline stages is dropped (stage outputs must be
-    activation-shaped); use dense FFN or accept coef=0 semantics under pp.
+    The expert layers' auxiliary losses and counters are not carried out of
+    the pipeline stages (stage outputs must be activation-shaped): under pp
+    an MoE model trains with both coefficients at 0.
     """
     from ray_tpu.parallel.pipeline import pipeline_apply, split_stages
     from ray_tpu.parallel.mesh import AXIS_PP
@@ -356,15 +429,15 @@ def forward_pipelined(params: Dict[str, Any], tokens: jax.Array,
         layer_fn = jax.checkpoint(layer_fn)
 
     def stage_fn(stage_params, x_mb):
-        (y, _), _ = jax.lax.scan(
-            layer_fn, (x_mb, jnp.zeros((), jnp.float32)), stage_params)
+        (y, _), _ = jax.lax.scan(layer_fn, (x_mb, _zero_aux(cfg)),
+                                 stage_params)
         return y
 
     stages = split_stages(params["layers"], mesh.shape[AXIS_PP])
     x = pipeline_apply(stage_fn, stages, x, mesh=mesh,
                        num_microbatches=num_microbatches,
                        manual_axes=manual_axes, x_spec=x_spec)
-    return _lm_head(params, x, cfg, cst), jnp.zeros((), jnp.float32)
+    return _lm_head(params, x, cfg, cst), _zero_aux(cfg)
 
 
 def pipeline_stage_params(params: Dict[str, Any],
@@ -408,8 +481,8 @@ def make_pipeline_stage_fn(cfg: LlamaConfig):
         if "embed" in sp:
             with jax.named_scope("embed"):
                 x = jnp.take(sp["embed"], x, axis=0).astype(cfg.dtype)
-        (x, _), _ = jax.lax.scan(
-            layer_fn, (x, jnp.zeros((), jnp.float32)), sp["layers"])
+        (x, _), _ = jax.lax.scan(layer_fn, (x, _zero_aux(cfg)),
+                                 sp["layers"])
         if "lm_head" in sp:
             x = _lm_head(sp, x, cfg, _make_cst(None, None))
         return x
@@ -449,8 +522,16 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
         logits, aux = forward_fn(params, inputs)
     with jax.named_scope("loss"):
         loss = _mean_nll(logits, targets)
-        total = loss + cfg.aux_loss_coef * aux
-        return total, {"loss": loss, "aux_loss": aux,
+        if not cfg.num_experts:
+            total = loss + cfg.aux_loss_coef * aux
+            return total, {"loss": loss, "aux_loss": aux,
+                           "perplexity": jnp.exp(loss)}
+        total = (loss + cfg.aux_loss_coef * aux["aux_loss"]
+                 + cfg.z_loss_coef * aux["z_loss"])
+        return total, {"loss": loss, "aux_loss": aux["aux_loss"],
+                       "z_loss": aux["z_loss"],
+                       "moe_load_max_over_mean": aux["load_max_over_mean"],
+                       "moe_dropped": aux["dropped"],
                        "perplexity": jnp.exp(loss)}
 
 

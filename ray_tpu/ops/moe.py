@@ -1,95 +1,421 @@
-"""Mixture-of-Experts routing (GShard/Switch-style) for expert parallelism.
+"""Mixture-of-experts layer: dropless token-choice routing through a
+grouped matmul.
 
-Token-choice top-k routing with fixed expert capacity, expressed as dense
-dispatch/combine einsums — the idiomatic XLA formulation: static shapes (no
-data-dependent gather), and when the expert dimension is sharded over the
-'ep' mesh axis the dispatch/combine contractions lower to all-to-alls over
-ICI.  The reference has no MoE; its expert-parallel analog would be NCCL
-all-to-all via ``ray.util.collective`` (SURVEY.md §2.3) — here the router is
-a framework op and the collective is XLA's.
+Every token goes to its ``num_selected`` best experts, whatever the
+imbalance: nothing has a capacity and nothing is dropped.  The layer
+(``moe_block``) is
 
-Returns auxiliary load-balancing loss (Switch §2.2 form: E * sum_e f_e * p_e).
+- ``moe_route``: RMSNorm, router logits accumulated in float32, float32
+  softmax, ``lax.top_k`` (renormalised only where the model says so), the
+  load-balancing loss over ALL the choices and the router z-loss;
+- ``moe_dispatch``: a stable sort of the ``T * k`` (token, choice) pairs
+  by expert, group sizes by ``bincount``, a gather to ``(T * k, d)`` rows;
+- ``moe_experts``: three grouped products over the ragged groups (row
+  ``r`` meets the weights of the group it lies in) and SwiGLU;
+- ``moe_combine``: each token's rows gathered back, weighted by its
+  gates, summed, and added to the residual.
+
+All shapes are static (``T * k`` rows, rounded up to a row tile); a group
+may be empty or hold every row.  The grouped product is two Pallas
+kernels, chosen over ``jax.lax.ragged_dot`` by measurement on a v5e
+(PERF.md §6, PR 25): ``moe_gmm`` (rows x their group's weights, also with
+the weights transposed for the rows' gradient) and ``moe_tgmm`` (the
+weights' gradient, per group).  Both walk one schedule of (group, row
+tile) visits handed over as scalar prefetch: a tile that two groups
+share is visited once for each, and the rows that are not the visit's
+are masked.  Rows past the groups' sum — the padding, and under expert
+parallelism the rows of other ranks' experts — come back as zeros.
+
+Inside a ``shard_map`` (a Pallas kernel has no partitioning rule) the
+layer takes the names of the mesh axes: tokens are split over
+``token_axes`` and the same on every other axis; each rank of
+``expert_axis`` holds ``E / ep`` experts, computes the rows routed to
+them, and the partial outputs are summed over ``sum_axes``.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+import functools
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.layers import swiglu
+from ray_tpu.ops import attention
+from ray_tpu.ops.layers import rms_norm, swiglu
 
-
-class MoEOutput(NamedTuple):
-    out: jax.Array        # (tokens, embed)
-    aux_loss: jax.Array   # scalar load-balancing loss
-    router_probs: jax.Array  # (tokens, experts) — for metrics
-
-
-def route_topk(router_logits: jax.Array, num_selected: int,
-               capacity: int) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Compute (dispatch (T,E,C) f32 0/1, combine (T,E,C) f32, aux_loss).
-
-    Over-capacity tokens are dropped (their combine weights are zero), which
-    keeps shapes static — the XLA-native alternative to dynamic routing.
-    """
-    t, e = router_logits.shape
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, num_selected)   # (T, k)
-
-    # Position of each (token, choice) in its expert's buffer: running count
-    # of earlier assignments to the same expert, counted over the flattened
-    # (choice-major) assignment order so k=2 second choices queue after
-    # first choices.
-    onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)      # (T, k, E)
-    flat = onehot.transpose(1, 0, 2).reshape(-1, e)              # (k*T, E)
-    pos_flat = jnp.cumsum(flat, axis=0) - flat                   # (k*T, E)
-    pos = pos_flat.reshape(num_selected, t, e).transpose(1, 0, 2)  # (T,k,E)
-    pos = jnp.sum(pos * onehot, axis=-1)                         # (T, k)
-    within = pos < capacity
-
-    disp = jnp.zeros((t, e, capacity), jnp.float32)
-    comb = jnp.zeros((t, e, capacity), jnp.float32)
-    tok = jnp.arange(t)
-    for c in range(num_selected):
-        idx = (tok, expert_idx[:, c], jnp.clip(pos[:, c], 0, capacity - 1))
-        keep = within[:, c].astype(jnp.float32)
-        disp = disp.at[idx].add(keep)
-        comb = comb.at[idx].add(keep * gate_vals[:, c])
-
-    # Load-balance loss: fraction of tokens per expert x mean router prob.
-    density = jnp.mean(
-        jax.nn.one_hot(expert_idx[:, 0], e, dtype=jnp.float32), axis=0)
-    aux = e * jnp.sum(density * jnp.mean(probs, axis=0))
-    return disp, comb, aux
+# Row tile of the grouped product.  A tile that two groups share is
+# computed once for each, so a call of G groups executes up to
+# (tiles + G - 1) * tile rows: the largest tile that keeps this within
+# MAX_EXECUTED of the rows wins (longer products feed the MXU better),
+# else the smallest.  Measured on TPU v5e, bf16 (PERF.md §6, PR 25).
+ROW_TILES = (512, 256, 128)
+MAX_EXECUTED = 1.15
+_WEIGHT_BLOCK_BYTES = 4 * 1024 * 1024   # one group's weights in VMEM
+_ACC_BLOCK_BYTES = 8 * 1024 * 1024      # float32 accumulator of moe_tgmm
 
 
-def moe_ffn(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
-            w_up: jax.Array, w_down: jax.Array, *, num_selected: int = 2,
-            capacity_factor: float = 1.25,
-            constrain=None) -> MoEOutput:
-    """SwiGLU MoE layer.  x: (tokens, embed); router_w: (embed, E);
-    w_gate/w_up: (E, embed, mlp); w_down: (E, mlp, embed).
+def executed_rows(rows: int, groups: int, tile: int) -> int:
+    """Rows the kernels compute at most for ``rows`` needed ones."""
+    return (-(-rows // tile) + groups - 1) * tile
 
-    ``constrain(x, logical_axes)`` optionally applies sharding constraints
-    (expert tensors get ('expert', ...), so 'ep' carries the all-to-all).
-    """
-    t, d = x.shape
-    e = router_w.shape[1]
-    capacity = max(1, int(capacity_factor * t * num_selected / e))
-    logits = x @ router_w.astype(x.dtype)
-    disp, comb, aux = route_topk(logits, num_selected, capacity)
 
-    expert_in = jnp.einsum("tec,td->ecd", disp.astype(x.dtype), x)
-    if constrain is not None:
-        expert_in = constrain(expert_in, ("expert", None, "embed"))
-    gate = jnp.einsum("ecd,edm->ecm", expert_in, w_gate.astype(x.dtype))
-    up = jnp.einsum("ecd,edm->ecm", expert_in, w_up.astype(x.dtype))
-    act = swiglu(gate, up)
-    expert_out = jnp.einsum("ecm,emd->ecd", act, w_down.astype(x.dtype))
-    if constrain is not None:
-        expert_out = constrain(expert_out, ("expert", None, "embed"))
-    out = jnp.einsum("tec,ecd->td", comb.astype(x.dtype), expert_out)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    return MoEOutput(out, aux, probs)
+def choose_tiles(rows: int, groups: int) -> int:
+    """The row tile for ``rows`` rows in ``groups`` groups."""
+    for tile in ROW_TILES:
+        if executed_rows(rows, groups, tile) <= MAX_EXECUTED * rows:
+            return tile
+    return ROW_TILES[-1]
+
+
+def _fit_columns(n: int, rows: int, itemsize: int, budget: int) -> int:
+    """Widest column block of an ``(rows, n)`` operand under ``budget``
+    bytes: ``n`` itself, or a divisor of it that is a multiple of 128."""
+    block = n
+    while rows * block * itemsize > budget and block % 256 == 0:
+        block //= 2
+    return block
+
+
+class Schedule(NamedTuple):
+    """The visits of one set of group sizes, as the kernels' scalar
+    prefetch.  The rows past the groups' sum are group ``G``, which has no
+    weights: its tiles are zero-filled."""
+    offsets: jax.Array     # (G + 2,) first row of each group, then the end
+    group_ids: jax.Array   # (V,) group of each visit
+    tile_ids: jax.Array    # (V,) row tile of each visit
+    num_visits: jax.Array  # (1,) visits that are real; the rest is padding
+
+
+def make_schedule(group_sizes: jax.Array, rows: int, tile: int) -> Schedule:
+    """Visits in row order: each group walks the tiles its rows touch
+    (an empty group keeps one visit, so its weight gradient is zeroed).
+    ``V = rows / tile + G`` bounds their number: every tile once, and once
+    more for each boundary inside a tile."""
+    groups = group_sizes.shape[0]
+    n_tiles = rows // tile
+    group_sizes = group_sizes.astype(jnp.int32)
+    sizes = jnp.concatenate(
+        [group_sizes, rows - jnp.sum(group_sizes, keepdims=True)])
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = jnp.minimum(starts // tile, n_tiles - 1)
+    last = jnp.where(sizes > 0, (ends - 1) // tile, first)
+    visits = last - first + 1
+    visit_ends = jnp.cumsum(visits)
+    n_visits = n_tiles + groups
+    group_ids = jnp.repeat(jnp.arange(groups + 1, dtype=jnp.int32), visits,
+                           total_repeat_length=n_visits)
+    nth = jnp.arange(n_visits, dtype=jnp.int32) - (
+        visit_ends - visits)[group_ids]
+    tile_ids = jnp.minimum(first[group_ids] + nth, n_tiles - 1)
+    return Schedule(
+        jnp.concatenate([jnp.zeros((1,), jnp.int32), ends]).astype(jnp.int32),
+        group_ids, tile_ids.astype(jnp.int32),
+        visit_ends[-1:].astype(jnp.int32))
+
+
+def _visit(sched_refs, i, tile):
+    """(group, first and one-past-last row of the group inside the
+    visit's tile, is the visit real) of visit ``i``."""
+    offsets, group_ids, tile_ids, num_visits = sched_refs
+    g = group_ids[i]
+    row0 = tile_ids[i] * tile
+    return g, offsets[g] - row0, offsets[g + 1] - row0, i < num_visits[0]
+
+
+def _row_mask(lo, hi, tile):
+    rows = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+    return (rows >= lo) & (rows < hi)
+
+
+def _gmm_kernel(offsets, group_ids, tile_ids, num_visits, lhs_ref, rhs_ref,
+                out_ref, *, tile, groups, transpose_rhs):
+    g, lo, hi, real = _visit((offsets, group_ids, tile_ids, num_visits),
+                             pl.program_id(1), tile)
+    live = real & (hi > lo)
+    interior = (lo <= 0) & (hi >= tile)
+
+    def store(value):
+        @pl.when(interior)
+        def _whole():
+            out_ref[...] = value()
+
+        @pl.when(jnp.logical_not(interior))
+        def _masked():
+            out_ref[...] = jnp.where(_row_mask(lo, hi, tile), value(),
+                                     out_ref[...])
+
+    @pl.when(live & (g < groups))
+    def _product():
+        dims = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+        store(lambda: jax.lax.dot_general(
+            lhs_ref[...], rhs_ref[...], dims,
+            preferred_element_type=jnp.float32).astype(out_ref.dtype))
+
+    @pl.when(live & (g == groups))
+    def _past_the_groups():
+        store(lambda: jnp.zeros(out_ref.shape, out_ref.dtype))
+
+
+def _tgmm_kernel(offsets, group_ids, tile_ids, num_visits, lhs_ref, rhs_ref,
+                 out_ref, acc_ref, *, tile, groups):
+    i = pl.program_id(1)
+    g, lo, hi, real = _visit((offsets, group_ids, tile_ids, num_visits),
+                             i, tile)
+    real = real & (g < groups)
+    # The pseudo-group's visit follows the last group's, so i + 1 exists.
+    before = group_ids[jnp.maximum(i - 1, 0)]
+    after = group_ids[jnp.minimum(i + 1, pl.num_programs(1) - 1)]
+
+    @pl.when(real & ((i == 0) | (before != g)))
+    def _first_visit():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def accumulate(rhs):
+        acc_ref[...] += jax.lax.dot_general(
+            lhs_ref[...], rhs, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    interior = (lo <= 0) & (hi >= tile)
+
+    @pl.when(real & (hi > lo) & interior)
+    def _whole():
+        accumulate(rhs_ref[...])
+
+    @pl.when(real & (hi > lo) & jnp.logical_not(interior))
+    def _masked():  # other groups' rows leave through ONE operand
+        rhs = rhs_ref[...]
+        accumulate(jnp.where(_row_mask(lo, hi, tile), rhs,
+                             jnp.zeros_like(rhs)))
+
+    @pl.when(real & (after != g))
+    def _last_visit():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _compiler_params(interpret):
+    if interpret:
+        return None
+    # Visits revisit output blocks in order: sequential.
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=100 * 1024 * 1024)
+
+
+def _gmm(lhs, rhs, sched: Schedule, tile, transpose_rhs, interpret):
+    """``out[r] = lhs[r] @ rhs[group of r]`` (``rhs[g].T`` if
+    ``transpose_rhs``); zeros for the rows past the groups."""
+    rows, k = lhs.shape
+    groups = rhs.shape[0]
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tn = _fit_columns(n, k, rhs.dtype.itemsize, _WEIGHT_BLOCK_BYTES)
+    group_of = lambda j, i, o, g, t, v: jnp.minimum(g[i], groups - 1)
+    if transpose_rhs:
+        rhs_spec = pl.BlockSpec(
+            (None, tn, k), lambda j, i, *s: (group_of(j, i, *s), j, 0))
+    else:
+        rhs_spec = pl.BlockSpec(
+            (None, k, tn), lambda j, i, *s: (group_of(j, i, *s), 0, j))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tile=tile, groups=groups,
+                          transpose_rhs=transpose_rhs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, sched.group_ids.shape[0]),
+            in_specs=[
+                pl.BlockSpec((tile, k), lambda j, i, o, g, t, v: (t[i], 0)),
+                rhs_spec],
+            out_specs=pl.BlockSpec(
+                (tile, tn), lambda j, i, o, g, t, v: (t[i], j))),
+        out_shape=jax.ShapeDtypeStruct((rows, n), lhs.dtype),
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+        name="moe_gmm",
+    )(*sched, lhs, rhs)
+
+
+def _tgmm(lhs, rhs, sched: Schedule, groups, tile, interpret):
+    """``out[g] = lhs[rows of g].T @ rhs[rows of g]``; zeros for an
+    empty group."""
+    rows, k = lhs.shape
+    n = rhs.shape[1]
+    tn = _fit_columns(n, k, 4, _ACC_BLOCK_BYTES)
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tile=tile, groups=groups),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, sched.group_ids.shape[0]),
+            in_specs=[
+                pl.BlockSpec((tile, k), lambda j, i, o, g, t, v: (t[i], 0)),
+                pl.BlockSpec((tile, tn), lambda j, i, o, g, t, v: (t[i], j))],
+            out_specs=pl.BlockSpec(
+                (None, k, tn),
+                lambda j, i, o, g, t, v: (jnp.minimum(g[i], groups - 1), 0,
+                                          j)),
+            scratch_shapes=[pltpu.VMEM((k, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((groups, k, n), lhs.dtype),
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+        name="moe_tgmm",
+    )(*sched, lhs, rhs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def grouped_matmul(rows: jax.Array, weights: jax.Array, sched: Schedule,
+                   tile: int, interpret: bool) -> jax.Array:
+    """``rows (M, K)`` x ``weights (G, K, N)`` -> ``(M, N)``: row ``r``
+    meets ``weights[g]`` for the group ``g`` that ``sched`` puts it in
+    (``make_schedule(group_sizes, M, tile)``; ``M`` a multiple of
+    ``tile``).  Rows past the groups' sum give zeros and no gradient."""
+    return _gmm(rows, weights.astype(rows.dtype), sched, tile, False,
+                interpret)
+
+
+def _grouped_matmul_fwd(rows, weights, sched, tile, interpret):
+    return (grouped_matmul(rows, weights, sched, tile, interpret),
+            (rows, weights, sched))
+
+
+def _grouped_matmul_bwd(tile, interpret, res, d_out):
+    rows, weights, sched = res
+    d_rows = _gmm(d_out, weights.astype(d_out.dtype), sched, tile, True,
+                  interpret)
+    d_weights = _tgmm(rows, d_out, sched, weights.shape[0], tile, interpret)
+    return d_rows, d_weights.astype(weights.dtype), None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+# Dispatch and combine are gathers both ways: the transpose of "row r
+# reads token t" is "token t reads its k rows", so neither direction
+# scatters (a TPU scatter-add serialises; a row gather streams).
+
+@jax.custom_vjp
+def _dispatch(x, row_token, slot_row):
+    """``x (T, d)`` -> rows ``(M, d)``: row ``r`` is ``x[row_token[r]]``;
+    ``slot_row (T, k)`` is the row of each (token, choice)."""
+    return jnp.take(x, row_token, axis=0)
+
+
+def _dispatch_fwd(x, row_token, slot_row):
+    return _dispatch(x, row_token, slot_row), slot_row
+
+
+def _dispatch_bwd(slot_row, d_rows):
+    d_x = jnp.sum(jnp.take(d_rows, slot_row, axis=0).astype(jnp.float32),
+                  axis=1)
+    return d_x.astype(d_rows.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y_rows, gates, row_token, row_slot, slot_row):
+    """``out[t] = sum_j gates[t, j] * y_rows[slot_row[t, j]]`` in
+    float32; ``row_slot (M,)`` is the flat (token, choice) of each row."""
+    picked = jnp.take(y_rows, slot_row, axis=0).astype(jnp.float32)
+    return jnp.einsum("tk,tkd->td", gates, picked).astype(y_rows.dtype)
+
+
+def _combine_fwd(y_rows, gates, row_token, row_slot, slot_row):
+    return (_combine(y_rows, gates, row_token, row_slot, slot_row),
+            (y_rows, gates, row_token, row_slot, slot_row))
+
+
+def _combine_bwd(res, d_out):
+    y_rows, gates, row_token, row_slot, slot_row = res
+    # One gather serves both gradients: the gate's is each row's product
+    # with ITS token's cotangent, taken in row order and then picked.
+    d_out_rows = jnp.take(d_out, row_token, axis=0).astype(jnp.float32)
+    row_gate = jnp.take(gates.reshape(-1), row_slot)
+    d_rows = (d_out_rows * row_gate[:, None]).astype(y_rows.dtype)
+    d_row_gate = jnp.sum(y_rows.astype(jnp.float32) * d_out_rows, axis=-1)
+    d_gates = jnp.take(d_row_gate, slot_row)
+    return d_rows, d_gates.astype(gates.dtype), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _psum(x, axes):
+    return jax.lax.psum(x, tuple(axes)) if axes else x
+
+
+def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
+              w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
+              num_selected: int, norm_eps: float = 1e-6,
+              norm_topk_prob: bool = False, tile: Optional[int] = None,
+              token_axes: Sequence[str] = (),
+              expert_axis: Optional[str] = None,
+              sum_axes: Sequence[str] = ()
+              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The expert layer on the residual stream ``x (..., d)``: returns
+    ``x + experts(norm(x))`` and float32 scalars ``aux_loss`` (load
+    balancing), ``z_loss``, ``load_max_over_mean`` (the busiest expert's
+    assignments over the mean) and ``dropped`` (assignments that reached
+    no expert: 0).  ``router_w (d, E)``; ``w_gate``/``w_up (E', d, m')``,
+    ``w_down (E', m', d)`` — all the experts, or inside a ``shard_map``
+    this rank's ``E / ep`` of them (``expert_axis``) at this rank's slice
+    of ``m`` (``sum_axes`` then names the axes the partial outputs are
+    summed over, and ``token_axes`` those the tokens are split over)."""
+    shape, d = x.shape, x.shape[-1]
+    x = x.reshape(-1, d)
+    t, e, k = x.shape[0], router_w.shape[1], num_selected
+    local = w_gate.shape[0]
+
+    with jax.named_scope("moe_route"):
+        h = rms_norm(x, norm_w, norm_eps)
+        logits = jnp.dot(h, router_w.astype(h.dtype),
+                         preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, experts = jax.lax.top_k(probs, k)                 # (T, k)
+        if norm_topk_prob:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        flat = experts.reshape(-1)
+        assigned = jnp.bincount(flat, length=e)   # of this shard's tokens
+        counts = _psum(assigned, token_axes)
+        tokens = _psum(jnp.float32(t), token_axes)
+        mean_prob = _psum(jnp.sum(probs, axis=0), token_axes) / tokens
+        aux = e * jnp.sum(counts.astype(jnp.float32) / tokens * mean_prob)
+        z = _psum(jnp.sum(jnp.square(
+            jax.nn.logsumexp(logits, axis=-1))), token_axes) / tokens
+        load = jnp.max(counts).astype(jnp.float32) * e / (tokens * k)
+
+    with jax.named_scope("moe_dispatch"):
+        group_sizes = assigned
+        if expert_axis is not None:  # this rank's experts sort first
+            first = jax.lax.axis_index(expert_axis) * local
+            flat = (flat - first) % e
+            group_sizes = jax.lax.dynamic_slice(assigned, (first,), (local,))
+        tile = tile or choose_tiles(t * k, local)
+        rows = -(-t * k // tile) * tile
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        sched = make_schedule(group_sizes, rows, tile)
+        pad = jnp.zeros((rows - t * k,), jnp.int32)
+        row_slot = jnp.concatenate([order, pad])
+        row_token = row_slot // k
+        slot_row = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32),
+            unique_indices=True).reshape(t, k)
+        x_rows = _dispatch(h, row_token, slot_row)
+        dropped = (tokens * k - _psum(
+            jnp.sum(group_sizes).astype(jnp.float32),
+            tuple(token_axes) + ((expert_axis,) if expert_axis else ())))
+
+    with jax.named_scope("moe_experts"):
+        product = functools.partial(
+            grouped_matmul, sched=sched, tile=tile,
+            interpret=attention._interpret_default())  # one rule for both
+        y_rows = product(swiglu(product(x_rows, w_gate),
+                                product(x_rows, w_up)), w_down)
+
+    with jax.named_scope("moe_combine"):
+        y = _combine(y_rows, gates, row_token, row_slot, slot_row)
+        out = (x + _psum(y, sum_axes)).reshape(shape)
+    return out, {"aux_loss": aux, "z_loss": z, "load_max_over_mean": load,
+                 "dropped": dropped}

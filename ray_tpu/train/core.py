@@ -28,12 +28,14 @@ from ray_tpu.parallel.sharding import (
 
 
 # The ``jax.named_scope`` names that between them cover the step program,
-# with no overlap: ``models/llama.py`` opens all but the last, ``step``
+# with no overlap: ``models/llama.py`` opens all but the last (an expert
+# layer opens ``ops/moe.py``'s four ``moe_*`` in place of ``ffn``), ``step``
 # below opens ``optimizer``.  A device op's ``op_name`` carries exactly one
 # of them, wrapped by JAX in the phase: bare or ``jvp(..)`` is the forward
 # pass, under ``rematted_computation`` the rematerialised forward,
 # ``transpose(jvp(..))`` the backward pass (``util.tracing.step_breakdown``).
 STEP_SCOPES = ("embed", "attn_qkv", "attention", "attn_out", "ffn",
+               "moe_route", "moe_dispatch", "moe_experts", "moe_combine",
                "lm_head", "loss", "optimizer")
 
 

@@ -292,6 +292,9 @@ PHASES = ("forward", "remat", "backward", "optimizer")
 # layer part: slicing one layer's weights out of the stacked parameters,
 # writing its gradients into the stacked gradients, the loop itself.
 SCAN = "scan"
+# How the ``name=`` of the program's Pallas kernels start (ops/attention.py,
+# ops/moe.py): the kernel rows of ``step_breakdown``.
+KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm")
 _SCOPE_TOKENS = re.compile(r"[^/()]+")
 
 
@@ -460,7 +463,7 @@ def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
                 row[phase] = row.get(phase, 0) + self_ns
             if 'custom_call_target="tpu_custom_call"' in text:
                 kernel = next((t for t in _SCOPE_TOKENS.findall(stack)
-                               if t.startswith("flash_")), "unnamed")
+                               if t.startswith(KERNEL_NAMES)), "unnamed")
                 key = kernel + (".remat" if phase == "remat" else "")
                 kernels[key] = kernels.get(key, 0) + self_ns
                 if key not in kernel_pairs:
@@ -534,16 +537,16 @@ def format_breakdown(b: Dict[str, Any]) -> str:
     busy = b["busy_s"] or 1.0
     out = [f"device {b['device']}: {b['steps']} steps, "
            f"step {b['step_s'] * 1e3:.3f} ms, busy {b['busy_s'] * 1e3:.3f} ms",
-           f"{'scope':<10}" + "".join(f"{p:>11}" for p in PHASES)
+           f"{'scope':<12}" + "".join(f"{p:>11}" for p in PHASES)
            + f"{'total ms':>11}{'share %':>9}"]
     for scope, row in sorted(b["scopes"].items(),
                              key=lambda kv: -sum(kv[1].values())):
         total = sum(row.values())
-        out.append(f"{scope:<10}"
+        out.append(f"{scope:<12}"
                    + "".join(f"{row.get(p, 0.0) * 1e3:>11.3f}"
                              for p in PHASES)
                    + f"{total * 1e3:>11.3f}{100 * total / busy:>9.2f}")
-    out.append(f"{'unscoped':<10}{'':>44}{b['unscoped_s'] * 1e3:>11.3f}"
+    out.append(f"{'unscoped':<12}{'':>44}{b['unscoped_s'] * 1e3:>11.3f}"
                f"{100 * b['unscoped_s'] / busy:>9.2f}")
     for name, t in b["unscoped_ops"]:
         out.append(f"  unscoped {t * 1e3:9.3f} ms  {name}")

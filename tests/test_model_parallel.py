@@ -172,19 +172,24 @@ def _op_names(hlo_text, opcodes):
     return out
 
 
-@pytest.mark.parametrize("mesh_kw", [None, dict(fsdp=2, tp=2)],
-                         ids=["one_device", "fsdp2_tp2"])
-def test_step_program_is_named_by_scope(mesh_kw):
-    """What a device trace can tell: the three kernels by name, and on
-    every matmul, kernel call and collective exactly one of STEP_SCOPES
+@pytest.mark.parametrize("mesh_kw,moe", [
+    (None, False), (dict(fsdp=2, tp=2), False), (None, True),
+    (dict(fsdp=2, ep=2), True)],
+    ids=["one_device", "fsdp2_tp2", "moe_one_device", "moe_fsdp2_ep2"])
+def test_step_program_is_named_by_scope(mesh_kw, moe):
+    """What a device trace can tell: the kernels by name, and on every
+    matmul, kernel call and collective exactly one of STEP_SCOPES
     (``util.tracing.step_breakdown`` reads them off the profiler's
-    ``op_name``)."""
+    ``op_name``).  An expert layer's four scopes take the place of
+    ``ffn``, each in every phase."""
     import re
 
     from ray_tpu.train.core import STEP_SCOPES
-    from ray_tpu.util.tracing import scope_and_phase
+    from ray_tpu.util.tracing import KERNEL_NAMES, scope_and_phase
 
-    cfg = LlamaConfig.tiny(attn_impl="flash", remat=True, num_kv_heads=2)
+    cfg = LlamaConfig.tiny(attn_impl="flash", remat=True, num_kv_heads=2,
+                           **(dict(num_experts=4, num_selected=2,
+                                   z_loss_coef=0.001) if moe else {}))
     opt = optax.adam(1e-2)
     mesh = None
     if mesh_kw:
@@ -194,7 +199,8 @@ def test_step_program_is_named_by_scope(mesh_kw):
     batch = _batch(cfg)
 
     jaxpr = str(jax.make_jaxpr(step)(state, batch))
-    for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
+    for kernel in ("flash_fwd", "flash_dkv", "flash_dq") + (
+            ("moe_gmm", "moe_tgmm") if moe else ()):
         assert re.search(rf"\bname={kernel}\b", jaxpr), kernel
 
     # As compiled (CPU): every matmul, the partitioner's collectives,
@@ -209,18 +215,39 @@ def test_step_program_is_named_by_scope(mesh_kw):
     for op, name in named:
         tokens = re.findall(r"[^/()]+", name)
         scopes = [t for t in tokens if t in STEP_SCOPES]
+        if moe and not scopes and name.endswith("moe_block)/shard_map/psum"):
+            # the expert region's own boundary: gradients of what every
+            # token shard holds, summed over the token axes; no scope
+            assert op == "all-reduce" and mesh is not None, (op, name)
+            continue
         assert len(scopes) == 1, (op, name)
         seen.add(scope_and_phase(name, STEP_SCOPES))
-        kernels = [t for t in tokens if t.startswith("flash_")]
-        assert not kernels or scopes == ["attention"], name
+        kernels = [t for t in tokens if t.startswith(KERNEL_NAMES)]
+        assert not kernels or scopes == [
+            "attention" if kernels[0].startswith("flash_")
+            else "moe_experts"], name
     # Every scope has a matmul or a collective of its own but the loss
     # (elementwise and reductions) — and each phase is told apart.
-    want = set(STEP_SCOPES) - ({"loss"} if mesh is None else set())
+    moe_scopes = {"moe_route", "moe_dispatch", "moe_experts", "moe_combine"}
+    want = set(STEP_SCOPES) - ({"ffn"} if moe else moe_scopes)
+    want -= {"loss"} if mesh is None or moe else set()  # tp splits it
     want -= {"embed"} if mesh is None else set()  # a gather, no matmul
     want -= {"optimizer"} if mesh is None else set()
+    # sort, gathers and the weighted sum: a matmul only in the interpreted
+    # kernels and the router
+    want -= {"moe_dispatch", "moe_combine"}
     assert want <= {s for s, _ in seen}, seen
-    assert {("ffn", "forward"), ("ffn", "remat"), ("ffn", "backward"),
+    block = "moe_experts" if moe else "ffn"
+    assert {(block, "forward"), (block, "remat"), (block, "backward"),
             ("attention", "remat"), ("lm_head", "backward")} <= seen
+    if moe:
+        # all four scopes in every phase, on ops of any kind — but the
+        # rematerialised combine: nothing of the backward reads its sum
+        every = {scope_and_phase(name, STEP_SCOPES) for _, name in _op_names(
+            step.lower(state, batch).compile().as_text(), ("",))}
+        assert {(s, p) for s in moe_scopes
+                for p in ("forward", "remat", "backward")} - {
+                    ("moe_combine", "remat")} <= every, every
 
 
 @pytest.mark.slow  # ~38s of multichip mesh dryruns (the single biggest

@@ -1,0 +1,236 @@
+"""The dropless expert layer (``ops/moe.py``) and an OLMoE-shaped model
+through ``loss_fn`` against the benchmark's plain reference.  CPU, float32
+unless said; the Pallas kernels run in interpret mode."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from benchmark.reference import olmoe
+from ray_tpu.models import LlamaConfig, init_params, loss_fn, \
+    param_logical_axes
+from ray_tpu.ops import moe
+from ray_tpu.parallel import MeshConfig, make_mesh, shard_pytree, use_mesh
+
+T, D, E, K, M = 96, 32, 8, 3, 48
+NAMES = ("x", "norm", "router", "w_gate", "w_up", "w_down")
+
+
+def _layer_inputs(seed=0, router_scale=0.5):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return (jax.random.normal(ks[0], (T, D)),
+            1.0 + 0.3 * jax.random.normal(ks[5], (D,)),
+            jax.random.normal(ks[1], (D, E)) * router_scale,
+            jax.random.normal(ks[2], (E, D, M)) * 0.2,
+            jax.random.normal(ks[3], (E, D, M)) * 0.2,
+            jax.random.normal(ks[4], (E, M, D)) * 0.2)
+
+
+def _per_token_loop(x, norm, router, w_gate, w_up, w_down, k=K):
+    """Every token through each of its k experts, one choice at a time."""
+    h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * norm
+    gates, experts = jax.lax.top_k(jax.nn.softmax(h @ router, -1), k)
+    out = x
+    for j in range(k):
+        a = jnp.einsum("td,tdm->tm", h, w_gate[experts[:, j]])
+        b = jnp.einsum("td,tdm->tm", h, w_up[experts[:, j]])
+        out = out + gates[:, j:j + 1] * jnp.einsum(
+            "tm,tmd->td", jax.nn.silu(a) * b, w_down[experts[:, j]])
+    return out
+
+
+@pytest.mark.parametrize("tile", [16, 128], ids=["tile16", "tile128"])
+def test_layer_equals_a_per_token_loop(tile):
+    """Output and the gradients to x, the norm, the router and all three
+    expert tensors; 288 rows in 8 uneven groups, no multiple of a tile."""
+    args = _layer_inputs()
+    layer = functools.partial(moe.moe_block, num_selected=K, tile=tile)
+    out, stats = layer(*args)
+    want = _per_token_loop(*args)
+    assert float(jnp.abs(out - want).max()) < 5e-6
+    assert float(stats["dropped"]) == 0
+    got = jax.grad(lambda *a: jnp.sum(layer(*a)[0] ** 2),
+                   argnums=range(6))(*args)
+    ref = jax.grad(lambda *a: jnp.sum(_per_token_loop(*a) ** 2),
+                   argnums=range(6))(*args)
+    for name, g, r in zip(NAMES, got, ref):
+        assert float(jnp.abs(g - r).max()) < 1e-6 * float(
+            jnp.abs(r).max()) + 1e-6, name
+
+
+def test_group_sizes_sum_to_all_assignments_and_fit_no_tile():
+    x, norm, router = _layer_inputs()[:3]
+    h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * norm
+    _, experts = jax.lax.top_k(jax.nn.softmax(h @ router, -1), K)
+    sizes = np.bincount(np.asarray(experts).reshape(-1), minlength=E)
+    assert sizes.sum() == T * K and (sizes % 16 != 0).any()
+    sched = moe.make_schedule(jnp.asarray(sizes), 288, 16)
+    visits = int(sched.num_visits[0])
+    groups, tiles = (np.asarray(a)[:visits] for a in sched[1:3])
+    offsets = np.asarray(sched.offsets)
+    assert offsets[-1] == 288 and (np.diff(offsets)[:E] == sizes).all()
+    # every row of every group lies in a tile one of its visits names
+    for g in range(E):
+        rows = np.arange(offsets[g], offsets[g + 1])
+        assert set(rows // 16) == set(tiles[groups == g])
+    assert visits <= 288 // 16 + E
+    assert (np.diff(tiles) >= 0).all() and (np.diff(groups) >= 0).all()
+
+
+def test_tile_keeps_executed_rows_within_the_bound():
+    # the benchmark cell: 131072 rows in 64 groups
+    assert moe.choose_tiles(131072, 64) == 256
+    assert moe.executed_rows(131072, 64, 256) <= 1.15 * 131072
+    assert moe.executed_rows(131072, 64, 512) > 1.15 * 131072
+    assert moe.choose_tiles(131072, 8) == 512
+    assert moe.choose_tiles(256, 64) == 128  # none fits: the smallest
+
+
+def test_every_token_to_the_same_experts():
+    """Adversarial routing: a router that sends every token to the same
+    three experts.  Nothing is dropped, the five idle experts get exactly
+    zero gradient, nothing is NaN."""
+    x, norm, router, w_gate, w_up, w_down = _layer_inputs()
+    x = jnp.abs(x)  # so that its product with the first K columns is > 0
+    router = jnp.zeros((D, E)).at[:, :K].set(1.0)
+    args = (x, jnp.ones_like(norm), router, w_gate, w_up, w_down)
+    out, stats = moe.moe_block(*args, num_selected=K, tile=16)
+    assert float(stats["dropped"]) == 0
+    assert float(stats["load_max_over_mean"]) == pytest.approx(E / K)
+    assert float(jnp.abs(out - _per_token_loop(*args)).max()) < 5e-6
+    grads = jax.grad(lambda *a: jnp.sum(
+        moe.moe_block(*a, num_selected=K, tile=16)[0] ** 2),
+        argnums=range(6))(*args)
+    for name, g in zip(NAMES, grads):
+        assert bool(jnp.isfinite(g).all()), name
+    for g in grads[3:]:
+        assert float(jnp.abs(g[K:]).max()) == 0.0
+        assert all(float(jnp.abs(g[e]).max()) > 0 for e in range(K))
+
+
+def test_auxiliary_losses_against_their_formulas():
+    x, norm, router = _layer_inputs(seed=3, router_scale=1.5)[:3]
+    _, stats = moe.moe_block(*_layer_inputs(seed=3, router_scale=1.5),
+                             num_selected=K, tile=16)
+    h = np.asarray(x * jax.lax.rsqrt(
+        jnp.mean(x * x, -1, keepdims=True) + 1e-6) * norm, np.float64)
+    logits = h @ np.asarray(router, np.float64)
+    p = np.exp(logits - logits.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    chosen = np.argsort(-p, axis=-1, kind="stable")[:, :K]
+    counts = np.bincount(chosen.reshape(-1), minlength=E)  # all K choices
+    balance = E * np.sum(counts / T * p.mean(0))
+    lse = np.log(np.exp(logits).sum(-1))
+    assert float(stats["aux_loss"]) == pytest.approx(balance, rel=1e-5)
+    assert float(stats["z_loss"]) == pytest.approx(np.mean(lse ** 2),
+                                                   rel=1e-5)
+    assert float(stats["load_max_over_mean"]) == pytest.approx(
+        counts.max() / (T * K / E), rel=1e-6)
+    first_choice_only = E * np.sum(
+        np.bincount(chosen[:, 0], minlength=E) / T * p.mean(0))
+    assert abs(balance - first_choice_only) > 0.5
+
+
+# ------------------------------------------- a tiny OLMoE through loss_fn --
+
+CONF = dict(num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=4, rope_theta=10000.0, rms_norm_eps=1e-5,
+            num_experts_per_tok=3, norm_topk_prob=False, qk_norm=True,
+            router_aux_loss_coef=0.01, router_z_loss_coef=0.001)
+
+
+def _tiny_olmoe(**kw):
+    fields = dict(num_experts=8, num_selected=3, qk_norm=True, norm_eps=1e-5,
+                  aux_loss_coef=0.01, z_loss_coef=0.001, attn_impl="flash")
+    fields.update(kw)
+    return LlamaConfig.tiny(**fields)
+
+
+def _params_and_tokens(cfg, rows=2, seq=64):
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    # norms away from their initial ones, so that leaving one out shows
+    keys = iter(jax.random.split(jax.random.PRNGKey(7), 16))
+    params["layers"] = {
+        name: (a * jax.random.uniform(next(keys), a.shape, jnp.float32,
+                                      0.5, 1.5).astype(a.dtype)
+               if name.endswith("norm") else a)
+        for name, a in params["layers"].items()}
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (rows, seq + 1), 0,
+                                cfg.vocab_size)
+    return params, tokens
+
+
+def test_tiny_olmoe_equals_the_plain_reference():
+    cfg = _tiny_olmoe()
+    params, tokens = _params_and_tokens(cfg)
+    (total, metrics), grads = jax.value_and_grad(
+        lambda p: loss_fn(p, {"tokens": tokens}, cfg), has_aux=True)(params)
+    want = olmoe.loss_parts(params, tokens, CONF)
+    assert float(total) == pytest.approx(float(want["total"]), rel=2e-6)
+    for part in ("loss", "aux_loss", "z_loss"):
+        assert float(metrics[part]) == pytest.approx(float(want[part]),
+                                                     rel=2e-6), part
+    assert float(metrics["moe_dropped"]) == 0
+    assert 1.0 <= float(metrics["moe_load_max_over_mean"]) <= 8 / 3
+    ref = jax.grad(lambda p: olmoe.loss(p, tokens, CONF))(params)
+    worst = jax.tree.map(
+        lambda g, r: float(jnp.abs(g - r).max() / jnp.abs(r).max()),
+        grads, ref)
+    assert max(jax.tree.leaves(worst)) < 1e-5, worst
+
+
+@pytest.mark.parametrize("left_out", ["qk_norm", "z_loss", "one_expert",
+                                      "aux_loss", "renormalised"])
+def test_reference_check_fails_when_part_of_the_layer_is_left_out(left_out):
+    """What the benchmark's check (relative ``LOSS_RTOL``) must catch."""
+    broken = {"qk_norm": dict(qk_norm=False),
+              "z_loss": dict(z_loss_coef=0.0),
+              "aux_loss": dict(aux_loss_coef=0.0),
+              "one_expert": dict(num_selected=2),
+              "renormalised": dict(norm_topk_prob=True)}[left_out]
+    cfg = _tiny_olmoe()
+    params, tokens = _params_and_tokens(cfg)
+    want = float(olmoe.loss(params, tokens, CONF))
+    got = float(loss_fn(params, {"tokens": tokens}, _tiny_olmoe(**broken))[0])
+    assert abs(got - want) > 10 * olmoe.LOSS_RTOL * want, (got, want)
+
+
+def test_bfloat16_inside_the_stated_tolerance():
+    """bfloat16 parameters and activations against the float32 reference
+    on the same (bfloat16) parameters, 2048 tokens."""
+    cfg = _tiny_olmoe(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                      max_seq_len=512)
+    params, tokens = _params_and_tokens(cfg, rows=4, seq=512)
+    got = float(loss_fn(params, {"tokens": tokens}, cfg)[0])
+    want = float(olmoe.loss(params, tokens, CONF))
+    assert abs(got - want) <= olmoe.loss_rtol(4 * 512) * want, (got, want)
+
+
+@pytest.mark.parametrize("mesh_kw", [dict(ep=2), dict(ep=4), dict(dp=2, ep=2,
+                                                                  tp=2)],
+                         ids=["ep2", "ep4", "dp2_ep2_tp2"])
+def test_expert_parallel_equals_one_device(mesh_kw):
+    cfg = _tiny_olmoe()
+    params, tokens = _params_and_tokens(cfg, rows=4)
+    loss = lambda p, t, mesh=None: loss_fn(p, {"tokens": t}, cfg, mesh=mesh)
+    (want, m1), g1 = jax.value_and_grad(loss, has_aux=True)(params, tokens)
+    n = int(np.prod(list(mesh_kw.values())))
+    mesh = make_mesh(MeshConfig(**mesh_kw), devices=jax.devices()[:n])
+    with use_mesh(mesh):
+        sharded = shard_pytree(params, param_logical_axes(cfg), mesh)
+        toks = jax.device_put(
+            tokens, NamedSharding(mesh, P(("dp", "fsdp"), None)))
+        (got, m2), g2 = jax.jit(jax.value_and_grad(
+            functools.partial(loss, mesh=mesh), has_aux=True))(sharded, toks)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for name in ("aux_loss", "z_loss", "moe_load_max_over_mean"):
+        assert float(m2[name]) == pytest.approx(float(m1[name]), rel=1e-5)
+    assert float(m2["moe_dropped"]) == 0
+    worst = jax.tree.map(
+        lambda a, b: float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-12)),
+        jax.device_get(g2), g1)
+    assert max(jax.tree.leaves(worst)) < 1e-4, worst

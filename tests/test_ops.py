@@ -15,7 +15,7 @@ from ray_tpu.ops import (
     rms_norm, rope, apply_rope,
 )
 from ray_tpu.ops.attention import causal_tile_counts, choose_tiles
-from ray_tpu.ops.moe import moe_ffn
+from ray_tpu.ops.moe import moe_block
 from ray_tpu.parallel import MeshConfig, make_mesh, use_mesh
 
 B, S, H, D = 2, 128, 4, 32
@@ -217,19 +217,31 @@ def test_rope_offset_consistency():
 
 
 def test_moe_routing_mass_conservation():
-    """Every kept token's combine weights sum to its top-k gate mass."""
+    """Dropless: every token's output is the gate-weighted sum of its k
+    experts — with experts that return their input unchanged in the sum of
+    a constant, the mass a token receives is its top-k gate mass."""
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (64, 16))
     rw = jax.random.normal(jax.random.PRNGKey(1), (16, 4)) * 0.1
     wg = jax.random.normal(jax.random.PRNGKey(2), (4, 16, 32)) * 0.1
     wu = jax.random.normal(jax.random.PRNGKey(3), (4, 16, 32)) * 0.1
     wd = jax.random.normal(jax.random.PRNGKey(4), (4, 32, 16)) * 0.1
-    out = moe_ffn(x, rw, wg, wu, wd, num_selected=2, capacity_factor=4.0)
-    assert out.out.shape == x.shape
-    assert jnp.isfinite(out.out).all()
-    assert float(out.aux_loss) > 0
-    # generous capacity => no token dropped => output is differentiable
-    # and gradient flows to every expert weight
-    g = jax.grad(lambda w: (moe_ffn(x, rw, w, wu, wd, num_selected=2,
-                                    capacity_factor=4.0).out ** 2).sum())(wg)
-    assert float(jnp.abs(g).sum()) > 0
+    norm = jnp.ones((16,))
+    out, stats = moe_block(x, norm, rw, wg, wu, wd, num_selected=2)
+    assert out.shape == x.shape
+    assert jnp.isfinite(out).all()
+    assert float(stats["aux_loss"]) > 0
+    assert float(stats["dropped"]) == 0  # all 128 assignments computed
+    # All experts equal: the layer is one expert scaled by each token's
+    # top-2 gate mass, whatever the routing.
+    same = [jnp.broadcast_to(w[:1], w.shape) for w in (wg, wu, wd)]
+    out, _ = moe_block(x, norm, rw, *same, num_selected=2)
+    h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
+    gates, _ = jax.lax.top_k(jax.nn.softmax(h @ rw, -1), 2)
+    one = (jax.nn.silu(h @ wg[0]) * (h @ wu[0])) @ wd[0]
+    assert jnp.allclose(out - x, gates.sum(-1, keepdims=True) * one,
+                        atol=1e-5)
+    # gradient flows to every expert weight
+    g = jax.grad(lambda w: (moe_block(x, norm, rw, w, wu, wd,
+                                      num_selected=2)[0] ** 2).sum())(wg)
+    assert float(jnp.abs(g).sum(axis=(1, 2)).min()) > 0

@@ -166,7 +166,10 @@ def test_paged_packs_past_dense_slot_cap():
 
     def stepfn(slots):
         peak["live"] = max(peak["live"], len(slots))
-        time.sleep(0.002)
+        # Long enough for the 48 submitting threads to queue up behind a
+        # step on a loaded host (at 2 ms the batch drained as fast as a
+        # busy machine admitted, and live never passed 8).
+        time.sleep(0.02)
         for s in slots:
             s.state = (s.state or 0) + 1
             if s.state >= s.request["tokens"]:

@@ -110,6 +110,32 @@ def test_flash_attention_compiles(one_chip, shape, grad):
     assert _has_kernel(jax.jit(fn).lower(x, x, x).compile())
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_compiles(one_chip, k, n, grad):
+    """olmoe-train-s4096's grouped products: 131072 rows in 64 ragged
+    groups at the tile ``choose_tiles`` picks; the masked stores, the
+    transposed-weights product and the row contraction of ``moe_tgmm``
+    are what Mosaic could refuse."""
+    from ray_tpu.ops import moe
+
+    rows, groups = 131072, 64
+    tile = moe.choose_tiles(rows, groups)
+
+    def f(x, w, sizes):
+        sched = moe.make_schedule(sizes, rows, tile)
+        return moe.grouped_matmul(x, w, sched, tile, False).astype(
+            jnp.float32).sum()
+
+    fn = jax.grad(f, argnums=(0, 1)) if grad else f
+    compiled = jax.jit(fn).lower(
+        _shape((rows, k), jnp.bfloat16, one_chip),
+        _shape((groups, k, n), jnp.bfloat16, one_chip),
+        _shape((groups,), jnp.int32, one_chip)).compile()
+    assert _has_kernel(compiled)
+
+
 # -- whole train steps -------------------------------------------------------
 
 @pytest.fixture
@@ -181,3 +207,24 @@ def test_fsdp2_tp2_train_step_compiles(mesh4, as_on_chip):
     assert "all-gather" in text and "all-reduce" in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_expert_parallel_train_step_compiles(mesh4, as_on_chip):
+    """An expert layer on fsdp=2 x ep=2 (the mesh fixture's devices,
+    re-meshed): the grouped-product kernels inside a region manual over
+    every axis, at OLMoE's widths cut to one layer and 8 experts."""
+    mesh = make_mesh(MeshConfig(fsdp=2, ep=2),
+                     devices=list(mesh4.devices.flat))
+    cfg = LlamaConfig(
+        vocab_size=50304, embed_dim=2048, num_layers=1, num_heads=16,
+        num_kv_heads=16, head_dim=128, mlp_dim=1024, num_experts=8,
+        num_selected=2, qk_norm=True, norm_eps=1e-5, z_loss_coef=0.001,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    opt = default_optimizer()
+    shardings = train_state_shardings(cfg, opt, mesh)
+    batch = {"tokens": _shape((4, 2049), jnp.int32, NamedSharding(
+        mesh, P(("dp", "fsdp"), None)))}
+    compiled = make_train_step(cfg, opt, mesh=mesh).lower(
+        _state_shapes(cfg, opt, shardings), batch).compile()
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "moe_tgmm" in text
