@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ray_tpu.ops import attention, moe
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
@@ -242,10 +243,22 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig, *,
         x = cst(x, ("batch", "seq", "embed"))
     layer_fn = _make_layer_fn(cfg, mesh, rules)
     if cfg.remat:
-        layer_fn = jax.checkpoint(layer_fn)
+        layer_fn = _checkpoint(layer_fn)
     (x, aux), _ = jax.lax.scan(layer_fn, (x, _zero_aux(cfg)),
                                params["layers"])
     return _lm_head(params, x, cfg, cst), _mean_aux(aux, cfg)
+
+
+def _checkpoint(layer_fn):
+    """The layer checkpoint of every path (``cfg.remat``): the backward
+    pass recomputes the layer from its input, except the few residuals
+    that are dear to recompute and cheap to hold, named where they are
+    made — the flash kernel's output and log-sum-exp, and an expert
+    layer's sorted rows with their indices.  A layer that never produces
+    a name (reference attention, a dense FFN) saves nothing under it."""
+    return jax.checkpoint(
+        layer_fn, policy=jax.checkpoint_policies.save_only_these_names(
+            *attention.SAVED_RESIDUALS, *moe.SAVED_RESIDUALS))
 
 
 def _zero_aux(cfg: LlamaConfig):
@@ -426,7 +439,7 @@ def forward_pipelined(params: Dict[str, Any], tokens: jax.Array,
         manual_axes = {AXIS_PP}
     layer_fn = _make_layer_fn(cfg, mesh, inner_rules, sp_manual=sp_manual)
     if cfg.remat:
-        layer_fn = jax.checkpoint(layer_fn)
+        layer_fn = _checkpoint(layer_fn)
 
     def stage_fn(stage_params, x_mb):
         (y, _), _ = jax.lax.scan(layer_fn, (x_mb, _zero_aux(cfg)),
@@ -477,7 +490,7 @@ def make_pipeline_stage_fn(cfg: LlamaConfig):
     def stage_fn(sp, x):
         layer_fn = _make_layer_fn(cfg, None, None)
         if cfg.remat:
-            layer_fn = jax.checkpoint(layer_fn)
+            layer_fn = _checkpoint(layer_fn)
         if "embed" in sp:
             with jax.named_scope("embed"):
                 x = jnp.take(sp["embed"], x, axis=0).astype(cfg.dtype)

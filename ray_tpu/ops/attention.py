@@ -42,6 +42,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -464,15 +465,26 @@ def _flash(q, k, v, sm_scale, causal, tiles, interpret):
     return _to_bhsd(o)
 
 
+# The residuals a layer checkpoint keeps (``models/llama.py`` hands these
+# names to its policy): with the kernel's output and log-sum-exp held, the
+# backward pass needs no second ``flash_fwd``.  q, k, v stay recomputed.
+SAVED_RESIDUALS = ("flash_out", "flash_lse")
+
+
 def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret):
     qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)
     qt, kt, vt = _to_bhsd(qs), _to_bhsd(k), _to_bhsd(v)
     ot, lse = _fwd_call(qt, kt, vt, causal, tiles, interpret)
+    ot = checkpoint_name(ot, "flash_out")
+    # One lane of the 128 the kernel writes: a float a row is what is
+    # worth holding; the backward kernels get the lanes back.
+    lse = checkpoint_name(lse[..., 0], "flash_lse")
     return _to_bhsd(ot), (qt, kt, vt, ot, lse)
 
 
 def _flash_bwd(sm_scale, causal, tiles, interpret, res, do):
     qt, kt, vt, ot, lse = res
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (_LANES,))
     dqt, dkt, dvt = _bwd_call(qt, kt, vt, ot, lse, _to_bhsd(do), sm_scale,
                               causal, tiles, interpret)
     return _to_bhsd(dqt), _to_bhsd(dkt), _to_bhsd(dvt)

@@ -40,6 +40,7 @@ from typing import Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -77,6 +78,11 @@ def _fit_columns(n: int, rows: int, itemsize: int, budget: int) -> int:
     while rows * block * itemsize > budget and block % 256 == 0:
         block //= 2
     return block
+
+
+# The residuals a layer checkpoint keeps (``models/llama.py`` hands these
+# names to its policy), named in ``moe_block``'s dispatch.
+SAVED_RESIDUALS = ("moe_rows", "moe_row_index")
 
 
 class Schedule(NamedTuple):
@@ -402,7 +408,13 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
         slot_row = jnp.zeros((t * k,), jnp.int32).at[order].set(
             jnp.arange(t * k, dtype=jnp.int32),
             unique_indices=True).reshape(t, k)
-        x_rows = _dispatch(h, row_token, slot_row)
+        # What a layer checkpoint keeps (SAVED_RESIDUALS): with the
+        # sorted rows and the integers the backward pass reads held, the
+        # rematerialised forward runs no sort and no gather.
+        sched, row_token, row_slot, slot_row = checkpoint_name(
+            (sched, row_token, row_slot, slot_row), "moe_row_index")
+        x_rows = checkpoint_name(_dispatch(h, row_token, slot_row),
+                                 "moe_rows")
         dropped = (tokens * k - _psum(
             jnp.sum(group_sizes).astype(jnp.float32),
             tuple(token_axes) + ((expert_axis,) if expert_axis else ())))

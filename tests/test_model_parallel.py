@@ -239,15 +239,22 @@ def test_step_program_is_named_by_scope(mesh_kw, moe):
     assert want <= {s for s, _ in seen}, seen
     block = "moe_experts" if moe else "ffn"
     assert {(block, "forward"), (block, "remat"), (block, "backward"),
-            ("attention", "remat"), ("lm_head", "backward")} <= seen
+            ("attn_qkv", "remat"), ("lm_head", "backward")} <= seen
+    # The layer checkpoint keeps the flash kernel's output and log-sum-exp
+    # (``models/llama.py::_checkpoint``): no second flash_fwd, so no matmul
+    # in the rematerialised attention.
+    assert {("attention", "forward"), ("attention", "backward")} <= seen
+    assert ("attention", "remat") not in seen
     if moe:
         # all four scopes in every phase, on ops of any kind — but the
-        # rematerialised combine: nothing of the backward reads its sum
+        # rematerialised combine (nothing of the backward reads its sum)
+        # and dispatch (the checkpoint keeps the sorted rows)
         every = {scope_and_phase(name, STEP_SCOPES) for _, name in _op_names(
             step.lower(state, batch).compile().as_text(), ("",))}
+        gone = {("moe_combine", "remat"), ("moe_dispatch", "remat")}
         assert {(s, p) for s in moe_scopes
-                for p in ("forward", "remat", "backward")} - {
-                    ("moe_combine", "remat")} <= every, every
+                for p in ("forward", "remat", "backward")} - gone <= every
+        assert not gone & every, gone & every
 
 
 @pytest.mark.slow  # ~38s of multichip mesh dryruns (the single biggest

@@ -273,16 +273,21 @@ def _xspace(ops, modules):
     return ProfileData.text_proto_to_serialized_xspace(text)
 
 
+# op_name prefixes of the layer scan's two loops, and the text of a flash
+# kernel's instruction at the s4096 cell's shapes.
+_FWD = "jit(step)/jvp()/while/body/closed_call/"
+_BWD = "jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+_KERNEL = (', custom_call_target=\\"tpu_custom_call\\", '
+           'operand_layout_constraints={bf16[4,32,4096,128]{3,2,1,0}, '
+           'bf16[4,32,4096,128]{3,2,1,0}, bf16[4,32,4096,128]{3,2,1,0}}')
+
+
 def test_step_breakdown_on_a_synthetic_trace(tmp_path):
     from ray_tpu.train.core import STEP_SCOPES
     from ray_tpu.util.tracing import (
         format_breakdown, scope_and_phase, step_breakdown)
 
-    fwd = "jit(step)/jvp()/while/body/closed_call/"
-    bwd = "jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
-    kernel = (', custom_call_target=\\"tpu_custom_call\\", '
-              'operand_layout_constraints={bf16[4,32,4096,128]{3,2,1,0}, '
-              'bf16[4,32,4096,128]{3,2,1,0}, bf16[4,32,4096,128]{3,2,1,0}}')
+    fwd, bwd, kernel = _FWD, _BWD, _KERNEL
     # One step of 1000 ns from t=2000 (the first execution is a lead-in):
     # a forward ``while`` [2000, 2400) over three ops, its own 100 ns left;
     # then a backward while [2500, 2900) holding a remat op, a nested
@@ -361,3 +366,39 @@ def test_step_breakdown_on_a_synthetic_trace(tmp_path):
     assert scope_and_phase("jit(step)/optimizer/add",
                            STEP_SCOPES) == ("optimizer", "optimizer")
     assert scope_and_phase("", STEP_SCOPES) == (None, "forward")
+
+
+def test_step_breakdown_without_a_rematerialised_kernel(tmp_path):
+    """A step whose checkpoint keeps the flash kernel's residuals: the
+    rematerialised attention holds a transpose and no kernel, so there is
+    no ``flash_fwd.remat`` row — none is assumed, printing included."""
+    from ray_tpu.util.tracing import format_breakdown, step_breakdown
+
+    fwd, bwd, kernel = _FWD, _BWD, _KERNEL
+    ops = [
+        ("%lead = f32[] add()", "jit(step)/optimizer/add", 500, 100),
+        ("%flash_fwd.1 = f32[] custom-call()" + kernel,
+         fwd + "attention/flash_fwd/pallas_call", 2000, 100),
+        ("%copy.1 = f32[] copy()",
+         bwd + "rematted_computation/attention/transpose", 2100, 30),
+        ("%flash_dq.1 = f32[] custom-call()" + kernel,
+         bwd + "attention/flash_dq/pallas_call", 2200, 120),
+    ]
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace(ops, modules=[(0, 1000), (2000, 1000)]))
+    b = step_breakdown(str(path), "jit_step")
+    ns = lambda s: round(s * 1e9)  # noqa: E731
+    assert {k: ns(t) for k, t in b["kernels"].items()} == {
+        "flash_fwd": 100, "flash_dq": 120}
+    assert set(b["kernel_pairs"]) == {"flash_fwd", "flash_dq"}
+    assert {p: ns(t) for p, t in b["scopes"]["attention"].items()} == {
+        "forward": 100, "remat": 30, "backward": 120}
+    text = format_breakdown(b)
+    assert "flash_fwd  executed/causal 1.0622" in text
+    assert ".remat" not in text
+    # A step with no kernel at all (reference attention) prints too.
+    path.write_bytes(_xspace(ops[:1] + ops[2:3],
+                             modules=[(0, 1000), (2000, 1000)]))
+    b = step_breakdown(str(path), "jit_step")
+    assert b["kernels"] == {} and b["kernel_pairs"] == {}
+    assert "kernel" not in format_breakdown(b)
