@@ -356,11 +356,11 @@ def _client_bench():
 
 def locality_bench():
     """Arg-locality microbench: a fan-out of tasks over one node-homed
-    large arg, run with locality scheduling on and off — reports tasks/s
-    and off_home_arg_bytes, the per-task upper bound on cross-node arg
-    traffic (tasks that ran away from the arg's home node x arg size;
-    singleflight dedup means actual wire bytes can be lower), so this
-    PR's effect and regressions stay visible in the round trajectory."""
+    large arg — reports tasks/s and off_home_arg_bytes, the per-task
+    upper bound on cross-node arg traffic (tasks that ran away from the
+    arg's home node x arg size; singleflight dedup means actual wire
+    bytes can be lower), so regressions stay visible in the round
+    trajectory."""
     import os
 
     import numpy as np
@@ -382,8 +382,8 @@ def locality_bench():
     def crunch(a):
         return os.environ["RAY_TPU_NODE_ID"]
 
-    def run(system_config):
-        c = Cluster(head_num_cpus=4, _system_config=system_config)
+    def run():
+        c = Cluster(head_num_cpus=4)
         try:
             home = c.add_node(num_cpus=4, external=True)
             ref = make.options(scheduling_strategy=NA(home)).remote(
@@ -419,12 +419,9 @@ def locality_bench():
             c.shutdown()
 
     out = {"arg_mb": arg_bytes >> 20, "n_tasks": n_tasks,
-           "locality_on": run(None),
-           "locality_off": run({"locality_scheduling": False})}
-    print(f"  [locality] on: {out['locality_on']['tasks_per_s']}/s, "
-          f"{out['locality_on']['off_home_arg_bytes'] >> 20} MB off-home; "
-          f"off: {out['locality_off']['tasks_per_s']}/s, "
-          f"{out['locality_off']['off_home_arg_bytes'] >> 20} MB off-home",
+           "locality_on": run()}
+    print(f"  [locality] {out['locality_on']['tasks_per_s']}/s, "
+          f"{out['locality_on']['off_home_arg_bytes'] >> 20} MB off-home",
           file=sys.stderr)
     return out
 
@@ -991,13 +988,10 @@ def disagg_serving_bench():
 def recovery_bench():
     """Fault-tolerance row: a 32-task fan-out (2 MB results pinned to an
     external node) suffers a mid-run worker kill (tasks retry) and then
-    loses the node itself before the results are consumed — recovery on
-    vs off.  Reports completion wall-clock, whether every get returned
-    the correct value, and the reconstruction counter; best-of-3 per
-    mode with raw samples in the round JSON (PR 6-8 convention).  The
-    off run documents today's failure (ObjectLostError at get), so the
-    row keeps both the subsystem's cost and its value in the
-    trajectory."""
+    loses the node itself before the results are consumed.  Reports
+    completion wall-clock, whether every get returned the correct
+    value, and the reconstruction counter; best-of-3 with raw samples
+    in the round JSON (PR 6-8 convention)."""
     import numpy as np
 
     import ray_tpu as ray
@@ -1018,8 +1012,8 @@ def recovery_bench():
     def check(a):
         return int(a[0])
 
-    def one_round(system_config):
-        c = Cluster(head_num_cpus=4, _system_config=system_config)
+    def one_round():
+        c = Cluster(head_num_cpus=4)
         chaos = None
         try:
             node = c.add_node(num_cpus=4, external=True)
@@ -1049,123 +1043,11 @@ def recovery_bench():
                 chaos.stop()
             c.shutdown()
 
-    def best_of(system_config, rounds=3):
-        samples = [one_round(system_config) for _ in range(rounds)]
-        best = min(samples, key=lambda s: (not s["completed"],
-                                           s["wall_s"]))
-        return {**best, "samples": samples}
-
-    out = {"n_tasks": n_tasks,
-           "recovery_on": best_of(None),
-           "recovery_off": best_of({"recovery": False})}
-    on, off = out["recovery_on"], out["recovery_off"]
-    print(f"  [recovery] on: {on['wall_s']}s, completed={on['completed']},"
-          f" reconstructions={on['reconstructions']}; off: "
-          f"{off['wall_s']}s, completed={off['completed']}",
-          file=sys.stderr)
-    return out
-
-
-def degraded_link_bench():
-    """Failure-detection row: a 4-node pull fan-out (producers homed on
-    one node, consumers spread over the other three pulling ~2 MB args
-    across the wire) with the producer node's DATA LINK stalled
-    mid-transfer (env net-chaos rule: its object server parks at chunk
-    2, socket open — the gray failure, nothing EOFs).
-    ``failure_detection`` on vs off: on, every pull's zero-progress
-    deadline trips, the transport retries, then hedges to the
-    head-relay fallback — completion bounded in seconds with the
-    stall/retry/hedge counters lit; off, the pulls block forever and
-    the run only ends at the get timeout (reported timeout-bounded —
-    today's behavior, the row documents exactly what the plane buys).
-    Best-of-3 per mode with raw samples (PR 6/7 convention)."""
-    import tempfile
-
-    import numpy as np
-
-    import ray_tpu as ray
-    from ray_tpu.cluster_utils import Cluster
-    from ray_tpu.util.scheduling_strategies import (
-        NodeAffinitySchedulingStrategy as NA,
-    )
-
-    n_objects = 9
-    get_timeout_s = 10.0
-
-    @ray.remote(max_retries=3)
-    def make(i):
-        return np.full(260_000, i, dtype=np.int64)  # ~2 MB
-
-    @ray.remote(max_retries=3)
-    def consume(a):
-        return int(a[0])
-
-    def one_round(fd_on):
-        cfg = {"failure_detection": fd_on}
-        if fd_on:
-            cfg.update({"net_stall_timeout_s": 0.5, "net_retry_count": 1,
-                        "net_retry_backoff_base_ms": 20.0})
-        chaos_dir = tempfile.mkdtemp()
-        # The head merges its own process-wide deadline-core counters
-        # into transfer_stats; rounds share this driver process, so
-        # report per-round DELTAS (the off round must read zero).
-        from ray_tpu._private import protocol as _protocol
-
-        base = _protocol.net_stats()
-        c = Cluster(head_num_cpus=0, _system_config=cfg)
-        try:
-            src = c.add_node(
-                num_cpus=2, external=True,
-                env_overrides={
-                    "RAY_TPU_CHAOS_NET": "agent:chunk_send:stall:2",
-                    "RAY_TPU_CHAOS_DIR": chaos_dir,
-                })
-            sinks = [c.add_node(num_cpus=1, external=True)
-                     for _ in range(3)]
-            s1 = [make.options(scheduling_strategy=NA(
-                node_id=src, soft=True)).remote(i)
-                for i in range(n_objects)]
-            ray.wait(s1, num_returns=len(s1), timeout=60)
-            t0 = time.perf_counter()
-            s2 = [consume.options(scheduling_strategy=NA(
-                node_id=sinks[i % 3], soft=True)).remote(r)
-                for i, r in enumerate(s1)]
-            ok = True
-            try:
-                vals = ray.get(s2, timeout=get_timeout_s)
-                ok = vals == list(range(n_objects))
-            except ray.exceptions.RayTpuError:
-                ok = False  # off: the gray stall only ends at timeout
-            dt = time.perf_counter() - t0
-            stats = c.rt.transfer_stats()
-            return {"wall_s": round(dt, 2), "completed": ok,
-                    "timeout_bounded": not ok,
-                    "stall_timeouts":
-                        stats["stall_timeouts"] - base["stall_timeouts"],
-                    "net_retries":
-                        stats["net_retries"] - base["net_retries"],
-                    "hedged_fetches":
-                        stats["hedged_fetches"] - base["hedged_fetches"],
-                    "suspected_nodes": stats["suspected_nodes"]}
-        finally:
-            c.shutdown()
-
-    def best_of(fd_on, rounds=3):
-        samples = [one_round(fd_on) for _ in range(rounds)]
-        best = min(samples, key=lambda s: (not s["completed"],
-                                           s["wall_s"]))
-        return {**best, "samples": samples}
-
-    out = {"n_objects": n_objects, "get_timeout_s": get_timeout_s,
-           "failure_detection_on": best_of(True),
-           "failure_detection_off": best_of(False)}
-    on, off = out["failure_detection_on"], out["failure_detection_off"]
-    print(f"  [degraded_link] on: {on['wall_s']}s, completed="
-          f"{on['completed']}, stalls={on['stall_timeouts']}, retries="
-          f"{on['net_retries']}, hedged={on['hedged_fetches']}; off: "
-          f"{off['wall_s']}s, completed={off['completed']} "
-          f"(timeout-bounded={off['timeout_bounded']})",
-          file=sys.stderr)
+    samples = [one_round() for _ in range(3)]
+    on = min(samples, key=lambda s: (not s["completed"], s["wall_s"]))
+    out = {"n_tasks": n_tasks, "recovery_on": {**on, "samples": samples}}
+    print(f"  [recovery] {on['wall_s']}s, completed={on['completed']},"
+          f" reconstructions={on['reconstructions']}", file=sys.stderr)
     return out
 
 
@@ -1599,13 +1481,11 @@ def impala_throughput_bench(iters=4):
 
 def elastic_drill_bench():
     """Elastic-pods row: sustained small-task traffic against an
-    autoscaled spot slice pool crosses ONE mid-run preemption — drain
-    on (graceful notice: leases revoked, sole-copy results migrated,
-    agent released cleanly) vs off (the same SIGUSR1 notice, but with
-    ``elastic_drain=False`` the agent exits immediately — today's
-    no-warning kill, lineage rebuilds).  Reports req/s and p99 task
-    latency under the churn plus the drain/reconstruction counters;
-    best-of-3 with raw per-round samples (PR 6/7 convention)."""
+    autoscaled spot slice pool crosses ONE mid-run preemption (graceful
+    notice: leases revoked, sole-copy results migrated, agent released
+    cleanly).  Reports req/s and p99 task latency under the churn plus
+    the drain/reconstruction counters; best-of-3 with raw per-round
+    samples (PR 6/7 convention)."""
     import numpy as np  # noqa: F401 -- workers import it; keep parity
 
     import ray_tpu as ray
@@ -1620,13 +1500,11 @@ def elastic_drill_bench():
         import numpy as np
 
         # ~1.6 MB: over the inline cutoff, so results are node-store
-        # homed — the sole-copy bytes the drain migrates (or, off, the
-        # kill loses and lineage rebuilds).
+        # homed — the sole-copy bytes the drain migrates.
         return np.full(200_000, i)
 
-    def one_round(drain_on):
-        sysconf = {} if drain_on else {"elastic_drain": False}
-        c = Cluster(head_num_cpus=2, _system_config=sysconf)
+    def one_round():
+        c = Cluster(head_num_cpus=2)
         scaler = chaos = None
         try:
             provider = FakeSliceProvider(c, {
@@ -1661,9 +1539,7 @@ def elastic_drill_bench():
                 v = ray.get(ref, timeout=120)
                 ok = ok and int(v[0]) == k
             # Real elapsed, not the nominal window: the loop overruns
-            # t_end when the preemption lands late, and that overrun
-            # differs between modes — a fixed denominator would bias
-            # the on/off comparison.
+            # t_end when the preemption lands late.
             elapsed = time.perf_counter() - t_start
             lat.sort()
             st = c.rt.transfer_stats()
@@ -1684,102 +1560,12 @@ def elastic_drill_bench():
                 scaler.stop()
             c.shutdown()
 
-    def best_of(drain_on, rounds=3):
-        samples = [one_round(drain_on) for _ in range(rounds)]
-        best = min(samples, key=lambda s: (not s["completed"],
-                                           s["p99_ms"]))
-        return {**best, "samples": samples}
-
-    out = {"duration_s": duration_s,
-           "drain_on": best_of(True),
-           "drain_off": best_of(False)}
-    on, off = out["drain_on"], out["drain_off"]
-    print(f"  [elastic] on: {on['req_per_s']} req/s p99 {on['p99_ms']}ms"
+    samples = [one_round() for _ in range(3)]
+    on = min(samples, key=lambda s: (not s["completed"], s["p99_ms"]))
+    out = {"duration_s": duration_s, "drain_on": {**on, "samples": samples}}
+    print(f"  [elastic] {on['req_per_s']} req/s p99 {on['p99_ms']}ms"
           f" migrated={on['objects_migrated']} rebuilds="
-          f"{on['reconstructions']}; off: {off['req_per_s']} req/s p99 "
-          f"{off['p99_ms']}ms rebuilds={off['reconstructions']}",
-          file=sys.stderr)
-    return out
-
-
-def head_restart_blip_bench():
-    """Head-failover row: sustained small-task traffic from a client
-    crosses a hard head SIGKILL + restart (external-head cluster, one
-    2-CPU agent).  Reports per-op p50/p99 latency, the blip duration
-    (longest completion gap), and whether every get returned correctly
-    — failover ON vs OFF.  The OFF run documents today's outage (the
-    agent tears its workers down and post-restart gets fail), so the
-    row keeps both the subsystem's cost and its value in the
-    trajectory.  Best-of-3 with raw samples (PR 6/7 convention)."""
-    import ray_tpu as ray
-    from ray_tpu.cluster_utils import Cluster
-
-    @ray.remote
-    def _inc(x):
-        return x + 1
-
-    def one_round(failover):
-        env = {} if failover else {"RAY_TPU_AGENT_RECONNECT": "0"}
-        sysconf = {} if failover else {"head_failover": False}
-        get_timeout = 30 if failover else 8
-        c = Cluster(external_head=True, head_num_cpus=0,
-                    _system_config=sysconf)
-        try:
-            c.add_node(num_cpus=2, external=True, env_overrides=env)
-            ray.get([_inc.remote(i) for i in range(8)], timeout=60)
-            lat, completions = [], []
-            errors = 0
-            killed = restarted = False
-            t_start = time.time()
-            t_end = t_start + 6.0
-            i = 0
-            while time.time() < t_end:
-                t0 = time.perf_counter()
-                try:
-                    assert ray.get(_inc.remote(i),
-                                   timeout=get_timeout) == i + 1
-                    lat.append(time.perf_counter() - t0)
-                    completions.append(time.time())
-                except Exception:
-                    errors += 1
-                i += 1
-                now = time.time() - t_start
-                if not killed and now > 1.5:
-                    c.kill_head()
-                    killed = True
-                elif killed and not restarted and now > 2.0:
-                    c.restart_head()
-                    restarted = True
-                time.sleep(0.005)
-            lat.sort()
-            gaps = [b - a for a, b in zip(completions, completions[1:])]
-            post_blip = [t for t in completions if t - t_start > 2.5]
-            return {
-                "ops": len(lat), "errors": errors,
-                "p50_ms": (round(lat[len(lat) // 2] * 1e3, 2)
-                           if lat else None),
-                "p99_ms": (round(lat[min(len(lat) - 1,
-                                         int(len(lat) * 0.99))] * 1e3, 2)
-                           if lat else None),
-                "blip_s": round(max(gaps), 2) if gaps else None,
-                "completed": errors == 0 and bool(post_blip),
-            }
-        finally:
-            c.shutdown()
-
-    def best_of(failover, rounds=3):
-        samples = [one_round(failover) for _ in range(rounds)]
-        best = min(samples, key=lambda s: (not s["completed"],
-                                           s["blip_s"] or 1e9))
-        return {**best, "samples": samples}
-
-    out = {"failover_on": best_of(True),
-           "failover_off": best_of(False)}
-    on, off = out["failover_on"], out["failover_off"]
-    print(f"  [head_restart_blip] on: blip {on['blip_s']}s, p99 "
-          f"{on['p99_ms']}ms, errors={on['errors']}, completed="
-          f"{on['completed']}; off: errors={off['errors']}, completed="
-          f"{off['completed']}", file=sys.stderr)
+          f"{on['reconstructions']}", file=sys.stderr)
     return out
 
 
@@ -2015,23 +1801,10 @@ def main():
         recovery = {"error": repr(e)}
 
     try:
-        head_restart_blip = head_restart_blip_bench()
-    except Exception as e:  # noqa: BLE001 — extra row must not kill core
-        print(f"  [head_restart_blip] bench failed: {e!r}",
-              file=sys.stderr)
-        head_restart_blip = {"error": repr(e)}
-
-    try:
         elastic_drill = elastic_drill_bench()
     except Exception as e:  # noqa: BLE001 — extra row must not kill core
         print(f"  [elastic_drill] bench failed: {e!r}", file=sys.stderr)
         elastic_drill = {"error": repr(e)}
-
-    try:
-        degraded_link = degraded_link_bench()
-    except Exception as e:  # noqa: BLE001 — extra row must not kill core
-        print(f"  [degraded_link] bench failed: {e!r}", file=sys.stderr)
-        degraded_link = {"error": repr(e)}
 
     try:
         push_shuffle = shuffle_bench()
@@ -2067,8 +1840,7 @@ def main():
 
     rows = {
         "arg_locality": locality, "data_streaming": data_streaming,
-        "recovery": recovery, "head_restart_blip": head_restart_blip,
-        "elastic_drill": elastic_drill, "degraded_link": degraded_link,
+        "recovery": recovery, "elastic_drill": elastic_drill,
         "serve_latency": serve_latency, "push_shuffle": push_shuffle,
         "pipeline_train": pipeline_train,
         "impala_throughput": impala_throughput,
@@ -2086,9 +1858,7 @@ def main():
         "arg_locality": locality,
         "data_streaming": data_streaming,
         "recovery": recovery,
-        "head_restart_blip": head_restart_blip,
         "elastic_drill": elastic_drill,
-        "degraded_link": degraded_link,
         "serve_latency": serve_latency,
         "push_shuffle": push_shuffle,
         # Last (before the small tpu dict): the round artifact keeps the

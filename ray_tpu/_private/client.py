@@ -94,7 +94,7 @@ class ClientRuntime(_WorkerRuntime):
             # commit.
             self._send(("put_commit", oid.binary(), descr, nested))
         else:
-            # Legacy path: ship parts for the head to assemble into ITS
+            # Whole-value path: ship parts for the head to assemble into ITS
             # store (clients share no /dev/shm).  PickleBuffer wrapping
             # sends the buffer views — pickle already copies once into
             # the message stream; the old [bytes(b) ...] copied twice.
@@ -106,13 +106,10 @@ class ClientRuntime(_WorkerRuntime):
     def _direct_put(self, oid: ObjectID, meta, views):
         """Push a large value straight into the head's store over the
         object-transfer data plane; returns the committed descriptor, or
-        None (caller falls back to legacy put_parts) when the head never
-        advertised the put verbs, the master switch is off, or the push
-        failed."""
-        from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
-
+        None (caller falls back to put_parts) when the head never
+        advertised the put verbs or the push failed."""
         info = self._head_put_info
-        if info is None or not _cfg.direct_puts:
+        if info is None:
             return None
         store_id, addr, caps = info
         if not object_transfer.peer_accepts_puts(caps):
@@ -195,7 +192,7 @@ class ClientRuntime(_WorkerRuntime):
         Deadline-aware (connect timeout + SO_KEEPALIVE) like every
         other dial site."""
         conn = protocol.dial(tuple(addr), authkey=self._authkey)
-        if self._fd_on and self._net_stall_t > 0:
+        if self._net_stall_t > 0:
             # Send half only (see _WorkerRuntime.dial).
             protocol.set_send_deadline(conn, self._net_stall_t)
         return conn

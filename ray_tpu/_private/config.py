@@ -6,8 +6,10 @@ default)``, 780 lines of flags, overridable via ``RAY_<name>`` env vars).
 
 We keep the same two properties — one flat flag namespace, env-var override —
 but as a plain dataclass: every field can be overridden with
-``RAY_TPU_<FIELD_NAME>`` in the environment, and programmatically via
-``ray_tpu.init(_system_config={...})``.
+``RAY_TPU_<FIELD_NAME>`` in the environment (``env_name``: two fields
+keep a shorter spelling), and programmatically via
+``ray_tpu.init(_system_config={...})``.  A spawned worker inherits every
+field outside ``HEAD_ONLY`` through that same environment.
 """
 
 from __future__ import annotations
@@ -17,8 +19,23 @@ import os
 from typing import Any
 
 
+# The two fields whose environment name is not RAY_TPU_<FIELD>: the short
+# spellings are read directly by worker_entry (and RAY_TPU_POOL_BYTES by
+# node_agent, whose own value wins per node).
+_ENV_ALIASES = {
+    "max_inline_object_size": "RAY_TPU_MAX_INLINE",
+    "shm_pool_bytes": "RAY_TPU_POOL_BYTES",
+}
+
+
+def env_name(field: str) -> str:
+    """The environment variable that carries ``field`` — the one spelling
+    ``Config.from_env`` reads and ``Runtime._worker_config_env`` writes."""
+    return _ENV_ALIASES.get(field) or "RAY_TPU_" + field.upper()
+
+
 def _env_override(name: str, default: Any) -> Any:
-    raw = os.environ.get("RAY_TPU_" + name.upper())
+    raw = os.environ.get(env_name(name))
     if raw is None:
         return default
     if isinstance(default, bool):
@@ -37,22 +54,22 @@ class Config:
     # reference cutoff is 100KB (``max_direct_call_object_size``,
     # ray_config_def.h:212); we default higher because host pipes on a TPU VM
     # comfortably move 1MB messages and shm setup has fixed cost.
-    # protocheck: env-alias RAY_TPU_MAX_INLINE -- legacy spelling read directly by worker_entry
     max_inline_object_size: int = 1024 * 1024
 
     # Shared-memory store capacity (bytes).  0 = unlimited (bounded by
     # /dev/shm).  Mirrors plasma's store size (object_manager/plasma/).
-    # protocheck: head-only -- workers get their per-node slice as RAY_TPU_STORE_BYTES (head spawn env / agent-computed cap), not this knob
+    # Head-only: workers get their per-node slice as RAY_TPU_STORE_BYTES
+    # (head spawn env / agent-computed cap), not this knob.
     object_store_memory: int = 0
 
     # Directory for shared-memory segments.
-    # protocheck: head-only -- workers inherit the session store path via RAY_TPU_SHM_DIR_OVERRIDE from their node's store owner
+    # Head-only: workers inherit the session store path via
+    # RAY_TPU_SHM_DIR_OVERRIDE from their node's store owner.
     shm_dir: str = "/dev/shm"
 
     # Bytes of freed-but-still-mapped shm segments kept pooled for in-place
     # reuse (plasma-arena analog: fresh tmpfs pages fault+zero at ~1 GB/s,
     # pooled pages take writes at memcpy speed).  0 disables pooling.
-    # protocheck: env-alias RAY_TPU_POOL_BYTES -- legacy spelling read directly by worker_entry/node_agent
     shm_pool_bytes: int = 1 << 30
 
     # --- Cross-node object transfer (the data-plane fast path;
@@ -71,19 +88,16 @@ class Config:
     # a NAT-internal address on some distros; node agents have the same
     # escape hatch via RAY_TPU_AGENT_ADVERTISE_HOST).  "" = derive from
     # listen_host.
-    # protocheck: head-only -- names the HEAD's advertised object-server host; agents have RAY_TPU_AGENT_ADVERTISE_HOST
+    # Head-only: names the HEAD's advertised object-server host.
     object_advertise_host: str = ""
 
     # --- Direct puts (the WRITE-direction twin of the pooled/striped
     # pull path; reference: plasma CreateObject/Seal on a dedicated
-    # store socket — writes never ride a GCS RPC).  Master switch: a
-    # client/worker put of a value destined for another store pushes
-    # the payload over the data plane (reserve_put/put_range/commit_put
-    # on the destination's object server) and sends only an O(1)
-    # ("put_commit", ...) control message.  Off = the legacy whole-value
-    # ("put_parts", ...) control message, byte-identical, with every
-    # direct-put counter zero. ---
-    direct_puts: bool = True
+    # store socket — writes never ride a GCS RPC): a client/worker put
+    # of a value destined for another store pushes the payload over the
+    # data plane (reserve_put/put_range/commit_put on the destination's
+    # object server) and sends only an O(1) ("put_commit", ...) control
+    # message. ---
     # A pushed value at least this big is streamed as concurrent
     # byte-range stripes of this length over multiple pooled
     # connections (needs the peer's "put_range" capability); smaller
@@ -101,12 +115,10 @@ class Config:
     # object store and prefers the top-locality node that fits; it never
     # stalls a class (a preferred-but-full node just falls back to the
     # head-first order, counted in ``locality_misses``).
-    # protocheck: head-only -- placement scoring runs in the head scheduler only
-    locality_scheduling: bool = True
     # Minimum bytes of node-homed argument data before locality overrides
     # the head-first placement order (below it, transfer is cheaper than
-    # disturbing the packing).
-    # protocheck: head-only -- placement scoring runs in the head scheduler only
+    # disturbing the packing).  Head-only: placement scoring runs in the
+    # head scheduler only.
     locality_min_bytes: int = 1024 * 1024
 
     # --- Pipelined argument prefetch (reference: raylets pull task
@@ -191,13 +203,10 @@ class Config:
     # --- Decentralized dispatch (reference: the raylet's lease-based
     # hybrid scheduling, RequestWorkerLease + spillback in
     # local_task_manager.h:58, with task metadata owned by the submitting
-    # worker — Ownership, NSDI'21).  Master switch for the lease-grant
-    # scheduling plane: bulk lease grants piggybacked on head-brokered
-    # submit bursts, holder-side renewal batching, executor spillback,
-    # lease revocation on node death, and the head's sharded/deferred
-    # dispatch passes.  Off = the pre-existing head-brokered path,
-    # byte-identical, with every decentralized-dispatch counter zero. ---
-    decentralized_dispatch: bool = True
+    # worker — Ownership, NSDI'21): bulk lease grants piggybacked on
+    # head-brokered submit bursts, holder-side renewal batching, executor
+    # spillback, lease revocation on node death, and the head's
+    # sharded/deferred dispatch passes. ---
     # Execution slots per granted lease: the holder pipelines at most this
     # many unacked pushes onto one leased worker (capped by
     # max_tasks_in_flight_per_worker at grant time).
@@ -285,22 +294,24 @@ class Config:
 
     # Seconds a worker may sit idle before the pool reaps it (reference:
     # idle worker killing in worker_pool.cc).
-    # protocheck: head-only -- the idle-worker reaper runs in the head's pool
+    # Head-only: the idle-worker reaper runs in the head's pool
     idle_worker_timeout_s: float = 300.0
 
     # Soft cap on extra workers spawned when existing workers block in
     # ``ray.get`` (reference: worker cap w/ backoff, ray_config_def.h:174-187).
-    # protocheck: head-only -- blocked-worker cap enforced by the head's spawn path
+    # Head-only: blocked-worker cap enforced by the head's spawn path
     max_extra_blocked_workers: int = 16
 
     # Task retry default (reference: max_retries=3 for normal tasks).
-    # protocheck: head-only -- retry budgets are seeded at head registration (direct-path specs carry explicit max_retries)
+    # Head-only: retry budgets are seeded at head registration (direct-path
+    # specs carry explicit max_retries).
     default_max_retries: int = 3
 
     # Tasks pipelined onto one leased worker before a new worker is leased
     # (reference: max_tasks_in_flight_per_worker in
     # direct_task_transport.h:75 — kills the per-task result round trip).
-    # protocheck: head-only -- the pipeline bound is applied at grant time; holders receive it as the grant's slots field
+    # Head-only: the pipeline bound is applied at grant time; holders
+    # receive it as the grant's slots field.
     max_tasks_in_flight_per_worker: int = 10
 
     # --- Failure detection (gray failures: alive-but-hung peers;
@@ -308,18 +319,13 @@ class Config:
     # health_check_initial_delay_ms / timeout / period /
     # failure_threshold in ray_config_def.h; "Gray Failure: The
     # Achilles' Heel of Cloud-Scale Systems", HotOS'17 — differential
-    # observation, peer-observed stalls rather than process liveness).
-    # Master switch for the whole plane: deadlines on every wire
-    # operation (connect timeouts + SO_KEEPALIVE on every dial,
-    # zero-progress stall deadlines on transfers with
+    # observation, peer-observed stalls rather than process liveness):
+    # deadlines on every wire operation (connect timeouts + SO_KEEPALIVE
+    # on every dial, zero-progress stall deadlines on transfers with
     # progress-resets-the-clock semantics, transport retries with
     # backoff+jitter), worker/agent heartbeat floors, the head's
     # suspicion state machine (SUSPECT -> probe -> DEAD), and the
-    # direct-channel liveness probes.  Off = the legacy fully-blocking
-    # behavior, byte-identical, with every new counter
-    # (stall_timeouts / net_retries / hedged_fetches / suspected_nodes)
-    # zero. ---
-    failure_detection: bool = True
+    # direct-channel liveness probes. ---
     # Zero-progress deadline for one wire operation: a transfer that
     # moves no bytes for this long is declared stalled (each received/
     # sent chunk resets the clock, so a slow-but-moving stripe is never
@@ -360,32 +366,24 @@ class Config:
     health_check_initial_delay_s: float = 10.0
 
     # Wait this long for a worker process to start before declaring failure.
-    # protocheck: head-only -- spawn timeout enforced by the head
+    # Head-only: spawn timeout enforced by the head
     worker_start_timeout_s: float = 60.0
 
     # Number of workers prestarted at init when num_cpus not yet demanded
     # (reference: prestart in worker_pool.cc).
-    # protocheck: head-only -- prestart happens at head init
+    # Head-only: prestart happens at head init
     prestart_workers: int = 0
 
     # Multiprocessing start method: "forkserver" is fastest that is still
     # safe with JAX in the driver ("fork" is not — XLA runtime threads).
-    # protocheck: head-only -- consumed by the head's process spawner
+    # Head-only: consumed by the head's process spawner
     worker_start_method: str = "forkserver"
 
     # --- Fault tolerance (reference: object_recovery_manager.h:41 +
-    # task_manager.h:174 lineage pinning; Ownership, NSDI'21). ---
-    # Master switch for the recovery subsystem: lineage recording +
-    # object reconstruction (head-owned AND worker-owned), actor
-    # state-checkpoint hooks, and the recovery counters.  Off = a lost
-    # object surfaces ObjectLostError exactly as the legacy path did,
-    # with reconstructions / reconstruction_failures / actor_restarts /
-    # chaos_kills all zero.
-    recovery: bool = True
-    # Lineage-based object reconstruction: keep creating-task specs for
-    # owned task returns; a lost object is rebuilt by re-executing its
-    # task.  (Legacy escape hatch; ``recovery`` is the master switch.)
-    lineage_enabled: bool = True
+    # task_manager.h:174 lineage pinning; Ownership, NSDI'21): lineage
+    # recording + object reconstruction (head-owned AND worker-owned; a
+    # lost object is rebuilt by re-executing its creating task) and
+    # actor state-checkpoint hooks. ---
     # Byte budget for each owner's retained lineage (the head's table
     # and every worker's DirectCaller table independently): entries
     # evict oldest-first past it, mirroring the reference's
@@ -402,12 +400,13 @@ class Config:
     # Where over-capacity shm objects spill (reference:
     # local_object_manager.h:41 spill to external storage).  Empty =
     # /tmp/ray_tpu_spill_<session>.
-    # protocheck: head-only -- workers/agents get the session-resolved path via RAY_TPU_SPILL_DIR_OVERRIDE
+    # Head-only: workers/agents get the session-resolved path via
+    # RAY_TPU_SPILL_DIR_OVERRIDE.
     spill_dir: str = ""
 
     # Host the head's TCP listener binds (node agents + their workers dial
     # in here).  Use "0.0.0.0" for real multi-host clusters.
-    # protocheck: head-only -- the head's own listener bind address
+    # Head-only: the head's own listener bind address
     listen_host: str = "127.0.0.1"
 
     # --- GCS-analog fault tolerance (reference: GCS table persistence via
@@ -415,40 +414,38 @@ class Config:
     # GcsInitData load-on-restart path, gcs_server.h:77). ---
     # Snapshot file for head metadata (KV, functions, named actors, jobs).
     # "" disables snapshotting.
-    # protocheck: head-only -- head snapshot machinery
+    # Head-only: head snapshot machinery
     gcs_snapshot_path: str = ""
     # Snapshot cadence; dirty state is written at most this often.
-    # protocheck: head-only -- head snapshot machinery
+    # Head-only: head snapshot machinery
     gcs_snapshot_interval_s: float = 2.0
     # Load the snapshot at init (head restart): restores KV/functions and
     # re-creates named actors per their creation specs.
-    # protocheck: head-only -- head restart restore switch
+    # Head-only: head restart restore switch
     gcs_restore: bool = False
     # Fixed TCP listener port (0 = ephemeral).  A restarting head must
     # rebind the old port so agents and clients can re-dial it.
-    # protocheck: head-only -- the head's own listener port
+    # Head-only: the head's own listener port
     listen_port: int = 0
     # Fixed cluster authkey (hex; "" = random per session).  Needed across
     # head restarts so agents/clients can re-authenticate.
-    # protocheck: head-only -- session authkey reaches workers as RAY_TPU_AUTHKEY in the spawn env
+    # Head-only: the session authkey reaches workers as RAY_TPU_AUTHKEY in
+    # the spawn env.
     authkey_hex: str = ""
 
     # --- Head failover (reference: workers reconnecting across a GCS
     # restart — gcs_rpc_server_reconnect_timeout_s /
     # gcs_failover_worker_reconnect_timeout, ray_config_def.h:62 — plus
     # per-owner metadata surviving the metadata server, Ownership
-    # NSDI'21). ---
-    # Master switch: on head-connection EOF, workers and clients PARK
+    # NSDI'21): on head-connection EOF, workers and clients PARK
     # in-flight head calls, re-dial with backoff, and re-register
     # (re-advertising owned objects, held leases, queued/running tasks,
     # and actor incarnations); node agents keep their workers ALIVE and
-    # re-dial.  Off = today's behavior: a worker exits on head EOF and
-    # an agent tears its workers down, so a head death is an outage.
-    head_failover: bool = True
+    # re-dial. ---
     # How long a disconnected peer (worker/client/agent) keeps re-dialing
     # the head before giving up — the failover grace window.  A peer that
-    # exhausts it behaves as with the switch off (worker exit / agent
-    # teardown); the head revokes whatever it was holding.
+    # exhausts it gives up (worker exit / agent teardown); the head
+    # revokes whatever it was holding.
     head_reconnect_grace_s: float = 20.0
     # How long a RESTARTED head waits for restored nodes, leases, and
     # actor incarnations to be re-claimed by reconnecting peers before
@@ -457,26 +454,15 @@ class Config:
     # __ray_save__ checkpoint, and unresolved blip-window objects fail
     # as reconstruction candidates.
     head_reregister_timeout_s: float = 10.0
-    # Node agents re-dial a restarted head instead of exiting ("0"
-    # disables — the previously-undocumented escape hatch, now paired
-    # with head_failover: with failover on a reconnecting agent keeps
-    # its workers; with it off it kills them first, the legacy
-    # behavior).
-    # protocheck: head-only -- agent-process knob, read from the agent's own environment (launcher/operator-set)
-    agent_reconnect: bool = True
 
     # --- Elastic pods (preemption-aware drain + spot slice pools;
     # reference: the GCS DrainNode RPC + raylet drain,
     # gcs_node_manager.h / node_manager.cc HandleDrainRaylet — node
-    # removal as a first-class protocol rather than a death). ---
-    # Master switch for the drain protocol: scale-down and preemption
-    # notices route through ``Runtime.drain_node`` (stop placements,
-    # revoke leases, force-checkpoint restartable actors to a surviving
-    # store, migrate small sole-copy objects) before the node goes
-    # away.  Off = the legacy hard-remove path, byte-identical, with
-    # every elastic counter (preemptions / drains_completed /
-    # drain_timeouts / objects_migrated) zero.
-    elastic_drain: bool = True
+    # removal as a first-class protocol rather than a death): scale-down
+    # and preemption notices route through ``Runtime.drain_node`` (stop
+    # placements, revoke leases, force-checkpoint restartable actors to
+    # a surviving store, migrate small sole-copy objects) before the
+    # node goes away. ---
     # Wall-clock budget for one node drain (the spot warning window —
     # e.g. ~30s on GCE preemptible TPUs).  Past it the drain falls
     # through to the existing hard-kill recovery: lineage reconstructs
@@ -499,21 +485,20 @@ class Config:
     # retriable task's worker before the kernel OOM-killer takes the
     # node). ---
     # Node memory usage fraction above which the monitor kills one task
-    # worker per interval.  0 disables.
-    # protocheck: head-only -- monitor knobs reach node agents in the agent_ack config dict
+    # worker per interval.  0 disables.  Head-only (all three): monitor
+    # knobs reach node agents in the agent_ack config dict.
     memory_monitor_threshold: float = 0.95
-    # protocheck: head-only -- monitor knobs reach node agents in the agent_ack config dict
     memory_monitor_interval_s: float = 1.0
     # Test hook: read the usage fraction from this file instead of
     # /proc/meminfo (reference tests inject usage the same way).
-    # protocheck: head-only -- monitor knobs reach node agents in the agent_ack config dict
     memory_monitor_test_file: str = ""
 
     # Stream worker stdout/stderr to the driver with a worker prefix
     # (reference: log_monitor.py + log_to_driver in ray.init).  Worker
     # output always lands in per-worker files under the session dir;
     # this flag controls the re-print at the driver.
-    # protocheck: head-only -- the re-print of worker logs happens in the head's monitor thread
+    # Head-only: the re-print of worker logs happens in the head's monitor
+    # thread.
     log_to_driver: bool = True
 
     @classmethod
@@ -528,5 +513,20 @@ class Config:
                 kwargs[k] = v
         return cls(**kwargs)
 
+
+# Fields a spawned worker does NOT inherit (the reason is at each field):
+# everything else rides ``Runtime._worker_config_env`` into both spawn
+# paths and is read back by ``from_env`` at the worker's import.
+HEAD_ONLY = frozenset({
+    "object_store_memory", "shm_dir", "object_advertise_host",
+    "locality_min_bytes", "idle_worker_timeout_s",
+    "max_extra_blocked_workers", "default_max_retries",
+    "max_tasks_in_flight_per_worker", "worker_start_timeout_s",
+    "prestart_workers", "worker_start_method", "spill_dir", "listen_host",
+    "gcs_snapshot_path", "gcs_snapshot_interval_s", "gcs_restore",
+    "listen_port", "authkey_hex", "memory_monitor_threshold",
+    "memory_monitor_interval_s", "memory_monitor_test_file",
+    "log_to_driver",
+})
 
 GLOBAL_CONFIG = Config.from_env()

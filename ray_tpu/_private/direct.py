@@ -218,7 +218,7 @@ class DirectCaller:
         self._send_event = threading.Event()
         self._sender_thread = None
         # Decentralized-dispatch holder counters, shipped to the head in
-        # the periodic xfer_stats deltas (zero while the switch is off):
+        # the periodic xfer_stats deltas:
         # leased_submits = specs pushed over leases (the traffic the head
         # never sees), spillbacks = pushes an oversubscribed executor
         # bounced back.
@@ -228,21 +228,17 @@ class DirectCaller:
         # specs, task_manager.h:174): THIS process is the owner directory
         # for its direct-submitted tasks, so reconstruction of their lost
         # returns must run here — the head never saw the specs.  Bounded
-        # by the same byte budget as the head's table; None when the
-        # recovery subsystem is off (every counter then stays zero).
+        # by the same byte budget as the head's table.
         # LOCK ORDER: the table's _lock is an independent LEAF acquired
         # under self.lock (record on submit, release on free) — pinned
         # in tests/test_lockcheck.py.
         cfg = GLOBAL_CONFIG
-        self.lineage = (recovery.LineageTable(cfg.lineage_bytes_budget)
-                        if cfg.recovery and cfg.lineage_enabled else None)
+        self.lineage = recovery.LineageTable(cfg.lineage_bytes_budget)
         self.reconstructions = 0
         self.reconstruction_failures = 0
         # Failure detection: the channel-liveness watchdog's stall
-        # window (0 = off, nothing new runs — the legacy behavior where
-        # only a conn EOF discovers a dead executor).
-        self._fd_stall_t = (cfg.net_stall_timeout_s
-                            if cfg.failure_detection else 0.0)
+        # window (0 = only a conn EOF discovers a dead executor).
+        self._fd_stall_t = cfg.net_stall_timeout_s
 
     def stats(self) -> Dict[str, int]:
         """Counter snapshot for the xfer_stats delta shipper."""
@@ -313,11 +309,10 @@ class DirectCaller:
             # pinned consumers).  Mark for free-on-complete.
             return
         self.owned.pop(oid, None)
-        if self.lineage is not None:
-            # Lineage pinning ends with the object: the table entry
-            # drops when its last return object does (leaf lock; no
-            # resources to release worker-side).
-            self.lineage.release(oid.binary())
+        # Lineage pinning ends with the object: the table entry drops
+        # when its last return object does (leaf lock; no resources to
+        # release worker-side).
+        self.lineage.release(oid.binary())
         if st.status == DELEGATED:
             # Head holds one aggregate ref for this process.
             self._outbound.append(("head", ("decref", oid.binary())))
@@ -494,8 +489,7 @@ class DirectCaller:
             for spec in specs:
                 entry, states = self._register_entry_locked(
                     spec, spec.get("max_retries", 3))
-                if self.lineage is not None \
-                        and spec.get("num_returns", 0) > 0:
+                if spec.get("num_returns", 0) > 0:
                     # Owner-side lineage (metadata only — evicted
                     # entries hold nothing to release here; a spec's
                     # lost args reconstruct through their OWN lineage,
@@ -573,8 +567,7 @@ class DirectCaller:
                     lease.last_renew = now
                     renew.append(lease.worker_id)
                 to_push.append((lease, entry))
-            if cfg.decentralized_dispatch:
-                self.leased_submits += len(to_push)
+            self.leased_submits += len(to_push)
             if q and not pool["requesting"]:
                 if now - pool["last_req"] > 0.05 or not leases:
                     pool["requesting"] = True
@@ -606,8 +599,7 @@ class DirectCaller:
         # caller marks may bounce — an executor never spills a push whose
         # sender would not understand the ("dspill", ...) reply.  Actor
         # channels never spill (per-caller ordering).
-        spill_ok = (cfg.decentralized_dispatch
-                    and cfg.lease_spillback_depth > 0
+        spill_ok = (cfg.lease_spillback_depth > 0
                     and not (lease.klass and lease.klass[0] == "actor"))
         tasks, failed = [], []
         for entry in entries:
@@ -891,26 +883,18 @@ class DirectCaller:
     # ------------------------------------------------------------ leases --
     def _request_leases(self, klass, n):
         pool = None
-        cfg = GLOBAL_CONFIG
-        hint = None
-        if cfg.decentralized_dispatch:
-            with self.lock:
-                p = self.pools.get(klass)
-                if p is not None:
-                    # One-shot spillback hint: steer this request toward
-                    # the node the head named as next-best.
-                    hint = p.pop("hint", None)
+        with self.lock:
+            p = self.pools.get(klass)
+            # One-shot spillback hint: steer this request toward the
+            # node the head named as next-best.
+            hint = p.pop("hint", None) if p is not None else None
         try:
             res = dict(klass)
-            if cfg.decentralized_dispatch:
-                opts = {"v": 1}
-                if hint:
-                    opts["hint"] = hint
-                reply = self.host.head_request(
-                    lambda rid: ("lease_req", rid, res, n, opts))
-            else:
-                reply = self.host.head_request(
-                    lambda rid: ("lease_req", rid, res, n))
+            opts = {"v": 1}
+            if hint:
+                opts["hint"] = hint
+            reply = self.host.head_request(
+                lambda rid: ("lease_req", rid, res, n, opts))
         except Exception:
             reply = []
         slots, ttl = PIPELINE_DEPTH, 0.0
@@ -1036,8 +1020,7 @@ class DirectCaller:
             entry = lease.inflight.pop(rid, None)
             if entry is None:
                 return
-            if GLOBAL_CONFIG.decentralized_dispatch:
-                self.spillbacks += 1
+            self.spillbacks += 1
             lease.saturated_until = time.monotonic() + SATURATED_S
             entry["spills"] = entry.get("spills", 0) + 1
             pool = self._pool_locked(lease.klass)
@@ -1560,8 +1543,6 @@ class DirectCaller:
         lineage entry's max_retries budget; returns True when the
         object is READY again (blocked getters already woke through the
         ownership cv)."""
-        if self.lineage is None:
-            return False
         visited = set() if _visited is None else _visited
         prefix = oid.binary()[:12]
         if prefix in visited:

@@ -129,9 +129,9 @@ class NodeAgent:
                              name="agent-preempt-poll").start()
         # Heartbeat floor (failure detection): one ("heartbeat", ...)
         # per health_check_period_s so head-side silence from this node
-        # is a SIGNAL, not an idle link.  The thread starts
-        # unconditionally and gates per-tick on the handshake-resolved
-        # knobs (env wins per node, else the head's agent_ack config).
+        # is a SIGNAL, not an idle link.  The thread waits for the
+        # handshake-resolved period (env wins per node, else the head's
+        # agent_ack config).
         threading.Thread(target=self._heartbeat_loop, daemon=True,
                          name="agent-heartbeat").start()
 
@@ -140,9 +140,7 @@ class NodeAgent:
             pass
         period = float(self._failover_knob("RAY_TPU_HEALTH_CHECK_PERIOD_S",
                                            "health_check_period_s", 5.0))
-        on = self._failover_knob("RAY_TPU_FAILURE_DETECTION",
-                                 "failure_detection", True)
-        if not on or period <= 0:
+        if period <= 0:
             return
         while not self._stopped:
             time.sleep(period)
@@ -228,8 +226,6 @@ class NodeAgent:
         ``_system_config`` governs the whole cluster); else default."""
         raw = os.environ.get(env_name)
         if raw is not None:
-            if isinstance(default, bool):
-                return raw.lower() in ("1", "true", "yes")
             return type(default)(raw)
         return self.head_config.get(cfg_key, default)
 
@@ -308,8 +304,7 @@ class NodeAgent:
             return
         if reconnect and self.workers:
             # The head came back as a DIFFERENT cluster (no restore):
-            # our workers belong to a dead session — tear them down, as
-            # the pre-failover reconnect always did.
+            # our workers belong to a dead session — tear them down.
             self._terminate_workers()
         # Store for read_segment + direct-put ingest.  Segments here are
         # otherwise created by this node's workers; the agent allocates
@@ -338,8 +333,7 @@ class NodeAgent:
                 msg = protocol.recv(self.conn)
             except (EOFError, OSError):
                 # Head gone.  If it persists GCS state it may restart on
-                # the same port: keep our workers ALIVE (head_failover —
-                # they park and re-register on their own conns) and
+                # the same port: keep our workers ALIVE (they park and re-register on their own conns) and
                 # re-dial for a grace period before giving the node up
                 # (reference: workers reconnecting across GCS restart,
                 # gcs_failover_worker_reconnect_timeout,
@@ -393,32 +387,20 @@ class NodeAgent:
         self.shutdown()
 
     def _reconnect(self) -> bool:
-        if not self._failover_knob("RAY_TPU_AGENT_RECONNECT",
-                                   "agent_reconnect", True):
-            return False
-        keep = self._failover_knob("RAY_TPU_HEAD_FAILOVER",
-                                   "head_failover", True)
-        if not keep:
-            # Legacy reconnect: the old session's workers hold dead head
-            # conns and stale state — terminate before re-dialing.  With
-            # failover ON the workers stay ALIVE (they park and
-            # re-register on their own conns; worker PIDs survive the
-            # blip), and connect() tears them down only if the head
-            # comes back as a different cluster.
-            self._terminate_workers()
+        # The workers stay ALIVE (they park and re-register on their own
+        # conns; worker PIDs survive the blip); connect() tears them down
+        # only if the head comes back as a different cluster.
         try:
             self.conn.close()
         except Exception:
             pass
         self.conn = None  # connect()'s retry-exhaustion guard needs this
         try:
-            self.connect(reconnect=keep)
+            self.connect(reconnect=True)
             return True
         except (SystemExit, Exception):
-            if keep:
-                # Grace exhausted with workers still up: fall through to
-                # shutdown(), which terminates them — the legacy outage.
-                pass
+            # Grace exhausted with workers still up: fall through to
+            # shutdown(), which terminates them.
             return False
 
     def notice_preemption(self, source: str):
@@ -431,10 +413,10 @@ class NodeAgent:
     def _self_drain(self, source: str):
         """Deadline-bounded self-drain before the plug pulls: ask the
         head to drain this node (``preempt_notice``), wait for its
-        ``drain_node`` release, then exit.  Degrades to the legacy
-        immediate exit when the drain protocol is off, the head never
-        advertised the verbs, or the deadline expires — exactly the
-        no-warning preemption the hard-kill recovery already covers."""
+        ``drain_node`` release, then exit.  Degrades to an immediate
+        exit when the head never advertised the verbs or the deadline
+        expires — exactly the no-warning preemption the hard-kill
+        recovery already covers."""
         with self._drain_lock:
             if self._draining or self._stopped:
                 return
@@ -444,10 +426,8 @@ class NodeAgent:
         recovery.syncpoint("preempt")
         deadline_s = float(self._failover_knob("RAY_TPU_DRAIN_DEADLINE_S",
                                                "drain_deadline_s", 10.0))
-        on = self._failover_knob("RAY_TPU_ELASTIC_DRAIN",
-                                 "elastic_drain", True)
         head_drain_caps = tuple(self.head_config.get("drain_caps") or ())
-        if on and self.conn is not None \
+        if self.conn is not None \
                 and "preempt_notice" in head_drain_caps:
             try:
                 self._send(("preempt_notice", deadline_s, source))

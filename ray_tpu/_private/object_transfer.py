@@ -79,20 +79,16 @@ def peer_accepts_puts(caps) -> bool:
 
 
 def _net_stall_timeout() -> float:
-    """This process's zero-progress deadline for wire transfers; 0.0
-    (never arm a deadline — the legacy fully-blocking behavior) with
-    ``failure_detection`` off."""
+    """This process's zero-progress deadline for wire transfers (0.0 =
+    never arm a deadline)."""
     from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
 
-    return (_cfg.net_stall_timeout_s if _cfg.failure_detection else 0.0)
+    return _cfg.net_stall_timeout_s
 
 
 def net_params(cfg) -> Tuple[float, float, int, float]:
     """A Config -> the pool hosts' frozen failure-detection tuple
-    (stall_timeout_s, connect_timeout_s, retry_count, backoff_base_ms);
-    all-zero with the master switch off so nothing new ever runs."""
-    if not cfg.failure_detection:
-        return (0.0, 0.0, 0, 0.0)
+    (stall_timeout_s, connect_timeout_s, retry_count, backoff_base_ms)."""
     return (cfg.net_stall_timeout_s, cfg.net_connect_timeout_s,
             int(cfg.net_retry_count), cfg.net_retry_backoff_base_ms)
 
@@ -399,7 +395,7 @@ def _drain_discard(conn, n: int):
     try:
         while got < n:
             try:
-                got += conn.recv_bytes_into(scratch)  # noqa: RTL403 -- deadline armed above (legacy blocking with the switch off)
+                got += conn.recv_bytes_into(scratch)  # noqa: RTL403 -- deadline armed above
             except BufferTooShort as e:
                 got += len(e.args[0])
     finally:
@@ -435,7 +431,7 @@ class _ConnPool:
         self.total = 0
         self.cv = threading.Condition()
         self.closed = False
-        # 0.0 = legacy unbounded dial (failure_detection off).
+        # 0.0 = unbounded dial.
         self.connect_timeout = connect_timeout
 
     def acquire(self, timeout: Optional[float] = None):
@@ -460,11 +456,9 @@ class _ConnPool:
                     return None
                 self.cv.wait(left)
         try:
-            # Deadline-aware dial: connect timeout + SO_KEEPALIVE when
-            # the failure-detection plane is on (a black-holed peer
-            # fails the dial in net_connect_timeout_s instead of the
-            # kernel's ~2 min default); the legacy Client() dial with
-            # it off.
+            # Deadline-aware dial: connect timeout + SO_KEEPALIVE (a
+            # black-holed peer fails the dial in net_connect_timeout_s
+            # instead of the kernel's ~2 min default).
             conn = protocol.dial(protocol.parse_address(self.addr),
                                  authkey=self.authkey,
                                  connect_timeout=self.connect_timeout)
@@ -543,9 +537,7 @@ class _PoolHost:
         # Failure-detection parameters, frozen at construction
         # (stall_timeout_s, connect_timeout_s, retry_count,
         # backoff_base_ms).  Default: this process's GLOBAL_CONFIG; the
-        # head passes its _system_config explicitly.  All zero with the
-        # switch off — no deadline is ever armed, no retry ever runs,
-        # byte-identical legacy blocking transfers.
+        # head passes its _system_config explicitly.
         if net_config is None:
             from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
 

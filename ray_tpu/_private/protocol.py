@@ -362,9 +362,7 @@ VERBS = {
     # the liveness FLOOR under the existing periodic traffic
     # (xfer_stats, renewals): a peer with nothing else to say still
     # sends one per health_check_period_s, so head-side silence is a
-    # signal.  All four verbs are sent only while the
-    # ``failure_detection`` switch is on (both sides read the same
-    # plumbed knob, so an off-switch cluster never sees them). --------
+    # signal. --------
     "heartbeat": Verb(("worker", "client", "agent"), ("head",), (2, 2),
                       "periodic liveness floor (worker/store id); also "
                       "the immediate reply to an hc_probe"),
@@ -497,8 +495,7 @@ def net_point(point: str, conn) -> Optional[str]:
 # Process-wide failure-detection counters (the deadline core is the one
 # place every stall/retry/hedge flows through).  Workers and clients
 # ship them to the head in the periodic xfer_stats deltas; the head
-# merges its own process's values in transfer_stats().  All zero with
-# failure_detection off.
+# merges its own process's values in transfer_stats().
 _NET_STATS_LOCK = threading.Lock()  # lock-order: leaf
 _NET_STATS = {"stall_timeouts": 0, "net_retries": 0, "hedged_fetches": 0}
 
@@ -646,9 +643,8 @@ def dial(address, authkey: Optional[bytes] = None,
     ``net_connect_timeout_s``, not the kernel's ~2 min default),
     ``SO_KEEPALIVE`` armed, Nagle off, and the auth handshake bounded
     by the same window (an accepted-but-stalled listener cannot hang
-    the dialer).  ``connect_timeout=None`` reads the config knob; with
-    ``failure_detection`` off this is byte-identical to the legacy
-    ``Client()`` dial."""
+    the dialer).  ``connect_timeout=None`` reads the config knob; 0 is
+    the plain unbounded ``Client()`` dial."""
     from multiprocessing.connection import Client
 
     if isinstance(address, str) and address.startswith("tcp://"):
@@ -656,8 +652,7 @@ def dial(address, authkey: Optional[bytes] = None,
     if connect_timeout is None:
         from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
 
-        connect_timeout = (_cfg.net_connect_timeout_s
-                           if _cfg.failure_detection else 0.0)
+        connect_timeout = _cfg.net_connect_timeout_s
     if not connect_timeout or connect_timeout <= 0:
         conn = Client(tuple(address) if isinstance(address, (tuple, list))
                       else address, authkey=authkey)

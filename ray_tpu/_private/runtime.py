@@ -24,6 +24,7 @@ This file is the TPU-native condensation of four reference components:
 from __future__ import annotations
 
 import atexit
+import dataclasses
 import itertools
 import multiprocessing
 import multiprocessing.connection
@@ -38,7 +39,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ray_tpu._private import device_env, object_transfer, protocol, \
     recovery, serialization
-from ray_tpu._private.config import Config
+from ray_tpu._private.config import HEAD_ONLY, Config, env_name
 from ray_tpu._private.ids import (
     ActorID,
     JobID,
@@ -524,21 +525,20 @@ class Runtime:
             net_config=object_transfer.net_params(config))
         self.relayed_segments = 0   # head-relayed agent reads (fallback)
         self.brokered_parts = 0     # worker getparts served via the head
-        # Write-direction counters (all zero while direct_puts is off —
-        # pinned by tests): direct_puts/direct_put_bytes = values that
-        # reached this store over the data plane (the head saw only the
-        # O(1) put_commit message); brokered_put_parts = legacy
-        # whole-value put_parts messages assembled here while the direct
-        # path was ON (old-verb clients, push failures, and mid-size
-        # puts under the client's direct-put floor — a few MB, where
-        # the fire-and-forget message beats three round trips).
+        # Write-direction counters: direct_puts/direct_put_bytes = values
+        # that reached this store over the data plane (the head saw only
+        # the O(1) put_commit message); brokered_put_parts = whole-value
+        # put_parts messages assembled here (old-verb clients, push
+        # failures, and mid-size puts under the client's direct-put
+        # floor — a few MB, where the fire-and-forget message beats
+        # three round trips).
         self.direct_puts = 0
         self.direct_put_bytes = 0
         self.brokered_put_parts = 0
-        # Legacy put_parts assemblies run off the reader threads but
+        # put_parts assemblies run off the reader threads but
         # BOUNDED: past this many in flight the reader blocks before
         # spawning (TCP backpressure then throttles the bursting
-        # client), so a legacy-put storm cannot pin unbounded buffer
+        # client), so a put_parts storm cannot pin unbounded buffer
         # memory in concurrent multi-hundred-MB memcpys.
         self._put_assembly_sem = threading.BoundedSemaphore(4)
         # Locality-aware placement counters (tentpole observability):
@@ -555,8 +555,7 @@ class Runtime:
         self.deduped_pulls = 0
         self.prefetch_hit_bytes = 0
         self.prefetch_waste_bytes = 0
-        # Decentralized-dispatch counters (all zero when the
-        # decentralized_dispatch switch is off — pinned by tests):
+        # Decentralized-dispatch counters:
         # lease_grants     = worker leases handed to peer holders
         #                    (solicited lease_req + unsolicited bulk
         #                    grants piggybacked on submit bursts),
@@ -572,8 +571,7 @@ class Runtime:
         self.head_brokered_submits = 0
         self.leased_submits = 0
         self.spillbacks = 0
-        # Recovery counters (all zero while config.recovery is off —
-        # pinned by tests): reconstructions = lost objects whose
+        # Recovery counters: reconstructions = lost objects whose
         # producer was re-queued from lineage (head-side, plus
         # worker-side deltas via xfer_stats); reconstruction_failures =
         # losses recovery could not cover (no/evicted lineage, depleted
@@ -584,8 +582,8 @@ class Runtime:
         self.reconstruction_failures = 0
         self.actor_restarts = 0
         self.chaos_kills = 0
-        # Head-failover counters (all zero while head_failover is off or
-        # no restart happened — pinned by tests): gcs_snapshots /
+        # Head-failover counters (all zero while no restart
+        # happened — pinned by tests): gcs_snapshots /
         # gcs_snapshot_failures count the persistence loop's writes;
         # reconnected_nodes = agents that re-dialed and re-claimed their
         # restored node; reregistered_workers = surviving worker/client
@@ -597,8 +595,7 @@ class Runtime:
         self.reconnected_nodes = 0
         self.reregistered_workers = 0
         self.adopted_actors = 0
-        # Elastic-pod counters (all zero while elastic_drain is off —
-        # pinned by tests): preemptions = preempt_notice messages
+        # Elastic-pod counters: preemptions = preempt_notice messages
         # received from agents (spot warning windows); drains_completed /
         # drain_timeouts = drain_node() outcomes (a timeout falls
         # through to hard-kill recovery); objects_migrated = sole-copy
@@ -609,8 +606,7 @@ class Runtime:
         self.drains_completed = 0
         self.drain_timeouts = 0
         self.objects_migrated = 0
-        # Failure-detection counters (all zero while failure_detection
-        # is off — pinned by tests): suspected_nodes = peers (node
+        # Failure-detection counters: suspected_nodes = peers (node
         # agents AND workers) the suspicion machine marked SUSPECT
         # after health_check_timeout_s of silence; stall_timeouts /
         # net_retries / hedged_fetches aggregate the deadline core's
@@ -771,14 +767,13 @@ class Runtime:
         self._reaper = threading.Thread(
             target=self._reap_loop, daemon=True, name="ray_tpu-reaper")
         self._reaper.start()
-        if config.failure_detection:
-            # Heartbeat suspicion (reference: GcsHealthCheckManager):
-            # silence -> SUSPECT -> probe -> DEAD, feeding the existing
-            # node/worker-death paths — a stalled peer becomes
-            # indistinguishable from a killed one within one suspicion
-            # window.  Off-switch = no thread, no probes, counter zero.
-            threading.Thread(target=self._suspicion_loop, daemon=True,
-                             name="ray_tpu-suspicion").start()
+        # Heartbeat suspicion (reference: GcsHealthCheckManager):
+        # silence -> SUSPECT -> probe -> DEAD, feeding the existing
+        # node/worker-death paths — a stalled peer becomes
+        # indistinguishable from a killed one within one suspicion
+        # window.
+        threading.Thread(target=self._suspicion_loop, daemon=True,
+                         name="ray_tpu-suspicion").start()
         if config.memory_monitor_threshold > 0:
             threading.Thread(target=self._memory_monitor_loop,
                              daemon=True, name="ray_tpu-memmon").start()
@@ -821,15 +816,13 @@ class Runtime:
             target=self._task_sender_loop, daemon=True,
             name="ray_tpu-sender")
         self._sender.start()
-        # Sharded dispatch (decentralized_dispatch on): the hot submit
-        # and reply paths no longer run the global dispatch scan inside
-        # their own lock hold — they mark the affected scheduling
-        # class(es) dirty (per-shard dirty set, own LEAF lock: never
-        # taken around another lock; the event is set outside it) and
-        # the dispatcher thread drains dirty shards, each pass scoped to
-        # its class instead of scanning every queue.  With the switch
-        # off the shards are never marked and every site dispatches
-        # inline exactly as before.
+        # Sharded dispatch: the hot submit and reply paths do not run
+        # the global dispatch scan inside their own lock hold — they
+        # mark the affected scheduling class(es) dirty (per-shard dirty
+        # set, own LEAF lock: never taken around another lock; the event
+        # is set outside it) and the dispatcher thread drains dirty
+        # shards, each pass scoped to its class instead of scanning
+        # every queue.
         self._dispatch_dirty: set = set()
         self._dispatch_dirty_lock = threading.Lock()  # lock-order: leaf
         self._dispatch_event = threading.Event()
@@ -921,15 +914,10 @@ class Runtime:
                 traceback.print_exc()
 
     def _request_dispatch_locked(self, keys=None):
-        """Dispatch trigger for the hot paths.  decentralized_dispatch
-        off: inline full pass, byte-identical to the pre-shard behavior.
-        On: mark the affected shard(s) dirty (``keys`` None = all — a
-        resource was freed, anything may now place) and let the
-        dispatcher thread run the scan outside this caller's lock
-        hold."""
-        if not self.config.decentralized_dispatch:
-            self._dispatch_locked()
-            return
+        """Dispatch trigger for the hot paths: mark the affected
+        shard(s) dirty (``keys`` None = all — a resource was freed,
+        anything may now place) and let the dispatcher thread run the
+        scan outside this caller's lock hold."""
         with self._dispatch_dirty_lock:
             if keys is None:
                 self._dispatch_dirty.add(self._DIRTY_ALL)
@@ -1025,8 +1013,6 @@ class Runtime:
         costlier)."""
         if isinstance(node_id, str):
             node_id = NodeID(bytes.fromhex(node_id))
-        if not self.config.elastic_drain:
-            return False
         if deadline_s is None:
             deadline_s = self.config.drain_deadline_s
         deadline = time.monotonic() + max(0.2, float(deadline_s))
@@ -1079,8 +1065,7 @@ class Runtime:
                     # would — the holder retries/reroutes everything the
                     # lease carried, but NOW, against a still-healthy
                     # cluster, instead of at the kill.
-                    if not holder.dead \
-                            and self.config.decentralized_dispatch:
+                    if not holder.dead:
                         self.lease_revocations += 1
                         self._queue_send(holder, ("lease_revoke",
                                                   [w.worker_id.hex()]))
@@ -1140,8 +1125,6 @@ class Runtime:
         landed before the deadline."""
         targets = []
         with self.lock:
-            if not self.config.recovery:
-                return True
             for aid, actor in self.actors.items():
                 w = actor.worker
                 if (actor.status == ALIVE and w is not None and not w.dead
@@ -1721,12 +1704,7 @@ class Runtime:
             raise serialization.loads_inline(descr[1])
         return value
 
-    def _recovery_on(self) -> bool:
-        return self.config.recovery and self.config.lineage_enabled
-
     def _register_lineage_locked(self, spec: dict):
-        if not self._recovery_on():
-            return
         if "actor_id" in spec or spec.get("num_returns", 0) <= 0:
             return  # actor methods have side effects; no re-execution
         # Keyed by the 12-byte task prefix: an ObjectID carries only the
@@ -1766,12 +1744,10 @@ class Runtime:
 
     def _try_recover_locked(self, oid: ObjectID) -> bool:
         """Queue re-execution of ``oid``'s creating task (reference:
-        ObjectRecoveryManager::RecoverObject).  Returns False when
-        recovery is off, no lineage exists (puts, actor results,
-        released/evicted lineage), or the entry's reconstruction budget
-        — per-task max_retries, a SYSTEM-failure budget — is spent."""
-        if not self._recovery_on():
-            return False
+        ObjectRecoveryManager::RecoverObject).  Returns False when no
+        lineage exists (puts, actor results, released/evicted lineage),
+        or the entry's reconstruction budget — per-task max_retries, a
+        SYSTEM-failure budget — is spent."""
         entry = self.lineage.get(oid.task_prefix())
         if entry is None:
             return False
@@ -1825,8 +1801,7 @@ class Runtime:
         """Trigger lineage recovery and block until the object is READY
         again.  Call WITHOUT the runtime lock.  A False return is a
         counted reconstruction failure — the caller surfaces
-        ObjectLostError (zero failures counted while recovery is off:
-        the refusal is then the legacy path, not a failure of it)."""
+        ObjectLostError."""
         ev = threading.Event()
         ok = False
         known = False
@@ -1855,7 +1830,7 @@ class Runtime:
                 ok = st is not None and st.status == READY
                 return ok
         finally:
-            if not ok and known and self._recovery_on():
+            if not ok and known:
                 with self.lock:
                     self.reconstruction_failures += 1
 
@@ -1933,9 +1908,7 @@ class Runtime:
                 pass  # conn trouble: fall back to the head relay
         with self.lock:
             self.relayed_segments += 1
-        cfg = self.config
-        relay_timeout = (max(2.0 * cfg.net_stall_timeout_s, 5.0)
-                         if cfg.failure_detection else 30.0)
+        relay_timeout = max(2.0 * self.config.net_stall_timeout_s, 5.0)
         return agent.request_segment(descr[1], timeout=relay_timeout)
 
     def get_objects(self, refs, timeout=None):
@@ -2061,7 +2034,7 @@ class Runtime:
         with self.lock:
             dispatch_keys: List[tuple] = []
             actor_ids: List[bytes] = []
-            if from_worker and self.config.decentralized_dispatch:
+            if from_worker:
                 # The decentralization observable: specs that reached the
                 # head's scheduler over the wire.  Under a healthy lease
                 # plane this stays bounded by lease-renewal/starvation
@@ -2112,9 +2085,7 @@ class Runtime:
                 self._pump_actor_locked(self.actors[aid])
             if dispatch_keys:
                 keys = list(dict.fromkeys(dispatch_keys))
-                if not self.config.decentralized_dispatch:
-                    self._dispatch_locked()
-                elif not from_worker and len(specs) == 1:
+                if not from_worker and len(specs) == 1:
                     # Driver sync-submit fast path: one spec, dispatch its
                     # class inline (no thread hop on the latency path; the
                     # scan is already scoped to one shard).
@@ -2235,16 +2206,14 @@ class Runtime:
     def _locality_pref_locked(
             self, rec: TaskRecord) -> Optional[Tuple[NodeState, int]]:
         """(top-locality node, argument bytes homed there), or None when
-        locality does not apply — strategy/PG tasks, no sizeable homed
-        args, or the feature switched off.  Walks the spec's arg/kwarg
+        locality does not apply — strategy/PG tasks or no sizeable homed
+        args.  Walks the spec's arg/kwarg
         descriptors once per record: every SHM/SPILLED descriptor carries
         (size, home store_id), and a "ref" arg's descriptor is READY in
         the object table by pick time (deps resolved before enqueue).
         Reference: locality-aware lease selection in
         hybrid_scheduling_policy.cc via the owner's object directory
         (the head IS the directory here — Ownership, NSDI'21)."""
-        if not self.config.locality_scheduling:
-            return None
         if rec.pg_id is not None or rec.spec.get("scheduling_strategy"):
             return None
         homes = rec.locality_homes
@@ -2675,151 +2644,14 @@ class Runtime:
         return w
 
     def _worker_config_env(self) -> Dict[str, str]:
-        """Config knobs that follow _system_config overrides into workers
-        via the env namespace (worker GLOBAL_CONFIG is rebuilt from env at
-        import).  Shared by both spawn paths so a knob added here reaches
-        agent-spawned workers too — the ray_tpu.data entries are what lets
-        a Dataset consumed INSIDE a worker (the Train shard contract) see
-        the driver's engine switch and byte budget."""
-        return {
-            "RAY_TPU_MAX_INLINE": str(self.config.max_inline_object_size),
-            "RAY_TPU_POOL_BYTES": str(self.config.shm_pool_bytes),
-            "RAY_TPU_OBJECT_POOL_SIZE": str(self.config.object_pool_size),
-            "RAY_TPU_OBJECT_STRIPE_THRESHOLD":
-                str(self.config.object_stripe_threshold),
-            "RAY_TPU_DIRECT_PUTS":
-                "1" if self.config.direct_puts else "0",
-            "RAY_TPU_OBJECT_PUT_STRIPE_THRESHOLD":
-                str(self.config.object_put_stripe_threshold),
-            "RAY_TPU_OBJECT_PUT_POOL_SIZE":
-                str(self.config.object_put_pool_size),
-            "RAY_TPU_ARG_PREFETCH_DEPTH":
-                str(self.config.arg_prefetch_depth),
-            "RAY_TPU_STREAMING_EXECUTOR":
-                "1" if self.config.streaming_executor else "0",
-            "RAY_TPU_DATA_MEMORY_BUDGET":
-                str(self.config.data_memory_budget),
-            "RAY_TPU_DATA_MEMORY_BUDGET_FRACTION":
-                str(self.config.data_memory_budget_fraction),
-            "RAY_TPU_DATA_MAX_INFLIGHT_TASKS":
-                str(self.config.data_max_inflight_tasks),
-            # Push-shuffle knobs: the switch and both tuning knobs are
-            # read in the WORKER process (map tasks partition + push,
-            # reducer actors merge on arrival), and a Dataset consumed
-            # inside a worker plans its shuffle there too.
-            "RAY_TPU_PUSH_SHUFFLE":
-                "1" if self.config.push_shuffle else "0",
-            "RAY_TPU_SHUFFLE_PARTITION_BYTES_TARGET":
-                str(self.config.shuffle_partition_bytes_target),
-            "RAY_TPU_SHUFFLE_MERGE_FANIN":
-                str(self.config.shuffle_merge_fanin),
-            # Distributed-training knobs: the switch and both tuning
-            # knobs are read wherever the trainer/learner runs — stage
-            # actors push in WORKER processes, and a PipelineTrainer or
-            # Impala built inside a Trainable worker must see the
-            # driver's _system_config.
-            "RAY_TPU_DISTRIBUTED_TRAINING":
-                "1" if self.config.distributed_training else "0",
-            "RAY_TPU_PIPELINE_MICROBATCHES":
-                str(self.config.pipeline_microbatches),
-            "RAY_TPU_IMPALA_QUEUE_DEPTH":
-                str(self.config.impala_queue_depth),
-            "RAY_TPU_DECENTRALIZED_DISPATCH":
-                "1" if self.config.decentralized_dispatch else "0",
-            "RAY_TPU_LEASE_SLOTS": str(self.config.lease_slots),
-            "RAY_TPU_LEASE_TTL_S": str(self.config.lease_ttl_s),
-            "RAY_TPU_LEASE_RENEW_TASKS":
-                str(self.config.lease_renew_tasks),
-            "RAY_TPU_LEASE_SPILLBACK_DEPTH":
-                str(self.config.lease_spillback_depth),
-            # Serving knobs: the continuous-batching switch is read in
-            # the REPLICA worker, the autoscale windows in the
-            # controller worker — both only see _system_config through
-            # this env namespace.
-            "RAY_TPU_CONTINUOUS_BATCHING":
-                "1" if self.config.continuous_batching else "0",
-            # Serving memory plane: all three are read in the REPLICA
-            # worker (paged admission + prefix reuse + draft length).
-            "RAY_TPU_PAGED_KV":
-                "1" if self.config.paged_kv else "0",
-            "RAY_TPU_PREFIX_CACHING":
-                "1" if self.config.prefix_caching else "0",
-            "RAY_TPU_SPECULATIVE_K": str(self.config.speculative_k),
-            "RAY_TPU_SERVE_METRIC_LOOKBACK_S":
-                str(self.config.serve_metric_lookback_s),
-            "RAY_TPU_SERVE_DOWNSCALE_DELAY_S":
-                str(self.config.serve_downscale_delay_s),
-            # Disaggregated serving: the split switch is read by the
-            # controller (pool twin deploys), replicas (prefill-only /
-            # chain-import step paths) and handles/proxies (affinity
-            # routing); the stripe threshold wherever a prefill replica
-            # pushes a chain.
-            "RAY_TPU_DISAGGREGATED_SERVING":
-                "1" if self.config.disaggregated_serving else "0",
-            "RAY_TPU_KV_STREAM_STRIPE_THRESHOLD":
-                str(self.config.kv_stream_stripe_threshold),
-            "RAY_TPU_PREFIX_AFFINITY":
-                "1" if self.config.prefix_affinity else "0",
-            # Fault-tolerance knobs: workers keep their own bounded
-            # lineage for direct-path tasks and arm actor checkpoint
-            # hooks — both must see the driver's _system_config.
-            "RAY_TPU_RECOVERY": "1" if self.config.recovery else "0",
-            # The legacy lineage escape hatch gates every DirectCaller's
-            # worker-side table exactly like the head's — a driver
-            # turning it off via _system_config must reach them (found
-            # by protocheck RTL504: the knob was read in workers but
-            # plumbed to neither spawn path).
-            "RAY_TPU_LINEAGE_ENABLED":
-                "1" if self.config.lineage_enabled else "0",
-            "RAY_TPU_LINEAGE_BYTES_BUDGET":
-                str(self.config.lineage_bytes_budget),
-            "RAY_TPU_ACTOR_CHECKPOINT_INTERVAL_S":
-                str(self.config.actor_checkpoint_interval_s),
-            # Elastic-pod knobs: the drain switch/deadline also reach
-            # node agents via the agent_ack config dict (their env wins
-            # per node); riding the worker env keeps the whole cluster
-            # on the driver's _system_config.
-            "RAY_TPU_ELASTIC_DRAIN":
-                "1" if self.config.elastic_drain else "0",
-            "RAY_TPU_DRAIN_DEADLINE_S":
-                str(self.config.drain_deadline_s),
-            "RAY_TPU_DRAIN_MIGRATE_MAX_BYTES":
-                str(self.config.drain_migrate_max_bytes),
-            "RAY_TPU_SPOT_FALLBACK_THRESHOLD":
-                str(self.config.spot_fallback_threshold),
-            # Head-failover knobs: workers park + re-dial + re-register
-            # across a head restart (the switch and both windows are
-            # read in the worker process).
-            "RAY_TPU_HEAD_FAILOVER":
-                "1" if self.config.head_failover else "0",
-            "RAY_TPU_HEAD_RECONNECT_GRACE_S":
-                str(self.config.head_reconnect_grace_s),
-            "RAY_TPU_HEAD_REREGISTER_TIMEOUT_S":
-                str(self.config.head_reregister_timeout_s),
-            # Failure-detection knobs (gray failures): workers read the
-            # master switch, the wire deadlines/retries, and the
-            # heartbeat period; the head-side suspicion knobs ride too
-            # so a worker-spawned subprocess that becomes a driver sees
-            # one coherent config.
-            "RAY_TPU_FAILURE_DETECTION":
-                "1" if self.config.failure_detection else "0",
-            "RAY_TPU_NET_STALL_TIMEOUT_S":
-                str(self.config.net_stall_timeout_s),
-            "RAY_TPU_NET_CONNECT_TIMEOUT_S":
-                str(self.config.net_connect_timeout_s),
-            "RAY_TPU_NET_RETRY_COUNT":
-                str(self.config.net_retry_count),
-            "RAY_TPU_NET_RETRY_BACKOFF_BASE_MS":
-                str(self.config.net_retry_backoff_base_ms),
-            "RAY_TPU_HEALTH_CHECK_PERIOD_S":
-                str(self.config.health_check_period_s),
-            "RAY_TPU_HEALTH_CHECK_TIMEOUT_S":
-                str(self.config.health_check_timeout_s),
-            "RAY_TPU_HEALTH_CHECK_FAILURE_THRESHOLD":
-                str(self.config.health_check_failure_threshold),
-            "RAY_TPU_HEALTH_CHECK_INITIAL_DELAY_S":
-                str(self.config.health_check_initial_delay_s),
-        }
+        """Every Config field a worker inherits (all but config.HEAD_ONLY),
+        under the environment name ``Config.from_env`` reads back at the
+        worker's import — so _system_config overrides follow into workers.
+        Shared by both spawn paths: agent-spawned workers get the same
+        map."""
+        values = dataclasses.asdict(self.config)
+        return {env_name(k): str(int(v) if isinstance(v, bool) else v)
+                for k, v in values.items() if k not in HEAD_ONLY}
 
     def _spawn_worker(self, node: NodeState, env_key: str,
                       rec: Optional[TaskRecord], tpu_chips) -> WorkerHandle:
@@ -2938,17 +2770,6 @@ class Runtime:
                                     lambda: self._stopped,
                                     "ray_tpu-objconn")
 
-    def _adv_caps(self, caps) -> tuple:
-        """Advertised object-server verbs, with the put verbs withheld
-        while ``direct_puts`` is off — pushers are capability-gated, so
-        not advertising IS the off switch (the legacy put_parts path,
-        byte-identical, every direct-put counter zero)."""
-        caps = tuple(caps or ())
-        if self.config.direct_puts:
-            return caps
-        return tuple(c for c in caps
-                     if c not in object_transfer.PUT_CAPS)
-
     def _accept_loop(self, listener):
         while not self._stopped:
             try:
@@ -2997,8 +2818,7 @@ class Runtime:
                 protocol.send(conn, ("client_ack", self.session_id, {
                     "store_id": self.store_id,
                     "object_addr": self.object_addr,
-                    "object_caps": list(self._adv_caps(
-                        object_transfer.CAPS)),
+                    "object_caps": list(object_transfer.CAPS),
                 }))
                 threading.Thread(target=self._worker_reader,
                                  args=(conn, w), daemon=True,
@@ -3096,27 +2916,20 @@ class Runtime:
                       self.config.memory_monitor_interval_s,
                   "memory_monitor_test_file":
                       self.config.memory_monitor_test_file,
-                  # Failover knobs the agent mirrors (its own env wins
-                  # when explicitly set — the per-node escape hatch):
-                  # keep-workers vs legacy teardown on head EOF, and
-                  # the re-dial grace window.
-                  "head_failover": self.config.head_failover,
+                  # The re-dial grace window the agent mirrors (its own
+                  # env wins when explicitly set — the per-node escape
+                  # hatch).
                   "head_reconnect_grace_s":
                       self.config.head_reconnect_grace_s,
-                  "agent_reconnect": self.config.agent_reconnect,
                   # Elastic pods: the drain verbs this head understands
                   # (the agent gates preempt_notice on membership — an
-                  # old head is never probed) plus the knobs the agent
-                  # mirrors for its self-drain deadline.
-                  "drain_caps": (["preempt_notice", "drain_node"]
-                                 if self.config.elastic_drain else []),
-                  "elastic_drain": self.config.elastic_drain,
+                  # old head is never probed) plus the self-drain
+                  # deadline the agent mirrors.
+                  "drain_caps": ["preempt_notice", "drain_node"],
                   "drain_deadline_s": self.config.drain_deadline_s,
-                  # Failure detection: the agent mirrors the master
-                  # switch and heartbeat cadence (its env wins per
-                  # node) so an off-switch cluster sends zero
-                  # heartbeats and a tuned period applies everywhere.
-                  "failure_detection": self.config.failure_detection,
+                  # The heartbeat cadence the agent mirrors (its env
+                  # wins per node), so a tuned period applies
+                  # everywhere.
                   "health_check_period_s":
                       self.config.health_check_period_s}))
         threading.Thread(target=self._agent_reader, args=(conn, agent),
@@ -3133,12 +2946,10 @@ class Runtime:
         node_hex = info.get("node_id", "")
         with self.lock:
             node = self._node_by_hex_locked(node_hex)
-            refused = node is None or not self.config.head_failover
-        if refused:
+        if node is None:
             # Unknown node (fresh head, no snapshot) or duplicate:
-            # refuse — the worker exits, which is the pre-failover
-            # behavior and the correct one for a cluster that did not
-            # restore.  (Outside the lock: nobody holds this conn yet.)
+            # refuse — the worker exits, which is the correct outcome
+            # for a cluster that did not restore.  (Outside the lock: nobody holds this conn yet.)
             try:
                 protocol.send(conn, ("reregister_nack",))
             except Exception:
@@ -3479,8 +3290,7 @@ class Runtime:
         scheduling class it belongs to)."""
         v1 = bool(opts and opts.get("v")) or rid is None
         cfg = self.config
-        ttl = (cfg.lease_ttl_s
-               if v1 and cfg.decentralized_dispatch else 0.0)
+        ttl = cfg.lease_ttl_s if v1 else 0.0
         slots = min(cfg.lease_slots, cfg.max_tasks_in_flight_per_worker)
 
         def finish():
@@ -3505,12 +3315,11 @@ class Runtime:
                 if failed:
                     self._dispatch_locked()
                 ok = [w for w in granted if w not in failed]
-                if cfg.decentralized_dispatch:
-                    self.lease_grants += len(ok)
-                    if ttl > 0:
-                        expiry = time.monotonic() + ttl
-                        for w in ok:
-                            w.lease_expiry = expiry
+                self.lease_grants += len(ok)
+                if ttl > 0:
+                    expiry = time.monotonic() + ttl
+                    for w in ok:
+                        w.lease_expiry = expiry
                 if v1 and ok:
                     hint = self._spill_hint_locked(ok[0].lease_req or {},
                                                    ok)
@@ -3547,7 +3356,7 @@ class Runtime:
         ("lease_grant", ...) verb — a peer that silently dropped it
         would leak the acquired leases (PR-3 convention: new verbs are
         never sent to a peer that would ignore them)."""
-        if not self.config.decentralized_dispatch or not worker.lease_caps:
+        if not worker.lease_caps:
             return
         elig = [s for s in specs
                 if "actor_id" not in s
@@ -3674,8 +3483,8 @@ class Runtime:
             self._record_creation_span("sched.wait", spec)
             actor = self.actors[rec.actor_id]
             # Restartable-actor checkpointing: the worker arms the
-            # __ray_save__ hook only when recovery is on AND the actor
-            # can actually restart; a retained checkpoint whose home
+            # __ray_save__ hook only when the actor can actually
+            # restart; a retained checkpoint whose home
             # store died with its node is dropped (fresh __init__ beats
             # a restore that can only fail).
             ck = actor.checkpoint
@@ -3683,9 +3492,7 @@ class Runtime:
                     and self._store_is_dead(ck[3]):
                 ck = None
             ck_interval = (self.config.actor_checkpoint_interval_s
-                           if (self.config.recovery
-                               and actor.options.get("max_restarts", 0)
-                               != 0)
+                           if actor.options.get("max_restarts", 0) != 0
                            else None)
             worker.queue_msg(("create_actor", {
                 "task_id": spec["task_id"],
@@ -4013,10 +3820,8 @@ class Runtime:
         # (adoption beats re-creation: state continuity is free).  A
         # snapshot written by a CLEAN shutdown has nothing surviving it
         # — its session's workers/agents/segments were torn down — so
-        # restore is immediate and SHM residue is skipped.  With the
-        # failover switch off, re-registration is refused anyway, so
-        # waiting would only delay the cold restores.
-        wait = (not data.get("clean")) and self.config.head_failover
+        # restore is immediate and SHM residue is skipped.
+        wait = not data.get("clean")
         with self.lock:
             for ns, tbl in data.get("kv", {}).items():
                 self.kv.setdefault(ns, {}).update(tbl)
@@ -4584,11 +4389,8 @@ class Runtime:
             # agent's deadline, then release it with drain_node so the
             # agent exits before the plug pulls.  Off-thread — the drain
             # waits on checkpoints and migration pulls, and this is the
-            # agent's reader thread.  With elastic_drain off the notice
-            # is ignored (the agent also never sends one then: the head
-            # withheld drain_caps in agent_ack) and the node death rides
-            # the legacy hard-kill path with every counter zero.
-            if self.config.elastic_drain and agent.node is not None:
+            # agent's reader thread.
+            if agent.node is not None:
                 with self.lock:
                     self.preemptions += 1
                 threading.Thread(
@@ -4734,7 +4536,7 @@ class Runtime:
                 self.reconstruction_failures += d.get(
                     "reconstruction_failures", 0)
                 # Failure-detection deltas from the worker's deadline
-                # core (zero with the switch off).
+                # core.
                 self.stall_timeouts += d.get("stall_timeouts", 0)
                 self.net_retries += d.get("net_retries", 0)
                 self.hedged_fetches += d.get("hedged_fetches", 0)
@@ -4912,15 +4714,14 @@ class Runtime:
             # replying, which would hang the requester instead.
             _, rid, store_hex = msg
             if store_hex == self.store_id:
-                reply = (self.object_addr,
-                         self._adv_caps(object_transfer.CAPS))
+                reply = (self.object_addr, object_transfer.CAPS)
             else:
                 with self.lock:
                     agent = self._agents.get(store_hex)
                     alive = agent is not None and not agent.dead
                     addr = (agent.info.get("object_addr")
                             if alive else None)
-                    caps = (self._adv_caps(agent.info.get("object_caps"))
+                    caps = (tuple(agent.info.get("object_caps") or ())
                             if alive else ())
                 reply = (addr, caps) if addr else None
             self._queue_send(worker, ("reply", rid, reply))
@@ -4946,7 +4747,8 @@ class Runtime:
             except ValueError:
                 self._queue_send(worker, ("reply", rid, (False, None, None)))
         elif tag == "put_parts":
-            # Legacy client-shipped value: land it in the HEAD's store
+            # Client-shipped value (puts under the direct-put floor,
+            # old-verb clients, push failures): land it in the HEAD's store
             # so any worker can consume it (clients share no /dev/shm).
             # The table entry registers PENDING under the lock here (so
             # later messages on this FIFO see the object), but the
@@ -4956,11 +4758,7 @@ class Runtime:
             _, oid_bin, meta, bufs, nested = msg
             oid = ObjectID(oid_bin)
             with self.lock:
-                if self.config.direct_puts:
-                    # Counted only while the direct path is on: this
-                    # message is then a FALLBACK (old-verb client, push
-                    # failure) worth watching.
-                    self.brokered_put_parts += 1
+                self.brokered_put_parts += 1
                 st = self.objects.get(oid)
                 if st is None:
                     st = self.objects[oid] = ObjectState()
@@ -5305,14 +5103,10 @@ class Runtime:
             # In-band re-registration from a CLIENT that re-dialed after
             # a head restart (its conn-level handshake already ran via
             # client_ready): reconcile its claims — held leases and
-            # re-advertised owned objects.  Gated like the worker path:
-            # with the failover switch off nothing reconciles and every
-            # failover counter stays zero (the client session itself
-            # still works — it re-entered through client_ready).
-            if self.config.head_failover:
-                with self.lock:
-                    self.reregistered_workers += 1
-                    self._apply_reregister_claims_locked(worker, msg[1])
+            # re-advertised owned objects.
+            with self.lock:
+                self.reregistered_workers += 1
+                self._apply_reregister_claims_locked(worker, msg[1])
         elif tag == "resubmit_batch":
             # Failover replay: specs whose fate at the dead head is
             # unknown to the submitter.  At-least-once semantics (the
@@ -5600,18 +5394,12 @@ class Runtime:
             # gained a slot — scan just that shard inline; the global
             # pass runs (deferred) only when the lease actually ends and
             # returns resources anything could use.
-            if self.config.decentralized_dispatch:
-                if worker.lease_key is not None:
-                    self._dispatch_class_locked(worker.lease_key)
-                if not worker.inflight and not worker.dead \
-                        and worker.lease_req is not None:
-                    self._end_lease_locked(worker)
-                    self._request_dispatch_locked()
-            else:
-                self._dispatch_locked()
-                if not worker.inflight and not worker.dead \
-                        and worker.lease_req is not None:
-                    self._end_lease_locked(worker)
+            if worker.lease_key is not None:
+                self._dispatch_class_locked(worker.lease_key)
+            if not worker.inflight and not worker.dead \
+                    and worker.lease_req is not None:
+                self._end_lease_locked(worker)
+                self._request_dispatch_locked()
 
     def _reroute_dead_worker_frees_locked(self, worker: WorkerHandle):
         """A dead worker's buffered free_segment messages would vanish
@@ -5693,8 +5481,7 @@ class Runtime:
                         if not w.dead:
                             self._end_lease_locked(w)
             if worker.client_lease is not None \
-                    and not worker.client_lease.dead \
-                    and self.config.decentralized_dispatch:
+                    and not worker.client_lease.dead:
                 # This worker was leased OUT and died (node death rides
                 # the same path — the agent's death handler drives it):
                 # revoke explicitly so the holder reroutes its pushed
@@ -5773,7 +5560,7 @@ class Runtime:
         replay: List[TaskRecord] = []
         mtr = actor.options.get("max_task_retries", 0)
         for tid_bin, rec in list(actor.inflight.items()):
-            if (will_restart and self.config.recovery and mtr != 0
+            if (will_restart and mtr != 0
                     and (mtr < 0 or rec.retries_left > 0)
                     and not rec.cancelled):
                 if rec.retries_left > 0:
@@ -5789,8 +5576,7 @@ class Runtime:
             if actor.restarts_left > 0:
                 actor.restarts_left -= 1
             actor.status = RESTARTING
-            if self.config.recovery:
-                self.actor_restarts += 1
+            self.actor_restarts += 1
             # Replayed calls go BACK TO THE FRONT in their original send
             # order, ahead of anything queued behind them.
             for rec in reversed(replay):
@@ -6097,8 +5883,7 @@ class Runtime:
             now = time.monotonic()
             dead_pending = []
             with self.lock:
-                if self.config.decentralized_dispatch \
-                        and self.config.lease_ttl_s > 0:
+                if self.config.lease_ttl_s > 0:
                     # Expired client leases: the holder stopped renewing
                     # (died or hung mid-push).  Pushed-task state is
                     # invisible to the head, so the worker is RETIRED,

@@ -163,8 +163,6 @@ class _WorkerRuntime:
         # head requests stay registered in ``pending`` and are replayed
         # verbatim after the re-dial + re-register handshake.  All
         # _conn_down/_head_outbox mutation happens under send_lock.
-        self._failover = os.environ.get("RAY_TPU_HEAD_FAILOVER",
-                                        "1") == "1"
         self._reconnect_grace = float(os.environ.get(
             "RAY_TPU_HEAD_RECONNECT_GRACE_S", "20") or 0)
         self._conn_down = False
@@ -197,7 +195,6 @@ class _WorkerRuntime:
         # the PR-10 reconnect-and-replay machinery already survives.
         from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
 
-        self._fd_on = _cfg.failure_detection
         self._hc_period = _cfg.health_check_period_s
         self._net_stall_t = _cfg.net_stall_timeout_s
         self._last_head_recv = time.monotonic()
@@ -232,9 +229,9 @@ class _WorkerRuntime:
 
     def _send_wire(self, msgs: list):
         """One batched write to the head — MUST be called under
-        send_lock.  On a broken head conn with failover on, the messages
-        PARK in _head_outbox (order preserved) for replay after the
-        reconnect instead of raising: every caller on this path is
+        send_lock.  On a broken head conn the messages PARK in
+        _head_outbox (order preserved) for replay after the reconnect
+        instead of raising: every caller on this path is
         fire-and-forget, and the reader thread drives the re-dial."""
         if not msgs:
             return
@@ -245,19 +242,19 @@ class _WorkerRuntime:
             protocol.send_batch(self.conn, msgs)
             self._last_head_send = time.monotonic()
         except Exception:
-            if not self._failover or self._shutting_down:
+            if self._shutting_down:
                 raise
             self._conn_down = True
             self._head_outbox.extend(msgs)
 
     def dial(self, addr):
-        # Deadline-aware dial (connect timeout + SO_KEEPALIVE when
-        # failure detection is on): direct channels to black-holed
-        # peers fail in net_connect_timeout_s, not the kernel default.
+        # Deadline-aware dial (connect timeout + SO_KEEPALIVE): direct
+        # channels to black-holed peers fail in net_connect_timeout_s,
+        # not the kernel default.
         conn = protocol.dial(tuple(addr),
                              authkey=bytes.fromhex(
                                  os.environ.get("RAY_TPU_AUTHKEY", "")))
-        if self._fd_on and self._net_stall_t > 0:
+        if self._net_stall_t > 0:
             # Send half only: pushes to a stalled executor error the
             # sender into the channel-death reroute; the reader stays
             # fully blocking (an idle channel is not a stalled one).
@@ -518,16 +515,16 @@ class _WorkerRuntime:
         self._hc_probe_sent = 0.0
 
     def heartbeat_and_watchdog(self):
-        """Periodic-flusher hook (failure detection; no-op with the
-        switch off).  Two jobs: (a) the heartbeat FLOOR — a link with
-        no other outgoing traffic for health_check_period_s sends one
-        ("heartbeat", ...) so head-side silence is a signal; (b) the
+        """Periodic-flusher hook (failure detection).  Two jobs: (a) the
+        heartbeat FLOOR — a link with no other outgoing traffic for
+        health_check_period_s sends one ("heartbeat", ...) so head-side
+        silence is a signal; (b) the
         stalled-head WATCHDOG — a pending request older than
         net_stall_timeout_s under total head silence probes with
         hc_ping, and a probe unanswered for another full window closes
         the conn, converting the gray stall into the clean EOF the
         reconnect-and-replay machinery (PR 10) already survives."""
-        if not self._fd_on or self._shutting_down or self._conn_down:
+        if self._shutting_down or self._conn_down:
             return
         now = time.monotonic()
         if self._hc_period > 0 \
@@ -537,9 +534,7 @@ class _WorkerRuntime:
             except Exception:
                 return
         stall_t = self._net_stall_t
-        if stall_t <= 0 or not self._failover:
-            # Without failover the only answer to a stalled head would
-            # be this worker's exit — strictly worse than waiting.
+        if stall_t <= 0:
             return
         with self.pending_lock:
             oldest = min((ent[2] for ent in self.pending.values()),
@@ -615,9 +610,8 @@ class _WorkerRuntime:
     def _reconnect_head(self) -> bool:
         """Reader-thread entry on head-conn EOF: re-dial with backoff
         for the grace window, re-register, then replay pending requests
-        and the parked outbox.  False = give up (caller exits, the
-        pre-failover behavior)."""
-        if not self._failover or self._shutting_down:
+        and the parked outbox.  False = give up (caller exits)."""
+        if self._shutting_down:
             return False
         with self._reconn_lock:
             with self.send_lock:  # noqa: RTL505 -- the reconnect serializer is strictly OUTER to send_lock; no send path takes _reconn_lock
@@ -699,7 +693,7 @@ class _WorkerRuntime:
     def _note_head_spec(self, spec: dict):
         """Retain a head-routed PLAIN spec for failover replay (dropped
         once a return materializes, or FIFO-evicted past the cap)."""
-        if not self._failover or "actor_id" in spec:
+        if "actor_id" in spec:
             return
         with self._spec_lock:
             self._inflight_head_specs[spec["task_id"][:12]] = spec
@@ -1971,8 +1965,7 @@ def worker_entry(conn, worker_id_hex: str, session: str, shm_dir: str,
         on_peer_msg=rt.dispatch_peer_msg, queue_empty=_queue_empty,
         on_task_queued=maybe_prefetch,
         queue_depth=lambda: len(tasks),
-        spill_depth=(_cfg.lease_spillback_depth
-                     if _cfg.decentralized_dispatch else 0),
+        spill_depth=_cfg.lease_spillback_depth,
         spill_info={"node": node_id_hex})
     rt.direct_addr = direct_server.address
 

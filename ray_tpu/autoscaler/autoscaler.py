@@ -64,7 +64,6 @@ class StandardAutoscaler:
         cfg = getattr(runtime, "config", None)
         if cfg is None:
             from ray_tpu._private.config import GLOBAL_CONFIG as cfg
-        self._elastic_drain = bool(getattr(cfg, "elastic_drain", False))
         self._drain_deadline_s = (
             float(drain_deadline_s) if drain_deadline_s is not None
             else float(getattr(cfg, "drain_deadline_s", 10.0)))
@@ -189,19 +188,19 @@ class StandardAutoscaler:
                 pass
 
     def _scale_down(self, nid: str):
-        """Idle scale-down — through the drain protocol when it is on
-        (leases revoked, actors checkpointed, small sole-copy objects
-        migrated, agent released cleanly), with ``terminate_node`` as
-        both the completion and the hard fallback.  The drain runs
-        OFF-THREAD: a reconcile tick must stay reactive (a serve
-        scale-up event cannot wait out a drain deadline), so update()
-        reports the node terminated now and the terminate itself
-        follows the drain's conclusion.  Off-switch
-        (``elastic_drain=False``) is the legacy inline bare terminate."""
+        """Idle scale-down — through the drain protocol (leases revoked,
+        actors checkpointed, small sole-copy objects migrated, agent
+        released cleanly), with ``terminate_node`` as both the
+        completion and the hard fallback.  The drain runs OFF-THREAD: a
+        reconcile tick must stay reactive (a serve scale-up event cannot
+        wait out a drain deadline), so update() reports the node
+        terminated now and the terminate itself follows the drain's
+        conclusion.  A runtime without ``drain_node`` gets the inline
+        bare terminate."""
         # Planned departure: never let _note_preemptions count it.
         self._tracked.pop(nid, None)
         drain = getattr(self._rt, "drain_node", None)
-        if not (self._elastic_drain and drain is not None):
+        if drain is None:
             self.provider.terminate_node(nid)
             return
         self._drains_requested += 1

@@ -4,8 +4,8 @@ The per-file linter (``ray_tpu.devtools.lint``) catches local patterns;
 this tool checks the contracts that span modules — exactly the bug
 classes every review-hardening round since PR 6 has re-found by hand: a
 sent verb whose handler arity drifted, a new verb sent to a peer that
-never advertised the capability, a config knob that reached only one of
-the two worker spawn paths, a counter incremented but never surfaced,
+never advertised the capability, a worker spawn path that stopped
+consuming the derived config env, a counter incremented but never surfaced,
 and lock nesting that contradicts a documented independent-leaf
 convention.  The reference makes these impossible by construction (22
 proto files under ``src/ray/protobuf/``); our contract is tuple literals
@@ -78,14 +78,11 @@ RTL503  capability gating
     any path into it tests caps membership.  Pins the PR 3/6/7 "never
     probe an old peer" convention.
 
-RTL504  knob & counter plumbing
-    A ``Config`` field (every field has a ``RAY_TPU_*`` env alias) that
-    neither rides ``_worker_config_env`` into BOTH spawn paths nor
-    carries a ``# protocheck: head-only -- reason`` /
-    ``# protocheck: env-alias RAY_TPU_X -- reason`` exemption; a spawn
-    path that stopped consuming ``_worker_config_env``; a worker-side
-    xfer-stats counter the head's aggregator drops; an aggregated
-    counter ``transfer_stats()`` never surfaces.
+RTL504  spawn-env & counter plumbing
+    A spawn path that stopped consuming ``_worker_config_env`` (the map
+    itself is derived from ``Config`` and cannot go stale); a
+    worker-side xfer-stats counter the head's aggregator drops; an
+    aggregated counter ``transfer_stats()`` never surfaces.
 
 RTL505  static lock-order inference
     The ``with self.<lock>:`` nesting graph across method bodies (one
@@ -120,9 +117,8 @@ RULES: Dict[str, str] = {
               "module's sender/handler",
     "RTL503": "caps-gated verb sent from a function with no capability "
               "gate on any path into it",
-    "RTL504": "config knob not plumbed through _worker_config_env (or "
-              "exempted), or a stats counter dropped before "
-              "transfer_stats()",
+    "RTL504": "spawn path not consuming _worker_config_env, or a stats "
+              "counter dropped before transfer_stats()",
     "RTL505": "undeclared lock nesting, or a lock acquired under a "
               "documented independent leaf",
 }
@@ -153,11 +149,6 @@ _ROLE_MARK_RE = re.compile(r"#\s*protocheck:\s*role=([a-z_]+)")
 _STANDS_FOR_RE = re.compile(r"#\s*protocheck:\s*stands-for=([a-z_.]+)")
 _LEAF_MARK_RE = re.compile(r"#\s*lock-order:\s*leaf\b")
 _NOQA_RE = re.compile(r"#\s*noqa:\s*([A-Z0-9, ]+)(--\s*(.*))?")
-_HEAD_ONLY_RE = re.compile(
-    r"#\s*protocheck:\s*head-only(\s*--\s*(?P<reason>.*))?")
-_ENV_ALIAS_RE = re.compile(
-    r"#\s*protocheck:\s*env-alias\s+(?P<alias>[A-Z0-9_]+)"
-    r"(\s*--\s*(?P<reason>.*))?")
 
 # A send carrier is any callee whose name smells like a socket write or
 # a message queue (protocol.send/send_batch, _send/_send_wire,
@@ -238,8 +229,8 @@ class _Module:
                         or (os.sep + "tests" + os.sep) in path)
         self.role: Optional[str] = MODULE_ROLES.get(base)
         # Fixtures impersonate special modules: `# protocheck: role=X`
-        # assigns a wire role, `# protocheck: stands-for=config.py`
-        # makes the knob pass treat the file as that module.
+        # assigns a wire role, `# protocheck: stands-for=runtime.py`
+        # makes the RTL504 pass treat the file as that module.
         self.stands_for: Optional[str] = None
         for line in self.lines[:10]:
             m = _ROLE_MARK_RE.search(line)
@@ -652,7 +643,7 @@ class Analysis:
         self.findings = []
         self._check_verbs()
         self._check_caps()
-        self._check_knobs()
+        self._check_spawn_paths()
         self._check_counters()
         self._check_serve_counters()
         self._check_locks()
@@ -861,7 +852,7 @@ class Analysis:
                         f"{s.fn.name if s.fn else '<module>'}() — old "
                         f"peers must never see it (PR 3/6/7 convention)")
 
-    # -- RTL504: knobs + counters ----------------------------------------
+    # -- RTL504: spawn env + counters ------------------------------------
     def _find_module(self, basename: str) -> Optional[_Module]:
         for mod in self.modules:
             if not mod.is_test \
@@ -870,99 +861,22 @@ class Analysis:
                 return mod
         return None
 
-    def _config_fields(self, cfg: _Module):
-        """[(field, line, exemption)] from the Config dataclass;
-        exemption is None, "head-only", or an env-alias string."""
-        out = []
-        for cls in cfg.classes:
-            if cls.name != "Config":
-                continue
-            for stmt in cls.node.body:
-                if not isinstance(stmt, ast.AnnAssign) \
-                        or not isinstance(stmt.target, ast.Name):
-                    continue
-                field = stmt.target.id
-                exempt = None
-                for ln in (stmt.lineno, stmt.lineno - 1):
-                    if not (1 <= ln <= len(cfg.lines)):
-                        continue
-                    text = cfg.lines[ln - 1]
-                    m = _HEAD_ONLY_RE.search(text)
-                    if m:
-                        exempt = ("head-only",
-                                  (m.group("reason") or "").strip(), ln)
-                        break
-                    m = _ENV_ALIAS_RE.search(text)
-                    if m:
-                        exempt = ("env-alias", m.group("alias"), ln)
-                        break
-                out.append((field, stmt.lineno, exempt))
-        return out
-
-    def _worker_env_keys(self, rt: _Module):
-        """String keys of the dict literal(s) inside
-        _worker_config_env, with the def's line for anchoring."""
-        keys: Set[str] = set()
-        line = None
-        for fn in rt.fns:
-            if fn.name != "_worker_config_env":
-                continue
-            line = fn.node.lineno
-            for sub in ast.walk(fn.node):
-                if isinstance(sub, ast.Dict):
-                    for k in sub.keys:
-                        if isinstance(k, ast.Constant) \
-                                and isinstance(k.value, str):
-                            keys.add(k.value)
-        return keys, line
-
-    def _check_knobs(self):
-        cfg = self._find_module("config.py")
+    def _check_spawn_paths(self):
+        """Both spawn paths must consume _worker_config_env."""
         rt = self._find_module("runtime.py")
-        if cfg is None or rt is None:
+        if rt is None \
+                or not any(fn.name == "_worker_config_env"
+                           for fn in rt.fns):
             return
-        env_keys, env_line = self._worker_env_keys(rt)
-        if env_line is None:
-            return
-        # Both spawn paths must consume _worker_config_env.
-        for spawn in ("_spawn_worker", "_spawn_worker_via_agent"):
-            fns = [fn for fn in rt.fns if fn.name == spawn]
-            for fn in fns:
-                if "_worker_config_env" not in fn.calls:
-                    self._emit(
-                        rt.path, fn.node.lineno, fn.node.col_offset,
-                        "RTL504",
-                        f"spawn path {spawn}() does not consume "
-                        f"_worker_config_env() — knobs will reach only "
-                        f"the other spawn path")
-        for field, line, exempt in self._config_fields(cfg):
-            canonical = "RAY_TPU_" + field.upper()
-            if canonical in env_keys:
-                continue
-            if exempt is not None:
-                kind, value, mline = exempt
-                if kind == "head-only":
-                    if not value:
-                        self._emit(cfg.path, mline, 0, "RTL500",
-                                   f"head-only exemption for {field!r} "
-                                   f"carries no '-- reason' tail")
-                    continue
-                if kind == "env-alias":
-                    if value in env_keys:
-                        continue
-                    self._emit(
-                        cfg.path, line, 0, "RTL504",
-                        f"config field {field!r} declares env-alias "
-                        f"{value} but _worker_config_env "
-                        f"(runtime.py:{env_line}) does not ship it")
-                    continue
-            self._emit(
-                cfg.path, line, 0, "RTL504",
-                f"config field {field!r} (env RAY_TPU_{field.upper()}) "
-                f"does not ride _worker_config_env "
-                f"(runtime.py:{env_line}) into the worker spawn paths — "
-                f"plumb it, or mark it '# protocheck: head-only -- "
-                f"reason' / '# protocheck: env-alias RAY_TPU_X'")
+        for fn in rt.fns:
+            if fn.name in ("_spawn_worker", "_spawn_worker_via_agent") \
+                    and "_worker_config_env" not in fn.calls:
+                self._emit(
+                    rt.path, fn.node.lineno, fn.node.col_offset,
+                    "RTL504",
+                    f"spawn path {fn.name}() does not consume "
+                    f"_worker_config_env() — knobs will reach only "
+                    f"the other spawn path")
 
     def _check_counters(self):
         rt = self._find_module("runtime.py")

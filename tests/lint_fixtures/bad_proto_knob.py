@@ -1,17 +1,28 @@
-# protocheck: stands-for=config.py
-# protocheck-with: bad_proto_knob_peer.py
-"""RTL504 bad fixture (config half): a worker-relevant knob that rides
-neither _worker_config_env nor an exemption marker.  The companion
-stands for runtime.py."""
-
-import dataclasses
+# protocheck: stands-for=runtime.py
+"""RTL504 bad fixture: the agent spawn path stopped
+consuming _worker_config_env, and a counter aggregated from worker
+deltas never reaches transfer_stats()."""
 
 
-@dataclasses.dataclass
-class Config:
-    lease_slots: int = 8  # EXPECT: RTL504
-    object_pool_size: int = 4
-    # protocheck: head-only -- the idle-worker reaper runs in the head
-    idle_worker_timeout_s: float = 300.0
-    # protocheck: head-only  # EXPECT: RTL500
-    prestart_workers: int = 0
+class RuntimeLike:
+    def _worker_config_env(self):
+        return {"RAY_TPU_OBJECT_POOL_SIZE": "4"}
+
+    def _spawn_worker(self):
+        env = {}
+        env.update(self._worker_config_env())
+        return env
+
+    def _spawn_worker_via_agent(self):  # EXPECT: RTL504
+        overrides = {}
+        return overrides
+
+    def _handle(self, msg):
+        tag = msg[0]
+        if tag == "xfer_stats":
+            d = msg[1]
+            self.deduped_pulls += d.get("deduped_pulls", 0)
+            self.spillbacks += d.get("spillbacks", 0)  # EXPECT: RTL504
+
+    def transfer_stats(self):
+        return {"deduped_pulls": self.deduped_pulls}

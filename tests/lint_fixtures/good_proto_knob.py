@@ -1,16 +1,33 @@
-# protocheck: stands-for=config.py
-# protocheck-with: good_proto_knob_peer.py
-"""RTL504 good fixture (config half): every field is plumbed, aliased,
-or exempted with a reason."""
-
-import dataclasses
+# protocheck: stands-for=runtime.py
+"""RTL504 good fixture: both spawn paths consume
+_worker_config_env, and every aggregated counter is surfaced."""
 
 
-@dataclasses.dataclass
-class Config:
-    lease_slots: int = 8
-    object_pool_size: int = 4
-    # protocheck: head-only -- the idle-worker reaper runs in the head
-    idle_worker_timeout_s: float = 300.0
-    # protocheck: env-alias RAY_TPU_POOL_BYTES -- legacy spelling
-    shm_pool_bytes: int = 1
+class RuntimeLike:
+    def _worker_config_env(self):
+        return {
+            "RAY_TPU_LEASE_SLOTS": "8",
+            "RAY_TPU_OBJECT_POOL_SIZE": "4",
+            "RAY_TPU_POOL_BYTES": "1",
+        }
+
+    def _spawn_worker(self):
+        env = {}
+        env.update(self._worker_config_env())
+        return env
+
+    def _spawn_worker_via_agent(self):
+        overrides = {}
+        overrides.update(self._worker_config_env())
+        return overrides
+
+    def _handle(self, msg):
+        tag = msg[0]
+        if tag == "xfer_stats":
+            d = msg[1]
+            self.deduped_pulls += d.get("deduped_pulls", 0)
+            self.spillbacks += d.get("spillbacks", 0)
+
+    def transfer_stats(self):
+        return {"deduped_pulls": self.deduped_pulls,
+                "spillbacks": self.spillbacks}

@@ -144,39 +144,6 @@ def test_chaos_acceptance_fanout_survives_worker_and_agent_kill():
         c.shutdown()
 
 
-def test_chaos_acceptance_recovery_off_reproduces_loss():
-    """Same shape with recovery=off: the agent kill surfaces the legacy
-    ObjectLostError and every recovery counter stays zero."""
-    from ray_tpu.cluster_utils import Cluster
-
-    c = Cluster(head_num_cpus=0, _system_config={"recovery": False})
-    try:
-        n1 = c.add_node(num_cpus=2, external=True)
-        n2 = c.add_node(num_cpus=2, external=True)
-        s1 = [_stage1.options(scheduling_strategy=NA(
-            node_id=n2, soft=True)).remote(i) for i in range(8)]
-        ray.wait(s1, num_returns=len(s1), timeout=60)
-        c.kill_agent(n2)  # not via the controller: counters must stay 0
-        time.sleep(0.5)
-        # The legacy failure shape: the loss surfaces — either directly
-        # (driver-side pull) or as the consumer task's failure cause
-        # (executor-side arg fetch).
-        with pytest.raises((ray.exceptions.ObjectLostError,
-                            ray.exceptions.TaskError)) as ei:
-            ray.get([_stage2.remote(r) for r in s1], timeout=60)
-        err = ei.value
-        assert isinstance(err, ray.exceptions.ObjectLostError) or \
-            isinstance(getattr(err, "cause", None),
-                       ray.exceptions.ObjectLostError) or \
-            "ObjectLostError" in str(err)
-        stats = c.rt.transfer_stats()
-        for k in ("reconstructions", "reconstruction_failures",
-                  "actor_restarts", "chaos_kills"):
-            assert stats[k] == 0, (k, stats[k])
-    finally:
-        c.shutdown()
-
-
 # ------------------------------------------------- env-rule chaos kills --
 
 def test_env_rule_kills_worker_mid_striped_pull():

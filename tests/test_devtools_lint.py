@@ -4,8 +4,8 @@ IDs and line numbers, suppression syntax, and the CLI contract.
 The EXPECT harness covers ALL THREE analyzers: per-file lint findings,
 whole-program protocheck findings (a proto fixture names its companion
 modules with `# protocheck-with: other.py`, so the two-module cases —
-sender/handler arity drift, knob plumbing — analyze as one program with
-findings attributed per file), and lockgraph's interprocedural RTL6xx
+sender/handler arity drift — analyze as one program with findings
+attributed per file), and lockgraph's interprocedural RTL6xx
 verdicts over the same file set."""
 
 import os

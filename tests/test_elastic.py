@@ -6,9 +6,7 @@ Reference pattern: the DrainNode protocol tests + chaos release jobs —
 a planned departure (scale-down, spot warning window) must lose nothing
 (leases revoked, restartable actors checkpointed to a surviving store,
 small sole-copy objects migrated), while a no-warning kill falls back
-to PR 9's lineage reconstruction.  The off-switch (``elastic_drain=
-False``) must reproduce the legacy hard-remove behavior with every new
-counter zero.
+to PR 9's lineage reconstruction.
 """
 
 import time
@@ -274,49 +272,12 @@ def test_scale_down_routes_through_drain():
         c.shutdown()
 
 
-def test_elastic_drain_off_is_legacy_hard_remove():
-    """The off-switch: scale-down is a bare terminate_node, drain_node
-    refuses, a preemption notice is never solicited (the head withholds
-    drain_caps) — and every elastic counter stays zero."""
-    c = Cluster(head_num_cpus=2,
-                _system_config={"elastic_drain": False})
-    try:
-        provider = FakeSliceProvider(c, {
-            "v5e": {"resources": {"CPU": 2, "slice": 1},
-                    "max_workers": 1},
-        })
-        scaler = StandardAutoscaler(c.rt, provider, idle_timeout_s=0.5)
-
-        @ray.remote(resources={"slice": 0.5})
-        def f():
-            return "ok"
-
-        ref = f.remote()
-        time.sleep(0.2)
-        (nid,) = scaler.update()["launched"]
-        assert ray.get(ref, timeout=120) == "ok"
-        assert c.rt.drain_node(nid) is False  # switched off: refuses
-        gone = []
-        deadline = time.monotonic() + 30
-        while time.monotonic() < deadline and not gone:
-            gone = scaler.update()["terminated"]
-            time.sleep(0.3)
-        assert gone == [nid]
-        assert _wait_for(lambda: _one_head_node(c.rt))
-        st = c.rt.transfer_stats()
-        for k in ELASTIC_KEYS:
-            assert st[k] == 0, (k, st[k])
-        assert scaler.stats()["drains_requested"] == 0
-    finally:
-        c.shutdown()
-
-
 def test_elastic_knobs_ride_worker_env():
     """_system_config elastic knobs reach spawned workers through
-    _worker_config_env (both spawn paths share it; RTL504 pins the
-    plumbing statically, this pins it live)."""
+    _worker_config_env (both spawn paths share it; test_config_env.py
+    pins the map for every field, this pins it live)."""
     ray.init(num_cpus=1, _system_config={
-        "elastic_drain": False, "drain_deadline_s": 3.5,
+        "drain_deadline_s": 3.5,
         "drain_migrate_max_bytes": 123456,
         "spot_fallback_threshold": 7})
     try:
@@ -324,13 +285,12 @@ def test_elastic_knobs_ride_worker_env():
         def probe():
             import os
 
-            return (os.environ.get("RAY_TPU_ELASTIC_DRAIN"),
-                    os.environ.get("RAY_TPU_DRAIN_DEADLINE_S"),
+            return (os.environ.get("RAY_TPU_DRAIN_DEADLINE_S"),
                     os.environ.get("RAY_TPU_DRAIN_MIGRATE_MAX_BYTES"),
                     os.environ.get("RAY_TPU_SPOT_FALLBACK_THRESHOLD"))
 
         assert ray.get(probe.remote(), timeout=60) == (
-            "0", "3.5", "123456", "7")
+            "3.5", "123456", "7")
     finally:
         ray.shutdown()
 

@@ -11,10 +11,8 @@ under sustained task + serve traffic crosses a hard head kill
   checkpoint for one that died with the head — NOT a fresh __init__),
 - traffic stalling for a bounded window rather than failing,
 
-plus the reconnect-off control (``RAY_TPU_AGENT_RECONNECT=0`` keeps
-today's kill-workers outage with every failover counter zero), the
-head-role chaos env rules, knob env-plumbing through both worker spawn
-paths, and the battery's lockcheck re-run.
+plus the head-role chaos env rules, knob env-plumbing through both
+worker spawn paths, and the battery's lockcheck re-run.
 
 Reference analog: GCS failover — redis-backed table persistence
 (redis_store_client.h:28), GcsInitData load (gcs_server.h:77), and
@@ -202,66 +200,28 @@ def test_head_failover_acceptance_live_cluster():
 
 
 def test_cold_restore_named_actor_from_checkpoint():
-    """An actor whose worker DIES WITH THE HEAD (head-hosted, worker
-    reconnect disabled) is re-created by the restarted head from its
-    retained ``__ray_save__`` checkpoint — state continues, __init__'s
-    fresh state does not win."""
+    """An actor whose worker DIES WITH THE HEAD (killed alongside it,
+    so nothing re-claims the incarnation) is re-created by the restarted
+    head from its retained ``__ray_save__`` checkpoint — state
+    continues, __init__'s fresh state does not win."""
     c = Cluster(external_head=True, head_num_cpus=2,
-                _system_config={"head_failover": False})
+                _system_config={"head_reregister_timeout_s": 2.0})
     try:
         cnt = _Counter.options(name="ck").remote()
         assert ray.get([cnt.incr.remote() for _ in range(3)],
                        timeout=60) == [1, 2, 3]
+        actor_pid = ray.get(cnt.pid.remote(), timeout=60)
         time.sleep(0.8)  # checkpoint + snapshot both land
         c.kill_head()
+        os.kill(actor_pid, 9)
         c.restart_head()
-        # head_failover=False on the head side killed its workers with
-        # it; this CLIENT still reconnects (its own switch is on).
+        # Nothing re-claims the actor inside the re-register window;
+        # this CLIENT reconnects on its own.
         cnt2 = ray.get_actor("ck")
         # 4, not 1: __ray_restore__ ran over the fresh __init__.
         assert ray.get(cnt2.incr.remote(), timeout=90) == 4
         stats = c.rt.transfer_stats()
         assert stats["adopted_actors"] == 0, stats  # cold path, not adoption
-    finally:
-        c.shutdown()
-
-
-def test_reconnect_off_reproduces_outage_with_zero_counters():
-    """The escape hatch: RAY_TPU_AGENT_RECONNECT=0 keeps today's
-    behavior — the agent tears its workers down on head death and never
-    returns, so the restarted head sees an empty cluster and every
-    failover counter stays zero."""
-    c = Cluster(external_head=True, head_num_cpus=0)
-    try:
-        nid = c.add_node(num_cpus=2, external=True,
-                         env_overrides={"RAY_TPU_AGENT_RECONNECT": "0"})
-        _v, worker_pid = ray.get(_double.remote(21), timeout=60)
-        agent_proc = c._agents[nid]
-        # Detach the client FIRST: this run drills the agent-side
-        # outage, and a fresh client against the restarted head must
-        # see zero failover counters.
-        ray.shutdown()
-        c.kill_head()
-        # Agent exits on its own (reconnect off) and its worker dies
-        # with it — today's outage.
-        agent_proc.wait(timeout=30)
-        deadline = time.time() + 15
-        while time.time() < deadline:
-            try:
-                os.kill(worker_pid, 0)
-            except OSError:
-                break
-            time.sleep(0.1)
-        else:
-            raise AssertionError("worker survived reconnect-off outage")
-        c.restart_head()
-        c.rt = ray.init(address=c._head_address,
-                        _authkey=c._authkey_hex)
-        assert all(not n["alive"] or n["labels"].get("head")
-                   for n in c.rt.list_nodes())
-        stats = c.rt.transfer_stats()
-        for k in FAILOVER_COUNTERS:
-            assert stats[k] == 0, (k, stats)
     finally:
         c.shutdown()
 
@@ -312,7 +272,6 @@ def test_failover_knob_env_plumbing_both_spawn_paths():
     subprocess and agent-forked), with every failover counter zero in a
     blip-free run."""
     c = Cluster(head_num_cpus=2, _system_config={
-        "head_failover": False,
         "head_reconnect_grace_s": 7.25,
         "head_reregister_timeout_s": 3.5,
     })
@@ -326,10 +285,10 @@ def test_failover_knob_env_plumbing_both_spawn_paths():
         def probe():
             from ray_tpu._private.config import GLOBAL_CONFIG as cfg
 
-            return (cfg.head_failover, cfg.head_reconnect_grace_s,
+            return (cfg.head_reconnect_grace_s,
                     cfg.head_reregister_timeout_s)
 
-        expected = (False, 7.25, 3.5)
+        expected = (7.25, 3.5)
         # Head-local spawn path.
         assert ray.get(probe.options(scheduling_strategy=NA(
             node_id=c.rt.head_node.node_id.hex(), soft=False)).remote(),

@@ -16,9 +16,6 @@ import pytest
 import ray_tpu as ray
 from ray_tpu._private import api_internal
 
-NEW_COUNTERS = ("lease_grants", "leased_submits", "spillbacks",
-                "lease_revocations", "head_brokered_submits")
-
 
 def _settled_stats(rt, timeout=6.0):
     """transfer_stats once the periodic worker deltas stop changing."""
@@ -126,44 +123,6 @@ def test_acceptance_head_brokered_stays_flat_under_fanin():
         ray.shutdown()
 
 
-def test_decentralized_off_zero_counters_and_knob_env_plumbing():
-    """The off switch, in one cluster boot: (a) a multi-client fan-in
-    runs entirely head-brokered with every decentralized-dispatch
-    counter pinned at zero; (b) the PR-5 contract for the new knobs —
-    _system_config overrides reach spawned workers through the
-    RAY_TPU_* env namespace (both spawn paths share
-    _worker_config_env), so a worker's GLOBAL_CONFIG agrees with the
-    driver's switch."""
-    ray.init(num_cpus=8, _system_config={
-        "decentralized_dispatch": False,
-        "lease_slots": 3,
-        "lease_ttl_s": 7.5,
-        "lease_renew_tasks": 17,
-        "lease_spillback_depth": 9,
-    })
-    rt = api_internal.get_runtime()
-    try:
-        assert rt.config.decentralized_dispatch is False
-        clients = [_Client.remote() for _ in range(3)]
-        assert ray.get([c.burst.remote(40) for c in clients]) == [40] * 3
-        stats = _settled_stats(rt)
-        zeros = {k: stats[k] for k in NEW_COUNTERS}
-        assert all(v == 0 for v in zeros.values()), zeros
-
-        @ray.remote
-        def probe():
-            from ray_tpu._private.config import GLOBAL_CONFIG as cfg
-
-            return (cfg.decentralized_dispatch, cfg.lease_slots,
-                    cfg.lease_ttl_s, cfg.lease_renew_tasks,
-                    cfg.lease_spillback_depth)
-
-        assert ray.get(probe.remote(), timeout=60) == \
-            (False, 3, 7.5, 17, 9)
-    finally:
-        ray.shutdown()
-
-
 @pytest.mark.slow  # the slots bound keeps its tier-1 representative in
                    # the renewal unit test below (stub-host, sub-second);
                    # this adds only the in-cluster sampling geometry
@@ -239,7 +198,6 @@ def test_renewal_batches_one_message_per_n_pushes(monkeypatch):
     from ray_tpu._private.config import GLOBAL_CONFIG
     from ray_tpu._private.ids import new_task_id
 
-    monkeypatch.setattr(GLOBAL_CONFIG, "decentralized_dispatch", True)
     monkeypatch.setattr(GLOBAL_CONFIG, "lease_ttl_s", 30.0)
     monkeypatch.setattr(GLOBAL_CONFIG, "lease_renew_tasks", 4)
 

@@ -13,8 +13,6 @@ Covered:
 - the acceptance micro: a fan-out whose single large arg is homed on one
   node agent schedules >= 80% of tasks onto that node (``locality_hits``)
   and ``locality_bytes_saved`` records the avoided transfers;
-- with ``locality_scheduling`` off, placement is the pre-PR head-first
-  order and every locality counter stays zero;
 - locality preference never bypasses ``max_tasks_in_flight_per_worker``:
   past the depth cap the spill-over tasks place normally (counted in
   ``locality_misses``);
@@ -92,22 +90,6 @@ def test_locality_fanout_prefers_home_node(cluster_factory):
         (c.rt.locality_hits, base_hits)
     saved = c.rt.locality_bytes_saved - base_saved
     assert saved >= int(n * 0.8) * (ARG_MB << 20), saved
-
-
-def test_locality_off_is_head_first_and_counters_zero(cluster_factory):
-    c = cluster_factory(head_num_cpus=4,
-                        _system_config={"locality_scheduling": False})
-    n1 = c.add_node(num_cpus=2, external=True)
-    ref = _home_big_arg(n1, ARG_MB << 20)
-
-    head_id = c.rt.head_node.node_id.hex()
-    # Pre-PR behavior: head-first packing — a burst within the head's
-    # capacity lands entirely on the head, args pulled across the wire.
-    nodes = ray.get([_where.remote(ref) for _ in range(4)], timeout=120)
-    assert nodes.count(head_id) == 4, nodes
-    assert c.rt.locality_hits == 0
-    assert c.rt.locality_misses == 0
-    assert c.rt.locality_bytes_saved == 0
 
 
 # -------------------------------------------- depth-cap interaction ------

@@ -489,7 +489,6 @@ def test_lineage_table_lock_is_leaf(checker):
     try:
         rt = api_internal.get_runtime()
         assert isinstance(rt.lineage._lock, lockcheck._LockProxy)
-        assert rt.config.recovery
 
         @ray.remote
         def f(x):
@@ -564,7 +563,6 @@ def test_dispatch_shard_dirty_lock_convention(checker):
     try:
         rt = api_internal.get_runtime()
         assert isinstance(rt._dispatch_dirty_lock, lockcheck._LockProxy)
-        assert rt.config.decentralized_dispatch
 
         @ray.remote
         def f(x):

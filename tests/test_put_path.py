@@ -28,9 +28,7 @@ Covered here:
   legacy whole-value-through-one-control-message baseline;
 - cluster: one large client put produces O(1) control-plane messages at
   the head (exactly one ``put_commit``, zero ``put_parts``) with
-  ``direct_puts``/``direct_put_bytes`` counted; ``direct_puts=off``
-  reproduces the legacy path with every new counter zero, and the knobs
-  follow ``_system_config`` into spawned workers;
+  ``direct_puts``/``direct_put_bytes`` counted;
 - the concurrent multi-client put battery re-run under the lockcheck
   instrumentation with zero lock-order cycles.
 """
@@ -647,49 +645,6 @@ def test_one_direct_put_is_o1_control_messages():
             counts = {tag: s[0] for tag, s in rt._handler_stats.items()}
         assert counts.get("put_commit", 0) == 1, counts
         assert counts.get("put_parts", 0) == 0, counts
-    finally:
-        ray.shutdown()
-
-
-def test_direct_puts_off_restores_legacy_with_zero_counters():
-    """Master switch off: the client put rides the legacy put_parts
-    path (the head never advertises the put verbs, so the client never
-    sends one), completes, and EVERY new counter stays zero.  The knobs
-    follow _system_config into spawned workers via the env namespace."""
-    import ray_tpu as ray
-    from ray_tpu._private import api_internal
-
-    ray.init(num_cpus=2, _system_config={
-        "direct_puts": False,
-        "object_put_stripe_threshold": 12345,
-        "object_put_pool_size": 7,
-    })
-    try:
-        rt = api_internal.get_runtime()
-
-        @ray.remote
-        def probe():
-            import os
-
-            return (os.environ.get("RAY_TPU_DIRECT_PUTS"),
-                    os.environ.get("RAY_TPU_OBJECT_PUT_STRIPE_THRESHOLD"),
-                    os.environ.get("RAY_TPU_OBJECT_PUT_POOL_SIZE"))
-
-        assert ray.get(probe.remote(), timeout=60) == \
-            ("0", "12345", "7")
-        p = subprocess.run([sys.executable, "-c", _CLIENT_PUT_SCRIPT],
-                           env=_client_env(rt), capture_output=True,
-                           text=True, timeout=180)
-        assert p.returncode == 0, p.stderr[-3000:]
-        assert "CLIENT_PUT_OK" in p.stdout
-        stats = rt.transfer_stats()
-        assert stats["direct_puts"] == 0, stats
-        assert stats["direct_put_bytes"] == 0, stats
-        assert stats["brokered_put_parts"] == 0, stats
-        with rt._handler_stats_lock:
-            counts = {tag: s[0] for tag, s in rt._handler_stats.items()}
-        assert counts.get("put_parts", 0) >= 1, counts
-        assert counts.get("put_commit", 0) == 0, counts
     finally:
         ray.shutdown()
 

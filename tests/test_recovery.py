@@ -2,9 +2,7 @@
 (head-owned and worker-owned objects, recursive arg rebuilds, depleted
 retries, byte-budget eviction), the system-vs-application retry split
 (``retry_exceptions=``), restartable actors with ``__ray_save__``/
-``__ray_restore__`` checkpoint hooks and ``max_task_retries`` replay,
-and the ``recovery=off`` switch (legacy ObjectLostError, every new
-counter zero).
+``__ray_restore__`` checkpoint hooks and ``max_task_retries`` replay.
 
 Reference analogs: ``python/ray/tests/test_reconstruction*.py``,
 ``test_actor_failures.py`` (checkpointing), ``test_task_retries``.
@@ -23,9 +21,6 @@ from ray_tpu._private import recovery
 from ray_tpu.util.scheduling_strategies import (
     NodeAffinitySchedulingStrategy as NA,
 )
-
-RECOVERY_COUNTERS = ("reconstructions", "reconstruction_failures",
-                     "actor_restarts", "chaos_kills")
 
 
 @pytest.fixture
@@ -417,43 +412,6 @@ def test_actor_inflight_fails_without_task_retries(ray_start_regular):
         ray.get(fut, timeout=30)
     # ...but the actor itself restarted and serves new calls.
     assert ray.get(c.pid.remote(), timeout=30) != pid
-
-
-# ----------------------------------------------------- off switch + env --
-
-def test_recovery_off_is_legacy_loss_with_zero_counters():
-    from ray_tpu.cluster_utils import Cluster
-
-    c = Cluster(head_num_cpus=2, _system_config={"recovery": False})
-    try:
-        n1 = c.add_node(num_cpus=2, external=True)
-
-        @ray.remote
-        def probe():
-            return (os.environ.get("RAY_TPU_RECOVERY"),
-                    os.environ.get("RAY_TPU_LINEAGE_BYTES_BUDGET"),
-                    os.environ.get("RAY_TPU_ACTOR_CHECKPOINT_INTERVAL_S"))
-
-        # Knob plumbing reaches agent-spawned workers too.
-        env = ray.get(probe.options(
-            scheduling_strategy=NA(node_id=n1)).remote(), timeout=30)
-        assert env[0] == "0" and env[1] and env[2] is not None
-
-        ref = _make.options(
-            scheduling_strategy=NA(node_id=n1, soft=True)).remote(
-                2_000_000)
-        ray.wait([ref], num_returns=1, timeout=30)
-        cluster_stats = c.rt.transfer_stats()
-        c.kill_agent(n1)
-        time.sleep(0.5)
-        with pytest.raises(ray.exceptions.ObjectLostError):
-            ray.get(ref, timeout=30)
-        stats = c.rt.transfer_stats()
-        for k in RECOVERY_COUNTERS:
-            assert stats[k] == 0, (k, stats[k])
-            assert cluster_stats[k] == 0
-    finally:
-        c.shutdown()
 
 
 def test_put_only_objects_stay_unrecoverable_and_count(cluster):
