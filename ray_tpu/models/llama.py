@@ -254,7 +254,7 @@ def _checkpoint(layer_fn):
     pass recomputes the layer from its input, except the few residuals
     that are dear to recompute and cheap to hold, named where they are
     made — the flash kernel's output and log-sum-exp, and an expert
-    layer's sorted rows with their indices.  A layer that never produces
+    layer's row index (its sorts' results).  A layer that never produces
     a name (reference attention, a dense FFN) saves nothing under it."""
     return jax.checkpoint(
         layer_fn, policy=jax.checkpoint_policies.save_only_these_names(

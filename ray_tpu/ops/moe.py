@@ -9,11 +9,20 @@ imbalance: nothing has a capacity and nothing is dropped.  The layer
   softmax, ``lax.top_k`` (renormalised only where the model says so), the
   load-balancing loss over ALL the choices and the router z-loss;
 - ``moe_dispatch``: a stable sort of the ``T * k`` (token, choice) pairs
-  by expert, group sizes by ``bincount``, a gather to ``(T * k, d)`` rows;
+  by expert that carries each pair's gate along, its inverse by a second
+  sort, a gather to ``(T * k, d)`` rows;
 - ``moe_experts``: three grouped products over the ragged groups (row
   ``r`` meets the weights of the group it lies in) and SwiGLU;
 - ``moe_combine``: each token's rows gathered back, weighted by its
   gates, summed, and added to the residual.
+
+Rows move by gathers in both directions, forward and backward, and every
+gather promises that its indices are in range (``_row_index`` says why
+they are), so none is followed by a pass that fills what was not.  Single
+elements (a gate to its row, a gate's gradient back, a row's number to
+its slot, a choice to its expert's count) are never gathered, scattered
+or added one at a time: they ride in a sort, or are counted by a compare
+and a sum.
 
 All shapes are static (``T * k`` rows, rounded up to a row tile); a group
 may be empty or hold every row.  The grouped product is two Pallas
@@ -81,8 +90,13 @@ def _fit_columns(n: int, rows: int, itemsize: int, budget: int) -> int:
 
 
 # The residuals a layer checkpoint keeps (``models/llama.py`` hands these
-# names to its policy), named in ``moe_block``'s dispatch.
-SAVED_RESIDUALS = ("moe_rows", "moe_row_index")
+# names to its policy), named in ``moe_block``'s dispatch: the row index,
+# a megabyte of integers and gates that three sorts made.  Not the sorted
+# rows themselves: gathering them again costs less than holding them
+# (PERF.md §6, PR 28: a kept value is rounded to its own precision in a
+# pass of its own behind the gather and copied into the scan's stack and
+# out, 537 MB each way a layer; the gather is 0.83 ms).
+SAVED_RESIDUALS = ("moe_row_index",)
 
 
 class Schedule(NamedTuple):
@@ -298,13 +312,52 @@ grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 # Dispatch and combine are gathers both ways: the transpose of "row r
 # reads token t" is "token t reads its k rows", so neither direction
-# scatters (a TPU scatter-add serialises; a row gather streams).
+# scatters (a TPU scatter-add serialises; a row gather streams).  Every
+# index is in range by construction (``_row_index``) and says so: a
+# gather that may be out of range is followed by a pass over its whole
+# result that fills the rows that were.
+
+def _take(a, index):
+    """``a[index]`` along axis 0, for indices that ARE in range."""
+    return a.at[index].get(mode="promise_in_bounds")
+
+
+def _place(values, where):
+    """``out[where[i]] = values[i]`` for a permutation ``where``: one
+    sort of (where, values) pairs.  A gather or scatter of single
+    elements moves them one at a time, seven times slower at the
+    benchmark's 131072 (PERF.md §6, PR 28)."""
+    return jax.lax.sort((where, values), num_keys=1)[1]
+
+
+def _row_index(flat, gates, rows):
+    """The row index of ``flat (T * k,)``, the group of each (token,
+    choice), sorted stably into ``rows >= T * k`` rows: ``row_token``,
+    ``row_slot (rows,)`` the flat (token, choice) of each row
+    (``row_token = row_slot // k``), ``slot_row (T, k)`` the row of each
+    (token, choice), and ``row_gate (rows,)`` each row's gate, which
+    rides in the sort (and carries no gradient: ``_combine`` returns the
+    gates').  ``row_slot[:T * k]`` and ``slot_row`` are permutations of
+    ``0 .. T * k - 1`` and each other's inverse.  The rows past ``T * k``
+    name (token 0, choice 0) at gate 0: any index in range will do, for
+    they lie past the groups' sum, where the schedule's pseudo-group
+    zero-fills what the kernels write and masks what they read."""
+    n, k = flat.shape[0], gates.shape[1]
+    slots = jnp.arange(n, dtype=jnp.int32)
+    _, order, row_gate = jax.lax.sort(
+        (flat, slots, jax.lax.stop_gradient(gates).reshape(-1)),
+        num_keys=1, is_stable=True)
+    row_slot = jnp.pad(order, (0, rows - n))
+    slot_row = _place(slots, order)
+    return (row_slot // k, row_slot, slot_row.reshape(-1, k),
+            jnp.pad(row_gate, (0, rows - n)))
+
 
 @jax.custom_vjp
 def _dispatch(x, row_token, slot_row):
     """``x (T, d)`` -> rows ``(M, d)``: row ``r`` is ``x[row_token[r]]``;
     ``slot_row (T, k)`` is the row of each (token, choice)."""
-    return jnp.take(x, row_token, axis=0)
+    return _take(x, row_token)
 
 
 def _dispatch_fwd(x, row_token, slot_row):
@@ -312,8 +365,7 @@ def _dispatch_fwd(x, row_token, slot_row):
 
 
 def _dispatch_bwd(slot_row, d_rows):
-    d_x = jnp.sum(jnp.take(d_rows, slot_row, axis=0).astype(jnp.float32),
-                  axis=1)
+    d_x = jnp.sum(_take(d_rows, slot_row).astype(jnp.float32), axis=1)
     return d_x.astype(d_rows.dtype), None, None
 
 
@@ -321,28 +373,30 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(y_rows, gates, row_token, row_slot, slot_row):
+def _combine(y_rows, gates, row_token, row_slot, slot_row, row_gate):
     """``out[t] = sum_j gates[t, j] * y_rows[slot_row[t, j]]`` in
-    float32; ``row_slot (M,)`` is the flat (token, choice) of each row."""
-    picked = jnp.take(y_rows, slot_row, axis=0).astype(jnp.float32)
+    float32; ``row_slot (M,)`` is the flat (token, choice) of each row
+    and ``row_gate (M,)`` its gate, which the gradient reads."""
+    picked = _take(y_rows, slot_row).astype(jnp.float32)
     return jnp.einsum("tk,tkd->td", gates, picked).astype(y_rows.dtype)
 
 
-def _combine_fwd(y_rows, gates, row_token, row_slot, slot_row):
-    return (_combine(y_rows, gates, row_token, row_slot, slot_row),
-            (y_rows, gates, row_token, row_slot, slot_row))
+def _combine_fwd(y_rows, gates, row_token, row_slot, slot_row, row_gate):
+    return (_combine(y_rows, gates, row_token, row_slot, slot_row, row_gate),
+            (y_rows, gates, row_token, row_slot, row_gate))
 
 
 def _combine_bwd(res, d_out):
-    y_rows, gates, row_token, row_slot, slot_row = res
+    y_rows, gates, row_token, row_slot, row_gate = res
+    n = gates.size
     # One gather serves both gradients: the gate's is each row's product
-    # with ITS token's cotangent, taken in row order and then picked.
-    d_out_rows = jnp.take(d_out, row_token, axis=0).astype(jnp.float32)
-    row_gate = jnp.take(gates.reshape(-1), row_slot)
+    # with ITS token's cotangent, taken in row order and then put back
+    # in (token, choice) order.  The rows past ``n`` get gate 0.
+    d_out_rows = _take(d_out, row_token).astype(jnp.float32)
     d_rows = (d_out_rows * row_gate[:, None]).astype(y_rows.dtype)
     d_row_gate = jnp.sum(y_rows.astype(jnp.float32) * d_out_rows, axis=-1)
-    d_gates = jnp.take(d_row_gate, slot_row)
-    return d_rows, d_gates.astype(gates.dtype), None, None, None
+    d_gates = _place(d_row_gate[:n], row_slot[:n]).reshape(gates.shape)
+    return d_rows, d_gates.astype(gates.dtype), None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -383,7 +437,10 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
         if norm_topk_prob:
             gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
         flat = experts.reshape(-1)
-        assigned = jnp.bincount(flat, length=e)   # of this shard's tokens
+        # of this shard's tokens; a compare and a sum, where ``bincount``
+        # adds into its bins one element at a time
+        assigned = jnp.sum(flat[:, None] == jnp.arange(e, dtype=flat.dtype),
+                           axis=0, dtype=jnp.int32)
         counts = _psum(assigned, token_axes)
         tokens = _psum(jnp.float32(t), token_axes)
         mean_prob = _psum(jnp.sum(probs, axis=0), token_axes) / tokens
@@ -400,21 +457,15 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
             group_sizes = jax.lax.dynamic_slice(assigned, (first,), (local,))
         tile = tile or choose_tiles(t * k, local)
         rows = -(-t * k // tile) * tile
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
         sched = make_schedule(group_sizes, rows, tile)
-        pad = jnp.zeros((rows - t * k,), jnp.int32)
-        row_slot = jnp.concatenate([order, pad])
-        row_token = row_slot // k
-        slot_row = jnp.zeros((t * k,), jnp.int32).at[order].set(
-            jnp.arange(t * k, dtype=jnp.int32),
-            unique_indices=True).reshape(t, k)
-        # What a layer checkpoint keeps (SAVED_RESIDUALS): with the
-        # sorted rows and the integers the backward pass reads held, the
-        # rematerialised forward runs no sort and no gather.
-        sched, row_token, row_slot, slot_row = checkpoint_name(
-            (sched, row_token, row_slot, slot_row), "moe_row_index")
-        x_rows = checkpoint_name(_dispatch(h, row_token, slot_row),
-                                 "moe_rows")
+        row_token, row_slot, slot_row, row_gate = _row_index(
+            flat, gates, rows)
+        # What a layer checkpoint keeps (SAVED_RESIDUALS): with the row
+        # index held, the rematerialised forward runs no sort.
+        sched, row_token, row_slot, slot_row, row_gate = checkpoint_name(
+            (sched, row_token, row_slot, slot_row, row_gate),
+            "moe_row_index")
+        x_rows = _dispatch(h, row_token, slot_row)
         dropped = (tokens * k - _psum(
             jnp.sum(group_sizes).astype(jnp.float32),
             tuple(token_axes) + ((expert_axis,) if expert_axis else ())))
@@ -427,7 +478,7 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
                                 product(x_rows, w_up)), w_down)
 
     with jax.named_scope("moe_combine"):
-        y = _combine(y_rows, gates, row_token, row_slot, slot_row)
+        y = _combine(y_rows, gates, row_token, row_slot, slot_row, row_gate)
         out = (x + _psum(y, sum_axes)).reshape(shape)
     return out, {"aux_loss": aux, "z_loss": z, "load_max_over_mean": load,
                  "dropped": dropped}

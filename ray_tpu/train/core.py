@@ -37,8 +37,8 @@ from ray_tpu.parallel.sharding import (
 # The layer checkpoint (``models/llama.py::_checkpoint``) recomputes a layer
 # but the residuals it keeps by name: the flash kernel's output and
 # log-sum-exp (``ops/attention.py::SAVED_RESIDUALS``: no ``flash_fwd`` under
-# ``rematted_computation``) and the expert layer's sorted rows and their
-# indices (``ops/moe.py::SAVED_RESIDUALS``: no sort and no row gather there).
+# ``rematted_computation``) and the expert layer's row index
+# (``ops/moe.py::SAVED_RESIDUALS``: no sort there; the row gather runs again).
 STEP_SCOPES = ("embed", "attn_qkv", "attention", "attn_out", "ffn",
                "moe_route", "moe_dispatch", "moe_experts", "moe_combine",
                "lm_head", "loss", "optimizer")

@@ -247,11 +247,12 @@ def test_step_program_is_named_by_scope(mesh_kw, moe):
     assert ("attention", "remat") not in seen
     if moe:
         # all four scopes in every phase, on ops of any kind — but the
-        # rematerialised combine (nothing of the backward reads its sum)
-        # and dispatch (the checkpoint keeps the sorted rows)
+        # rematerialised combine (nothing of the backward reads its sum);
+        # the rematerialised dispatch is the row gather alone (the
+        # checkpoint keeps the row index, not the rows)
         every = {scope_and_phase(name, STEP_SCOPES) for _, name in _op_names(
             step.lower(state, batch).compile().as_text(), ("",))}
-        gone = {("moe_combine", "remat"), ("moe_dispatch", "remat")}
+        gone = {("moe_combine", "remat")}
         assert {(s, p) for s in moe_scopes
                 for p in ("forward", "remat", "backward")} - gone <= every
         assert not gone & every, gone & every

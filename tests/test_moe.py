@@ -63,10 +63,8 @@ def test_layer_equals_a_per_token_loop(tile):
 
 
 def test_group_sizes_sum_to_all_assignments_and_fit_no_tile():
-    x, norm, router = _layer_inputs()[:3]
-    h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * norm
-    _, experts = jax.lax.top_k(jax.nn.softmax(h @ router, -1), K)
-    sizes = np.bincount(np.asarray(experts).reshape(-1), minlength=E)
+    sizes = np.bincount(np.asarray(_seeded_experts()).reshape(-1),
+                        minlength=E)
     assert sizes.sum() == T * K and (sizes % 16 != 0).any()
     sched = moe.make_schedule(jnp.asarray(sizes), 288, 16)
     visits = int(sched.num_visits[0])
@@ -110,6 +108,161 @@ def test_every_token_to_the_same_experts():
     for g in grads[3:]:
         assert float(jnp.abs(g[K:]).max()) == 0.0
         assert all(float(jnp.abs(g[e]).max()) > 0 for e in range(K))
+
+
+def _seeded_experts():
+    x, norm, router = _layer_inputs()[:3]
+    h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * norm
+    return jax.lax.top_k(jax.nn.softmax(h @ router, -1), K)[1]
+
+
+def _routing(case):
+    """(experts (T, k), row tile, ep ranks, this rank) of a named case."""
+    seeded = _seeded_experts()
+    if case == "same_experts":
+        return jnp.broadcast_to(jnp.arange(K), (T, K)), 16, 1, 0
+    if case == "empty_expert":  # 5's choices go to the next expert
+        return jnp.where(seeded == 5, (seeded + 1) % E, seeded), 16, 1, 0
+    if case == "ragged_tile":   # 288 rows in 384
+        return seeded, 128, 1, 0
+    if case.startswith("ep"):
+        ep, rank = (int(part.strip("eprank")) for part in case.split("_"))
+        return seeded, 16, ep, rank
+    return seeded, 16, 1, 0
+
+
+@pytest.mark.parametrize("case", [
+    "seeded", "same_experts", "empty_expert", "ragged_tile", "ep2_rank0",
+    "ep2_rank1", "ep4_rank0", "ep4_rank1", "ep4_rank2", "ep4_rank3"])
+def test_row_index_is_in_range_and_its_own_inverse(case):
+    """What the gathers promise (``mode="promise_in_bounds"``): every
+    index ``_row_index`` builds is in range, ``slot_row[t, j]`` is the
+    row whose ``row_slot`` is ``t * k + j``, the rows lie group by group
+    with their gates, and what is past the assignments lies past the
+    groups' sum."""
+    experts, tile, ep, rank = _routing(case)
+    local, n = E // ep, T * K
+    flat = (experts.reshape(-1) - rank * local) % E  # as moe_block's ranks
+    sizes = np.bincount(np.asarray(flat), minlength=E)[:local]
+    if case == "empty_expert":
+        assert sizes[5] == 0
+    rows = -(-n // tile) * tile
+    gates = jax.random.uniform(jax.random.PRNGKey(1), (T, K), minval=0.1)
+    row_token, row_slot, slot_row, row_gate = (
+        np.asarray(a) for a in moe._row_index(flat, gates, rows))
+    assert row_token.shape == row_slot.shape == (rows,)
+    assert slot_row.shape == (T, K)
+    assert (0 <= row_slot).all() and (row_slot < n).all()
+    assert (0 <= row_token).all() and (row_token < T).all()
+    assert (0 <= slot_row).all() and (slot_row < n).all()
+    assert (row_token == row_slot // K).all()
+    assert (row_slot[slot_row.reshape(-1)] == np.arange(n)).all()
+    assert (slot_row.reshape(-1)[row_slot[:n]] == np.arange(n)).all()
+    groups = np.asarray(flat)[row_slot[:n]]
+    assert (np.diff(groups) >= 0).all()
+    offsets = np.asarray(moe.make_schedule(jnp.asarray(sizes), rows,
+                                           tile).offsets)
+    for g in range(local):
+        assert (groups[offsets[g]:offsets[g + 1]] == g).all()
+    # other ranks' rows and the padding: the pseudo-group's, masked
+    assert offsets[local] == sizes.sum() <= n and offsets[-1] == rows
+    assert (groups[offsets[local]:] >= local).all()
+    assert (row_slot[n:] == 0).all()
+    # each row's gate rode along in the sort; the padding's is 0
+    assert (row_gate[:n] == np.asarray(gates).reshape(-1)[row_slot[:n]]).all()
+    assert row_gate.shape == (rows,) and (row_gate[n:] == 0).all()
+
+
+def _plain_formulation():
+    """The four gathers as they were before they promised anything
+    (``jnp.take``'s fill mode, scalars gathered one by one): what the
+    layer must still compute, to the bit."""
+    take = functools.partial(jnp.take, axis=0)
+
+    @jax.custom_vjp
+    def dispatch(x, row_token, slot_row):
+        return take(x, row_token)
+
+    def dispatch_bwd(slot_row, d_rows):
+        d_x = jnp.sum(take(d_rows, slot_row).astype(jnp.float32), axis=1)
+        return d_x.astype(d_rows.dtype), None, None
+
+    dispatch.defvjp(lambda x, rt, sr: (dispatch(x, rt, sr), sr),
+                    dispatch_bwd)
+
+    @jax.custom_vjp
+    def combine(y_rows, gates, row_token, row_slot, slot_row, row_gate):
+        picked = take(y_rows, slot_row).astype(jnp.float32)
+        return jnp.einsum("tk,tkd->td", gates, picked).astype(y_rows.dtype)
+
+    def combine_bwd(res, d_out):
+        y_rows, gates, row_token, row_slot, slot_row, _ = res
+        d_out_rows = take(d_out, row_token).astype(jnp.float32)
+        row_gate = jnp.take(gates.reshape(-1), row_slot)
+        d_rows = (d_out_rows * row_gate[:, None]).astype(y_rows.dtype)
+        d_row_gate = jnp.sum(y_rows.astype(jnp.float32) * d_out_rows, -1)
+        d_gates = jnp.take(d_row_gate, slot_row)
+        return d_rows, d_gates.astype(gates.dtype), None, None, None, None
+
+    combine.defvjp(lambda *a: (combine(*a), a), combine_bwd)
+    return dispatch, combine
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _layer_and_grads(ep):
+    """jit(value and gradients of the layer), on one device or with the
+    experts over ``ep`` devices."""
+    layer = functools.partial(moe.moe_block, num_selected=K, tile=16)
+    if ep > 1:
+        mesh = jax.make_mesh((ep,), ("ep",), devices=jax.devices()[:ep])
+        rank = P("ep")
+        layer = jax.shard_map(
+            functools.partial(layer, expert_axis="ep", sum_axes=("ep",)),
+            mesh=mesh, in_specs=(P(), P(), P(), rank, rank, rank),
+            out_specs=(P(), P()), check_vma=False)
+    return jax.value_and_grad(lambda *a: jnp.sum(layer(*a)[0] ** 2),
+                              argnums=range(6))
+
+
+@pytest.mark.parametrize("ep", [1, 2], ids=["one_device", "ep2"])
+def test_gathers_promise_their_indices_and_equal_the_plain_ones(
+        ep, monkeypatch):
+    """No row gather of the layer's gradient program may be out of range
+    and be filled (``FILL_OR_DROP``: a pass over ``(M, d)`` behind each
+    on the chip), and nothing it computes moves: no sum is reordered, so
+    output and gradients equal the plain formulation's bit for bit."""
+    args = _layer_inputs()
+    mode = jax.lax.GatherScatterMode
+
+    def row_gather_modes(fn):
+        return [e.params["mode"]
+                for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+                if e.primitive.name == "gather"
+                and e.invars[0].aval.shape[1:] == (D,)]
+
+    fn = _layer_and_grads(ep)
+    # dispatch, combine, and their gradients
+    assert row_gather_modes(fn) == [mode.PROMISE_IN_BOUNDS] * 4
+    got = jax.jit(fn)(*args)
+    dispatch, combine = _plain_formulation()
+    monkeypatch.setattr(moe, "_dispatch", dispatch)
+    monkeypatch.setattr(moe, "_combine", combine)
+    fn = _layer_and_grads(ep)
+    assert row_gather_modes(fn) == [mode.FILL_OR_DROP] * 4
+    want = jax.jit(fn)(*args)
+    assert float(got[0]) == float(want[0])
+    for name, g, w in zip(NAMES, got[1], want[1]):
+        assert bool((g == w).all()), name
 
 
 def test_auxiliary_losses_against_their_formulas():

@@ -3,8 +3,8 @@ backward pass recomputes a layer except the residuals named in
 ``ops/attention.py`` and ``ops/moe.py``.  Saving a value instead of
 recomputing it changes no arithmetic, so gradients equal a bare
 ``jax.checkpoint`` to the bit; what changes is what the gradient's program
-holds: the ``flash_fwd`` kernel and the expert layer's sort and row gather
-once a layer, not twice."""
+holds: the ``flash_fwd`` kernel and the expert layer's sorts once a layer,
+not twice."""
 
 import dataclasses
 
@@ -74,7 +74,11 @@ def _counts(grad, args, cfg):
     """What one layer of the gradient's program holds (the scan's body is
     traced once, so a count is per layer).  A row gather reads ``(T, d)``
     tokens into ``(T * k, d)`` sorted rows: ``_dispatch`` in the forward
-    pass, and the cotangent's rows in ``_combine``'s gradient."""
+    pass and again when it is rematerialised (cheaper than keeping the
+    rows: ``ops/moe.py::SAVED_RESIDUALS``), and the cotangent's rows in
+    ``_combine``'s gradient.  Two sorts build the row index
+    (``_row_index``: the routing sort and its inverse) and a third puts
+    the gates' gradient in place in the backward pass."""
     eqns = list(_eqns(jax.make_jaxpr(grad)(*args).jaxpr))
     kernels = [e.params["name"] for e in eqns
                if e.primitive.name == "pallas_call"]
@@ -118,13 +122,13 @@ def test_backward_runs_no_second_flash_fwd_and_no_second_dispatch(
     moe = bool(cfg.num_experts)
     grad, args = _grad_fn(cfg, MESHES[mesh_name])
     kept = _counts(grad, args, cfg)
-    assert kept == {"flash_fwd": 1, "flash_dkv": 1, "sorts": int(moe),
-                    "row_gathers": 2 * moe}
+    assert kept == {"flash_fwd": 1, "flash_dkv": 1, "sorts": 3 * moe,
+                    "row_gathers": 3 * moe}
     # The same count sees the second copies under a bare checkpoint.
     monkeypatch.setattr(llama, "_checkpoint", jax.checkpoint)
     bare, _ = _grad_fn(cfg, MESHES[mesh_name])
     assert _counts(bare, args, cfg) == {
-        "flash_fwd": 2, "flash_dkv": 1, "sorts": 2 * moe,
+        "flash_fwd": 2, "flash_dkv": 1, "sorts": 5 * moe,
         "row_gathers": 3 * moe}
 
 
