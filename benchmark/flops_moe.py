@@ -1,8 +1,10 @@
 """Operations and bytes a mixture-of-experts configuration needs, from
-shapes alone: the yardstick of ``train_step.moe_mfu_pct`` and
+shapes alone: what ``"flops": "flops_moe"`` in a configuration file
+names, the yardstick of its ``train_step.mfu_pct`` and
 ``moe.experts_roofline``, kept beside ``flops.py`` (whose dense count
 takes ``intermediate_size`` for one FFN and so sees one expert of the
-``num_experts_per_tok`` a token uses).
+``num_experts_per_tok`` a token uses).  Attention is the dense
+decoder's: its counts are ``flops.py``'s.
 
 Counted is what forward and backward REQUIRE: every token meets
 ``num_experts_per_tok`` experts and the router.  Work the program adds —
@@ -18,6 +20,7 @@ from __future__ import annotations
 from typing import Dict
 
 from benchmark import flops
+from benchmark.flops import flash_step_bytes, flash_step_flops  # noqa: F401
 
 
 def expert_params(conf: Dict) -> int:

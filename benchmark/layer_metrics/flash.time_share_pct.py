@@ -1,4 +1,4 @@
-"""Device time in the Mosaic custom calls (flash forward, dKV, dQ) as a
+"""Device time in the Mosaic kernels named ``flash_*`` (forward, dKV, dQ) as a
 share of the traced steps' device time; on a mesh, the chip where it is
 largest."""
 

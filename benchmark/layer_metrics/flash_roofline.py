@@ -1,19 +1,20 @@
-"""The least time a chip could take for the attention a step needs
-(``benchmark/flops.py``: causal FLOPs over the bf16 peak or bytes over the
-HBM peak, whichever is larger — ``bound(run)`` says which) over the device
-time of the Mosaic custom calls; each chip of a mesh has 1/chips of the
-work, and the slowest chip is read."""
+"""The least time a chip could take for the attention a step needs (the
+FLOP module the configuration names, ``benchmark/flops.py::of``: causal
+FLOPs of its attention layers over the bf16 peak or bytes over the HBM
+peak, whichever is larger — ``bound(run)`` says which) over the device time
+of the Mosaic kernels named ``flash_*``; each chip of a mesh has 1/chips of
+the work, and the slowest chip is read."""
 
 from benchmark import flops
 
 
 def _least(run):
-    job = run["job"]
+    conf, job = run["conf"], run["job"]
+    count = flops.of(conf)
     return flops.roofline_seconds(
-        flops.flash_step_flops(run["conf"], job["rows"], job["seq"])
-        / run["chips"],
-        flops.flash_step_bytes(run["conf"], job["rows"], job["seq"])
-        / run["chips"], run["peak"])
+        count.flash_step_flops(conf, job["rows"], job["seq"]) / run["chips"],
+        count.flash_step_bytes(conf, job["rows"], job["seq"]) / run["chips"],
+        run["peak"])
 
 
 def bound(run):

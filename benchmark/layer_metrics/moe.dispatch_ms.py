@@ -7,9 +7,8 @@ from benchmark import trace_scopes
 
 
 def read(run):
-    trace = run["worker"]["trace"]
-    if not trace or "scopes" not in trace["devices"][0]:
+    d = trace_scopes.device(run)
+    if d is None:
         return None
-    ms = 1e3 * trace_scopes.scope_seconds(
-        trace["devices"][0], ("moe_route", "moe_dispatch", "moe_combine"))
-    return ms or None
+    return 1e3 * trace_scopes.scope_seconds(
+        d, ("moe_route", "moe_dispatch", "moe_combine")) or None

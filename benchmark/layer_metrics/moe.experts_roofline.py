@@ -22,11 +22,8 @@ def bound(run):
 
 
 def read(run):
-    trace = run["worker"]["trace"]
-    if not trace or "scopes" not in trace["devices"][0]:
-        return None
-    experts_s = trace_scopes.scope_seconds(trace["devices"][0],
-                                           ("moe_experts",))
+    d = trace_scopes.device(run)
+    experts_s = d and trace_scopes.scope_seconds(d, ("moe_experts",))
     if not experts_s:
         return None
     return 100.0 * _least(run)["seconds"] / experts_s

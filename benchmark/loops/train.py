@@ -10,6 +10,14 @@ program, then step on a NEW batch every time (numpy on the host, then
 once a step.  The loss is fetched one step late, so the fetch never
 drains the device.
 
+It runs EVERY training cell and holds no model's name.  What differs
+between models is named by the configuration file: ``"reference"`` (a
+module under ``benchmark/reference/``) supplies ``loss_parts`` (the loss
+the check compares, and its parts where it has them), ``loss_rtol`` (the
+tolerance) and ``STEP_METRICS`` (what the window fetches with each loss,
+how it is kept, and what ``correct`` requires of it); optional
+``"scopes"`` and ``"kernels"`` add to the names the trace is reduced by.
+
 Shape copied from ``chip_smoke.py``'s ``_train_loop`` (PR 21), not
 imported: the yardstick may not move when the program does.  From the
 program it takes only the system under test (``ray_tpu.models.llama``,
@@ -19,6 +27,7 @@ program it takes only the system under test (``ray_tpu.models.llama``,
 from __future__ import annotations
 
 import glob
+import importlib
 import math
 import os
 import shutil
@@ -27,6 +36,10 @@ import time
 from typing import Any, Dict, List
 
 ANNOTATIONS = ("make_batch", "device_put", "step", "report")
+# The program's spans a step can run under (``ray_tpu.util.tracing``; on
+# the trace's host lines where they opened while the profiler ran).
+PROGRAM_SPANS = ("session.report", "train.loop")
+KEEP = {"sum": sum, "max": max}
 
 
 def require_chips(devs, chips: int, peaks: Dict) -> None:
@@ -61,6 +74,101 @@ def program_config(conf: Dict):
     return LlamaConfig(**fields)
 
 
+def reference_module(conf: Dict):
+    """The plain reference a configuration file names."""
+    return importlib.import_module("benchmark.reference." + conf["reference"])
+
+
+def placement(job: Dict, devs):
+    """(mesh, sharding of a batch) for a job: its mesh over the worker's
+    chips, rows split over the data axes; or the one chip."""
+    from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+    from ray_tpu.parallel.sharding import named_sharding
+
+    if not job["mesh"]:
+        return None, devs[0]
+    mesh = make_mesh(MeshConfig(**job["mesh"]))
+    return mesh, named_sharding(mesh, "batch", None)
+
+
+def program_check(cfg, mesh):
+    """What the check runs of the program, as one function to jit:
+    ``loss_fn``'s loss and parts, and each position's next-token loss from
+    the logits of ``forward``, the forward pass ``loss_fn`` calls."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import forward, loss_fn
+
+    def check(params, tokens):
+        loss, parts = loss_fn(params, {"tokens": tokens}, cfg, mesh=mesh)
+        logits, _ = forward(params, tokens[:, :-1], cfg, mesh=mesh)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        token_nll = -jnp.take_along_axis(
+            logp, tokens[:, 1:, None], axis=-1)[..., 0]
+        return loss, parts, token_nll
+
+    return check
+
+
+def reference_check(reference, conf: Dict, job: Dict, cfg, params, seed: int,
+                    mesh, batch_sharding, places=None) -> Dict[str, Any]:
+    """(a) and (b) of ``compared``: the program against the plain
+    reference on a seeded sample of the cell's own sequence length — the
+    per-token losses as the root of their mean squared difference, and the
+    step-0 training loss; its arrays are gone before the step program's
+    temporaries are needed.
+    At step 0 every norm's weight is 1 and what it norms has unit RMS
+    already, so a missing norm would not show: the check runs on a copy
+    whose norm weights are drawn from the seed (the rest is shared).
+    ``places`` (``benchmark/control.py``): name -> ``f(check_params,
+    sample)`` giving per-token losses, each read IN THE PROGRAM'S PLACE."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    norm_rng = np.random.default_rng([seed, 2])
+
+    def drawn(path, a):
+        if not str(getattr(path[-1], "key", "")).endswith("norm"):
+            return a
+        return jax.device_put(
+            (a.astype(np.float32) * norm_rng.uniform(
+                0.5, 1.5, a.shape).astype(np.float32)).astype(a.dtype),
+            a.sharding)
+
+    def rms_apart(a, b):
+        return float(jnp.sqrt(jnp.mean(jnp.square(a - b))))
+
+    check_params = jax.tree_util.tree_map_with_path(drawn, params)
+    sample = jax.device_put(
+        np.random.default_rng([seed, 1]).integers(
+            0, cfg.vocab_size, (job["check_rows"], job["seq"] + 1),
+            dtype=np.int32),
+        batch_sharding)
+    program_loss, program_parts, program_nll = jax.jit(
+        program_check(cfg, mesh))(check_params, sample)
+    program_parts = {k: float(v) for k, v in program_parts.items()}
+    reference_parts = reference.loss_parts(check_params, sample, conf)
+    reference_nll = reference_parts["token_nll"]
+    reference_parts = {k: float(v) for k, v in reference_parts.items()
+                       if k == "total" or k in program_parts}
+    placed = {} if places is None else {"placed_nll_rms": {
+        name: rms_apart(place(check_params, sample), reference_nll)
+        for name, place in places.items()}}
+    return {**placed,
+            "token_nll_rms": rms_apart(program_nll, reference_nll),
+            "token_nll_limit": conf["check"]["token_nll_rms"],
+            "program_loss": float(program_loss),
+            "reference_loss": reference_parts["total"],
+            "rtol": reference.loss_rtol(job["check_rows"] * job["seq"]),
+            "program_parts": program_parts,
+            "reference_parts": reference_parts,
+            "step_metrics": {k: want for k, (_, want)
+                             in reference.STEP_METRICS.items()
+                             if want is not None}}
+
+
 def measure(config: Dict[str, Any], devs, marks: Dict[str, float]
             ) -> Dict[str, Any]:
     """Set up, check against the reference, warm up, run the window and
@@ -70,11 +178,7 @@ def measure(config: Dict[str, Any], devs, marks: Dict[str, float]
     from jax import monitoring
     from jax.profiler import TraceAnnotation
 
-    from benchmark.reference import decoder
     from ray_tpu.air import session
-    from ray_tpu.models.llama import loss_fn
-    from ray_tpu.parallel.mesh import MeshConfig, make_mesh
-    from ray_tpu.parallel.sharding import named_sharding
     from ray_tpu.train.core import (
         default_optimizer, init_train_state, make_train_step)
 
@@ -96,29 +200,18 @@ def measure(config: Dict[str, Any], devs, marks: Dict[str, float]
     monitoring.register_event_duration_secs_listener(on_duration)
 
     conf, job, seed = config["conf"], config["job"], config["seed"]
+    reference = reference_module(conf)
     cfg = program_config(conf)
     rows, seq = job["rows"], job["seq"]
-    mesh = make_mesh(MeshConfig(**job["mesh"])) if job["mesh"] else None
-    batch_sharding = (devs[0] if mesh is None
-                      else named_sharding(mesh, "batch", None))
+    mesh, batch_sharding = placement(job, devs)
     opt = default_optimizer()
     marks["imports"] = time.time()
     state = jax.block_until_ready(
         init_train_state(jax.random.PRNGKey(seed), cfg, opt, mesh=mesh))
     marks["state_init"] = time.time()
 
-    # (a) step-0 loss of the program against the plain reference, on a
-    # seeded sample of the cell's own sequence length; its arrays are
-    # gone before the step program's temporaries are needed.
-    sample = jax.device_put(
-        np.random.default_rng([seed, 1]).integers(
-            0, cfg.vocab_size, (job["check_rows"], seq + 1), dtype=np.int32),
-        batch_sharding)
-    program_loss = float(jax.jit(
-        lambda p, t: loss_fn(p, {"tokens": t}, cfg, mesh=mesh)[0])(
-            state.params, sample))
-    reference_loss = float(decoder.loss(state.params, sample, conf))
-    del sample
+    check = reference_check(reference, conf, job, cfg, state.params, seed,
+                            mesh, batch_sharding)
     marks["reference_check"] = time.time()
 
     rng = np.random.default_rng([seed, 0])
@@ -138,12 +231,16 @@ def measure(config: Dict[str, Any], devs, marks: Dict[str, float]
     marks["step_load"] = time.time()
 
     losses: List[float] = []
+    kept: Dict[str, List[float]] = {k: [] for k in reference.STEP_METRICS}
     pending = None
 
     def fetch_pending():
         nonlocal pending
         if pending is not None:
-            losses.append(float(pending["loss"]))
+            got = jax.device_get({k: pending[k] for k in ("loss", *kept)})
+            losses.append(float(got["loss"]))
+            for k in kept:
+                kept[k].append(float(got[k]))
             session.report({"step": len(losses), "loss": losses[-1]})
             pending = None
 
@@ -188,6 +285,10 @@ def measure(config: Dict[str, Any], devs, marks: Dict[str, float]
     failed += sum(1 for x in window_losses if not np.isfinite(x))
     compiles_in_window = events["compiles"] - compiles_before
 
+    step_metrics = {k: KEEP[how](kept[k][n_warm:]) if kept[k][n_warm:]
+                    else None
+                    for k, (how, _) in reference.STEP_METRICS.items()}
+
     trace = None
     if config["trace"] and error is None:
         trace = _traced_steps(config, run_steps, f"jit_{step_fn.__name__}")
@@ -198,16 +299,15 @@ def measure(config: Dict[str, Any], devs, marks: Dict[str, float]
         "loop_start": marks["loop_start"],
         "setup_marks": marks,
         "window_start": window_start,
-        "check": {"program_loss": program_loss,
-                  "reference_loss": reference_loss,
-                  "rtol": decoder.LOSS_RTOL},
+        "check": check,
         "window": {"attempted": attempted, "failed": failed,
                    "steps": len(window_losses),
                    "tokens": len(window_losses) * rows * seq,
                    "elapsed_s": elapsed, "error": error,
                    "first_loss": window_losses[0] if window_losses else None,
                    "last_loss": window_losses[-1] if window_losses else None,
-                   "compiles": compiles_in_window},
+                   "compiles": compiles_in_window,
+                   "step_metrics": step_metrics},
         "compile": {"step_load_s": step_load_s,
                     "cache_hits": events["hits"],
                     "cache_misses": events["misses"],
@@ -223,7 +323,8 @@ def measure(config: Dict[str, Any], devs, marks: Dict[str, float]
 
 def _traced_steps(config, run_steps, step_module):
     """After the window: one lead-in step and ``traced_steps`` more under
-    the profiler, reduced here — only this process can trace its chips."""
+    the profiler, reduced here, before the trace file is deleted — only
+    this process can trace its chips."""
     import jax
 
     from benchmark import trace_reduce
@@ -242,29 +343,55 @@ def _traced_steps(config, run_steps, step_module):
                           recursive=True)
         if not files:
             return None
-        return trace_reduce.reduce_file(max(files, key=os.path.getmtime),
-                                        step_module=step_module,
-                                        annotations=ANNOTATIONS)
+        conf = config["conf"]
+        return trace_reduce.reduce_file(
+            max(files, key=os.path.getmtime), step_module=step_module,
+            annotations=ANNOTATIONS, spans=PROGRAM_SPANS,
+            scopes=conf.get("scopes", ()), kernels=conf.get("kernels", ()))
     finally:
         if not keep:
             shutil.rmtree(trace_dir, ignore_errors=True)
 
 
-def correct(run: Dict[str, Any]) -> bool:
-    """Driver side.  (a) the program's step-0 loss equals the plain
-    reference's within its stated tolerance; (b) every loss of the window
-    finite and no step raised; (c) on several chips, per-chip peaks
-    within 20 %; (d) nothing compiled or loaded inside the window."""
+def compared(run: Dict[str, Any]) -> List[Dict[str, Any]]:
+    """Driver side.  Every number ``correct`` compares, beside its limit:
+    (a) the program's per-token losses against the plain reference's, the
+    root of their mean squared difference in nats, within the limit the
+    configuration file states — the comparison that sees precision; (b) the program's
+    step-0 training loss against the reference's, relative, within its
+    stated tolerance — the structure of ``loss_fn``; (c) no step of the
+    window raised or gave a loss that is not finite; (d) nothing compiled
+    or loaded inside the window; (e) on several chips, per-chip memory
+    peaks within 20 %; (f) each step metric the reference module holds to
+    a value (kept over the window as it says) equal to it."""
     w = run["worker"]
     check, window, peaks = w["check"], w["window"], w["peak_bytes_in_use"]
-    return bool(
-        math.isfinite(check["program_loss"])
-        and abs(check["program_loss"] - check["reference_loss"])
-        <= check["rtol"] * abs(check["reference_loss"])
-        and window["steps"] > 0 and window["failed"] == 0
-        and window["error"] is None
-        and min(peaks) > 0 and max(peaks) <= 1.2 * min(peaks)
-        and window["compiles"] == 0)
+    rms, rms_limit = check["token_nll_rms"], check["token_nll_limit"]
+    apart = abs(check["program_loss"] - check["reference_loss"]) \
+        / abs(check["reference_loss"])
+    rows = [("per-token loss apart from the reference's, RMS in nats", rms,
+             rms_limit, math.isfinite(rms) and rms <= rms_limit),
+            ("loss apart from the reference's, relative", apart,
+             check["rtol"], math.isfinite(apart) and apart <= check["rtol"]),
+            ("failed steps", window["failed"], 0, window["failed"] == 0),
+            ("compiles in the window", window["compiles"], 0,
+             window["compiles"] == 0),
+            ("memory peak, fullest chip over emptiest",
+             max(peaks) / min(peaks) if min(peaks) > 0 else math.inf, 1.2,
+             min(peaks) > 0 and max(peaks) <= 1.2 * min(peaks))]
+    for name, want in check["step_metrics"].items():
+        got = window["step_metrics"][name]
+        rows.append((name, got, want, got == want))
+    return [{"what": what, "value": value, "limit": limit, "ok": bool(ok)}
+            for what, value, limit, ok in rows]
+
+
+def correct(run: Dict[str, Any]) -> bool:
+    """Driver side: every row of ``compared`` holds, and the window ran
+    whole steps to its end."""
+    window = run["worker"]["window"]
+    return bool(window["steps"] > 0 and window["error"] is None
+                and all(row["ok"] for row in compared(run)))
 
 
 def end_to_end(run: Dict[str, Any]) -> Dict[str, float]:

@@ -5,7 +5,8 @@ without a chip (on-chip-measurement guide, section 2, rehearsal 3).
     JAX_PLATFORMS=cpu python benchmark/rehearse_compile.py [<cell> ...]
 
 Prints what the chip's compiler counts per device for the step program,
-the program's check loss and one layer of the plain reference.  A compile
+the program's side of the check and one layer of the plain reference the
+configuration names.  A compile
 is not a run: nothing here is a time or a result.  It is how the cells
 were cut to size (benchmark/README.md) and what to run before a chip call
 after changing a size.
@@ -38,8 +39,6 @@ def rehearse(cell, topo):
     from jax.sharding import SingleDeviceSharding
 
     from benchmark.loops import train
-    from benchmark.reference import decoder
-    from ray_tpu.models.llama import loss_fn
     from ray_tpu.ops import attention
     from ray_tpu.parallel.mesh import MeshConfig, make_mesh
     from ray_tpu.train.core import (
@@ -55,6 +54,7 @@ def rehearse(cell, topo):
             return json.load(f)
 
     conf, job = load("configs", cell["config"]), load("jobs", cell["traffic"])
+    reference = train.reference_module(conf)
     cfg, opt = train.program_config(conf), default_optimizer()
     shapes = jax.eval_shape(lambda k: init_train_state(k, cfg, opt),
                             jax.random.PRNGKey(0))
@@ -81,22 +81,18 @@ def rehearse(cell, topo):
         state, {"tokens": tokens(job["rows"])}).compile()
     print("  step program:   ", _gb(step),
           "| flash kernel in it:", "tpu_custom_call" in step.as_text())
-    check = jax.jit(
-        lambda p, t: loss_fn(p, {"tokens": t}, cfg, mesh=mesh)[0]).lower(
-            state.params, tokens(job["check_rows"])).compile()
-    print("  check loss:     ", _gb(check))
+    check = jax.jit(train.program_check(cfg, mesh)).lower(
+        state.params, tokens(job["check_rows"])).compile()
+    print("  check program:  ", _gb(check))
     x = jax.ShapeDtypeStruct(
         (job["check_rows"], job["seq"], conf["hidden_size"]), jnp.float32,
         sharding=(NamedSharding(mesh, P(("dp", "fsdp"), None, None))
                   if mesh else batch_sharding))
     with jax.default_matmul_precision("highest"):
-        ref = decoder.layer.lower(
-            x, state.params["layers"], 0,
-            heads=conf["num_attention_heads"],
-            kv_heads=conf["num_key_value_heads"],
-            theta=float(conf["rope_theta"]),
-            eps=float(conf["rms_norm_eps"])).compile()
-    print("  reference layer:", _gb(ref), "(arguments: all stacked layers)")
+        ref = reference.layer.lower(x, state.params["layers"], 0,
+                                    **reference.layer_kwargs(conf)).compile()
+    print(f"  reference layer ({conf['reference']}):", _gb(ref),
+          "(arguments: all stacked layers)")
 
 
 def main(argv):

@@ -17,6 +17,7 @@ so a later PR adds a cell, a configuration, a job or a metric by adding
 files and entries.  This file holds no name of any of them.
 
 The last line of standard output is one JSON object (``correct``,
+``compared`` — every number ``correct`` compares, beside its limit —
 ``attempted``, ``failed``, ``metrics``, ``device``, and ``breakdown`` in a
 traced run).  No chip, too few chips, an unknown device kind, a failed
 ``fit()`` or a missing program: a non-zero exit and no result line.
@@ -137,7 +138,8 @@ def result_line(bench: dict, cell: dict, run: dict, trace: bool) -> dict:
         for m in cell_metrics(bench, "end_to_end", cell["name"]):
             metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
     device = dict(w["device"], memory_peak_bytes=max(w["peak_bytes_in_use"]))
-    line = {"correct": loop.correct(run), "attempted": w["window"]["attempted"],
+    line = {"correct": loop.correct(run), "compared": loop.compared(run),
+            "attempted": w["window"]["attempted"],
             "failed": w["window"]["failed"], "metrics": metrics,
             "device": device}
     if trace and w["trace"]:

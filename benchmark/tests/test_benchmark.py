@@ -13,12 +13,24 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 
-from benchmark import flops, trace_reduce  # noqa: E402
+from benchmark import flops, trace_reduce, trace_scopes  # noqa: E402
+
+PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
 def _conf(name):
     with open(os.path.join(BENCH, "configs", name + ".json")) as f:
         return json.load(f)
+
+
+def _reader(metric):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "_m", os.path.join(BENCH, "layer_metrics", metric + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 # A configuration file in the public key names, at CPU size.
@@ -28,8 +40,15 @@ TINY = {
     "rope_theta": 10000.0, "vocab_size": 256,
     "assumed": {"param_dtype": {"value": "float32"},
                 "dtype": {"value": "float32"}},
+    "reference": "decoder", "flops": "flops",
+    "check": {"token_nll_rms": 0.012},
     "llama_config": _conf("mistral-7b-v0.1-d4")["llama_config"],
 }
+# The same for the expert layer: the OLMoE file's own keys at CPU widths.
+TINY_MOE = dict(
+    _conf("olmoe-1b-7b-0125-1chip"), hidden_size=64, num_attention_heads=4,
+    num_key_value_heads=4, intermediate_size=32, vocab_size=256,
+    num_experts=8, num_experts_per_tok=3, num_hidden_layers=2)
 
 
 # ------------------------------------------------------------- flops.py --
@@ -46,6 +65,7 @@ TINY = {
 ])
 def test_flops_against_hand_counts(name, seq, matmul, total, attention):
     conf = _conf(name)
+    assert flops.of(conf) is flops
     assert flops.matmul_params(conf) == matmul
     assert flops.total_params(conf) == total
     assert flops.attention_flops_per_token(conf, seq) == attention
@@ -68,30 +88,87 @@ def test_flash_roofline_names_its_bound():
     assert short["bound"] == "memory"
 
 
-def test_published_widths_and_reduced_keys():
-    """Every published width equals its source (as written in ISSUE 22
-    from the public config.json files); only depth is reduced."""
+def test_one_attention_layer_in_ten_is_counted_once():
+    """A configuration with ``layer_types`` (nine ``mamba`` layers and one
+    ``attention`` layer, as granite-4.0-h has them) has ONE layer of causal
+    attention for ``flash_roofline`` and ``train_step.mfu_pct`` to count."""
+    dense = dict(_conf("mistral-7b-v0.1-d4"), num_hidden_layers=10)
+    hybrid = dict(dense, layer_types=["mamba"] * 5 + ["attention"]
+                  + ["mamba"] * 4)
+    assert flops.attention_layers(dense) == 10
+    assert flops.attention_layers(hybrid) == 1
+    assert flops.attention_layers(
+        dict(dense, layer_types=["full_attention", "linear_attention"])) == 2
+    assert flops.flash_step_flops(hybrid, 4, 4096) == \
+        flops.flash_step_flops(dense, 4, 4096) / 10
+    assert flops.flash_step_bytes(hybrid, 4, 4096) == \
+        flops.flash_step_bytes(dense, 4, 4096) / 10
+    # the readers, on a step whose one flash layer took 10 ms
+    def run(conf):
+        device = {"steps": 2, "flash_s": 0.020, "step_s": [0.5, 0.5]}
+        return {"conf": conf, "job": {"rows": 4, "seq": 4096}, "chips": 1,
+                "peak": PEAK, "worker": {"trace": {"devices": [device]}},
+                "end_to_end": {"train_tokens_per_s": 20000.0}}
+
+    roofline = _reader("flash_roofline").read
+    assert roofline(run(hybrid)) == pytest.approx(roofline(run(dense)) / 10)
+    assert roofline(run(hybrid)) == pytest.approx(
+        100 * 6 * 4 * 4096 ** 2 * 32 * 128 / 197e12 / 0.010)
+    mfu = _reader("train_step.mfu_pct").read
+    per_layer_attention = 6 * 4096 * 32 * 128
+    projections = 6 * (2 * 4096 * 4096 + 2 * 4096 * 1024)
+    assert mfu(run(dense)) - mfu(run(hybrid)) == pytest.approx(
+        100 * 20000.0 * 9 * (per_layer_attention + projections) / 197e12)
+
+
+CONFIGS = sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, "configs")))
+# keys of a configuration file that are the benchmark's own
+# (``architectures`` is the public file's; the catalog's rows leave it out)
+OWN_KEYS = {"source", "paper", "reduced", "assumed", "deployment",
+            "llama_config", "reference", "flops", "scopes", "kernels",
+            "check", "architectures"}
+# numbers of a public file that no model code reads: a file may leave them out
+NOT_READ = {"bos_token_id", "eos_token_id", "pad_token_id",
+            "initializer_range", "pretraining_tp"}
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_published_widths_and_reduced_keys(name):
+    """Every file under ``configs/`` against the copy of its public
+    ``config.json`` under ``testdata/published/``: equal key for key,
+    except the keys it lists under ``reduced``; no number left out; nothing
+    added that is not the benchmark's own or explained under ``assumed``."""
+    conf = _conf(name)
+    with open(os.path.join(BENCH, "testdata", "published",
+                           name + ".json")) as f:
+        published = json.load(f)
+    differ = [k for k, v in published.items() if k in conf and conf[k] != v]
+    assert differ == list(conf["reduced"]), differ
+    for key, cut in conf["reduced"].items():
+        assert (cut["published"], cut["run"]) == (published[key], conf[key])
+    left_out = {k for k, v in published.items() if k not in conf
+                and isinstance(v, (int, float)) and not isinstance(v, bool)}
+    assert left_out <= NOT_READ, left_out
+    added = set(conf) - set(published) - OWN_KEYS
+    assert added <= set(conf["assumed"]), added
+    for key in added:
+        assert conf[key] == conf["assumed"][key]["value"]
+    assert conf["reduced"].keys() <= {"num_hidden_layers"}  # depth alone
+    # the modules it names are there
+    for kind, module in (("reference", conf["reference"]), ("", conf["flops"])):
+        assert os.path.isfile(os.path.join(BENCH, kind, module + ".py"))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entries = {c["name"]: c for c in json.load(f)["configs"]}
+    if name in entries:
+        assert entries[name]["reduced"] == list(conf["reduced"])
+        assert entries[name]["source"] == conf["source"]
+        assert entries[name]["file"] == f"benchmark/configs/{name}.json"
+
+
+def test_every_configuration_in_benchmark_json_has_its_files():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    want = {
-        "mistral-7b-v0.1-d4": dict(
-            hidden_size=4096, num_attention_heads=32, num_key_value_heads=8,
-            intermediate_size=14336, vocab_size=32000, rope_theta=10000.0,
-            rms_norm_eps=1e-5, sliding_window=4096, num_hidden_layers=4,
-            tie_word_embeddings=False),
-        "deepseek-llm-7b-d20-x4": dict(
-            hidden_size=4096, num_attention_heads=32, num_key_value_heads=32,
-            intermediate_size=11008, vocab_size=102400, rope_theta=10000.0,
-            rms_norm_eps=1e-6, num_hidden_layers=20,
-            tie_word_embeddings=False),
-    }
-    for entry in bench["configs"]:
-        conf = _conf(entry["name"])
-        for key, value in want[entry["name"]].items():
-            assert conf[key] == value, (entry["name"], key)
-        assert entry["reduced"] == list(conf["reduced"]) == [
-            "num_hidden_layers"]
-        assert conf["source"] == entry["source"]
+    assert sorted(c["name"] for c in bench["configs"]) == CONFIGS
 
 
 # --------------------------------------------------- reference/decoder.py --
@@ -189,8 +266,19 @@ def test_parse_op():
                                                  "fusion.3")
 
 
-def test_reduce_synthetic_planes():
-    """Two steps after a lead-in: window from the lead-in's end."""
+# The ops' JAX name stacks (the stat ``tf_op``), as ``trace_scopes.op_names``
+# reads them from a file; ``ssm_scan`` and ``ssm_chunk`` stand for the scope
+# and the kernel a configuration file of a later model would list.
+STACKS = {
+    FLASH: "jit(step)/while/body/rematted_computation/ssm_scan/ssm_chunk",
+    USES_ONE: "jit(step)/transpose(jvp(ffn))/dot_general",
+    WHILE: "jit(step)/while/body/dynamic_slice",
+    GATHER_START: "jit(step)/while/body/jvp(attn_qkv)/all-gather",
+    GATHER_DONE: "jit(step)/while/body/jvp(attn_qkv)/all-gather",
+}
+
+
+def _synthetic_planes():
     ops, mods = [], []
     for i, start in enumerate((0, 1000, 2100)):
         mods.append((f"jit_step({i})", start, start + 900))
@@ -199,44 +287,125 @@ def test_reduce_synthetic_planes():
                 (GATHER_START, start + 600, start + 610),
                 (GATHER_DONE, start + 610, start + 700),
                 (USES_ONE, start + 700, start + 900)]
-    planes = {"/device:TPU:0": {"XLA Ops": sorted(ops, key=lambda e: e[1]),
-                                "XLA Modules": mods},
-              "/host:CPU": {"python": [("make_batch", 890, 950),
-                                       ("report", 1900, 2095)]}}
-    out = trace_reduce.reduce_planes(planes, step_module="jit_step",
-                                     annotations=("make_batch", "report"))
+    return {"/device:TPU:0": {"XLA Ops": sorted(ops, key=lambda e: e[1]),
+                              "XLA Modules": mods},
+            "/host:CPU": {"python": [("make_batch", 890, 950),
+                                     ("report", 1900, 2095),
+                                     ("session.report", 1850, 2110)]}}
+
+
+def test_reduce_synthetic_planes():
+    """Two steps after a lead-in: window from the lead-in's end.  Without
+    the ops' name stacks everything is unscoped and no kernel has a name."""
+    out = trace_reduce.reduce_planes(
+        _synthetic_planes(), step_module="jit_step",
+        annotations=("make_batch", "report"), spans=("session.report",))
     d, = out["devices"]
     assert d["steps"] == 2
     assert d["window_s"] == pytest.approx(2100e-9)
     assert d["busy_s"] + d["idle_s"] == pytest.approx(d["window_s"])
     assert d["idle_s"] == pytest.approx(300e-9)
     assert d["gap_s"] == pytest.approx([100e-9, 200e-9])
-    assert d["flash_s"] == pytest.approx(400e-9)
+    assert d["kernels_s"] == pytest.approx(400e-9)
+    assert d["flash_s"] == 0 and d["kernels"] == {
+        "unnamed": pytest.approx(200e-9)}
+    assert d["scopes"] == {} and d["unscoped_s"] == pytest.approx(900e-9)
     assert d["collective_s"] == pytest.approx(200e-9)
     assert d["collectives_per_step"] == 1
-    assert d["idle_gaps"][0] == ["report", pytest.approx(200e-9)]
+    # the program's span that covers the gap, then the loop's annotation
+    assert d["idle_gaps"][0] == ["session.report/report",
+                                 pytest.approx(200e-9)]
     assert d["idle_gaps"][1] == ["make_batch", pytest.approx(100e-9)]
+    assert all(label.startswith("unscoped/forward ")
+               for label, _ in d["device_ops"])
+    assert out["host_spans"] == {"make_batch": 1, "report": 1}
     assert trace_reduce.reduce_planes(
         {"/host:CPU": {}}, step_module="jit_step", annotations=()) is None
+
+
+def test_a_configurations_scopes_and_kernels_reach_the_reduction(monkeypatch):
+    """Scopes and kernel names are data: what a configuration file lists
+    under ``"scopes"`` and ``"kernels"`` is reduced by, beside
+    ``trace_scopes``' own tuples, and the loop hands both over."""
+    names = {"/device:TPU:0": STACKS}
+    ns = 1e-9
+    d, = trace_reduce.reduce_planes(
+        _synthetic_planes(), step_module="jit_step", annotations=(),
+        names=names, scopes=["ssm_scan"], kernels=["ssm_chunk"])["devices"]
+    assert d["scopes"] == {
+        "ssm_scan": {"remat": pytest.approx(200 * ns)},
+        "scan": {"forward": pytest.approx(400 * ns)},
+        "attn_qkv": {"forward": pytest.approx(100 * ns)},
+        "ffn": {"backward": pytest.approx(200 * ns)}}
+    assert d["unscoped_s"] == 0
+    assert d["kernels"] == {"ssm_chunk.remat": pytest.approx(200 * ns)}
+    assert d["kernels_s"] == pytest.approx(400 * ns) and d["flash_s"] == 0
+    assert d["device_ops"][0] == [
+        "scan/forward while.9 while (s32[], bf16[32,512,4096])",
+        pytest.approx(800 * ns)]
+    assert {label.split(" ")[0] for label, _ in d["device_ops"]} == {
+        "scan/forward", "ssm_scan/remat", "attn_qkv/forward", "ffn/backward"}
+    # without the configuration's names the same ops fall to the scan
+    d, = trace_reduce.reduce_planes(
+        _synthetic_planes(), step_module="jit_step", annotations=(),
+        names=names)["devices"]
+    assert "ssm_scan" not in d["scopes"]
+    assert d["scopes"]["scan"] == {"forward": pytest.approx(400 * ns),
+                                   "remat": pytest.approx(200 * ns)}
+    assert d["kernels"] == {"unnamed.remat": pytest.approx(200 * ns)}
+
+    # the loop: what it hands to the reduction of a traced run
+    import jax.numpy as jnp
+
+    from benchmark.loops import train
+
+    seen = {}
+
+    def reduce_file(path, **kw):
+        seen.update(kw, path=path, there=os.path.isfile(path))
+        return {"devices": []}
+
+    monkeypatch.setattr(trace_reduce, "reduce_file", reduce_file)
+    config = {"conf": {"scopes": ["ssm_scan"], "kernels": ["ssm_chunk"]},
+              "job": {"traced_steps": 1}, "trace_dir": None}
+    assert train._traced_steps(
+        config, lambda n: jnp.ones(n).block_until_ready(),
+        "jit_step") == {"devices": []}
+    assert seen["there"] and not os.path.exists(seen["path"])
+    assert (seen["scopes"], seen["kernels"]) == (["ssm_scan"], ["ssm_chunk"])
+    assert seen["spans"] == train.PROGRAM_SPANS
+    assert seen["annotations"] == train.ANNOTATIONS
 
 
 RECORDED = os.path.join(BENCH, "testdata", "mistral7b-train-s512.xplane.pb.gz")
 
 
-def test_reduce_recorded_chip_trace(tmp_path):
-    """The trace recorded on the v5e in PR 22 (benchmark/testdata): busy +
-    idle = window, the step found, the custom calls found."""
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    """The trace recorded on the v5e (benchmark/testdata), reduced as the
+    loop reduces it, and what ``expected.json`` says of it."""
     import gzip
     import shutil
 
+    from benchmark.loops import train
+
     with open(os.path.join(BENCH, "testdata", "expected.json")) as f:
         expected = json.load(f)
-    path = str(tmp_path / "recorded.xplane.pb")
+    path = str(tmp_path_factory.mktemp("trace") / "recorded.xplane.pb")
     with gzip.open(RECORDED, "rb") as src, open(path, "wb") as dst:
         shutil.copyfileobj(src, dst)
     out = trace_reduce.reduce_file(
         path, step_module=expected["step_module"],
-        annotations=expected["annotations"])
+        annotations=train.ANNOTATIONS, spans=train.PROGRAM_SPANS)
+    return out, expected
+
+
+def test_reduce_recorded_chip_trace(recorded):
+    """Busy + idle = window, the step found, the kernels found by name,
+    every op under a scope and a phase."""
+    from benchmark.loops import train
+
+    out, expected = recorded
     d, = out["devices"]
     assert d["steps"] == expected["steps"] == len(d["step_s"])
     assert d["busy_s"] + d["idle_s"] == pytest.approx(d["window_s"],
@@ -246,20 +415,69 @@ def test_reduce_recorded_chip_trace(tmp_path):
     assert idle_pct == pytest.approx(expected["idle_pct"], rel=1e-6)
     assert idle_pct + 100.0 * d["busy_s"] / d["window_s"] == \
         pytest.approx(100.0, rel=1e-12)
-    # the Mosaic kernels: forward, rematerialised forward, dKV, dQ per
-    # layer and step, and nothing that merely reads a custom call's result
+    # the Mosaic kernels: forward, dKV, dQ per layer and step, each under
+    # its name, and nothing that merely reads a custom call's result
     assert d["flash_s"] == pytest.approx(expected["flash_s"], rel=1e-9)
-    assert 0.03 < d["flash_s"] / sum(d["step_s"]) < 0.06
+    assert d["kernels_s"] == d["flash_s"]  # a dense step has no other
+    assert sorted(d["kernels"]) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert sum(d["kernels"].values()) * d["steps"] == pytest.approx(
+        d["flash_s"], rel=1e-9)
+    assert 0.02 < d["flash_s"] / sum(d["step_s"]) < 0.04
     assert statistics.median(d["step_s"]) * 1e3 == pytest.approx(
         expected["step_ms_median"], rel=1e-9)
     assert d["collective_s"] == 0 and d["collectives_per_step"] == 0
+    # scopes + scan + unscoped = busy, to the nanosecond
+    scoped = sum(t for row in d["scopes"].values() for t in row.values())
+    assert (scoped + d["unscoped_s"]) * d["steps"] == pytest.approx(
+        d["busy_s"], rel=1e-9)
+    assert set(d["scopes"]) == set(trace_scopes.SCOPES) - set(
+        trace_scopes.MOE_SCOPES) | {trace_scopes.SCAN}
     assert len(d["device_ops"]) == 10 and 0 < len(d["idle_gaps"]) <= 5
     assert d["device_ops"][0][0] == expected["top_op"]
     assert all(len(label) <= 120 for label, _ in d["device_ops"])
-    assert {label for label, _ in d["idle_gaps"]} <= set(
-        expected["annotations"]) | {"unannotated"}
+    places = {f"{s}/{p}" for s in d["scopes"] for p in trace_scopes.PHASES}
+    assert {label.split(" ")[0] for label, _ in d["device_ops"]} <= places
+    labels = {"/".join(filter(None, (s, a)))
+              for s in ("",) + train.PROGRAM_SPANS
+              for a in ("",) + train.ANNOTATIONS} - {""}
+    assert {label for label, _ in d["idle_gaps"]} <= labels | {"unannotated"}
     assert all(out["host_spans"][n] == expected["steps"] + 1
-               for n in expected["annotations"])
+               for n in train.ANNOTATIONS)
+
+
+with open(os.path.join(BENCH, "testdata", "expected.json")) as _f:
+    RECORDED_READERS = sorted(json.load(_f)["readers"].items())
+
+
+@pytest.mark.parametrize("metric,value", RECORDED_READERS,
+                         ids=[m for m, _ in RECORDED_READERS])
+def test_readers_on_the_recorded_chip_trace(recorded, metric, value):
+    """Every reader of the trace on the recorded run, against the values
+    ``expected.json`` holds (each checked against ``python -m
+    ray_tpu.scripts step-breakdown`` of the same file when it was
+    recorded); None where the run has nothing for it."""
+    out, expected = recorded
+    with open(os.path.join(BENCH, "jobs", expected["job"] + ".json")) as f:
+        job = json.load(f)
+    run = {"worker": {"trace": out, "window": {}},
+           "conf": _conf(expected["config"]), "job": job, "chips": 1,
+           "peak": PEAK}
+    got = _reader(metric).read(run)
+    if value is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(value, rel=1e-9)
+    if metric.endswith("_pct") or metric.endswith("_roofline"):
+        assert got is None or 0 <= got < 100
+
+
+def test_step_shares_of_the_recorded_trace_make_a_hundred():
+    with open(os.path.join(BENCH, "testdata", "expected.json")) as f:
+        readers = json.load(f)["readers"]
+    parts = [v for m, v in readers.items() if m.startswith("step.")
+             and m != "step.remat_pct"]
+    assert len(parts) == 7
+    assert sum(parts) == pytest.approx(100.0, abs=0.01)
 
 
 # ------------------------------------------------------------ the loop --
@@ -277,29 +495,36 @@ def _rehearsal_loop(config):
                                  {"loop_start": time.time()}))
 
 
-@pytest.mark.parametrize("mesh", [None, {"fsdp": 2, "tp": 2}],
-                         ids=["one-device", "fsdp2-tp2"])
-def test_train_loop_rehearsal_on_cpu_worker(mesh):
-    """The whole loop at a tiny config through JaxTrainer.fit() with a
-    CPU worker.  Asserts the shape of what comes back, no speed."""
+BF16 = {"param_dtype": {"value": "bfloat16"}, "dtype": {"value": "bfloat16"}}
+
+
+@pytest.mark.parametrize("conf,mesh,check_rows", [
+    (dict(TINY, assumed=BF16), None, 4),
+    # 8 virtual devices: dp=2 x fsdp=2 split the rows four ways
+    (dict(TINY, assumed=BF16), {"fsdp": 2, "tp": 2}, 4),
+    (TINY_MOE, None, 2),
+], ids=["dense-one-device", "dense-fsdp2-tp2", "moe-one-device"])
+def test_train_loop_rehearsal_on_cpu_worker(conf, mesh, check_rows):
+    """The ONE loop at a tiny size through JaxTrainer.fit() with a CPU
+    worker: a dense cell, the mesh cell and the MoE cell.  Asserts the
+    shape of what comes back, no speed."""
     import ray_tpu as ray
     from ray_tpu.air.config import ScalingConfig
     from ray_tpu.train import JaxTrainer
 
     from benchmark.loops import train
 
+    moe = "num_experts" in conf
     job = {"loop": "train", "rows": 4, "seq": 64, "mesh": mesh,
-           # 8 virtual devices: dp=2 x fsdp=2 split the rows four ways
-           "check_rows": 4, "warmup_steps": 2, "traced_steps": 2}
-    conf = dict(TINY, assumed={"param_dtype": {"value": "bfloat16"},
-                               "dtype": {"value": "bfloat16"}})
+           "check_rows": check_rows, "warmup_steps": 2, "traced_steps": 2}
     ray.init(num_cpus=4, num_tpus=0)
     try:
         result = JaxTrainer(
             _rehearsal_loop,
             train_loop_config={"conf": conf, "job": job, "chips": 0,
-                               "peaks": {}, "seed": 5, "seconds": 1.0,
-                               "trace": True, "trace_dir": None},
+                               "peaks": {}, "seed": 2147483653,
+                               "seconds": 1.0, "trace": True,
+                               "trace_dir": None},
             scaling_config=ScalingConfig(num_workers=1,
                                          tpu_chips_per_worker=0)).fit()
     finally:
@@ -314,13 +539,118 @@ def test_train_loop_rehearsal_on_cpu_worker(mesh):
     assert w["trace"] is None  # a CPU trace has no device plane to read
     # every step reported, as a user's loop does: warm-up, window, traced
     assert len(result.metrics_history) == 2 + win["steps"] + 3 + 1
-    # bfloat16 against the float32 reference at a tiny size
+    # bfloat16 against the float32 reference at a tiny size, on norm
+    # weights drawn from the seed: the total loss and each of its parts
     check = w["check"]
     assert abs(check["program_loss"] - check["reference_loss"]) \
         < 2e-2 * check["reference_loss"]
+    assert check["rtol"] == pytest.approx(1e-4 * (4096 / (check_rows * 64))
+                                          ** 0.5)
+    parts = ("loss", "aux_loss", "z_loss") if moe else ("loss",)
+    assert set(check["reference_parts"]) == set(parts) | {"total"}
+    for part in parts:
+        assert check["program_parts"][part] == pytest.approx(
+            check["reference_parts"][part], rel=3e-2), part
+    if moe:
+        assert check["step_metrics"] == {"moe_dropped": 0.0}
+        assert win["step_metrics"]["moe_dropped"] == 0
+        assert 1.0 <= win["step_metrics"]["moe_load_max_over_mean"] <= 8.0
+        assert _reader("moe.load_max_over_mean").read({"worker": w}) == \
+            win["step_metrics"]["moe_load_max_over_mean"]
+    else:
+        assert check["step_metrics"] == win["step_metrics"] == {}
+        assert _reader("moe.load_max_over_mean").read({"worker": w}) is None
     run = {"worker": w, "process_start": w["loop_start"] - 1.0}
     assert train.end_to_end(run)["train_tokens_per_s"] > 0
     assert train.end_to_end(run)["setup_s"] > 1.0
+
+    # correct(): a chip reports its memory, and bfloat16 at this size is
+    # outside the chip check's tolerance, so both are put right here
+    good = dict(w, peak_bytes_in_use=[1] * (4 if mesh else 1), check=dict(
+        check, program_loss=check["reference_loss"], token_nll_rms=0.0))
+    rows = train.compared({"worker": good})
+    assert [r["what"] for r in rows][:5] == [
+        "per-token loss apart from the reference's, RMS in nats",
+        "loss apart from the reference's, relative", "failed steps",
+        "compiles in the window", "memory peak, fullest chip over emptiest"]
+    assert all(r["ok"] for r in rows) and len(rows) == (6 if moe else 5)
+    assert train.correct({"worker": good}) is True
+    for broken in (
+            dict(check=dict(good["check"], token_nll_rms=1.001
+                            * check["token_nll_limit"])),
+            dict(check=dict(good["check"], token_nll_rms=float("nan"))),
+            dict(check=dict(check, program_loss=1.001
+                            * check["reference_loss"])),
+            dict(check=dict(check, program_loss=float("nan"))),
+            dict(window=dict(win, failed=1)),
+            dict(window=dict(win, compiles=1)),
+            dict(window=dict(win, steps=0)),
+            dict(window=dict(win, error="RuntimeError()")),
+            dict(peak_bytes_in_use=[0]), dict(peak_bytes_in_use=[10, 13])):
+        assert train.correct({"worker": dict(good, **broken)}) is False, broken
+    if moe:
+        assert train.correct({"worker": dict(good, window=dict(
+            win, step_metrics=dict(win["step_metrics"], moe_dropped=1.0)))
+        }) is False
+
+
+def test_a_missing_norm_shows_in_a_dense_model():
+    """Why the loop draws the norm weights of the parameters it checks: at
+    step 0 they are all 1 and what they norm has unit RMS, so a program
+    that left a norm out would read the same loss.  With the weights drawn,
+    the reference with one norm's weights back at 1 (the nearest thing to
+    the norm left out) is far outside the tolerance."""
+    import jax
+    import numpy as np
+
+    from benchmark.loops import train
+    from benchmark.reference import decoder
+    from ray_tpu.models.llama import init_params, loss_fn
+
+    cfg = train.program_config(TINY)
+    params = init_params(jax.random.PRNGKey(1), cfg)
+    tokens = jax.numpy.asarray(np.random.default_rng(1).integers(
+        0, cfg.vocab_size, (4, 65), dtype=np.int32))
+    rng = np.random.default_rng(2)
+    drawn = jax.tree_util.tree_map_with_path(
+        lambda path, a: a * rng.uniform(0.5, 1.5, a.shape).astype(np.float32)
+        if str(path[-1].key).endswith("norm") else a, params)
+    with jax.default_matmul_precision("highest"):
+        program = float(loss_fn(drawn, {"tokens": tokens}, cfg)[0])
+    parts = decoder.loss_parts(drawn, tokens, TINY)
+    reference = float(parts["total"])
+    assert float(parts["loss"]) == reference
+    assert abs(program - reference) <= 2e-6 * reference
+    rtol = decoder.loss_rtol(4 * 64)
+    for name in ("attn_norm", "mlp_norm"):
+        without = dict(drawn, layers=dict(
+            drawn["layers"], **{name: params["layers"][name]}))
+        apart = abs(float(decoder.loss(without, tokens, TINY)) - reference)
+        assert apart > 3 * rtol * reference, (name, apart / reference)
+
+
+@pytest.mark.parametrize("conf,seq", [(TINY, 64), (TINY_MOE, 64)],
+                         ids=["dense", "moe"])
+def test_control_readings_at_a_size_a_test_can_hold(conf, seq):
+    """``benchmark/control.py`` at CPU size, float32: the program's
+    per-token losses are the reference's to rounding (``sound``), and the
+    reference with its matrices through int8 (THE control), float8, or its
+    log-probabilities kept in bfloat16 stands far off.  What they read at
+    a cell's own size is a chip reading: PERF.md section 6, PR 29."""
+    import jax
+
+    from benchmark import control
+
+    conf = dict(conf, assumed={"param_dtype": {"value": "float32"},
+                               "dtype": {"value": "float32"}})
+    job = {"rows": 4, "seq": seq, "mesh": None, "check_rows": 4}
+    for seed in (2147483653, 7, 11):
+        got = control.readings(conf, job, seed, jax.devices())
+        assert got["limit"] == conf["check"]["token_nll_rms"]
+        assert got["sound"] < 1e-4 and got["mean_rel"] < 1e-5
+        for name in ("int8", "fp8", "bf16_logp"):
+            assert got[name] > 100 * got["sound"], (name, got)
+        assert got["int8"] < got["fp8"]
 
 
 # -------------------------------------------------------------- run.py --

@@ -7,8 +7,8 @@ import os
 
 import pytest
 
-from benchmark import flops_moe, trace_scopes
-from benchmark.loops import train_moe
+from benchmark import flops, flops_moe, trace_reduce
+from benchmark.loops import train
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
@@ -30,17 +30,12 @@ def _reader(metric):
 
 def test_published_widths_against_the_catalog_row():
     """Every key of the catalog row's ``config`` (model-configs guide,
-    ``architectures.jsonl``, OLMoE-1B-7B-0125-Instruct, as written in
-    ISSUE 25) is in the file unchanged; only depth is reduced."""
-    published = {
-        "attention_bias": False, "clip_qkv": None, "hidden_act": "silu",
-        "hidden_size": 2048, "intermediate_size": 1024,
-        "max_position_embeddings": 4096, "model_type": "olmoe",
-        "norm_topk_prob": False, "num_attention_heads": 16,
-        "num_experts": 64, "num_experts_per_tok": 8,
-        "num_hidden_layers": 16, "num_key_value_heads": 16,
-        "rms_norm_eps": 1e-05, "rope_scaling": None, "rope_theta": 10000,
-        "tie_word_embeddings": False, "vocab_size": 50304}
+    ``architectures.jsonl``, OLMoE-1B-7B-0125-Instruct: the copy under
+    ``testdata/published``) is in the file unchanged; only depth is reduced."""
+    with open(os.path.join(BENCH, "testdata", "published",
+                           NAME + ".json")) as f:
+        published = json.load(f)
+    assert (published["num_hidden_layers"], len(published)) == (16, 18)
     conf = _conf()
     differ = [k for k, v in published.items() if conf[k] != v]
     assert differ == list(conf["reduced"]) == ["num_hidden_layers"]
@@ -53,7 +48,7 @@ def test_published_widths_against_the_catalog_row():
     assert entry["reduced"] == ["num_hidden_layers"]
     assert entry["source"] == conf["source"]
     # what the program is given, through the file's own map
-    cfg = train_moe.program_config(conf)
+    cfg = train.program_config(conf)
     assert (cfg.embed_dim, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
             cfg.mlp_dim, cfg.vocab_size) == (2048, 16, 16, 128, 1024, 50304)
     assert (cfg.num_experts, cfg.num_selected, cfg.norm_topk_prob,
@@ -147,8 +142,8 @@ def _planes():
     return planes, names
 
 
-def test_trace_scopes_and_the_flash_correction_on_synthetic_planes():
-    from benchmark import trace_reduce
+def test_scopes_kernels_and_readers_on_synthetic_planes():
+    from benchmark import trace_scopes
 
     assert trace_scopes.scope_and_phase(
         "jit(step)/transpose(jvp(moe_experts))/moe_tgmm") == (
@@ -162,9 +157,9 @@ def test_trace_scopes_and_the_flash_correction_on_synthetic_planes():
     assert trace_scopes.kernel_name("a/moe_experts/dot") == "unnamed"
 
     planes, names = _planes()
-    scoped = trace_scopes.reduce_planes(planes, names,
-                                        step_module="jit_step")
-    d = scoped[0]
+    trace = trace_reduce.reduce_planes(planes, step_module="jit_step",
+                                       annotations=(), names=names)
+    d, = trace["devices"]
     ns = 1e-9
     assert d["steps"] == 2
     assert d["scopes"]["moe_experts"] == {
@@ -177,24 +172,22 @@ def test_trace_scopes_and_the_flash_correction_on_synthetic_planes():
     assert d["kernels"] == {"flash_fwd": pytest.approx(100 * ns),
                             "moe_gmm.remat": pytest.approx(300 * ns),
                             "moe_tgmm": pytest.approx(200 * ns)}
-    assert d["flash_s"] == pytest.approx(100 * ns)
-
-    # trace_reduce counts every Mosaic call as flash; the loop corrects it
-    trace = trace_reduce.reduce_planes(planes, step_module="jit_step",
-                                       annotations=())
-    assert trace["devices"][0]["flash_s"] == pytest.approx(2 * 600 * ns)
-    trace = train_moe.by_scope(trace, scoped)
-    dev, = trace["devices"]
-    assert dev["flash_s"] == pytest.approx(2 * 100 * ns)
-    assert dev["all_kernels_s"] == pytest.approx(2 * 600 * ns)
+    # every Mosaic call is a kernel; flash is those named flash_* alone
+    assert d["kernels_s"] == pytest.approx(2 * 600 * ns)
+    assert d["flash_s"] == pytest.approx(2 * 100 * ns)
+    assert d["device_ops"][0] == [
+        "moe_experts/remat custom-call.7 custom-call bf16[4096,2048]",
+        pytest.approx(2 * 300 * ns)]
 
     conf = dict(_conf())
-    run = {"worker": {"trace": trace, "window": {
-               "moe_load_max_over_mean": [1.1, 1.25, 1.2]}},
+    assert flops.of(conf) is flops_moe
+    run = {"worker": {"trace": trace, "window": {"step_metrics": {
+               "moe_dropped": 0.0, "moe_load_max_over_mean": 1.25}}},
            "conf": conf, "job": {"rows": 4, "seq": 4096}, "chips": 1,
            "peak": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
            "end_to_end": {"train_tokens_per_s": 50000.0}}
-    step_s = sum(dev["step_s"]) / 2
+    step_s = sum(d["step_s"]) / 2
+    assert step_s == pytest.approx(900 * ns)
     assert _reader("moe.time_share_pct").read(run) == pytest.approx(
         100 * 590 * ns / step_s)
     assert _reader("moe.dispatch_ms").read(run) == pytest.approx(90e-6)
@@ -204,89 +197,46 @@ def test_trace_scopes_and_the_flash_correction_on_synthetic_planes():
     assert _reader("flash.time_share_pct").read(run) == pytest.approx(
         100 * 100 * ns / step_s)
     assert _reader("moe.load_max_over_mean").read(run) == 1.25
-    assert _reader("train_step.moe_mfu_pct").read(run) == pytest.approx(
+    # the one MFU reader counts with the module the configuration names:
+    # eight experts and the router a token, not flops.py's one FFN
+    assert _reader("train_step.mfu_pct").read(run) == pytest.approx(
         100 * 50000.0 * flops_moe.train_flops_per_token(conf, 4096) / 197e12)
+    assert _reader("train_step.mfu_pct").read(run) > 1.5 * _reader(
+        "train_step.mfu_pct").read(dict(run, conf=dict(conf, flops="flops")))
+    # the step by scope: the shares, and the kernels' milliseconds
+    shares = {m: _reader("step." + m + "_pct").read(run) for m in (
+        "ffn", "attn_proj", "attention", "head_loss", "optimizer", "remat",
+        "scan", "unscoped")}
+    assert shares == {
+        "ffn": 0.0, "attn_proj": None, "head_loss": None,
+        "attention": pytest.approx(100 * 100 / 900),
+        "optimizer": pytest.approx(100 * 80 / 900),
+        "remat": pytest.approx(100 * 300 / 900),
+        "scan": pytest.approx(100 * 20 / 900),
+        "unscoped": pytest.approx(100 * 20 / 900)}
+    assert sum(v for m, v in shares.items() if v and m != "remat") \
+        + _reader("moe.time_share_pct").read(run) == pytest.approx(
+            100 * 810 / 900)  # the trace's ops; 90 of the 900 ns are idle
+    assert _reader("flash.fwd_ms").read(run) == pytest.approx(100e-6)
+    assert _reader("flash.dkv_ms").read(run) is None
+    assert _reader("flash.dq_ms").read(run) is None
 
-    # a program from before the scopes: nothing to read, nothing raised
+    # a trace without name stacks (a program from before the scopes), and
+    # a run without a trace: nothing to read, nothing raised
     bare = trace_reduce.reduce_planes(planes, step_module="jit_step",
                                       annotations=())
-    old = {"worker": {"trace": bare, "window": {}}, "conf": {"a": 1},
-           "job": run["job"], "chips": 1, "peak": run["peak"],
-           "end_to_end": run["end_to_end"]}
+    old = dict(run, worker={"trace": bare, "window": {}})
+    untraced = dict(old, worker={"trace": None, "window": {}})
     for metric in ("moe.time_share_pct", "moe.dispatch_ms",
                    "moe.experts_roofline", "moe.load_max_over_mean",
-                   "train_step.moe_mfu_pct"):
+                   "step.attention_pct", "step.scan_pct", "step.remat_pct",
+                   "flash.fwd_ms"):
         assert _reader(metric).read(old) is None, metric
-        assert _reader(metric).read(
-            dict(old, worker={"trace": None, "window": {}})) is None
-
-
-def _rehearsal_loop(config):
-    """Test-only entry: the loop without the chip requirement."""
-    import time
-
-    import jax
-
-    from benchmark.loops import train_moe
-    from ray_tpu.air import session
-
-    session.report(train_moe.measure(config, jax.devices(),
-                                     {"loop_start": time.time()}))
-
-
-def test_train_moe_loop_rehearsal_on_cpu_worker():
-    """The whole loop at a tiny config through JaxTrainer.fit() with a
-    CPU worker.  Asserts the shape of what comes back, no speed."""
-    import ray_tpu as ray
-    from ray_tpu.air.config import ScalingConfig
-    from ray_tpu.train import JaxTrainer
-
-    job = {"loop": "train_moe", "rows": 4, "seq": 64, "mesh": None,
-           "check_rows": 2, "warmup_steps": 2, "traced_steps": 2}
-    conf = dict(_conf(), hidden_size=64, num_attention_heads=4,
-                num_key_value_heads=4, intermediate_size=32, vocab_size=256,
-                num_experts=8, num_experts_per_tok=3, num_hidden_layers=2)
-    ray.init(num_cpus=4, num_tpus=0)
-    try:
-        result = JaxTrainer(
-            _rehearsal_loop,
-            train_loop_config={"conf": conf, "job": job, "chips": 0,
-                               "peaks": {}, "seed": 2147483653,
-                               "seconds": 1.0, "trace": True,
-                               "trace_dir": None},
-            scaling_config=ScalingConfig(num_workers=1,
-                                         tpu_chips_per_worker=0)).fit()
-    finally:
-        ray.shutdown()
-    assert result.error is None, result.error
-    w = result.metrics
-    win = w["window"]
-    assert win["attempted"] == win["steps"] >= 1 and win["failed"] == 0
-    assert win["tokens"] == win["steps"] * 4 * 64
-    assert win["compiles"] == 0 and win["error"] is None
-    assert win["moe_dropped"] == 0
-    assert len(win["moe_load_max_over_mean"]) == win["steps"]
-    assert all(1.0 <= x <= 8.0 for x in win["moe_load_max_over_mean"])
-    assert w["trace"] is None  # a CPU trace has no device plane to read
-    assert len(result.metrics_history) == 2 + win["steps"] + 3 + 1
-    # bfloat16 against the float32 reference at a tiny size: the total
-    # loss and each of its three parts
-    check = w["check"]
-    assert abs(check["program_loss"] - check["reference_loss"]) \
-        < 2e-2 * check["reference_loss"]
-    for part in ("loss", "aux_loss", "z_loss"):
-        assert check["program_parts"][part] == pytest.approx(
-            check["reference_parts"][part], rel=3e-2), part
-    assert check["program_parts"]["moe_dropped"] == 0
-    run = {"worker": w, "process_start": w["loop_start"] - 1.0}
-    assert train_moe.end_to_end(run)["train_tokens_per_s"] > 0
-    # correct(): the dense loop's conditions (a chip reports its memory;
-    # bfloat16 at this size is outside the chip check's tolerance), and
-    # no dropped assignment
-    good = dict(w, peak_bytes_in_use=[1], check=dict(
-        check, program_loss=check["reference_loss"]))
-    assert train_moe.correct({"worker": good}) is True
-    assert train_moe.correct({"worker": dict(good, window=dict(
-        win, moe_dropped=1.0))}) is False
-    assert train_moe.correct({"worker": dict(good, check=dict(
-        check, program_loss=1.001 * check["reference_loss"]))}) is False
+        assert _reader(metric).read(untraced) is None
+    # ``step.ffn_pct`` carries no list of cells: nothing under ``ffn`` in a
+    # traced step (the MoE run above) is the share 0, no step is no share
+    assert _reader("step.ffn_pct").read(run) == 0.0
+    assert _reader("step.ffn_pct").read(old) == 0.0
+    assert _reader("step.ffn_pct").read(untraced) is None
+    assert _reader("step.unscoped_pct").read(old) == pytest.approx(
+        100 * 810 / 900)

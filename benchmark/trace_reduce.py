@@ -16,13 +16,14 @@ trace of this repository's train step holds (looked at by hand, PR 22;
   ``Async XLA Ops`` holds copies that run beside the op stream; it is not
   read.  (``Steps`` repeats the modules; ``TC Overlay`` was empty.)
 - the Mosaic kernels are the ``custom-call`` ops whose target is
-  ``tpu_custom_call``; unnamed as they are today they appear as
-  ``closed_call.N`` (flash forward), ``rematted_computation.N`` (the same
-  forward, rematerialised) and ``checkpoint.N`` (dKV and dQ).  Other
-  custom calls (``AllocateBuffer``, ``ConcatBitcast``) take no time.
+  ``tpu_custom_call``.  Each is named by what its ``pallas_call``'s
+  ``name=`` left on the op's JAX name stack (``trace_scopes.py``; in a
+  trace from before PR 23 they are ``unnamed``).  Other custom calls
+  (``AllocateBuffer``, ``ConcatBitcast``) take no time.
 - ``/host:CPU`` has one line per host thread; the loop's
   ``TraceAnnotation`` spans are events on the line ``python3``, on the
-  same clock as the device lines.
+  same clock as the device lines, and so are the program's own spans
+  (``ray_tpu.util.tracing.span``) that opened while the profiler ran.
 
 Definitions (one chip; a mesh reports the worst chip where it says so):
 
@@ -32,10 +33,16 @@ Definitions (one chip; a mesh reports the worst chip where it says so):
   the last step, so it holds every measured step and every gap before one.
 - busy: the union of the ``XLA Ops`` intervals inside the window.
   idle = window - busy, exactly.
-- a gap: a maximal idle interval; labelled with the host annotation that
-  overlaps it longest, else ``unannotated``.
-- flash: the Mosaic custom calls (these programs have no other
-  kernel).  collectives: all-gather, all-reduce,
+- a gap: a maximal idle interval; labelled with the shortest of the
+  program's spans that covers it, then ``/`` and the loop's annotation
+  that overlaps it longest (either alone where there is only one), else
+  ``unannotated``.
+- every op has a scope and a phase (``trace_scopes.py``): ``scopes``,
+  ``kernels`` and ``unscoped_s`` are device seconds A STEP by them, and a
+  ``device_ops`` label starts ``<scope>/<phase>``.
+- kernels: the Mosaic custom calls (``kernels_s``); flash: those whose
+  kernel name starts ``flash_`` (``flash_s``).  Both over the whole
+  window.  collectives: all-gather, all-reduce,
   reduce-scatter, collective-permute, all-to-all, with their async
   ``-start``/``-done`` halves.  An op line is one stream: whatever time a
   collective event takes there, no compute runs beside it on that chip,
@@ -48,6 +55,8 @@ import functools
 import re
 import sys
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+
+from benchmark import trace_scopes
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
@@ -125,22 +134,39 @@ def clip(events: Sequence[Event], a: int, b: int) -> List[Event]:
             if e > a and s < b]
 
 
-def _label(gap: Tuple[int, int], spans: Sequence[Event]) -> str:
-    best, best_ns = "unannotated", 0
-    for name, s, e in spans:
+def _label(gap: Tuple[int, int], annotated: Sequence[Event],
+           spans: Sequence[Event]) -> str:
+    covering = [(e - s, name) for name, s, e in spans
+                if s <= gap[0] and gap[1] <= e]
+    best, best_ns = "", 0
+    for name, s, e in annotated:
         ns = min(e, gap[1]) - max(s, gap[0])
         if ns > best_ns:
             best, best_ns = name, ns
-    return best
+    parts = ([min(covering)[1]] if covering else []) + ([best] if best else [])
+    return "/".join(parts) or "unannotated"
 
 
 def reduce_planes(planes: Dict[str, Dict[str, List[Event]]], *,
-                  step_module: str, annotations: Sequence[str]
+                  step_module: str, annotations: Sequence[str],
+                  spans: Sequence[str] = (),
+                  names: Optional[Dict[str, Dict[str, str]]] = None,
+                  scopes: Sequence[str] = (), kernels: Sequence[str] = ()
                   ) -> Optional[Dict]:
     """The reduction; None when the trace has no device plane with at
-    least two executions of ``step_module`` (nothing to read)."""
-    spans = [e for lines in (planes.get("/host:CPU") or {}).values()
-             for e in lines if e[0] in annotations]
+    least two executions of ``step_module`` (nothing to read).
+
+    ``annotations`` are the loop's own ``TraceAnnotation`` names and
+    ``spans`` the program's span names, both looked for on the host's
+    lines.  ``names`` is ``trace_scopes.op_names`` of the same file
+    (without it every op is ``unscoped``); ``scopes`` and ``kernels`` are
+    what the configuration adds to ``trace_scopes``' own tuples."""
+    host = [e for lines in (planes.get("/host:CPU") or {}).values()
+            for e in lines]
+    annotated = [e for e in host if e[0] in annotations]
+    program = [e for e in host if e[0] in spans]
+    scopes = trace_scopes.SCOPES + tuple(scopes)
+    kernels = trace_scopes.KERNELS + tuple(kernels)
     devices = []
     for plane_name, lines in sorted(planes.items()):
         m = DEVICE_PLANE.match(plane_name)
@@ -157,17 +183,38 @@ def reduce_planes(planes: Dict[str, Dict[str, List[Event]]], *,
         edges = [a] + [t for iv in busy for t in iv] + [b]
         gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
                 if edges[i + 1] > edges[i]]
+        op_name = (names or {}).get(plane_name, {})
+        placed: Dict[str, Tuple] = {}  # an op text, parsed and placed once
         by_name: Dict[str, int] = {}
-        flash_ns = coll_ns = coll_n = 0
+        by_scope: Dict[str, Dict[str, int]] = {}
+        by_kernel: Dict[str, int] = {}
+        unscoped_ns = coll_ns = coll_n = 0
         for text, ns in self_times(ops):
-            _, opcode, label = parse_op(text)
+            if text not in placed:
+                _, opcode, label = parse_op(text)
+                stack = op_name.get(text, "")
+                scope, phase = trace_scopes.scope_and_phase(stack, scopes)
+                kernel = None
+                if opcode == "custom-call" and MOSAIC_TARGET in text:
+                    kernel = trace_scopes.kernel_name(stack, kernels) + (
+                        ".remat" if phase == "remat" else "")
+                placed[text] = (
+                    scope, phase, opcode, kernel,
+                    f"{scope or 'unscoped'}/{phase} {label}"[:120])
+            scope, phase, opcode, kernel, label = placed[text]
             by_name[label] = by_name.get(label, 0) + ns
-            if opcode == "custom-call" and MOSAIC_TARGET in text:
-                flash_ns += ns
+            if scope is None:
+                unscoped_ns += ns
+            else:
+                row = by_scope.setdefault(scope, {})
+                row[phase] = row.get(phase, 0) + ns
+            if kernel is not None:
+                by_kernel[kernel] = by_kernel.get(kernel, 0) + ns
             elif COLLECTIVE.match(opcode):
                 coll_ns += ns
                 coll_n += not opcode.endswith("-done")
         measured = steps[1:]
+        per_step = 1e9 * len(measured)
         devices.append({
             "device": int(m.group(1)),
             "steps": len(measured),
@@ -177,25 +224,35 @@ def reduce_planes(planes: Dict[str, Dict[str, List[Event]]], *,
             "step_s": [(e - s) / 1e9 for _, s, e in measured],
             "gap_s": [(measured[i][1] - steps[i][2]) / 1e9
                       for i in range(len(measured))],
-            "flash_s": flash_ns / 1e9,
+            "kernels_s": sum(by_kernel.values()) / 1e9,
+            "flash_s": sum(t for k, t in by_kernel.items()
+                           if k.startswith("flash_")) / 1e9,
+            "scopes": {scope: {p: t / per_step for p, t in row.items()}
+                       for scope, row in by_scope.items()},
+            "kernels": {k: t / per_step for k, t in by_kernel.items()},
+            "unscoped_s": unscoped_ns / per_step,
             "collective_s": coll_ns / 1e9,
             "collectives_per_step": coll_n / len(measured),
             "device_ops": [[n, t / 1e9] for n, t in sorted(
                 by_name.items(), key=lambda kv: -kv[1])[:10]],
-            "idle_gaps": [[_label(g, spans), (g[1] - g[0]) / 1e9]
+            "idle_gaps": [[_label(g, annotated, program), (g[1] - g[0]) / 1e9]
                           for g in sorted(gaps, key=lambda g: g[0] - g[1])[:5]],
         })
     if not devices:
         return None
     return {"step_module": step_module, "devices": devices,
-            "host_spans": {n: sum(1 for e in spans if e[0] == n)
+            "host_spans": {n: sum(1 for e in annotated if e[0] == n)
                            for n in annotations}}
 
 
-def reduce_file(path: str, *, step_module: str,
-                annotations: Sequence[str]) -> Optional[Dict]:
+def reduce_file(path: str, *, step_module: str, annotations: Sequence[str],
+                spans: Sequence[str] = (), scopes: Sequence[str] = (),
+                kernels: Sequence[str] = ()) -> Optional[Dict]:
+    with open(path, "rb") as f:
+        names = trace_scopes.op_names(f.read())
     return reduce_planes(load(path), step_module=step_module,
-                         annotations=annotations)
+                         annotations=annotations, spans=spans, names=names,
+                         scopes=scopes, kernels=kernels)
 
 
 def overview(path: str, top: int = 12) -> str:

@@ -2,9 +2,9 @@
 of the yardstick: a COPY of what ``ray_tpu/util/tracing.py`` does for the
 operator's ``step-breakdown`` (its ``op_names`` and ``scope_and_phase``;
 the self-time walk is ``trace_reduce.self_times``), so that no change to
-the program's tool can move a metric.  ``loops/train_moe.py`` reduces with
-it before the trace file is deleted; ``loops/train.py`` can adopt it the
-same way (PERF.md §7, ROADMAP S1b).
+the program's tool can move a metric.  ``trace_reduce.py`` lays it over
+every traced run of every cell, and the readers under ``layer_metrics/``
+take their rows through the helpers at the end of this file.
 
 What the trace gives (TPU v5e, jaxlib 0.9; looked at by hand, PR 23): every
 event of the line ``XLA Ops`` has, in its METADATA, the stat ``tf_op``: the
@@ -17,22 +17,23 @@ map is read from the file's protobuf wire format (``XSpace.planes=1``;
 
 The scope of an op is the first element of its name stack that is one of
 ``SCOPES`` — the names ``ray_tpu.train.core.STEP_SCOPES`` had when this
-file was written; a program without one of them (the parent of the PR
-that adds it) simply has no time under it.  Ops of the layer scan itself
+file was written — or of the names a configuration file lists under its
+optional ``"scopes"``: a model that opens new scopes brings them as data.
+A program without one of them (the parent of the PR that adds it) simply
+has no time under it.  Ops of the layer scan itself
 carry a ``while`` and no scope: row ``scan``.  The phase: scope
 ``optimizer`` is its own; else a stack holding ``rematted_computation``
 is the rematerialised forward, else one holding ``transpose(`` the
 backward pass, else forward.  A Mosaic kernel (custom call to
 ``tpu_custom_call``) is named by the stack element its ``pallas_call``'s
-``name=`` left: one that starts with one of ``KERNELS``.
+``name=`` left: one that starts with one of ``KERNELS`` or of the
+configuration's optional ``"kernels"``.
 """
 
 from __future__ import annotations
 
 import re
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
-
-from benchmark import trace_reduce
+from typing import Any, Dict, Iterable, Optional, Sequence, Tuple
 
 SCOPES = ("embed", "attn_qkv", "attention", "attn_out", "ffn", "moe_route",
           "moe_dispatch", "moe_experts", "moe_combine", "lm_head", "loss",
@@ -59,9 +60,9 @@ def scope_and_phase(op_name: str, scopes: Sequence[str] = SCOPES
     return scope, "forward"
 
 
-def kernel_name(op_name: str) -> str:
+def kernel_name(op_name: str, kernels: Sequence[str] = KERNELS) -> str:
     return next((t for t in _TOKENS.findall(op_name)
-                 if t.startswith(KERNELS)), "unnamed")
+                 if t.startswith(tuple(kernels))), "unnamed")
 
 
 def _varint(buf: bytes, i: int) -> Tuple[int, int]:
@@ -132,58 +133,44 @@ def op_names(xplane: bytes) -> Dict[str, Dict[str, str]]:
     return out
 
 
-def reduce_planes(planes: Dict[str, Dict[str, List[trace_reduce.Event]]],
-                  names: Dict[str, Dict[str, str]], *, step_module: str
-                  ) -> Dict[int, Dict[str, Any]]:
-    """device number -> device seconds PER STEP over ``trace_reduce``'s
-    window (the first execution of ``step_module`` is a lead-in):
-    ``scopes`` {scope: {phase: s}}, ``unscoped_s``, ``kernels`` {name
-    (``.remat`` for the rematerialised forward): s}, ``flash_s`` (the
-    kernels named ``flash_*`` alone) and ``steps``."""
-    out: Dict[int, Dict[str, Any]] = {}
-    for plane_name, lines in planes.items():
-        m = trace_reduce.DEVICE_PLANE.match(plane_name)
-        if not m or trace_reduce.OPS_LINE not in lines:
-            continue
-        steps = [e for e in lines.get(trace_reduce.MODULES_LINE, ())
-                 if e[0] == step_module or e[0].startswith(step_module + "(")]
-        if len(steps) < 2:
-            continue
-        n = len(steps) - 1
-        ops = trace_reduce.clip(lines[trace_reduce.OPS_LINE],
-                                steps[0][2], steps[-1][2])
-        op_name = names.get(plane_name, {})
-        scopes: Dict[str, Dict[str, float]] = {}
-        kernels: Dict[str, float] = {}
-        unscoped = 0.0
-        for text, ns in trace_reduce.self_times(ops):
-            stack = op_name.get(text, "")
-            scope, phase = scope_and_phase(stack)
-            if scope is None:
-                unscoped += ns / n / 1e9
-            else:
-                row = scopes.setdefault(scope, {})
-                row[phase] = row.get(phase, 0.0) + ns / n / 1e9
-            if trace_reduce.MOSAIC_TARGET in text \
-                    and trace_reduce.parse_op(text)[1] == "custom-call":
-                key = kernel_name(stack) + (
-                    ".remat" if phase == "remat" else "")
-                kernels[key] = kernels.get(key, 0.0) + ns / n / 1e9
-        out[int(m.group(1))] = {
-            "steps": n, "scopes": scopes, "unscoped_s": unscoped,
-            "kernels": kernels,
-            "flash_s": sum(t for k, t in kernels.items()
-                           if k.startswith("flash_"))}
-    return out
+# ------------------------------------------------ what the readers share --
+
+def device(run: Dict[str, Any]) -> Optional[Dict[str, Any]]:
+    """The device a reader of scopes or kernels reads: of a traced run
+    the chip whose traced steps took longest (every such reader the same
+    one, so their shares add up); None where the run has no trace."""
+    trace = run["worker"].get("trace")
+    if not trace:
+        return None
+    return max(trace["devices"], key=lambda d: sum(d["step_s"]))
 
 
-def reduce_file(path: str, *, step_module: str) -> Dict[int, Dict[str, Any]]:
-    with open(path, "rb") as f:
-        names = op_names(f.read())
-    return reduce_planes(trace_reduce.load(path), names,
-                         step_module=step_module)
+def scope_seconds(device: Dict[str, Any], scopes: Sequence[str],
+                  phases: Sequence[str] = PHASES) -> float:
+    """Seconds a step of one device's ``scopes`` rows, in ``phases``."""
+    return sum(device["scopes"].get(s, {}).get(p, 0.0)
+               for s in scopes for p in phases)
 
 
-def scope_seconds(device: Dict[str, Any], scopes: Sequence[str]) -> float:
-    """Seconds a step of one device's ``scopes`` rows, all phases."""
-    return sum(sum(device["scopes"].get(s, {}).values()) for s in scopes)
+def step_share_pct(run: Dict[str, Any], scopes: Optional[Sequence[str]],
+                   phases: Sequence[str] = PHASES) -> Optional[float]:
+    """Device self time a step under ``scopes`` (None: every scope the
+    trace has) in ``phases``, as a share of the traced steps' device time;
+    None where the trace has nothing there."""
+    d = device(run)
+    if d is None:
+        return None
+    seconds = scope_seconds(d, d["scopes"] if scopes is None else scopes,
+                            phases)
+    return 100.0 * seconds * d["steps"] / sum(d["step_s"]) or None
+
+
+def kernel_ms(run: Dict[str, Any], prefix: str) -> Optional[float]:
+    """Device milliseconds a step in the Mosaic kernels whose name (with
+    ``.remat`` where rematerialised) starts with ``prefix``; None where
+    the trace has none."""
+    d = device(run)
+    if d is None:
+        return None
+    return 1e3 * sum(t for k, t in d["kernels"].items()
+                     if k.startswith(prefix)) or None
