@@ -1,4 +1,6 @@
-"""Flagship model family: Llama-style decoder LM (dense or MoE), TPU-first.
+"""Flagship model family: Llama-style decoder LM, TPU-first.  A layer is a
+MIXER (softmax attention | a Mamba-2 state-space mixer) followed by an FFN
+(dense SwiGLU | dropless experts), and a model is a pattern of such layers.
 
 Pure-functional design: params are a pytree of arrays, every tensor
 dimension has a *logical axis name*, and one rules table
@@ -19,6 +21,11 @@ TPU-first choices:
   ``num_selected`` experts through a grouped matmul over the sorted
   assignments; expert tensors are sharded over 'ep', each rank computes its
   own experts' rows inside a shard_map and the partial outputs are summed.
+- a model whose layers differ (``layer_types``: granite-4.0-h's Mamba-2
+  layers with an attention layer every tenth) is scanned by maximal RUNS of
+  one kind, each run one ``lax.scan`` over its own stacked parameters
+  (``params["layers"]`` is then a tuple of stacks, one a run); a model of
+  one kind is one run and ``params["layers"]`` the one stack.
 
 Reference counterpart: none in Ray core (no tensor ops); RLlib's model zoo
 (``rllib/models/catalog.py``) plays the "models shipped with the framework"
@@ -34,6 +41,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops import attention, moe
@@ -44,6 +52,7 @@ from ray_tpu.ops.layers import (
     rms_norm, rope, apply_rope, swiglu, repeat_kv_heads,
 )
 from ray_tpu.ops.moe import moe_block
+from ray_tpu.ops.ssm import causal_conv1d, gated_rms_norm, ssd_chunked
 from ray_tpu.parallel.mesh import (
     AXIS_DP, AXIS_EP, AXIS_FSDP, AXIS_SP, AXIS_TP,
 )
@@ -74,6 +83,33 @@ class LlamaConfig:
     norm_eps: float = 1e-6            # every RMSNorm
     qk_norm: bool = False             # RMSNorm over the q and k projections
     remat: bool = True
+    # The mixer of each layer, "attention" | "mamba"; only the first
+    # ``num_layers`` entries are the model, empty = attention everywhere.
+    layer_types: Tuple[str, ...] = ()
+    ssm_heads: int = 0                # Mamba-2: heads x head_dim = inner width
+    ssm_head_dim: int = 64
+    ssm_state: int = 128              # state size a head (d_state)
+    ssm_groups: int = 1               # groups that share B and C
+    ssm_conv: int = 4                 # width of the causal depthwise conv
+    ssm_chunk: int = 256              # tokens a chunk of the scan
+    position_embedding: str = "rope"  # rope | nope (no position signal)
+    attention_multiplier: Optional[float] = None  # None: head_dim ** -0.5
+    embedding_multiplier: float = 1.0  # on the embedded tokens
+    residual_multiplier: float = 1.0  # on what each block adds to the stream
+    logits_scaling: float = 1.0       # logits are divided by it
+    tie_embeddings: bool = False      # the head reads the embedding table
+
+    def __post_init__(self):
+        # a configuration file hands a list: keep the config hashable
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        unknown = set(self.layer_types) - set(_MIXERS)
+        if unknown:
+            raise ValueError(
+                f"layer_types {sorted(unknown)}: not in {sorted(_MIXERS)}")
+        if self.layer_types and len(self.layer_types) < self.num_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"num_layers is {self.num_layers}")
 
     @property
     def qkv_dim(self) -> int:
@@ -82,6 +118,28 @@ class LlamaConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """What the convolution runs over: x, B and C side by side."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def layer_runs(self) -> Tuple[Tuple[str, int], ...]:
+        """The model as maximal runs of one mixer: ((kind, layers), ...)."""
+        kinds = self.layer_types[:self.num_layers] or (
+            ("attention",) * self.num_layers)
+        runs = []
+        for kind in kinds:
+            if runs and runs[-1][0] == kind:
+                runs[-1][1] += 1
+            else:
+                runs.append([kind, 1])
+        return tuple((kind, n) for kind, n in runs)
 
     @staticmethod
     def llama2_7b(**kw) -> "LlamaConfig":
@@ -103,73 +161,158 @@ class LlamaConfig:
         return LlamaConfig(**defaults)
 
 
-def _dense_layer_shapes(cfg: LlamaConfig) -> Dict[str, Tuple[Tuple[int, ...],
-                                                             Tuple]]:
-    """name -> (shape-per-layer, logical axes incl. the stacked 'layer' dim)."""
-    d, h, kvd, m = cfg.embed_dim, cfg.qkv_dim, cfg.kv_dim, cfg.mlp_dim
+def _attention_shapes(cfg: LlamaConfig):
+    d, h, kvd = cfg.embed_dim, cfg.qkv_dim, cfg.kv_dim
     shapes = {
         "attn_norm": ((d,), ("layer", "embed")),
         "wq": ((d, h), ("layer", "kernel_in", "heads")),
         "wk": ((d, kvd), ("layer", "kernel_in", "kv_heads")),
         "wv": ((d, kvd), ("layer", "kernel_in", "kv_heads")),
         "wo": ((h, d), ("layer", "heads", "kernel_in")),
-        "mlp_norm": ((d,), ("layer", "embed")),
     }
     if cfg.qk_norm:  # over the whole projection, before heads and RoPE
         shapes.update({"q_norm": ((h,), ("layer", "heads")),
                        "k_norm": ((kvd,), ("layer", "kv_heads"))})
+    return shapes
+
+
+def _mamba_shapes(cfg: LlamaConfig):
+    """A Mamba-2 mixer: ``ssm_in`` gives [z | x B C | dt] side by side
+    (the published layout of ``in_proj``); the convolution runs over
+    x, B and C; ``dt_bias``, ``A_log`` and ``D`` are a number a head."""
+    d, inner, conv = cfg.embed_dim, cfg.ssm_inner, cfg.ssm_conv_dim
+    return {
+        "ssm_norm": ((d,), ("layer", "embed")),
+        "ssm_in": ((d, inner + conv + cfg.ssm_heads),
+                   ("layer", "kernel_in", "ssm_inner")),
+        "conv_w": ((cfg.ssm_conv, conv), ("layer", None, "ssm_inner")),
+        "conv_b": ((conv,), ("layer", "ssm_inner")),
+        "dt_bias": ((cfg.ssm_heads,), ("layer", None)),
+        "A_log": ((cfg.ssm_heads,), ("layer", None)),
+        "D": ((cfg.ssm_heads,), ("layer", None)),
+        "gate_norm": ((inner,), ("layer", "ssm_inner")),
+        "ssm_out": ((inner, d), ("layer", "ssm_inner", "kernel_in")),
+    }
+
+
+def _ffn_shapes(cfg: LlamaConfig):
+    d, m = cfg.embed_dim, cfg.mlp_dim
     if cfg.num_experts:
         e = cfg.num_experts
-        shapes.update({
+        return {
+            "mlp_norm": ((d,), ("layer", "embed")),
             "router": ((d, e), ("layer", "kernel_in", None)),
             "w_gate": ((e, d, m), ("layer", "expert", "kernel_in", "mlp")),
             "w_up": ((e, d, m), ("layer", "expert", "kernel_in", "mlp")),
             "w_down": ((e, m, d), ("layer", "expert", "mlp", "kernel_in")),
-        })
-    else:
-        shapes.update({
-            "w_gate": ((d, m), ("layer", "kernel_in", "mlp")),
-            "w_up": ((d, m), ("layer", "kernel_in", "mlp")),
-            "w_down": ((m, d), ("layer", "mlp", "kernel_in")),
-        })
-    return shapes
-
-
-def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
-    layers = {k: ax for k, (_, ax) in _dense_layer_shapes(cfg).items()}
+        }
     return {
-        "embed": ("vocab", "kernel_in"),
-        "layers": layers,
-        "final_norm": ("embed",),
-        "lm_head": ("kernel_in", "vocab"),
+        "mlp_norm": ((d,), ("layer", "embed")),
+        "w_gate": ((d, m), ("layer", "kernel_in", "mlp")),
+        "w_up": ((d, m), ("layer", "kernel_in", "mlp")),
+        "w_down": ((m, d), ("layer", "mlp", "kernel_in")),
     }
 
 
+_MIXER_SHAPES = {"attention": _attention_shapes, "mamba": _mamba_shapes}
+
+
+def _layer_shapes(cfg: LlamaConfig, mixer: str = "attention"
+                  ) -> Dict[str, Tuple[Tuple[int, ...], Tuple]]:
+    """name -> (shape-per-layer, logical axes incl. the stacked 'layer'
+    dim) of a layer with this mixer: the mixer's tensors, then the FFN's."""
+    return {**_MIXER_SHAPES[mixer](cfg), **_ffn_shapes(cfg)}
+
+
+def _per_run(runs: list):
+    """``params["layers"]`` (or a tree shaped like it) from one entry a
+    run: the entry itself for a model of one kind of layer."""
+    return runs[0] if len(runs) == 1 else tuple(runs)
+
+
+def _runs(cfg: LlamaConfig, layers) -> list:
+    """[(mixer, that run's entry of ``layers``), ...]: ``_per_run``'s
+    inverse."""
+    runs = cfg.layer_runs
+    if len(runs) == 1:
+        layers = (layers,)
+    return [(kind, lp) for (kind, _), lp in zip(runs, layers)]
+
+
+def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
+    axes = {
+        "embed": ("vocab", "kernel_in"),
+        "layers": _per_run([
+            {k: ax for k, (_, ax) in _layer_shapes(cfg, kind).items()}
+            for kind, _ in cfg.layer_runs]),
+        "final_norm": ("embed",),
+    }
+    if not cfg.tie_embeddings:
+        axes["lm_head"] = ("kernel_in", "vocab")
+    return axes
+
+
+def _ssm_init(name: str, key: jax.Array, shape, cfg: LlamaConfig):
+    """The Mamba-2 reference code's initialisation of what is not a
+    projection: A uniform in 1..16 (kept as its log), dt log-uniform in
+    1e-3..1e-1 through the inverse of the softplus it passes, D = 1, the
+    convolution as torch's ``Conv1d`` (uniform within 1/sqrt(width))."""
+    if name == "D":
+        return jnp.ones(shape, jnp.float32)
+    u = jax.random.uniform(key, shape, jnp.float32)
+    if name == "A_log":
+        return jnp.log(1.0 + 15.0 * u)
+    if name == "dt_bias":
+        dt = jnp.maximum(jnp.exp(jnp.log(1e-3) + u * jnp.log(1e2)), 1e-4)
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return (2.0 * u - 1.0) * cfg.ssm_conv ** -0.5      # conv_w, conv_b
+
+
+_SSM_INIT = ("conv_w", "conv_b", "dt_bias", "A_log", "D")
+
+
 def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
-    """Scaled-normal init (fan-in), params in ``cfg.param_dtype``."""
-    shapes = _dense_layer_shapes(cfg)
-    n_tensors = len(shapes) + 3
+    """Scaled-normal init (fan-in), params in ``cfg.param_dtype``.  A tied
+    table is initialised as the head it also is (fan-in: the step-0 loss
+    is then ln(vocab) to a hundredth)."""
+    run_shapes = [(n, _layer_shapes(cfg, kind)) for kind, n in cfg.layer_runs]
+    n_tensors = sum(len(shapes) for _, shapes in run_shapes) + 3
     keys = iter(jax.random.split(key, n_tensors))
 
     def norm_init(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(cfg.param_dtype)
 
-    layers = {}
-    for name, (shape, _) in shapes.items():
-        full = (cfg.num_layers,) + shape
-        if name.endswith("norm"):
-            layers[name] = jnp.ones(full, cfg.param_dtype)
-        else:
-            fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
-            layers[name] = norm_init(next(keys), full, fan_in)
-    return {
-        "embed": norm_init(next(keys), (cfg.vocab_size, cfg.embed_dim), 1.0),
-        "layers": layers,
+    runs = []
+    for n, shapes in run_shapes:
+        layers = {}
+        for name, (shape, _) in shapes.items():
+            full = (n,) + shape
+            if name.endswith("norm"):
+                layers[name] = jnp.ones(full, cfg.param_dtype)
+            elif name in _SSM_INIT:
+                layers[name] = _ssm_init(name, next(keys), full, cfg).astype(
+                    cfg.param_dtype)
+            else:
+                fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
+                layers[name] = norm_init(next(keys), full, fan_in)
+        runs.append(layers)
+    params = {
+        "embed": norm_init(next(keys), (cfg.vocab_size, cfg.embed_dim),
+                           cfg.embed_dim if cfg.tie_embeddings else 1.0),
+        "layers": _per_run(runs),
         "final_norm": jnp.ones((cfg.embed_dim,), cfg.param_dtype),
-        "lm_head": norm_init(next(keys), (cfg.embed_dim, cfg.vocab_size),
-                             cfg.embed_dim),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = norm_init(
+            next(keys), (cfg.embed_dim, cfg.vocab_size), cfg.embed_dim)
+    return params
+
+
+def _sm_scale(cfg: LlamaConfig) -> float:
+    if cfg.attention_multiplier is None:
+        return cfg.head_dim ** -0.5
+    return cfg.attention_multiplier
 
 
 def _attention(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh]):
@@ -179,19 +322,20 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh]):
     path runs inside shard_map (batch over (dp,fsdp), heads over tp); ring /
     ulysses manage the 'sp' axis themselves.
     """
-    impl = cfg.attn_impl
+    impl, scale = cfg.attn_impl, _sm_scale(cfg)
     if mesh is None:
         # Ring/ulysses degenerate to plain attention on one device.
         if impl == "flash":
-            return flash_attention(q, k, v, causal=True)
+            return flash_attention(q, k, v, causal=True, sm_scale=scale)
         k, v = repeat_kv_heads(q, k, v)
-        return mha_reference(q, k, v, causal=True)
+        return mha_reference(q, k, v, causal=True, sm_scale=scale)
     if impl == "ring":
-        return ring_attention(q, k, v, causal=True, mesh=mesh)
+        return ring_attention(q, k, v, causal=True, sm_scale=scale, mesh=mesh)
     if impl == "ulysses":
-        return ulysses_attention(q, k, v, causal=True, mesh=mesh)
+        return ulysses_attention(q, k, v, causal=True, sm_scale=scale,
+                                 mesh=mesh)
     if impl == "reference":
-        return mha_reference(q, k, v, causal=True)
+        return mha_reference(q, k, v, causal=True, sm_scale=scale)
     # flash under a mesh: pallas has no SPMD partitioning rule, so run the
     # kernel per-shard: batch over (dp,fsdp), heads over tp, seq replicated.
     # Manual over EVERY mesh axis — the TPU lowering refuses a Mosaic
@@ -200,7 +344,8 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh]):
     k, v = repeat_kv_heads(q, k, v)
     spec = P((AXIS_DP, AXIS_FSDP), None, AXIS_TP, None)
     fn = manual_shard_map(
-        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True),
+        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True,
+                                           sm_scale=scale),
         set(mesh.axis_names), in_specs=(spec, spec, spec),
         out_specs=spec, mesh=mesh)
     return fn(q, k, v)
@@ -212,11 +357,10 @@ def _attention_sp_manual(q, k, v, cfg: LlamaConfig):
     from ray_tpu.ops.ring_attention import _ring_attention_sharded
     from ray_tpu.ops.ulysses import _ulysses_sharded
     k, v = repeat_kv_heads(q, k, v)
-    sm_scale = cfg.head_dim ** -0.5
     if cfg.attn_impl == "ulysses":
-        return _ulysses_sharded(q, k, v, sm_scale, True, AXIS_SP,
+        return _ulysses_sharded(q, k, v, _sm_scale(cfg), True, AXIS_SP,
                                 use_flash=False)
-    return _ring_attention_sharded(q, k, v, sm_scale, True, AXIS_SP)
+    return _ring_attention_sharded(q, k, v, _sm_scale(cfg), True, AXIS_SP)
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig, *,
@@ -240,25 +384,52 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig, *,
             x = onehot @ params["embed"].astype(cfg.dtype)
         else:
             x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
-        x = cst(x, ("batch", "seq", "embed"))
-    layer_fn = _make_layer_fn(cfg, mesh, rules)
-    if cfg.remat:
-        layer_fn = _checkpoint(layer_fn)
-    (x, aux), _ = jax.lax.scan(layer_fn, (x, _zero_aux(cfg)),
-                               params["layers"])
+        x = cst(_scaled(x, cfg.embedding_multiplier),
+                ("batch", "seq", "embed"))
+    x, aux = _scan_layers(params["layers"], x, cfg, mesh, rules)
     return _lm_head(params, x, cfg, cst), _mean_aux(aux, cfg)
+
+
+def _scaled(x, multiplier: float):
+    """``x * multiplier``; a multiplier of 1 adds no op to the program."""
+    return x if multiplier == 1.0 else x * multiplier
+
+
+def _scan_layers(layers, x, cfg: LlamaConfig, mesh, rules,
+                 sp_manual: bool = False):
+    """The layers over ``x``: one ``lax.scan`` a run of one kind of layer,
+    over that run's stacked parameters, each under the layer checkpoint.
+    Returns ``(x, aux)``."""
+    carry = (x, _zero_aux(cfg))
+    for mixer, stacked in _runs(cfg, layers):
+        layer_fn = _make_layer_fn(cfg, mesh, rules, sp_manual, mixer)
+        if cfg.remat:
+            layer_fn = _checkpoint(layer_fn)
+        carry, _ = jax.lax.scan(layer_fn, carry, stacked)
+    return carry
+
+
+# What the layer checkpoint keeps of a Mamba layer: the input projection's
+# output [z | xBC | dt] (bf16, 139 MB a layer at 8192 tokens).  With it the
+# backward pass runs no second ``ssm_in`` matmul; the convolution, the
+# scan and the gated norm ARE run again (their intermediates are several
+# times that size).  On the v5e: 8.8 ms of a 507 ms step for 1.25 GB held,
+# 2.4 GB of program (PERF.md §6, PR 30).
+MAMBA_SAVED_RESIDUALS = ("ssm_proj",)
 
 
 def _checkpoint(layer_fn):
     """The layer checkpoint of every path (``cfg.remat``): the backward
     pass recomputes the layer from its input, except the few residuals
     that are dear to recompute and cheap to hold, named where they are
-    made — the flash kernel's output and log-sum-exp, and an expert
-    layer's row index (its sorts' results).  A layer that never produces
-    a name (reference attention, a dense FFN) saves nothing under it."""
+    made — the flash kernel's output and log-sum-exp, an expert layer's
+    row index (its sorts' results), a Mamba layer's input projection.  A
+    layer that never produces a name (reference attention, a dense FFN)
+    saves nothing under it."""
     return jax.checkpoint(
         layer_fn, policy=jax.checkpoint_policies.save_only_these_names(
-            *attention.SAVED_RESIDUALS, *moe.SAVED_RESIDUALS))
+            *attention.SAVED_RESIDUALS, *moe.SAVED_RESIDUALS,
+            *MAMBA_SAVED_RESIDUALS))
 
 
 def _zero_aux(cfg: LlamaConfig):
@@ -326,7 +497,14 @@ def _lm_head(params, x, cfg: LlamaConfig, cst):
     """Final norm and head product -> f32 logits (scope ``lm_head``)."""
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+        if cfg.tie_embeddings:  # one table, read twice: its gradient is
+            # the sum of both uses
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["embed"].astype(cfg.dtype))
+        else:
+            logits = x @ params["lm_head"].astype(cfg.dtype)
+        logits = _scaled(logits.astype(jnp.float32),
+                         1.0 / cfg.logits_scaling)
         return cst(logits, ("batch", "seq", "vocab"))
 
 
@@ -337,10 +515,104 @@ def _make_cst(mesh, rules):
                                                  rules=rules)
 
 
-def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False):
-    """One transformer layer as a scan body over stacked layer params.
-    Shapes are read off the activation so the same body serves the full
-    batch (forward) and microbatches (forward_pipelined).
+def _attention_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual):
+    """Softmax attention on the residual stream (scopes ``attn_qkv``,
+    ``attention``, ``attn_out``)."""
+    b, s = x.shape[0], x.shape[1]
+    with jax.named_scope("attn_qkv"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = h @ lp["wq"].astype(cfg.dtype)
+        k = h @ lp["wk"].astype(cfg.dtype)
+        if cfg.qk_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
+        k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        v = (h @ lp["wv"].astype(cfg.dtype)).reshape(
+            b, s, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.position_embedding == "rope":
+            offset = 0
+            if sp_manual:
+                offset = jax.lax.axis_index(AXIS_SP) * s
+            cos, sin = rope(s, cfg.head_dim, cfg.rope_theta, offset=offset)
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        q = cst(q, ("batch", "seq", "heads", "head_dim"))
+        k = cst(k, ("batch", "seq", "kv_heads", "head_dim"))
+    with jax.named_scope("attention"):
+        if sp_manual:
+            o = _attention_sp_manual(q, k, v, cfg)
+        else:
+            o = _attention(q, k, v, cfg, mesh)
+    with jax.named_scope("attn_out"):
+        o = o.reshape(b, s, cfg.qkv_dim)
+        return x + _scaled(cst(o @ lp["wo"].astype(cfg.dtype),
+                               ("batch", "seq", "embed")),
+                           cfg.residual_multiplier)
+
+
+def _mamba_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual):
+    """A Mamba-2 mixer on the residual stream (``ops/ssm.py``): scopes
+    ``ssm_in`` (norm, the one input projection, its split), ``ssm_conv``
+    (the convolution over x, B, C with its SiLU; dt's softplus),
+    ``ssm_scan`` (the chunked scan, ``D x`` included), ``ssm_out`` (the
+    norm of the GATED output — gate first, then one norm over the whole
+    inner width —, the output projection, the residual add).  Plain XLA:
+    under a mesh the partitioner splits it over the batch."""
+    b, s = x.shape[0], x.shape[1]
+    inner, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
+    f32 = jnp.float32
+    with jax.named_scope("ssm_in"):
+        h = rms_norm(x, lp["ssm_norm"], cfg.norm_eps)
+        zxbcdt = checkpoint_name(h @ lp["ssm_in"].astype(cfg.dtype),
+                                 *MAMBA_SAVED_RESIDUALS)
+        z, xbc, dt = jnp.split(zxbcdt, [inner, inner + cfg.ssm_conv_dim], -1)
+    with jax.named_scope("ssm_conv"):
+        xbc = causal_conv1d(xbc, lp["conv_w"], lp["conv_b"])
+        dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+        xs, bm, cm = jnp.split(xbc, [inner, inner + gn], -1)
+    with jax.named_scope("ssm_scan"):
+        y = ssd_chunked(
+            xs.reshape(b, s, cfg.ssm_heads, cfg.ssm_head_dim), dt,
+            -jnp.exp(lp["A_log"].astype(f32)),
+            bm.reshape(b, s, cfg.ssm_groups, cfg.ssm_state),
+            cm.reshape(b, s, cfg.ssm_groups, cfg.ssm_state),
+            lp["D"], chunk=cfg.ssm_chunk)
+    with jax.named_scope("ssm_out"):
+        y = gated_rms_norm(y.reshape(b, s, inner), z, lp["gate_norm"],
+                           cfg.norm_eps)
+        return x + _scaled(cst(y @ lp["ssm_out"].astype(cfg.dtype),
+                               ("batch", "seq", "embed")),
+                           cfg.residual_multiplier)
+
+
+def _dense_ffn(x, aux, lp, cfg: LlamaConfig, mesh, cst):
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        gate = h @ lp["w_gate"].astype(cfg.dtype)
+        up = h @ lp["w_up"].astype(cfg.dtype)
+        ff = swiglu(gate, up) @ lp["w_down"].astype(cfg.dtype)
+        return x + _scaled(cst(ff, ("batch", "seq", "embed")),
+                           cfg.residual_multiplier), aux
+
+
+def _moe_ffn(x, aux, lp, cfg: LlamaConfig, mesh, cst):
+    # opens its own four scopes in place of ffn
+    x, stats = _moe(x, lp, cfg, mesh, cst)
+    x = cst(x, ("batch", "seq", "embed"))
+    return x, {k: (jnp.maximum if k == "load_max_over_mean"
+                   else jnp.add)(v, stats[k]) for k, v in aux.items()}
+
+
+_MIXERS = {"attention": _attention_mixer, "mamba": _mamba_mixer}
+
+
+def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
+                   mixer: str = "attention"):
+    """One layer as a scan body over stacked layer params: a mixer
+    (``_MIXERS``) then an FFN (dense, or the expert layer), each adding to
+    the residual stream.  Shapes are read off the activation so the same
+    body serves the full batch (forward) and microbatches
+    (forward_pipelined).
 
     ``sp_manual``: the body runs inside a shard_map that is manual over
     'sp' (the pipeline path — jax/shardy cannot nest manual regions): the
@@ -348,54 +620,23 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False):
     ring/ulysses attention run inline over the bound 'sp' axis.
     """
     cst = _make_cst(mesh, rules)
+    mix = _MIXERS[mixer]
+    ffn = _moe_ffn if cfg.num_experts else _dense_ffn
 
     def layer_fn(carry, lp):
         x, aux = carry
-        b, s = x.shape[0], x.shape[1]
-        with jax.named_scope("attn_qkv"):
-            offset = 0
-            if sp_manual:
-                offset = jax.lax.axis_index(AXIS_SP) * s
-            cos, sin = rope(s, cfg.head_dim, cfg.rope_theta, offset=offset)
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = h @ lp["wq"].astype(cfg.dtype)
-            k = h @ lp["wk"].astype(cfg.dtype)
-            if cfg.qk_norm:
-                q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
-                k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-            q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
-            k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-            v = (h @ lp["wv"].astype(cfg.dtype)).reshape(
-                b, s, cfg.num_kv_heads, cfg.head_dim)
-            q = cst(apply_rope(q, cos, sin),
-                    ("batch", "seq", "heads", "head_dim"))
-            k = cst(apply_rope(k, cos, sin),
-                    ("batch", "seq", "kv_heads", "head_dim"))
-        with jax.named_scope("attention"):
-            if sp_manual:
-                o = _attention_sp_manual(q, k, v, cfg)
-            else:
-                o = _attention(q, k, v, cfg, mesh)
-        with jax.named_scope("attn_out"):
-            o = o.reshape(b, s, cfg.qkv_dim)
-            x = x + cst(o @ lp["wo"].astype(cfg.dtype),
-                        ("batch", "seq", "embed"))
-
-        if cfg.num_experts:  # opens its own four scopes in place of ffn
-            x, stats = _moe(x, lp, cfg, mesh, cst)
-            x = cst(x, ("batch", "seq", "embed"))
-            aux = {k: (jnp.maximum if k == "load_max_over_mean"
-                       else jnp.add)(v, stats[k]) for k, v in aux.items()}
-            return (x, aux), None
-        with jax.named_scope("ffn"):
-            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            gate = h @ lp["w_gate"].astype(cfg.dtype)
-            up = h @ lp["w_up"].astype(cfg.dtype)
-            ff = swiglu(gate, up) @ lp["w_down"].astype(cfg.dtype)
-            x = x + cst(ff, ("batch", "seq", "embed"))
-        return (x, aux), None
+        x = mix(x, lp, cfg, mesh, cst, sp_manual)
+        return ffn(x, aux, lp, cfg, mesh, cst), None
 
     return layer_fn
+
+
+def _one_kind(cfg: LlamaConfig, what: str) -> None:
+    if len(cfg.layer_runs) > 1:
+        raise NotImplementedError(
+            f"{what} splits ONE stack of layers into stages; this model's "
+            f"layers differ ({cfg.layer_runs}): train it with "
+            "make_train_step(pipelined=False)")
 
 
 def forward_pipelined(params: Dict[str, Any], tokens: jax.Array,
@@ -418,10 +659,12 @@ def forward_pipelined(params: Dict[str, Any], tokens: jax.Array,
     from ray_tpu.parallel.pipeline import pipeline_apply, split_stages
     from ray_tpu.parallel.mesh import AXIS_PP
 
+    _one_kind(cfg, "forward_pipelined")
     cst = _make_cst(mesh, rules)
     with jax.named_scope("embed"):
         onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.dtype)
-        x = cst(onehot @ params["embed"].astype(cfg.dtype),
+        x = cst(_scaled(onehot @ params["embed"].astype(cfg.dtype),
+                        cfg.embedding_multiplier),
                 ("batch", "seq", "embed"))
 
     sp_manual = cfg.attn_impl in ("ring", "ulysses") and \
@@ -437,14 +680,9 @@ def forward_pipelined(params: Dict[str, Any], tokens: jax.Array,
         inner_rules = rules
         x_spec = P()
         manual_axes = {AXIS_PP}
-    layer_fn = _make_layer_fn(cfg, mesh, inner_rules, sp_manual=sp_manual)
-    if cfg.remat:
-        layer_fn = _checkpoint(layer_fn)
-
     def stage_fn(stage_params, x_mb):
-        (y, _), _ = jax.lax.scan(layer_fn, (x_mb, _zero_aux(cfg)),
-                                 stage_params)
-        return y
+        return _scan_layers(stage_params, x_mb, cfg, mesh, inner_rules,
+                            sp_manual)[0]
 
     stages = split_stages(params["layers"], mesh.shape[AXIS_PP])
     x = pipeline_apply(stage_fn, stages, x, mesh=mesh,
@@ -461,6 +699,11 @@ def pipeline_stage_params(params: Dict[str, Any],
     0 and the final norm + LM head into the last stage — each stage
     actor then owns exactly its stage's tensors, nothing replicated."""
     layers = params["layers"]
+    if not isinstance(layers, dict) or "lm_head" not in params:
+        raise NotImplementedError(
+            "the actor pipeline splits one stack of layers and gives the "
+            "embedding and the head to different stages: not a model whose "
+            "layers differ, nor one with a tied head")
     n_layers = next(iter(layers.values())).shape[0]
     if n_layers % num_stages:
         raise ValueError(
@@ -487,15 +730,14 @@ def make_pipeline_stage_fn(cfg: LlamaConfig):
     the stage holding ``lm_head``.  Key presence is trace-time static,
     so each stage jits to exactly its own program."""
 
+    _one_kind(cfg, "make_pipeline_stage_fn")
+
     def stage_fn(sp, x):
-        layer_fn = _make_layer_fn(cfg, None, None)
-        if cfg.remat:
-            layer_fn = _checkpoint(layer_fn)
         if "embed" in sp:
             with jax.named_scope("embed"):
-                x = jnp.take(sp["embed"], x, axis=0).astype(cfg.dtype)
-        (x, _), _ = jax.lax.scan(layer_fn, (x, _zero_aux(cfg)),
-                                 sp["layers"])
+                x = _scaled(jnp.take(sp["embed"], x, axis=0).astype(
+                    cfg.dtype), cfg.embedding_multiplier)
+        x, _ = _scan_layers(sp["layers"], x, cfg, None, None)
         if "lm_head" in sp:
             x = _lm_head(sp, x, cfg, _make_cst(None, None))
         return x
@@ -549,6 +791,13 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
 
 
 def _mean_nll(logits, targets):
+    if logits.shape[0] == 1:
+        # One row: drop the degenerate dimension.  With it XLA's TPU
+        # compiler turns the gradient of the gather below into a FLAT
+        # scatter — float32 zeros the size of the logits, a relayout of
+        # them and 5 GB more of temporaries at 8192 x 100352 (PERF.md §6,
+        # PR 30).  Batches of several rows compile as they always have.
+        logits, targets = logits[0], targets[0]
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     return jnp.mean(nll)

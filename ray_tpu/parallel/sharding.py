@@ -39,6 +39,9 @@ DEFAULT_RULES: LogicalAxisRules = {
     "vocab": AXIS_TP,             # embedding/vocab-parallel output head
     "kernel_in": AXIS_FSDP,       # ZeRO-3: param input dim over fsdp
     "expert": AXIS_EP,            # MoE experts over expert axis
+    "ssm_inner": None,            # a Mamba mixer's inner width: [z|xBC|dt]
+                                  # lie side by side in one projection, so
+                                  # a tp split has to cut each part (later)
     "stage": AXIS_PP,             # pipeline stages (stacked-stage layout)
     "layer": None,                # scanned-layer leading dim (non-pipelined)
 }

@@ -29,7 +29,8 @@ from ray_tpu.parallel.sharding import (
 
 # The ``jax.named_scope`` names that between them cover the step program,
 # with no overlap: ``models/llama.py`` opens all but the last (an expert
-# layer opens ``ops/moe.py``'s four ``moe_*`` in place of ``ffn``), ``step``
+# layer opens ``ops/moe.py``'s four ``moe_*`` in place of ``ffn``, a Mamba
+# layer the four ``ssm_*`` in place of the three ``attn*``), ``step``
 # below opens ``optimizer``.  A device op's ``op_name`` carries exactly one
 # of them, wrapped by JAX in the phase: bare or ``jvp(..)`` is the forward
 # pass, under ``rematted_computation`` the rematerialised forward,
@@ -37,10 +38,13 @@ from ray_tpu.parallel.sharding import (
 # The layer checkpoint (``models/llama.py::_checkpoint``) recomputes a layer
 # but the residuals it keeps by name: the flash kernel's output and
 # log-sum-exp (``ops/attention.py::SAVED_RESIDUALS``: no ``flash_fwd`` under
-# ``rematted_computation``) and the expert layer's row index
-# (``ops/moe.py::SAVED_RESIDUALS``: no sort there; the row gather runs again).
+# ``rematted_computation``), the expert layer's row index
+# (``ops/moe.py::SAVED_RESIDUALS``: no sort there; the row gather runs again)
+# and a Mamba layer's input projection (``MAMBA_SAVED_RESIDUALS``: no
+# ``ssm_in`` matmul there; convolution, scan and gated norm run again).
 STEP_SCOPES = ("embed", "attn_qkv", "attention", "attn_out", "ffn",
                "moe_route", "moe_dispatch", "moe_experts", "moe_combine",
+               "ssm_in", "ssm_conv", "ssm_scan", "ssm_out",
                "lm_head", "loss", "optimizer")
 
 

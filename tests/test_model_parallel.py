@@ -172,16 +172,19 @@ def _op_names(hlo_text, opcodes):
     return out
 
 
-@pytest.mark.parametrize("mesh_kw,moe", [
-    (None, False), (dict(fsdp=2, tp=2), False), (None, True),
-    (dict(fsdp=2, ep=2), True)],
-    ids=["one_device", "fsdp2_tp2", "moe_one_device", "moe_fsdp2_ep2"])
-def test_step_program_is_named_by_scope(mesh_kw, moe):
+@pytest.mark.parametrize("mesh_kw,moe,hybrid", [
+    (None, False, False), (dict(fsdp=2, tp=2), False, False),
+    (None, True, False), (dict(fsdp=2, ep=2), True, False),
+    (None, False, True), (dict(fsdp=2, tp=2), False, True)],
+    ids=["one_device", "fsdp2_tp2", "moe_one_device", "moe_fsdp2_ep2",
+         "hybrid_one_device", "hybrid_fsdp2_tp2"])
+def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
     """What a device trace can tell: the kernels by name, and on every
     matmul, kernel call and collective exactly one of STEP_SCOPES
     (``util.tracing.step_breakdown`` reads them off the profiler's
     ``op_name``).  An expert layer's four scopes take the place of
-    ``ffn``, each in every phase."""
+    ``ffn``, each in every phase; a Mamba layer's four stand beside the
+    attention layer's three (a hybrid has both kinds of layer)."""
     import re
 
     from ray_tpu.train.core import STEP_SCOPES
@@ -189,7 +192,12 @@ def test_step_program_is_named_by_scope(mesh_kw, moe):
 
     cfg = LlamaConfig.tiny(attn_impl="flash", remat=True, num_kv_heads=2,
                            **(dict(num_experts=4, num_selected=2,
-                                   z_loss_coef=0.001) if moe else {}))
+                                   z_loss_coef=0.001) if moe else {}),
+                           **(dict(num_layers=3, layer_types=(
+                               "mamba", "attention", "mamba"), ssm_heads=4,
+                               ssm_head_dim=16, ssm_state=8, ssm_chunk=16,
+                               tie_embeddings=True, logits_scaling=8.0,
+                               residual_multiplier=0.22) if hybrid else {}))
     opt = optax.adam(1e-2)
     mesh = None
     if mesh_kw:
@@ -220,6 +228,11 @@ def test_step_program_is_named_by_scope(mesh_kw, moe):
             # token shard holds, summed over the token axes; no scope
             assert op == "all-reduce" and mesh is not None, (op, name)
             continue
+        if hybrid and op == "dot" and not name:
+            # the CPU compiler rewrites the chunked scan's einsums (batch
+            # dimensions that do not lead) into dots that carry no name;
+            # the scan's other ops keep theirs (``every`` below)
+            continue
         assert len(scopes) == 1, (op, name)
         seen.add(scope_and_phase(name, STEP_SCOPES))
         kernels = [t for t in tokens if t.startswith(KERNEL_NAMES)]
@@ -229,7 +242,11 @@ def test_step_program_is_named_by_scope(mesh_kw, moe):
     # Every scope has a matmul or a collective of its own but the loss
     # (elementwise and reductions) — and each phase is told apart.
     moe_scopes = {"moe_route", "moe_dispatch", "moe_experts", "moe_combine"}
+    ssm_scopes = {"ssm_in", "ssm_conv", "ssm_scan", "ssm_out"}
     want = set(STEP_SCOPES) - ({"ffn"} if moe else moe_scopes)
+    # the convolution is shifted adds: no matmul, no collective; the
+    # scan's matmuls lose their names on the CPU (above)
+    want -= {"ssm_conv", "ssm_scan"} if hybrid else ssm_scopes
     want -= {"loss"} if mesh is None or moe else set()  # tp splits it
     want -= {"embed"} if mesh is None else set()  # a gather, no matmul
     want -= {"optimizer"} if mesh is None else set()
@@ -245,6 +262,13 @@ def test_step_program_is_named_by_scope(mesh_kw, moe):
     # in the rematerialised attention.
     assert {("attention", "forward"), ("attention", "backward")} <= seen
     assert ("attention", "remat") not in seen
+    if hybrid:
+        # a Mamba layer keeps nothing in the checkpoint: its scan runs
+        # again under remat, and every scope has ops in every phase
+        every = {scope_and_phase(name, STEP_SCOPES) for _, name in _op_names(
+            step.lower(state, batch).compile().as_text(), ("",))}
+        assert {(s, p) for s in ssm_scopes
+                for p in ("forward", "remat", "backward")} <= every
     if moe:
         # all four scopes in every phase, on ops of any kind — but the
         # rematerialised combine (nothing of the backward reads its sum);

@@ -1,0 +1,386 @@
+"""The state-space mixer (``ops/ssm.py``) and the hybrid model it makes
+possible (``models/llama.py``: a pattern of Mamba-2 and attention layers,
+NoPE, a given softmax scale, three multipliers, a tied head), on the CPU at
+tiny sizes.  The model's yardstick is the benchmark's own plain reference
+(``benchmark/reference/granite_hybrid.py``: the recurrence a token at a
+time, nothing shared with the code under test)."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import granite_hybrid
+from ray_tpu.models import llama
+from ray_tpu.models.llama import (
+    LlamaConfig, forward_pipelined, init_params, loss_fn,
+    make_pipeline_stage_fn, param_logical_axes, pipeline_stage_params)
+from ray_tpu.ops.layers import rms_norm
+from ray_tpu.ops.ssm import causal_conv1d, ssd_chunked, ssd_reference
+from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+from ray_tpu.train.core import init_train_state, train_state_shardings
+
+HIGHEST = jax.default_matmul_precision("highest")
+
+
+def _scan_inputs(seq, seed=0, batch=2, heads=4, p=8, groups=2, n=16):
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.5),
+                                        (batch, seq, heads))), jnp.float32)
+    a = -jnp.asarray(rng.uniform(1, 16, (heads,)), jnp.float32)
+    return (f(batch, seq, heads, p), dt, a, f(batch, seq, groups, n),
+            f(batch, seq, groups, n), f(heads))
+
+
+@pytest.mark.parametrize("seq,chunk", [(40, 8), (32, 32), (24, 256), (37, 8)],
+                         ids=["several-chunks", "one-chunk",
+                              "shorter-than-a-chunk", "ragged"])
+def test_ssd_chunked_equals_the_recurrence(seq, chunk):
+    """Values and every gradient, against the recurrence a token at a
+    time: a sequence of several chunks, one that is a single chunk, and one
+    that no chunk divides (padded with tokens that neither decay nor add)."""
+    args = _scan_inputs(seq)
+    weight = jnp.asarray(np.random.default_rng(1).normal(
+        size=args[0].shape), jnp.float32)
+    with HIGHEST:
+        want = jax.jit(ssd_reference)(*args)
+        got = jax.jit(lambda *t: ssd_chunked(*t, chunk=chunk))(*args)
+        assert got.shape == want.shape and got.dtype == args[0].dtype
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        grads = [jax.jit(jax.grad(lambda *t: jnp.sum(f(*t) * weight),
+                                  argnums=range(6)))(*args)
+                 for f in (lambda *t: ssd_chunked(*t, chunk=chunk),
+                           ssd_reference)]
+    for name, g, w in zip("x dt a b c d".split(), *grads):
+        assert np.all(np.isfinite(g)), name
+        np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4, err_msg=name)
+
+
+def test_ssd_chunked_in_bfloat16_keeps_its_decays_in_float32():
+    """Operands of the big products in the input's dtype, decays and the
+    carried state in float32: a bfloat16 call stays within bfloat16's
+    rounding of the float32 recurrence over 8 chunks."""
+    x, dt, a, b, c, d = _scan_inputs(64, seed=3)
+    got = ssd_chunked(*(t.astype(jnp.bfloat16) for t in (x,)), dt, a,
+                      b.astype(jnp.bfloat16), c.astype(jnp.bfloat16), d,
+                      chunk=8)
+    assert got.dtype == jnp.bfloat16
+    want = ssd_reference(x, dt, a, b, c, d)
+    err = jnp.abs(got.astype(jnp.float32) - want)
+    assert float(jnp.sqrt(jnp.mean(err ** 2))) < 0.02 * float(
+        jnp.sqrt(jnp.mean(want ** 2)))
+
+
+def test_causal_conv1d_is_the_direct_sum_and_causal():
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(2, 11, 6)).astype(np.float32)
+    w = rng.normal(size=(4, 6)).astype(np.float32)
+    bias = rng.normal(size=(6,)).astype(np.float32)
+    want = np.zeros_like(x)
+    for t in range(x.shape[1]):
+        for i in range(4):
+            if t - 3 + i >= 0:  # w[3] meets the current token
+                want[:, t] += w[i] * x[:, t - 3 + i]
+    want = want + bias
+    want = want / (1 + np.exp(-want))
+    got = causal_conv1d(jnp.asarray(x), jnp.asarray(w), jnp.asarray(bias))
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    # a later token changes no earlier output
+    later = x.copy()
+    later[:, 7:] += 1.0
+    moved = causal_conv1d(jnp.asarray(later), jnp.asarray(w),
+                          jnp.asarray(bias))
+    np.testing.assert_array_equal(np.asarray(moved)[:, :7],
+                                  np.asarray(got)[:, :7])
+    assert not np.allclose(np.asarray(moved)[:, 7], np.asarray(got)[:, 7])
+
+    # the written-out backward pass against autodiff of the direct sum
+    def direct(x, w, bias):
+        padded = jnp.pad(x, ((0, 0), (3, 0), (0, 0)))
+        return jax.nn.silu(bias + sum(padded[:, i:i + x.shape[1]] * w[i]
+                                      for i in range(4)))
+
+    weight = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    args = (jnp.asarray(x), jnp.asarray(w), jnp.asarray(bias))
+    grads, want_grads = (jax.grad(lambda *t: jnp.sum(f(*t) * weight),
+                                  argnums=(0, 1, 2))(*args)
+                         for f in (causal_conv1d, direct))
+    for g, wg in zip(grads, want_grads):
+        np.testing.assert_allclose(g, wg, atol=1e-5, rtol=1e-5)
+
+
+# ------------------------------------------------- the hybrid model ------
+
+# The public key names of a granitemoehybrid config.json, at CPU size:
+# mamba, attention, mamba (of a longer published list); no multiplier 1.
+CONF = {
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "shared_intermediate_size": 96, "vocab_size": 256, "rms_norm_eps": 1e-5,
+    "num_hidden_layers": 3,
+    "layer_types": ["mamba", "attention", "mamba", "mamba", "attention"],
+    "mamba_n_heads": 8, "mamba_d_head": 16, "mamba_d_state": 8,
+    "mamba_n_groups": 2, "mamba_d_conv": 4, "mamba_chunk_size": 8,
+    "position_embedding_type": "nope", "attention_multiplier": 0.1,
+    "embedding_multiplier": 3.0, "residual_multiplier": 0.5,
+    "logits_scaling": 2.0, "tie_word_embeddings": True,
+}
+
+
+def _cfg(**kw):
+    fields = dict(
+        vocab_size=256, embed_dim=64, num_layers=3, num_heads=4,
+        num_kv_heads=2, head_dim=16, mlp_dim=96, norm_eps=1e-5,
+        layer_types=CONF["layer_types"], ssm_heads=8, ssm_head_dim=16,
+        ssm_state=8, ssm_groups=2, ssm_conv=4, ssm_chunk=8,
+        position_embedding="nope", attention_multiplier=0.1,
+        embedding_multiplier=3.0, residual_multiplier=0.5,
+        logits_scaling=2.0, tie_embeddings=True, max_seq_len=64,
+        dtype=jnp.float32, remat=True, attn_impl="flash")
+    fields.update(kw)
+    return LlamaConfig(**fields)
+
+
+def _drawn(params, seed=5):
+    """Norm weights drawn away from 1, as the benchmark's check draws
+    them, and ``D`` away from its initial 1."""
+    rng = np.random.default_rng(seed)
+
+    def leaf(path, a):
+        name = str(getattr(path[-1], "key", ""))
+        if name.endswith("norm") or name == "D":
+            return a * jnp.asarray(rng.uniform(0.5, 1.5, a.shape), a.dtype)
+        return a
+
+    return jax.tree_util.tree_map_with_path(leaf, params)
+
+
+TOKENS = jnp.asarray(np.random.default_rng(7).integers(
+    0, 256, (2, 41), dtype=np.int32))
+LOSS_TOL, GRAD_TOL = 2e-6, 2e-4   # float32 against float32, relative
+
+
+def _apart(cfg, params):
+    """(loss, gradients) of the program apart from the reference's,
+    relative: the loss, and the largest gradient leaf's difference over
+    that leaf's own scale."""
+    with HIGHEST:
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0]))(params)
+        want, want_grads = jax.jit(jax.value_and_grad(
+            lambda p: granite_hybrid.loss(p, TOKENS, CONF)))(params)
+    apart = jax.tree.map(
+        lambda g, w: float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w))),
+        grads, want_grads)
+    return (abs(float(loss) - float(want)) / float(want),
+            max(jax.tree.leaves(apart)))
+
+
+def test_hybrid_loss_and_gradients_equal_the_plain_reference():
+    """mamba, attention, mamba through ``loss_fn`` (the flash kernel
+    interpreted, the layer checkpoint on) against the benchmark's
+    reference, which computes the recurrence a token at a time: the loss
+    within 2e-6, every gradient leaf within 2e-4 of its scale — the tied
+    table's too, whose gradient is the sum of both of its uses."""
+    cfg = _cfg()
+    assert cfg.layer_runs == (("mamba", 1), ("attention", 1), ("mamba", 1))
+    params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
+    assert "lm_head" not in params and len(params["layers"]) == 3
+    loss, grads = _apart(cfg, params)
+    assert loss <= LOSS_TOL and grads <= GRAD_TOL, (loss, grads)
+
+
+def _no_d(params):
+    return dict(params, layers=tuple(
+        dict(run, D=jnp.zeros_like(run["D"])) if "D" in run else run
+        for run in params["layers"]))
+
+
+@pytest.mark.parametrize("wrong", [
+    dict(embedding_multiplier=1.0), dict(residual_multiplier=1.0),
+    dict(logits_scaling=1.0), dict(position_embedding="rope"),
+    dict(attention_multiplier=None), "D dropped", "gate after the norm",
+], ids=lambda w: w if isinstance(w, str) else "-".join(
+    f"{k}={v}" for k, v in w.items()))
+def test_structural_controls_fail_against_the_reference(wrong, monkeypatch):
+    """What the comparison must be able to tell from the published
+    structure: a multiplier left at 1, RoPE left on, the softmax scale
+    1/sqrt(d) in place of the given one, ``D x`` dropped, the gate applied
+    after the norm.  Each moves the loss fifty tolerances or more (RoPE,
+    the least, a hundred: one small attention layer of three)."""
+    cfg = _cfg(attn_impl="reference", remat=False)
+    params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
+    program_params = params
+    if wrong == "D dropped":
+        program_params = _no_d(params)
+    elif wrong == "gate after the norm":
+        monkeypatch.setattr(
+            llama, "gated_rms_norm", lambda y, z, w, eps: rms_norm(
+                y, w, eps) * jax.nn.silu(z))
+    else:
+        cfg = dataclasses.replace(cfg, **wrong)
+    with HIGHEST:
+        loss = float(jax.jit(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(
+            program_params))
+        want = float(granite_hybrid.loss(params, TOKENS, CONF))
+    assert abs(loss - want) / want > 50 * LOSS_TOL, (loss, want)
+
+
+def test_layer_types_runs_and_hashing():
+    published = (["mamba"] * 5 + ["attention"] + ["mamba"] * 9
+                 + ["attention"] + ["mamba"] * 9 + ["attention"]
+                 + ["mamba"] * 9 + ["attention"] + ["mamba"] * 4)
+    whole = LlamaConfig(num_layers=40, layer_types=published, ssm_heads=64)
+    assert [n for _, n in whole.layer_runs] == [5, 1, 9, 1, 9, 1, 9, 1, 4]
+    cut = dataclasses.replace(whole, num_layers=10)  # the first entries
+    assert cut.layer_runs == (("mamba", 5), ("attention", 1), ("mamba", 4))
+    assert isinstance(cut.layer_types, tuple) and hash(cut) != hash(whole)
+    assert LlamaConfig(num_layers=3).layer_runs == (("attention", 3),)
+    assert LlamaConfig(num_layers=2, layer_types=["mamba"] * 2, ssm_heads=2
+                       ).layer_runs == (("mamba", 2),)
+    with pytest.raises(ValueError, match="layer_types"):
+        LlamaConfig(num_layers=2, layer_types=["mamba", "conv"])
+    with pytest.raises(ValueError, match="num_layers"):
+        LlamaConfig(num_layers=3, layer_types=["mamba", "attention"])
+
+
+# Recorded on the parent commit (5579c54), where this tree gave the same
+# bits: loss and the sum of absolute gradients of LlamaConfig.tiny(**kw)
+# from PRNGKey(0) on the tokens below, and the sum of the parameters.  (The
+# last bit of a sum follows the host's thread count: one device read
+# 0x1.7c49de p+2, the tests' eight 0x1.7c49dc p+2; hence the 1e-6.)
+PARENT = [
+    (dict(), "0x1.7c49dc0000000p+2", "0x1.b999100000000p+8",
+     "0x1.06f5080000000p+9"),
+    (dict(num_experts=4, num_selected=2, z_loss_coef=0.001, qk_norm=True),
+     "0x1.8989560000000p+2", "0x1.db375a0000000p+8", "0x1.01ee1e0000000p+9"),
+    (dict(attn_impl="flash", remat=True, num_kv_heads=2),
+     "0x1.7593960000000p+2", "0x1.a424b60000000p+8", "0x1.0c9c9c0000000p+9"),
+]
+
+
+@pytest.mark.parametrize("kw,loss,grads,weights", PARENT,
+                         ids=["dense", "moe-qknorm", "gqa-flash-remat"])
+def test_existing_models_are_equal_to_the_parent(kw, loss, grads, weights):
+    """A model of one kind of layer keeps its parameter tree, its
+    initialisation and its arithmetic: values recorded on the parent."""
+    cfg = LlamaConfig.tiny(**kw)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        0, 256, (2, 33), dtype=np.int32))
+    got, g = jax.value_and_grad(
+        lambda p: loss_fn(p, {"tokens": tokens}, cfg)[0])(params)
+    total = lambda tree, f: float(sum(jnp.sum(f(a))
+                                      for a in jax.tree.leaves(tree)))
+    assert (float(got), total(g, jnp.abs), total(params, lambda a: a)) == \
+        pytest.approx([float.fromhex(v) for v in (loss, grads, weights)],
+                      rel=1e-6)
+
+
+def test_existing_models_parameter_trees_and_axes_are_unchanged():
+    dense = {"attn_norm": ("layer", "embed"),
+             "wq": ("layer", "kernel_in", "heads"),
+             "wk": ("layer", "kernel_in", "kv_heads"),
+             "wv": ("layer", "kernel_in", "kv_heads"),
+             "wo": ("layer", "heads", "kernel_in"),
+             "mlp_norm": ("layer", "embed"),
+             "w_gate": ("layer", "kernel_in", "mlp"),
+             "w_up": ("layer", "kernel_in", "mlp"),
+             "w_down": ("layer", "mlp", "kernel_in")}
+    top = {"embed": ("vocab", "kernel_in"), "final_norm": ("embed",),
+           "lm_head": ("kernel_in", "vocab")}
+    assert param_logical_axes(LlamaConfig.tiny()) == dict(top, layers=dense)
+    moe = dict(dense, q_norm=("layer", "heads"), k_norm=("layer", "kv_heads"),
+               router=("layer", "kernel_in", None),
+               w_gate=("layer", "expert", "kernel_in", "mlp"),
+               w_up=("layer", "expert", "kernel_in", "mlp"),
+               w_down=("layer", "expert", "mlp", "kernel_in"))
+    cfg = LlamaConfig.tiny(num_experts=4, qk_norm=True)
+    assert param_logical_axes(cfg) == dict(top, layers=moe)
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    assert set(shapes) == set(top) | {"layers"}
+    assert {k: v.shape for k, v in shapes["layers"].items()} == {
+        "attn_norm": (2, 64), "mlp_norm": (2, 64), "q_norm": (2, 64),
+        "k_norm": (2, 64), "wq": (2, 64, 64), "wk": (2, 64, 64),
+        "wv": (2, 64, 64), "wo": (2, 64, 64), "router": (2, 64, 4),
+        "w_gate": (2, 4, 64, 128), "w_up": (2, 4, 64, 128),
+        "w_down": (2, 4, 128, 64)}
+
+
+def test_mamba_initialisation_is_the_reference_codes():
+    """A in 1..16, dt in 1e-3..1e-1 through the inverse softplus, D = 1,
+    the convolution within 1/sqrt(width); the tied table as a head."""
+    cfg = _cfg(ssm_heads=64, ssm_head_dim=4, param_dtype=jnp.float32)
+    run = init_params(jax.random.PRNGKey(1), cfg)["layers"][0]
+    a = np.exp(np.asarray(run["A_log"]))
+    assert 1.0 <= a.min() < 3.0 and 14.0 < a.max() <= 16.0
+    dt = np.asarray(jax.nn.softplus(run["dt_bias"]))
+    assert 1e-3 * 0.99 <= dt.min() < 3e-3 and 3e-2 < dt.max() <= 0.1 * 1.01
+    assert np.all(np.asarray(run["D"]) == 1.0)
+    for name in ("conv_w", "conv_b"):
+        w = np.asarray(run[name])
+        assert -0.5 <= w.min() < -0.4 and 0.4 < w.max() <= 0.5
+    assert np.all(np.asarray(run["gate_norm"]) == 1.0)
+    table = np.asarray(init_params(jax.random.PRNGKey(1), cfg)["embed"])
+    assert table.std() == pytest.approx(64 ** -0.5, rel=0.05)
+
+
+def test_hybrid_on_an_fsdp2_mesh_equals_one_device():
+    """``init_train_state(mesh=...)`` shards the new parameters by their
+    logical axes, and the loss on fsdp=2 is the one-device loss."""
+    import optax
+
+    cfg, opt = _cfg(remat=False), optax.adam(1e-2)
+    mesh = make_mesh(MeshConfig(fsdp=2), devices=jax.devices()[:2])
+    state = init_train_state(jax.random.PRNGKey(0), cfg, opt, mesh=mesh)
+    shardings = train_state_shardings(cfg, opt, mesh)
+    mamba = shardings.params["layers"][0]
+    assert mamba["ssm_in"].spec == jax.sharding.PartitionSpec(
+        None, "fsdp", None)
+    assert mamba["ssm_out"].spec == jax.sharding.PartitionSpec(
+        None, None, "fsdp")
+    assert state.params["layers"][0]["ssm_in"].sharding == mamba["ssm_in"]
+    assert shardings.opt_state[0].mu["layers"][2]["conv_w"] == \
+        shardings.params["layers"][2]["conv_w"]
+    single = init_params(jax.random.PRNGKey(0), cfg)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-6),
+                 jax.device_get(state.params), jax.device_get(single))
+    with HIGHEST:
+        sharded = jax.jit(lambda p, t: loss_fn(p, {"tokens": t}, cfg,
+                                               mesh=mesh)[0])(
+            state.params, TOKENS)
+        one = loss_fn(single, {"tokens": TOKENS}, cfg)[0]
+    assert float(sharded) == pytest.approx(float(one), rel=1e-5)
+
+
+def test_pipelines_refuse_a_mixed_pattern():
+    cfg = _cfg()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    mesh = make_mesh(MeshConfig(pp=2), devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match="layers differ"):
+        forward_pipelined(params, TOKENS[:, :-1], cfg, mesh=mesh,
+                          num_microbatches=2)
+    with pytest.raises(NotImplementedError, match="layers differ"):
+        make_pipeline_stage_fn(cfg)
+    with pytest.raises(NotImplementedError, match="tied head"):
+        pipeline_stage_params(params, 2)
+
+
+def test_a_one_row_batch_takes_the_loss_of_the_batched_form():
+    """``_mean_nll`` drops the degenerate dimension of a one-row batch (on
+    the TPU the gather's gradient over it compiles to a flat scatter:
+    5 GB at 8192 x 100352): the same loss and gradients as the same row
+    twice."""
+    cfg = LlamaConfig.tiny()
+    params = init_params(jax.random.PRNGKey(2), cfg)
+    row = TOKENS[:1, :33]
+    loss = lambda t: jax.value_and_grad(
+        lambda p: loss_fn(p, {"tokens": t}, cfg)[0])(params)
+    (one, one_grads), (two, two_grads) = loss(row), loss(
+        jnp.concatenate([row, row]))
+    assert float(one) == pytest.approx(float(two), rel=1e-6)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, rtol=1e-4, atol=1e-7), one_grads, two_grads)

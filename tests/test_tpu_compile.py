@@ -228,3 +228,31 @@ def test_expert_parallel_train_step_compiles(mesh4, as_on_chip):
         _state_shapes(cfg, opt, shardings), batch).compile()
     text = compiled.as_text()
     assert "moe_gmm" in text and "moe_tgmm" in text
+
+
+@pytest.mark.parametrize("layer_types,kernel", [
+    (("mamba",), False), (("attention",), True)],
+    ids=["mamba", "attention-nope-head64"])
+def test_granite_hybrid_layer_train_step_compiles(one_chip, as_on_chip,
+                                                  layer_types, kernel):
+    """One layer of each kind of granite-4.0-h-micro at its published
+    widths and 8192 positions, as a train step (the vocabulary cut, so the
+    head is not what is compiled): the Mamba-2 layer's chunked scan is
+    plain XLA and fits; the attention layer's flash kernels take head size
+    64 (half of Mosaic's minor dimension), 32 query heads on 8 KV heads,
+    a softmax scale that is a given number, and no RoPE."""
+    cfg = LlamaConfig(
+        vocab_size=4096, embed_dim=2048, num_layers=1, num_heads=32,
+        num_kv_heads=8, head_dim=64, mlp_dim=8192, norm_eps=1e-5,
+        layer_types=layer_types, ssm_heads=64, ssm_head_dim=64,
+        ssm_state=128, ssm_groups=1, ssm_conv=4, ssm_chunk=256,
+        position_embedding="nope", attention_multiplier=1 / 64,
+        embedding_multiplier=12, residual_multiplier=0.22, logits_scaling=8,
+        tie_embeddings=True, param_dtype=jnp.bfloat16)
+    opt = default_optimizer()
+    compiled = make_train_step(cfg, opt).lower(
+        _state_shapes(cfg, opt, one_chip),
+        {"tokens": _shape((1, 8193), jnp.int32, one_chip)}).compile()
+    assert _has_kernel(compiled) is kernel
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8e9
