@@ -556,8 +556,8 @@ def _mamba_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual):
     (the convolution over x, B, C with its SiLU; dt's softplus),
     ``ssm_scan`` (the chunked scan, ``D x`` included), ``ssm_out`` (the
     norm of the GATED output — gate first, then one norm over the whole
-    inner width —, the output projection, the residual add).  Plain XLA:
-    under a mesh the partitioner splits it over the batch."""
+    inner width —, the output projection, the residual add).  The scan is
+    Pallas kernels where its shapes allow, per shard of the batch."""
     b, s = x.shape[0], x.shape[1]
     inner, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
     f32 = jnp.float32
@@ -571,7 +571,7 @@ def _mamba_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual):
         dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
         xs, bm, cm = jnp.split(xbc, [inner, inner + gn], -1)
     with jax.named_scope("ssm_scan"):
-        y = ssd_chunked(
+        y = _ssd_scan(mesh, sp_manual)(
             xs.reshape(b, s, cfg.ssm_heads, cfg.ssm_head_dim), dt,
             -jnp.exp(lp["A_log"].astype(f32)),
             bm.reshape(b, s, cfg.ssm_groups, cfg.ssm_state),
@@ -801,3 +801,25 @@ def _mean_nll(logits, targets):
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     return jnp.mean(nll)
+
+
+def _ssd_scan(mesh: Optional[Mesh], sp_manual: bool):
+    """``ssd_chunked`` as ``_mamba_mixer`` calls it.  A Pallas kernel has
+    no partitioning rule, so under a mesh the scan runs per shard of the
+    batch, manual over EVERY axis as ``_attention`` runs flash: ``ssm_inner``
+    maps to no mesh axis, so a shard holds whole heads and whole
+    sequences.  Inside an already-manual region it is called inline."""
+    if mesh is None or sp_manual:
+        return ssd_chunked
+    from ray_tpu.parallel.sharding import manual_shard_map
+
+    def rows(ndim):
+        return P((AXIS_DP, AXIS_FSDP), *(None,) * (ndim - 1))
+
+    def per_shard(x, dt, a, bm, cm, d, *, chunk):
+        return manual_shard_map(
+            lambda *t: ssd_chunked(*t, chunk=chunk), set(mesh.axis_names),
+            in_specs=(rows(4), rows(3), P(), rows(4), rows(4), P()),
+            out_specs=rows(4), mesh=mesh)(x, dt, a, bm, cm, d)
+
+    return per_shard

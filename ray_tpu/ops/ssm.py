@@ -14,12 +14,33 @@ across chunks.  ``ssd_reference`` is the recurrence itself, a token at a
 time in float32: what the tests hold the chunked form to, as
 ``ops/attention.py`` has ``mha_reference`` beside its kernels.
 
-Plain XLA, differentiated by autodiff: under the layer checkpoint
-(``models/llama.py::_checkpoint``) a layer's backward pass runs its
-forward again, so the intra-chunk matrices of one layer at a time exist
-(``(chunks, heads, chunk, chunk)``: 268 MB in bfloat16 at 8192 tokens, 64
-heads, chunks of 256).  A Pallas kernel that keeps them in VMEM is sized by
-the benchmark's ``ssm.scan_roofline`` (ROADMAP S10).
+Two forms of ONE algorithm, chosen by what a call's shapes show
+(``kernels_fit``): where heads, state and chunk tile the chip — the head
+size divides the 128 lanes and is at least 16, the heads fill whole lane
+blocks, the state and the chunk are multiples of 128, one group (the
+published 64 heads x 64, state 128, chunks of 256) — ``ssd_kernels``, two
+Pallas kernels under a ``custom_vjp`` (interpreted off the chip, so the
+tests run the same code); elsewhere ``ssd_xla``, the same sums as plain
+XLA differentiated by autodiff, which writes the intra-chunk matrices
+(``(chunks, heads, chunk, chunk)``: 268 MB in bfloat16 at 8192 tokens) to
+memory in every pass.  ``ssd_xla`` is also the tests' second oracle.
+
+The kernels (``ssd_fwd``, ``ssd_bwd``): grid ``(batch, chunk, head
+block)``, a head block being the heads that fill 128 lanes of the ``(b, s,
+heads * head_dim)`` layout the model's projections read and write, so
+nothing is transposed around a call.  The chunk axis is sequential and the
+state of every head rides in a VMEM scratch from one chunk to the next
+(backward: its gradient, chunks in reverse); the chunk's ``C B^T o L``
+matrix is made, used and dropped in VMEM.  ``ssd_fwd`` also writes the
+state ENTERING each chunk (float32, 67 MB a layer at the published
+sizes), which is all ``ssd_bwd`` needs beside the inputs: under a layer
+checkpoint the forward kernel runs again in the backward pass and its
+states never reach the checkpoint's stack.  The cumulative log-decays of
+a chunk are made by XLA around the call (small ``(b, s, heads)`` float32
+arrays), which also differentiates them: the backward kernel returns the
+gradient to the cumulative sums (row sums less column sums of ``dM o M``,
+what the decays to and from the chunk's ends collect, and ``<dH, H>`` at
+a chunk's last token).
 
 Precision: the decays (``dt A``, their cumulative sums, every ``exp``) and
 the state carried across chunks are float32; the operands of the big
@@ -30,12 +51,23 @@ float32 accumulation.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import attention
 from ray_tpu.ops.layers import rms_norm
 
 _F32 = jnp.float32
+_LANES = 128          # a head block fills the TPU's lane width
+_MAX_BLOCK_HEADS = 8  # a block's heads are written out in the kernel body
+# Head blocks a grid step works through, at most: fewer, longer steps (TPU
+# v5e, one granite layer: forward 0.83 ms with 1, 0.78 with 4, 0.74 with 8;
+# PERF.md §6, PR 32), their B, C and C B^T made once.
+_STEP_BLOCKS = 8
 
 
 def _conv_pre(x, weight, bias):
@@ -114,16 +146,48 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     ``heads / groups`` heads; ``d (heads,)``.  Returns ``y`` like ``x``.
     A sequence that is no multiple of the chunk is padded with tokens
     whose ``dt`` is 0 (no decay, no input), which the causal order keeps
-    from every real output."""
+    from every real output.
+
+    The Pallas kernels where the shapes tile the chip (``kernels_fit``),
+    the XLA form elsewhere: one algorithm, the same values to rounding."""
+    form = ssd_kernels if kernels_fit(
+        x.shape[2], x.shape[3], b.shape[2], b.shape[3],
+        min(chunk, x.shape[1])) else ssd_xla
+    return form(x, dt, a, b, c, d, chunk=chunk)
+
+
+def kernels_fit(heads: int, head_dim: int, groups: int, state: int,
+                chunk: int) -> bool:
+    """Whether a call's shapes tile the chip for ``ssd_kernels``: whole
+    head blocks of 128 lanes, a state and a chunk that are multiples of
+    the lane width (both are a matmul's minor dimension), one group (B
+    and C are then shared by every head block)."""
+    block = _LANES // head_dim if head_dim and _LANES % head_dim == 0 else 0
+    return (groups == 1 and 1 <= block <= _MAX_BLOCK_HEADS
+            and heads % block == 0 and state % _LANES == 0
+            and chunk % _LANES == 0)
+
+
+def _padded(q, *tensors):
+    """``tensors`` ``(batch, s, ...)`` padded with zeros along ``s`` to a
+    multiple of ``q``."""
+    pad = -tensors[0].shape[1] % q
+    if not pad:
+        return tensors
+    return tuple(jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                 for t in tensors)
+
+
+def ssd_xla(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+            c: jax.Array, d: jax.Array, *, chunk: int) -> jax.Array:
+    """``ssd_chunked`` as plain XLA, for any shapes: the chunk's matrices
+    are arrays in memory and autodiff writes the backward pass."""
     batch, s, heads, p = x.shape
     groups, n = b.shape[2], b.shape[3]
     r = heads // groups
     q = min(chunk, s)
-    pad = -s % q
-    if pad:
-        x, dt, b, c = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-                       for t in (x, dt, b, c))
-    nc = (s + pad) // q
+    x, dt, b, c = _padded(q, x, dt, b, c)
+    nc = x.shape[1] // q
     dtype = x.dtype
     dt = dt.astype(_F32)
     xg = x.reshape(batch, nc, q, groups, r, p)
@@ -164,7 +228,365 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                        preferred_element_type=_F32) * jnp.exp(acs)[..., None]
 
     y = y + xg.astype(_F32) * d.astype(_F32).reshape(groups, r)[..., None]
-    return y.astype(dtype).reshape(batch, s + pad, heads, p)[:, :s]
+    return y.astype(dtype).reshape(batch, nc * q, heads, p)[:, :s]
+
+
+# ------------------------------------------------------- the Pallas form
+#
+# A grid step takes one chunk of a few head blocks in turn, a block
+# being ``hb`` heads whose ``hb * p`` values a token fill the lanes of a
+# ``(q, w)`` tile of x.  A
+# head's scalars a token (dt, its cumulative log-decay) come as columns
+# ``(q, 1)`` picked from the ``(q, heads)`` block and as rows ``(1, q)``
+# cut from its transpose; ``_spread`` lays a value a head over that
+# head's lanes, ``_only`` blanks the other heads' lanes so that one
+# 128-wide product serves a head of 64 at the cost the MXU charges for 64
+# anyway.
+
+
+def _dot(a, b, contract):
+    """``a`` x ``b`` contracting axis ``contract[0]`` of ``a`` with axis
+    ``contract[1]`` of ``b``, float32 out."""
+    return jax.lax.dot_general(
+        a, b, (((contract[0],), (contract[1],)), ((), ())),
+        preferred_element_type=_F32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _spread(values, shape, axis, p):
+    """``shape`` filled along ``axis`` with ``values[i]`` where head ``i``
+    of the block sits (``p`` positions each)."""
+    out = jnp.broadcast_to(values[-1], shape)
+    for i in range(len(values) - 2, -1, -1):
+        out = jnp.where(_iota(shape, axis) < (i + 1) * p, values[i], out)
+    return out
+
+
+def _only(v, i, hb, p, axis=1):
+    """``v`` with the positions of every head but ``i`` zeroed."""
+    if hb == 1:
+        return v
+    at = _iota(v.shape, axis)
+    return jnp.where((at >= i * p) & (at < (i + 1) * p), v,
+                     jnp.zeros_like(v))
+
+
+def _per_head(v, hb, p, axis):
+    """The sums of ``v`` over each head's positions along ``axis``."""
+    return [jnp.sum(_only(v, i, hb, p, axis), axis=axis, keepdims=True)
+            for i in range(hb)]
+
+
+def _causal(cb, transposed=False):
+    """``cb (q, q)`` with zeros above the diagonal (below it, for the
+    transposed matrix)."""
+    rows, cols = _iota(cb.shape, 0), _iota(cb.shape, 1)
+    return jnp.where(rows <= cols if transposed else rows >= cols, cb, 0.0)
+
+
+def _chunk_terms(x, dt_ref, acs_ref, acsr_ref, cb, k, hb, p,
+                 transposed=False):
+    """What forward and backward both make of one chunk of head block
+    ``k`` from ``cb``, the causal part of ``C B^T``: per head the masked
+    matrix ``M = (C B^T o L)`` in ``x.dtype`` and ``L`` in float32, which
+    is right on and below the diagonal only (above it the decays are
+    capped at 1 and ``cb`` is 0, so the step masks once, not once a
+    head) — or, ``transposed``, ``M^T`` and ``L^T`` from ``cb^T``, made
+    in place: a transpose of ``M`` a head is a third of the backward
+    kernel's time; over the block's lanes ``dt``, ``dt x``, ``exp(acs)``
+    (decay from the chunk's start), ``dt x`` decayed to the chunk's end;
+    down the state's rows the chunk's whole decay."""
+    q, w = x.shape
+    dtype = x.dtype
+    heads = [k * hb + i for i in range(hb)]
+    dt_all, acs_all = dt_ref[0], acs_ref[0]               # (q, heads)
+    lane = _iota(dt_all.shape, 1)
+
+    def column(v, h):
+        return jnp.sum(jnp.where(lane == h, v, 0.0), axis=1, keepdims=True)
+
+    dts = [column(dt_all, h) for h in heads]              # (q, 1) each
+    cols = [column(acs_all, h) for h in heads]
+    rows = [acsr_ref[0, pl.ds(h, 1), :] for h in heads]   # (1, q) each
+    at_end = _iota((1, q), 1) == q - 1
+    lasts = [jnp.sum(jnp.where(at_end, r, 0.0), axis=1, keepdims=True)
+             for r in rows]                               # (1, 1) each
+    ls = [jnp.exp(jnp.minimum(row - col if transposed else col - row, 0.0))
+          for col, row in zip(cols, rows)]
+    dt_l = _spread(dts, (q, w), 1, p)
+    acs_l = _spread(cols, (q, w), 1, p)
+    xd = (x.astype(_F32) * dt_l).astype(dtype)
+    to_end = jnp.exp(_spread(lasts, (q, w), 1, p) - acs_l)
+    ends = [jnp.exp(v) for v in lasts]                    # the chunk's decay
+    return dict(
+        heads=heads, dts=dts, ls=ls, ms=[(cb * l).astype(dtype) for l in ls],
+        dt_l=dt_l, xd=xd, from_start=jnp.exp(acs_l), to_end=to_end,
+        xd_end=(xd.astype(_F32) * to_end).astype(dtype),
+        ends=ends, decay=_spread(ends, (w, 1), 0, p))
+
+
+def _each_block(nb, w, body):
+    """``body(g, lanes)`` for each of a grid step's ``nb`` head blocks,
+    ``lanes`` the block's ``w`` of the step's ``nb * w``: a loop, not
+    ``nb`` copies of the body."""
+    def block(g, carry):
+        body(g, pl.ds(pl.multiple_of(g * w, w), w))
+        return carry
+
+    jax.lax.fori_loop(0, nb, block, 0)
+
+
+def _fwd_kernel(x_ref, dt_ref, acs_ref, acsr_ref, b_ref, c_ref, d_ref,
+                y_ref, states_ref, h_scr, *, hb, p, nb):
+    j, first_chunk = pl.program_id(2), pl.program_id(1) == 0
+    bm, cm = b_ref[0], c_ref[0]
+    cb = _causal(_dot(cm, bm, (1, 1)))                    # (q, q)
+
+    def block(g, lanes):
+        k = j * nb + g
+        x = x_ref[0, :, lanes]
+        t = _chunk_terms(x, dt_ref, acs_ref, acsr_ref, cb, k, hb, p)
+        h = jnp.where(first_chunk, 0.0, h_scr[k])         # (w, n) float32
+        y = (t["from_start"] * _dot(cm, h.astype(x.dtype), (1, 1))
+             + x.astype(_F32) * d_ref[:, lanes])
+        for i, m in enumerate(t["ms"]):
+            y += _dot(m, _only(t["xd"], i, hb, p), (1, 0))
+        y_ref[0, :, lanes] = y.astype(y_ref.dtype)
+        states_ref[0, 0, lanes, :] = h
+        h_scr[k] = t["decay"] * h + _dot(t["xd_end"], bm, (0, 0))
+
+    _each_block(nb, hb * p, block)
+
+
+def _bwd_kernel(x_ref, dt_ref, acs_ref, acsr_ref, b_ref, c_ref, d_ref,
+                states_ref, dy_ref,
+                dx_ref, ddt_ref, dacs_ref, dacsr_ref, db_ref, dc_ref, dd_ref,
+                dh_scr, db_scr, dc_scr, dcbt_scr, *, hb, p, nb):
+    """Chunks from the last to the first (the index maps turn the chunk
+    axis round); ``dh_scr[k]`` is the gradient to the state LEAVING the
+    chunk.  Every ``(q, q)`` matrix here is the TRANSPOSE of the forward
+    kernel's (``[j, i]``: token ``j`` feeds token ``i >= j``).  The
+    gradient to a head's cumulative log-decays comes in two parts that
+    are added outside: down the tokens (``dacs_ref``) what each token's
+    own decays collect less the row sums of ``dM^T o M^T``, and along
+    them (``dacsr_ref``) its column sums — sums of ONE float32 matrix,
+    because their difference is summed again over the chunk and does not
+    survive two roundings."""
+    j, nj = pl.program_id(2), pl.num_programs(2)
+    last_chunk = pl.program_id(1) == 0
+    bm, cm = b_ref[0], c_ref[0]
+    q, dtype = bm.shape[0], x_ref.dtype
+    cbt = _causal(_dot(bm, cm, (1, 1)), transposed=True)
+    dcbt_scr[...] = jnp.zeros_like(dcbt_scr)
+
+    @pl.when(j == 0)
+    def _first_step():
+        db_scr[...] = jnp.zeros_like(db_scr)
+        dc_scr[...] = jnp.zeros_like(dc_scr)
+        ddt_ref[...] = jnp.zeros_like(ddt_ref)
+        dacs_ref[...] = jnp.zeros_like(dacs_ref)
+
+    def block(g, lanes):
+        k = j * nb + g
+        x, dy = x_ref[0, :, lanes], dy_ref[0, :, lanes]
+        t = _chunk_terms(x, dt_ref, acs_ref, acsr_ref, cbt, k, hb, p,
+                         transposed=True)
+        xf, dyf = x.astype(_F32), dy.astype(_F32)
+        h = states_ref[0, 0, lanes, :]                    # entering, (w, n)
+        dh = jnp.where(last_chunk, 0.0, dh_scr[k])
+        h_lo, dh_lo = h.astype(dtype), dh.astype(dtype)
+
+        y_in = t["from_start"] * _dot(cm, h_lo, (1, 1))   # from the state
+        dxd_end = t["to_end"] * _dot(bm, dh_lo, (1, 1))   # through the state
+        dxd, fed = dxd_end, []
+        for i, (mt, lt) in enumerate(zip(t["ms"], t["ls"])):
+            dy_i = _only(dy, i, hb, p)
+            dxd += _dot(mt, dy_i, (1, 0))
+            dlt = _dot(t["xd"], dy_i, (1, 1)) * lt        # (dM o L)^T
+            dcbt_scr[...] += dlt
+            dmmt = dlt * cbt                              # (dM o M)^T
+            fed.append(jnp.sum(dmmt, axis=1, keepdims=True))
+            dacsr_ref[0, pl.ds(t["heads"][i], 1), :] = jnp.sum(
+                dmmt, axis=0, keepdims=True)
+        dy_start = (t["from_start"] * dyf).astype(dtype)
+        dc_scr[...] += _dot(dy_start, h_lo, (1, 0))
+        db_scr[...] += _dot(t["xd_end"], dh_lo, (1, 0))
+        dh_scr[k] = t["decay"] * dh + _dot(dy_start, cm, (0, 0))
+
+        dx_ref[0, :, lanes] = (t["dt_l"] * dxd + d_ref[:, lanes] * dyf
+                               ).astype(dx_ref.dtype)
+        dd_ref[0, 0, :, lanes] = jnp.sum(dyf * xf, axis=0, keepdims=True)
+        at_last = _iota((q, 1), 0) == q - 1
+        lane = _iota(ddt_ref.shape[1:], 1)
+        ddt = jnp.zeros(ddt_ref.shape[1:], _F32)
+        dacs = jnp.zeros(dacs_ref.shape[1:], _F32)
+        for i, (ddt_i, start_i, end_i, carry_i) in enumerate(zip(
+                _per_head(dxd * xf, hb, p, 1),
+                _per_head(dyf * y_in, hb, p, 1),
+                _per_head(dxd_end * t["xd"].astype(_F32), hb, p, 1),
+                _per_head(jnp.sum(dh * h, axis=1, keepdims=True),
+                          hb, p, 0))):
+            at_end = (jnp.sum(end_i, axis=0, keepdims=True)
+                      + t["ends"][i] * carry_i)
+            dacs_i = (start_i - end_i - fed[i]
+                      + jnp.where(at_last, at_end, 0.0))
+            ddt = jnp.where(lane == t["heads"][i], ddt_i, ddt)
+            dacs = jnp.where(lane == t["heads"][i], dacs_i, dacs)
+        ddt_ref[0] += ddt
+        dacs_ref[0] += dacs
+
+    _each_block(nb, hb * p, block)
+    # of the step's heads; dcb = dcbt^T
+    dcbt = _causal(dcbt_scr[...], transposed=True).astype(dtype)
+    dc_scr[...] += _dot(dcbt, bm, (0, 0))
+    db_scr[...] += _dot(dcbt, cm, (1, 0))
+
+    @pl.when(j == nj - 1)
+    def _last_step():
+        db_ref[0] = db_scr[...].astype(db_ref.dtype)
+        dc_ref[0] = dc_scr[...].astype(dc_ref.dtype)
+
+
+def _compiler_params(interpret):
+    if interpret:
+        return None
+    # Chunks carry the state and head blocks share the chunk's B and C
+    # gradients: both axes run in order.
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _plan(x, dt, bm, q, p, reverse):
+    """What the two calls share: the grid ``(batch, chunk, step of the
+    chunk)``; the kernels' blocking — a block is the heads that fill the
+    lanes, a step as many blocks as ``_STEP_BLOCKS`` allows and the head
+    count divides —; the BlockSpecs, a grid step working on chunk ``c``
+    or, ``reverse``, on the chunk as far from the end; the VMEM scratch
+    that carries one ``(w, n)`` state a head block."""
+    batch, s, _ = x.shape
+    heads, n = dt.shape[2], bm.shape[2]
+    hb = min(heads, max(1, _LANES // p), _MAX_BLOCK_HEADS)
+    nb = _STEP_BLOCKS
+    while (heads // hb) % nb:
+        nb //= 2
+    nc, w = s // q, nb * hb * p
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda b_, c_, j_: index(
+            b_, nc - 1 - c_ if reverse else c_, j_))
+
+    return dict(
+        grid=(batch, nc, heads // hb // nb), blocking=dict(hb=hb, p=p, nb=nb),
+        carry=pltpu.VMEM((heads // hb, hb * p, n), _F32),
+        x=spec((1, q, w), lambda b_, c_, j_: (b_, c_, j_)),
+        col=spec((1, q, heads), lambda b_, c_, j_: (b_, c_, 0)),
+        row=spec((1, heads, q), lambda b_, c_, j_: (b_, 0, c_)),
+        bc=spec((1, q, n), lambda b_, c_, j_: (b_, c_, 0)),
+        d=spec((1, w), lambda b_, c_, j_: (0, j_)),
+        states=spec((1, 1, w, n), lambda b_, c_, j_: (b_, c_, j_, 0)),
+        dd=spec((1, 1, 1, w), lambda b_, c_, j_: (b_, c_, 0, j_)))
+
+
+@functools.partial(jax.jit, static_argnames=("q", "p", "interpret"))
+def _fwd_call(x, dt, acs, bm, cm, d_l, *, q, p, interpret):
+    """``x (b, s, heads * p)``, ``dt`` and ``acs`` (its cumulative
+    log-decay inside each chunk of ``q``) ``(b, s, heads)`` float32,
+    ``bm``, ``cm`` ``(b, s, n)``, ``d_l (1, heads * p)`` float32.  Returns
+    ``y`` like ``x`` and the state entering every chunk ``(b, s / q,
+    heads * p, n)`` float32."""
+    sp = _plan(x, dt, bm, q, p, reverse=False)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, **sp["blocking"]),
+        grid=sp["grid"],
+        in_specs=[sp["x"], sp["col"], sp["col"], sp["row"], sp["bc"],
+                  sp["bc"], sp["d"]],
+        out_specs=[sp["x"], sp["states"]],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(
+                       (x.shape[0], x.shape[1] // q, x.shape[2],
+                        bm.shape[2]), _F32)],
+        scratch_shapes=[sp["carry"]],
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+        name="ssd_fwd",
+    )(x, dt, acs, jnp.swapaxes(acs, 1, 2), bm, cm, d_l)
+
+
+@functools.partial(jax.jit, static_argnames=("q", "p", "interpret"))
+def _bwd_call(x, dt, acs, bm, cm, d_l, states, dy, *, q, p, interpret):
+    """Gradients to ``x``, ``dt``, ``acs`` (two parts: like ``acs`` and
+    like its transpose), ``bm``, ``cm`` (each like its argument) and to
+    ``d_l`` a chunk ``(b, s / q, 1, heads * p)``."""
+    sp = _plan(x, dt, bm, q, p, reverse=True)
+    n = bm.shape[2]
+    like = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, **sp["blocking"]),
+        grid=sp["grid"],
+        in_specs=[sp["x"], sp["col"], sp["col"], sp["row"], sp["bc"],
+                  sp["bc"], sp["d"], sp["states"], sp["x"]],
+        out_specs=[sp["x"], sp["col"], sp["col"], sp["row"], sp["bc"],
+                   sp["bc"], sp["dd"]],
+        out_shape=[like(x), like(dt), like(acs),
+                   jax.ShapeDtypeStruct(
+                       (acs.shape[0], acs.shape[2], acs.shape[1]), _F32),
+                   like(bm), like(cm),
+                   jax.ShapeDtypeStruct(
+                       (x.shape[0], x.shape[1] // q, 1, x.shape[2]), _F32)],
+        scratch_shapes=[sp["carry"], pltpu.VMEM((q, n), _F32),
+                        pltpu.VMEM((q, n), _F32), pltpu.VMEM((q, q), _F32)],
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+        name="ssd_bwd",
+    )(x, dt, acs, jnp.swapaxes(acs, 1, 2), bm, cm, d_l, states, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _scan(x, dt, acs, bm, cm, d_l, q, p, interpret):
+    return _fwd_call(x, dt, acs, bm, cm, d_l, q=q, p=p,
+                     interpret=interpret)[0]
+
+
+def _scan_fwd(x, dt, acs, bm, cm, d_l, q, p, interpret):
+    y, states = _fwd_call(x, dt, acs, bm, cm, d_l, q=q, p=p,
+                          interpret=interpret)
+    return y, (x, dt, acs, bm, cm, d_l, states)
+
+
+def _scan_bwd(q, p, interpret, res, dy):
+    dx, ddt, dacs, dacs_t, db, dc, dd = _bwd_call(*res, dy, q=q, p=p,
+                                                  interpret=interpret)
+    return (dx, ddt, dacs + jnp.swapaxes(dacs_t, 1, 2), db, dc,
+            jnp.sum(dd, axis=(0, 1)))
+
+
+_scan.defvjp(_scan_fwd, _scan_bwd)
+
+
+def ssd_kernels(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+                c: jax.Array, d: jax.Array, *, chunk: int) -> jax.Array:
+    """``ssd_chunked`` through the Pallas kernels (one group; compiled on
+    the TPU, interpreted elsewhere).  XLA pads, makes each chunk's
+    cumulative log-decays and lays ``D`` over its heads' lanes, and
+    differentiates those; the rest is ``ssd_fwd`` and ``ssd_bwd``."""
+    batch, s, heads, p = x.shape
+    if b.shape[2] != 1:
+        raise ValueError(f"ssd_kernels takes one group, got {b.shape[2]}")
+    q = min(chunk, s)
+    x, dt, b, c = _padded(q, x, dt.astype(_F32), b, c)
+    padded = x.shape[1]
+    acs = jnp.cumsum((dt * a.astype(_F32)).reshape(batch, padded // q, q,
+                                                   heads), axis=2)
+    y = _scan(x.reshape(batch, padded, heads * p), dt,
+              acs.reshape(batch, padded, heads),
+              b.reshape(batch, padded, -1), c.reshape(batch, padded, -1),
+              jnp.repeat(d.astype(_F32), p)[None], q, p,
+              attention._interpret_default())
+    return y.reshape(batch, padded, heads, p)[:, :s]
 
 
 def ssd_reference(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,

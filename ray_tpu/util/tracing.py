@@ -293,8 +293,8 @@ PHASES = ("forward", "remat", "backward", "optimizer")
 # writing its gradients into the stacked gradients, the loop itself.
 SCAN = "scan"
 # How the ``name=`` of the program's Pallas kernels start (ops/attention.py,
-# ops/moe.py): the kernel rows of ``step_breakdown``.
-KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm")
+# ops/moe.py, ops/ssm.py): the kernel rows of ``step_breakdown``.
+KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm", "ssd_")
 _SCOPE_TOKENS = re.compile(r"[^/()]+")
 
 
@@ -441,6 +441,7 @@ def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
         op_name = names.get(plane_name, {})
         by_scope: Dict[str, Dict[str, float]] = {}
         kernels: Dict[str, float] = {}
+        kernel_calls: Dict[str, int] = {}
         kernel_pairs: Dict[str, float] = {}
         unscoped: Dict[str, float] = {}
         busy = 0
@@ -466,6 +467,7 @@ def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
                                if t.startswith(KERNEL_NAMES)), "unnamed")
                 key = kernel + (".remat" if phase == "remat" else "")
                 kernels[key] = kernels.get(key, 0) + self_ns
+                kernel_calls[key] = kernel_calls.get(key, 0) + 1
                 if key not in kernel_pairs:
                     ratio = flash_executed_over_causal(text)
                     if ratio is not None:
@@ -494,6 +496,7 @@ def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
                 ([k, t / n / 1e9] for k, t in unscoped.items()),
                 key=lambda kv: -kv[1])[:10],
             "kernels": {k: t / n / 1e9 for k, t in kernels.items()},
+            "kernel_calls": {k: c / n for k, c in kernel_calls.items()},
             "kernel_pairs": kernel_pairs,
         }
         if best is None or result["busy_s"] > best["busy_s"]:
@@ -506,8 +509,9 @@ def step_breakdown(xplane_path: str, step_module: str = "jit_step",
                    ) -> Optional[Dict[str, Any]]:
     """Device seconds per step of a profiler trace (``.xplane.pb``) by step
     scope and phase: ``{"scopes": {scope: {phase: s}}, "unscoped_s",
-    "unscoped_ops", "kernels": {name: s}, "kernel_pairs": {name: executed
-    / causal pairs}, "step_s", "busy_s", "steps", "device"}``.  SELF times
+    "unscoped_ops", "kernels": {name: s}, "kernel_calls": {name: calls a
+    step}, "kernel_pairs": {name: executed / causal pairs}, "step_s",
+    "busy_s", "steps", "device"}``.  SELF times
     (a ``while`` covers its body), over the executions of ``step_module``
     after the first; the Mosaic kernels by the ``name=`` of their
     ``pallas_call``.  None when the trace holds no two executions of the
@@ -552,6 +556,8 @@ def format_breakdown(b: Dict[str, Any]) -> str:
         out.append(f"  unscoped {t * 1e3:9.3f} ms  {name}")
     for name, t in sorted(b["kernels"].items()):
         pairs = b.get("kernel_pairs", {}).get(name)
+        calls = b.get("kernel_calls", {}).get(name)
         out.append(f"  kernel   {t * 1e3:9.3f} ms  {name}" + (
-            "" if pairs is None else f"  executed/causal {pairs:.4f}"))
+            "" if pairs is None else f"  executed/causal {pairs:.4f}") + (
+            "" if calls is None else f"  x {calls:g} a step"))
     return "\n".join(out)

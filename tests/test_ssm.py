@@ -18,7 +18,9 @@ from ray_tpu.models.llama import (
     LlamaConfig, forward_pipelined, init_params, loss_fn,
     make_pipeline_stage_fn, param_logical_axes, pipeline_stage_params)
 from ray_tpu.ops.layers import rms_norm
-from ray_tpu.ops.ssm import causal_conv1d, ssd_chunked, ssd_reference
+from ray_tpu.ops.ssm import (
+    causal_conv1d, kernels_fit, ssd_chunked, ssd_kernels, ssd_reference,
+    ssd_xla)
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.train.core import init_train_state, train_state_shardings
 
@@ -35,43 +37,114 @@ def _scan_inputs(seq, seed=0, batch=2, heads=4, p=8, groups=2, n=16):
             f(batch, seq, groups, n), f(heads))
 
 
+def _grads(form, args, weight):
+    return jax.jit(jax.grad(lambda *t: jnp.sum(form(*t) * weight),
+                            argnums=range(6)))(*args)
+
+
+@pytest.mark.parametrize("form", ["chunked", "kernels"])
 @pytest.mark.parametrize("seq,chunk", [(40, 8), (32, 32), (24, 256), (37, 8)],
                          ids=["several-chunks", "one-chunk",
                               "shorter-than-a-chunk", "ragged"])
-def test_ssd_chunked_equals_the_recurrence(seq, chunk):
+def test_ssd_chunked_equals_the_recurrence(seq, chunk, form):
     """Values and every gradient, against the recurrence a token at a
     time: a sequence of several chunks, one that is a single chunk, and one
-    that no chunk divides (padded with tokens that neither decay nor add)."""
-    args = _scan_inputs(seq)
+    that no chunk divides (padded with tokens that neither decay nor add).
+    ``chunked`` is the public call, which takes the XLA form at these
+    sizes (two groups); ``kernels`` the Pallas kernels, interpreted, on
+    one group, held to the XLA form as well."""
+    if form == "chunked":
+        args, run = _scan_inputs(seq), ssd_chunked
+    else:
+        args, run = _scan_inputs(seq, groups=1), ssd_kernels
     weight = jnp.asarray(np.random.default_rng(1).normal(
         size=args[0].shape), jnp.float32)
+    forms = (lambda *t: run(*t, chunk=chunk), ssd_reference,
+             lambda *t: ssd_xla(*t, chunk=chunk))
     with HIGHEST:
-        want = jax.jit(ssd_reference)(*args)
-        got = jax.jit(lambda *t: ssd_chunked(*t, chunk=chunk))(*args)
+        got, want, xla = (jax.jit(f)(*args) for f in forms)
         assert got.shape == want.shape and got.dtype == args[0].dtype
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-        grads = [jax.jit(jax.grad(lambda *t: jnp.sum(f(*t) * weight),
-                                  argnums=range(6)))(*args)
-                 for f in (lambda *t: ssd_chunked(*t, chunk=chunk),
-                           ssd_reference)]
-    for name, g, w in zip("x dt a b c d".split(), *grads):
+        np.testing.assert_allclose(got, xla, atol=2e-5, rtol=2e-5)
+        grads = [_grads(f, args, weight) for f in forms]
+    for name, g, w, x in zip("x dt a b c d".split(), *grads):
         assert np.all(np.isfinite(g)), name
         np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4, err_msg=name)
+        np.testing.assert_allclose(g, x, atol=2e-4, rtol=2e-4, err_msg=name)
 
 
-def test_ssd_chunked_in_bfloat16_keeps_its_decays_in_float32():
+@pytest.mark.parametrize("form,groups", [(ssd_xla, 2), (ssd_kernels, 1)],
+                         ids=["xla", "kernels"])
+def test_ssd_chunked_in_bfloat16_keeps_its_decays_in_float32(form, groups):
     """Operands of the big products in the input's dtype, decays and the
     carried state in float32: a bfloat16 call stays within bfloat16's
     rounding of the float32 recurrence over 8 chunks."""
-    x, dt, a, b, c, d = _scan_inputs(64, seed=3)
-    got = ssd_chunked(*(t.astype(jnp.bfloat16) for t in (x,)), dt, a,
-                      b.astype(jnp.bfloat16), c.astype(jnp.bfloat16), d,
-                      chunk=8)
+    x, dt, a, b, c, d = _scan_inputs(64, seed=3, groups=groups)
+    got = form(x.astype(jnp.bfloat16), dt, a, b.astype(jnp.bfloat16),
+               c.astype(jnp.bfloat16), d, chunk=8)
     assert got.dtype == jnp.bfloat16
     want = ssd_reference(x, dt, a, b, c, d)
     err = jnp.abs(got.astype(jnp.float32) - want)
     assert float(jnp.sqrt(jnp.mean(err ** 2))) < 0.02 * float(
         jnp.sqrt(jnp.mean(want ** 2)))
+
+
+def _rms_apart(got, want):
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    return float(np.sqrt(np.mean((got - want) ** 2))
+                 / np.sqrt(np.mean(want ** 2)))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
+                                       (jnp.bfloat16, 1e-2)],
+                         ids=["float32", "bfloat16"])
+def test_published_sizes_take_the_kernels(dtype, tol, monkeypatch):
+    """granite-4.0-h-micro's mixer (heads of 64, state 128, one group,
+    chunks of 256) at 4 heads and 600 positions, which the chunk does not
+    divide: ``ssd_chunked`` takes the Pallas kernels (interpreted here),
+    and values and the six gradients are the recurrence's and the XLA
+    form's — in bfloat16 within its rounding, the gradients to the decays
+    (``dt``, ``a``) too, which are differences of sums over a chunk."""
+    from ray_tpu.ops import ssm
+
+    calls = []
+    monkeypatch.setattr(ssm, "ssd_kernels", lambda *t, **kw: (
+        calls.append(t[0].shape), ssd_kernels(*t, **kw))[1])
+    args = _scan_inputs(600, seed=4, batch=1, heads=4, p=64, groups=1, n=128)
+    cast = tuple(t.astype(dtype) if i in (0, 3, 4) else t
+                 for i, t in enumerate(args))
+    weight = jnp.asarray(np.random.default_rng(2).normal(
+        size=args[0].shape), jnp.float32)
+    with HIGHEST:
+        want = ssd_reference(*args)
+        want_grads = _grads(ssd_reference, args, weight)
+        out = [(jax.jit(f)(*cast), _grads(
+            lambda *t: f(*t).astype(jnp.float32), cast, weight))
+            for f in (lambda *t: ssd_chunked(*t, chunk=256),
+                      lambda *t: ssd_xla(*t, chunk=256))]
+    assert calls and all(shape == (1, 600, 4, 64) for shape in calls)
+    for got, grads in out:
+        assert got.shape == want.shape and got.dtype == dtype
+        assert _rms_apart(got, want) < tol
+        for name, g, w in zip("x dt a b c d".split(), grads, want_grads):
+            assert _rms_apart(g, w) < tol, name
+
+
+@pytest.mark.parametrize("heads,head_dim,groups,state,chunk,fits", [
+    (64, 64, 1, 128, 256, True),      # granite-4.0-h-micro
+    (48, 128, 1, 128, 256, True),     # a head fills the lanes
+    (8, 32, 1, 256, 128, True),       # four heads a block
+    (64, 64, 8, 128, 256, False),     # B and C differ between head blocks
+    (63, 64, 1, 128, 256, False),     # half a block left over
+    (64, 48, 1, 128, 256, False),     # heads straddle the lanes
+    (64, 8, 1, 128, 256, False),      # sixteen heads a block
+    (64, 64, 1, 64, 256, False),      # the state half fills a tile
+    (64, 64, 1, 128, 192, False),     # a sequence of 192, or such a chunk
+    (8, 16, 2, 8, 8, False),          # the tests' models
+], ids=lambda v: str(v))
+def test_the_shape_rule_that_picks_the_kernels(heads, head_dim, groups,
+                                               state, chunk, fits):
+    assert kernels_fit(heads, head_dim, groups, state, chunk) is fits
 
 
 def test_causal_conv1d_is_the_direct_sum_and_causal():

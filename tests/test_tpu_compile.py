@@ -230,29 +230,71 @@ def test_expert_parallel_train_step_compiles(mesh4, as_on_chip):
     assert "moe_gmm" in text and "moe_tgmm" in text
 
 
-@pytest.mark.parametrize("layer_types,kernel", [
-    (("mamba",), False), (("attention",), True)],
-    ids=["mamba", "attention-nope-head64"])
+def _granite_cfg(layer_types, vocab_size=4096):
+    """granite-4.0-h-micro at its published widths, the vocabulary cut
+    (so the head is not what is compiled), a layer of each kind named."""
+    return LlamaConfig(
+        vocab_size=vocab_size, embed_dim=2048, num_layers=len(layer_types),
+        num_heads=32, num_kv_heads=8, head_dim=64, mlp_dim=8192,
+        norm_eps=1e-5, layer_types=layer_types, ssm_heads=64,
+        ssm_head_dim=64, ssm_state=128, ssm_groups=1, ssm_conv=4,
+        ssm_chunk=256, position_embedding="nope",
+        attention_multiplier=1 / 64, embedding_multiplier=12,
+        residual_multiplier=0.22, logits_scaling=8, tie_embeddings=True,
+        param_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("layer_types", [("mamba",), ("attention",)],
+                         ids=["mamba", "attention-nope-head64"])
 def test_granite_hybrid_layer_train_step_compiles(one_chip, as_on_chip,
-                                                  layer_types, kernel):
+                                                  layer_types):
     """One layer of each kind of granite-4.0-h-micro at its published
-    widths and 8192 positions, as a train step (the vocabulary cut, so the
-    head is not what is compiled): the Mamba-2 layer's chunked scan is
-    plain XLA and fits; the attention layer's flash kernels take head size
+    widths and 8192 positions, as a train step: the Mamba-2 layer's scan
+    is the ``ssd_fwd`` / ``ssd_bwd`` kernels (heads of 64 in pairs over
+    the lanes, 8 pairs a grid step cut out of the block by a dynamic lane
+    offset, the states of all heads in 2 MB of VMEM: what Mosaic could
+    refuse) and fits; the attention layer's flash kernels take head size
     64 (half of Mosaic's minor dimension), 32 query heads on 8 KV heads,
     a softmax scale that is a given number, and no RoPE."""
-    cfg = LlamaConfig(
-        vocab_size=4096, embed_dim=2048, num_layers=1, num_heads=32,
-        num_kv_heads=8, head_dim=64, mlp_dim=8192, norm_eps=1e-5,
-        layer_types=layer_types, ssm_heads=64, ssm_head_dim=64,
-        ssm_state=128, ssm_groups=1, ssm_conv=4, ssm_chunk=256,
-        position_embedding="nope", attention_multiplier=1 / 64,
-        embedding_multiplier=12, residual_multiplier=0.22, logits_scaling=8,
-        tie_embeddings=True, param_dtype=jnp.bfloat16)
-    opt = default_optimizer()
+    cfg, opt = _granite_cfg(layer_types), default_optimizer()
     compiled = make_train_step(cfg, opt).lower(
         _state_shapes(cfg, opt, one_chip),
         {"tokens": _shape((1, 8193), jnp.int32, one_chip)}).compile()
-    assert _has_kernel(compiled) is kernel
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    assert ("ssd_fwd" in text and "ssd_bwd" in text) is (
+        layer_types == ("mamba",))
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8e9
+
+
+def test_granite_hybrid_programs_lower_the_scan_kernels_once_a_use(
+        one_chip, as_on_chip):
+    """The structural guard of the cell's set-up budget (what is lowered
+    is paid by every process, warm cache or not): granite's step program
+    over BOTH Mamba runs and the attention layer, and the benchmark's
+    check program (``loss_fn`` AND ``forward``: two forward passes),
+    lowered for the described chip.  A kernel's body is traced once a
+    process (``_fwd_call`` and ``_bwd_call`` are module-level ``jit``s);
+    the step program holds the forward kernel four times (a Mamba run's
+    forward pass, and its rematerialised forward, which also returns the
+    states — two runs) and the backward kernel ONCE for both runs; the
+    check program the forward kernel once for its four calls."""
+    from benchmark.loops import train
+
+    cfg = _granite_cfg(("mamba", "attention", "mamba"))
+    opt = default_optimizer()
+    state = _state_shapes(cfg, opt, one_chip)
+    tokens = _shape((1, 8193), jnp.int32, one_chip)
+    step = make_train_step(cfg, opt).lower(state, {"tokens": tokens}
+                                           ).as_text()
+    check = jax.jit(train.program_check(cfg, None)).lower(
+        state.params, tokens).as_text()
+
+    def kernels(text, name):
+        return text.count(f'kernel_name = "{name}"')
+
+    assert (kernels(step, "ssd_fwd"), kernels(step, "ssd_bwd")) == (4, 1)
+    assert (kernels(check, "ssd_fwd"), kernels(check, "ssd_bwd")) == (1, 0)
+    assert check.count("call @_fwd_call") == 4
+    assert (kernels(step, "flash_fwd"), kernels(check, "flash_fwd")) == (1, 2)

@@ -402,3 +402,48 @@ def test_step_breakdown_without_a_rematerialised_kernel(tmp_path):
     b = step_breakdown(str(path), "jit_step")
     assert b["kernels"] == {} and b["kernel_pairs"] == {}
     assert "kernel" not in format_breakdown(b)
+
+
+def test_step_breakdown_counts_the_state_space_kernels(tmp_path):
+    """A Mamba-2 layer's scan (``ops/ssm.py``): kernel rows ``ssd_fwd``
+    (forward pass, and rematerialised: its states are not kept) and
+    ``ssd_bwd`` with their calls a step — layers x passes — beside the
+    flash rows; their time is the ``ssm_scan`` scope's with the XLA ops
+    around them."""
+    from ray_tpu.util.tracing import format_breakdown, step_breakdown
+
+    fwd, bwd = _FWD, _BWD
+    call = ', custom_call_target=\\"tpu_custom_call\\"'
+    scan = "ssm_scan/jit(_fwd_call)/ssd_fwd/pallas_call"
+    ops = [("%lead = f32[] add()", "jit(step)/optimizer/add", 500, 100)]
+    at = 2000
+    for layer in range(3):
+        ops += [
+            (f"%cumsum.{layer} = f32[] fusion()",
+             fwd + "ssm_scan/cumsum", at, 5),
+            (f"%ssd_fwd.{layer} = bf16[] custom-call()" + call,
+             fwd + scan, at + 5, 40),
+            (f"%ssd_fwd.{layer + 3} = bf16[] custom-call()" + call,
+             bwd + "rematted_computation/" + scan, at + 45, 41),
+            (f"%ssd_bwd.{layer} = bf16[] custom-call()" + call,
+             bwd + "ssm_scan/jit(_bwd_call)/ssd_bwd/pallas_call",
+             at + 86, 100),
+        ]
+        at += 200
+    ops.append(("%flash_fwd.1 = f32[] custom-call()" + _KERNEL,
+                fwd + "attention/flash_fwd/pallas_call", at, 50))
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace(ops, modules=[(0, 1000), (2000, 1000)]))
+    b = step_breakdown(str(path), "jit_step")
+    ns = lambda s: round(s * 1e9)  # noqa: E731
+    assert {k: ns(t) for k, t in b["kernels"].items()} == {
+        "ssd_fwd": 120, "ssd_fwd.remat": 123, "ssd_bwd": 300,
+        "flash_fwd": 50}
+    assert b["kernel_calls"] == {"ssd_fwd": 3, "ssd_fwd.remat": 3,
+                                 "ssd_bwd": 3, "flash_fwd": 1}
+    assert set(b["kernel_pairs"]) == {"flash_fwd"}
+    assert {p: ns(t) for p, t in b["scopes"]["ssm_scan"].items()} == {
+        "forward": 135, "remat": 123, "backward": 300}
+    text = format_breakdown(b)
+    assert "ssd_bwd  x 3 a step" in text
+    assert "flash_fwd  executed/causal 1.0622  x 1 a step" in text
