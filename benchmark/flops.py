@@ -27,6 +27,12 @@ def of(conf: Dict):
     return importlib.import_module("benchmark." + conf["flops"])
 
 
+def counts_experts(conf: Dict) -> bool:
+    """Whether that module counts an expert layer (``experts_step_flops``):
+    a cell of such a configuration runs the ``moe_*`` scopes."""
+    return hasattr(of(conf), "experts_step_flops")
+
+
 def head_dim(conf: Dict) -> int:
     return conf.get("head_dim") or (
         conf["hidden_size"] // conf["num_attention_heads"])

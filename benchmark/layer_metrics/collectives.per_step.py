@@ -1,5 +1,5 @@
 """Collective operations executed per step on one chip (an async pair
-counts once), from the trace."""
+counts once), from the trace.  No list of cells: 0 on one chip."""
 
 
 def read(run):

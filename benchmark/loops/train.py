@@ -54,24 +54,47 @@ def require_chips(devs, chips: int, peaks: Dict) -> None:
         raise KeyError(f"device kind {kind!r} is not in benchmark/peaks.json")
 
 
-def program_config(conf: Dict):
-    """The program's ``LlamaConfig`` for a configuration file, through
-    the file's own ``llama_config`` map (field -> published key, or
-    ``a/b`` of two published keys)."""
+def program_fields(conf: Dict) -> Dict[str, Any]:
+    """The fields of the program's ``LlamaConfig`` for a configuration
+    file, through the file's own ``llama_config`` map: field -> a key of
+    the file, ``a/b`` of two keys, or ``<key>@published``, the PUBLISHED
+    value of a key the file lists under ``reduced`` (one chip's share maps
+    the router's width to ``n_routed_experts@published`` and the experts
+    held to ``n_routed_experts``).  A key the file does not have fails by
+    its name."""
     import jax.numpy as jnp
 
-    from ray_tpu.models.llama import LlamaConfig
+    def one(name: str):
+        key, at, which = name.partition("@")
+        if at and which != "published":
+            raise KeyError(name)
+        cut = conf.get("reduced", {}).get(key) if at else None
+        return cut["published"] if cut else conf[key]
 
     def value(expr: str):
-        if "/" in expr:
-            a, b = expr.split("/")
-            return conf[a] // conf[b]
-        return conf[expr]
+        a, slash, b = expr.partition("/")
+        return one(a) // one(b) if slash else one(a)
 
     fields = {k: value(v) for k, v in conf["llama_config"].items()}
     for k in ("dtype", "param_dtype"):
         fields[k] = jnp.dtype(conf["assumed"][k]["value"])
-    return LlamaConfig(**fields)
+    return fields
+
+
+def program_config(conf: Dict):
+    """The program's ``LlamaConfig`` for a configuration file."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig(**program_fields(conf))
+
+
+def draw_tokens(rng, cfg, rows: int, seq: int):
+    """``rows`` sequences of ``seq`` + 1 ids below ``cfg.vocab_size``: of a
+    file cut to a vocabulary slice, the slice, which the traffic and the
+    check's sample never leave."""
+    import numpy as np
+
+    return rng.integers(0, cfg.vocab_size, (rows, seq + 1), dtype=np.int32)
 
 
 def reference_module(conf: Dict):
@@ -142,9 +165,8 @@ def reference_check(reference, conf: Dict, job: Dict, cfg, params, seed: int,
 
     check_params = jax.tree_util.tree_map_with_path(drawn, params)
     sample = jax.device_put(
-        np.random.default_rng([seed, 1]).integers(
-            0, cfg.vocab_size, (job["check_rows"], job["seq"] + 1),
-            dtype=np.int32),
+        draw_tokens(np.random.default_rng([seed, 1]), cfg, job["check_rows"],
+                    job["seq"]),
         batch_sharding)
     program_loss, program_parts, program_nll = jax.jit(
         program_check(cfg, mesh))(check_params, sample)
@@ -218,8 +240,7 @@ def measure(config: Dict[str, Any], devs, marks: Dict[str, float]
 
     def new_batch():
         with TraceAnnotation("make_batch"):
-            tokens = rng.integers(0, cfg.vocab_size, (rows, seq + 1),
-                                  dtype=np.int32)
+            tokens = draw_tokens(rng, cfg, rows, seq)
         with TraceAnnotation("device_put"):
             return {"tokens": jax.device_put(tokens, batch_sharding)}
 

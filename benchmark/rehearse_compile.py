@@ -4,9 +4,10 @@ without a chip (on-chip-measurement guide, section 2, rehearsal 3).
 
     JAX_PLATFORMS=cpu python benchmark/rehearse_compile.py [<cell> ...]
 
-Prints what the chip's compiler counts per device for the step program,
-the program's side of the check and one layer of the plain reference the
-configuration names.  A compile
+Prints what ``cuts.py`` holds against the configuration's file (how it may
+differ from its public ``config.json``), then what the chip's compiler counts
+per device for the step program, the program's side of the check and one
+layer of the plain reference the configuration names.  A compile
 is not a run: nothing here is a time or a result.  It is how the cells
 were cut to size (benchmark/README.md) and what to run before a chip call
 after changing a size.
@@ -38,6 +39,7 @@ def rehearse(cell, topo):
     from jax.sharding import NamedSharding, PartitionSpec as P
     from jax.sharding import SingleDeviceSharding
 
+    from benchmark import cuts
     from benchmark.loops import train
     from ray_tpu.ops import attention
     from ray_tpu.parallel.mesh import MeshConfig, make_mesh
@@ -54,6 +56,9 @@ def rehearse(cell, topo):
             return json.load(f)
 
     conf, job = load("configs", cell["config"]), load("jobs", cell["traffic"])
+    for fault in cuts.complaints(
+            conf, load(os.path.join("testdata", "published"), cell["config"])):
+        print(f"  {cell['config']}: THE CUT: {fault}")
     reference = train.reference_module(conf)
     cfg, opt = train.program_config(conf), default_optimizer()
     shapes = jax.eval_shape(lambda k: init_train_state(k, cfg, opt),

@@ -13,7 +13,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 
-from benchmark import flops, trace_reduce, trace_scopes  # noqa: E402
+from benchmark import cuts, flops, trace_reduce, trace_scopes  # noqa: E402
 
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
@@ -49,6 +49,12 @@ TINY_MOE = dict(
     _conf("olmoe-1b-7b-0125-1chip"), hidden_size=64, num_attention_heads=4,
     num_key_value_heads=4, intermediate_size=32, vocab_size=256,
     num_experts=8, num_experts_per_tok=3, num_hidden_layers=2)
+# TINY as one chip's slice of a vocabulary of 2048 rows, eight chips a layer.
+SLICED = dict(
+    TINY, deployment="made up: eight chips share each layer",
+    share={"chips_per_layer": 8, "how": "rows of the embedding and the head"},
+    reduced={"vocab_size": {"kind": "vocabulary", "published": 2048,
+                            "run": 256, "why": "made up"}})
 
 
 # ------------------------------------------------------------- flops.py --
@@ -122,47 +128,284 @@ def test_one_attention_layer_in_ten_is_counted_once():
 
 
 CONFIGS = sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, "configs")))
-# keys of a configuration file that are the benchmark's own
-# (``architectures`` is the public file's; the catalog's rows leave it out)
-OWN_KEYS = {"source", "paper", "reduced", "assumed", "deployment",
-            "llama_config", "reference", "flops", "scopes", "kernels",
-            "check", "architectures"}
-# numbers of a public file that no model code reads: a file may leave them out
-NOT_READ = {"bos_token_id", "eos_token_id", "pad_token_id",
-            "initializer_range", "pretraining_tp"}
+
+
+def _published(name):
+    with open(os.path.join(BENCH, "testdata", "published",
+                           name + ".json")) as f:
+        return json.load(f)
 
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_published_widths_and_reduced_keys(name):
     """Every file under ``configs/`` against the copy of its public
-    ``config.json`` under ``testdata/published/``: equal key for key,
-    except the keys it lists under ``reduced``; no number left out; nothing
-    added that is not the benchmark's own or explained under ``assumed``."""
+    ``config.json`` under ``testdata/published/``, by the rule of
+    ``benchmark/cuts.py``: equal key for key, except the keys it lists
+    under ``reduced``, each of a kind the rule knows; no number left out;
+    nothing added that is not the benchmark's own or explained under
+    ``assumed``; the modules it names are there."""
     conf = _conf(name)
-    with open(os.path.join(BENCH, "testdata", "published",
-                           name + ".json")) as f:
-        published = json.load(f)
-    differ = [k for k, v in published.items() if k in conf and conf[k] != v]
-    assert differ == list(conf["reduced"]), differ
-    for key, cut in conf["reduced"].items():
-        assert (cut["published"], cut["run"]) == (published[key], conf[key])
-    left_out = {k for k, v in published.items() if k not in conf
-                and isinstance(v, (int, float)) and not isinstance(v, bool)}
-    assert left_out <= NOT_READ, left_out
-    added = set(conf) - set(published) - OWN_KEYS
-    assert added <= set(conf["assumed"]), added
-    for key in added:
-        assert conf[key] == conf["assumed"][key]["value"]
-    assert conf["reduced"].keys() <= {"num_hidden_layers"}  # depth alone
-    # the modules it names are there
-    for kind, module in (("reference", conf["reference"]), ("", conf["flops"])):
-        assert os.path.isfile(os.path.join(BENCH, kind, module + ".py"))
+    assert cuts.complaints(conf, _published(name)) == []
+    # PR 33 widened the rule and left the files as they were: an entry of
+    # ``reduced`` that names no kind is the depth
+    assert all("kind" not in cut for cut in conf["reduced"].values())
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         entries = {c["name"]: c for c in json.load(f)["configs"]}
     if name in entries:
         assert entries[name]["reduced"] == list(conf["reduced"])
         assert entries[name]["source"] == conf["source"]
         assert entries[name]["file"] == f"benchmark/configs/{name}.json"
+
+
+# A made-up public ``config.json`` at sizes a test can hold: 64 routed
+# experts, 2 leading dense layers, a ``layer_types`` of period 4.
+PUBLIC = {
+    "first_k_dense_replace": 2, "hidden_size": 64, "intermediate_size": 128,
+    "layer_types": (["sliding_attention"] * 3 + ["full_attention"]) * 4,
+    "moe_intermediate_size": 32, "n_routed_experts": 64,
+    "num_attention_heads": 4, "num_experts_per_tok": 4,
+    "num_hidden_layers": 16, "num_key_value_heads": 2, "rms_norm_eps": 1e-6,
+    "rope_theta": 10000.0, "vocab_size": 4096,
+}
+PERIOD_8 = (["sliding_attention"] * 7 + ["full_attention"]) * 2
+
+
+def _cut(public, reduced, **own):
+    """A configuration file cut from ``public``: ``reduced`` is key ->
+    (kind, run), the kind None for an entry that names none; ``own`` sets
+    further keys of the file (None takes one out)."""
+    conf = dict(
+        public, source="made up", reference="decoder", flops="flops",
+        assumed=TINY["assumed"], check=TINY["check"],
+        deployment="made up: eight chips share each layer",
+        share={"chips_per_layer": 8, "how": "experts and vocabulary rows",
+               "leading_dense": "first_k_dense_replace"},
+        llama_config={"vocab_size": "vocab_size",
+                      "num_layers": "num_hidden_layers",
+                      "head_dim": "hidden_size/num_attention_heads",
+                      "num_experts": "n_routed_experts@published",
+                      "experts_held": "n_routed_experts"},
+        reduced={})
+    for key, (kind, run) in reduced.items():
+        conf[key] = run
+        conf["reduced"][key] = {"published": public[key], "run": run,
+                                "why": "made up"}
+        if kind:
+            conf["reduced"][key]["kind"] = kind
+    for key, value in own.items():
+        conf[key] = value
+        if value is None:
+            del conf[key]
+    return conf
+
+
+DEPTH_6 = {"num_hidden_layers": ("depth", 6),
+           "layer_types": ("pattern", PUBLIC["layer_types"][:6])}
+SHARE = dict(DEPTH_6, n_routed_experts=("experts_held", 8),
+             vocab_size=("vocabulary", 512))
+
+
+NO_LIST = {k: v for k, v in PUBLIC.items() if k != "layer_types"}
+# the same leading dense layers as other public files say them
+OTHER_NAME = dict({k: v for k, v in NO_LIST.items()
+                   if k != "first_k_dense_replace"}, moe_layer_start_index=2)
+MLP_TYPES = dict(OTHER_NAME, mlp_layer_types=["dense"] * 2 + ["sparse"] * 14)
+EXPERTS_5 = dict(n_routed_experts=("experts_held", 8),
+                 num_hidden_layers=("depth", 5))
+KEEPS_4 = "; a share keeps at least 4"
+
+
+def _shared(**more):
+    return {"share": dict({"chips_per_layer": 8, "how": "experts"}, **more)}
+
+
+@pytest.mark.parametrize("public,reduced,own,complaint", [
+    (PUBLIC, {"num_hidden_layers": (None, 4)}, {"share": None}, None),
+    (PUBLIC, SHARE, {}, None),
+    (dict(PUBLIC, n_routed_experts=56),
+     dict(SHARE, n_routed_experts=("experts_held", 7)), {},
+     "reduced[n_routed_experts]: 7 experts held"),
+    (PUBLIC, dict(DEPTH_6, vocab_size=("vocabulary", 256)),
+     {"share": {"chips_per_layer": 16, "how": "vocabulary rows"}},
+     "reduced[vocab_size]: 256 rows are under an eighth"),
+    (NO_LIST, EXPERTS_5, {},
+     "reduced[num_hidden_layers]: 3 layers after the 2 leading dense ones"
+     + KEEPS_4),
+    (OTHER_NAME, EXPERTS_5, _shared(leading_dense="moe_layer_start_index"),
+     "reduced[num_hidden_layers]: 3 layers after the 2 leading dense ones"
+     + KEEPS_4),
+    (MLP_TYPES, dict(EXPERTS_5, mlp_layer_types=(
+        "pattern", MLP_TYPES["mlp_layer_types"][:5])),
+     _shared(leading_dense="mlp_layer_types"),
+     "reduced[num_hidden_layers]: 3 layers after the 2 leading dense ones"
+     + KEEPS_4),
+    (OTHER_NAME, EXPERTS_5, _shared(),
+     "share: a file with experts held states leading_dense"),
+    (OTHER_NAME, EXPERTS_5, _shared(leading_dense="first_k_dense_replace"),
+     "share: leading_dense names 'first_k_dense_replace', which is no"),
+    (OTHER_NAME, EXPERTS_5, _shared(leading_dense=None), None),
+    (dict(PUBLIC, layer_types=PERIOD_8),
+     dict(SHARE, layer_types=("pattern", PERIOD_8[:6])), {},
+     "reduced[num_hidden_layers]: 4 layers after the 2 leading dense ones "
+     "are not a whole period of layer_types (8)"),
+    (PUBLIC, dict(SHARE, vocab_size=("vocabulary", 1024)),
+     {"share": {"chips_per_layer": 4, "how": "half the experts",
+                "leading_dense": "first_k_dense_replace"}},
+     "reduced[n_routed_experts]: run 8 x chips_per_layer 4 is not the "
+     "published 64"),
+    (PUBLIC, SHARE, {"hidden_size": 32},
+     "hidden_size: differs from the published file and is not listed"),
+    (PUBLIC, dict(SHARE, hidden_size=("depth", 32)), {},
+     "reduced[hidden_size]: kind depth is for the key that llama_config "
+     "gives the program as num_layers (num_hidden_layers); no other count "
+     "and no width is ever cut"),
+    (PUBLIC, dict(SHARE, num_attention_heads=(None, 2)), {},
+     "reduced[num_attention_heads]: kind depth is for the key that"),
+    (PUBLIC, {"n_routed_experts": (None, 2)},
+     {"share": None, "deployment": None},
+     "reduced[n_routed_experts]: kind depth is for the key that"),
+    (PUBLIC, {"n_routed_experts": ("experts_held", 2)},
+     {"share": None, "deployment": None},
+     "share: a file with experts held or a vocabulary slice states"),
+    (PUBLIC, dict(SHARE, num_attention_heads=("experts_held", 2)), {},
+     "reduced[num_attention_heads]: kind experts_held is for the key that "
+     "counts the routed experts"),
+    (PUBLIC, dict(SHARE, num_experts_per_tok=("experts_held", 2)), {},
+     "reduced[num_experts_per_tok]: kind experts_held is for the key that"),
+    (PUBLIC, dict(SHARE, num_key_value_heads=("vocabulary", 1)), {},
+     "reduced[num_key_value_heads]: kind vocabulary is for vocab_size"),
+    (PUBLIC, SHARE, {"share": None},
+     "share: a file with experts held or a vocabulary slice states"),
+    (PUBLIC, SHARE, {"deployment": None},
+     "deployment: a share states the deployment"),
+    (PUBLIC, dict(SHARE, layer_types=("pattern",
+                                      PUBLIC["layer_types"][1:7])), {},
+     "reduced[layer_types]: run is not the first 6 entries"),
+], ids=["depth-alone", "one-chips-share", "seven-experts-held",
+        "a-sixteenth-of-the-vocabulary", "three-layers-after-the-dense-ones",
+        "dense-layers-under-another-name", "dense-layers-in-a-per-layer-list",
+        "experts-held-and-no-leading-dense", "leading-dense-names-no-key",
+        "no-dense-layers-said-so",
+        "half-a-period", "run-times-chips-is-not-published",
+        "a-width-changed-and-not-listed", "a-width-listed",
+        "heads-as-depth", "experts-as-depth-and-no-share",
+        "experts-held-and-no-share", "heads-as-experts-held",
+        "experts-a-token-as-experts-held", "heads-as-vocabulary",
+        "no-share-key", "no-deployment", "a-pattern-that-is-no-prefix"])
+def test_the_rule_of_a_cut(public, reduced, own, complaint):
+    """``cuts.complaints`` on files cut from a made-up public file: those
+    that keep the rule give none, every other gives the ONE complaint that
+    names its fault."""
+    got = cuts.complaints(_cut(public, reduced, **own), public)
+    if complaint is None:
+        assert got == []
+    else:
+        assert len(got) == 1 and got[0].startswith(complaint), got
+
+
+def test_the_leading_period_of_a_per_layer_list():
+    assert cuts.period(_conf("granite-4.0-h-micro-d10")["layer_types"]) == 10
+    assert cuts.period(PUBLIC["layer_types"][2:]) == 4
+    assert cuts.period(PERIOD_8[2:]) == 8   # the second copy as far as it goes
+    assert cuts.period(["sparse"] * 47) == 1
+    # an irregular tail does not count; a list that never repeats is whole
+    assert cuts.period(["conv", "conv", "full"] * 3 + ["conv", "full"]) == 3
+    assert cuts.period(["dense"] + ["sparse"] * 5) == 6
+
+
+@pytest.mark.parametrize("value,dense", [
+    (2, 2), (0, 0), ([0, 1], 2), ([], 0), ([0, 1, 5], 2), ([3], 0),
+    (["dense"] + ["sparse"] * 5, 1), (["sparse"] * 6, 0),
+    (["dense", "dense", "sparse", "dense", "sparse"], 2),
+    ("two", None), (None, None), (2.0, None)])
+def test_the_leading_dense_layers_as_public_files_say_them(value, dense):
+    assert cuts.leading_dense({"said_here": value}, "said_here") == dense
+    assert cuts.leading_dense({}, "said_here") is None
+    assert cuts.leading_dense({"said_here": value}, ["said_here"]) is None
+
+
+# ------------------------------------ what the program is told: train.py --
+
+def test_llama_config_resolves_published_and_held_counts():
+    """One key gives the router its published width and the expert layer the
+    count this chip holds; ``a/b`` and plain keys as before; a key the file
+    does not have fails by its name.  ``experts_held`` is no field of the
+    program yet: the resolution is read, no ``LlamaConfig`` built."""
+    from benchmark.loops import train
+
+    conf = _cut(PUBLIC, SHARE)
+    fields = train.program_fields(conf)
+    assert (fields["num_experts"], fields["experts_held"]) == (64, 8)
+    assert (fields["vocab_size"], fields["num_layers"], fields["head_dim"]
+            ) == (512, 6, 16)
+    assert str(fields["dtype"]) == str(fields["param_dtype"]) == "float32"
+    both = dict(conf, llama_config={
+        "vocab_whole": "vocab_size@published", "heads": "num_attention_heads"
+        "@published", "rows_a_chip": "vocab_size@published/vocab_size"})
+    assert train.program_fields(both) == dict(
+        vocab_whole=4096, heads=4, rows_a_chip=8, dtype=fields["dtype"],
+        param_dtype=fields["param_dtype"])
+    for expr, named in (("no_such_key", "no_such_key"),
+                        ("no_such_key@published", "no_such_key"),
+                        ("vocab_size@run", "vocab_size@run"),
+                        ("hidden_size/no_such_key", "no_such_key")):
+        with pytest.raises(KeyError, match=f"'{named}'"):
+            train.program_fields(dict(conf, llama_config={"x": expr}))
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_program_config_gives_what_it_gave(name):
+    """The resolution as it was before ``@published`` (a key, or ``a/b``),
+    written out here, builds the same ``LlamaConfig``."""
+    import jax.numpy as jnp
+
+    from benchmark.loops import train
+    from ray_tpu.models.llama import LlamaConfig
+
+    conf = _conf(name)
+    fields = {}
+    for field, expr in conf["llama_config"].items():
+        a, _, b = expr.partition("/")
+        fields[field] = conf[a] // conf[b] if b else conf[a]
+    for k in ("dtype", "param_dtype"):
+        fields[k] = jnp.dtype(conf["assumed"][k]["value"])
+    assert train.program_config(conf) == LlamaConfig(**fields)
+    assert train.program_fields(conf) == fields
+
+
+def test_the_traffic_of_a_sliced_file_never_leaves_the_slice(monkeypatch):
+    """A sliced vocabulary is a smaller vocabulary: the program is built at
+    the slice, and the window's batches and the check's sample (both
+    ``train.draw_tokens``) draw their ids below it."""
+    import jax
+    import numpy as np
+
+    from benchmark.loops import train
+    from benchmark.reference import decoder
+    from ray_tpu.models.llama import init_params
+
+    conf = SLICED
+    assert cuts.complaints(conf, dict(conf, vocab_size=2048)) == []
+    cfg = train.program_config(conf)
+    assert cfg.vocab_size == 256
+    assert train.program_fields(dict(conf, llama_config={
+        "whole": "vocab_size@published"}))["whole"] == 2048
+    batch = train.draw_tokens(np.random.default_rng([2147483653, 0]), cfg,
+                              64, 128)
+    assert batch.shape == (64, 129) and batch.dtype == np.int32
+    assert (batch.min(), batch.max()) == (0, 255)
+    drawn = []
+    draw = train.draw_tokens
+    monkeypatch.setattr(train, "draw_tokens", lambda *a: drawn.append(
+        draw(*a)) or drawn[-1])
+    job = {"rows": 4, "seq": 64, "mesh": None, "check_rows": 4}
+    check = train.reference_check(
+        decoder, conf, job, cfg, init_params(jax.random.PRNGKey(5), cfg),
+        5, None, jax.devices()[0])
+    sample, = drawn
+    assert sample.shape == (4, 65) and 0 <= sample.min() <= sample.max() < 256
+    # the loss is over the slice: ln(256) at seeded weights, not ln(2048)
+    assert check["reference_loss"] == pytest.approx(np.log(256), abs=0.8)
 
 
 def test_every_configuration_in_benchmark_json_has_its_files():

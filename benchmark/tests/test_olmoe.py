@@ -227,16 +227,25 @@ def test_scopes_kernels_and_readers_on_synthetic_planes():
                                       annotations=())
     old = dict(run, worker={"trace": bare, "window": {}})
     untraced = dict(old, worker={"trace": None, "window": {}})
-    for metric in ("moe.time_share_pct", "moe.dispatch_ms",
-                   "moe.experts_roofline", "moe.load_max_over_mean",
+    for metric in ("moe.experts_roofline", "moe.load_max_over_mean",
                    "step.attention_pct", "step.scan_pct", "step.remat_pct",
                    "flash.fwd_ms"):
         assert _reader(metric).read(old) is None, metric
         assert _reader(metric).read(untraced) is None
-    # ``step.ffn_pct`` carries no list of cells: nothing under ``ffn`` in a
-    # traced step (the MoE run above) is the share 0, no step is no share
+    # ``step.ffn_pct``, ``moe.time_share_pct`` and ``moe.dispatch_ms`` carry
+    # no list of cells: nothing under the scope in a traced step (``ffn`` in
+    # the MoE run above, ``moe_*`` in a dense model's step) is the share 0,
+    # no step is no share.  An expert model's step with nothing under
+    # ``moe_*`` (its name stacks lost) is a fault: no number, not a 0
     assert _reader("step.ffn_pct").read(run) == 0.0
     assert _reader("step.ffn_pct").read(old) == 0.0
-    assert _reader("step.ffn_pct").read(untraced) is None
+    dense = dict(old, conf=dict(conf, flops="flops"))
+    for metric in ("moe.time_share_pct", "moe.dispatch_ms"):
+        assert _reader(metric).read(dense) == 0.0, metric
+        assert _reader(metric).read(old) is None, metric
+    for metric in ("step.ffn_pct", "moe.time_share_pct", "moe.dispatch_ms"):
+        assert _reader(metric).read(untraced) is None, metric
+        assert _reader(metric).read(dict(dense, worker=untraced["worker"])
+                                    ) is None, metric
     assert _reader("step.unscoped_pct").read(old) == pytest.approx(
         100 * 810 / 900)
