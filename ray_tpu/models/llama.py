@@ -1,6 +1,10 @@
 """Flagship model family: Llama-style decoder LM, TPU-first.  A layer is a
-MIXER (softmax attention | a Mamba-2 state-space mixer) followed by an FFN
-(dense SwiGLU | dropless experts), and a model is a pattern of such layers.
+MIXER (softmax attention | latent attention | a Mamba-2 state-space mixer)
+followed by an FFN (dense SwiGLU | dropless experts with or without a
+shared expert), each on a RESIDUAL (one stream that every block adds to |
+``hc_mult`` streams mixed round every block by learned doubly stochastic
+maps): mixer x FFN x residual, and a model is a pattern of such layers, with
+or without a predicted-ahead module behind them.
 
 Pure-functional design: params are a pytree of arrays, every tensor
 dimension has a *logical axis name*, and one rules table
@@ -22,10 +26,23 @@ TPU-first choices:
   assignments; expert tensors are sharded over 'ep', each rank computes its
   own experts' rows inside a shard_map and the partial outputs are summed.
 - a model whose layers differ (``layer_types``: granite-4.0-h's Mamba-2
-  layers with an attention layer every tenth) is scanned by maximal RUNS of
-  one kind, each run one ``lax.scan`` over its own stacked parameters
-  (``params["layers"]`` is then a tuple of stacks, one a run); a model of
-  one kind is one run and ``params["layers"]`` the one stack.
+  layers with an attention layer every tenth; ``leading_dense``: dense FFNs
+  in the first layers of an expert model) is scanned by maximal RUNS of
+  one kind (mixer, FFN), each run one ``lax.scan`` over its own stacked
+  parameters, which hold only what that kind has (``params["layers"]`` is
+  then a tuple of stacks, one a run); a model of one kind is one run and
+  ``params["layers"]`` the one stack.
+- latent attention (arXiv:2412.19437 §2.1.1): q and k/v come up from
+  normed low-rank projections, a head's q and k are [no-position part |
+  rotary part, the k's shared by all heads] and wider than its v; the flash
+  kernels take the two head sizes.
+- the n-stream residual (manifold-constrained hyper-connections,
+  arXiv:2512.24880 §4): the scan carries ``(b, s, n * d)``, and every block
+  reads a learned mix of the streams and writes back through two more maps,
+  made per token from the normed streams (``hc_map``, ``hc_mix``).
+- one chip's share of a layer (``experts_held``, ``first_expert``): the
+  router keeps its published width, the expert tensors hold the experts
+  that live here, and what the absent ones would add is left out.
 
 Reference counterpart: none in Ray core (no tensor ops); RLlib's model zoo
 (``rllib/models/catalog.py``) plays the "models shipped with the framework"
@@ -41,6 +58,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -49,9 +67,10 @@ from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
 from ray_tpu.ops.layers import (
-    rms_norm, rope, apply_rope, swiglu, repeat_kv_heads,
+    rms_norm, rope, apply_rope, swiglu, repeat_kv_heads, sinkhorn,
+    yarn_inv_freq, yarn_mscale,
 )
-from ray_tpu.ops.moe import moe_block
+from ray_tpu.ops.moe import moe_block, update_selection_bias
 from ray_tpu.ops.ssm import causal_conv1d, gated_rms_norm, ssd_chunked
 from ray_tpu.parallel.mesh import (
     AXIS_DP, AXIS_EP, AXIS_FSDP, AXIS_SP, AXIS_TP,
@@ -98,10 +117,47 @@ class LlamaConfig:
     residual_multiplier: float = 1.0  # on what each block adds to the stream
     logits_scaling: float = 1.0       # logits are divided by it
     tie_embeddings: bool = False      # the head reads the embedding table
+    # Latent attention: kv_lora_rank > 0 makes "latent" the mixer of a
+    # model without layer_types.  A head's q and k are qk_nope_dim +
+    # qk_rope_dim wide, its v and output v_head_dim.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Any = None          # the public file's YaRN group
+    # Expert layers: mlp_dim is an expert's width.
+    leading_dense: int = 0            # layers 0.. with a dense FFN instead
+    dense_mlp_dim: int = 0            # their width (0: mlp_dim)
+    experts_held: int = 0             # of num_experts, here (0: all)
+    first_expert: int = 0             # the first one held
+    shared_experts: int = 0           # experts every token meets
+    router_scoring: str = "softmax"   # softmax | sigmoid
+    topk_method: str = "greedy"       # noaux_tc: a selection bias
+    router_groups: int = 1            # group-limited routing: 1 = none
+    routed_scaling_factor: float = 1.0
+    bias_update_speed: float = 0.001  # of the selection bias, a step
+    # The residual: hc_mult streams (1: the plain one).
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp_min: float = -30.0
+    hc_clamp_max: float = 30.0
+    num_nextn: int = 0                # predicted-ahead modules (0 | 1)
+    mtp_loss_coef: float = 0.3
 
     def __post_init__(self):
         # a configuration file hands a list: keep the config hashable
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+        if self.router_groups != 1 or self.num_nextn > 1:
+            raise NotImplementedError(
+                "group-limited routing (n_group > 1) and more than one "
+                "predicted-ahead module are not implemented")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_scoring {self.router_scoring!r}")
         unknown = set(self.layer_types) - set(_MIXERS)
         if unknown:
             raise ValueError(
@@ -129,17 +185,57 @@ class LlamaConfig:
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
-    def layer_runs(self) -> Tuple[Tuple[str, int], ...]:
-        """The model as maximal runs of one mixer: ((kind, layers), ...)."""
-        kinds = self.layer_types[:self.num_layers] or (
-            ("attention",) * self.num_layers)
+    def latent_qk_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    @property
+    def dense_width(self) -> int:
+        return self.dense_mlp_dim or self.mlp_dim
+
+    @property
+    def local_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def select_bias(self) -> bool:
+        return self.topk_method == "noaux_tc"
+
+    @property
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, FFN) of every layer: the mixer ``layer_types`` names
+        (latent attention for a model with a ``kv_lora_rank``, else
+        attention), a dense FFN in the ``leading_dense`` first layers and
+        in a model without experts, the expert layer elsewhere."""
+        mixers = self.layer_types[:self.num_layers] or (
+            ("latent" if self.kv_lora_rank else "attention",)
+            * self.num_layers)
+        return tuple(
+            (mixer, "moe" if self.num_experts and i >= self.leading_dense
+             else "dense") for i, mixer in enumerate(mixers))
+
+    @property
+    def kind_runs(self) -> Tuple[Tuple[Tuple[str, str], int], ...]:
+        """The model as maximal runs of one kind of layer:
+        (((mixer, FFN), layers), ...)."""
         runs = []
-        for kind in kinds:
+        for kind in self.layer_kinds:
             if runs and runs[-1][0] == kind:
                 runs[-1][1] += 1
             else:
                 runs.append([kind, 1])
         return tuple((kind, n) for kind, n in runs)
+
+    @property
+    def layer_runs(self) -> Tuple[Tuple[str, int], ...]:
+        """``kind_runs`` by the mixer alone: ((mixer, layers), ...)."""
+        return tuple((mixer, n) for (mixer, _), n in self.kind_runs)
+
+    @property
+    def mtp_runs(self):
+        """The predicted-ahead module's block: one expert layer (a dense
+        one in a model without experts)."""
+        mixer = self.layer_kinds[-1][0]
+        return (((mixer, "moe" if self.num_experts else "dense"), 1),)
 
     @staticmethod
     def llama2_7b(**kw) -> "LlamaConfig":
@@ -176,6 +272,28 @@ def _attention_shapes(cfg: LlamaConfig):
     return shapes
 
 
+def _latent_shapes(cfg: LlamaConfig):
+    """Latent attention: ``wq_a``/``wq_b`` take q down to ``q_lora_rank``
+    and up to heads x [nope | rope]; ``wkv_a`` gives [the latent c_kv | the
+    one rotary k every head shares], ``wkv_b`` takes the normed latent up
+    to heads x [k_nope | v] (the published layouts of ``kv_a_proj_with_mqa``
+    and ``kv_b_proj``)."""
+    d, heads, qk = cfg.embed_dim, cfg.num_heads, cfg.latent_qk_dim
+    return {
+        "attn_norm": ((d,), ("layer", "embed")),
+        "wq_a": ((d, cfg.q_lora_rank), ("layer", "kernel_in", None)),
+        "q_a_norm": ((cfg.q_lora_rank,), ("layer", None)),
+        "wq_b": ((cfg.q_lora_rank, heads * qk), ("layer", None, "heads")),
+        "wkv_a": ((d, cfg.kv_lora_rank + cfg.qk_rope_dim),
+                  ("layer", "kernel_in", None)),
+        "kv_a_norm": ((cfg.kv_lora_rank,), ("layer", None)),
+        "wkv_b": ((cfg.kv_lora_rank,
+                   heads * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                  ("layer", None, "heads")),
+        "wo": ((heads * cfg.v_head_dim, d), ("layer", "heads", "kernel_in")),
+    }
+
+
 def _mamba_shapes(cfg: LlamaConfig):
     """A Mamba-2 mixer: ``ssm_in`` gives [z | x B C | dt] side by side
     (the published layout of ``in_proj``); the convolution runs over
@@ -195,33 +313,67 @@ def _mamba_shapes(cfg: LlamaConfig):
     }
 
 
-def _ffn_shapes(cfg: LlamaConfig):
-    d, m = cfg.embed_dim, cfg.mlp_dim
-    if cfg.num_experts:
-        e = cfg.num_experts
-        return {
-            "mlp_norm": ((d,), ("layer", "embed")),
-            "router": ((d, e), ("layer", "kernel_in", None)),
-            "w_gate": ((e, d, m), ("layer", "expert", "kernel_in", "mlp")),
-            "w_up": ((e, d, m), ("layer", "expert", "kernel_in", "mlp")),
-            "w_down": ((e, m, d), ("layer", "expert", "mlp", "kernel_in")),
-        }
+def _dense_shapes(d: int, m: int, prefix: str = "w_"):
     return {
-        "mlp_norm": ((d,), ("layer", "embed")),
-        "w_gate": ((d, m), ("layer", "kernel_in", "mlp")),
-        "w_up": ((d, m), ("layer", "kernel_in", "mlp")),
-        "w_down": ((m, d), ("layer", "mlp", "kernel_in")),
+        prefix + "gate": ((d, m), ("layer", "kernel_in", "mlp")),
+        prefix + "up": ((d, m), ("layer", "kernel_in", "mlp")),
+        prefix + "down": ((m, d), ("layer", "mlp", "kernel_in")),
     }
 
 
-_MIXER_SHAPES = {"attention": _attention_shapes, "mamba": _mamba_shapes}
+def _ffn_shapes(cfg: LlamaConfig, ffn: str):
+    """A dense SwiGLU, or the expert layer: the router over ALL the
+    experts, the tensors of those held here, the selection bias (float32
+    whatever the parameters': it moves by thousandths) and the shared
+    expert where the model has them."""
+    d, m = cfg.embed_dim, cfg.mlp_dim
+    shapes = {"mlp_norm": ((d,), ("layer", "embed"))}
+    if ffn == "dense":
+        return {**shapes, **_dense_shapes(d, cfg.dense_width)}
+    e, held = cfg.num_experts, cfg.local_experts
+    shapes.update({
+        "router": ((d, e), ("layer", "kernel_in", None)),
+        "w_gate": ((held, d, m), ("layer", "expert", "kernel_in", "mlp")),
+        "w_up": ((held, d, m), ("layer", "expert", "kernel_in", "mlp")),
+        "w_down": ((held, m, d), ("layer", "expert", "mlp", "kernel_in")),
+    })
+    if cfg.select_bias:
+        shapes["router_bias"] = ((e,), ("layer", None))
+    if cfg.shared_experts:
+        shapes.update(_dense_shapes(d, cfg.shared_experts * m, "shared_"))
+    return shapes
 
 
-def _layer_shapes(cfg: LlamaConfig, mixer: str = "attention"
+def _residual_shapes(cfg: LlamaConfig):
+    """The maps of the n-stream residual, a set for each of a layer's two
+    blocks: one projection of the normed streams to [pre (n) | post (n) |
+    res (n x n, row-major)], its bias, and the three scales."""
+    n = cfg.hc_mult
+    if n == 1:
+        return {}
+    maps = 2 * n + n * n
+    shapes = {}
+    for block in ("attn", "ffn"):
+        shapes.update({
+            f"hc_{block}_proj": ((n * cfg.embed_dim, maps),
+                                 ("layer", None, None)),
+            f"hc_{block}_bias": ((maps,), ("layer", None)),
+            f"hc_{block}_scale": ((3,), ("layer", None))})
+    return shapes
+
+
+_MIXER_SHAPES = {"attention": _attention_shapes, "latent": _latent_shapes,
+                 "mamba": _mamba_shapes}
+
+
+def _layer_shapes(cfg: LlamaConfig, kind=("attention", "dense")
                   ) -> Dict[str, Tuple[Tuple[int, ...], Tuple]]:
     """name -> (shape-per-layer, logical axes incl. the stacked 'layer'
-    dim) of a layer with this mixer: the mixer's tensors, then the FFN's."""
-    return {**_MIXER_SHAPES[mixer](cfg), **_ffn_shapes(cfg)}
+    dim) of a layer of this kind (mixer, FFN): the mixer's tensors, the
+    FFN's, the residual's maps."""
+    mixer, ffn = kind
+    return {**_MIXER_SHAPES[mixer](cfg), **_ffn_shapes(cfg, ffn),
+            **_residual_shapes(cfg)}
 
 
 def _per_run(runs: list):
@@ -230,25 +382,41 @@ def _per_run(runs: list):
     return runs[0] if len(runs) == 1 else tuple(runs)
 
 
-def _runs(cfg: LlamaConfig, layers) -> list:
-    """[(mixer, that run's entry of ``layers``), ...]: ``_per_run``'s
-    inverse."""
-    runs = cfg.layer_runs
+def _runs(layers, runs) -> list:
+    """[(kind, that run's entry of ``layers``), ...] for ``runs`` as
+    ``LlamaConfig.kind_runs`` gives them: ``_per_run``'s inverse."""
     if len(runs) == 1:
         layers = (layers,)
     return [(kind, lp) for (kind, _), lp in zip(runs, layers)]
 
 
+def _mtp_shapes(cfg: LlamaConfig):
+    """What a predicted-ahead module holds beside its block (arXiv:
+    2412.19437 §2.2): a norm each for the stream and the next token's
+    embedding, the projection of the two side by side, its own last norm.
+    The embedding table and the head are the model's."""
+    d = cfg.embed_dim
+    return {"h_norm": ((d,), ("embed",)), "e_norm": ((d,), ("embed",)),
+            "proj": ((2 * d, d), (None, "kernel_in")),
+            "final_norm": ((d,), ("embed",))}
+
+
 def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
+    def stacks(runs):
+        return _per_run([
+            {k: ax for k, (_, ax) in _layer_shapes(cfg, kind).items()}
+            for kind, _ in runs])
+
     axes = {
         "embed": ("vocab", "kernel_in"),
-        "layers": _per_run([
-            {k: ax for k, (_, ax) in _layer_shapes(cfg, kind).items()}
-            for kind, _ in cfg.layer_runs]),
+        "layers": stacks(cfg.kind_runs),
         "final_norm": ("embed",),
     }
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("kernel_in", "vocab")
+    if cfg.num_nextn:
+        axes["mtp"] = {**{k: ax for k, (_, ax) in _mtp_shapes(cfg).items()},
+                       "layers": stacks(cfg.mtp_runs)}
     return axes
 
 
@@ -271,20 +439,50 @@ def _ssm_init(name: str, key: jax.Array, shape, cfg: LlamaConfig):
 _SSM_INIT = ("conv_w", "conv_b", "dt_bias", "A_log", "D")
 
 
+def _map_init(name: str, key: jax.Array, shape, cfg: LlamaConfig):
+    """The residual's maps start NEAR the plain residual and not AT it
+    (a comparison with a reference could not see a map that is the
+    identity, nor a uniform one): a block reads about the streams' mean
+    (pre: sigmoid(-ln(n - 1)) = 1 / n each), writes to every stream (post:
+    2 sigmoid(0) = 1), and a stream mostly keeps itself (res: 4 on the
+    diagonal before exp and Sinkhorn, 0.95 after), each bias with normal
+    noise of 0.1 on it; the three scales are 1, so the part that depends
+    on the token is of the order of the bias.  The selection bias is drawn
+    at 0.02 for the same reason (a trained one starts at 0)."""
+    if name == "router_bias":
+        return 0.02 * jax.random.normal(key, shape, jnp.float32)
+    if name.endswith("_scale"):
+        return jnp.ones(shape, jnp.float32)
+    n = cfg.hc_mult
+    static = jnp.concatenate([
+        jnp.full((n,), -jnp.log(n - 1.0)), jnp.zeros((n,)),
+        4.0 * jnp.eye(n).reshape(-1)])
+    return static + 0.1 * jax.random.normal(key, shape, jnp.float32)
+
+
+def _is_map(name: str) -> bool:
+    return name == "router_bias" or (
+        name.startswith("hc_") and not name.endswith("_proj"))
+
+
 def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     """Scaled-normal init (fan-in), params in ``cfg.param_dtype``.  A tied
     table is initialised as the head it also is (fan-in: the step-0 loss
     is then ln(vocab) to a hundredth)."""
-    run_shapes = [(n, _layer_shapes(cfg, kind)) for kind, n in cfg.layer_runs]
-    n_tensors = sum(len(shapes) for _, shapes in run_shapes) + 3
+    def run_shapes(runs):
+        return [(n, _layer_shapes(cfg, kind)) for kind, n in runs]
+
+    main = run_shapes(cfg.kind_runs)
+    mtp = run_shapes(cfg.mtp_runs) if cfg.num_nextn else []
+    n_tensors = sum(len(shapes) for _, shapes in main + mtp) + 3 + (
+        len(_mtp_shapes(cfg)) if mtp else 0)
     keys = iter(jax.random.split(key, n_tensors))
 
     def norm_init(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(cfg.param_dtype)
 
-    runs = []
-    for n, shapes in run_shapes:
+    def stack(n, shapes):
         layers = {}
         for name, (shape, _) in shapes.items():
             full = (n,) + shape
@@ -293,26 +491,58 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
             elif name in _SSM_INIT:
                 layers[name] = _ssm_init(name, next(keys), full, cfg).astype(
                     cfg.param_dtype)
+            elif _is_map(name):
+                layers[name] = _map_init(name, next(keys), full, cfg).astype(
+                    jnp.float32 if name == "router_bias"
+                    else cfg.param_dtype)
             else:
                 fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
                 layers[name] = norm_init(next(keys), full, fan_in)
-        runs.append(layers)
+        return layers
+
+    layers = _per_run([stack(n, shapes) for n, shapes in main])
     params = {
         "embed": norm_init(next(keys), (cfg.vocab_size, cfg.embed_dim),
                            cfg.embed_dim if cfg.tie_embeddings else 1.0),
-        "layers": _per_run(runs),
+        "layers": layers,
         "final_norm": jnp.ones((cfg.embed_dim,), cfg.param_dtype),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = norm_init(
             next(keys), (cfg.embed_dim, cfg.vocab_size), cfg.embed_dim)
+    if mtp:
+        params["mtp"] = {
+            name: (jnp.ones(shape, cfg.param_dtype) if name.endswith("norm")
+                   else norm_init(next(keys), shape, shape[-2]))
+            for name, (shape, _) in _mtp_shapes(cfg).items()}
+        params["mtp"]["layers"] = _per_run(
+            [stack(n, shapes) for n, shapes in mtp])
     return params
 
 
 def _sm_scale(cfg: LlamaConfig) -> float:
-    if cfg.attention_multiplier is None:
+    if cfg.attention_multiplier is not None:
+        return cfg.attention_multiplier
+    if not cfg.kv_lora_rank:
         return cfg.head_dim ** -0.5
-    return cfg.attention_multiplier
+    # latent attention: over the whole q/k head, times the square of
+    # YaRN's temperature where the model states ``mscale_all_dim``
+    scaling = dict(cfg.rope_scaling or ())
+    return cfg.latent_qk_dim ** -0.5 * yarn_mscale(
+        scaling.get("factor", 1.0), scaling.get("mscale_all_dim", 0.0)) ** 2
+
+
+def _rope_inv_freq(cfg: LlamaConfig, dim: int):
+    """YaRN's frequencies where the model's ``rope_scaling`` is of that
+    type, else None (the plain ones)."""
+    scaling = dict(cfg.rope_scaling or ())
+    if scaling.get("type", scaling.get("rope_type")) != "yarn":
+        return None
+    return yarn_inv_freq(
+        dim, cfg.rope_theta, factor=scaling["factor"],
+        original=scaling["original_max_position_embeddings"],
+        beta_fast=scaling.get("beta_fast", 32.0),
+        beta_slow=scaling.get("beta_slow", 1.0))
 
 
 def _attention(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh]):
@@ -373,21 +603,34 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig, *,
     constraints steer XLA's partitioner.  (The pipeline-parallel path is
     ``parallel.pipeline.forward_pipelined`` — manual SPMD.)
     """
+    h, aux, _ = _hidden(params, tokens, cfg, mesh, rules)
+    return (_lm_head(params, h, cfg, _make_cst(mesh, rules)),
+            _mean_aux(aux, cfg, _expert_layers(cfg.kind_runs)))
+
+
+def _embed(params, tokens, cfg: LlamaConfig, mesh, cst):
+    """The tokens' embeddings (inside the scope ``embed``)."""
+    if mesh is not None:
+        # One-hot matmul instead of gather: with a ('vocab','embed')-
+        # sharded table this lowers to a local matmul + psum over 'tp'
+        # — the gather form makes the SPMD partitioner fully
+        # rematerialize the table.
+        onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.dtype)
+        x = onehot @ params["embed"].astype(cfg.dtype)
+    else:
+        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    return cst(_scaled(x, cfg.embedding_multiplier),
+               ("batch", "seq", "embed"))
+
+
+def _hidden(params, tokens, cfg: LlamaConfig, mesh, rules):
+    """Embedding and layers: ``(h (b, s, d) before the last norm, aux,
+    each run's per-layer expert counts or None)``."""
     cst = _make_cst(mesh, rules)
     with jax.named_scope("embed"):
-        if mesh is not None:
-            # One-hot matmul instead of gather: with a ('vocab','embed')-
-            # sharded table this lowers to a local matmul + psum over 'tp'
-            # — the gather form makes the SPMD partitioner fully
-            # rematerialize the table.
-            onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.dtype)
-            x = onehot @ params["embed"].astype(cfg.dtype)
-        else:
-            x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
-        x = cst(_scaled(x, cfg.embedding_multiplier),
-                ("batch", "seq", "embed"))
-    x, aux = _scan_layers(params["layers"], x, cfg, mesh, rules)
-    return _lm_head(params, x, cfg, cst), _mean_aux(aux, cfg)
+        x = _to_streams(_embed(params, tokens, cfg, mesh, cst), cfg)
+    x, aux, counts = _scan_layers(params["layers"], x, cfg, mesh, rules)
+    return _from_streams(x, cfg), aux, counts
 
 
 def _scaled(x, multiplier: float):
@@ -396,17 +639,23 @@ def _scaled(x, multiplier: float):
 
 
 def _scan_layers(layers, x, cfg: LlamaConfig, mesh, rules,
-                 sp_manual: bool = False):
-    """The layers over ``x``: one ``lax.scan`` a run of one kind of layer,
-    over that run's stacked parameters, each under the layer checkpoint.
-    Returns ``(x, aux)``."""
-    carry = (x, _zero_aux(cfg))
-    for mixer, stacked in _runs(cfg, layers):
-        layer_fn = _make_layer_fn(cfg, mesh, rules, sp_manual, mixer)
+                 sp_manual: bool = False, aux=None, runs=None):
+    """The layers over ``x`` (the streams side by side where the model has
+    several): one ``lax.scan`` a run of one kind of layer (``runs``:
+    ``cfg.kind_runs``), over that run's stacked parameters, each under the
+    layer checkpoint.  Returns ``(x, aux, counts)``: ``counts`` holds, a
+    run, what its layers hand out of the scan — the experts' assignments
+    ``(layers, E)`` of a run whose router has a selection bias, else None."""
+    carry = (x, _zero_aux(cfg) if aux is None else aux)
+    counts = []
+    for kind, stacked in _runs(layers, cfg.kind_runs if runs is None
+                               else runs):
+        layer_fn = _make_layer_fn(cfg, mesh, rules, sp_manual, kind)
         if cfg.remat:
             layer_fn = _checkpoint(layer_fn)
-        carry, _ = jax.lax.scan(layer_fn, carry, stacked)
-    return carry
+        carry, out = jax.lax.scan(layer_fn, carry, stacked)
+        counts.append(out)
+    return (*carry, counts)
 
 
 # What the layer checkpoint keeps of a Mamba layer: the input projection's
@@ -438,19 +687,30 @@ def _zero_aux(cfg: LlamaConfig):
     zero = jnp.zeros((), jnp.float32)
     if not cfg.num_experts:
         return zero
-    return {"aux_loss": zero, "z_loss": zero, "load_max_over_mean": zero,
-            "dropped": zero}
+    aux = {"aux_loss": zero, "z_loss": zero, "load_max_over_mean": zero,
+           "dropped": zero}
+    if cfg.experts_held:  # one chip's share: how much of the rows is here
+        aux["held_share"] = zero
+    return aux
 
 
-def _mean_aux(aux, cfg: LlamaConfig):
+def _expert_layers(runs) -> int:
+    return sum(n for (_, ffn), n in runs if ffn == "moe")
+
+
+def _mean_aux(aux, cfg: LlamaConfig, expert_layers: int):
+    """The sums the scan carried, as means over the ``expert_layers`` that
+    added to them (``dropped`` stays a sum, the load a maximum)."""
     if not cfg.num_experts:
         return aux / cfg.num_layers
-    return dict(aux, aux_loss=aux["aux_loss"] / cfg.num_layers,
-                z_loss=aux["z_loss"] / cfg.num_layers)
+    return {k: v if k in ("load_max_over_mean", "dropped")
+            else v / expert_layers for k, v in aux.items()}
 
 
-def _moe(x, lp, cfg: LlamaConfig, mesh: Optional[Mesh], cst):
-    """The expert layer (``ops.moe.moe_block``) on the residual stream.
+def _moe(x, lp, cfg: LlamaConfig, mesh: Optional[Mesh], cst,
+         residual: bool = True):
+    """The expert layer (``ops.moe.moe_block``) on the residual stream
+    (its experts' sum alone without ``residual``).
     Under a mesh it runs per shard, as the flash kernel does: tokens over
     (dp, fsdp) x sp, experts over ep, their width over tp, partial outputs
     summed over ep x tp.  Inside a region that is already manual (the
@@ -458,9 +718,12 @@ def _moe(x, lp, cfg: LlamaConfig, mesh: Optional[Mesh], cst):
     the TPU lowering refuses for a Mosaic kernel."""
     block = functools.partial(
         moe_block, num_selected=cfg.num_selected, norm_eps=cfg.norm_eps,
-        norm_topk_prob=cfg.norm_topk_prob)
+        norm_topk_prob=cfg.norm_topk_prob, scoring=cfg.router_scoring,
+        gate_scale=cfg.routed_scaling_factor,
+        first_expert=cfg.first_expert, residual=residual)
+    bias = (lp["router_bias"],) if cfg.select_bias else ()
     args = (x, lp["mlp_norm"], lp["router"], lp["w_gate"], lp["w_up"],
-            lp["w_down"])
+            lp["w_down"]) + bias
     if mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
         return block(*args)
     from ray_tpu.parallel.sharding import manual_shard_map
@@ -469,7 +732,7 @@ def _moe(x, lp, cfg: LlamaConfig, mesh: Optional[Mesh], cst):
     # moves the change of layout up to the scan's slice): the fsdp gathers
     # and their gradients' scatters then carry a step scope like every
     # other collective.
-    axes = param_logical_axes(cfg)["layers"]
+    axes = {k: ax for k, (_, ax) in _ffn_shapes(cfg, "moe").items()}
 
     def laid_out(name, *gathered):
         return cst(cst(lp[name], axes[name][1:]), gathered)
@@ -480,7 +743,7 @@ def _moe(x, lp, cfg: LlamaConfig, mesh: Optional[Mesh], cst):
         args = (x,) + small + (
             laid_out("w_gate", "expert", None, "mlp"),
             laid_out("w_up", "expert", None, "mlp"),
-            laid_out("w_down", "expert", "mlp", None))
+            laid_out("w_down", "expert", "mlp", None)) + bias
     x_spec = P((AXIS_DP, AXIS_FSDP), AXIS_SP, None)
     up_spec = P(AXIS_EP, None, AXIS_TP)
     fn = manual_shard_map(
@@ -488,7 +751,7 @@ def _moe(x, lp, cfg: LlamaConfig, mesh: Optional[Mesh], cst):
                           expert_axis=AXIS_EP, sum_axes=(AXIS_EP, AXIS_TP)),
         set(mesh.axis_names),
         in_specs=(x_spec, P(), P(), up_spec, up_spec,
-                  P(AXIS_EP, AXIS_TP, None)),
+                  P(AXIS_EP, AXIS_TP, None)) + (P(),) * len(bias),
         out_specs=(x_spec, P()), mesh=mesh)
     return fn(*args)
 
@@ -515,7 +778,31 @@ def _make_cst(mesh, rules):
                                                  rules=rules)
 
 
-def _attention_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual):
+def _add(x, y, cfg: LlamaConfig, cst, residual: bool):
+    """What a block hands on: the stream plus its output ``y`` (inside
+    the block's last scope), or ``y`` alone where the layer mixes it into
+    several streams itself."""
+    y = _scaled(cst(y, ("batch", "seq", "embed")), cfg.residual_multiplier)
+    return x + y if residual else y
+
+
+def _attend(x, q, k, v, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
+            residual: bool):
+    """What every softmax mixer ends in: the attention itself (scope
+    ``attention``), then the heads' outputs side by side through ``wo``
+    and onto the stream (scope ``attn_out``)."""
+    with jax.named_scope("attention"):
+        if sp_manual:
+            o = _attention_sp_manual(q, k, v, cfg)
+        else:
+            o = _attention(q, k, v, cfg, mesh)
+    with jax.named_scope("attn_out"):
+        o = o.reshape(*x.shape[:2], -1)
+        return _add(x, o @ lp["wo"].astype(cfg.dtype), cfg, cst, residual)
+
+
+def _attention_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
+                     residual: bool = True):
     """Softmax attention on the residual stream (scopes ``attn_qkv``,
     ``attention``, ``attn_out``)."""
     b, s = x.shape[0], x.shape[1]
@@ -538,19 +825,47 @@ def _attention_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual):
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         q = cst(q, ("batch", "seq", "heads", "head_dim"))
         k = cst(k, ("batch", "seq", "kv_heads", "head_dim"))
-    with jax.named_scope("attention"):
-        if sp_manual:
-            o = _attention_sp_manual(q, k, v, cfg)
-        else:
-            o = _attention(q, k, v, cfg, mesh)
-    with jax.named_scope("attn_out"):
-        o = o.reshape(b, s, cfg.qkv_dim)
-        return x + _scaled(cst(o @ lp["wo"].astype(cfg.dtype),
-                               ("batch", "seq", "embed")),
-                           cfg.residual_multiplier)
+    return _attend(x, q, k, v, lp, cfg, mesh, cst, sp_manual, residual)
 
 
-def _mamba_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual):
+def _latent_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
+                  residual: bool = True):
+    """Latent attention on the residual stream (arXiv:2412.19437 §2.1.1)
+    under the scopes of ``_attention_mixer``: ``attn_qkv`` holds both
+    down-projections, their norms, both up-projections and RoPE.  A
+    head's q and k are [no-position part | rotary part] — the k's rotary
+    part is ONE head, shared by all and laid beside each head's own part
+    in the one k the kernel reads — and its v is narrower; the softmax
+    scale is over the whole q/k head."""
+    b, s = x.shape[0], x.shape[1]
+    heads, nope, rot = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    with jax.named_scope("attn_qkv"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = (rms_norm(h @ lp["wq_a"].astype(cfg.dtype), lp["q_a_norm"],
+                      cfg.norm_eps) @ lp["wq_b"].astype(cfg.dtype)).reshape(
+                          b, s, heads, nope + rot)
+        c_kv, k_rot = jnp.split(h @ lp["wkv_a"].astype(cfg.dtype),
+                                [cfg.kv_lora_rank], -1)
+        kv = (rms_norm(c_kv, lp["kv_a_norm"], cfg.norm_eps)
+              @ lp["wkv_b"].astype(cfg.dtype)).reshape(
+                  b, s, heads, nope + cfg.v_head_dim)
+        offset = jax.lax.axis_index(AXIS_SP) * s if sp_manual else 0
+        cos, sin = rope(s, rot, cfg.rope_theta, offset=offset,
+                        inv_freq=_rope_inv_freq(cfg, rot))
+        q = jnp.concatenate(
+            [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], -1)
+        k_rot = apply_rope(k_rot[:, :, None, :], cos, sin)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rot, (b, s, heads, rot))],
+            -1)
+        v = kv[..., nope:]
+        q = cst(q, ("batch", "seq", "heads", "head_dim"))
+        k = cst(k, ("batch", "seq", "heads", "head_dim"))
+    return _attend(x, q, k, v, lp, cfg, mesh, cst, sp_manual, residual)
+
+
+def _mamba_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
+                 residual: bool = True):
     """A Mamba-2 mixer on the residual stream (``ops/ssm.py``): scopes
     ``ssm_in`` (norm, the one input projection, its split), ``ssm_conv``
     (the convolution over x, B, C with its SiLU; dt's softplus),
@@ -580,38 +895,133 @@ def _mamba_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual):
     with jax.named_scope("ssm_out"):
         y = gated_rms_norm(y.reshape(b, s, inner), z, lp["gate_norm"],
                            cfg.norm_eps)
-        return x + _scaled(cst(y @ lp["ssm_out"].astype(cfg.dtype),
-                               ("batch", "seq", "embed")),
-                           cfg.residual_multiplier)
+        return _add(x, y @ lp["ssm_out"].astype(cfg.dtype), cfg, cst,
+                    residual)
 
 
-def _dense_ffn(x, aux, lp, cfg: LlamaConfig, mesh, cst):
+def _swiglu_ffn(h, lp, cfg: LlamaConfig, prefix: str = "w_"):
+    return swiglu(h @ lp[prefix + "gate"].astype(cfg.dtype),
+                  h @ lp[prefix + "up"].astype(cfg.dtype)
+                  ) @ lp[prefix + "down"].astype(cfg.dtype)
+
+
+def _dense_ffn(x, aux, lp, cfg: LlamaConfig, mesh, cst,
+               residual: bool = True):
+    """-> (the stream, aux, nothing handed out of the scan)."""
     with jax.named_scope("ffn"):
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        gate = h @ lp["w_gate"].astype(cfg.dtype)
-        up = h @ lp["w_up"].astype(cfg.dtype)
-        ff = swiglu(gate, up) @ lp["w_down"].astype(cfg.dtype)
-        return x + _scaled(cst(ff, ("batch", "seq", "embed")),
-                           cfg.residual_multiplier), aux
+        return _add(x, _swiglu_ffn(h, lp, cfg), cfg, cst, residual), aux, None
 
 
-def _moe_ffn(x, aux, lp, cfg: LlamaConfig, mesh, cst):
-    # opens its own four scopes in place of ffn
-    x, stats = _moe(x, lp, cfg, mesh, cst)
-    x = cst(x, ("batch", "seq", "embed"))
-    return x, {k: (jnp.maximum if k == "load_max_over_mean"
-                   else jnp.add)(v, stats[k]) for k, v in aux.items()}
+def _moe_ffn(x, aux, lp, cfg: LlamaConfig, mesh, cst, residual: bool = True):
+    """The expert layer (its own four scopes in place of ``ffn``) and,
+    where the model has one, the shared expert, which every token meets
+    (scope ``ffn``).  Hands the experts' assignments out of the scan where
+    a selection bias is moved by them."""
+    out, stats = _moe(x, lp, cfg, mesh, cst, residual)
+    out = cst(out, ("batch", "seq", "embed"))
+    if cfg.shared_experts:
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            out = out + cst(_swiglu_ffn(h, lp, cfg, "shared_"),
+                            ("batch", "seq", "embed"))
+    aux = {k: (jnp.maximum if k == "load_max_over_mean"
+               else jnp.add)(v, stats[k]) for k, v in aux.items()}
+    return out, aux, stats["counts"] if cfg.select_bias else None
 
 
-_MIXERS = {"attention": _attention_mixer, "mamba": _mamba_mixer}
+_MIXERS = {"attention": _attention_mixer, "latent": _latent_mixer,
+           "mamba": _mamba_mixer}
+_FFNS = {"dense": _dense_ffn, "moe": _moe_ffn}
+
+
+# ---- the n-stream residual (arXiv:2512.24880 §4) -------------------------
+# The streams lie side by side, ``(b, s, n * d)``: stream j is the columns
+# j * d .. (j + 1) * d, so ``vec X`` is the array as it lies and every
+# slice starts on a lane tile.  (A (b, s, n, d) array would pad its n = 4
+# rows to a 16-row tile.)  The maps are kept with the TOKENS minor.
+
+def _to_streams(x, cfg: LlamaConfig):
+    """The embedded tokens copied to every stream (arXiv:2409.19606 §3)."""
+    return x if cfg.hc_mult == 1 else jnp.tile(x, (1, 1, cfg.hc_mult))
+
+
+def _stream(xs, j: int, cfg: LlamaConfig):
+    d = xs.shape[-1] // cfg.hc_mult
+    return xs[..., j * d:(j + 1) * d].astype(jnp.float32)
+
+
+def _from_streams(xs, cfg: LlamaConfig):
+    """The streams summed, for the last norm (arXiv:2409.19606 §3)."""
+    if cfg.hc_mult == 1:
+        return xs
+    with jax.named_scope("hc_mix"):
+        return sum(_stream(xs, j, cfg)
+                   for j in range(cfg.hc_mult)).astype(cfg.dtype)
+
+
+def _hc_maps(xs, lp, block: str, cfg: LlamaConfig):
+    """The three maps of one block for every token, float32 with the
+    tokens minor: ``pre (n, b, s, 1)`` (what the block reads of each
+    stream), ``post (n, b, s, 1)`` (what each stream takes of the block's
+    output) and ``res (n, n, b, s, 1)`` (stream i's share of stream j,
+    rows and columns summing to 1).  The streams are normed as ONE vector
+    of n * d (no learned weight), projected by one matrix to [pre | post |
+    res], scaled, biased; pre through a sigmoid, post through twice a
+    sigmoid, res clipped and through ``sinkhorn``."""
+    n, f32 = cfg.hc_mult, jnp.float32
+    b, s, width = xs.shape
+    x32 = xs.astype(f32)
+    normed = (x32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        + cfg.norm_eps)).astype(cfg.dtype)
+    raw = jnp.dot(normed.reshape(b * s, width),
+                  lp[f"hc_{block}_proj"].astype(cfg.dtype),
+                  preferred_element_type=f32).T          # (2n + n*n, T)
+    # one scale each for [pre | post | res], laid over the columns: ONE
+    # multiply-add over the whole array (scaled slice by slice, the step
+    # program grew by 0.43 GB at 8192 tokens: PERF.md §6, PR 34)
+    scale = jnp.repeat(lp[f"hc_{block}_scale"].astype(f32),
+                       np.array([n, n, n * n]))
+    raw = raw * scale[:, None] + lp[f"hc_{block}_bias"].astype(f32)[:, None]
+    pre = jax.nn.sigmoid(raw[:n])
+    post = 2.0 * jax.nn.sigmoid(raw[n:2 * n])
+    res = sinkhorn(jnp.clip(raw[2 * n:].reshape(n, n, b * s),
+                            cfg.hc_clamp_min, cfg.hc_clamp_max),
+                   cfg.hc_sinkhorn_iters, cfg.hc_eps)
+    return (pre.reshape(n, b, s, 1), post.reshape(n, b, s, 1),
+            res.reshape(n, n, b, s, 1))
+
+
+def _hc_block(xs, lp, block: str, cfg: LlamaConfig, fn):
+    """One block ``fn(x) -> (y, rest)`` on the streams ``xs``: ``X' = res
+    X + post^T fn(pre X)``; returns ``(X', rest)``.  Scopes ``hc_map`` (the
+    maps) and ``hc_mix`` (reading the block's input off the streams and
+    writing its output back); the block opens its own between them."""
+    n, f32 = cfg.hc_mult, jnp.float32
+    with jax.named_scope("hc_map"):
+        pre, post, res = _hc_maps(xs, lp, block, cfg)
+    with jax.named_scope("hc_mix"):
+        x = sum(pre[j] * _stream(xs, j, cfg) for j in range(n)).astype(
+            cfg.dtype)
+    y, rest = fn(x)
+    with jax.named_scope("hc_mix"):
+        y32 = y.astype(f32)
+        out = jnp.concatenate([
+            (post[i] * y32 + sum(res[i, j] * _stream(xs, j, cfg)
+                                 for j in range(n))).astype(cfg.dtype)
+            for i in range(n)], axis=-1)
+    return out, rest
 
 
 def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
-                   mixer: str = "attention"):
-    """One layer as a scan body over stacked layer params: a mixer
-    (``_MIXERS``) then an FFN (dense, or the expert layer), each adding to
-    the residual stream.  Shapes are read off the activation so the same
-    body serves the full batch (forward) and microbatches
+                   kind=("attention", "dense")):
+    """One layer of ``kind`` (mixer, FFN) as a scan body over stacked
+    layer params: a mixer (``_MIXERS``) then an FFN (``_FFNS``), each
+    adding to the residual stream — or, in a model of several streams,
+    each reading its input off them and written back into them through
+    the layer's maps (``_hc_block``).  Shapes are read off the activation
+    so the same body serves the full batch (forward) and microbatches
     (forward_pipelined).
 
     ``sp_manual``: the body runs inside a shard_map that is manual over
@@ -620,22 +1030,35 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
     ring/ulysses attention run inline over the bound 'sp' axis.
     """
     cst = _make_cst(mesh, rules)
-    mix = _MIXERS[mixer]
-    ffn = _moe_ffn if cfg.num_experts else _dense_ffn
+    mix, ffn = _MIXERS[kind[0]], _FFNS[kind[1]]
 
     def layer_fn(carry, lp):
         x, aux = carry
         x = mix(x, lp, cfg, mesh, cst, sp_manual)
-        return ffn(x, aux, lp, cfg, mesh, cst), None
+        x, aux, out = ffn(x, aux, lp, cfg, mesh, cst)
+        return (x, aux), out
 
-    return layer_fn
+    def streams_layer_fn(carry, lp):
+        xs, aux = carry
+        xs, _ = _hc_block(xs, lp, "attn", cfg, lambda x: (
+            mix(x, lp, cfg, mesh, cst, sp_manual, residual=False), None))
+
+        def ffn_block(x):
+            y, aux_, out = ffn(x, aux, lp, cfg, mesh, cst, residual=False)
+            return y, (aux_, out)
+
+        xs, (aux, out) = _hc_block(xs, lp, "ffn", cfg, ffn_block)
+        return (xs, aux), out
+
+    return layer_fn if cfg.hc_mult == 1 else streams_layer_fn
 
 
 def _one_kind(cfg: LlamaConfig, what: str) -> None:
-    if len(cfg.layer_runs) > 1:
+    if len(cfg.kind_runs) > 1 or cfg.hc_mult > 1 or cfg.num_nextn:
         raise NotImplementedError(
             f"{what} splits ONE stack of layers into stages; this model's "
-            f"layers differ ({cfg.layer_runs}): train it with "
+            f"layers differ ({cfg.kind_runs}), or it carries several "
+            "streams or a predicted-ahead module: train it with "
             "make_train_step(pipelined=False)")
 
 
@@ -691,6 +1114,54 @@ def forward_pipelined(params: Dict[str, Any], tokens: jax.Array,
     return _lm_head(params, x, cfg, cst), _zero_aux(cfg)
 
 
+def _predicted_ahead(params, h, next_tokens, aux, cfg: LlamaConfig, mesh,
+                     rules):
+    """The predicted-ahead module (arXiv:2412.19437 §2.2) on the model's
+    ``h (b, s, d)`` (the summed streams before the last norm) and the
+    tokens that FOLLOW each position: the two are normed, laid side by
+    side and projected back to d (scope ``mtp_in``), go through one more
+    layer of the module's own and its own last norm, and meet the model's
+    head.  Position t then predicts token t + 2.  Returns ``(logits, aux,
+    counts)`` as ``_hidden`` and the head do."""
+    mp, cst = params["mtp"], _make_cst(mesh, rules)
+    with jax.named_scope("embed"):
+        e = _embed(params, next_tokens, cfg, mesh, cst)
+    with jax.named_scope("mtp_in"):
+        x = jnp.concatenate([rms_norm(h, mp["h_norm"], cfg.norm_eps),
+                             rms_norm(e, mp["e_norm"], cfg.norm_eps)], -1)
+        x = cst(x @ mp["proj"].astype(cfg.dtype), ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        x = _to_streams(x, cfg)
+    x, aux, counts = _scan_layers(mp["layers"], x, cfg, mesh, rules,
+                                  aux=aux, runs=cfg.mtp_runs)
+    logits = _lm_head(dict(params, final_norm=mp["final_norm"]),
+                      _from_streams(x, cfg), cfg, cst)
+    return logits, aux, counts
+
+
+def update_router_bias(old, new, counts, cfg: LlamaConfig):
+    """``new`` parameters with every selection bias moved from its ``old``
+    value by the bias rule (``ops.moe.update_selection_bias``) in place
+    of whatever the optimizer made of it; ``counts`` as
+    ``loss_and_counts`` returns them."""
+    def moved(old_stacks, new_stacks, runs, run_counts):
+        return _per_run([
+            new_lp if c is None else dict(
+                new_lp, router_bias=update_selection_bias(
+                    old_lp["router_bias"], c, cfg.bias_update_speed))
+            for (_, old_lp), (_, new_lp), c in zip(
+                _runs(old_stacks, runs), _runs(new_stacks, runs),
+                run_counts)])
+
+    out = dict(new, layers=moved(old["layers"], new["layers"],
+                                 cfg.kind_runs, counts["layers"]))
+    if cfg.num_nextn:
+        out["mtp"] = dict(new["mtp"], layers=moved(
+            old["mtp"]["layers"], new["mtp"]["layers"], cfg.mtp_runs,
+            counts["mtp"]))
+    return out
+
+
 def pipeline_stage_params(params: Dict[str, Any],
                           num_stages: int) -> list:
     """Stage-sliced construction for the ACTOR pipeline
@@ -737,7 +1208,7 @@ def make_pipeline_stage_fn(cfg: LlamaConfig):
             with jax.named_scope("embed"):
                 x = _scaled(jnp.take(sp["embed"], x, axis=0).astype(
                     cfg.dtype), cfg.embedding_multiplier)
-        x, _ = _scan_layers(sp["layers"], x, cfg, None, None)
+        x = _scan_layers(sp["layers"], x, cfg, None, None)[0]
         if "lm_head" in sp:
             x = _lm_head(sp, x, cfg, _make_cst(None, None))
         return x
@@ -757,40 +1228,85 @@ def make_pipeline_loss_fn(cfg: LlamaConfig):
     return pipeline_loss
 
 
-def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
-            cfg: LlamaConfig, *, mesh: Optional[Mesh] = None,
-            rules: Optional[LogicalAxisRules] = None,
-            forward_fn=None) -> Tuple[jax.Array, Dict[str, Any]]:
-    """Next-token cross-entropy.  batch: {"tokens": (b, s+1) int32} or
-    {"inputs": (b, s), "targets": (b, s)}; returns (loss, metrics).
-
-    ``forward_fn(params, inputs) -> (logits, aux)`` overrides the forward
-    pass (e.g. the pipelined path) so there is exactly one loss definition.
-    """
+def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
+                    cfg: LlamaConfig, *, mesh: Optional[Mesh] = None,
+                    rules: Optional[LogicalAxisRules] = None,
+                    forward_fn=None):
+    """``loss_fn`` and, beside its metrics, what the train step needs and
+    no metric can carry: ``(loss, (metrics, counts))``, ``counts`` the
+    experts' assignments of every layer whose router has a selection bias
+    (``{"layers": a run, "mtp": ...}``; ``update_router_bias`` reads it),
+    None for a model without one."""
     if "tokens" in batch:
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     else:
         inputs, targets = batch["inputs"], batch["targets"]
+    counts = ahead = None
     if forward_fn is None:
-        logits, aux = forward(params, inputs, cfg, mesh=mesh, rules=rules)
+        h, aux, layer_counts = _hidden(params, inputs, cfg, mesh, rules)
+        logits = _lm_head(params, h, cfg, _make_cst(mesh, rules))
+        expert_layers = _expert_layers(cfg.kind_runs)
+        if cfg.num_nextn:
+            ahead, aux, mtp_counts = _predicted_ahead(
+                params, h, targets, aux, cfg, mesh, rules)
+            expert_layers += _expert_layers(cfg.mtp_runs)
+        if cfg.select_bias:
+            counts = {"layers": layer_counts,
+                      "mtp": mtp_counts if cfg.num_nextn else None}
+        aux = _mean_aux(aux, cfg, expert_layers)
+    elif cfg.num_nextn or cfg.select_bias:
+        raise NotImplementedError(
+            "a predicted-ahead module and a selection bias need the layers' "
+            "own outputs, which a replaced forward pass does not hand on")
     else:
         logits, aux = forward_fn(params, inputs)
     with jax.named_scope("loss"):
         loss = _mean_nll(logits, targets)
         if not cfg.num_experts:
             total = loss + cfg.aux_loss_coef * aux
-            return total, {"loss": loss, "aux_loss": aux,
-                           "perplexity": jnp.exp(loss)}
-        total = (loss + cfg.aux_loss_coef * aux["aux_loss"]
-                 + cfg.z_loss_coef * aux["z_loss"])
-        return total, {"loss": loss, "aux_loss": aux["aux_loss"],
+            metrics = {"loss": loss, "aux_loss": aux}
+        else:
+            total = (loss + cfg.aux_loss_coef * aux["aux_loss"]
+                     + cfg.z_loss_coef * aux["z_loss"])
+            metrics = {"loss": loss, "aux_loss": aux["aux_loss"],
                        "z_loss": aux["z_loss"],
                        "moe_load_max_over_mean": aux["load_max_over_mean"],
-                       "moe_dropped": aux["dropped"],
-                       "perplexity": jnp.exp(loss)}
+                       "moe_dropped": aux["dropped"]}
+            if "held_share" in aux:
+                metrics["moe_held_share"] = aux["held_share"]
+        if ahead is not None:
+            # position t's target is token t + 2: the last has none
+            seq = targets.shape[1]
+            mtp_loss = _mean_nll(
+                ahead, jnp.concatenate(
+                    [targets[:, 1:], jnp.zeros_like(targets[:, :1])], axis=1),
+                (jnp.arange(seq) < seq - 1).astype(jnp.float32))
+            total = total + cfg.mtp_loss_coef * mtp_loss
+            metrics["mtp_loss"] = mtp_loss
+        metrics["perplexity"] = jnp.exp(loss)
+        return total, (metrics, counts)
 
 
-def _mean_nll(logits, targets):
+def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
+            cfg: LlamaConfig, *, mesh: Optional[Mesh] = None,
+            rules: Optional[LogicalAxisRules] = None,
+            forward_fn=None) -> Tuple[jax.Array, Dict[str, Any]]:
+    """Next-token cross-entropy (plus, at the model's weights, the expert
+    layers' auxiliary losses and the predicted-ahead module's loss over
+    the positions that have a target).  batch: {"tokens": (b, s+1) int32}
+    or {"inputs": (b, s), "targets": (b, s)}; returns (loss, metrics).
+
+    ``forward_fn(params, inputs) -> (logits, aux)`` overrides the forward
+    pass (e.g. the pipelined path) so there is exactly one loss definition.
+    """
+    total, (metrics, _) = loss_and_counts(
+        params, batch, cfg, mesh=mesh, rules=rules, forward_fn=forward_fn)
+    return total, metrics
+
+
+def _mean_nll(logits, targets, weights=None):
+    """Mean next-token loss; ``weights (seq,)`` of 0 and 1 leaves the
+    positions at 0 out of the mean."""
     if logits.shape[0] == 1:
         # One row: drop the degenerate dimension.  With it XLA's TPU
         # compiler turns the gradient of the gather below into a FLAT
@@ -800,7 +1316,10 @@ def _mean_nll(logits, targets):
         logits, targets = logits[0], targets[0]
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(nll)
+    if weights is None:
+        return jnp.mean(nll)
+    return jnp.sum(nll * weights) / (jnp.sum(weights) * nll.size
+                                     / weights.size)
 
 
 def _ssd_scan(mesh: Optional[Mesh], sp_manual: bool):

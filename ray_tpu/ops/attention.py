@@ -273,14 +273,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = m_scr[...] + jnp.log2(l)   # log2-domain lse
 
 
-def _grid_and_specs(qt, kt, causal, tiles):
+def _grid_and_specs(qt, kt, vt, causal, tiles):
     """``(nq, nk)`` tiles of the call and the BlockSpecs of the q-side and
     kv-side operands for both grid orders: ``q_i, k_j, row_i`` for grids
     ``(b, h, q, kv)`` and ``q_j, k_i, row_j`` for ``(b, h, kv, q)``.
-    Under the mask a dead grid step names the block its nearest live step
-    holds, which Pallas does not copy again."""
+    q and k have one head size, v (and with it o and do: ``o_i``, ``v_j``,
+    ``v_i``, ``o_j``) may have another; where the two are equal the specs
+    are.  Under the mask a dead grid step names the block its nearest live
+    step holds, which Pallas does not copy again."""
     block_q, block_k = tiles[:2]
-    d = qt.shape[3]
     nq, nk = qt.shape[2] // block_q, kt.shape[2] // block_k
     if causal:
         def inner_k(i, j):
@@ -297,36 +298,39 @@ def _grid_and_specs(qt, kt, causal, tiles):
                             lambda b_, h_, i, j: (b_, h_, index(i, j), 0))
 
     outer = lambda i, j: i
+    d, dv = qt.shape[3], vt.shape[3]
     return (nq, nk), {
         "q_i": spec(block_q, d, outer), "row_i": spec(block_q, _LANES, outer),
-        "k_j": spec(block_k, d, inner_k),
-        "k_i": spec(block_k, d, outer),
-        "q_j": spec(block_q, d, inner_q),
+        "o_i": spec(block_q, dv, outer),
+        "k_j": spec(block_k, d, inner_k), "v_j": spec(block_k, dv, inner_k),
+        "k_i": spec(block_k, d, outer), "v_i": spec(block_k, dv, outer),
+        "q_j": spec(block_q, d, inner_q), "o_j": spec(block_q, dv, inner_q),
         "row_j": spec(block_q, _LANES, inner_q),
     }
 
 
 def _fwd_call(qt, kt, vt, causal, tiles, interpret):
-    """qt/kt/vt: (b, h, s, d); qt PRE-SCALED by sm_scale*log2e.  Returns
-    (o_t, lse) with o_t (b, h, sq, d) and lse (b, h, sq, LANES)
-    lane-replicated f32 in the log2 domain."""
-    b, h, sq, d = qt.shape
+    """qt/kt: (b, h, s, d), vt: (b, h, s, dv); qt PRE-SCALED by
+    sm_scale*log2e.  Returns (o_t, lse) with o_t (b, h, sq, dv) and lse
+    (b, h, sq, LANES) lane-replicated f32 in the log2 domain."""
+    b, h, sq, _ = qt.shape
+    dv = vt.shape[3]
     block_q = tiles[0]
-    (nq, nk), specs = _grid_and_specs(qt, kt, causal, tiles)
+    (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, tiles=tiles,
                           grid_qk=(nq, nk)),
         grid=(b, h, nq, nk),
-        in_specs=[specs["q_i"], specs["k_j"], specs["k_j"]],
-        out_specs=[specs["q_i"], specs["row_i"]],
+        in_specs=[specs["q_i"], specs["k_j"], specs["v_j"]],
+        out_specs=[specs["o_i"], specs["row_i"]],
         out_shape=[
-            jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), qt.dtype),
             jax.ShapeDtypeStruct((b, h, sq, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
@@ -408,27 +412,29 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret):
-    """All tensors (b, h, s, d); lse (b, h, sq, LANES).  Returns transposed
-    grads (dqt, dkt, dvt)."""
+    """qt/kt (b, h, s, d), vt/ot/dot (b, h, s, dv); lse (b, h, sq, LANES).
+    Returns transposed grads (dqt, dkt, dvt)."""
     b, h, sq, d = qt.shape
+    dv = vt.shape[3]
     block_q, block_k = tiles[:2]
     delta = jnp.sum(ot.astype(jnp.float32) * dot.astype(jnp.float32),
                     axis=-1, keepdims=True)                  # (b, h, sq, 1)
     delta = jnp.broadcast_to(delta, (b, h, sq, _LANES))
-    (nq, nk), specs = _grid_and_specs(qt, kt, causal, tiles)
+    (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles)
     q_i, k_j, row_i = specs["q_i"], specs["k_j"], specs["row_i"]
     q_j, k_i, row_j = specs["q_j"], specs["k_i"], specs["row_j"]
+    o_i, v_j, o_j, v_i = (specs[n] for n in ("o_i", "v_j", "o_j", "v_i"))
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel, causal=causal, tiles=tiles,
                           grid_qk=(nq, nk)),
         grid=(b, h, nk, nq),
-        in_specs=[q_j, k_i, k_i, q_j, row_j, row_j],
-        out_specs=[k_i, k_i],
+        in_specs=[q_j, k_i, v_i, o_j, row_j, row_j],
+        out_specs=[k_i, v_i],
         out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
                    jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name="flash_dkv",
@@ -438,7 +444,7 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret):
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
                           tiles=tiles, grid_qk=(nq, nk)),
         grid=(b, h, nq, nk),
-        in_specs=[q_i, k_j, k_j, q_i, row_i, row_i],
+        in_specs=[q_i, k_j, v_j, o_i, row_i, row_i],
         out_specs=q_i,
         out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -497,7 +503,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, sm_scale: Optional[float] = None,
                     block_q: int = MAX_BLOCK, block_k: int = MAX_BLOCK,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """Memory-efficient MHA.  q: (b, sq, h, d); k/v: (b, sk, h, d).
+    """Memory-efficient MHA.  q: (b, sq, h, d); k: (b, sk, h, d); v:
+    (b, sk, h, dv), the output (b, sq, h, dv): v's head size may differ
+    from q's and k's (a latent-attention mixer's 192 / 128).
 
     Supports grouped-query attention: if k/v have fewer heads than q and
     ``h % h_kv == 0``, kv heads are repeated (XLA fuses the broadcast).
@@ -510,8 +518,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         interpret = _interpret_default()
     from ray_tpu.ops.layers import repeat_kv_heads
     k, v = repeat_kv_heads(q, k, v)
-    tiles = choose_tiles(q.shape[1], k.shape[1], causal, q.shape[-1],
-                         q.dtype, block_q, block_k)
+    tiles = choose_tiles(q.shape[1], k.shape[1], causal,
+                         max(q.shape[-1], v.shape[-1]), q.dtype, block_q,
+                         block_k)
     if tiles is None:
         # No block >= 8 tiles the sequence exactly: the XLA reference is
         # correct, at O(S^2) memory.

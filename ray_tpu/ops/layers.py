@@ -8,7 +8,8 @@ overlap — see ops/attention.py, ops/ring_attention.py.)
 
 from __future__ import annotations
 
-from typing import Tuple
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -23,12 +24,43 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (x * weight.astype(jnp.float32)).astype(dtype)
 
 
+def yarn_inv_freq(head_dim: int, theta: float, *, factor: float,
+                  original: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> jax.Array:
+    """YaRN's frequencies (arXiv:2309.00071 §3.2, "NTK-by-parts"):
+    a dimension that turns more than ``beta_fast`` times within the
+    ``original`` context keeps its frequency, one that turns fewer than
+    ``beta_slow`` times has it divided by ``factor``, and a linear ramp
+    over the dimensions lies between."""
+    exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    kept = 1.0 / theta ** exponent
+
+    def dimension_of(turns):  # the dimension that turns this often
+        return (head_dim * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dimension_of(beta_fast)), 0)
+    high = min(math.ceil(dimension_of(beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return kept / factor * ramp + kept * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature, ``0.1 mscale ln(factor) + 1``: the
+    softmax scale of a model that states ``mscale_all_dim`` is multiplied
+    by its square."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 def rope(seq_len: int, head_dim: int, theta: float = 10000.0,
-         offset=0) -> Tuple[jax.Array, jax.Array]:
+         offset=0, inv_freq: Optional[jax.Array] = None
+         ) -> Tuple[jax.Array, jax.Array]:
     """Rotary position embedding tables (cos, sin): (seq_len, head_dim/2).
-    ``offset`` may be traced (e.g. an 'sp' rank offset inside shard_map)."""
-    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                             / head_dim))
+    ``offset`` may be traced (e.g. an 'sp' rank offset inside shard_map);
+    ``inv_freq`` replaces the plain frequencies (``yarn_inv_freq``)."""
+    freqs = inv_freq if inv_freq is not None else 1.0 / (
+        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     t = jnp.arange(seq_len, dtype=jnp.float32) + offset
     angles = jnp.outer(t, freqs)
     return jnp.cos(angles), jnp.sin(angles)
@@ -42,6 +74,21 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     sin = sin[None, :, None, :]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def sinkhorn(logits: jax.Array, iters: int, eps: float) -> jax.Array:
+    """``exp(logits)`` made nearly doubly stochastic by ``iters`` rounds of
+    Sinkhorn-Knopp (arXiv:2512.24880 §4.2): each round divides every row
+    by its sum + ``eps``, then every column by its.  ``logits (n, n, ...)``:
+    axis 0 the rows, axis 1 the columns, the rest a batch kept MINOR (a
+    (T, n, n) layout would fill a sixteenth of each vector register)."""
+    def one_round(m, _):
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+        return m / (jnp.sum(m, axis=0, keepdims=True) + eps), None
+
+    # a loop, not ``iters`` copies of the round in the program: a third of
+    # the step's compile time at 20 rounds (PERF.md §6, PR 34)
+    return jax.lax.scan(one_round, jnp.exp(logits), None, length=iters)[0]
 
 
 def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:

@@ -6,8 +6,11 @@ imbalance: nothing has a capacity and nothing is dropped.  The layer
 (``moe_block``) is
 
 - ``moe_route``: RMSNorm, router logits accumulated in float32, float32
-  softmax, ``lax.top_k`` (renormalised only where the model says so), the
-  load-balancing loss over ALL the choices and the router z-loss;
+  scores (a softmax over the experts, or each logit's sigmoid),
+  ``lax.top_k`` of the scores — plus a selection bias where the model has
+  one, which reaches the choice and not the gate — (renormalised only
+  where the model says so, then scaled), the load-balancing loss over ALL
+  the choices and the router z-loss;
 - ``moe_dispatch``: a stable sort of the ``T * k`` (token, choice) pairs
   by expert that carries each pair's gate along, its inverse by a second
   sort, a gather to ``(T * k, d)`` rows;
@@ -32,8 +35,11 @@ the weights transposed for the rows' gradient) and ``moe_tgmm`` (the
 weights' gradient, per group).  Both walk one schedule of (group, row
 tile) visits handed over as scalar prefetch: a tile that two groups
 share is visited once for each, and the rows that are not the visit's
-are masked.  Rows past the groups' sum — the padding, and under expert
-parallelism the rows of other ranks' experts — come back as zeros.
+are masked.  Rows past the groups' sum — the padding, and the rows of
+experts that are not held here: other ranks' under expert parallelism,
+other chips' where this chip holds its share of a layer (``first_expert``
+and the leading dimension of the expert tensors say which) — come back as
+zeros.  The row buffer is static, ``T * k`` rows however few are held.
 
 Inside a ``shard_map`` (a Pallas kernel has no partitioning rule) the
 layer takes the names of the mesh axes: tokens are split over
@@ -406,23 +412,49 @@ def _psum(x, axes):
     return jax.lax.psum(x, tuple(axes)) if axes else x
 
 
+def update_selection_bias(bias: jax.Array, counts: jax.Array,
+                          speed: float) -> jax.Array:
+    """The auxiliary-loss-free balancing of arXiv:2412.19437 §2.1.2: after
+    a step the selection bias of an expert whose load (``counts (..., E)``,
+    its assignments in that step) was over the mean goes down by ``speed``,
+    that of one under the mean up, one at the mean stays."""
+    counts = counts.astype(jnp.float32)
+    over = counts - jnp.mean(counts, axis=-1, keepdims=True)
+    return (bias.astype(jnp.float32) - speed * jnp.sign(over)).astype(
+        bias.dtype)
+
+
 def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
-              w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
+              w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+              select_bias: Optional[jax.Array] = None, *,
               num_selected: int, norm_eps: float = 1e-6,
               norm_topk_prob: bool = False, tile: Optional[int] = None,
+              scoring: str = "softmax", gate_scale: float = 1.0, first_expert: int = 0,
+              residual: bool = True,
               token_axes: Sequence[str] = (),
               expert_axis: Optional[str] = None,
               sum_axes: Sequence[str] = ()
               ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The expert layer on the residual stream ``x (..., d)``: returns
-    ``x + experts(norm(x))`` and float32 scalars ``aux_loss`` (load
-    balancing), ``z_loss``, ``load_max_over_mean`` (the busiest expert's
-    assignments over the mean) and ``dropped`` (assignments that reached
-    no expert: 0).  ``router_w (d, E)``; ``w_gate``/``w_up (E', d, m')``,
-    ``w_down (E', m', d)`` — all the experts, or inside a ``shard_map``
-    this rank's ``E / ep`` of them (``expert_axis``) at this rank's slice
-    of ``m`` (``sum_axes`` then names the axes the partial outputs are
-    summed over, and ``token_axes`` those the tokens are split over)."""
+    ``x + experts(norm(x))`` (the experts' sum alone without ``residual``)
+    and float32 scalars ``aux_loss`` (load balancing), ``z_loss``,
+    ``load_max_over_mean`` (the busiest expert's assignments over the
+    mean), ``dropped`` (assignments to an expert that is held and that
+    reached none: 0) and ``held_share`` (the assignments to held experts
+    over all of them), beside ``counts (E,)``, every expert's assignments.
+    ``router_w (d, E)``; ``w_gate``/``w_up (E', d, m')``, ``w_down (E',
+    m', d)`` — all the experts, or the ``E'`` of them from ``first_expert``
+    on that THIS chip holds of a layer divided over several (the router
+    keeps its ``E`` outputs and ``num_selected`` a token; a row routed to
+    an absent expert is computed nowhere and added nowhere), or inside a
+    ``shard_map`` this rank's ``E / ep`` of them (``expert_axis``) at this
+    rank's slice of ``m`` (``sum_axes`` then names the axes the partial
+    outputs are summed over, and ``token_axes`` those the tokens are split
+    over).  ``scoring``: ``softmax`` over the experts, or ``sigmoid`` of
+    each logit; ``select_bias (E,)`` is added to the scores for the
+    SELECTION only, the gates are the scores themselves and no gradient
+    reaches it (``update_selection_bias`` moves it); the gates, renormalised
+    where ``norm_topk_prob``, are multiplied by ``gate_scale``."""
     shape, d = x.shape, x.shape[-1]
     x = x.reshape(-1, d)
     t, e, k = x.shape[0], router_w.shape[1], num_selected
@@ -432,10 +464,22 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
         h = rms_norm(x, norm_w, norm_eps)
         logits = jnp.dot(h, router_w.astype(h.dtype),
                          preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, experts = jax.lax.top_k(probs, k)                 # (T, k)
+        if scoring == "softmax":
+            probs = scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        if select_bias is None:
+            gates, experts = jax.lax.top_k(scores, k)            # (T, k)
+        else:
+            _, experts = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(select_bias).astype(
+                    jnp.float32), k)
+            gates = jnp.take_along_axis(scores, experts, axis=-1)
         if norm_topk_prob:
             gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        if gate_scale != 1.0:
+            gates = gates * gate_scale
         flat = experts.reshape(-1)
         # of this shard's tokens; a compare and a sum, where ``bincount``
         # adds into its bins one element at a time
@@ -451,8 +495,11 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
 
     with jax.named_scope("moe_dispatch"):
         group_sizes = assigned
-        if expert_axis is not None:  # this rank's experts sort first
-            first = jax.lax.axis_index(expert_axis) * local
+        ranks = (expert_axis,) if expert_axis else ()
+        if local < e:  # the experts held here sort first
+            first = first_expert
+            if expert_axis is not None:
+                first = first + jax.lax.axis_index(expert_axis) * local
             flat = (flat - first) % e
             group_sizes = jax.lax.dynamic_slice(assigned, (first,), (local,))
         tile = tile or choose_tiles(t * k, local)
@@ -466,9 +513,14 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
             (sched, row_token, row_slot, slot_row, row_gate),
             "moe_row_index")
         x_rows = _dispatch(h, row_token, slot_row)
-        dropped = (tokens * k - _psum(
-            jnp.sum(group_sizes).astype(jnp.float32),
-            tuple(token_axes) + ((expert_axis,) if expert_axis else ())))
+        # rows the schedule gives a held expert, against the choices that
+        # name one (all of them where every expert is held somewhere)
+        reached = _psum(jnp.sum(group_sizes).astype(jnp.float32),
+                        tuple(token_axes) + ranks)
+        held = local * (jax.lax.psum(1, ranks) if ranks else 1)
+        wanted = tokens * k if held == e else _psum(jnp.sum(
+            (flat < local).astype(jnp.float32)), tuple(token_axes) + ranks)
+        dropped = wanted - reached
 
     with jax.named_scope("moe_experts"):
         product = functools.partial(
@@ -478,7 +530,9 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
                                 product(x_rows, w_up)), w_down)
 
     with jax.named_scope("moe_combine"):
-        y = _combine(y_rows, gates, row_token, row_slot, slot_row, row_gate)
-        out = (x + _psum(y, sum_axes)).reshape(shape)
+        y = _psum(_combine(y_rows, gates, row_token, row_slot, slot_row,
+                           row_gate), sum_axes)
+        out = ((x + y) if residual else y).reshape(shape)
     return out, {"aux_loss": aux, "z_loss": z, "load_max_over_mean": load,
-                 "dropped": dropped}
+                 "dropped": dropped, "held_share": reached / (tokens * k),
+                 "counts": counts}

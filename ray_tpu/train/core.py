@@ -19,7 +19,8 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.llama import (
-    LlamaConfig, forward_pipelined, init_params, loss_fn, param_logical_axes,
+    LlamaConfig, forward_pipelined, init_params, loss_and_counts,
+    param_logical_axes, update_router_bias,
 )
 from ray_tpu.parallel.mesh import AXIS_DP, AXIS_FSDP, AXIS_PP
 from ray_tpu.parallel.sharding import (
@@ -29,9 +30,11 @@ from ray_tpu.parallel.sharding import (
 
 # The ``jax.named_scope`` names that between them cover the step program,
 # with no overlap: ``models/llama.py`` opens all but the last (an expert
-# layer opens ``ops/moe.py``'s four ``moe_*`` in place of ``ffn``, a Mamba
-# layer the four ``ssm_*`` in place of the three ``attn*``), ``step``
-# below opens ``optimizer``.  A device op's ``op_name`` carries exactly one
+# layer opens ``ops/moe.py``'s four ``moe_*`` in place of ``ffn`` — and
+# ``ffn`` too for a shared expert —, a Mamba layer the four ``ssm_*`` in
+# place of the three ``attn*``, a layer of several residual streams
+# ``hc_map`` and ``hc_mix`` round each of its blocks, a predicted-ahead
+# module ``mtp_in``), ``step`` below opens ``optimizer``.  A device op's ``op_name`` carries exactly one
 # of them, wrapped by JAX in the phase: bare or ``jvp(..)`` is the forward
 # pass, under ``rematted_computation`` the rematerialised forward,
 # ``transpose(jvp(..))`` the backward pass (``util.tracing.step_breakdown``).
@@ -45,6 +48,7 @@ from ray_tpu.parallel.sharding import (
 STEP_SCOPES = ("embed", "attn_qkv", "attention", "attn_out", "ffn",
                "moe_route", "moe_dispatch", "moe_experts", "moe_combine",
                "ssm_in", "ssm_conv", "ssm_scan", "ssm_out",
+               "hc_map", "hc_mix", "mtp_in",
                "lm_head", "loss", "optimizer")
 
 
@@ -123,16 +127,22 @@ def make_train_step(cfg: LlamaConfig,
             forward_fn = lambda p, t: forward_pipelined(
                 p, t, cfg, mesh=mesh, num_microbatches=num_microbatches,
                 rules=rules)
-        return loss_fn(params, batch, cfg, mesh=mesh, rules=rules,
-                       forward_fn=forward_fn)
+        return loss_and_counts(params, batch, cfg, mesh=mesh, rules=rules,
+                               forward_fn=forward_fn)
 
     def step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
-        (_, metrics), grads = jax.value_and_grad(
+        (_, (metrics, counts)), grads = jax.value_and_grad(
             compute_loss, has_aux=True)(state.params, batch)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(grads, state.opt_state,
                                                   state.params)
             params = optax.apply_updates(state.params, updates)
+            if counts is not None:
+                # a router's selection bias has no gradient: it moves by
+                # its own rule, from this step's load, in place of the
+                # optimizer's update (which would only decay it)
+                params = update_router_bias(state.params, params, counts,
+                                            cfg)
             metrics = dict(
                 metrics,
                 grad_norm=optax.global_norm(grads).astype(jnp.float32))

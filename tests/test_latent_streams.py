@@ -1,0 +1,400 @@
+"""Xing4.0's mechanisms at CPU size: latent attention at two head sizes,
+the n-stream residual and its Sinkhorn maps, sigmoid-scored experts with a
+selection bias, a shared expert and a held share, the predicted-ahead
+module — the program (``ray_tpu/models/llama.py`` and its ops) against the
+plain reference (``benchmark/reference/xing4.py``) on seeded weights."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.loops import train
+from benchmark.reference import xing4
+from ray_tpu.models.llama import (
+    LlamaConfig, forward, init_params, loss_fn, param_logical_axes)
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import flash_attention, mha_reference
+from ray_tpu.ops.layers import sinkhorn, yarn_inv_freq, yarn_mscale
+from ray_tpu.ops.moe import moe_block, update_selection_bias
+from ray_tpu.train.core import (
+    STEP_SCOPES, default_optimizer, init_train_state, make_train_step)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+NAME = "xing4.0-29b-a4b-1of8"
+SCALING = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
+           "mscale_all_dim": 1, "original_max_position_embeddings": 16,
+           "type": "yarn"}
+# the reference's configuration (public key names) of the tiny model below
+CONF = dict(
+    first_k_dense_replace=2, hc_mult=4, num_attention_heads=4,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=16,
+    rope_theta=10000, rms_norm_eps=1e-6, rope_scaling=SCALING,
+    num_experts_per_tok=4, routed_scaling_factor=2, first_expert=4,
+    hc_sinkhorn_iters=20, hc_eps=1e-6, mhc_h_res_clamp_min=-30,
+    mhc_h_res_clamp_max=30, mtp_loss_coef=0.3)
+
+
+def tiny(**kw) -> LlamaConfig:
+    fields = dict(
+        vocab_size=128, embed_dim=64, num_layers=4, num_heads=4,
+        num_kv_heads=4, head_dim=16, mlp_dim=32, dense_mlp_dim=96,
+        max_seq_len=64, dtype=jnp.float32, remat=False,
+        attn_impl="reference", q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, rope_scaling=SCALING,
+        num_experts=16, num_selected=4, norm_topk_prob=True, experts_held=4,
+        first_expert=4, shared_experts=1, router_scoring="sigmoid",
+        topk_method="noaux_tc", routed_scaling_factor=2.0, leading_dense=2,
+        hc_mult=4, num_nextn=1, aux_loss_coef=0.0)
+    fields.update(kw)
+    return LlamaConfig(**fields)
+
+
+def seeded(cfg, seed=0):
+    """Parameters whose norm weights are drawn away from 1, as the train
+    loop draws them for its check."""
+    rng = np.random.default_rng(seed)
+
+    def drawn(path, a):
+        if not str(getattr(path[-1], "key", "")).endswith("norm"):
+            return a
+        return a * rng.uniform(0.5, 1.5, a.shape).astype(np.float32)
+
+    return jax.tree_util.tree_map_with_path(
+        drawn, init_params(jax.random.PRNGKey(seed), cfg))
+
+
+TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, 128)
+
+
+def _program_loss(cfg, params):
+    return jax.jit(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg))(params)
+
+
+# -- 1a: runs of (mixer, FFN) -------------------------------------------------
+
+def test_runs_are_of_mixer_and_ffn_and_hold_only_their_kind():
+    cfg = tiny()
+    assert cfg.kind_runs == ((("latent", "dense"), 2), (("latent", "moe"), 2))
+    assert cfg.layer_runs == (("latent", 2), ("latent", 2))
+    assert cfg.mtp_runs == ((("latent", "moe"), 1),)
+    dense, moe = init_params(jax.random.PRNGKey(0), cfg)["layers"]
+    assert dense["w_gate"].shape == (2, 64, 96) and "router" not in dense
+    assert moe["w_gate"].shape == (2, 4, 64, 32)         # the 4 held
+    assert moe["router"].shape == (2, 64, 16)            # ALL the experts
+    assert moe["router_bias"].dtype == jnp.float32
+    assert moe["shared_down"].shape == (2, 32, 64)
+    assert moe["hc_attn_proj"].shape == (2, 4 * 64, 2 * 4 + 16)
+    axes = param_logical_axes(cfg)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    assert jax.tree.structure(jax.tree.map(lambda a: 0, params)) == \
+        jax.tree.structure(jax.tree.map(
+            lambda a: 0, axes, is_leaf=lambda a: isinstance(a, tuple)
+            and all(isinstance(x, (str, type(None))) for x in a)))
+    # the models there were keep their runs and their stacks
+    assert LlamaConfig(num_layers=3).kind_runs == (
+        (("attention", "dense"), 3),)
+    assert LlamaConfig(num_layers=2, num_experts=4).kind_runs == (
+        (("attention", "moe"), 2),)
+
+
+# -- the whole model against the reference ------------------------------------
+
+def test_loss_parts_and_gradients_equal_the_plain_reference():
+    cfg = tiny()
+    params = seeded(cfg)
+    total, parts = _program_loss(cfg, params)
+    want = xing4.loss_parts(params, TOKENS, CONF)
+    for ours, theirs in (("loss", "loss"), ("mtp_loss", "mtp_loss"),
+                         ("moe_held_share", "moe_held_share")):
+        np.testing.assert_allclose(parts[ours], want[theirs], rtol=2e-5)
+    np.testing.assert_allclose(total, want["total"], rtol=2e-5)
+    assert float(parts["moe_dropped"]) == 0.0
+    logits, _ = forward(params, TOKENS[:, :-1], cfg)
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
+                               TOKENS[:, 1:, None], -1)[..., 0]
+    np.testing.assert_allclose(nll, want["token_nll"], atol=3e-5)
+    ours = jax.grad(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(params)
+    theirs = jax.grad(lambda p: xing4.loss(p, TOKENS, CONF))(params)
+    apart = jax.tree.map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b))
+                           / (jnp.max(jnp.abs(b)) + 1e-12)), ours, theirs)
+    assert max(jax.tree.leaves(apart)) < 1e-4, apart
+    # no gradient reaches a selection bias
+    assert not np.any(np.asarray(ours["layers"][1]["router_bias"]))
+
+
+def test_flash_kernels_and_the_checkpoint_give_the_same_loss():
+    cfg = tiny()
+    params = seeded(cfg)
+    plain, _ = _program_loss(cfg, params)
+    kernels, _ = _program_loss(
+        dataclasses.replace(cfg, attn_impl="flash", remat=True), params)
+    np.testing.assert_allclose(kernels, plain, rtol=1e-5)
+
+
+# what each part of the model is worth to the loss: the program with the
+# part changed must stand apart from the reference by far more than the
+# check's tolerance (1e-4), or the check could not see that part
+@pytest.mark.parametrize("change", [
+    dict(rope_scaling=None),                      # 1b: YaRN and its scale
+    dict(routed_scaling_factor=1.0),              # 1c: the gates' scale
+    dict(router_scoring="softmax"),
+    dict(norm_topk_prob=False),
+    dict(first_expert=0),                         # another chip's experts
+    dict(hc_sinkhorn_iters=0),                    # 1d: exp alone
+    dict(hc_clamp_max=0.0),
+    dict(mtp_loss_coef=0.0),                      # 1e
+], ids=lambda c: "-".join(c))
+def test_a_changed_part_stands_apart_from_the_reference(change):
+    cfg = tiny()
+    params = seeded(cfg)
+    want = float(xing4.loss(params, TOKENS, CONF))
+    got = float(_program_loss(dataclasses.replace(cfg, **change), params)[0])
+    assert abs(got - want) / want > 3e-4
+
+
+@pytest.mark.parametrize("leaf", [
+    "router_bias", "shared_down", "hc_attn_bias", "hc_ffn_scale", "wkv_b"])
+def test_a_zeroed_leaf_stands_apart_from_the_reference(leaf):
+    cfg = tiny()
+    params = seeded(cfg)
+    want = float(xing4.loss(params, TOKENS, CONF))
+    dense, moe = params["layers"]
+    changed = dict(params, layers=(dense, dict(moe, **{
+        leaf: jnp.zeros_like(moe[leaf])})))
+    got = float(_program_loss(cfg, changed)[0])
+    assert abs(got - want) / want > 2e-4
+
+
+# -- 1b: the flash kernels at two head sizes -----------------------------------
+
+def _qkv(seq, d, dv, heads=2, rows=1, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    return (jax.random.normal(keys[0], (rows, seq, heads, d), dtype),
+            jax.random.normal(keys[1], (rows, seq, heads, d), dtype),
+            jax.random.normal(keys[2], (rows, seq, heads, dv), dtype))
+
+
+@pytest.mark.parametrize("which", ["forward", "dq", "dk", "dv"])
+@pytest.mark.parametrize("d,dv", [(192, 128), (24, 16), (16, 32)],
+                         ids=["192x128", "24x16", "16x32"])
+def test_flash_takes_a_v_head_apart_from_the_qk_head(d, dv, which):
+    q, k, v = _qkv(256, d, dv)
+    scale = 0.7 * d ** -0.5
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            jnp.sin(fn(q, k, v, causal=True, sm_scale=scale)))
+
+    if which == "forward":
+        got = flash_attention(q, k, v, sm_scale=scale, block_q=128,
+                              block_k=64)
+        want = mha_reference(q, k, v, sm_scale=scale)
+        assert got.shape == (1, 256, 2, dv)
+    else:
+        arg = ("dq", "dk", "dv").index(which)
+        got = jax.grad(loss(flash_attention), arg)(q, k, v)
+        want = jax.grad(loss(mha_reference), arg)(q, k, v)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_equal_head_sizes_give_the_kernels_the_operands_they_had():
+    """With v as wide as q and k, every v-side block is the k-side block
+    (and o's q's): the three calls are the calls they were."""
+    q = jnp.zeros((1, 2, 256, 128), jnp.bfloat16)
+    _, specs = attention._grid_and_specs(q, q, q, True, (128, 128, 128, 128))
+    for ours, theirs in (("v_j", "k_j"), ("v_i", "k_i"), ("o_i", "q_i"),
+                         ("o_j", "q_j")):
+        assert specs[ours].block_shape == specs[theirs].block_shape
+        for at in ((0, 1, 0, 1), (0, 0, 1, 0)):
+            assert specs[ours].index_map(*at) == specs[theirs].index_map(*at)
+    assert attention.choose_tiles(8192, 8192, True, 192, jnp.bfloat16) == \
+        attention.choose_tiles(8192, 8192, True, 128, jnp.bfloat16)
+    q, k, v = _qkv(128, 128, 128)
+    np.testing.assert_array_equal(
+        flash_attention(q, k, v), flash_attention(q, k, v + 0.0))
+
+
+def test_yarn_frequencies_and_scale():
+    freq = np.asarray(yarn_inv_freq(64, 10000.0, factor=64, original=4096,
+                                    beta_fast=32, beta_slow=1))
+    plain = 1.0 / 10000.0 ** (np.arange(0, 64, 2) / 64)
+    np.testing.assert_allclose(freq[:10], plain[:10], rtol=1e-6)  # fast: kept
+    np.testing.assert_allclose(freq[-8:], plain[-8:] / 64, rtol=1e-6)
+    assert np.all(np.diff(freq) < 0)
+    # the reference builds its own tables: position 1's angles ARE the
+    # frequencies
+    _, sin = xing4.yarn_tables(3, 64, 10000.0, dict(
+        SCALING, original_max_position_embeddings=4096))
+    np.testing.assert_allclose(sin[1], np.sin(freq), rtol=1e-5, atol=1e-7)
+    assert yarn_mscale(64, 1) == pytest.approx(0.1 * np.log(64) + 1)
+    assert yarn_mscale(1, 1) == 1.0
+    conf = dict(CONF, qk_nope_head_dim=128, qk_rope_head_dim=64)
+    assert xing4.softmax_scale(conf) == pytest.approx(
+        192 ** -0.5 * (0.1 * np.log(64) + 1) ** 2)
+
+
+# -- 1c: the expert layer ------------------------------------------------------
+
+def _expert_layer(seed=3, tokens=96, d=32, m=16, experts=16):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 9)
+    normal = jax.random.normal
+    return dict(
+        x=normal(keys[0], (tokens, d)),
+        mlp_norm=1.0 + 0.3 * normal(keys[1], (d,)),
+        router=normal(keys[2], (d, experts)) * d ** -0.5,
+        router_bias=0.05 * normal(keys[3], (experts,)),
+        w_gate=normal(keys[4], (experts, d, m)) * d ** -0.5,
+        w_up=normal(keys[5], (experts, d, m)) * d ** -0.5,
+        w_down=normal(keys[6], (experts, m, d)) * m ** -0.5,
+        shared_gate=normal(keys[7], (d, m)) * d ** -0.5,
+        shared_up=normal(keys[8], (d, m)) * d ** -0.5,
+        shared_down=normal(keys[0], (m, d)) * m ** -0.5)
+
+
+def _share(p, first, held):
+    """What the chip that holds ``held`` experts from ``first`` on adds:
+    the routed part alone, its step counters beside it."""
+    return moe_block(
+        p["x"], p["mlp_norm"], p["router"], *(
+            p[w][first:first + held] for w in ("w_gate", "w_up", "w_down")),
+        num_selected=4, norm_topk_prob=True, scoring="sigmoid",
+        select_bias=p["router_bias"], gate_scale=2.0, first_expert=first,
+        residual=False)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """8 chips with 2 of 16 experts each: their routed parts, and the
+    shared expert ONCE, are the whole layer as the reference has it."""
+    p = _expert_layer()
+    parts = [_share(p, first, 2) for first in range(0, 16, 2)]
+    routed = sum(y for y, _ in parts)
+    n = xing4.rms_norm(p["x"], p["mlp_norm"], 1e-6)
+    shared = xing4.swiglu(n, p["shared_gate"], p["shared_up"],
+                          p["shared_down"])
+    whole, _ = xing4.expert_ffn(p["x"][None], p, k=4, factor=2.0, first=0,
+                                eps=1e-6)
+    np.testing.assert_allclose(routed + shared, whole[0], atol=2e-5)
+    stats = [s for _, s in parts]
+    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
+    assert all(float(s["dropped"]) == 0.0 for s in stats)
+    for s in stats:     # every chip counts ALL the experts, and alike
+        np.testing.assert_array_equal(s["counts"], stats[0]["counts"])
+    assert int(jnp.sum(stats[0]["counts"])) == 96 * 4
+    # one share alone is the reference's with the same experts held
+    alone, _ = xing4.expert_ffn(
+        p["x"][None], {**p, **{w: p[w][6:8] for w in (
+            "w_gate", "w_up", "w_down")}}, k=4, factor=2.0, first=6,
+        eps=1e-6)
+    np.testing.assert_allclose(parts[3][0] + shared, alone[0], atol=2e-5)
+
+
+def test_the_bias_reaches_the_selection_and_not_the_gate():
+    p = _expert_layer()
+    pushed = dict(p, router_bias=p["router_bias"].at[5].add(10.0))
+    out, stats = _share(pushed, 4, 4)
+    assert int(stats["counts"][5]) == 96          # every token takes 5
+    gates, experts = xing4.route(
+        xing4.rms_norm(p["x"], p["mlp_norm"], 1e-6), p["router"],
+        pushed["router_bias"], 4, 2.0)
+    assert np.all(np.asarray(gates) < 2.0)        # sigmoid scores, not 10
+    np.testing.assert_allclose(jnp.sum(gates, -1), 2.0, rtol=1e-6)
+    grad = jax.grad(lambda b: jnp.sum(_share(dict(p, router_bias=b), 4, 4)[0]
+                                      ** 2))(p["router_bias"])
+    assert not np.any(np.asarray(grad))
+
+
+def test_selection_bias_update_sign_and_size():
+    bias = jnp.array([0.0, 0.1, -0.2, 0.05])
+    counts = jnp.array([10, 2, 4, 4])             # mean 5
+    moved = update_selection_bias(bias, counts, 0.001)
+    np.testing.assert_allclose(moved - bias, [-0.001, 0.001, 0.001, 0.001],
+                               atol=1e-7)
+    even = update_selection_bias(bias, jnp.array([5, 5, 5, 5]), 0.001)
+    np.testing.assert_array_equal(even, bias)
+    stacked = update_selection_bias(jnp.zeros((2, 4)), jnp.array(
+        [[10, 2, 4, 4], [1, 9, 5, 5]]), 0.01)     # a layer a row
+    np.testing.assert_allclose(stacked, [[-0.01, 0.01, 0.01, 0.01],
+                                         [0.01, -0.01, 0.0, 0.0]])
+
+
+def test_the_train_step_moves_the_bias_by_its_rule_and_nothing_else_does():
+    cfg = tiny()
+    opt = default_optimizer()
+    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
+    before = jax.tree.map(np.asarray, state.params)
+    state, metrics = make_train_step(cfg, opt, donate=False)(
+        state, {"tokens": TOKENS})
+    for old, new in ((before["layers"][1], state.params["layers"][1]),
+                     (before["mtp"]["layers"], state.params["mtp"]["layers"])):
+        step = np.asarray(new["router_bias"]) - old["router_bias"]
+        assert np.all((step == 0) | np.isclose(np.abs(step), 0.001,
+                                               atol=1e-6))
+        assert np.any(step > 0) and np.any(step < 0)
+    assert {"mtp_loss", "moe_held_share", "moe_dropped"} <= set(metrics)
+    assert 0.0 < float(metrics["moe_held_share"]) < 1.0
+    # a model without such a leaf hands the step no counts
+    from ray_tpu.models.llama import loss_and_counts
+    plain = LlamaConfig.tiny(num_experts=4)
+    _, (_, counts) = loss_and_counts(
+        init_params(jax.random.PRNGKey(0), plain), {"tokens": TOKENS}, plain)
+    assert counts is None
+
+
+# -- 1d: the residual's maps ---------------------------------------------------
+
+def test_sinkhorn_rows_and_columns_sum_to_one():
+    logits = jnp.clip(3.0 * jax.random.normal(
+        jax.random.PRNGKey(0), (4, 4, 500)), -30, 30)
+    m = sinkhorn(logits, 20, 1e-6)
+    # columns are divided last: 1 to the eps.  Rows are 1 as far as 20
+    # rounds converge: to a thousandth for the median token, to a tenth
+    # for the worst of 500 drawn three times as wide as the model's start
+    np.testing.assert_allclose(jnp.sum(m, axis=0), 1.0, atol=1e-5)
+    off = np.abs(np.asarray(jnp.sum(m, axis=1)) - 1.0)
+    assert off.max() < 0.1 and np.median(off) < 1e-3
+    assert float(jnp.min(m)) >= 0.0
+    ours = jnp.moveaxis(m, -1, 0)                  # (T, n, n)
+    theirs = xing4.sinkhorn(jnp.moveaxis(logits, -1, 0), 20, 1e-6)
+    np.testing.assert_allclose(ours, theirs, rtol=1e-5)
+
+
+def test_the_maps_start_near_the_plain_residual_and_not_at_it():
+    cfg = tiny()
+    moe = init_params(jax.random.PRNGKey(0), cfg)["layers"][1]
+    bias = np.asarray(moe["hc_ffn_bias"][0])
+    res = np.asarray(sinkhorn(jnp.asarray(bias[8:].reshape(4, 4, 1)), 20,
+                              1e-6))[..., 0]
+    assert np.all(np.diag(res) > 0.85) and np.all(np.diag(res) < 0.99)
+    assert not np.allclose(res, 0.25, atol=0.1)    # not the uniform matrix
+    np.testing.assert_allclose(1 / (1 + np.exp(-bias[:4])), 0.25, atol=0.06)
+    assert np.all(bias != 0.0)     # control.py scales a column by its peak
+
+
+# -- the traffic, the scopes ---------------------------------------------------
+
+def test_the_sliced_files_traffic_never_leaves_the_slice():
+    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
+        conf = json.load(f)
+    cfg = train.program_config(conf)
+    assert (cfg.vocab_size, cfg.num_experts, cfg.experts_held) == (
+        16384, 64, 8)
+    drawn = train.draw_tokens(np.random.default_rng([2**31 + 5, 0]), cfg, 4,
+                              8192)
+    assert drawn.shape == (4, 8193) and drawn.dtype == np.int32
+    assert 0 <= drawn.min() and 16000 < drawn.max() < 16384
+
+
+def test_the_new_scopes_are_step_scopes_and_open_in_the_program():
+    assert {"hc_map", "hc_mix", "mtp_in"} <= set(STEP_SCOPES)
+    cfg = tiny()
+    text = jax.jit(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0]).lower(
+        init_params(jax.random.PRNGKey(0), cfg)).as_text(debug_info=True)
+    for scope in ("hc_map", "hc_mix", "mtp_in", "attn_qkv", "moe_experts",
+                  "ffn"):
+        assert f"/{scope}/" in text or f"{scope}/" in text, scope
