@@ -11,14 +11,17 @@ Design (standard memory-efficient attention, mapped to the TPU grid model):
   dimension is sequential on TPU, so softmax running stats ``(m, l)`` and the
   output accumulator live in VMEM scratch that persists across kv iterations;
   the normalized output and the logsumexp are written on the last kv block.
-- The logsumexp residual is lane-replicated to ``(b, h, s, LANES)`` — a 1D
-  row per q position cannot be expressed as a legal minor block shape, so
-  stats ride in full vector registers (the layout jax's own TPU
+- The forward writes its logsumexp lane-replicated ``(b, h, s, LANES)``:
+  per-row stats of a ``(q rows, k columns)`` tile are COLUMNS, and a
+  column rides in full vector registers (the layout jax's own TPU
   flash-attention kernel uses for its ``l``/``m`` outputs).
 - Backward: two kernels (the classic split): one accumulates ``dk, dv`` with
   grid ``(b, h, kv_blocks, q_blocks)``, one accumulates ``dq`` with grid
   ``(b, h, q_blocks, kv_blocks)``; both recompute ``p = exp(s - lse)`` from
   the saved per-row logsumexp instead of materializing the S x S matrix.
+  The dk/dv kernel keeps its scores TRANSPOSED, ``s^T = k q^T`` with the q
+  rows along the lanes, so its two gradient products are plain ones and
+  its per-row stats are rows ``(b, h, 1, sq)``, not lane-replicated.
 - Causal schedule: a grid step FETCHES a large tile and the kernel walks
   it in COMPUTE sub-tiles, each dead (no code runs), interior (no mask) or
   diagonal (masked); grid steps whose whole tile is dead name the block
@@ -56,11 +59,15 @@ _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
 
 # Fetch tile (what one grid step copies into VMEM) and compute sub-tile,
-# measured on TPU v5e at d=128 bf16 (PERF.md §6, PR 24).  Under the causal
-# mask the largest tile wins: fewer ~0.35 us grid steps, fewer running-
-# softmax updates, longer contractions, and the sub-tile walk keeps what is
-# computed above the diagonal small whatever the tile.  Without a mask the
-# tile stays what the kernels always used.
+# measured on TPU v5e in bf16 at d=128 (PERF.md §6, PR 24) and at a latent
+# mixer's 192 / 128 (PR 35).  Under the causal mask the largest tile wins:
+# fewer ~0.35 us grid steps, fewer running-softmax updates, longer
+# contractions, and the sub-tile walk keeps what is computed above the
+# diagonal small whatever the tile.  Without a mask the tile stays what the
+# kernels always used.  What a large tile costs is CODE: one call over a
+# whole 2048 x 2048 tile is straight-line code, and the dk/dv kernel's four
+# products at 192 / 128 ran at half speed until its interior tile became a
+# loop over strips (``_dkdv_kernel``).
 MAX_BLOCK = 2048                   # rows of a causal fetch tile, q and kv
 UNMASKED_BLOCK = (512, 1024)       # (q, kv) rows of a non-causal one
 _BLOCK_BYTES = 2 * 1024 * 1024     # one operand's block: bounds rows by d
@@ -207,24 +214,30 @@ def _walk_tile(causal, off, tiles, body, strips):
         pl.when(off == straddling)(functools.partial(walk, straddling))
 
 
-def _scores(q, k, mask):
-    """f32 ``q @ k^T`` of one strip.  ``mask`` = (axis, n, off): the first
-    ``n`` rows (axis 0) or the last ``n`` columns (axis 1) are masked, row
-    r of that part seeing its column c iff r - c >= off."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+def _scores(q, k, mask, transposed=False):
+    """f32 ``q @ k^T`` of one strip, or ``k @ q^T`` (the q rows along the
+    lanes) if ``transposed``.  ``mask`` = (axis, n, off): the first ``n``
+    q rows (axis 0) or the last ``n`` k columns (axis 1) are masked, row r
+    of that part seeing its column c iff r - c >= off."""
+    a, b = (k, q) if transposed else (q, k)
+    s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if mask is None:
         return s
     axis, n, off = mask
-    cut = n if axis == 0 else s.shape[1] - n
-    part = s[:cut] if axis == 0 else s[:, cut:]
-    diff = (jax.lax.broadcasted_iota(jnp.int32, part.shape, 0)
-            - jax.lax.broadcasted_iota(jnp.int32, part.shape, 1))
+    rows = int(transposed)           # the axis of s the q rows lie along
+    cut_axis = axis ^ rows
+    cut = n if axis == 0 else s.shape[cut_axis] - n
+    head, tail = (jax.lax.slice_in_dim(s, 0, cut, axis=cut_axis),
+                  jax.lax.slice_in_dim(s, cut, None, axis=cut_axis))
+    part = head if axis == 0 else tail
+    diff = (jax.lax.broadcasted_iota(jnp.int32, part.shape, rows)
+            - jax.lax.broadcasted_iota(jnp.int32, part.shape, 1 - rows))
     part = jnp.where(diff >= off, part, NEG_INF)
     if part.shape == s.shape:
         return part
-    return jnp.concatenate(
-        [part, s[cut:]] if axis == 0 else [s[:, :cut], part], axis)
+    return jnp.concatenate([part, tail] if axis == 0 else [head, part],
+                           cut_axis)
 
 
 def _tile_offset(causal, qi, ki, tiles, grid_qk):
@@ -276,7 +289,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 def _grid_and_specs(qt, kt, vt, causal, tiles):
     """``(nq, nk)`` tiles of the call and the BlockSpecs of the q-side and
     kv-side operands for both grid orders: ``q_i, k_j, row_i`` for grids
-    ``(b, h, q, kv)`` and ``q_j, k_i, row_j`` for ``(b, h, kv, q)``.
+    ``(b, h, q, kv)`` and ``q_j, k_i, stat_j`` for ``(b, h, kv, q)``
+    (``row``: per-row stats lane-replicated ``(b, h, sq, LANES)``;
+    ``stat``: the same stats as rows ``(b, h, 1, sq)``).
     q and k have one head size, v (and with it o and do: ``o_i``, ``v_j``,
     ``v_i``, ``o_j``) may have another; where the two are equal the specs
     are.  Under the mask a dead grid step names the block its nearest live
@@ -305,7 +320,9 @@ def _grid_and_specs(qt, kt, vt, causal, tiles):
         "k_j": spec(block_k, d, inner_k), "v_j": spec(block_k, dv, inner_k),
         "k_i": spec(block_k, d, outer), "v_i": spec(block_k, dv, outer),
         "q_j": spec(block_q, d, inner_q), "o_j": spec(block_q, dv, inner_q),
-        "row_j": spec(block_q, _LANES, inner_q),
+        "stat_j": pl.BlockSpec(
+            (1, 1, 1, block_q),
+            lambda b_, h_, i, j: (b_, h_, 0, inner_q(i, j))),
     }
 
 
@@ -341,19 +358,26 @@ def _fwd_call(qt, kt, vt, causal, tiles, interpret):
 
 # ---------------------------------------------------------------- backward
 
-def _p_and_ds(q, k, v, do, lse, delta, mask):
-    """Recomputed probabilities and score gradients of one sub-tile, both
-    f32 ``(sq, sk)``: ``p = exp2(s - lse)``, ``ds = p * (dp - delta)``."""
-    p = jnp.exp2(_scores(q, k, mask) - lse[:, :1])
+def _p_and_ds(q, k, v, do, lse, delta, mask, transposed=False):
+    """Recomputed probabilities and score gradients of one strip, both
+    f32: ``p = exp2(s - lse)``, ``ds = p * (dp - delta)``.  ``(sq, sk)``
+    from lane-replicated stats ``(sq, LANES)``; if ``transposed``
+    ``(sk, sq)`` from stats that are rows ``(1, sq)``."""
+    if transposed:
+        do, v = v, do
+    else:
+        lse, delta = lse[:, :1], delta[:, :1]
+    p = jnp.exp2(_scores(q, k, mask, transposed) - lse)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    return p, p * (dp - delta[:, :1])
+    return p, p * (dp - delta)
 
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dk_ref, dv_ref, dk_scr, dv_scr, *, causal, tiles, grid_qk):
     ki, qi = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
+    sub_k = tiles[3]
 
     @pl.when(qi == 0)
     def _init():
@@ -361,17 +385,29 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def update(qs, ks, mask):
+        if ks.size > sub_k:
+            # An interior tile comes whole.  Its strips run in a loop that
+            # is NOT unrolled: as one call the tile's four products are
+            # straight-line code, and past a size that code runs at half
+            # speed (q/k head 192: 21.1 ms a call for 10.4; PERF.md §6).
+            def strip(i, carry):
+                update(qs, pl.ds(pl.multiple_of(ks.start + i * sub_k, sub_k),
+                                 sub_k), mask)
+                return carry
+
+            return jax.lax.fori_loop(0, ks.size // sub_k, strip, None)
         q, do = q_ref[0, 0, qs], do_ref[0, 0, qs]
+        # Transposed, (sk, sq): p^T and ds^T are what the two gradient
+        # products take on the left, so no matrix is turned round.
         p, ds = _p_and_ds(q, k_ref[0, 0, ks], v_ref[0, 0, ks], do,
-                          lse_ref[0, 0, qs], delta_ref[0, 0, qs], mask)
+                          lse_ref[0, 0, :, qs], delta_ref[0, 0, :, qs], mask,
+                          transposed=True)
         # Grad matmuls in the INPUT dtype (bf16 on TPU): the MXU runs
         # bf16 natively; f32 operands would force multi-pass matmuls.
-        dv_scr[ks] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (sk, d)
-        dk_scr[ks] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dv_scr[ks] += jnp.dot(p.astype(do.dtype), do,
+                              preferred_element_type=jnp.float32)  # (sk, dv)
+        dk_scr[ks] += jnp.dot(ds.astype(q.dtype), q,
+                              preferred_element_type=jnp.float32)  # (sk, d)
 
     _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
                update, strips="k")
@@ -412,24 +448,23 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret):
-    """qt/kt (b, h, s, d), vt/ot/dot (b, h, s, dv); lse (b, h, sq, LANES).
-    Returns transposed grads (dqt, dkt, dvt)."""
+    """qt/kt (b, h, s, d), vt/ot/dot (b, h, s, dv); lse (b, h, sq), a float
+    a row.  Returns transposed grads (dqt, dkt, dvt)."""
     b, h, sq, d = qt.shape
     dv = vt.shape[3]
     block_q, block_k = tiles[:2]
     delta = jnp.sum(ot.astype(jnp.float32) * dot.astype(jnp.float32),
-                    axis=-1, keepdims=True)                  # (b, h, sq, 1)
-    delta = jnp.broadcast_to(delta, (b, h, sq, _LANES))
+                    axis=-1)                                 # (b, h, sq)
     (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles)
     q_i, k_j, row_i = specs["q_i"], specs["k_j"], specs["row_i"]
-    q_j, k_i, row_j = specs["q_j"], specs["k_i"], specs["row_j"]
+    q_j, k_i, stat_j = specs["q_j"], specs["k_i"], specs["stat_j"]
     o_i, v_j, o_j, v_i = (specs[n] for n in ("o_i", "v_j", "o_j", "v_i"))
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel, causal=causal, tiles=tiles,
                           grid_qk=(nq, nk)),
         grid=(b, h, nk, nq),
-        in_specs=[q_j, k_i, v_i, o_j, row_j, row_j],
+        in_specs=[q_j, k_i, v_i, o_j, stat_j, stat_j],
         out_specs=[k_i, v_i],
         out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
                    jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
@@ -438,8 +473,11 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret):
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name="flash_dkv",
-    )(qt, kt, vt, dot, lse, delta)
+    )(qt, kt, vt, dot, lse[:, :, None], delta[:, :, None])
 
+    # flash_dq walks "q" strips: its stats stay columns, lane-replicated.
+    lse, delta = (jnp.broadcast_to(x[..., None], (b, h, sq, _LANES))
+                  for x in (lse, delta))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
                           tiles=tiles, grid_qk=(nq, nk)),
@@ -483,14 +521,13 @@ def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret):
     ot, lse = _fwd_call(qt, kt, vt, causal, tiles, interpret)
     ot = checkpoint_name(ot, "flash_out")
     # One lane of the 128 the kernel writes: a float a row is what is
-    # worth holding; the backward kernels get the lanes back.
+    # worth holding; flash_dq gets the lanes back, flash_dkv takes rows.
     lse = checkpoint_name(lse[..., 0], "flash_lse")
     return _to_bhsd(ot), (qt, kt, vt, ot, lse)
 
 
 def _flash_bwd(sm_scale, causal, tiles, interpret, res, do):
     qt, kt, vt, ot, lse = res
-    lse = jnp.broadcast_to(lse[..., None], lse.shape + (_LANES,))
     dqt, dkt, dvt = _bwd_call(qt, kt, vt, ot, lse, _to_bhsd(do), sm_scale,
                               causal, tiles, interpret)
     return _to_bhsd(dqt), _to_bhsd(dkt), _to_bhsd(dvt)

@@ -5,6 +5,7 @@ module — the program (``ray_tpu/models/llama.py`` and its ops) against the
 plain reference (``benchmark/reference/xing4.py``) on seeded weights."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -180,27 +181,79 @@ def _qkv(seq, d, dv, heads=2, rows=1, dtype=jnp.float32):
             jax.random.normal(keys[2], (rows, seq, heads, dv), dtype))
 
 
-@pytest.mark.parametrize("which", ["forward", "dq", "dk", "dv"])
-@pytest.mark.parametrize("d,dv", [(192, 128), (24, 16), (16, 32)],
-                         ids=["192x128", "24x16", "16x32"])
-def test_flash_takes_a_v_head_apart_from_the_qk_head(d, dv, which):
-    q, k, v = _qkv(256, d, dv)
+HEADS = [(192, 128), (128, 128), (64, 64), (24, 16), (16, 32)]
+# How a "k" strip of the dk/dv kernel can come: (seq, causal, tiles).
+# "chosen" goes through ``flash_attention`` and ``choose_tiles``.
+STRIPS = {
+    "chosen": None,
+    # a 2 x 2 grid: the interior tile comes whole and is walked by the
+    # kernel's own loop, the diagonal ones in strips of falling extent
+    "interior-tile-looped": (256, True, (128, 128, 32, 32)),
+    # block_q != block_k: tiles straddle the diagonal at two offsets each
+    "straddling-wide-k": (256, True, (64, 128, 16, 64)),     # -64, 0
+    "straddling-tall-q": (256, True, (128, 64, 64, 16)),     # 0, 64
+    # sub_q = block_q: the masked part of a strip is all of it
+    "all-of-a-strip-masked": (128, True, (64, 64, 64, 16)),
+    "one-tile-grid": (128, True, (128, 128, 32, 32)),
+    "no-mask": (128, False, (64, 128, 64, 128)),
+}
+_FLASH_CASES = (
+    [(d, dv, which, "chosen") for d, dv in HEADS
+     for which in ("forward", "dq", "dk", "dv")]
+    + [(d, dv, which, strips) for d, dv in HEADS for strips in STRIPS
+       if strips != "chosen" for which in ("dk", "dv")])
+
+
+@functools.lru_cache(maxsize=None)     # one backward pass a (heads, strips)
+def _flash_grads(d, dv, strips):
+    """((value, dq, dk, dv) of the kernels, the same of the reference)."""
+    seq, causal, tiles = STRIPS[strips] or (256, True, None)
+    q, k, v = _qkv(seq, d, dv)
     scale = 0.7 * d ** -0.5
-
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(
-            jnp.sin(fn(q, k, v, causal=True, sm_scale=scale)))
-
-    if which == "forward":
-        got = flash_attention(q, k, v, sm_scale=scale, block_q=128,
-                              block_k=64)
-        want = mha_reference(q, k, v, sm_scale=scale)
-        assert got.shape == (1, 256, 2, dv)
+    if tiles is None:
+        flash = lambda q, k, v: flash_attention(
+            q, k, v, sm_scale=scale, block_q=128, block_k=64)
     else:
-        arg = ("dq", "dk", "dv").index(which)
-        got = jax.grad(loss(flash_attention), arg)(q, k, v)
-        want = jax.grad(loss(mha_reference), arg)(q, k, v)
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        flash = lambda q, k, v: attention._flash(
+            q, k, v, scale, causal, tiles, True)
+    ref = lambda q, k, v: mha_reference(q, k, v, causal=causal,
+                                        sm_scale=scale)
+    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+    return tuple((fn(q, k, v), *jax.grad(loss(fn), (0, 1, 2))(q, k, v))
+                 for fn in (flash, ref))
+
+
+@pytest.mark.parametrize(
+    "d,dv,which,strips", _FLASH_CASES,
+    ids=[f"{d}x{dv}-{which}-{strips}" for d, dv, which, strips in _FLASH_CASES])
+def test_flash_takes_a_v_head_apart_from_the_qk_head(d, dv, which, strips):
+    """Value and gradients against ``mha_reference`` at head sizes equal
+    and apart, whole and fractions of a lane block; dk and dv (the kernel
+    whose scores are transposed and whose interior tile is a loop) on
+    every kind of part a "k" strip has."""
+    got, want = _flash_grads(d, dv, strips)
+    arg = ("forward", "dq", "dk", "dv").index(which)
+    if which == "forward":
+        assert got[0].shape == (1, 256, 2, dv)
+    np.testing.assert_allclose(got[arg], want[arg], atol=2e-5, rtol=2e-5)
+
+
+def test_flash_dkv_is_one_loop_over_an_interior_tile():
+    """ONE body for every shape.  On a grid with an interior tile the
+    dk/dv kernel holds the one loop of the backward pass (the tile's
+    strips, not unrolled); on a one-tile grid the strips are static and
+    nothing loops.  Its per-row stats are rows ``(b, h, 1, sq)`` where
+    ``flash_dq`` takes lane-replicated columns."""
+    q, k, v = _qkv(256, 24, 16)
+
+    def backward(tiles):
+        return str(jax.make_jaxpr(jax.grad(lambda q, k, v: attention._flash(
+            q, k, v, 1.0, True, tiles, True).sum(), (0, 1, 2)))(q, k, v))
+
+    loops = lambda text: text.count("scan[") + text.count("while[")
+    gridded = backward((128, 128, 32, 32))
+    assert loops(gridded) == 1 and loops(backward((256, 256, 32, 32))) == 0
+    assert "f32[1,2,1,256]" in gridded and "f32[1,2,256,128]" in gridded
 
 
 def test_equal_head_sizes_give_the_kernels_the_operands_they_had():

@@ -55,6 +55,8 @@ FLASH_CASES = {
     "sq-longer-rect-tile": (1, 256, 128, 1, 1, D, jnp.float32, True, 128),
     "gqa-4to1": (1, 128, 128, 8, 2, D, jnp.float32, True, 64),
     "bf16-d128": (1, 256, 256, 2, 2, 128, jnp.bfloat16, True, None),
+    # a latent mixer's head sizes (v 128 wide) on a 2 x 2 grid of 128 tiles
+    "bf16-d192-v128": (1, 256, 256, 2, 2, 192, jnp.bfloat16, True, 128, 128),
     "noncausal-rect": (1, 128, 256, 2, 2, D, jnp.float32, False, 64),
     # no block >= 8 tiles 100 rows: the XLA reference answers
     "untileable": (1, 100, 100, 2, 2, D, jnp.float32, True, None),
@@ -65,8 +67,9 @@ FLASH_CASES = {
 @pytest.mark.parametrize("case", FLASH_CASES)
 def test_flash_attention(case, grads):
     """Value and the three gradients against ``mha_reference``."""
-    b, sq, sk, h, h_kv, d, dtype, causal, cap = FLASH_CASES[case]
+    b, sq, sk, h, h_kv, d, dtype, causal, cap, *dv = FLASH_CASES[case]
     q, k, v = _qkv(b, sq, sk, h, h_kv, d, dtype)
+    v = v[..., :dv[0]] if dv else v
     caps = {} if cap is None else {"block_q": cap, "block_k": cap}
     rep = h // h_kv
     flash = lambda q, k, v: flash_attention(q, k, v, causal=causal, **caps)
@@ -89,12 +92,15 @@ def test_flash_attention(case, grads):
 
 @pytest.mark.parametrize("tiles", [
     (64, 64, 32, 32), (128, 128, 32, 32), (32, 128, 32, 64),
-    (128, 32, 64, 16), (64, 128, 16, 64), (128, 64, 64, 16)],
+    (128, 32, 64, 16), (64, 128, 16, 64), (128, 64, 64, 16),
+    # strips whose masked part is all of them; an interior tile of 4 strips
+    (64, 64, 64, 16), (32, 64, 16, 16)],
     ids=lambda t: "x".join(map(str, t)))
 def test_flash_tile_schedules(qkv, tiles):
     """Any fetch tile and sub-tile give the reference's numbers: square
     and rectangular, sub-tile wider than tall and the reverse, tiles that
-    straddle the diagonal at several offsets."""
+    straddle the diagonal at several offsets, interior tiles that the
+    dk/dv kernel walks in its own loop."""
     from ray_tpu.ops.attention import _flash
 
     q, k, v = qkv

@@ -90,24 +90,48 @@ def test_paged_attention_compiles(one_chip, B, h, d, bs, blocks, dtype,
     assert _has_kernel(compiled)
 
 
+XING4_HEADS = ((1, 8192, 32, 192), 128)    # (q/k shape, v head size)
+
+
+def _flash_shapes(shape, dv, sharding):
+    x = _shape(shape, jnp.bfloat16, sharding)
+    return x, x, _shape(shape[:3] + (dv,), jnp.bfloat16, sharding)
+
+
+def _flash_sum(q, k, v):
+    return flash_attention(q, k, v, causal=True,
+                           interpret=False).astype(jnp.float32).sum()
+
+
+_flash_grads = jax.grad(_flash_sum, argnums=(0, 1, 2))
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
-@pytest.mark.parametrize("shape", [
-    (8, 2048, 32, 128),     # the smoke's: one fetch tile, walked in sub-tiles
-    (4, 4096, 32, 128),     # mistral7b-train-s4096: 2 x 2 tiles, one dead
-    (32, 512, 32, 128),     # mistral7b-train-s512: narrow sub-tiles
-    (2, 4096, 16, 128),     # a chip of deepseek7b-train-s4096-x4
-], ids=lambda s: "x".join(map(str, s)))
-def test_flash_attention_compiles(one_chip, shape, grad):
-    """Sub-tile slices, the masked part's concatenation and the clamped
-    index maps are what Mosaic could refuse."""
-    x = _shape(shape, jnp.bfloat16, one_chip)
+@pytest.mark.parametrize("shape,dv", [
+    ((8, 2048, 32, 128), 128),  # the smoke's: one fetch tile, walked in sub-tiles
+    ((4, 4096, 32, 128), 128),  # mistral7b-train-s4096: 2 x 2 tiles, one dead
+    ((32, 512, 32, 128), 128),  # mistral7b-train-s512: narrow sub-tiles
+    ((2, 4096, 16, 128), 128),  # a chip of deepseek7b-train-s4096-x4
+    XING4_HEADS,                # xing4-train-s8192: q/k 192, v 128, 4 x 4 tiles
+    ((1, 8192, 32, 64), 64),    # granite4h-train-s8192: half a lane block
+], ids=lambda s: "x".join(map(str, s)) if isinstance(s, tuple) else f"v{s}")
+def test_flash_attention_compiles(one_chip, shape, dv, grad):
+    """Sub-tile slices, the masked part's concatenation, the clamped
+    index maps, the dk/dv kernel's row stats, transposed mask and strip
+    loop are what Mosaic could refuse."""
+    fn = _flash_grads if grad else _flash_sum
+    assert _has_kernel(
+        jax.jit(fn).lower(*_flash_shapes(shape, dv, one_chip)).compile())
 
-    def f(q, k, v):
-        return flash_attention(q, k, v, causal=True,
-                               interpret=False).astype(jnp.float32).sum()
 
-    fn = jax.grad(f, argnums=(0, 1, 2)) if grad else f
-    assert _has_kernel(jax.jit(fn).lower(x, x, x).compile())
+def test_flash_backward_lowers_each_kernel_once(one_chip):
+    """A backward pass at Xing4's head sizes holds ONE dk/dv kernel (its
+    interior tile is a loop inside the kernel, not a second call), one dq
+    kernel, and the forward that makes the residuals."""
+    text = jax.jit(_flash_grads).lower(
+        *_flash_shapes(*XING4_HEADS, one_chip)).as_text()
+    assert [text.count(f'kernel_name = "{name}"')
+            for name in ("flash_fwd", "flash_dkv", "flash_dq")] == [1, 1, 1]
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
