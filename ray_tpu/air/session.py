@@ -16,6 +16,7 @@ the iterative Trainable API instead).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Dict, List, Optional
 
 from ray_tpu.air.checkpoint import Checkpoint
@@ -38,16 +39,23 @@ class _TrainSession:
         self.stream_topic = stream_topic
         self.reports: List[Dict[str, Any]] = []
         self.checkpoints: List[Checkpoint] = []
+        self._last_report = time.time()  # the first interval's start
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None):
-        with tracing.span("session.report"):
-            self._report(metrics, checkpoint)
+        with tracing.span("session.report") as s:
+            self._report(metrics, checkpoint, s.start)
 
     def _report(self, metrics: Dict[str, Any],
-                checkpoint: Optional[Checkpoint]):
+                checkpoint: Optional[Checkpoint], now: float):
+        # Auto-filled as the reference's session fills its results
+        # (train/_internal/session.py): the host's clock of every report,
+        # on the clock of the spans (``now`` is this report's span's start).
         entry = dict(metrics)
         entry["_training_iteration"] = len(self.reports)
+        entry["_timestamp"] = now
+        entry["_time_this_iter_s"] = now - self._last_report
+        self._last_report = now
         self.reports.append(entry)
         if checkpoint is not None:
             self.checkpoints.append(checkpoint)

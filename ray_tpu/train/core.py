@@ -26,6 +26,12 @@ from ray_tpu.parallel.mesh import AXIS_DP, AXIS_FSDP, AXIS_PP
 from ray_tpu.parallel.sharding import (
     LogicalAxisRules, named_sharding, sharding_tree,
 )
+from ray_tpu.util import tracing
+
+# A process that builds train steps has JAX: from here on every program
+# it traces, lowers, compiles or loads is a span by function name, and so
+# is every pause of the garbage collector (``tracing.watch_process``).
+tracing.watch_process()
 
 
 # The ``jax.named_scope`` names that between them cover the step program,

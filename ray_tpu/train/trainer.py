@@ -79,8 +79,15 @@ class DataParallelTrainer(BaseTrainer):
         where the time of this call went: per span name (``train.*`` of
         the driver, the head's ``sched.wait`` / ``worker.spawn`` of the
         workers, rank 0's ``train.session_start``, ``train.loop`` and
-        ``session.report``)
-        ``count``, ``total_s``, ``max_s``, ``first_start``, ``last_end``."""
+        ``session.report``; where the loop builds train steps, JAX's
+        ``jax.trace``, ``jax.lower``, ``jax.compile``, ``jax.cache_load``,
+        ``jax.cache_miss`` of every program, and the collector's
+        ``gc.pause``: ``tracing.watch_process``)
+        ``count``, ``total_s``, ``max_s``, ``first_start``, ``last_end``
+        and ``recent``, the ``(start, end)`` of its last 256 spans.  Every
+        entry of ``metrics_history`` carries ``_timestamp`` (the start of
+        its ``session.report``) and ``_time_this_iter_s`` (since the
+        report before it) beside ``_training_iteration``."""
         with tracing.collect() as got, tracing.span("train.fit"):
             result = self._fit()
         got.merge(result.metrics.get("_spans"))

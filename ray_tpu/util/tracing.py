@@ -11,6 +11,11 @@ the task, carried in the task spec with the submit time — so a task's wait
 is ``start - submitted``, a field.  Spans go where task spans always went:
 a worker's buffer, the periodic ``spans`` message, the head's deque,
 ``timeline()``.  The driver records straight into the head's store.
+``record(name, start, end)`` is the same for an interval that is already
+over; ``watch_process()`` turns what JAX reports of its compile pipeline
+(``jax.trace``, ``jax.lower``, ``jax.compile``, ``jax.cache_load``,
+``jax.cache_miss``) and the garbage collector's pauses (``gc.pause``)
+into such spans.
 
 The shared clock with the chip: when ``jax`` is ALREADY imported in the
 process, a span also enters ``jax.profiler.TraceAnnotation(name)``, so under
@@ -28,7 +33,9 @@ part of the step is this op".
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
 import itertools
 import json
 import os
@@ -43,6 +50,10 @@ from ray_tpu._private.api_internal import get_runtime, require_runtime
 _PROCESS = os.urandom(4).hex()  # span ids are unique across processes
 _ids = itertools.count(1)
 _local = threading.local()  # .stack: open span ids; .collectors: _Collected
+# Every collector open in the process, whichever thread opened it: what a
+# ``gc.pause`` is added to, since a collection stops every thread.
+_open_collectors: List["_Collected"] = []
+RECENT = 256  # (start, end) pairs a collector keeps per name
 
 
 def new_id() -> str:
@@ -105,17 +116,40 @@ class span:
         if self._annotation is not None:
             self._annotation.__exit__(*exc_info)
         _stack().pop()
-        for got in getattr(_local, "collectors", ()):
-            got.add(self.name, self.start, end)
-        rt = get_runtime()
-        if rt is not None:
-            if not self.task_id and rt.is_worker() \
-                    and rt.current_task_id is not None:
-                self.task_id = rt.current_task_id.binary()
-            rt.record_span((self.task_id, self.name, self.start, end,
-                            self.kind, self.id, self.parent, self.submitted,
-                            self.args or None))
+        _emit(self.name, self.start, end, self.id, self.parent, self.args,
+              self.task_id, self.kind, self.submitted)
         return False
+
+
+def _emit(name: str, start: float, end: float, sid: str,
+          parent: Optional[str], args: Optional[dict],
+          task_id: bytes = b"", kind: str = "span",
+          submitted: Optional[float] = None) -> None:
+    """A closed span goes to this thread's collectors and to the
+    runtime's store (a worker files it under the task it is running)."""
+    for got in getattr(_local, "collectors", ()):
+        got.add(name, start, end)
+    rt = get_runtime()
+    if rt is not None:
+        if not task_id and rt.is_worker() \
+                and rt.current_task_id is not None:
+            task_id = rt.current_task_id.binary()
+        while _gc_pauses:  # see _gc_phase: filed with the next span
+            began, ended, generation = _gc_pauses.popleft()
+            rt.record_span((task_id, "gc.pause", began, ended, "span",
+                            new_id(), None, None,
+                            {"generation": generation}))
+        rt.record_span((task_id, name, start, end, kind, sid, parent,
+                        submitted, args or None))
+
+
+def record(name: str, start: float, end: float, **args) -> None:
+    """A span reported after the fact: ``start`` and ``end`` are known, on
+    ``time.time()``.  The same record as a ``with span(...)`` block makes
+    (own id, this thread's innermost open span as cause, the task id,
+    collectors, the runtime's store), but no ``TraceAnnotation``: it is
+    over."""
+    _emit(name, start, end, new_id(), current_span(), args)
 
 
 def task_span(task: dict) -> span:
@@ -144,36 +178,51 @@ def span_record(rec: tuple, worker_id: str, node_id: str) -> Dict[str, Any]:
 # ------------------------------------------------------------ summaries --
 
 class _Collected:
-    """Per-name totals of the spans a thread closed while collecting."""
+    """Per-name totals of the spans a thread closed while collecting, and
+    the ``(start, end)`` of each name's last ``RECENT`` spans."""
 
     def __init__(self):
         # Ids of this thread's spans under which a task or an actor was
         # submitted: what add_caused asks the head about.
         self.causes: set = set()
-        self.summary: Dict[str, Dict[str, float]] = {}
+        self._names: Dict[str, Dict[str, Any]] = {}
 
-    def add(self, name: str, start: float, end: float, count: int = 1,
-            total_s: Optional[float] = None, max_s: Optional[float] = None):
-        dur = end - start
-        total_s = dur if total_s is None else total_s
-        max_s = dur if max_s is None else max_s
-        s = self.summary.get(name)
+    def _fold(self, name: str, count: int, total_s: float, max_s: float,
+              first_start: float, last_end: float) -> collections.deque:
+        s = self._names.get(name)
         if s is None:
-            self.summary[name] = {
+            s = self._names[name] = {
                 "count": count, "total_s": total_s, "max_s": max_s,
-                "first_start": start, "last_end": end}
-            return
-        s["count"] += count
-        s["total_s"] += total_s
-        s["max_s"] = max(s["max_s"], max_s)
-        s["first_start"] = min(s["first_start"], start)
-        s["last_end"] = max(s["last_end"], end)
+                "first_start": first_start, "last_end": last_end,
+                "recent": collections.deque(maxlen=RECENT)}
+        else:
+            s["count"] += count
+            s["total_s"] += total_s
+            s["max_s"] = max(s["max_s"], max_s)
+            s["first_start"] = min(s["first_start"], first_start)
+            s["last_end"] = max(s["last_end"], last_end)
+        return s["recent"]
 
-    def merge(self, summary: Optional[Dict[str, Dict[str, float]]]):
+    def add(self, name: str, start: float, end: float):
+        dur = end - start
+        self._fold(name, 1, dur, dur, start, end).append((start, end))
+
+    def merge(self, summary: Optional[Dict[str, Dict[str, Any]]]):
         """Fold in another summary (a worker session's)."""
         for name, s in (summary or {}).items():
-            self.add(name, s["first_start"], s["last_end"], s["count"],
-                     s["total_s"], s["max_s"])
+            recent = self._fold(name, s["count"], s["total_s"], s["max_s"],
+                                s["first_start"], s["last_end"])
+            both = sorted([*recent, *map(tuple, s.get("recent", ()))])
+            recent.clear()
+            recent.extend(both)  # the deque keeps the newest RECENT
+
+    @property
+    def summary(self) -> Dict[str, Dict[str, Any]]:
+        """name -> ``count``, ``total_s``, ``max_s``, ``first_start``,
+        ``last_end`` and ``recent``, a list of ``(start, end)``, oldest
+        first."""
+        return {name: dict(s, recent=list(s["recent"]))
+                for name, s in list(self._names.items())}
 
     def add_caused(self):
         """Fold in the head's own spans that one of OUR spans caused:
@@ -189,14 +238,150 @@ class _Collected:
 
 @contextlib.contextmanager
 def collect():
-    """Summarise every span this thread closes inside the block."""
+    """Summarise every span this thread closes inside the block (and
+    every ``gc.pause`` of the process: it stops this thread too)."""
     got = _Collected()
     active = _local.__dict__.setdefault("collectors", [])
     active.append(got)
+    _open_collectors.append(got)
     try:
         yield got
     finally:
+        _open_collectors.remove(got)
         active.remove(got)
+
+
+# ------------------------------------ JAX's pipeline and the collector --
+
+# jax.monitoring's events (jax 0.9, ``jax/_src/dispatch.py``) -> span name.
+_JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_JAX_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAX_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+GC_PAUSE_MIN_S = 1e-3  # a younger generation's collection is a span from here
+
+
+def _jax_open(event: str, start: float, fun_name: str = "", **_):
+    """Scalar listener: ``log_elapsed_time.__enter__`` reports each
+    pipeline stage's start.  A stage takes an id and opens — so what runs
+    inside it (a cache load, an eager op's own compile) is its child —
+    unless it only deepens one that is open: an inner ``jit``'s trace
+    inside its caller's, or the trace of a helper that a lowering rule
+    calls (lowering's time, hundreds of them a Pallas kernel)."""
+    name = _JAX_SPANS.get(event)
+    if name is None:
+        return
+    opened = _local.__dict__.setdefault("jax_open", [])
+    outer = [e[0] for e in opened if e[1] is not None]
+    if name in outer or (name == "jax.trace" and "jax.lower" in outer):
+        opened.append((name, None, None, fun_name))
+        return
+    stack = _stack()
+    sid = new_id()
+    opened.append((name, sid, stack[-1] if stack else None, fun_name))
+    stack.append(sid)
+
+
+def _jax_close(event: str, start: float, end: float, fun_name: str = "",
+               **_):
+    """Time-span listener: both ends are ``time.time()``'s."""
+    name = _JAX_SPANS.get(event)
+    if name is None:
+        return
+    opened = _local.__dict__.get("jax_open")
+    if not opened or opened[-1][0] != name:
+        # Opened before watch_process(): nothing was kept of its start.
+        record(name, start, end, fun=fun_name)
+        return
+    _, sid, parent, _ = opened.pop()
+    if sid is None:
+        return
+    stack = _stack()
+    if sid in stack:
+        stack.remove(sid)
+    _emit(name, start, end, sid, parent, {"fun": fun_name})
+
+
+def _compiling() -> Dict[str, str]:
+    """``fun=`` of the ``jax.compile`` open on this thread, if one is."""
+    for name, sid, _, fun_name in reversed(
+            _local.__dict__.get("jax_open", ())):
+        if name == "jax.compile" and sid is not None:
+            return {"fun": fun_name}
+    return {}
+
+
+def _jax_duration(event: str, duration: float, **_):
+    if event == _JAX_CACHE_LOAD:  # the read ends where JAX reports it
+        end = time.time()
+        record("jax.cache_load", end - duration, end, **_compiling())
+
+
+def _jax_event(event: str, **_):
+    if event == _JAX_CACHE_MISS:  # an event is a span of no length
+        now = time.time()
+        record("jax.cache_miss", now, now, **_compiling())
+
+
+# The collection under way: (its TraceAnnotation or None, its start).
+# Collections are serialised and both callbacks of one run on the
+# collecting thread, so one slot does.
+_gc_started: Tuple[Any, float] = (None, 0.0)
+# Pauses the store has not seen yet: (start, end, generation).
+_gc_pauses: collections.deque = collections.deque(maxlen=1024)
+
+
+def _gc_phase(phase: str, info: Dict[str, int]):
+    """``gc.callbacks`` entry.  It may run at any allocation, inside code
+    that holds the runtime's locks: so it takes none.  A pause goes to
+    the collectors here and to the store with the next span this process
+    closes (``_emit``)."""
+    global _gc_started
+    if phase == "start":
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        annotation = None
+        if profiler is not None:  # on the /host:CPU plane, under a trace
+            annotation = profiler.TraceAnnotation(
+                "gc.pause", generation=info["generation"])
+            annotation.__enter__()
+        _gc_started = (annotation, time.time())
+        return
+    end = time.time()
+    annotation, start = _gc_started
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+    if info["generation"] < 2 and end - start < GC_PAUSE_MIN_S:
+        return
+    # The pause stopped every thread: every open collector takes it, the
+    # one place where a collector is not thread-local.
+    for got in list(_open_collectors):
+        got.add("gc.pause", start, end)
+    _gc_pauses.append((start, end, info["generation"]))
+
+
+def watch_process() -> None:
+    """From here on this process records, as spans: JAX's compile
+    pipeline by function — ``jax.trace``, ``jax.lower``, ``jax.compile``
+    (``fun=``; only the outermost of its kind on a thread, and no trace
+    that a lowering makes, so the totals add up), ``jax.cache_load``
+    inside the ``jax.compile`` that read the persistent cache,
+    ``jax.cache_miss`` (no length) — and ``gc.pause`` (``generation=``)
+    for every collection of generation 2 and any that took over
+    ``GC_PAUSE_MIN_S``.  Idempotent.  For a process that has imported JAX
+    already (``train/core.py`` calls it): a driver that must stay off the
+    chip never comes here."""
+    if _gc_phase in gc.callbacks:
+        return
+    from jax import monitoring
+
+    monitoring.register_scalar_listener(_jax_open)
+    monitoring.register_event_time_span_listener(_jax_close)
+    monitoring.register_event_duration_secs_listener(_jax_duration)
+    monitoring.register_event_listener(_jax_event)
+    gc.callbacks.append(_gc_phase)
 
 
 # ------------------------------------------------------- head-side reads --

@@ -217,7 +217,9 @@ def test_fit_returns_its_spans():
         ray.shutdown()
     assert result.error is None
     spans = result.metrics["_spans"]
-    assert set(spans) == {
+    # (a test before this one may have made this process watch its
+    # collector: a pause during fit() is then a span of the driver's too)
+    assert set(spans) - {"gc.pause"} == {
         "train.fit", "train.placement_group", "train.start_workers",
         "train.backend_start", "train.run", "train.shutdown",
         "sched.wait", "worker.spawn", "train.session_start", "train.loop",
@@ -228,7 +230,7 @@ def test_fit_returns_its_spans():
     assert "_spans" not in result.metrics_history[-1]
     for name, s in spans.items():
         assert set(s) == {"count", "total_s", "max_s", "first_start",
-                          "last_end"}, name
+                          "last_end", "recent"}, name
         assert 0 <= s["max_s"] <= s["total_s"], name
         assert s["first_start"] <= s["last_end"], name
     fit = spans["train.fit"]
@@ -240,7 +242,286 @@ def test_fit_returns_its_spans():
     assert spans["worker.spawn"]["last_end"] <= inside["last_end"]
     assert spans["train.loop"]["total_s"] <= spans["train.run"]["total_s"]
     import pickle
-    assert len(pickle.dumps(spans)) < 1024
+    assert len(pickle.dumps(spans)) < 2048  # 13 (start, end) pairs in it
+
+
+# ------------------- spans after the fact, JAX's pipeline, the collector --
+
+def test_record_reaches_collectors_and_head_with_its_cause(init2):
+    from ray_tpu.util import tracing
+
+    with tracing.collect() as got:
+        with tracing.span("probe.outer") as outer:
+            tracing.record("probe.past", 100.0, 100.5, what="x")
+        tracing.record("probe.past", 101.0, 101.25)
+    past = got.summary["probe.past"]
+    assert past["count"] == 2 and past["total_s"] == 0.75
+    assert past["max_s"] == 0.5
+    assert past["recent"] == [(100.0, 100.5), (101.0, 101.25)]
+    first, second = [s for s in get_task_spans()
+                     if s["name"] == "probe.past"]
+    assert (first["start"], first["end"]) == (100.0, 100.5)
+    assert first["parent"] == outer.id and first["args"] == {"what": "x"}
+    assert first["worker_id"] == "driver" and first["kind"] == "span"
+    assert second["parent"] is None and "args" not in second
+    assert len({first["span_id"], second["span_id"], outer.id}) == 3
+
+
+def test_jax_pipeline_is_spans_by_function(init2):
+    """A nested ``jit`` compiled under ``collect()``: one ``jax.trace``,
+    ``jax.lower``, ``jax.compile`` for the outer function — the inner
+    ``jit``'s trace, which JAX reports too, is inside the outer's and is
+    not counted again; watching twice records once."""
+    import jax
+    import jax.numpy as jnp
+    from jax import monitoring
+
+    from ray_tpu.util import tracing
+
+    tracing.watch_process()
+    tracing.watch_process()
+
+    @jax.jit
+    def probe_inner(x):
+        return jnp.sin(x) * 2
+
+    def probe_step(x):
+        return probe_inner(x) + probe_inner(x * 2)
+
+    traced = []
+
+    def on_span(event, start, end, fun_name="", **_):
+        if event.endswith("/jaxpr_trace_duration"):
+            traced.append((fun_name, start, end))
+
+    x = jnp.ones((8, 8))  # its own eager programs: before collecting
+    monitoring.register_event_time_span_listener(on_span)
+    try:
+        with tracing.collect() as got:
+            with tracing.span("probe.compiling") as outer:
+                jax.jit(probe_step).lower(x).compile()
+    finally:
+        monitoring.unregister_event_time_span_listener(on_span)
+    # JAX reported the inner jit's trace (and jnp's own jits'), each
+    # inside the outer function's, which ends last.
+    assert "probe_inner" in [t[0] for t in traced[:-1]]
+    fun, start, end = traced[-1]
+    assert fun == "probe_step"
+    assert all(start <= a <= b <= end for _, a, b in traced)
+    summary = got.summary
+    for name in ("jax.trace", "jax.lower", "jax.compile"):
+        assert summary[name]["count"] == 1, name
+    assert summary["jax.trace"]["recent"] == [(start, end)]
+    assert summary["jax.trace"]["total_s"] == end - start
+    assert "jax.cache_load" not in summary  # no persistent cache here
+    mine = {s["name"]: s for s in get_task_spans()
+            if s["parent"] == outer.id}
+    assert mine["jax.trace"]["args"] == {"fun": "probe_step"}
+    assert mine["jax.lower"]["args"] == {"fun": "jit(probe_step)"}
+    assert mine["jax.compile"]["args"] == {"fun": "jit(probe_step)"}
+    assert set(mine) == {"jax.trace", "jax.lower", "jax.compile"}
+
+    # A helper that a lowering rule traces is lowering's time: JAX's own
+    # events, as ``log_elapsed_time`` sends them, with nothing compiled.
+    trace, lower = ("/jax/core/compile/jaxpr_trace_duration",
+                    "/jax/core/compile/jaxpr_to_mlir_module_duration")
+    with tracing.collect() as got:
+        monitoring.record_scalar(lower, 10.0, fun_name="jit(f)")
+        monitoring.record_scalar(trace, 11.0, fun_name="add")
+        monitoring.record_event_time_span(trace, 11.0, 12.0, fun_name="add")
+        monitoring.record_event_time_span(lower, 10.0, 14.0,
+                                          fun_name="jit(f)")
+        # ... and one whose start nobody saw is recorded as it is
+        monitoring.record_event_time_span(trace, 20.0, 21.0, fun_name="g")
+    assert got.summary["jax.lower"]["recent"] == [(10.0, 14.0)]
+    assert got.summary["jax.trace"]["recent"] == [(20.0, 21.0)]
+    assert tracing.current_span() is None
+
+
+def test_jax_cache_load_and_miss_are_children_of_the_compile(init2,
+                                                             tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from ray_tpu.util import tracing
+
+    tracing.watch_process()
+    settings = {"jax_compilation_cache_dir": str(tmp_path),
+                "jax_persistent_cache_min_compile_time_secs": 0,
+                "jax_persistent_cache_min_entry_size_bytes": -1}
+    before = {k: getattr(jax.config, k) for k in settings}
+
+    def probe_cached(x):
+        return jnp.cos(x) + 3
+
+    x = jnp.ones((4, 4))
+    try:
+        for k, v in settings.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+        with tracing.collect() as got:
+            jax.jit(probe_cached).lower(x).compile()  # a miss, written
+            jax.clear_caches()
+            jax.jit(probe_cached).lower(x).compile()  # read back
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    summary = got.summary
+    assert summary["jax.compile"]["count"] == 2
+    assert summary["jax.cache_miss"]["count"] == 1
+    assert summary["jax.cache_miss"]["total_s"] == 0.0
+    assert summary["jax.cache_load"]["count"] == 1
+    spans = [s for s in get_task_spans()
+             if (s.get("args") or {}).get("fun") == "jit(probe_cached)"]
+    compiles = [s for s in spans if s["name"] == "jax.compile"]
+    miss, = [s for s in spans if s["name"] == "jax.cache_miss"]
+    load, = [s for s in spans if s["name"] == "jax.cache_load"]
+    assert miss["parent"] == compiles[0]["span_id"]
+    assert load["parent"] == compiles[1]["span_id"]
+    assert compiles[1]["start"] <= load["start"] <= load["end"] \
+        <= compiles[1]["end"]
+
+
+def test_recent_is_bounded_ordered_and_survives_merge():
+    from ray_tpu.util import tracing
+
+    got = tracing._Collected()
+    for i in range(300):
+        got.add("probe.many", float(i), i + 0.5)
+    many = got.summary["probe.many"]
+    assert many["count"] == 300 and tracing.RECENT == 256
+    assert many["recent"] == [(float(i), i + 0.5) for i in range(44, 300)]
+    assert isinstance(many["recent"], list)
+
+    into = tracing._Collected()
+    into.add("probe.many", 500.0, 501.0)
+    into.add("probe.other", 1.0, 2.0)
+    into.merge(got.summary)
+    into.merge({"probe.old": {  # a summary from before ``recent``
+        "count": 2, "total_s": 3.0, "max_s": 2.0, "first_start": 0.0,
+        "last_end": 9.0}})
+    merged = into.summary
+    assert merged["probe.many"]["count"] == 301
+    assert merged["probe.many"]["total_s"] == 151.0
+    recent = merged["probe.many"]["recent"]
+    assert len(recent) == 256 and recent == sorted(recent)
+    assert recent[0] == (45.0, 45.5) and recent[-1] == (500.0, 501.0)
+    assert merged["probe.other"]["recent"] == [(1.0, 2.0)]
+    assert merged["probe.old"]["recent"] == []
+    assert merged["probe.old"]["count"] == 2
+
+
+def test_every_report_carries_the_hosts_clock():
+    """``_timestamp`` and ``_time_this_iter_s`` in every report, and the
+    same clock in ``_spans["session.report"]["recent"]``, which is what a
+    reader of the LAST report has of the earlier ones."""
+    from ray_tpu.air import session
+    from ray_tpu.air.config import ScalingConfig
+    from ray_tpu.train import JaxTrainer
+
+    def loop(config):
+        for i in range(5):
+            time.sleep(0.01 * (i + 1))
+            session.report({"i": i})
+
+    ray.init(num_cpus=2, ignore_reinit_error=True)
+    try:
+        result = JaxTrainer(
+            loop, scaling_config=ScalingConfig(num_workers=1)).fit()
+    finally:
+        ray.shutdown()
+    assert result.error is None
+    history = result.metrics_history
+    recent = result.metrics["_spans"]["session.report"]["recent"]
+    assert len(recent) == 5 == len(history)
+    starts = [start for start, _ in recent]
+    assert starts == [r["_timestamp"] for r in history]
+    assert all(start <= end for start, end in recent)
+    assert [r["_training_iteration"] for r in history] == list(range(5))
+    for i in range(1, 5):
+        assert starts[i] - starts[i - 1] == history[i]["_time_this_iter_s"]
+        assert history[i]["_time_this_iter_s"] >= 0.01 * (i + 1)
+    # The first: since the session began, inside train.session_start.
+    began = result.metrics["_spans"]["train.session_start"]
+    first = starts[0] - history[0]["_time_this_iter_s"]
+    assert began["first_start"] <= first <= began["last_end"]
+    assert result.metrics["_timestamp"] == starts[-1]
+
+
+def test_gc_pause_is_a_span_of_full_and_of_slow_collections(init2):
+    import gc
+
+    import jax  # noqa: F401 — watch_process is for a process with JAX
+
+    from ray_tpu.util import tracing
+
+    tracing.watch_process()
+    tracing.watch_process()
+    assert gc.callbacks.count(tracing._gc_phase) == 1
+    gc.collect()  # leave little for the collections under test
+    was = gc.isenabled()
+    gc.disable()  # no collection of the interpreter's own choosing
+    try:
+        with tracing.collect() as got:
+            gc.collect(0)  # young and fast: two callback calls, no span
+            assert "gc.pause" not in got.summary
+            before = time.time()
+            gc.collect()
+            after = time.time()
+            with tracing.span("probe.next"):
+                pass  # the store sees a pause with the next span closed
+    finally:
+        if was:
+            gc.enable()
+    pause = got.summary["gc.pause"]
+    assert pause["count"] == 1
+    (start, end), = pause["recent"]
+    assert before <= start <= end <= after
+    stored = [s for s in get_task_spans() if s["name"] == "gc.pause"
+              and s["start"] == start]
+    assert len(stored) == 1 and stored[0]["end"] == end
+    assert stored[0]["args"] == {"generation": 2}
+
+
+def test_gc_pause_reaches_every_open_collector():
+    """A collection stops every thread: one forced on a second thread is
+    in the first thread's ``collect()`` — the one span that is."""
+    import gc
+    import threading
+
+    import jax  # noqa: F401
+
+    from ray_tpu.util import tracing
+
+    tracing.watch_process()
+    theirs = {}
+
+    def other():
+        with tracing.collect() as got:
+            with tracing.span("probe.theirs"):
+                gc.collect()
+        theirs.update(got.summary)
+
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        with tracing.collect() as mine:
+            t = threading.Thread(target=other)
+            t.start()
+            t.join(timeout=30)
+            assert not t.is_alive()
+        with tracing.collect() as later:
+            pass
+    finally:
+        if was:
+            gc.enable()
+    assert mine.summary["gc.pause"]["count"] == 1
+    assert mine.summary["gc.pause"]["recent"] \
+        == theirs["gc.pause"]["recent"]
+    assert "probe.theirs" not in mine.summary  # spans stay thread-local
+    assert "gc.pause" not in later.summary
 
 
 # ------------------------------------- device trace -> scope and phase --
