@@ -37,9 +37,9 @@ TPU-first choices:
   rotary part, the k's shared by all heads] and wider than its v; the flash
   kernels take the two head sizes.
 - the n-stream residual (manifold-constrained hyper-connections,
-  arXiv:2512.24880 §4): the scan carries ``(b, s, n * d)``, and every block
-  reads a learned mix of the streams and writes back through two more maps,
-  made per token from the normed streams (``hc_map``, ``hc_mix``).
+  arXiv:2512.24880 §4): the scan carries ``(b, s, n * d)``; a block's two
+  halves (its maps and input: ``hc_map``; the write back: ``hc_mix``) are
+  ``ops/streams.py``'s operations, one read of the streams a pass each.
 - one chip's share of a layer (``experts_held``, ``first_expert``): the
   router keeps its published width, the expert tensors hold the experts
   that live here, and what the absent ones would add is left out.
@@ -62,13 +62,13 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops import attention, moe
+from ray_tpu.ops import attention, moe, streams
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
 from ray_tpu.ops.layers import (
-    rms_norm, rope, apply_rope, swiglu, repeat_kv_heads, sinkhorn,
-    yarn_inv_freq, yarn_mscale,
+    rms_norm, rope, apply_rope, swiglu, repeat_kv_heads, yarn_inv_freq,
+    yarn_mscale,
 )
 from ray_tpu.ops.moe import moe_block, update_selection_bias
 from ray_tpu.ops.ssm import causal_conv1d, gated_rms_norm, ssd_chunked
@@ -939,7 +939,26 @@ _FFNS = {"dense": _dense_ffn, "moe": _moe_ffn}
 # The streams lie side by side, ``(b, s, n * d)``: stream j is the columns
 # j * d .. (j + 1) * d, so ``vec X`` is the array as it lies and every
 # slice starts on a lane tile.  (A (b, s, n, d) array would pad its n = 4
-# rows to a 16-row tile.)  The maps are kept with the TOKENS minor.
+# rows to a 16-row tile.)
+#
+# Round every block the streams are read and written ONCE a pass, in their
+# own dtype (``ops/streams.py``): ``streams_read`` makes a token's maps and
+# the block's input from one read, ``streams_write`` writes the streams
+# back from one read of them and of the block's output, and each has its
+# backward pass written out — what goes round the block (``res^T dX'``)
+# reaches ``streams_read``'s backward as a cotangent of the streams it
+# handed on, and is added where ``dX`` is written.  No float32 or normed
+# copy of the ``(tokens, n d)`` streams exists in memory in any pass
+# (autodiff of the plain sums made four in the norm's gradient alone: 59
+# of a step's 824 ms in ``xing4-train-s8192``, PERF.md §6, PR 37).  Where
+# the shapes fit (``streams.kernels_fit``: d in whole lane blocks, tokens
+# in tiles of 128, no mesh) the four bodies are Pallas kernels
+# (``hc_read_fwd``, ``hc_read_bwd``, ``hc_write_fwd``, ``hc_write_bwd``);
+# elsewhere the same sums as plain XLA under the same ``custom_vjp``.
+# The layer checkpoint keeps nothing of either half: the rematerialised
+# forward runs ``streams_read`` again (it also hands out the token's
+# ``r (X proj)`` and ``r``, all its backward needs beside its arguments)
+# and, of a layer's two blocks, the first one's ``streams_write``.
 
 def _to_streams(x, cfg: LlamaConfig):
     """The embedded tokens copied to every stream (arXiv:2409.19606 §3)."""
@@ -960,58 +979,36 @@ def _from_streams(xs, cfg: LlamaConfig):
                    for j in range(cfg.hc_mult)).astype(cfg.dtype)
 
 
-def _hc_maps(xs, lp, block: str, cfg: LlamaConfig):
-    """The three maps of one block for every token, float32 with the
-    tokens minor: ``pre (n, b, s, 1)`` (what the block reads of each
-    stream), ``post (n, b, s, 1)`` (what each stream takes of the block's
-    output) and ``res (n, n, b, s, 1)`` (stream i's share of stream j,
-    rows and columns summing to 1).  The streams are normed as ONE vector
-    of n * d (no learned weight), projected by one matrix to [pre | post |
-    res], scaled, biased; pre through a sigmoid, post through twice a
-    sigmoid, res clipped and through ``sinkhorn``."""
-    n, f32 = cfg.hc_mult, jnp.float32
-    b, s, width = xs.shape
-    x32 = xs.astype(f32)
-    normed = (x32 * jax.lax.rsqrt(
-        jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        + cfg.norm_eps)).astype(cfg.dtype)
-    raw = jnp.dot(normed.reshape(b * s, width),
-                  lp[f"hc_{block}_proj"].astype(cfg.dtype),
-                  preferred_element_type=f32).T          # (2n + n*n, T)
-    # one scale each for [pre | post | res], laid over the columns: ONE
-    # multiply-add over the whole array (scaled slice by slice, the step
-    # program grew by 0.43 GB at 8192 tokens: PERF.md §6, PR 34)
-    scale = jnp.repeat(lp[f"hc_{block}_scale"].astype(f32),
-                       np.array([n, n, n * n]))
-    raw = raw * scale[:, None] + lp[f"hc_{block}_bias"].astype(f32)[:, None]
-    pre = jax.nn.sigmoid(raw[:n])
-    post = 2.0 * jax.nn.sigmoid(raw[n:2 * n])
-    res = sinkhorn(jnp.clip(raw[2 * n:].reshape(n, n, b * s),
-                            cfg.hc_clamp_min, cfg.hc_clamp_max),
-                   cfg.hc_sinkhorn_iters, cfg.hc_eps)
-    return (pre.reshape(n, b, s, 1), post.reshape(n, b, s, 1),
-            res.reshape(n, n, b, s, 1))
+def _hc_plan(xs, cfg: LlamaConfig, kernels: bool):
+    """What is static in a block's two halves (``streams.Plan``): the
+    structure's numbers off the configuration, and the form off the
+    shapes — ``kernels`` False (under a mesh, inside a manual region)
+    keeps the XLA form whatever they are."""
+    return streams.plan_for(
+        xs, cfg.hc_mult, norm_eps=cfg.norm_eps,
+        clamp=(cfg.hc_clamp_min, cfg.hc_clamp_max),
+        iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+        form=None if kernels else "xla")
 
 
-def _hc_block(xs, lp, block: str, cfg: LlamaConfig, fn):
+def _hc_block(xs, lp, block: str, fn, cfg: LlamaConfig, kernels=True):
     """One block ``fn(x) -> (y, rest)`` on the streams ``xs``: ``X' = res
-    X + post^T fn(pre X)``; returns ``(X', rest)``.  Scopes ``hc_map`` (the
-    maps) and ``hc_mix`` (reading the block's input off the streams and
-    writing its output back); the block opens its own between them."""
-    n, f32 = cfg.hc_mult, jnp.float32
+    X + post^T fn(pre X)``; returns ``(X', rest)``.  Before the block
+    (scope ``hc_map``, ``streams.streams_read``) the token's maps — the
+    streams normed as ONE vector of n d (no learned weight), projected by
+    one matrix to [pre | post | res], scaled, biased; pre through a
+    sigmoid, post through twice a sigmoid, res clipped and through the
+    Sinkhorn rounds — and the block's input ``x = pre X``; after it (scope
+    ``hc_mix``, ``streams.streams_write``) the write back.  The block
+    opens its own scopes between them."""
+    plan = _hc_plan(xs, cfg, kernels)
     with jax.named_scope("hc_map"):
-        pre, post, res = _hc_maps(xs, lp, block, cfg)
-    with jax.named_scope("hc_mix"):
-        x = sum(pre[j] * _stream(xs, j, cfg) for j in range(n)).astype(
-            cfg.dtype)
+        x, maps, xs = streams.streams_read(
+            plan, xs, lp[f"hc_{block}_proj"], lp[f"hc_{block}_scale"],
+            lp[f"hc_{block}_bias"])
     y, rest = fn(x)
     with jax.named_scope("hc_mix"):
-        y32 = y.astype(f32)
-        out = jnp.concatenate([
-            (post[i] * y32 + sum(res[i, j] * _stream(xs, j, cfg)
-                                 for j in range(n))).astype(cfg.dtype)
-            for i in range(n)], axis=-1)
-    return out, rest
+        return streams.streams_write(plan, xs, y, maps), rest
 
 
 def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
@@ -1031,6 +1028,9 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
     """
     cst = _make_cst(mesh, rules)
     mix, ffn = _MIXERS[kind[0]], _FFNS[kind[1]]
+    # the streams' kernels take one chip's whole arrays (ops/streams.py)
+    hc = functools.partial(_hc_block, cfg=cfg,
+                           kernels=mesh is None and not sp_manual)
 
     def layer_fn(carry, lp):
         x, aux = carry
@@ -1040,14 +1040,14 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
 
     def streams_layer_fn(carry, lp):
         xs, aux = carry
-        xs, _ = _hc_block(xs, lp, "attn", cfg, lambda x: (
+        xs, _ = hc(xs, lp, "attn", lambda x: (
             mix(x, lp, cfg, mesh, cst, sp_manual, residual=False), None))
 
         def ffn_block(x):
             y, aux_, out = ffn(x, aux, lp, cfg, mesh, cst, residual=False)
             return y, (aux_, out)
 
-        xs, (aux, out) = _hc_block(xs, lp, "ffn", cfg, ffn_block)
+        xs, (aux, out) = hc(xs, lp, "ffn", ffn_block)
         return (xs, aux), out
 
     return layer_fn if cfg.hc_mult == 1 else streams_layer_fn

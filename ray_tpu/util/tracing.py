@@ -478,8 +478,10 @@ PHASES = ("forward", "remat", "backward", "optimizer")
 # writing its gradients into the stacked gradients, the loop itself.
 SCAN = "scan"
 # How the ``name=`` of the program's Pallas kernels start (ops/attention.py,
-# ops/moe.py, ops/ssm.py): the kernel rows of ``step_breakdown``.
-KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm", "ssd_")
+# ops/moe.py, ops/ssm.py, ops/streams.py): the kernel rows of
+# ``step_breakdown``.  A step scope that starts the same way (``hc_map``,
+# ``hc_mix``) is no kernel's name.
+KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm", "ssd_", "hc_")
 _SCOPE_TOKENS = re.compile(r"[^/()]+")
 
 
@@ -649,7 +651,8 @@ def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
                 row[phase] = row.get(phase, 0) + self_ns
             if 'custom_call_target="tpu_custom_call"' in text:
                 kernel = next((t for t in _SCOPE_TOKENS.findall(stack)
-                               if t.startswith(KERNEL_NAMES)), "unnamed")
+                               if t.startswith(KERNEL_NAMES)
+                               and t not in scopes), "unnamed")
                 key = kernel + (".remat" if phase == "remat" else "")
                 kernels[key] = kernels.get(key, 0) + self_ns
                 kernel_calls[key] = kernel_calls.get(key, 0) + 1

@@ -451,3 +451,201 @@ def test_the_new_scopes_are_step_scopes_and_open_in_the_program():
     for scope in ("hc_map", "hc_mix", "mtp_in", "attn_qkv", "moe_experts",
                   "ffn"):
         assert f"/{scope}/" in text or f"{scope}/" in text, scope
+
+
+# -- the two halves of a block as operations (ops/streams.py) ----------------
+
+HC = dict(norm_eps=1e-6, clamp=(-30.0, 30.0), iters=20, eps=1e-6)
+
+
+def _plain_maps(xs, proj, scale, bias, n):
+    """``_hc_maps`` as it stood before the operations (PR 34): plain sums
+    that autodiff differentiates.  ``xs (T, n d)``; tokens minor."""
+    x32 = xs.astype(jnp.float32)
+    normed = (x32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        + HC["norm_eps"])).astype(xs.dtype)
+    # the rounded operands widened: the same products and float32 sums as
+    # the model's bfloat16 x bfloat16 -> float32, whose gradient the CPU's
+    # compiler refuses
+    raw = jnp.dot(normed.astype(jnp.float32),
+                  proj.astype(xs.dtype).astype(jnp.float32)).T
+    raw = (raw * jnp.repeat(scale, np.array([n, n, n * n]))[:, None]
+           + bias[:, None])
+    res = sinkhorn(jnp.clip(raw[2 * n:].reshape(n, n, -1), *HC["clamp"]),
+                   HC["iters"], HC["eps"])
+    return jax.nn.sigmoid(raw[:n]), 2.0 * jax.nn.sigmoid(raw[n:2 * n]), res
+
+
+def _plain_read(xs, proj, scale, bias, n):
+    """-> ``x`` and the maps as ``streams.maps_of`` gives them."""
+    pre, post, res = _plain_maps(xs, proj, scale, bias, n)
+    d = xs.shape[-1] // n
+    x = sum(pre[j][:, None] * xs[:, j * d:(j + 1) * d].astype(jnp.float32)
+            for j in range(n)).astype(xs.dtype)
+    return x, (pre.T, post.T, jnp.moveaxis(res, -1, 0))
+
+
+def _plain_write(xs, y, post, res, n):
+    """``_hc_block``'s second half as it stood; ``post (T, n)``, ``res
+    (T, n, n)``."""
+    d = xs.shape[-1] // n
+    xj = [xs[:, j * d:(j + 1) * d].astype(jnp.float32) for j in range(n)]
+    return jnp.concatenate([
+        (post[:, i, None] * y.astype(jnp.float32)
+         + sum(res[:, i, j, None] * xj[j] for j in range(n))
+         ).astype(xs.dtype) for i in range(n)], axis=-1)
+
+
+def _stream_case(n, d, tokens, seed=0, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    maps = n * (2 + n)
+    return dict(
+        xs=(draw(tokens, n * d) * (1 + draw(tokens, 1) ** 2)).astype(dtype),
+        y=draw(tokens, d).astype(dtype),
+        # wide enough for the rounds to matter and the clip to bite nowhere
+        proj=draw(n * d, maps) / np.sqrt(n * d) * 2.0,
+        scale=jnp.asarray([0.7, 1.3, 1.1], jnp.float32),
+        bias=draw(maps) * 0.5,
+        w_x=draw(tokens, d), w_out=draw(tokens, n * d),
+        w_pre=draw(tokens, n), w_post=draw(tokens, n),
+        w_res=draw(tokens, n, n))
+
+
+def _plan(case, n, form, tile):
+    from ray_tpu.ops import streams
+    return streams.plan_for(case["xs"], n, form=form, tile=tile, **HC)
+
+
+STREAM_SHAPES = [
+    # n, d, tokens, tile: several tiles; one tile; two streams
+    (4, 256, 512, 128), (4, 128, 256, 256), (2, 128, 384, 128),
+    (2, 640, 128, 128)]
+
+
+@pytest.mark.parametrize("form", ["kernels", "xla"])
+@pytest.mark.parametrize("n,d,tokens,tile", STREAM_SHAPES)
+def test_streams_read_equals_the_plain_sums_and_their_gradients(
+        n, d, tokens, tile, form):
+    """``x``, the maps, and the gradients to the streams, the projection,
+    the three scales and the bias of a weighted sum of ALL its outputs
+    (the streams handed on included), against autodiff of the plain form."""
+    from ray_tpu.ops import streams
+    c = _stream_case(n, d, tokens)
+    plan = _plan(c, n, form, tile)
+    assert plan.tile == (tile if form == "kernels" else 0)
+
+    def weighed(x, pre, post, res, handed):
+        return (jnp.sum(c["w_x"] * x) + jnp.sum(c["w_pre"] * pre)
+                + jnp.sum(c["w_post"] * post) + jnp.sum(c["w_res"] * res)
+                + jnp.sum(c["w_out"] * handed))
+
+    def ours(xs, proj, scale, bias):
+        x, maps, handed = streams.streams_read(plan, xs, proj, scale, bias)
+        return weighed(x, *streams.maps_of(maps, n), handed), (x, maps)
+
+    def plain(xs, proj, scale, bias):
+        x, maps = _plain_read(xs, proj, scale, bias, n)
+        return weighed(x, *maps, xs), (x, maps)
+
+    args = (c["xs"], c["proj"], c["scale"], c["bias"])
+    (_, (x, maps)), got = jax.jit(jax.value_and_grad(
+        ours, argnums=(0, 1, 2, 3), has_aux=True))(*args)
+    (_, (want_x, want_maps)), want = jax.jit(jax.value_and_grad(
+        plain, argnums=(0, 1, 2, 3), has_aux=True))(*args)
+    np.testing.assert_allclose(x, want_x, rtol=2e-5, atol=2e-5)
+    for ours_, theirs in zip(streams.maps_of(maps, n), want_maps):
+        np.testing.assert_allclose(ours_, theirs, rtol=2e-5, atol=2e-6)
+    # what no map's number lies in stays zero
+    assert float(jnp.sum(jnp.abs(maps))) == pytest.approx(float(sum(
+        jnp.sum(jnp.abs(m)) for m in want_maps)), rel=1e-5)
+    for name, g, w in zip(("xs", "proj", "scale", "bias"), got, want):
+        scale_ = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=2e-5 * scale_,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("form", ["kernels", "xla"])
+@pytest.mark.parametrize("n,d,tokens,tile", STREAM_SHAPES)
+def test_streams_write_equals_the_plain_sums_and_their_gradients(
+        n, d, tokens, tile, form):
+    """``X'`` and the gradients to the streams (what goes round the block),
+    to the block's output and to the maps."""
+    from ray_tpu.ops import streams
+    c = _stream_case(n, d, tokens, seed=1)
+    plan = _plan(c, n, form, tile)
+    _, maps, _ = streams.streams_read(plan, c["xs"], c["proj"], c["scale"],
+                                      c["bias"])
+    pre, post, res = streams.maps_of(maps, n)
+
+    def ours(xs, y, maps):
+        out = streams.streams_write(plan, xs, y, maps)
+        return jnp.sum(c["w_out"] * out), out
+
+    def plain(xs, y, post, res):
+        out = _plain_write(xs, y, post, res, n)
+        return jnp.sum(c["w_out"] * out), out
+
+    (_, out), (dxs, dy, dmaps) = jax.jit(jax.value_and_grad(
+        ours, argnums=(0, 1, 2), has_aux=True))(c["xs"], c["y"], maps)
+    (_, want_out), (want_dxs, want_dy, dpost, dres) = jax.jit(
+        jax.value_and_grad(plain, argnums=(0, 1, 2, 3), has_aux=True))(
+            c["xs"], c["y"], post, res)
+    np.testing.assert_allclose(out, want_out, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(dxs, want_dxs, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(dy, want_dy, rtol=2e-5, atol=2e-5)
+    got_pre, got_post, got_res = streams.maps_of(dmaps, n)
+    assert float(jnp.max(jnp.abs(got_pre))) == 0.0
+    np.testing.assert_allclose(got_post, dpost, rtol=1e-4, atol=1e-3)
+    np.testing.assert_allclose(got_res, dres, rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("form", ["kernels", "xla"])
+def test_a_block_on_bfloat16_streams_stays_beside_the_plain_sums(form):
+    """The dtype the model runs: a whole block ``X' = res X + post^T
+    f(pre X)`` and its gradients, both halves chained (so the part that
+    goes round the block meets the rest where ``dX`` is written), within
+    bfloat16's rounding of the plain form."""
+    from ray_tpu.ops import streams
+    n, d, tokens = 4, 128, 256
+    c = _stream_case(n, d, tokens, seed=2, dtype=jnp.bfloat16)
+    plan = _plan(c, n, form, None)
+    block = lambda x: jnp.tanh(x.astype(jnp.float32) * 0.5).astype(x.dtype)
+
+    def ours(xs, proj, scale, bias):
+        x, maps, xs = streams.streams_read(plan, xs, proj, scale, bias)
+        out = streams.streams_write(plan, xs, block(x), maps)
+        return jnp.sum(c["w_out"] * out.astype(jnp.float32))
+
+    def plain(xs, proj, scale, bias):
+        x, (_, post, res) = _plain_read(xs, proj, scale, bias, n)
+        out = _plain_write(xs, block(x), post, res, n)
+        return jnp.sum(c["w_out"] * out.astype(jnp.float32))
+
+    args = (c["xs"], c["proj"], c["scale"], c["bias"])
+    got_v, got = jax.jit(jax.value_and_grad(ours, argnums=(0, 1, 2, 3)))(*args)
+    want_v, want = jax.jit(jax.value_and_grad(plain, argnums=(0, 1, 2, 3)))(
+        *args)
+    assert got[0].dtype == jnp.bfloat16
+    np.testing.assert_allclose(got_v, want_v, rtol=2e-2)
+    for name, g, w in zip(("xs", "proj", "scale", "bias"), got, want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        off = np.sqrt(np.mean(np.square(g - w)) / np.mean(np.square(w)))
+        assert off < 3e-2, (name, off)
+
+
+def test_the_stream_kernels_take_what_tiles_the_chip_and_nothing_else():
+    from ray_tpu.ops import streams
+    assert streams.kernels_fit(4, 3584, 8192)      # the published block
+    assert not streams.kernels_fit(4, 64, 8192)    # half a lane block
+    assert not streams.kernels_fit(4, 128, 66)     # no whole tile
+    assert not streams.kernels_fit(9, 128, 256)    # a row of res in a group
+    xs = jnp.zeros((2, 33, 4 * 64))
+    assert streams.plan_for(xs, 4, **HC).tile == 0
+    assert streams.plan_for(jnp.zeros((1, 512, 512)), 4, **HC).tile == 256
+    assert streams.plan_for(jnp.zeros((3, 128, 512)), 4, **HC).tile == 128
+    assert streams.plan_for(jnp.zeros((1, 512, 512)), 4, form="xla",
+                            **HC).tile == 0
+    with pytest.raises(ValueError, match="stream kernels"):
+        streams.plan_for(xs, 4, form="kernels", **HC)

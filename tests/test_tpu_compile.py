@@ -13,6 +13,7 @@ loads the TPU library (one process at a time may), and it compiles in
 its own process.
 """
 
+import functools
 import os
 
 import jax
@@ -322,3 +323,95 @@ def test_granite_hybrid_programs_lower_the_scan_kernels_once_a_use(
     assert (kernels(check, "ssd_fwd"), kernels(check, "ssd_bwd")) == (1, 0)
     assert check.count("call @_fwd_call") == 4
     assert (kernels(step, "flash_fwd"), kernels(check, "flash_fwd")) == (1, 2)
+
+
+def _xing4_cfg(**kw):
+    """Xing4.0-29B-A4B as ``xing4-train-s8192`` runs it (the benchmark's
+    configuration file: hidden 3584, 4 residual streams mixed by maps from
+    20 Sinkhorn rounds, latent attention at 192 / 128), without its
+    predicted-ahead module and with the vocabulary cut, so that the
+    layers are what is compiled."""
+    import dataclasses
+    import json
+
+    from benchmark.loops import train
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "xing4.0-29b-a4b-1of8.json")
+    with open(path) as f:
+        cfg = train.program_config(json.load(f))
+    assert (cfg.embed_dim, cfg.hc_mult, cfg.hc_sinkhorn_iters) == (3584, 4, 20)
+    return dataclasses.replace(cfg, vocab_size=4096, num_nextn=0, **kw)
+
+
+_HC_KERNELS = ("hc_read_fwd", "hc_write_fwd", "hc_read_bwd", "hc_write_bwd")
+
+
+def test_xing4_layer_train_step_compiles_with_the_stream_kernels(
+        one_chip, as_on_chip):
+    """One (latent, dense) layer of Xing4 at its published widths and 8192
+    positions as a train step: both halves of both blocks are the ``hc_*``
+    kernels — a tile of 256 tokens x 14336 lanes of the four streams in
+    VMEM three times over in ``hc_read_bwd`` beside the projection's
+    gradient (128, 14336) float32, strips cut out at a dynamic lane
+    offset, the (256, 128) maps transposed both ways, 40 round states in
+    a scratch: what Mosaic could refuse — and it fits."""
+    cfg = _xing4_cfg(num_layers=1, leading_dense=1)
+    assert cfg.kind_runs == ((("latent", "dense"), 1),)
+    opt = default_optimizer()
+    compiled = make_train_step(cfg, opt).lower(
+        _state_shapes(cfg, opt, one_chip),
+        {"tokens": _shape((1, 8193), jnp.int32, one_chip)}).compile()
+    text = compiled.as_text()
+    assert all(name in text for name in _HC_KERNELS)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8e9
+
+
+def test_xing4_programs_lower_the_stream_kernels_once_a_use(one_chip,
+                                                            as_on_chip):
+    """The structural guard of the cell's set-up budget, as granite's is:
+    Xing4's step program over a dense and an expert run, and the
+    benchmark's check program, lowered for the described chip.  The call
+    wrappers are module-level ``jit``s, so a body is traced once a
+    process and lowered once a USE: ``hc_read_fwd`` twice a run (the
+    forward pass, and the rematerialised forward, which also hands out
+    what its backward needs) for a layer's two blocks, ``hc_write_fwd``
+    twice and each backward kernel ONCE for both runs and all their
+    blocks; the check program each forward kernel once for its two
+    forward passes.  The rounds are a loop inside the bodies, never 20
+    copies."""
+    from benchmark.loops import train
+    from ray_tpu.ops import streams
+
+    cfg = _xing4_cfg(num_layers=2, leading_dense=1)
+    assert len(cfg.kind_runs) == 2
+    opt = default_optimizer()
+    state = _state_shapes(cfg, opt, one_chip)
+    tokens = _shape((1, 8193), jnp.int32, one_chip)
+    step = make_train_step(cfg, opt).lower(state, {"tokens": tokens}
+                                           ).as_text()
+    check = jax.jit(train.program_check(cfg, None)).lower(
+        state.params, tokens).as_text()
+
+    def kernels(text):
+        return tuple(text.count(f'kernel_name = "{name}"')
+                     for name in _HC_KERNELS)
+
+    assert kernels(step) == (4, 2, 1, 1)
+    assert kernels(check) == (1, 1, 0, 0)
+    # a round's divisions appear once in a body, not once a round
+    xs = jax.ShapeDtypeStruct((256, 4 * 128), jnp.bfloat16)
+    plan = streams.plan_for(xs, 4, norm_eps=1e-6, clamp=(-30.0, 30.0),
+                            iters=20, eps=1e-6, form="kernels")
+    small = jax.ShapeDtypeStruct((256, 128), jnp.float32)
+    row = jax.ShapeDtypeStruct((1, 128), jnp.float32)
+    fwd = str(jax.make_jaxpr(functools.partial(
+        streams._read_fwd_call, plan=plan))(
+            xs, jax.ShapeDtypeStruct((512, 128), jnp.bfloat16), row, row))
+    bwd = str(jax.make_jaxpr(functools.partial(
+        streams._read_bwd_call, plan=plan))(
+            xs, xs, jax.ShapeDtypeStruct((256, 128), jnp.bfloat16), small,
+            small, jax.ShapeDtypeStruct((128, 512), jnp.bfloat16), row, row))
+    assert 8 <= fwd.count(" div ") < 20 and 8 <= bwd.count(" div ") < 40

@@ -554,6 +554,58 @@ def _xspace(ops, modules):
     return ProfileData.text_proto_to_serialized_xspace(text)
 
 
+def test_step_breakdown_counts_the_stream_kernels_under_their_scopes(
+        tmp_path):
+    """The n-stream residual's halves (``ops/streams.py``): an ``hc_*``
+    custom call is a kernel row under ITS name (the scope's own name
+    starts the same way and is no kernel) with its calls a step, and its
+    time is its scope's, in the phase its stack shows — the backward
+    kernels under the forward operation's scope."""
+    from ray_tpu.util.tracing import format_breakdown, step_breakdown
+
+    fwd, bwd = _FWD, _BWD
+    call = ', custom_call_target=\\"tpu_custom_call\\"'
+
+    def kernel(scope, name):
+        return f"{scope}/jit(_{name[3:]}_call)/{name}/pallas_call"
+
+    ops = [("%lead = f32[] add()", "jit(step)/optimizer/add", 500, 100)]
+    at = 2000
+    for block in range(2):
+        ops += [
+            (f"%hc_read_fwd.{block} = bf16[] custom-call()" + call,
+             fwd + kernel("hc_map", "hc_read_fwd"), at, 30),
+            (f"%hc_write_fwd.{block} = bf16[] custom-call()" + call,
+             fwd + kernel("hc_mix", "hc_write_fwd"), at + 30, 50),
+            (f"%hc_read_fwd.{block + 2} = bf16[] custom-call()" + call,
+             bwd + "rematted_computation/" + kernel("hc_map", "hc_read_fwd"),
+             at + 80, 31),
+            (f"%hc_write_bwd.{block} = bf16[] custom-call()" + call,
+             bwd + kernel("hc_mix", "hc_write_bwd"), at + 111, 90),
+            (f"%hc_read_bwd.{block} = bf16[] custom-call()" + call,
+             bwd + kernel("hc_map", "hc_read_bwd"), at + 201, 80),
+            (f"%pad.{block} = f32[] fusion()", bwd + "hc_map/pad", at + 281,
+             4),
+        ]
+        at += 300
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace(ops, modules=[(0, 1000), (2000, 1000)]))
+    b = step_breakdown(str(path), "jit_step")
+    ns = lambda s: round(s * 1e9)  # noqa: E731
+    assert {k: ns(t) for k, t in b["kernels"].items()} == {
+        "hc_read_fwd": 60, "hc_read_fwd.remat": 62, "hc_write_fwd": 100,
+        "hc_write_bwd": 180, "hc_read_bwd": 160}
+    assert b["kernel_calls"] == {
+        "hc_read_fwd": 2, "hc_read_fwd.remat": 2, "hc_write_fwd": 2,
+        "hc_write_bwd": 2, "hc_read_bwd": 2}
+    assert {p: ns(t) for p, t in b["scopes"]["hc_map"].items()} == {
+        "forward": 60, "remat": 62, "backward": 168}
+    assert {p: ns(t) for p, t in b["scopes"]["hc_mix"].items()} == {
+        "forward": 100, "backward": 180}
+    assert b["unscoped_s"] == 0
+    assert "hc_write_bwd  x 2 a step" in format_breakdown(b)
+
+
 # op_name prefixes of the layer scan's two loops, and the text of a flash
 # kernel's instruction at the s4096 cell's shapes.
 _FWD = "jit(step)/jvp()/while/body/closed_call/"
