@@ -1,10 +1,11 @@
 """Flagship model family: Llama-style decoder LM, TPU-first.  A layer is a
-MIXER (softmax attention | latent attention | a Mamba-2 state-space mixer)
-followed by an FFN (dense SwiGLU | dropless experts with or without a
-shared expert), each on a RESIDUAL (one stream that every block adds to |
-``hc_mult`` streams mixed round every block by learned doubly stochastic
-maps): mixer x FFN x residual, and a model is a pattern of such layers, with
-or without a predicted-ahead module behind them.
+MIXER (softmax attention | latent attention | a Mamba-2 state-space mixer |
+a gated delta-rule linear-attention mixer) followed by an FFN (dense SwiGLU
+| dropless experts with or without a shared expert), each on a RESIDUAL
+(one stream that every block adds to | ``hc_mult`` streams mixed round
+every block by learned doubly stochastic maps): mixer x FFN x residual, and
+a model is a pattern of such layers, with or without a predicted-ahead
+module behind them.
 
 Pure-functional design: params are a pytree of arrays, every tensor
 dimension has a *logical axis name*, and one rules table
@@ -43,6 +44,11 @@ TPU-first choices:
 - one chip's share of a layer (``experts_held``, ``first_expert``): the
   router keeps its published width, the expert tensors hold the experts
   that live here, and what the absent ones would add is left out.
+- a gated delta-rule mixer (``ops/delta.py``, arXiv:2412.06464) where
+  ``layer_types`` says ``linear_attention``, beside ``full_attention``
+  layers (the softmax mixer under its other public name), and the OLMo 2
+  family's block, which norms what a block ADDS (``block_norm="output"``:
+  ``x + norm(f(x))``) where every other model norms what it reads.
 
 Reference counterpart: none in Ray core (no tensor ops); RLlib's model zoo
 (``rllib/models/catalog.py``) plays the "models shipped with the framework"
@@ -70,6 +76,7 @@ from ray_tpu.ops.layers import (
     rms_norm, rope, apply_rope, swiglu, repeat_kv_heads, yarn_inv_freq,
     yarn_mscale,
 )
+from ray_tpu.ops.delta import delta_chunked
 from ray_tpu.ops.moe import moe_block, update_selection_bias
 from ray_tpu.ops.ssm import causal_conv1d, gated_rms_norm, ssd_chunked
 from ray_tpu.parallel.mesh import (
@@ -102,8 +109,9 @@ class LlamaConfig:
     norm_eps: float = 1e-6            # every RMSNorm
     qk_norm: bool = False             # RMSNorm over the q and k projections
     remat: bool = True
-    # The mixer of each layer, "attention" | "mamba"; only the first
-    # ``num_layers`` entries are the model, empty = attention everywhere.
+    # The mixer of each layer, "attention" (or "full_attention") | "mamba"
+    # | "linear_attention"; only the first ``num_layers`` entries are the
+    # model, empty = attention everywhere.
     layer_types: Tuple[str, ...] = ()
     ssm_heads: int = 0                # Mamba-2: heads x head_dim = inner width
     ssm_head_dim: int = 64
@@ -145,6 +153,16 @@ class LlamaConfig:
     hc_clamp_max: float = 30.0
     num_nextn: int = 0                # predicted-ahead modules (0 | 1)
     mtp_loss_coef: float = 0.3
+    # The gated delta-rule mixer: gdn_heads heads whose q and k are
+    # gdn_key_dim wide and whose v and output gdn_value_dim.
+    gdn_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv: int = 4                 # width of the causal depthwise conv
+    gdn_neg_eigval: bool = False      # beta in (0, 2): eigenvalues (-1, 1)
+    # Where a block's RMSNorm sits: "input", x + f(norm(x)), or "output",
+    # x + norm(f(x)) with the same weight on what the block adds.
+    block_norm: str = "input"
 
     def __post_init__(self):
         # a configuration file hands a list: keep the config hashable
@@ -158,6 +176,13 @@ class LlamaConfig:
                 "predicted-ahead module are not implemented")
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"router_scoring {self.router_scoring!r}")
+        if self.block_norm not in ("input", "output"):
+            raise ValueError(f"block_norm {self.block_norm!r}")
+        if self.block_norm == "output" and (
+                self.num_experts or "mamba" in self.layer_types):
+            raise NotImplementedError(
+                "block_norm='output' is implemented for the softmax, latent "
+                "and delta-rule mixers and the dense FFN")
         unknown = set(self.layer_types) - set(_MIXERS)
         if unknown:
             raise ValueError(
@@ -183,6 +208,19 @@ class LlamaConfig:
     def ssm_conv_dim(self) -> int:
         """What the convolution runs over: x, B and C side by side."""
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def gdn_key_inner(self) -> int:
+        return self.gdn_heads * self.gdn_key_dim
+
+    @property
+    def gdn_value_inner(self) -> int:
+        return self.gdn_heads * self.gdn_value_dim
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """What the convolution runs over: q, k and v side by side."""
+        return 2 * self.gdn_key_inner + self.gdn_value_inner
 
     @property
     def latent_qk_dim(self) -> int:
@@ -313,6 +351,26 @@ def _mamba_shapes(cfg: LlamaConfig):
     }
 
 
+def _delta_shapes(cfg: LlamaConfig):
+    """A gated delta-rule mixer: ``gdn_in`` gives [q | k | v | gate | a |
+    b] side by side (``a`` the decay's input and ``b`` the write
+    strength's, a number a head each); the convolution runs over q, k and
+    v; ``gdn_dt_bias`` and ``gdn_A_log`` are a number a head,
+    ``gdn_gate_norm`` ONE weight of a head's value size."""
+    d, keys, values = cfg.embed_dim, cfg.gdn_key_inner, cfg.gdn_value_inner
+    return {
+        "gdn_norm": ((d,), ("layer", "embed")),
+        "gdn_in": ((d, 2 * keys + 2 * values + 2 * cfg.gdn_heads),
+                   ("layer", "kernel_in", "gdn_inner")),
+        "gdn_conv_w": ((cfg.gdn_conv, cfg.gdn_conv_dim),
+                       ("layer", None, "gdn_inner")),
+        "gdn_dt_bias": ((cfg.gdn_heads,), ("layer", None)),
+        "gdn_A_log": ((cfg.gdn_heads,), ("layer", None)),
+        "gdn_gate_norm": ((cfg.gdn_value_dim,), ("layer", None)),
+        "gdn_out": ((values, d), ("layer", "gdn_inner", "kernel_in")),
+    }
+
+
 def _dense_shapes(d: int, m: int, prefix: str = "w_"):
     return {
         prefix + "gate": ((d, m), ("layer", "kernel_in", "mlp")),
@@ -363,7 +421,8 @@ def _residual_shapes(cfg: LlamaConfig):
 
 
 _MIXER_SHAPES = {"attention": _attention_shapes, "latent": _latent_shapes,
-                 "mamba": _mamba_shapes}
+                 "mamba": _mamba_shapes, "full_attention": _attention_shapes,
+                 "linear_attention": _delta_shapes}
 
 
 def _layer_shapes(cfg: LlamaConfig, kind=("attention", "dense")
@@ -420,23 +479,31 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     return axes
 
 
+# A recurrent mixer's tensors that are no projection: what each is, and
+# the ``LlamaConfig`` field that holds its convolution's width.
+_SSM_INIT = {
+    "conv_w": ("conv", "ssm_conv"), "conv_b": ("conv", "ssm_conv"),
+    "dt_bias": ("dt", None), "A_log": ("A", None), "D": ("D", None),
+    "gdn_conv_w": ("conv", "gdn_conv"), "gdn_dt_bias": ("dt", None),
+    "gdn_A_log": ("A", None)}
+
+
 def _ssm_init(name: str, key: jax.Array, shape, cfg: LlamaConfig):
     """The Mamba-2 reference code's initialisation of what is not a
-    projection: A uniform in 1..16 (kept as its log), dt log-uniform in
-    1e-3..1e-1 through the inverse of the softplus it passes, D = 1, the
-    convolution as torch's ``Conv1d`` (uniform within 1/sqrt(width))."""
-    if name == "D":
+    projection (``_SSM_INIT``; the delta-rule mixer's likewise): A uniform
+    in 1..16 (kept as its log), dt log-uniform in 1e-3..1e-1 through the
+    inverse of the softplus it passes, D = 1, the convolution as torch's
+    ``Conv1d`` (uniform within 1/sqrt(width))."""
+    what, width = _SSM_INIT[name]
+    if what == "D":
         return jnp.ones(shape, jnp.float32)
     u = jax.random.uniform(key, shape, jnp.float32)
-    if name == "A_log":
+    if what == "A":
         return jnp.log(1.0 + 15.0 * u)
-    if name == "dt_bias":
+    if what == "dt":
         dt = jnp.maximum(jnp.exp(jnp.log(1e-3) + u * jnp.log(1e2)), 1e-4)
         return dt + jnp.log(-jnp.expm1(-dt))
-    return (2.0 * u - 1.0) * cfg.ssm_conv ** -0.5      # conv_w, conv_b
-
-
-_SSM_INIT = ("conv_w", "conv_b", "dt_bias", "A_log", "D")
+    return (2.0 * u - 1.0) * getattr(cfg, width) ** -0.5
 
 
 def _map_init(name: str, key: jax.Array, shape, cfg: LlamaConfig):
@@ -665,6 +732,9 @@ def _scan_layers(layers, x, cfg: LlamaConfig, mesh, rules,
 # times that size).  On the v5e: 8.8 ms of a 507 ms step for 1.25 GB held,
 # 2.4 GB of program (PERF.md §6, PR 30).
 MAMBA_SAVED_RESIDUALS = ("ssm_proj",)
+# ... and of a delta-rule layer, likewise: [q | k | v | gate | a | b]
+# (bf16, 142 MB a layer at 4096 tokens of the published 17340 columns).
+DELTA_SAVED_RESIDUALS = ("gdn_proj",)
 
 
 def _checkpoint(layer_fn):
@@ -672,25 +742,31 @@ def _checkpoint(layer_fn):
     pass recomputes the layer from its input, except the few residuals
     that are dear to recompute and cheap to hold, named where they are
     made — the flash kernel's output and log-sum-exp, an expert layer's
-    row index (its sorts' results), a Mamba layer's input projection.  A
+    row index (its sorts' results), a Mamba or delta-rule layer's input
+    projection.  A
     layer that never produces a name (reference attention, a dense FFN)
     saves nothing under it."""
     return jax.checkpoint(
         layer_fn, policy=jax.checkpoint_policies.save_only_these_names(
             *attention.SAVED_RESIDUALS, *moe.SAVED_RESIDUALS,
-            *MAMBA_SAVED_RESIDUALS))
+            *MAMBA_SAVED_RESIDUALS, *DELTA_SAVED_RESIDUALS))
 
 
 def _zero_aux(cfg: LlamaConfig):
     """What the layer scan carries beside the activations: a dense model's
-    auxiliary loss (0), or the expert layers' float32 scalars."""
+    auxiliary loss (0), or float32 scalars by name: the expert layers',
+    and the largest state a delta-rule layer saw."""
     zero = jnp.zeros((), jnp.float32)
-    if not cfg.num_experts:
+    delta = "linear_attention" in cfg.layer_types[:cfg.num_layers]
+    if not cfg.num_experts and not delta:
         return zero
-    aux = {"aux_loss": zero, "z_loss": zero, "load_max_over_mean": zero,
-           "dropped": zero}
+    aux = {"aux_loss": zero}
+    if cfg.num_experts:
+        aux.update(z_loss=zero, load_max_over_mean=zero, dropped=zero)
     if cfg.experts_held:  # one chip's share: how much of the rows is here
         aux["held_share"] = zero
+    if delta:
+        aux[GDN_STATE_ABSMAX] = zero
     return aux
 
 
@@ -700,11 +776,12 @@ def _expert_layers(runs) -> int:
 
 def _mean_aux(aux, cfg: LlamaConfig, expert_layers: int):
     """The sums the scan carried, as means over the ``expert_layers`` that
-    added to them (``dropped`` stays a sum, the load a maximum)."""
-    if not cfg.num_experts:
+    added to them (``dropped`` stays a sum, the load and the delta-rule
+    layers' state maxima)."""
+    if not isinstance(aux, dict):
         return aux / cfg.num_layers
-    return {k: v if k in ("load_max_over_mean", "dropped")
-            else v / expert_layers for k, v in aux.items()}
+    return {k: v if k in ("load_max_over_mean", "dropped", GDN_STATE_ABSMAX)
+            else v / max(expert_layers, 1) for k, v in aux.items()}
 
 
 def _moe(x, lp, cfg: LlamaConfig, mesh: Optional[Mesh], cst,
@@ -778,15 +855,32 @@ def _make_cst(mesh, rules):
                                                  rules=rules)
 
 
-def _add(x, y, cfg: LlamaConfig, cst, residual: bool):
+def _block_in(x, weight, cfg: LlamaConfig):
+    """What a block reads: the stream through the block's norm, or, in a
+    model that norms what a block adds (``_add``), the stream as it is."""
+    if cfg.block_norm == "output":
+        return x
+    return rms_norm(x, weight, cfg.norm_eps)
+
+
+def _add(x, y, cfg: LlamaConfig, cst, residual: bool, weight=None):
     """What a block hands on: the stream plus its output ``y`` (inside
     the block's last scope), or ``y`` alone where the layer mixes it into
-    several streams itself."""
+    several streams itself.  ``weight`` is the block's norm: where the
+    model norms what a block adds, it is applied here."""
+    if cfg.block_norm == "output":
+        y = rms_norm(y, weight, cfg.norm_eps)
     y = _scaled(cst(y, ("batch", "seq", "embed")), cfg.residual_multiplier)
     return x + y if residual else y
 
 
-def _attend(x, q, k, v, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
+# A mixer, like an FFN, takes the stream and what the layer scan carries
+# beside it (``_zero_aux``) and returns both: ``(x, aux, lp, cfg, mesh,
+# cst, sp_manual, residual) -> (x, aux)``.  Only one that keeps a step
+# statistic (``_delta_mixer``) touches ``aux``.
+
+
+def _attend(x, aux, q, k, v, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
             residual: bool):
     """What every softmax mixer ends in: the attention itself (scope
     ``attention``), then the heads' outputs side by side through ``wo``
@@ -798,16 +892,17 @@ def _attend(x, q, k, v, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
             o = _attention(q, k, v, cfg, mesh)
     with jax.named_scope("attn_out"):
         o = o.reshape(*x.shape[:2], -1)
-        return _add(x, o @ lp["wo"].astype(cfg.dtype), cfg, cst, residual)
+        return _add(x, o @ lp["wo"].astype(cfg.dtype), cfg, cst, residual,
+                    lp["attn_norm"]), aux
 
 
-def _attention_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
+def _attention_mixer(x, aux, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
                      residual: bool = True):
     """Softmax attention on the residual stream (scopes ``attn_qkv``,
     ``attention``, ``attn_out``)."""
     b, s = x.shape[0], x.shape[1]
     with jax.named_scope("attn_qkv"):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        h = _block_in(x, lp["attn_norm"], cfg)
         q = h @ lp["wq"].astype(cfg.dtype)
         k = h @ lp["wk"].astype(cfg.dtype)
         if cfg.qk_norm:
@@ -825,10 +920,10 @@ def _attention_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         q = cst(q, ("batch", "seq", "heads", "head_dim"))
         k = cst(k, ("batch", "seq", "kv_heads", "head_dim"))
-    return _attend(x, q, k, v, lp, cfg, mesh, cst, sp_manual, residual)
+    return _attend(x, aux, q, k, v, lp, cfg, mesh, cst, sp_manual, residual)
 
 
-def _latent_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
+def _latent_mixer(x, aux, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
                   residual: bool = True):
     """Latent attention on the residual stream (arXiv:2412.19437 §2.1.1)
     under the scopes of ``_attention_mixer``: ``attn_qkv`` holds both
@@ -840,7 +935,7 @@ def _latent_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
     b, s = x.shape[0], x.shape[1]
     heads, nope, rot = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
     with jax.named_scope("attn_qkv"):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        h = _block_in(x, lp["attn_norm"], cfg)
         q = (rms_norm(h @ lp["wq_a"].astype(cfg.dtype), lp["q_a_norm"],
                       cfg.norm_eps) @ lp["wq_b"].astype(cfg.dtype)).reshape(
                           b, s, heads, nope + rot)
@@ -861,10 +956,10 @@ def _latent_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
         v = kv[..., nope:]
         q = cst(q, ("batch", "seq", "heads", "head_dim"))
         k = cst(k, ("batch", "seq", "heads", "head_dim"))
-    return _attend(x, q, k, v, lp, cfg, mesh, cst, sp_manual, residual)
+    return _attend(x, aux, q, k, v, lp, cfg, mesh, cst, sp_manual, residual)
 
 
-def _mamba_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
+def _mamba_mixer(x, aux, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
                  residual: bool = True):
     """A Mamba-2 mixer on the residual stream (``ops/ssm.py``): scopes
     ``ssm_in`` (norm, the one input projection, its split), ``ssm_conv``
@@ -896,7 +991,62 @@ def _mamba_mixer(x, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
         y = gated_rms_norm(y.reshape(b, s, inner), z, lp["gate_norm"],
                            cfg.norm_eps)
         return _add(x, y @ lp["ssm_out"].astype(cfg.dtype), cfg, cst,
-                    residual)
+                    residual), aux
+
+
+GDN_STATE_ABSMAX = "gdn_state_absmax"
+
+
+def _delta_mixer(x, aux, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
+                 residual: bool = True):
+    """A gated delta-rule mixer on the residual stream (``ops/delta.py``;
+    arXiv:2412.06464): scopes ``gdn_in`` (the block's norm where it norms
+    its input, the one [q | k | v | gate | a | b] projection, its split),
+    ``gdn_conv`` (the convolution over q, k, v with its SiLU, the L2 norm
+    of each head's q and k — q then times ``key_dim ** -0.5`` —, ``beta =
+    sigmoid(b)``, twice that where the rule may have negative eigenvalues,
+    and the log-decay ``g = -exp(A_log) softplus(a + dt_bias)``),
+    ``gdn_scan`` (the chunked rule, per shard of the batch), ``gdn_out``
+    (each head's output through ONE RMSNorm weight of its value size, times
+    SiLU of the gate; the output projection; the add).  ``aux`` keeps the
+    largest state a layer saw at a chunk's end."""
+    b, s = x.shape[0], x.shape[1]
+    heads, dk, dv = cfg.gdn_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
+    keys, values = cfg.gdn_key_inner, cfg.gdn_value_inner
+    f32 = jnp.float32
+    with jax.named_scope("gdn_in"):
+        h = _block_in(x, lp["gdn_norm"], cfg)
+        proj = checkpoint_name(h @ lp["gdn_in"].astype(cfg.dtype),
+                               *DELTA_SAVED_RESIDUALS)
+        qkv, gate, a, bt = jnp.split(
+            proj, [cfg.gdn_conv_dim, cfg.gdn_conv_dim + values,
+                   cfg.gdn_conv_dim + values + heads], -1)
+    with jax.named_scope("gdn_conv"):
+        qkv = causal_conv1d(qkv, lp["gdn_conv_w"])
+        q, k, v = jnp.split(qkv, [keys, 2 * keys], -1)
+
+        def unit(t):  # each head's vector at length 1, float32
+            t = t.reshape(b, s, heads, dk).astype(f32)
+            return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+
+        q = (unit(q) * dk ** -0.5).astype(cfg.dtype)
+        k = unit(k).astype(cfg.dtype)
+        beta = jax.nn.sigmoid(bt.astype(f32))
+        if cfg.gdn_neg_eigval:
+            beta = 2.0 * beta
+        g = -jnp.exp(lp["gdn_A_log"].astype(f32)) * jax.nn.softplus(
+            a.astype(f32) + lp["gdn_dt_bias"].astype(f32))
+    with jax.named_scope("gdn_scan"):
+        o, peak = _delta_scan(mesh, sp_manual)(
+            q, k, v.reshape(b, s, heads, dv), g, beta)
+    with jax.named_scope("gdn_out"):
+        o = (rms_norm(o.astype(f32), lp["gdn_gate_norm"], cfg.norm_eps)
+             * jax.nn.silu(gate.reshape(b, s, heads, dv).astype(f32))
+             ).astype(cfg.dtype)
+        return _add(x, o.reshape(b, s, values) @ lp["gdn_out"].astype(
+            cfg.dtype), cfg, cst, residual, lp["gdn_norm"]), {
+                **aux, GDN_STATE_ABSMAX: jnp.maximum(
+                    aux[GDN_STATE_ABSMAX], peak)}
 
 
 def _swiglu_ffn(h, lp, cfg: LlamaConfig, prefix: str = "w_"):
@@ -909,8 +1059,9 @@ def _dense_ffn(x, aux, lp, cfg: LlamaConfig, mesh, cst,
                residual: bool = True):
     """-> (the stream, aux, nothing handed out of the scan)."""
     with jax.named_scope("ffn"):
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        return _add(x, _swiglu_ffn(h, lp, cfg), cfg, cst, residual), aux, None
+        h = _block_in(x, lp["mlp_norm"], cfg)
+        return _add(x, _swiglu_ffn(h, lp, cfg), cfg, cst, residual,
+                    lp["mlp_norm"]), aux, None
 
 
 def _moe_ffn(x, aux, lp, cfg: LlamaConfig, mesh, cst, residual: bool = True):
@@ -926,12 +1077,14 @@ def _moe_ffn(x, aux, lp, cfg: LlamaConfig, mesh, cst, residual: bool = True):
             out = out + cst(_swiglu_ffn(h, lp, cfg, "shared_"),
                             ("batch", "seq", "embed"))
     aux = {k: (jnp.maximum if k == "load_max_over_mean"
-               else jnp.add)(v, stats[k]) for k, v in aux.items()}
+               else jnp.add)(v, stats[k]) if k in stats else v
+           for k, v in aux.items()}
     return out, aux, stats["counts"] if cfg.select_bias else None
 
 
 _MIXERS = {"attention": _attention_mixer, "latent": _latent_mixer,
-           "mamba": _mamba_mixer}
+           "mamba": _mamba_mixer, "full_attention": _attention_mixer,
+           "linear_attention": _delta_mixer}
 _FFNS = {"dense": _dense_ffn, "moe": _moe_ffn}
 
 
@@ -1034,14 +1187,14 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
 
     def layer_fn(carry, lp):
         x, aux = carry
-        x = mix(x, lp, cfg, mesh, cst, sp_manual)
+        x, aux = mix(x, aux, lp, cfg, mesh, cst, sp_manual)
         x, aux, out = ffn(x, aux, lp, cfg, mesh, cst)
         return (x, aux), out
 
     def streams_layer_fn(carry, lp):
         xs, aux = carry
-        xs, _ = hc(xs, lp, "attn", lambda x: (
-            mix(x, lp, cfg, mesh, cst, sp_manual, residual=False), None))
+        xs, aux = hc(xs, lp, "attn", lambda x: mix(
+            x, aux, lp, cfg, mesh, cst, sp_manual, residual=False))
 
         def ffn_block(x):
             y, aux_, out = ffn(x, aux, lp, cfg, mesh, cst, residual=False)
@@ -1263,9 +1416,12 @@ def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
     with jax.named_scope("loss"):
         loss = _mean_nll(logits, targets)
         if not cfg.num_experts:
+            stats, aux = (aux, aux["aux_loss"]) if isinstance(
+                aux, dict) else ({}, aux)
             total = loss + cfg.aux_loss_coef * aux
             metrics = {"loss": loss, "aux_loss": aux}
         else:
+            stats = aux
             total = (loss + cfg.aux_loss_coef * aux["aux_loss"]
                      + cfg.z_loss_coef * aux["z_loss"])
             metrics = {"loss": loss, "aux_loss": aux["aux_loss"],
@@ -1274,6 +1430,8 @@ def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
                        "moe_dropped": aux["dropped"]}
             if "held_share" in aux:
                 metrics["moe_held_share"] = aux["held_share"]
+        if GDN_STATE_ABSMAX in stats:
+            metrics[GDN_STATE_ABSMAX] = stats[GDN_STATE_ABSMAX]
         if ahead is not None:
             # position t's target is token t + 2: the last has none
             seq = targets.shape[1]
@@ -1342,3 +1500,30 @@ def _ssd_scan(mesh: Optional[Mesh], sp_manual: bool):
             out_specs=rows(4), mesh=mesh)(x, dt, a, bm, cm, d)
 
     return per_shard
+
+
+def _delta_scan(mesh: Optional[Mesh], sp_manual: bool):
+    """``delta_chunked`` as ``_delta_mixer`` calls it: ``(o, the largest
+    state at a chunk's end)``.  Under a mesh the rule runs per shard of the
+    batch, as ``_ssd_scan`` runs the state-space scan: ``gdn_inner`` maps
+    to no mesh axis, so a shard holds whole heads and whole sequences, and
+    the statistic is the largest over the shards."""
+    def rule(q, k, v, g, beta):
+        o, _, peak = delta_chunked(q, k, v, g, beta)
+        return o, peak
+
+    if mesh is None or sp_manual:
+        return rule
+    from ray_tpu.parallel.sharding import manual_shard_map
+
+    def rows(ndim):
+        return P((AXIS_DP, AXIS_FSDP), *(None,) * (ndim - 1))
+
+    def shard_rule(*t):
+        o, peak = rule(*t)
+        return o, jax.lax.pmax(peak, tuple(mesh.axis_names))
+
+    return manual_shard_map(
+        shard_rule, set(mesh.axis_names),
+        in_specs=(rows(4), rows(4), rows(4), rows(3), rows(3)),
+        out_specs=(rows(4), P()), mesh=mesh)

@@ -52,6 +52,7 @@ float32 accumulation.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -72,24 +73,26 @@ _STEP_BLOCKS = 8
 
 def _conv_pre(x, weight, bias):
     """The convolution before its SiLU, float32: ``bias + sum_i weight[i]
-    * x[t - (k-1) + i]``.  The shifted copies are cut from ``x`` in its
-    own dtype and widened inside the sum, so no float32 copy of ``x`` is
-    written."""
+    * x[t - (k-1) + i]`` (no ``bias`` where it is None).  The shifted
+    copies are cut from ``x`` in its own dtype and widened inside the sum,
+    so no float32 copy of ``x`` is written."""
     k, s = weight.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     w = weight.astype(_F32)
-    return bias.astype(_F32) + sum(
-        padded[:, i:i + s].astype(_F32) * w[i] for i in range(k))
+    lead = None if bias is None else bias.astype(_F32)
+    taps = sum(padded[:, i:i + s].astype(_F32) * w[i] for i in range(k))
+    return taps if lead is None else lead + taps
 
 
 @jax.custom_vjp
-def causal_conv1d(x: jax.Array, weight: jax.Array, bias: jax.Array
-                  ) -> jax.Array:
+def causal_conv1d(x: jax.Array, weight: jax.Array,
+                  bias: Optional[jax.Array] = None) -> jax.Array:
     """SiLU of the causal depthwise convolution of ``x (b, s, c)`` along
     ``s``: ``y[t] = bias + sum_i weight[i] * x[t - (k-1) + i]`` with
     ``weight (k, c)`` and zeros before the sequence — ``weight[k-1]``
     meets the current token, as a torch ``Conv1d(groups=c, padding=k-1)``
-    cut to ``s`` outputs has it.  Float32 inside, ``x.dtype`` out.
+    cut to ``s`` outputs has it; ``bias`` None is a convolution without
+    one.  Float32 inside, ``x.dtype`` out.
 
     Its backward pass is written out (autodiff of pad-and-slice writes one
     float32 ``(b, s, c)`` array a tap and sums them in a second pass): the
@@ -114,7 +117,8 @@ def _conv_bwd(res, dy):
     dw = jnp.stack([jnp.sum(dpre * padded[:, i:i + s].astype(_F32), (0, 1))
                     for i in range(k)])
     return (dx.astype(x.dtype), dw.astype(weight.dtype),
-            jnp.sum(dpre, (0, 1)).astype(bias.dtype))
+            None if bias is None
+            else jnp.sum(dpre, (0, 1)).astype(bias.dtype))
 
 
 causal_conv1d.defvjp(_conv_fwd, _conv_bwd)
@@ -130,7 +134,7 @@ def gated_rms_norm(y: jax.Array, z: jax.Array, weight: jax.Array,
     return rms_norm(gated, weight, eps).astype(y.dtype)
 
 
-def _exp_where(mask, x):
+def exp_where(mask, x):
     """``exp(x)`` where ``mask`` and 0 elsewhere, with a gradient that is
     finite there too (the masked side may overflow)."""
     return jnp.exp(jnp.where(mask, x, -jnp.inf))
@@ -168,7 +172,7 @@ def kernels_fit(heads: int, head_dim: int, groups: int, state: int,
             and chunk % _LANES == 0)
 
 
-def _padded(q, *tensors):
+def pad_to_multiple(q, *tensors):
     """``tensors`` ``(batch, s, ...)`` padded with zeros along ``s`` to a
     multiple of ``q``."""
     pad = -tensors[0].shape[1] % q
@@ -186,7 +190,7 @@ def ssd_xla(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     groups, n = b.shape[2], b.shape[3]
     r = heads // groups
     q = min(chunk, s)
-    x, dt, b, c = _padded(q, x, dt, b, c)
+    x, dt, b, c = pad_to_multiple(q, x, dt, b, c)
     nc = x.shape[1] // q
     dtype = x.dtype
     dt = dt.astype(_F32)
@@ -203,7 +207,7 @@ def ssd_xla(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     # inside a chunk: (C B^T o L) (dt x)
     cb = jnp.einsum("zcqgn,zckgn->zcgqk", c, b, preferred_element_type=_F32)
     causal = jnp.tril(jnp.ones((q, q), bool))
-    decay = _exp_where(causal, acs_t[..., :, None] - acs_t[..., None, :])
+    decay = exp_where(causal, acs_t[..., :, None] - acs_t[..., None, :])
     m = (cb[:, :, :, None] * decay).astype(dtype)         # (B, C, g, r, q, k)
     y = jnp.einsum("zcgrqk,zckgrp->zcqgrp", m, xdt,
                    preferred_element_type=_F32)
@@ -221,7 +225,7 @@ def ssd_xla(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     upto = jnp.cumsum(total, axis=-1)
     start = upto - total                                  # log-decay before i
     earlier = jnp.tril(jnp.ones((nc, nc), bool), -1)
-    carry = _exp_where(earlier, start[..., :, None] - upto[..., None, :])
+    carry = exp_where(earlier, start[..., :, None] - upto[..., None, :])
     entering = jnp.einsum("zgrij,zjgrpn->zigrpn", carry, left,
                           precision=jax.lax.Precision.HIGHEST)
     y = y + jnp.einsum("zcqgn,zcgrpn->zcqgrp", c, entering.astype(dtype),
@@ -577,7 +581,7 @@ def ssd_kernels(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     if b.shape[2] != 1:
         raise ValueError(f"ssd_kernels takes one group, got {b.shape[2]}")
     q = min(chunk, s)
-    x, dt, b, c = _padded(q, x, dt.astype(_F32), b, c)
+    x, dt, b, c = pad_to_multiple(q, x, dt.astype(_F32), b, c)
     padded = x.shape[1]
     acs = jnp.cumsum((dt * a.astype(_F32)).reshape(batch, padded // q, q,
                                                    heads), axis=2)

@@ -42,6 +42,8 @@ DEFAULT_RULES: LogicalAxisRules = {
     "ssm_inner": None,            # a Mamba mixer's inner width: [z|xBC|dt]
                                   # lie side by side in one projection, so
                                   # a tp split has to cut each part (later)
+    "gdn_inner": None,            # a delta-rule mixer's inner width: [q|k|v|
+                                  # gate|a|b] in one projection, likewise
     "stage": AXIS_PP,             # pipeline stages (stacked-stage layout)
     "layer": None,                # scanned-layer leading dim (non-pipelined)
 }

@@ -1,0 +1,423 @@
+"""The gated delta rule (``ops/delta.py``) and the hybrid model it makes
+possible (``models/llama.py``: linear-attention and full-attention layers in
+the published three-to-one pattern, QK-norm, NoPE, a norm on what each
+block ADDS), on the CPU at tiny sizes in float32.  The model's yardstick is
+the benchmark's own plain reference (``benchmark/reference/olmo_hybrid.py``:
+the recurrence a token at a time, nothing shared with the code under
+test)."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from benchmark.reference import olmo_hybrid
+from ray_tpu.models import llama
+from ray_tpu.models.llama import (
+    GDN_STATE_ABSMAX, LlamaConfig, init_params, loss_fn, param_logical_axes)
+from ray_tpu.ops.delta import (
+    delta_chunked, delta_reference, unit_lower_inverse)
+from ray_tpu.ops.layers import rms_norm, swiglu
+from ray_tpu.ops.ssm import causal_conv1d
+from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+from ray_tpu.train.core import (
+    STEP_SCOPES, init_train_state, make_train_step)
+
+HIGHEST = jax.default_matmul_precision("highest")
+GDN_SCOPES = ("gdn_in", "gdn_conv", "gdn_scan", "gdn_out")
+
+
+def _rule_inputs(seq, neg_eigval, seed=0, batch=2, heads=3, dk=12, dv=24,
+                 dtype=jnp.float32):
+    """q and k as the mixer hands them over (unit length a head, q times
+    ``dk ** -0.5``), head sizes in the published 1 : 2, decays of up to a
+    third a token, beta in (0, 2) with the eigenvalue switch, and a state
+    that is carried in."""
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+    q, k = unit(f(batch, seq, heads, dk)) * dk ** -0.5, unit(
+        f(batch, seq, heads, dk))
+    g = -0.3 * jax.nn.softplus(f(batch, seq, heads))
+    beta = (2.0 if neg_eigval else 1.0) * jax.nn.sigmoid(f(batch, seq, heads))
+    return (q.astype(dtype), k.astype(dtype), f(batch, seq, heads, dv).astype(
+        dtype), g, beta, f(batch, heads, dv, dk))
+
+
+def _rule_grads(form, args, weight):
+    def scalar(*t):
+        o, state = form(*t)[:2]
+        return jnp.sum(o * weight) + 0.1 * jnp.sum(jnp.square(state))
+
+    return jax.jit(jax.grad(scalar, argnums=range(6)))(*args)
+
+
+@pytest.mark.parametrize("neg_eigval", [True, False],
+                         ids=["beta-to-2", "beta-to-1"])
+@pytest.mark.parametrize("seq,chunk", [(100, 16), (128, 64), (24, 64)],
+                         ids=["ragged-16", "two-chunks-64",
+                              "shorter-than-a-chunk"])
+def test_delta_chunked_equals_the_recurrence(seq, chunk, neg_eigval):
+    """Values, the state handed on and the gradient of every input — q, k,
+    v, the log-decay, beta and the state carried in — against the
+    recurrence a token at a time, with and without negative eigenvalues, at
+    two chunk sizes, on a sequence no chunk divides (padded with tokens
+    that neither decay nor write) and one shorter than a chunk.  Float32
+    against float32 in another order of sums: 2e-5 on values, 2e-4 of each
+    gradient's scale."""
+    args = _rule_inputs(seq, neg_eigval)
+    weight = jnp.asarray(np.random.default_rng(1).normal(
+        size=args[2].shape), jnp.float32)
+    chunked = lambda *t: delta_chunked(*t, chunk=chunk)
+    with HIGHEST:
+        (o, state, peak), (want, want_state) = (
+            jax.jit(f)(*args) for f in (chunked, delta_reference))
+        grads, want_grads = (_rule_grads(f, args, weight)
+                             for f in (chunked, delta_reference))
+    assert o.shape == want.shape and o.dtype == args[2].dtype
+    np.testing.assert_allclose(o, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(state, want_state, atol=2e-5, rtol=2e-5)
+    assert float(peak) >= float(jnp.max(jnp.abs(want_state))) - 2e-5
+    for name, g, w in zip("q k v g beta state".split(), grads, want_grads):
+        assert np.all(np.isfinite(g)), name
+        np.testing.assert_allclose(g, w, atol=2e-4 * float(jnp.max(jnp.abs(
+            w))), rtol=2e-4, err_msg=name)
+
+
+def test_the_result_does_not_depend_on_the_chunk():
+    """Chunks of 8, 16 and 64 give one output and one last state; what
+    differs is where a chunk ends, so the largest state SEEN there may."""
+    args = _rule_inputs(96, True, seed=3)
+    with HIGHEST:
+        results = [jax.jit(lambda *t, c=c: delta_chunked(*t, chunk=c))(*args)
+                   for c in (8, 16, 64)]
+    for o, state, _ in results[1:]:
+        np.testing.assert_allclose(o, results[0][0], atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(state, results[0][1], atol=2e-5,
+                                   rtol=2e-5)
+
+
+def test_unit_lower_inverse_is_the_inverse_with_its_gradient():
+    """Blocks of two rows merged by doubling, against ``jnp.linalg.inv``
+    at 64 rows (and at 24 and 7, whose last block is short at some level),
+    and its written backward pass against autodiff of the solve; an entry
+    on or above the diagonal gets no gradient."""
+    rng = np.random.default_rng(0)
+    for n in (64, 24, 7):
+        a = jnp.tril(jnp.asarray(rng.normal(size=(3, n, n)) * 0.2,
+                                 jnp.float32), -1)
+        weight = jnp.asarray(rng.normal(size=(3, n, n)), jnp.float32)
+        with HIGHEST:
+            want = jnp.linalg.inv(jnp.eye(n) + a)
+            np.testing.assert_allclose(unit_lower_inverse(a), want,
+                                       atol=1e-4, rtol=1e-4)
+            got = jax.grad(lambda t: jnp.sum(unit_lower_inverse(t) * weight)
+                           )(a)
+            auto = jax.grad(lambda t: jnp.sum(jnp.linalg.inv(
+                jnp.eye(n) + jnp.tril(t, -1)) * weight))(a)
+        np.testing.assert_allclose(got, auto, atol=1e-3, rtol=1e-3)
+        assert not np.any(np.triu(np.asarray(got)))
+
+
+def test_the_inverse_holds_where_the_plain_series_loses_it():
+    """Why no finite series ``(I - A)(I + A^2)...(I + A^32)`` runs.  A
+    chunk whose keys lean one way (after a SiLU they do) with ``beta`` near
+    1 or near 2 and little decay: ``A``'s entries are all near 0.5 or 0.9,
+    its powers reach 1e9 and more before they cancel, and the six-factor
+    series at 64 rows is off by hundreds in float32 where the inverse
+    itself stays under 2; by doubling the same matrix inverts to 2e-5 and
+    1e-3 (against numpy in float64)."""
+    def series(a):
+        inv, power = jnp.eye(64, dtype=jnp.float32) - a, a
+        for _ in range(5):
+            power = power @ power
+            inv = inv + inv @ power
+        return inv
+
+    rng = np.random.default_rng(0)
+    for level, atol in ((0.5, 2e-5), (0.9, 1e-3)):
+        a = np.tril(level + 0.05 * rng.normal(size=(64, 64)), -1)
+        want = np.linalg.inv(np.eye(64) + a)
+        assert np.abs(want).max() < 2.0        # the rule keeps it bounded
+        with HIGHEST:
+            got = np.asarray(unit_lower_inverse(jnp.asarray(a, jnp.float32)))
+            lost = np.asarray(series(jnp.asarray(a, jnp.float32)))
+        np.testing.assert_allclose(got, want, atol=atol)
+        assert not np.abs(lost - want).max() < 100.0
+
+
+def test_delta_chunked_in_bfloat16_keeps_its_decays_and_state_in_float32():
+    """Operands of the big products in the input's dtype, the decays, the
+    inverse and the carried state in float32: a bfloat16 call stays within
+    bfloat16's rounding of the float32 one (a relative 2 ** -8 an operand,
+    a few operands deep: 3e-2 of the output's scale), the state it hands
+    on is float32, and the output has v's dtype."""
+    args = _rule_inputs(128, True, seed=2)
+    half = tuple(t.astype(jnp.bfloat16) for t in args[:3]) + args[3:]
+    want, want_state, _ = delta_chunked(*args, chunk=64)
+    o, state, _ = delta_chunked(*half, chunk=64)
+    assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+    scale = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(o.astype(jnp.float32) - want))) < 3e-2 * scale
+    assert float(jnp.max(jnp.abs(state - want_state))) < 3e-2 * float(
+        jnp.max(jnp.abs(want_state)))
+
+
+def test_causal_conv1d_without_a_bias():
+    """``bias=None``, given or left out, is the convolution with a bias of
+    zeros: values and gradients."""
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(2, 19, 6)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(4, 6)), jnp.float32)
+    zeros = jnp.zeros((6,), jnp.float32)
+    np.testing.assert_allclose(causal_conv1d(x, w), causal_conv1d(x, w, zeros),
+                               atol=1e-6)
+    np.testing.assert_allclose(causal_conv1d(x, w, None),
+                               causal_conv1d(x, w, zeros), atol=1e-6)
+    got = jax.grad(lambda x_, w_: jnp.sum(jnp.sin(causal_conv1d(x_, w_))),
+                   argnums=(0, 1))(x, w)
+    want = jax.grad(lambda x_, w_: jnp.sum(jnp.sin(causal_conv1d(
+        x_, w_, zeros))), argnums=(0, 1))(x, w)
+    for g, v in zip(got, want):
+        np.testing.assert_allclose(g, v, atol=1e-6)
+
+
+# ---- the model --------------------------------------------------------
+
+# A configuration file's keys (the public names), tiny: the published
+# pattern (three linear, one full) with a fifth entry that is not run.
+CONF = {
+    "num_hidden_layers": 4,
+    "layer_types": ["linear_attention"] * 3 + ["full_attention",
+                                               "linear_attention"],
+    "num_attention_heads": 4, "num_key_value_heads": 4,
+    "linear_num_key_heads": 4, "linear_num_value_heads": 4,
+    "linear_key_head_dim": 8, "linear_value_head_dim": 16,
+    "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
+    "rms_norm_eps": 1e-6,
+}
+
+
+def _cfg(**kw):
+    fields = dict(
+        vocab_size=256, embed_dim=64, num_layers=4, num_heads=4,
+        num_kv_heads=4, head_dim=16, mlp_dim=96, norm_eps=1e-6,
+        layer_types=CONF["layer_types"], gdn_heads=4, gdn_key_dim=8,
+        gdn_value_dim=16, gdn_conv=4, gdn_neg_eigval=True,
+        position_embedding="nope", qk_norm=True, block_norm="output",
+        max_seq_len=128, dtype=jnp.float32, remat=True, attn_impl="flash")
+    fields.update(kw)
+    return LlamaConfig(**fields)
+
+
+def _drawn(params, seed=5):
+    """Norm weights drawn away from 1, as the benchmark's check draws
+    them."""
+    rng = np.random.default_rng(seed)
+
+    def leaf(path, a):
+        if str(getattr(path[-1], "key", "")).endswith("norm"):
+            return a * jnp.asarray(rng.uniform(0.5, 1.5, a.shape), a.dtype)
+        return a
+
+    return jax.tree_util.tree_map_with_path(leaf, params)
+
+
+# 96 positions: a chunk of the rule's 64 and a ragged second one
+TOKENS = jnp.asarray(np.random.default_rng(7).integers(
+    0, 256, (2, 97), dtype=np.int32))
+# float32 against float32, relative: the loss a mean of 192 numbers near
+# 5.5, the gradients through three recurrences of 96 tokens in two orders
+LOSS_TOL, NLL_TOL, GRAD_TOL = 2e-6, 2e-5, 5e-4
+
+
+def test_config_names_the_published_pattern():
+    cfg = _cfg()
+    assert cfg.layer_runs == (("linear_attention", 3), ("full_attention", 1))
+    assert (cfg.gdn_key_inner, cfg.gdn_value_inner, cfg.gdn_conv_dim) == (
+        32, 64, 128)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    linear, full = params["layers"]
+    assert linear["gdn_in"].shape == (3, 64, 32 + 32 + 64 + 64 + 4 + 4)
+    assert linear["gdn_conv_w"].shape == (3, 4, 128)
+    assert "gdn_conv_b" not in linear and linear["gdn_gate_norm"].shape == (
+        3, 16)
+    assert full["q_norm"].shape == (1, 64) and "lm_head" in params
+    axes = param_logical_axes(cfg)["layers"][0]
+    assert axes["gdn_in"] == ("layer", "kernel_in", "gdn_inner")
+    assert jax.tree.structure(axes, is_leaf=lambda t: isinstance(
+        t, tuple)) == jax.tree.structure(linear)
+    with pytest.raises(ValueError):
+        _cfg(block_norm="both")
+    with pytest.raises(NotImplementedError):
+        _cfg(num_experts=4)
+
+
+def test_hybrid_loss_token_losses_and_gradients_equal_the_plain_reference():
+    """linear x 3, full through ``loss_fn`` (the flash kernel interpreted,
+    the layer checkpoint on) against the benchmark's reference, which
+    computes the recurrence a token at a time: the loss within 2e-6, each
+    position's loss within 2e-5 nats, every gradient leaf within 5e-4 of
+    its scale."""
+    cfg = _cfg()
+    params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
+    with HIGHEST:
+        (loss, metrics), grads = jax.jit(jax.value_and_grad(
+            lambda p: loss_fn(p, {"tokens": TOKENS}, cfg), has_aux=True))(
+                params)
+        logits, aux = jax.jit(lambda p: llama.forward(
+            p, TOKENS[:, :-1], cfg))(params)
+        want, want_grads = jax.jit(jax.value_and_grad(
+            lambda p: olmo_hybrid.loss(p, TOKENS, CONF)))(params)
+        want_nll = olmo_hybrid.loss_parts(params, TOKENS, CONF)["token_nll"]
+    assert abs(float(loss) - float(want)) / float(want) <= LOSS_TOL
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
+                               TOKENS[:, 1:, None], -1)[..., 0]
+    np.testing.assert_allclose(nll, want_nll, atol=NLL_TOL)
+    apart = jax.tree.map(
+        lambda g, w: float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w))),
+        grads, want_grads)
+    assert max(jax.tree.leaves(apart)) <= GRAD_TOL, apart
+    # the layers' largest state, as a step metric and beside the logits
+    assert 0.1 < float(metrics[GDN_STATE_ABSMAX]) < 100.0
+    assert float(aux[GDN_STATE_ABSMAX]) == float(metrics[GDN_STATE_ABSMAX])
+    assert set(olmo_hybrid.STEP_METRICS) <= set(metrics)
+
+
+@pytest.mark.parametrize("wrong", [
+    dict(block_norm="input"), dict(qk_norm=False),
+    dict(position_embedding="rope"), dict(gdn_neg_eigval=False),
+    "gate before the norm", "keys not normalised",
+], ids=lambda w: w if isinstance(w, str) else "-".join(
+    f"{k}={v}" for k, v in w.items()))
+def test_structural_controls_fail_against_the_reference(wrong, monkeypatch):
+    """What the comparison must be able to tell from the assumed structure:
+    the norm on a block's input, no QK-norm, RoPE left on, beta kept under
+    1, the gate applied before the head's norm, keys left at their length.
+    Each moves the loss thirty tolerances or more."""
+    cfg = _cfg(attn_impl="reference", remat=False)
+    params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
+    program_params = params
+    if wrong == "gate before the norm":
+        monkeypatch.setattr(llama, "rms_norm", lambda x, w, eps=1e-6: (
+            x if w.shape[-1] == 16 and x.ndim == 4
+            else rms_norm(x, w, eps)))
+    elif wrong == "keys not normalised":
+        monkeypatch.setattr(jax.lax, "rsqrt", lambda x: (
+            jnp.ones_like(x) if x.ndim == 4 and x.shape[-1] == 1
+            else 1.0 / jnp.sqrt(x)))
+    elif wrong == dict(qk_norm=False):
+        cfg = dataclasses.replace(cfg, qk_norm=False)
+        program_params = dict(params, layers=(params["layers"][0], {
+            k: v for k, v in params["layers"][1].items()
+            if k not in ("q_norm", "k_norm")}))
+    else:
+        cfg = dataclasses.replace(cfg, **wrong)
+    with HIGHEST:
+        loss = float(jax.jit(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(
+            program_params))
+        want = float(olmo_hybrid.loss(params, TOKENS, CONF))
+    assert abs(loss - want) / want > 30 * LOSS_TOL, (wrong, loss, want)
+
+
+def _by_hand(x, lp, cfg, place):
+    """One attention layer and its MLP written out, the norm on each
+    block's input (``place`` "input") or on what it adds ("output")."""
+    def block(x, norm, f):
+        if place == "input":
+            return x + f(rms_norm(x, norm, cfg.norm_eps))
+        return x + rms_norm(f(x), norm, cfg.norm_eps)
+
+    def attention(h):
+        b, s, _ = h.shape
+        q, k, v = (
+            (h @ lp[w]).reshape(b, s, cfg.num_heads, cfg.head_dim)
+            for w in ("wq", "wk", "wv"))
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * cfg.head_dim ** -0.5
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+        o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
+        return o.reshape(b, s, -1) @ lp["wo"]
+
+    x = block(x, lp["attn_norm"], attention)
+    return block(x, lp["mlp_norm"], lambda h: swiglu(
+        h @ lp["w_gate"], h @ lp["w_up"]) @ lp["w_down"])
+
+
+@pytest.mark.parametrize("place", ["input", "output"])
+def test_block_norm_against_a_block_written_out(place):
+    """``block_norm="output"`` is ``x + norm(f(x))`` for the mixer and the
+    MLP alike; the field left at its default is today's block, ``x +
+    f(norm(x))`` (and builds the same program as naming that default)."""
+    kw = dict(num_layers=1, position_embedding="nope",
+              attn_impl="reference", remat=False)
+    cfg = LlamaConfig.tiny(**kw, **({} if place == "input"
+                                    else {"block_norm": place}))
+    assert cfg.block_norm == place
+    params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 9, 64)),
+                    jnp.float32)
+    layer_fn = llama._make_layer_fn(cfg, None, None)
+    with HIGHEST:
+        (got, _), _ = layer_fn((x, llama._zero_aux(cfg)), lp)
+        want = _by_hand(x, lp, cfg, place)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    if place == "input":
+        named = LlamaConfig.tiny(**kw, block_norm="input")
+        assert named == cfg
+        text = [str(jax.make_jaxpr(lambda p: loss_fn(
+            p, {"tokens": TOKENS}, c)[0])(params)) for c in (cfg, named)]
+        assert text[0] == text[1] and "gdn" not in text[0]
+
+
+def test_train_step_reports_the_state_and_names_its_scopes():
+    """A train step of the hybrid: ``gdn_state_absmax`` is among the step's
+    metrics, the loss falls, and the four ``gdn_*`` scopes — members of
+    ``STEP_SCOPES`` — are on the compiled program's ops in the forward,
+    the rematerialised and the backward pass, except ``gdn_in``'s
+    rematerialised matmul: the checkpoint keeps the projection by name."""
+    import re
+
+    from ray_tpu.util.tracing import scope_and_phase
+
+    assert set(GDN_SCOPES) <= set(STEP_SCOPES)
+    cfg = _cfg()
+    opt = optax.adam(1e-2)
+    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
+    step = make_train_step(cfg, opt, donate=False)
+    batch = {"tokens": TOKENS}
+    losses = []
+    for _ in range(3):
+        state, metrics = step(state, batch)
+        losses.append(float(metrics["loss"]))
+    assert losses[2] < losses[0] and np.isfinite(
+        float(metrics[GDN_STATE_ABSMAX]))
+    text = step.lower(state, batch).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    seen = {scope_and_phase(n, STEP_SCOPES) for n in names}
+    assert {(s, p) for s in GDN_SCOPES
+            for p in ("forward", "remat", "backward")} <= seen
+    dots = {scope_and_phase(n, STEP_SCOPES) for n in re.findall(
+        r'dot\([^\n]*op_name="([^"]*)"', text)}
+    assert ("gdn_in", "forward") in dots and ("gdn_in", "backward") in dots
+    assert ("gdn_in", "remat") not in dots
+
+
+def test_on_a_mesh_the_rule_runs_per_shard_of_the_batch():
+    """fsdp=2 x tp=2: the loss and the state's maximum equal one device's
+    (the rule inside a manual region, rows over the data axes, the maximum
+    taken over the shards), and the mixer's inner width maps to no axis."""
+    cfg = _cfg()
+    params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
+    mesh = make_mesh(MeshConfig(fsdp=2, tp=2), devices=jax.devices()[:4])
+    with HIGHEST:
+        want, want_m = jax.jit(lambda p: loss_fn(
+            p, {"tokens": TOKENS}, cfg))(params)
+        got, got_m = jax.jit(lambda p: loss_fn(
+            p, {"tokens": TOKENS}, cfg, mesh=mesh))(params)
+    assert abs(float(got) - float(want)) <= 1e-5 * float(want)
+    np.testing.assert_allclose(got_m[GDN_STATE_ABSMAX],
+                               want_m[GDN_STATE_ABSMAX], rtol=1e-5)

@@ -325,6 +325,18 @@ def test_granite_hybrid_programs_lower_the_scan_kernels_once_a_use(
     assert (kernels(step, "flash_fwd"), kernels(check, "flash_fwd")) == (1, 2)
 
 
+def _benchmark_cfg(name):
+    """The program's config of a benchmark configuration file."""
+    import json
+
+    from benchmark.loops import train
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs", name + ".json")
+    with open(path) as f:
+        return train.program_config(json.load(f))
+
+
 def _xing4_cfg(**kw):
     """Xing4.0-29B-A4B as ``xing4-train-s8192`` runs it (the benchmark's
     configuration file: hidden 3584, 4 residual streams mixed by maps from
@@ -332,15 +344,8 @@ def _xing4_cfg(**kw):
     predicted-ahead module and with the vocabulary cut, so that the
     layers are what is compiled."""
     import dataclasses
-    import json
 
-    from benchmark.loops import train
-
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "configs",
-        "xing4.0-29b-a4b-1of8.json")
-    with open(path) as f:
-        cfg = train.program_config(json.load(f))
+    cfg = _benchmark_cfg("xing4.0-29b-a4b-1of8")
     assert (cfg.embed_dim, cfg.hc_mult, cfg.hc_sinkhorn_iters) == (3584, 4, 20)
     return dataclasses.replace(cfg, vocab_size=4096, num_nextn=0, **kw)
 
@@ -415,3 +420,28 @@ def test_xing4_programs_lower_the_stream_kernels_once_a_use(one_chip,
             xs, xs, jax.ShapeDtypeStruct((256, 128), jnp.bfloat16), small,
             small, jax.ShapeDtypeStruct((128, 512), jnp.bfloat16), row, row))
     assert 8 <= fwd.count(" div ") < 20 and 8 <= bwd.count(" div ") < 40
+
+
+def test_olmo_hybrid_linear_layer_train_step_compiles(one_chip, as_on_chip):
+    """One gated delta-rule layer of Olmo-Hybrid-7B as
+    ``olmohybrid-train-1seq`` runs it (the benchmark's configuration file:
+    hidden 3840, 30 heads with keys of 96 and values of 192, chunks of 64,
+    the norm on what the block adds), the vocabulary cut, at 4096
+    positions as a train step: plain XLA — keys of 96 tile no 128 lanes —
+    whose chunk matrices, inverses and entering states are arrays; what
+    the chip's compiler makes of them must fit beside a layer's state."""
+    import dataclasses
+
+    cfg = _benchmark_cfg("olmo-hybrid-7b-d4")
+    assert (cfg.embed_dim, cfg.gdn_heads, cfg.gdn_key_dim, cfg.gdn_value_dim,
+            cfg.block_norm) == (3840, 30, 96, 192, "output")
+    cfg = dataclasses.replace(cfg, vocab_size=4096, num_layers=1)
+    assert cfg.layer_runs == (("linear_attention", 1),)
+    opt = default_optimizer()
+    compiled = make_train_step(cfg, opt).lower(
+        _state_shapes(cfg, opt, one_chip),
+        {"tokens": _shape((1, 4097), jnp.int32, one_chip)}).compile()
+    assert not _has_kernel(compiled)
+    assert "gdn_scan" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9
