@@ -762,7 +762,8 @@ def _zero_aux(cfg: LlamaConfig):
         return zero
     aux = {"aux_loss": zero}
     if cfg.num_experts:
-        aux.update(z_loss=zero, load_max_over_mean=zero, dropped=zero)
+        aux.update(z_loss=zero, load_max_over_mean=zero, dropped=zero,
+                   rows_visited_share=zero)
     if cfg.experts_held:  # one chip's share: how much of the rows is here
         aux["held_share"] = zero
     if delta:
@@ -1427,7 +1428,8 @@ def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
             metrics = {"loss": loss, "aux_loss": aux["aux_loss"],
                        "z_loss": aux["z_loss"],
                        "moe_load_max_over_mean": aux["load_max_over_mean"],
-                       "moe_dropped": aux["dropped"]}
+                       "moe_dropped": aux["dropped"],
+                       "moe_rows_visited_share": aux["rows_visited_share"]}
             if "held_share" in aux:
                 metrics["moe_held_share"] = aux["held_share"]
         if GDN_STATE_ABSMAX in stats:

@@ -35,11 +35,21 @@ the weights transposed for the rows' gradient) and ``moe_tgmm`` (the
 weights' gradient, per group).  Both walk one schedule of (group, row
 tile) visits handed over as scalar prefetch: a tile that two groups
 share is visited once for each, and the rows that are not the visit's
-are masked.  Rows past the groups' sum — the padding, and the rows of
-experts that are not held here: other ranks' under expert parallelism,
-other chips' where this chip holds its share of a layer (``first_expert``
-and the leading dimension of the expert tensors say which) — come back as
-zeros.  The row buffer is static, ``T * k`` rows however few are held.
+are masked.
+
+The row buffer is static, ``T * k`` rows however few are held, and the
+work on it ends at the LIVE rows, the groups' sum (a value the device
+has; nothing has a capacity).  The rows past it — the padding, and the
+rows of experts that are not held here: other ranks' under expert
+parallelism, other chips' where this chip holds its share of a layer
+(``first_expert`` and the leading dimension of the expert tensors say
+which) — get no visit of the schedule and no trip of the row-side
+gathers: they hold NOTHING ANYONE MAY READ UNMASKED (what the allocator
+left, NaN in interpret mode).  Whoever can meet one (a token's choice of
+an absent expert names a row there; the last live tile and chunk run
+over) selects by ``row < live``, which is exact on garbage.  Where every
+expert is held (``E' == E``) every row is live by construction, and the
+gathers are the single ones, unmasked.
 
 Inside a ``shard_map`` (a Pallas kernel has no partitioning rule) the
 layer takes the names of the mesh axes: tokens are split over
@@ -71,6 +81,9 @@ ROW_TILES = (512, 256, 128)
 MAX_EXECUTED = 1.15
 _WEIGHT_BLOCK_BYTES = 4 * 1024 * 1024   # one group's weights in VMEM
 _ACC_BLOCK_BYTES = 8 * 1024 * 1024      # float32 accumulator of moe_tgmm
+# Rows a trip of a row-side gather moves (``_over_live_rows``): the trips
+# run over the live rows by up to one chunk.
+ROW_CHUNK = 1024
 
 
 def executed_rows(rows: int, groups: int, tile: int) -> int:
@@ -79,7 +92,10 @@ def executed_rows(rows: int, groups: int, tile: int) -> int:
 
 
 def choose_tiles(rows: int, groups: int) -> int:
-    """The row tile for ``rows`` rows in ``groups`` groups."""
+    """The row tile for ``rows`` rows in ``groups`` groups: the rows
+    EXPECTED LIVE (``moe_block`` hands over ``T * k * E' / E``), not the
+    buffer's — the kernels visit only those, so the buffer's rows would
+    pad the estimate and pick a tile eight times a held group's share."""
     for tile in ROW_TILES:
         if executed_rows(rows, groups, tile) <= MAX_EXECUTED * rows:
             return tile
@@ -107,24 +123,24 @@ SAVED_RESIDUALS = ("moe_row_index",)
 
 class Schedule(NamedTuple):
     """The visits of one set of group sizes, as the kernels' scalar
-    prefetch.  The rows past the groups' sum are group ``G``, which has no
-    weights: its tiles are zero-filled."""
-    offsets: jax.Array     # (G + 2,) first row of each group, then the end
+    prefetch.  They end at the groups' sum: the rows past it have no
+    group and no visit."""
+    offsets: jax.Array     # (G + 1,) first row of each group, then the end
     group_ids: jax.Array   # (V,) group of each visit
     tile_ids: jax.Array    # (V,) row tile of each visit
-    num_visits: jax.Array  # (1,) visits that are real; the rest is padding
+    num_visits: jax.Array  # (1,) visits that are real: the grid's length
 
 
 def make_schedule(group_sizes: jax.Array, rows: int, tile: int) -> Schedule:
     """Visits in row order: each group walks the tiles its rows touch
     (an empty group keeps one visit, so its weight gradient is zeroed).
     ``V = rows / tile + G`` bounds their number: every tile once, and once
-    more for each boundary inside a tile."""
+    more for each boundary inside a tile.  The kernels' grid ends at the
+    real visits (its length is the device's, like the live rows); the
+    padding behind them names the last real visit's group and tile."""
     groups = group_sizes.shape[0]
     n_tiles = rows // tile
-    group_sizes = group_sizes.astype(jnp.int32)
-    sizes = jnp.concatenate(
-        [group_sizes, rows - jnp.sum(group_sizes, keepdims=True)])
+    sizes = group_sizes.astype(jnp.int32)
     ends = jnp.cumsum(sizes)
     starts = ends - sizes
     first = jnp.minimum(starts // tile, n_tiles - 1)
@@ -132,24 +148,35 @@ def make_schedule(group_sizes: jax.Array, rows: int, tile: int) -> Schedule:
     visits = last - first + 1
     visit_ends = jnp.cumsum(visits)
     n_visits = n_tiles + groups
-    group_ids = jnp.repeat(jnp.arange(groups + 1, dtype=jnp.int32), visits,
-                           total_repeat_length=n_visits)
-    nth = jnp.arange(n_visits, dtype=jnp.int32) - (
-        visit_ends - visits)[group_ids]
-    tile_ids = jnp.minimum(first[group_ids] + nth, n_tiles - 1)
+    # A visit's group, and that group's entries, by a compare and a sum
+    # over (V, G): element gathers move one element at a time.  The
+    # padding counts as the last group's, whose visits (every group has
+    # one) are the last real ones.
+    nth = jnp.arange(n_visits, dtype=jnp.int32)
+    group_ids = jnp.minimum(jnp.sum(
+        nth[:, None] >= visit_ends[None, :], axis=1, dtype=jnp.int32),
+        groups - 1)
+    mine = group_ids[:, None] == jnp.arange(groups, dtype=jnp.int32)
+
+    def of_group(a):
+        return jnp.sum(jnp.where(mine, a[None, :], 0), axis=1)
+
+    # no group's tiles reach past the last group's last: a scalar keeps
+    # the padding in range
+    tile_ids = jnp.minimum(
+        of_group(first) + nth - of_group(visit_ends - visits), last[-1])
     return Schedule(
         jnp.concatenate([jnp.zeros((1,), jnp.int32), ends]).astype(jnp.int32),
         group_ids, tile_ids.astype(jnp.int32),
         visit_ends[-1:].astype(jnp.int32))
 
 
-def _visit(sched_refs, i, tile):
+def _visit(offsets, group_ids, tile_ids, i, tile):
     """(group, first and one-past-last row of the group inside the
-    visit's tile, is the visit real) of visit ``i``."""
-    offsets, group_ids, tile_ids, num_visits = sched_refs
+    visit's tile) of visit ``i``."""
     g = group_ids[i]
     row0 = tile_ids[i] * tile
-    return g, offsets[g] - row0, offsets[g + 1] - row0, i < num_visits[0]
+    return g, offsets[g] - row0, offsets[g + 1] - row0
 
 
 def _row_mask(lo, hi, tile):
@@ -158,66 +185,64 @@ def _row_mask(lo, hi, tile):
 
 
 def _gmm_kernel(offsets, group_ids, tile_ids, num_visits, lhs_ref, rhs_ref,
-                out_ref, *, tile, groups, transpose_rhs):
-    g, lo, hi, real = _visit((offsets, group_ids, tile_ids, num_visits),
-                             pl.program_id(1), tile)
-    live = real & (hi > lo)
+                out_ref, *, tile, transpose_rhs):
+    _, lo, hi = _visit(offsets, group_ids, tile_ids, pl.program_id(1), tile)
+    live = hi > lo  # an empty group's visit writes nothing
     interior = (lo <= 0) & (hi >= tile)
 
-    def store(value):
-        @pl.when(interior)
-        def _whole():
-            out_ref[...] = value()
-
-        @pl.when(jnp.logical_not(interior))
-        def _masked():
-            out_ref[...] = jnp.where(_row_mask(lo, hi, tile), value(),
-                                     out_ref[...])
-
-    @pl.when(live & (g < groups))
-    def _product():
+    def product():
         dims = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
-        store(lambda: jax.lax.dot_general(
+        return jax.lax.dot_general(
             lhs_ref[...], rhs_ref[...], dims,
-            preferred_element_type=jnp.float32).astype(out_ref.dtype))
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
-    @pl.when(live & (g == groups))
-    def _past_the_groups():
-        store(lambda: jnp.zeros(out_ref.shape, out_ref.dtype))
+    @pl.when(live & interior)
+    def _whole():
+        out_ref[...] = product()
+
+    # Another group's rows keep what its visit wrote; the rows past the
+    # last group keep what the block held (nothing: the module docstring).
+    @pl.when(live & jnp.logical_not(interior))
+    def _masked():
+        out_ref[...] = jnp.where(_row_mask(lo, hi, tile), product(),
+                                 out_ref[...])
 
 
 def _tgmm_kernel(offsets, group_ids, tile_ids, num_visits, lhs_ref, rhs_ref,
-                 out_ref, acc_ref, *, tile, groups):
+                 out_ref, acc_ref, *, tile):
     i = pl.program_id(1)
-    g, lo, hi, real = _visit((offsets, group_ids, tile_ids, num_visits),
-                             i, tile)
-    real = real & (g < groups)
-    # The pseudo-group's visit follows the last group's, so i + 1 exists.
+    g, lo, hi = _visit(offsets, group_ids, tile_ids, i, tile)
+    # The last group's last visit is the grid's last: no visit follows it.
     before = group_ids[jnp.maximum(i - 1, 0)]
-    after = group_ids[jnp.minimum(i + 1, pl.num_programs(1) - 1)]
+    after = group_ids[jnp.minimum(i + 1, num_visits[0] - 1)]
+    last = (i + 1 == num_visits[0]) | (after != g)
 
-    @pl.when(real & ((i == 0) | (before != g)))
+    @pl.when((i == 0) | (before != g))
     def _first_visit():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def accumulate(rhs):
+    def accumulate(lhs, rhs):
         acc_ref[...] += jax.lax.dot_general(
-            lhs_ref[...], rhs, (((0,), (0,)), ((), ())),
+            lhs, rhs, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     interior = (lo <= 0) & (hi >= tile)
 
-    @pl.when(real & (hi > lo) & interior)
+    @pl.when((hi > lo) & interior)
     def _whole():
-        accumulate(rhs_ref[...])
+        accumulate(lhs_ref[...], rhs_ref[...])
 
-    @pl.when(real & (hi > lo) & jnp.logical_not(interior))
-    def _masked():  # other groups' rows leave through ONE operand
-        rhs = rhs_ref[...]
-        accumulate(jnp.where(_row_mask(lo, hi, tile), rhs,
-                             jnp.zeros_like(rhs)))
+    @pl.when((hi > lo) & jnp.logical_not(interior))
+    def _masked():
+        # Out of BOTH operands: another group's rows are numbers and one
+        # zero factor would do, but the rows past the last group may hold
+        # anything, and 0 x NaN is NaN.
+        mask = _row_mask(lo, hi, tile)
+        lhs, rhs = lhs_ref[...], rhs_ref[...]
+        accumulate(jnp.where(mask, lhs, jnp.zeros_like(lhs)),
+                   jnp.where(mask, rhs, jnp.zeros_like(rhs)))
 
-    @pl.when(real & (after != g))
+    @pl.when(last)
     def _last_visit():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
@@ -233,24 +258,22 @@ def _compiler_params(interpret):
 
 def _gmm(lhs, rhs, sched: Schedule, tile, transpose_rhs, interpret):
     """``out[r] = lhs[r] @ rhs[group of r]`` (``rhs[g].T`` if
-    ``transpose_rhs``); zeros for the rows past the groups."""
+    ``transpose_rhs``); the rows past the groups are not written."""
     rows, k = lhs.shape
-    groups = rhs.shape[0]
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tn = _fit_columns(n, k, rhs.dtype.itemsize, _WEIGHT_BLOCK_BYTES)
-    group_of = lambda j, i, o, g, t, v: jnp.minimum(g[i], groups - 1)
     if transpose_rhs:
         rhs_spec = pl.BlockSpec(
-            (None, tn, k), lambda j, i, *s: (group_of(j, i, *s), j, 0))
+            (None, tn, k), lambda j, i, o, g, t, v: (g[i], j, 0))
     else:
         rhs_spec = pl.BlockSpec(
-            (None, k, tn), lambda j, i, *s: (group_of(j, i, *s), 0, j))
+            (None, k, tn), lambda j, i, o, g, t, v: (g[i], 0, j))
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, tile=tile, groups=groups,
+        functools.partial(_gmm_kernel, tile=tile,
                           transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n // tn, sched.group_ids.shape[0]),
+            grid=(n // tn, sched.num_visits[0]),
             in_specs=[
                 pl.BlockSpec((tile, k), lambda j, i, o, g, t, v: (t[i], 0)),
                 rhs_spec],
@@ -265,22 +288,20 @@ def _gmm(lhs, rhs, sched: Schedule, tile, transpose_rhs, interpret):
 
 def _tgmm(lhs, rhs, sched: Schedule, groups, tile, interpret):
     """``out[g] = lhs[rows of g].T @ rhs[rows of g]``; zeros for an
-    empty group."""
+    empty group.  The rows past the groups are not read."""
     rows, k = lhs.shape
     n = rhs.shape[1]
     tn = _fit_columns(n, k, 4, _ACC_BLOCK_BYTES)
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, tile=tile, groups=groups),
+        functools.partial(_tgmm_kernel, tile=tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n // tn, sched.group_ids.shape[0]),
+            grid=(n // tn, sched.num_visits[0]),
             in_specs=[
                 pl.BlockSpec((tile, k), lambda j, i, o, g, t, v: (t[i], 0)),
                 pl.BlockSpec((tile, tn), lambda j, i, o, g, t, v: (t[i], j))],
             out_specs=pl.BlockSpec(
-                (None, k, tn),
-                lambda j, i, o, g, t, v: (jnp.minimum(g[i], groups - 1), 0,
-                                          j)),
+                (None, k, tn), lambda j, i, o, g, t, v: (g[i], 0, j)),
             scratch_shapes=[pltpu.VMEM((k, tn), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((groups, k, n), lhs.dtype),
         compiler_params=_compiler_params(interpret),
@@ -295,7 +316,8 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array, sched: Schedule,
     """``rows (M, K)`` x ``weights (G, K, N)`` -> ``(M, N)``: row ``r``
     meets ``weights[g]`` for the group ``g`` that ``sched`` puts it in
     (``make_schedule(group_sizes, M, tile)``; ``M`` a multiple of
-    ``tile``).  Rows past the groups' sum give zeros and no gradient."""
+    ``tile``).  Rows past the groups' sum are neither read nor written:
+    what comes back there, and as their gradient, is unspecified."""
     return _gmm(rows, weights.astype(rows.dtype), sched, tile, False,
                 interpret)
 
@@ -322,6 +344,14 @@ grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 # index is in range by construction (``_row_index``) and says so: a
 # gather that may be out of range is followed by a pass over its whole
 # result that fills the rows that were.
+#
+# ``live`` is the groups' sum where it may be under ``T * k`` (a share of
+# the experts) and None where every row is live by construction.  The two
+# ROW-side gathers (``_dispatch``, ``_combine``'s gradient) then stop at
+# it: a loop over chunks of rows whose trip count the device computes,
+# exact at any count up to the buffer's.  The two TOKEN-side ones
+# (``_combine``, ``_dispatch``'s gradient) read a row for every (token,
+# choice) and select by ``row < live``.
 
 def _take(a, index):
     """``a[index]`` along axis 0, for indices that ARE in range."""
@@ -346,8 +376,10 @@ def _row_index(flat, gates, rows):
     gates').  ``row_slot[:T * k]`` and ``slot_row`` are permutations of
     ``0 .. T * k - 1`` and each other's inverse.  The rows past ``T * k``
     name (token 0, choice 0) at gate 0: any index in range will do, for
-    they lie past the groups' sum, where the schedule's pseudo-group
-    zero-fills what the kernels write and masks what they read."""
+    they lie past the groups' sum, where the schedule has no visit and
+    the buffers hold nothing anyone may read unmasked (the module
+    docstring).  So do the rows of absent experts, which sort behind the
+    held ones and whose ``slot_row`` names a row there."""
     n, k = flat.shape[0], gates.shape[1]
     slots = jnp.arange(n, dtype=jnp.int32)
     _, order, row_gate = jax.lax.sort(
@@ -359,50 +391,123 @@ def _row_index(flat, gates, rows):
             jnp.pad(row_gate, (0, rows - n)))
 
 
+def _row_buffer(shape, dtype):
+    """A buffer nobody has written: what a loop over the live rows fills
+    as far as it goes.  A kernel that writes nothing, because a
+    ``jnp.zeros`` of ``(32768, 3584)`` is a pass of 0.29 ms on a v5e (three
+    a layer) and 0.4 GB of the benchmark's fullest program (PERF.md §6,
+    PR 39)."""
+    return pl.pallas_call(
+        lambda out_ref: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        interpret=attention._interpret_default(), name="moe_row_buffer")()
+
+
+def _over_live_rows(live, rows, body, carry):
+    """``carry = body(start, chunk, carry)`` over chunks of ``chunk`` rows
+    from row 0 until one ends at or past ``live`` (the last starts where
+    it still fits the ``rows``, so it may run over rows a trip has seen: a
+    body WRITES what it computes, it does not add).  The trip count is the
+    device's; nothing differentiates through the loop (the callers are
+    rules of a ``custom_vjp``)."""
+    chunk = min(ROW_CHUNK, rows)
+
+    def trip(i, carry):
+        return body(jnp.minimum(i * chunk, rows - chunk), chunk, carry)
+
+    return jax.lax.fori_loop(0, (live + chunk - 1) // chunk, trip, carry)
+
+
+def _select_live(picked, slot_row, live):
+    """``picked (T, k, d)``, the rows ``slot_row`` names, with those at or
+    past ``live`` as zeros: a select, exact whatever lies there."""
+    if live is None:
+        return picked
+    return jnp.where((slot_row < live)[..., None], picked,
+                     jnp.zeros((), picked.dtype))
+
+
 @jax.custom_vjp
-def _dispatch(x, row_token, slot_row):
-    """``x (T, d)`` -> rows ``(M, d)``: row ``r`` is ``x[row_token[r]]``;
-    ``slot_row (T, k)`` is the row of each (token, choice)."""
-    return _take(x, row_token)
+def _dispatch(x, row_token, slot_row, live):
+    """``x (T, d)`` -> rows ``(M, d)``: row ``r`` is ``x[row_token[r]]``
+    for ``r < live`` (every row where ``live`` is None); ``slot_row (T,
+    k)`` is the row of each (token, choice)."""
+    if live is None:
+        return _take(x, row_token)
+    rows = row_token.shape[0]
+
+    def gather(start, chunk, out):
+        index = jax.lax.dynamic_slice_in_dim(row_token, start, chunk)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, _take(x, index), start, 0)
+
+    return _over_live_rows(live, rows, gather,
+                           _row_buffer((rows, x.shape[1]), x.dtype))
 
 
-def _dispatch_fwd(x, row_token, slot_row):
-    return _dispatch(x, row_token, slot_row), slot_row
+def _dispatch_fwd(x, row_token, slot_row, live):
+    return _dispatch(x, row_token, slot_row, live), (slot_row, live)
 
 
-def _dispatch_bwd(slot_row, d_rows):
-    d_x = jnp.sum(_take(d_rows, slot_row).astype(jnp.float32), axis=1)
-    return d_x.astype(d_rows.dtype), None, None
+def _dispatch_bwd(res, d_rows):
+    slot_row, live = res
+    picked = _select_live(_take(d_rows, slot_row), slot_row, live)
+    d_x = jnp.sum(picked.astype(jnp.float32), axis=1)
+    return d_x.astype(d_rows.dtype), None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(y_rows, gates, row_token, row_slot, slot_row, row_gate):
+def _combine(y_rows, gates, row_token, row_slot, slot_row, row_gate, live):
     """``out[t] = sum_j gates[t, j] * y_rows[slot_row[t, j]]`` in
-    float32; ``row_slot (M,)`` is the flat (token, choice) of each row
-    and ``row_gate (M,)`` its gate, which the gradient reads."""
-    picked = _take(y_rows, slot_row).astype(jnp.float32)
-    return jnp.einsum("tk,tkd->td", gates, picked).astype(y_rows.dtype)
+    float32, over the choices whose row is live; ``row_slot (M,)`` is the
+    flat (token, choice) of each row and ``row_gate (M,)`` its gate, which
+    the gradient reads."""
+    picked = _select_live(_take(y_rows, slot_row), slot_row, live)
+    return jnp.einsum("tk,tkd->td", gates,
+                      picked.astype(jnp.float32)).astype(y_rows.dtype)
 
 
-def _combine_fwd(y_rows, gates, row_token, row_slot, slot_row, row_gate):
-    return (_combine(y_rows, gates, row_token, row_slot, slot_row, row_gate),
-            (y_rows, gates, row_token, row_slot, row_gate))
+def _combine_fwd(y_rows, gates, row_token, row_slot, slot_row, row_gate,
+                 live):
+    return (_combine(y_rows, gates, row_token, row_slot, slot_row, row_gate,
+                     live),
+            (y_rows, gates, row_token, row_slot, row_gate, live))
 
 
 def _combine_bwd(res, d_out):
-    y_rows, gates, row_token, row_slot, row_gate = res
-    n = gates.size
-    # One gather serves both gradients: the gate's is each row's product
-    # with ITS token's cotangent, taken in row order and then put back
-    # in (token, choice) order.  The rows past ``n`` get gate 0.
-    d_out_rows = _take(d_out, row_token).astype(jnp.float32)
-    d_rows = (d_out_rows * row_gate[:, None]).astype(y_rows.dtype)
-    d_row_gate = jnp.sum(y_rows.astype(jnp.float32) * d_out_rows, axis=-1)
+    y_rows, gates, row_token, row_slot, row_gate, live = res
+    n, rows = gates.size, row_token.shape[0]
+
+    def weigh(row_token, row_gate, y_rows):
+        # One gather serves both gradients: the gate's is each row's
+        # product with ITS token's cotangent, taken in row order and then
+        # put back in (token, choice) order.
+        d_out_rows = _take(d_out, row_token).astype(jnp.float32)
+        return ((d_out_rows * row_gate[:, None]).astype(y_rows.dtype),
+                jnp.sum(y_rows.astype(jnp.float32) * d_out_rows, axis=-1))
+
+    if live is None:
+        d_rows, d_row_gate = weigh(row_token, row_gate, y_rows)
+    else:
+        def chunk_of(start, chunk, carry):
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, start, chunk)
+            put = lambda whole, part: jax.lax.dynamic_update_slice_in_dim(
+                whole, part, start, 0)
+            d_rows, d_row_gate = weigh(cut(row_token), cut(row_gate),
+                                       cut(y_rows))
+            below = start + jnp.arange(chunk, dtype=jnp.int32) < live
+            return (put(carry[0], d_rows),
+                    put(carry[1], jnp.where(below, d_row_gate, 0.0)))
+
+        # a row that is not live moves no gate: its gradient is 0
+        d_rows, d_row_gate = _over_live_rows(
+            live, rows, chunk_of, (_row_buffer(y_rows.shape, y_rows.dtype),
+                                   jnp.zeros((rows,), jnp.float32)))
     d_gates = _place(d_row_gate[:n], row_slot[:n]).reshape(gates.shape)
-    return d_rows, d_gates.astype(gates.dtype), None, None, None, None
+    return d_rows, d_gates.astype(gates.dtype), None, None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -440,8 +545,10 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
     and float32 scalars ``aux_loss`` (load balancing), ``z_loss``,
     ``load_max_over_mean`` (the busiest expert's assignments over the
     mean), ``dropped`` (assignments to an expert that is held and that
-    reached none: 0) and ``held_share`` (the assignments to held experts
-    over all of them), beside ``counts (E,)``, every expert's assignments.
+    reached none: 0), ``held_share`` (the assignments to held experts
+    over all of them) and ``rows_visited_share`` (the rows of the tiles the
+    kernels visit, a tile once for each group in it, over the buffer's
+    rows), beside ``counts (E,)``, every expert's assignments.
     ``router_w (d, E)``; ``w_gate``/``w_up (E', d, m')``, ``w_down (E',
     m', d)`` — all the experts, or the ``E'`` of them from ``first_expert``
     on that THIS chip holds of a layer divided over several (the router
@@ -502,7 +609,7 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
                 first = first + jax.lax.axis_index(expert_axis) * local
             flat = (flat - first) % e
             group_sizes = jax.lax.dynamic_slice(assigned, (first,), (local,))
-        tile = tile or choose_tiles(t * k, local)
+        tile = tile or choose_tiles(t * k * local // e, local)
         rows = -(-t * k // tile) * tile
         sched = make_schedule(group_sizes, rows, tile)
         row_token, row_slot, slot_row, row_gate = _row_index(
@@ -512,15 +619,20 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
         sched, row_token, row_slot, slot_row, row_gate = checkpoint_name(
             (sched, row_token, row_slot, slot_row, row_gate),
             "moe_row_index")
-        x_rows = _dispatch(h, row_token, slot_row)
+        # every row is live by construction where every expert is held
+        live = None if local == e else sched.offsets[local]
+        x_rows = _dispatch(h, row_token, slot_row, live)
         # rows the schedule gives a held expert, against the choices that
         # name one (all of them where every expert is held somewhere)
-        reached = _psum(jnp.sum(group_sizes).astype(jnp.float32),
-                        tuple(token_axes) + ranks)
+        shards = tuple(token_axes) + ranks
+        reached = _psum(jnp.sum(group_sizes).astype(jnp.float32), shards)
         held = local * (jax.lax.psum(1, ranks) if ranks else 1)
         wanted = tokens * k if held == e else _psum(jnp.sum(
-            (flat < local).astype(jnp.float32)), tuple(token_axes) + ranks)
+            (flat < local).astype(jnp.float32)), shards)
         dropped = wanted - reached
+        # the row tiles the kernels visit over the buffer's, a shard
+        visited = _psum(sched.num_visits[0].astype(jnp.float32) * tile / rows,
+                        shards) / (jax.lax.psum(1, shards) if shards else 1)
 
     with jax.named_scope("moe_experts"):
         product = functools.partial(
@@ -531,8 +643,8 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
 
     with jax.named_scope("moe_combine"):
         y = _psum(_combine(y_rows, gates, row_token, row_slot, slot_row,
-                           row_gate), sum_axes)
+                           row_gate, live), sum_axes)
         out = ((x + y) if residual else y).reshape(shape)
     return out, {"aux_loss": aux, "z_loss": z, "load_max_over_mean": load,
                  "dropped": dropped, "held_share": reached / (tokens * k),
-                 "counts": counts}
+                 "rows_visited_share": visited, "counts": counts}

@@ -30,17 +30,35 @@ def _layer_inputs(seed=0, router_scale=0.5):
             jax.random.normal(ks[4], (E, M, D)) * 0.2)
 
 
-def _per_token_loop(x, norm, router, w_gate, w_up, w_down, k=K):
-    """Every token through each of its k experts, one choice at a time."""
+def _per_token_loop(x, norm, router, w_gate, w_up, w_down, k=K, first=0):
+    """Every token through each of its k experts, one choice at a time;
+    of the chip that holds the ``w_gate.shape[0]`` experts from ``first``
+    on, a choice of an absent expert adds nothing."""
+    held = w_gate.shape[0]
     h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * norm
     gates, experts = jax.lax.top_k(jax.nn.softmax(h @ router, -1), k)
     out = x
     for j in range(k):
-        a = jnp.einsum("td,tdm->tm", h, w_gate[experts[:, j]])
-        b = jnp.einsum("td,tdm->tm", h, w_up[experts[:, j]])
-        out = out + gates[:, j:j + 1] * jnp.einsum(
-            "tm,tmd->td", jax.nn.silu(a) * b, w_down[experts[:, j]])
+        mine = (experts[:, j] >= first) & (experts[:, j] < first + held)
+        e = jnp.clip(experts[:, j] - first, 0, held - 1)
+        a = jnp.einsum("td,tdm->tm", h, w_gate[e])
+        b = jnp.einsum("td,tdm->tm", h, w_up[e])
+        y = jnp.einsum("tm,tmd->td", jax.nn.silu(a) * b, w_down[e])
+        out = out + jnp.where(mine[:, None], gates[:, j:j + 1] * y, 0.0)
     return out
+
+
+def _assert_gradients_equal(layer, loop, args):
+    """Every gradient of ``sum(layer(...) ** 2)`` against the loop's."""
+    got = jax.grad(lambda *a: jnp.sum(layer(*a) ** 2),
+                   argnums=range(6))(*args)
+    ref = jax.grad(lambda *a: jnp.sum(loop(*a) ** 2),
+                   argnums=range(6))(*args)
+    for name, g, r in zip(NAMES, got, ref):
+        assert bool(jnp.isfinite(g).all()), name
+        assert float(jnp.abs(g - r).max()) < 1e-6 * float(
+            jnp.abs(r).max()) + 1e-6, name
+    return got
 
 
 @pytest.mark.parametrize("tile", [16, 128], ids=["tile16", "tile128"])
@@ -53,13 +71,7 @@ def test_layer_equals_a_per_token_loop(tile):
     want = _per_token_loop(*args)
     assert float(jnp.abs(out - want).max()) < 5e-6
     assert float(stats["dropped"]) == 0
-    got = jax.grad(lambda *a: jnp.sum(layer(*a)[0] ** 2),
-                   argnums=range(6))(*args)
-    ref = jax.grad(lambda *a: jnp.sum(_per_token_loop(*a) ** 2),
-                   argnums=range(6))(*args)
-    for name, g, r in zip(NAMES, got, ref):
-        assert float(jnp.abs(g - r).max()) < 1e-6 * float(
-            jnp.abs(r).max()) + 1e-6, name
+    _assert_gradients_equal(lambda *a: layer(*a)[0], _per_token_loop, args)
 
 
 def test_group_sizes_sum_to_all_assignments_and_fit_no_tile():
@@ -70,7 +82,7 @@ def test_group_sizes_sum_to_all_assignments_and_fit_no_tile():
     visits = int(sched.num_visits[0])
     groups, tiles = (np.asarray(a)[:visits] for a in sched[1:3])
     offsets = np.asarray(sched.offsets)
-    assert offsets[-1] == 288 and (np.diff(offsets)[:E] == sizes).all()
+    assert offsets[-1] == 288 and (np.diff(offsets) == sizes).all()
     # every row of every group lies in a tile one of its visits names
     for g in range(E):
         rows = np.arange(offsets[g], offsets[g + 1])
@@ -86,6 +98,11 @@ def test_tile_keeps_executed_rows_within_the_bound():
     assert moe.executed_rows(131072, 64, 512) > 1.15 * 131072
     assert moe.choose_tiles(131072, 8) == 512
     assert moe.choose_tiles(256, 64) == 128  # none fits: the smallest
+    # one chip's share of eight at 8192 tokens x 4 is given the rows
+    # EXPECTED live, 4096 in 8 groups, and not the buffer's 32768: the
+    # smallest tile, 39 visits of 128 rows for 4096 needed
+    assert moe.choose_tiles(8192 * 4 * 8 // 64, 8) == 128
+    assert moe.executed_rows(4096, 8, 128) == 39 * 128
 
 
 def test_every_token_to_the_same_experts():
@@ -108,6 +125,138 @@ def test_every_token_to_the_same_experts():
     for g in grads[3:]:
         assert float(jnp.abs(g[K:]).max()) == 0.0
         assert all(float(jnp.abs(g[e]).max()) > 0 for e in range(K))
+
+
+def _real_visits(sched):
+    visits = int(sched.num_visits[0])
+    return (visits, np.asarray(sched.group_ids)[:visits],
+            np.asarray(sched.tile_ids)[:visits])
+
+
+@pytest.mark.parametrize("sizes", [
+    [40, 30, 36, 22],      # every row of the buffer is live
+    [5, 3, 4, 4],          # an eighth
+    [0, 0, 0, 0],          # none
+    [0, 128, 0, 0],        # one group holds every row
+    [16, 0, 16, 9],        # the last group ends inside a tile
+    [16, 16, 0, 0],        # the live rows end on a tile's edge
+], ids=["all", "eighth", "none", "one_group", "inside_a_tile", "on_an_edge"])
+def test_schedule_ends_at_the_held_groups_sum(sizes):
+    """No visit past the live rows: at most a visit a live tile and one
+    more a group, none of a group that does not exist, every held group's
+    rows under exactly the visits that name it, and the padding names the
+    last real visit again, so the pipeline moves no block for it."""
+    rows, tile, groups = 128, 16, len(sizes)
+    sched = moe.make_schedule(jnp.asarray(sizes), rows, tile)
+    visits, group_ids, tile_ids = _real_visits(sched)
+    live = sum(sizes)
+    offsets = np.asarray(sched.offsets)
+    assert offsets.shape == (groups + 1,) and offsets[-1] == live
+    assert groups <= visits <= -(-live // tile) + groups
+    assert sched.group_ids.shape == (rows // tile + groups,)
+    assert (np.asarray(sched.group_ids) < groups).all()
+    assert (np.asarray(sched.tile_ids) < rows // tile).all()
+    for g, size in enumerate(sizes):
+        mine = tile_ids[group_ids == g]
+        assert len(mine) == len(set(mine)) >= 1  # an empty group keeps one
+        if size:
+            held = np.arange(offsets[g], offsets[g + 1])
+            assert sorted(mine) == sorted(set(held // tile))
+    assert (np.diff(tile_ids) >= 0).all() and (np.diff(group_ids) >= 0).all()
+    # no real visit of a tile that holds no live row, but an empty group's
+    assert all(t * tile < max(live, 1) or sizes[g] == 0
+               for g, t in zip(group_ids, tile_ids))
+    assert (np.asarray(sched.group_ids)[visits:] == group_ids[-1]).all()
+    assert (np.asarray(sched.tile_ids)[visits:] == tile_ids[-1]).all()
+
+
+def _poisoned(monkeypatch):
+    """Every buffer the share's path allocates or a kernel leaves
+    unvisited holds NaN past the live rows BEFORE anyone reads it: the
+    row buffers under the two loops, and the grouped products' outputs
+    (interpret mode hands out NaN there already; said again, so the test
+    does not rest on it)."""
+    monkeypatch.setattr(
+        moe, "_row_buffer", lambda shape, dtype: jnp.full(shape, jnp.nan,
+                                                          dtype))
+    gmm = moe._gmm
+
+    def gmm_with_a_poisoned_tail(lhs, rhs, sched, *rest):
+        out = gmm(lhs, rhs, sched, *rest)
+        row = jnp.arange(out.shape[0])[:, None]
+        return jnp.where(row < sched.offsets[-1], out, jnp.nan)
+
+    monkeypatch.setattr(moe, "_gmm", gmm_with_a_poisoned_tail)
+
+
+@pytest.mark.parametrize("first,held,tile", [
+    (0, 2, 16), (2, 3, 16), (5, 3, 128), (6, 2, 16), (0, 1, None)],
+    ids=["first2", "middle3", "last3_tile128", "last2", "one_default_tile"])
+def test_share_equals_the_per_token_loop_on_poisoned_tails(
+        first, held, tile, monkeypatch):
+    """A chip's share of the layer (``first_expert``, ``E' < E``): value
+    and EVERY gradient against the per-token loop, with the buffers'
+    tails poisoned: nothing may read a row past the live ones unmasked."""
+    _poisoned(monkeypatch)
+    args = _layer_inputs()
+    cut = lambda a: tuple(a[:3]) + tuple(w[first:first + held]
+                                         for w in a[3:])
+    layer = lambda *a: moe.moe_block(*cut(a), num_selected=K, tile=tile,
+                                     first_expert=first)
+    loop = lambda *a: _per_token_loop(*cut(a), first=first)
+    out, stats = layer(*args)
+    assert float(jnp.abs(out - loop(*args)).max()) < 5e-6
+    assert float(stats["dropped"]) == 0
+    assert 0 < float(stats["held_share"]) < 1
+    got = _assert_gradients_equal(lambda *a: layer(*a)[0], loop, args)
+    for g in got[3:]:  # the absent experts' tensors got no gradient
+        assert float(jnp.abs(g[:first]).max(initial=0)) == 0
+        assert float(jnp.abs(g[first + held:]).max(initial=0)) == 0
+
+
+@pytest.mark.parametrize("chunk", [1024, 64], ids=["one_chunk", "chunks"])
+def test_share_that_every_token_chooses_is_exact_at_full_buffer(
+        chunk, monkeypatch):
+    """There is no capacity: when every token chooses only held experts
+    the live rows are ALL ``T * k`` of the buffer, the loops run to its
+    end, nothing is dropped and value and gradients are the loop's."""
+    _poisoned(monkeypatch)
+    monkeypatch.setattr(moe, "ROW_CHUNK", chunk)
+    x, norm, router, w_gate, w_up, w_down = _layer_inputs()
+    x = jnp.abs(x)
+    router = jnp.zeros((D, E)).at[:, 2:2 + K].set(
+        1.0 + 0.1 * jnp.arange(K))       # everyone to experts 2, 3, 4
+    args = (x, jnp.ones_like(norm), router, w_gate, w_up, w_down)
+    cut = lambda a: tuple(a[:3]) + tuple(w[2:6] for w in a[3:])
+    layer = lambda *a: moe.moe_block(*cut(a), num_selected=K, tile=16,
+                                     first_expert=2)
+    loop = lambda *a: _per_token_loop(*cut(a), first=2)
+    out, stats = layer(*args)
+    assert float(stats["held_share"]) == 1.0
+    assert float(stats["dropped"]) == 0
+    assert float(jnp.abs(out - loop(*args)).max()) < 5e-6
+    _assert_gradients_equal(lambda *a: layer(*a)[0], loop, args)
+
+
+@pytest.mark.parametrize("first,held", [(0, E), (0, 2), (5, 3)],
+                         ids=["every_expert", "first2", "last3"])
+def test_rows_visited_share_reads_what_the_schedule_says(first, held):
+    """The counter is the schedule's own: real visits x tile over the
+    buffer's rows — about 1 + groups x tile / rows where every expert is
+    held, about the held share where few are."""
+    args = _layer_inputs()
+    tile, rows = 16, T * K
+    _, stats = moe.moe_block(
+        *args[:3], *(w[first:first + held] for w in args[3:]),
+        num_selected=K, tile=tile, first_expert=first)
+    sizes = np.bincount(np.asarray(_seeded_experts()).reshape(-1),
+                        minlength=E)[first:first + held]
+    sched = moe.make_schedule(jnp.asarray(sizes), rows, tile)
+    want = int(sched.num_visits[0]) * tile / rows
+    assert float(stats["rows_visited_share"]) == pytest.approx(want)
+    live = sizes.sum()
+    assert live / rows <= want <= (live + (held + 1) * tile) / rows
+    assert (want > 1.0) == (held == E)
 
 
 def _seeded_experts():
@@ -164,8 +313,8 @@ def test_row_index_is_in_range_and_its_own_inverse(case):
                                            tile).offsets)
     for g in range(local):
         assert (groups[offsets[g]:offsets[g + 1]] == g).all()
-    # other ranks' rows and the padding: the pseudo-group's, masked
-    assert offsets[local] == sizes.sum() <= n and offsets[-1] == rows
+    # other ranks' rows and the padding: past the schedule's end
+    assert offsets[-1] == offsets[local] == sizes.sum() <= n <= rows
     assert (groups[offsets[local]:] >= local).all()
     assert (row_slot[n:] == 0).all()
     # each row's gate rode along in the sort; the padding's is 0
@@ -174,35 +323,49 @@ def test_row_index_is_in_range_and_its_own_inverse(case):
 
 
 def _plain_formulation():
-    """The four gathers as they were before they promised anything
-    (``jnp.take``'s fill mode, scalars gathered one by one): what the
-    layer must still compute, to the bit."""
+    """The four gathers as they were before they promised anything or
+    stopped anywhere (``jnp.take``'s fill mode over EVERY row of the
+    buffer, scalars gathered one by one; of a share, the rows that are
+    not live selected away where they are read): what the layer must
+    still compute, to the bit."""
     take = functools.partial(jnp.take, axis=0)
 
+    def live_only(picked, row, live):
+        if live is None:
+            return picked
+        return jnp.where((row < live).reshape(row.shape + (1,) * (
+            picked.ndim - row.ndim)), picked, 0.0)
+
     @jax.custom_vjp
-    def dispatch(x, row_token, slot_row):
+    def dispatch(x, row_token, slot_row, live):
         return take(x, row_token)
 
-    def dispatch_bwd(slot_row, d_rows):
-        d_x = jnp.sum(take(d_rows, slot_row).astype(jnp.float32), axis=1)
-        return d_x.astype(d_rows.dtype), None, None
+    def dispatch_bwd(res, d_rows):
+        slot_row, live = res
+        picked = live_only(take(d_rows, slot_row), slot_row, live)
+        d_x = jnp.sum(picked.astype(jnp.float32), axis=1)
+        return d_x.astype(d_rows.dtype), None, None, None
 
-    dispatch.defvjp(lambda x, rt, sr: (dispatch(x, rt, sr), sr),
-                    dispatch_bwd)
+    dispatch.defvjp(lambda x, rt, sr, live: (dispatch(x, rt, sr, live),
+                                             (sr, live)), dispatch_bwd)
 
     @jax.custom_vjp
-    def combine(y_rows, gates, row_token, row_slot, slot_row, row_gate):
-        picked = take(y_rows, slot_row).astype(jnp.float32)
-        return jnp.einsum("tk,tkd->td", gates, picked).astype(y_rows.dtype)
+    def combine(y_rows, gates, row_token, row_slot, slot_row, row_gate,
+                live):
+        picked = live_only(take(y_rows, slot_row), slot_row, live)
+        return jnp.einsum("tk,tkd->td", gates,
+                          picked.astype(jnp.float32)).astype(y_rows.dtype)
 
     def combine_bwd(res, d_out):
-        y_rows, gates, row_token, row_slot, slot_row, _ = res
+        y_rows, gates, row_token, row_slot, slot_row, _, live = res
         d_out_rows = take(d_out, row_token).astype(jnp.float32)
         row_gate = jnp.take(gates.reshape(-1), row_slot)
         d_rows = (d_out_rows * row_gate[:, None]).astype(y_rows.dtype)
-        d_row_gate = jnp.sum(y_rows.astype(jnp.float32) * d_out_rows, -1)
+        d_row_gate = live_only(
+            jnp.sum(y_rows.astype(jnp.float32) * d_out_rows, -1),
+            jnp.arange(row_token.shape[0]), live)
         d_gates = jnp.take(d_row_gate, slot_row)
-        return d_rows, d_gates.astype(gates.dtype), None, None, None, None
+        return (d_rows, d_gates.astype(gates.dtype)) + (None,) * 5
 
     combine.defvjp(lambda *a: (combine(*a), a), combine_bwd)
     return dispatch, combine
