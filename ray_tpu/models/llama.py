@@ -1,6 +1,7 @@
 """Flagship model family: Llama-style decoder LM, TPU-first.  A layer is a
 MIXER (softmax attention | latent attention | a Mamba-2 state-space mixer |
-a gated delta-rule linear-attention mixer) followed by an FFN (dense SwiGLU
+a gated delta-rule linear-attention mixer | a gated short convolution)
+followed by an FFN (dense SwiGLU
 | dropless experts with or without a shared expert), each on a RESIDUAL
 (one stream that every block adds to | ``hc_mult`` streams mixed round
 every block by learned doubly stochastic maps): mixer x FFN x residual, and
@@ -49,6 +50,12 @@ TPU-first choices:
   layers (the softmax mixer under its other public name), and the OLMo 2
   family's block, which norms what a block ADDS (``block_norm="output"``:
   ``x + norm(f(x))``) where every other model norms what it reads.
+- a gated short-convolution mixer (``ops/ssm.py::gated_short_conv``: ``C
+  * conv(B * x)``, three taps a channel, no bias, no activation) where
+  ``layer_types`` says ``conv``, beside attention layers with a QK-norm
+  over EACH head (``qk_head_norm``; ``qk_norm`` is over the whole
+  projection) and expert layers behind leading dense ones: a model whose
+  runs differ in mixer AND FFN (LFM2-8B-A1B: five scans at depth 8).
 
 Reference counterpart: none in Ray core (no tensor ops); RLlib's model zoo
 (``rllib/models/catalog.py``) plays the "models shipped with the framework"
@@ -78,7 +85,8 @@ from ray_tpu.ops.layers import (
 )
 from ray_tpu.ops.delta import delta_chunked
 from ray_tpu.ops.moe import moe_block, update_selection_bias
-from ray_tpu.ops.ssm import causal_conv1d, gated_rms_norm, ssd_chunked
+from ray_tpu.ops.ssm import (
+    causal_conv1d, gated_rms_norm, gated_short_conv, ssd_chunked)
 from ray_tpu.parallel.mesh import (
     AXIS_DP, AXIS_EP, AXIS_FSDP, AXIS_SP, AXIS_TP,
 )
@@ -104,14 +112,16 @@ class LlamaConfig:
     num_experts: int = 0              # 0 = dense FFN
     num_selected: int = 2             # experts a token goes to (all of them)
     norm_topk_prob: bool = False      # renormalise the selected gates
+    topk_norm_eps: float = 0.0        # ... over their sum plus this
     aux_loss_coef: float = 0.01       # load-balancing loss
     z_loss_coef: float = 0.0          # router z-loss
     norm_eps: float = 1e-6            # every RMSNorm
     qk_norm: bool = False             # RMSNorm over the q and k projections
+    qk_head_norm: bool = False        # ... over EACH head's q and k instead
     remat: bool = True
     # The mixer of each layer, "attention" (or "full_attention") | "mamba"
-    # | "linear_attention"; only the first ``num_layers`` entries are the
-    # model, empty = attention everywhere.
+    # | "linear_attention" | "conv"; only the first ``num_layers`` entries
+    # are the model, empty = attention everywhere.
     layer_types: Tuple[str, ...] = ()
     ssm_heads: int = 0                # Mamba-2: heads x head_dim = inner width
     ssm_head_dim: int = 64
@@ -160,6 +170,7 @@ class LlamaConfig:
     gdn_value_dim: int = 128
     gdn_conv: int = 4                 # width of the causal depthwise conv
     gdn_neg_eigval: bool = False      # beta in (0, 2): eigenvalues (-1, 1)
+    sconv_width: int = 3              # taps of the gated short convolution
     # Where a block's RMSNorm sits: "input", x + f(norm(x)), or "output",
     # x + norm(f(x)) with the same weight on what the block adds.
     block_norm: str = "input"
@@ -179,10 +190,13 @@ class LlamaConfig:
         if self.block_norm not in ("input", "output"):
             raise ValueError(f"block_norm {self.block_norm!r}")
         if self.block_norm == "output" and (
-                self.num_experts or "mamba" in self.layer_types):
+                self.num_experts or {"mamba", "conv"} & set(self.layer_types)):
             raise NotImplementedError(
                 "block_norm='output' is implemented for the softmax, latent "
                 "and delta-rule mixers and the dense FFN")
+        if self.qk_norm and self.qk_head_norm:
+            raise ValueError("qk_norm is over the whole projection, "
+                             "qk_head_norm over each head: one of the two")
         unknown = set(self.layer_types) - set(_MIXERS)
         if unknown:
             raise ValueError(
@@ -307,6 +321,9 @@ def _attention_shapes(cfg: LlamaConfig):
     if cfg.qk_norm:  # over the whole projection, before heads and RoPE
         shapes.update({"q_norm": ((h,), ("layer", "heads")),
                        "k_norm": ((kvd,), ("layer", "kv_heads"))})
+    if cfg.qk_head_norm:  # over each head, ONE weight of a head's size
+        shapes.update({"q_norm": ((cfg.head_dim,), ("layer", "head_dim")),
+                       "k_norm": ((cfg.head_dim,), ("layer", "head_dim"))})
     return shapes
 
 
@@ -371,6 +388,20 @@ def _delta_shapes(cfg: LlamaConfig):
     }
 
 
+def _conv_shapes(cfg: LlamaConfig):
+    """A gated short-convolution mixer: ``sconv_in`` gives [B | C | x]
+    side by side, each as wide as the model (the published layout of
+    ``in_proj``); the convolution has ``sconv_width`` taps a channel and
+    no bias."""
+    d = cfg.embed_dim
+    return {
+        "sconv_norm": ((d,), ("layer", "embed")),
+        "sconv_in": ((d, 3 * d), ("layer", "kernel_in", "sconv_inner")),
+        "sconv_w": ((cfg.sconv_width, d), ("layer", None, "sconv_inner")),
+        "sconv_out": ((d, d), ("layer", "sconv_inner", "kernel_in")),
+    }
+
+
 def _dense_shapes(d: int, m: int, prefix: str = "w_"):
     return {
         prefix + "gate": ((d, m), ("layer", "kernel_in", "mlp")),
@@ -422,7 +453,7 @@ def _residual_shapes(cfg: LlamaConfig):
 
 _MIXER_SHAPES = {"attention": _attention_shapes, "latent": _latent_shapes,
                  "mamba": _mamba_shapes, "full_attention": _attention_shapes,
-                 "linear_attention": _delta_shapes}
+                 "linear_attention": _delta_shapes, "conv": _conv_shapes}
 
 
 def _layer_shapes(cfg: LlamaConfig, kind=("attention", "dense")
@@ -485,7 +516,7 @@ _SSM_INIT = {
     "conv_w": ("conv", "ssm_conv"), "conv_b": ("conv", "ssm_conv"),
     "dt_bias": ("dt", None), "A_log": ("A", None), "D": ("D", None),
     "gdn_conv_w": ("conv", "gdn_conv"), "gdn_dt_bias": ("dt", None),
-    "gdn_A_log": ("A", None)}
+    "gdn_A_log": ("A", None), "sconv_w": ("conv", "sconv_width")}
 
 
 def _ssm_init(name: str, key: jax.Array, shape, cfg: LlamaConfig):
@@ -796,7 +827,8 @@ def _moe(x, lp, cfg: LlamaConfig, mesh: Optional[Mesh], cst,
     the TPU lowering refuses for a Mosaic kernel."""
     block = functools.partial(
         moe_block, num_selected=cfg.num_selected, norm_eps=cfg.norm_eps,
-        norm_topk_prob=cfg.norm_topk_prob, scoring=cfg.router_scoring,
+        norm_topk_prob=cfg.norm_topk_prob,
+        topk_norm_eps=cfg.topk_norm_eps, scoring=cfg.router_scoring,
         gate_scale=cfg.routed_scaling_factor,
         first_expert=cfg.first_expert, residual=residual)
     bias = (lp["router_bias"],) if cfg.select_bias else ()
@@ -911,6 +943,9 @@ def _attention_mixer(x, aux, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
         q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
         k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.qk_head_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
         v = (h @ lp["wv"].astype(cfg.dtype)).reshape(
             b, s, cfg.num_kv_heads, cfg.head_dim)
         if cfg.position_embedding == "rope":
@@ -1050,6 +1085,33 @@ def _delta_mixer(x, aux, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
                     aux[GDN_STATE_ABSMAX], peak)}
 
 
+def _conv_mixer(x, aux, lp, cfg: LlamaConfig, mesh, cst, sp_manual,
+                residual: bool = True):
+    """A gated short-convolution mixer on the residual stream (LFM2's
+    ``conv`` layers; ``ops/ssm.py::gated_short_conv``): scopes ``sconv_in``
+    (norm, the one [B | C | x] projection), ``sconv_gate`` (``C * conv(B *
+    x)``: a causal depthwise convolution of ``sconv_width`` taps with no
+    bias and no activation between two elementwise gates), ``sconv_out``
+    (the output projection, the add).  Its state is the convolution's
+    tail alone, ``sconv_width - 1`` tokens; elementwise and local in time,
+    so under a mesh the partitioner splits it by rows as it does a norm
+    (``sconv_inner`` maps to no mesh axis); not under a split of the
+    sequence."""
+    if sp_manual:
+        raise NotImplementedError(
+            "the short convolution needs the tail of the sequence shard "
+            "before its own: not under a manual 'sp' region")
+    with jax.named_scope("sconv_in"):
+        h = rms_norm(x, lp["sconv_norm"], cfg.norm_eps)
+        bcx = cst(h @ lp["sconv_in"].astype(cfg.dtype),
+                  ("batch", "seq", "sconv_inner"))
+    with jax.named_scope("sconv_gate"):
+        y = gated_short_conv(bcx, lp["sconv_w"])
+    with jax.named_scope("sconv_out"):
+        return _add(x, y @ lp["sconv_out"].astype(cfg.dtype), cfg, cst,
+                    residual), aux
+
+
 def _swiglu_ffn(h, lp, cfg: LlamaConfig, prefix: str = "w_"):
     return swiglu(h @ lp[prefix + "gate"].astype(cfg.dtype),
                   h @ lp[prefix + "up"].astype(cfg.dtype)
@@ -1085,7 +1147,7 @@ def _moe_ffn(x, aux, lp, cfg: LlamaConfig, mesh, cst, residual: bool = True):
 
 _MIXERS = {"attention": _attention_mixer, "latent": _latent_mixer,
            "mamba": _mamba_mixer, "full_attention": _attention_mixer,
-           "linear_attention": _delta_mixer}
+           "linear_attention": _delta_mixer, "conv": _conv_mixer}
 _FFNS = {"dense": _dense_ffn, "moe": _moe_ffn}
 
 

@@ -5,8 +5,8 @@ CPUs/GPUs and moves bytes; math lives in torch/tf — SURVEY.md §5
 "Long-context / sequence parallelism: absent").  In a TPU-native framework
 the hot ops are part of the framework: flash attention on the MXU, ring
 attention over the ICI 'sp' axis, Ulysses all-to-all attention, MoE routing,
-the state-space scan (``ops/ssm.py``) and the gated delta rule
-(``ops/delta.py``) of the two kinds of recurrent mixer.
+the state-space scan and the gated short convolution (``ops/ssm.py``) and
+the gated delta rule (``ops/delta.py``) of the recurrent mixers.
 Every op has a pure-XLA reference implementation used for numerics tests and
 as the CPU fallback.
 """

@@ -533,7 +533,8 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
               w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
               select_bias: Optional[jax.Array] = None, *,
               num_selected: int, norm_eps: float = 1e-6,
-              norm_topk_prob: bool = False, tile: Optional[int] = None,
+              norm_topk_prob: bool = False, topk_norm_eps: float = 0.0,
+              tile: Optional[int] = None,
               scoring: str = "softmax", gate_scale: float = 1.0, first_expert: int = 0,
               residual: bool = True,
               token_axes: Sequence[str] = (),
@@ -561,7 +562,9 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
     each logit; ``select_bias (E,)`` is added to the scores for the
     SELECTION only, the gates are the scores themselves and no gradient
     reaches it (``update_selection_bias`` moves it); the gates, renormalised
-    where ``norm_topk_prob``, are multiplied by ``gate_scale``."""
+    where ``norm_topk_prob`` (over their sum plus ``topk_norm_eps``, which
+    a model that guards the division states), are multiplied by
+    ``gate_scale``."""
     shape, d = x.shape, x.shape[-1]
     x = x.reshape(-1, d)
     t, e, k = x.shape[0], router_w.shape[1], num_selected
@@ -584,7 +587,10 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
                     jnp.float32), k)
             gates = jnp.take_along_axis(scores, experts, axis=-1)
         if norm_topk_prob:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            total = jnp.sum(gates, axis=-1, keepdims=True)
+            if topk_norm_eps:  # 0 adds no op to the program
+                total = total + topk_norm_eps
+            gates = gates / total
         if gate_scale != 1.0:
             gates = gates * gate_scale
         flat = experts.reshape(-1)

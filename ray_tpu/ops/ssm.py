@@ -42,6 +42,12 @@ gradient to the cumulative sums (row sums less column sums of ``dM o M``,
 what the decays to and from the chunk's ends collect, and ``<dH, H>`` at
 a chunk's last token).
 
+Beside them the two short causal convolutions of the recurrent mixers:
+``causal_conv1d`` (depthwise, SiLU fused: Mamba-2's and the delta rule's)
+and ``gated_short_conv`` (``C * conv(B * x)``, no activation: LFM2's whole
+mixer between its two projections), plain XLA with their backward passes
+written out over one shared pair of helpers.
+
 Precision: the decays (``dt A``, their cumulative sums, every ``exp``) and
 the state carried across chunks are float32; the operands of the big
 products (``C B^T``, the masked matrix times ``dt x``, ``B^T`` times the
@@ -104,24 +110,74 @@ def _conv_fwd(x, weight, bias):
     return causal_conv1d(x, weight, bias), (x, weight, bias)
 
 
-def _conv_bwd(res, dy):
-    x, weight, bias = res
+def _conv_grads(dpre, x, weight):
+    """What ``dpre``, the gradient to ``_conv_pre``'s output, sends to its
+    input ``x`` (the same convolution run against time) and to ``weight``
+    (``dw_i = sum_t dpre_t x_(t - (k-1) + i)``), both float32."""
     k, s = weight.shape[0], x.shape[1]
-    pre = _conv_pre(x, weight, bias)          # cheap to run again
-    sig = jax.nn.sigmoid(pre)
-    dpre = dy.astype(_F32) * sig * (1.0 + pre * (1.0 - sig))
     w = weight.astype(_F32)
     ahead = jnp.pad(dpre, ((0, 0), (0, k - 1), (0, 0)))
     dx = sum(ahead[:, k - 1 - i:k - 1 - i + s] * w[i] for i in range(k))
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     dw = jnp.stack([jnp.sum(dpre * padded[:, i:i + s].astype(_F32), (0, 1))
                     for i in range(k)])
+    return dx, dw
+
+
+def _conv_bwd(res, dy):
+    x, weight, bias = res
+    pre = _conv_pre(x, weight, bias)          # cheap to run again
+    sig = jax.nn.sigmoid(pre)
+    dpre = dy.astype(_F32) * sig * (1.0 + pre * (1.0 - sig))
+    dx, dw = _conv_grads(dpre, x, weight)
     return (dx.astype(x.dtype), dw.astype(weight.dtype),
             None if bias is None
             else jnp.sum(dpre, (0, 1)).astype(bias.dtype))
 
 
 causal_conv1d.defvjp(_conv_fwd, _conv_bwd)
+
+
+def _gate_split(bcx):
+    """``[B | C | x]`` side by side -> the three, float32."""
+    return tuple(t.astype(_F32) for t in jnp.split(bcx, 3, axis=-1))
+
+
+@jax.custom_vjp
+def gated_short_conv(bcx: jax.Array, weight: jax.Array) -> jax.Array:
+    """The gated short convolution of LFM2's ``conv`` layers: with ``bcx
+    (b, s, 3 d)`` holding ``[B | C | x]`` side by side and ``weight (k,
+    d)``, ``y = C * conv(B * x)`` — the causal depthwise convolution of
+    ``causal_conv1d`` (``weight[k-1]`` meets the current token, zeros
+    before the sequence) with NO bias and NO activation, between two
+    elementwise gates.  Float32 inside, ``bcx.dtype`` out ``(b, s, d)``.
+
+    The backward pass is written out and keeps nothing but the arguments
+    (``z = B * x`` and ``c = conv(z)`` are cheap to make again): ``dC = dy
+    * c``, ``dc = dy * C``, ``dz`` the same convolution of ``dc`` run
+    against time, ``dB = dz * x``, ``dx = dz * B``, ``dw_i = sum_t dc_t
+    z_(t - (k-1) + i)``."""
+    gate_in, gate_out, x = _gate_split(bcx)
+    return (gate_out * _conv_pre(gate_in * x, weight, None)).astype(
+        bcx.dtype)
+
+
+def _gated_fwd(bcx, weight):
+    return gated_short_conv(bcx, weight), (bcx, weight)
+
+
+def _gated_bwd(res, dy):
+    bcx, weight = res
+    gate_in, gate_out, x = _gate_split(bcx)
+    z = gate_in * x
+    dy = dy.astype(_F32)
+    dz, dw = _conv_grads(dy * gate_out, z, weight)
+    d_bcx = jnp.concatenate(
+        [dz * x, dy * _conv_pre(z, weight, None), dz * gate_in], axis=-1)
+    return d_bcx.astype(bcx.dtype), dw.astype(weight.dtype)
+
+
+gated_short_conv.defvjp(_gated_fwd, _gated_bwd)
 
 
 def gated_rms_norm(y: jax.Array, z: jax.Array, weight: jax.Array,

@@ -44,6 +44,8 @@ DEFAULT_RULES: LogicalAxisRules = {
                                   # a tp split has to cut each part (later)
     "gdn_inner": None,            # a delta-rule mixer's inner width: [q|k|v|
                                   # gate|a|b] in one projection, likewise
+    "sconv_inner": None,          # a short-convolution mixer's: [B|C|x] in
+                                  # one projection, likewise
     "stage": AXIS_PP,             # pipeline stages (stacked-stage layout)
     "layer": None,                # scanned-layer leading dim (non-pipelined)
 }

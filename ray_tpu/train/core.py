@@ -39,9 +39,10 @@ tracing.watch_process()
 # layer opens ``ops/moe.py``'s four ``moe_*`` in place of ``ffn`` — and
 # ``ffn`` too for a shared expert —, a Mamba layer the four ``ssm_*`` in
 # place of the three ``attn*``, a delta-rule layer the four ``gdn_*``
-# likewise, a layer of several residual streams ``hc_map`` and ``hc_mix``
-# round each of its blocks, a predicted-ahead module ``mtp_in``), ``step``
-# below opens ``optimizer``.  A device op's ``op_name`` carries exactly one
+# likewise, a short-convolution layer the three ``sconv_*``, a layer of
+# several residual streams ``hc_map`` and ``hc_mix`` round each of its
+# blocks, a predicted-ahead module ``mtp_in``), ``step`` below opens
+# ``optimizer``.  A device op's ``op_name`` carries exactly one
 # of them, wrapped by JAX in the phase: bare or ``jvp(..)`` is the forward
 # pass, under ``rematted_computation`` the rematerialised forward,
 # ``transpose(jvp(..))`` the backward pass (``util.tracing.step_breakdown``).
@@ -57,6 +58,7 @@ STEP_SCOPES = ("embed", "attn_qkv", "attention", "attn_out", "ffn",
                "moe_route", "moe_dispatch", "moe_experts", "moe_combine",
                "ssm_in", "ssm_conv", "ssm_scan", "ssm_out",
                "gdn_in", "gdn_conv", "gdn_scan", "gdn_out",
+               "sconv_in", "sconv_gate", "sconv_out",
                "hc_map", "hc_mix", "mtp_in",
                "lm_head", "loss", "optimizer")
 

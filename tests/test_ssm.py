@@ -314,7 +314,7 @@ def test_layer_types_runs_and_hashing():
     assert LlamaConfig(num_layers=2, layer_types=["mamba"] * 2, ssm_heads=2
                        ).layer_runs == (("mamba", 2),)
     with pytest.raises(ValueError, match="layer_types"):
-        LlamaConfig(num_layers=2, layer_types=["mamba", "conv"])
+        LlamaConfig(num_layers=2, layer_types=["mamba", "hyena"])
     with pytest.raises(ValueError, match="num_layers"):
         LlamaConfig(num_layers=3, layer_types=["mamba", "attention"])
 

@@ -445,3 +445,33 @@ def test_olmo_hybrid_linear_layer_train_step_compiles(one_chip, as_on_chip):
     assert "gdn_scan" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9
+
+
+def test_lfm2_conv_layer_train_step_compiles(one_chip, as_on_chip):
+    """One gated short-convolution layer with its expert FFN of
+    LFM2-8B-A1B as ``lfm2moe-train-s8192`` runs it (the benchmark's
+    configuration file: hidden 2048, 3 taps, experts of 1792 with 16 of
+    32 held), the vocabulary cut, at 8192 positions as a train step: the
+    convolution is plain XLA (shifted slices of a (8192, 6144) array),
+    the grouped kernels are Mosaic's at the new shapes (column blocks of
+    1792, half the rows live); what the chip's compiler makes of both
+    must fit beside the layer's state.  (The attention layers' flash
+    kernels at head 64 are granite's case above.)"""
+    import dataclasses
+
+    cfg = _benchmark_cfg("lfm2-8b-a1b-1of2")
+    assert (cfg.embed_dim, cfg.sconv_width, cfg.head_dim, cfg.qk_head_norm,
+            cfg.mlp_dim, cfg.experts_held, cfg.num_experts) == (
+                2048, 3, 64, True, 1792, 16, 32)
+    cfg = dataclasses.replace(cfg, vocab_size=4096, num_layers=1,
+                              leading_dense=0, layer_types=("conv",))
+    assert cfg.kind_runs == ((("conv", "moe"), 1),)
+    opt = default_optimizer()
+    compiled = make_train_step(cfg, opt).lower(
+        _state_shapes(cfg, opt, one_chip),
+        {"tokens": _shape((1, 8193), jnp.int32, one_chip)}).compile()
+    assert _has_kernel(compiled)
+    text = compiled.as_text()
+    assert "sconv_gate" in text and "moe_experts" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 5e9
