@@ -794,7 +794,7 @@ def _zero_aux(cfg: LlamaConfig):
     aux = {"aux_loss": zero}
     if cfg.num_experts:
         aux.update(z_loss=zero, load_max_over_mean=zero, dropped=zero,
-                   rows_visited_share=zero)
+                   rows_visited_share=zero, token_rows_read_share=zero)
     if cfg.experts_held:  # one chip's share: how much of the rows is here
         aux["held_share"] = zero
     if delta:
@@ -1491,7 +1491,9 @@ def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
                        "z_loss": aux["z_loss"],
                        "moe_load_max_over_mean": aux["load_max_over_mean"],
                        "moe_dropped": aux["dropped"],
-                       "moe_rows_visited_share": aux["rows_visited_share"]}
+                       "moe_rows_visited_share": aux["rows_visited_share"],
+                       "moe_token_rows_read_share":
+                           aux["token_rows_read_share"]}
             if "held_share" in aux:
                 metrics["moe_held_share"] = aux["held_share"]
         if GDN_STATE_ABSMAX in stats:

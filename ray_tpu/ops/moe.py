@@ -17,7 +17,7 @@ imbalance: nothing has a capacity and nothing is dropped.  The layer
 - ``moe_experts``: three grouped products over the ragged groups (row
   ``r`` meets the weights of the group it lies in) and SwiGLU;
 - ``moe_combine``: each token's rows gathered back, weighted by its
-  gates, summed, and added to the residual.
+  gates, summed (``_token_sum``), and added to the residual.
 
 Rows move by gathers in both directions, forward and backward, and every
 gather promises that its indices are in range (``_row_index`` says why
@@ -45,11 +45,16 @@ parallelism, other chips' where this chip holds its share of a layer
 (``first_expert`` and the leading dimension of the expert tensors say
 which) — get no visit of the schedule and no trip of the row-side
 gathers: they hold NOTHING ANYONE MAY READ UNMASKED (what the allocator
-left, NaN in interpret mode).  Whoever can meet one (a token's choice of
-an absent expert names a row there; the last live tile and chunk run
-over) selects by ``row < live``, which is exact on garbage.  Where every
-expert is held (``E' == E``) every row is live by construction, and the
-gathers are the single ones, unmasked.
+left, NaN in interpret mode).  The TOKEN side stops there too: a token's
+choice of an absent expert names a row past the live ones, so the two
+token-side sums (``_token_sum``) sort the choices that name a live row to
+the front, token by token, fetch those rows alone, chunk by chunk, add
+each token's run of them and gather the ``T`` sums; no ``(T, k, d)``
+array of a row a (token, choice) is made.  Whoever can still meet a row
+past the live ones (the last live tile and chunk run over) selects,
+which is exact on garbage.  Where every expert is held (``E' == E``)
+every row is live by construction, and the gathers are the single ones,
+unmasked.
 
 Inside a ``shard_map`` (a Pallas kernel has no partitioning rule) the
 layer takes the names of the mesh axes: tokens are split over
@@ -350,8 +355,9 @@ grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 # ROW-side gathers (``_dispatch``, ``_combine``'s gradient) then stop at
 # it: a loop over chunks of rows whose trip count the device computes,
 # exact at any count up to the buffer's.  The two TOKEN-side ones
-# (``_combine``, ``_dispatch``'s gradient) read a row for every (token,
-# choice) and select by ``row < live``.
+# (``_combine``, ``_dispatch``'s gradient) are one sum, ``_token_sum``,
+# whose work follows the live rows as well: the same loop over the live
+# (token, choice) slots in token order, then one gather of ``T`` rows.
 
 def _take(a, index):
     """``a[index]`` along axis 0, for indices that ARE in range."""
@@ -403,6 +409,12 @@ def _row_buffer(shape, dtype):
         interpret=attention._interpret_default(), name="moe_row_buffer")()
 
 
+def _live_trips(live, rows):
+    """(trips, chunk) of a loop over the live of ``rows`` rows."""
+    chunk = min(ROW_CHUNK, rows)
+    return (live + chunk - 1) // chunk, chunk
+
+
 def _over_live_rows(live, rows, body, carry):
     """``carry = body(start, chunk, carry)`` over chunks of ``chunk`` rows
     from row 0 until one ends at or past ``live`` (the last starts where
@@ -410,21 +422,102 @@ def _over_live_rows(live, rows, body, carry):
     body WRITES what it computes, it does not add).  The trip count is the
     device's; nothing differentiates through the loop (the callers are
     rules of a ``custom_vjp``)."""
-    chunk = min(ROW_CHUNK, rows)
+    trips, chunk = _live_trips(live, rows)
 
     def trip(i, carry):
         return body(jnp.minimum(i * chunk, rows - chunk), chunk, carry)
 
-    return jax.lax.fori_loop(0, (live + chunk - 1) // chunk, trip, carry)
+    return jax.lax.fori_loop(0, trips, trip, carry)
 
 
-def _select_live(picked, slot_row, live):
-    """``picked (T, k, d)``, the rows ``slot_row`` names, with those at or
-    past ``live`` as zeros: a select, exact whatever lies there."""
+def _token_sum(rows, slot_row, weights, live):
+    """``out[t] = sum_j weights[t, j] * rows[slot_row[t, j]]`` over the
+    choices ``j`` whose row is below ``live``: float32 products (the rows
+    alone where ``weights`` is None) and sum, cast to the rows' dtype.
+
+    Where ``live`` is None every row is live: ONE gather of a row a (token,
+    choice) and the sum.  Else the work follows the live rows, and no ``(T,
+    k, d)`` array is made: the live slots in (token, choice) order (one
+    stable sort: a token's live choices are then a RUN of at most ``k``
+    adjacent entries), their rows fetched chunk by chunk
+    (``_over_live_rows``), weighted, and each entry's sum with the entries
+    of its own token before it (``k - 1`` shifted, selected adds: a trip
+    fetches the ``k - 1`` entries before its chunk again) written, rounded
+    once, to a buffer nobody wrote; then ONE gather of ``T`` rows, the END
+    of each token's run, which holds the whole sum (zeros where it has
+    none).  A token's run may cross chunks; the entries past ``live``
+    (dead slots, sorted behind) are computed by the last trip and read by
+    nobody: a sum looks only backwards."""
     if live is None:
-        return picked
-    return jnp.where((slot_row < live)[..., None], picked,
-                     jnp.zeros((), picked.dtype))
+        picked = _take(rows, slot_row).astype(jnp.float32)
+        out = (jnp.sum(picked, axis=1) if weights is None
+               else jnp.einsum("tk,tkd->td", weights, picked))
+        return out.astype(rows.dtype)
+    return _live_token_sum(rows, slot_row, weights, live,
+                           (ROW_CHUNK, attention._interpret_default()))
+
+
+@functools.partial(jax.jit, static_argnums=4, inline=True)
+def _live_token_sum(rows, slot_row, weights, live, traced_under):
+    """``_token_sum`` of a share.  Under ``jit`` for its CACHE, not for a
+    program of its own (``inline``: its equations join the caller's): the
+    rules that call it are traced a dozen times a layer body, and this is
+    17 ms of Python a time where the parent's gather and sum were 0.3 —
+    2.7 s of ``compile.trace_s`` in the cell of five scans, 11 % of its
+    set-up (PERF.md §6, PR 41).  One trace a shape now.  ``traced_under``
+    is the module state the trace reads (the chunk, whether the buffer's
+    kernel is interpreted): part of the cache's key, so a test that changes
+    either is not handed a trace from before."""
+    t, k = slot_row.shape
+    f32 = jnp.float32
+    n, halo = t * k, k - 1
+    below = slot_row < live
+    operands = (jnp.logical_not(below).reshape(-1).astype(jnp.int32),
+                jnp.arange(n, dtype=jnp.int32), slot_row.reshape(-1))
+    if weights is not None:
+        operands += (weights.reshape(-1).astype(f32),)
+    _, slot, *row_and_gate = jax.lax.sort(operands, num_keys=1,
+                                          is_stable=True)
+    # ``halo`` entries of no token in front: the first trip looks back too
+    token = jnp.pad(slot // k, (halo, 0), constant_values=-1)
+    row_and_gate = [jnp.pad(a, (halo, 0)) for a in row_and_gate]
+
+    def runs_of(start, chunk, out):
+        tok, row, *gate = (jax.lax.dynamic_slice_in_dim(a, start, chunk + halo)
+                           for a in (token, *row_and_gate))
+        part = _take(rows, row).astype(f32)
+        if gate:
+            part = part * gate[0][:, None]
+
+        def older(back):  # a select: exact on what a dead row holds
+            here = slice(halo - back, halo - back + chunk)
+            return jnp.where((tok[here] == tok[halo:])[:, None], part[here],
+                             0.0)
+
+        # oldest first: a token's choices in their own order
+        total = functools.reduce(
+            jnp.add, [older(back) for back in range(halo, 0, -1)]
+            + [part[halo:]])
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, total.astype(rows.dtype), start, 0)
+
+    runs = _over_live_rows(live, n, runs_of,
+                           _row_buffer((n, rows.shape[1]), rows.dtype))
+    count = jnp.sum(below, axis=1, dtype=jnp.int32)
+    end = jnp.maximum(jnp.cumsum(count) - 1, 0)
+    return jnp.where((count > 0)[:, None], _take(runs, end),
+                     jnp.zeros((), rows.dtype))
+
+
+def _token_rows_read(live, t, k):
+    """The rows ``_token_sum`` fetches for ``t`` tokens of ``k`` choices
+    over those ``t * k``: its trips' chunks, each with the ``k - 1``
+    entries before it, and the ``t`` at the runs' ends; 1 where every row
+    is live."""
+    if live is None:
+        return jnp.float32(1.0)
+    trips, chunk = _live_trips(live, t * k)
+    return (trips * (chunk + k - 1) + t).astype(jnp.float32) / (t * k)
 
 
 @jax.custom_vjp
@@ -451,9 +544,7 @@ def _dispatch_fwd(x, row_token, slot_row, live):
 
 def _dispatch_bwd(res, d_rows):
     slot_row, live = res
-    picked = _select_live(_take(d_rows, slot_row), slot_row, live)
-    d_x = jnp.sum(picked.astype(jnp.float32), axis=1)
-    return d_x.astype(d_rows.dtype), None, None, None
+    return _token_sum(d_rows, slot_row, None, live), None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -465,9 +556,7 @@ def _combine(y_rows, gates, row_token, row_slot, slot_row, row_gate, live):
     float32, over the choices whose row is live; ``row_slot (M,)`` is the
     flat (token, choice) of each row and ``row_gate (M,)`` its gate, which
     the gradient reads."""
-    picked = _select_live(_take(y_rows, slot_row), slot_row, live)
-    return jnp.einsum("tk,tkd->td", gates,
-                      picked.astype(jnp.float32)).astype(y_rows.dtype)
+    return _token_sum(y_rows, slot_row, gates, live)
 
 
 def _combine_fwd(y_rows, gates, row_token, row_slot, slot_row, row_gate,
@@ -549,7 +638,9 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
     reached none: 0), ``held_share`` (the assignments to held experts
     over all of them) and ``rows_visited_share`` (the rows of the tiles the
     kernels visit, a tile once for each group in it, over the buffer's
-    rows), beside ``counts (E,)``, every expert's assignments.
+    rows) and ``token_rows_read_share`` (the rows ONE token-side sum
+    fetches over the ``T * k`` (token, choice) pairs: 1 where every expert
+    is held), beside ``counts (E,)``, every expert's assignments.
     ``router_w (d, E)``; ``w_gate``/``w_up (E', d, m')``, ``w_down (E',
     m', d)`` — all the experts, or the ``E'`` of them from ``first_expert``
     on that THIS chip holds of a layer divided over several (the router
@@ -637,8 +728,12 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
             (flat < local).astype(jnp.float32)), shards)
         dropped = wanted - reached
         # the row tiles the kernels visit over the buffer's, a shard
+        n_shards = jax.lax.psum(1, shards) if shards else 1
         visited = _psum(sched.num_visits[0].astype(jnp.float32) * tile / rows,
-                        shards) / (jax.lax.psum(1, shards) if shards else 1)
+                        shards) / n_shards
+        # the rows one token-side sum fetches over the (token, choice)
+        # pairs, a shard
+        fetched = _psum(_token_rows_read(live, t, k), shards) / n_shards
 
     with jax.named_scope("moe_experts"):
         product = functools.partial(
@@ -653,4 +748,5 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
         out = ((x + y) if residual else y).reshape(shape)
     return out, {"aux_loss": aux, "z_loss": z, "load_max_over_mean": load,
                  "dropped": dropped, "held_share": reached / (tokens * k),
-                 "rows_visited_share": visited, "counts": counts}
+                 "rows_visited_share": visited,
+                 "token_rows_read_share": fetched, "counts": counts}

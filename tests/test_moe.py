@@ -175,7 +175,9 @@ def _poisoned(monkeypatch):
     unvisited holds NaN past the live rows BEFORE anyone reads it: the
     row buffers under the two loops, and the grouped products' outputs
     (interpret mode hands out NaN there already; said again, so the test
-    does not rest on it)."""
+    does not rest on it).  ``_live_token_sum`` keeps its traces: none
+    from before the poison may serve."""
+    jax.clear_caches()
     monkeypatch.setattr(
         moe, "_row_buffer", lambda shape, dtype: jnp.full(shape, jnp.nan,
                                                           dtype))
@@ -192,12 +194,16 @@ def _poisoned(monkeypatch):
 @pytest.mark.parametrize("first,held,tile", [
     (0, 2, 16), (2, 3, 16), (5, 3, 128), (6, 2, 16), (0, 1, None)],
     ids=["first2", "middle3", "last3_tile128", "last2", "one_default_tile"])
+@pytest.mark.parametrize("chunk", [1024, 40], ids=["one_chunk", "chunks"])
 def test_share_equals_the_per_token_loop_on_poisoned_tails(
-        first, held, tile, monkeypatch):
+        first, held, tile, chunk, monkeypatch):
     """A chip's share of the layer (``first_expert``, ``E' < E``): value
     and EVERY gradient against the per-token loop, with the buffers'
-    tails poisoned: nothing may read a row past the live ones unmasked."""
+    tails poisoned: nothing may read a row past the live ones unmasked.
+    In chunks of 40 the loops over the live rows make several trips and
+    a token's run of live choices crosses them."""
     _poisoned(monkeypatch)
+    monkeypatch.setattr(moe, "ROW_CHUNK", chunk)
     args = _layer_inputs()
     cut = lambda a: tuple(a[:3]) + tuple(w[first:first + held]
                                          for w in a[3:])
@@ -236,6 +242,95 @@ def test_share_that_every_token_chooses_is_exact_at_full_buffer(
     assert float(stats["dropped"]) == 0
     assert float(jnp.abs(out - loop(*args)).max()) < 5e-6
     _assert_gradients_equal(lambda *a: layer(*a)[0], loop, args)
+
+
+def _token_sum_inputs(live, dtype, tokens=41, k=4, d=24, seed=0):
+    """``rows (n, d)`` with NaN from ``live`` on, ``slot_row (tokens, k)``
+    a permutation of the rows in which token 0 has ``k`` live choices,
+    token 1 one and token 2 none (as far as ``live`` allows), the others
+    what the seed deals them, and float32 ``weights``."""
+    n = tokens * k
+    rng = np.random.default_rng(seed)
+    alive, dead = (list(rng.permutation(np.arange(lo, hi)))
+                   for lo, hi in ((0, live), (live, n)))
+    slot_row = np.full((tokens, k), -1)
+
+    def deal(token, pile, count):
+        count = min(count, len(pile))
+        free = np.flatnonzero(slot_row[token] < 0)
+        for j in rng.permutation(free)[:count]:
+            slot_row[token, j] = pile.pop()
+
+    deal(0, alive, k)
+    deal(1, alive, 1), deal(1, dead, k - 1)
+    deal(2, dead, k)
+    rest = list(rng.permutation(alive + dead))
+    for token in range(tokens):
+        deal(token, rest, k)
+    assert sorted(slot_row.reshape(-1)) == list(range(n))
+    rows = rng.standard_normal((n, d)).astype(np.float32)
+    rows[live:] = np.nan
+    weights = rng.uniform(0.1, 1.0, (tokens, k)).astype(np.float32)
+    return (jnp.asarray(rows, dtype), jnp.asarray(slot_row, jnp.int32),
+            jnp.asarray(weights))
+
+
+@pytest.mark.parametrize("chunk", [16, 1024], ids=["chunks", "one_chunk"])
+@pytest.mark.parametrize("weighted", [True, False], ids=["gates", "ones"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("live", [0, 20, 82, 164],
+                         ids=["none", "eighth", "half", "all"])
+def test_token_sum_equals_a_loop_over_the_live_choices(
+        live, dtype, weighted, chunk, monkeypatch):
+    """The one token-side sum of a share (``_combine``'s forward with the
+    gates, ``_dispatch``'s gradient without) against a loop over each
+    token's choices, with every row from ``live`` on AND the buffer of
+    the runs poisoned: no live rows, an eighth, half and all ``T * k`` of
+    them; tokens with 0, 1 and ``k`` live choices; runs that cross the
+    chunks of 16 (164 slots: the last trip runs over the one before it);
+    24 columns."""
+    _poisoned(monkeypatch)
+    monkeypatch.setattr(moe, "ROW_CHUNK", chunk)
+    rows, slot_row, weights = _token_sum_inputs(live, dtype)
+    got = moe._token_sum(rows, slot_row, weights if weighted else None,
+                         jnp.int32(live))
+    assert got.dtype == dtype and got.shape == (41, 24)
+    want = np.zeros((41, 24), np.float32)
+    for t, choices in enumerate(np.asarray(slot_row)):
+        for j, row in enumerate(choices):
+            if row < live:
+                want[t] += np.asarray(rows[row], np.float32) * (
+                    np.float32(weights[t, j]) if weighted else 1.0)
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    if live < 164:  # a token without a live choice reads exact zeros
+        assert (got[2] == 0).all()
+    tol = 1e-6 if dtype == jnp.float32 else 2.0 ** -8
+    assert np.abs(got - want).max() <= tol * max(np.abs(want).max(), 1.0)
+
+
+@pytest.mark.parametrize("chunk", [1024, 32], ids=["one_chunk", "chunks"])
+@pytest.mark.parametrize("first,held", [(0, E), (0, 2), (5, 3)],
+                         ids=["every_expert", "first2", "last3"])
+def test_token_rows_read_share_reads_what_the_index_says(
+        first, held, chunk, monkeypatch):
+    """The counter is the code's own: a token-side sum of a share fetches
+    ``chunk + k - 1`` rows a trip over the live rows and ``T`` at the
+    runs' ends; where every expert is held it is the one gather of a row
+    a (token, choice): 1."""
+    monkeypatch.setattr(moe, "ROW_CHUNK", chunk)
+    args = _layer_inputs()
+    _, stats = moe.moe_block(
+        *args[:3], *(w[first:first + held] for w in args[3:]),
+        num_selected=K, tile=16, first_expert=first)
+    live = int(np.bincount(np.asarray(_seeded_experts()).reshape(-1),
+                           minlength=E)[first:first + held].sum())
+    trip = min(chunk, T * K)
+    want = 1.0 if held == E else (
+        -(-live // trip) * (trip + K - 1) + T) / (T * K)
+    assert float(stats["token_rows_read_share"]) == pytest.approx(want)
+    assert float(stats["held_share"]) == pytest.approx(live / (T * K))
 
 
 @pytest.mark.parametrize("first,held", [(0, E), (0, 2), (5, 3)],
@@ -403,7 +498,9 @@ def test_gathers_promise_their_indices_and_equal_the_plain_ones(
     """No row gather of the layer's gradient program may be out of range
     and be filled (``FILL_OR_DROP``: a pass over ``(M, d)`` behind each
     on the chip), and nothing it computes moves: no sum is reordered, so
-    output and gradients equal the plain formulation's bit for bit."""
+    output and gradients equal the plain formulation's bit for bit (of a
+    share, whose token-side sums add in their own order, to the last
+    bits)."""
     args = _layer_inputs()
     mode = jax.lax.GatherScatterMode
 
@@ -414,8 +511,10 @@ def test_gathers_promise_their_indices_and_equal_the_plain_ones(
                 and e.invars[0].aval.shape[1:] == (D,)]
 
     fn = _layer_and_grads(ep)
-    # dispatch, combine, and their gradients
-    assert row_gather_modes(fn) == [mode.PROMISE_IN_BOUNDS] * 4
+    # dispatch, combine, and their gradients; of a share (an ``ep`` rank)
+    # each token-side sum is two: the live rows, then the runs' ends
+    assert row_gather_modes(fn) == [mode.PROMISE_IN_BOUNDS] * (
+        4 if ep == 1 else 6)
     got = jax.jit(fn)(*args)
     dispatch, combine = _plain_formulation()
     monkeypatch.setattr(moe, "_dispatch", dispatch)
@@ -423,6 +522,14 @@ def test_gathers_promise_their_indices_and_equal_the_plain_ones(
     fn = _layer_and_grads(ep)
     assert row_gather_modes(fn) == [mode.FILL_OR_DROP] * 4
     want = jax.jit(fn)(*args)
+    if ep > 1:
+        # a share adds a token's live rows oldest first, where XLA's
+        # reduction over all k picks its own order: the last bits
+        assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-6)
+        for name, g, w in zip(NAMES, got[1], want[1]):
+            assert float(jnp.abs(g - w).max()) <= 4e-6 * float(
+                jnp.abs(w).max()), name
+        return
     assert float(got[0]) == float(want[0])
     for name, g, w in zip(NAMES, got[1], want[1]):
         assert bool((g == w).all()), name
@@ -546,6 +653,13 @@ def test_expert_parallel_equals_one_device(mesh_kw):
     for name in ("aux_loss", "z_loss", "moe_load_max_over_mean"):
         assert float(m2[name]) == pytest.approx(float(m1[name]), rel=1e-5)
     assert float(m2["moe_dropped"]) == 0
+    # an ``ep`` rank is a share and takes the token-side sum over its live
+    # rows (one trip over a shard's t * 3 slots, and t rows at the ends);
+    # one device holds every expert and gathers a row a (token, choice)
+    t = 4 * 64 // mesh_kw.get("dp", 1)
+    assert float(m1["moe_token_rows_read_share"]) == 1.0
+    assert float(m2["moe_token_rows_read_share"]) == pytest.approx(
+        (t * 3 + 2 + t) / (t * 3))
     worst = jax.tree.map(
         lambda a, b: float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-12)),
         jax.device_get(g2), g1)
