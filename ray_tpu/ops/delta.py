@@ -29,15 +29,44 @@ the chunk's outputs and the state it leaves are
     o = (exp(G) q) H + (exp(G_t - G_j) (q_t . k_j))_(j <= t) U
     H' = exp(G_last) H + (exp(G_last - G_j) k_j)^T U
 
-Everything but ``H`` is computed for all chunks at once; ONE state a head
-is carried across chunks by ``lax.scan`` (not unrolled), which makes ``U``
-and hands out the state entering each chunk; the outputs follow for all
-chunks at once.  ``T`` is the inverse of a unit lower triangular matrix of
-``chunk`` rows (``unit_lower_inverse``): the inverses of its diagonal
-blocks of two rows, merged by doubling, ten products of ``chunk`` rows at
-64 in place of a substitution of ``chunk`` dependent steps; its backward
-pass is written out (``dA = -T^T dT T^T``), the rest is autodiff.  Plain XLA: the per-chunk matrices and
-the entering states are arrays in memory.
+Two forms of ONE algorithm, chosen by what a call's shapes show
+(``kernels_fit``): where chunks are 64 tokens, the keys fill whole sublane
+tiles up to one lane block and the values whole half-blocks of lanes up to
+two (the published 96 and 192) — ``delta_kernels``, two Pallas kernels
+under a ``custom_vjp`` (interpreted off the chip, so the tests run the
+same code); elsewhere ``delta_xla``, the same sums as plain XLA: everything
+but ``H`` for all chunks at once, ONE state a head carried across chunks by
+``lax.scan`` (not unrolled), the per-chunk matrices and the entering states
+arrays in memory, autodiff but for the inverse.  ``delta_xla`` is also the
+tests' second oracle.  Both take a state that enters.  ``T`` is the inverse
+of a unit lower triangular matrix of ``chunk`` rows
+(``unit_lower_inverse``): the inverses of its diagonal blocks of two rows,
+merged by doubling, ten products of ``chunk`` rows at 64 in place of a
+substitution of ``chunk`` dependent steps; its backward pass is written out
+(``dA = -T^T dT T^T``).
+
+The kernels (``delta_fwd``, ``delta_bwd``): grid ``(batch, head, step)``,
+a step being a few PAIRS of chunks worked through by a loop; the step axis
+is sequential and the head's ``(keys, values)`` float32 state rides in a
+VMEM scratch from one chunk to the next (backward: its gradient, chunks in
+reverse).  A pair's two chunks sit on the block diagonal of ONE ``(128,
+128)`` matrix (a ``(64, 64)`` float32 tile fills half the lanes; the zeros
+add nothing, so it stays exact): ``A``, ``T``, ``T (beta v)``, ``T (beta
+decay k)``, the masked ``q k^T`` and the state never leave VMEM.  q, k, v
+and o are read and written a head at a time with the SEQUENCE as the minor
+dimension, ``(batch, heads, d, s)`` — how XLA lays the mixer's arrays out
+round its convolution anyway, so nothing is transposed in memory round a
+call — and a pair's ``(d, 128)`` tile is stood up in VMEM.  ``delta_fwd``
+also writes what ``delta_bwd`` needs beside the inputs: the state ENTERING
+each chunk (float32, 142 MB a layer at the published sizes) and each
+pair's inverse as the products read it (``q.dtype``, 31 MB); under a layer
+checkpoint the forward kernel runs again in the backward pass and neither
+reaches the checkpoint's stack.  With the entering states saved, the
+backward kernel remakes a pair's ``u`` for both chunks at once and only the
+state's gradient walks; the inverse's gradient needs no float32 product of
+128 rows (``dA = -(T^T du0) (T vb)^T - (T^T dw) (T kb)^T``: two big
+products of what the pair has).  The cumulative log-decays of a chunk are
+made by XLA round the call, which also differentiates them.
 
 Precision: log-decays, their cumulative sums, every ``exp``, ``A``, the
 inverse and the carried state are float32 (the inverse's products at
@@ -50,15 +79,26 @@ with the recurrence drifts.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
+from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import attention
 from ray_tpu.ops.ssm import exp_where, pad_to_multiple
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
+_LANES = 128
+_CHUNK = 64           # the kernels' chunk: two side by side fill the lanes
+_PAIR = 2 * _CHUNK
+_HALVES = (slice(0, _CHUNK), slice(_CHUNK, _PAIR))   # a pair's two chunks
+# Pairs of chunks a grid step works through, at most: fewer, longer steps.
+_STEP_PAIRS = 4
 
 
 def delta_reference(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
@@ -156,7 +196,36 @@ def delta_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     float32, ``state_absmax``: the largest ``|S|`` at any chunk's end, no
     gradient through it``)``.  A sequence that is no multiple of the chunk
     is padded with tokens of ``beta`` 0 and ``g`` 0, which leave the state
-    as it is."""
+    as it is.
+
+    The Pallas kernels where the shapes tile the chip (``kernels_fit``),
+    a state that enters included; the XLA form elsewhere: one algorithm,
+    the same values to rounding."""
+    form = delta_kernels if kernels_fit(
+        q.shape[3], v.shape[3], min(chunk, q.shape[1])) else delta_xla
+    return form(q, k, v, g, beta, state, chunk=chunk)
+
+
+def kernels_fit(key_dim: int, value_dim: int, chunk: int) -> bool:
+    """Whether a call's shapes tile the chip for ``delta_kernels``: chunks
+    of 64 tokens, so that two side by side fill the 128 lanes; keys that
+    fill whole sublane tiles of either dtype up to ONE lane block (a
+    multiple of 16 up to 128: the published 96); values in whole
+    half-blocks of lanes up to two blocks (64, 128, 192 — the published —,
+    256).  Any number of heads, any batch, any sequence of a chunk or more
+    (padded to a multiple of 128)."""
+    return (chunk == _CHUNK and 16 <= key_dim <= _LANES
+            and key_dim % 16 == 0 and 64 <= value_dim <= 2 * _LANES
+            and value_dim % 64 == 0)
+
+
+def delta_xla(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+              beta: jax.Array, state: Optional[jax.Array] = None, *,
+              chunk: int = 64) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``delta_chunked`` as plain XLA, for any shapes: the per-chunk
+    matrices and the entering states are arrays in memory, ONE ``lax.scan``
+    carries the state across chunks, and autodiff writes the backward pass
+    but for the inverse's."""
     batch, s, heads, dk = q.shape
     dv, dtype = v.shape[-1], q.dtype
     c = min(chunk, s)
@@ -213,3 +282,383 @@ def delta_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                        preferred_element_type=_F32)
     o = jnp.moveaxis(o, 2, 3).reshape(batch, n * c, heads, dv)[:, :s]
     return o.astype(v.dtype), jnp.swapaxes(h_last, -1, -2), peak
+
+
+# ------------------------------------------------------- the Pallas form
+#
+# A grid step takes a few PAIRS of chunks of one head in turn.  A pair is
+# 128 tokens: its two chunks' (64, 64) matrices sit on the block diagonal
+# of ONE (128, 128) matrix, which fills the lanes (a (64, 64) float32 tile
+# fills half of them) and halves the MXU's weight loads; the zeros off the
+# blocks add nothing, so every sum is the chunk's own.  A token's scalars
+# (the cumulative log-decay of its chunk, beta) come as ROWS ``(2, 128)``
+# — a ``(tokens, 1)`` array would be laid out 128 lanes wide in memory —
+# and are stood up as columns through the diagonal; q, k, v come with the
+# tokens in the lanes too, ``(d, 128)``, and are transposed in VMEM.
+
+
+def _dot(a, b, contract, precision=None):
+    """``a`` x ``b`` contracting axis ``contract[0]`` of ``a`` with axis
+    ``contract[1]`` of ``b``, float32 out."""
+    return jax.lax.dot_general(
+        a, b, (((contract[0],), (contract[1],)), ((), ())),
+        precision=precision, preferred_element_type=_F32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _halves(parts):
+    """Two ``(64, n)`` results of a pair's chunks, one under the other."""
+    return jnp.concatenate(parts, axis=0)
+
+
+def _pair_inverse(a, row, col):
+    """``unit_lower_inverse`` of a pair's ``a (128, 128)`` — zero but
+    below the diagonal of its two blocks —: the same doubling, its five
+    levels on the whole matrix, float32 at full precision."""
+    def together(rows):   # rows a power of two: one diagonal block of them
+        shift = rows.bit_length() - 1
+        return (row >> shift) == (col >> shift)
+
+    inv = jnp.where(row == col, 1.0, 0.0) - jnp.where(together(2), a, 0.0)
+    rows = 2
+    while rows < _CHUNK:
+        across = jnp.where(together(2 * rows) & ~together(rows), a, 0.0)
+        inv = inv - _dot(_dot(inv, across, (1, 0), _HIGHEST), inv, (1, 0),
+                         _HIGHEST)
+        rows *= 2
+    return inv
+
+
+def _pair_terms(q, k, v, sc):
+    """What forward and backward both make of one pair from its ``q``,
+    ``k`` ``(128, keys)``, ``v (128, values)`` and scalar rows ``sc (2,
+    128)``, before anything reads the state."""
+    dtype = q.dtype
+    big = _HIGHEST if dtype == _F32 else None   # operands are q.dtype's
+    row, col = _iota((_PAIR, _PAIR), 0), _iota((_PAIR, _PAIR), 1)
+    eye = row == col
+    same = (row >= _CHUNK) == (col >= _CHUNK)
+
+    def column(r):   # (1, 128) -> (128, 1)
+        return jnp.sum(jnp.where(eye, r, 0.0), axis=1, keepdims=True)
+
+    cum_r, beta_r = sc[0:1, :], sc[1:2, :]
+    cum, beta = column(cum_r), column(beta_r)
+    # exp(G_t - G_j): right where j <= t in one chunk, capped at 1 elsewhere
+    decay = jnp.exp(jnp.minimum(cum - cum_r, 0.0))
+    lane, tok = _iota((1, _PAIR), 1), _iota((_PAIR, 1), 0)
+    lasts = [jnp.sum(jnp.where(lane == i * _CHUNK + _CHUNK - 1, cum_r, 0.0),
+                     axis=1, keepdims=True) for i in range(2)]   # (1, 1)
+    grown = jnp.exp(cum)                                         # exp(G_t)
+    to_end = jnp.exp(jnp.where(tok < _CHUNK, lasts[0], lasts[1]) - cum)
+    kf, qf = k.astype(_F32), q.astype(_F32)
+    below, upto = same & (col < row), same & (col <= row)
+    kk = _dot(k, k, (1, 1), big)
+    qk = jnp.where(upto, decay * _dot(q, k, (1, 1), big), 0.0)
+    return dict(
+        big=big, row=row, col=col, eye=eye, below=below, upto=upto, tok=tok,
+        beta=beta, decay=decay, grown=grown, to_end=to_end,
+        wholes=[jnp.exp(v_) for v_ in lasts], kf=kf, qf=qf, kk=kk, qk=qk,
+        a=jnp.where(below, beta * decay * kk, 0.0),
+        vb=(beta * v.astype(_F32)).astype(dtype),
+        kb=(beta * grown * kf).astype(dtype),
+        qg=(grown * qf).astype(dtype), k_end=(to_end * kf).astype(dtype))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, sc_ref, h0_ref,
+                o_ref, states_ref, tb_ref, hlast_ref, peak_ref, h_scr, *,
+                pairs):
+    """One head's chunks in order, the state in ``h_scr`` ``(keys,
+    values)`` float32.  Beside ``o`` it writes what the backward kernel
+    needs and cannot cheaply remake: the state ENTERING every chunk and
+    the pair's inverse as the products read it (``q.dtype``)."""
+    dtype = q_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_step():
+        h_scr[...] = h0_ref[0, 0]
+        peak_ref[...] = jnp.zeros_like(peak_ref)
+
+    def pair(p, peak):
+        at = pl.ds(pl.multiple_of(p * _PAIR, _PAIR), _PAIR)
+        t = _pair_terms(q_ref[0, 0, :, at].T, k_ref[0, 0, :, at].T,
+                        v_ref[0, 0, :, at].T, sc_ref[0, 0, :, at])
+        big = t["big"]
+        tb = _pair_inverse(t["a"], t["row"], t["col"]).astype(dtype)
+        tb_ref[0, 0, at, :] = tb
+        u0 = _dot(tb, t["vb"], (1, 0), big)
+        w = _dot(tb, t["kb"], (1, 0), big).astype(dtype)
+        h, us, from_state = h_scr[...], [], []
+        for i, rows in enumerate(_HALVES):
+            states_ref[0, 0, 2 * p + i] = h
+            hb = h.astype(dtype)
+            u = (u0[rows] - _dot(w[rows], hb, (1, 0), big)).astype(dtype)
+            from_state.append(_dot(t["qg"][rows], hb, (1, 0), big))
+            h = t["wholes"][i] * h + _dot(t["k_end"][rows], u, (0, 0), big)
+            peak = jnp.maximum(peak, jnp.max(jnp.abs(h)))
+            us.append(u)
+        h_scr[...] = h
+        o = _dot(t["qk"].astype(dtype), _halves(us), (1, 0), big) + _halves(
+            from_state)
+        o_ref[0, 0, :, at] = o.astype(o_ref.dtype).T
+        return peak
+
+    peak = jax.lax.fori_loop(0, pairs, pair, jnp.zeros((), _F32))
+    peak_ref[...] = jnp.maximum(peak_ref[...], peak)
+    hlast_ref[0, 0] = h_scr[...]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, sc_ref, states_ref, tb_ref, do_ref,
+                dhl_ref, dq_ref, dk_ref, dv_ref, dsc_ref, dh0_ref, dh_scr, *,
+                pairs):
+    """One head's chunks from the last to the first (the index maps turn
+    the grid round); ``dh_scr`` is the gradient to the state LEAVING the
+    chunk.  With the entering states saved nothing but that gradient walks:
+    a pair's ``u`` is remade for both chunks at once.  The inverse's
+    gradient needs no product of 128-row float32 matrices: with ``dT = du0
+    vb^T + dw kb^T``, ``dA = -T^T dT T^T = -(T^T du0) (T vb)^T - (T^T dw)
+    (T kb)^T``, two big products of what the pair has anyway.  The
+    gradient to a token's cumulative log-decay is row sums less column
+    sums of ONE float32 matrix (they are summed again over the chunk
+    outside and would not survive two roundings)."""
+    dtype = q_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_step():
+        dh_scr[...] = dhl_ref[0, 0]
+
+    def pair(j, carry):
+        p = pairs - 1 - j
+        at = pl.ds(pl.multiple_of(p * _PAIR, _PAIR), _PAIR)
+        q, k, v = (ref[0, 0, :, at].T for ref in (q_ref, k_ref, v_ref))
+        t = _pair_terms(q, k, v, sc_ref[0, 0, :, at])
+        big, tok, beta, grown = t["big"], t["tok"], t["beta"], t["grown"]
+        tb, do = tb_ref[0, 0, at, :], do_ref[0, 0, :, at].T
+        hs = [states_ref[0, 0, 2 * p + i] for i in range(2)]
+        hbs = [h.astype(dtype) for h in hs]
+
+        u0 = _dot(tb, t["vb"], (1, 0), big)
+        w = _dot(tb, t["kb"], (1, 0), big).astype(dtype)
+        u = (u0 - _halves([_dot(w[r], hb, (1, 0), big)
+                           for r, hb in zip(_HALVES, hbs)])).astype(dtype)
+        # o = (masked q k^T) u + (exp(G) q) H
+        dm = jnp.where(t["upto"], _dot(do, u, (1, 1), big), 0.0)
+        du_o = _dot(t["qk"].astype(dtype), do, (0, 0), big)
+        dqg = _halves([_dot(do[r], hb, (1, 1), big)
+                       for r, hb in zip(_HALVES, hbs)])
+
+        dh, walked = dh_scr[...], {}
+        for i in (1, 0):   # du, dw, d k_end, and what <dH', H> gives G_last
+            r, hb, whole = _HALVES[i], hbs[i], t["wholes"][i]
+            dhb = dh.astype(dtype)
+            du = du_o[r] + _dot(t["k_end"][r], dhb, (1, 0), big)
+            dub = du.astype(dtype)
+            walked[i] = (du, -_dot(dub, hb, (1, 1), big),
+                         _dot(u[r], dhb, (1, 1), big),
+                         whole * jnp.sum(dh * hs[i]))
+            dh = (whole * dh + _dot(t["qg"][r], do[r], (0, 0), big)
+                  - _dot(w[r], dub, (0, 0), big))
+        dh_scr[...] = dh
+        du, dw, dk_end = (_halves([walked[i][n] for i in range(2)])
+                          for n in range(3))
+
+        dvb = _dot(tb, du.astype(dtype), (0, 0), big)      # T^T du0
+        dkb = _dot(tb, dw.astype(dtype), (0, 0), big)      # T^T dw
+        da = -(_dot(dvb.astype(dtype), u0.astype(dtype), (1, 1), big)
+               + _dot(dkb.astype(dtype), w, (1, 1), big))
+        x = jnp.where(t["below"], da * t["decay"], 0.0)
+        dkk = beta * x
+        both = dkk * t["kk"] + dm * t["qk"]                # dA o A + dM o M
+        by_kb = jnp.sum(dkb * t["kf"], axis=1, keepdims=True) * grown
+        to_ends = t["to_end"] * jnp.sum(dk_end * t["kf"], axis=1,
+                                        keepdims=True)
+        dcum = (jnp.sum(both, axis=1, keepdims=True) + beta * by_kb
+                + grown * jnp.sum(dqg * t["qf"], axis=1, keepdims=True)
+                - to_ends)
+        for i in range(2):   # what a chunk's last token collects
+            mine = (tok >= i * _CHUNK) & (tok < (i + 1) * _CHUNK)
+            dcum = dcum + jnp.where(
+                tok == i * _CHUNK + _CHUNK - 1,
+                jnp.sum(jnp.where(mine, to_ends, 0.0)) + walked[i][3], 0.0)
+        dbeta = (jnp.sum(x * t["kk"], axis=1, keepdims=True) + by_kb
+                 + jnp.sum(dvb * v.astype(_F32), axis=1, keepdims=True))
+
+        def row(c):   # (128, 1) -> (1, 128)
+            return jnp.sum(jnp.where(t["eye"], c, 0.0), axis=0,
+                           keepdims=True)
+
+        dsc_ref[0, 0, 0:1, at] = row(dcum) - jnp.sum(both, axis=0,
+                                                     keepdims=True)
+        dsc_ref[0, 0, 1:2, at] = row(dbeta)
+        dqk = (dm * t["decay"]).astype(dtype)
+        dkkb = dkk.astype(dtype)
+        dq_ref[0, 0, :, at] = (_dot(dqk, k, (1, 0), big)
+                               + grown * dqg).astype(dq_ref.dtype).T
+        dk_ref[0, 0, :, at] = (
+            _dot(dkkb, k, (1, 0), big) + _dot(dkkb, k, (0, 0), big)
+            + _dot(dqk, q, (0, 0), big) + beta * grown * dkb
+            + t["to_end"] * dk_end).astype(dk_ref.dtype).T
+        dv_ref[0, 0, :, at] = (beta * dvb).astype(dv_ref.dtype).T
+        return carry
+
+    jax.lax.fori_loop(0, pairs, pair, 0)
+    dh0_ref[0, 0] = dh_scr[...]
+
+
+def _compiler_params(interpret):
+    if interpret:
+        return None
+    # Heads are independent; a head's chunks carry its state in order.
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=64 * 1024 * 1024)
+
+
+def _plan(q, v, reverse):
+    """What the two calls share: the grid ``(batch, head, step)``, a step
+    being as many pairs of chunks as ``_STEP_PAIRS`` allows and the
+    sequence divides, taken in order or, ``reverse``, from the end; the
+    BlockSpecs; the VMEM scratch that carries one state."""
+    batch, heads, keys, s = q.shape
+    values = v.shape[2]
+    pairs = _STEP_PAIRS
+    while (s // _PAIR) % pairs:
+        pairs //= 2
+    steps, tokens = s // _PAIR // pairs, pairs * _PAIR
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda b_, h_, c_: index(
+            b_, h_, steps - 1 - c_ if reverse else c_))
+
+    return dict(
+        grid=(batch, heads, steps), pairs=pairs,
+        carry=pltpu.VMEM((keys, values), _F32),
+        qk=spec((1, 1, keys, tokens), lambda b_, h_, c_: (b_, h_, 0, c_)),
+        v=spec((1, 1, values, tokens), lambda b_, h_, c_: (b_, h_, 0, c_)),
+        sc=spec((1, 1, 2, tokens), lambda b_, h_, c_: (b_, h_, 0, c_)),
+        tb=spec((1, 1, tokens, _PAIR), lambda b_, h_, c_: (b_, h_, c_, 0)),
+        states=spec((1, 1, 2 * pairs, keys, values),
+                    lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
+        state=spec((1, 1, keys, values), lambda b_, h_, c_: (b_, h_, 0, 0)),
+        peak=spec((1, 1, 8, _LANES), lambda b_, h_, c_: (b_, h_, 0, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _fwd_call(q, k, v, sc, h0, *, interpret):
+    """``q``, ``k`` ``(b, heads, keys, s)``, ``v (b, heads, values, s)``,
+    ``sc (b, heads, 2, s)`` float32 (a token's cumulative log-decay inside
+    its chunk of 64, its beta), ``h0 (b, heads, keys, values)`` float32;
+    ``s`` a multiple of 128.  Returns ``o`` like ``v``, the state entering
+    every chunk ``(b, heads, s / 64, keys, values)`` float32, every pair's
+    inverse ``(b, heads, s, 128)`` in ``q.dtype``, the last state like
+    ``h0`` and its largest entry at any chunk's end ``(b, heads, 8,
+    128)``."""
+    sp = _plan(q, v, reverse=False)
+    batch, heads, keys, s = q.shape
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, pairs=sp["pairs"]),
+        grid=sp["grid"],
+        in_specs=[sp["qk"], sp["qk"], sp["v"], sp["sc"], sp["state"]],
+        out_specs=[sp["v"], sp["states"], sp["tb"], sp["state"],
+                   sp["peak"]],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(
+                       (batch, heads, s // _CHUNK, keys, v.shape[2]), _F32),
+                   jax.ShapeDtypeStruct((batch, heads, s, _PAIR), q.dtype),
+                   jax.ShapeDtypeStruct(h0.shape, _F32),
+                   jax.ShapeDtypeStruct((batch, heads, 8, _LANES), _F32)],
+        scratch_shapes=[sp["carry"]],
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+        name="delta_fwd",
+    )(q, k, v, sc, h0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _bwd_call(q, k, v, sc, states, tb, do, dh_last, *, interpret):
+    """Gradients to ``q``, ``k``, ``v``, ``sc`` and ``h0``, each like its
+    argument."""
+    sp = _plan(q, v, reverse=True)
+    like = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, pairs=sp["pairs"]),
+        grid=sp["grid"],
+        in_specs=[sp["qk"], sp["qk"], sp["v"], sp["sc"], sp["states"],
+                  sp["tb"], sp["v"], sp["state"]],
+        out_specs=[sp["qk"], sp["qk"], sp["v"], sp["sc"], sp["state"]],
+        out_shape=[like(q), like(k), like(v), like(sc), like(dh_last)],
+        scratch_shapes=[sp["carry"]],
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+        name="delta_bwd",
+    )(q, k, v, sc, states, tb, do, dh_last)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule(q, k, v, sc, h0, interpret):
+    o, _, _, h_last, peak = _fwd_call(q, k, v, sc, h0, interpret=interpret)
+    return o, h_last, peak
+
+
+def _rule_fwd(q, k, v, sc, h0, interpret):
+    o, states, tb, h_last, peak = _fwd_call(q, k, v, sc, h0,
+                                            interpret=interpret)
+    return (o, h_last, peak), (q, k, v, sc, states, tb)
+
+
+def _rule_bwd(interpret, res, cts):
+    do, dh_last, _ = cts
+    return _bwd_call(*res, do, dh_last, interpret=interpret)
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def _tokens_minor(t):
+    """``t (batch, s, ...)`` held to the layout XLA gives the mixer's
+    arrays when it is left alone: the sequence as the minor dimension, as
+    its convolution along the sequence wants it.  A custom call's operands
+    have their layouts fixed, and XLA would rather lay out everything UP
+    to the projections to suit them (which turns the transposes round the
+    call into bitcasts and puts the batch of ONE between the two tiled
+    dimensions: tiles of one row, the convolution at a fifth of its
+    speed) than transpose once."""
+    order = (0, *range(2, t.ndim), 1)
+    return with_layout_constraint(t, Layout(major_to_minor=order))
+
+
+def delta_kernels(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                  beta: jax.Array, state: Optional[jax.Array] = None, *,
+                  chunk: int = 64) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``delta_chunked`` through the Pallas kernels (chunks of 64;
+    compiled on the TPU, interpreted elsewhere).  XLA pads the sequence to
+    whole pairs of chunks, makes each chunk's cumulative log-decays and
+    differentiates both; the kernels read q, k, v a head at a time with
+    the sequence as the minor dimension, ``(b, heads, d, s)``, which is
+    how XLA lays the mixer's arrays out anyway (``_tokens_minor``), so
+    nothing is copied round a call, and stand a pair's tile up in VMEM."""
+    batch, s, heads, dk = q.shape
+    if min(chunk, s) != _CHUNK:
+        raise ValueError(f"delta_kernels takes chunks of {_CHUNK}, got "
+                         f"{min(chunk, s)}")
+    q, k, v, g, beta = pad_to_multiple(
+        _PAIR, *(_tokens_minor(t) for t in (
+            q, k, v, g.astype(_F32), beta.astype(_F32))))
+    padded = q.shape[1]
+    cum = jnp.cumsum(g.reshape(batch, padded // _CHUNK, _CHUNK, heads),
+                     axis=2).reshape(batch, padded, heads)
+    sc = jnp.transpose(jnp.stack([cum, beta], axis=1), (0, 3, 1, 2))
+
+    def by_head(t):   # (b, s, h, d) -> (b, h, d, s)
+        return jnp.transpose(t, (0, 2, 3, 1))
+
+    h0 = (jnp.zeros((batch, heads, dk, v.shape[-1]), _F32) if state is None
+          else jnp.swapaxes(state.astype(_F32), -1, -2))
+    o, h_last, peak = _rule(by_head(q), by_head(k), by_head(v), sc, h0,
+                            attention._interpret_default())
+    return (_tokens_minor(jnp.transpose(o, (0, 3, 1, 2))[:, :s]),
+            jnp.swapaxes(h_last, -1, -2),
+            jnp.max(jax.lax.stop_gradient(peak)))

@@ -478,10 +478,10 @@ PHASES = ("forward", "remat", "backward", "optimizer")
 # writing its gradients into the stacked gradients, the loop itself.
 SCAN = "scan"
 # How the ``name=`` of the program's Pallas kernels start (ops/attention.py,
-# ops/moe.py, ops/ssm.py, ops/streams.py): the kernel rows of
+# ops/moe.py, ops/ssm.py, ops/streams.py, ops/delta.py): the kernel rows of
 # ``step_breakdown``.  A step scope that starts the same way (``hc_map``,
 # ``hc_mix``) is no kernel's name.
-KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm", "ssd_", "hc_")
+KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm", "ssd_", "hc_", "delta_")
 _SCOPE_TOKENS = re.compile(r"[^/()]+")
 
 
@@ -656,7 +656,7 @@ def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
                 key = kernel + (".remat" if phase == "remat" else "")
                 kernels[key] = kernels.get(key, 0) + self_ns
                 kernel_calls[key] = kernel_calls.get(key, 0) + 1
-                if key not in kernel_pairs:
+                if kernel.startswith("flash_") and key not in kernel_pairs:
                     ratio = flash_executed_over_causal(text)
                     if ratio is not None:
                         kernel_pairs[key] = ratio

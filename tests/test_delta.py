@@ -19,7 +19,8 @@ from ray_tpu.models import llama
 from ray_tpu.models.llama import (
     GDN_STATE_ABSMAX, LlamaConfig, init_params, loss_fn, param_logical_axes)
 from ray_tpu.ops.delta import (
-    delta_chunked, delta_reference, unit_lower_inverse)
+    delta_chunked, delta_kernels, delta_reference, delta_xla, kernels_fit,
+    unit_lower_inverse)
 from ray_tpu.ops.layers import rms_norm, swiglu
 from ray_tpu.ops.ssm import causal_conv1d
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
@@ -55,23 +56,44 @@ def _rule_grads(form, args, weight):
     return jax.jit(jax.grad(scalar, argnums=range(6)))(*args)
 
 
+SMALL = dict(heads=3, dk=12, dv=24)        # no kernel fits: the XLA form
+PUBLISHED = dict(batch=1, heads=2, dk=96, dv=192)   # Olmo-Hybrid's heads
+
+
+def _takes_the_kernels(form, args):
+    """Whether ``form``'s program holds the forward kernel: the dispatch
+    as it can be observed."""
+    return "delta_fwd" in str(jax.make_jaxpr(form)(*args))
+
+
 @pytest.mark.parametrize("neg_eigval", [True, False],
                          ids=["beta-to-2", "beta-to-1"])
-@pytest.mark.parametrize("seq,chunk", [(100, 16), (128, 64), (24, 64)],
-                         ids=["ragged-16", "two-chunks-64",
-                              "shorter-than-a-chunk"])
-def test_delta_chunked_equals_the_recurrence(seq, chunk, neg_eigval):
+@pytest.mark.parametrize("seq,chunk,sizes,kernels", [
+    (100, 16, SMALL, False), (128, 64, SMALL, False), (24, 64, SMALL, False),
+    (200, 64, PUBLISHED, True), (128, 64, PUBLISHED, True),
+    (64, 64, dict(batch=2, heads=1, dk=32, dv=64), True)],
+    ids=["ragged-16", "two-chunks-64", "shorter-than-a-chunk",
+         "kernels-ragged-published", "kernels-one-pair-published",
+         "kernels-one-chunk-32-64"])
+def test_delta_chunked_equals_the_recurrence(seq, chunk, sizes, kernels,
+                                             neg_eigval):
     """Values, the state handed on and the gradient of every input — q, k,
     v, the log-decay, beta and the state carried in — against the
-    recurrence a token at a time, with and without negative eigenvalues, at
-    two chunk sizes, on a sequence no chunk divides (padded with tokens
-    that neither decay nor write) and one shorter than a chunk.  Float32
-    against float32 in another order of sums: 2e-5 on values, 2e-4 of each
-    gradient's scale."""
-    args = _rule_inputs(seq, neg_eigval)
+    recurrence a token at a time, with and without negative eigenvalues:
+    the XLA form at two chunk sizes, on a sequence no chunk divides (padded
+    with tokens that neither decay nor write) and one shorter than a chunk;
+    the Pallas kernels (interpreted here) at the published head sizes, keys
+    96 and values 192, on a sequence that is no multiple of the chunk, on
+    one pair of chunks, and on ONE chunk of smaller heads (the other half
+    of its pair is padding).  Nothing but the shapes chooses the form, and
+    the program shows which ran.  Float32 against float32 in another order
+    of sums: 2e-5 on values, 2e-4 of each gradient's scale."""
+    args = _rule_inputs(seq, neg_eigval, **sizes)
     weight = jnp.asarray(np.random.default_rng(1).normal(
         size=args[2].shape), jnp.float32)
     chunked = lambda *t: delta_chunked(*t, chunk=chunk)
+    assert _takes_the_kernels(chunked, args) == kernels == kernels_fit(
+        sizes["dk"], sizes["dv"], min(chunk, seq))
     with HIGHEST:
         (o, state, peak), (want, want_state) = (
             jax.jit(f)(*args) for f in (chunked, delta_reference))
@@ -85,6 +107,57 @@ def test_delta_chunked_equals_the_recurrence(seq, chunk, neg_eigval):
         assert np.all(np.isfinite(g)), name
         np.testing.assert_allclose(g, w, atol=2e-4 * float(jnp.max(jnp.abs(
             w))), rtol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.bfloat16, 2e-2),
+                                       (jnp.float32, 2e-5)],
+                         ids=["bfloat16", "float32"])
+def test_the_kernels_equal_the_xla_form_at_the_published_sizes(dtype, tol):
+    """Both forms of ONE algorithm on the same arguments — keys 96, values
+    192, ``beta`` up to 2, a state that enters, a sequence of three chunks
+    and a half —: the kernels round where the XLA form rounds (the
+    operands of the big products to ``q.dtype``, everything of the decays,
+    the inverse and the state in float32), so in bfloat16 the outputs, the
+    last state and the largest state agree far inside bfloat16's rounding,
+    and each of the six gradients (the backward kernel, which the
+    benchmark's check never runs) to 2e-2 of its scale; in float32 to
+    2e-5.  The output has v's dtype, the state is float32."""
+    args = _rule_inputs(224, True, seed=4, dtype=dtype, **PUBLISHED)
+    weight = jnp.asarray(np.random.default_rng(1).normal(
+        size=args[2].shape), jnp.float32)
+    assert _takes_the_kernels(delta_chunked, args)
+    assert not _takes_the_kernels(delta_xla, args)
+    with HIGHEST:
+        (o, state, peak), (want, want_state, want_peak) = (
+            jax.jit(f)(*args) for f in (delta_kernels, delta_xla))
+        grads, want_grads = (_rule_grads(f, args, weight)
+                             for f in (delta_kernels, delta_xla))
+    assert o.dtype == dtype and state.dtype == jnp.float32
+    f32 = lambda t: np.asarray(t.astype(jnp.float32))
+    scale = float(np.max(np.abs(f32(want))))
+    np.testing.assert_allclose(f32(o), f32(want), atol=tol * scale)
+    np.testing.assert_allclose(state, want_state, atol=tol * float(
+        jnp.max(jnp.abs(want_state))))
+    np.testing.assert_allclose(peak, want_peak, rtol=tol)
+    for name, g, w in zip("q k v g beta state".split(), grads, want_grads):
+        assert g.dtype == w.dtype and np.all(np.isfinite(f32(g))), name
+        np.testing.assert_allclose(f32(g), f32(w), atol=tol * float(np.max(
+            np.abs(f32(w)))), err_msg=name)
+
+
+@pytest.mark.parametrize("key_dim,value_dim,chunk,fits", [
+    (96, 192, 64, True),      # the published heads
+    (128, 128, 64, True), (32, 64, 64, True), (64, 256, 64, True),
+    (96, 192, 16, False),     # the kernels are written for chunks of 64
+    (96, 192, 128, False),
+    (96, 192, 24, False),     # a sequence shorter than a chunk
+    (12, 24, 64, False),      # keys under a sublane tile of bfloat16
+    (100, 192, 64, False),    # keys that fill no whole tiles
+    (256, 192, 64, False),    # keys past one lane block
+    (96, 200, 64, False), (96, 512, 64, False)])
+def test_kernels_fit_says_what_the_kernels_were_written_for(
+        key_dim, value_dim, chunk, fits):
+    assert kernels_fit(key_dim, value_dim, chunk) == fits
 
 
 def test_the_result_does_not_depend_on_the_chunk():
@@ -421,3 +494,25 @@ def test_on_a_mesh_the_rule_runs_per_shard_of_the_batch():
     assert abs(float(got) - float(want)) <= 1e-5 * float(want)
     np.testing.assert_allclose(got_m[GDN_STATE_ABSMAX],
                                want_m[GDN_STATE_ABSMAX], rtol=1e-5)
+
+
+def test_on_a_mesh_the_kernels_run_per_shard_of_the_batch():
+    """fsdp=2 x tp=2 with heads the kernels fit: inside the manual region
+    each shard of the batch runs ``delta_fwd`` / ``delta_bwd`` on its own
+    rows (the layouts the wrapper asks for are the shard's own), and the
+    outputs, the state's maximum and the five gradients are one device's
+    to the last bit."""
+    args = _rule_inputs(128, True, seed=6, batch=4, heads=2, dk=32, dv=64)[:5]
+    mesh = make_mesh(MeshConfig(fsdp=2, tp=2), devices=jax.devices()[:4])
+    one, many = llama._delta_scan(None, False), llama._delta_scan(mesh, False)
+    assert _takes_the_kernels(many, args)
+    scalar = lambda rule: (lambda *t: jnp.sum(jnp.square(rule(*t)[0])))
+    with HIGHEST:
+        (want, want_peak), (o, peak) = (jax.jit(f)(*args)
+                                        for f in (one, many))
+        want_grads, grads = (jax.jit(jax.grad(scalar(f), argnums=range(5)))(
+            *args) for f in (one, many))
+    np.testing.assert_array_equal(o, want)
+    assert float(peak) == float(want_peak)
+    for g, w in zip(grads, want_grads):
+        np.testing.assert_array_equal(g, w)

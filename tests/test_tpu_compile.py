@@ -422,29 +422,85 @@ def test_xing4_programs_lower_the_stream_kernels_once_a_use(one_chip,
     assert 8 <= fwd.count(" div ") < 20 and 8 <= bwd.count(" div ") < 40
 
 
-def test_olmo_hybrid_linear_layer_train_step_compiles(one_chip, as_on_chip):
-    """One gated delta-rule layer of Olmo-Hybrid-7B as
-    ``olmohybrid-train-1seq`` runs it (the benchmark's configuration file:
-    hidden 3840, 30 heads with keys of 96 and values of 192, chunks of 64,
-    the norm on what the block adds), the vocabulary cut, at 4096
-    positions as a train step: plain XLA — keys of 96 tile no 128 lanes —
-    whose chunk matrices, inverses and entering states are arrays; what
-    the chip's compiler makes of them must fit beside a layer's state."""
+def _olmo_hybrid_cfg(num_layers):
+    """Olmo-Hybrid-7B as ``olmohybrid-train-1seq`` runs it (the
+    benchmark's configuration file: hidden 3840, 30 delta-rule heads with
+    keys of 96 and values of 192, the norm on what a block adds), the
+    vocabulary cut, the first ``num_layers`` of its pattern."""
     import dataclasses
 
     cfg = _benchmark_cfg("olmo-hybrid-7b-d4")
     assert (cfg.embed_dim, cfg.gdn_heads, cfg.gdn_key_dim, cfg.gdn_value_dim,
             cfg.block_norm) == (3840, 30, 96, 192, "output")
-    cfg = dataclasses.replace(cfg, vocab_size=4096, num_layers=1)
+    return dataclasses.replace(cfg, vocab_size=4096, num_layers=num_layers)
+
+
+_DELTA_KERNELS = ("delta_fwd", "delta_bwd")
+
+
+def test_olmo_hybrid_linear_layer_train_step_compiles(one_chip, as_on_chip):
+    """One gated delta-rule layer of Olmo-Hybrid-7B at 4096 positions as a
+    train step, WITH the ``delta_*`` kernels (``ops/delta.py``: a head's
+    pairs of 64-token chunks as (128, 128) matrices, tiles of (96, 128)
+    keys and (192, 128) values with the tokens in the lanes stood up in
+    VMEM, products that contract 96 of 128 lanes, the (96, 192) float32
+    state in a VMEM scratch, ten float32 products at full precision a
+    pair): what Mosaic could refuse — and what the chip's compiler makes
+    of the step must fit beside a layer's state."""
+    cfg = _olmo_hybrid_cfg(1)
     assert cfg.layer_runs == (("linear_attention", 1),)
     opt = default_optimizer()
     compiled = make_train_step(cfg, opt).lower(
         _state_shapes(cfg, opt, one_chip),
         {"tokens": _shape((1, 4097), jnp.int32, one_chip)}).compile()
-    assert not _has_kernel(compiled)
-    assert "gdn_scan" in compiled.as_text()
+    text = compiled.as_text()
+    assert all(name in text for name in _DELTA_KERNELS)
+    assert "gdn_scan" in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9
+
+
+def test_olmo_hybrid_programs_lower_the_delta_kernels_once_a_use(
+        one_chip, as_on_chip):
+    """The structural guard of the cell's set-up budget, as granite's is:
+    Olmo-Hybrid's step program over one period of its pattern (a run of
+    three linear layers and the full-attention layer) and the benchmark's
+    check program, lowered for the described chip.  The call wrappers are
+    module-level ``jit``s, so a body is traced once a process and lowered
+    once a USE: the step program holds ``delta_fwd`` twice (the run's
+    forward pass, and its rematerialised forward, whose entering states
+    and inverses the backward reads) and ``delta_bwd`` once; the check
+    program the forward kernel twice, once in the layer scan of each of
+    its two forward passes.  Chunks and heads are grid axes and loops: no
+    body is a copy a chunk."""
+    from benchmark.loops import train
+
+    cfg = _olmo_hybrid_cfg(4)
+    assert cfg.layer_runs == (("linear_attention", 3), ("full_attention", 1))
+    opt = default_optimizer()
+    state = _state_shapes(cfg, opt, one_chip)
+    tokens = _shape((1, 4097), jnp.int32, one_chip)
+    step = make_train_step(cfg, opt).lower(state, {"tokens": tokens}
+                                           ).as_text()
+    check = jax.jit(train.program_check(cfg, None)).lower(
+        state.params, tokens).as_text()
+
+    def kernels(text):
+        return tuple(text.count(f'kernel_name = "{name}"')
+                     for name in _DELTA_KERNELS)
+
+    assert kernels(step) == (2, 1)
+    assert kernels(check) == (2, 0)
+    # a pair's ten float32 products are in the body once, not once a chunk
+    from ray_tpu.ops import delta
+
+    qk = jax.ShapeDtypeStruct((1, 30, 96, 4096), jnp.bfloat16)
+    body = str(jax.make_jaxpr(functools.partial(
+        delta._fwd_call, interpret=False))(
+            qk, qk, jax.ShapeDtypeStruct((1, 30, 192, 4096), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 30, 2, 4096), jnp.float32),
+            jax.ShapeDtypeStruct((1, 30, 96, 192), jnp.float32)))
+    assert body.count("Precision.HIGHEST") == 2 * 10
 
 
 def test_lfm2_conv_layer_train_step_compiles(one_chip, as_on_chip):

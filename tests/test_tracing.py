@@ -780,3 +780,58 @@ def test_step_breakdown_counts_the_state_space_kernels(tmp_path):
     text = format_breakdown(b)
     assert "ssd_bwd  x 3 a step" in text
     assert "flash_fwd  executed/causal 1.0622  x 1 a step" in text
+
+
+def test_step_breakdown_counts_the_delta_rule_kernels(tmp_path):
+    """A gated delta-rule layer's rule (``ops/delta.py``): kernel rows
+    ``delta_fwd`` (forward pass, and rematerialised: its entering states
+    and inverses are not kept) and ``delta_bwd`` with their calls a step —
+    linear layers x passes — beside the flash rows of the full-attention
+    layer; their time is the ``gdn_scan`` scope's with the XLA ops round
+    them (the layouts the kernels read, the cumulative log-decays).  The
+    scope ``gdn_scan`` is no kernel's name: the kernels start ``delta_``."""
+    from ray_tpu.util.tracing import (
+        KERNEL_NAMES, format_breakdown, step_breakdown)
+
+    assert "delta_" in KERNEL_NAMES and not "gdn_scan".startswith(
+        KERNEL_NAMES)
+    fwd, bwd = _FWD, _BWD
+    # operands (b, heads, d, s), four-dimensional as a flash kernel's are:
+    # the causal-pairs ratio is the flash rows' alone
+    call = (', custom_call_target=\\"tpu_custom_call\\", '
+            'operand_layout_constraints={bf16[1,30,96,4096]{3,2,1,0}, '
+            'bf16[1,30,96,4096]{3,2,1,0}, bf16[1,30,192,4096]{3,2,1,0}}')
+    rule = "gdn_scan/jit(_fwd_call)/delta_fwd/pallas_call"
+    ops = [("%lead = f32[] add()", "jit(step)/optimizer/add", 500, 100)]
+    at = 2000
+    for layer in range(3):
+        ops += [
+            (f"%copy.{layer} = bf16[] copy()",
+             fwd + "gdn_scan/transpose", at, 7),
+            (f"%delta_fwd.{layer} = bf16[] custom-call()" + call,
+             fwd + rule, at + 7, 60),
+            (f"%delta_fwd.{layer + 3} = bf16[] custom-call()" + call,
+             bwd + "rematted_computation/" + rule, at + 67, 61),
+            (f"%delta_bwd.{layer} = bf16[] custom-call()" + call,
+             bwd + "gdn_scan/jit(_bwd_call)/delta_bwd/pallas_call",
+             at + 128, 35),
+        ]
+        at += 200
+    ops.append(("%flash_fwd.1 = f32[] custom-call()" + _KERNEL,
+                fwd + "attention/flash_fwd/pallas_call", at, 50))
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace(ops, modules=[(0, 1000), (2000, 1000)]))
+    b = step_breakdown(str(path), "jit_step")
+    ns = lambda s: round(s * 1e9)  # noqa: E731
+    assert {k: ns(t) for k, t in b["kernels"].items()} == {
+        "delta_fwd": 180, "delta_fwd.remat": 183, "delta_bwd": 105,
+        "flash_fwd": 50}
+    assert b["kernel_calls"] == {"delta_fwd": 3, "delta_fwd.remat": 3,
+                                 "delta_bwd": 3, "flash_fwd": 1}
+    assert set(b["kernel_pairs"]) == {"flash_fwd"}
+    assert {p: ns(t) for p, t in b["scopes"]["gdn_scan"].items()} == {
+        "forward": 201, "remat": 183, "backward": 105}
+    text = format_breakdown(b)
+    assert "delta_fwd.remat  x 3 a step" in text
+    assert "delta_bwd  x 3 a step" in text
+    assert "flash_fwd  executed/causal 1.0622  x 1 a step" in text
