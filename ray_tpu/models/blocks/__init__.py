@@ -1,0 +1,32 @@
+"""ray_tpu.models.blocks — what a decoder layer is composed of: a MIXER
+(``MIXERS``) followed by an FFN (``FFNS``), each on a RESIDUAL
+(``residual.py``).  A mixer or FFN is ONE module that ends in ONE
+``base.Block``.  To add one: write the module, register its ``Block`` below
+under the name ``layer_types`` gives it, and list its scopes in the
+``"scopes"`` of the benchmark configuration that uses it; its
+``LlamaConfig`` fields sit in ``models/llama.py`` with the others (the
+benchmark builds the configuration from flat keys).  Nothing else in the
+tree names a mixer.
+"""
+
+from ray_tpu.models.blocks import (
+    attention, conv, delta, ffn, mamba, residual)
+
+MIXERS = {
+    "attention": attention.SOFTMAX,
+    "full_attention": attention.SOFTMAX,   # as the public files spell it
+    "latent": attention.LATENT,
+    "mamba": mamba.BLOCK,
+    "linear_attention": delta.BLOCK,
+    "conv": conv.BLOCK,
+}
+FFNS = {"dense": ffn.DENSE, "moe": ffn.MOE}
+
+
+def layer_scopes():
+    """The scopes a layer can open, each once: the plain layer's first (the
+    softmax mixer's, then every FFN's), then the other mixers' in the order
+    they are registered, then the residual's."""
+    blocks = (MIXERS["attention"], *FFNS.values(), *MIXERS.values())
+    return tuple(dict.fromkeys(
+        [s for b in blocks for s in b.scopes] + list(residual.SCOPES)))

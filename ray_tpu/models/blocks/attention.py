@@ -1,0 +1,226 @@
+"""The softmax mixers: attention (``layer_types``: ``attention`` or, as
+the OLMo and LFM2 files spell it, ``full_attention``) and latent attention
+(arXiv:2412.19437 §2.1.1: q and k/v come up from normed low-rank
+projections, a head's q and k are [no-position part | rotary part, the k's
+shared by all heads] and wider than its v; the flash kernels take the two
+head sizes).  The attention itself is pluggable (``cfg.attn_impl``): pallas
+flash (``ops/attention.py``), ring over 'sp', Ulysses all-to-all, or the
+XLA reference — all numerically interchangeable (tested).
+
+Both open the scopes ``attn_qkv`` (norm, projections, RoPE), ``attention``
+and ``attn_out`` (``wo`` and the add), and the layer checkpoint keeps the
+flash kernel's output and log-sum-exp (``ops.attention.SAVED_RESIDUALS``:
+no ``flash_fwd`` under ``rematted_computation``).
+"""
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.models.blocks.base import Block, Ctx, Param, ones
+from ray_tpu.models.blocks.residual import add, block_in
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import flash_attention, mha_reference
+from ray_tpu.ops.layers import (
+    apply_rope, repeat_kv_heads, rms_norm, rope, yarn_inv_freq, yarn_mscale)
+from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.ops.ulysses import ulysses_attention
+from ray_tpu.parallel.mesh import AXIS_DP, AXIS_FSDP, AXIS_SP, AXIS_TP
+from ray_tpu.parallel.sharding import manual_shard_map
+
+SCOPES = ("attn_qkv", "attention", "attn_out")
+
+
+def _attention_shapes(cfg):
+    d, h, kvd = cfg.embed_dim, cfg.qkv_dim, cfg.kv_dim
+    shapes = {
+        "attn_norm": Param((d,), ("layer", "embed"), ones),
+        "wq": Param((d, h), ("layer", "kernel_in", "heads")),
+        "wk": Param((d, kvd), ("layer", "kernel_in", "kv_heads")),
+        "wv": Param((d, kvd), ("layer", "kernel_in", "kv_heads")),
+        "wo": Param((h, d), ("layer", "heads", "kernel_in")),
+    }
+    if cfg.qk_norm:  # over the whole projection, before heads and RoPE
+        shapes.update({"q_norm": Param((h,), ("layer", "heads"), ones),
+                       "k_norm": Param((kvd,), ("layer", "kv_heads"), ones)})
+    if cfg.qk_head_norm:  # over each head, ONE weight of a head's size
+        head = Param((cfg.head_dim,), ("layer", "head_dim"), ones)
+        shapes.update({"q_norm": head, "k_norm": head})
+    return shapes
+
+
+def _latent_shapes(cfg):
+    """``wq_a``/``wq_b`` take q down to ``q_lora_rank`` and up to heads x
+    [nope | rope]; ``wkv_a`` gives [the latent c_kv | the one rotary k every
+    head shares], ``wkv_b`` takes the normed latent up to heads x [k_nope |
+    v] (the published layouts of ``kv_a_proj_with_mqa`` and
+    ``kv_b_proj``)."""
+    d, heads, qk = cfg.embed_dim, cfg.num_heads, cfg.latent_qk_dim
+    return {
+        "attn_norm": Param((d,), ("layer", "embed"), ones),
+        "wq_a": Param((d, cfg.q_lora_rank), ("layer", "kernel_in", None)),
+        "q_a_norm": Param((cfg.q_lora_rank,), ("layer", None), ones),
+        "wq_b": Param((cfg.q_lora_rank, heads * qk),
+                      ("layer", None, "heads")),
+        "wkv_a": Param((d, cfg.kv_lora_rank + cfg.qk_rope_dim),
+                       ("layer", "kernel_in", None)),
+        "kv_a_norm": Param((cfg.kv_lora_rank,), ("layer", None), ones),
+        "wkv_b": Param((cfg.kv_lora_rank,
+                        heads * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                       ("layer", None, "heads")),
+        "wo": Param((heads * cfg.v_head_dim, d),
+                    ("layer", "heads", "kernel_in")),
+    }
+
+
+def _sm_scale(cfg) -> float:
+    if cfg.attention_multiplier is not None:
+        return cfg.attention_multiplier
+    if not cfg.kv_lora_rank:
+        return cfg.head_dim ** -0.5
+    # latent attention: over the whole q/k head, times the square of
+    # YaRN's temperature where the model states ``mscale_all_dim``
+    scaling = dict(cfg.rope_scaling or ())
+    return cfg.latent_qk_dim ** -0.5 * yarn_mscale(
+        scaling.get("factor", 1.0), scaling.get("mscale_all_dim", 0.0)) ** 2
+
+
+def _rope_inv_freq(cfg, dim: int):
+    """YaRN's frequencies where the model's ``rope_scaling`` is of that
+    type, else None (the plain ones)."""
+    scaling = dict(cfg.rope_scaling or ())
+    if scaling.get("type", scaling.get("rope_type")) != "yarn":
+        return None
+    return yarn_inv_freq(
+        dim, cfg.rope_theta, factor=scaling["factor"],
+        original=scaling["original_max_position_embeddings"],
+        beta_fast=scaling.get("beta_fast", 32.0),
+        beta_slow=scaling.get("beta_slow", 1.0))
+
+
+def _attention(q, k, v, cfg, mesh):
+    """Dispatch to the configured attention impl; ring / ulysses manage the
+    'sp' axis themselves."""
+    impl, scale = cfg.attn_impl, _sm_scale(cfg)
+    if mesh is None:
+        # Ring/ulysses degenerate to plain attention on one device.
+        if impl == "flash":
+            return flash_attention(q, k, v, causal=True, sm_scale=scale)
+        k, v = repeat_kv_heads(q, k, v)
+        return mha_reference(q, k, v, causal=True, sm_scale=scale)
+    if impl == "ring":
+        return ring_attention(q, k, v, causal=True, sm_scale=scale, mesh=mesh)
+    if impl == "ulysses":
+        return ulysses_attention(q, k, v, causal=True, sm_scale=scale,
+                                 mesh=mesh)
+    if impl == "reference":
+        return mha_reference(q, k, v, causal=True, sm_scale=scale)
+    # flash under a mesh: pallas has no SPMD partitioning rule, so run the
+    # kernel per-shard: batch over (dp,fsdp), heads over tp, seq replicated.
+    # Manual over EVERY mesh axis — the TPU lowering refuses a Mosaic
+    # kernel in a region that leaves any axis to the partitioner.
+    k, v = repeat_kv_heads(q, k, v)
+    spec = P((AXIS_DP, AXIS_FSDP), None, AXIS_TP, None)
+    fn = manual_shard_map(
+        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True,
+                                           sm_scale=scale),
+        set(mesh.axis_names), in_specs=(spec, spec, spec),
+        out_specs=spec, mesh=mesh)
+    return fn(q, k, v)
+
+
+def _attention_sp_manual(q, k, v, cfg):
+    """Attention inside an already-manual 'sp' region (pipeline path):
+    call the sharded bodies inline — no nested shard_map."""
+    from ray_tpu.ops.ring_attention import _ring_attention_sharded
+    from ray_tpu.ops.ulysses import _ulysses_sharded
+    k, v = repeat_kv_heads(q, k, v)
+    if cfg.attn_impl == "ulysses":
+        return _ulysses_sharded(q, k, v, _sm_scale(cfg), True, AXIS_SP,
+                                use_flash=False)
+    return _ring_attention_sharded(q, k, v, _sm_scale(cfg), True, AXIS_SP)
+
+
+def _attend(ctx: Ctx, x, aux, q, k, v, lp, residual: bool):
+    """What every softmax mixer ends in: the attention itself (scope
+    ``attention``), then the heads' outputs side by side through ``wo``
+    and onto the stream (scope ``attn_out``)."""
+    cfg = ctx.cfg
+    with jax.named_scope("attention"):
+        if ctx.sp_manual:
+            o = _attention_sp_manual(q, k, v, cfg)
+        else:
+            o = _attention(q, k, v, cfg, ctx.mesh)
+    with jax.named_scope("attn_out"):
+        o = o.reshape(*x.shape[:2], -1)
+        return add(ctx, x, o @ lp["wo"].astype(cfg.dtype), residual,
+                   lp["attn_norm"]), aux
+
+
+def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
+    cfg, cst = ctx.cfg, ctx.cst
+    b, s = x.shape[0], x.shape[1]
+    with jax.named_scope("attn_qkv"):
+        h = block_in(x, lp["attn_norm"], cfg)
+        q = h @ lp["wq"].astype(cfg.dtype)
+        k = h @ lp["wk"].astype(cfg.dtype)
+        if cfg.qk_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
+        k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.qk_head_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        v = (h @ lp["wv"].astype(cfg.dtype)).reshape(
+            b, s, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.position_embedding == "rope":
+            offset = 0
+            if ctx.sp_manual:
+                offset = jax.lax.axis_index(AXIS_SP) * s
+            cos, sin = rope(s, cfg.head_dim, cfg.rope_theta, offset=offset)
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        q = cst(q, ("batch", "seq", "heads", "head_dim"))
+        k = cst(k, ("batch", "seq", "kv_heads", "head_dim"))
+    return _attend(ctx, x, aux, q, k, v, lp, residual)
+
+
+def _latent_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
+    """Latent attention on the residual stream: ``attn_qkv`` holds both
+    down-projections, their norms, both up-projections and RoPE.  A
+    head's q and k are [no-position part | rotary part] — the k's rotary
+    part is ONE head, shared by all and laid beside each head's own part
+    in the one k the kernel reads — and its v is narrower; the softmax
+    scale is over the whole q/k head."""
+    cfg, cst = ctx.cfg, ctx.cst
+    b, s = x.shape[0], x.shape[1]
+    heads, nope, rot = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    with jax.named_scope("attn_qkv"):
+        h = block_in(x, lp["attn_norm"], cfg)
+        q = (rms_norm(h @ lp["wq_a"].astype(cfg.dtype), lp["q_a_norm"],
+                      cfg.norm_eps) @ lp["wq_b"].astype(cfg.dtype)).reshape(
+                          b, s, heads, nope + rot)
+        c_kv, k_rot = jnp.split(h @ lp["wkv_a"].astype(cfg.dtype),
+                                [cfg.kv_lora_rank], -1)
+        kv = (rms_norm(c_kv, lp["kv_a_norm"], cfg.norm_eps)
+              @ lp["wkv_b"].astype(cfg.dtype)).reshape(
+                  b, s, heads, nope + cfg.v_head_dim)
+        offset = jax.lax.axis_index(AXIS_SP) * s if ctx.sp_manual else 0
+        cos, sin = rope(s, rot, cfg.rope_theta, offset=offset,
+                        inv_freq=_rope_inv_freq(cfg, rot))
+        q = jnp.concatenate(
+            [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], -1)
+        k_rot = apply_rope(k_rot[:, :, None, :], cos, sin)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rot, (b, s, heads, rot))],
+            -1)
+        v = kv[..., nope:]
+        q = cst(q, ("batch", "seq", "heads", "head_dim"))
+        k = cst(k, ("batch", "seq", "heads", "head_dim"))
+    return _attend(ctx, x, aux, q, k, v, lp, residual)
+
+
+SOFTMAX = Block(_attention_shapes, _attention_mixer,
+                saved=attention.SAVED_RESIDUALS, scopes=SCOPES)
+LATENT = Block(_latent_shapes, _latent_mixer,
+               saved=attention.SAVED_RESIDUALS, scopes=SCOPES)

@@ -1,0 +1,169 @@
+"""The FFNs: a dense SwiGLU (scope ``ffn``), and the dropless expert layer
+(``ops/moe.py``, under its four scopes ``moe_route`` / ``moe_dispatch`` /
+``moe_experts`` / ``moe_combine`` in place of ``ffn``) with or without a
+shared expert, which every token meets (scope ``ffn``).  Expert tensors are
+sharded over 'ep': each rank computes its own experts' rows inside a
+shard_map and the partial outputs are summed.  One chip's share of a layer
+(``experts_held``, ``first_expert``): the router keeps its published width,
+the expert tensors hold the experts that live here, and what the absent
+ones would add is left out.  The layer checkpoint keeps the row index
+(``ops.moe.SAVED_RESIDUALS``: no sort under ``rematted_computation``; the
+row gather runs again).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.models.blocks.base import Block, Ctx, Param, fold, ones
+from ray_tpu.models.blocks.residual import add, block_in
+from ray_tpu.ops import moe
+from ray_tpu.ops.layers import rms_norm, swiglu
+from ray_tpu.ops.moe import moe_block
+from ray_tpu.parallel.mesh import (
+    AXIS_DP, AXIS_EP, AXIS_FSDP, AXIS_SP, AXIS_TP)
+from ray_tpu.parallel.sharding import manual_shard_map
+
+
+def _swiglu_shapes(d: int, m: int, prefix: str = "w_"):
+    return {
+        prefix + "gate": Param((d, m), ("layer", "kernel_in", "mlp")),
+        prefix + "up": Param((d, m), ("layer", "kernel_in", "mlp")),
+        prefix + "down": Param((m, d), ("layer", "mlp", "kernel_in")),
+    }
+
+
+def _dense_shapes(cfg):
+    return {"mlp_norm": Param((cfg.embed_dim,), ("layer", "embed"), ones),
+            **_swiglu_shapes(cfg.embed_dim, cfg.dense_width)}
+
+
+def _select_bias(key, shape):
+    """Drawn at 0.02 so that a comparison with a reference can see it (a
+    trained one starts at 0)."""
+    return 0.02 * jax.random.normal(key, shape, jnp.float32)
+
+
+def _moe_shapes(cfg):
+    """The router over ALL the experts, the tensors of those held here
+    (``mlp_dim`` is an expert's width), the selection bias (float32
+    whatever the parameters': it moves by thousandths) and the shared
+    expert where the model has them."""
+    d, m = cfg.embed_dim, cfg.mlp_dim
+    e, held = cfg.num_experts, cfg.local_experts
+    shapes = {
+        "mlp_norm": Param((d,), ("layer", "embed"), ones),
+        "router": Param((d, e), ("layer", "kernel_in", None)),
+        "w_gate": Param((held, d, m),
+                        ("layer", "expert", "kernel_in", "mlp")),
+        "w_up": Param((held, d, m), ("layer", "expert", "kernel_in", "mlp")),
+        "w_down": Param((held, m, d),
+                        ("layer", "expert", "mlp", "kernel_in")),
+    }
+    if cfg.select_bias:
+        shapes["router_bias"] = Param((e,), ("layer", None), _select_bias,
+                                      jnp.float32)
+    if cfg.shared_experts:
+        shapes.update(_swiglu_shapes(d, cfg.shared_experts * m, "shared_"))
+    return shapes
+
+
+def _moe_stats(cfg):
+    """``ops.moe``'s statistics under the names the step reports them,
+    ``moe_`` before its own but for the two losses; ``moe_held_share`` (how
+    much of the rows is here) only where the layer holds a share."""
+    held = {"moe_held_share": "mean"} if cfg.experts_held else {}
+    return {"aux_loss": "mean", "z_loss": "mean",
+            "moe_load_max_over_mean": "max", "moe_dropped": "sum",
+            "moe_rows_visited_share": "mean",
+            "moe_token_rows_read_share": "mean", **held}
+
+
+def _swiglu_ffn(h, lp, cfg, prefix: str = "w_"):
+    return swiglu(h @ lp[prefix + "gate"].astype(cfg.dtype),
+                  h @ lp[prefix + "up"].astype(cfg.dtype)
+                  ) @ lp[prefix + "down"].astype(cfg.dtype)
+
+
+def _dense_ffn(ctx: Ctx, x, aux, lp, residual: bool = True):
+    """-> (the stream, aux, nothing handed out of the scan)."""
+    cfg = ctx.cfg
+    with jax.named_scope("ffn"):
+        h = block_in(x, lp["mlp_norm"], cfg)
+        return add(ctx, x, _swiglu_ffn(h, lp, cfg), residual,
+                   lp["mlp_norm"]), aux, None
+
+
+def _moe(ctx: Ctx, x, lp, residual: bool = True):
+    """The expert layer (``ops.moe.moe_block``) on the residual stream
+    (its experts' sum alone without ``residual``).
+    Under a mesh it runs per shard, as the flash kernel does: tokens over
+    (dp, fsdp) x sp, experts over ep, their width over tp, partial outputs
+    summed over ep x tp.  Inside a region that is already manual (the
+    pipeline) it is called as it is and the partitioner splits it, which
+    the TPU lowering refuses for a Mosaic kernel."""
+    cfg, mesh, cst = ctx.cfg, ctx.mesh, ctx.cst
+    block = functools.partial(
+        moe_block, num_selected=cfg.num_selected, norm_eps=cfg.norm_eps,
+        norm_topk_prob=cfg.norm_topk_prob,
+        topk_norm_eps=cfg.topk_norm_eps, scoring=cfg.router_scoring,
+        gate_scale=cfg.routed_scaling_factor,
+        first_expert=cfg.first_expert, residual=residual)
+    bias = (lp["router_bias"],) if cfg.select_bias else ()
+    args = (x, lp["mlp_norm"], lp["router"], lp["w_gate"], lp["w_up"],
+            lp["w_down"]) + bias
+    if mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return block(*args)
+    # The parameters as the region takes them, laid out under the scope
+    # that uses them (pinned first as they are stored, or the partitioner
+    # moves the change of layout up to the scan's slice): the fsdp gathers
+    # and their gradients' scatters then carry a step scope like every
+    # other collective.
+    stored = _moe_shapes(cfg)
+
+    def laid_out(name, *gathered):
+        return cst(cst(lp[name], stored[name].axes[1:]), gathered)
+
+    with jax.named_scope("moe_route"):
+        small = (laid_out("mlp_norm", None), laid_out("router", None, None))
+    with jax.named_scope("moe_experts"):
+        args = (x,) + small + (
+            laid_out("w_gate", "expert", None, "mlp"),
+            laid_out("w_up", "expert", None, "mlp"),
+            laid_out("w_down", "expert", "mlp", None)) + bias
+    x_spec = P((AXIS_DP, AXIS_FSDP), AXIS_SP, None)
+    up_spec = P(AXIS_EP, None, AXIS_TP)
+    fn = manual_shard_map(
+        functools.partial(block, token_axes=(AXIS_DP, AXIS_FSDP, AXIS_SP),
+                          expert_axis=AXIS_EP, sum_axes=(AXIS_EP, AXIS_TP)),
+        set(mesh.axis_names),
+        in_specs=(x_spec, P(), P(), up_spec, up_spec,
+                  P(AXIS_EP, AXIS_TP, None)) + (P(),) * len(bias),
+        out_specs=(x_spec, P()), mesh=mesh)
+    return fn(*args)
+
+
+def _moe_ffn(ctx: Ctx, x, aux, lp, residual: bool = True):
+    """The expert layer and, where the model has one, the shared expert.
+    Hands the experts' assignments out of the scan where a selection bias
+    is moved by them."""
+    cfg, cst = ctx.cfg, ctx.cst
+    out, seen = _moe(ctx, x, lp, residual)
+    out = cst(out, ("batch", "seq", "embed"))
+    if cfg.shared_experts:
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            out = out + cst(_swiglu_ffn(h, lp, cfg, "shared_"),
+                            ("batch", "seq", "embed"))
+    how = _moe_stats(cfg)
+    aux = fold(aux, {k: seen[k.removeprefix("moe_")] for k in how}, how)
+    return out, aux, seen["counts"] if cfg.select_bias else None
+
+
+DENSE = Block(_dense_shapes, _dense_ffn, scopes=("ffn",))
+MOE = Block(_moe_shapes, _moe_ffn, saved=moe.SAVED_RESIDUALS,
+            scopes=("moe_route", "moe_dispatch", "moe_experts",
+                    "moe_combine", "ffn"),
+            stats=_moe_stats)

@@ -6,7 +6,9 @@ CPUs/GPUs and moves bytes; math lives in torch/tf — SURVEY.md §5
 the hot ops are part of the framework: flash attention on the MXU, ring
 attention over the ICI 'sp' axis, Ulysses all-to-all attention, MoE routing,
 the state-space scan and the gated short convolution (``ops/ssm.py``) and
-the gated delta rule (``ops/delta.py``) of the recurrent mixers.
+the gated delta rule (``ops/delta.py``) of the recurrent mixers.  The ops
+know no model: the mixers and FFNs that call them, one module each, live
+in ``ray_tpu/models/blocks/``.
 Every op has a pure-XLA reference implementation used for numerics tests and
 as the CPU fallback.
 """

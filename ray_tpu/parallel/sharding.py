@@ -11,6 +11,7 @@ baked into wrapper modules; here it is data.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
@@ -39,13 +40,6 @@ DEFAULT_RULES: LogicalAxisRules = {
     "vocab": AXIS_TP,             # embedding/vocab-parallel output head
     "kernel_in": AXIS_FSDP,       # ZeRO-3: param input dim over fsdp
     "expert": AXIS_EP,            # MoE experts over expert axis
-    "ssm_inner": None,            # a Mamba mixer's inner width: [z|xBC|dt]
-                                  # lie side by side in one projection, so
-                                  # a tp split has to cut each part (later)
-    "gdn_inner": None,            # a delta-rule mixer's inner width: [q|k|v|
-                                  # gate|a|b] in one projection, likewise
-    "sconv_inner": None,          # a short-convolution mixer's: [B|C|x] in
-                                  # one projection, likewise
     "stage": AXIS_PP,             # pipeline stages (stacked-stage layout)
     "layer": None,                # scanned-layer leading dim (non-pipelined)
 }
@@ -136,6 +130,36 @@ def manual_shard_map(f, axis_names, in_specs, out_specs,
         return in_ctx(*args)
 
     return call
+
+
+def batch_shard_map(fn, mesh: Mesh, in_ranks, out_ranks, reduce=None):
+    """``fn`` per shard of the batch: a Pallas kernel has no partitioning
+    rule, so a recurrent mixer's scan runs in a region that is manual over
+    EVERY mesh axis (the TPU lowering refuses a Mosaic kernel in one that
+    leaves an axis to the partitioner), each operand and output split by
+    its leading dimension over (dp, fsdp) and whole in every other.
+    ``in_ranks`` and ``out_ranks`` (a tuple where ``fn`` returns one) give
+    each one's rank, None for a replicated one; a replicated output is each
+    shard's own until ``reduce(value, axis names)`` joins them."""
+    def rows(rank):
+        if rank is None:
+            return P()
+        return P((AXIS_DP, AXIS_FSDP), *(None,) * (rank - 1))
+
+    def specs(ranks):
+        return (tuple(map(rows, ranks)) if isinstance(ranks, tuple)
+                else rows(ranks))
+
+    @functools.wraps(fn)   # the region keeps ``fn``'s name in the name stack
+    def joined(*args):
+        return tuple(
+            out if rank is not None else reduce(out, tuple(mesh.axis_names))
+            for out, rank in zip(fn(*args), out_ranks))
+
+    return manual_shard_map(
+        fn if reduce is None else joined, set(mesh.axis_names),
+        in_specs=specs(tuple(in_ranks)), out_specs=specs(out_ranks),
+        mesh=mesh)
 
 
 def sharding_tree(spec_tree: Any, mesh: Mesh,

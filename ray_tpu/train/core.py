@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu.models.blocks import layer_scopes
 from ray_tpu.models.llama import (
     LlamaConfig, forward_pipelined, init_params, loss_and_counts,
     param_logical_axes, update_router_bias,
@@ -35,31 +36,14 @@ tracing.watch_process()
 
 
 # The ``jax.named_scope`` names that between them cover the step program,
-# with no overlap: ``models/llama.py`` opens all but the last (an expert
-# layer opens ``ops/moe.py``'s four ``moe_*`` in place of ``ffn`` — and
-# ``ffn`` too for a shared expert —, a Mamba layer the four ``ssm_*`` in
-# place of the three ``attn*``, a delta-rule layer the four ``gdn_*``
-# likewise, a short-convolution layer the three ``sconv_*``, a layer of
-# several residual streams ``hc_map`` and ``hc_mix`` round each of its
-# blocks, a predicted-ahead module ``mtp_in``), ``step`` below opens
-# ``optimizer``.  A device op's ``op_name`` carries exactly one
-# of them, wrapped by JAX in the phase: bare or ``jvp(..)`` is the forward
-# pass, under ``rematted_computation`` the rematerialised forward,
-# ``transpose(jvp(..))`` the backward pass (``util.tracing.step_breakdown``).
-# The layer checkpoint (``models/llama.py::_checkpoint``) recomputes a layer
-# but the residuals it keeps by name: the flash kernel's output and
-# log-sum-exp (``ops/attention.py::SAVED_RESIDUALS``: no ``flash_fwd`` under
-# ``rematted_computation``), the expert layer's row index
-# (``ops/moe.py::SAVED_RESIDUALS``: no sort there; the row gather runs again)
-# and a Mamba or delta-rule layer's input projection
-# (``MAMBA_SAVED_RESIDUALS``, ``DELTA_SAVED_RESIDUALS``: no ``ssm_in`` or
-# ``gdn_in`` matmul there; convolution, scan and gated norm run again).
-STEP_SCOPES = ("embed", "attn_qkv", "attention", "attn_out", "ffn",
-               "moe_route", "moe_dispatch", "moe_experts", "moe_combine",
-               "ssm_in", "ssm_conv", "ssm_scan", "ssm_out",
-               "gdn_in", "gdn_conv", "gdn_scan", "gdn_out",
-               "sconv_in", "sconv_gate", "sconv_out",
-               "hc_map", "hc_mix", "mtp_in",
+# with no overlap: the decoder's own, those the registered blocks declare
+# (``models/blocks``) and ``optimizer``, which ``step`` below opens.  A
+# device op's ``op_name`` carries exactly one of them, wrapped by JAX in the
+# phase: bare or ``jvp(..)`` is the forward pass, under
+# ``rematted_computation`` the rematerialised forward (of all but the
+# residuals a block keeps by name), ``transpose(jvp(..))`` the backward
+# pass (``util.tracing.step_breakdown``).
+STEP_SCOPES = ("embed", *layer_scopes(), "mtp_in",
                "lm_head", "loss", "optimizer")
 
 

@@ -16,14 +16,17 @@ import pytest
 
 from benchmark.reference import olmo_hybrid
 from ray_tpu.models import llama
+from ray_tpu.models.blocks import delta
+from ray_tpu.models.blocks.delta import GDN_STATE_ABSMAX
 from ray_tpu.models.llama import (
-    GDN_STATE_ABSMAX, LlamaConfig, init_params, loss_fn, param_logical_axes)
+    LlamaConfig, init_params, loss_fn, param_logical_axes)
 from ray_tpu.ops.delta import (
     delta_chunked, delta_kernels, delta_reference, delta_xla, kernels_fit,
     unit_lower_inverse)
 from ray_tpu.ops.layers import rms_norm, swiglu
 from ray_tpu.ops.ssm import causal_conv1d
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+from ray_tpu.parallel.sharding import batch_shard_map
 from ray_tpu.train.core import (
     STEP_SCOPES, init_train_state, make_train_step)
 
@@ -375,7 +378,7 @@ def test_structural_controls_fail_against_the_reference(wrong, monkeypatch):
     params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
     program_params = params
     if wrong == "gate before the norm":
-        monkeypatch.setattr(llama, "rms_norm", lambda x, w, eps=1e-6: (
+        monkeypatch.setattr(delta, "rms_norm", lambda x, w, eps=1e-6: (
             x if w.shape[-1] == 16 and x.ndim == 4
             else rms_norm(x, w, eps)))
     elif wrong == "keys not normalised":
@@ -498,13 +501,15 @@ def test_on_a_mesh_the_rule_runs_per_shard_of_the_batch():
 
 def test_on_a_mesh_the_kernels_run_per_shard_of_the_batch():
     """fsdp=2 x tp=2 with heads the kernels fit: inside the manual region
+    (``parallel.sharding.batch_shard_map``, as the delta block calls it)
     each shard of the batch runs ``delta_fwd`` / ``delta_bwd`` on its own
     rows (the layouts the wrapper asks for are the shard's own), and the
     outputs, the state's maximum and the five gradients are one device's
     to the last bit."""
     args = _rule_inputs(128, True, seed=6, batch=4, heads=2, dk=32, dv=64)[:5]
     mesh = make_mesh(MeshConfig(fsdp=2, tp=2), devices=jax.devices()[:4])
-    one, many = llama._delta_scan(None, False), llama._delta_scan(mesh, False)
+    one, many = delta.shard_rule, batch_shard_map(
+        delta.shard_rule, mesh, (4, 4, 4, 3, 3), (4, None), reduce=jax.lax.pmax)
     assert _takes_the_kernels(many, args)
     scalar = lambda rule: (lambda *t: jnp.sum(jnp.square(rule(*t)[0])))
     with HIGHEST:

@@ -323,18 +323,18 @@ def test_a_changed_part_stands_apart_from_the_reference(change):
                 p = _changed(p, run, "sconv_in", lambda a: jnp.concatenate(
                     [a[..., 64:128], a[..., :64], a[..., 128:]], -1))
     if change == "silu-in-the-conv":
-        import ray_tpu.models.llama as llama
-        plain = llama.gated_short_conv
+        from ray_tpu.models.blocks import conv
+        plain = conv.gated_short_conv
 
         def with_silu(bcx, w):
             gate_in, gate_out, x = jnp.split(bcx, 3, -1)
             return gate_out * causal_conv1d(gate_in * x, w)
 
-        llama.gated_short_conv = with_silu
+        conv.gated_short_conv = with_silu
         try:
             got, _ = loss_fn(p, {"tokens": TOKENS}, wrong)
         finally:
-            llama.gated_short_conv = plain
+            conv.gated_short_conv = plain
     else:
         got, _ = _program_loss(wrong, p)
     apart = abs(float(got) - want) / want

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import granite_hybrid
-from ray_tpu.models import llama
+from ray_tpu.models.blocks import mamba
 from ray_tpu.models.llama import (
     LlamaConfig, forward_pipelined, init_params, loss_fn,
     make_pipeline_stage_fn, param_logical_axes, pipeline_stage_params)
@@ -290,7 +290,7 @@ def test_structural_controls_fail_against_the_reference(wrong, monkeypatch):
         program_params = _no_d(params)
     elif wrong == "gate after the norm":
         monkeypatch.setattr(
-            llama, "gated_rms_norm", lambda y, z, w, eps: rms_norm(
+            mamba, "gated_rms_norm", lambda y, z, w, eps: rms_norm(
                 y, w, eps) * jax.nn.silu(z))
     else:
         cfg = dataclasses.replace(cfg, **wrong)
