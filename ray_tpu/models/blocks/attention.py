@@ -25,8 +25,8 @@ from ray_tpu.ops.layers import (
     apply_rope, repeat_kv_heads, rms_norm, rope, yarn_inv_freq, yarn_mscale)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
-from ray_tpu.parallel.mesh import AXIS_DP, AXIS_FSDP, AXIS_SP, AXIS_TP
-from ray_tpu.parallel.sharding import manual_shard_map
+from ray_tpu.parallel.mesh import AXIS_SP, AXIS_TP
+from ray_tpu.parallel.sharding import BATCH_AXES, manual_shard_map
 
 SCOPES = ("attn_qkv", "attention", "attn_out")
 
@@ -116,11 +116,11 @@ def _attention(q, k, v, cfg, mesh):
     if impl == "reference":
         return mha_reference(q, k, v, causal=True, sm_scale=scale)
     # flash under a mesh: pallas has no SPMD partitioning rule, so run the
-    # kernel per-shard: batch over (dp,fsdp), heads over tp, seq replicated.
+    # kernel per-shard: batch over (dp,fsdp,ep), heads over tp, seq replicated.
     # Manual over EVERY mesh axis — the TPU lowering refuses a Mosaic
     # kernel in a region that leaves any axis to the partitioner.
     k, v = repeat_kv_heads(q, k, v)
-    spec = P((AXIS_DP, AXIS_FSDP), None, AXIS_TP, None)
+    spec = P(BATCH_AXES, None, AXIS_TP, None)
     fn = manual_shard_map(
         lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True,
                                            sm_scale=scale),

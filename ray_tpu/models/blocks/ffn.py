@@ -1,9 +1,12 @@
 """The FFNs: a dense SwiGLU (scope ``ffn``), and the dropless expert layer
-(``ops/moe.py``, under its four scopes ``moe_route`` / ``moe_dispatch`` /
-``moe_experts`` / ``moe_combine`` in place of ``ffn``) with or without a
+(``ops/moe.py``, under its scopes ``moe_route`` / ``moe_dispatch`` /
+``moe_experts`` / ``moe_combine`` in place of ``ffn``, and ``moe_exchange``
+where its experts are spread over an ``ep`` axis) with or without a
 shared expert, which every token meets (scope ``ffn``).  Expert tensors are
-sharded over 'ep': each rank computes its own experts' rows inside a
-shard_map and the partial outputs are summed.  One chip's share of a layer
+sharded over 'ep' and so are the tokens: inside a shard_map the layer's
+exchange (scope ``moe_exchange``) brings each rank the tokens of its group,
+the rank computes its own experts' rows, and each token's parts come back
+summed to the rank that owns it.  One chip's share of a layer
 (``experts_held``, ``first_expert``): the router keeps its published width,
 the expert tensors hold the experts that live here, and what the absent
 ones would add is left out.  The layer checkpoint keeps the row index
@@ -24,7 +27,7 @@ from ray_tpu.ops.layers import rms_norm, swiglu
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.parallel.mesh import (
     AXIS_DP, AXIS_EP, AXIS_FSDP, AXIS_SP, AXIS_TP)
-from ray_tpu.parallel.sharding import manual_shard_map
+from ray_tpu.parallel.sharding import BATCH_AXES, manual_shard_map
 
 
 def _swiglu_shapes(d: int, m: int, prefix: str = "w_"):
@@ -78,7 +81,8 @@ def _moe_stats(cfg):
     return {"aux_loss": "mean", "z_loss": "mean",
             "moe_load_max_over_mean": "max", "moe_dropped": "sum",
             "moe_rows_visited_share": "mean",
-            "moe_token_rows_read_share": "mean", **held}
+            "moe_token_rows_read_share": "mean",
+            "moe_rank_rows_max_over_mean": "max", **held}
 
 
 def _swiglu_ffn(h, lp, cfg, prefix: str = "w_"):
@@ -100,10 +104,11 @@ def _moe(ctx: Ctx, x, lp, residual: bool = True):
     """The expert layer (``ops.moe.moe_block``) on the residual stream
     (its experts' sum alone without ``residual``).
     Under a mesh it runs per shard, as the flash kernel does: tokens over
-    (dp, fsdp) x sp, experts over ep, their width over tp, partial outputs
-    summed over ep x tp.  Inside a region that is already manual (the
-    pipeline) it is called as it is and the partitioner splits it, which
-    the TPU lowering refuses for a Mosaic kernel."""
+    (dp, fsdp, ep) x sp, experts over ep, their width over tp; the layer
+    gathers its group's tokens over ep, and the partial outputs are
+    scattered back over ep and summed over tp.  Inside a region that is
+    already manual (the pipeline) it is called as it is and the partitioner
+    splits it, which the TPU lowering refuses for a Mosaic kernel."""
     cfg, mesh, cst = ctx.cfg, ctx.mesh, ctx.cst
     block = functools.partial(
         moe_block, num_selected=cfg.num_selected, norm_eps=cfg.norm_eps,
@@ -133,11 +138,11 @@ def _moe(ctx: Ctx, x, lp, residual: bool = True):
             laid_out("w_gate", "expert", None, "mlp"),
             laid_out("w_up", "expert", None, "mlp"),
             laid_out("w_down", "expert", "mlp", None)) + bias
-    x_spec = P((AXIS_DP, AXIS_FSDP), AXIS_SP, None)
+    x_spec = P(BATCH_AXES, AXIS_SP, None)
     up_spec = P(AXIS_EP, None, AXIS_TP)
     fn = manual_shard_map(
         functools.partial(block, token_axes=(AXIS_DP, AXIS_FSDP, AXIS_SP),
-                          expert_axis=AXIS_EP, sum_axes=(AXIS_EP, AXIS_TP)),
+                          expert_axis=AXIS_EP, sum_axes=(AXIS_TP,)),
         set(mesh.axis_names),
         in_specs=(x_spec, P(), P(), up_spec, up_spec,
                   P(AXIS_EP, AXIS_TP, None)) + (P(),) * len(bias),
@@ -164,6 +169,6 @@ def _moe_ffn(ctx: Ctx, x, aux, lp, residual: bool = True):
 
 DENSE = Block(_dense_shapes, _dense_ffn, scopes=("ffn",))
 MOE = Block(_moe_shapes, _moe_ffn, saved=moe.SAVED_RESIDUALS,
-            scopes=("moe_route", "moe_dispatch", "moe_experts",
-                    "moe_combine", "ffn"),
+            scopes=("moe_route", "moe_exchange", "moe_dispatch",
+                    "moe_experts", "moe_combine", "ffn"),
             stats=_moe_stats)

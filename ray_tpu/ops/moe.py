@@ -58,9 +58,19 @@ unmasked.
 
 Inside a ``shard_map`` (a Pallas kernel has no partitioning rule) the
 layer takes the names of the mesh axes: tokens are split over
-``token_axes`` and the same on every other axis; each rank of
-``expert_axis`` holds ``E / ep`` experts, computes the rows routed to
-them, and the partial outputs are summed over ``sum_axes``.
+``token_axes`` AND over ``expert_axis``, whose ranks are data parallel
+everywhere outside this layer, and each rank of ``expert_axis`` holds ``E /
+ep`` experts.  A rank routes its own tokens; then THE EXCHANGE (scope
+``moe_exchange``): an all-gather over ``expert_axis`` of the normed tokens
+``(T_local, d)``, their choices and their gates brings every rank the ``T =
+ep * T_local`` tokens of its group, the rank computes the rows that name
+its experts (the others lie past its live rows), and a ``psum_scatter`` of
+the ``(T, d)`` partial sums returns each token's parts, summed, to the rank
+that owns it.  Either way ``(ep - 1) * T_local * d`` numbers a rank: what
+an all-to-all of rows moves at 8 choices a token over 4 ranks, with a token
+sent once a rank however many of that rank's experts it chose and no
+ragged collective.  The partial outputs of a split expert width are summed
+over ``sum_axes``.
 """
 
 from __future__ import annotations
@@ -640,17 +650,21 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
     kernels visit, a tile once for each group in it, over the buffer's
     rows) and ``token_rows_read_share`` (the rows ONE token-side sum
     fetches over the ``T * k`` (token, choice) pairs: 1 where every expert
-    is held), beside ``counts (E,)``, every expert's assignments.
+    is held) and ``rank_rows_max_over_mean`` (the most live rows on a rank
+    of ``expert_axis`` over the ranks' mean: the straggler the others wait
+    for in the exchange; 1 without ranks), beside ``counts (E,)``, every
+    expert's assignments.
     ``router_w (d, E)``; ``w_gate``/``w_up (E', d, m')``, ``w_down (E',
     m', d)`` — all the experts, or the ``E'`` of them from ``first_expert``
     on that THIS chip holds of a layer divided over several (the router
     keeps its ``E`` outputs and ``num_selected`` a token; a row routed to
     an absent expert is computed nowhere and added nowhere), or inside a
-    ``shard_map`` this rank's ``E / ep`` of them (``expert_axis``) at this
-    rank's slice of ``m`` (``sum_axes`` then names the axes the partial
-    outputs are summed over, and ``token_axes`` those the tokens are split
-    over).  ``scoring``: ``softmax`` over the experts, or ``sigmoid`` of
-    each logit; ``select_bias (E,)`` is added to the scores for the
+    ``shard_map`` this rank's ``E / ep`` of them (``expert_axis``, over
+    which the tokens are split too and exchanged: the module docstring) at
+    this rank's slice of ``m`` (``sum_axes`` then names the axes the
+    partial outputs are summed over, and ``token_axes`` the other axes the
+    tokens are split over).  ``scoring``: ``softmax`` over the experts, or
+    ``sigmoid`` of each logit; ``select_bias (E,)`` is added to the scores for the
     SELECTION only, the gates are the scores themselves and no gradient
     reaches it (``update_selection_bias`` moves it); the gates, renormalised
     where ``norm_topk_prob`` (over their sum plus ``topk_norm_eps``, which
@@ -660,6 +674,8 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
     x = x.reshape(-1, d)
     t, e, k = x.shape[0], router_w.shape[1], num_selected
     local = w_gate.shape[0]
+    ranks = (expert_axis,) if expert_axis else ()
+    shards = tuple(token_axes) + ranks  # the axes the tokens are split over
 
     with jax.named_scope("moe_route"):
         h = rms_norm(x, norm_w, norm_eps)
@@ -689,17 +705,25 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
         # adds into its bins one element at a time
         assigned = jnp.sum(flat[:, None] == jnp.arange(e, dtype=flat.dtype),
                            axis=0, dtype=jnp.int32)
-        counts = _psum(assigned, token_axes)
-        tokens = _psum(jnp.float32(t), token_axes)
-        mean_prob = _psum(jnp.sum(probs, axis=0), token_axes) / tokens
+        counts = _psum(assigned, shards)
+        tokens = _psum(jnp.float32(t), shards)
+        mean_prob = _psum(jnp.sum(probs, axis=0), shards) / tokens
         aux = e * jnp.sum(counts.astype(jnp.float32) / tokens * mean_prob)
         z = _psum(jnp.sum(jnp.square(
-            jax.nn.logsumexp(logits, axis=-1))), token_axes) / tokens
+            jax.nn.logsumexp(logits, axis=-1))), shards) / tokens
         load = jnp.max(counts).astype(jnp.float32) * e / (tokens * k)
+
+    if expert_axis is not None:
+        with jax.named_scope("moe_exchange"):
+            h, experts, gates = (
+                jax.lax.all_gather(a, expert_axis, axis=0, tiled=True)
+                for a in (h, experts, gates))
+            assigned = jax.lax.psum(assigned, expert_axis)
+        # the group's tokens, rank by rank
+        t, flat = h.shape[0], experts.reshape(-1)
 
     with jax.named_scope("moe_dispatch"):
         group_sizes = assigned
-        ranks = (expert_axis,) if expert_axis else ()
         if local < e:  # the experts held here sort first
             first = first_expert
             if expert_axis is not None:
@@ -721,8 +745,8 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
         x_rows = _dispatch(h, row_token, slot_row, live)
         # rows the schedule gives a held expert, against the choices that
         # name one (all of them where every expert is held somewhere)
-        shards = tuple(token_axes) + ranks
-        reached = _psum(jnp.sum(group_sizes).astype(jnp.float32), shards)
+        here = jnp.sum(group_sizes).astype(jnp.float32)
+        reached = _psum(here, shards)
         held = local * (jax.lax.psum(1, ranks) if ranks else 1)
         wanted = tokens * k if held == e else _psum(jnp.sum(
             (flat < local).astype(jnp.float32)), shards)
@@ -734,6 +758,9 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
         # the rows one token-side sum fetches over the (token, choice)
         # pairs, a shard
         fetched = _psum(_token_rows_read(live, t, k), shards) / n_shards
+        # the fullest rank's live rows over the ranks' mean
+        uneven = (jax.lax.pmax(here, shards) * n_shards
+                  / jnp.maximum(reached, 1.0)) if ranks else jnp.float32(1.0)
 
     with jax.named_scope("moe_experts"):
         product = functools.partial(
@@ -743,10 +770,17 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
                                 product(x_rows, w_up)), w_down)
 
     with jax.named_scope("moe_combine"):
-        y = _psum(_combine(y_rows, gates, row_token, row_slot, slot_row,
-                           row_gate, live), sum_axes)
+        y = _combine(y_rows, gates, row_token, row_slot, slot_row, row_gate,
+                     live)
+    if expert_axis is not None:
+        with jax.named_scope("moe_exchange"):
+            y = jax.lax.psum_scatter(y, expert_axis, scatter_dimension=0,
+                                     tiled=True)
+    with jax.named_scope("moe_combine"):
+        y = _psum(y, sum_axes)
         out = ((x + y) if residual else y).reshape(shape)
     return out, {"aux_loss": aux, "z_loss": z, "load_max_over_mean": load,
                  "dropped": dropped, "held_share": reached / (tokens * k),
                  "rows_visited_share": visited,
-                 "token_rows_read_share": fetched, "counts": counts}
+                 "token_rows_read_share": fetched,
+                 "rank_rows_max_over_mean": uneven, "counts": counts}

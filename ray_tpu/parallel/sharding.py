@@ -26,11 +26,16 @@ from ray_tpu.parallel.mesh import (
 MeshAxes = Union[None, str, Tuple[str, ...]]
 LogicalAxisRules = Dict[str, MeshAxes]
 
-# Megatron-style 2D sharding + MoE + sequence parallelism.  Batch is split
-# over (dp, fsdp): fsdp behaves as extra data parallelism for activations
-# while sharding parameters ZeRO-3 style on their "embed"-like dimension.
+# The mesh axes a batch's rows are split over: fsdp behaves as extra data
+# parallelism for activations while sharding parameters ZeRO-3 style on
+# their "embed"-like dimension, and so does ep outside the expert layer:
+# the ranks that share a layer's experts each own a part of the tokens, and
+# the layer's exchange (``ops/moe.py``) brings a token to its experts.
+BATCH_AXES = (AXIS_DP, AXIS_FSDP, AXIS_EP)
+
+# Megatron-style 2D sharding + MoE + sequence parallelism.
 DEFAULT_RULES: LogicalAxisRules = {
-    "batch": (AXIS_DP, AXIS_FSDP),
+    "batch": BATCH_AXES,
     "seq": AXIS_SP,               # sequence/context parallelism (ring attn)
     "embed": None,                # activation embed dim stays replicated
     "heads": AXIS_TP,             # attention heads over tensor axis
@@ -49,7 +54,7 @@ def logical_to_mesh_axes(
     logical_axes: Sequence[Optional[str]],
     rules: Optional[LogicalAxisRules] = None,
 ) -> P:
-    """('batch','seq','embed') -> PartitionSpec(('dp','fsdp'),'sp',None).
+    """('batch','seq','embed') -> P(('dp','fsdp','ep'), 'sp', None).
 
     Mesh axes already consumed by an earlier dimension are dropped (a mesh
     axis can shard at most one dimension of a given tensor) — same contract
@@ -137,14 +142,14 @@ def batch_shard_map(fn, mesh: Mesh, in_ranks, out_ranks, reduce=None):
     rule, so a recurrent mixer's scan runs in a region that is manual over
     EVERY mesh axis (the TPU lowering refuses a Mosaic kernel in one that
     leaves an axis to the partitioner), each operand and output split by
-    its leading dimension over (dp, fsdp) and whole in every other.
+    its leading dimension over ``BATCH_AXES`` and whole in every other.
     ``in_ranks`` and ``out_ranks`` (a tuple where ``fn`` returns one) give
     each one's rank, None for a replicated one; a replicated output is each
     shard's own until ``reduce(value, axis names)`` joins them."""
     def rows(rank):
         if rank is None:
             return P()
-        return P((AXIS_DP, AXIS_FSDP), *(None,) * (rank - 1))
+        return P(BATCH_AXES, *(None,) * (rank - 1))
 
     def specs(ranks):
         return (tuple(map(rows, ranks)) if isinstance(ranks, tuple)

@@ -1,0 +1,258 @@
+"""JoyAI-LLM-Flash's block at CPU size — latent attention on the plain
+residual, sigmoid-scored experts with a selection bias, a shared expert and
+a held share spread over an ``ep`` axis WITH its exchange, a predicted-ahead
+module — the program (``ray_tpu/models/llama.py`` and its ops) against the
+plain reference (``benchmark/reference/joyai_flash.py``) on seeded weights.
+That the same model on ``MeshConfig(ep=2)`` and ``(ep=4)`` equals the
+one-device program is ``tests/test_moe.py::
+test_a_share_over_ep_equals_one_device``."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from benchmark.reference import joyai_flash, xing4
+from ray_tpu.models.llama import LlamaConfig, forward, init_params, loss_fn
+from ray_tpu.ops.moe import moe_block
+from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+from ray_tpu.parallel.sharding import named_sharding
+from ray_tpu.train.core import (
+    STEP_SCOPES, default_optimizer, init_train_state, make_train_step,
+    train_state_shardings)
+from ray_tpu.util.tracing import scope_and_phase
+
+# the reference's configuration (public key names) of the tiny model below
+CONF = dict(
+    first_k_dense_replace=1, num_attention_heads=4, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=16, rope_theta=32e6,
+    rms_norm_eps=1e-6, num_experts_per_tok=4, routed_scaling_factor=2.5,
+    first_expert=0, mtp_loss_coef=0.3)
+
+
+def tiny(**kw) -> LlamaConfig:
+    """The published pattern in small: 1 dense layer then expert layers,
+    16 experts of which this host holds the first 8, 4 a token."""
+    fields = dict(
+        vocab_size=128, embed_dim=64, num_layers=3, num_heads=4,
+        num_kv_heads=4, head_dim=16, mlp_dim=32, dense_mlp_dim=96,
+        max_seq_len=64, rope_theta=32e6, dtype=jnp.float32, remat=False,
+        attn_impl="reference", q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, num_experts=16,
+        num_selected=4, norm_topk_prob=True, experts_held=8, first_expert=0,
+        shared_experts=1, router_scoring="sigmoid", topk_method="noaux_tc",
+        routed_scaling_factor=2.5, leading_dense=1, num_nextn=1,
+        aux_loss_coef=0.0)
+    fields.update(kw)
+    return LlamaConfig(**fields)
+
+
+def seeded(cfg, seed=0):
+    """Parameters whose norm weights are drawn away from 1, as the train
+    loop draws them for its check."""
+    rng = np.random.default_rng(seed)
+
+    def drawn(path, a):
+        if not str(getattr(path[-1], "key", "")).endswith("norm"):
+            return a
+        return a * rng.uniform(0.5, 1.5, a.shape).astype(np.float32)
+
+    return jax.tree_util.tree_map_with_path(
+        drawn, init_params(jax.random.PRNGKey(seed), cfg))
+
+
+TOKENS = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0, 128)
+
+
+# -- (a) the whole model against the reference, one device --------------------
+
+def test_loss_per_token_loss_and_gradients_equal_the_plain_reference():
+    cfg = tiny()
+    assert cfg.kind_runs == ((("latent", "dense"), 1), (("latent", "moe"), 2))
+    params = seeded(cfg)
+    total, parts = jax.jit(
+        lambda p: loss_fn(p, {"tokens": TOKENS}, cfg))(params)
+    want = joyai_flash.loss_parts(params, TOKENS, CONF)
+    for name in ("loss", "mtp_loss", "moe_held_share"):
+        np.testing.assert_allclose(parts[name], want[name], rtol=2e-5)
+    np.testing.assert_allclose(total, want["total"], rtol=2e-5)
+    assert float(parts["moe_dropped"]) == 0.0
+    assert float(parts["moe_rank_rows_max_over_mean"]) == 1.0  # no ranks
+    logits, _ = forward(params, TOKENS[:, :-1], cfg)
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
+                               TOKENS[:, 1:, None], -1)[..., 0]
+    np.testing.assert_allclose(nll, want["token_nll"], atol=3e-5)
+    ours = jax.grad(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(params)
+    theirs = jax.grad(lambda p: joyai_flash.loss(p, TOKENS, CONF))(params)
+    apart = jax.tree.map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b))
+                           / (jnp.max(jnp.abs(b)) + 1e-12)), ours, theirs)
+    assert max(jax.tree.leaves(apart)) < 1e-4, apart
+    # no gradient reaches a selection bias
+    assert not np.any(np.asarray(ours["layers"][1]["router_bias"]))
+
+
+@pytest.mark.parametrize("change", [
+    dict(routed_scaling_factor=1.0), dict(shared_experts=0),
+    dict(norm_topk_prob=False), dict(mtp_loss_coef=0.0),
+    dict(rope_theta=1e4), dict(first_expert=8)],
+    ids=["gate_scale", "shared_expert", "renormalised", "mtp_weight",
+         "rope_theta", "the_other_host"])
+def test_a_changed_part_stands_apart_from_the_reference(change):
+    """What each part is worth to the loss: the program with the part
+    changed stands apart from the reference by more than the check's
+    tolerance, or the check could not see that part."""
+    params = seeded(tiny())
+    want = float(joyai_flash.loss(params, TOKENS, CONF))
+    got = float(loss_fn(params, {"tokens": TOKENS}, tiny(**change))[0])
+    assert abs(got - want) / want > joyai_flash.LOSS_RTOL, (got, want)
+
+
+# -- (c) the two hosts' shares add up -----------------------------------------
+
+def _expert_layer(tokens=96, d=64, m=32, experts=16, seed=3):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 10)
+    normal = jax.random.normal
+    return dict(
+        x=normal(keys[0], (tokens, d)),
+        mlp_norm=1.0 + 0.3 * normal(keys[1], (d,)),
+        router=normal(keys[2], (d, experts)) * d ** -0.5,
+        router_bias=0.05 * normal(keys[3], (experts,)),
+        w_gate=normal(keys[4], (experts, d, m)) * d ** -0.5,
+        w_up=normal(keys[5], (experts, d, m)) * d ** -0.5,
+        w_down=normal(keys[6], (experts, m, d)) * m ** -0.5,
+        shared_gate=normal(keys[7], (d, m)) * d ** -0.5,
+        shared_up=normal(keys[8], (d, m)) * d ** -0.5,
+        shared_down=normal(keys[9], (m, d)) * m ** -0.5)
+
+
+def _block(p, first, held):
+    """The routed part alone of the host that holds ``held`` experts from
+    ``first`` on, its step counters beside it."""
+    return moe_block(
+        p["x"], p["mlp_norm"], p["router"], *(
+            p[w][first:first + held] for w in ("w_gate", "w_up", "w_down")),
+        num_selected=4, norm_topk_prob=True, scoring="sigmoid",
+        select_bias=p["router_bias"], gate_scale=2.5, first_expert=first,
+        residual=False)
+
+
+def test_the_two_hosts_shares_add_up_to_the_uncut_layer():
+    """Host 0 with experts 0..7 and host 1 with 8..15: their routed parts,
+    and the shared expert ONCE, are the whole layer as the reference has
+    it."""
+    p = _expert_layer()
+    parts = [_block(p, first, 8) for first in (0, 8)]
+    routed = sum(y for y, _ in parts)
+    n = xing4.rms_norm(p["x"], p["mlp_norm"], 1e-6)
+    shared = xing4.swiglu(n, p["shared_gate"], p["shared_up"],
+                          p["shared_down"])
+    whole, _ = xing4.expert_ffn(p["x"][None], p, k=4, factor=2.5, first=0,
+                                eps=1e-6)
+    np.testing.assert_allclose(routed + shared, whole[0], atol=2e-5)
+    stats = [s for _, s in parts]
+    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
+    assert all(float(s["dropped"]) == 0.0 for s in stats)
+    np.testing.assert_array_equal(stats[0]["counts"], stats[1]["counts"])
+    assert int(jnp.sum(stats[0]["counts"])) == 96 * 4
+
+
+# -- (e) the straggler's statistic --------------------------------------------
+
+def _over_ep(p, mesh, first=0, held=8):
+    """``_block`` with the tokens and the held experts split over the
+    mesh's ``ep`` axis: the output and the statistics."""
+    weights = P("ep", None, None)
+    fn = jax.jit(jax.shard_map(
+        lambda x, norm, router, bias, *w: moe_block(
+            x, norm, router, *w, select_bias=bias, num_selected=4,
+            norm_topk_prob=True, scoring="sigmoid", gate_scale=2.5,
+            first_expert=first, residual=False, expert_axis="ep"),
+        mesh=mesh, in_specs=(P("ep", None), P(), P(), P(), weights, weights,
+                             weights),
+        out_specs=(P("ep", None), P()), check_vma=False))
+    return fn(p["x"], p["mlp_norm"], p["router"], p["router_bias"],
+              *(p[w][first:first + held]
+                for w in ("w_gate", "w_up", "w_down")))
+
+
+def test_rank_rows_max_over_mean_reads_a_routing_made_uneven_by_hand():
+    mesh = make_mesh(MeshConfig(ep=4), devices=jax.devices()[:4])
+    p = _expert_layer()
+    # as drawn: near even, and the exchange gives what one device gives
+    y, stats = _over_ep(p, mesh)
+    want, alone = _block(p, 0, 8)
+    np.testing.assert_allclose(y, want, atol=2e-5)
+    np.testing.assert_array_equal(stats["counts"], alone["counts"])
+    rows = np.asarray(alone["counts"][:8]).reshape(4, 2).sum(1)
+    assert float(stats["rank_rows_max_over_mean"]) == pytest.approx(
+        rows.max() / rows.mean())
+    assert float(stats["dropped"]) == 0.0
+    # every token pushed to experts 0 and 1 (rank 0's) and 14, 15 (the
+    # other host's): rank 0 has all the live rows, 4 times the mean
+    pushed = dict(p, router_bias=jnp.zeros(16).at[
+        jnp.array([0, 1, 14, 15])].set(10.0))
+    y, stats = _over_ep(pushed, mesh)
+    np.testing.assert_allclose(y, _block(pushed, 0, 8)[0], atol=2e-5)
+    assert float(stats["rank_rows_max_over_mean"]) == 4.0
+    assert float(stats["held_share"]) == 0.5
+    assert float(stats["dropped"]) == 0.0
+
+
+# -- (d) outside the expert layer the ranks are data parallel -----------------
+
+def _dot_rows(hlo: str, scopes):
+    """{scope: the leading dimensions of the results of the matrix products
+    the partitioned program runs under it}."""
+    rows = {}
+    for line in hlo.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\][^ ]* (?:dot|convolution)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not m or not name:
+            continue
+        scope = scope_and_phase(name.group(1), STEP_SCOPES)[0]
+        if scope in scopes:
+            rows.setdefault(scope, set()).add(
+                tuple(int(n) for n in m.group(1).split(",")))
+    return rows
+
+
+def test_tokens_are_split_over_ep_outside_the_experts():
+    """In the partitioned ``ep=4`` step of 4 rows x 40 positions the mixer,
+    the dense FFN, the shared expert, the module's projection and both
+    heads multiply ONE row's 40 tokens a chip, never the 4 rows' 160 (the
+    heads: ``T_local`` x the host's whole slice); the expert layer alone
+    sees the group's tokens, under its own scopes.  (40 and 160 are no
+    width of the model.)"""
+    cfg = tiny(remat=True)
+    opt = default_optimizer()
+    mesh = make_mesh(MeshConfig(ep=4), devices=jax.devices()[:4])
+    state = jax.eval_shape(lambda k: init_train_state(k, cfg, opt),
+                           jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        state, train_state_shardings(cfg, opt, mesh))
+    tokens = jax.ShapeDtypeStruct((4, 41), jnp.int32,
+                                  sharding=named_sharding(mesh, "batch", None))
+    assert tokens.sharding.spec == P(("dp", "fsdp", "ep"), None)
+    hlo = make_train_step(cfg, opt, mesh=mesh).lower(
+        state, {"tokens": tokens}).compile().as_text()
+    outside = ("attn_qkv", "attn_out", "ffn", "mtp_in", "lm_head")
+    seen = _dot_rows(hlo, outside + ("moe_experts", "moe_route"))
+    assert set(outside) <= set(seen), sorted(seen)
+    for scope in outside:
+        # a product's result holds a chip's 40 tokens (as 1 x 40 or flat) or
+        # no token at all (a weight's gradient), never the host's 4 x 40
+        assert any(40 in shape for shape in seen[scope]), (scope, seen[scope])
+        for shape in seen[scope]:
+            assert 160 not in shape and shape[:2] != (4, 40), (scope, shape)
+    assert any(128 in s for s in seen["lm_head"])       # the whole slice
+    # the exchange is there, under its own scope, and nowhere else are
+    # tokens gathered over the ranks
+    gathers = [line for line in hlo.splitlines()
+               if re.search(r"= \S+ all-gather(-start)?\(", line)]
+    assert gathers and all("moe_exchange" in g for g in gathers), gathers
+    assert "moe_exchange" in STEP_SCOPES

@@ -241,7 +241,8 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
             else "moe_experts"], name
     # Every scope has a matmul or a collective of its own but the loss
     # (elementwise and reductions) — and each phase is told apart.
-    moe_scopes = {"moe_route", "moe_dispatch", "moe_experts", "moe_combine"}
+    moe_scopes = {"moe_route", "moe_exchange", "moe_dispatch", "moe_experts",
+                  "moe_combine"}
     ssm_scopes = {"ssm_in", "ssm_conv", "ssm_scan", "ssm_out"}
     want = set(STEP_SCOPES) - ({"ffn"} if moe else moe_scopes)
     # one residual stream, no predicted-ahead module (tests/test_latent_streams.py
@@ -260,6 +261,8 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
     # sort, gathers and the weighted sum: a matmul only in the interpreted
     # kernels and the router
     want -= {"moe_dispatch", "moe_combine"}
+    # the exchange is collectives over an ``ep`` axis, and only there
+    want -= {"moe_exchange"} if mesh is None else set()
     assert want <= {s for s, _ in seen}, seen
     block = "moe_experts" if moe else "ffn"
     assert {(block, "forward"), (block, "remat"), (block, "backward"),
@@ -284,7 +287,8 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
         every = {scope_and_phase(name, STEP_SCOPES) for _, name in _op_names(
             step.lower(state, batch).compile().as_text(), ("",))}
         gone = {("moe_combine", "remat")}
-        assert {(s, p) for s in moe_scopes
+        opened = moe_scopes - ({"moe_exchange"} if mesh is None else set())
+        assert {(s, p) for s in opened
                 for p in ("forward", "remat", "backward")} - gone <= every
         assert not gone & every, gone & every
 

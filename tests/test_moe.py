@@ -664,3 +664,68 @@ def test_expert_parallel_equals_one_device(mesh_kw):
         lambda a, b: float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-12)),
         jax.device_get(g2), g1)
     assert max(jax.tree.leaves(worst)) < 1e-4, worst
+
+
+@pytest.mark.parametrize("ep", [2, 4], ids=["ep2", "ep4"])
+def test_a_share_over_ep_equals_one_device(ep):
+    """A JoyAI-LLM-Flash-shaped model (latent attention, a leading dense
+    layer, sigmoid top-4 of 16 with a selection bias, a shared expert, a
+    predicted-ahead module) of which this host holds 8 experts, on
+    ``MeshConfig(ep=ep)`` with its tokens split over the ranks and the
+    exchange between them: the loss, every gradient, the experts' counts
+    and the selection bias a train step moves are the one-device
+    program's."""
+    from ray_tpu.models.llama import loss_and_counts
+    from ray_tpu.parallel.sharding import named_sharding
+    from ray_tpu.train.core import (
+        default_optimizer, init_train_state, make_train_step)
+
+    cfg = LlamaConfig.tiny(
+        num_layers=3, leading_dense=1, dense_mlp_dim=96, mlp_dim=32,
+        q_lora_rank=24, kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, num_experts=16, num_selected=4, experts_held=8,
+        norm_topk_prob=True, shared_experts=1, router_scoring="sigmoid",
+        topk_method="noaux_tc", routed_scaling_factor=2.5, num_nextn=1,
+        aux_loss_coef=0.0, attn_impl="flash", remat=True)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 65), 0,
+                                cfg.vocab_size)
+    loss = lambda p, t, mesh=None: loss_and_counts(
+        p, {"tokens": t}, cfg, mesh=mesh)
+    (want, (m1, c1)), g1 = jax.value_and_grad(loss, has_aux=True)(
+        params, tokens)
+    mesh = make_mesh(MeshConfig(ep=ep), devices=jax.devices()[:ep])
+    rows = named_sharding(mesh, "batch", None)
+    assert rows.spec == P(("dp", "fsdp", "ep"), None)
+    with use_mesh(mesh):
+        sharded = shard_pytree(params, param_logical_axes(cfg), mesh)
+        assert sharded["layers"][1]["w_gate"].sharding.spec[1] == "ep"
+        toks = jax.device_put(tokens, rows)
+        (got, (m2, c2)), g2 = jax.jit(jax.value_and_grad(
+            functools.partial(loss, mesh=mesh), has_aux=True))(sharded, toks)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for name in ("loss", "mtp_loss", "moe_held_share",
+                 "moe_load_max_over_mean"):
+        assert float(m2[name]) == pytest.approx(float(m1[name]), rel=1e-5)
+    assert float(m2["moe_dropped"]) == 0
+    assert float(m1["moe_rank_rows_max_over_mean"]) == 1.0
+    assert 1.0 <= float(m2["moe_rank_rows_max_over_mean"]) <= ep
+    for a, b in zip(jax.tree.leaves(c1), jax.tree.leaves(c2)):
+        np.testing.assert_array_equal(a, b)     # over ALL the token shards
+    worst = jax.tree.map(
+        lambda a, b: float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-12)),
+        jax.device_get(g2), g1)
+    assert max(jax.tree.leaves(worst)) < 1e-4, worst
+    # one train step through the normal path: the bias moves by the host's
+    # counts, the same way on every rank
+    opt = default_optimizer()
+    alone, _ = make_train_step(cfg, opt, donate=False)(
+        init_train_state(jax.random.PRNGKey(0), cfg, opt), {"tokens": tokens})
+    state = init_train_state(jax.random.PRNGKey(0), cfg, opt, mesh=mesh)
+    spread, metrics = make_train_step(cfg, opt, mesh=mesh, donate=False)(
+        state, {"tokens": toks})
+    for a, b in ((alone.params["layers"][1], spread.params["layers"][1]),
+                 (alone.params["mtp"]["layers"],
+                  spread.params["mtp"]["layers"])):
+        np.testing.assert_array_equal(a["router_bias"], b["router_bias"])
+    assert np.isfinite(float(metrics["grad_norm"]))
