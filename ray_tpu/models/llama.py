@@ -57,7 +57,8 @@ from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.moe import update_selection_bias
 from ray_tpu.parallel.mesh import AXIS_SP
 from ray_tpu.parallel.sharding import (
-    DEFAULT_RULES, LogicalAxisRules, with_logical_constraint,
+    DEFAULT_RULES, LogicalAxisRules, logical_to_mesh_axes, manual_shard_map,
+    with_logical_constraint,
 )
 
 
@@ -383,27 +384,52 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig, *,
             _mean_aux(aux, cfg, cfg.kind_runs))
 
 
-def _embed(params, tokens, cfg: LlamaConfig, mesh, cst):
-    """The tokens' embeddings (inside the scope ``embed``)."""
-    if mesh is not None:
-        # One-hot matmul instead of gather: with a ('vocab','embed')-
-        # sharded table this lowers to a local matmul + psum over 'tp'
-        # — the gather form makes the SPMD partitioner fully
-        # rematerialize the table.
-        onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.dtype)
-        x = onehot @ params["embed"].astype(cfg.dtype)
+def _embed(params, tokens, cfg: LlamaConfig, mesh, rules):
+    """The tokens' embeddings (inside the scope ``embed``): the rows of the
+    table that the tokens name.  Where the rules in force lay the vocabulary
+    over mesh axes of more than one device, each shard takes the rows it
+    holds and leaves zeros for the other shards' tokens, and the parts are
+    summed over those axes; the table is never gathered over them, and its
+    gradient is the scatter-add of each token's cotangent into the shard
+    that holds its row."""
+    axes = logical_to_mesh_axes(("vocab",), rules)[0] or ()
+    axes = tuple(a for a in ((axes,) if isinstance(axes, str) else axes)
+                 if mesh is not None and mesh.shape[a] > 1)
+
+    def take(table, tokens, **kw):
+        return jnp.take(table, tokens, axis=0, **kw).astype(cfg.dtype)
+
+    def take_held(shard, tokens):
+        n = shard.shape[0]
+        local = tokens - n * jax.lax.axis_index(axes)
+        held = (local >= 0) & (local < n)
+        return jnp.where(held[..., None], take(shard, local, mode="clip"),
+                         0)[None]
+
+    if axes:
+        parts = manual_shard_map(take_held, axes, in_specs=(P(axes), P()),
+                                 out_specs=P(axes), mesh=mesh)
+        x = parts(params["embed"], tokens).sum(0)
+    elif mesh is None:
+        x = take(params["embed"], tokens)
     else:
-        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
-    return cst(scaled(x, cfg.embedding_multiplier),
-               ("batch", "seq", "embed"))
+        # Left to the partitioner, the rows are taken sequence-first.  The
+        # gradient of a table read twice (a predicted-ahead module's
+        # tokens beside the model's) is ONE scatter-add: XLA joins the two
+        # before it partitions them, by concatenating indices and
+        # cotangents along their leading dimension — the sequence, which
+        # no chip shares, where the batch's rows would then be moved
+        # between the chips that share them.
+        x = take(params["embed"], tokens.swapaxes(0, 1)).swapaxes(0, 1)
+    x = scaled(x, cfg.embedding_multiplier)
+    return _make_cst(mesh, rules)(x, ("batch", "seq", "embed"))
 
 
 def _hidden(params, tokens, cfg: LlamaConfig, mesh, rules):
     """Embedding and layers: ``(h (b, s, d) before the last norm, aux,
     each run's per-layer expert counts or None)``."""
-    cst = _make_cst(mesh, rules)
     with jax.named_scope("embed"):
-        x = to_streams(_embed(params, tokens, cfg, mesh, cst), cfg)
+        x = to_streams(_embed(params, tokens, cfg, mesh, rules), cfg)
     x, aux, counts = _scan_layers(params["layers"], x, cfg, mesh, rules)
     return from_streams(x, cfg), aux, counts
 
@@ -573,10 +599,7 @@ def forward_pipelined(params: Dict[str, Any], tokens: jax.Array,
     _one_kind(cfg, "forward_pipelined")
     cst = _make_cst(mesh, rules)
     with jax.named_scope("embed"):
-        onehot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=cfg.dtype)
-        x = cst(scaled(onehot @ params["embed"].astype(cfg.dtype),
-                        cfg.embedding_multiplier),
-                ("batch", "seq", "embed"))
+        x = _embed(params, tokens, cfg, mesh, rules)
 
     sp_manual = cfg.attn_impl in ("ring", "ulysses") and \
         mesh.shape[AXIS_SP] > 1
@@ -613,7 +636,7 @@ def _predicted_ahead(params, h, next_tokens, aux, cfg: LlamaConfig, mesh,
     counts)`` as ``_hidden`` and the head do."""
     mp, cst = params["mtp"], _make_cst(mesh, rules)
     with jax.named_scope("embed"):
-        e = _embed(params, next_tokens, cfg, mesh, cst)
+        e = _embed(params, next_tokens, cfg, mesh, rules)
     with jax.named_scope("mtp_in"):
         x = jnp.concatenate([rms_norm(h, mp["h_norm"], cfg.norm_eps),
                              rms_norm(e, mp["e_norm"], cfg.norm_eps)], -1)
@@ -694,8 +717,7 @@ def make_pipeline_stage_fn(cfg: LlamaConfig):
     def stage_fn(sp, x):
         if "embed" in sp:
             with jax.named_scope("embed"):
-                x = scaled(jnp.take(sp["embed"], x, axis=0).astype(
-                    cfg.dtype), cfg.embedding_multiplier)
+                x = _embed(sp, x, cfg, None, None)
         x = _scan_layers(sp["layers"], x, cfg, None, None)[0]
         if "lm_head" in sp:
             x = _lm_head(sp, x, cfg, _make_cst(None, None))

@@ -3,6 +3,9 @@ single-device numerics (the reference tests multi-node semantics with an
 in-process Cluster, SURVEY.md §4.2; here the analog is the virtual 8-device
 CPU mesh)."""
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import optax
@@ -12,10 +15,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.models import (
     LlamaConfig, init_params, forward, loss_fn, param_logical_axes,
 )
-from ray_tpu.models.llama import forward_pipelined
+from ray_tpu.models.llama import _embed, forward_pipelined
 from ray_tpu.parallel import (MeshConfig, make_mesh, shard_pytree,
                               use_mesh)
+from ray_tpu.parallel.sharding import DEFAULT_RULES, named_sharding
 from ray_tpu.train import TrainState, init_train_state, make_train_step
+from ray_tpu.train.core import STEP_SCOPES
+from ray_tpu.util.tracing import scope_and_phase
 
 
 KEY = jax.random.PRNGKey(0)
@@ -172,6 +178,95 @@ def _op_names(hlo_text, opcodes):
     return out
 
 
+VOCAB_OVER_FSDP = dict(DEFAULT_RULES, vocab="fsdp", kernel_in=None)
+VOCAB_OVER_BOTH = dict(DEFAULT_RULES, vocab=("fsdp", "tp"), kernel_in=None)
+
+
+def _embed_tokens(kind, vocab, b=8, s=32):
+    """A batch of tokens: uniform; one token on three rows in four (its
+    cotangents add up in ONE row of the table's gradient); or all inside the
+    SECOND of two vocabulary shards (the first shard's part is zeros)."""
+    toks = jax.random.randint(KEY, (b, s), 0, vocab, dtype=jnp.int32)
+    if kind == "repeats":
+        often = jax.random.bernoulli(jax.random.PRNGKey(5), 0.75, (b, s))
+        return jnp.where(often, vocab // 2 + 3, toks)
+    if kind == "one_shard":
+        return vocab // 2 + toks % (vocab // 2)
+    return toks
+
+
+@pytest.mark.parametrize("tokens", ["uniform", "repeats", "one_shard"])
+@pytest.mark.parametrize("mesh_kw,rules", [
+    (dict(tp=2), None), (dict(fsdp=2, tp=2), None), (dict(ep=4), None),
+    (dict(dp=2), None), (dict(fsdp=2, tp=2), VOCAB_OVER_FSDP),
+    (dict(dp=2, fsdp=2, tp=2), VOCAB_OVER_BOTH)],
+    ids=["tp2", "fsdp2_tp2", "ep4", "dp2", "vocab_over_fsdp",
+         "vocab_over_fsdp_and_tp"])
+def test_embedding_under_a_mesh_takes_the_rows_one_device_takes(
+        mesh_kw, rules, tokens):
+    """``_embed`` under a mesh, whatever axis the rules in force lay the
+    vocabulary on: the values ``jnp.take`` gives on one device bit for bit
+    (a row is read, not computed), and the table's gradient to float32's
+    rounding (a row's cotangents are summed in another order)."""
+    toks = _embed_tokens(tokens, 256)
+    ct = jax.random.normal(jax.random.PRNGKey(7), (*toks.shape, 64))
+    mesh = make_mesh(MeshConfig(**mesh_kw),
+                     devices=jax.devices()[:math.prod(mesh_kw.values())])
+    placed = functools.partial(named_sharding, mesh, rules=rules)
+
+    def value_and_table_grad(cfg, mesh, rules, table, toks):
+        def f(table):
+            x = _embed({"embed": table}, toks, cfg, mesh, rules)
+            return jnp.sum(x.astype(jnp.float32) * ct), x
+        (_, x), g = jax.value_and_grad(f, has_aux=True)(table)
+        return x, g
+
+    # the gradients in float32: in bfloat16 the CPU's compiler drops the
+    # cotangent's rounding from one of the two programs and not the other
+    for dtype in (jnp.bfloat16, jnp.float32):
+        cfg = LlamaConfig.tiny(dtype=dtype, embedding_multiplier=12.0)
+        table = init_params(KEY, cfg)["embed"]
+        want_x = (jnp.take(table, toks, axis=0).astype(dtype)
+                  * jnp.asarray(12.0, dtype))
+        x1, g1 = jax.jit(functools.partial(
+            value_and_table_grad, cfg, None, None))(table, toks)
+        assert x1.dtype == dtype and jnp.array_equal(x1, want_x)
+        x, g = jax.jit(functools.partial(
+            value_and_table_grad, cfg, mesh, rules))(
+            jax.device_put(table, placed("vocab", "kernel_in")),
+            jax.device_put(toks, placed("batch", "seq")))
+        assert x.dtype == dtype and jnp.array_equal(x, want_x)
+        assert x.sharding.is_equivalent_to(
+            placed("batch", "seq", "embed"), 3)
+        assert g.sharding.is_equivalent_to(placed("vocab", "kernel_in"), 2)
+    # up to 200 cotangents of size 12 in a row: 1e-3 is five ulps of the sum
+    assert float(jnp.max(jnp.abs(g - g1))) < 1e-3
+    assert float(jnp.max(jnp.abs(g1))) > 100 or tokens != "repeats"
+
+
+def test_embedding_over_tp_gathers_no_table_and_multiplies_nothing():
+    """The compiled step under ``tp=2``: the vocabulary's shard stays a
+    shard (no all-gather gives an array of the whole table's shape, which
+    is what the partitioner makes of a plain gather from a table split by
+    rows) and scope ``embed`` holds no matmul: the rows are taken, and the
+    shards' parts summed by the scope's one all-reduce."""
+    cfg = LlamaConfig.tiny(attn_impl="flash", remat=True, vocab_size=384)
+    opt = optax.adam(1e-2)
+    mesh = make_mesh(MeshConfig(tp=2), devices=jax.devices()[:2])
+    state = init_train_state(KEY, cfg, opt, mesh=mesh)
+    step = make_train_step(cfg, opt, mesh=mesh, donate=False)
+    text = step.lower(state, _batch(cfg)).compile().as_text()
+    table = f"[{cfg.vocab_size},{cfg.embed_dim}]"
+    shard = f"[{cfg.vocab_size // 2},{cfg.embed_dim}]"
+    assert shard in text and not [
+        line for line in text.splitlines()
+        if "all-gather" in line and table in line.split("all-gather")[0]]
+    in_scope = {op for op, name in _op_names(text, ("",))
+                if scope_and_phase(name, STEP_SCOPES)[0] == "embed"}
+    assert {"scatter", "all-reduce"} <= in_scope, in_scope
+    assert not {"dot", "convolution", "all-gather"} & in_scope, in_scope
+
+
 @pytest.mark.parametrize("mesh_kw,moe,hybrid", [
     (None, False, False), (dict(fsdp=2, tp=2), False, False),
     (None, True, False), (dict(fsdp=2, ep=2), True, False),
@@ -187,8 +282,7 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
     attention layer's three (a hybrid has both kinds of layer)."""
     import re
 
-    from ray_tpu.train.core import STEP_SCOPES
-    from ray_tpu.util.tracing import KERNEL_NAMES, scope_and_phase
+    from ray_tpu.util.tracing import KERNEL_NAMES
 
     cfg = LlamaConfig.tiny(attn_impl="flash", remat=True, num_kv_heads=2,
                            **(dict(num_experts=4, num_selected=2,
@@ -256,7 +350,17 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
     # no short-convolution layer (tests/test_lfm2.py has such a model)
     want -= {"sconv_in", "sconv_gate", "sconv_out"}
     want -= {"loss"} if mesh is None or moe else set()  # tp splits it
-    want -= {"embed"} if mesh is None else set()  # a gather, no matmul
+    # the embedding takes the rows its tokens name: a gather, never a
+    # matmul.  On one device the scope shows nothing here; under a mesh
+    # its collectives: the sum of the shards' parts where the vocabulary
+    # is split (tp), the moves between the table's columns (fsdp) and the
+    # batch's rows, the gradient's sum over the ranks that share the table
+    want -= {"embed"} if mesh is None else set()
+    in_embed = {op for op, name in named
+                if scope_and_phase(name, STEP_SCOPES)[0] == "embed"}
+    assert "dot" not in in_embed, in_embed
+    assert not (mesh_kw or {}).get("tp") or {
+        ("embed", "forward"), ("embed", "backward")} <= seen, seen
     want -= {"optimizer"} if mesh is None else set()
     # sort, gathers and the weighted sum: a matmul only in the interpreted
     # kernels and the router

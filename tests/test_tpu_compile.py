@@ -234,6 +234,66 @@ def test_fsdp2_tp2_train_step_compiles(mesh4, as_on_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
+def _embed_twice_grad(cfg, mesh):
+    """The table's gradient of a table read twice — a model's tokens and
+    its predicted-ahead module's, one position on — compiled for ``mesh``
+    at ``cfg``'s widths, four rows of 4096."""
+    from ray_tpu.models.llama import _embed
+    from ray_tpu.parallel.sharding import named_sharding
+
+    def loss(table, tokens, ct):
+        with jax.named_scope("embed"):
+            x = _embed({"embed": table}, tokens[:, :-1], cfg, mesh, None)
+            e = _embed({"embed": table}, tokens[:, 1:], cfg, mesh, None)
+        return jnp.sum((x * ct + jnp.tanh(e) * ct).astype(jnp.float32))
+
+    in_table = named_sharding(mesh, "vocab", "kernel_in")
+    return jax.jit(jax.grad(loss), out_shardings=in_table).lower(
+        _shape((cfg.vocab_size, cfg.embed_dim), jnp.float32, in_table),
+        _shape((4, 4097), jnp.int32, named_sharding(mesh, "batch", "seq")),
+        _shape((4, 4096, cfg.embed_dim), jnp.bfloat16,
+               named_sharding(mesh, "batch", "seq", "embed"))).compile()
+
+
+def _collectives(text):
+    """(opcode, result's shapes) of every collective of a compiled text."""
+    import re
+
+    return [(m.group(2), m.group(1)) for m in re.finditer(
+        r"= (.*?) (all-gather|all-reduce|reduce-scatter|all-to-all|"
+        r"collective-permute)(?:-start)?\(", text)]
+
+
+def test_embedding_over_fsdp2_tp2_gathers_no_table(mesh4):
+    """DeepSeek's table (102400 x 4096, rows over tp, columns over fsdp):
+    a chip keeps its (51200, 2048) block — no collective's result is as
+    large as the block, let alone a row shard or the table — and scope
+    ``embed`` multiplies nothing."""
+    cfg = LlamaConfig.tiny(vocab_size=102400, embed_dim=4096,
+                           dtype=jnp.bfloat16)
+    text = _embed_twice_grad(cfg, mesh4).as_text()
+    assert " scatter(" in text and " gather(" in text
+    assert " convolution(" not in text and " dot(" not in text
+    moved = _collectives(text)
+    assert moved and not [
+        m for m in moved if "51200" in m[1] or "102400" in m[1]], moved
+
+
+def test_embedding_read_twice_moves_no_rows_between_chips(topo):
+    """JoyAI's slice (64640 x 2048, whole on each of four ``ep`` ranks,
+    a sequence a rank): XLA joins the two uses' scatter-adds before it
+    partitions them, and joins them along the sequence — the table's
+    all-reduce is the scope's only collective.  (Joined along the batch's
+    rows, twelve collective-permutes move tokens and cotangents between
+    the ranks.)"""
+    mesh = make_mesh(MeshConfig(ep=4), devices=topo.devices)
+    cfg = LlamaConfig.tiny(vocab_size=64640, embed_dim=2048,
+                           dtype=jnp.bfloat16)
+    text = _embed_twice_grad(cfg, mesh).as_text()
+    assert text.count(" scatter(") == 1
+    assert [m[0] for m in _collectives(text)] == ["all-reduce"]
+
+
 def test_expert_parallel_train_step_compiles(mesh4, as_on_chip):
     """An expert layer on fsdp=2 x ep=2 (the mesh fixture's devices,
     re-meshed): the grouped-product kernels inside a region manual over
