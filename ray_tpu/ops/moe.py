@@ -14,8 +14,13 @@ imbalance: nothing has a capacity and nothing is dropped.  The layer
 - ``moe_dispatch``: a stable sort of the ``T * k`` (token, choice) pairs
   by expert that carries each pair's gate along, its inverse by a second
   sort, a gather to ``(T * k, d)`` rows;
-- ``moe_experts``: three grouped products over the ragged groups (row
-  ``r`` meets the weights of the group it lies in) and SwiGLU;
+- ``moe_experts``: the experts' FFN over the ragged groups (row ``r``
+  meets the weights of the group it lies in) as ONE rule, ``expert_ffn``:
+  the gate and up products with SwiGLU in their kernel's epilogue, the
+  down product; backward, SwiGLU's derivative in the epilogue of the
+  product with the down weights transposed, the rows' gradient as one
+  kernel that adds its two products, three weight gradients.  Between the
+  kernels XLA does nothing: no pass over the row buffer;
 - ``moe_combine``: each token's rows gathered back, weighted by its
   gates, summed (``_token_sum``), and added to the residual.
 
@@ -32,10 +37,14 @@ may be empty or hold every row.  The grouped product is two Pallas
 kernels, chosen over ``jax.lax.ragged_dot`` by measurement on a v5e
 (PERF.md §6, PR 25): ``moe_gmm`` (rows x their group's weights, also with
 the weights transposed for the rows' gradient) and ``moe_tgmm`` (the
-weights' gradient, per group).  Both walk one schedule of (group, row
-tile) visits handed over as scalar prefetch: a tile that two groups
-share is visited once for each, and the rows that are not the visit's
-are masked.
+weights' gradient, per group).  ``moe_gmm`` is one body in four forms
+(``_GMM_FORMS``): the plain product, ``moe_gmm_swiglu`` (two products of
+one row tile and SwiGLU of their float32 accumulators), ``moe_gmm_dswiglu``
+(a product and SwiGLU's derivative) and ``moe_gmm_pair`` (the float32 sum
+of two products).  All walk one schedule of (group, row tile) visits
+handed over as scalar prefetch: a tile that two groups share is visited
+once for each, and the rows that are not the visit's are masked, in every
+output.
 
 The row buffer is static, ``T * k`` rows however few are held, and the
 work on it ends at the LIVE rows, the groups' sum (a value the device
@@ -85,7 +94,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import attention
-from ray_tpu.ops.layers import rms_norm, swiglu
+from ray_tpu.ops.layers import rms_norm
 
 # Row tile of the grouped product.  A tile that two groups share is
 # computed once for each, so a call of G groups executes up to
@@ -199,28 +208,55 @@ def _row_mask(lo, hi, tile):
     return (rows >= lo) & (rows < hi)
 
 
-def _gmm_kernel(offsets, group_ids, tile_ids, num_visits, lhs_ref, rhs_ref,
-                out_ref, *, tile, transpose_rhs):
+# The forms of ``moe_gmm``: a call's operands in order (``r`` a tile of
+# rows at their whole width, ``w`` the visit's group's weight block, ``t`` a
+# tile of rows at the output's column block) and the outputs it may write.
+# Every product accumulates in float32 and an output is rounded once.
+_GMM_FORMS = {
+    "plain": ("rw", 1),      # lhs @ w
+    "swiglu": ("rww", 3),    # h = silu(x @ w_gate) * (x @ w_up); then g, u
+    "dswiglu": ("rwtt", 2),  # dh = d_y @ w_down^T against g, u: dg, du
+    "pair": ("rwrw", 1),     # dg @ w_gate^T + du @ w_up^T
+}
+
+
+def _gmm_kernel(offsets, group_ids, tile_ids, num_visits, *refs, tile, form,
+                transpose_rhs, n_out):
+    ins, outs = refs[:-n_out], refs[-n_out:]
     _, lo, hi = _visit(offsets, group_ids, tile_ids, pl.program_id(1), tile)
-    live = hi > lo  # an empty group's visit writes nothing
-    interior = (lo <= 0) & (hi >= tile)
+    f32 = jnp.float32
 
-    def product():
+    def dot(lhs_ref, rhs_ref):
         dims = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
-        return jax.lax.dot_general(
-            lhs_ref[...], rhs_ref[...], dims,
-            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+        return jax.lax.dot_general(lhs_ref[...], rhs_ref[...], dims,
+                                   preferred_element_type=f32)
 
-    @pl.when(live & interior)
-    def _whole():
-        out_ref[...] = product()
+    def values():
+        if form == "plain":
+            return (dot(ins[0], ins[1]),)
+        if form == "swiglu":
+            g, u = dot(ins[0], ins[1]), dot(ins[0], ins[2])
+            return (jax.nn.silu(g) * u, g, u)[:n_out]
+        if form == "dswiglu":
+            dh = dot(ins[0], ins[1])
+            g, u = ins[2][...].astype(f32), ins[3][...].astype(f32)
+            s = jax.nn.sigmoid(g)
+            # d silu(g) = s (1 + g (1 - s))
+            return dh * u * (s * (1.0 + g * (1.0 - s))), dh * (g * s)
+        return (dot(ins[0], ins[1]) + dot(ins[2], ins[3]),)
 
-    # Another group's rows keep what its visit wrote; the rows past the
-    # last group keep what the block held (nothing: the module docstring).
-    @pl.when(live & jnp.logical_not(interior))
-    def _masked():
-        out_ref[...] = jnp.where(_row_mask(lo, hi, tile), product(),
-                                 out_ref[...])
+    # ONE copy of the products, whatever the visit: a select against the
+    # block costs nothing beside them (PERF.md §6, PR 47), and a second
+    # copy for the tiles one group fills is code.  Another group's rows
+    # keep what its visit wrote; the rows past the last group keep what the
+    # block held (nothing: the module docstring), and what an operand tile
+    # holds there (``g``, ``u``: anything) reaches no row that is read.
+    @pl.when(hi > lo)  # an empty group's visit writes nothing
+    def _visit_rows():
+        mask = _row_mask(lo, hi, tile)
+        for out_ref, new in zip(outs, values()):
+            out_ref[...] = jnp.where(mask, new.astype(out_ref.dtype),
+                                     out_ref[...])
 
 
 def _tgmm_kernel(offsets, group_ids, tile_ids, num_visits, lhs_ref, rhs_ref,
@@ -271,34 +307,55 @@ def _compiler_params(interpret):
         vmem_limit_bytes=100 * 1024 * 1024)
 
 
-def _gmm(lhs, rhs, sched: Schedule, tile, transpose_rhs, interpret):
-    """``out[r] = lhs[r] @ rhs[group of r]`` (``rhs[g].T`` if
-    ``transpose_rhs``); the rows past the groups are not written."""
-    rows, k = lhs.shape
-    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tn = _fit_columns(n, k, rhs.dtype.itemsize, _WEIGHT_BLOCK_BYTES)
-    if transpose_rhs:
-        rhs_spec = pl.BlockSpec(
-            (None, tn, k), lambda j, i, o, g, t, v: (g[i], j, 0))
-    else:
-        rhs_spec = pl.BlockSpec(
-            (None, k, tn), lambda j, i, o, g, t, v: (g[i], 0, j))
+def _gmm_call(form, operands, sched: Schedule, tile, interpret, *,
+              transpose_rhs=False, n_out=1):
+    """One ``moe_gmm`` kernel in ``form`` (``_GMM_FORMS``) over the
+    schedule's visits: ``n_out`` arrays of ``(rows, n)``, whose rows past
+    the groups are not written.  A call holds up to two weight blocks,
+    each under ``_WEIGHT_BLOCK_BYTES`` and double-buffered: 16 MB of the
+    100 the compiler is given, whatever the form."""
+    kinds, most = _GMM_FORMS[form]
+    assert len(operands) == len(kinds) and 1 <= n_out <= most
+    rows, k = operands[0].shape
+    weights = operands[1]
+    n = weights.shape[1] if transpose_rhs else weights.shape[2]
+    tn = _fit_columns(n, k, weights.dtype.itemsize, _WEIGHT_BLOCK_BYTES)
+    tiles = pl.BlockSpec((tile, tn), lambda j, i, o, g, t, v: (t[i], j))
+    specs = {
+        "r": pl.BlockSpec((tile, k), lambda j, i, o, g, t, v: (t[i], 0)),
+        "w": (pl.BlockSpec((None, tn, k),
+                           lambda j, i, o, g, t, v: (g[i], j, 0))
+              if transpose_rhs else
+              pl.BlockSpec((None, k, tn),
+                           lambda j, i, o, g, t, v: (g[i], 0, j))),
+        "t": tiles}
+    out = jax.ShapeDtypeStruct((rows, n), operands[0].dtype)
+    # The ``t`` operands give their buffers to the outputs, in order: a
+    # visit has read its tile of them before it writes one (a tile two
+    # groups share stays in VMEM between its visits), and XLA could write
+    # SwiGLU's gradient over its inputs too.
+    given = [4 + i for i, kind in enumerate(kinds) if kind == "t"]
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, tile=tile,
-                          transpose_rhs=transpose_rhs),
+        functools.partial(_gmm_kernel, tile=tile, form=form,
+                          transpose_rhs=transpose_rhs, n_out=n_out),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n // tn, sched.num_visits[0]),
-            in_specs=[
-                pl.BlockSpec((tile, k), lambda j, i, o, g, t, v: (t[i], 0)),
-                rhs_spec],
-            out_specs=pl.BlockSpec(
-                (tile, tn), lambda j, i, o, g, t, v: (t[i], j))),
-        out_shape=jax.ShapeDtypeStruct((rows, n), lhs.dtype),
+            in_specs=[specs[kind] for kind in kinds],
+            out_specs=[tiles] * n_out),
+        out_shape=[out] * n_out,
+        input_output_aliases=dict(zip(given, range(n_out))),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-        name="moe_gmm",
-    )(*sched, lhs, rhs)
+        name="moe_gmm" if form == "plain" else f"moe_gmm_{form}",
+    )(*sched, *operands)
+
+
+def _gmm(lhs, rhs, sched: Schedule, tile, transpose_rhs, interpret):
+    """``out[r] = lhs[r] @ rhs[group of r]`` (``rhs[g].T`` if
+    ``transpose_rhs``); the rows past the groups are not written."""
+    return _gmm_call("plain", (lhs, rhs), sched, tile, interpret,
+                     transpose_rhs=transpose_rhs)[0]
 
 
 def _tgmm(lhs, rhs, sched: Schedule, groups, tile, interpret):
@@ -332,7 +389,10 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array, sched: Schedule,
     meets ``weights[g]`` for the group ``g`` that ``sched`` puts it in
     (``make_schedule(group_sizes, M, tile)``; ``M`` a multiple of
     ``tile``).  Rows past the groups' sum are neither read nor written:
-    what comes back there, and as their gradient, is unspecified."""
+    what comes back there, and as their gradient, is unspecified.  ONE
+    grouped product with its gradients; the expert layer's three are
+    ``expert_ffn``, which shares these kernels and puts what lies between
+    the products into them (this is what it is tested against)."""
     return _gmm(rows, weights.astype(rows.dtype), sched, tile, False,
                 interpret)
 
@@ -351,6 +411,65 @@ def _grouped_matmul_bwd(tile, interpret, res, d_out):
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def expert_ffn(rows: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+               w_down: jax.Array, sched: Schedule, tile: int,
+               interpret: bool) -> jax.Array:
+    """The routed experts' FFN on their rows: ``(silu(rows @ w_gate[g]) *
+    (rows @ w_up[g])) @ w_down[g]`` for the group ``g`` that ``sched`` puts
+    a row in; ``rows (M, d)``, ``w_gate``, ``w_up (G, d, m)``, ``w_down (G,
+    m, d)``.  ONE rule, no op of which reads or writes a row the schedule
+    does not visit (what comes back past the groups' sum, and as its
+    gradient, is unspecified):
+
+    - forward ``moe_gmm_swiglu`` reads a row tile once for both products
+      and writes ``h = silu(g) * u`` from the float32 accumulators; then
+      ``moe_gmm`` with ``w_down``.  Differentiated, it writes ``g`` and
+      ``u`` beside ``h``, rounded to the rows' precision, as residuals: a
+      forward pass that keeps none (the first under a layer checkpoint)
+      is spared them;
+    - backward ``moe_gmm_dswiglu`` is ``d_y @ w_down^T`` with SwiGLU's
+      derivative at ``g``, ``u`` in its epilogue (``dg``, ``du``; no
+      ``dh``), ``moe_gmm_pair`` adds ``dg @ w_gate^T + du @ w_up^T`` in
+      float32 before the one cast (no two cotangents of the rows to sum),
+      and three ``moe_tgmm`` make the weights' gradients."""
+    return _ffn_forward(rows, w_gate, w_up, w_down, sched, tile, interpret,
+                        n_out=1)[0]
+
+
+def _ffn_forward(rows, w_gate, w_up, w_down, sched, tile, interpret, n_out):
+    """``(y, h, ...)``: the FFN's output, then ``moe_gmm_swiglu``'s
+    ``n_out`` outputs (``h``; ``g`` and ``u`` behind it if asked)."""
+    cast = lambda w: w.astype(rows.dtype)
+    hidden = _gmm_call("swiglu", (rows, cast(w_gate), cast(w_up)), sched,
+                       tile, interpret, n_out=n_out)
+    return (_gmm(hidden[0], cast(w_down), sched, tile, False, interpret),
+            *hidden)
+
+
+def _expert_ffn_fwd(rows, w_gate, w_up, w_down, sched, tile, interpret):
+    y, h, g, u = _ffn_forward(rows, w_gate, w_up, w_down, sched, tile,
+                              interpret, n_out=3)
+    return y, (rows, g, u, h, w_gate, w_up, w_down, sched)
+
+
+def _expert_ffn_bwd(tile, interpret, res, d_y):
+    rows, g, u, h, w_gate, w_up, w_down, sched = res
+    cast = lambda w: w.astype(d_y.dtype)
+    dg, du = _gmm_call("dswiglu", (d_y, cast(w_down), g, u), sched, tile,
+                       interpret, transpose_rhs=True, n_out=2)
+    d_rows, = _gmm_call("pair", (dg, cast(w_gate), du, cast(w_up)), sched,
+                        tile, interpret, transpose_rhs=True)
+    d_weights = (
+        _tgmm(lhs, rhs, sched, w.shape[0], tile, interpret).astype(w.dtype)
+        for lhs, rhs, w in ((rows, dg, w_gate), (rows, du, w_up),
+                            (h, d_y, w_down)))
+    return (d_rows, *d_weights, None)
+
+
+expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
 
 
 # Dispatch and combine are gathers both ways: the transpose of "row r
@@ -763,11 +882,8 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
                   / jnp.maximum(reached, 1.0)) if ranks else jnp.float32(1.0)
 
     with jax.named_scope("moe_experts"):
-        product = functools.partial(
-            grouped_matmul, sched=sched, tile=tile,
-            interpret=attention._interpret_default())  # one rule for both
-        y_rows = product(swiglu(product(x_rows, w_gate),
-                                product(x_rows, w_up)), w_down)
+        y_rows = expert_ffn(x_rows, w_gate, w_up, w_down, sched, tile,
+                            attention._interpret_default())
 
     with jax.named_scope("moe_combine"):
         y = _combine(y_rows, gates, row_token, row_slot, slot_row, row_gate,

@@ -173,22 +173,22 @@ def test_schedule_ends_at_the_held_groups_sum(sizes):
 def _poisoned(monkeypatch):
     """Every buffer the share's path allocates or a kernel leaves
     unvisited holds NaN past the live rows BEFORE anyone reads it: the
-    row buffers under the two loops, and the grouped products' outputs
-    (interpret mode hands out NaN there already; said again, so the test
-    does not rest on it).  ``_live_token_sum`` keeps its traces: none
+    row buffers under the two loops, and every output of the grouped
+    kernels, the FFN's residuals among them (interpret mode hands out NaN
+    there already; said again, so the test does not rest on it).  ``_live_token_sum`` keeps its traces: none
     from before the poison may serve."""
     jax.clear_caches()
     monkeypatch.setattr(
         moe, "_row_buffer", lambda shape, dtype: jnp.full(shape, jnp.nan,
                                                           dtype))
-    gmm = moe._gmm
+    call = moe._gmm_call
 
-    def gmm_with_a_poisoned_tail(lhs, rhs, sched, *rest):
-        out = gmm(lhs, rhs, sched, *rest)
-        row = jnp.arange(out.shape[0])[:, None]
-        return jnp.where(row < sched.offsets[-1], out, jnp.nan)
+    def call_with_poisoned_tails(form, operands, sched, *rest, **kw):
+        row = jnp.arange(operands[0].shape[0])[:, None]
+        return [jnp.where(row < sched.offsets[-1], out, jnp.nan)
+                for out in call(form, operands, sched, *rest, **kw)]
 
-    monkeypatch.setattr(moe, "_gmm", gmm_with_a_poisoned_tail)
+    monkeypatch.setattr(moe, "_gmm_call", call_with_poisoned_tails)
 
 
 @pytest.mark.parametrize("first,held,tile", [
@@ -242,6 +242,111 @@ def test_share_that_every_token_chooses_is_exact_at_full_buffer(
     assert float(stats["dropped"]) == 0
     assert float(jnp.abs(out - loop(*args)).max()) < 5e-6
     _assert_gradients_equal(lambda *a: layer(*a)[0], loop, args)
+
+
+FFN_ROWS = 256
+FFN_CASES = {  # group sizes over FFN_ROWS rows
+    "every_group_held": (128, 128),
+    "share_poisoned_past_live": (40, 30, 50, 20),
+    "empty_group": (90, 0, 100, 66),
+    "tile_two_groups_share": (100, 60, 50, 46),
+    "every_row_in_one_group": (0, 256, 0),
+}
+
+
+def _composition(x, w_gate, w_up, w_down, sched, tile):
+    """What ``expert_ffn`` replaces: three grouped products, SwiGLU
+    between them in plain XLA over the whole buffer."""
+    from ray_tpu.ops.layers import swiglu
+
+    product = functools.partial(moe.grouped_matmul, sched=sched, tile=tile,
+                                interpret=True)
+    return product(swiglu(product(x, w_gate), product(x, w_up)), w_down)
+
+
+@pytest.mark.parametrize("tile", [16, 128], ids=["tile16", "tile128"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(FFN_CASES))
+def test_expert_ffn_equals_the_composition_it_replaces(case, dtype, tile):
+    """Value and all four gradients of the one rule against three
+    ``grouped_matmul`` and ``swiglu``, on the live rows (past them both
+    are unspecified; of the share, rows and cotangent hold NaN there, so
+    a kernel that read one unmasked would spread it into a weight's
+    gradient).  In bfloat16 the rule rounds SwiGLU once, from float32:
+    it lies no further from the float32 composition than today's does."""
+    sizes = FFN_CASES[case]
+    live, groups, d, m = sum(sizes), len(sizes), 32, 48
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    f32 = jnp.float32
+    past = (jnp.arange(FFN_ROWS) >= live)[:, None]
+    x, d_y = (jnp.where(past, jnp.nan, jax.random.normal(k, (FFN_ROWS, d)))
+              for k in ks[:2])
+    weights = tuple(jax.random.normal(k, shape) * 0.3 for k, shape in zip(
+        ks[2:], [(groups, d, m), (groups, d, m), (groups, m, d)]))
+    sched = moe.make_schedule(jnp.asarray(sizes), FFN_ROWS, tile)
+
+    def value_and_grads(fn, dtype):
+        args = tuple(a.astype(dtype) for a in (x,) + weights)
+        y, vjp = jax.vjp(lambda *a: fn(*a, sched, tile), *args)
+        d_x, *d_w = vjp(d_y.astype(dtype))
+        return [a.astype(f32) for a in (y[:live], d_x[:live], *d_w)]
+
+    fused = functools.partial(moe.expert_ffn, interpret=True)
+    got = value_and_grads(fused, dtype)
+    want = value_and_grads(_composition, f32)
+    names = ("y", "d_x", "d_w_gate", "d_w_up", "d_w_down")
+    if dtype == f32:
+        for name, g, w in zip(names, got, want):
+            assert bool(jnp.isfinite(g).all()), name
+            assert float(jnp.abs(g - w).max()) <= 2e-6 * float(
+                jnp.abs(w).max()), name
+        return
+    rms = lambda a: float(jnp.sqrt(jnp.mean(jnp.square(a))))
+    today = value_and_grads(_composition, dtype)
+    for name, g, t, w in zip(names, got, today, want):
+        assert bool(jnp.isfinite(g).all()), name
+        assert float(jnp.abs(g - w).max()) <= 3e-2 * float(
+            jnp.abs(w).max()), name
+        assert rms(g - w) <= 1.02 * rms(t - w), name
+
+
+def test_no_pass_over_the_rows_between_the_expert_kernels():
+    """The lowered layer's gradient: under scope ``moe_experts`` every
+    array of a row a (token, choice) is made and read by a Pallas kernel
+    alone — no ``add_any`` of two cotangents of the rows, no SwiGLU
+    (``logistic``, ``mul``) or its derivative as an XLA pass over the
+    static buffer — and the rule's seven kernels are there by name."""
+    args = _layer_inputs()
+    tile = 16
+    rows = -(-T * K // tile) * tile
+    fn = jax.grad(lambda *a: jnp.sum(moe.moe_block(
+        *a, num_selected=K, tile=tile)[0] ** 2), argnums=range(6))
+
+    def outside_kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from outside_kernels(sub)
+
+    under = [e for e in outside_kernels(jax.make_jaxpr(fn)(*args).jaxpr)
+             if "moe_experts" in str(e.source_info.name_stack)]
+    kernels = sorted(e.params["name"] for e in under
+                     if e.primitive.name == "pallas_call")
+    assert kernels == ["moe_gmm", "moe_gmm_dswiglu", "moe_gmm_pair",
+                       "moe_gmm_swiglu", "moe_tgmm", "moe_tgmm", "moe_tgmm"]
+    for eqn in under:
+        name = eqn.primitive.name
+        assert name not in ("add_any", "logistic"), eqn
+        over_rows = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)
+                     if getattr(v.aval, "shape", ())[:1] == (rows,)]
+        assert name == "pallas_call" or not over_rows, eqn
 
 
 def _token_sum_inputs(live, dtype, tokens=41, k=4, d=24, seed=0):

@@ -161,6 +161,36 @@ def test_grouped_matmul_compiles(one_chip, k, n, grad):
     assert _has_kernel(compiled)
 
 
+@pytest.mark.parametrize("rows,groups,d,m,live", [
+    (131072, 32, 2048, 768, 8), (32768, 8, 3584, 1024, 8),
+    (32768, 16, 2048, 1792, 2), (131072, 64, 2048, 1024, 1)],
+    ids=["joyai", "xing4", "lfm2", "olmoe"])
+def test_expert_ffn_compiles(one_chip, rows, groups, d, m, live):
+    """The routed experts' FFN as one rule, value and the four gradients,
+    at the four expert cells' widths, static rows and tile: two weight
+    blocks beside three output blocks in VMEM, SwiGLU and its derivative
+    in the epilogues, the two-product sum and the masked stores of up to
+    three outputs are what Mosaic could refuse."""
+    from ray_tpu.ops import moe
+
+    tile = moe.choose_tiles(rows // live, groups)
+
+    def f(x, w_gate, w_up, w_down, sizes):
+        sched = moe.make_schedule(sizes, rows, tile)
+        return moe.expert_ffn(x, w_gate, w_up, w_down, sched, tile,
+                              False).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2, 3))).lower(
+        _shape((rows, d), jnp.bfloat16, one_chip),
+        _shape((groups, d, m), jnp.bfloat16, one_chip),
+        _shape((groups, d, m), jnp.bfloat16, one_chip),
+        _shape((groups, m, d), jnp.bfloat16, one_chip),
+        _shape((groups,), jnp.int32, one_chip)).compile().as_text()
+    for kernel in ("moe_gmm_swiglu", "moe_gmm_dswiglu", "moe_gmm_pair",
+                   "moe_tgmm"):
+        assert kernel in text, kernel
+
+
 # -- whole train steps -------------------------------------------------------
 
 @pytest.fixture

@@ -606,6 +606,51 @@ def test_step_breakdown_counts_the_stream_kernels_under_their_scopes(
     assert "hc_write_bwd  x 2 a step" in format_breakdown(b)
 
 
+def test_step_breakdown_names_the_expert_ffn_kernels(tmp_path):
+    """The routed experts' FFN (``ops/moe.py::expert_ffn``): each of its
+    kernels is a row under ITS name — ``moe_gmm_swiglu`` is not filed
+    under ``moe_gmm`` — with its calls a step, the rerun's apart, and
+    all of their time is the scope ``moe_experts``'."""
+    from ray_tpu.util.tracing import format_breakdown, step_breakdown
+
+    call = ', custom_call_target=\\"tpu_custom_call\\"'
+    times = {"moe_gmm_swiglu": 40, "moe_gmm": 30, "moe_gmm_dswiglu": 35,
+             "moe_gmm_pair": 45, "moe_tgmm": 20}
+
+    def kernel(prefix, name, n, at):
+        return (f"%{name}.{n} = bf16[] custom-call()" + call,
+                f"{prefix}moe_experts/{name}/pallas_call", at, times[name])
+
+    ops = [("%lead = f32[] add()", "jit(step)/optimizer/add", 500, 100)]
+    at = 2000
+    for layer in range(2):
+        ops += [kernel(_FWD, "moe_gmm_swiglu", layer, at),
+                kernel(_FWD, "moe_gmm", layer, at + 40),
+                kernel(_BWD + "rematted_computation/", "moe_gmm_swiglu",
+                       layer + 2, at + 70),
+                kernel(_BWD + "rematted_computation/", "moe_gmm", layer + 2,
+                       at + 110),
+                kernel(_BWD, "moe_gmm_dswiglu", layer, at + 140),
+                kernel(_BWD, "moe_gmm_pair", layer, at + 175)]
+        ops += [kernel(_BWD, "moe_tgmm", 3 * layer + n, at + 220 + 20 * n)
+                for n in range(3)]
+        at += 300
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace(ops, modules=[(0, 1000), (2000, 1000)]))
+    b = step_breakdown(str(path), "jit_step")
+    assert b["kernel_calls"] == {
+        "moe_gmm_swiglu": 2, "moe_gmm": 2, "moe_gmm_swiglu.remat": 2,
+        "moe_gmm.remat": 2, "moe_gmm_dswiglu": 2, "moe_gmm_pair": 2,
+        "moe_tgmm": 6}
+    ns = lambda s: round(s * 1e9)  # noqa: E731
+    assert {p: ns(t) for p, t in b["scopes"]["moe_experts"].items()} == {
+        "forward": 140, "remat": 140, "backward": 280}
+    assert ns(sum(b["kernels"].values())) == 560
+    text = format_breakdown(b)
+    assert "moe_gmm_pair  x 2 a step" in text
+    assert "moe_tgmm  x 6 a step" in text
+
+
 # op_name prefixes of the layer scan's two loops, and the text of a flash
 # kernel's instruction at the s4096 cell's shapes.
 _FWD = "jit(step)/jvp()/while/body/closed_call/"
