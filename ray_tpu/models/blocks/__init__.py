@@ -1,6 +1,8 @@
 """ray_tpu.models.blocks — what a decoder layer is composed of: a MIXER
 (``MIXERS``) followed by an FFN (``FFNS``), each on a RESIDUAL
-(``residual.py``).  A mixer or FFN is ONE module that ends in ONE
+(``residual.py``); either may be ``none``, the empty block
+(``base.EMPTY_MIXER``, ``base.EMPTY_FFN``), in a model whose layers are one
+sub-block each.  A mixer or FFN is ONE module that ends in ONE
 ``base.Block``.  To add one: write the module, register its ``Block`` below
 under the name ``layer_types`` gives it, and list its scopes in the
 ``"scopes"`` of the benchmark configuration that uses it; its
@@ -10,7 +12,7 @@ tree names a mixer.
 """
 
 from ray_tpu.models.blocks import (
-    attention, conv, delta, ffn, mamba, residual)
+    attention, base, conv, delta, ffn, mamba, residual)
 
 MIXERS = {
     "attention": attention.SOFTMAX,
@@ -19,8 +21,9 @@ MIXERS = {
     "mamba": mamba.BLOCK,
     "linear_attention": delta.BLOCK,
     "conv": conv.BLOCK,
+    "none": base.EMPTY_MIXER,
 }
-FFNS = {"dense": ffn.DENSE, "moe": ffn.MOE}
+FFNS = {"dense": ffn.DENSE, "moe": ffn.MOE, "none": base.EMPTY_FFN}
 
 
 def layer_scopes():

@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models.blocks.base import Block, Ctx, Param, ones
+from ray_tpu.models.blocks.base import (
+    Block, Ctx, Param, ones, residual_out)
 from ray_tpu.models.blocks.residual import add, block_in
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import flash_attention, mha_reference
@@ -38,7 +39,8 @@ def _attention_shapes(cfg):
         "wq": Param((d, h), ("layer", "kernel_in", "heads")),
         "wk": Param((d, kvd), ("layer", "kernel_in", "kv_heads")),
         "wv": Param((d, kvd), ("layer", "kernel_in", "kv_heads")),
-        "wo": Param((h, d), ("layer", "heads", "kernel_in")),
+        "wo": Param((h, d), ("layer", "heads", "kernel_in"),
+                    residual_out(cfg)),
     }
     if cfg.qk_norm:  # over the whole projection, before heads and RoPE
         shapes.update({"q_norm": Param((h,), ("layer", "heads"), ones),
@@ -69,7 +71,8 @@ def _latent_shapes(cfg):
                         heads * (cfg.qk_nope_dim + cfg.v_head_dim)),
                        ("layer", None, "heads")),
         "wo": Param((heads * cfg.v_head_dim, d),
-                    ("layer", "heads", "kernel_in")),
+                    ("layer", "heads", "kernel_in"),
+                    residual_out(cfg)),
     }
 
 

@@ -22,6 +22,17 @@ def normal(key, shape, fan_in=None):
     return jax.random.normal(key, shape, jnp.float32) * (fan_in ** -0.5)
 
 
+def residual_out(cfg):
+    """``normal``, of a projection whose product a block adds to the
+    residual: at ``cfg.residual_init_scale`` of it where the model's public
+    file asks for that (``rescale_prenorm_residual``), else ``normal``
+    itself."""
+    scale = cfg.residual_init_scale
+    if scale == 1.0:
+        return normal
+    return lambda key, shape: normal(key, shape) * scale
+
+
 def ones(key, shape):
     return jnp.ones(shape, jnp.float32)
 
@@ -95,6 +106,18 @@ class Block:
     saved: Tuple[str, ...] = ()
     scopes: Tuple[str, ...] = ()
     stats: Callable[[Any], Dict[str, str]] = lambda cfg: {}
+
+
+def _hand_on(ctx, x, aux, lp, residual: bool = True):
+    """The stream as it came (nothing to add, without ``residual``)."""
+    return (x if residual else jnp.zeros_like(x)), aux
+
+
+# The empty block, ``none`` in both registries: no tensor, no scope, no
+# saved residual, no statistic, and not one operation — the absent half of
+# a layer that is a mixer OR an FFN alone.
+EMPTY_MIXER = Block(lambda cfg: {}, _hand_on)
+EMPTY_FFN = Block(lambda cfg: {}, lambda *a, **kw: (*_hand_on(*a, **kw), None))
 
 
 def fold(aux, seen, how):

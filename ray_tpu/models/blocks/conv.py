@@ -14,7 +14,8 @@ checkpoint keeps nothing of it.
 
 import jax
 
-from ray_tpu.models.blocks.base import Block, Ctx, Param, conv, ones
+from ray_tpu.models.blocks.base import (
+    Block, Ctx, Param, conv, ones, residual_out)
 from ray_tpu.models.blocks.residual import add
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.ssm import gated_short_conv
@@ -30,7 +31,8 @@ def _shapes(cfg):
         "sconv_in": Param((d, 3 * d), ("layer", "kernel_in", "sconv_inner")),
         "sconv_w": Param((cfg.sconv_width, d), ("layer", None, "sconv_inner"),
                          conv(cfg.sconv_width)),
-        "sconv_out": Param((d, d), ("layer", "sconv_inner", "kernel_in")),
+        "sconv_out": Param((d, d), ("layer", "sconv_inner", "kernel_in"),
+                           residual_out(cfg)),
     }
 
 

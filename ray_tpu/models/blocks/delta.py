@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.blocks.base import (
-    Block, Ctx, Param, a_log, conv, dt_bias, fold, ones)
+    Block, Ctx, Param, a_log, conv, dt_bias, fold, ones, residual_out)
 from ray_tpu.models.blocks.residual import add, block_in
 from ray_tpu.ops.delta import delta_chunked
 from ray_tpu.ops.layers import rms_norm
@@ -50,7 +50,8 @@ def _shapes(cfg):
         "gdn_dt_bias": Param((cfg.gdn_heads,), ("layer", None), dt_bias),
         "gdn_A_log": Param((cfg.gdn_heads,), ("layer", None), a_log),
         "gdn_gate_norm": Param((cfg.gdn_value_dim,), ("layer", None), ones),
-        "gdn_out": Param((values, d), ("layer", "gdn_inner", "kernel_in")),
+        "gdn_out": Param((values, d), ("layer", "gdn_inner", "kernel_in"),
+                         residual_out(cfg)),
     }
 
 

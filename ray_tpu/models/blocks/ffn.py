@@ -1,8 +1,11 @@
-"""The FFNs: a dense SwiGLU (scope ``ffn``), and the dropless expert layer
+"""The FFNs: a dense one (scope ``ffn``), and the dropless expert layer
 (``ops/moe.py``, under its scopes ``moe_route`` / ``moe_dispatch`` /
 ``moe_experts`` / ``moe_combine`` in place of ``ffn``, and ``moe_exchange``
 where its experts are spread over an ``ep`` axis) with or without a
-shared expert, which every token meets (scope ``ffn``).  Expert tensors are
+shared expert, which every token meets (scope ``ffn``).  Every FFN of a
+model — dense, routed, shared — has the model's one activation
+(``cfg.ffn_act``): SwiGLU over a gate and an up matrix, or the ungated
+``relu(h W_up) ** 2 W_down`` of two matrices.  Expert tensors are
 sharded over 'ep' and so are the tokens: inside a shard_map the layer's
 exchange (scope ``moe_exchange``) brings each rank the tokens of its group,
 the rank computes its own experts' rows, and each token's parts come back
@@ -20,33 +23,41 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models.blocks.base import Block, Ctx, Param, fold, ones
+from ray_tpu.models.blocks.base import (
+    Block, Ctx, Param, fold, ones, residual_out)
 from ray_tpu.models.blocks.residual import add, block_in
 from ray_tpu.ops import moe
 from ray_tpu.ops.layers import rms_norm, swiglu
-from ray_tpu.ops.moe import moe_block
 from ray_tpu.parallel.mesh import (
     AXIS_DP, AXIS_EP, AXIS_FSDP, AXIS_SP, AXIS_TP)
 from ray_tpu.parallel.sharding import BATCH_AXES, manual_shard_map
 
 
-def _swiglu_shapes(d: int, m: int, prefix: str = "w_"):
-    return {
-        prefix + "gate": Param((d, m), ("layer", "kernel_in", "mlp")),
-        prefix + "up": Param((d, m), ("layer", "kernel_in", "mlp")),
-        prefix + "down": Param((m, d), ("layer", "mlp", "kernel_in")),
-    }
+def _gated(cfg) -> bool:
+    return cfg.ffn_act == "swiglu"
+
+
+def _ffn_shapes(cfg, m: int, prefix: str = "w_", *held):
+    """An FFN of width ``m`` (``held`` experts of it): gate, up and down,
+    or up and down alone where the activation has no gate."""
+    d = cfg.embed_dim
+    expert = ("expert",) * len(held)
+    up = Param((*held, d, m), ("layer", *expert, "kernel_in", "mlp"))
+    down = Param((*held, m, d), ("layer", *expert, "mlp", "kernel_in"),
+                 residual_out(cfg))
+    gate = {prefix + "gate": up} if _gated(cfg) else {}
+    return {**gate, prefix + "up": up, prefix + "down": down}
 
 
 def _dense_shapes(cfg):
     return {"mlp_norm": Param((cfg.embed_dim,), ("layer", "embed"), ones),
-            **_swiglu_shapes(cfg.embed_dim, cfg.dense_width)}
+            **_ffn_shapes(cfg, cfg.dense_width)}
 
 
-def _select_bias(key, shape):
-    """Drawn at 0.02 so that a comparison with a reference can see it (a
-    trained one starts at 0)."""
-    return 0.02 * jax.random.normal(key, shape, jnp.float32)
+def _select_bias(std: float):
+    """Drawn off zero (``cfg.select_bias_init``) so that a comparison with
+    a reference can see it (a trained one starts at 0)."""
+    return lambda key, shape: std * jax.random.normal(key, shape, jnp.float32)
 
 
 def _moe_shapes(cfg):
@@ -54,22 +65,18 @@ def _moe_shapes(cfg):
     (``mlp_dim`` is an expert's width), the selection bias (float32
     whatever the parameters': it moves by thousandths) and the shared
     expert where the model has them."""
-    d, m = cfg.embed_dim, cfg.mlp_dim
-    e, held = cfg.num_experts, cfg.local_experts
+    d, e = cfg.embed_dim, cfg.num_experts
     shapes = {
         "mlp_norm": Param((d,), ("layer", "embed"), ones),
         "router": Param((d, e), ("layer", "kernel_in", None)),
-        "w_gate": Param((held, d, m),
-                        ("layer", "expert", "kernel_in", "mlp")),
-        "w_up": Param((held, d, m), ("layer", "expert", "kernel_in", "mlp")),
-        "w_down": Param((held, m, d),
-                        ("layer", "expert", "mlp", "kernel_in")),
+        **_ffn_shapes(cfg, cfg.mlp_dim, "w_", cfg.local_experts),
     }
     if cfg.select_bias:
-        shapes["router_bias"] = Param((e,), ("layer", None), _select_bias,
-                                      jnp.float32)
+        shapes["router_bias"] = Param(
+            (e,), ("layer", None), _select_bias(cfg.select_bias_init),
+            jnp.float32)
     if cfg.shared_experts:
-        shapes.update(_swiglu_shapes(d, cfg.shared_experts * m, "shared_"))
+        shapes.update(_ffn_shapes(cfg, cfg.shared_width, "shared_"))
     return shapes
 
 
@@ -85,10 +92,11 @@ def _moe_stats(cfg):
             "moe_rank_rows_max_over_mean": "max", **held}
 
 
-def _swiglu_ffn(h, lp, cfg, prefix: str = "w_"):
-    return swiglu(h @ lp[prefix + "gate"].astype(cfg.dtype),
-                  h @ lp[prefix + "up"].astype(cfg.dtype)
-                  ) @ lp[prefix + "down"].astype(cfg.dtype)
+def _ffn(h, lp, cfg, prefix: str = "w_"):
+    w = lambda name: lp[prefix + name].astype(cfg.dtype)  # noqa: E731
+    hidden = (swiglu(h @ w("gate"), h @ w("up")) if _gated(cfg)
+              else jnp.square(jax.nn.relu(h @ w("up"))))
+    return hidden @ w("down")
 
 
 def _dense_ffn(ctx: Ctx, x, aux, lp, residual: bool = True):
@@ -96,7 +104,7 @@ def _dense_ffn(ctx: Ctx, x, aux, lp, residual: bool = True):
     cfg = ctx.cfg
     with jax.named_scope("ffn"):
         h = block_in(x, lp["mlp_norm"], cfg)
-        return add(ctx, x, _swiglu_ffn(h, lp, cfg), residual,
+        return add(ctx, x, _ffn(h, lp, cfg), residual,
                    lp["mlp_norm"]), aux, None
 
 
@@ -110,17 +118,25 @@ def _moe(ctx: Ctx, x, lp, residual: bool = True):
     already manual (the pipeline) it is called as it is and the partitioner
     splits it, which the TPU lowering refuses for a Mosaic kernel."""
     cfg, mesh, cst = ctx.cfg, ctx.mesh, ctx.cst
-    block = functools.partial(
-        moe_block, num_selected=cfg.num_selected, norm_eps=cfg.norm_eps,
-        norm_topk_prob=cfg.norm_topk_prob,
-        topk_norm_eps=cfg.topk_norm_eps, scoring=cfg.router_scoring,
-        gate_scale=cfg.routed_scaling_factor,
-        first_expert=cfg.first_expert, residual=residual)
+    # the experts' matrices, in ``moe_block``'s order; no gate: None there
+    names = ("w_gate", "w_up", "w_down")[not _gated(cfg):]
+
+    no_gate = (None,) * (not _gated(cfg))
+
+    def moe_block(x, norm_w, router_w, *rest, **axes):  # the region's name
+        return moe.moe_block(
+            x, norm_w, router_w, *no_gate, *rest,
+            num_selected=cfg.num_selected, norm_eps=cfg.norm_eps,
+            norm_topk_prob=cfg.norm_topk_prob,
+            topk_norm_eps=cfg.topk_norm_eps, scoring=cfg.router_scoring,
+            gate_scale=cfg.routed_scaling_factor,
+            first_expert=cfg.first_expert, residual=residual, **axes)
+
     bias = (lp["router_bias"],) if cfg.select_bias else ()
-    args = (x, lp["mlp_norm"], lp["router"], lp["w_gate"], lp["w_up"],
-            lp["w_down"]) + bias
+    args = (x, lp["mlp_norm"], lp["router"],
+            *(lp[name] for name in names)) + bias
     if mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
-        return block(*args)
+        return moe_block(*args)
     # The parameters as the region takes them, laid out under the scope
     # that uses them (pinned first as they are stored, or the partitioner
     # moves the change of layout up to the scan's slice): the fsdp gathers
@@ -133,19 +149,19 @@ def _moe(ctx: Ctx, x, lp, residual: bool = True):
 
     with jax.named_scope("moe_route"):
         small = (laid_out("mlp_norm", None), laid_out("router", None, None))
+    up_axes, down_axes = ("expert", None, "mlp"), ("expert", "mlp", None)
     with jax.named_scope("moe_experts"):
-        args = (x,) + small + (
-            laid_out("w_gate", "expert", None, "mlp"),
-            laid_out("w_up", "expert", None, "mlp"),
-            laid_out("w_down", "expert", "mlp", None)) + bias
+        args = (x,) + small + tuple(
+            laid_out(name, *(down_axes if name == "w_down" else up_axes))
+            for name in names) + bias
     x_spec = P(BATCH_AXES, AXIS_SP, None)
     up_spec = P(AXIS_EP, None, AXIS_TP)
     fn = manual_shard_map(
-        functools.partial(block, token_axes=(AXIS_DP, AXIS_FSDP, AXIS_SP),
+        functools.partial(moe_block, token_axes=(AXIS_DP, AXIS_FSDP, AXIS_SP),
                           expert_axis=AXIS_EP, sum_axes=(AXIS_TP,)),
         set(mesh.axis_names),
-        in_specs=(x_spec, P(), P(), up_spec, up_spec,
-                  P(AXIS_EP, AXIS_TP, None)) + (P(),) * len(bias),
+        in_specs=(x_spec, P(), P()) + (up_spec,) * (len(names) - 1)
+        + (P(AXIS_EP, AXIS_TP, None),) + (P(),) * len(bias),
         out_specs=(x_spec, P()), mesh=mesh)
     return fn(*args)
 
@@ -160,7 +176,7 @@ def _moe_ffn(ctx: Ctx, x, aux, lp, residual: bool = True):
     if cfg.shared_experts:
         with jax.named_scope("ffn"):
             h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            out = out + cst(_swiglu_ffn(h, lp, cfg, "shared_"),
+            out = out + cst(_ffn(h, lp, cfg, "shared_"),
                             ("batch", "seq", "embed"))
     how = _moe_stats(cfg)
     aux = fold(aux, {k: seen[k.removeprefix("moe_")] for k in how}, how)

@@ -4,8 +4,9 @@ every tenth.  Scopes: ``ssm_in`` (norm, the one input projection, its
 split), ``ssm_conv`` (the convolution over x, B, C with its SiLU; dt's
 softplus), ``ssm_scan`` (the chunked scan, ``D x`` included: Pallas kernels
 where ``ssm.kernels_fit``, per shard of the batch under a mesh),
-``ssm_out`` (the norm of the GATED output — gate first, then one norm over
-the whole inner width —, the output projection, the residual add).
+``ssm_out`` (the norm of the GATED output — gate first, then a norm over
+each group's own channels, ``ssm_groups`` equal parts of the inner width:
+the whole of it for one group —, the output projection, the residual add).
 
 The layer checkpoint keeps the input projection's output [z | xBC | dt]
 (``ssm_proj``: bf16, 139 MB a layer at 8192 tokens).  With it the backward
@@ -20,7 +21,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.blocks.base import (
-    Block, Ctx, Param, a_log, conv, dt_bias, keyed_ones, ones)
+    Block, Ctx, Param, a_log, conv, dt_bias, keyed_ones, ones, residual_out)
 from ray_tpu.models.blocks.residual import add
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.ssm import causal_conv1d, gated_rms_norm, ssd_chunked
@@ -48,7 +49,8 @@ def _shapes(cfg):
         "A_log": Param((cfg.ssm_heads,), ("layer", None), a_log),
         "D": Param((cfg.ssm_heads,), ("layer", None), keyed_ones),
         "gate_norm": Param((inner,), ("layer", "ssm_inner"), ones),
-        "ssm_out": Param((inner, d), ("layer", "ssm_inner", "kernel_in")),
+        "ssm_out": Param((inner, d), ("layer", "ssm_inner", "kernel_in"),
+                         residual_out(cfg)),
     }
 
 
@@ -77,7 +79,7 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
             lp["D"])
     with jax.named_scope("ssm_out"):
         y = gated_rms_norm(y.reshape(b, s, inner), z, lp["gate_norm"],
-                           cfg.norm_eps)
+                           cfg.norm_eps, cfg.ssm_groups)
         return add(ctx, x, y @ lp["ssm_out"].astype(cfg.dtype),
                    residual), aux
 

@@ -4,14 +4,17 @@ under the layer checkpoint, head, predicted-ahead module, loss, pipeline
 entry points.  What a layer is made of lives in ``models/blocks/``: a MIXER
 (``blocks.MIXERS``: softmax attention | latent attention | a Mamba-2
 state-space mixer | a gated delta-rule linear-attention mixer | a gated
-short convolution) followed by an FFN (``blocks.FFNS``: dense SwiGLU |
-dropless experts with or without a shared expert), each on a RESIDUAL
+short convolution) followed by an FFN (``blocks.FFNS``: dense, SwiGLU or
+ungated relu^2 | dropless experts with or without a shared expert) —
+either of the two may be the empty block (``none``), so a layer can be a
+mixer OR an FFN alone —, each on a RESIDUAL
 (``blocks/residual.py``: one stream | ``hc_mult`` streams mixed round every
 block by learned doubly stochastic maps).  A model is a pattern of such
-layers (``layer_types``, ``leading_dense``), with or without a
-predicted-ahead module behind them.  Each block declares its own tensors,
-initialisers, saved residuals, scopes and step statistics
-(``blocks.base.Block``); the decoder reads those and names no mixer.
+layers (``layer_types``, ``leading_dense``; or ``layer_pattern``, a
+character a layer), with or without a predicted-ahead module behind them.
+Each block declares its own tensors, initialisers, saved residuals, scopes
+and step statistics (``blocks.base.Block``); the decoder reads those and
+names no mixer.
 
 Pure-functional design: params are a pytree of arrays, every tensor
 dimension has a *logical axis name*, and one rules table
@@ -60,6 +63,13 @@ from ray_tpu.parallel.sharding import (
     DEFAULT_RULES, LogicalAxisRules, logical_to_mesh_axes, manual_shard_map,
     with_logical_constraint,
 )
+
+
+# A layer of ONE sub-block on the residual, by the character the public
+# files of such models give it (``hybrid_override_pattern``): the (mixer,
+# FFN) pair it is, the absent half the empty block.
+LAYER_PATTERN = {"M": ("mamba", "none"), "E": ("none", "moe"),
+                 "*": ("attention", "none"), "-": ("none", "dense")}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,6 +151,21 @@ class LlamaConfig:
     # Where a block's RMSNorm sits: "input", x + f(norm(x)), or "output",
     # x + norm(f(x)) with the same weight on what the block adds.
     block_norm: str = "input"
+    # A model whose layers are ONE sub-block each, as its public file
+    # spells them (``LAYER_PATTERN``: a character a layer; only the first
+    # ``num_layers`` are the model).  Empty: ``layer_types`` says the mixers.
+    layer_pattern: str = ""
+    ffn_act: str = "swiglu"           # swiglu | relu2 (ungated: two matrices)
+    shared_mlp_dim: int = 0           # the shared expert's width (0:
+    #                                   shared_experts x mlp_dim)
+    # Initialisation.  The public files' ``rescale_prenorm_residual``, as
+    # Megatron-LM's ``scaled_init_method_normal`` draws a model's output
+    # layers: every projection that writes to the residual at
+    # 1/sqrt(2 x depth) of the rest, the depth the PUBLISHED model's
+    # whatever part of it is run (``published_layers``; 0: ``num_layers``).
+    rescale_prenorm_residual: bool = False
+    published_layers: int = 0
+    select_bias_init: float = 0.02    # std the selection bias is drawn at
 
     def __post_init__(self):
         # a configuration file hands a list: keep the config hashable
@@ -164,6 +189,21 @@ class LlamaConfig:
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm is over the whole projection, "
                              "qk_head_norm over each head: one of the two")
+        if self.ffn_act not in ("swiglu", "relu2"):
+            raise ValueError(f"ffn_act {self.ffn_act!r}")
+        bad = sorted(set(self.layer_pattern) - set(LAYER_PATTERN))
+        if bad:
+            raise ValueError(
+                f"layer_pattern holds {bad}: a layer is one of "
+                f"{sorted(LAYER_PATTERN)}")
+        if self.layer_pattern and (
+                len(self.layer_pattern) < self.num_layers or self.layer_types
+                or self.hc_mult > 1 or self.num_nextn or self.leading_dense):
+            raise ValueError(
+                "layer_pattern names every layer's one sub-block, "
+                f"num_layers ({self.num_layers}) of them or more, in place "
+                "of layer_types and leading_dense, on the plain residual "
+                "without a predicted-ahead module")
         unknown = set(self.layer_types) - set(MIXERS)
         if unknown:
             raise ValueError(
@@ -216,6 +256,16 @@ class LlamaConfig:
         return self.experts_held or self.num_experts
 
     @property
+    def shared_width(self) -> int:
+        return self.shared_mlp_dim or self.shared_experts * self.mlp_dim
+
+    @property
+    def residual_init_scale(self) -> float:
+        if not self.rescale_prenorm_residual:
+            return 1.0
+        return (2 * (self.published_layers or self.num_layers)) ** -0.5
+
+    @property
     def select_bias(self) -> bool:
         return self.topk_method == "noaux_tc"
 
@@ -224,7 +274,12 @@ class LlamaConfig:
         """(mixer, FFN) of every layer: the mixer ``layer_types`` names
         (latent attention for a model with a ``kv_lora_rank``, else
         attention), a dense FFN in the ``leading_dense`` first layers and
-        in a model without experts, the expert layer elsewhere."""
+        in a model without experts, the expert layer elsewhere; or, of a
+        model with a ``layer_pattern``, the pair each character stands
+        for (``LAYER_PATTERN``)."""
+        if self.layer_pattern:
+            return tuple(LAYER_PATTERN[c]
+                         for c in self.layer_pattern[:self.num_layers])
         mixers = self.layer_types[:self.num_layers] or (
             ("latent" if self.kv_lora_rank else "attention",)
             * self.num_layers)

@@ -19,8 +19,11 @@ imbalance: nothing has a capacity and nothing is dropped.  The layer
   the gate and up products with SwiGLU in their kernel's epilogue, the
   down product; backward, SwiGLU's derivative in the epilogue of the
   product with the down weights transposed, the rows' gradient as one
-  kernel that adds its two products, three weight gradients.  Between the
-  kernels XLA does nothing: no pass over the row buffer;
+  kernel that adds its two products, three weight gradients.  An expert
+  without a gate (``relu(x W_up) ** 2 W_down``, two matrices) is the same
+  rule with the square and its derivative in those epilogues, one product
+  for the rows' gradient and two weight gradients.  Between the kernels
+  XLA does nothing: no pass over the row buffer;
 - ``moe_combine``: each token's rows gathered back, weighted by its
   gates, summed (``_token_sum``), and added to the residual.
 
@@ -37,14 +40,16 @@ may be empty or hold every row.  The grouped product is two Pallas
 kernels, chosen over ``jax.lax.ragged_dot`` by measurement on a v5e
 (PERF.md §6, PR 25): ``moe_gmm`` (rows x their group's weights, also with
 the weights transposed for the rows' gradient) and ``moe_tgmm`` (the
-weights' gradient, per group).  ``moe_gmm`` is one body in four forms
+weights' gradient, per group).  ``moe_gmm`` is one body in six forms
 (``_GMM_FORMS``): the plain product, ``moe_gmm_swiglu`` (two products of
 one row tile and SwiGLU of their float32 accumulators), ``moe_gmm_dswiglu``
-(a product and SwiGLU's derivative) and ``moe_gmm_pair`` (the float32 sum
-of two products).  All walk one schedule of (group, row tile) visits
-handed over as scalar prefetch: a tile that two groups share is visited
-once for each, and the rows that are not the visit's are masked, in every
-output.
+(a product and SwiGLU's derivative), ``moe_gmm_pair`` (the float32 sum
+of two products), and for an expert without a gate ``moe_gmm_relu2`` (a
+product and the square of its positive part) and ``moe_gmm_drelu2`` (a
+product and that square's derivative).  All walk one schedule of (group,
+row tile) visits handed over as scalar prefetch: a tile that two groups
+share is visited once for each, and the rows that are not the visit's are
+masked, in every output.
 
 The row buffer is static, ``T * k`` rows however few are held, and the
 work on it ends at the LIVE rows, the groups' sum (a value the device
@@ -217,6 +222,8 @@ _GMM_FORMS = {
     "swiglu": ("rww", 3),    # h = silu(x @ w_gate) * (x @ w_up); then g, u
     "dswiglu": ("rwtt", 2),  # dh = d_y @ w_down^T against g, u: dg, du
     "pair": ("rwrw", 1),     # dg @ w_gate^T + du @ w_up^T
+    "relu2": ("rw", 2),      # no gate: h = relu(x @ w_up) ** 2; then u
+    "drelu2": ("rwt", 1),    # dh = d_y @ w_down^T against u: du
 }
 
 
@@ -243,6 +250,12 @@ def _gmm_kernel(offsets, group_ids, tile_ids, num_visits, *refs, tile, form,
             s = jax.nn.sigmoid(g)
             # d silu(g) = s (1 + g (1 - s))
             return dh * u * (s * (1.0 + g * (1.0 - s))), dh * (g * s)
+        if form == "relu2":
+            u = dot(ins[0], ins[1])
+            return (jnp.square(jnp.maximum(u, 0.0)), u)[:n_out]
+        if form == "drelu2":
+            u = ins[2][...].astype(f32)
+            return (dot(ins[0], ins[1]) * (2.0 * jnp.maximum(u, 0.0)),)
         return (dot(ins[0], ins[1]) + dot(ins[2], ins[3]),)
 
     # ONE copy of the products, whatever the visit: a select against the
@@ -414,15 +427,16 @@ grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def expert_ffn(rows: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+def expert_ffn(rows: jax.Array, w_gate: Optional[jax.Array], w_up: jax.Array,
                w_down: jax.Array, sched: Schedule, tile: int,
                interpret: bool) -> jax.Array:
     """The routed experts' FFN on their rows: ``(silu(rows @ w_gate[g]) *
     (rows @ w_up[g])) @ w_down[g]`` for the group ``g`` that ``sched`` puts
-    a row in; ``rows (M, d)``, ``w_gate``, ``w_up (G, d, m)``, ``w_down (G,
-    m, d)``.  ONE rule, no op of which reads or writes a row the schedule
-    does not visit (what comes back past the groups' sum, and as its
-    gradient, is unspecified):
+    a row in — or, ``w_gate`` None, the ungated ``relu(rows @ w_up[g]) ** 2
+    @ w_down[g]``; ``rows (M, d)``, ``w_gate``, ``w_up (G, d, m)``,
+    ``w_down (G, m, d)``.  ONE rule, no op of which reads or writes a row
+    the schedule does not visit (what comes back past the groups' sum, and
+    as its gradient, is unspecified):
 
     - forward ``moe_gmm_swiglu`` reads a row tile once for both products
       and writes ``h = silu(g) * u`` from the float32 accumulators; then
@@ -434,39 +448,58 @@ def expert_ffn(rows: jax.Array, w_gate: jax.Array, w_up: jax.Array,
       derivative at ``g``, ``u`` in its epilogue (``dg``, ``du``; no
       ``dh``), ``moe_gmm_pair`` adds ``dg @ w_gate^T + du @ w_up^T`` in
       float32 before the one cast (no two cotangents of the rows to sum),
-      and three ``moe_tgmm`` make the weights' gradients."""
+      and three ``moe_tgmm`` make the weights' gradients;
+    - without a gate: forward ``moe_gmm_relu2`` writes ``h = relu(u) ** 2``
+      (and ``u`` where differentiated), then ``moe_gmm``; backward
+      ``moe_gmm_drelu2`` writes ``du = (d_y @ w_down^T) * 2 relu(u)`` over
+      ``u``, the plain ``moe_gmm`` with ``w_up`` transposed is the rows'
+      gradient, and two ``moe_tgmm`` the weights'."""
     return _ffn_forward(rows, w_gate, w_up, w_down, sched, tile, interpret,
-                        n_out=1)[0]
+                        differentiated=False)[0]
 
 
-def _ffn_forward(rows, w_gate, w_up, w_down, sched, tile, interpret, n_out):
-    """``(y, h, ...)``: the FFN's output, then ``moe_gmm_swiglu``'s
-    ``n_out`` outputs (``h``; ``g`` and ``u`` behind it if asked)."""
+def _ffn_forward(rows, w_gate, w_up, w_down, sched, tile, interpret,
+                 differentiated):
+    """``(y, h, ...)``: the FFN's output, then the first kernel's outputs
+    (``h``; ``differentiated``, behind it what its derivative is taken at:
+    ``g`` and ``u``, or without a gate ``u``)."""
     cast = lambda w: w.astype(rows.dtype)
-    hidden = _gmm_call("swiglu", (rows, cast(w_gate), cast(w_up)), sched,
-                       tile, interpret, n_out=n_out)
+    if w_gate is None:
+        form, weights = "relu2", (cast(w_up),)
+    else:
+        form, weights = "swiglu", (cast(w_gate), cast(w_up))
+    hidden = _gmm_call(form, (rows, *weights), sched, tile, interpret,
+                       n_out=_GMM_FORMS[form][1] if differentiated else 1)
     return (_gmm(hidden[0], cast(w_down), sched, tile, False, interpret),
             *hidden)
 
 
 def _expert_ffn_fwd(rows, w_gate, w_up, w_down, sched, tile, interpret):
-    y, h, g, u = _ffn_forward(rows, w_gate, w_up, w_down, sched, tile,
-                              interpret, n_out=3)
-    return y, (rows, g, u, h, w_gate, w_up, w_down, sched)
+    y, h, *at = _ffn_forward(rows, w_gate, w_up, w_down, sched, tile,
+                             interpret, differentiated=True)
+    return y, (rows, at, h, w_gate, w_up, w_down, sched)
 
 
 def _expert_ffn_bwd(tile, interpret, res, d_y):
-    rows, g, u, h, w_gate, w_up, w_down, sched = res
+    rows, at, h, w_gate, w_up, w_down, sched = res
     cast = lambda w: w.astype(d_y.dtype)
-    dg, du = _gmm_call("dswiglu", (d_y, cast(w_down), g, u), sched, tile,
+
+    def d_weights(*products):
+        return (_tgmm(lhs, rhs, sched, w.shape[0], tile, interpret
+                      ).astype(w.dtype) for lhs, rhs, w in products)
+
+    if w_gate is None:
+        du, = _gmm_call("drelu2", (d_y, cast(w_down), *at), sched, tile,
+                        interpret, transpose_rhs=True)
+        d_rows = _gmm(du, cast(w_up), sched, tile, True, interpret)
+        return (d_rows, None,
+                *d_weights((rows, du, w_up), (h, d_y, w_down)), None)
+    dg, du = _gmm_call("dswiglu", (d_y, cast(w_down), *at), sched, tile,
                        interpret, transpose_rhs=True, n_out=2)
     d_rows, = _gmm_call("pair", (dg, cast(w_gate), du, cast(w_up)), sched,
                         tile, interpret, transpose_rhs=True)
-    d_weights = (
-        _tgmm(lhs, rhs, sched, w.shape[0], tile, interpret).astype(w.dtype)
-        for lhs, rhs, w in ((rows, dg, w_gate), (rows, du, w_up),
-                            (h, d_y, w_down)))
-    return (d_rows, *d_weights, None)
+    return (d_rows, *d_weights((rows, dg, w_gate), (rows, du, w_up),
+                               (h, d_y, w_down)), None)
 
 
 expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
@@ -748,7 +781,7 @@ def update_selection_bias(bias: jax.Array, counts: jax.Array,
 
 
 def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
-              w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+              w_gate: Optional[jax.Array], w_up: jax.Array, w_down: jax.Array,
               select_bias: Optional[jax.Array] = None, *,
               num_selected: int, norm_eps: float = 1e-6,
               norm_topk_prob: bool = False, topk_norm_eps: float = 0.0,
@@ -774,7 +807,8 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
     for in the exchange; 1 without ranks), beside ``counts (E,)``, every
     expert's assignments.
     ``router_w (d, E)``; ``w_gate``/``w_up (E', d, m')``, ``w_down (E',
-    m', d)`` — all the experts, or the ``E'`` of them from ``first_expert``
+    m', d)`` (``w_gate`` None: experts without a gate, ``expert_ffn``) —
+    all the experts, or the ``E'`` of them from ``first_expert``
     on that THIS chip holds of a layer divided over several (the router
     keeps its ``E`` outputs and ``num_selected`` a token; a row routed to
     an absent expert is computed nowhere and added nowhere), or inside a
@@ -792,7 +826,7 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
     shape, d = x.shape, x.shape[-1]
     x = x.reshape(-1, d)
     t, e, k = x.shape[0], router_w.shape[1], num_selected
-    local = w_gate.shape[0]
+    local = w_up.shape[0]
     ranks = (expert_axis,) if expert_axis else ()
     shards = tuple(token_axes) + ranks  # the axes the tokens are split over
 

@@ -17,8 +17,10 @@ time in float32: what the tests hold the chunked form to, as
 Two forms of ONE algorithm, chosen by what a call's shapes show
 (``kernels_fit``): where heads, state and chunk tile the chip — the head
 size divides the 128 lanes and is at least 16, the heads fill whole lane
-blocks, the state and the chunk are multiples of 128, one group (the
-published 64 heads x 64, state 128, chunks of 256) — ``ssd_kernels``, two
+blocks, the state and the chunk are multiples of 128, one group (granite's
+published 64 heads x 64, state 128, chunks of 256; a model of several
+groups, whose heads read their own group's B and C, runs ``ssd_xla``) —
+``ssd_kernels``, two
 Pallas kernels under a ``custom_vjp`` (interpreted off the chip, so the
 tests run the same code); elsewhere ``ssd_xla``, the same sums as plain
 XLA differentiated by autodiff, which writes the intra-chunk matrices
@@ -181,13 +183,19 @@ gated_short_conv.defvjp(_gated_fwd, _gated_bwd)
 
 
 def gated_rms_norm(y: jax.Array, z: jax.Array, weight: jax.Array,
-                   eps: float) -> jax.Array:
-    """Mamba-2's output norm: the GATE FIRST (``y * silu(z)``), then one
-    RMSNorm over the whole last dimension; float32 inside, ``y.dtype``
-    out.  (The other order, norm then gate, is Mamba-2's
-    ``norm_before_gate``, which the published models do not use.)"""
+                   eps: float, groups: int = 1) -> jax.Array:
+    """Mamba-2's output norm: the GATE FIRST (``y * silu(z)``), then an
+    RMSNorm over each of the ``groups`` equal parts of the last dimension
+    on its own (one group: over the whole of it), under one weight of the
+    whole width; float32 inside, ``y.dtype`` out.  (The other order, norm
+    then gate, is Mamba-2's ``norm_before_gate``, which the published
+    models do not use.)"""
     gated = y.astype(_F32) * jax.nn.silu(z.astype(_F32))
-    return rms_norm(gated, weight, eps).astype(y.dtype)
+    if groups == 1:  # no reshape: the one-group program is what it was
+        return rms_norm(gated, weight, eps).astype(y.dtype)
+    split = (*gated.shape[:-1], groups, gated.shape[-1] // groups)
+    return rms_norm(gated.reshape(split), weight.reshape(split[-2:]),
+                    eps).reshape(gated.shape).astype(y.dtype)
 
 
 def exp_where(mask, x):

@@ -34,6 +34,7 @@ FIELDS = {
                   ssm_chunk=8),
     "linear_attention": dict(gdn_heads=4, gdn_key_dim=8, gdn_value_dim=16),
     "conv": {},
+    "none": {},   # the empty block, a mixer and an FFN
     "dense": {},
     "moe": dict(num_experts=4, num_selected=2, shared_experts=1,
                 experts_held=2, first_expert=2, z_loss_coef=0.001),
@@ -45,11 +46,14 @@ ENTRIES = [("mixer", name) for name in MIXERS] + [
 def _model(role, name):
     """A two-layer model whose layers hold the block ``name``, beside the
     plain partner (a dense FFN for a mixer, softmax attention for an
-    FFN): ``(cfg, kind, the two blocks)``."""
+    FFN): ``(cfg, kind, the two blocks)``.  A layer without an FFN is
+    spelled by a pattern string, as the public files of such models do."""
     mixer, ffn = (name, "dense") if role == "mixer" else ("attention", name)
+    layers = (dict(layer_pattern="**") if ffn == "none" else dict(
+        layer_types=() if mixer == "latent" else (mixer,) * 2))
     cfg = LlamaConfig.tiny(
-        attn_impl="flash", remat=True, max_seq_len=SEQ,
-        layer_types=() if mixer == "latent" else (mixer,) * 2, **FIELDS[name])
+        attn_impl="flash", remat=True, max_seq_len=SEQ, **layers,
+        **FIELDS[name])
     assert cfg.kind_runs == (((mixer, ffn), 2),)
     return cfg, (mixer, ffn), (MIXERS[mixer], FFNS[ffn])
 

@@ -256,11 +256,14 @@ FFN_CASES = {  # group sizes over FFN_ROWS rows
 
 def _composition(x, w_gate, w_up, w_down, sched, tile):
     """What ``expert_ffn`` replaces: three grouped products, SwiGLU
-    between them in plain XLA over the whole buffer."""
+    between them in plain XLA over the whole buffer — or, without a gate
+    (``w_gate`` None), two and the square of the positive part."""
     from ray_tpu.ops.layers import swiglu
 
     product = functools.partial(moe.grouped_matmul, sched=sched, tile=tile,
                                 interpret=True)
+    if w_gate is None:
+        return product(jnp.square(jax.nn.relu(product(x, w_up))), w_down)
     return product(swiglu(product(x, w_gate), product(x, w_up)), w_down)
 
 
@@ -268,13 +271,16 @@ def _composition(x, w_gate, w_up, w_down, sched, tile):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("case", list(FFN_CASES))
-def test_expert_ffn_equals_the_composition_it_replaces(case, dtype, tile):
-    """Value and all four gradients of the one rule against three
-    ``grouped_matmul`` and ``swiglu``, on the live rows (past them both
-    are unspecified; of the share, rows and cotangent hold NaN there, so
-    a kernel that read one unmasked would spread it into a weight's
-    gradient).  In bfloat16 the rule rounds SwiGLU once, from float32:
-    it lies no further from the float32 composition than today's does."""
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+def test_expert_ffn_equals_the_composition_it_replaces(gated, case, dtype,
+                                                       tile):
+    """Value and all gradients (four; three of the expert without a gate)
+    of the one rule against ``grouped_matmul``s with the activation
+    between them, on the live rows (past them both are unspecified; of the
+    share, rows and cotangent hold NaN there, so a kernel that read one
+    unmasked would spread it into a weight's gradient).  In bfloat16 the
+    rule rounds the activation once, from float32: it lies no further from
+    the float32 composition than today's does."""
     sizes = FFN_CASES[case]
     live, groups, d, m = sum(sizes), len(sizes), 32, 48
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
@@ -287,8 +293,9 @@ def test_expert_ffn_equals_the_composition_it_replaces(case, dtype, tile):
     sched = moe.make_schedule(jnp.asarray(sizes), FFN_ROWS, tile)
 
     def value_and_grads(fn, dtype):
-        args = tuple(a.astype(dtype) for a in (x,) + weights)
-        y, vjp = jax.vjp(lambda *a: fn(*a, sched, tile), *args)
+        args = tuple(a.astype(dtype) for a in (x,) + weights[not gated:])
+        gate = () if gated else (None,)
+        y, vjp = jax.vjp(lambda x, *w: fn(x, *gate, *w, sched, tile), *args)
         d_x, *d_w = vjp(d_y.astype(dtype))
         return [a.astype(f32) for a in (y[:live], d_x[:live], *d_w)]
 
@@ -296,6 +303,8 @@ def test_expert_ffn_equals_the_composition_it_replaces(case, dtype, tile):
     got = value_and_grads(fused, dtype)
     want = value_and_grads(_composition, f32)
     names = ("y", "d_x", "d_w_gate", "d_w_up", "d_w_down")
+    names = names if gated else names[:2] + names[3:]
+    assert len(got) == len(want) == len(names)
     if dtype == f32:
         for name, g, w in zip(names, got, want):
             assert bool(jnp.isfinite(g).all()), name
@@ -311,17 +320,26 @@ def test_expert_ffn_equals_the_composition_it_replaces(case, dtype, tile):
         assert rms(g - w) <= 1.02 * rms(t - w), name
 
 
-def test_no_pass_over_the_rows_between_the_expert_kernels():
+@pytest.mark.parametrize("gated,kernels", [
+    (True, ["moe_gmm", "moe_gmm_dswiglu", "moe_gmm_pair", "moe_gmm_swiglu",
+            "moe_tgmm", "moe_tgmm", "moe_tgmm"]),
+    (False, ["moe_gmm", "moe_gmm", "moe_gmm_drelu2", "moe_gmm_relu2",
+             "moe_tgmm", "moe_tgmm"])], ids=["swiglu", "relu2"])
+def test_no_pass_over_the_rows_between_the_expert_kernels(gated, kernels):
     """The lowered layer's gradient: under scope ``moe_experts`` every
     array of a row a (token, choice) is made and read by a Pallas kernel
-    alone — no ``add_any`` of two cotangents of the rows, no SwiGLU
-    (``logistic``, ``mul``) or its derivative as an XLA pass over the
-    static buffer — and the rule's seven kernels are there by name."""
+    alone — no ``add_any`` of two cotangents of the rows, no activation
+    (SwiGLU's ``logistic`` and ``mul``, the ungated expert's ``max`` and
+    square) or its derivative as an XLA pass over the static buffer — and
+    the rule's kernels are there by name: seven, or six without a gate."""
     args = _layer_inputs()
+    if not gated:
+        args = args[:3] + (None,) + args[4:]
     tile = 16
     rows = -(-T * K // tile) * tile
     fn = jax.grad(lambda *a: jnp.sum(moe.moe_block(
-        *a, num_selected=K, tile=tile)[0] ** 2), argnums=range(6))
+        *a, num_selected=K, tile=tile)[0] ** 2),
+        argnums=[i for i in range(6) if args[i] is not None])
 
     def outside_kernels(jaxpr):
         for eqn in jaxpr.eqns:
@@ -337,13 +355,11 @@ def test_no_pass_over_the_rows_between_the_expert_kernels():
 
     under = [e for e in outside_kernels(jax.make_jaxpr(fn)(*args).jaxpr)
              if "moe_experts" in str(e.source_info.name_stack)]
-    kernels = sorted(e.params["name"] for e in under
-                     if e.primitive.name == "pallas_call")
-    assert kernels == ["moe_gmm", "moe_gmm_dswiglu", "moe_gmm_pair",
-                       "moe_gmm_swiglu", "moe_tgmm", "moe_tgmm", "moe_tgmm"]
+    assert sorted(e.params["name"] for e in under
+                  if e.primitive.name == "pallas_call") == kernels
     for eqn in under:
         name = eqn.primitive.name
-        assert name not in ("add_any", "logistic"), eqn
+        assert name not in ("add_any", "logistic", "max"), eqn
         over_rows = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)
                      if getattr(v.aval, "shape", ())[:1] == (rows,)]
         assert name == "pallas_call" or not over_rows, eqn

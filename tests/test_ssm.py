@@ -19,8 +19,8 @@ from ray_tpu.models.llama import (
     make_pipeline_stage_fn, param_logical_axes, pipeline_stage_params)
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.ssm import (
-    causal_conv1d, kernels_fit, ssd_chunked, ssd_kernels, ssd_reference,
-    ssd_xla)
+    causal_conv1d, gated_rms_norm, kernels_fit, ssd_chunked, ssd_kernels,
+    ssd_reference, ssd_xla)
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.train.core import init_train_state, train_state_shardings
 
@@ -93,6 +93,74 @@ def _rms_apart(got, want):
     got, want = (np.asarray(t, np.float32) for t in (got, want))
     return float(np.sqrt(np.mean((got - want) ** 2))
                  / np.sqrt(np.mean(want ** 2)))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
+                                       (jnp.bfloat16, 1e-2)],
+                         ids=["float32", "bfloat16"])
+def test_several_groups_at_chunks_of_128_equal_the_recurrence(dtype, tol,
+                                                              monkeypatch):
+    """Nemotron-H's mixer in small (heads of 64, state 128, chunks of 128,
+    SEVERAL groups: head ``i`` reads B and C of group ``i // (heads /
+    groups)``) at 8 heads in 4 groups and 300 positions, which the chunk
+    does not divide.  The groups alone keep these shapes from the Pallas
+    kernels — ``ssd_chunked`` takes the XLA form —, and values and the six
+    gradients are the recurrence's, which repeats each group's B and C to
+    its heads; one group's B and C for every head is another function."""
+    from ray_tpu.ops import ssm
+
+    assert kernels_fit(8, 64, 1, 128, 128)
+    assert not kernels_fit(8, 64, 4, 128, 128)
+    monkeypatch.setattr(ssm, "ssd_kernels", lambda *t, **kw: pytest.fail(
+        "the kernels index B and C by no group"))
+    args = _scan_inputs(300, seed=6, batch=1, heads=8, p=64, groups=4, n=128)
+    cast = tuple(t.astype(dtype) if i in (0, 3, 4) else t
+                 for i, t in enumerate(args))
+    weight = jnp.asarray(np.random.default_rng(3).normal(
+        size=args[0].shape), jnp.float32)
+    chunked = lambda *t: ssd_chunked(*t, chunk=128)  # noqa: E731
+    with HIGHEST:
+        want = ssd_reference(*args)
+        want_grads = _grads(ssd_reference, args, weight)
+        got = jax.jit(chunked)(*cast)
+        grads = _grads(lambda *t: chunked(*t).astype(jnp.float32), cast,
+                       weight)
+        x, dt, a, b, c, d = args
+        first = lambda t: jnp.repeat(t[:, :, :1], 4, 2)  # noqa: E731
+        wrong = ssd_reference(x, dt, a, first(b), first(c), d)
+    assert got.shape == want.shape and got.dtype == dtype
+    assert _rms_apart(got, want) < tol
+    assert _rms_apart(wrong, want) > 0.5
+    for name, g, w in zip("x dt a b c d".split(), grads, want_grads):
+        assert g.shape == w.shape and _rms_apart(g, w) < tol, name
+
+
+@pytest.mark.parametrize("groups", [1, 2, 8])
+def test_the_gated_norm_norms_each_group_on_its_own(groups):
+    """``y * silu(z)`` first, then every group's channels over their own
+    root mean square, under one weight of the whole width — written out
+    here.  One group is the function as it was, to the bit."""
+    rng = np.random.default_rng(groups)
+    y, z = (jnp.asarray(rng.normal(size=(2, 5, 64)), jnp.float32)
+            for _ in range(2))
+    # groups of unlike scale: a norm over the whole width would show
+    y = y * jnp.repeat(jnp.asarray(rng.uniform(0.1, 10.0, 8)), 8)
+    w = jnp.asarray(rng.uniform(0.5, 1.5, 64), jnp.float32)
+    gated = np.asarray(y * jax.nn.silu(z), np.float64).reshape(
+        2, 5, groups, 64 // groups)
+    want = (gated / np.sqrt(np.mean(gated ** 2, -1, keepdims=True) + 1e-5)
+            ).reshape(2, 5, 64) * np.asarray(w)
+    got = gated_rms_norm(y, z, w, 1e-5, groups)
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-6)
+    whole = rms_norm(y * jax.nn.silu(z), w, 1e-5)
+    if groups == 1:
+        np.testing.assert_array_equal(got, whole)
+        np.testing.assert_array_equal(gated_rms_norm(y, z, w, 1e-5), whole)
+    else:
+        assert _rms_apart(got, whole) > 0.1
+    low = gated_rms_norm(y.astype(jnp.bfloat16), z.astype(jnp.bfloat16), w,
+                         1e-5, groups)
+    assert low.dtype == jnp.bfloat16 and _rms_apart(low, want) < 2e-2
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
@@ -188,14 +256,16 @@ def test_causal_conv1d_is_the_direct_sum_and_causal():
 # ------------------------------------------------- the hybrid model ------
 
 # The public key names of a granitemoehybrid config.json, at CPU size:
-# mamba, attention, mamba (of a longer published list); no multiplier 1.
+# mamba, attention, mamba (of a longer published list); no multiplier 1;
+# one group, as published (its reference norms the gated output whole:
+# several groups, each normed apart, are ``tests/test_nemotron.py``'s).
 CONF = {
     "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
     "shared_intermediate_size": 96, "vocab_size": 256, "rms_norm_eps": 1e-5,
     "num_hidden_layers": 3,
     "layer_types": ["mamba", "attention", "mamba", "mamba", "attention"],
     "mamba_n_heads": 8, "mamba_d_head": 16, "mamba_d_state": 8,
-    "mamba_n_groups": 2, "mamba_d_conv": 4, "mamba_chunk_size": 8,
+    "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_chunk_size": 8,
     "position_embedding_type": "nope", "attention_multiplier": 0.1,
     "embedding_multiplier": 3.0, "residual_multiplier": 0.5,
     "logits_scaling": 2.0, "tie_word_embeddings": True,
@@ -207,7 +277,7 @@ def _cfg(**kw):
         vocab_size=256, embed_dim=64, num_layers=3, num_heads=4,
         num_kv_heads=2, head_dim=16, mlp_dim=96, norm_eps=1e-5,
         layer_types=CONF["layer_types"], ssm_heads=8, ssm_head_dim=16,
-        ssm_state=8, ssm_groups=2, ssm_conv=4, ssm_chunk=8,
+        ssm_state=8, ssm_groups=1, ssm_conv=4, ssm_chunk=8,
         position_embedding="nope", attention_multiplier=0.1,
         embedding_multiplier=3.0, residual_multiplier=0.5,
         logits_scaling=2.0, tie_embeddings=True, max_seq_len=64,
@@ -290,7 +360,7 @@ def test_structural_controls_fail_against_the_reference(wrong, monkeypatch):
         program_params = _no_d(params)
     elif wrong == "gate after the norm":
         monkeypatch.setattr(
-            mamba, "gated_rms_norm", lambda y, z, w, eps: rms_norm(
+            mamba, "gated_rms_norm", lambda y, z, w, eps, *_: rms_norm(
                 y, w, eps) * jax.nn.silu(z))
     else:
         cfg = dataclasses.replace(cfg, **wrong)

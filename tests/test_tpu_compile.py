@@ -161,33 +161,37 @@ def test_grouped_matmul_compiles(one_chip, k, n, grad):
     assert _has_kernel(compiled)
 
 
-@pytest.mark.parametrize("rows,groups,d,m,live", [
-    (131072, 32, 2048, 768, 8), (32768, 8, 3584, 1024, 8),
-    (32768, 16, 2048, 1792, 2), (131072, 64, 2048, 1024, 1)],
-    ids=["joyai", "xing4", "lfm2", "olmoe"])
-def test_expert_ffn_compiles(one_chip, rows, groups, d, m, live):
-    """The routed experts' FFN as one rule, value and the four gradients,
-    at the four expert cells' widths, static rows and tile: two weight
-    blocks beside three output blocks in VMEM, SwiGLU and its derivative
-    in the epilogues, the two-product sum and the masked stores of up to
-    three outputs are what Mosaic could refuse."""
+@pytest.mark.parametrize("rows,groups,d,m,live,gated", [
+    (131072, 32, 2048, 768, 8, True), (32768, 8, 3584, 1024, 8, True),
+    (32768, 16, 2048, 1792, 2, True), (131072, 64, 2048, 1024, 1, True),
+    (98304, 16, 2688, 1856, 8, False)],
+    ids=["joyai", "xing4", "lfm2", "olmoe", "nemotronh"])
+def test_expert_ffn_compiles(one_chip, rows, groups, d, m, live, gated):
+    """The routed experts' FFN as one rule, value and all gradients, at the
+    five expert cells' widths, static rows and tile: two weight blocks
+    beside three output blocks in VMEM, SwiGLU and its derivative in the
+    epilogues, the two-product sum and the masked stores of up to three
+    outputs are what Mosaic could refuse; of the expert without a gate, a
+    width (1856) that is no multiple of the lanes and whose weight block
+    cannot be cut, the square and its derivative in the epilogues."""
     from ray_tpu.ops import moe
 
     tile = moe.choose_tiles(rows // live, groups)
+    gate = () if gated else (None,)
 
-    def f(x, w_gate, w_up, w_down, sizes):
+    def f(x, sizes, *weights):
         sched = moe.make_schedule(sizes, rows, tile)
-        return moe.expert_ffn(x, w_gate, w_up, w_down, sched, tile,
+        return moe.expert_ffn(x, *gate, *weights, sched, tile,
                               False).astype(jnp.float32).sum()
 
-    text = jax.jit(jax.grad(f, argnums=(0, 1, 2, 3))).lower(
+    ups = [_shape((groups, d, m), jnp.bfloat16, one_chip)] * (1 + gated)
+    text = jax.jit(jax.grad(f, argnums=(0, *range(2, 3 + len(ups))))).lower(
         _shape((rows, d), jnp.bfloat16, one_chip),
-        _shape((groups, d, m), jnp.bfloat16, one_chip),
-        _shape((groups, d, m), jnp.bfloat16, one_chip),
-        _shape((groups, m, d), jnp.bfloat16, one_chip),
-        _shape((groups,), jnp.int32, one_chip)).compile().as_text()
-    for kernel in ("moe_gmm_swiglu", "moe_gmm_dswiglu", "moe_gmm_pair",
-                   "moe_tgmm"):
+        _shape((groups,), jnp.int32, one_chip), *ups,
+        _shape((groups, m, d), jnp.bfloat16, one_chip)).compile().as_text()
+    for kernel in (("moe_gmm_swiglu", "moe_gmm_dswiglu", "moe_gmm_pair")
+                   if gated else ("moe_gmm_relu2", "moe_gmm_drelu2")) + (
+                       "moe_tgmm",):
         assert kernel in text, kernel
 
 

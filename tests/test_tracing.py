@@ -606,16 +606,23 @@ def test_step_breakdown_counts_the_stream_kernels_under_their_scopes(
     assert "hc_write_bwd  x 2 a step" in format_breakdown(b)
 
 
-def test_step_breakdown_names_the_expert_ffn_kernels(tmp_path):
+@pytest.mark.parametrize("first,backward,weight_grads", [
+    ("moe_gmm_swiglu", ("moe_gmm_dswiglu", "moe_gmm_pair"), 3),
+    ("moe_gmm_relu2", ("moe_gmm_drelu2", "moe_gmm"), 2)],
+    ids=["swiglu", "relu2"])
+def test_step_breakdown_names_the_expert_ffn_kernels(tmp_path, first,
+                                                     backward, weight_grads):
     """The routed experts' FFN (``ops/moe.py::expert_ffn``): each of its
     kernels is a row under ITS name — ``moe_gmm_swiglu`` is not filed
     under ``moe_gmm`` — with its calls a step, the rerun's apart, and
-    all of their time is the scope ``moe_experts``'."""
+    all of their time is the scope ``moe_experts``'.  Of the expert without
+    a gate the rows are ``moe_gmm_relu2`` and ``moe_gmm_drelu2``, the rows'
+    gradient one more plain ``moe_gmm`` and the weights' two ``moe_tgmm``."""
     from ray_tpu.util.tracing import format_breakdown, step_breakdown
 
     call = ', custom_call_target=\\"tpu_custom_call\\"'
-    times = {"moe_gmm_swiglu": 40, "moe_gmm": 30, "moe_gmm_dswiglu": 35,
-             "moe_gmm_pair": 45, "moe_tgmm": 20}
+    times = {first: 40, "moe_gmm": 30, backward[0]: 35, "moe_tgmm": 20}
+    times.setdefault(backward[1], 45)
 
     def kernel(prefix, name, n, at):
         return (f"%{name}.{n} = bf16[] custom-call()" + call,
@@ -624,31 +631,35 @@ def test_step_breakdown_names_the_expert_ffn_kernels(tmp_path):
     ops = [("%lead = f32[] add()", "jit(step)/optimizer/add", 500, 100)]
     at = 2000
     for layer in range(2):
-        ops += [kernel(_FWD, "moe_gmm_swiglu", layer, at),
+        ops += [kernel(_FWD, first, layer, at),
                 kernel(_FWD, "moe_gmm", layer, at + 40),
-                kernel(_BWD + "rematted_computation/", "moe_gmm_swiglu",
+                kernel(_BWD + "rematted_computation/", first,
                        layer + 2, at + 70),
                 kernel(_BWD + "rematted_computation/", "moe_gmm", layer + 2,
                        at + 110),
-                kernel(_BWD, "moe_gmm_dswiglu", layer, at + 140),
-                kernel(_BWD, "moe_gmm_pair", layer, at + 175)]
+                kernel(_BWD, backward[0], layer, at + 140),
+                kernel(_BWD, backward[1], layer + 4, at + 175)]
         ops += [kernel(_BWD, "moe_tgmm", 3 * layer + n, at + 220 + 20 * n)
-                for n in range(3)]
+                for n in range(weight_grads)]
         at += 300
     path = tmp_path / "t.xplane.pb"
     path.write_bytes(_xspace(ops, modules=[(0, 1000), (2000, 1000)]))
     b = step_breakdown(str(path), "jit_step")
-    assert b["kernel_calls"] == {
-        "moe_gmm_swiglu": 2, "moe_gmm": 2, "moe_gmm_swiglu.remat": 2,
-        "moe_gmm.remat": 2, "moe_gmm_dswiglu": 2, "moe_gmm_pair": 2,
-        "moe_tgmm": 6}
+    calls = {first: 2, "moe_gmm": 2, first + ".remat": 2,
+             "moe_gmm.remat": 2, backward[0]: 2,
+             "moe_tgmm": 2 * weight_grads}
+    calls[backward[1]] = calls.get(backward[1], 0) + 2
+    assert b["kernel_calls"] == calls
     ns = lambda s: round(s * 1e9)  # noqa: E731
+    rows_grad = times[backward[1]]
     assert {p: ns(t) for p, t in b["scopes"]["moe_experts"].items()} == {
-        "forward": 140, "remat": 140, "backward": 280}
-    assert ns(sum(b["kernels"].values())) == 560
+        "forward": 140, "remat": 140,
+        "backward": 2 * (35 + rows_grad + 20 * weight_grads)}
+    assert ns(sum(b["kernels"].values())) == 280 + 2 * (
+        35 + rows_grad + 20 * weight_grads)
     text = format_breakdown(b)
-    assert "moe_gmm_pair  x 2 a step" in text
-    assert "moe_tgmm  x 6 a step" in text
+    assert f"{backward[0]}  x 2 a step" in text
+    assert f"moe_tgmm  x {2 * weight_grads} a step" in text
 
 
 # op_name prefixes of the layer scan's two loops, and the text of a flash
