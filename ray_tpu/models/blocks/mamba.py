@@ -3,7 +3,9 @@
 every tenth.  Scopes: ``ssm_in`` (norm, the one input projection, its
 split), ``ssm_conv`` (the convolution over x, B, C with its SiLU; dt's
 softplus), ``ssm_scan`` (the chunked scan, ``D x`` included: Pallas kernels
-where ``ssm.kernels_fit``, per shard of the batch under a mesh),
+where ``ssm.kernels_fit`` — heads that fill whole lane blocks inside each
+of the ``ssm_groups`` groups, whose B and C go in side by side as the
+convolution's split leaves them —, per shard of the batch under a mesh),
 ``ssm_out`` (the norm of the GATED output — gate first, then a norm over
 each group's own channels, ``ssm_groups`` equal parts of the inner width:
 the whole of it for one group —, the output projection, the residual add).

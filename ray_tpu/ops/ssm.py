@@ -16,24 +16,31 @@ time in float32: what the tests hold the chunked form to, as
 
 Two forms of ONE algorithm, chosen by what a call's shapes show
 (``kernels_fit``): where heads, state and chunk tile the chip — the head
-size divides the 128 lanes and is at least 16, the heads fill whole lane
-blocks, the state and the chunk are multiples of 128, one group (granite's
-published 64 heads x 64, state 128, chunks of 256; a model of several
-groups, whose heads read their own group's B and C, runs ``ssd_xla``) —
-``ssd_kernels``, two
+size divides the 128 lanes and is at least 16, the heads of each GROUP
+(B and C are a group's, shared by its ``heads / groups`` heads) fill whole
+lane blocks, the state and the chunk are multiples of 128: granite's
+published 64 heads x 64 in one group, state 128, chunks of 256;
+Nemotron-H's 64 x 64 in 8 groups, chunks of 128 — ``ssd_kernels``, two
 Pallas kernels under a ``custom_vjp`` (interpreted off the chip, so the
-tests run the same code); elsewhere ``ssd_xla``, the same sums as plain
-XLA differentiated by autodiff, which writes the intra-chunk matrices
-(``(chunks, heads, chunk, chunk)``: 268 MB in bfloat16 at 8192 tokens) to
-memory in every pass.  ``ssd_xla`` is also the tests' second oracle.
+tests run the same code); elsewhere (the tests' tiny models, a head size
+that straddles the lanes, a group of less than a lane block)
+``ssd_xla``, the same sums as plain XLA differentiated by autodiff, which
+writes the intra-chunk matrices (``(chunks, heads, chunk, chunk)``: 268 MB
+in bfloat16 at 8192 tokens) to memory in every pass.  ``ssd_xla`` is also
+the tests' second oracle.
 
 The kernels (``ssd_fwd``, ``ssd_bwd``): grid ``(batch, chunk, head
 block)``, a head block being the heads that fill 128 lanes of the ``(b, s,
 heads * head_dim)`` layout the model's projections read and write, so
-nothing is transposed around a call.  The chunk axis is sequential and the
-state of every head rides in a VMEM scratch from one chunk to the next
-(backward: its gradient, chunks in reverse); the chunk's ``C B^T o L``
-matrix is made, used and dropped in VMEM.  ``ssd_fwd`` also writes the
+nothing is transposed around a call.  A grid step's head blocks lie in ONE
+group: B and C come as ``(b, s, groups * state)``, the groups side by side
+as the convolution's split leaves them, and a step's block of them is cut
+at its group — nothing is repeated to the heads; backward, a group's
+``dB`` and ``dC`` are summed over its steps in VMEM and written at its
+last.  The chunk axis is sequential and the state of every head rides in
+a VMEM scratch from one chunk to the next (backward: its gradient, chunks
+in reverse); the chunk's ``C B^T o L`` matrix is made, used and dropped in
+VMEM.  ``ssd_fwd`` also writes the
 state ENTERING each chunk (float32, 67 MB a layer at the published
 sizes), which is all ``ssd_bwd`` needs beside the inputs: under a layer
 checkpoint the forward kernel runs again in the backward pass and its
@@ -60,7 +67,7 @@ float32 accumulation.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +84,12 @@ _MAX_BLOCK_HEADS = 8  # a block's heads are written out in the kernel body
 # v5e, one granite layer: forward 0.83 ms with 1, 0.78 with 4, 0.74 with 8;
 # PERF.md §6, PR 32), their B, C and C B^T made once.
 _STEP_BLOCKS = 8
+# The ``(q, q)`` elements of a head a trip of a step's loop over its blocks
+# covers, at least: a block of granite's chunks of 256 is a trip, while at
+# chunks of 128 a block is too short to hide its own latencies (TPU v5e, a
+# Nemotron-H layer's forward call: 3.32 ms with 1 block a trip, 2.91 with
+# 2, 2.48 with all 4 of the step; PERF.md §6, PR 49).
+_TRIP_ELEMENTS = 256 * 256
 
 
 def _conv_pre(x, weight, bias):
@@ -227,12 +240,13 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 def kernels_fit(heads: int, head_dim: int, groups: int, state: int,
                 chunk: int) -> bool:
     """Whether a call's shapes tile the chip for ``ssd_kernels``: whole
-    head blocks of 128 lanes, a state and a chunk that are multiples of
-    the lane width (both are a matmul's minor dimension), one group (B
-    and C are then shared by every head block)."""
+    head blocks of 128 lanes, each inside ONE group (a block's heads share
+    the B and C that its grid step is handed), a state and a chunk that
+    are multiples of the lane width (both are a matmul's minor
+    dimension)."""
     block = _LANES // head_dim if head_dim and _LANES % head_dim == 0 else 0
-    return (groups == 1 and 1 <= block <= _MAX_BLOCK_HEADS
-            and heads % block == 0 and state % _LANES == 0
+    return (1 <= block <= _MAX_BLOCK_HEADS and heads % groups == 0
+            and (heads // groups) % block == 0 and state % _LANES == 0
             and chunk % _LANES == 0)
 
 
@@ -301,15 +315,14 @@ def ssd_xla(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 
 # ------------------------------------------------------- the Pallas form
 #
-# A grid step takes one chunk of a few head blocks in turn, a block
-# being ``hb`` heads whose ``hb * p`` values a token fill the lanes of a
-# ``(q, w)`` tile of x.  A
-# head's scalars a token (dt, its cumulative log-decay) come as columns
-# ``(q, 1)`` picked from the ``(q, heads)`` block and as rows ``(1, q)``
-# cut from its transpose; ``_spread`` lays a value a head over that
-# head's lanes, ``_only`` blanks the other heads' lanes so that one
-# 128-wide product serves a head of 64 at the cost the MXU charges for 64
-# anyway.
+# A grid step takes one chunk of a few head blocks of one group in turn, a
+# block being ``hb`` heads whose ``hb * p`` values a token fill the lanes
+# of a ``(q, w)`` tile of x.  A head's scalars a token (dt, its cumulative
+# log-decay) come as columns ``(q, 1)`` picked from the ``(q, heads)``
+# block and as rows ``(1, q)`` cut from its transpose; ``_spread`` lays a
+# value a head over that head's lanes, ``_only`` blanks the other heads'
+# lanes so that one 128-wide product serves a head of 64 at the cost the
+# MXU charges for 64 anyway.
 
 
 def _dot(a, b, contract):
@@ -396,19 +409,21 @@ def _chunk_terms(x, dt_ref, acs_ref, acsr_ref, cb, k, hb, p,
         ends=ends, decay=_spread(ends, (w, 1), 0, p))
 
 
-def _each_block(nb, w, body):
+def _each_block(nb, w, trip, body):
     """``body(g, lanes)`` for each of a grid step's ``nb`` head blocks,
-    ``lanes`` the block's ``w`` of the step's ``nb * w``: a loop, not
-    ``nb`` copies of the body."""
-    def block(g, carry):
-        body(g, pl.ds(pl.multiple_of(g * w, w), w))
+    ``lanes`` the block's ``w`` of the step's ``nb * w``: a loop of
+    ``trip`` blocks a trip, not ``nb`` copies of the body."""
+    def blocks(t, carry):
+        for i in range(trip):
+            g = t * trip + i
+            body(g, pl.ds(pl.multiple_of(g * w, w), w))
         return carry
 
-    jax.lax.fori_loop(0, nb, block, 0)
+    jax.lax.fori_loop(0, nb // trip, blocks, 0)
 
 
 def _fwd_kernel(x_ref, dt_ref, acs_ref, acsr_ref, b_ref, c_ref, d_ref,
-                y_ref, states_ref, h_scr, *, hb, p, nb):
+                y_ref, states_ref, h_scr, *, hb, p, nb, trip):
     j, first_chunk = pl.program_id(2), pl.program_id(1) == 0
     bm, cm = b_ref[0], c_ref[0]
     cb = _causal(_dot(cm, bm, (1, 1)))                    # (q, q)
@@ -426,34 +441,41 @@ def _fwd_kernel(x_ref, dt_ref, acs_ref, acsr_ref, b_ref, c_ref, d_ref,
         states_ref[0, 0, lanes, :] = h
         h_scr[k] = t["decay"] * h + _dot(t["xd_end"], bm, (0, 0))
 
-    _each_block(nb, hb * p, block)
+    _each_block(nb, hb * p, trip, block)
 
 
 def _bwd_kernel(x_ref, dt_ref, acs_ref, acsr_ref, b_ref, c_ref, d_ref,
                 states_ref, dy_ref,
                 dx_ref, ddt_ref, dacs_ref, dacsr_ref, db_ref, dc_ref, dd_ref,
-                dh_scr, db_scr, dc_scr, dcbt_scr, *, hb, p, nb):
+                dh_scr, db_scr, dc_scr, dcbt_scr, *, hb, p, nb, trip,
+                group_steps):
     """Chunks from the last to the first (the index maps turn the chunk
     axis round); ``dh_scr[k]`` is the gradient to the state LEAVING the
-    chunk.  Every ``(q, q)`` matrix here is the TRANSPOSE of the forward
-    kernel's (``[j, i]``: token ``j`` feeds token ``i >= j``).  The
-    gradient to a head's cumulative log-decays comes in two parts that
+    chunk.  ``db_scr`` and ``dc_scr`` collect a GROUP's gradients to B
+    and C over its ``group_steps`` grid steps, the sums over its heads.
+    Every ``(q, q)`` matrix here is the TRANSPOSE of the forward kernel's
+    (``[j, i]``: token ``j`` feeds token ``i >= j``).  The gradient to a
+    head's cumulative log-decays comes in two parts that
     are added outside: down the tokens (``dacs_ref``) what each token's
     own decays collect less the row sums of ``dM^T o M^T``, and along
     them (``dacsr_ref``) its column sums — sums of ONE float32 matrix,
     because their difference is summed again over the chunk and does not
     survive two roundings."""
-    j, nj = pl.program_id(2), pl.num_programs(2)
+    j = pl.program_id(2)
+    group_step = j % group_steps
     last_chunk = pl.program_id(1) == 0
     bm, cm = b_ref[0], c_ref[0]
     q, dtype = bm.shape[0], x_ref.dtype
     cbt = _causal(_dot(bm, cm, (1, 1)), transposed=True)
     dcbt_scr[...] = jnp.zeros_like(dcbt_scr)
 
-    @pl.when(j == 0)
-    def _first_step():
+    @pl.when(group_step == 0)
+    def _group_first_step():
         db_scr[...] = jnp.zeros_like(db_scr)
         dc_scr[...] = jnp.zeros_like(dc_scr)
+
+    @pl.when(j == 0)
+    def _first_step():
         ddt_ref[...] = jnp.zeros_like(ddt_ref)
         dacs_ref[...] = jnp.zeros_like(dacs_ref)
 
@@ -506,14 +528,14 @@ def _bwd_kernel(x_ref, dt_ref, acs_ref, acsr_ref, b_ref, c_ref, d_ref,
         ddt_ref[0] += ddt
         dacs_ref[0] += dacs
 
-    _each_block(nb, hb * p, block)
+    _each_block(nb, hb * p, trip, block)
     # of the step's heads; dcb = dcbt^T
     dcbt = _causal(dcbt_scr[...], transposed=True).astype(dtype)
     dc_scr[...] += _dot(dcbt, bm, (0, 0))
     db_scr[...] += _dot(dcbt, cm, (1, 0))
 
-    @pl.when(j == nj - 1)
-    def _last_step():
+    @pl.when(group_step == group_steps - 1)
+    def _group_last_step():
         db_ref[0] = db_scr[...].astype(db_ref.dtype)
         dc_ref[0] = dc_scr[...].astype(dc_ref.dtype)
 
@@ -521,52 +543,69 @@ def _bwd_kernel(x_ref, dt_ref, acs_ref, acsr_ref, b_ref, c_ref, d_ref,
 def _compiler_params(interpret):
     if interpret:
         return None
-    # Chunks carry the state and head blocks share the chunk's B and C
-    # gradients: both axes run in order.
+    # Chunks carry the state and a group's head blocks share the chunk's B
+    # and C gradients: both axes run in order.
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         vmem_limit_bytes=64 * 1024 * 1024)
 
 
-def _plan(x, dt, bm, q, p, reverse):
+def _plan(x, dt, bm, q, p, groups, reverse):
     """What the two calls share: the grid ``(batch, chunk, step of the
     chunk)``; the kernels' blocking — a block is the heads that fill the
-    lanes, a step as many blocks as ``_STEP_BLOCKS`` allows and the head
-    count divides —; the BlockSpecs, a grid step working on chunk ``c``
-    or, ``reverse``, on the chunk as far from the end; the VMEM scratch
-    that carries one ``(w, n)`` state a head block."""
+    lanes, a step as many blocks as ``_STEP_BLOCKS`` allows and ONE
+    GROUP's blocks divide into, so that a step's heads share one B and C,
+    a group being ``group_steps`` steps in a row, ``trip`` blocks a trip
+    of the step's loop —; the BlockSpecs, a grid
+    step working on chunk ``c`` or, ``reverse``, on the chunk as far from
+    the end, B and C ``(b, s, groups * n)`` cut at the step's group; the
+    VMEM scratch that carries one ``(w, n)`` state a head block."""
     batch, s, _ = x.shape
-    heads, n = dt.shape[2], bm.shape[2]
+    heads, n = dt.shape[2], bm.shape[2] // groups
     hb = min(heads, max(1, _LANES // p), _MAX_BLOCK_HEADS)
+    if heads % groups or (heads // groups) % hb:
+        raise ValueError(f"a block of {hb} heads straddles the {groups} "
+                         f"groups of {heads} heads")
     nb = _STEP_BLOCKS
-    while (heads // hb) % nb:
+    while (heads // groups // hb) % nb:
         nb //= 2
+    trip = min(nb, max(1, _TRIP_ELEMENTS // (q * q)))
     nc, w = s // q, nb * hb * p
+    group_steps = heads // groups // hb // nb
 
     def spec(block, index):
         return pl.BlockSpec(block, lambda b_, c_, j_: index(
             b_, nc - 1 - c_ if reverse else c_, j_))
 
     return dict(
-        grid=(batch, nc, heads // hb // nb), blocking=dict(hb=hb, p=p, nb=nb),
+        grid=(batch, nc, groups * group_steps),
+        blocking=dict(hb=hb, p=p, nb=nb, trip=trip), group_steps=group_steps,
         carry=pltpu.VMEM((heads // hb, hb * p, n), _F32),
         x=spec((1, q, w), lambda b_, c_, j_: (b_, c_, j_)),
         col=spec((1, q, heads), lambda b_, c_, j_: (b_, c_, 0)),
         row=spec((1, heads, q), lambda b_, c_, j_: (b_, 0, c_)),
-        bc=spec((1, q, n), lambda b_, c_, j_: (b_, c_, 0)),
+        bc=spec((1, q, n), lambda b_, c_, j_: (b_, c_, j_ // group_steps)),
         d=spec((1, w), lambda b_, c_, j_: (0, j_)),
         states=spec((1, 1, w, n), lambda b_, c_, j_: (b_, c_, j_, 0)),
         dd=spec((1, 1, 1, w), lambda b_, c_, j_: (b_, c_, 0, j_)))
 
 
-@functools.partial(jax.jit, static_argnames=("q", "p", "interpret"))
-def _fwd_call(x, dt, acs, bm, cm, d_l, *, q, p, interpret):
+class _Static(NamedTuple):
+    """What the two calls are compiled for, beside their shapes."""
+    q: int            # the chunk
+    p: int            # the head size
+    groups: int
+    interpret: bool
+
+
+@functools.partial(jax.jit, static_argnames=_Static._fields)
+def _fwd_call(x, dt, acs, bm, cm, d_l, *, q, p, groups, interpret):
     """``x (b, s, heads * p)``, ``dt`` and ``acs`` (its cumulative
     log-decay inside each chunk of ``q``) ``(b, s, heads)`` float32,
-    ``bm``, ``cm`` ``(b, s, n)``, ``d_l (1, heads * p)`` float32.  Returns
-    ``y`` like ``x`` and the state entering every chunk ``(b, s / q,
-    heads * p, n)`` float32."""
-    sp = _plan(x, dt, bm, q, p, reverse=False)
+    ``bm``, ``cm`` ``(b, s, groups * n)``, ``d_l (1, heads * p)`` float32.
+    Returns ``y`` like ``x`` and the state entering every chunk ``(b, s /
+    q, heads * p, n)`` float32."""
+    sp = _plan(x, dt, bm, q, p, groups, reverse=False)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **sp["blocking"]),
         grid=sp["grid"],
@@ -576,7 +615,7 @@ def _fwd_call(x, dt, acs, bm, cm, d_l, *, q, p, interpret):
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct(
                        (x.shape[0], x.shape[1] // q, x.shape[2],
-                        bm.shape[2]), _F32)],
+                        bm.shape[2] // groups), _F32)],
         scratch_shapes=[sp["carry"]],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
@@ -584,16 +623,19 @@ def _fwd_call(x, dt, acs, bm, cm, d_l, *, q, p, interpret):
     )(x, dt, acs, jnp.swapaxes(acs, 1, 2), bm, cm, d_l)
 
 
-@functools.partial(jax.jit, static_argnames=("q", "p", "interpret"))
-def _bwd_call(x, dt, acs, bm, cm, d_l, states, dy, *, q, p, interpret):
+@functools.partial(jax.jit, static_argnames=_Static._fields)
+def _bwd_call(x, dt, acs, bm, cm, d_l, states, dy, *, q, p, groups,
+              interpret):
     """Gradients to ``x``, ``dt``, ``acs`` (two parts: like ``acs`` and
-    like its transpose), ``bm``, ``cm`` (each like its argument) and to
-    ``d_l`` a chunk ``(b, s / q, 1, heads * p)``."""
-    sp = _plan(x, dt, bm, q, p, reverse=True)
-    n = bm.shape[2]
+    like its transpose), ``bm``, ``cm`` (each like its argument: a group's
+    is the sum over its heads) and to ``d_l`` a chunk ``(b, s / q, 1,
+    heads * p)``."""
+    sp = _plan(x, dt, bm, q, p, groups, reverse=True)
+    n = bm.shape[2] // groups
     like = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, **sp["blocking"]),
+        functools.partial(_bwd_kernel, **sp["blocking"],
+                          group_steps=sp["group_steps"]),
         grid=sp["grid"],
         in_specs=[sp["x"], sp["col"], sp["col"], sp["row"], sp["bc"],
                   sp["bc"], sp["d"], sp["states"], sp["x"]],
@@ -613,21 +655,19 @@ def _bwd_call(x, dt, acs, bm, cm, d_l, states, dy, *, q, p, interpret):
     )(x, dt, acs, jnp.swapaxes(acs, 1, 2), bm, cm, d_l, states, dy)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def _scan(x, dt, acs, bm, cm, d_l, q, p, interpret):
-    return _fwd_call(x, dt, acs, bm, cm, d_l, q=q, p=p,
-                     interpret=interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _scan(x, dt, acs, bm, cm, d_l, static: _Static):
+    return _fwd_call(x, dt, acs, bm, cm, d_l, **static._asdict())[0]
 
 
-def _scan_fwd(x, dt, acs, bm, cm, d_l, q, p, interpret):
-    y, states = _fwd_call(x, dt, acs, bm, cm, d_l, q=q, p=p,
-                          interpret=interpret)
+def _scan_fwd(x, dt, acs, bm, cm, d_l, static):
+    y, states = _fwd_call(x, dt, acs, bm, cm, d_l, **static._asdict())
     return y, (x, dt, acs, bm, cm, d_l, states)
 
 
-def _scan_bwd(q, p, interpret, res, dy):
-    dx, ddt, dacs, dacs_t, db, dc, dd = _bwd_call(*res, dy, q=q, p=p,
-                                                  interpret=interpret)
+def _scan_bwd(static, res, dy):
+    dx, ddt, dacs, dacs_t, db, dc, dd = _bwd_call(*res, dy,
+                                                  **static._asdict())
     return (dx, ddt, dacs + jnp.swapaxes(dacs_t, 1, 2), db, dc,
             jnp.sum(dd, axis=(0, 1)))
 
@@ -637,13 +677,13 @@ _scan.defvjp(_scan_fwd, _scan_bwd)
 
 def ssd_kernels(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                 c: jax.Array, d: jax.Array, *, chunk: int) -> jax.Array:
-    """``ssd_chunked`` through the Pallas kernels (one group; compiled on
-    the TPU, interpreted elsewhere).  XLA pads, makes each chunk's
-    cumulative log-decays and lays ``D`` over its heads' lanes, and
-    differentiates those; the rest is ``ssd_fwd`` and ``ssd_bwd``."""
+    """``ssd_chunked`` through the Pallas kernels (compiled on the TPU,
+    interpreted elsewhere).  XLA pads, makes each chunk's cumulative
+    log-decays and lays ``D`` over its heads' lanes, and differentiates
+    those; the rest is ``ssd_fwd`` and ``ssd_bwd``, which take B and C
+    with their groups side by side, ``(b, s, groups * state)``: the
+    layout the convolution's split has, nothing repeated to the heads."""
     batch, s, heads, p = x.shape
-    if b.shape[2] != 1:
-        raise ValueError(f"ssd_kernels takes one group, got {b.shape[2]}")
     q = min(chunk, s)
     x, dt, b, c = pad_to_multiple(q, x, dt.astype(_F32), b, c)
     padded = x.shape[1]
@@ -652,8 +692,8 @@ def ssd_kernels(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     y = _scan(x.reshape(batch, padded, heads * p), dt,
               acs.reshape(batch, padded, heads),
               b.reshape(batch, padded, -1), c.reshape(batch, padded, -1),
-              jnp.repeat(d.astype(_F32), p)[None], q, p,
-              attention._interpret_default())
+              jnp.repeat(d.astype(_F32), p)[None],
+              _Static(q, p, b.shape[2], attention._interpret_default()))
     return y.reshape(batch, padded, heads, p)[:, :s]
 
 

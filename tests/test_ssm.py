@@ -95,6 +95,17 @@ def _rms_apart(got, want):
                  / np.sqrt(np.mean(want ** 2)))
 
 
+def _records_the_kernels(monkeypatch):
+    """``ssd_chunked``'s kernel form patched to note each call's shape;
+    returns the list."""
+    from ray_tpu.ops import ssm
+
+    calls = []
+    monkeypatch.setattr(ssm, "ssd_kernels", lambda *t, **kw: (
+        calls.append(t[0].shape), ssd_kernels(*t, **kw))[1])
+    return calls
+
+
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
                                        (jnp.bfloat16, 1e-2)],
                          ids=["float32", "bfloat16"])
@@ -103,16 +114,14 @@ def test_several_groups_at_chunks_of_128_equal_the_recurrence(dtype, tol,
     """Nemotron-H's mixer in small (heads of 64, state 128, chunks of 128,
     SEVERAL groups: head ``i`` reads B and C of group ``i // (heads /
     groups)``) at 8 heads in 4 groups and 300 positions, which the chunk
-    does not divide.  The groups alone keep these shapes from the Pallas
-    kernels — ``ssd_chunked`` takes the XLA form —, and values and the six
+    does not divide.  A pair of heads fills a lane block inside one group,
+    so ``ssd_chunked`` takes the Pallas kernels (interpreted here), which
+    cut B and C at the grid step's group, and values and the six
     gradients are the recurrence's, which repeats each group's B and C to
     its heads; one group's B and C for every head is another function."""
-    from ray_tpu.ops import ssm
-
     assert kernels_fit(8, 64, 1, 128, 128)
-    assert not kernels_fit(8, 64, 4, 128, 128)
-    monkeypatch.setattr(ssm, "ssd_kernels", lambda *t, **kw: pytest.fail(
-        "the kernels index B and C by no group"))
+    assert kernels_fit(8, 64, 4, 128, 128)
+    calls = _records_the_kernels(monkeypatch)
     args = _scan_inputs(300, seed=6, batch=1, heads=8, p=64, groups=4, n=128)
     cast = tuple(t.astype(dtype) if i in (0, 3, 4) else t
                  for i, t in enumerate(args))
@@ -128,11 +137,73 @@ def test_several_groups_at_chunks_of_128_equal_the_recurrence(dtype, tol,
         x, dt, a, b, c, d = args
         first = lambda t: jnp.repeat(t[:, :, :1], 4, 2)  # noqa: E731
         wrong = ssd_reference(x, dt, a, first(b), first(c), d)
+    assert calls and all(shape == (1, 300, 8, 64) for shape in calls)
     assert got.shape == want.shape and got.dtype == dtype
     assert _rms_apart(got, want) < tol
     assert _rms_apart(wrong, want) > 0.5
     for name, g, w in zip("x dt a b c d".split(), grads, want_grads):
         assert g.shape == w.shape and _rms_apart(g, w) < tol, name
+
+
+def _planned(batch, seq, heads, p, groups, n, q):
+    """``_plan``'s grid, blocks a step and steps a group for a call of
+    these shapes (and the blocks a trip of the step's loop)."""
+    from ray_tpu.ops import ssm
+
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32)
+    sp = ssm._plan(shape(batch, seq, heads * p), shape(batch, seq, heads),
+                   shape(batch, seq, groups * n), q, p, groups, reverse=False)
+    assert sp["blocking"]["nb"] % sp["blocking"]["trip"] == 0
+    return (sp["grid"], sp["blocking"]["nb"], sp["group_steps"],
+            sp["blocking"]["trip"])
+
+
+@pytest.mark.parametrize("heads,p,groups,plan", [
+    (4, 64, 2, ((2, 3, 2), 1, 1)),
+    (8, 64, 2, ((2, 3, 2), 2, 1)),
+    (12, 64, 2, ((2, 3, 6), 1, 3)),
+    (6, 128, 2, ((2, 3, 6), 1, 3)),
+], ids=["a-block-a-group", "a-step-a-group", "a-group-over-three-steps",
+        "three-steps-of-one-head"])
+def test_a_groups_heads_read_its_b_and_c_whatever_the_blocking(
+        heads, p, groups, plan, monkeypatch):
+    """The three layouts of a group on the kernels' grid, at 300 positions
+    in chunks of 128 (which do not divide them), two rows, float32: a
+    group that is ONE head block (a step is a block), a group that is one
+    step of several blocks (Nemotron-H's: 4 blocks, one step), and a
+    group spread over several steps, whose gradients to B and C
+    accumulate from step to step and are written at the group's last —
+    with a pair of heads a block and with a head that fills the lanes.
+    Values and the six gradients are the recurrence's and the XLA form's,
+    B's and C's the sums over each group's own heads."""
+    assert _planned(2, 384, heads, p, groups, 128, 128)[:3] == plan
+    calls = _records_the_kernels(monkeypatch)
+    args = _scan_inputs(300, seed=8, heads=heads, p=p, groups=groups, n=128)
+    weight = jnp.asarray(np.random.default_rng(4).normal(
+        size=args[0].shape), jnp.float32)
+    forms = (lambda *t: ssd_chunked(*t, chunk=128), ssd_reference,
+             lambda *t: ssd_xla(*t, chunk=128))
+    with HIGHEST:
+        got, want, xla = (jax.jit(f)(*args) for f in forms)
+        grads = [_grads(f, args, weight) for f in forms]
+    assert calls and all(shape == (2, 300, heads, p) for shape in calls)
+    assert _rms_apart(got, want) < 1e-4 and _rms_apart(got, xla) < 1e-4
+    for name, g, w, x in zip("x dt a b c d".split(), *grads):
+        assert g.shape == w.shape and np.all(np.isfinite(g)), name
+        assert _rms_apart(g, w) < 1e-4 and _rms_apart(g, x) < 1e-4, name
+
+
+@pytest.mark.parametrize("shapes,plan", [
+    ((1, 8192, 64, 64, 1, 128, 256), ((1, 32, 4), 8, 4, 1)),
+    ((2, 8192, 64, 64, 8, 128, 128), ((2, 64, 8), 4, 1, 4)),
+], ids=["granite-4.0-h-micro", "nemotron-h"])
+def test_the_published_shapes_blocking(shapes, plan):
+    """The one-group call keeps the blocking it had (granite's 32 head
+    blocks in its one group: 8 a step, 4 steps a chunk, the step a loop of
+    one block a trip); Nemotron-H's 4 blocks a group are one step, a
+    chunk its 8 groups in turn, and at chunks of 128 the step's 4 blocks
+    are one trip."""
+    assert _planned(*shapes) == plan
 
 
 @pytest.mark.parametrize("groups", [1, 2, 8])
@@ -173,11 +244,7 @@ def test_published_sizes_take_the_kernels(dtype, tol, monkeypatch):
     and values and the six gradients are the recurrence's and the XLA
     form's — in bfloat16 within its rounding, the gradients to the decays
     (``dt``, ``a``) too, which are differences of sums over a chunk."""
-    from ray_tpu.ops import ssm
-
-    calls = []
-    monkeypatch.setattr(ssm, "ssd_kernels", lambda *t, **kw: (
-        calls.append(t[0].shape), ssd_kernels(*t, **kw))[1])
+    calls = _records_the_kernels(monkeypatch)
     args = _scan_inputs(600, seed=4, batch=1, heads=4, p=64, groups=1, n=128)
     cast = tuple(t.astype(dtype) if i in (0, 3, 4) else t
                  for i, t in enumerate(args))
@@ -198,11 +265,59 @@ def test_published_sizes_take_the_kernels(dtype, tol, monkeypatch):
             assert _rms_apart(g, w) < tol, name
 
 
+@pytest.mark.parametrize("fsdp", [0, 2], ids=["one-device", "fsdp2"])
+def test_a_mixer_of_several_groups_through_the_kernels_is_the_xla_forms(
+        fsdp, monkeypatch):
+    """The block (``blocks/mamba.py::_apply``) at 8 heads x 64 in 4 groups,
+    state 128, chunks of 128, 256 tokens: the convolution's split hands
+    the scan B and C with their groups side by side and the kernels cut
+    them there.  Output and every parameter's gradient against the same
+    call with ``ssd_xla`` patched in — on one device, and per shard of the
+    batch under an fsdp=2 mesh (``batch_shard_map``)."""
+    from ray_tpu.models.blocks.base import Ctx
+    from ray_tpu.models.llama import _make_cst
+
+    cfg = _cfg(num_layers=1, layer_types=["mamba"], embed_dim=128,
+               ssm_heads=8, ssm_head_dim=64, ssm_groups=4, ssm_state=128,
+               ssm_chunk=128, max_seq_len=256)
+    assert kernels_fit(8, 64, 4, 128, 128)
+    layer = _drawn(init_params(jax.random.PRNGKey(3), cfg))["layers"]
+    lp = {k: layer[k][0] for k in mamba.BLOCK.shapes(cfg)}
+    rng = np.random.default_rng(9)
+    x, weight = (jnp.asarray(rng.normal(size=(2, 256, 128)), jnp.float32)
+                 for _ in range(2))
+    mesh = make_mesh(MeshConfig(fsdp=2), devices=jax.devices()[:2]
+                     ) if fsdp else None
+    ctx = Ctx(cfg, mesh, _make_cst(mesh, None), False)
+
+    def run(lp, x):
+        out, _ = mamba.BLOCK.apply(ctx, x, {}, lp)
+        return jnp.sum(out * weight), out
+
+    both = jax.jit(jax.value_and_grad(run, argnums=(0, 1), has_aux=True))
+    calls = _records_the_kernels(monkeypatch)
+    with HIGHEST:
+        (_, got), got_grads = both(lp, x)
+        assert calls == [(2 // (fsdp or 1), 256, 8, 64)]
+        monkeypatch.setattr(mamba, "ssd_chunked", ssd_xla)
+        (_, want), want_grads = jax.jit(jax.value_and_grad(
+            run, argnums=(0, 1), has_aux=True))(lp, x)
+    assert len(calls) == 1
+    assert _rms_apart(got, want) < 1e-5
+    names = sorted(lp) + ["x"]
+    flat = lambda g: [g[0][k] for k in sorted(lp)] + [g[1]]  # noqa: E731
+    for name, g, w in zip(names, flat(got_grads), flat(want_grads)):
+        assert g.shape == w.shape and _rms_apart(g, w) < 1e-4, name
+
+
 @pytest.mark.parametrize("heads,head_dim,groups,state,chunk,fits", [
     (64, 64, 1, 128, 256, True),      # granite-4.0-h-micro
     (48, 128, 1, 128, 256, True),     # a head fills the lanes
     (8, 32, 1, 256, 128, True),       # four heads a block
-    (64, 64, 8, 128, 256, False),     # B and C differ between head blocks
+    (64, 64, 8, 128, 256, True),      # four head blocks in each group
+    (64, 64, 8, 128, 128, True),      # Nemotron-H
+    (8, 64, 8, 128, 128, False),      # a group is half a head block
+    (64, 64, 3, 128, 128, False),     # groups that do not divide the heads
     (63, 64, 1, 128, 256, False),     # half a block left over
     (64, 48, 1, 128, 256, False),     # heads straddle the lanes
     (64, 8, 1, 128, 256, False),      # sixteen heads a block
@@ -213,6 +328,14 @@ def test_published_sizes_take_the_kernels(dtype, tol, monkeypatch):
 def test_the_shape_rule_that_picks_the_kernels(heads, head_dim, groups,
                                                state, chunk, fits):
     assert kernels_fit(heads, head_dim, groups, state, chunk) is fits
+
+
+def test_the_kernels_refuse_a_head_block_that_straddles_two_groups():
+    """``ssd_chunked`` never sends them such a call (``kernels_fit``); a
+    direct one is refused rather than answered with another group's B."""
+    args = _scan_inputs(16, heads=4, p=8, groups=2)
+    with pytest.raises(ValueError, match="straddles"):
+        ssd_kernels(*args, chunk=8)
 
 
 def test_causal_conv1d_is_the_direct_sum_and_causal():

@@ -419,6 +419,32 @@ def test_granite_hybrid_programs_lower_the_scan_kernels_once_a_use(
     assert (kernels(step, "flash_fwd"), kernels(check, "flash_fwd")) == (1, 2)
 
 
+@pytest.mark.parametrize("batch,groups,chunk", [(1, 1, 256), (2, 8, 128)],
+                         ids=["granite-one-group", "nemotronh-8-groups"])
+def test_state_space_scan_compiles_at_the_published_shapes(
+        one_chip, as_on_chip, batch, groups, chunk):
+    """``ssd_chunked``, value and the six gradients, at 8192 positions of
+    64 heads x 64 with a state of 128: granite's one group in chunks of
+    256 and Nemotron-H's 8 groups in chunks of 128, whose B and C blocks
+    are cut out of ``(b, s, 8 * 128)`` at the grid step's group — a block
+    index that is a quotient, outputs (the group's ``dB``, ``dC``) whose
+    block changes along an ``arbitrary`` axis: what Mosaic could refuse.
+    Both are the kernels, once forward and once backward."""
+    from ray_tpu.ops.ssm import ssd_chunked
+
+    def f(*t):
+        return ssd_chunked(*t, chunk=chunk).astype(jnp.float32).sum()
+
+    bc = _shape((batch, 8192, groups, 128), jnp.bfloat16, one_chip)
+    heads = _shape((64,), jnp.float32, one_chip)
+    compiled = jax.jit(jax.grad(f, argnums=range(6))).lower(
+        _shape((batch, 8192, 64, 64), jnp.bfloat16, one_chip),
+        _shape((batch, 8192, 64), jnp.float32, one_chip), heads, bc, bc,
+        heads).compile()
+    text = compiled.as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+
+
 def _benchmark_cfg(name):
     """The program's config of a benchmark configuration file."""
     import json
