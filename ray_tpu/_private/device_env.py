@@ -15,7 +15,7 @@ from __future__ import annotations
 import glob
 import os
 import subprocess
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Mapping, Optional, Sequence
 
 _MESH_CONTROLLER_PORT = 8476
 
@@ -68,6 +68,15 @@ def pick_chips(free: Sequence[int], n: int,
         pairs = [c for c in free if c % 2 == 0 and c + 1 in free]
         return [pairs[0], pairs[0] + 1] if pairs else None
     return list(free[:n]) if len(free) >= n else None
+
+
+def granted_chips(env: Optional[Mapping[str, str]] = None) -> List[int]:
+    """The chips a process was granted: its ``TPU_VISIBLE_CHIPS`` (this
+    process's environment unless another is given), [] for a CPU
+    worker.  The one reader of what ``worker_device_env`` writes."""
+    env = os.environ if env is None else env
+    return [int(c) for c in env.get("TPU_VISIBLE_CHIPS", "").split(",")
+            if c]
 
 
 def worker_device_env(tpu_chips: Sequence[int],

@@ -479,8 +479,7 @@ class NodeAgent:
         # The head sends the grant; the rest of the device environment
         # is built here, on the host whose chips they are.
         env.update(device_env.worker_device_env(
-            [int(c) for c in env.get("TPU_VISIBLE_CHIPS", "").split(",")
-             if c]))
+            device_env.granted_chips(env)))
         env["RAY_TPU_SHM_DIR_OVERRIDE"] = self.shm_dir
         env["RAY_TPU_STORE_ID"] = self.store_id
         # THIS node's store policy wins over head defaults (see

@@ -34,8 +34,8 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, Optional
 
 from ray_tpu._private import direct as direct_mod
-from ray_tpu._private import object_transfer, protocol, recovery, \
-    serialization
+from ray_tpu._private import device_env, object_transfer, protocol, \
+    recovery, serialization
 from ray_tpu._private.ids import ActorID, ObjectID, TaskID, new_task_id
 from ray_tpu._private import object_ref as object_ref_mod
 from ray_tpu._private.object_ref import ObjectRef
@@ -1791,9 +1791,7 @@ def worker_entry(conn, worker_id_hex: str, session: str, shm_dir: str,
     rt.worker_id_hex = worker_id_hex
     rt.node_id_hex = node_id_hex
     rt.job_id_hex = job_id_hex
-    rt.tpu_chips = [
-        c for c in os.environ.get("TPU_VISIBLE_CHIPS", "").split(",") if c
-    ]
+    rt.tpu_chips = device_env.granted_chips()
     _runtime = rt
     object_ref_mod._set_runtime_accessor(lambda: _runtime)
 

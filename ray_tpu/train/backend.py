@@ -8,16 +8,36 @@ Here the backend is JAX: rank 0 publishes a coordinator address; every
 worker calls ``jax.distributed.initialize(coordinator, n, rank)`` and the
 global device mesh spans all workers' chips — collectives are XLA over
 ICI (in-host) / DCN (cross-host), no NCCL-style library in sight.
+
+Who opens the chips, and when: the worker that was granted them, itself,
+before the user's loop (``bring_up``, the backend's ``worker_setup``,
+which ``TrainWorker.run_train_fn`` runs between ``train.session_start``
+and ``train.loop`` in a process with a non-empty ``TPU_VISIBLE_CHIPS``).
+Its spans are ``device.bring_up`` (``chips=``) > ``jax.import``,
+``jax.backend_init``; between the two ``tracing.watch_process()`` starts,
+so JAX's pipeline is watched from the first program the process makes;
+then ``check_devices``: the platform is ``tpu`` and the local devices
+are as many as the chips granted, or ``fit()`` fails before the loop.
+The loop's own ``import jax`` / ``jax.devices()`` are then cached calls.
+A CPU worker runs none of it and imports no JAX.  In a gang ``on_start``'s
+``jax.distributed.initialize`` comes first, as it must (its reply's
+``device_count`` starts the runtime there: under the driver's
+``train.backend_start``, and the worker's own spans are short).
 """
 
 from __future__ import annotations
 
 import socket
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Sequence
 
 
 class Backend:
     """Plugin interface (reference: train/backend.py BackendConfig/Backend)."""
+
+    # Per-worker set-up: a module-level function of the chips the
+    # process was granted (``device_env.granted_chips()``), or None.
+    # Runs in every worker that was granted chips, before the loop.
+    worker_setup: Optional[Callable[[Sequence[int]], None]] = None
 
     def on_start(self, worker_group, backend_config) -> None:
         pass
@@ -63,8 +83,34 @@ def _init_jax_distributed(coordinator: str, num_processes: int,
             "local_device_count": jax.local_device_count()}
 
 
+def check_devices(devices: Sequence[Any], chips: Sequence[int]) -> None:
+    """The process opened what it was granted: ``devices`` (its local
+    devices) are TPU chips, as many as ``chips``."""
+    platforms = sorted({d.platform for d in devices})
+    if platforms != ["tpu"] or len(devices) != len(chips):
+        raise RuntimeError(
+            f"this worker was granted {len(chips)} TPU chip(s) "
+            f"({','.join(map(str, chips))}) and opened {len(devices)} "
+            f"device(s) of platform {'/'.join(platforms) or 'none'}")
+
+
+def bring_up(chips: Sequence[int]) -> None:
+    """Open the chips this process was granted (the module's header)."""
+    from ray_tpu.util import tracing
+
+    with tracing.span("device.bring_up", chips=len(chips)):
+        with tracing.span("jax.import"):
+            import jax
+        tracing.watch_process()
+        with tracing.span("jax.backend_init"):
+            devices = jax.local_devices()  # starts the TPU runtime
+        check_devices(devices, chips)
+
+
 class _JaxBackend(Backend):
     """Reference analog: _TorchBackend (train/torch/config.py:103)."""
+
+    worker_setup = staticmethod(bring_up)
 
     def on_start(self, worker_group, backend_config: JaxConfig):
         n = worker_group.num_workers

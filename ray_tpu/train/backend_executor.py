@@ -98,8 +98,9 @@ class BackendExecutor:
                                    if ckpt else None),
                     "stream_topic": topic,
                 }
-                futs.append(w.run_train_fn.remote(train_fn, config,
-                                                  session_kwargs))
+                futs.append(w.run_train_fn.remote(
+                    train_fn, config, session_kwargs,
+                    self._backend.worker_setup))
             pending = list(futs)
             try:
                 while pending:

@@ -29,9 +29,9 @@ from ray_tpu.parallel.sharding import (
 )
 from ray_tpu.util import tracing
 
-# A process that builds train steps has JAX: from here on every program
-# it traces, lowers, compiles or loads is a span by function name, and so
-# is every pause of the garbage collector (``tracing.watch_process``).
+# A process that builds train steps has JAX: from here on (in a worker
+# granted chips: since ``train/backend.py::bring_up`` opened them) each
+# program and collector pause is a span (``tracing.watch_process``).
 tracing.watch_process()
 
 

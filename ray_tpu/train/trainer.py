@@ -79,7 +79,11 @@ class DataParallelTrainer(BaseTrainer):
         where the time of this call went: per span name (``train.*`` of
         the driver, the head's ``sched.wait`` / ``worker.spawn`` of the
         workers, rank 0's ``train.session_start``, ``train.loop`` and
-        ``session.report``; where the loop builds train steps, JAX's
+        ``session.report``; of a worker that was granted chips,
+        ``device.bring_up`` with its ``jax.import`` and
+        ``jax.backend_init``, between ``train.session_start`` and
+        ``train.loop``: ``train/backend.py::bring_up``; from there on,
+        or where a CPU worker's loop builds train steps, JAX's
         ``jax.trace``, ``jax.lower``, ``jax.compile``, ``jax.cache_load``,
         ``jax.cache_miss`` of every program, and the collector's
         ``gc.pause``: ``tracing.watch_process``)

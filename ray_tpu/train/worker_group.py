@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu as ray
+from ray_tpu._private import device_env
 from ray_tpu.remote_function import _bulk_submit
 from ray_tpu.util.placement_group import PlacementGroup
 
@@ -37,16 +38,20 @@ class TrainWorker:
         return {
             "hostname": socket.gethostname(),
             "pid": os.getpid(),
-            "tpu_chips": os.environ.get("TPU_VISIBLE_CHIPS", ""),
+            "tpu_chips": ",".join(map(str, device_env.granted_chips())),
         }
 
     def execute(self, fn: Callable, *args, **kwargs):
         return fn(*args, **kwargs)
 
     def run_train_fn(self, train_fn: Callable, config: Dict[str, Any],
-                     session_kwargs: Dict[str, Any]):
+                     session_kwargs: Dict[str, Any],
+                     worker_setup: Optional[Callable] = None):
         """Run the user loop under an active air session; return the
-        session's reports + checkpoints (driver-side aggregation)."""
+        session's reports + checkpoints (driver-side aggregation).
+        A process that was granted chips first runs the backend's
+        ``worker_setup`` on them (``train/backend.py::bring_up``); one
+        that raises fails the call before the loop."""
         from ray_tpu.util import tracing
         with tracing.collect() as got:
             # The first import of ray_tpu.air in this process brings
@@ -56,6 +61,9 @@ class TrainWorker:
                 sess = _TrainSession(**session_kwargs)
                 _set_session(sess)
             try:
+                chips = device_env.granted_chips()
+                if worker_setup is not None and chips:
+                    worker_setup(chips)
                 with tracing.span("train.loop"):
                     train_fn(config)
             finally:

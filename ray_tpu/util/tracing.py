@@ -15,7 +15,10 @@ a worker's buffer, the periodic ``spans`` message, the head's deque,
 over; ``watch_process()`` turns what JAX reports of its compile pipeline
 (``jax.trace``, ``jax.lower``, ``jax.compile``, ``jax.cache_load``,
 ``jax.cache_miss``) and the garbage collector's pauses (``gc.pause``)
-into such spans.
+into such spans.  A train worker that was granted chips opens them under
+``device.bring_up`` (``chips=``) > ``jax.import``, ``jax.backend_init``
+before the user's loop (``train/backend.py::bring_up``) and is watched
+from between the two: from the first program it makes.
 
 The shared clock with the chip: when ``jax`` is ALREADY imported in the
 process, a span also enters ``jax.profiler.TraceAnnotation(name)``, so under
@@ -371,8 +374,10 @@ def watch_process() -> None:
     ``jax.cache_miss`` (no length) — and ``gc.pause`` (``generation=``)
     for every collection of generation 2 and any that took over
     ``GC_PAUSE_MIN_S``.  Idempotent.  For a process that has imported JAX
-    already (``train/core.py`` calls it): a driver that must stay off the
-    chip never comes here."""
+    already: a worker that was granted chips calls it as it opens them
+    (``train/backend.py::bring_up``), any other process that builds train
+    steps where ``train/core.py`` is imported; a driver that must stay
+    off the chip never comes here."""
     if _gc_phase in gc.callbacks:
         return
     from jax import monitoring
