@@ -8,14 +8,19 @@ of the ``ssm_groups`` groups, whose B and C go in side by side as the
 convolution's split leaves them —, per shard of the batch under a mesh),
 ``ssm_out`` (the norm of the GATED output — gate first, then a norm over
 each group's own channels, ``ssm_groups`` equal parts of the inner width:
-the whole of it for one group —, the output projection, the residual add).
+the whole of it for one group; several groups are one rule that never
+leaves ``(b, s, inner)``, Pallas kernels where ``ssm.norm_kernels_fit``,
+per shard of the batch under a mesh as the scan —, the output projection,
+the residual add).
 
 The layer checkpoint keeps the input projection's output [z | xBC | dt]
 (``ssm_proj``: bf16, 139 MB a layer at 8192 tokens).  With it the backward
 pass runs no second ``ssm_in`` matmul; the convolution, the scan and the
-gated norm ARE run again (their intermediates are several times that
-size).  On the v5e: 8.8 ms of a 507 ms step for 1.25 GB held, 2.4 GB of
-program (PERF.md §6, PR 30).
+gated norm ARE run again: the convolution's and the scan's intermediates
+are several times that size, and the norm's rule keeps nothing but its
+arguments — the rerun scan's output and ``z``, a slice of ``ssm_proj``.
+On the v5e: 8.8 ms of a 507 ms step for 1.25 GB held, 2.4 GB of program
+(PERF.md §6, PR 30).
 """
 
 import jax
@@ -61,6 +66,8 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
     b, s = x.shape[0], x.shape[1]
     inner, gn = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state
     f32 = jnp.float32
+    # a Pallas kernel has no partitioning rule: per shard of the batch
+    per_shard = mesh is not None and not ctx.sp_manual
     with jax.named_scope("ssm_in"):
         h = rms_norm(x, lp["ssm_norm"], cfg.norm_eps)
         zxbcdt = checkpoint_name(h @ lp["ssm_in"].astype(cfg.dtype), *SAVED)
@@ -71,7 +78,7 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
         xs, bm, cm = jnp.split(xbc, [inner, inner + gn], -1)
     with jax.named_scope("ssm_scan"):
         scan = lambda *t: ssd_chunked(*t, chunk=cfg.ssm_chunk)  # noqa: E731
-        if mesh is not None and not ctx.sp_manual:
+        if per_shard:
             scan = batch_shard_map(scan, mesh, (4, 3, None, 4, 4, None), 4)
         y = scan(
             xs.reshape(b, s, cfg.ssm_heads, cfg.ssm_head_dim), dt,
@@ -80,8 +87,11 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
             cm.reshape(b, s, cfg.ssm_groups, cfg.ssm_state),
             lp["D"])
     with jax.named_scope("ssm_out"):
-        y = gated_rms_norm(y.reshape(b, s, inner), z, lp["gate_norm"],
-                           cfg.norm_eps, cfg.ssm_groups)
+        norm = lambda *t: gated_rms_norm(  # noqa: E731
+            *t, cfg.norm_eps, cfg.ssm_groups)
+        if per_shard:
+            norm = batch_shard_map(norm, mesh, (3, 3, None), 3)
+        y = norm(y.reshape(b, s, inner), z, lp["gate_norm"])
         return add(ctx, x, y @ lp["ssm_out"].astype(cfg.dtype),
                    residual), aux
 

@@ -55,7 +55,11 @@ Beside them the two short causal convolutions of the recurrent mixers:
 ``causal_conv1d`` (depthwise, SiLU fused: Mamba-2's and the delta rule's)
 and ``gated_short_conv`` (``C * conv(B * x)``, no activation: LFM2's whole
 mixer between its two projections), plain XLA with their backward passes
-written out over one shared pair of helpers.
+written out over one shared pair of helpers; and Mamba-2's output norm,
+``gated_rms_norm``: one group is plain XLA, several are one rule with a
+written-out backward pass — the kernels ``gated_norm_fwd`` /
+``gated_norm_bwd`` over the ``(tokens, inner)`` arrays as they stand where
+a group's channels fill lane tiles (``norm_kernels_fit``).
 
 Precision: the decays (``dt A``, their cumulative sums, every ``exp``) and
 the state carried across chunks are float32; the operands of the big
@@ -202,13 +206,181 @@ def gated_rms_norm(y: jax.Array, z: jax.Array, weight: jax.Array,
     on its own (one group: over the whole of it), under one weight of the
     whole width; float32 inside, ``y.dtype`` out.  (The other order, norm
     then gate, is Mamba-2's ``norm_before_gate``, which the published
-    models do not use.)"""
-    gated = y.astype(_F32) * jax.nn.silu(z.astype(_F32))
+    models do not use.)
+
+    Several groups are ONE rule with its backward pass written out
+    (``_grouped_norm``), which keeps nothing but its arguments and makes
+    no array with an axis of groups where a group's channels fill whole
+    lane tiles: a reshape of ``(b, s, inner)`` to ``(b, s, groups,
+    width)`` puts the groups in the sublanes, and XLA then pays for the
+    relayouts of every float32 intermediate, forward and backward."""
     if groups == 1:  # no reshape: the one-group program is what it was
+        gated = y.astype(_F32) * jax.nn.silu(z.astype(_F32))
         return rms_norm(gated, weight, eps).astype(y.dtype)
-    split = (*gated.shape[:-1], groups, gated.shape[-1] // groups)
-    return rms_norm(gated.reshape(split), weight.reshape(split[-2:]),
-                    eps).reshape(gated.shape).astype(y.dtype)
+    return _grouped_norm(y, z, weight, eps, groups)
+
+
+# ---------------------------------------------- the gated norm by groups
+#
+# Two forms of one rule, chosen by what the call's shapes show
+# (``norm_kernels_fit``): where a group's channels fill whole lane tiles
+# (Nemotron-H's 512), two Pallas kernels over tiles of the ``(tokens,
+# inner)`` arrays as they stand, a grid step one group's lanes of a tile
+# of rows — the statistics are lane reductions of a block and live in
+# VMEM; elsewhere (the tests' tiny widths) the same sums as plain XLA on
+# the reshaped arrays.  Both are ``_norm_rows`` / ``_norm_rows_bwd`` over
+# a last axis that is ONE group's channels.
+
+_NORM_ROWS = 1024     # token rows a grid step
+
+
+def _gate_terms(y, z, eps):
+    """What the norm's two passes both start from, float32, the last axis
+    one group's channels: ``y``, ``z``, ``sigmoid(z)``, the gated value
+    ``g = y * silu(z)`` and ``r = rsqrt(mean(g^2) + eps)``."""
+    y, z = y.astype(_F32), z.astype(_F32)
+    sig = jax.nn.sigmoid(z)
+    g = y * (z * sig)
+    r = jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return y, z, sig, g, r
+
+
+def _norm_rows(y, z, w, eps):
+    *_, g, r = _gate_terms(y, z, eps)
+    return g * r * w.astype(_F32)
+
+
+def _norm_rows_bwd(y, z, w, dout, eps):
+    """The gradients to ``y`` and ``z`` and, a row, what it adds to the
+    weight's: with ``n = g r`` the normed value and ``u = dout * w``, ``dg
+    = r (u - n mean(n u))`` (the mean over the group, as ``r`` is its),
+    ``dy = dg silu(z)``, ``dz = dg y silu'(z)``, ``dw = sum of dout n``."""
+    y, z, sig, g, r = _gate_terms(y, z, eps)
+    dout = dout.astype(_F32)
+    n, u = g * r, dout * w.astype(_F32)
+    dg = r * (u - n * jnp.mean(n * u, axis=-1, keepdims=True))
+    return (dg * (z * sig), dg * y * (sig * (1.0 + z * (1.0 - sig))),
+            dout * n)
+
+
+def norm_kernels_fit(inner: int, groups: int) -> bool:
+    """Whether a group's channels fill whole lane tiles: the kernels'
+    blocks are cut at the groups."""
+    return inner % groups == 0 and (inner // groups) % _LANES == 0
+
+
+def _norm_fwd_kernel(y_ref, z_ref, w_ref, out_ref, *, eps):
+    out_ref[...] = _norm_rows(y_ref[...], z_ref[...], w_ref[...], eps
+                              ).astype(out_ref.dtype)
+
+
+def _norm_bwd_kernel(y_ref, z_ref, w_ref, dout_ref, dy_ref, dz_ref, dw_ref,
+                     *, eps, rows):
+    """``dw_ref`` takes the tile's own sum (the tiles' are added outside);
+    ``rows`` are the array's where its last tile overhangs it, else None:
+    what lies beyond them is not data and stays out of the sum."""
+    dy, dz, dw = _norm_rows_bwd(y_ref[...], z_ref[...], w_ref[...],
+                                dout_ref[...], eps)
+    dy_ref[...] = dy.astype(dy_ref.dtype)
+    dz_ref[...] = dz.astype(dz_ref.dtype)
+    if rows is not None:
+        tile = dw.shape[0]
+        inside = _iota((tile, 1), 0) < rows - pl.program_id(0) * tile
+        dw = jnp.where(inside, dw, 0.0)
+    dw_ref[0] = jnp.sum(dw, axis=0, keepdims=True)
+
+
+def _norm_plan(y, groups, interpret):
+    """What the two calls share: rows ``(tokens, inner)`` in tiles of
+    ``_NORM_ROWS`` (the last may overhang), a grid step one group's lanes
+    of a tile."""
+    rows, inner = y.shape
+    tile, width = min(_NORM_ROWS, rows), inner // groups
+    return dict(
+        grid=(pl.cdiv(rows, tile), groups),
+        rows=pl.BlockSpec((tile, width), lambda i, j: (i, j)),
+        w=pl.BlockSpec((1, width), lambda i, j: (0, j)),
+        dw=pl.BlockSpec((1, 1, width), lambda i, j: (i, 0, j)),
+        overhang=rows if rows % tile else None,
+        params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=64 * 1024 * 1024))
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "groups", "interpret"))
+def _norm_fwd_call(y, z, w, *, eps, groups, interpret):
+    """``y``, ``z`` ``(tokens, inner)``, ``w (1, inner)``: the normed
+    gated value like ``y``."""
+    sp = _norm_plan(y, groups, interpret)
+    return pl.pallas_call(
+        functools.partial(_norm_fwd_kernel, eps=eps),
+        grid=sp["grid"],
+        in_specs=[sp["rows"], sp["rows"], sp["w"]],
+        out_specs=sp["rows"],
+        out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+        compiler_params=sp["params"],
+        interpret=interpret,
+        name="gated_norm_fwd",
+    )(y, z, w)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "groups", "interpret"))
+def _norm_bwd_call(y, z, w, dout, *, eps, groups, interpret):
+    """Gradients to ``y`` and ``z`` (each like its argument) and to ``w``
+    a tile of rows ``(tiles, 1, inner)`` float32."""
+    sp = _norm_plan(y, groups, interpret)
+    return pl.pallas_call(
+        functools.partial(_norm_bwd_kernel, eps=eps, rows=sp["overhang"]),
+        grid=sp["grid"],
+        in_specs=[sp["rows"], sp["rows"], sp["w"], sp["rows"]],
+        out_specs=[sp["rows"], sp["rows"], sp["dw"]],
+        out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
+                   jax.ShapeDtypeStruct(z.shape, z.dtype),
+                   jax.ShapeDtypeStruct((sp["grid"][0], 1, y.shape[1]),
+                                        _F32)],
+        compiler_params=sp["params"],
+        interpret=interpret,
+        name="gated_norm_bwd",
+    )(y, z, w, dout)
+
+
+def _by_groups(t, groups):
+    return t.reshape(*t.shape[:-1], groups, t.shape[-1] // groups)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped_norm(y, z, weight, eps, groups):
+    inner = y.shape[-1]
+    if norm_kernels_fit(inner, groups):
+        return _norm_fwd_call(
+            y.reshape(-1, inner), z.reshape(-1, inner), weight[None],
+            eps=eps, groups=groups,
+            interpret=attention._interpret_default()).reshape(y.shape)
+    return _norm_rows(*(_by_groups(t, groups) for t in (y, z, weight)),
+                      eps).reshape(y.shape).astype(y.dtype)
+
+
+def _grouped_norm_fwd(y, z, weight, eps, groups):
+    return _grouped_norm(y, z, weight, eps, groups), (y, z, weight)
+
+
+def _grouped_norm_bwd(eps, groups, res, dout):
+    y, z, weight = res
+    inner = y.shape[-1]
+    if norm_kernels_fit(inner, groups):
+        dy, dz, dw = _norm_bwd_call(
+            *(t.reshape(-1, inner) for t in (y, z)), weight[None],
+            dout.reshape(-1, inner), eps=eps, groups=groups,
+            interpret=attention._interpret_default())
+    else:
+        dy, dz, dw = _norm_rows_bwd(
+            *(_by_groups(t, groups) for t in (y, z, weight, dout)), eps)
+    dw = jnp.sum(dw.reshape(-1, inner), axis=0)
+    return (dy.reshape(y.shape).astype(y.dtype),
+            dz.reshape(z.shape).astype(z.dtype), dw.astype(weight.dtype))
+
+
+_grouped_norm.defvjp(_grouped_norm_fwd, _grouped_norm_bwd)
 
 
 def exp_where(mask, x):

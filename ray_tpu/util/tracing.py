@@ -486,7 +486,8 @@ SCAN = "scan"
 # ops/moe.py, ops/ssm.py, ops/streams.py, ops/delta.py): the kernel rows of
 # ``step_breakdown``.  A step scope that starts the same way (``hc_map``,
 # ``hc_mix``) is no kernel's name.
-KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm", "ssd_", "hc_", "delta_")
+KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm", "ssd_", "gated_norm_", "hc_",
+                "delta_")
 _SCOPE_TOKENS = re.compile(r"[^/()]+")
 
 

@@ -19,8 +19,8 @@ from ray_tpu.models.llama import (
     make_pipeline_stage_fn, param_logical_axes, pipeline_stage_params)
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.ssm import (
-    causal_conv1d, gated_rms_norm, kernels_fit, ssd_chunked, ssd_kernels,
-    ssd_reference, ssd_xla)
+    causal_conv1d, gated_rms_norm, kernels_fit, norm_kernels_fit,
+    ssd_chunked, ssd_kernels, ssd_reference, ssd_xla)
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.train.core import init_train_state, train_state_shardings
 
@@ -234,6 +234,58 @@ def test_the_gated_norm_norms_each_group_on_its_own(groups):
     assert low.dtype == jnp.bfloat16 and _rms_apart(low, want) < 2e-2
 
 
+def _norm_written_out(y, z, w, eps, groups):
+    """The gated norm by groups as the test above spells it, float32, for
+    autodiff: the yardstick of the rule's written-out backward pass."""
+    f32 = jnp.float32
+    gated = (y.astype(f32) * jax.nn.silu(z.astype(f32))).reshape(
+        *y.shape[:-1], groups, -1)
+    return (gated / jnp.sqrt(jnp.mean(gated ** 2, -1, keepdims=True) + eps)
+            ).reshape(y.shape) * w
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 1e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("shape,groups,kernels", [
+    ((2, 5, 64), 2, False), ((2, 5, 64), 8, False),
+    ((2, 600, 256), 2, True), ((2, 600, 4096), 8, True),
+], ids=["xla-2-groups", "xla-8-groups", "kernels-2-groups",
+        "kernels-nemotron-h-width"])
+def test_the_grouped_norm_and_its_written_out_gradients(shape, groups,
+                                                        kernels, dtype, tol):
+    """Value, ``dy``, ``dz`` and ``dweight`` of the rule against autodiff
+    of the written-out norm, in both forms: the XLA one at widths that
+    tile nothing and the Pallas kernels (interpreted here) where a
+    group's channels fill lane tiles — Nemotron-H's published 4096 in 8
+    groups — at 1200 tokens, which the tile of 1024 rows does not divide
+    (the weight's gradient leaves the overhang out).  Groups of unlike
+    scale: a norm over the whole width is a tenth and more away."""
+    width = shape[-1]
+    assert norm_kernels_fit(width, groups) is kernels
+    rng = np.random.default_rng(width + groups)
+    y, z, dout = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                  for _ in range(3))
+    y = (y * jnp.repeat(jnp.asarray(rng.uniform(0.1, 10.0, groups)),
+                        width // groups)).astype(dtype)
+    z, dout = z.astype(dtype), dout.astype(dtype)
+    w = jnp.asarray(rng.uniform(0.5, 1.5, width), jnp.float32)
+
+    def both(norm, n):
+        out, vjp = jax.vjp(lambda *t: norm(*t, 1e-5, n), y, z, w)
+        return (out, *vjp(dout.astype(out.dtype)))
+
+    got, want, whole = (both(gated_rms_norm, groups),
+                        both(_norm_written_out, groups),
+                        both(_norm_written_out, 1))
+    for name, g, t, o in zip(("out", "dy", "dz", "dweight"), got, want,
+                             whole):
+        assert g.shape == t.shape and g.dtype == (
+            jnp.float32 if name == "dweight" else dtype), name
+        assert _rms_apart(g, t) < tol, (name, _rms_apart(g, t))
+        assert _rms_apart(o, t) > 0.1, name
+
+
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
                                        (jnp.bfloat16, 1e-2)],
                          ids=["float32", "bfloat16"])
@@ -308,6 +360,40 @@ def test_a_mixer_of_several_groups_through_the_kernels_is_the_xla_forms(
     flat = lambda g: [g[0][k] for k in sorted(lp)] + [g[1]]  # noqa: E731
     for name, g, w in zip(names, flat(got_grads), flat(want_grads)):
         assert g.shape == w.shape and _rms_apart(g, w) < 1e-4, name
+
+
+def test_the_grouped_norm_per_shard_of_the_batch_sums_its_weights_gradient():
+    """Under a mesh the block runs the norm's kernels per shard of the
+    batch as it runs the scan's (``batch_shard_map``): the layer's output
+    and the gradients to ``gate_norm`` — each shard's tiles sum their own
+    rows, the region's transpose adds the shards' — and to ``x`` on an
+    fsdp=2 mesh are the one-device ones."""
+    from ray_tpu.models.blocks.base import Ctx
+    from ray_tpu.models.llama import _make_cst
+
+    cfg = _cfg(num_layers=1, layer_types=["mamba"], embed_dim=128,
+               ssm_heads=8, ssm_head_dim=64, ssm_groups=4, ssm_state=128,
+               ssm_chunk=128, max_seq_len=256)
+    assert norm_kernels_fit(cfg.ssm_inner, cfg.ssm_groups)
+    layer = _drawn(init_params(jax.random.PRNGKey(3), cfg))["layers"]
+    lp = {k: layer[k][0] for k in mamba.BLOCK.shapes(cfg)}
+    rng = np.random.default_rng(11)
+    x, weight = (jnp.asarray(rng.normal(size=(2, 256, 128)), jnp.float32)
+                 for _ in range(2))
+
+    def grads(mesh):
+        ctx = Ctx(cfg, mesh, _make_cst(mesh, None), False)
+        run = lambda lp, x: jnp.sum(  # noqa: E731
+            mamba.BLOCK.apply(ctx, x, {}, lp)[0] * weight)
+        with HIGHEST:
+            value, (d_lp, dx) = jax.jit(jax.value_and_grad(
+                run, argnums=(0, 1)))(lp, x)
+        return value, d_lp["gate_norm"], dx
+
+    want = grads(None)
+    got = grads(make_mesh(MeshConfig(fsdp=2), devices=jax.devices()[:2]))
+    for name, g, w in zip(("value", "gate_norm", "x"), got, want):
+        assert g.shape == w.shape and _rms_apart(g, w) < 1e-5, name
 
 
 @pytest.mark.parametrize("heads,head_dim,groups,state,chunk,fits", [

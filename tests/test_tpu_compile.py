@@ -445,6 +445,39 @@ def test_state_space_scan_compiles_at_the_published_shapes(
     assert "ssd_fwd" in text and "ssd_bwd" in text
 
 
+def test_the_grouped_norm_compiles_with_no_axis_of_groups(one_chip,
+                                                          as_on_chip):
+    """The ``ssm_out`` part of a Nemotron-H Mamba layer — the gated norm
+    over 8 groups of 512, the output projection, the residual add — at 2
+    x 8192 tokens, value and gradients: the norm is the ``gated_norm_fwd``
+    / ``gated_norm_bwd`` kernels, lowered once a use, over the ``(tokens,
+    4096)`` arrays as they stand (a block of 1024 rows x one group's 512
+    lanes, the weight's gradient a tile: what Mosaic could refuse), and
+    the compiled program holds NO float32 array with the groups as an
+    axis of their own — the reshape to ``f32[2,8192,8,512]`` put the
+    groups in the sublanes and cost a relayout of every intermediate."""
+    import re
+
+    from ray_tpu.ops.ssm import gated_rms_norm
+
+    def ssm_out(y, z, norm, w, x):
+        with jax.named_scope("ssm_out"):
+            h = gated_rms_norm(y, z, norm, 1e-5, 8)
+            return (x + h @ w).astype(jnp.float32).sum()
+
+    bf16 = functools.partial(_shape, dtype=jnp.bfloat16, sharding=one_chip)
+    lowered = jax.jit(jax.value_and_grad(ssm_out, argnums=(0, 1, 2, 3))
+                      ).lower(bf16((2, 8192, 4096)), bf16((2, 8192, 4096)),
+                              bf16((4096,)), bf16((4096, 2688)),
+                              bf16((2, 8192, 2688)))
+    text = lowered.as_text()
+    assert [text.count(f'kernel_name = "gated_norm_{k}"')
+            for k in ("fwd", "bwd")] == [1, 1]
+    compiled = lowered.compile().as_text()
+    assert "gated_norm_fwd" in compiled and "gated_norm_bwd" in compiled
+    assert not re.search(r"f32\[[0-9,]*,8,512\]", compiled)
+
+
 def _benchmark_cfg(name):
     """The program's config of a benchmark configuration file."""
     import json
