@@ -9,7 +9,7 @@ A key of both files is equal unless the file lists it under ``reduced``; no
 number of the public file is left out (``NOT_READ`` apart); nothing is added
 that is not the benchmark's own (``OWN_KEYS``) or explained under
 ``assumed``.  Each ``reduced`` entry holds ``published``, ``run`` and
-``why``, and is of one of four kinds, which it names under ``"kind"``
+``why``, and is of one of five kinds, which it names under ``"kind"``
 (without the key it is ``depth``):
 
 - ``depth``: the key that counts the layers, which is the one that the
@@ -20,20 +20,38 @@ that is not the benchmark's own (``OWN_KEYS``) or explained under
 - ``vocabulary``: ``vocab_size`` holds the rows of this chip's slice.
 - ``pattern``: a list of the public file with one entry a layer; ``run`` is
   the first ``<depth>`` entries of ``published``.
+- ``leading_dense``: the key that the file's ``share.leading_dense`` names,
+  where the public file gives it as a WHOLE NUMBER (``num_dense_layers``,
+  ``first_k_dense_replace``, ``moe_layer_start_index``), holds how many of
+  the leading dense layers THIS chip keeps, from 1 to the published count.
+  They are of one kind, so one of them is "the leading dense layers once";
+  those left out lie on further chips as the stages of a pipeline, which
+  the entry's ``why`` says.  Only in a file that states a share; a key
+  whose public value is a list (``mlp_layer_types``, ``mlp_only_layers``)
+  is not cut by it.
 
 A kind admits the keys named here and no other, so nothing else is ever cut:
 no width, no count of heads, of groups or of shared experts.  A file with
-an ``experts_held`` or a ``vocabulary`` entry is one chip's share of a stated
-deployment (model-configs guide, section 4): it says so under ``"share"``
-(``{"chips_per_layer": n, "how": "..."}``, and with experts held
-``"leading_dense"``: the key of the public file that says how many dense
-layers lead, see ``leading_dense`` below, or null where it has none) and
-``"deployment"``, ``run x chips_per_layer`` is the published count for both
-kinds, and it keeps to the guide's floors: at least 8 experts held, at least
-an eighth of the vocabulary, the leading dense layers once with at least
-four layers after them, and a whole period of every per-layer list of the
-public file.  The floors bind files that state a share only, and experts
-are held only by a file that states one.
+an ``experts_held``, a ``vocabulary`` or a ``leading_dense`` entry is one
+chip's share of a stated deployment (model-configs guide, section 4): it
+says so under ``"share"`` (``{"chips_per_layer": n, "how": "..."}``; with
+experts held ``"leading_dense"``: the key of the public file that says how
+many dense layers lead, see ``leading_dense`` below, or null where it has
+none; optionally ``"vocabulary_over"``, below) and ``"deployment"``.  The
+two shares are stated apart: ``experts held x chips_per_layer`` is the
+published count of experts, and ``rows x vocabulary_over`` the published
+vocabulary, where ``vocabulary_over`` is a whole number from 2 that
+divides ``chips_per_layer`` (256 experts over 32 chips, the rows over 8 of
+them; ``how`` says which chips split the rows) and, left unsaid, is
+``chips_per_layer`` itself.  The file keeps to the guide's floors: at
+least 8 experts held, at least an eighth of the vocabulary (so
+``vocabulary_over`` is at most 8), the leading dense layers once with at
+least four layers after them, and a whole period of every per-layer list
+of the public file.  The layers after the dense ones are the depth less
+the leading dense layers KEPT (the ``leading_dense`` entry's ``run``; the
+published count where no entry cuts it), and a list's period is read
+behind the PUBLISHED count of dense layers.  The floors bind files that
+state a share only, and experts are held only by a file that states one.
 """
 
 from __future__ import annotations
@@ -52,7 +70,8 @@ OWN_KEYS = {"source", "paper", "reduced", "assumed", "deployment", "share",
 # numbers of a public file that no model code reads: a file may leave them out
 NOT_READ = {"bos_token_id", "eos_token_id", "pad_token_id",
             "initializer_range", "pretraining_tp"}
-KINDS = ("depth", "experts_held", "vocabulary", "pattern")
+KINDS = ("depth", "experts_held", "vocabulary", "pattern",
+         "leading_dense")
 # the public key that counts the routed experts, by its name
 EXPERTS = re.compile(r"^(n|num)_(routed_|local_)?experts$")
 MIN_EXPERTS_HELD = 8
@@ -128,6 +147,8 @@ def complaints(conf: Dict[str, Any], published: Dict[str, Any]) -> List[str]:
 
     by_kind: Dict[str, List[str]] = {k: [] for k in KINDS}
     layers_key = conf.get("llama_config", {}).get("num_layers")
+    share = conf.get("share")
+    dense_key = share.get("leading_dense") if isinstance(share, dict) else None
     for key, cut in reduced.items():
         if not {"published", "run", "why"} <= set(cut):
             out.append(f"reduced[{key}]: needs published, run and why")
@@ -156,6 +177,11 @@ def complaints(conf: Dict[str, Any], published: Dict[str, Any]) -> List[str]:
                        "num_experts, num_local_experts)")
         elif kind == "vocabulary" and key != "vocab_size":
             out.append(f"reduced[{key}]: kind vocabulary is for vocab_size")
+        elif kind == "leading_dense" and key != dense_key:
+            out.append(f"reduced[{key}]: kind leading_dense is for the key "
+                       "that the file's share.leading_dense names "
+                       f"({dense_key}), in a file that states a share; no "
+                       "other count and no width is ever cut")
         elif kind == "pattern" and not (isinstance(published[key], list)
                                         and isinstance(conf[key], list)):
             out.append(f"reduced[{key}]: kind pattern is for a list")
@@ -183,7 +209,8 @@ def complaints(conf: Dict[str, Any], published: Dict[str, Any]) -> List[str]:
             out.append(f"reduced[{key}]: run is not the first "
                        f"{conf[depth_key]} entries of the published list")
 
-    if by_kind["experts_held"] or by_kind["vocabulary"]:
+    if (by_kind["experts_held"] or by_kind["vocabulary"]
+            or by_kind["leading_dense"]):
         out += _share_complaints(conf, published, by_kind, depth_key)
     return out
 
@@ -200,15 +227,26 @@ def _share_complaints(conf, published, by_kind, depth_key) -> List[str]:
         out.append("deployment: a share states the deployment it is one "
                    "chip's part of")
     chips = share["chips_per_layer"]
-    for key in by_kind["experts_held"] + by_kind["vocabulary"]:
+    # the chips that split the vocabulary's rows: all that share a layer,
+    # unless the file states them apart
+    rows_over = "vocabulary_over" if "vocabulary_over" in share \
+        else "chips_per_layer"
+    over = share[rows_over]
+    if not (_is_count(over) and over >= 2 and chips % over == 0):
+        out.append(f"share: vocabulary_over {over!r} is no whole number "
+                   f"from 2 that divides chips_per_layer {chips}")
+        over = None
+    for key in by_kind["experts_held"]:
         if conf[key] * chips != published[key]:
             out.append(f"reduced[{key}]: run {conf[key]} x chips_per_layer "
                        f"{chips} is not the published {published[key]}")
-    for key in by_kind["experts_held"]:
         if conf[key] < MIN_EXPERTS_HELD:
             out.append(f"reduced[{key}]: {conf[key]} experts held; a share "
                        f"keeps at least {MIN_EXPERTS_HELD}")
-    for key in by_kind["vocabulary"]:
+    for key in by_kind["vocabulary"] if over else ():
+        if conf[key] * over != published[key]:
+            out.append(f"reduced[{key}]: run {conf[key]} x {rows_over} "
+                       f"{over} is not the published {published[key]}")
         if conf[key] * MIN_VOCABULARY_SHARE < published[key]:
             out.append(f"reduced[{key}]: {conf[key]} rows are under an "
                        "eighth of the vocabulary")
@@ -226,9 +264,12 @@ def _share_complaints(conf, published, by_kind, depth_key) -> List[str]:
             dense = 0
     if depth_key is None:
         return out
-    after = conf[depth_key] - dense
+    # the dense layers KEPT: the published count unless an entry cuts it
+    kept = conf[by_kind["leading_dense"][0]] if by_kind["leading_dense"] \
+        else dense
+    after = conf[depth_key] - kept
     if after < MIN_LAYERS_AFTER_DENSE:
-        out.append(f"reduced[{depth_key}]: {after} layers after the {dense} "
+        out.append(f"reduced[{depth_key}]: {after} layers after the {kept} "
                    "leading dense ones; a share keeps at least "
                    f"{MIN_LAYERS_AFTER_DENSE}")
     for key, value in published.items():
@@ -238,6 +279,6 @@ def _share_complaints(conf, published, by_kind, depth_key) -> List[str]:
         whole = period(value[dense:])
         if after < whole:
             out.append(f"reduced[{depth_key}]: {after} layers after the "
-                       f"{dense} leading dense ones are not a whole period "
+                       f"{kept} leading dense ones are not a whole period "
                        f"of {key} ({whole})")
     return out

@@ -4,8 +4,7 @@ The yardstick for ``train_step.mfu_pct`` and ``flash_roofline``: kept
 here, beside the cells, so that no change to the program can move it.
 Counts are what the forward and backward passes REQUIRE: work the
 program repeats (rematerialised layers, the flash backward's recomputed
-scores, the mesh path's one-hot embedding matmul) is executed but not
-counted, so doing less of it shows as a gain.
+scores) is executed but not counted, so doing less of it shows as a gain.
 
 ``conf`` is a configuration file of ``benchmark/configs`` (the public
 ``config.json`` key names).  Its ``"flops"`` names the module under

@@ -146,9 +146,14 @@ def test_published_widths_and_reduced_keys(name):
     ``assumed``; the modules it names are there."""
     conf = _conf(name)
     assert cuts.complaints(conf, _published(name)) == []
-    # PR 33 widened the rule and left the files as they were: an entry of
-    # ``reduced`` that names no kind is the depth
-    assert all("kind" not in cut for cut in conf["reduced"].values())
+    # PR 33 widened the rule and left the older files as they were: an entry
+    # of ``reduced`` that names no kind is the depth, and a file that states
+    # no share cuts the depth (and a list with it) alone
+    for key, cut in conf["reduced"].items():
+        if "kind" not in cut:
+            assert key == conf["llama_config"]["num_layers"], key
+        if "share" not in conf:
+            assert cut.get("kind", "depth") in ("depth", "pattern"), key
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         entries = {c["name"]: c for c in json.load(f)["configs"]}
     if name in entries:
@@ -219,6 +224,24 @@ def _shared(**more):
     return {"share": dict({"chips_per_layer": 8, "how": "experts"}, **more)}
 
 
+# ONE leading dense layer of the two, four layers after it
+DENSE_1 = {"num_hidden_layers": ("depth", 5),
+           "layer_types": ("pattern", PUBLIC["layer_types"][:5]),
+           "first_k_dense_replace": ("leading_dense", 1)}
+ONE_OF_TWO = dict(SHARE, **DENSE_1)
+NAMES_THE_KEY = "kind leading_dense is for the key that the file's " \
+    "share.leading_dense names "
+MLP_ONLY = dict(OTHER_NAME, mlp_only_layers=[0, 1])
+# 256 experts over 32 chips, the vocabulary's rows over 8 of them
+PUBLIC_256 = dict(PUBLIC, n_routed_experts=256)
+
+
+def _over(n):
+    return {"share": {"chips_per_layer": 32, "vocabulary_over": n,
+                      "how": "experts over 32; rows over the 8 of a host",
+                      "leading_dense": "first_k_dense_replace"}}
+
+
 @pytest.mark.parametrize("public,reduced,own,complaint", [
     (PUBLIC, {"num_hidden_layers": (None, 4)}, {"share": None}, None),
     (PUBLIC, SHARE, {}, None),
@@ -281,6 +304,37 @@ def _shared(**more):
     (PUBLIC, dict(SHARE, layer_types=("pattern",
                                       PUBLIC["layer_types"][1:7])), {},
      "reduced[layer_types]: run is not the first 6 entries"),
+    (PUBLIC, ONE_OF_TWO, {}, None),
+    (PUBLIC, dict(SHARE, first_k_dense_replace=("leading_dense", 0)), {},
+     "reduced[first_k_dense_replace]: kind leading_dense is for whole "
+     "numbers, run from 1 to published"),
+    (dict(PUBLIC, moe_layer_start_index=2),
+     dict(SHARE, moe_layer_start_index=("leading_dense", 1)), {},
+     "reduced[moe_layer_start_index]: " + NAMES_THE_KEY
+     + "(first_k_dense_replace)"),
+    (PUBLIC, dict(SHARE, hidden_size=("leading_dense", 32)), {},
+     "reduced[hidden_size]: " + NAMES_THE_KEY + "(first_k_dense_replace)"),
+    (MLP_TYPES, dict(EXPERTS_5, num_hidden_layers=("depth", 6),
+                     mlp_layer_types=("leading_dense",
+                                      MLP_TYPES["mlp_layer_types"][1:])),
+     _shared(leading_dense="mlp_layer_types"),
+     "reduced[mlp_layer_types]: kind leading_dense is for whole numbers"),
+    (MLP_ONLY, dict(EXPERTS_5, num_hidden_layers=("depth", 6),
+                    mlp_only_layers=("leading_dense", [0])),
+     _shared(leading_dense="mlp_only_layers"),
+     "reduced[mlp_only_layers]: kind leading_dense is for whole numbers"),
+    (PUBLIC, DENSE_1, {"share": None, "deployment": None},
+     "reduced[first_k_dense_replace]: " + NAMES_THE_KEY + "(None), in a "
+     "file that states a share"),
+    (PUBLIC_256, SHARE, _over(8), None),
+    (PUBLIC_256, dict(SHARE, vocab_size=("vocabulary", 256)), _over(16),
+     "reduced[vocab_size]: 256 rows are under an eighth"),
+    (PUBLIC_256, SHARE, _over(3),
+     "share: vocabulary_over 3 is no whole number from 2 that divides "
+     "chips_per_layer 32"),
+    (PUBLIC_256, dict(SHARE, vocab_size=("vocabulary", 1024)), _over(8),
+     "reduced[vocab_size]: run 1024 x vocabulary_over 8 is not the "
+     "published 4096"),
 ], ids=["depth-alone", "one-chips-share", "seven-experts-held",
         "a-sixteenth-of-the-vocabulary", "three-layers-after-the-dense-ones",
         "dense-layers-under-another-name", "dense-layers-in-a-per-layer-list",
@@ -291,7 +345,14 @@ def _shared(**more):
         "heads-as-depth", "experts-as-depth-and-no-share",
         "experts-held-and-no-share", "heads-as-experts-held",
         "experts-a-token-as-experts-held", "heads-as-vocabulary",
-        "no-share-key", "no-deployment", "a-pattern-that-is-no-prefix"])
+        "no-share-key", "no-deployment", "a-pattern-that-is-no-prefix",
+        "one-dense-layer-of-two", "no-dense-layer-kept",
+        "leading-dense-on-a-key-the-share-does-not-name",
+        "a-width-as-leading-dense", "a-per-layer-list-as-leading-dense",
+        "a-list-of-layer-numbers-as-leading-dense",
+        "leading-dense-cut-and-no-share",
+        "experts-over-32-and-rows-over-8", "rows-over-16",
+        "rows-over-3-of-32", "rows-that-do-not-multiply-out"])
 def test_the_rule_of_a_cut(public, reduced, own, complaint):
     """``cuts.complaints`` on files cut from a made-up public file: those
     that keep the rule give none, every other gives the ONE complaint that
@@ -301,6 +362,49 @@ def test_the_rule_of_a_cut(public, reduced, own, complaint):
         assert got == []
     else:
         assert len(got) == 1 and got[0].startswith(complaint), got
+
+
+def test_the_rule_takes_the_catalogs_row_with_six_leading_dense_layers():
+    """A catalog row under ``testdata/published`` before any configuration
+    of it exists (Trinity-Large-Preview: 6 leading dense layers, 256
+    experts, 200192 rows), cut to the guide's floors as the driver counts
+    them: ONE leading dense layer and 4 after it, 8 experts = one chip of
+    32, an eighth of the vocabulary.  The two module names are stubbed."""
+    public = _published("trinity-large-preview")
+    assert (public["num_dense_layers"], public["num_experts"],
+            public["num_hidden_layers"], cuts.period(
+                public["layer_types"][6:])) == (6, 256, 60, 4)
+    share = {"chips_per_layer": 32, "vocabulary_over": 8,
+             "leading_dense": "num_dense_layers",
+             "how": "experts over 32 chips, 8 a chip; the rows over the 8 "
+                    "chips of a group; the layers left out as stages"}
+    cut = {"num_hidden_layers": ("depth", 5),
+           "num_dense_layers": ("leading_dense", 1),
+           "num_experts": ("experts_held", 8),
+           "vocab_size": ("vocabulary", 25024)}
+
+    def conf(reduced, **share_keys):
+        made = _cut(public, reduced, share=dict(share, **share_keys))
+        made["llama_config"] = {"num_layers": "num_hidden_layers"}
+        return made
+
+    assert cuts.complaints(conf(cut), public) == []
+    # all six dense layers demanded: nothing is left after them
+    six = {k: v for k, v in cut.items() if k != "num_dense_layers"}
+    assert cuts.complaints(conf(six), public) == [
+        "reduced[num_hidden_layers]: -1 layers after the 6 leading dense "
+        "ones; a share keeps at least 4",
+        "reduced[num_hidden_layers]: -1 layers after the 6 leading dense "
+        "ones are not a whole period of layer_types (4)"]
+    # one share for experts and rows alike: a thirty-second of the rows
+    del share["vocabulary_over"]
+    assert cuts.complaints(conf(cut), public) == [
+        "reduced[vocab_size]: run 25024 x chips_per_layer 32 is not the "
+        "published 200192"]
+    assert cuts.complaints(conf(dict(cut, vocab_size=("vocabulary", 6256))),
+                           public) == [
+        "reduced[vocab_size]: 6256 rows are under an eighth of the "
+        "vocabulary"]
 
 
 def test_the_leading_period_of_a_per_layer_list():
@@ -355,18 +459,25 @@ def test_llama_config_resolves_published_and_held_counts():
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_program_config_gives_what_it_gave(name):
-    """The resolution as it was before ``@published`` (a key, or ``a/b``),
-    written out here, builds the same ``LlamaConfig``."""
+    """The resolution (a key, ``a/b``, and since PR 33 ``<key>@published``),
+    written out here with the published count read from the COPY of the
+    public file, builds the same ``LlamaConfig``."""
     import jax.numpy as jnp
 
     from benchmark.loops import train
     from ray_tpu.models.llama import LlamaConfig
 
-    conf = _conf(name)
+    conf, published = _conf(name), _published(name)
+
+    def value(term):
+        key, at, which = term.partition("@")
+        assert which == ("published" if at else ""), term
+        return published[key] if at else conf[key]
+
     fields = {}
     for field, expr in conf["llama_config"].items():
         a, _, b = expr.partition("/")
-        fields[field] = conf[a] // conf[b] if b else conf[a]
+        fields[field] = value(a) // value(b) if b else value(a)
     for k in ("dtype", "param_dtype"):
         fields[k] = jnp.dtype(conf["assumed"][k]["value"])
     assert train.program_config(conf) == LlamaConfig(**fields)
@@ -412,6 +523,17 @@ def test_every_configuration_in_benchmark_json_has_its_files():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     assert sorted(c["name"] for c in bench["configs"]) == CONFIGS
+
+
+def test_at_most_a_quarter_of_the_cells_take_four_chips():
+    """A four-chip cell costs four times the chip time in every later
+    check: at most a quarter of the cells, rounded down, and one always
+    may ("one cell takes four chips", as six files said it until PR 52)."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        cells = json.load(f)["workloads"]
+    assert {c["chips"] for c in cells} <= {1, 4}
+    four = sum(c["chips"] == 4 for c in cells)
+    assert 1 <= four <= max(1, len(cells) // 4)
 
 
 # --------------------------------------------------- reference/decoder.py --

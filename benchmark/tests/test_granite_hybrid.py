@@ -54,11 +54,18 @@ def test_the_file_is_the_catalog_row_and_the_model_its_first_ten_layers():
     cell, = [c for c in bench["workloads"] if c["config"] == NAME]
     assert (cell["name"], cell["traffic"], cell["chips"]) == (
         CELL, "train-1x8192", 1)
-    ours = [m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [CELL]]
-    assert ours == ["ssm.time_share_pct", "ssm.scan_ms", "ssm.conv_ms",
-                    "ssm.scan_roofline"]
-    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
+    # the four entries this cell brought: its name leads their lists (a
+    # later state-space cell is appended to three; the roofline imports
+    # this model's FLOP module and stays this cell's alone)
+    ours = [m for m in bench["per_layer"]
+            if m.get("workloads", [None])[0] == CELL]
+    assert [m["name"] for m in ours][:4] == [
+        "ssm.time_share_pct", "ssm.scan_ms", "ssm.conv_ms",
+        "ssm.scan_roofline"]
+    assert ours[3]["workloads"] == [CELL]
+    assert {m["layer"] for m in ours} == {"state-space mixer"}
+    # a one-chip cell (above); how many cells may take four is ONE rule:
+    # test_benchmark.py::test_at_most_a_quarter_of_the_cells_take_four_chips
 
 
 def test_flops_hybrid_against_hand_counts():
@@ -231,7 +238,7 @@ TINY = dict(
     _conf(), hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
     shared_intermediate_size=96, intermediate_size=96, vocab_size=256,
     num_hidden_layers=3, layer_types=["mamba", "attention", "mamba", "mamba"],
-    mamba_n_heads=8, mamba_d_head=16, mamba_d_state=8, mamba_n_groups=2,
+    mamba_n_heads=8, mamba_d_head=16, mamba_d_state=8, mamba_n_groups=1,
     mamba_chunk_size=16, attention_multiplier=0.25)
 
 

@@ -130,11 +130,16 @@ def test_entries_in_benchmark_json():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entries = bench["per_layer"]
-    # Appended, in this order, after everything the file had.
-    assert [m["name"] for m in entries[-len(NAMES):]] == list(NAMES)
-    older_layers = {m["layer"] for m in entries[:-len(NAMES)]}
+    names = [m["name"] for m in entries]
+    # Found by name: in this order, side by side, after everything the file
+    # had then; what later PRs append comes after them.
+    first = names.index(NAMES[0])
+    assert names[first:first + len(NAMES)] == list(NAMES)
+    assert all(names.count(n) == 1 for n in NAMES)
+    ours = entries[first:first + len(NAMES)]
+    older_layers = {m["layer"] for m in entries[:first]}
     end_to_end = {m["name"] for m in bench["end_to_end"]}
-    for m in entries[-len(NAMES):]:
+    for m in ours:
         assert os.path.isfile(os.path.join(
             BENCH, "layer_metrics", m["name"] + ".py")), m["name"]
         assert m["source"] == "program_counter" and m["better"] == "lower"

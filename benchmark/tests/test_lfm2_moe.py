@@ -20,6 +20,8 @@ SCONV_SCOPES = ["sconv_in", "sconv_gate", "sconv_out"]
 METRICS = ["sconv.time_share_pct", "sconv.gate_ms", "sconv.gate_roofline"]
 APPENDED_TO = ["moe.experts_roofline", "moe.load_max_over_mean",
                "moe.held_rows_share", "moe.rows_visited_share"]
+# the readers later PRs brought and listed this cell under (PRs 41, 47)
+LATER = ["moe.token_rows_read_share", "moe.experts_xla_ms"]
 CUT = {"num_hidden_layers": (24, 8), "num_experts": (32, 16),
        "vocab_size": (65536, 32768)}
 
@@ -97,13 +99,17 @@ def test_the_file_is_the_catalog_row_cut_to_one_chip_of_two():
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     assert [m["name"] for m in bench["per_layer"]
             if m.get("workloads") == [CELL]] == METRICS
-    assert [m["name"] for m in bench["per_layer"]][-3:] == METRICS
+    # appended side by side and in this order, wherever the list ends now
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(METRICS[0])
+    assert names[first:first + len(METRICS)] == METRICS
     for name in APPENDED_TO:
-        assert per_layer[name]["workloads"][-1] == CELL
+        assert CELL in per_layer[name]["workloads"]
     assert sorted(m["name"] for m in bench["per_layer"]
                   if CELL in m.get("workloads", ())) == sorted(
-                      METRICS + APPENDED_TO)
-    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
+                      METRICS + APPENDED_TO + LATER)
+    # a one-chip cell (above); how many cells may take four is ONE rule:
+    # test_benchmark.py::test_at_most_a_quarter_of_the_cells_take_four_chips
     assert lfm2_moe.STEP_METRICS["moe_dropped"] == ("sum", 0.0)
     assert {"moe_held_share", "moe_load_max_over_mean",
             "moe_rows_visited_share"} <= set(lfm2_moe.STEP_METRICS)

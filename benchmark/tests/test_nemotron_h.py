@@ -21,7 +21,8 @@ METRICS = ["ssm.groups_scan_roofline", "ssm.kernel_ms"]
 APPENDED_TO = ["moe.experts_roofline", "moe.load_max_over_mean",
                "moe.rows_visited_share", "moe.token_rows_read_share",
                "moe.experts_xla_ms", "moe.held_rows_share",
-               "ssm.time_share_pct", "ssm.scan_ms", "ssm.conv_ms"]
+               "ssm.time_share_pct", "ssm.scan_ms", "ssm.conv_ms",
+               "ssm.out_ms"]    # the last by PR 51, which named both cells
 CUT = {"num_hidden_layers": (52, 9), "n_routed_experts": (128, 16),
        "vocab_size": (131072, 16384)}
 PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -128,7 +129,9 @@ def test_the_cell_its_job_and_its_metrics():
     # ssm.scan_roofline imports granite's FLOP module and key names
     assert CELL not in per_layer["ssm.scan_roofline"]["workloads"]
     # both four-chip places were taken: this one is a one-chip cell
-    assert sum(c["chips"] == 4 for c in bench["workloads"][:10]) == 2
+    upto = bench["workloads"][:[c["name"] for c in bench["workloads"]
+                                ].index(CELL) + 1]
+    assert sum(c["chips"] == 4 for c in upto) == 2 == len(upto) // 4
     assert nemotron_h.STEP_METRICS["moe_dropped"] == ("sum", 0.0)
     assert {"moe_held_share", "moe_load_max_over_mean",
             "moe_rows_visited_share"} <= set(nemotron_h.STEP_METRICS)

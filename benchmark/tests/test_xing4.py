@@ -99,17 +99,23 @@ def test_the_cell_its_job_and_its_metrics():
     assert (job["loop"], job["rows"], job["seq"], job["check_rows"],
             job["warmup_steps"], job["traced_steps"], job["mesh"]) == (
                 "train", 1, 8192, 1, 2, 4, None)
+    # the six entries this cell brought: its name leads their lists, and
+    # the cells later PRs appended to two of them come after it
     ours = [m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [CELL]]
+            if m.get("workloads", [None])[0] == CELL]
     assert ours == ["hc.time_share_pct", "hc.map_ms", "hc.mix_ms",
                     "hc.mix_roofline", "mtp.in_pct", "moe.held_rows_share"]
+    for name in ours[:4]:
+        metric, = [m for m in bench["per_layer"] if m["name"] == name]
+        assert metric["workloads"] == [CELL], name
     for name in ("moe.experts_roofline", "moe.load_max_over_mean"):
         metric, = [m for m in bench["per_layer"] if m["name"] == name]
-        assert metric["workloads"] == ["olmoe-train-s4096", CELL]
+        assert metric["workloads"][:2] == ["olmoe-train-s4096", CELL]
     for m in bench["per_layer"]:
         assert os.path.isfile(os.path.join(
             BENCH, "layer_metrics", m["name"] + ".py")), m["name"]
-    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
+    # a one-chip cell (above); how many cells may take four is ONE rule:
+    # test_benchmark.py::test_at_most_a_quarter_of_the_cells_take_four_chips
 
 
 def test_flops_xing4_against_hand_counts():
