@@ -3,7 +3,10 @@
 (``residual.py``); either may be ``none``, the empty block
 (``base.EMPTY_MIXER``, ``base.EMPTY_FFN``), in a model whose layers are one
 sub-block each.  A mixer or FFN is ONE module that ends in ONE
-``base.Block``.  To add one: write the module, register its ``Block`` below
+``base.Block`` (the softmax mixer is registered three times: ``attention``
+and ``full_attention`` see every earlier token, ``sliding_attention`` is the
+same module under the model's ``sliding_window``).  To add one: write the
+module, register its ``Block`` below
 under the name ``layer_types`` gives it, and list its scopes in the
 ``"scopes"`` of the benchmark configuration that uses it; its
 ``LlamaConfig`` fields sit in ``models/llama.py`` with the others (the
@@ -17,6 +20,8 @@ from ray_tpu.models.blocks import (
 MIXERS = {
     "attention": attention.SOFTMAX,
     "full_attention": attention.SOFTMAX,   # as the public files spell it
+    # ... the same mixer under the model's ``sliding_window``
+    "sliding_attention": attention.SLIDING,
     "latent": attention.LATENT,
     "mamba": mamba.BLOCK,
     "linear_attention": delta.BLOCK,

@@ -1,5 +1,9 @@
 """The softmax mixers: attention (``layer_types``: ``attention`` or, as
-the OLMo and LFM2 files spell it, ``full_attention``) and latent attention
+the OLMo and LFM2 files spell it, ``full_attention``; ``sliding_attention``,
+as the ``afmoe`` file spells it, is the same mixer under the model's
+``sliding_window``: a query sees itself and the ``window - 1`` tokens before
+it, the flash kernels skip what lies beyond either edge, and the layer
+reports ``attn_window_executed_share``) and latent attention
 (arXiv:2412.19437 §2.1.1: q and k/v come up from normed low-rank
 projections, a head's q and k are [no-position part | rotary part, the k's
 shared by all heads] and wider than its v; the flash kernels take the two
@@ -7,19 +11,27 @@ head sizes).  The attention itself is pluggable (``cfg.attn_impl``): pallas
 flash (``ops/attention.py``), ring over 'sp', Ulysses all-to-all, or the
 XLA reference — all numerically interchangeable (tested).
 
-Both open the scopes ``attn_qkv`` (norm, projections, RoPE), ``attention``
+All open the scopes ``attn_qkv`` (norm, projections, RoPE), ``attention``
 and ``attn_out`` (``wo`` and the add), and the layer checkpoint keeps the
 flash kernel's output and log-sum-exp (``ops.attention.SAVED_RESIDUALS``:
-no ``flash_fwd`` under ``rematted_computation``).
+no ``flash_fwd`` under ``rematted_computation``).  Which layers rotate q
+and k is the configuration's to say (``cfg.rotary``).  With
+``attn_output_gate`` a fourth projection ``wg`` of the block's input
+(scope ``attn_qkv``) gates the heads' outputs, ``o * sigmoid(g)``, before
+``wo`` (scope ``attn_out``); the checkpoint keeps nothing of it: the
+rematerialised forward computes ``g`` with q, k and v.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.blocks.base import (
-    Block, Ctx, Param, ones, residual_out)
-from ray_tpu.models.blocks.residual import add, block_in
+    Block, Ctx, Param, fold, ones, residual_out)
+from ray_tpu.models.blocks.residual import (
+    add, block_in, norm_shapes, out_norm)
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.layers import (
@@ -30,12 +42,16 @@ from ray_tpu.parallel.mesh import AXIS_SP, AXIS_TP
 from ray_tpu.parallel.sharding import BATCH_AXES, manual_shard_map
 
 SCOPES = ("attn_qkv", "attention", "attn_out")
+# A windowed layer's statistic: the (q, k) pairs its attention computes
+# over the pairs its window leaves (1.0: no masked pair computed).
+WINDOW_EXECUTED = "attn_window_executed_share"
+WINDOW_STATS = {WINDOW_EXECUTED: "max"}
 
 
 def _attention_shapes(cfg):
     d, h, kvd = cfg.embed_dim, cfg.qkv_dim, cfg.kv_dim
     shapes = {
-        "attn_norm": Param((d,), ("layer", "embed"), ones),
+        **norm_shapes(cfg, "attn"),
         "wq": Param((d, h), ("layer", "kernel_in", "heads")),
         "wk": Param((d, kvd), ("layer", "kernel_in", "kv_heads")),
         "wv": Param((d, kvd), ("layer", "kernel_in", "kv_heads")),
@@ -48,6 +64,8 @@ def _attention_shapes(cfg):
     if cfg.qk_head_norm:  # over each head, ONE weight of a head's size
         head = Param((cfg.head_dim,), ("layer", "head_dim"), ones)
         shapes.update({"q_norm": head, "k_norm": head})
+    if cfg.attn_output_gate:  # a gate a head and channel of the output
+        shapes["wg"] = Param((d, h), ("layer", "kernel_in", "heads"))
     return shapes
 
 
@@ -59,7 +77,7 @@ def _latent_shapes(cfg):
     ``kv_b_proj``)."""
     d, heads, qk = cfg.embed_dim, cfg.num_heads, cfg.latent_qk_dim
     return {
-        "attn_norm": Param((d,), ("layer", "embed"), ones),
+        **norm_shapes(cfg, "attn"),
         "wq_a": Param((d, cfg.q_lora_rank), ("layer", "kernel_in", None)),
         "q_a_norm": Param((cfg.q_lora_rank,), ("layer", None), ones),
         "wq_b": Param((cfg.q_lora_rank, heads * qk),
@@ -101,23 +119,31 @@ def _rope_inv_freq(cfg, dim: int):
         beta_slow=scaling.get("beta_slow", 1.0))
 
 
-def _attention(q, k, v, cfg, mesh):
+def _attention(q, k, v, cfg, mesh, window=None):
     """Dispatch to the configured attention impl; ring / ulysses manage the
-    'sp' axis themselves."""
+    'sp' axis themselves.  ``window``: the keys a query sees, where fewer
+    than all before it (flash and the reference alone take one)."""
     impl, scale = cfg.attn_impl, _sm_scale(cfg)
     if mesh is None:
         # Ring/ulysses degenerate to plain attention on one device.
         if impl == "flash":
-            return flash_attention(q, k, v, causal=True, sm_scale=scale)
+            return flash_attention(q, k, v, causal=True, sm_scale=scale,
+                                   window=window)
         k, v = repeat_kv_heads(q, k, v)
-        return mha_reference(q, k, v, causal=True, sm_scale=scale)
+        return mha_reference(q, k, v, causal=True, sm_scale=scale,
+                             window=window)
+    if window is not None and impl in ("ring", "ulysses"):
+        raise NotImplementedError(
+            "a window over a sequence split over 'sp' (ring, ulysses): each "
+            "rank would skip the ranks wholly before its window")
     if impl == "ring":
         return ring_attention(q, k, v, causal=True, sm_scale=scale, mesh=mesh)
     if impl == "ulysses":
         return ulysses_attention(q, k, v, causal=True, sm_scale=scale,
                                  mesh=mesh)
     if impl == "reference":
-        return mha_reference(q, k, v, causal=True, sm_scale=scale)
+        return mha_reference(q, k, v, causal=True, sm_scale=scale,
+                             window=window)
     # flash under a mesh: pallas has no SPMD partitioning rule, so run the
     # kernel per-shard: batch over (dp,fsdp,ep), heads over tp, seq replicated.
     # Manual over EVERY mesh axis — the TPU lowering refuses a Mosaic
@@ -126,7 +152,7 @@ def _attention(q, k, v, cfg, mesh):
     spec = P(BATCH_AXES, None, AXIS_TP, None)
     fn = manual_shard_map(
         lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True,
-                                           sm_scale=scale),
+                                           sm_scale=scale, window=window),
         set(mesh.axis_names), in_specs=(spec, spec, spec),
         out_specs=spec, mesh=mesh)
     return fn(q, k, v)
@@ -144,23 +170,53 @@ def _attention_sp_manual(q, k, v, cfg):
     return _ring_attention_sharded(q, k, v, _sm_scale(cfg), True, AXIS_SP)
 
 
-def _attend(ctx: Ctx, x, aux, q, k, v, lp, residual: bool):
+def _window_executed(cfg, sq: int, sk: int, d: int, window):
+    """``WINDOW_EXECUTED`` of one call, from shapes alone: the pairs the
+    flash schedule's live sub-tiles compute (``causal_tile_counts``) — the
+    whole rectangle where no flash kernel runs — over the pairs the window
+    leaves."""
+    tiles = attention.choose_tiles(
+        sq, sk, True, d, cfg.dtype, window=window
+    ) if cfg.attn_impl == "flash" else None
+    n = attention.causal_tile_counts(sq, sk, *(tiles or (sq, sk, sq, sk)),
+                                     window=window)
+    executed = n["executed_pairs"] if tiles else sq * sk
+    return jnp.float32(executed / n["causal_pairs"])
+
+
+def _attend(ctx: Ctx, x, aux, q, k, v, lp, residual: bool, gate=None,
+            windowed: bool = False):
     """What every softmax mixer ends in: the attention itself (scope
-    ``attention``), then the heads' outputs side by side through ``wo``
-    and onto the stream (scope ``attn_out``)."""
+    ``attention``; ``windowed``: under the model's ``sliding_window``),
+    then the heads' outputs side by side — times ``sigmoid(gate)`` where
+    the mixer has an output gate — through ``wo`` and onto the stream
+    (scope ``attn_out``)."""
     cfg = ctx.cfg
     with jax.named_scope("attention"):
-        if ctx.sp_manual:
+        if windowed:
+            if ctx.sp_manual:
+                raise NotImplementedError(
+                    "a window inside a region that is manual over 'sp'")
+            window = attention.live_window(cfg.sliding_window, k.shape[1])
+            o = _attention(q, k, v, cfg, ctx.mesh, window)
+            aux = fold(aux, {WINDOW_EXECUTED: _window_executed(
+                cfg, q.shape[1], k.shape[1], max(q.shape[-1], v.shape[-1]),
+                window)}, WINDOW_STATS)
+        elif ctx.sp_manual:
             o = _attention_sp_manual(q, k, v, cfg)
         else:
             o = _attention(q, k, v, cfg, ctx.mesh)
     with jax.named_scope("attn_out"):
         o = o.reshape(*x.shape[:2], -1)
+        if gate is not None:
+            o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(cfg.dtype)
         return add(ctx, x, o @ lp["wo"].astype(cfg.dtype), residual,
-                   lp["attn_norm"]), aux
+                   out_norm(lp, "attn", cfg)), aux
 
 
-def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
+def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
+                     windowed: bool = False):
     cfg, cst = ctx.cfg, ctx.cst
     b, s = x.shape[0], x.shape[1]
     with jax.named_scope("attn_qkv"):
@@ -177,7 +233,9 @@ def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
         v = (h @ lp["wv"].astype(cfg.dtype)).reshape(
             b, s, cfg.num_kv_heads, cfg.head_dim)
-        if cfg.position_embedding == "rope":
+        gate = (h @ lp["wg"].astype(cfg.dtype) if cfg.attn_output_gate
+                else None)
+        if cfg.rotary(windowed):
             offset = 0
             if ctx.sp_manual:
                 offset = jax.lax.axis_index(AXIS_SP) * s
@@ -185,7 +243,7 @@ def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         q = cst(q, ("batch", "seq", "heads", "head_dim"))
         k = cst(k, ("batch", "seq", "kv_heads", "head_dim"))
-    return _attend(ctx, x, aux, q, k, v, lp, residual)
+    return _attend(ctx, x, aux, q, k, v, lp, residual, gate, windowed)
 
 
 def _latent_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
@@ -225,5 +283,9 @@ def _latent_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
 
 SOFTMAX = Block(_attention_shapes, _attention_mixer,
                 saved=attention.SAVED_RESIDUALS, scopes=SCOPES)
+SLIDING = Block(_attention_shapes,
+                functools.partial(_attention_mixer, windowed=True),
+                saved=attention.SAVED_RESIDUALS, scopes=SCOPES,
+                stats=lambda cfg: WINDOW_STATS)
 LATENT = Block(_latent_shapes, _latent_mixer,
                saved=attention.SAVED_RESIDUALS, scopes=SCOPES)

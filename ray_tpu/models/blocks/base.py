@@ -37,6 +37,14 @@ def ones(key, shape):
     return jnp.ones(shape, jnp.float32)
 
 
+def constant(value: float):
+    """``value`` everywhere (a key is drawn for it: ``ones`` alone takes
+    none): a norm's gain that starts off 1."""
+    if value == 1.0:
+        return ones
+    return lambda key, shape: jnp.full(shape, value, jnp.float32)
+
+
 def keyed_ones(key, shape):
     """1, with a key drawn: Mamba's D and the stream maps' scales took one."""
     return ones(key, shape)

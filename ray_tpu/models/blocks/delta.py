@@ -23,7 +23,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.blocks.base import (
     Block, Ctx, Param, a_log, conv, dt_bias, fold, ones, residual_out)
-from ray_tpu.models.blocks.residual import add, block_in
+from ray_tpu.models.blocks.residual import add, block_in, out_norm
 from ray_tpu.ops.delta import delta_chunked
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.ssm import causal_conv1d
@@ -100,7 +100,7 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
              * jax.nn.silu(gate.reshape(b, s, heads, dv).astype(f32))
              ).astype(cfg.dtype)
         return add(ctx, x, o.reshape(b, s, values) @ lp["gdn_out"].astype(
-            cfg.dtype), residual, lp["gdn_norm"]), fold(
+            cfg.dtype), residual, out_norm(lp, "gdn", cfg)), fold(
                 aux, {GDN_STATE_ABSMAX: peak}, STATS)
 
 

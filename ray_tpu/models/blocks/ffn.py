@@ -24,8 +24,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.blocks.base import (
-    Block, Ctx, Param, fold, ones, residual_out)
-from ray_tpu.models.blocks.residual import add, block_in
+    Block, Ctx, Param, fold, residual_out)
+from ray_tpu.models.blocks.residual import (
+    add, block_in, norm_shapes, out_norm)
 from ray_tpu.ops import moe
 from ray_tpu.ops.layers import rms_norm, swiglu
 from ray_tpu.parallel.mesh import (
@@ -50,8 +51,7 @@ def _ffn_shapes(cfg, m: int, prefix: str = "w_", *held):
 
 
 def _dense_shapes(cfg):
-    return {"mlp_norm": Param((cfg.embed_dim,), ("layer", "embed"), ones),
-            **_ffn_shapes(cfg, cfg.dense_width)}
+    return {**norm_shapes(cfg, "mlp"), **_ffn_shapes(cfg, cfg.dense_width)}
 
 
 def _select_bias(std: float):
@@ -67,7 +67,7 @@ def _moe_shapes(cfg):
     expert where the model has them."""
     d, e = cfg.embed_dim, cfg.num_experts
     shapes = {
-        "mlp_norm": Param((d,), ("layer", "embed"), ones),
+        **norm_shapes(cfg, "mlp"),
         "router": Param((d, e), ("layer", "kernel_in", None)),
         **_ffn_shapes(cfg, cfg.mlp_dim, "w_", cfg.local_experts),
     }
@@ -105,7 +105,7 @@ def _dense_ffn(ctx: Ctx, x, aux, lp, residual: bool = True):
     with jax.named_scope("ffn"):
         h = block_in(x, lp["mlp_norm"], cfg)
         return add(ctx, x, _ffn(h, lp, cfg), residual,
-                   lp["mlp_norm"]), aux, None
+                   out_norm(lp, "mlp", cfg)), aux, None
 
 
 def _moe(ctx: Ctx, x, lp, residual: bool = True):
@@ -169,15 +169,22 @@ def _moe(ctx: Ctx, x, lp, residual: bool = True):
 def _moe_ffn(ctx: Ctx, x, aux, lp, residual: bool = True):
     """The expert layer and, where the model has one, the shared expert.
     Hands the experts' assignments out of the scan where a selection bias
-    is moved by them."""
+    is moved by them.  In a model that norms what a block adds too
+    (``block_norm="sandwich"``; the layer norms its input inside, scope
+    ``moe_route``) the experts' sum PLUS the shared expert's output goes
+    through that norm, and then onto the stream (scope ``moe_combine``)."""
     cfg, cst = ctx.cfg, ctx.cst
-    out, seen = _moe(ctx, x, lp, residual)
+    post = out_norm(lp, "mlp", cfg)
+    out, seen = _moe(ctx, x, lp, residual and post is None)
     out = cst(out, ("batch", "seq", "embed"))
     if cfg.shared_experts:
         with jax.named_scope("ffn"):
             h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
             out = out + cst(_ffn(h, lp, cfg, "shared_"),
                             ("batch", "seq", "embed"))
+    if post is not None:
+        with jax.named_scope("moe_combine"):
+            out = add(ctx, x, out, residual, post)
     how = _moe_stats(cfg)
     aux = fold(aux, {k: seen[k.removeprefix("moe_")] for k in how}, how)
     return out, aux, seen["counts"] if cfg.select_bias else None

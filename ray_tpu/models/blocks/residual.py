@@ -16,7 +16,8 @@ keeps nothing of either half: the rematerialised forward runs
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.blocks.base import Ctx, Param, keyed_ones
+from ray_tpu.models.blocks.base import (
+    Ctx, Param, constant, keyed_ones, ones)
 from ray_tpu.ops import streams
 from ray_tpu.ops.layers import rms_norm
 
@@ -29,21 +30,42 @@ def scaled(x, multiplier: float):
     return x if multiplier == 1.0 else x * multiplier
 
 
+def norm_shapes(cfg, name: str):
+    """A block's norm ``<name>_norm`` and, in a model that norms both what
+    a block reads and what it adds (``block_norm="sandwich"``), the second
+    one, ``<name>_post_norm``, whose gain starts at ``cfg.post_norm_init``."""
+    axes = ("layer", "embed")
+    shapes = {name + "_norm": Param((cfg.embed_dim,), axes, ones)}
+    if cfg.block_norm == "sandwich":
+        shapes[name + "_post_norm"] = Param(
+            (cfg.embed_dim,), axes, constant(cfg.post_norm_init))
+    return shapes
+
+
 def block_in(x, weight, cfg):
     """What a block reads: the stream through the block's norm, or, in a
-    model that norms what a block adds (``add``), the stream as it is."""
+    model that norms ONLY what a block adds (``add``), the stream as it
+    is."""
     if cfg.block_norm == "output":
         return x
     return rms_norm(x, weight, cfg.norm_eps)
 
 
+def out_norm(lp, name: str, cfg):
+    """The weight of the norm on what the block ``name`` adds, for ``add``:
+    the block's one norm in a model that norms its output, its second in
+    one that norms both sides, None in one that norms its input alone."""
+    return {"input": None, "output": lp.get(name + "_norm"),
+            "sandwich": lp.get(name + "_post_norm")}[cfg.block_norm]
+
+
 def add(ctx: Ctx, x, y, residual: bool, weight=None):
     """What a block hands on: the stream plus its output ``y`` (inside
     the block's last scope), or ``y`` alone where the layer mixes it into
-    several streams itself.  ``weight`` is the block's norm: where the
-    model norms what a block adds, it is applied here."""
+    several streams itself.  ``weight`` (``out_norm``): where the model
+    norms what a block adds, that norm's, applied here."""
     cfg = ctx.cfg
-    if cfg.block_norm == "output":
+    if weight is not None:
         y = rms_norm(y, weight, cfg.norm_eps)
     y = scaled(ctx.cst(y, ("batch", "seq", "embed")), cfg.residual_multiplier)
     return x + y if residual else y

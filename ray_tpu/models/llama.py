@@ -2,7 +2,8 @@
 the DECODER: configuration, parameter tree, embedding, the scan over layers
 under the layer checkpoint, head, predicted-ahead module, loss, pipeline
 entry points.  What a layer is made of lives in ``models/blocks/``: a MIXER
-(``blocks.MIXERS``: softmax attention | latent attention | a Mamba-2
+(``blocks.MIXERS``: softmax attention, over every earlier token or over a
+window of them | latent attention | a Mamba-2
 state-space mixer | a gated delta-rule linear-attention mixer | a gated
 short convolution) followed by an FFN (``blocks.FFNS``: dense, SwiGLU or
 ungated relu^2 | dropless experts with or without a shared expert) —
@@ -96,19 +97,29 @@ class LlamaConfig:
     qk_norm: bool = False             # RMSNorm over the q and k projections
     qk_head_norm: bool = False        # ... over EACH head's q and k instead
     remat: bool = True
-    # The mixer of each layer, "attention" (or "full_attention") | "mamba"
-    # | "linear_attention" | "conv"; only the first ``num_layers`` entries
-    # are the model, empty = attention everywhere.
+    # The mixer of each layer, "attention" (or "full_attention") |
+    # "sliding_attention" | "mamba" | "linear_attention" | "conv"; only the
+    # first ``num_layers`` entries are the model, empty = attention
+    # everywhere.
     layer_types: Tuple[str, ...] = ()
+    # What a "sliding_attention" layer's query sees: itself and the
+    # sliding_window - 1 tokens before it.
+    sliding_window: int = 0
+    attn_output_gate: bool = False    # o * sigmoid(h W_g) before W_o
     ssm_heads: int = 0                # Mamba-2: heads x head_dim = inner width
     ssm_head_dim: int = 64
     ssm_state: int = 128              # state size a head (d_state)
     ssm_groups: int = 1               # groups that share B and C
     ssm_conv: int = 4                 # width of the causal depthwise conv
     ssm_chunk: int = 256              # tokens a chunk of the scan
-    position_embedding: str = "rope"  # rope | nope (no position signal)
+    # rope | nope (no position signal) | rope_windowed (``rotary``: RoPE
+    # in the "sliding_attention" layers, none in the others)
+    position_embedding: str = "rope"
     attention_multiplier: Optional[float] = None  # None: head_dim ** -0.5
     embedding_multiplier: float = 1.0  # on the embedded tokens
+    # std an UNTIED embedding table is drawn at (0: 1).  A model that
+    # multiplies its embeddings by sqrt(d) draws them at about 1 / sqrt(d).
+    embed_init_std: float = 0.0
     residual_multiplier: float = 1.0  # on what each block adds to the stream
     logits_scaling: float = 1.0       # logits are divided by it
     tie_embeddings: bool = False      # the head reads the embedding table
@@ -148,9 +159,15 @@ class LlamaConfig:
     gdn_conv: int = 4                 # width of the causal depthwise conv
     gdn_neg_eigval: bool = False      # beta in (0, 2): eigenvalues (-1, 1)
     sconv_width: int = 3              # taps of the gated short convolution
-    # Where a block's RMSNorm sits: "input", x + f(norm(x)), or "output",
-    # x + norm(f(x)) with the same weight on what the block adds.
+    # Where a block's RMSNorm sits: "input", x + f(norm(x)); "output",
+    # x + norm(f(x)) with the same weight on what the block adds; or
+    # "sandwich", x + post_norm(f(norm(x))): two norms a block.
     block_norm: str = "input"
+    # The gain a "sandwich" model's second norms start at: where they
+    # start at 1 every block hands on unit RMS whatever it computed, and
+    # what a random attention layer adds to every token alike (the mean
+    # of its values) is as large as the token's own part.
+    post_norm_init: float = 1.0
     # A model whose layers are ONE sub-block each, as its public file
     # spells them (``LAYER_PATTERN``: a character a layer; only the first
     # ``num_layers`` are the model).  Empty: ``layer_types`` says the mixers.
@@ -179,13 +196,30 @@ class LlamaConfig:
                 "predicted-ahead module are not implemented")
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"router_scoring {self.router_scoring!r}")
-        if self.block_norm not in ("input", "output"):
+        if self.block_norm not in ("input", "output", "sandwich"):
             raise ValueError(f"block_norm {self.block_norm!r}")
+        if self.position_embedding not in ("rope", "nope", "rope_windowed"):
+            raise ValueError(
+                f"position_embedding {self.position_embedding!r}")
         if self.block_norm == "output" and (
                 self.num_experts or {"mamba", "conv"} & set(self.layer_types)):
             raise NotImplementedError(
                 "block_norm='output' is implemented for the softmax, latent "
-                "and delta-rule mixers and the dense FFN")
+                "and delta-rule mixers and the dense FFN (not the expert "
+                "layer, which norms its input inside); 'sandwich' for the "
+                "softmax and latent mixers, the dense FFN and the expert "
+                "layer")
+        if self.block_norm == "sandwich" and (
+                {"mamba", "conv", "linear_attention"} & set(self.layer_types)
+                or self.layer_pattern):
+            raise NotImplementedError(
+                "block_norm='sandwich' is implemented for the softmax and "
+                "latent mixers, the dense FFN and the expert layer")
+        if "sliding_attention" in self.layer_types and (
+                self.sliding_window < 1):
+            raise ValueError(
+                "a sliding_attention layer needs sliding_window, the keys a "
+                f"query sees: {self.sliding_window}")
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm is over the whole projection, "
                              "qk_head_norm over each head: one of the two")
@@ -268,6 +302,15 @@ class LlamaConfig:
     @property
     def select_bias(self) -> bool:
         return self.topk_method == "noaux_tc"
+
+    def rotary(self, windowed: bool) -> bool:
+        """Whether a softmax mixer rotates its q and k: everywhere under
+        ``rope``, nowhere under ``nope``, and under ``rope_windowed`` in
+        the layers with a window alone — the ONE place that says which
+        kind of layer carries the position signal."""
+        if self.position_embedding == "rope_windowed":
+            return windowed
+        return self.position_embedding == "rope"
 
     @property
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
@@ -409,7 +452,8 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     layers = _per_run([stack(n, shapes) for n, shapes in main])
     params = {
         "embed": matrix((cfg.vocab_size, cfg.embed_dim),
-                        cfg.embed_dim if cfg.tie_embeddings else 1.0),
+                        cfg.embed_dim if cfg.tie_embeddings
+                        else (cfg.embed_init_std or 1.0) ** -2),
         "layers": layers,
         "final_norm": jnp.ones((cfg.embed_dim,), cfg.param_dtype),
     }

@@ -24,11 +24,20 @@ Design (standard memory-efficient attention, mapped to the TPU grid model):
   its per-row stats are rows ``(b, h, 1, sq)``, not lane-replicated.
 - Causal schedule: a grid step FETCHES a large tile and the kernel walks
   it in COMPUTE sub-tiles, each dead (no code runs), interior (no mask) or
-  diagonal (masked); grid steps whose whole tile is dead name the block
+  on an edge (masked); grid steps whose whole tile is dead name the block
   their neighbour holds, so nothing is copied for them.  With the sizes
   ``choose_tiles`` picks the kernels compute 1.06 times the causal pairs at
   s=4096 and 1.25 times at s=512 (``causal_tile_counts``), where whole
   512 x 1024 tiles computed 1.25 and 2.0 times.
+- A WINDOW (``flash_attention(window=w)``: query i sees key j iff ``0 <= i
+  - j < w``) gives the schedule a second, FAR edge beside the diagonal: a
+  sub-tile wholly older than the window is dead too, one that straddles
+  the far edge takes a mask of its own, and each q tile has a first live
+  kv tile as it has a last one (each kv tile a last live q tile).  The
+  windowed calls are named ``flash_fwd_win`` / ``flash_dq_win`` /
+  ``flash_dkv_win``; without a window nothing of this is traced and the
+  kernels are what they were.  At s=8192, w=4096 they compute 1.06 times
+  the 25.17 M pairs the window leaves of the 33.56 M causal ones.
 - Accumulation is f32 regardless of input dtype (bf16 inputs hit the MXU).
 
 The reference framework has no counterpart (Ray core has no tensor ops —
@@ -40,6 +49,7 @@ pallas interpret mode, so the same code path is tested on CPU.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 from typing import Optional
 
@@ -91,20 +101,27 @@ def _compiler_params(interpret):
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, sm_scale: Optional[float] = None,
-                  q_offset: int = 0, kv_offset: int = 0) -> jax.Array:
+                  q_offset: int = 0, kv_offset: int = 0,
+                  window: Optional[int] = None) -> jax.Array:
     """Pure-XLA multi-head attention, the numerics oracle for every kernel.
 
+    Under ``causal`` query ``i`` sees key ``j`` iff ``j <= i`` (the near
+    edge, the diagonal) and, with a ``window``, iff also ``i - j < window``
+    (the far edge: the query itself and the ``window - 1`` keys before it).
     ``q_offset``/``kv_offset`` are global positions of element 0 of the q/kv
     chunks — used by ring attention where each device holds a seq slice.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    if window is not None and not causal:
+        raise ValueError("a window is the causal mask's far edge")
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
         qi = q_offset + jnp.arange(q.shape[1])[:, None]
         ki = kv_offset + jnp.arange(k.shape[1])[None, :]
-        s = jnp.where(qi >= ki, s, NEG_INF)
+        seen = qi >= ki if window is None else (qi >= ki) & (qi - ki < window)
+        s = jnp.where(seen, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
@@ -113,17 +130,34 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
 #
 # A FETCH tile ``(block_q, block_k)`` is what one grid step copies into
 # VMEM; the kernels walk it in COMPUTE sub-tiles ``(sub_q, sub_k)``.
-# With ``off`` = (first kv column) - (first q row) of a sub-tile:
-#   dead      off >= sub_q      its last q row is before its first column
-#   interior  off <= 1 - sub_k  its first q row sees its last column
-#   diagonal  otherwise         the only kind that needs the mask
+# With ``off`` = (first kv column) - (first q row) of a sub-tile, row r of
+# it sees its column c iff ``r - c >= off`` (the NEAR edge, the diagonal)
+# and, under a window of ``w``, iff also ``r - c < off + w`` (the FAR edge):
+#   dead      off >= sub_q           its last q row is before its first column
+#             off <= 1 - sub_k - w   its first row is past the window of its
+#                                    last column
+#   interior  sub_q - w <= off <= 1 - sub_k   every pair is seen
+#   edge      otherwise: near (off > 1 - sub_k, the diagonal), far
+#             (off < sub_q - w) or both; the only kinds that need a mask
 # Causal positions are top-left aligned (row r sees column c iff r >= c),
 # as ``mha_reference`` has them with zero offsets.
 
-def _tile_kind(off, sub_q, sub_k):
-    """(interior, diagonal) of a sub-tile: Python bools for a Python int
-    ``off``, traced scalars otherwise.  Neither holds for a dead one."""
-    return off <= 1 - sub_k, (off > 1 - sub_k) & (off < sub_q)
+def _tile_kind(off, sub_q, sub_k, window=None):
+    """(interior, on an edge) of a sub-tile: Python bools for a Python int
+    ``off`` (with a window always), traced scalars otherwise.  Neither
+    holds for a dead one.  Without a window the one edge is the diagonal."""
+    if window is None:
+        return off <= 1 - sub_k, (off > 1 - sub_k) & (off < sub_q)
+    edges = _edges(off, sub_q, sub_k, window)
+    return edges == (False, False), edges is not None and any(edges)
+
+
+def _edges(off: int, sub_q, sub_k, window):
+    """(near, far): the edges a live sub-tile straddles; None for a dead
+    one."""
+    if off >= sub_q or (window is not None and off <= 1 - sub_k - window):
+        return None
+    return off > 1 - sub_k, window is not None and off < sub_q - window
 
 
 def _last_live_k(i, block_q, block_k):
@@ -136,11 +170,26 @@ def _first_live_q(i, block_q, block_k):
     return (i * block_k) // block_q
 
 
+def _first_live_k(i, block_q, block_k, window):
+    """First kv tile that q tile ``i`` sees any column of: the one that
+    holds the oldest key of its first row's window."""
+    return jnp.maximum(i * block_q - (window - 1), 0) // block_k
+
+
+def _last_live_q(i, block_q, block_k, window):
+    """Last q tile that holds a row seeing kv tile ``i``: the one whose
+    window still reaches the tile's last column."""
+    return (i * block_k + block_k + window - 2) // block_q
+
+
 def causal_tile_counts(sq: int, sk: int, block_q: int, block_k: int,
-                       sub_q: int, sub_k: int, causal: bool = True) -> dict:
+                       sub_q: int, sub_k: int, causal: bool = True,
+                       window: Optional[int] = None) -> dict:
     """What the schedule executes for one (batch, head), from shapes
-    alone: the number of compute sub-tiles of each kind, the (q, k) pairs
-    the live ones compute, and the pairs the mask leaves (``sq * sk``
+    alone: the number of compute sub-tiles of each kind (``diagonal``:
+    those on an edge, the diagonal or a window's far one), the (q, k)
+    pairs the live ones compute, and the pairs the mask leaves
+    (``causal_pairs``; under a ``window`` those inside it, ``sq * sk``
     without a mask).  Sub-tiles never span fetch tiles, so the fetch tile
     only matters where it cuts a sub-tile short."""
     sub_q, sub_k = min(sub_q, block_q), min(sub_k, block_k)
@@ -148,35 +197,43 @@ def causal_tile_counts(sq: int, sk: int, block_q: int, block_k: int,
     for q0 in range(0, sq, sub_q):
         for k0 in range(0, sk, sub_k):
             interior, diagonal = (True, False) if not causal else (
-                _tile_kind(k0 - q0, sub_q, sub_k))
+                _tile_kind(k0 - q0, sub_q, sub_k, window))
             counts["interior" if interior else
                    "diagonal" if diagonal else "dead"] += 1
     live = counts["interior"] + counts["diagonal"]
     counts["executed_pairs"] = live * sub_q * sub_k
     rows = min(sq, sk)   # row r sees min(r + 1, sk) columns
-    counts["causal_pairs"] = (rows * (rows + 1) // 2 + (sq - rows) * sk
-                              if causal else sq * sk)
+    if not causal:
+        counts["causal_pairs"] = sq * sk
+    elif window is None:
+        counts["causal_pairs"] = rows * (rows + 1) // 2 + (sq - rows) * sk
+    else:   # ... of which the last ``window`` at most
+        counts["causal_pairs"] = sum(
+            max(0, min(r, sk - 1) - max(0, r - window + 1) + 1)
+            for r in range(sq))
     return counts
 
 
-def _walk_tile(causal, off, tiles, body, strips):
+def _walk_tile(causal, off, tiles, body, strips, window=None):
     """Run ``body(q_slice, k_slice, mask)`` over the live part of the
     fetched tile whose first column minus first row is ``off``.
 
     A fetched tile is dead (nothing runs), interior as a whole (one call
-    over all of it, no mask) or straddles the diagonal.  ``off`` is a
-    multiple of gcd(block_q, block_k), so the straddling offsets are few
-    and known when the kernel is traced: each gets ONE branch of
-    straight-line code, which the scheduler overlaps where per-sub-tile
-    branches would serialise it.  In it the tile is cut in strips of
-    sub-tiles along ``strips`` ("q": one per ``sub_q`` rows, for kernels
-    that accumulate per q row; "k": one per ``sub_k`` columns).  A strip's
-    live sub-tiles are adjacent and run as ONE call; its dead ones run no
-    code; its diagonal ones lie at the end nearest the diagonal, and
-    ``mask`` = (axis, n, sub_off) says that those ``n`` trailing columns
-    (axis 1, "q" strips) or leading rows (axis 0, "k" strips), whose own
-    offset is ``sub_off``, need the mask (None: every sub-tile of the
-    strip is interior)."""
+    over all of it, no mask) or straddles an edge: the diagonal or, under
+    a ``window``, the far edge.  ``off`` is a multiple of gcd(block_q,
+    block_k), so the straddling offsets are few and known when the kernel
+    is traced: each gets ONE branch of straight-line code, which the
+    scheduler overlaps where per-sub-tile branches would serialise it.
+    In it the tile is cut in strips of sub-tiles along ``strips`` ("q":
+    one per ``sub_q`` rows, for kernels that accumulate per q row; "k":
+    one per ``sub_k`` columns).  A strip's live sub-tiles are adjacent and
+    run as ONE call; its dead ones run no code; those on an edge lie at
+    its ends, and ``mask`` = (axis, segments) says which part of the call
+    needs which test: ``segments`` cuts the call's columns (axis 1, "q"
+    strips) or rows (axis 0, "k" strips) into ``(size, lo, hi)``, row r of
+    a segment seeing its column c iff ``lo <= r - c <= hi`` (a bound that
+    is None is not tested; a segment with neither is interior).  ``mask``
+    is None where every sub-tile of the strip is interior."""
     block_q, block_k, sub_q, sub_k = tiles
     whole = functools.partial(body, pl.ds(0, block_q), pl.ds(0, block_k), None)
     if not causal:
@@ -188,56 +245,82 @@ def _walk_tile(causal, off, tiles, body, strips):
 
     def walk(off):
         for s0 in range(0, *across):
-            kinds = {}      # start of each live sub-tile -> is it diagonal
+            # the live sub-tiles of the strip, as runs of one kind:
+            # [start, size, needs the near test, needs the far test]
+            runs = []
             for x0 in range(0, *along):
                 a, t = (s0, x0) if by_q else (x0, s0)
-                interior, diagonal = _tile_kind(off + t - a, sub_q, sub_k)
-                if interior or diagonal:
-                    kinds[x0] = diagonal
-            if not kinds:
+                tests = _edges(off + t - a, sub_q, sub_k, window)
+                if tests is None:
+                    continue
+                if runs and tuple(runs[-1][2:]) == tests:
+                    runs[-1][1] += along[1]
+                else:
+                    runs.append([x0, along[1], *tests])
+            if not runs:
                 continue
-            first, n_diag = min(kinds), sum(kinds.values()) * along[1]
-            extent = max(kinds) + along[1] - first
+            first = runs[0][0]
+            extent = runs[-1][0] + runs[-1][1] - first
+            segments = []
+            for x0, size, near, far in runs:
+                # first column minus first row of this run of sub-tiles
+                run_off = off + (x0 - s0 if by_q else s0 - x0)
+                segments.append((size, run_off if near else None,
+                                 run_off + window - 1 if far else None))
+            masked = any(lo is not None or hi is not None
+                         for _, lo, hi in segments)
             if by_q:
                 qs, ks = pl.ds(s0, sub_q), pl.ds(first, extent)
-                mask = (1, n_diag, off + first + extent - n_diag - s0)
             else:
                 qs, ks = pl.ds(first, extent), pl.ds(s0, sub_k)
-                mask = (0, n_diag, off + s0 - first)
-            body(qs, ks, mask if n_diag else None)
+            body(qs, ks, (int(by_q), tuple(segments)) if masked else None)
 
     if isinstance(off, int):     # a one-tile grid: decided while tracing
         return walk(off)
     step = math.gcd(block_q, block_k)
-    pl.when(off <= -block_k)(whole)     # the next multiple of step straddles
-    for straddling in range(step - block_k, block_q, step):
-        pl.when(off == straddling)(functools.partial(walk, straddling))
+    if window is None:
+        pl.when(off <= -block_k)(whole)  # the next multiple of step straddles
+        straddling = range(step - block_k, block_q, step)
+    else:
+        # live: 1 - block_k - window < off < block_q; of those, interior as
+        # a whole: block_q - window <= off <= -block_k
+        oldest = block_q - window
+        if oldest <= -block_k:
+            pl.when((off <= -block_k) & (off >= oldest))(whole)
+        live = range(((1 - block_k - window) // step + 1) * step, block_q,
+                     step)
+        straddling = [o for o in live if not oldest <= o <= -block_k]
+    for o in straddling:
+        pl.when(off == o)(functools.partial(walk, o))
 
 
 def _scores(q, k, mask, transposed=False):
     """f32 ``q @ k^T`` of one strip, or ``k @ q^T`` (the q rows along the
-    lanes) if ``transposed``.  ``mask`` = (axis, n, off): the first ``n``
-    q rows (axis 0) or the last ``n`` k columns (axis 1) are masked, row r
-    of that part seeing its column c iff r - c >= off."""
+    lanes) if ``transposed``.  ``mask`` = (axis, segments) as ``_walk_tile``
+    hands it: the k columns (axis 1) or the q rows (axis 0) in segments
+    ``(size, lo, hi)``, row r of one seeing its column c iff ``lo <= r - c
+    <= hi``."""
     a, b = (k, q) if transposed else (q, k)
     s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if mask is None:
         return s
-    axis, n, off = mask
+    axis, segments = mask
     rows = int(transposed)           # the axis of s the q rows lie along
     cut_axis = axis ^ rows
-    cut = n if axis == 0 else s.shape[cut_axis] - n
-    head, tail = (jax.lax.slice_in_dim(s, 0, cut, axis=cut_axis),
-                  jax.lax.slice_in_dim(s, cut, None, axis=cut_axis))
-    part = head if axis == 0 else tail
-    diff = (jax.lax.broadcasted_iota(jnp.int32, part.shape, rows)
-            - jax.lax.broadcasted_iota(jnp.int32, part.shape, 1 - rows))
-    part = jnp.where(diff >= off, part, NEG_INF)
-    if part.shape == s.shape:
-        return part
-    return jnp.concatenate([part, tail] if axis == 0 else [head, part],
-                           cut_axis)
+    ends = list(itertools.accumulate(size for size, _, _ in segments))
+    parts = [jax.lax.slice_in_dim(s, end - size, end, axis=cut_axis)
+             for end, (size, _, _) in zip(ends, segments)]
+    for i, (_, lo, hi) in enumerate(segments):
+        if lo is None and hi is None:
+            continue
+        part = parts[i]
+        diff = (jax.lax.broadcasted_iota(jnp.int32, part.shape, rows)
+                - jax.lax.broadcasted_iota(jnp.int32, part.shape, 1 - rows))
+        seen = (diff >= lo if hi is None else diff <= hi if lo is None
+                else (diff >= lo) & (diff <= hi))
+        parts[i] = jnp.where(seen, part, NEG_INF)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, cut_axis)
 
 
 def _tile_offset(causal, qi, ki, tiles, grid_qk):
@@ -251,7 +334,8 @@ def _tile_offset(causal, qi, ki, tiles, grid_qk):
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, causal, tiles, grid_qk):
+                m_scr, l_scr, acc_scr, *, causal, tiles, grid_qk,
+                window=None):
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -277,7 +361,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[qs] = m_next
 
     _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
-               update, strips="q")
+               update, strips="q", window=window)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -286,7 +370,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = m_scr[...] + jnp.log2(l)   # log2-domain lse
 
 
-def _grid_and_specs(qt, kt, vt, causal, tiles):
+def _grid_and_specs(qt, kt, vt, causal, tiles, window=None):
     """``(nq, nk)`` tiles of the call and the BlockSpecs of the q-side and
     kv-side operands for both grid orders: ``q_i, k_j, row_i`` for grids
     ``(b, h, q, kv)`` and ``q_j, k_i, stat_j`` for ``(b, h, kv, q)``
@@ -295,16 +379,23 @@ def _grid_and_specs(qt, kt, vt, causal, tiles):
     q and k have one head size, v (and with it o and do: ``o_i``, ``v_j``,
     ``v_i``, ``o_j``) may have another; where the two are equal the specs
     are.  Under the mask a dead grid step names the block its nearest live
-    step holds, which Pallas does not copy again."""
+    step holds, which Pallas does not copy again: past the diagonal and,
+    under a ``window``, before the far edge."""
     block_q, block_k = tiles[:2]
     nq, nk = qt.shape[2] // block_q, kt.shape[2] // block_k
     if causal:
         def inner_k(i, j):
-            return jnp.minimum(j, _last_live_k(i, block_q, block_k))
+            j = jnp.minimum(j, _last_live_k(i, block_q, block_k))
+            if window is None:
+                return j
+            return jnp.maximum(j, _first_live_k(i, block_q, block_k, window))
 
         def inner_q(i, j):
-            return jnp.minimum(
+            j = jnp.minimum(
                 jnp.maximum(j, _first_live_q(i, block_q, block_k)), nq - 1)
+            if window is None:
+                return j
+            return jnp.minimum(j, _last_live_q(i, block_q, block_k, window))
     else:
         inner_k = inner_q = lambda i, j: j
 
@@ -326,17 +417,24 @@ def _grid_and_specs(qt, kt, vt, causal, tiles):
     }
 
 
-def _fwd_call(qt, kt, vt, causal, tiles, interpret):
+def _kernel_name(name: str, window) -> str:
+    """The windowed calls carry names of their own that START with the
+    plain ones, so what sums ``flash_*`` holds them and a reader can tell
+    them apart."""
+    return name if window is None else name + "_win"
+
+
+def _fwd_call(qt, kt, vt, causal, tiles, interpret, window=None):
     """qt/kt: (b, h, s, d), vt: (b, h, s, dv); qt PRE-SCALED by
     sm_scale*log2e.  Returns (o_t, lse) with o_t (b, h, sq, dv) and lse
     (b, h, sq, LANES) lane-replicated f32 in the log2 domain."""
     b, h, sq, _ = qt.shape
     dv = vt.shape[3]
     block_q = tiles[0]
-    (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles)
+    (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, tiles=tiles,
-                          grid_qk=(nq, nk)),
+                          grid_qk=(nq, nk), window=window),
         grid=(b, h, nq, nk),
         in_specs=[specs["q_i"], specs["k_j"], specs["v_j"]],
         out_specs=[specs["o_i"], specs["row_i"]],
@@ -351,7 +449,7 @@ def _fwd_call(qt, kt, vt, causal, tiles, interpret):
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", window),
     )(qt, kt, vt)
     return o, lse
 
@@ -374,7 +472,8 @@ def _p_and_ds(q, k, v, do, lse, delta, mask, transposed=False):
 
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_ref, dv_ref, dk_scr, dv_scr, *, causal, tiles, grid_qk):
+                 dk_ref, dv_ref, dk_scr, dv_scr, *, causal, tiles, grid_qk,
+                 window=None):
     ki, qi = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
     sub_k = tiles[3]
@@ -410,7 +509,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                               preferred_element_type=jnp.float32)  # (sk, d)
 
     _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
-               update, strips="k")
+               update, strips="k", window=window)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -421,7 +520,8 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *, sm_scale, causal, tiles, grid_qk):
+               dq_ref, dq_scr, *, sm_scale, causal, tiles, grid_qk,
+               window=None):
     # sm_scale is applied once at finalize: dL/dq_orig = sm_scale * ds@k.
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -440,14 +540,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
 
     _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
-               update, strips="q")
+               update, strips="q", window=window)
 
     @pl.when(ki == nk - 1)
     def _finalize():
         dq_ref[0, 0] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret):
+def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret,
+              window=None):
     """qt/kt (b, h, s, d), vt/ot/dot (b, h, s, dv); lse (b, h, sq), a float
     a row.  Returns transposed grads (dqt, dkt, dvt)."""
     b, h, sq, d = qt.shape
@@ -455,14 +556,14 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret):
     block_q, block_k = tiles[:2]
     delta = jnp.sum(ot.astype(jnp.float32) * dot.astype(jnp.float32),
                     axis=-1)                                 # (b, h, sq)
-    (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles)
+    (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window)
     q_i, k_j, row_i = specs["q_i"], specs["k_j"], specs["row_i"]
     q_j, k_i, stat_j = specs["q_j"], specs["k_i"], specs["stat_j"]
     o_i, v_j, o_j, v_i = (specs[n] for n in ("o_i", "v_j", "o_j", "v_i"))
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel, causal=causal, tiles=tiles,
-                          grid_qk=(nq, nk)),
+                          grid_qk=(nq, nk), window=window),
         grid=(b, h, nk, nq),
         in_specs=[q_j, k_i, v_i, o_j, stat_j, stat_j],
         out_specs=[k_i, v_i],
@@ -472,7 +573,7 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret):
                         pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-        name="flash_dkv",
+        name=_kernel_name("flash_dkv", window),
     )(qt, kt, vt, dot, lse[:, :, None], delta[:, :, None])
 
     # flash_dq walks "q" strips: its stats stay columns, lane-replicated.
@@ -480,7 +581,8 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret):
                   for x in (lse, delta))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          tiles=tiles, grid_qk=(nq, nk)),
+                          tiles=tiles, grid_qk=(nq, nk),
+                          window=window),
         grid=(b, h, nq, nk),
         in_specs=[q_i, k_j, v_j, o_i, row_i, row_i],
         out_specs=q_i,
@@ -488,7 +590,7 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret):
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-        name="flash_dq",
+        name=_kernel_name("flash_dq", window),
     )(qt, kt, vt, dot, lse, delta)
     return dq, dk, dv
 
@@ -499,13 +601,13 @@ def _to_bhsd(x):
     return jnp.transpose(x, (0, 2, 1, 3))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, sm_scale, causal, tiles, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, sm_scale, causal, tiles, interpret, window=None):
     """``tiles`` = (block_q, block_k, sub_q, sub_k): each sub divides its
     block, each block its sequence."""
     qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)
     o, _ = _fwd_call(_to_bhsd(qs), _to_bhsd(k), _to_bhsd(v), causal,
-                     tiles, interpret)
+                     tiles, interpret, window)
     return _to_bhsd(o)
 
 
@@ -515,10 +617,10 @@ def _flash(q, k, v, sm_scale, causal, tiles, interpret):
 SAVED_RESIDUALS = ("flash_out", "flash_lse")
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret):
+def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret, window=None):
     qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)
     qt, kt, vt = _to_bhsd(qs), _to_bhsd(k), _to_bhsd(v)
-    ot, lse = _fwd_call(qt, kt, vt, causal, tiles, interpret)
+    ot, lse = _fwd_call(qt, kt, vt, causal, tiles, interpret, window)
     ot = checkpoint_name(ot, "flash_out")
     # One lane of the 128 the kernel writes: a float a row is what is
     # worth holding; flash_dq gets the lanes back, flash_dkv takes rows.
@@ -526,10 +628,10 @@ def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret):
     return _to_bhsd(ot), (qt, kt, vt, ot, lse)
 
 
-def _flash_bwd(sm_scale, causal, tiles, interpret, res, do):
+def _flash_bwd(sm_scale, causal, tiles, interpret, window, res, do):
     qt, kt, vt, ot, lse = res
     dqt, dkt, dvt = _bwd_call(qt, kt, vt, ot, lse, _to_bhsd(do), sm_scale,
-                              causal, tiles, interpret)
+                              causal, tiles, interpret, window)
     return _to_bhsd(dqt), _to_bhsd(dkt), _to_bhsd(dvt)
 
 
@@ -539,10 +641,16 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, sm_scale: Optional[float] = None,
                     block_q: int = MAX_BLOCK, block_k: int = MAX_BLOCK,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Memory-efficient MHA.  q: (b, sq, h, d); k: (b, sk, h, d); v:
     (b, sk, h, dv), the output (b, sq, h, dv): v's head size may differ
     from q's and k's (a latent-attention mixer's 192 / 128).
+
+    ``window`` (causal only): query ``i`` sees key ``j`` iff ``0 <= i - j <
+    window``; the kernels then neither fetch nor compute a tile beyond
+    either edge (``flash_*_win``).  A window that reaches every key the
+    diagonal leaves (``window >= sk``) cuts nothing: the plain kernels run.
 
     Supports grouped-query attention: if k/v have fewer heads than q and
     ``h % h_kv == 0``, kv heads are repeated (XLA fuses the broadcast).
@@ -555,14 +663,27 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         interpret = _interpret_default()
     from ray_tpu.ops.layers import repeat_kv_heads
     k, v = repeat_kv_heads(q, k, v)
+    window = live_window(window, k.shape[1], causal)
     tiles = choose_tiles(q.shape[1], k.shape[1], causal,
                          max(q.shape[-1], v.shape[-1]), q.dtype, block_q,
-                         block_k)
+                         block_k, window)
     if tiles is None:
         # No block >= 8 tiles the sequence exactly: the XLA reference is
         # correct, at O(S^2) memory.
-        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-    return _flash(q, k, v, sm_scale, causal, tiles, interpret)
+        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
+                             window=window)
+    return _flash(q, k, v, sm_scale, causal, tiles, interpret, window)
+
+
+def live_window(window: Optional[int], sk: int, causal: bool = True
+                ) -> Optional[int]:
+    """``window`` where it cuts any of ``sk`` keys off, else None."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"window {window}: a whole number from 1, the far "
+                         "edge of the causal mask")
+    return window if window < sk else None
 
 
 def _fit_block(block: int, seq: int) -> Optional[int]:
@@ -577,15 +698,17 @@ def _fit_block(block: int, seq: int) -> Optional[int]:
 
 
 def choose_tiles(sq: int, sk: int, causal: bool, d: int, dtype,
-                 block_q: int = MAX_BLOCK, block_k: int = MAX_BLOCK):
+                 block_q: int = MAX_BLOCK, block_k: int = MAX_BLOCK,
+                 window: Optional[int] = None):
     """(block_q, block_k, sub_q, sub_k) for a call from what it can see,
     or None where no block tiles a sequence.  The fetch tile is the
     largest under the caps (the caller's, the mode's, and ``_BLOCK_BYTES``
     for one operand's block).  Without a mask every sub-tile is interior,
     so the sub-tile is the tile.  Under it the sub-tile is the widest of
     ``SUB_TILES`` that computes at most ``MAX_EXECUTED`` times the causal
-    pairs (``causal_tile_counts``), else the narrowest: wide strips feed
-    the MXU longer products, narrow ones compute less above the diagonal."""
+    pairs (``causal_tile_counts``; under a ``window`` the pairs it leaves),
+    else the narrowest: wide strips feed the MXU longer products, narrow
+    ones compute less beyond the edges."""
     rows = _BLOCK_BYTES // (d * jnp.dtype(dtype).itemsize)
     caps = (MAX_BLOCK, MAX_BLOCK) if causal else UNMASKED_BLOCK
     block_q = _fit_block(min(block_q, caps[0], rows), sq)
@@ -598,7 +721,7 @@ def choose_tiles(sq: int, sk: int, causal: bool, d: int, dtype,
     for sub in SUB_TILES:
         tiles = (block_q, block_k,
                  _fit_block(sub, block_q), _fit_block(sub, block_k))
-        n = causal_tile_counts(sq, sk, *tiles)
+        n = causal_tile_counts(sq, sk, *tiles, window=window)
         if n["executed_pairs"] <= MAX_EXECUTED * n["causal_pairs"]:
             break
     return tiles

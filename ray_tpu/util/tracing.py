@@ -662,7 +662,11 @@ def breakdown_planes(planes, names: Dict[str, Dict[str, str]],
                 key = kernel + (".remat" if phase == "remat" else "")
                 kernels[key] = kernels.get(key, 0) + self_ns
                 kernel_calls[key] = kernel_calls.get(key, 0) + 1
-                if kernel.startswith("flash_") and key not in kernel_pairs:
+                # (a windowed kernel's ratio needs its window, which the
+                # text does not hold: the step's metric
+                # ``attn_window_executed_share`` has it)
+                if (kernel.startswith("flash_") and key not in kernel_pairs
+                        and not kernel.endswith("_win")):
                     ratio = flash_executed_over_causal(text)
                     if ratio is not None:
                         kernel_pairs[key] = ratio

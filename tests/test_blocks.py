@@ -28,6 +28,9 @@ TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ + 1), 0, 256)
 # (interpreted here) so that its kernel's residuals are made
 FIELDS = {
     "attention": {}, "full_attention": {},
+    # a third of the later rows' keys lie outside the window
+    "sliding_attention": dict(sliding_window=24, attn_output_gate=True,
+                              position_embedding="rope_windowed"),
     "latent": dict(q_lora_rank=24, kv_lora_rank=16, qk_nope_dim=16,
                    qk_rope_dim=8, v_head_dim=16),
     "mamba": dict(ssm_heads=8, ssm_head_dim=16, ssm_state=8, ssm_groups=2,
@@ -215,7 +218,35 @@ TINY = {
         topk_method="noaux_tc", routed_scaling_factor=2.0, leading_dense=2,
         hc_mult=4, num_nextn=1, aux_loss_coef=0.0),
 }
-AT_PARENT = {"dense": {"embed": "f32[256,64]",
+# ... and one this tree brought (PR 53): two norms a block, the output gate,
+# a windowed run beside a full one, experts behind one dense layer.
+TINY["trinity"] = lambda: LlamaConfig(
+    vocab_size=128, embed_dim=64, num_layers=3, num_heads=4, num_kv_heads=2,
+    head_dim=16, mlp_dim=32, dense_mlp_dim=96, max_seq_len=64,
+    dtype=jnp.float32, remat=False, attn_impl="reference", norm_eps=1e-5,
+    layer_types=("sliding_attention", "full_attention", "sliding_attention"),
+    sliding_window=16, attn_output_gate=True, block_norm="sandwich",
+    post_norm_init=0.25, position_embedding="rope_windowed",
+    qk_head_norm=True, num_experts=8, num_selected=2, experts_held=4,
+    first_expert=4, shared_experts=1, router_scoring="sigmoid",
+    topk_method="noaux_tc", leading_dense=1, aux_loss_coef=0.0)
+_TRINITY_MIXER = (
+    "attn_norm=f32[1,64] attn_post_norm=f32[1,64] wq=f32[1,64,64] "
+    "wk=f32[1,64,32] wv=f32[1,64,32] wo=f32[1,64,64] q_norm=f32[1,16] "
+    "k_norm=f32[1,16] wg=f32[1,64,64] mlp_norm=f32[1,64] "
+    "mlp_post_norm=f32[1,64] ")
+_TRINITY_EXPERTS = (
+    "router=f32[1,64,8] w_gate=f32[1,4,64,32] w_up=f32[1,4,64,32] "
+    "w_down=f32[1,4,32,64] router_bias=f32[1,8] shared_gate=f32[1,64,32] "
+    "shared_up=f32[1,64,32] shared_down=f32[1,32,64]")
+AT_PARENT = {"trinity": {"embed": "f32[128,64]",
+             "layers": (_TRINITY_MIXER + "w_gate=f32[1,64,96] "
+                        "w_up=f32[1,64,96] w_down=f32[1,96,64]",
+                        _TRINITY_MIXER + _TRINITY_EXPERTS,
+                        _TRINITY_MIXER + _TRINITY_EXPERTS),
+             "final_norm": "f32[64]",
+             "lm_head": "f32[64,128]"},
+ "dense": {"embed": "f32[256,64]",
            "layers": "attn_norm=f32[2,64] wq=f32[2,64,64] wk=f32[2,64,64] "
                      "wv=f32[2,64,64] wo=f32[2,64,64] mlp_norm=f32[2,64] "
                      "w_gate=f32[2,64,128] w_up=f32[2,64,128] "

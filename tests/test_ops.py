@@ -175,6 +175,190 @@ def test_no_visible_pair_in_a_dead_tile(sq, sk):
                 min(q + 1, sk) for q in range(sq))
 
 
+# -- a window: the causal mask's far edge ---------------------------------------
+
+def _seen(q, k, window):
+    return k <= q and (window is None or q - k < window)
+
+
+# (window, tiles) on the module's 128 positions: the window below, at and
+# past the sequence; its far edge ON a sub-tile's border (a multiple of the
+# sub-tile), INSIDE a sub-tile, and BETWEEN two (one short of a border: the
+# border's sub-tile keeps one live pair); a window narrower than a sub-tile
+# (one sub-tile straddles BOTH edges); rectangular fetch tiles, whose
+# straddling offsets differ by the edge.
+WINDOW_CASES = [
+    (64, (64, 64, 32, 32)), (48, (64, 64, 32, 32)), (33, (64, 64, 32, 32)),
+    (31, (64, 64, 32, 32)), (5, (32, 32, 16, 16)), (1, (64, 64, 32, 32)),
+    (96, (128, 128, 32, 32)), (40, (32, 128, 32, 64)),
+    (72, (128, 32, 64, 16)), (20, (64, 128, 16, 64)),
+    (100, (128, 64, 64, 16)), (64, (32, 32, 32, 32)),
+    (127, (64, 64, 32, 32)), (128, (64, 64, 32, 32)),
+    (200, (64, 64, 32, 32))]
+
+
+@pytest.mark.parametrize("window,tiles", WINDOW_CASES, ids=lambda t: (
+    "x".join(map(str, t)) if isinstance(t, tuple) else f"w{t}"))
+def test_windowed_flash_kernels_against_the_reference(qkv, window, tiles):
+    """The three windowed kernels (interpreted), value and gradients,
+    against ``mha_reference(window=)``; the reference itself against a
+    mask written out."""
+    from ray_tpu.ops.attention import _flash
+
+    q, k, v = qkv
+    out = _flash(q, k, v, D ** -0.5, True, tiles, True, window)
+    want = mha_reference(q, k, v, causal=True, window=window)
+    mask = jnp.asarray([[_seen(i, j, window) for j in range(S)]
+                        for i in range(S)])
+    scores = jnp.where(mask, jnp.einsum("bqhd,bkhd->bhqk", q, k)
+                       * D ** -0.5, -jnp.inf)
+    written_out = jnp.einsum("bhqk,bkhd->bqhd",
+                             jax.nn.softmax(scores, -1), v)
+    assert jnp.max(jnp.abs(want - written_out)) < 1e-5
+    assert jnp.max(jnp.abs(out - want)) < 1e-4
+    f = lambda *a: (_flash(*a, D ** -0.5, True, tiles, True, window)
+                    ** 2).sum()
+    g = lambda *a: (mha_reference(*a, causal=True, window=window) ** 2).sum()
+    for a, w in zip(jax.grad(f, (0, 1, 2))(q, k, v),
+                    jax.grad(g, (0, 1, 2))(q, k, v)):
+        assert jnp.max(jnp.abs(a - w)) < 1e-3
+
+
+@pytest.mark.parametrize("window", [None, 128, 200], ids=str)
+def test_without_a_live_window_the_call_is_the_plain_one_bit_for_bit(
+        qkv, window):
+    """``window=None`` — and a window that reaches every key — run the
+    plain kernels under their plain names: the same bits out, the same
+    gradients, no ``_win`` in the program."""
+    q, k, v = qkv
+    plain = lambda *a: flash_attention(*a, causal=True, block_q=64,
+                                       block_k=64)
+    windowed = lambda *a: flash_attention(*a, causal=True, block_q=64,
+                                          block_k=64, window=window)
+    assert jnp.array_equal(plain(q, k, v), windowed(q, k, v))
+    loss = lambda fn: lambda *a: (fn(*a) ** 2).sum()
+    for a, w in zip(jax.grad(loss(windowed), (0, 1, 2))(q, k, v),
+                    jax.grad(loss(plain), (0, 1, 2))(q, k, v)):
+        assert jnp.array_equal(a, w)
+    text = jax.jit(jax.grad(loss(windowed), (0, 1, 2))).lower(
+        q, k, v).as_text(debug_info=True)
+    assert "flash_fwd" in text and "flash_dkv" in text
+    assert not any(name + "_win" in text
+                   for name in ("flash_fwd", "flash_dq", "flash_dkv"))
+    live = jax.jit(jax.grad(loss(lambda *a: flash_attention(
+        *a, causal=True, window=64)), (0, 1, 2))).lower(q, k, v).as_text(
+            debug_info=True)
+    for name in ("flash_fwd_win", "flash_dq_win", "flash_dkv_win"):
+        assert name in live
+    with pytest.raises(ValueError):
+        flash_attention(q, k, v, causal=False, window=64)
+    with pytest.raises(ValueError):
+        flash_attention(q, k, v, causal=True, window=0)
+
+
+@pytest.mark.parametrize("sq,sk", [(32, 32), (48, 16), (16, 48), (40, 24)])
+def test_tile_kinds_and_counts_under_a_window_against_a_brute_count(sq, sk):
+    """Exhaustive on small sizes, every sub-tile shape that divides and
+    every window from 1 past the sequence: a dead sub-tile holds no pair
+    inside the window, an interior one none outside it, the edges named
+    are the edges straddled, and the counter agrees with a count of the
+    pairs one by one."""
+    from ray_tpu.ops.attention import _edges, _tile_kind
+
+    for window in (1, 2, 3, 7, 8, 9, 16, 17, 31, 40, 64):
+        for sub_q in (1, 2, 4, 8, 16):
+            for sub_k in (1, 2, 4, 8, 16):
+                if sq % sub_q or sk % sub_k:
+                    continue
+                kinds = {"dead": 0, "interior": 0, "diagonal": 0}
+                for q0 in range(0, sq, sub_q):
+                    for k0 in range(0, sk, sub_k):
+                        interior, edge = _tile_kind(k0 - q0, sub_q, sub_k,
+                                                    window)
+                        assert not (interior and edge)
+                        pairs = [(q, k) for q in range(q0, q0 + sub_q)
+                                 for k in range(k0, k0 + sub_k)]
+                        seen = [_seen(q, k, window) for q, k in pairs]
+                        if interior:
+                            assert all(seen)
+                        elif edge:
+                            assert any(seen) and not all(seen)
+                            near, far = _edges(k0 - q0, sub_q, sub_k, window)
+                            assert near == any(k > q for q, k in pairs)
+                            assert far == any(q - k >= window
+                                              for q, k in pairs)
+                        else:
+                            assert not any(seen)
+                        kinds["interior" if interior else
+                              "diagonal" if edge else "dead"] += 1
+                n = causal_tile_counts(sq, sk, sq, sk, sub_q, sub_k,
+                                       window=window)
+                assert {k: n[k] for k in kinds} == kinds
+                assert n["executed_pairs"] >= n["causal_pairs"] == sum(
+                    _seen(q, k, window) for q in range(sq)
+                    for k in range(sk))
+
+
+def test_the_windowed_grid_fetches_no_tile_beyond_either_edge():
+    """The kv blocks a q tile's grid steps name (and the q blocks a kv
+    tile's) are its live tiles and no other: a dead step names its nearest
+    live neighbour's block, which Pallas does not copy again.  At the
+    cell's shape: 8192 positions under 4096 in 2048 x 2048 tiles."""
+    from ray_tpu.ops.attention import _grid_and_specs
+
+    for s, window, bq, bk in ((8192, 4096, 2048, 2048), (512, 100, 64, 128),
+                              (512, 300, 128, 32), (256, 64, 64, 64)):
+        qt = jax.ShapeDtypeStruct((1, 1, s, 128), jnp.bfloat16)
+        (nq, nk), specs = _grid_and_specs(qt, qt, qt, True, (bq, bk, 8, 8),
+                                          window)
+        assert (nq, nk) == (s // bq, s // bk)
+        live = [[any(_seen(q, k, window)
+                     for q in (i * bq, i * bq + bq - 1)
+                     for k in range(j * bk, j * bk + bk))
+                 or any(_seen(q, k, window)
+                        for q in range(i * bq, i * bq + bq)
+                        for k in (j * bk, j * bk + bk - 1))
+                 for j in range(nk)] for i in range(nq)]
+        for i in range(nq):
+            named = {int(specs["k_j"].index_map(0, 0, i, j)[2])
+                     for j in range(nk)}
+            assert named == {j for j in range(nk) if live[i][j]}, (s, i)
+        for j in range(nk):
+            named = {int(specs["q_j"].index_map(0, 0, j, i)[2])
+                     for i in range(nq)}
+            assert named == {i for i in range(nq) if live[i][j]}, (s, j)
+            stats = {int(specs["stat_j"].index_map(0, 0, j, i)[3])
+                     for i in range(nq)}
+            assert stats == named
+    # the plain grid names what it named
+    (nq, nk), specs = _grid_and_specs(qt, qt, qt, True, (64, 64, 8, 8))
+    assert {int(specs["k_j"].index_map(0, 0, 1, j)[2])
+            for j in range(nk)} == {0, 1}
+
+
+def test_causal_tile_counts_at_the_windowed_cells_shape():
+    """trinity-train-s8192: 8192 positions under a window of 4096 at the
+    tiles ``choose_tiles`` picks for d=128 bf16 — 25.17 M pairs of the
+    33.56 M causal ones, 1.062 times of them computed, a third of the
+    sub-tiles dead on the far side."""
+    tiles = choose_tiles(8192, 8192, True, 128, jnp.bfloat16, window=4096)
+    assert tiles == choose_tiles(8192, 8192, True, 128, jnp.bfloat16) == (
+        2048, 2048, 256, 256)
+    n = causal_tile_counts(8192, 8192, *tiles, window=4096)
+    plain = causal_tile_counts(8192, 8192, *tiles)
+    assert n["causal_pairs"] == 4096 * 4097 // 2 + 4096 * 4096 == 25167872
+    assert plain["causal_pairs"] == 33558528
+    assert n["executed_pairs"] / n["causal_pairs"] == pytest.approx(
+        1.0624, abs=1e-4)
+    assert (n["interior"], n["diagonal"], n["dead"]) == (360, 48, 616)
+    assert (plain["interior"], plain["diagonal"], plain["dead"]) == (
+        496, 32, 496)
+    # at the window's length and below, the window counts as the mask does
+    short = choose_tiles(4096, 4096, True, 128, jnp.bfloat16)
+    assert causal_tile_counts(4096, 4096, *short, window=4096) == \
+        causal_tile_counts(4096, 4096, *short)
+
+
 @pytest.mark.parametrize("impl", ["ring", "ulysses"])
 @pytest.mark.parametrize("causal", [False, True])
 def test_sequence_parallel_attention(qkv, impl, causal):

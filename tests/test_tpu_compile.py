@@ -135,6 +135,28 @@ def test_flash_backward_lowers_each_kernel_once(one_chip):
             for name in ("flash_fwd", "flash_dkv", "flash_dq")] == [1, 1, 1]
 
 
+@pytest.mark.parametrize("shape,window", [
+    ((1, 8192, 48, 128), 4096),  # trinity-train-s8192: a far tile of 4 x 4
+    ((1, 8192, 48, 128), 1000),  # both edges inside one fetch tile
+    ((4, 4096, 32, 128), 4000),  # an edge between two sub-tiles
+], ids=lambda s: "x".join(map(str, s)) if isinstance(s, tuple) else f"w{s}")
+def test_windowed_flash_attention_compiles_under_its_own_names(
+        one_chip, shape, window):
+    """The far edge's masks (a segment with an upper bound, one with both),
+    the index maps clamped from both sides and the second straddling branch
+    are what Mosaic could refuse; the three windowed kernels are named
+    apart from the plain ones."""
+    grads = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, interpret=False).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+    lowered = jax.jit(grads).lower(*_flash_shapes(shape, 128, one_chip))
+    text = lowered.as_text()
+    assert [text.count(f'kernel_name = "{name}"') for name in (
+        "flash_fwd_win", "flash_dkv_win", "flash_dq_win", "flash_fwd",
+        "flash_dkv", "flash_dq")] == [1, 1, 1, 0, 0, 0]
+    assert _has_kernel(lowered.compile())
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],
                          ids=["gate_up", "down"])

@@ -1,0 +1,396 @@
+"""Trinity-Large-Preview's mechanisms at CPU size in float32: a window in
+three attention layers of four (rotary positions in those alone), an output
+gate on the attention, four norms a layer, sigmoid-scored experts with a
+selection bias beside a shared expert behind one leading dense layer, an
+untied head over a vocabulary slice and a held share — the program
+(``ray_tpu/models/llama.py`` and its blocks) against the benchmark's plain
+reference (``benchmark/reference/afmoe.py``: nothing shared with the code
+under test) on seeded weights."""
+
+import dataclasses
+import functools
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.loops import train
+from benchmark.reference import afmoe
+from ray_tpu.models.blocks import MIXERS, attention as attention_block
+from ray_tpu.models.llama import (
+    LlamaConfig, forward, init_params, loss_and_counts, loss_fn)
+from ray_tpu.ops.attention import causal_tile_counts, choose_tiles
+from ray_tpu.ops.moe import moe_block
+from ray_tpu.train.core import (
+    default_optimizer, init_train_state, make_train_step)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+NAME = "trinity-large-preview-1of32"
+S, F = "sliding_attention", "full_attention"
+PATTERN = (S, S, S, F, S)      # the file's: one dense layer, then s s f s
+WINDOW, SEQ = 16, 48           # a third of each later row's keys cut off
+# the reference's configuration (public key names) of the tiny model below
+CONF = dict(
+    layer_types=list(PATTERN), num_hidden_layers=5, num_dense_layers=1,
+    num_attention_heads=4, num_key_value_heads=2, hidden_size=64,
+    rope_theta=10000, rms_norm_eps=1e-5, sliding_window=WINDOW,
+    num_experts_per_tok=2, route_scale=2.448, first_expert=4,
+    mup_enabled=True)
+
+
+def tiny(**kw) -> LlamaConfig:
+    fields = dict(
+        vocab_size=128, embed_dim=64, num_layers=5, num_heads=4,
+        num_kv_heads=2, head_dim=16, mlp_dim=32, dense_mlp_dim=96,
+        max_seq_len=64, dtype=jnp.float32, remat=False,
+        attn_impl="reference", rope_theta=1e4, norm_eps=1e-5,
+        layer_types=PATTERN, sliding_window=WINDOW, attn_output_gate=True,
+        block_norm="sandwich", post_norm_init=0.25,
+        position_embedding="rope_windowed",
+        qk_head_norm=True, embedding_multiplier=8.0, embed_init_std=0.125,
+        num_experts=16, num_selected=2, norm_topk_prob=True,
+        topk_norm_eps=1e-20, experts_held=4, first_expert=4,
+        shared_experts=1, router_scoring="sigmoid", topk_method="noaux_tc",
+        routed_scaling_factor=2.448, leading_dense=1, aux_loss_coef=0.0)
+    fields.update(kw)
+    return LlamaConfig(**fields)
+
+
+def seeded(cfg, seed=0):
+    """Parameters whose norm weights are drawn away from 1, as the train
+    loop draws them for its check."""
+    rng = np.random.default_rng(seed)
+
+    def drawn(path, a):
+        if not str(getattr(path[-1], "key", "")).endswith("norm"):
+            return a
+        return a * rng.uniform(0.5, 1.5, a.shape).astype(np.float32)
+
+    return jax.tree_util.tree_map_with_path(
+        drawn, init_params(jax.random.PRNGKey(seed), cfg))
+
+
+TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ + 1), 0, 128)
+
+
+def _program_loss(cfg, params):
+    return jax.jit(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg))(params)
+
+
+def _apart(ours, theirs):
+    return jax.tree.map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b))
+                           / (jnp.max(jnp.abs(b)) + 1e-12)), ours, theirs)
+
+
+# -- the model against the reference -------------------------------------------
+
+def test_four_kinds_of_layer_hold_only_their_tensors():
+    cfg = tiny()
+    assert cfg.kind_runs == (((S, "dense"), 1), ((S, "moe"), 2),
+                             ((F, "moe"), 1), ((S, "moe"), 1))
+    assert afmoe.kinds(CONF) == cfg.layer_kinds
+    layers = init_params(jax.random.PRNGKey(0), cfg)["layers"]
+    mixer = ["attn_norm", "attn_post_norm", "wq", "wk", "wv", "wo", "q_norm",
+             "k_norm", "wg"]
+    assert list(layers[0]) == mixer + [
+        "mlp_norm", "mlp_post_norm", "w_gate", "w_up", "w_down"]
+    for run, n in ((1, 2), (2, 1), (3, 1)):
+        assert list(layers[run]) == mixer + [
+            "mlp_norm", "mlp_post_norm", "router", "w_gate", "w_up",
+            "w_down", "router_bias", "shared_gate", "shared_up",
+            "shared_down"]
+        assert layers[run]["w_gate"].shape == (n, 4, 64, 32)
+        assert layers[run]["router"].shape == (n, 64, 16)
+        assert layers[run]["wg"].shape == (n, 64, 64)
+        assert layers[run]["q_norm"].shape == (n, 16)
+    # the scaled embedding starts at unit RMS, the second norms below 1
+    embed = init_params(jax.random.PRNGKey(0), cfg)["embed"]
+    assert float(jnp.std(embed)) * 8.0 == pytest.approx(1.0, abs=0.05)
+    for run in layers:
+        assert np.all(np.asarray(run["attn_norm"]) == 1.0)
+        assert np.all(np.asarray(run["mlp_post_norm"]) == 0.25)
+
+
+@pytest.mark.parametrize("impl", ["reference", "flash-under-the-checkpoint"])
+def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
+    """Tolerances: both sides are float32, the program at XLA's default
+    matmul precision on the CPU (float32) and the reference at "highest";
+    what is left is the order of sums — 2e-5 relative on means of 96
+    tokens, 3e-5 nats on one token's loss, 1e-4 of a gradient's largest
+    entry (the selection is discrete: a swapped expert would read 1e-2 and
+    more).  Once with the XLA attention, once with the windowed flash
+    kernels (interpreted) under the layer checkpoint, the chip's path."""
+    cfg = tiny() if impl == "reference" else tiny(attn_impl="flash",
+                                                  remat=True)
+    params = seeded(cfg)
+    total, parts = _program_loss(cfg, params)
+    want = afmoe.loss_parts(params, TOKENS, CONF)
+    np.testing.assert_allclose(total, want["total"], rtol=2e-5)
+    np.testing.assert_allclose(parts["loss"], want["loss"], rtol=2e-5)
+    np.testing.assert_allclose(parts["moe_held_share"],
+                               want["moe_held_share"], rtol=1e-6)
+    assert 0.1 < float(parts["moe_held_share"]) < 0.5
+    assert float(parts["moe_dropped"]) == 0.0
+    assert len(want["experts"]) == 4
+    logits, _ = forward(params, TOKENS[:, :-1], cfg)
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
+                               TOKENS[:, 1:, None], -1)[..., 0]
+    np.testing.assert_allclose(nll, want["token_nll"], atol=3e-5)
+    ours = jax.grad(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(params)
+    theirs = jax.grad(lambda p: afmoe.loss(p, TOKENS, CONF))(params)
+    apart = _apart(ours, theirs)
+    assert max(jax.tree.leaves(apart)) < 1e-4, apart
+    # every tensor of every kind of layer has a gradient but the selection
+    # bias, which no gradient reaches
+    for run in ours["layers"]:
+        for name, g in run.items():
+            assert np.any(np.asarray(g)) == (name != "router_bias"), name
+
+
+@functools.lru_cache(maxsize=None)
+def _sound():
+    """The tiny model, its seeded parameters and the reference's per-token
+    losses on them, once for every case below."""
+    cfg = tiny()
+    params = seeded(cfg)
+    return cfg, params, afmoe.loss_parts(params, TOKENS, CONF)["token_nll"]
+
+
+def _token_nll(cfg, params):
+    logits, _ = forward(params, TOKENS[:, :-1], cfg)
+    return -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
+                                TOKENS[:, 1:, None], -1)[..., 0]
+
+
+@pytest.mark.parametrize("change", [
+    "rope-in-the-full-layer-too", "no-rope-in-the-windowed-layers",
+    "rope-in-the-full-layer-alone", "no-window", "window-one-short",
+    "no-output-gate", "no-post-norms", "no-head-norm", "no-embedding-scale",
+    "no-bias", "no-route-scale", "no-shared-expert"])
+def test_a_changed_part_stands_apart_from_the_reference(change):
+    """Each structural point of the configuration, got wrong in the
+    program, moves a token's loss by more than a thousandth of a nat (the
+    sound program stands 3e-5 off at most): the position signal flipped in
+    EITHER kind of layer, the window dropped or a key short, the gate, the
+    second norms, the per-head norm, muP's factor, the selection bias,
+    ``route_scale``, the shared expert."""
+    cfg, params, want = _sound()
+    wrong, p = cfg, params
+    if change == "rope-in-the-full-layer-too":
+        wrong = dataclasses.replace(cfg, position_embedding="rope")
+    elif change == "no-rope-in-the-windowed-layers":
+        wrong = dataclasses.replace(cfg, position_embedding="nope")
+    elif change == "rope-in-the-full-layer-alone":
+        class Flipped(LlamaConfig):
+            def rotary(self, windowed):
+                return not windowed
+        wrong = Flipped(**{f.name: getattr(cfg, f.name)
+                           for f in dataclasses.fields(cfg)})
+    elif change == "no-window":
+        wrong = dataclasses.replace(cfg, sliding_window=SEQ)
+    elif change == "window-one-short":
+        wrong = dataclasses.replace(cfg, sliding_window=WINDOW - 1)
+    elif change == "no-output-gate":
+        wrong = dataclasses.replace(cfg, attn_output_gate=False)
+    elif change == "no-post-norms":
+        wrong = dataclasses.replace(cfg, block_norm="input")
+    elif change == "no-head-norm":
+        wrong = dataclasses.replace(cfg, qk_head_norm=False)
+    elif change == "no-embedding-scale":
+        wrong = dataclasses.replace(cfg, embedding_multiplier=1.0)
+    elif change == "no-bias":
+        wrong = dataclasses.replace(cfg, topk_method="greedy")
+    elif change == "no-route-scale":
+        wrong = dataclasses.replace(cfg, routed_scaling_factor=1.0)
+    elif change == "no-shared-expert":
+        wrong = dataclasses.replace(cfg, shared_experts=0)
+    np.testing.assert_allclose(_token_nll(cfg, params), want, atol=3e-5)
+    assert float(jnp.max(jnp.abs(_token_nll(wrong, p) - want))) > 1e-3
+
+
+def test_rotary_positions_follow_the_kind_of_layer():
+    """The rule is stated once: ``LlamaConfig.rotary``.  A windowed layer
+    alone moves when the sequence is shifted under it... seen directly: with
+    the same tensors, a full layer's output at a position does not depend
+    on where the sequence starts, a windowed layer's q and k do."""
+    cfg = tiny()
+    assert (cfg.rotary(True), cfg.rotary(False)) == (True, False)
+    for name, flags in (("rope", (True, True)), ("nope", (False, False))):
+        other = dataclasses.replace(cfg, position_embedding=name)
+        assert (other.rotary(True), other.rotary(False)) == flags
+    with pytest.raises(ValueError):
+        tiny(position_embedding="alibi")
+    with pytest.raises(ValueError):
+        tiny(sliding_window=0)
+    assert MIXERS[S] is attention_block.SLIDING
+    assert MIXERS[F] is attention_block.SOFTMAX is MIXERS["attention"]
+
+
+# -- the shares ----------------------------------------------------------------
+
+def _expert_layer(seed=3, tokens=96, d=32, m=16, experts=32):
+    rng = np.random.default_rng(seed)
+    n = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa
+    return {"x": n(tokens, d), "mlp_norm": 1.0 + 0.1 * n(d),
+            "mlp_post_norm": 1.0 + 0.1 * n(d),
+            "router": n(d, experts) * d ** -0.5,
+            "router_bias": 0.02 * n(experts),
+            "w_gate": n(experts, d, m) * d ** -0.5,
+            "w_up": n(experts, d, m) * d ** -0.5,
+            "w_down": n(experts, m, d) * m ** -0.5,
+            "shared_gate": n(d, m) * d ** -0.5,
+            "shared_up": n(d, m) * d ** -0.5,
+            "shared_down": n(m, d) * m ** -0.5}
+
+
+def _share(p, first, held):
+    return moe_block(
+        p["x"], p["mlp_norm"], p["router"], *(
+            p[w][first:first + held] for w in ("w_gate", "w_up", "w_down")),
+        num_selected=4, norm_eps=1e-5, norm_topk_prob=True,
+        topk_norm_eps=1e-20, scoring="sigmoid", gate_scale=2.448,
+        select_bias=p["router_bias"], first_expert=first, residual=False)
+
+
+def test_the_32_shares_add_up_to_the_uncut_layer_before_its_last_norm():
+    """32 chips with ONE of 32 experts each (the file's 32 chips a layer):
+    their experts' parts and the shared expert ONCE, summed BEFORE
+    ``n_post_mlp`` — a norm is not linear: the normed parts do not add up
+    — are the whole layer as the reference has it."""
+    p = _expert_layer()
+    parts = [_share(p, first, 1) for first in range(32)]
+    h = afmoe.rms_norm(p["x"], p["mlp_norm"], 1e-5)
+    whole, chosen = afmoe.expert_ffn(h[None], p, k=4, scale=2.448, first=0)
+    shared = afmoe.swiglu(h, p["shared_gate"], p["shared_up"],
+                          p["shared_down"])
+    summed = sum(part for part, _ in parts) + shared
+    np.testing.assert_allclose(summed, whole[0], atol=2e-5)
+    post = lambda y: afmoe.rms_norm(y, p["mlp_post_norm"], 1e-5)  # noqa: E731
+    np.testing.assert_allclose(post(summed), post(whole[0]), atol=2e-5)
+    normed_parts = sum(post(part) for part, _ in parts) + post(shared)
+    assert float(jnp.max(jnp.abs(normed_parts - post(whole[0])))) > 1.0
+    stats = [s for _, s in parts]
+    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
+    assert all(float(s["dropped"]) == 0.0 for s in stats)
+    assert int(jnp.sum(stats[0]["counts"])) == 96 * 4
+    np.testing.assert_array_equal(
+        stats[0]["counts"], np.bincount(np.asarray(chosen).ravel(),
+                                        minlength=32))
+    # one share alone is the reference's with the same expert held, less
+    # the shared expert the reference adds
+    alone, _ = afmoe.expert_ffn(
+        h[None], {**p, **{w: p[w][7:8] for w in ("w_gate", "w_up",
+                                                  "w_down")}},
+        k=4, scale=2.448, first=7)
+    np.testing.assert_allclose(parts[7][0] + shared, alone[0], atol=2e-5)
+    # the gates sum to route_scale
+    gates, _ = afmoe.route(h, p["router"], p["router_bias"], 4, 2.448)
+    np.testing.assert_allclose(jnp.sum(gates, -1), 2.448, rtol=1e-6)
+
+
+def test_the_programs_expert_layer_norms_the_sum_once():
+    """The layer as the decoder composes it (``blocks/ffn.py``): x +
+    n_post_mlp(experts' part + shared expert)."""
+    from ray_tpu.models.blocks import FFNS
+    from ray_tpu.models.blocks.base import Ctx
+
+    cfg = tiny(embed_dim=32, mlp_dim=16, num_experts=32, experts_held=8,
+               first_expert=8, num_selected=4)
+    p = _expert_layer()
+    lp = {k: v for k, v in p.items() if k != "x"}
+    lp.update({w: p[w][8:16] for w in ("w_gate", "w_up", "w_down")})
+    x = p["x"][None]
+    out, _, counts = FFNS["moe"].apply(
+        Ctx(cfg, None, lambda a, _: a, False), x,
+        {k: jnp.zeros(()) for k in (
+            "aux_loss", *FFNS["moe"].stats(cfg))}, lp)
+    h = afmoe.rms_norm(p["x"], p["mlp_norm"], 1e-5)
+    want, _ = afmoe.expert_ffn(h[None], lp, k=4, scale=2.448, first=8)
+    np.testing.assert_allclose(
+        out, x + afmoe.rms_norm(want, p["mlp_post_norm"], 1e-5), atol=2e-5)
+    assert counts.shape == (32,)
+
+
+# -- the statistic and the train step ------------------------------------------
+
+def test_the_window_statistic_is_the_schedules_count():
+    """``attn_window_executed_share``: the flash schedule's executed pairs
+    over the pairs the window leaves, a ``max`` over the windowed layers —
+    1.0624 at the cell's 8192 under 4096 (``causal_tile_counts``)."""
+    cfg = tiny(attn_impl="flash")
+    _, (metrics, _) = loss_and_counts(seeded(cfg), {"tokens": TOKENS}, cfg)
+    tiles = choose_tiles(SEQ, SEQ, True, 16, jnp.float32, window=WINDOW)
+    n = causal_tile_counts(SEQ, SEQ, *tiles, window=WINDOW)
+    assert n["causal_pairs"] == 16 * 17 // 2 + 32 * 16
+    assert float(metrics["attn_window_executed_share"]) == pytest.approx(
+        n["executed_pairs"] / n["causal_pairs"])
+    # the XLA form computes the whole square
+    _, (metrics, _) = loss_and_counts(seeded(tiny()), {"tokens": TOKENS},
+                                      tiny())
+    assert float(metrics["attn_window_executed_share"]) == pytest.approx(
+        SEQ * SEQ / n["causal_pairs"])
+    big = causal_tile_counts(8192, 8192, *choose_tiles(
+        8192, 8192, True, 128, jnp.bfloat16, window=4096), window=4096)
+    assert big["causal_pairs"] == 25167872
+    assert big["executed_pairs"] / big["causal_pairs"] == pytest.approx(
+        1.0624, abs=1e-4)
+
+
+def test_the_train_step_runs_the_windowed_kernels_and_reports():
+    cfg = tiny(attn_impl="flash", remat=True)
+    opt = default_optimizer()
+    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
+    before = jax.tree.map(np.asarray, state.params)
+    step = make_train_step(cfg, opt, donate=False)
+    text = step.lower(state, {"tokens": TOKENS}).as_text(debug_info=True)
+    for name in ("flash_fwd_win", "flash_dq_win", "flash_dkv_win",
+                 "flash_fwd", "attn_qkv/", "attn_out/", "moe_experts/",
+                 "moe_combine/", "ffn/"):
+        assert name in text, name
+    state, metrics = step(state, {"tokens": TOKENS})
+    assert {"attn_window_executed_share", "moe_held_share", "moe_dropped",
+            "moe_rows_visited_share", "moe_load_max_over_mean"
+            } <= set(metrics)
+    assert float(metrics["moe_dropped"]) == 0.0
+    assert np.isfinite(float(metrics["loss"]))
+    for run in (1, 2, 3):
+        moved = np.asarray(state.params["layers"][run]["router_bias"]) \
+            - before["layers"][run]["router_bias"]
+        assert np.all((moved == 0) | np.isclose(
+            np.abs(moved), cfg.bias_update_speed, atol=1e-7))
+
+
+def test_a_window_is_refused_where_no_kernel_takes_one():
+    for impl in ("ring", "ulysses"):
+        with pytest.raises(NotImplementedError):
+            attention_block._attention(
+                None, None, None, tiny(attn_impl=impl), object(), window=8)
+    with pytest.raises(NotImplementedError):
+        tiny(layer_types=("mamba",) * 5)
+    with pytest.raises(NotImplementedError):
+        tiny(block_norm="output")       # the expert layer norms inside
+
+
+# -- the configuration file ----------------------------------------------------
+
+def test_the_files_fields_reach_the_program_and_its_traffic_stays_in_the_slice():
+    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
+        conf = json.load(f)
+    cfg = train.program_config(conf)
+    assert (cfg.vocab_size, cfg.num_experts, cfg.experts_held,
+            cfg.first_expert, cfg.leading_dense, cfg.num_layers) == (
+                25024, 256, 8, 0, 1, 5)
+    assert (cfg.head_dim, cfg.sliding_window, cfg.attn_output_gate,
+            cfg.block_norm, cfg.position_embedding, cfg.qk_head_norm) == (
+                128, 4096, True, "sandwich", "rope_windowed", True)
+    assert cfg.select_bias and cfg.router_scoring == "sigmoid"
+    assert [n for _, n in cfg.kind_runs] == [1, 2, 1, 1]
+    assert afmoe.kinds(conf) == cfg.layer_kinds
+    drawn = train.draw_tokens(np.random.default_rng([2**31 + 5, 0]), cfg, 1,
+                              8192)
+    assert drawn.shape == (1, 8193) and drawn.dtype == np.int32
+    assert 0 <= drawn.min() and 24000 < drawn.max() < 25024

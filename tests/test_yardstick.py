@@ -1,0 +1,17 @@
+"""The yardstick's own chip-free cases, collected in tier-1 so that the next
+rot of ``benchmark/tests`` shows in the driver's run: every case
+``benchmark/tests/tier1_cases.py`` lists (what needs no chip, no train loop
+and no compile), and the Trinity cell's, by name.  No assertion lives here."""
+
+import pytest
+
+pytest.register_assert_rewrite("benchmark.tests.test_trinity")
+
+from benchmark.tests.tier1_cases import *  # noqa: E402,F401,F403
+from benchmark.tests.test_trinity import (  # noqa: E402,F401
+    test_flops_count_the_windows_pairs_not_the_causal_ones,
+    test_on_a_program_without_the_window_the_readers_return_nothing,
+    test_the_cell_its_job_and_its_metrics as test_trinity_cell_job_and_metrics,
+    test_the_file_is_the_catalog_row_cut_to_one_chip_of_thirty_two,
+    test_the_parameter_count_is_init_params as test_trinity_parameter_count,
+    test_window_readers_on_synthetic_planes)
