@@ -148,7 +148,11 @@ def _attention(q, k, v, cfg, mesh, window=None):
     # kernel per-shard: batch over (dp,fsdp,ep), heads over tp, seq replicated.
     # Manual over EVERY mesh axis — the TPU lowering refuses a Mosaic
     # kernel in a region that leaves any axis to the partitioner.
-    k, v = repeat_kv_heads(q, k, v)
+    # k and v go in with their own heads (the kernels find a q head's KV
+    # head themselves) wherever 'tp' divides them: a rank's q heads are
+    # then whole groups.  Where it does not, they are repeated first.
+    if k.shape[2] % mesh.shape[AXIS_TP]:
+        k, v = repeat_kv_heads(q, k, v)
     spec = P(BATCH_AXES, None, AXIS_TP, None)
     fn = manual_shard_map(
         lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True,

@@ -2,11 +2,28 @@
 
 Design (standard memory-efficient attention, mapped to the TPU grid model):
 
-- Layout: kernels run on ``(batch, heads, seq, head_dim)`` so every block's
-  minor two dims are ``(block_seq, head_dim)`` — Mosaic requires the minor
-  dims of a block to be (8, 128)-tile friendly or equal to the array dims;
-  the model-side ``(b, s, h, d)`` tensors are transposed at the call
-  boundary (XLA fuses the transpose into neighbouring ops).
+- Layout: a block's minor two dims are ``(block_seq, head_dim)`` — Mosaic
+  wants them (8, 128)-tile friendly or equal to the array's —, and the
+  kernels take their operands in ONE OF TWO ADDRESSINGS, chosen from the
+  call's shapes alone (``_in_place``; ``_grid_and_specs``).  Where a head
+  fills whole lane blocks (``d % 128 == 0`` for q/k's head and v's) they
+  read q, k, v — and write o, dq, dk, dv — IN the model's own ``(b, s,
+  heads x d)``, the free reshape of the ``(b, s, h, d)`` the mixer holds: a
+  head is the lane block ``(rows, d)`` at ``(b_, tile, head)``, 256 B a row
+  of 128 bf16 lanes, rows ``heads x d`` elements apart; Mosaic's DMA hides
+  the stride (PERF.md §6, PR 54: the three kernels' sum within 1.4 % of
+  the same calls on contiguous heads, and under the parent's).  Else (a head of 64 lanes, a latent mixer's
+  192 / 128, the tests' tiny heads) the operands are turned round to
+  ``(b, heads, s, d)`` at the call's boundary.  XLA does NOT fuse those
+  transposes into its neighbours: the traces read 16-22 ms a step of
+  copies round the calls in the cells of 128-lane heads, which is why the
+  first addressing exists.  The per-row stats are the kernels' own in both.
+- Grouped queries: k and v come with their own ``h_kv`` heads and nothing
+  repeats them.  ``flash_fwd`` and ``flash_dq`` read KV head ``h // rep``
+  by the index map; ``flash_dkv`` runs over the KV heads, its sequential
+  axis walking the ``rep`` q heads of the group and each head's q tiles,
+  so ``dk`` / ``dv`` are summed over the group in float32 in VMEM and
+  leave the kernel once a KV head.
 - Forward: grid ``(batch, heads, q_blocks, kv_blocks)``.  The last grid
   dimension is sequential on TPU, so softmax running stats ``(m, l)`` and the
   output accumulator live in VMEM scratch that persists across kv iterations;
@@ -16,9 +33,10 @@ Design (standard memory-efficient attention, mapped to the TPU grid model):
   column rides in full vector registers (the layout jax's own TPU
   flash-attention kernel uses for its ``l``/``m`` outputs).
 - Backward: two kernels (the classic split): one accumulates ``dk, dv`` with
-  grid ``(b, h, kv_blocks, q_blocks)``, one accumulates ``dq`` with grid
-  ``(b, h, q_blocks, kv_blocks)``; both recompute ``p = exp(s - lse)`` from
-  the saved per-row logsumexp instead of materializing the S x S matrix.
+  grid ``(b, h_kv, kv_blocks, rep x q_blocks)``, one accumulates ``dq``
+  with grid ``(b, h, q_blocks, kv_blocks)``; both recompute ``p = exp(s -
+  lse)`` from the saved per-row logsumexp instead of materializing the S x
+  S matrix.
   The dk/dv kernel keeps its scores TRANSPOSED, ``s^T = k q^T`` with the q
   rows along the lanes, so its two gradient products are plain ones and
   its per-row stats are rows ``(b, h, 1, sq)``, not lane-replicated.
@@ -346,8 +364,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def update(qs, ks, mask):
-        s = _scores(q_ref[0, 0, qs], k_ref[0, 0, ks], mask)   # f32
-        v = v_ref[0, 0, ks]
+        s = _scores(q_ref[qs], k_ref[ks], mask)   # f32
+        v = v_ref[ks]
         m_prev = m_scr[qs]                           # (sq, LANES) replicated
         m_cur = jnp.max(s, axis=-1, keepdims=True)   # (sq, 1)
         m_next = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
@@ -366,23 +384,54 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(ki == nk - 1)
     def _finalize():
         l = l_scr[...]
-        o_ref[0, 0] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[...] + jnp.log2(l)   # log2-domain lse
+        o_ref[...] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
+        lse_ref[...] = m_scr[...] + jnp.log2(l)   # log2-domain lse
 
 
-def _grid_and_specs(qt, kt, vt, causal, tiles, window=None):
+def _dims(qt, kt, vt, heads):
+    """``(b, h, h_kv, sq, sk, d, dv)`` of a call's operands in either
+    addressing: ``heads`` is None for ``(b, heads, s, d)`` operands, q's and
+    kv's head counts ``(h, h_kv)`` for ``(b, s, heads x d)`` ones."""
+    if heads is None:
+        (b, h, sq, d), (_, h_kv, sk, _) = qt.shape, kt.shape
+        return b, h, h_kv, sq, sk, d, vt.shape[3]
+    h, h_kv = heads
+    (b, sq, width), sk = qt.shape, kt.shape[1]
+    return b, h, h_kv, sq, sk, width // h, vt.shape[2] // h_kv
+
+
+def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
     """``(nq, nk)`` tiles of the call and the BlockSpecs of the q-side and
     kv-side operands for both grid orders: ``q_i, k_j, row_i`` for grids
-    ``(b, h, q, kv)`` and ``q_j, k_i, stat_j`` for ``(b, h, kv, q)``
-    (``row``: per-row stats lane-replicated ``(b, h, sq, LANES)``;
+    ``(b, h, q, kv)`` and ``q_j, k_i, stat_j`` for ``(b, h_kv, kv, rep x
+    q)`` (``row``: per-row stats lane-replicated ``(b, h, sq, LANES)``;
     ``stat``: the same stats as rows ``(b, h, 1, sq)``).
+
+    TWO ADDRESSINGS of q, k, v, o and their gradients, one body a kernel
+    (every leading dimension of a block is squeezed: a ref is ``(rows,
+    width)``).  ``heads=None``: the operands are ``(b, heads, s, d)`` and
+    a block is ``(rows, d)`` at ``(b_, head, tile, 0)``.  ``heads=(h,
+    h_kv)``: they are the model's own ``(b, s, heads x d)`` and a head is a
+    LANE BLOCK, ``(rows, d)`` at ``(b_, tile, head)``: its rows lie ``heads
+    x d`` elements apart, and nothing is transposed round the call.  The
+    stats are the kernels' own in both.
+
+    A KV head serves ``rep = h // h_kv`` q heads BY THE INDEX MAP: the
+    q-ordered grids read kv head ``h_ // rep``; the kv-ordered grid runs
+    over the KV heads, and its sequential axis ``t`` walks the group's q
+    heads and each head's q tiles (q head ``g x rep + t // nq``, q tile
+    ``t % nq``), so ``dk`` and ``dv`` leave the kernel once a KV head.  At
+    ``rep == 1`` the maps are what they were.
+
     q and k have one head size, v (and with it o and do: ``o_i``, ``v_j``,
     ``v_i``, ``o_j``) may have another; where the two are equal the specs
     are.  Under the mask a dead grid step names the block its nearest live
     step holds, which Pallas does not copy again: past the diagonal and,
     under a ``window``, before the far edge."""
     block_q, block_k = tiles[:2]
-    nq, nk = qt.shape[2] // block_q, kt.shape[2] // block_k
+    _, h, h_kv, sq, sk, d, dv = _dims(qt, kt, vt, heads)
+    nq, nk = sq // block_q, sk // block_k
+    rep = h // h_kv
     if causal:
         def inner_k(i, j):
             j = jnp.minimum(j, _last_live_k(i, block_q, block_k))
@@ -399,21 +448,45 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None):
     else:
         inner_k = inner_q = lambda i, j: j
 
-    def spec(block, width, index):
-        return pl.BlockSpec((1, 1, block, width),
-                            lambda b_, h_, i, j: (b_, h_, index(i, j), 0))
+    # (head, tile) a grid step names, by operand and grid order: (h_, i, j)
+    # of the q-ordered grids, (g, i, t) of the kv-ordered one
+    if rep == 1:
+        kv_head = lambda h_: h_
+        walk = lambda g, t: (g, t)
+    else:
+        kv_head = lambda h_: h_ // rep
+        walk = lambda g, t: (g * rep + t // nq, t % nq)
+    outer = lambda h_, i, j: (h_, i)
+    k_inner = lambda h_, i, j: (kv_head(h_), inner_k(i, j))
 
-    outer = lambda i, j: i
-    d, dv = qt.shape[3], vt.shape[3]
+    def q_inner(g, i, t):
+        head, j = walk(g, t)
+        return head, inner_q(i, j)
+
+    def spec(block, width, at):
+        if heads is None:
+            return pl.BlockSpec(
+                (None, None, block, width),
+                lambda b_, h_, i, j: (b_, *at(h_, i, j), 0))
+        return pl.BlockSpec(
+            (None, block, width),
+            lambda b_, h_, i, j: (b_, *at(h_, i, j)[::-1]))
+
+    def stat_at(g, i, t):
+        head, tile = q_inner(g, i, t)
+        return head, 0, tile
+
     return (nq, nk), {
-        "q_i": spec(block_q, d, outer), "row_i": spec(block_q, _LANES, outer),
-        "o_i": spec(block_q, dv, outer),
-        "k_j": spec(block_k, d, inner_k), "v_j": spec(block_k, dv, inner_k),
+        "q_i": spec(block_q, d, outer), "o_i": spec(block_q, dv, outer),
+        "k_j": spec(block_k, d, k_inner), "v_j": spec(block_k, dv, k_inner),
         "k_i": spec(block_k, d, outer), "v_i": spec(block_k, dv, outer),
-        "q_j": spec(block_q, d, inner_q), "o_j": spec(block_q, dv, inner_q),
+        "q_j": spec(block_q, d, q_inner), "o_j": spec(block_q, dv, q_inner),
+        "row_i": pl.BlockSpec(
+            (None, None, block_q, _LANES),
+            lambda b_, h_, i, j: (b_, h_, i, 0)),
         "stat_j": pl.BlockSpec(
-            (1, 1, 1, block_q),
-            lambda b_, h_, i, j: (b_, h_, 0, inner_q(i, j))),
+            (None, None, 1, block_q),
+            lambda b_, h_, i, j: (b_, *stat_at(h_, i, j))),
     }
 
 
@@ -424,14 +497,16 @@ def _kernel_name(name: str, window) -> str:
     return name if window is None else name + "_win"
 
 
-def _fwd_call(qt, kt, vt, causal, tiles, interpret, window=None):
-    """qt/kt: (b, h, s, d), vt: (b, h, s, dv); qt PRE-SCALED by
-    sm_scale*log2e.  Returns (o_t, lse) with o_t (b, h, sq, dv) and lse
-    (b, h, sq, LANES) lane-replicated f32 in the log2 domain."""
-    b, h, sq, _ = qt.shape
-    dv = vt.shape[3]
+def _fwd_call(qt, kt, vt, causal, tiles, interpret, window=None, heads=None):
+    """qt, kt, vt in either addressing (``_grid_and_specs``); qt PRE-SCALED
+    by sm_scale*log2e.  Returns (o_t, lse) with o_t addressed as qt, v's
+    head size wide, and lse (b, h, sq, LANES) lane-replicated f32 in the
+    log2 domain."""
+    b, h, _, sq, _, _, dv = _dims(qt, kt, vt, heads)
     block_q = tiles[0]
-    (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window)
+    (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window,
+                                      heads)
+    o_shape = (b, h, sq, dv) if heads is None else (b, sq, h * dv)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, tiles=tiles,
                           grid_qk=(nq, nk), window=window),
@@ -439,7 +514,7 @@ def _fwd_call(qt, kt, vt, causal, tiles, interpret, window=None):
         in_specs=[specs["q_i"], specs["k_j"], specs["v_j"]],
         out_specs=[specs["o_i"], specs["row_i"]],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, dv), qt.dtype),
+            jax.ShapeDtypeStruct(o_shape, qt.dtype),
             jax.ShapeDtypeStruct((b, h, sq, _LANES), jnp.float32),
         ],
         scratch_shapes=[
@@ -473,12 +548,14 @@ def _p_and_ds(q, k, v, do, lse, delta, mask, transposed=False):
 
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dk_ref, dv_ref, dk_scr, dv_scr, *, causal, tiles, grid_qk,
-                 window=None):
-    ki, qi = pl.program_id(2), pl.program_id(3)
-    nq = pl.num_programs(3)
+                 window=None, rep=1):
+    # The sequential axis walks the ``rep`` q heads of this KV head, each
+    # head's q tiles in turn: dk and dv gather the whole group's parts.
+    ki, t = pl.program_id(2), pl.program_id(3)
+    qi = t if rep == 1 else t % grid_qk[0]
     sub_k = tiles[3]
 
-    @pl.when(qi == 0)
+    @pl.when(t == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -495,12 +572,11 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 return carry
 
             return jax.lax.fori_loop(0, ks.size // sub_k, strip, None)
-        q, do = q_ref[0, 0, qs], do_ref[0, 0, qs]
+        q, do = q_ref[qs], do_ref[qs]
         # Transposed, (sk, sq): p^T and ds^T are what the two gradient
         # products take on the left, so no matrix is turned round.
-        p, ds = _p_and_ds(q, k_ref[0, 0, ks], v_ref[0, 0, ks], do,
-                          lse_ref[0, 0, :, qs], delta_ref[0, 0, :, qs], mask,
-                          transposed=True)
+        p, ds = _p_and_ds(q, k_ref[ks], v_ref[ks], do, lse_ref[:, qs],
+                          delta_ref[:, qs], mask, transposed=True)
         # Grad matmuls in the INPUT dtype (bf16 on TPU): the MXU runs
         # bf16 natively; f32 operands would force multi-pass matmuls.
         dv_scr[ks] += jnp.dot(p.astype(do.dtype), do,
@@ -511,12 +587,12 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
                update, strips="k", window=window)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(t == pl.num_programs(3) - 1)
     def _finalize():
         # q arrives pre-scaled by c = sm_scale*log2e; the true gradient
         # is sm_scale * ds^T @ q_unscaled = ln2 * ds^T @ (q*c).
-        dk_ref[0, 0] = (dk_scr[...] * _LN2).astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[...] = (dk_scr[...] * _LN2).astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -531,10 +607,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     def update(qs, ks, mask):
-        k = k_ref[0, 0, ks]
-        _, ds = _p_and_ds(q_ref[0, 0, qs], k, v_ref[0, 0, ks],
-                          do_ref[0, 0, qs], lse_ref[0, 0, qs],
-                          delta_ref[0, 0, qs], mask)
+        k = k_ref[ks]
+        _, ds = _p_and_ds(q_ref[qs], k, v_ref[ks], do_ref[qs], lse_ref[qs],
+                          delta_ref[qs], mask)
         dq_scr[qs] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -544,27 +619,34 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        dq_ref[0, 0] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
+        dq_ref[...] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret,
-              window=None):
-    """qt/kt (b, h, s, d), vt/ot/dot (b, h, s, dv); lse (b, h, sq), a float
-    a row.  Returns transposed grads (dqt, dkt, dvt)."""
-    b, h, sq, d = qt.shape
-    dv = vt.shape[3]
+              window=None, heads=None):
+    """qt, kt, vt, ot, dot in either addressing (``_grid_and_specs``); lse
+    (b, h, sq), a float a row.  Returns (dqt, dkt, dvt), each addressed as
+    its operand: dkt and dvt at k's and v's OWN head count, summed over
+    each KV head's group of q heads inside ``flash_dkv``."""
+    b, h, h_kv, sq, _, d, dv = _dims(qt, kt, vt, heads)
     block_q, block_k = tiles[:2]
-    delta = jnp.sum(ot.astype(jnp.float32) * dot.astype(jnp.float32),
-                    axis=-1)                                 # (b, h, sq)
-    (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window)
+    if heads is None:
+        delta = jnp.sum(ot.astype(jnp.float32) * dot.astype(jnp.float32),
+                        axis=-1)                             # (b, h, sq)
+    else:   # summed where o stands: what is turned round is a float a row
+        delta = jnp.sum((ot.astype(jnp.float32) * dot.astype(jnp.float32)
+                         ).reshape(b, sq, h, dv), axis=-1).transpose(0, 2, 1)
+    (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window,
+                                      heads)
     q_i, k_j, row_i = specs["q_i"], specs["k_j"], specs["row_i"]
     q_j, k_i, stat_j = specs["q_j"], specs["k_i"], specs["stat_j"]
     o_i, v_j, o_j, v_i = (specs[n] for n in ("o_i", "v_j", "o_j", "v_i"))
 
+    rep = h // h_kv
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_kernel, causal=causal, tiles=tiles,
-                          grid_qk=(nq, nk), window=window),
-        grid=(b, h, nk, nq),
+                          grid_qk=(nq, nk), window=window, rep=rep),
+        grid=(b, h_kv, nk, rep * nq),
         in_specs=[q_j, k_i, v_i, o_j, stat_j, stat_j],
         out_specs=[k_i, v_i],
         out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
@@ -597,18 +679,49 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret,
 
 # ----------------------------------------------------------------- public
 
-def _to_bhsd(x):
+def _in_place(q, v) -> bool:
+    """Whether a head fills whole lane blocks, so the kernels can cut it out
+    of the model's ``(b, s, heads x d)`` by the index map alone."""
+    return q.shape[-1] % _LANES == 0 and v.shape[-1] % _LANES == 0
+
+
+def _enter(x, in_place: bool):
+    """The model's ``(b, s, heads, d)`` as the kernels address it: ``(b, s,
+    heads x d)`` where it stands (the reshape moves nothing), else turned
+    round to ``(b, heads, s, d)``."""
+    if in_place:
+        return x.reshape(*x.shape[:2], -1)
     return jnp.transpose(x, (0, 2, 1, 3))
+
+
+def _leave(x, heads: int):
+    """``_enter``'s inverse, for what a kernel wrote: three dimensions are
+    an array left in place, four one turned round."""
+    if x.ndim == 3:
+        return x.reshape(*x.shape[:2], heads, -1)
+    return jnp.transpose(x, (0, 2, 1, 3))
+
+
+def _forward(q, k, v, sm_scale, causal, tiles, interpret, window):
+    """``flash_fwd`` on the model's q, k, v: (q, k, v as the kernels took
+    them — the residuals of the backward pass —, o and lse as the kernel
+    wrote them)."""
+    in_place = _in_place(q, v)
+    heads = (q.shape[2], k.shape[2]) if in_place else None
+    # the pre-scale is XLA's: it rides in the fusion that writes q (RoPE's)
+    qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)
+    qt, kt, vt = (_enter(x, in_place) for x in (qs, k, v))
+    ot, lse = _fwd_call(qt, kt, vt, causal, tiles, interpret, window, heads)
+    return (qt, kt, vt), ot, lse
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, sm_scale, causal, tiles, interpret, window=None):
     """``tiles`` = (block_q, block_k, sub_q, sub_k): each sub divides its
-    block, each block its sequence."""
-    qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)
-    o, _ = _fwd_call(_to_bhsd(qs), _to_bhsd(k), _to_bhsd(v), causal,
-                     tiles, interpret, window)
-    return _to_bhsd(o)
+    block, each block its sequence.  k and v come with their OWN head
+    count, a divisor of q's."""
+    _, ot, _ = _forward(q, k, v, sm_scale, causal, tiles, interpret, window)
+    return _leave(ot, q.shape[2])
 
 
 # The residuals a layer checkpoint keeps (``models/llama.py`` hands these
@@ -618,21 +731,24 @@ SAVED_RESIDUALS = ("flash_out", "flash_lse")
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret, window=None):
-    qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)
-    qt, kt, vt = _to_bhsd(qs), _to_bhsd(k), _to_bhsd(v)
-    ot, lse = _fwd_call(qt, kt, vt, causal, tiles, interpret, window)
+    operands, ot, lse = _forward(q, k, v, sm_scale, causal, tiles, interpret,
+                                 window)
     ot = checkpoint_name(ot, "flash_out")
     # One lane of the 128 the kernel writes: a float a row is what is
     # worth holding; flash_dq gets the lanes back, flash_dkv takes rows.
     lse = checkpoint_name(lse[..., 0], "flash_lse")
-    return _to_bhsd(ot), (qt, kt, vt, ot, lse)
+    return _leave(ot, q.shape[2]), (*operands, ot, lse)
 
 
 def _flash_bwd(sm_scale, causal, tiles, interpret, window, res, do):
     qt, kt, vt, ot, lse = res
-    dqt, dkt, dvt = _bwd_call(qt, kt, vt, ot, lse, _to_bhsd(do), sm_scale,
-                              causal, tiles, interpret, window)
-    return _to_bhsd(dqt), _to_bhsd(dkt), _to_bhsd(dvt)
+    in_place = qt.ndim == 3     # the residuals' own shapes say how they stand
+    h = do.shape[2]
+    h_kv = kt.shape[2] * h // qt.shape[2] if in_place else kt.shape[1]
+    dqt, dkt, dvt = _bwd_call(qt, kt, vt, ot, lse, _enter(do, in_place),
+                              sm_scale, causal, tiles, interpret, window,
+                              (h, h_kv) if in_place else None)
+    return _leave(dqt, h), _leave(dkt, h_kv), _leave(dvt, h_kv)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -643,8 +759,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: int = MAX_BLOCK, block_k: int = MAX_BLOCK,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None) -> jax.Array:
-    """Memory-efficient MHA.  q: (b, sq, h, d); k: (b, sk, h, d); v:
-    (b, sk, h, dv), the output (b, sq, h, dv): v's head size may differ
+    """Memory-efficient MHA.  q: (b, sq, h, d); k: (b, sk, h_kv, d); v:
+    (b, sk, h_kv, dv), the output (b, sq, h, dv): v's head size may differ
     from q's and k's (a latent-attention mixer's 192 / 128).
 
     ``window`` (causal only): query ``i`` sees key ``j`` iff ``0 <= i - j <
@@ -652,8 +768,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     either edge (``flash_*_win``).  A window that reaches every key the
     diagonal leaves (``window >= sk``) cuts nothing: the plain kernels run.
 
-    Supports grouped-query attention: if k/v have fewer heads than q and
-    ``h % h_kv == 0``, kv heads are repeated (XLA fuses the broadcast).
+    Grouped-query attention: k and v may have fewer heads than q, ``h %
+    h_kv == 0``.  Nothing repeats them: the kernels find a q head's KV
+    head by the index map, and ``dk`` / ``dv`` come back at ``h_kv`` heads,
+    summed over each group in the kernel (``_grid_and_specs``).
     ``block_q``/``block_k`` are upper bounds of the fetch tile; the tile
     and the compute sub-tile follow the call's shapes (``choose_tiles``).
     """
@@ -661,8 +779,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         sm_scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = _interpret_default()
-    from ray_tpu.ops.layers import repeat_kv_heads
-    k, v = repeat_kv_heads(q, k, v)
+    if q.shape[2] % k.shape[2]:
+        raise ValueError(f"q heads {q.shape[2]} not a multiple of kv heads "
+                         f"{k.shape[2]}")
     window = live_window(window, k.shape[1], causal)
     tiles = choose_tiles(q.shape[1], k.shape[1], causal,
                          max(q.shape[-1], v.shape[-1]), q.dtype, block_q,
@@ -670,6 +789,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if tiles is None:
         # No block >= 8 tiles the sequence exactly: the XLA reference is
         # correct, at O(S^2) memory.
+        from ray_tpu.ops.layers import repeat_kv_heads
+        k, v = repeat_kv_heads(q, k, v)
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                              window=window)
     return _flash(q, k, v, sm_scale, causal, tiles, interpret, window)

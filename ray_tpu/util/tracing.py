@@ -586,10 +586,19 @@ def op_names(xplane: bytes) -> Dict[str, Dict[str, str]]:
 
 
 # The q and k operands of a flash kernel's custom call, as the HLO text
-# of its trace event gives them: ``dtype[b,h,sq,d]..., dtype[b,h,sk,d]``.
+# of its trace event gives them.  Turned round to the kernels' own layout:
+# ``dtype[b,h,sq,d]..., dtype[b,h_kv,sk,d]``.  Where the model leaves them
+# (a head of whole lane blocks, ``ops/attention.py``): ``dtype[b,sq,h x
+# d]..., dtype[b,sk,h_kv x d]``, and the q heads' count is that of the
+# call's float32 stats, ``f32[b,h,sq,128]`` or ``f32[b,h,1,sq]`` (a result
+# of ``flash_fwd``, operands of the other two).
 _FLASH_OPERANDS = re.compile(
     r"operand_layout_constraints=\{(\w+)\[\d+,\d+,(\d+),(\d+)\]\{[^}]*\}, "
     r"\w+\[\d+,\d+,(\d+),\d+\]")
+_FLASH_OPERANDS_IN_PLACE = re.compile(
+    r"operand_layout_constraints=\{(\w+)\[\d+,(\d+),(\d+)\]\{[^}]*\}, "
+    r"\w+\[\d+,(\d+),\d+\]\{")
+_FLASH_STATS = re.compile(r"f32\[\d+,(\d+),\d+,\d+\]")
 _HLO_DTYPES = {"bf16": "bfloat16", "f16": "float16", "f32": "float32"}
 
 
@@ -597,16 +606,25 @@ def flash_executed_over_causal(text: str) -> Optional[float]:
     """(q, k) pairs a flash kernel computes over the pairs the causal
     mask leaves, for the kernel whose custom call has the HLO ``text``:
     ``ops.attention.causal_tile_counts`` at the tile sizes this tree
-    picks for the operands' shapes (the step's attention is causal).
+    picks for the operands' shapes (the step's attention is causal), in
+    either of the two forms the kernels take their operands in.
     Static per shape: nothing is counted at run time.  None where the
     text names no such operands."""
     m = _FLASH_OPERANDS.search(text)
-    if m is None or m.group(1) not in _HLO_DTYPES:
+    if m is not None:
+        dtype, sq, d, sk = m.group(1), *map(int, m.groups()[1:])
+    else:
+        m = _FLASH_OPERANDS_IN_PLACE.search(text)
+        stats = _FLASH_STATS.search(text)
+        if m is None or stats is None:
+            return None
+        dtype, sq, width, sk = m.group(1), *map(int, m.groups()[1:])
+        d = width // int(stats.group(1))
+    if dtype not in _HLO_DTYPES:
         return None
     from ray_tpu.ops.attention import causal_tile_counts, choose_tiles
 
-    sq, d, sk = int(m.group(2)), int(m.group(3)), int(m.group(4))
-    tiles = choose_tiles(sq, sk, True, d, _HLO_DTYPES[m.group(1)])
+    tiles = choose_tiles(sq, sk, True, d, _HLO_DTYPES[dtype])
     if tiles is None:
         return None
     n = causal_tile_counts(sq, sk, *tiles)

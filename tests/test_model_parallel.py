@@ -165,6 +165,35 @@ def test_reference_attention_repeats_kv_heads_without_a_mesh():
     assert abs(float(got) - float(want)) < 1e-4
 
 
+@pytest.mark.parametrize("head_dim,kv_heads", [(128, 2), (16, 2), (16, 1)],
+                         ids=["in-place-own-heads", "turned-own-heads",
+                              "repeated"])
+def test_flash_under_tp_takes_kv_heads_as_they_are(head_dim, kv_heads):
+    """Under a mesh k and v enter the flash kernels' manual region with
+    their OWN heads where 'tp' divides them (a rank's q heads are whole
+    groups), repeated where it does not: loss and gradients are the
+    unsharded reference's either way, at a head the kernels read in place
+    and at one they turn round."""
+    kw = dict(num_heads=4, num_kv_heads=kv_heads, head_dim=head_dim)
+    cfg = LlamaConfig.tiny(attn_impl="flash", **kw)
+    params = init_params(KEY, cfg)
+    batch = _batch(cfg, b=4)
+    loss = lambda cfg, mesh=None: jax.value_and_grad(
+        lambda p: loss_fn(p, batch, cfg, mesh=mesh)[0])
+    want, want_g = loss(LlamaConfig.tiny(attn_impl="reference", **kw))(params)
+    mesh = make_mesh(MeshConfig(fsdp=2, tp=2), devices=jax.devices()[:4])
+    text = str(jax.make_jaxpr(loss(cfg, mesh))(params))
+    # a rank's k: a head of its own, or (repeated) one for each q head
+    own, repeated = (f"f32[2,32,{n},{head_dim}]" for n in (1, 2))
+    assert own in text if kv_heads == 2 else own not in text
+    assert text.count(repeated) > (0 if kv_heads == 2 else 4)
+    got, got_g = jax.jit(loss(cfg, mesh))(params)
+    assert abs(float(got) - float(want)) < 1e-4
+    worst = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))),
+                         got_g, want_g)
+    assert max(jax.tree.leaves(worst)) < 1e-4
+
+
 def _op_names(hlo_text, opcodes):
     """(opcode, op_name) of every instruction of these opcodes."""
     import re

@@ -6,6 +6,8 @@ Pallas kernels run in interpret mode on the CPU backend — same code path
 that compiles for TPU.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -357,6 +359,158 @@ def test_causal_tile_counts_at_the_windowed_cells_shape():
     short = choose_tiles(4096, 4096, True, 128, jnp.bfloat16)
     assert causal_tile_counts(4096, 4096, *short, window=4096) == \
         causal_tile_counts(4096, 4096, *short)
+
+
+# -- heads where the model leaves them, a KV head by the index map -----------
+
+# (rep, q/k head, v head, mode) on 128 positions in 64 x 64 tiles (a 2 x 2
+# grid with a dead tile under the mask: ``flash_dkv``'s composite axis of
+# ``rep x nq`` steps crosses both a head and a dead tile wherever rep > 1).
+# Head sizes 128 and 256 fill whole lane blocks and are read IN PLACE out
+# of ``(b, s, heads x d)``; 64 and a latent mixer's 192 / 128 are turned
+# round to ``(b, heads, s, d)`` as always.  Every rep meets every head
+# size, and every mode (the window's far edge inside a sub-tile, 40, and on
+# a tile's border, 64) both addressings and every rep.
+_HEAD_MODES = ("causal", "window", "full")
+HEAD_CASES = [
+    (rep, d, dv, _HEAD_MODES[(i + j) % 3])
+    for i, rep in enumerate((1, 4, 6, 16))
+    for j, (d, dv) in enumerate(((128, 128), (256, 256), (64, 64),
+                                 (192, 128)))
+] + [(6, 128, 128, "causal"), (6, 128, 128, "window"), (4, 128, 128, "full"),
+     (16, 128, 128, "window"), (4, 256, 256, "causal"), (4, 64, 64, "window"),
+     (6, 192, 128, "window"), (16, 64, 64, "window"), (1, 256, 128, "causal")]
+
+
+def _heads_qkv(rep, d, dv, sq=128, sk=128, h_kv=2):
+    q, k, v = _qkv(1, sq, sk, rep * h_kv, h_kv, d, jnp.float32)
+    return q, k, v[..., :dv]
+
+
+@pytest.mark.parametrize("rep,d,dv,mode", HEAD_CASES,
+                         ids=lambda x: str(x))
+def test_flash_reads_heads_where_they_stand(rep, d, dv, mode):
+    """Value AND the three gradients against ``mha_reference`` on k, v
+    repeated by hand: the kernels read KV head ``h // rep``, and dk, dv
+    come back at the KV heads' own count, each the sum over its group."""
+    q, k, v = _heads_qkv(rep, d, dv)
+    window = {"window": 40 if rep % 4 else 64}.get(mode)
+    kw = dict(causal=mode != "full", window=window)
+    flash = lambda q, k, v: flash_attention(q, k, v, block_q=64, block_k=64,
+                                            **kw)
+    ref = lambda q, k, v: mha_reference(
+        q, jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2), **kw)
+    loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))
+    out, grads = flash(q, k, v), jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    assert out.shape == (1, 128, 2 * rep, dv)
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+    assert jnp.max(jnp.abs(out - ref(q, k, v))) < 1e-4
+    for a, w in zip(grads, jax.grad(loss(ref), (0, 1, 2))(q, k, v)):
+        assert jnp.max(jnp.abs(a - w)) < 1e-4 * max(1.0, float(
+            jnp.max(jnp.abs(w))))
+
+
+def _eqns_outside_kernels(jaxpr):
+    """Every equation of ``jaxpr`` and of what it calls, the bodies of the
+    ``pallas_call``s left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns_outside_kernels(sub)
+
+
+@pytest.mark.parametrize("d,in_place", [(128, True), (64, False)],
+                         ids=["128-in-place", "64-turned-round"])
+def test_round_the_in_place_calls_nothing_is_transposed_or_repeated(
+        d, in_place):
+    """At a head of whole lane blocks the program round the three kernels
+    holds ONE transpose, of ``delta``'s float a row, and no k, v, dk or dv
+    at q's head count; a head of 64 lanes keeps the transposes, and loses
+    the repeat all the same.  (sk != sq, so a kv-side array is known by
+    its length.)"""
+    rep, h_kv, sq, sk = 4, 2, 128, 256
+    q, k, v = _heads_qkv(rep, d, d, sq, sk, h_kv)
+    grads = jax.grad(lambda *a: flash_attention(
+        *a, causal=False, block_q=64, block_k=64).sum(), (0, 1, 2))
+    eqns = list(_eqns_outside_kernels(jax.make_jaxpr(grads)(q, k, v).jaxpr))
+    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 3
+    turned = [e.outvars[0].aval.shape for e in eqns
+              if e.primitive.name == "transpose"]
+    if in_place:
+        assert turned == [(1, rep * h_kv, sq)]
+    else:       # q, k, v in; o out; do in; dq, dk, dv out
+        assert len(turned) == 8 and all(len(t) == 4 for t in turned)
+    repeated = rep * h_kv * sk * d      # a k at q's head count
+    for e in eqns:
+        for var in (*e.invars, *e.outvars):
+            shape = getattr(var.aval, "shape", ())
+            assert not (sk in shape and math.prod(shape) == repeated), (
+                e.primitive.name, shape)
+
+
+def test_the_kv_ordered_grid_walks_a_groups_heads_and_their_tiles():
+    """``flash_dkv``'s maps at rep 6, 4 x 4 tiles under a window, in both
+    addressings: step ``t`` of KV head ``g`` names q head ``6 g + t // 4``
+    and the live q tile nearest ``t % 4``; k, v, dk, dv name head ``g``
+    whatever ``t``; the q-ordered grids name KV head ``h // 6``."""
+    from ray_tpu.ops.attention import _grid_and_specs
+
+    rep, h_kv, s, block, d = 6, 2, 512, 128, 128
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16)
+    turned = (shape(1, rep * h_kv, s, d), shape(1, h_kv, s, d))
+    in_place = (shape(1, s, rep * h_kv * d), shape(1, s, h_kv * d))
+    tiles = (block, block, 8, 8)
+    for window in (None, 200):
+        _, plain = _grid_and_specs(turned[0], turned[0], turned[0], True,
+                                   tiles, window)
+        (nq, nk), old = _grid_and_specs(turned[0], turned[1], turned[1],
+                                        True, tiles, window)
+        _, new = _grid_and_specs(in_place[0], in_place[1], in_place[1], True,
+                                 tiles, window, heads=(rep * h_kv, h_kv))
+        assert (nq, nk) == (4, 4)
+        at = lambda spec, *idx: tuple(int(x) for x in spec.index_map(*idx))
+        for g in range(h_kv):
+            for i in range(nk):
+                for t in range(rep * nq):
+                    head = g * rep + t // nq
+                    tile = at(plain["q_j"], 0, 0, i, t % nq)[2]
+                    for name in ("q_j", "o_j"):
+                        assert at(old[name], 0, g, i, t) == (0, head, tile, 0)
+                        assert at(new[name], 0, g, i, t) == (0, tile, head)
+                    for spec in (old, new):
+                        assert at(spec["stat_j"], 0, g, i, t) == (
+                            0, head, 0, tile)
+                    for name in ("k_i", "v_i"):
+                        assert at(old[name], 0, g, i, t) == (0, g, i, 0)
+                        assert at(new[name], 0, g, i, t) == (0, i, g)
+        for h_ in range(rep * h_kv):
+            for i in range(nq):
+                for j in range(nk):
+                    tile = at(plain["k_j"], 0, 0, i, j)[2]
+                    for name in ("k_j", "v_j"):
+                        assert at(old[name], 0, h_, i, j) == (
+                            0, h_ // rep, tile, 0)
+                        assert at(new[name], 0, h_, i, j) == (
+                            0, tile, h_ // rep)
+                    assert at(new["q_i"], 0, h_, i, j) == (0, i, h_)
+                    assert at(new["row_i"], 0, h_, i, j) == (0, h_, i, 0)
+        assert new["q_i"].block_shape == (None, block, d)
+        assert old["q_i"].block_shape == (None, None, block, d)
+
+
+def test_an_untileable_gqa_call_repeats_for_the_reference_alone():
+    """No block of 64 rows or fewer tiles 100: the XLA reference answers,
+    on k and v repeated — the one place the flash path still repeats
+    them."""
+    q, k, v = _heads_qkv(4, 128, 128, 100, 100)
+    flash = lambda *a: flash_attention(*a, block_q=64, block_k=64)
+    assert "pallas_call" not in str(jax.make_jaxpr(flash)(q, k, v))
+    want = mha_reference(q, jnp.repeat(k, 4, 2), jnp.repeat(v, 4, 2))
+    assert jnp.max(jnp.abs(flash(q, k, v) - want)) < 1e-5
+    with pytest.raises(ValueError):     # 8 q heads over 3 kv heads
+        flash(q, k[:, :, :1].repeat(3, 2), v[:, :, :1].repeat(3, 2))
 
 
 @pytest.mark.parametrize("impl", ["ring", "ulysses"])

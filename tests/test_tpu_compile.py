@@ -15,6 +15,7 @@ its own process.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -133,6 +134,51 @@ def test_flash_backward_lowers_each_kernel_once(one_chip):
         *_flash_shapes(*XING4_HEADS, one_chip)).as_text()
     assert [text.count(f'kernel_name = "{name}"')
             for name in ("flash_fwd", "flash_dkv", "flash_dq")] == [1, 1, 1]
+
+
+@pytest.mark.parametrize("b,h,h_kv,window", [
+    (1, 48, 8, None),    # trinity-train-s8192's full layer: groups of 6
+    (1, 48, 8, 4096),    # ... and its four windowed ones
+    (2, 32, 2, None),    # nemotronh-train-s8192: groups of 16
+    (4, 32, 8, None),    # mistral7b-train-s4096's heads, at 8192
+], ids=lambda x: str(x))
+def test_flash_reads_lane_block_heads_in_place(one_chip, b, h, h_kv, window):
+    """At a head of 128 lanes the three kernels take q ``(b, s, h x 128)``
+    and k, v ``(b, s, h_kv x 128)`` as the model leaves them: the lowered
+    gradient holds each kernel once, nothing is transposed but ``delta``'s
+    float a row, and no k, v, dk or dv stands at q's head count — Mosaic
+    takes the strided blocks and the composite ``rep x nq`` axis."""
+    s, d = 8192, 128
+    q = _shape((b, s, h, d), jnp.bfloat16, one_chip)
+    kv = _shape((b, s, h_kv, d), jnp.bfloat16, one_chip)
+
+    def summed(q, k, v):
+        with jax.named_scope("attention"):
+            return flash_attention(q, k, v, causal=True, window=window,
+                                   interpret=False).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(summed, argnums=(0, 1, 2))).lower(q, kv, kv)
+    text = lowered.as_text()
+    names = [n + ("_win" if window else "")
+             for n in ("flash_fwd", "flash_dkv", "flash_dq")]
+    assert [text.count(f'kernel_name = "{n}"') for n in names] == [1, 1, 1]
+    turned = [line for line in text.splitlines()
+              if "stablehlo.transpose" in line]
+    assert len(turned) == 1 and f"tensor<{b}x{s}x{h}xf32>" in turned[0]
+    assert f"tensor<{b}x{s}x{h * d}xbf16>" in text       # q where it stands
+    assert f"tensor<{b}x{h}x{s}x{d}xbf16>" not in text   # nothing turned round
+    compiled = lowered.compile()
+    assert _has_kernel(compiled)
+    hlo = compiled.as_text()
+    assert not [line for line in hlo.splitlines()
+                if " transpose(" in line and "bf16[" in line]
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    for line in calls:      # q-side operands at h heads, kv-side at h_kv
+        widths = {int(w) for w in re.findall(
+            rf"bf16\[{b},{s},(\d+)\]", line)}
+        assert widths == {h * d, h_kv * d}, line
+        assert f"bf16[{b},{h}," not in line
 
 
 @pytest.mark.parametrize("shape,window", [

@@ -757,6 +757,68 @@ def test_step_breakdown_on_a_synthetic_trace(tmp_path):
     assert scope_and_phase("", STEP_SCOPES) == (None, "forward")
 
 
+# The three calls as a compiled step holds them where the kernels read q,
+# k, v in the model's own (b, s, heads x d) — mistral7b-train-s4096's
+# shapes, 32 q heads over 8 KV heads of 128: the q heads' count stands in
+# the stats alone (a result of flash_fwd, operands of the other two).
+_IN_PLACE = ('operand_layout_constraints={bf16[4,4096,4096]{2,1,0}, '
+             'bf16[4,4096,1024]{2,1,0}, bf16[4,4096,1024]{2,1,0}')
+_IN_PLACE_CALLS = {
+    "flash_fwd": ("%flash_fwd.1 = (bf16[4,4096,4096]{2,1,0:T(8,128)(2,1)}, "
+                  "f32[4,32,4096,128]{3,2,1,0:T(8,128)}) custom-call(%a, %b, "
+                  '%c), custom_call_target=\\"tpu_custom_call\\", '
+                  + _IN_PLACE + "}"),
+    "flash_dq": ("%flash_dq.1 = bf16[4,4096,4096]{2,1,0:T(8,128)(2,1)} "
+                 'custom-call(%a), custom_call_target=\\"tpu_custom_call\\", '
+                 + _IN_PLACE + ", bf16[4,4096,4096]{2,1,0}, "
+                 "f32[4,32,4096,128]{3,2,1,0}, f32[4,32,4096,128]{3,2,1,0}}"),
+    "flash_dkv": ("%flash_dkv.1 = (bf16[4,4096,1024]{2,1,0:T(8,128)(2,1)}, "
+                  "bf16[4,4096,1024]{2,1,0:T(8,128)(2,1)}) custom-call(%a), "
+                  'custom_call_target=\\"tpu_custom_call\\", '
+                  + _IN_PLACE + ", bf16[4,4096,4096]{2,1,0}, "
+                  "f32[4,32,1,4096]{3,2,1,0}, f32[4,32,1,4096]{3,2,1,0}}"),
+}
+
+
+def test_executed_over_causal_reads_operands_where_the_model_leaves_them(
+        tmp_path):
+    """``flash_executed_over_causal`` on both forms of a call's operands:
+    (b, h, s, d) as always; (b, s, h x d) with sq, sk from dimension 1 and
+    the head size from the width over the stats' head count — the same
+    ratio for the same call, and the ``step-breakdown`` rows keep their
+    ``executed/causal`` column."""
+    from ray_tpu.ops.attention import causal_tile_counts, choose_tiles
+    from ray_tpu.util.tracing import (
+        flash_executed_over_causal, format_breakdown, step_breakdown)
+
+    want = flash_executed_over_causal(_KERNEL.replace("\\", ""))
+    assert want == pytest.approx(1.0622, abs=1e-4)
+    for text in _IN_PLACE_CALLS.values():
+        assert flash_executed_over_causal(text.replace("\\", "")) == want
+    # a head of 512 lanes halves the fetch tile: the width is not the head
+    wide = ("operand_layout_constraints={f32[1,8192,2048]{2,1,0}, "
+            "f32[1,4096,512]{2,1,0}, f32[1,4096,512]{2,1,0}, "
+            "f32[1,4,1,8192]{3,2,1,0}}")
+    tiles = choose_tiles(8192, 4096, True, 512, "float32")
+    assert tiles[0] == 1024
+    n = causal_tile_counts(8192, 4096, *tiles)
+    assert flash_executed_over_causal(wide) == (
+        n["executed_pairs"] / n["causal_pairs"])
+    # no stats to take the heads from, a dtype no kernel takes: nothing
+    assert flash_executed_over_causal(_IN_PLACE + "}") is None
+    assert flash_executed_over_causal(
+        _IN_PLACE_CALLS["flash_dq"].replace("bf16", "s8")) is None
+    ops = [("%lead = f32[] add()", "jit(step)/optimizer/add", 500, 100)] + [
+        (text, (_FWD if name == "flash_fwd" else _BWD)
+         + f"attention/{name}/pallas_call", 2000 + 100 * i, 100)
+        for i, (name, text) in enumerate(_IN_PLACE_CALLS.items())]
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace(ops, modules=[(0, 1000), (2000, 1000)]))
+    b = step_breakdown(str(path), "jit_step")
+    assert b["kernel_pairs"] == {name: want for name in _IN_PLACE_CALLS}
+    assert "flash_dkv  executed/causal 1.0622" in format_breakdown(b)
+
+
 def test_step_breakdown_without_a_rematerialised_kernel(tmp_path):
     """A step whose checkpoint keeps the flash kernel's residuals: the
     rematerialised attention holds a transpose and no kernel, so there is

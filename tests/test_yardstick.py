@@ -1,11 +1,13 @@
 """The yardstick's own chip-free cases, collected in tier-1 so that the next
 rot of ``benchmark/tests`` shows in the driver's run: every case
 ``benchmark/tests/tier1_cases.py`` lists (what needs no chip, no train loop
-and no compile), and the Trinity cell's, by name.  No assertion lives here."""
+and no compile), the Trinity cell's and the reader ``flash.xla_ms``'s, by
+name.  No assertion lives here."""
 
 import pytest
 
-pytest.register_assert_rewrite("benchmark.tests.test_trinity")
+pytest.register_assert_rewrite("benchmark.tests.test_trinity",
+                               "benchmark.tests.test_flash_xla_ms")
 
 from benchmark.tests.tier1_cases import *  # noqa: E402,F401,F403
 from benchmark.tests.test_trinity import (  # noqa: E402,F401
@@ -15,3 +17,8 @@ from benchmark.tests.test_trinity import (  # noqa: E402,F401
     test_the_file_is_the_catalog_row_cut_to_one_chip_of_thirty_two,
     test_the_parameter_count_is_init_params as test_trinity_parameter_count,
     test_window_readers_on_synthetic_planes)
+from benchmark.tests.test_flash_xla_ms import (  # noqa: E402,F401
+    test_nothing_left_reads_zero_and_no_scope_reads_none,
+    test_on_a_mesh_the_slowest_chip_is_read,
+    test_the_entry_is_the_kernels_own_in_every_cell,
+    test_the_scope_less_the_flash_kernels_whatever_their_names)
