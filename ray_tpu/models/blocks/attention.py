@@ -3,7 +3,8 @@ the OLMo and LFM2 files spell it, ``full_attention``; ``sliding_attention``,
 as the ``afmoe`` file spells it, is the same mixer under the model's
 ``sliding_window``: a query sees itself and the ``window - 1`` tokens before
 it, the flash kernels skip what lies beyond either edge, and the layer
-reports ``attn_window_executed_share``) and latent attention
+reports ``attn_window_executed_share`` and
+``attn_window_masked_tile_share``) and latent attention
 (arXiv:2412.19437 §2.1.1: q and k/v come up from normed low-rank
 projections, a head's q and k are [no-position part | rotary part, the k's
 shared by all heads] and wider than its v; the flash kernels take the two
@@ -15,7 +16,9 @@ All open the scopes ``attn_qkv`` (norm, projections, RoPE), ``attention``
 and ``attn_out`` (``wo`` and the add), and the layer checkpoint keeps the
 flash kernel's output and log-sum-exp (``ops.attention.SAVED_RESIDUALS``:
 no ``flash_fwd`` under ``rematted_computation``).  Which layers rotate q
-and k is the configuration's to say (``cfg.rotary``).  With
+and k is the configuration's to say (``cfg.rotary``), and by which tables
+— one rule a model, or one a KIND of layer (``cfg.rope_rule``; scope
+``rope`` inside ``attn_qkv``: ``_rope_tables``, for both mixers).  With
 ``attn_output_gate`` a fourth projection ``wg`` of the block's input
 (scope ``attn_qkv``) gates the heads' outputs, ``o * sigmoid(g)``, before
 ``wo`` (scope ``attn_out``); the checkpoint keeps nothing of it: the
@@ -35,17 +38,20 @@ from ray_tpu.models.blocks.residual import (
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.layers import (
-    apply_rope, repeat_kv_heads, rms_norm, rope, yarn_inv_freq, yarn_mscale)
+    apply_rope, repeat_kv_heads, rms_norm, scaled_rope, yarn_mscale)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
 from ray_tpu.parallel.mesh import AXIS_SP, AXIS_TP
 from ray_tpu.parallel.sharding import BATCH_AXES, manual_shard_map
 
 SCOPES = ("attn_qkv", "attention", "attn_out")
-# A windowed layer's statistic: the (q, k) pairs its attention computes
-# over the pairs its window leaves (1.0: no masked pair computed).
+# A windowed layer's statistics: the (q, k) pairs its attention computes
+# over the pairs its window leaves (1.0: no masked pair computed), and of
+# the sub-tiles it executes the share that lies on an edge and takes a mask
+# (1.0 where no flash kernel runs: the XLA form masks the whole square).
 WINDOW_EXECUTED = "attn_window_executed_share"
-WINDOW_STATS = {WINDOW_EXECUTED: "max"}
+WINDOW_MASKED = "attn_window_masked_tile_share"
+WINDOW_STATS = {WINDOW_EXECUTED: "max", WINDOW_MASKED: "max"}
 
 
 def _attention_shapes(cfg):
@@ -101,22 +107,22 @@ def _sm_scale(cfg) -> float:
         return cfg.head_dim ** -0.5
     # latent attention: over the whole q/k head, times the square of
     # YaRN's temperature where the model states ``mscale_all_dim``
-    scaling = dict(cfg.rope_scaling or ())
+    scaling = dict(cfg.rope_rule(False)[1])
     return cfg.latent_qk_dim ** -0.5 * yarn_mscale(
         scaling.get("factor", 1.0), scaling.get("mscale_all_dim", 0.0)) ** 2
 
 
-def _rope_inv_freq(cfg, dim: int):
-    """YaRN's frequencies where the model's ``rope_scaling`` is of that
-    type, else None (the plain ones)."""
-    scaling = dict(cfg.rope_scaling or ())
-    if scaling.get("type", scaling.get("rope_type")) != "yarn":
-        return None
-    return yarn_inv_freq(
-        dim, cfg.rope_theta, factor=scaling["factor"],
-        original=scaling["original_max_position_embeddings"],
-        beta_fast=scaling.get("beta_fast", 32.0),
-        beta_slow=scaling.get("beta_slow", 1.0))
+def _rope_tables(ctx: Ctx, windowed: bool, s: int, dim: int):
+    """``(cos, sin) (s, dim / 2)`` a layer of this kind rotates its q and k
+    by — the configuration's rule (``cfg.rope_rule``: the theta and the
+    scaling group of the model, or of the layer's KIND) through
+    ``scaled_rope``: plain or YaRN's frequencies, cos and sin times YaRN's
+    ``attention_factor`` — from the rank's offset inside a region that is
+    manual over 'sp'.  The ONE place a mixer gets its tables, the softmax
+    and the latent one alike (each under the scope ``rope``, inside
+    ``attn_qkv``, with the rotations)."""
+    offset = jax.lax.axis_index(AXIS_SP) * s if ctx.sp_manual else 0
+    return scaled_rope(s, dim, *ctx.cfg.rope_rule(windowed), offset=offset)
 
 
 def _attention(q, k, v, cfg, mesh, window=None):
@@ -174,18 +180,21 @@ def _attention_sp_manual(q, k, v, cfg):
     return _ring_attention_sharded(q, k, v, _sm_scale(cfg), True, AXIS_SP)
 
 
-def _window_executed(cfg, sq: int, sk: int, d: int, window):
-    """``WINDOW_EXECUTED`` of one call, from shapes alone: the pairs the
-    flash schedule's live sub-tiles compute (``causal_tile_counts``) — the
-    whole rectangle where no flash kernel runs — over the pairs the window
-    leaves."""
+def _window_stats(cfg, sq: int, sk: int, d: int, window):
+    """``WINDOW_STATS`` of one call, from shapes alone: the pairs the flash
+    schedule's live sub-tiles compute (``causal_tile_counts``) — the whole
+    rectangle where no flash kernel runs — over the pairs the window
+    leaves, and the live sub-tiles on an edge over all the live ones (the
+    one rectangle likewise)."""
     tiles = attention.choose_tiles(
         sq, sk, True, d, cfg.dtype, window=window
     ) if cfg.attn_impl == "flash" else None
     n = attention.causal_tile_counts(sq, sk, *(tiles or (sq, sk, sq, sk)),
                                      window=window)
     executed = n["executed_pairs"] if tiles else sq * sk
-    return jnp.float32(executed / n["causal_pairs"])
+    return {WINDOW_EXECUTED: jnp.float32(executed / n["causal_pairs"]),
+            WINDOW_MASKED: jnp.float32(
+                n["diagonal"] / (n["interior"] + n["diagonal"]))}
 
 
 def _attend(ctx: Ctx, x, aux, q, k, v, lp, residual: bool, gate=None,
@@ -203,9 +212,9 @@ def _attend(ctx: Ctx, x, aux, q, k, v, lp, residual: bool, gate=None,
                     "a window inside a region that is manual over 'sp'")
             window = attention.live_window(cfg.sliding_window, k.shape[1])
             o = _attention(q, k, v, cfg, ctx.mesh, window)
-            aux = fold(aux, {WINDOW_EXECUTED: _window_executed(
+            aux = fold(aux, _window_stats(
                 cfg, q.shape[1], k.shape[1], max(q.shape[-1], v.shape[-1]),
-                window)}, WINDOW_STATS)
+                window), WINDOW_STATS)
         elif ctx.sp_manual:
             o = _attention_sp_manual(q, k, v, cfg)
         else:
@@ -240,11 +249,9 @@ def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
         gate = (h @ lp["wg"].astype(cfg.dtype) if cfg.attn_output_gate
                 else None)
         if cfg.rotary(windowed):
-            offset = 0
-            if ctx.sp_manual:
-                offset = jax.lax.axis_index(AXIS_SP) * s
-            cos, sin = rope(s, cfg.head_dim, cfg.rope_theta, offset=offset)
-            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            with jax.named_scope("rope"):
+                cos, sin = _rope_tables(ctx, windowed, s, cfg.head_dim)
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         q = cst(q, ("batch", "seq", "heads", "head_dim"))
         k = cst(k, ("batch", "seq", "kv_heads", "head_dim"))
     return _attend(ctx, x, aux, q, k, v, lp, residual, gate, windowed)
@@ -270,12 +277,11 @@ def _latent_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
         kv = (rms_norm(c_kv, lp["kv_a_norm"], cfg.norm_eps)
               @ lp["wkv_b"].astype(cfg.dtype)).reshape(
                   b, s, heads, nope + cfg.v_head_dim)
-        offset = jax.lax.axis_index(AXIS_SP) * s if ctx.sp_manual else 0
-        cos, sin = rope(s, rot, cfg.rope_theta, offset=offset,
-                        inv_freq=_rope_inv_freq(cfg, rot))
-        q = jnp.concatenate(
-            [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], -1)
-        k_rot = apply_rope(k_rot[:, :, None, :], cos, sin)
+        with jax.named_scope("rope"):
+            cos, sin = _rope_tables(ctx, False, s, rot)
+            q = jnp.concatenate(
+                [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], -1)
+            k_rot = apply_rope(k_rot[:, :, None, :], cos, sin)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rot, (b, s, heads, rot))],
             -1)

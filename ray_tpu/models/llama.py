@@ -57,7 +57,7 @@ from ray_tpu.models.blocks import FFNS, MIXERS, residual
 from ray_tpu.models.blocks.base import Ctx, Param, normal, ones
 from ray_tpu.models.blocks.residual import (
     from_streams, hc_block, scaled, to_streams)
-from ray_tpu.ops.layers import rms_norm
+from ray_tpu.ops.layers import rms_norm, rope_type
 from ray_tpu.ops.moe import update_selection_bias
 from ray_tpu.parallel.mesh import AXIS_SP
 from ray_tpu.parallel.sharding import (
@@ -71,6 +71,14 @@ from ray_tpu.parallel.sharding import (
 # FFN) pair it is, the absent half the empty block.
 LAYER_PATTERN = {"M": ("mamba", "none"), "E": ("none", "moe"),
                  "*": ("attention", "none"), "-": ("none", "dense")}
+
+
+# ``position_embedding``: rotary positions in every layer, the tables by
+# the layer's kind; the key of ``rope_parameters`` a layer without (False)
+# or with (True) a window reads; and the mixers that rotate at all.
+ROPE_BY_KIND = "rope_by_layer_type"
+ROPE_KINDS = {False: "full_attention", True: "sliding_attention"}
+ROTARY_MIXERS = ("attention", "full_attention", "sliding_attention", "latent")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,7 +121,9 @@ class LlamaConfig:
     ssm_conv: int = 4                 # width of the causal depthwise conv
     ssm_chunk: int = 256              # tokens a chunk of the scan
     # rope | nope (no position signal) | rope_windowed (``rotary``: RoPE
-    # in the "sliding_attention" layers, none in the others)
+    # in the "sliding_attention" layers, none in the others) |
+    # rope_by_layer_type (RoPE in every layer, by the rule ``rope_parameters``
+    # gives the layer's KIND: ``rope_rule``)
     position_embedding: str = "rope"
     attention_multiplier: Optional[float] = None  # None: head_dim ** -0.5
     embedding_multiplier: float = 1.0  # on the embedded tokens
@@ -132,6 +142,13 @@ class LlamaConfig:
     qk_rope_dim: int = 0
     v_head_dim: int = 0
     rope_scaling: Any = None          # the public file's YaRN group
+    # The public file's rotary rule BY KIND of layer: {"full_attention":
+    # {"rope_type", "rope_theta", and of a "yarn" one "factor",
+    # "original_max_position_embeddings", "beta_fast", "beta_slow",
+    # "attention_factor"}, "sliding_attention": {...}}.  Read under
+    # position_embedding="rope_by_layer_type", in place of ``rope_theta``
+    # and ``rope_scaling``.
+    rope_parameters: Any = None
     # Expert layers: mlp_dim is an expert's width.
     leading_dense: int = 0            # layers 0.. with a dense FFN instead
     dense_mlp_dim: int = 0            # their width (0: mlp_dim)
@@ -190,6 +207,10 @@ class LlamaConfig:
         if isinstance(self.rope_scaling, dict):
             object.__setattr__(self, "rope_scaling",
                                tuple(sorted(self.rope_scaling.items())))
+        if isinstance(self.rope_parameters, dict):
+            object.__setattr__(self, "rope_parameters", tuple(sorted(
+                (kind, tuple(sorted(group.items())))
+                for kind, group in self.rope_parameters.items())))
         if self.router_groups != 1 or self.num_nextn > 1:
             raise NotImplementedError(
                 "group-limited routing (n_group > 1) and more than one "
@@ -198,7 +219,8 @@ class LlamaConfig:
             raise ValueError(f"router_scoring {self.router_scoring!r}")
         if self.block_norm not in ("input", "output", "sandwich"):
             raise ValueError(f"block_norm {self.block_norm!r}")
-        if self.position_embedding not in ("rope", "nope", "rope_windowed"):
+        if self.position_embedding not in ("rope", "nope", "rope_windowed",
+                                           ROPE_BY_KIND):
             raise ValueError(
                 f"position_embedding {self.position_embedding!r}")
         if self.block_norm == "output" and (
@@ -246,6 +268,23 @@ class LlamaConfig:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"num_layers is {self.num_layers}")
+        if self.position_embedding == ROPE_BY_KIND:
+            groups = dict(self.rope_parameters or ())
+            for mixer in {m for m, _ in self.layer_kinds} & set(ROTARY_MIXERS):
+                kind = ROPE_KINDS[mixer == "sliding_attention"]
+                if "rope_theta" not in dict(groups.get(kind, ())):
+                    raise ValueError(
+                        f"position_embedding {ROPE_BY_KIND!r}: "
+                        "rope_parameters holds no rope_theta for the "
+                        f"model's {kind} layers")
+        for windowed in (False, True):
+            kind = rope_type(self.rope_rule(windowed)[1])
+            if kind not in ("default", "yarn"):
+                raise NotImplementedError(
+                    f"rope scaling of type {kind!r}: the plain tables "
+                    "('default') and YaRN's are implemented, and a model "
+                    "is never trained with plain frequencies in place of "
+                    "the ones its file states")
 
     @property
     def qkv_dim(self) -> int:
@@ -310,7 +349,21 @@ class LlamaConfig:
         kind of layer carries the position signal."""
         if self.position_embedding == "rope_windowed":
             return windowed
-        return self.position_embedding == "rope"
+        return self.position_embedding in ("rope", ROPE_BY_KIND)
+
+    def rope_rule(self, windowed: bool) -> Tuple[float, Tuple]:
+        """``(theta, the scaling group's items)`` of the tables a layer of
+        this kind rotates by (``ops.layers.scaled_rope`` takes the two): the
+        model's one ``rope_theta`` and ``rope_scaling``, or under
+        ``rope_by_layer_type`` the group ``rope_parameters`` holds for the
+        kind — ``sliding_attention`` for a layer with a window,
+        ``full_attention`` for every other."""
+        if self.position_embedding != ROPE_BY_KIND:
+            return self.rope_theta, self.rope_scaling or ()
+        group = dict(dict(self.rope_parameters or ()).get(
+            ROPE_KINDS[windowed], ()))
+        return group.pop("rope_theta", self.rope_theta), tuple(
+            sorted(group.items()))
 
     @property
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:

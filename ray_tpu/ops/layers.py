@@ -66,6 +66,47 @@ def rope(seq_len: int, head_dim: int, theta: float = 10000.0,
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def rope_type(scaling) -> str:
+    """The type a public file's rope-scaling group (a dict, or its items)
+    names, under the key either generation of the files gives it;
+    ``default``, the plain tables, for no group."""
+    scaling = dict(scaling or ())
+    return scaling.get("rope_type", scaling.get("type")) or "default"
+
+
+def scaled_rope(seq_len: int, head_dim: int, theta: float, scaling=(),
+                offset=0) -> Tuple[jax.Array, jax.Array]:
+    """``rope``'s tables under a public file's scaling group ``scaling`` (a
+    dict or its items; none, or one of type ``default``: the plain tables):
+    of a ``yarn`` group ``yarn_inv_freq``'s frequencies, and cos and sin
+    TIMES the group's ``attention_factor`` — so q and k both carry it and
+    the scores its square.  Where the group states none it is ``0.1 ln
+    factor + 1``, or, of a group that states ``mscale`` and
+    ``mscale_all_dim``, the ratio of the two temperatures (1 where they
+    are equal: the model then scales its softmax itself)."""
+    scaling = dict(scaling or ())
+    kind = rope_type(scaling)
+    if kind == "default":
+        return rope(seq_len, head_dim, theta, offset=offset)
+    if kind != "yarn":
+        raise NotImplementedError(f"rope scaling of type {kind!r}")
+    factor = scaling["factor"]
+    cos, sin = rope(seq_len, head_dim, theta, offset=offset,
+                    inv_freq=yarn_inv_freq(
+                        head_dim, theta, factor=factor,
+                        original=scaling["original_max_position_embeddings"],
+                        beta_fast=scaling.get("beta_fast", 32.0),
+                        beta_slow=scaling.get("beta_slow", 1.0)))
+    on_tables = scaling.get("attention_factor")
+    if on_tables is None:
+        m, m_all = scaling.get("mscale"), scaling.get("mscale_all_dim")
+        on_tables = (yarn_mscale(factor, m) / yarn_mscale(factor, m_all)
+                     if m and m_all else yarn_mscale(factor, 1.0))
+    if on_tables == 1.0:  # no op added to the program
+        return cos, sin
+    return cos * on_tables, sin * on_tables
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """x: (b, s, h, d); cos/sin: (s, d/2).  Rotate-half formulation."""
     d2 = x.shape[-1] // 2

@@ -141,6 +141,8 @@ def test_flash_backward_lowers_each_kernel_once(one_chip):
     (1, 48, 8, 4096),    # ... and its four windowed ones
     (2, 32, 2, None),    # nemotronh-train-s8192: groups of 16
     (4, 32, 8, None),    # mistral7b-train-s4096's heads, at 8192
+    (1, 32, 4, None),    # mellum2-train-s16384's full layer: groups of 8
+    (1, 32, 4, 1024),    # ... and its three windowed ones, at 8192
 ], ids=lambda x: str(x))
 def test_flash_reads_lane_block_heads_in_place(one_chip, b, h, h_kv, window):
     """At a head of 128 lanes the three kernels take q ``(b, s, h x 128)``
@@ -185,6 +187,7 @@ def test_flash_reads_lane_block_heads_in_place(one_chip, b, h, h_kv, window):
     ((1, 8192, 48, 128), 4096),  # trinity-train-s8192: a far tile of 4 x 4
     ((1, 8192, 48, 128), 1000),  # both edges inside one fetch tile
     ((4, 4096, 32, 128), 4000),  # an edge between two sub-tiles
+    ((1, 16384, 32, 128), 1024),  # mellum2-train-s16384: half a fetch tile
 ], ids=lambda s: "x".join(map(str, s)) if isinstance(s, tuple) else f"w{s}")
 def test_windowed_flash_attention_compiles_under_its_own_names(
         one_chip, shape, window):

@@ -1,24 +1,43 @@
 """The yardstick's own chip-free cases, collected in tier-1 so that the next
 rot of ``benchmark/tests`` shows in the driver's run: every case
 ``benchmark/tests/tier1_cases.py`` lists (what needs no chip, no train loop
-and no compile), the Trinity cell's and the reader ``flash.xla_ms``'s, by
-name.  No assertion lives here."""
+and no compile), the Trinity cell's, the Mellum2 cell's and the reader
+``flash.xla_ms``'s, by name.  No assertion lives here.
+
+Trinity's ``test_the_cell_its_job_and_its_metrics`` pins the ``workloads``
+of its three ``flash.window_*`` entries to its own cell alone; PR 55 was
+asked to append a cell to them, and may not edit that file, so its case is
+no longer collected here: ``test_mellum.py``'s
+``test_trinitys_window_entries_stand_as_they_were_with_this_cell_appended``
+holds the same fields in the form an appended cell leaves true.  Likewise
+``test_flash_xla_ms.py``'s first case pins its entry as the LAST of
+``per_layer``, behind which this PR's two entries now stand:
+``test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries``."""
 
 import pytest
 
 pytest.register_assert_rewrite("benchmark.tests.test_trinity",
+                               "benchmark.tests.test_mellum",
                                "benchmark.tests.test_flash_xla_ms")
 
 from benchmark.tests.tier1_cases import *  # noqa: E402,F401,F403
 from benchmark.tests.test_trinity import (  # noqa: E402,F401
     test_flops_count_the_windows_pairs_not_the_causal_ones,
     test_on_a_program_without_the_window_the_readers_return_nothing,
-    test_the_cell_its_job_and_its_metrics as test_trinity_cell_job_and_metrics,
     test_the_file_is_the_catalog_row_cut_to_one_chip_of_thirty_two,
     test_the_parameter_count_is_init_params as test_trinity_parameter_count,
     test_window_readers_on_synthetic_planes)
+from benchmark.tests.test_mellum import (  # noqa: E402,F401
+    test_each_floor_and_each_width_violated_in_turn,
+    test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries,
+    test_flops_count_what_the_window_leaves_and_the_held_experts_compute,
+    test_on_a_program_without_them_the_two_readers_return_nothing,
+    test_the_cell_its_job_and_its_metrics as test_mellum_cell_job_and_metrics,
+    test_the_file_is_the_catalog_row_cut_to_one_chip_of_four,
+    test_the_parameter_count_is_init_params as test_mellum_parameter_count,
+    test_the_two_readers_on_recorded_values,
+    test_trinitys_window_entries_stand_as_they_were_with_this_cell_appended)
 from benchmark.tests.test_flash_xla_ms import (  # noqa: E402,F401
     test_nothing_left_reads_zero_and_no_scope_reads_none,
     test_on_a_mesh_the_slowest_chip_is_read,
-    test_the_entry_is_the_kernels_own_in_every_cell,
     test_the_scope_less_the_flash_kernels_whatever_their_names)
