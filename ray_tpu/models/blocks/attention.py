@@ -18,7 +18,12 @@ flash kernel's output and log-sum-exp (``ops.attention.SAVED_RESIDUALS``:
 no ``flash_fwd`` under ``rematted_computation``).  Which layers rotate q
 and k is the configuration's to say (``cfg.rotary``), and by which tables
 — one rule a model, or one a KIND of layer (``cfg.rope_rule``; scope
-``rope`` inside ``attn_qkv``: ``_rope_tables``, for both mixers).  With
+``rope`` inside ``attn_qkv``: ``_rope_tables``, for both mixers).  The
+softmax mixer rotates q and k where the projections leave them, ``(b, s,
+heads x d)`` — the layout the flash kernels read a head of 128 lanes in —
+wherever what it can see allows (``_rotates_flat``: no per-head norm, no
+'tp' over the lanes), else on the 4-D view, as the latent mixer rotates
+its 64-wide rotary part.  With
 ``attn_output_gate`` a fourth projection ``wg`` of the block's input
 (scope ``attn_qkv``) gates the heads' outputs, ``o * sigmoid(g)``, before
 ``wo`` (scope ``attn_out``); the checkpoint keeps nothing of it: the
@@ -38,7 +43,8 @@ from ray_tpu.models.blocks.residual import (
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.layers import (
-    apply_rope, repeat_kv_heads, rms_norm, scaled_rope, yarn_mscale)
+    apply_rope, apply_rope_flat, repeat_kv_heads, rms_norm, scaled_rope,
+    yarn_mscale)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
 from ray_tpu.parallel.mesh import AXIS_SP, AXIS_TP
@@ -123,6 +129,31 @@ def _rope_tables(ctx: Ctx, windowed: bool, s: int, dim: int):
     ``attn_qkv``, with the rotations)."""
     offset = jax.lax.axis_index(AXIS_SP) * s if ctx.sp_manual else 0
     return scaled_rope(s, dim, *ctx.cfg.rope_rule(windowed), offset=offset)
+
+
+def _rotates_flat(ctx: Ctx) -> bool:
+    """Whether a softmax mixer rotates q and k where the projections leave
+    them, ``(b, s, heads x d)`` — the layout the flash kernels read, so no
+    copy of q, k or o stands between the projections, RoPE, the kernels
+    and the checkpoint's stack — or on the 4-D view.  From what the call
+    can see: a per-head norm already holds q and k to ``(b, s, heads,
+    d)``, and 'tp' shards the lanes by heads (a roll across the shards
+    would be a collective)."""
+    return not ctx.cfg.qk_head_norm and (
+        ctx.mesh is None or ctx.mesh.shape[AXIS_TP] == 1)
+
+
+def _rotated(ctx: Ctx, windowed: bool, q, k):
+    """q and k rotated by the tables of a layer of this kind (scope
+    ``rope``), in the view they come in: ``(b, s, heads x d)`` or ``(b, s,
+    heads, d)`` — the same sums either way (``apply_rope_flat``)."""
+    d = ctx.cfg.head_dim
+    with jax.named_scope("rope"):
+        cos, sin = _rope_tables(ctx, windowed, q.shape[1], d)
+        if q.ndim == 3:
+            return (apply_rope_flat(q, cos, sin, d),
+                    apply_rope_flat(k, cos, sin, d))
+        return apply_rope(q, cos, sin), apply_rope(k, cos, sin)
 
 
 def _attention(q, k, v, cfg, mesh, window=None):
@@ -239,6 +270,9 @@ def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
         if cfg.qk_norm:
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        rotate, flat = cfg.rotary(windowed), _rotates_flat(ctx)
+        if rotate and flat:
+            q, k = _rotated(ctx, windowed, q, k)
         q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
         k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
         if cfg.qk_head_norm:
@@ -248,10 +282,8 @@ def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
             b, s, cfg.num_kv_heads, cfg.head_dim)
         gate = (h @ lp["wg"].astype(cfg.dtype) if cfg.attn_output_gate
                 else None)
-        if cfg.rotary(windowed):
-            with jax.named_scope("rope"):
-                cos, sin = _rope_tables(ctx, windowed, s, cfg.head_dim)
-                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        if rotate and not flat:
+            q, k = _rotated(ctx, windowed, q, k)
         q = cst(q, ("batch", "seq", "heads", "head_dim"))
         k = cst(k, ("batch", "seq", "kv_heads", "head_dim"))
     return _attend(ctx, x, aux, q, k, v, lp, residual, gate, windowed)

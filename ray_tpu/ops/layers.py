@@ -117,6 +117,40 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def _along_the_lanes(table: jax.Array, heads: int) -> jax.Array:
+    """``table (s, d)`` once a head along the lanes, ``(s, heads * d)`` —
+    by way of ``(s / 8, 8, heads, d)``, a float32 tile's eight rows split
+    off, so that ONE fusion writes it in the tiled layout (``jnp.tile``: a
+    broadcast and a copy, 0.7 GB more in a step of 16384 positions)."""
+    s, d = table.shape
+    r = math.gcd(s, 8)
+    return jnp.broadcast_to(table.reshape(s // r, r, 1, d),
+                            (s // r, r, heads, d)).reshape(s, heads * d)
+
+
+def apply_rope_flat(x: jax.Array, cos: jax.Array, sin: jax.Array,
+                    head_dim: int) -> jax.Array:
+    """``apply_rope`` on the heads side by side: x ``(b, s, n * head_dim)``
+    as a projection leaves it, cos/sin ``(s, head_dim / 2)``; op by op
+    equal to ``apply_rope`` of the 4-D view, values and gradient (the same
+    products in the same precision; ``a + (-b)`` is ``a - b`` — inside one
+    compiled program the compiler's fusion decides the last place of
+    either form).  A lane's partner is ``head_dim / 2`` lanes up in a
+    head's first half and as far down in its second — two rolls of the
+    whole lane axis and a select, so no op sees a head as a dimension and
+    XLA can keep q and k in the tiled layout of the arrays on both sides of
+    the rotation.  Its price: XLA fuses no repeat along the lanes into the
+    products, so ``[cos|cos]`` and ``[-sin|sin]`` stand as float32 tables
+    as wide as x, 4 / rows times a bfloat16 x's bytes, made anew a layer
+    and pass (PERF.md §6, PR 56: made once a step they cost more)."""
+    d2, heads = head_dim // 2, x.shape[-1] // head_dim
+    first_half = jnp.arange(x.shape[-1]) % head_dim < d2
+    other = jnp.where(first_half, jnp.roll(x, -d2, -1), jnp.roll(x, d2, -1))
+    cos = _along_the_lanes(jnp.concatenate([cos, cos], -1), heads)
+    sin = _along_the_lanes(jnp.concatenate([-sin, sin], -1), heads)
+    return (x * cos + other * sin).astype(x.dtype)
+
+
 def sinkhorn(logits: jax.Array, iters: int, eps: float) -> jax.Array:
     """``exp(logits)`` made nearly doubly stochastic by ``iters`` rounds of
     Sinkhorn-Knopp (arXiv:2512.24880 §4.2): each round divides every row

@@ -352,6 +352,47 @@ def test_one_rule_a_model_goes_through_the_same_helper(mixer):
         assert float(jnp.max(jnp.abs(logits - plain))) > 1e-3
 
 
+def test_the_view_rope_works_on_follows_the_norm_and_the_mesh_not_the_sums(
+        monkeypatch):
+    """The softmax mixers rotate ``(b, s, heads x d)`` unless a per-head
+    norm holds q and k to four dimensions or 'tp' shards the lanes — one
+    row or two alike, so a cell's one-row check runs what its step runs;
+    both kinds of layer, the logits are those of the 4-D view to float32's
+    last places."""
+    from ray_tpu.parallel import MeshConfig, make_mesh
+
+    cfg = tiny()
+
+    def flat(cfg, mesh=None):
+        return attention_block._rotates_flat(
+            attention_block.Ctx(cfg, mesh, lambda a, _: a, False))
+
+    devices = jax.devices()[:4]
+    assert flat(cfg)
+    assert not flat(dataclasses.replace(cfg, qk_head_norm=True))
+    assert flat(cfg, make_mesh(MeshConfig(fsdp=2), devices=devices[:2]))
+    assert not flat(cfg, make_mesh(MeshConfig(fsdp=2, tp=2), devices=devices))
+    params, inputs = seeded(cfg), (TOKENS[:, :-1], TOKENS[:1, :-1])
+
+    def programs():
+        return [str(jax.make_jaxpr(lambda p, t: forward(p, t, cfg)[0])(
+            params, t)) for t in inputs]
+
+    def of_two_rolls(text, rows):   # a lane's partner, on the flat q
+        return f"f32[{rows},{SEQ},{cfg.qkv_dim}] = select_n" in text
+
+    assert [of_two_rolls(t, r) for t, r in zip(programs(), (2, 1))] == [
+        True, True]
+    by_rows = [forward(params, t, cfg)[0] for t in inputs]
+    monkeypatch.setattr(attention_block, "_rotates_flat", lambda ctx: False)
+    assert [of_two_rolls(t, r) for t, r in zip(programs(), (2, 1))] == [
+        False, False]
+    for got, tokens in zip(by_rows, inputs):
+        want = forward(params, tokens, cfg)[0]
+        assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * float(
+            jnp.max(jnp.abs(want)))
+
+
 # -- the window no wider than half a tile --------------------------------------
 
 @pytest.mark.parametrize("window", [200, 50, 128],

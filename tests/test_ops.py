@@ -17,6 +17,7 @@ from ray_tpu.ops import (
     rms_norm, rope, apply_rope,
 )
 from ray_tpu.ops.attention import causal_tile_counts, choose_tiles
+from ray_tpu.ops.layers import apply_rope_flat, scaled_rope
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.parallel import MeshConfig, make_mesh, use_mesh
 
@@ -558,6 +559,56 @@ def test_rope_offset_consistency():
     full = apply_rope(x, cos_full, sin_full)
     part = apply_rope(x[:, 32:], cos_off, sin_off)
     assert jnp.allclose(full[:, 32:], part, atol=1e-5)
+
+
+_YARN = {"rope_type": "yarn", "factor": 16.0,
+         "original_max_position_embeddings": 8}
+
+
+@pytest.mark.parametrize("tables", ["offset", "yarn"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("heads", [1, 4, 8])
+@pytest.mark.parametrize("d", [16, 64, 128])
+def test_apply_rope_flat_is_apply_rope_bit_for_bit(d, heads, dtype, tables):
+    """RoPE on ``(b, s, heads x d)`` is RoPE on ``(b, s, heads, d)``, values
+    and the gradient w.r.t. x, to the BIT (-0.0 is not 0.0 here): the same
+    products and sums in the same precision, under tables that start at an
+    'sp' rank's offset and under YaRN's frequencies times its factor.  Op
+    by op — inside ONE jitted program the CPU's compiler contracts a
+    product and a sum into a fused multiply-add, another one in each form,
+    and float32 then differs in the last place: held to that below."""
+    b, s = 2, 24
+    cos, sin = (rope(s, d, 1e4, offset=40) if tables == "offset"
+                else scaled_rope(s, d, 1e4, _YARN))
+    assert tables == "offset" or float(jnp.max(jnp.abs(cos))) > 1.0
+    x, g = (jax.random.normal(k, (b, s, heads * d), jnp.float32).astype(dtype)
+            for k in jax.random.split(jax.random.PRNGKey(d + heads)))
+
+    def by_head(x):
+        return apply_rope(x.reshape(b, s, heads, d), cos, sin).reshape(x.shape)
+
+    def flat(x):
+        return apply_rope_flat(x, cos, sin, d)
+
+    def bits(a):
+        return jax.lax.bitcast_convert_type(
+            a, jnp.uint16 if dtype == jnp.bfloat16 else jnp.uint32)
+
+    want, want_vjp = jax.vjp(by_head, x)
+    got, got_vjp = jax.vjp(flat, x)
+    assert got.dtype == dtype and jnp.array_equal(bits(got), bits(want))
+    assert not jnp.array_equal(got, x)          # it does rotate
+    (dx_want,), (dx_got,) = want_vjp(g), got_vjp(g)
+    assert dx_got.dtype == dtype
+    assert jnp.array_equal(bits(dx_got), bits(dx_want))
+    last_place = 2.0 ** (-7 if dtype == jnp.bfloat16 else -22)
+    for a, w in ((jax.jit(flat)(x), want),
+                 (jax.jit(jax.grad(lambda x: jnp.sum(
+                     (flat(x) * g).astype(jnp.float32))))(x), dx_want)):
+        assert float(jnp.max(jnp.abs(
+            a.astype(jnp.float32) - w.astype(jnp.float32)))) <= (
+                last_place * float(jnp.max(jnp.abs(w))))
 
 
 def test_moe_routing_mass_conservation():

@@ -335,6 +335,11 @@ def test_fsdp2_tp2_train_step_compiles(mesh4, as_on_chip):
     # fsdp gathers parameters and scatters gradients; tp reduces partial
     # matmul sums: a step without them was not partitioned.
     assert "all-gather" in text and "all-reduce" in text
+    # 'tp' shards q's and k's lanes by heads: RoPE stays on the 4-D view
+    # there — a roll of the lane axis crosses the shards: rotated flat, this
+    # step read 29 collective-permutes, 24 of them the rolls'
+    assert [op for op, _ in _collectives(text)].count(
+        "collective-permute") == 5
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
@@ -755,3 +760,78 @@ def test_lfm2_conv_layer_train_step_compiles(one_chip, as_on_chip):
     assert "sconv_gate" in text and "moe_experts" in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 5e9
+
+
+# -- RoPE where the projections leave q and k ---------------------------------
+
+def _ops_of(text, opcode, scopes):
+    """``(shape with layout, op_name)`` of every ``opcode`` of a compiled
+    text whose JAX name stack holds one of ``scopes``."""
+    found = []
+    for m in re.finditer(
+            r"= (\S+) " + opcode + r"\(.*?op_name=\"([^\"]*)\"", text):
+        if any(f"/{scope}/" in m.group(2) for scope in scopes):
+            found.append(m.groups())
+    return found
+
+
+@pytest.mark.parametrize("config,rows,seq,heads,kv_heads", [
+    ("mistral-7b-v0.1-d4", 4, 4096, 32, 8),       # grouped KV, no norm
+    ("olmoe-1b-7b-0125-1chip", 4, 4096, 16, 16),  # MHA, a norm over all of q
+    ("mistral-7b-v0.1-d4", 1, 4096, 32, 8),       # ONE row, as a cell's check
+], ids=["mistral", "olmoe", "one-row"])
+def test_rope_on_the_flat_arrays_leaves_no_copy_of_q_or_k(
+        one_chip, as_on_chip, config, rows, seq, heads, kv_heads):
+    """Two layers (the scan stays a loop) of a cell whose mixer rotates
+    ``(b, s, heads x 128)`` before the reshape, the vocabulary cut, as the
+    one-chip train step: between the projections and ``flash_fwd`` /
+    ``flash_dq`` / ``flash_dkv`` XLA copies neither q nor k (on the 4-D
+    view it laid RoPE's fusion out with the SEQUENCE on the lanes,
+    ``{1,3,2,0}``, and copied both into the kernels' ``{2,1,0}`` every
+    layer and pass: PERF.md §6, PR 54)."""
+    import dataclasses
+
+    cfg = _benchmark_cfg(config)
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+            cfg.qk_head_norm) == (heads, kv_heads, 128, False)
+    cfg = dataclasses.replace(cfg, num_layers=2, vocab_size=4096)
+    opt = default_optimizer()
+    text = make_train_step(cfg, opt).lower(
+        _state_shapes(cfg, opt, one_chip),
+        {"tokens": _shape((rows, seq + 1), jnp.int32, one_chip)}
+    ).compile().as_text()
+    assert all(name in text
+               for name in ("flash_fwd", "flash_dq", "flash_dkv"))
+    mixer = ("rope", "attn_qkv", "attention")
+    q_or_k = re.compile(
+        rf"bf16\[{rows},{seq},(?:{heads * 128}|{kv_heads * 128}"
+        rf"|{heads},128|{kv_heads},128)\]")
+    assert not [c for c in _ops_of(text, "copy", mixer)
+                if q_or_k.match(c[0])]
+    assert not [f for f in _ops_of(text, "fusion", mixer) + _ops_of(
+        text, "copy", mixer) if "{1,3,2,0" in f[0]]
+
+
+def test_rope_stays_on_the_4d_view_after_a_per_head_norm():
+    """What the mixer can see decides the view RoPE works on: a layer with
+    ``qk_head_norm`` already holds q and k to ``(b, s, heads, d)`` and
+    keeps ``apply_rope`` (rotate-half by slices of a HEAD: the flat form
+    after the norm compiled to float32 copies of q, PERF.md §6, PR 54); the
+    same layer without it rotates ``(b, s, heads x d)``, one row or two,
+    with no op that has a head for a dimension."""
+    from ray_tpu.models import llama
+
+    def rope_ops(rows, **kw):
+        cfg = LlamaConfig.tiny(num_layers=1, **kw)
+        params = jax.eval_shape(
+            lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0))
+        text = str(jax.make_jaxpr(
+            lambda p, t: llama.loss_fn(p, {"tokens": t}, cfg)[0])(
+                params, jax.ShapeDtypeStruct((rows, 17), jnp.int32)))
+        half_a_head = f"f32[{rows},16,{cfg.num_heads},{cfg.head_dim // 2}]"
+        of_two_rolls = f"f32[{rows},16,{cfg.qkv_dim}] = select_n"
+        return half_a_head in text, of_two_rolls in text
+
+    assert rope_ops(2, qk_head_norm=True) == (True, False)
+    assert rope_ops(1) == (False, True)
+    assert rope_ops(2) == (False, True)
