@@ -21,6 +21,7 @@ from ray_tpu.models.llama import (
     LlamaConfig, init_params, loss_and_counts, param_logical_axes)
 from ray_tpu.train.core import STEP_SCOPES, init_train_state, make_train_step
 from ray_tpu.util.tracing import scope_and_phase
+import tiny_models
 
 SEQ = 64
 TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ + 1), 0, 256)
@@ -162,74 +163,20 @@ def test_step_scopes_are_the_27_names_in_their_order():
 
 
 # -- (c) the parameter trees, as they were ------------------------------------
-# The tiny configurations the suites build (test_ssm.py: granite;
-# test_delta.py: olmo_hybrid; test_lfm2.py: lfm2; test_latent_streams.py:
-# xing4; test_moe.py: olmoe; and ``LlamaConfig.tiny`` bare and with
-# experts), and their trees as the parent commit (d286de7) built them: a
-# stack is its tensors in insertion order.
+# The pin guards a tree's tensors and their ORDER: a stack is its tensors
+# in insertion order, which a checkpoint's layout, the optimizer's state
+# and the sharding rules all follow, and which a block that declares its
+# tensors in another order would change without a test of values noticing.
+# The tiny configurations are ``tests/tiny_models.py``'s rows (granite with
+# two groups, Trinity at three layers and eight experts: the shapes pinned
+# below were taken at those).
 
-SCALING = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
-           "mscale_all_dim": 1, "original_max_position_embeddings": 16,
-           "type": "yarn"}
 TINY = {
-    "dense": lambda: LlamaConfig.tiny(),
-    "moe": lambda: LlamaConfig.tiny(
-        num_experts=4, num_selected=2, z_loss_coef=0.001, qk_norm=True),
-    "olmoe": lambda: LlamaConfig.tiny(
-        num_experts=8, num_selected=3, qk_norm=True, norm_eps=1e-5,
-        aux_loss_coef=0.01, z_loss_coef=0.001, attn_impl="flash"),
-    "granite": lambda: LlamaConfig(
-        vocab_size=256, embed_dim=64, num_layers=3, num_heads=4,
-        num_kv_heads=2, head_dim=16, mlp_dim=96, norm_eps=1e-5,
-        layer_types=["mamba", "attention", "mamba", "mamba", "attention"],
-        ssm_heads=8, ssm_head_dim=16, ssm_state=8, ssm_groups=2, ssm_conv=4,
-        ssm_chunk=8, position_embedding="nope", attention_multiplier=0.1,
-        embedding_multiplier=3.0, residual_multiplier=0.5,
-        logits_scaling=2.0, tie_embeddings=True, max_seq_len=64,
-        dtype=jnp.float32, remat=True, attn_impl="flash"),
-    "olmo_hybrid": lambda: LlamaConfig(
-        vocab_size=256, embed_dim=64, num_layers=4, num_heads=4,
-        num_kv_heads=4, head_dim=16, mlp_dim=96, norm_eps=1e-6,
-        layer_types=["linear_attention"] * 3 + ["full_attention",
-                                                "linear_attention"],
-        gdn_heads=4, gdn_key_dim=8, gdn_value_dim=16, gdn_conv=4,
-        gdn_neg_eigval=True, position_embedding="nope", qk_norm=True,
-        block_norm="output", max_seq_len=128, dtype=jnp.float32, remat=True,
-        attn_impl="flash"),
-    "lfm2": lambda: LlamaConfig(
-        vocab_size=128, embed_dim=64, num_layers=8, num_heads=4,
-        num_kv_heads=2, head_dim=16, mlp_dim=32, dense_mlp_dim=96,
-        max_seq_len=64, dtype=jnp.float32, remat=False,
-        attn_impl="reference", rope_theta=1e6, norm_eps=1e-5,
-        layer_types=("conv", "conv", "full_attention", "conv", "conv",
-                     "conv", "full_attention", "conv"),
-        sconv_width=3, qk_head_norm=True, num_experts=8, num_selected=4,
-        norm_topk_prob=True, topk_norm_eps=1e-6, experts_held=4,
-        first_expert=4, router_scoring="sigmoid", topk_method="noaux_tc",
-        leading_dense=2, aux_loss_coef=0.0, tie_embeddings=True),
-    "xing4": lambda: LlamaConfig(
-        vocab_size=128, embed_dim=64, num_layers=4, num_heads=4,
-        num_kv_heads=4, head_dim=16, mlp_dim=32, dense_mlp_dim=96,
-        max_seq_len=64, dtype=jnp.float32, remat=False,
-        attn_impl="reference", q_lora_rank=24, kv_lora_rank=16,
-        qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, rope_scaling=SCALING,
-        num_experts=16, num_selected=4, norm_topk_prob=True, experts_held=4,
-        first_expert=4, shared_experts=1, router_scoring="sigmoid",
-        topk_method="noaux_tc", routed_scaling_factor=2.0, leading_dense=2,
-        hc_mult=4, num_nextn=1, aux_loss_coef=0.0),
+    "dense": {}, "moe": {}, "olmoe": {}, "granite": dict(ssm_groups=2),
+    "olmo_hybrid": {}, "lfm2": {}, "xing4": {},
+    "trinity": dict(num_layers=3, num_experts=8, layer_types=(
+        "sliding_attention", "full_attention", "sliding_attention")),
 }
-# ... and one this tree brought (PR 53): two norms a block, the output gate,
-# a windowed run beside a full one, experts behind one dense layer.
-TINY["trinity"] = lambda: LlamaConfig(
-    vocab_size=128, embed_dim=64, num_layers=3, num_heads=4, num_kv_heads=2,
-    head_dim=16, mlp_dim=32, dense_mlp_dim=96, max_seq_len=64,
-    dtype=jnp.float32, remat=False, attn_impl="reference", norm_eps=1e-5,
-    layer_types=("sliding_attention", "full_attention", "sliding_attention"),
-    sliding_window=16, attn_output_gate=True, block_norm="sandwich",
-    post_norm_init=0.25, position_embedding="rope_windowed",
-    qk_head_norm=True, num_experts=8, num_selected=2, experts_held=4,
-    first_expert=4, shared_experts=1, router_scoring="sigmoid",
-    topk_method="noaux_tc", leading_dense=1, aux_loss_coef=0.0)
 _TRINITY_MIXER = (
     "attn_norm=f32[1,64] attn_post_norm=f32[1,64] wq=f32[1,64,64] "
     "wk=f32[1,64,32] wv=f32[1,64,32] wo=f32[1,64,64] q_norm=f32[1,16] "
@@ -387,9 +334,19 @@ def _sketch(tree):
 @pytest.mark.parametrize("kind", list(TINY))
 def test_the_parameter_tree_is_what_the_parent_built(kind):
     # not ``eval_shape``: a tree that went through JAX has its keys sorted
-    got = _sketch(init_params(jax.random.PRNGKey(0), TINY[kind]()))
+    got = _sketch(init_params(jax.random.PRNGKey(0),
+                              tiny_models.tiny(kind, **TINY[kind])))
     assert got == AT_PARENT[kind]
     assert list(got) == list(AT_PARENT[kind])   # dicts compare unordered
+
+
+def test_every_registered_block_stands_in_a_tiny_model():
+    """A new mixer or FFN cannot arrive without a row of the table that
+    runs it."""
+    kinds = {kind for name in tiny_models.ROWS
+             for kind in tiny_models.tiny(name).layer_kinds}
+    assert set(MIXERS) <= {mixer for mixer, _ in kinds}
+    assert set(FFNS) <= {ffn for _, ffn in kinds}
 
 
 # -- (d) the seam, shown -------------------------------------------------------

@@ -7,6 +7,7 @@ the recurrence a token at a time, nothing shared with the code under
 test)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +31,9 @@ from ray_tpu.parallel.sharding import batch_shard_map
 from ray_tpu.train.core import (
     STEP_SCOPES, init_train_state, make_train_step)
 
+import tiny_models
+from tiny_models import ROWS, against_the_reference, program, reference
+
 HIGHEST = jax.default_matmul_precision("highest")
 GDN_SCOPES = ("gdn_in", "gdn_conv", "gdn_scan", "gdn_out")
 
@@ -51,12 +55,17 @@ def _rule_inputs(seq, neg_eigval, seed=0, batch=2, heads=3, dk=12, dv=24,
         dtype), g, beta, f(batch, heads, dv, dk))
 
 
-def _rule_grads(form, args, weight):
+def _rule_value_and_grads(form, args, weight):
+    """What ``form(*args)`` returns and the gradients of a weighted sum of
+    its output and state to all six arguments: ONE compiled program."""
     def scalar(*t):
-        o, state = form(*t)[:2]
-        return jnp.sum(o * weight) + 0.1 * jnp.sum(jnp.square(state))
+        out = form(*t)
+        o, state = out[:2]
+        return jnp.sum(o * weight) + 0.1 * jnp.sum(jnp.square(state)), out
 
-    return jax.jit(jax.grad(scalar, argnums=range(6)))(*args)
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        scalar, argnums=range(6), has_aux=True))(*args)
+    return out, grads
 
 
 SMALL = dict(heads=3, dk=12, dv=24)        # no kernel fits: the XLA form
@@ -98,10 +107,9 @@ def test_delta_chunked_equals_the_recurrence(seq, chunk, sizes, kernels,
     assert _takes_the_kernels(chunked, args) == kernels == kernels_fit(
         sizes["dk"], sizes["dv"], min(chunk, seq))
     with HIGHEST:
-        (o, state, peak), (want, want_state) = (
-            jax.jit(f)(*args) for f in (chunked, delta_reference))
-        grads, want_grads = (_rule_grads(f, args, weight)
-                             for f in (chunked, delta_reference))
+        ((o, state, peak), grads), ((want, want_state), want_grads) = (
+            _rule_value_and_grads(f, args, weight)
+            for f in (chunked, delta_reference))
     assert o.shape == want.shape and o.dtype == args[2].dtype
     np.testing.assert_allclose(o, want, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(state, want_state, atol=2e-5, rtol=2e-5)
@@ -131,10 +139,10 @@ def test_the_kernels_equal_the_xla_form_at_the_published_sizes(dtype, tol):
     assert _takes_the_kernels(delta_chunked, args)
     assert not _takes_the_kernels(delta_xla, args)
     with HIGHEST:
-        (o, state, peak), (want, want_state, want_peak) = (
-            jax.jit(f)(*args) for f in (delta_kernels, delta_xla))
-        grads, want_grads = (_rule_grads(f, args, weight)
-                             for f in (delta_kernels, delta_xla))
+        ((o, state, peak), grads), (
+            (want, want_state, want_peak), want_grads) = (
+                _rule_value_and_grads(f, args, weight)
+                for f in (delta_kernels, delta_xla))
     assert o.dtype == dtype and state.dtype == jnp.float32
     f32 = lambda t: np.asarray(t.astype(jnp.float32))
     scale = float(np.max(np.abs(f32(want))))
@@ -263,48 +271,9 @@ def test_causal_conv1d_without_a_bias():
 
 # ---- the model --------------------------------------------------------
 
-# A configuration file's keys (the public names), tiny: the published
-# pattern (three linear, one full) with a fifth entry that is not run.
-CONF = {
-    "num_hidden_layers": 4,
-    "layer_types": ["linear_attention"] * 3 + ["full_attention",
-                                               "linear_attention"],
-    "num_attention_heads": 4, "num_key_value_heads": 4,
-    "linear_num_key_heads": 4, "linear_num_value_heads": 4,
-    "linear_key_head_dim": 8, "linear_value_head_dim": 16,
-    "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
-    "rms_norm_eps": 1e-6,
-}
-
-
-def _cfg(**kw):
-    fields = dict(
-        vocab_size=256, embed_dim=64, num_layers=4, num_heads=4,
-        num_kv_heads=4, head_dim=16, mlp_dim=96, norm_eps=1e-6,
-        layer_types=CONF["layer_types"], gdn_heads=4, gdn_key_dim=8,
-        gdn_value_dim=16, gdn_conv=4, gdn_neg_eigval=True,
-        position_embedding="nope", qk_norm=True, block_norm="output",
-        max_seq_len=128, dtype=jnp.float32, remat=True, attn_impl="flash")
-    fields.update(kw)
-    return LlamaConfig(**fields)
-
-
-def _drawn(params, seed=5):
-    """Norm weights drawn away from 1, as the benchmark's check draws
-    them."""
-    rng = np.random.default_rng(seed)
-
-    def leaf(path, a):
-        if str(getattr(path[-1], "key", "")).endswith("norm"):
-            return a * jnp.asarray(rng.uniform(0.5, 1.5, a.shape), a.dtype)
-        return a
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
-# 96 positions: a chunk of the rule's 64 and a ragged second one
-TOKENS = jnp.asarray(np.random.default_rng(7).integers(
-    0, 256, (2, 97), dtype=np.int32))
+TOKENS = ROWS["olmo_hybrid"].tokens
+_cfg = functools.partial(tiny_models.tiny, "olmo_hybrid")
+_drawn = functools.partial(tiny_models.drawn, seed=5)
 # float32 against float32, relative: the loss a mean of 192 numbers near
 # 5.5, the gradients through three recurrences of 96 tokens in two orders
 LOSS_TOL, NLL_TOL, GRAD_TOL = 2e-6, 2e-5, 5e-4
@@ -338,25 +307,13 @@ def test_hybrid_loss_token_losses_and_gradients_equal_the_plain_reference():
     computes the recurrence a token at a time: the loss within 2e-6, each
     position's loss within 2e-5 nats, every gradient leaf within 5e-4 of
     its scale."""
-    cfg = _cfg()
-    params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
+    ours = program("olmo_hybrid")
+    _, metrics, _, _ = against_the_reference(
+        "olmo_hybrid", parts=(), rtol=LOSS_TOL, nll_atol=NLL_TOL,
+        grad_rtol=GRAD_TOL)
     with HIGHEST:
-        (loss, metrics), grads = jax.jit(jax.value_and_grad(
-            lambda p: loss_fn(p, {"tokens": TOKENS}, cfg), has_aux=True))(
-                params)
-        logits, aux = jax.jit(lambda p: llama.forward(
-            p, TOKENS[:, :-1], cfg))(params)
-        want, want_grads = jax.jit(jax.value_and_grad(
-            lambda p: olmo_hybrid.loss(p, TOKENS, CONF)))(params)
-        want_nll = olmo_hybrid.loss_parts(params, TOKENS, CONF)["token_nll"]
-    assert abs(float(loss) - float(want)) / float(want) <= LOSS_TOL
-    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
-                               TOKENS[:, 1:, None], -1)[..., 0]
-    np.testing.assert_allclose(nll, want_nll, atol=NLL_TOL)
-    apart = jax.tree.map(
-        lambda g, w: float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w))),
-        grads, want_grads)
-    assert max(jax.tree.leaves(apart)) <= GRAD_TOL, apart
+        _, aux = jax.jit(lambda p: llama.forward(
+            p, TOKENS[:, :-1], ours.cfg))(ours.params)
     # the layers' largest state, as a step metric and beside the logits
     assert 0.1 < float(metrics[GDN_STATE_ABSMAX]) < 100.0
     assert float(aux[GDN_STATE_ABSMAX]) == float(metrics[GDN_STATE_ABSMAX])
@@ -375,8 +332,9 @@ def test_structural_controls_fail_against_the_reference(wrong, monkeypatch):
     1, the gate applied before the head's norm, keys left at their length.
     Each moves the loss thirty tolerances or more."""
     cfg = _cfg(attn_impl="reference", remat=False)
-    params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
-    program_params = params
+    params = program_params = program("olmo_hybrid").params
+    # before any patch: the reference's answer is kept for the process
+    want = float(reference("olmo_hybrid").parts["total"])
     if wrong == "gate before the norm":
         monkeypatch.setattr(delta, "rms_norm", lambda x, w, eps=1e-6: (
             x if w.shape[-1] == 16 and x.ndim == 4
@@ -395,7 +353,6 @@ def test_structural_controls_fail_against_the_reference(wrong, monkeypatch):
     with HIGHEST:
         loss = float(jax.jit(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(
             program_params))
-        want = float(olmo_hybrid.loss(params, TOKENS, CONF))
     assert abs(loss - want) / want > 30 * LOSS_TOL, (wrong, loss, want)
 
 
@@ -463,15 +420,16 @@ def test_train_step_reports_the_state_and_names_its_scopes():
     cfg = _cfg()
     opt = optax.adam(1e-2)
     state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    step = make_train_step(cfg, opt, donate=False)
     batch = {"tokens": TOKENS}
+    step = make_train_step(cfg, opt, donate=False).lower(
+        state, batch).compile()     # compiled once, for the steps and the text
     losses = []
     for _ in range(3):
         state, metrics = step(state, batch)
         losses.append(float(metrics["loss"]))
     assert losses[2] < losses[0] and np.isfinite(
         float(metrics[GDN_STATE_ABSMAX]))
-    text = step.lower(state, batch).compile().as_text()
+    text = step.as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
     seen = {scope_and_phase(n, STEP_SCOPES) for n in names}
     assert {(s, p) for s in GDN_SCOPES

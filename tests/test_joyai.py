@@ -7,6 +7,7 @@ That the same model on ``MeshConfig(ep=2)`` and ``(ep=4)`` equals the
 one-device program is ``tests/test_moe.py::
 test_a_share_over_ep_equals_one_device``."""
 
+import functools
 import re
 
 import jax
@@ -16,7 +17,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from benchmark.reference import joyai_flash, xing4
-from ray_tpu.models.llama import LlamaConfig, forward, init_params, loss_fn
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.parallel.sharding import named_sharding
@@ -24,73 +24,21 @@ from ray_tpu.train.core import (
     STEP_SCOPES, default_optimizer, init_train_state, make_train_step,
     train_state_shardings)
 from ray_tpu.util.tracing import scope_and_phase
+import tiny_models
+from tiny_models import against_the_reference, program, reference
 
-# the reference's configuration (public key names) of the tiny model below
-CONF = dict(
-    first_k_dense_replace=1, num_attention_heads=4, qk_nope_head_dim=16,
-    qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=16, rope_theta=32e6,
-    rms_norm_eps=1e-6, num_experts_per_tok=4, routed_scaling_factor=2.5,
-    first_expert=0, mtp_loss_coef=0.3)
-
-
-def tiny(**kw) -> LlamaConfig:
-    """The published pattern in small: 1 dense layer then expert layers,
-    16 experts of which this host holds the first 8, 4 a token."""
-    fields = dict(
-        vocab_size=128, embed_dim=64, num_layers=3, num_heads=4,
-        num_kv_heads=4, head_dim=16, mlp_dim=32, dense_mlp_dim=96,
-        max_seq_len=64, rope_theta=32e6, dtype=jnp.float32, remat=False,
-        attn_impl="reference", q_lora_rank=24, kv_lora_rank=16,
-        qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, num_experts=16,
-        num_selected=4, norm_topk_prob=True, experts_held=8, first_expert=0,
-        shared_experts=1, router_scoring="sigmoid", topk_method="noaux_tc",
-        routed_scaling_factor=2.5, leading_dense=1, num_nextn=1,
-        aux_loss_coef=0.0)
-    fields.update(kw)
-    return LlamaConfig(**fields)
-
-
-def seeded(cfg, seed=0):
-    """Parameters whose norm weights are drawn away from 1, as the train
-    loop draws them for its check."""
-    rng = np.random.default_rng(seed)
-
-    def drawn(path, a):
-        if not str(getattr(path[-1], "key", "")).endswith("norm"):
-            return a
-        return a * rng.uniform(0.5, 1.5, a.shape).astype(np.float32)
-
-    return jax.tree_util.tree_map_with_path(
-        drawn, init_params(jax.random.PRNGKey(seed), cfg))
-
-
-TOKENS = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0, 128)
+tiny = functools.partial(tiny_models.tiny, "joyai")
 
 
 # -- (a) the whole model against the reference, one device --------------------
 
 def test_loss_per_token_loss_and_gradients_equal_the_plain_reference():
-    cfg = tiny()
-    assert cfg.kind_runs == ((("latent", "dense"), 1), (("latent", "moe"), 2))
-    params = seeded(cfg)
-    total, parts = jax.jit(
-        lambda p: loss_fn(p, {"tokens": TOKENS}, cfg))(params)
-    want = joyai_flash.loss_parts(params, TOKENS, CONF)
-    for name in ("loss", "mtp_loss", "moe_held_share"):
-        np.testing.assert_allclose(parts[name], want[name], rtol=2e-5)
-    np.testing.assert_allclose(total, want["total"], rtol=2e-5)
+    assert program("joyai").cfg.kind_runs == (
+        (("latent", "dense"), 1), (("latent", "moe"), 2))
+    _, parts, _, ours = against_the_reference(
+        "joyai", parts=("loss", "mtp_loss", "moe_held_share"))
     assert float(parts["moe_dropped"]) == 0.0
     assert float(parts["moe_rank_rows_max_over_mean"]) == 1.0  # no ranks
-    logits, _ = forward(params, TOKENS[:, :-1], cfg)
-    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
-                               TOKENS[:, 1:, None], -1)[..., 0]
-    np.testing.assert_allclose(nll, want["token_nll"], atol=3e-5)
-    ours = jax.grad(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(params)
-    theirs = jax.grad(lambda p: joyai_flash.loss(p, TOKENS, CONF))(params)
-    apart = jax.tree.map(
-        lambda a, b: float(jnp.max(jnp.abs(a - b))
-                           / (jnp.max(jnp.abs(b)) + 1e-12)), ours, theirs)
-    assert max(jax.tree.leaves(apart)) < 1e-4, apart
     # no gradient reaches a selection bias
     assert not np.any(np.asarray(ours["layers"][1]["router_bias"]))
 
@@ -105,9 +53,9 @@ def test_a_changed_part_stands_apart_from_the_reference(change):
     """What each part is worth to the loss: the program with the part
     changed stands apart from the reference by more than the check's
     tolerance, or the check could not see that part."""
-    params = seeded(tiny())
-    want = float(joyai_flash.loss(params, TOKENS, CONF))
-    got = float(loss_fn(params, {"tokens": TOKENS}, tiny(**change))[0])
+    params = program("joyai").params
+    want = float(reference("joyai").parts["total"])
+    got = float(program("joyai", **change).loss(params)[0])
     assert abs(got - want) / want > joyai_flash.LOSS_RTOL, (got, want)
 
 
@@ -129,12 +77,15 @@ def _expert_layer(tokens=96, d=64, m=32, experts=16, seed=3):
         shared_down=normal(keys[9], (m, d)) * m ** -0.5)
 
 
+@functools.partial(jax.jit, static_argnums=2)
 def _block(p, first, held):
     """The routed part alone of the host that holds ``held`` experts from
-    ``first`` on, its step counters beside it."""
+    ``first`` on, its step counters beside it; one program, ``first``
+    traced."""
     return moe_block(
         p["x"], p["mlp_norm"], p["router"], *(
-            p[w][first:first + held] for w in ("w_gate", "w_up", "w_down")),
+            jax.lax.dynamic_slice_in_dim(p[w], first, held)
+            for w in ("w_gate", "w_up", "w_down")),
         num_selected=4, norm_topk_prob=True, scoring="sigmoid",
         select_bias=p["router_bias"], gate_scale=2.5, first_expert=first,
         residual=False)

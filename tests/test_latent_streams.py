@@ -17,63 +17,22 @@ import pytest
 from benchmark.loops import train
 from benchmark.reference import xing4
 from ray_tpu.models.llama import (
-    LlamaConfig, forward, init_params, loss_fn, param_logical_axes)
+    LlamaConfig, init_params, loss_fn, param_logical_axes)
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.layers import sinkhorn, yarn_inv_freq, yarn_mscale
 from ray_tpu.ops.moe import moe_block, update_selection_bias
 from ray_tpu.train.core import (
     STEP_SCOPES, default_optimizer, init_train_state, make_train_step)
+import tiny_models
+from tiny_models import (
+    ROWS, XING4_SCALING as SCALING, against_the_reference, program,
+    reference, side_of)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "xing4.0-29b-a4b-1of8"
-SCALING = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
-           "mscale_all_dim": 1, "original_max_position_embeddings": 16,
-           "type": "yarn"}
-# the reference's configuration (public key names) of the tiny model below
-CONF = dict(
-    first_k_dense_replace=2, hc_mult=4, num_attention_heads=4,
-    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=16,
-    rope_theta=10000, rms_norm_eps=1e-6, rope_scaling=SCALING,
-    num_experts_per_tok=4, routed_scaling_factor=2, first_expert=4,
-    hc_sinkhorn_iters=20, hc_eps=1e-6, mhc_h_res_clamp_min=-30,
-    mhc_h_res_clamp_max=30, mtp_loss_coef=0.3)
-
-
-def tiny(**kw) -> LlamaConfig:
-    fields = dict(
-        vocab_size=128, embed_dim=64, num_layers=4, num_heads=4,
-        num_kv_heads=4, head_dim=16, mlp_dim=32, dense_mlp_dim=96,
-        max_seq_len=64, dtype=jnp.float32, remat=False,
-        attn_impl="reference", q_lora_rank=24, kv_lora_rank=16,
-        qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, rope_scaling=SCALING,
-        num_experts=16, num_selected=4, norm_topk_prob=True, experts_held=4,
-        first_expert=4, shared_experts=1, router_scoring="sigmoid",
-        topk_method="noaux_tc", routed_scaling_factor=2.0, leading_dense=2,
-        hc_mult=4, num_nextn=1, aux_loss_coef=0.0)
-    fields.update(kw)
-    return LlamaConfig(**fields)
-
-
-def seeded(cfg, seed=0):
-    """Parameters whose norm weights are drawn away from 1, as the train
-    loop draws them for its check."""
-    rng = np.random.default_rng(seed)
-
-    def drawn(path, a):
-        if not str(getattr(path[-1], "key", "")).endswith("norm"):
-            return a
-        return a * rng.uniform(0.5, 1.5, a.shape).astype(np.float32)
-
-    return jax.tree_util.tree_map_with_path(
-        drawn, init_params(jax.random.PRNGKey(seed), cfg))
-
-
-TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, 128)
-
-
-def _program_loss(cfg, params):
-    return jax.jit(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg))(params)
+CONF, TOKENS = ROWS["xing4"].conf, ROWS["xing4"].tokens
+tiny = functools.partial(tiny_models.tiny, "xing4")
 
 
 # -- 1a: runs of (mixer, FFN) -------------------------------------------------
@@ -106,35 +65,17 @@ def test_runs_are_of_mixer_and_ffn_and_hold_only_their_kind():
 # -- the whole model against the reference ------------------------------------
 
 def test_loss_parts_and_gradients_equal_the_plain_reference():
-    cfg = tiny()
-    params = seeded(cfg)
-    total, parts = _program_loss(cfg, params)
-    want = xing4.loss_parts(params, TOKENS, CONF)
-    for ours, theirs in (("loss", "loss"), ("mtp_loss", "mtp_loss"),
-                         ("moe_held_share", "moe_held_share")):
-        np.testing.assert_allclose(parts[ours], want[theirs], rtol=2e-5)
-    np.testing.assert_allclose(total, want["total"], rtol=2e-5)
+    _, parts, _, ours = against_the_reference(
+        "xing4", parts=("loss", "mtp_loss", "moe_held_share"))
     assert float(parts["moe_dropped"]) == 0.0
-    logits, _ = forward(params, TOKENS[:, :-1], cfg)
-    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
-                               TOKENS[:, 1:, None], -1)[..., 0]
-    np.testing.assert_allclose(nll, want["token_nll"], atol=3e-5)
-    ours = jax.grad(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(params)
-    theirs = jax.grad(lambda p: xing4.loss(p, TOKENS, CONF))(params)
-    apart = jax.tree.map(
-        lambda a, b: float(jnp.max(jnp.abs(a - b))
-                           / (jnp.max(jnp.abs(b)) + 1e-12)), ours, theirs)
-    assert max(jax.tree.leaves(apart)) < 1e-4, apart
     # no gradient reaches a selection bias
     assert not np.any(np.asarray(ours["layers"][1]["router_bias"]))
 
 
 def test_flash_kernels_and_the_checkpoint_give_the_same_loss():
-    cfg = tiny()
-    params = seeded(cfg)
-    plain, _ = _program_loss(cfg, params)
-    kernels, _ = _program_loss(
-        dataclasses.replace(cfg, attn_impl="flash", remat=True), params)
+    params = program("xing4").params
+    plain, _ = program("xing4").loss(params)
+    kernels, _ = program("xing4", attn_impl="flash", remat=True).loss(params)
     np.testing.assert_allclose(kernels, plain, rtol=1e-5)
 
 
@@ -152,23 +93,22 @@ def test_flash_kernels_and_the_checkpoint_give_the_same_loss():
     dict(mtp_loss_coef=0.0),                      # 1e
 ], ids=lambda c: "-".join(c))
 def test_a_changed_part_stands_apart_from_the_reference(change):
-    cfg = tiny()
-    params = seeded(cfg)
-    want = float(xing4.loss(params, TOKENS, CONF))
-    got = float(_program_loss(dataclasses.replace(cfg, **change), params)[0])
+    cfg, params = program("xing4").cfg, program("xing4").params
+    want = float(reference("xing4").parts["total"])
+    got = float(side_of("xing4", dataclasses.replace(cfg, **change),
+                        params).loss(params)[0])
     assert abs(got - want) / want > 3e-4
 
 
 @pytest.mark.parametrize("leaf", [
     "router_bias", "shared_down", "hc_attn_bias", "hc_ffn_scale", "wkv_b"])
 def test_a_zeroed_leaf_stands_apart_from_the_reference(leaf):
-    cfg = tiny()
-    params = seeded(cfg)
-    want = float(xing4.loss(params, TOKENS, CONF))
+    params = program("xing4").params
+    want = float(reference("xing4").parts["total"])
     dense, moe = params["layers"]
     changed = dict(params, layers=(dense, dict(moe, **{
         leaf: jnp.zeros_like(moe[leaf])})))
-    got = float(_program_loss(cfg, changed)[0])
+    got = float(program("xing4").loss(changed)[0])
     assert abs(got - want) / want > 2e-4
 
 
@@ -204,6 +144,24 @@ _FLASH_CASES = (
        if strips != "chosen" for which in ("dk", "dv")])
 
 
+def _value_and_grads(fn, q, k, v):
+    def scalar(q, k, v):
+        out = fn(q, k, v)
+        return jnp.sum(jnp.sin(out)), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        scalar, (0, 1, 2), has_aux=True))(q, k, v)
+    return (out, *grads)
+
+
+@functools.lru_cache(maxsize=None)     # the reference's: whatever the tiles
+def _reference_grads(d, dv, seq, causal):
+    return _value_and_grads(
+        lambda q, k, v: mha_reference(q, k, v, causal=causal,
+                                      sm_scale=0.7 * d ** -0.5),
+        *_qkv(seq, d, dv))
+
+
 @functools.lru_cache(maxsize=None)     # one backward pass a (heads, strips)
 def _flash_grads(d, dv, strips):
     """((value, dq, dk, dv) of the kernels, the same of the reference)."""
@@ -216,11 +174,8 @@ def _flash_grads(d, dv, strips):
     else:
         flash = lambda q, k, v: attention._flash(
             q, k, v, scale, causal, tiles, True)
-    ref = lambda q, k, v: mha_reference(q, k, v, causal=causal,
-                                        sm_scale=scale)
-    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
-    return tuple((fn(q, k, v), *jax.grad(loss(fn), (0, 1, 2))(q, k, v))
-                 for fn in (flash, ref))
+    return (_value_and_grads(flash, q, k, v),
+            _reference_grads(d, dv, seq, causal))
 
 
 @pytest.mark.parametrize(
@@ -310,12 +265,15 @@ def _expert_layer(seed=3, tokens=96, d=32, m=16, experts=16):
         shared_down=normal(keys[0], (m, d)) * m ** -0.5)
 
 
+@functools.partial(jax.jit, static_argnums=2)
 def _share(p, first, held):
     """What the chip that holds ``held`` experts from ``first`` on adds:
-    the routed part alone, its step counters beside it."""
+    the routed part alone, its step counters beside it; one program,
+    ``first`` traced."""
     return moe_block(
         p["x"], p["mlp_norm"], p["router"], *(
-            p[w][first:first + held] for w in ("w_gate", "w_up", "w_down")),
+            jax.lax.dynamic_slice_in_dim(p[w], first, held)
+            for w in ("w_gate", "w_up", "w_down")),
         num_selected=4, norm_topk_prob=True, scoring="sigmoid",
         select_bias=p["router_bias"], gate_scale=2.0, first_expert=first,
         residual=False)
@@ -394,8 +352,9 @@ def test_the_train_step_moves_the_bias_by_its_rule_and_nothing_else_does():
     # a model without such a leaf hands the step no counts
     from ray_tpu.models.llama import loss_and_counts
     plain = LlamaConfig.tiny(num_experts=4)
-    _, (_, counts) = loss_and_counts(
-        init_params(jax.random.PRNGKey(0), plain), {"tokens": TOKENS}, plain)
+    _, (_, counts) = jax.jit(lambda p: loss_and_counts(
+        p, {"tokens": TOKENS}, plain))(init_params(jax.random.PRNGKey(0),
+                                                   plain))
     assert counts is None
 
 

@@ -20,57 +20,22 @@ import pytest
 from benchmark.loops import train
 from benchmark.reference import lfm2_moe
 from ray_tpu.models.llama import (
-    LlamaConfig, forward, init_params, loss_and_counts, loss_fn,
-    param_logical_axes, update_router_bias)
+    init_params, loss_and_counts, param_logical_axes, update_router_bias)
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.ops.ssm import causal_conv1d, gated_short_conv
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.train.core import (
     STEP_SCOPES, default_optimizer, init_train_state, make_train_step)
+import tiny_models
+from tiny_models import (
+    LFM2_PATTERN as PATTERN, ROWS, against_the_reference, apart as _apart,
+    program, reference, side_of)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "lfm2-8b-a1b-1of2"
 SCONV_SCOPES = ("sconv_in", "sconv_gate", "sconv_out")
-PATTERN = ("conv", "conv", "full_attention", "conv", "conv", "conv",
-           "full_attention", "conv")
-# the reference's configuration (public key names) of the tiny model below
-CONF = dict(
-    layer_types=list(PATTERN) + ["conv"] * 4, num_hidden_layers=8,
-    num_dense_layers=2, num_attention_heads=4, num_key_value_heads=2,
-    rope_theta=1000000, norm_eps=1e-5, num_experts_per_tok=4,
-    routed_scaling_factor=1, first_expert=4)
-
-
-def tiny(**kw) -> LlamaConfig:
-    fields = dict(
-        vocab_size=128, embed_dim=64, num_layers=8, num_heads=4,
-        num_kv_heads=2, head_dim=16, mlp_dim=32, dense_mlp_dim=96,
-        max_seq_len=64, dtype=jnp.float32, remat=False,
-        attn_impl="reference", rope_theta=1e6, norm_eps=1e-5,
-        layer_types=PATTERN, sconv_width=3, qk_head_norm=True,
-        num_experts=8, num_selected=4, norm_topk_prob=True,
-        topk_norm_eps=1e-6, experts_held=4, first_expert=4,
-        router_scoring="sigmoid", topk_method="noaux_tc", leading_dense=2,
-        aux_loss_coef=0.0, tie_embeddings=True)
-    fields.update(kw)
-    return LlamaConfig(**fields)
-
-
-def seeded(cfg, seed=0):
-    """Parameters whose norm weights are drawn away from 1, as the train
-    loop draws them for its check."""
-    rng = np.random.default_rng(seed)
-
-    def drawn(path, a):
-        if not str(getattr(path[-1], "key", "")).endswith("norm"):
-            return a
-        return a * rng.uniform(0.5, 1.5, a.shape).astype(np.float32)
-
-    return jax.tree_util.tree_map_with_path(
-        drawn, init_params(jax.random.PRNGKey(seed), cfg))
-
-
-TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, 128)
+TOKENS = ROWS["lfm2"].tokens
+tiny = functools.partial(tiny_models.tiny, "lfm2")
 # half the depth where the pattern itself is not what is tested: four runs
 # (conv, dense), (attention, moe), (conv, moe) x 2 compile in half the time
 SHALLOW = dict(num_layers=4, layer_types=PATTERN[1:5], leading_dense=1)
@@ -208,16 +173,6 @@ def test_five_runs_that_differ_in_mixer_and_ffn_hold_only_their_kind():
         tiny(qk_norm=True)
 
 
-def _program_loss(cfg, params):
-    return jax.jit(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg))(params)
-
-
-def _apart(ours, theirs):
-    return jax.tree.map(
-        lambda a, b: float(jnp.max(jnp.abs(a - b))
-                           / (jnp.max(jnp.abs(b)) + 1e-12)), ours, theirs)
-
-
 def test_loss_parts_and_gradients_equal_the_plain_reference():
     """Tolerances: both sides are float32, the program at XLA's default
     matmul precision on the CPU (float32) and the reference at "highest";
@@ -225,54 +180,34 @@ def test_loss_parts_and_gradients_equal_the_plain_reference():
     tokens, 3e-5 nats on one token's loss, 1e-4 of a gradient's largest
     entry (the selection is discrete: a swapped expert would read 1e-2
     and more)."""
-    cfg = tiny()
-    params = seeded(cfg)
-    total, parts = _program_loss(cfg, params)
-    want = lfm2_moe.loss_parts(params, TOKENS, CONF)
-    np.testing.assert_allclose(total, want["total"], rtol=2e-5)
-    np.testing.assert_allclose(parts["loss"], want["loss"], rtol=2e-5)
+    _, parts, want, ours = against_the_reference("lfm2")
     np.testing.assert_allclose(parts["moe_held_share"],
                                want["moe_held_share"], rtol=1e-6)
     assert 0.3 < float(parts["moe_held_share"]) < 0.7
     assert float(parts["moe_dropped"]) == 0.0
     assert len(want["experts"]) == 6
-    logits, _ = forward(params, TOKENS[:, :-1], cfg)
-    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
-                               TOKENS[:, 1:, None], -1)[..., 0]
-    np.testing.assert_allclose(nll, want["token_nll"], atol=3e-5)
-    ours = jax.grad(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(params)
-    theirs = jax.grad(lambda p: lfm2_moe.loss(p, TOKENS, CONF))(params)
-    apart = _apart(ours, theirs)
-    assert max(jax.tree.leaves(apart)) < 1e-4, apart
     # no gradient reaches a selection bias; the tied table gets both uses'
     assert not np.any(np.asarray(ours["layers"][1]["router_bias"]))
     assert np.any(np.asarray(ours["embed"]))
 
 
 def test_the_checkpoint_and_the_flash_kernels_give_the_same_loss():
-    cfg = tiny(**SHALLOW)
-    params = seeded(cfg)
-    plain, _ = _program_loss(cfg, params)
-    remat, _ = _program_loss(dataclasses.replace(cfg, remat=True), params)
+    shallow, under_remat = (program("lfm2", **SHALLOW, **kw)
+                            for kw in ({}, dict(remat=True)))
+    params = shallow.params
+    (plain, _), g_plain = shallow.value_and_grad(params)
+    (remat, _), g_remat = under_remat.value_and_grad(params)
     np.testing.assert_allclose(remat, plain, rtol=1e-6)
-    flash, _ = _program_loss(dataclasses.replace(cfg, attn_impl="flash"),
-                             params)
+    flash, _ = program("lfm2", **SHALLOW, attn_impl="flash").loss(params)
     np.testing.assert_allclose(flash, plain, rtol=2e-5)
-    g_plain = jax.grad(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(
-        params)
-    g_remat = jax.grad(lambda p: loss_fn(
-        p, {"tokens": TOKENS}, dataclasses.replace(cfg, remat=True))[0])(
-            params)
     assert max(jax.tree.leaves(_apart(g_remat, g_plain))) < 1e-5
 
 
-@functools.lru_cache(maxsize=None)
 def _sound():
     """The tiny model, its seeded parameters and the reference's loss on
-    them, once for every case below."""
-    cfg = tiny()
-    params = seeded(cfg)
-    return cfg, params, float(lfm2_moe.loss(params, TOKENS, CONF))
+    them, compiled once for every case below."""
+    sound = program("lfm2")
+    return sound.cfg, sound.params, float(reference("lfm2").parts["total"])
 
 
 def _changed(params, run, name, fn):
@@ -332,11 +267,11 @@ def test_a_changed_part_stands_apart_from_the_reference(change):
 
         conv.gated_short_conv = with_silu
         try:
-            got, _ = loss_fn(p, {"tokens": TOKENS}, wrong)
+            got, _ = side_of("lfm2", wrong, p).loss(p)
         finally:
             conv.gated_short_conv = plain
     else:
-        got, _ = _program_loss(wrong, p)
+        got, _ = side_of("lfm2", wrong, p).loss(p)
     apart = abs(float(got) - want) / want
     if change == "no-topk-eps":
         assert apart < 2e-5       # below anything a check resolves
@@ -359,12 +294,14 @@ def _expert_layer(seed=3, tokens=96, d=32, m=16, experts=8):
         w_down=normal(keys[0], (experts, m, d)) * m ** -0.5)
 
 
+@functools.partial(jax.jit, static_argnums=2)
 def _share(p, first, held):
     """What the chip that holds ``held`` experts from ``first`` on adds,
-    its step counters beside it."""
+    its step counters beside it; one program, ``first`` traced."""
     return moe_block(
         p["x"], p["mlp_norm"], p["router"], *(
-            p[w][first:first + held] for w in ("w_gate", "w_up", "w_down")),
+            jax.lax.dynamic_slice_in_dim(p[w], first, held)
+            for w in ("w_gate", "w_up", "w_down")),
         num_selected=4, norm_eps=1e-5, norm_topk_prob=True,
         topk_norm_eps=1e-6, scoring="sigmoid", select_bias=p["router_bias"],
         first_expert=first, residual=False)
@@ -407,7 +344,8 @@ def test_update_router_bias_moves_the_bias_of_every_expert_run():
     from its OLD value, whatever the optimizer made of it."""
     cfg = tiny()
     old = init_params(jax.random.PRNGKey(0), cfg)
-    _, (_, counts) = loss_and_counts(old, {"tokens": TOKENS}, cfg)
+    _, (_, counts) = jax.jit(lambda p: loss_and_counts(
+        p, {"tokens": TOKENS}, cfg))(old)
     assert counts["layers"][0] is None and counts["mtp"] is None
     assert [None if c is None else c.shape for c in counts["layers"]] == [
         None, (1, 8), (3, 8), (1, 8), (1, 8)]
@@ -429,10 +367,11 @@ def test_the_train_step_reports_the_scopes_and_the_counters():
     state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
     before = jax.tree.map(np.asarray, state.params)
     step = make_train_step(cfg, opt, donate=False)
-    text = step.lower(state, {"tokens": TOKENS}).as_text(debug_info=True)
+    lowered = step.lower(state, {"tokens": TOKENS})   # traced once
+    text = lowered.as_text(debug_info=True)
     for scope in SCONV_SCOPES + ("attn_qkv", "moe_experts", "ffn"):
         assert f"{scope}/" in text, scope
-    state, metrics = step(state, {"tokens": TOKENS})
+    state, metrics = lowered.compile()(state, {"tokens": TOKENS})
     assert {"moe_held_share", "moe_dropped", "moe_rows_visited_share",
             "moe_load_max_over_mean"} <= set(metrics)
     assert float(metrics["moe_dropped"]) == 0.0

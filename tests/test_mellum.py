@@ -22,8 +22,7 @@ from benchmark.loops import train
 from benchmark.reference import mellum
 from ray_tpu.models.blocks import attention as attention_block
 from ray_tpu.models.llama import (
-    ROPE_BY_KIND, LlamaConfig, forward, init_params, loss_and_counts,
-    loss_fn)
+    ROPE_BY_KIND, forward, loss_and_counts, loss_fn)
 from ray_tpu.ops.attention import (
     causal_tile_counts, choose_tiles, flash_attention, mha_reference)
 from ray_tpu.ops.layers import (
@@ -31,79 +30,29 @@ from ray_tpu.ops.layers import (
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.train.core import (
     default_optimizer, init_train_state, make_train_step)
+import tiny_models
+from tiny_models import (
+    F, MELLUM_GROUPS, MELLUM_WINDOW, MELLUM_YARN, ROWS, S,
+    against_the_reference, program, reference, seeded, side_of)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "mellum2-12b-a2.5b-1of4"
-S, F = "sliding_attention", "full_attention"
-PATTERN = (S, S, S, F) * 2     # two periods
-WINDOW, SEQ = 16, 48           # a third of each later row's keys cut off
-# the tiny model's rule: the sample's 48 positions pass the original 16,
-# and c(1) = 1.62 lies between two pairs, so the bounds' truncation shows
-YARN = {"rope_type": "yarn", "rope_theta": 100, "factor": 4,
-        "original_max_position_embeddings": 16, "beta_fast": 32,
-        "beta_slow": 1, "attention_factor": 0.1 * math.log(4) + 1}
-PLAIN = {"rope_type": "default", "rope_theta": 100}
-GROUPS = {F: YARN, S: PLAIN}
+CONF, TOKENS = ROWS["mellum"].conf, ROWS["mellum"].tokens
+WINDOW, SEQ = MELLUM_WINDOW, TOKENS.shape[1] - 1
+YARN, GROUPS = MELLUM_YARN, MELLUM_GROUPS
+PLAIN = GROUPS[S]
+tiny = functools.partial(tiny_models.tiny, "mellum")
 # the published group of the full layers
 PUBLISHED = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
              "original_max_position_embeddings": 8192, "beta_fast": 32,
              "beta_slow": 1, "attention_factor": 1.2772588722239782}
-# the reference's configuration (public key names) of the tiny model below
-CONF = dict(
-    layer_types=list(PATTERN), mlp_layer_types=["sparse"] * 8,
-    num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=2,
-    hidden_size=64, rms_norm_eps=1e-6, sliding_window=WINDOW,
-    rope_parameters=GROUPS, num_experts_per_tok=4, norm_topk_prob=True,
-    first_expert=4, router_aux_loss_coef=0.001)
-ONE_PERIOD = dict(CONF, num_hidden_layers=4)
-
-
-def tiny(**kw) -> LlamaConfig:
-    fields = dict(
-        vocab_size=128, embed_dim=64, num_layers=8, num_heads=4,
-        num_kv_heads=2, head_dim=16, mlp_dim=32, max_seq_len=64,
-        dtype=jnp.float32, remat=False, attn_impl="reference",
-        norm_eps=1e-6, layer_types=PATTERN, sliding_window=WINDOW,
-        position_embedding=ROPE_BY_KIND, rope_parameters=GROUPS,
-        num_experts=16, num_selected=4, norm_topk_prob=True, experts_held=4,
-        first_expert=4, aux_loss_coef=0.001)
-    fields.update(kw)
-    return LlamaConfig(**fields)
-
-
-def seeded(cfg, seed=0):
-    """Parameters whose norm weights are drawn away from 1, as the train
-    loop draws them for its check."""
-    rng = np.random.default_rng(seed)
-
-    def drawn(path, a):
-        if not str(getattr(path[-1], "key", "")).endswith("norm"):
-            return a
-        return a * rng.uniform(0.5, 1.5, a.shape).astype(np.float32)
-
-    return jax.tree_util.tree_map_with_path(
-        drawn, init_params(jax.random.PRNGKey(seed), cfg))
-
-
-TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ + 1), 0, 128)
 
 
 def _token_nll(cfg, params):
-    logits, _ = jax.jit(lambda p: forward(p, TOKENS[:, :-1], cfg))(params)
-    return -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
-                                TOKENS[:, 1:, None], -1)[..., 0]
+    return side_of("mellum", cfg, params).token_nll(params)
 
 
 # -- the model against the reference ------------------------------------------
-
-@functools.lru_cache(maxsize=None)
-def _reference_side():
-    """Two periods' seeded parameters (the same under either attention),
-    the reference's parts on them and its gradients: once for both cases."""
-    params = seeded(tiny())
-    return (params, mellum.loss_parts(params, TOKENS, CONF),
-            jax.grad(lambda p: mellum.loss(p, TOKENS, CONF))(params))
-
 
 @pytest.mark.parametrize("impl", ["reference", "flash-under-the-checkpoint"])
 def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
@@ -115,17 +64,12 @@ def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
     expert would read 1e-2 and more).  Once with the XLA attention, once
     with the flash kernels (interpreted; the windowed ones in six layers)
     under the layer checkpoint, the chip's path."""
-    cfg = tiny() if impl == "reference" else tiny(attn_impl="flash",
-                                                  remat=True)
+    kw = {} if impl == "reference" else dict(attn_impl="flash", remat=True)
+    cfg = program("mellum", **kw).cfg
     assert [n for _, n in cfg.kind_runs] == [3, 1, 3, 1]
     assert mellum.kinds(CONF) == cfg.layer_kinds
-    params, want, theirs = _reference_side()
-    total, parts = jax.jit(
-        lambda p: loss_fn(p, {"tokens": TOKENS}, cfg))(params)
-    np.testing.assert_allclose(total, want["total"], rtol=2e-5)
-    np.testing.assert_allclose(parts["loss"], want["loss"], rtol=2e-5)
-    np.testing.assert_allclose(parts["aux_loss"], want["aux_loss"],
-                               rtol=2e-5)
+    total, parts, want, ours = against_the_reference(
+        "mellum", parts=("loss", "aux_loss"), nll_atol=5e-5, **kw)
     assert float(total) - float(parts["loss"]) == pytest.approx(
         0.001 * float(want["aux_loss"]), rel=1e-3)
     np.testing.assert_allclose(parts["moe_held_share"],
@@ -133,31 +77,22 @@ def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
     assert 0.1 < float(parts["moe_held_share"]) < 0.5
     assert float(parts["moe_dropped"]) == 0.0
     assert len(want["experts"]) == 8
-    np.testing.assert_allclose(_token_nll(cfg, params), want["token_nll"],
-                               atol=5e-5)
-    ours = jax.jit(jax.grad(
-        lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0]))(params)
-    apart = jax.tree.map(
-        lambda a, b: float(jnp.max(jnp.abs(a - b))
-                           / (jnp.max(jnp.abs(b)) + 1e-12)), ours, theirs)
-    assert max(jax.tree.leaves(apart)) < 1e-4, apart
     for run in ours["layers"]:      # every tensor of every run has one
         assert set(run) == {"attn_norm", "wq", "wk", "wv", "wo", "mlp_norm",
                             "router", "w_gate", "w_up", "w_down"}
         assert all(np.any(np.asarray(g)) for g in run.values())
 
 
-@functools.lru_cache(maxsize=None)
 def _sound():
     """One period of the tiny model, its seeded parameters, the reference's
     per-token losses on them (which the sound program stands 5e-5 off) and
-    its total, once for every case below."""
-    cfg = tiny(num_layers=4)
-    params = seeded(cfg)
-    want = mellum.loss_parts(params, TOKENS, ONE_PERIOD)
-    np.testing.assert_allclose(_token_nll(cfg, params), want["token_nll"],
-                               atol=5e-5)
-    return cfg, params, want["token_nll"], want["total"]
+    its total: each compiled once for every case below."""
+    sound = program("mellum", num_layers=4)
+    want = reference("mellum", dict(num_hidden_layers=4),
+                     num_layers=4).parts
+    np.testing.assert_allclose(sound.token_nll(sound.params),
+                               want["token_nll"], atol=5e-5)
+    return sound.cfg, sound.params, want["token_nll"], want["total"]
 
 
 def _groups(**kinds):
@@ -217,8 +152,9 @@ def test_a_changed_part_stands_apart_from_the_reference(change):
             patch.setattr(layers, "yarn_inv_freq", untruncated)
             got = _token_nll(cfg, params)
     elif change == "no-balance-loss":
-        total = loss_fn(params, {"tokens": TOKENS},
-                        dataclasses.replace(cfg, **wrong))[0]
+        total = jax.jit(lambda p: loss_fn(
+            p, {"tokens": TOKENS}, dataclasses.replace(cfg, **wrong))[0])(
+                params)
         # what the chip's mean-loss row sees: 0.001 x E-ish of ln(vocab)
         assert abs(float(total) - float(sound)) / float(sound) > \
             2 * mellum.LOSS_RTOL
@@ -346,9 +282,9 @@ def test_one_rule_a_model_goes_through_the_same_helper(mixer):
             0.1 * math.log(4.0) + 1.0) ** 2
     else:
         assert attention_block._sm_scale(cfg) == 16 ** -0.5
-        logits = forward(seeded(cfg), TOKENS[:, :-1], cfg)[0]
-        plain = forward(seeded(cfg), TOKENS[:, :-1], dataclasses.replace(
-            cfg, rope_scaling=None))[0]
+        logits, plain = (
+            jax.jit(lambda p: forward(p, TOKENS[:, :-1], c)[0])(seeded(cfg))
+            for c in (cfg, dataclasses.replace(cfg, rope_scaling=None)))
         assert float(jnp.max(jnp.abs(logits - plain))) > 1e-3
 
 
@@ -383,12 +319,15 @@ def test_the_view_rope_works_on_follows_the_norm_and_the_mesh_not_the_sums(
 
     assert [of_two_rolls(t, r) for t, r in zip(programs(), (2, 1))] == [
         True, True]
-    by_rows = [forward(params, t, cfg)[0] for t in inputs]
+    # a program a call: what is traced follows the patch below
+    logits = lambda t: jax.jit(  # noqa: E731
+        lambda p: forward(p, t, cfg)[0])(params)
+    by_rows = [logits(t) for t in inputs]
     monkeypatch.setattr(attention_block, "_rotates_flat", lambda ctx: False)
     assert [of_two_rolls(t, r) for t, r in zip(programs(), (2, 1))] == [
         False, False]
     for got, tokens in zip(by_rows, inputs):
-        want = forward(params, tokens, cfg)[0]
+        want = logits(tokens)
         assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * float(
             jnp.max(jnp.abs(want)))
 
@@ -421,10 +360,14 @@ def test_a_narrow_window_through_the_flash_kernels_at_a_group_of_8(window):
         return mha_reference(q, *repeat_kv_heads(q, k, v), causal=True,
                              window=window)
 
-    out, pull = jax.vjp(ours, q, k, v)
-    want, pull_ref = jax.vjp(theirs, q, k, v)
+    def value_and_pull(fn):
+        out, pull = jax.vjp(fn, q, k, v)
+        return out, pull(do)
+
+    out, grads = jax.jit(lambda: value_and_pull(ours))()
+    want, want_grads = jax.jit(lambda: value_and_pull(theirs))()
     np.testing.assert_allclose(out, want, atol=2e-5)
-    for got, ref in zip(pull(do), pull_ref(do)):
+    for got, ref in zip(grads, want_grads):
         np.testing.assert_allclose(got, ref, atol=1e-4)
 
 
@@ -434,8 +377,9 @@ def test_the_window_statistics_are_the_schedules_counts():
     schedule keeps sub-tiles of 256, executes 1.25 times the pairs the
     window leaves — 12.1 % of the causal ones — and masks 40 % of the 310
     sub-tiles it runs (Trinity's 8192 under 4096: 1.0624)."""
-    cfg = tiny(attn_impl="flash", num_layers=4)
-    _, (metrics, _) = loss_and_counts(seeded(cfg), {"tokens": TOKENS}, cfg)
+    counts = lambda cfg: jax.jit(lambda p: loss_and_counts(  # noqa: E731
+        p, {"tokens": TOKENS}, cfg))(seeded(cfg))
+    _, (metrics, _) = counts(tiny(attn_impl="flash", num_layers=4))
     tiles = choose_tiles(SEQ, SEQ, True, 16, jnp.float32, window=WINDOW)
     n = causal_tile_counts(SEQ, SEQ, *tiles, window=WINDOW)
     assert float(metrics["attn_window_executed_share"]) == pytest.approx(
@@ -443,8 +387,7 @@ def test_the_window_statistics_are_the_schedules_counts():
     assert float(metrics["attn_window_masked_tile_share"]) == pytest.approx(
         n["diagonal"] / (n["diagonal"] + n["interior"]))
     # the XLA form computes, and masks, the whole square
-    cfg = tiny(num_layers=4)
-    _, (metrics, _) = loss_and_counts(seeded(cfg), {"tokens": TOKENS}, cfg)
+    _, (metrics, _) = counts(tiny(num_layers=4))
     assert float(metrics["attn_window_masked_tile_share"]) == 1.0
     tiles = choose_tiles(16384, 16384, True, 128, jnp.bfloat16, window=1024)
     assert tiles == (2048, 2048, 256, 256)
@@ -470,10 +413,13 @@ def _expert_layer(seed=3, tokens=96, d=32, m=16, experts=64):
             "w_down": n(experts, m, d) * m ** -0.5}
 
 
+@functools.partial(jax.jit, static_argnums=(2, 3))
 def _share(p, first, held, renormalise=True):
+    """One program for every share: ``first`` is traced."""
     return moe_block(
         p["x"], p["mlp_norm"], p["router"], *(
-            p[w][first:first + held] for w in ("w_gate", "w_up", "w_down")),
+            jax.lax.dynamic_slice_in_dim(p[w], first, held)
+            for w in ("w_gate", "w_up", "w_down")),
         num_selected=8, norm_eps=1e-6, norm_topk_prob=renormalise,
         scoring="softmax", first_expert=first, residual=False)
 
@@ -523,14 +469,15 @@ def test_the_train_step_runs_both_kinds_of_kernel_and_reports():
     opt = default_optimizer()
     state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
     step = make_train_step(cfg, opt, donate=False)
-    text = step.lower(state, {"tokens": TOKENS}).as_text(debug_info=True)
+    lowered = step.lower(state, {"tokens": TOKENS})   # traced once
+    text = lowered.as_text(debug_info=True)
     for name in ("flash_fwd_win", "flash_dq_win", "flash_dkv_win",
                  "flash_fwd", "attn_qkv/rope/", "attn_out/", "moe_experts/",
                  "moe_combine/"):
         assert name in text, name
     # the rotary ops sit INSIDE attn_qkv: no name stack starts at ``rope``
     assert "/rope/" in text and "jit(step)/rope" not in text
-    state, metrics = step(state, {"tokens": TOKENS})
+    state, metrics = lowered.compile()(state, {"tokens": TOKENS})
     assert set(mellum.STEP_METRICS) <= set(metrics)
     assert float(metrics["moe_dropped"]) == 0.0
     assert np.isfinite(float(metrics["loss"]))

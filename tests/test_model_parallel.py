@@ -45,7 +45,7 @@ def test_sharded_loss_matches_single_device(name, cfg_kw, mesh_kw):
     cfg = LlamaConfig.tiny(**cfg_kw)
     params = init_params(KEY, cfg)
     batch = _batch(cfg)
-    ref, _ = loss_fn(params, batch, cfg)
+    ref, _ = jax.jit(lambda p: loss_fn(p, batch, cfg))(params)
     mesh = make_mesh(MeshConfig(**mesh_kw))
     with use_mesh(mesh):
         sp = shard_pytree(params, param_logical_axes(cfg), mesh)
@@ -62,7 +62,7 @@ def test_pipelined_forward_matches(attn):
     params = init_params(KEY, cfg)
     toks = jax.random.randint(KEY, (8, 32), 0, cfg.vocab_size,
                               dtype=jnp.int32)
-    ref_logits, _ = forward(params, toks, cfg)
+    ref_logits, _ = jax.jit(lambda p: forward(p, toks, cfg))(params)
     mesh = make_mesh(MeshConfig(dp=2, pp=2, sp=2 if attn == "ring" else 1,
                                 tp=1 if attn == "ring" else 2))
     with use_mesh(mesh):
@@ -159,9 +159,9 @@ def test_reference_attention_repeats_kv_heads_without_a_mesh():
     ref_cfg = LlamaConfig.tiny(attn_impl="reference", **kw)
     params = init_params(KEY, ref_cfg)
     batch = _batch(ref_cfg)
-    got, _ = loss_fn(params, batch, ref_cfg)
-    want, _ = loss_fn(params, batch, LlamaConfig.tiny(attn_impl="flash",
-                                                      **kw))
+    got, want = (
+        jax.jit(lambda p: loss_fn(p, batch, cfg))(params)[0]
+        for cfg in (ref_cfg, LlamaConfig.tiny(attn_impl="flash", **kw)))
     assert abs(float(got) - float(want)) < 1e-4
 
 
@@ -180,7 +180,8 @@ def test_flash_under_tp_takes_kv_heads_as_they_are(head_dim, kv_heads):
     batch = _batch(cfg, b=4)
     loss = lambda cfg, mesh=None: jax.value_and_grad(
         lambda p: loss_fn(p, batch, cfg, mesh=mesh)[0])
-    want, want_g = loss(LlamaConfig.tiny(attn_impl="reference", **kw))(params)
+    want, want_g = jax.jit(
+        loss(LlamaConfig.tiny(attn_impl="reference", **kw)))(params)
     mesh = make_mesh(MeshConfig(fsdp=2, tp=2), devices=jax.devices()[:4])
     text = str(jax.make_jaxpr(loss(cfg, mesh))(params))
     # a rank's k: a head of its own, or (repeated) one for each q head
@@ -329,14 +330,15 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
     step = make_train_step(cfg, opt, mesh=mesh, donate=False)
     batch = _batch(cfg)
 
-    jaxpr = str(jax.make_jaxpr(step)(state, batch))
+    traced = step.trace(state, batch)       # one trace for both readings
+    jaxpr = str(traced.jaxpr)
     for kernel in ("flash_fwd", "flash_dkv", "flash_dq") + (
             ("moe_gmm", "moe_tgmm") if moe else ()):
         assert re.search(rf"\bname={kernel}\b", jaxpr), kernel
 
     # As compiled (CPU): every matmul, the partitioner's collectives,
     # and the interpret-mode kernels' own dots under attention/<kernel>.
-    named = _op_names(step.lower(state, batch).compile().as_text(), (
+    named = _op_names(traced.lower().compile().as_text(), (
         "dot", "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
         "collective-permute"))
     assert sum(op == "dot" for op, _ in named) >= 20

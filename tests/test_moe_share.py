@@ -1,0 +1,151 @@
+"""A chip's share of the dropless expert layer (``first_expert``, ``E' <
+E``) with every row past the live ones poisoned, the token-side sum of a
+share and the counter of the rows it reads.  CPU; the Pallas kernels run in
+interpret mode."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from moe_layer import (
+    D, E, K, T, against_the_loop, layer_inputs, per_token_loop, poisoned,
+    seeded_experts)
+from ray_tpu.ops import moe
+
+
+@pytest.mark.parametrize("first,held,tile", [
+    (0, 2, 16), (2, 3, 16), (5, 3, 128), (6, 2, 16), (0, 1, None)],
+    ids=["first2", "middle3", "last3_tile128", "last2", "one_default_tile"])
+@pytest.mark.parametrize("chunk", [1024, 40], ids=["one_chunk", "chunks"])
+def test_share_equals_the_per_token_loop_on_poisoned_tails(
+        first, held, tile, chunk, monkeypatch):
+    """A chip's share of the layer (``first_expert``, ``E' < E``): value
+    and EVERY gradient against the per-token loop, with the buffers'
+    tails poisoned: nothing may read a row past the live ones unmasked.
+    In chunks of 40 the loops over the live rows make several trips and
+    a token's run of live choices crosses them."""
+    poisoned(monkeypatch)
+    monkeypatch.setattr(moe, "ROW_CHUNK", chunk)
+    args = layer_inputs()
+    cut = lambda a: tuple(a[:3]) + tuple(w[first:first + held]
+                                         for w in a[3:])
+    layer = lambda *a: moe.moe_block(*cut(a), num_selected=K, tile=tile,
+                                     first_expert=first)
+    loop = lambda *a: per_token_loop(*cut(a), first=first)
+    stats, got = against_the_loop(layer, loop, args, (first, held))
+    assert float(stats["dropped"]) == 0
+    assert 0 < float(stats["held_share"]) < 1
+    for g in got[3:]:  # the absent experts' tensors got no gradient
+        assert float(jnp.abs(g[:first]).max(initial=0)) == 0
+        assert float(jnp.abs(g[first + held:]).max(initial=0)) == 0
+
+
+@pytest.mark.parametrize("chunk", [1024, 64], ids=["one_chunk", "chunks"])
+def test_share_that_every_token_chooses_is_exact_at_full_buffer(
+        chunk, monkeypatch):
+    """There is no capacity: when every token chooses only held experts
+    the live rows are ALL ``T * k`` of the buffer, the loops run to its
+    end, nothing is dropped and value and gradients are the loop's."""
+    poisoned(monkeypatch)
+    monkeypatch.setattr(moe, "ROW_CHUNK", chunk)
+    x, norm, router, w_gate, w_up, w_down = layer_inputs()
+    x = jnp.abs(x)
+    router = jnp.zeros((D, E)).at[:, 2:2 + K].set(
+        1.0 + 0.1 * jnp.arange(K))       # everyone to experts 2, 3, 4
+    args = (x, jnp.ones_like(norm), router, w_gate, w_up, w_down)
+    cut = lambda a: tuple(a[:3]) + tuple(w[2:6] for w in a[3:])
+    layer = lambda *a: moe.moe_block(*cut(a), num_selected=K, tile=16,
+                                     first_expert=2)
+    loop = lambda *a: per_token_loop(*cut(a), first=2)
+    stats, _ = against_the_loop(layer, loop, args, "every token's")
+    assert float(stats["held_share"]) == 1.0
+    assert float(stats["dropped"]) == 0
+
+
+def _token_sum_inputs(live, dtype, tokens=41, k=4, d=24, seed=0):
+    """``rows (n, d)`` with NaN from ``live`` on, ``slot_row (tokens, k)``
+    a permutation of the rows in which token 0 has ``k`` live choices,
+    token 1 one and token 2 none (as far as ``live`` allows), the others
+    what the seed deals them, and float32 ``weights``."""
+    n = tokens * k
+    rng = np.random.default_rng(seed)
+    alive, dead = (list(rng.permutation(np.arange(lo, hi)))
+                   for lo, hi in ((0, live), (live, n)))
+    slot_row = np.full((tokens, k), -1)
+
+    def deal(token, pile, count):
+        count = min(count, len(pile))
+        free = np.flatnonzero(slot_row[token] < 0)
+        for j in rng.permutation(free)[:count]:
+            slot_row[token, j] = pile.pop()
+
+    deal(0, alive, k)
+    deal(1, alive, 1), deal(1, dead, k - 1)
+    deal(2, dead, k)
+    rest = list(rng.permutation(alive + dead))
+    for token in range(tokens):
+        deal(token, rest, k)
+    assert sorted(slot_row.reshape(-1)) == list(range(n))
+    rows = rng.standard_normal((n, d)).astype(np.float32)
+    rows[live:] = np.nan
+    weights = rng.uniform(0.1, 1.0, (tokens, k)).astype(np.float32)
+    return (jnp.asarray(rows, dtype), jnp.asarray(slot_row, jnp.int32),
+            jnp.asarray(weights))
+
+
+@pytest.mark.parametrize("chunk", [16, 1024], ids=["chunks", "one_chunk"])
+@pytest.mark.parametrize("weighted", [True, False], ids=["gates", "ones"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("live", [0, 20, 82, 164],
+                         ids=["none", "eighth", "half", "all"])
+def test_token_sum_equals_a_loop_over_the_live_choices(
+        live, dtype, weighted, chunk, monkeypatch):
+    """The one token-side sum of a share (``_combine``'s forward with the
+    gates, ``_dispatch``'s gradient without) against a loop over each
+    token's choices, with every row from ``live`` on AND the buffer of
+    the runs poisoned: no live rows, an eighth, half and all ``T * k`` of
+    them; tokens with 0, 1 and ``k`` live choices; runs that cross the
+    chunks of 16 (164 slots: the last trip runs over the one before it);
+    24 columns."""
+    poisoned(monkeypatch)
+    monkeypatch.setattr(moe, "ROW_CHUNK", chunk)
+    rows, slot_row, weights = _token_sum_inputs(live, dtype)
+    got = moe._token_sum(rows, slot_row, weights if weighted else None,
+                         jnp.int32(live))
+    assert got.dtype == dtype and got.shape == (41, 24)
+    want = np.zeros((41, 24), np.float32)
+    for t, choices in enumerate(np.asarray(slot_row)):
+        for j, row in enumerate(choices):
+            if row < live:
+                want[t] += np.asarray(rows[row], np.float32) * (
+                    np.float32(weights[t, j]) if weighted else 1.0)
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    if live < 164:  # a token without a live choice reads exact zeros
+        assert (got[2] == 0).all()
+    tol = 1e-6 if dtype == jnp.float32 else 2.0 ** -8
+    assert np.abs(got - want).max() <= tol * max(np.abs(want).max(), 1.0)
+
+
+@pytest.mark.parametrize("chunk", [1024, 32], ids=["one_chunk", "chunks"])
+@pytest.mark.parametrize("first,held", [(0, E), (0, 2), (5, 3)],
+                         ids=["every_expert", "first2", "last3"])
+def test_token_rows_read_share_reads_what_the_index_says(
+        first, held, chunk, monkeypatch):
+    """The counter is the code's own: a token-side sum of a share fetches
+    ``chunk + k - 1`` rows a trip over the live rows and ``T`` at the
+    runs' ends; where every expert is held it is the one gather of a row
+    a (token, choice): 1."""
+    monkeypatch.setattr(moe, "ROW_CHUNK", chunk)
+    args = layer_inputs()
+    _, stats = moe.moe_block(
+        *args[:3], *(w[first:first + held] for w in args[3:]),
+        num_selected=K, tile=16, first_expert=first)
+    live = int(np.bincount(np.asarray(seeded_experts()).reshape(-1),
+                           minlength=E)[first:first + held].sum())
+    trip = min(chunk, T * K)
+    want = 1.0 if held == E else (
+        -(-live // trip) * (trip + K - 1) + T) / (T * K)
+    assert float(stats["token_rows_read_share"]) == pytest.approx(want)
+    assert float(stats["held_share"]) == pytest.approx(live / (T * K))

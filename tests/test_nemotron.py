@@ -8,6 +8,7 @@ plain reference (``benchmark/reference/nemotron_h.py``) on seeded weights
 in float32."""
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -19,55 +20,16 @@ import pytest
 from benchmark.reference import nemotron_h
 from ray_tpu.models.blocks import FFNS, MIXERS, mamba
 from ray_tpu.models.blocks.base import Ctx
-from ray_tpu.models.llama import (
-    LAYER_PATTERN, LlamaConfig, forward, init_params, loss_fn)
+from ray_tpu.models.llama import LAYER_PATTERN, forward, init_params
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.train.core import STEP_SCOPES, init_train_state, make_train_step
 from ray_tpu.util.tracing import scope_and_phase
+import tiny_models
+from tiny_models import (
+    ROWS, against_the_reference, apart as _apart, program, reference)
 
-PATTERN = "MEM*EMEME"  # longer than the model: only the first 5 are run
-# the reference's configuration (public key names) of the tiny model below
-CONF = dict(
-    hybrid_override_pattern=PATTERN, num_hidden_layers=5,
-    layer_norm_epsilon=1e-5, num_attention_heads=8, num_key_value_heads=2,
-    mamba_num_heads=8, mamba_head_dim=16, ssm_state_size=8, n_groups=2,
-    num_experts_per_tok=3, routed_scaling_factor=2.5, first_expert=4)
-
-
-def tiny(**kw) -> LlamaConfig:
-    """The published layers in small: M, E, M, *, E; 2 groups of 4 heads;
-    16 experts of width 32 of which this chip holds 4..11, 3 a token; a
-    shared expert of width 48; 8 query heads over 2 KV heads."""
-    fields = dict(
-        vocab_size=128, embed_dim=64, num_layers=5, layer_pattern=PATTERN,
-        num_heads=8, num_kv_heads=2, head_dim=16, position_embedding="nope",
-        norm_eps=1e-5, max_seq_len=64, dtype=jnp.float32, remat=False,
-        attn_impl="reference", ssm_heads=8, ssm_head_dim=16, ssm_state=8,
-        ssm_groups=2, ssm_conv=4, ssm_chunk=8,
-        ffn_act="relu2", mlp_dim=32, shared_experts=1, shared_mlp_dim=48,
-        num_experts=16, experts_held=8, first_expert=4, num_selected=3,
-        norm_topk_prob=True, topk_norm_eps=1e-20, router_scoring="sigmoid",
-        topk_method="noaux_tc", routed_scaling_factor=2.5, aux_loss_coef=0.0)
-    fields.update(kw)
-    return LlamaConfig(**fields)
-
-
-def seeded(cfg, seed=0):
-    """Parameters whose norm weights (and ``D``) are drawn away from 1, as
-    the train loop draws the norms for its check."""
-    rng = np.random.default_rng(seed)
-
-    def drawn(path, a):
-        name = str(getattr(path[-1], "key", ""))
-        if not (name.endswith("norm") or name == "D"):
-            return a
-        return a * rng.uniform(0.5, 1.5, a.shape).astype(np.float32)
-
-    return jax.tree_util.tree_map_with_path(
-        drawn, init_params(jax.random.PRNGKey(seed), cfg))
-
-
-TOKENS = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0, 128)
+TOKENS = ROWS["nemotron"].tokens
+tiny = functools.partial(tiny_models.tiny, "nemotron")
 HIGHEST = jax.default_matmul_precision("highest")
 
 
@@ -145,28 +107,10 @@ def test_the_residual_scheme_draws_what_writes_to_the_stream_smaller():
 # -- (b) the whole model against the reference --------------------------------
 
 def test_loss_per_token_loss_and_gradients_equal_the_plain_reference():
-    cfg = tiny()
-    params = seeded(cfg)
-    with HIGHEST:
-        total, parts = jax.jit(
-            lambda p: loss_fn(p, {"tokens": TOKENS}, cfg))(params)
-        want = nemotron_h.loss_parts(params, TOKENS, CONF)
-        logits, _ = forward(params, TOKENS[:, :-1], cfg)
-        ours = jax.grad(
-            lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(params)
-        theirs = jax.grad(lambda p: nemotron_h.loss(p, TOKENS, CONF))(params)
-    for name in ("loss", "moe_held_share"):
-        np.testing.assert_allclose(parts[name], want[name], rtol=2e-5)
-    np.testing.assert_allclose(total, want["total"], rtol=2e-5)
+    _, parts, _, ours = against_the_reference(
+        "nemotron", parts=("loss", "moe_held_share"), grad_rtol=2e-4)
     assert float(parts["moe_dropped"]) == 0.0
     assert 0.3 < float(parts["moe_held_share"]) < 0.7   # half are held
-    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
-                               TOKENS[:, 1:, None], -1)[..., 0]
-    np.testing.assert_allclose(nll, want["token_nll"], atol=3e-5)
-    apart = jax.tree.map(
-        lambda a, b: float(jnp.max(jnp.abs(a - b))
-                           / (jnp.max(jnp.abs(b)) + 1e-12)), ours, theirs)
-    assert max(jax.tree.leaves(apart)) < 2e-4, apart
     # every tensor has a gradient but the selection bias, which none reaches
     for stack in ours["layers"]:
         for name, g in stack.items():
@@ -177,19 +121,12 @@ def test_the_kernels_under_the_checkpoint_give_the_same_loss_and_gradients():
     """The same model as a chip runs it — the flash kernel and the grouped
     kernels interpreted, the layer checkpoint on — against the plain XLA
     forms of the test above."""
-    cfg, params = tiny(), seeded(tiny())
-    as_run = dataclasses.replace(cfg, attn_impl="flash", remat=True)
-    with HIGHEST:
-        (want, _), want_g = jax.value_and_grad(
-            lambda p: loss_fn(p, {"tokens": TOKENS}, cfg), has_aux=True)(
-                params)
-        (got, _), got_g = jax.jit(jax.value_and_grad(
-            lambda p: loss_fn(p, {"tokens": TOKENS}, as_run),
-            has_aux=True))(params)
+    params = program("nemotron").params
+    (want, _), want_g = program("nemotron").value_and_grad(params)
+    (got, _), got_g = program("nemotron", attn_impl="flash",
+                              remat=True).value_and_grad(params)
     np.testing.assert_allclose(got, want, rtol=1e-6)
-    apart = jax.tree.map(
-        lambda a, b: float(jnp.max(jnp.abs(a - b))
-                           / (jnp.max(jnp.abs(b)) + 1e-12)), got_g, want_g)
+    apart = _apart(got_g, want_g)
     assert max(jax.tree.leaves(apart)) < 1e-4, apart
 
 
@@ -220,7 +157,9 @@ def test_a_changed_part_stands_apart_from_the_reference(fault, monkeypatch):
     part changed stand apart from the reference's by a hundred times what
     the sound program's do (3e-5 at most, the test above)."""
     cfg = tiny()
-    params = program_params = seeded(cfg)
+    params = program_params = program("nemotron").params
+    # before any patch: the reference's answer is kept for the process
+    want = reference("nemotron").parts["token_nll"]
     if fault == "gate_in_place_of_relu2":
         cfg, program_params = _gate_in_place_of_relu2(cfg, params)
     elif fault == "norm_over_the_whole_width":
@@ -245,9 +184,9 @@ def test_a_changed_part_stands_apart_from_the_reference(fault, monkeypatch):
         cfg = dataclasses.replace(cfg, routed_scaling_factor=1.0)
     else:
         cfg = dataclasses.replace(cfg, first_expert=12)
-    with HIGHEST:
-        want = nemotron_h.loss_parts(params, TOKENS, CONF)["token_nll"]
-        logits, _ = forward(program_params, TOKENS[:, :-1], cfg)
+    with HIGHEST:   # a program of its own: traced under the patch
+        logits, _ = jax.jit(lambda p: forward(p, TOKENS[:, :-1], cfg))(
+            program_params)
     got = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
                                TOKENS[:, 1:, None], -1)[..., 0]
     apart = float(jnp.sqrt(jnp.mean(jnp.square(got - want))))
@@ -270,12 +209,15 @@ def _expert_layer(tokens=96, d=64, m=32, shared=48, experts=16, seed=3):
         shared_down=normal(keys[7], (shared, d)) * shared ** -0.5)
 
 
+@functools.partial(jax.jit, static_argnums=2)
 def _share(p, first, held):
     """The routed part alone of the chip that holds ``held`` experts from
-    ``first`` on, its step counters beside it."""
+    ``first`` on, its step counters beside it; one program, ``first``
+    traced."""
     return moe_block(
         p["x"], p["mlp_norm"], p["router"], None,
-        p["w_up"][first:first + held], p["w_down"][first:first + held],
+        *(jax.lax.dynamic_slice_in_dim(p[w], first, held)
+          for w in ("w_up", "w_down")),
         num_selected=3, norm_topk_prob=True, topk_norm_eps=1e-20,
         scoring="sigmoid", select_bias=p["router_bias"], gate_scale=2.5,
         first_expert=first, residual=False)

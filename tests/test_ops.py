@@ -38,6 +38,30 @@ def _qkv(b, sq, sk, h, h_kv, d, dtype):
             jax.random.normal(keys[2], (b, sk, h_kv, d), jnp.float32).astype(dtype))
 
 
+def _value_and_grads(fn, loss, *args):
+    """``fn(*args)`` and the gradients of ``loss(fn(*args))`` to every
+    argument, as ONE compiled program."""
+    def scalar(*a):
+        out = fn(*a)
+        return loss(out), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        scalar, tuple(range(len(args))), has_aux=True))(*args)
+    return out, grads
+
+
+def _sum_of_squares(out):
+    return (out.astype(jnp.float32) ** 2).sum()
+
+
+@pytest.fixture(scope="module")
+def causal_reference(qkv):
+    """``mha_reference`` on the module's q, k, v and its gradients: once
+    for every tile schedule."""
+    return _value_and_grads(lambda *a: mha_reference(*a, causal=True),
+                            _sum_of_squares, *qkv)
+
+
 # (b, sq, sk, h, h_kv, d, dtype, causal, block caps) -> the tiles
 # ``choose_tiles`` picks and the kinds of tile they produce.
 FLASH_CASES = {
@@ -82,12 +106,12 @@ def test_flash_attention(case, grads):
     # bf16 carries 8 bits: one ulp of an O(1) output is 2**-8
     tol = 1e-4 if dtype == jnp.float32 else 2e-2
     if not grads:
-        err = jnp.max(jnp.abs(f32(flash(q, k, v)) - f32(ref(q, k, v))))
+        err = jnp.max(jnp.abs(f32(jax.jit(flash)(q, k, v))
+                              - f32(jax.jit(ref)(q, k, v))))
         assert err < tol
         return
-    loss = lambda fn: lambda *a: (f32(fn(*a)) ** 2).sum()
-    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
-    want = jax.grad(loss(ref), (0, 1, 2))(q, k, v)
+    _, got = _value_and_grads(flash, _sum_of_squares, q, k, v)
+    _, want = _value_and_grads(ref, _sum_of_squares, q, k, v)
     for a, w in zip(got, want):
         scale = max(1.0, float(jnp.max(jnp.abs(f32(w)))))
         assert jnp.max(jnp.abs(f32(a) - f32(w))) < 10 * tol * scale
@@ -99,7 +123,7 @@ def test_flash_attention(case, grads):
     # strips whose masked part is all of them; an interior tile of 4 strips
     (64, 64, 64, 16), (32, 64, 16, 16)],
     ids=lambda t: "x".join(map(str, t)))
-def test_flash_tile_schedules(qkv, tiles):
+def test_flash_tile_schedules(qkv, causal_reference, tiles):
     """Any fetch tile and sub-tile give the reference's numbers: square
     and rectangular, sub-tile wider than tall and the reverse, tiles that
     straddle the diagonal at several offsets, interior tiles that the
@@ -107,12 +131,12 @@ def test_flash_tile_schedules(qkv, tiles):
     from ray_tpu.ops.attention import _flash
 
     q, k, v = qkv
-    f = lambda *a: (_flash(*a, D ** -0.5, True, tiles, True) ** 2).sum()
-    g = lambda *a: (mha_reference(*a, causal=True) ** 2).sum()
-    out = _flash(q, k, v, D ** -0.5, True, tiles, True)
-    assert jnp.max(jnp.abs(out - mha_reference(q, k, v, causal=True))) < 1e-4
-    for a, w in zip(jax.grad(f, (0, 1, 2))(q, k, v),
-                    jax.grad(g, (0, 1, 2))(q, k, v)):
+    out, got = _value_and_grads(
+        lambda *a: _flash(*a, D ** -0.5, True, tiles, True),
+        _sum_of_squares, q, k, v)
+    want, ref = causal_reference
+    assert jnp.max(jnp.abs(out - want)) < 1e-4
+    for a, w in zip(got, ref):
         assert jnp.max(jnp.abs(a - w)) < 1e-3
 
 
@@ -209,8 +233,12 @@ def test_windowed_flash_kernels_against_the_reference(qkv, window, tiles):
     from ray_tpu.ops.attention import _flash
 
     q, k, v = qkv
-    out = _flash(q, k, v, D ** -0.5, True, tiles, True, window)
-    want = mha_reference(q, k, v, causal=True, window=window)
+    out, got = _value_and_grads(
+        lambda *a: _flash(*a, D ** -0.5, True, tiles, True, window),
+        _sum_of_squares, q, k, v)
+    want, ref = _value_and_grads(
+        lambda *a: mha_reference(*a, causal=True, window=window),
+        _sum_of_squares, q, k, v)
     mask = jnp.asarray([[_seen(i, j, window) for j in range(S)]
                         for i in range(S)])
     scores = jnp.where(mask, jnp.einsum("bqhd,bkhd->bhqk", q, k)
@@ -219,11 +247,7 @@ def test_windowed_flash_kernels_against_the_reference(qkv, window, tiles):
                              jax.nn.softmax(scores, -1), v)
     assert jnp.max(jnp.abs(want - written_out)) < 1e-5
     assert jnp.max(jnp.abs(out - want)) < 1e-4
-    f = lambda *a: (_flash(*a, D ** -0.5, True, tiles, True, window)
-                    ** 2).sum()
-    g = lambda *a: (mha_reference(*a, causal=True, window=window) ** 2).sum()
-    for a, w in zip(jax.grad(f, (0, 1, 2))(q, k, v),
-                    jax.grad(g, (0, 1, 2))(q, k, v)):
+    for a, w in zip(got, ref):
         assert jnp.max(jnp.abs(a - w)) < 1e-3
 
 
@@ -401,12 +425,13 @@ def test_flash_reads_heads_where_they_stand(rep, d, dv, mode):
                                             **kw)
     ref = lambda q, k, v: mha_reference(
         q, jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2), **kw)
-    loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))
-    out, grads = flash(q, k, v), jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    loss = lambda out: jnp.sum(jnp.sin(out))
+    out, grads = _value_and_grads(flash, loss, q, k, v)
+    want, ref_grads = _value_and_grads(ref, loss, q, k, v)
     assert out.shape == (1, 128, 2 * rep, dv)
     assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
-    assert jnp.max(jnp.abs(out - ref(q, k, v))) < 1e-4
-    for a, w in zip(grads, jax.grad(loss(ref), (0, 1, 2))(q, k, v)):
+    assert jnp.max(jnp.abs(out - want)) < 1e-4
+    for a, w in zip(grads, ref_grads):
         assert jnp.max(jnp.abs(a - w)) < 1e-4 * max(1.0, float(
             jnp.max(jnp.abs(w))))
 
@@ -622,7 +647,8 @@ def test_moe_routing_mass_conservation():
     wu = jax.random.normal(jax.random.PRNGKey(3), (4, 16, 32)) * 0.1
     wd = jax.random.normal(jax.random.PRNGKey(4), (4, 32, 16)) * 0.1
     norm = jnp.ones((16,))
-    out, stats = moe_block(x, norm, rw, wg, wu, wd, num_selected=2)
+    layer = jax.jit(lambda *w: moe_block(x, norm, rw, *w, num_selected=2))
+    out, stats = layer(wg, wu, wd)
     assert out.shape == x.shape
     assert jnp.isfinite(out).all()
     assert float(stats["aux_loss"]) > 0
@@ -630,13 +656,12 @@ def test_moe_routing_mass_conservation():
     # All experts equal: the layer is one expert scaled by each token's
     # top-2 gate mass, whatever the routing.
     same = [jnp.broadcast_to(w[:1], w.shape) for w in (wg, wu, wd)]
-    out, _ = moe_block(x, norm, rw, *same, num_selected=2)
+    out, _ = layer(*same)
     h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
     gates, _ = jax.lax.top_k(jax.nn.softmax(h @ rw, -1), 2)
     one = (jax.nn.silu(h @ wg[0]) * (h @ wu[0])) @ wd[0]
     assert jnp.allclose(out - x, gates.sum(-1, keepdims=True) * one,
                         atol=1e-5)
     # gradient flows to every expert weight
-    g = jax.grad(lambda w: (moe_block(x, norm, rw, w, wu, wd,
-                                      num_selected=2)[0] ** 2).sum())(wg)
+    g = jax.jit(jax.grad(lambda w: (layer(w, wu, wd)[0] ** 2).sum()))(wg)
     assert float(jnp.abs(g).sum(axis=(1, 2)).min()) > 0

@@ -61,8 +61,9 @@ def test_paged_attention_matches_mha_reference(h, d, bs):
             k, v, n = _gathered(kc, vc, bt, cls, b, bs)
             # One decode query at position n-1 attending to the whole
             # context == causal attention with q_offset = n-1.
-            ref = mha_reference(q[b][None, None], k[None], v[None],
-                                causal=True, q_offset=n - 1)
+            ref = jax.jit(lambda q, k, v: mha_reference(
+                q, k, v, causal=True, q_offset=n - 1))(
+                    q[b][None, None], k[None], v[None])
             assert float(jnp.max(jnp.abs(out[b] - ref[0, 0]))) < 1e-5, \
                 (trial, b)
 

@@ -167,29 +167,36 @@ def test_autoscaling_up_and_down(ray8):
     h = serve.run(Slow.bind(), name="auto")
     from ray_tpu.serve.api import _get_controller
     controller = _get_controller()
-    assert ray.get(controller.num_replicas.remote("auto")) == 1
 
-    # sustained load: keep ~8 in flight for a few seconds
-    stop = time.monotonic() + 6
+    def replicas():
+        return ray.get(controller.num_replicas.remote("auto"))
+
+    def events(kind):
+        return ray.get(controller.serving_stats.remote("auto"))[kind]
+
+    assert replicas() == 1
+    ups, downs = events("scale_ups"), events("scale_downs")  # the deploy's
+    # load: 8 in flight, four times one replica's target, for as long as
+    # it takes the controller to COUNT a scale-up and run a second
+    # replica; the deadline is for a hang, not for the pace of the host
+    deadline = time.monotonic() + 120
     refs = []
-    peak = 1
-    while time.monotonic() < stop:
+    while not (events("scale_ups") > ups and replicas() >= 2):
+        assert time.monotonic() < deadline, "never scaled up"
         refs = [r for r in refs
                 if not ray.wait([r], num_returns=1, timeout=0)[0]]
         while len(refs) < 8:
             refs.append(h.remote({}))
-        peak = max(peak, ray.get(controller.num_replicas.remote("auto")))
-        time.sleep(0.2)
-    assert peak >= 2, f"never scaled up (peak={peak})"
+        time.sleep(0.05)
+    assert replicas() <= 3
     for r in refs:
         ray.get(r, timeout=60)
-    # idle: back to min after the downscale delay
-    deadline = time.monotonic() + 25
-    while time.monotonic() < deadline:
-        if ray.get(controller.num_replicas.remote("auto")) == 1:
-            return
-        time.sleep(0.5)
-    raise AssertionError("never scaled back down to min_replicas")
+    # idle: the handle's samples age out, the downscale delay passes, and
+    # the controller counts a scale-down on its way back to min_replicas
+    deadline = time.monotonic() + 120
+    while not (events("scale_downs") > downs and replicas() == 1):
+        assert time.monotonic() < deadline, "never scaled back down"
+        time.sleep(0.1)
 
 
 def test_rolling_update_changes_version(ray8):
@@ -391,6 +398,13 @@ def test_redeploy_same_name_ignores_stale_handle_metrics(ray8):
     stale_inc = ray.get(
         controller.deployment_incarnation.remote("redeploy"))
     ray.get(controller.delete_deployment.remote("redeploy"))
+    # A fence, not a pause: ticks are serialised, so when this one returns
+    # no tick that read A's spec before the delete is still running.  One
+    # that is, and ends after the redeploy below, writes ITS replicas (A's)
+    # over B's — ``_reconcile_once`` asks whether the NAME is deployed, not
+    # the incarnation — and the fresh handle then answers "a": seen once
+    # under the driver's load (ROADMAP D11).
+    ray.get(controller.reconcile.remote())
 
     @serve.deployment(autoscaling_config=cfg)
     class B:
@@ -406,14 +420,13 @@ def test_redeploy_same_name_ignores_stale_handle_metrics(ray8):
     # new incarnation along with the replica set, so a handle that
     # keeps being used after a redeploy reports under the fresh key
     # instead of being dropped forever.
-    deadline = time.monotonic() + 15
-    while time.monotonic() < deadline:
+    deadline = time.monotonic() + 120    # for a hang, not for the pace
+    while True:
         with handle._lock:
             if handle._incarnation == new_inc:
                 break
-        time.sleep(0.2)
-    with handle._lock:
-        assert handle._incarnation == new_inc
+        assert time.monotonic() < deadline, "the old handle never re-keyed"
+        time.sleep(0.05)
     # The stale handle screams "12 ongoing" (dangling refs against dead
     # replicas).  Keyed by incarnation, the report is dropped...
     assert ray.get(controller.record_handle_metric.remote(
@@ -421,16 +434,17 @@ def test_redeploy_same_name_ignores_stale_handle_metrics(ray8):
     for _ in range(3):
         ray.get(controller.reconcile.remote())
     assert ray.get(controller.num_replicas.remote("redeploy")) == 1
-    # ...while a current-incarnation report still drives scaling.
-    assert ray.get(controller.record_handle_metric.remote(
-        "redeploy", "live-handle", 4, new_inc)) is True
-    deadline = time.monotonic() + 10
-    while time.monotonic() < deadline:
+    # ...while a current-incarnation report still drives scaling: a live
+    # handle keeps reporting (a sample ages out of the look-back window,
+    # however long the host takes to start three replicas), and the
+    # verdict is the controller's own count of replicas and scale-ups.
+    deadline = time.monotonic() + 120    # for a hang, not for the pace
+    while ray.get(controller.num_replicas.remote("redeploy")) != 4:
+        assert time.monotonic() < deadline, "the live report never scaled"
+        assert ray.get(controller.record_handle_metric.remote(
+            "redeploy", "live-handle", 4, new_inc)) is True
         ray.get(controller.reconcile.remote())
-        if ray.get(controller.num_replicas.remote("redeploy")) == 4:
-            break
-        time.sleep(0.2)
-    assert ray.get(controller.num_replicas.remote("redeploy")) == 4
+        time.sleep(0.05)
     stats = ray.get(controller.serving_stats.remote("redeploy"))
     assert stats["scale_ups"] >= 1
 

@@ -166,10 +166,13 @@ def test_paged_packs_past_dense_slot_cap():
 
     def stepfn(slots):
         peak["live"] = max(peak["live"], len(slots))
-        # Long enough for the 48 submitting threads to queue up behind a
-        # step on a loaded host (at 2 ms the batch drained as fast as a
-        # busy machine admitted, and live never passed 8).
-        time.sleep(0.02)
+        # The first step holds until the batcher has COUNTED all 48
+        # submissions: what the next boundary packs is then decided by
+        # the free blocks, not by how fast a loaded host starts threads.
+        deadline = time.monotonic() + 60         # for a hang alone
+        while b.stats()["admitted"] < 48:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
         for s in slots:
             s.state = (s.state or 0) + 1
             if s.state >= s.request["tokens"]:
@@ -179,7 +182,7 @@ def test_paged_packs_past_dense_slot_cap():
     reqs = [{"id": i, "tokens": 4} for i in range(48)]
     results, errors = _drive(b, reqs)
     assert not errors and len(results) == 48
-    assert peak["live"] > 8, peak                # past the dense HBM cap
+    assert peak["live"] == 32, peak     # every block held: 4x the dense cap
     assert b.stats()["batch_occupancy"] > 8
 
 

@@ -6,13 +6,13 @@ tiny sizes.  The model's yardstick is the benchmark's own plain reference
 time, nothing shared with the code under test)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.reference import granite_hybrid
 from ray_tpu.models.blocks import mamba
 from ray_tpu.models.llama import (
     LlamaConfig, forward_pipelined, init_params, loss_fn,
@@ -23,6 +23,8 @@ from ray_tpu.ops.ssm import (
     ssd_chunked, ssd_kernels, ssd_reference, ssd_xla)
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.train.core import init_train_state, train_state_shardings
+import tiny_models
+from tiny_models import ROWS, against_the_reference, program, reference
 
 HIGHEST = jax.default_matmul_precision("highest")
 
@@ -37,9 +39,16 @@ def _scan_inputs(seq, seed=0, batch=2, heads=4, p=8, groups=2, n=16):
             f(batch, seq, groups, n), f(heads))
 
 
-def _grads(form, args, weight):
-    return jax.jit(jax.grad(lambda *t: jnp.sum(form(*t) * weight),
-                            argnums=range(6)))(*args)
+def _value_and_grads(form, args, weight):
+    """``form(*args)`` and the gradients of its sum weighted by ``weight``
+    (in float32) to all six arguments: ONE compiled program."""
+    def scalar(*t):
+        out = form(*t)
+        return jnp.sum(out.astype(jnp.float32) * weight), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        scalar, argnums=range(6), has_aux=True))(*args)
+    return out, grads
 
 
 @pytest.mark.parametrize("form", ["chunked", "kernels"])
@@ -62,11 +71,11 @@ def test_ssd_chunked_equals_the_recurrence(seq, chunk, form):
     forms = (lambda *t: run(*t, chunk=chunk), ssd_reference,
              lambda *t: ssd_xla(*t, chunk=chunk))
     with HIGHEST:
-        got, want, xla = (jax.jit(f)(*args) for f in forms)
-        assert got.shape == want.shape and got.dtype == args[0].dtype
-        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-        np.testing.assert_allclose(got, xla, atol=2e-5, rtol=2e-5)
-        grads = [_grads(f, args, weight) for f in forms]
+        (got, want, xla), grads = zip(*(
+            _value_and_grads(f, args, weight) for f in forms))
+    assert got.shape == want.shape and got.dtype == args[0].dtype
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(got, xla, atol=2e-5, rtol=2e-5)
     for name, g, w, x in zip("x dt a b c d".split(), *grads):
         assert np.all(np.isfinite(g)), name
         np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4, err_msg=name)
@@ -129,14 +138,11 @@ def test_several_groups_at_chunks_of_128_equal_the_recurrence(dtype, tol,
         size=args[0].shape), jnp.float32)
     chunked = lambda *t: ssd_chunked(*t, chunk=128)  # noqa: E731
     with HIGHEST:
-        want = ssd_reference(*args)
-        want_grads = _grads(ssd_reference, args, weight)
-        got = jax.jit(chunked)(*cast)
-        grads = _grads(lambda *t: chunked(*t).astype(jnp.float32), cast,
-                       weight)
+        want, want_grads = _value_and_grads(ssd_reference, args, weight)
+        got, grads = _value_and_grads(chunked, cast, weight)
         x, dt, a, b, c, d = args
         first = lambda t: jnp.repeat(t[:, :, :1], 4, 2)  # noqa: E731
-        wrong = ssd_reference(x, dt, a, first(b), first(c), d)
+        wrong = jax.jit(ssd_reference)(x, dt, a, first(b), first(c), d)
     assert calls and all(shape == (1, 300, 8, 64) for shape in calls)
     assert got.shape == want.shape and got.dtype == dtype
     assert _rms_apart(got, want) < tol
@@ -184,8 +190,8 @@ def test_a_groups_heads_read_its_b_and_c_whatever_the_blocking(
     forms = (lambda *t: ssd_chunked(*t, chunk=128), ssd_reference,
              lambda *t: ssd_xla(*t, chunk=128))
     with HIGHEST:
-        got, want, xla = (jax.jit(f)(*args) for f in forms)
-        grads = [_grads(f, args, weight) for f in forms]
+        (got, want, xla), grads = zip(*(
+            _value_and_grads(f, args, weight) for f in forms))
     assert calls and all(shape == (2, 300, heads, p) for shape in calls)
     assert _rms_apart(got, want) < 1e-4 and _rms_apart(got, xla) < 1e-4
     for name, g, w, x in zip("x dt a b c d".split(), *grads):
@@ -303,12 +309,10 @@ def test_published_sizes_take_the_kernels(dtype, tol, monkeypatch):
     weight = jnp.asarray(np.random.default_rng(2).normal(
         size=args[0].shape), jnp.float32)
     with HIGHEST:
-        want = ssd_reference(*args)
-        want_grads = _grads(ssd_reference, args, weight)
-        out = [(jax.jit(f)(*cast), _grads(
-            lambda *t: f(*t).astype(jnp.float32), cast, weight))
-            for f in (lambda *t: ssd_chunked(*t, chunk=256),
-                      lambda *t: ssd_xla(*t, chunk=256))]
+        want, want_grads = _value_and_grads(ssd_reference, args, weight)
+        out = [_value_and_grads(f, cast, weight)
+               for f in (lambda *t: ssd_chunked(*t, chunk=256),
+                         lambda *t: ssd_xla(*t, chunk=256))]
     assert calls and all(shape == (1, 600, 4, 64) for shape in calls)
     for got, grads in out:
         assert got.shape == want.shape and got.dtype == dtype
@@ -464,70 +468,10 @@ def test_causal_conv1d_is_the_direct_sum_and_causal():
 
 # ------------------------------------------------- the hybrid model ------
 
-# The public key names of a granitemoehybrid config.json, at CPU size:
-# mamba, attention, mamba (of a longer published list); no multiplier 1;
-# one group, as published (its reference norms the gated output whole:
-# several groups, each normed apart, are ``tests/test_nemotron.py``'s).
-CONF = {
-    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
-    "shared_intermediate_size": 96, "vocab_size": 256, "rms_norm_eps": 1e-5,
-    "num_hidden_layers": 3,
-    "layer_types": ["mamba", "attention", "mamba", "mamba", "attention"],
-    "mamba_n_heads": 8, "mamba_d_head": 16, "mamba_d_state": 8,
-    "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_chunk_size": 8,
-    "position_embedding_type": "nope", "attention_multiplier": 0.1,
-    "embedding_multiplier": 3.0, "residual_multiplier": 0.5,
-    "logits_scaling": 2.0, "tie_word_embeddings": True,
-}
-
-
-def _cfg(**kw):
-    fields = dict(
-        vocab_size=256, embed_dim=64, num_layers=3, num_heads=4,
-        num_kv_heads=2, head_dim=16, mlp_dim=96, norm_eps=1e-5,
-        layer_types=CONF["layer_types"], ssm_heads=8, ssm_head_dim=16,
-        ssm_state=8, ssm_groups=1, ssm_conv=4, ssm_chunk=8,
-        position_embedding="nope", attention_multiplier=0.1,
-        embedding_multiplier=3.0, residual_multiplier=0.5,
-        logits_scaling=2.0, tie_embeddings=True, max_seq_len=64,
-        dtype=jnp.float32, remat=True, attn_impl="flash")
-    fields.update(kw)
-    return LlamaConfig(**fields)
-
-
-def _drawn(params, seed=5):
-    """Norm weights drawn away from 1, as the benchmark's check draws
-    them, and ``D`` away from its initial 1."""
-    rng = np.random.default_rng(seed)
-
-    def leaf(path, a):
-        name = str(getattr(path[-1], "key", ""))
-        if name.endswith("norm") or name == "D":
-            return a * jnp.asarray(rng.uniform(0.5, 1.5, a.shape), a.dtype)
-        return a
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
-TOKENS = jnp.asarray(np.random.default_rng(7).integers(
-    0, 256, (2, 41), dtype=np.int32))
+TOKENS = ROWS["granite"].tokens
+_cfg = functools.partial(tiny_models.tiny, "granite")
+_drawn = functools.partial(tiny_models.drawn, seed=5, also=("D",))
 LOSS_TOL, GRAD_TOL = 2e-6, 2e-4   # float32 against float32, relative
-
-
-def _apart(cfg, params):
-    """(loss, gradients) of the program apart from the reference's,
-    relative: the loss, and the largest gradient leaf's difference over
-    that leaf's own scale."""
-    with HIGHEST:
-        loss, grads = jax.jit(jax.value_and_grad(
-            lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0]))(params)
-        want, want_grads = jax.jit(jax.value_and_grad(
-            lambda p: granite_hybrid.loss(p, TOKENS, CONF)))(params)
-    apart = jax.tree.map(
-        lambda g, w: float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w))),
-        grads, want_grads)
-    return (abs(float(loss) - float(want)) / float(want),
-            max(jax.tree.leaves(apart)))
 
 
 def test_hybrid_loss_and_gradients_equal_the_plain_reference():
@@ -536,12 +480,12 @@ def test_hybrid_loss_and_gradients_equal_the_plain_reference():
     reference, which computes the recurrence a token at a time: the loss
     within 2e-6, every gradient leaf within 2e-4 of its scale — the tied
     table's too, whose gradient is the sum of both of its uses."""
-    cfg = _cfg()
-    assert cfg.layer_runs == (("mamba", 1), ("attention", 1), ("mamba", 1))
-    params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
-    assert "lm_head" not in params and len(params["layers"]) == 3
-    loss, grads = _apart(cfg, params)
-    assert loss <= LOSS_TOL and grads <= GRAD_TOL, (loss, grads)
+    ours = program("granite")
+    assert ours.cfg.layer_runs == (
+        ("mamba", 1), ("attention", 1), ("mamba", 1))
+    assert "lm_head" not in ours.params and len(ours.params["layers"]) == 3
+    against_the_reference("granite", parts=(), rtol=LOSS_TOL, nll_atol=None,
+                          grad_rtol=GRAD_TOL)
 
 
 def _no_d(params):
@@ -563,8 +507,9 @@ def test_structural_controls_fail_against_the_reference(wrong, monkeypatch):
     after the norm.  Each moves the loss fifty tolerances or more (RoPE,
     the least, a hundred: one small attention layer of three)."""
     cfg = _cfg(attn_impl="reference", remat=False)
-    params = _drawn(init_params(jax.random.PRNGKey(0), cfg))
-    program_params = params
+    params = program_params = program("granite").params
+    # before any patch: the reference's answer is kept for the process
+    want = float(reference("granite").parts["total"])
     if wrong == "D dropped":
         program_params = _no_d(params)
     elif wrong == "gate after the norm":
@@ -576,7 +521,6 @@ def test_structural_controls_fail_against_the_reference(wrong, monkeypatch):
     with HIGHEST:
         loss = float(jax.jit(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(
             program_params))
-        want = float(granite_hybrid.loss(params, TOKENS, CONF))
     assert abs(loss - want) / want > 50 * LOSS_TOL, (loss, want)
 
 

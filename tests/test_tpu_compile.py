@@ -108,6 +108,13 @@ def _flash_sum(q, k, v):
 _flash_grads = jax.grad(_flash_sum, argnums=(0, 1, 2))
 
 
+@functools.lru_cache(maxsize=None)
+def _lowered_flash_grads(shape, dv, sharding):
+    """The backward pass at a shape, lowered once for the test that
+    compiles it and the one that counts its kernels."""
+    return jax.jit(_flash_grads).lower(*_flash_shapes(shape, dv, sharding))
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("shape,dv", [
     ((8, 2048, 32, 128), 128),  # the smoke's: one fetch tile, walked in sub-tiles
@@ -121,17 +128,17 @@ def test_flash_attention_compiles(one_chip, shape, dv, grad):
     """Sub-tile slices, the masked part's concatenation, the clamped
     index maps, the dk/dv kernel's row stats, transposed mask and strip
     loop are what Mosaic could refuse."""
-    fn = _flash_grads if grad else _flash_sum
-    assert _has_kernel(
-        jax.jit(fn).lower(*_flash_shapes(shape, dv, one_chip)).compile())
+    lowered = (_lowered_flash_grads(shape, dv, one_chip) if grad
+               else jax.jit(_flash_sum).lower(
+                   *_flash_shapes(shape, dv, one_chip)))
+    assert _has_kernel(lowered.compile())
 
 
 def test_flash_backward_lowers_each_kernel_once(one_chip):
     """A backward pass at Xing4's head sizes holds ONE dk/dv kernel (its
     interior tile is a loop inside the kernel, not a second call), one dq
     kernel, and the forward that makes the residuals."""
-    text = jax.jit(_flash_grads).lower(
-        *_flash_shapes(*XING4_HEADS, one_chip)).as_text()
+    text = _lowered_flash_grads(*XING4_HEADS, one_chip).as_text()
     assert [text.count(f'kernel_name = "{name}"')
             for name in ("flash_fwd", "flash_dkv", "flash_dq")] == [1, 1, 1]
 

@@ -21,69 +21,21 @@ from benchmark.loops import train
 from benchmark.reference import afmoe
 from ray_tpu.models.blocks import MIXERS, attention as attention_block
 from ray_tpu.models.llama import (
-    LlamaConfig, forward, init_params, loss_and_counts, loss_fn)
+    LlamaConfig, init_params, loss_and_counts)
 from ray_tpu.ops.attention import causal_tile_counts, choose_tiles
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.train.core import (
     default_optimizer, init_train_state, make_train_step)
+import tiny_models
+from tiny_models import (
+    F, ROWS, S, TRINITY_WINDOW, against_the_reference,
+    program, reference, side_of)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "trinity-large-preview-1of32"
-S, F = "sliding_attention", "full_attention"
-PATTERN = (S, S, S, F, S)      # the file's: one dense layer, then s s f s
-WINDOW, SEQ = 16, 48           # a third of each later row's keys cut off
-# the reference's configuration (public key names) of the tiny model below
-CONF = dict(
-    layer_types=list(PATTERN), num_hidden_layers=5, num_dense_layers=1,
-    num_attention_heads=4, num_key_value_heads=2, hidden_size=64,
-    rope_theta=10000, rms_norm_eps=1e-5, sliding_window=WINDOW,
-    num_experts_per_tok=2, route_scale=2.448, first_expert=4,
-    mup_enabled=True)
-
-
-def tiny(**kw) -> LlamaConfig:
-    fields = dict(
-        vocab_size=128, embed_dim=64, num_layers=5, num_heads=4,
-        num_kv_heads=2, head_dim=16, mlp_dim=32, dense_mlp_dim=96,
-        max_seq_len=64, dtype=jnp.float32, remat=False,
-        attn_impl="reference", rope_theta=1e4, norm_eps=1e-5,
-        layer_types=PATTERN, sliding_window=WINDOW, attn_output_gate=True,
-        block_norm="sandwich", post_norm_init=0.25,
-        position_embedding="rope_windowed",
-        qk_head_norm=True, embedding_multiplier=8.0, embed_init_std=0.125,
-        num_experts=16, num_selected=2, norm_topk_prob=True,
-        topk_norm_eps=1e-20, experts_held=4, first_expert=4,
-        shared_experts=1, router_scoring="sigmoid", topk_method="noaux_tc",
-        routed_scaling_factor=2.448, leading_dense=1, aux_loss_coef=0.0)
-    fields.update(kw)
-    return LlamaConfig(**fields)
-
-
-def seeded(cfg, seed=0):
-    """Parameters whose norm weights are drawn away from 1, as the train
-    loop draws them for its check."""
-    rng = np.random.default_rng(seed)
-
-    def drawn(path, a):
-        if not str(getattr(path[-1], "key", "")).endswith("norm"):
-            return a
-        return a * rng.uniform(0.5, 1.5, a.shape).astype(np.float32)
-
-    return jax.tree_util.tree_map_with_path(
-        drawn, init_params(jax.random.PRNGKey(seed), cfg))
-
-
-TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ + 1), 0, 128)
-
-
-def _program_loss(cfg, params):
-    return jax.jit(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg))(params)
-
-
-def _apart(ours, theirs):
-    return jax.tree.map(
-        lambda a, b: float(jnp.max(jnp.abs(a - b))
-                           / (jnp.max(jnp.abs(b)) + 1e-12)), ours, theirs)
+CONF, TOKENS = ROWS["trinity"].conf, ROWS["trinity"].tokens
+WINDOW, SEQ = TRINITY_WINDOW, TOKENS.shape[1] - 1
+tiny = functools.partial(tiny_models.tiny, "trinity")
 
 
 # -- the model against the reference -------------------------------------------
@@ -124,46 +76,18 @@ def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
     entry (the selection is discrete: a swapped expert would read 1e-2 and
     more).  Once with the XLA attention, once with the windowed flash
     kernels (interpreted) under the layer checkpoint, the chip's path."""
-    cfg = tiny() if impl == "reference" else tiny(attn_impl="flash",
-                                                  remat=True)
-    params = seeded(cfg)
-    total, parts = _program_loss(cfg, params)
-    want = afmoe.loss_parts(params, TOKENS, CONF)
-    np.testing.assert_allclose(total, want["total"], rtol=2e-5)
-    np.testing.assert_allclose(parts["loss"], want["loss"], rtol=2e-5)
+    kw = {} if impl == "reference" else dict(attn_impl="flash", remat=True)
+    _, parts, want, ours = against_the_reference("trinity", **kw)
     np.testing.assert_allclose(parts["moe_held_share"],
                                want["moe_held_share"], rtol=1e-6)
     assert 0.1 < float(parts["moe_held_share"]) < 0.5
     assert float(parts["moe_dropped"]) == 0.0
     assert len(want["experts"]) == 4
-    logits, _ = forward(params, TOKENS[:, :-1], cfg)
-    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
-                               TOKENS[:, 1:, None], -1)[..., 0]
-    np.testing.assert_allclose(nll, want["token_nll"], atol=3e-5)
-    ours = jax.grad(lambda p: loss_fn(p, {"tokens": TOKENS}, cfg)[0])(params)
-    theirs = jax.grad(lambda p: afmoe.loss(p, TOKENS, CONF))(params)
-    apart = _apart(ours, theirs)
-    assert max(jax.tree.leaves(apart)) < 1e-4, apart
     # every tensor of every kind of layer has a gradient but the selection
     # bias, which no gradient reaches
     for run in ours["layers"]:
         for name, g in run.items():
             assert np.any(np.asarray(g)) == (name != "router_bias"), name
-
-
-@functools.lru_cache(maxsize=None)
-def _sound():
-    """The tiny model, its seeded parameters and the reference's per-token
-    losses on them, once for every case below."""
-    cfg = tiny()
-    params = seeded(cfg)
-    return cfg, params, afmoe.loss_parts(params, TOKENS, CONF)["token_nll"]
-
-
-def _token_nll(cfg, params):
-    logits, _ = forward(params, TOKENS[:, :-1], cfg)
-    return -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
-                                TOKENS[:, 1:, None], -1)[..., 0]
 
 
 @pytest.mark.parametrize("change", [
@@ -178,7 +102,9 @@ def test_a_changed_part_stands_apart_from_the_reference(change):
     EITHER kind of layer, the window dropped or a key short, the gate, the
     second norms, the per-head norm, muP's factor, the selection bias,
     ``route_scale``, the shared expert."""
-    cfg, params, want = _sound()
+    sound = program("trinity")
+    cfg, params = sound.cfg, sound.params
+    want = reference("trinity").parts["token_nll"]
     wrong, p = cfg, params
     if change == "rope-in-the-full-layer-too":
         wrong = dataclasses.replace(cfg, position_embedding="rope")
@@ -208,8 +134,9 @@ def test_a_changed_part_stands_apart_from_the_reference(change):
         wrong = dataclasses.replace(cfg, routed_scaling_factor=1.0)
     elif change == "no-shared-expert":
         wrong = dataclasses.replace(cfg, shared_experts=0)
-    np.testing.assert_allclose(_token_nll(cfg, params), want, atol=3e-5)
-    assert float(jnp.max(jnp.abs(_token_nll(wrong, p) - want))) > 1e-3
+    np.testing.assert_allclose(sound.token_nll(params), want, atol=3e-5)
+    got = side_of("trinity", wrong, p).token_nll(p)
+    assert float(jnp.max(jnp.abs(got - want))) > 1e-3
 
 
 def test_rotary_positions_follow_the_kind_of_layer():
@@ -247,10 +174,13 @@ def _expert_layer(seed=3, tokens=96, d=32, m=16, experts=32):
             "shared_down": n(m, d) * m ** -0.5}
 
 
+@functools.partial(jax.jit, static_argnums=2)
 def _share(p, first, held):
+    """One program for every share: ``first`` is traced."""
     return moe_block(
         p["x"], p["mlp_norm"], p["router"], *(
-            p[w][first:first + held] for w in ("w_gate", "w_up", "w_down")),
+            jax.lax.dynamic_slice_in_dim(p[w], first, held)
+            for w in ("w_gate", "w_up", "w_down")),
         num_selected=4, norm_eps=1e-5, norm_topk_prob=True,
         topk_norm_eps=1e-20, scoring="sigmoid", gate_scale=2.448,
         select_bias=p["router_bias"], first_expert=first, residual=False)
@@ -322,15 +252,16 @@ def test_the_window_statistic_is_the_schedules_count():
     over the pairs the window leaves, a ``max`` over the windowed layers —
     1.0624 at the cell's 8192 under 4096 (``causal_tile_counts``)."""
     cfg = tiny(attn_impl="flash")
-    _, (metrics, _) = loss_and_counts(seeded(cfg), {"tokens": TOKENS}, cfg)
+    counts = lambda cfg: jax.jit(lambda p: loss_and_counts(  # noqa: E731
+        p, {"tokens": TOKENS}, cfg))(program("trinity").params)
+    _, (metrics, _) = counts(cfg)
     tiles = choose_tiles(SEQ, SEQ, True, 16, jnp.float32, window=WINDOW)
     n = causal_tile_counts(SEQ, SEQ, *tiles, window=WINDOW)
     assert n["causal_pairs"] == 16 * 17 // 2 + 32 * 16
     assert float(metrics["attn_window_executed_share"]) == pytest.approx(
         n["executed_pairs"] / n["causal_pairs"])
     # the XLA form computes the whole square
-    _, (metrics, _) = loss_and_counts(seeded(tiny()), {"tokens": TOKENS},
-                                      tiny())
+    _, (metrics, _) = counts(tiny())
     assert float(metrics["attn_window_executed_share"]) == pytest.approx(
         SEQ * SEQ / n["causal_pairs"])
     big = causal_tile_counts(8192, 8192, *choose_tiles(
@@ -346,12 +277,13 @@ def test_the_train_step_runs_the_windowed_kernels_and_reports():
     state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
     before = jax.tree.map(np.asarray, state.params)
     step = make_train_step(cfg, opt, donate=False)
-    text = step.lower(state, {"tokens": TOKENS}).as_text(debug_info=True)
+    lowered = step.lower(state, {"tokens": TOKENS})   # traced once
+    text = lowered.as_text(debug_info=True)
     for name in ("flash_fwd_win", "flash_dq_win", "flash_dkv_win",
                  "flash_fwd", "attn_qkv/", "attn_out/", "moe_experts/",
                  "moe_combine/", "ffn/"):
         assert name in text, name
-    state, metrics = step(state, {"tokens": TOKENS})
+    state, metrics = lowered.compile()(state, {"tokens": TOKENS})
     assert {"attn_window_executed_share", "moe_held_share", "moe_dropped",
             "moe_rows_visited_share", "moe_load_max_over_mean"
             } <= set(metrics)
