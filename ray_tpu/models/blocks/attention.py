@@ -21,9 +21,12 @@ and k is the configuration's to say (``cfg.rotary``), and by which tables
 ``rope`` inside ``attn_qkv``: ``_rope_tables``, for both mixers).  The
 softmax mixer rotates q and k where the projections leave them, ``(b, s,
 heads x d)`` — the layout the flash kernels read a head of 128 lanes in —
-wherever what it can see allows (``_rotates_flat``: no per-head norm, no
-'tp' over the lanes), else on the 4-D view, as the latent mixer rotates
-its 64-wide rotary part.  With
+wherever what it can see allows (``_rotates_flat``: a head of whole lane
+blocks, no per-head norm, no 'tp' over the lanes), by ONE small kernel
+(``ops/rotary.py``: the tables' ``(rows, d)`` block in VMEM serves every
+head of a row tile, a lane rotate swaps a head's halves, the flash
+kernels' pre-scale of q is its epilogue), else on the 4-D view by
+``apply_rope``, as the latent mixer rotates its 64-wide rotary part.  With
 ``attn_output_gate`` a fourth projection ``wg`` of the block's input
 (scope ``attn_qkv``) gates the heads' outputs, ``o * sigmoid(g)``, before
 ``wo`` (scope ``attn_out``); the checkpoint keeps nothing of it: the
@@ -40,15 +43,15 @@ from ray_tpu.models.blocks.base import (
     Block, Ctx, Param, fold, ones, residual_out)
 from ray_tpu.models.blocks.residual import (
     add, block_in, norm_shapes, out_norm)
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, rotary
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.layers import (
-    apply_rope, apply_rope_flat, repeat_kv_heads, rms_norm, scaled_rope,
-    yarn_mscale)
+    apply_rope, repeat_kv_heads, rms_norm, scaled_rope, yarn_mscale)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
 from ray_tpu.parallel.mesh import AXIS_SP, AXIS_TP
-from ray_tpu.parallel.sharding import BATCH_AXES, manual_shard_map
+from ray_tpu.parallel.sharding import (
+    BATCH_AXES, batch_shard_map, manual_shard_map)
 
 SCOPES = ("attn_qkv", "attention", "attn_out")
 # A windowed layer's statistics: the (q, k) pairs its attention computes
@@ -131,41 +134,69 @@ def _rope_tables(ctx: Ctx, windowed: bool, s: int, dim: int):
     return scaled_rope(s, dim, *ctx.cfg.rope_rule(windowed), offset=offset)
 
 
-def _rotates_flat(ctx: Ctx) -> bool:
+def _rotates_flat(ctx: Ctx, s: int) -> bool:
     """Whether a softmax mixer rotates q and k where the projections leave
     them, ``(b, s, heads x d)`` — the layout the flash kernels read, so no
     copy of q, k or o stands between the projections, RoPE, the kernels
-    and the checkpoint's stack — or on the 4-D view.  From what the call
-    can see: a per-head norm already holds q and k to ``(b, s, heads,
-    d)``, and 'tp' shards the lanes by heads (a roll across the shards
-    would be a collective)."""
-    return not ctx.cfg.qk_head_norm and (
-        ctx.mesh is None or ctx.mesh.shape[AXIS_TP] == 1)
+    and the checkpoint's stack —, by the kernel ``ops/rotary.py``, or on
+    the 4-D view by ``apply_rope``.  From what the call can see: the
+    kernel takes a head of whole lane blocks, as the flash kernels read q
+    and k in place (``rotary.fits``); a per-head norm already holds q and k
+    to ``(b, s, heads, d)``; 'tp' shards the lanes by heads and a region
+    manual over 'sp' leaves the other axes to the partitioner, which
+    takes no Mosaic kernel."""
+    cfg, mesh = ctx.cfg, ctx.mesh
+    return (rotary.fits(s, cfg.head_dim) and not cfg.qk_head_norm
+            and not ctx.sp_manual
+            and (mesh is None or mesh.shape[AXIS_TP] == 1))
 
 
 def _rotated(ctx: Ctx, windowed: bool, q, k):
     """q and k rotated by the tables of a layer of this kind (scope
-    ``rope``), in the view they come in: ``(b, s, heads x d)`` or ``(b, s,
-    heads, d)`` — the same sums either way (``apply_rope_flat``)."""
-    d = ctx.cfg.head_dim
+    ``rope``), in the view they come in — the same sums either way: ``(b,
+    s, heads, d)`` by ``apply_rope``; ``(b, s, heads x d)`` by the kernel
+    (under a mesh per shard of the batch), which hands q on times the
+    flash kernels' pre-scale where they are the attention
+    (``_q_prescale``)."""
+    cfg = ctx.cfg
+    d = cfg.head_dim
     with jax.named_scope("rope"):
         cos, sin = _rope_tables(ctx, windowed, q.shape[1], d)
-        if q.ndim == 3:
-            return (apply_rope_flat(q, cos, sin, d),
-                    apply_rope_flat(k, cos, sin, d))
-        return apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        if q.ndim == 4:
+            return apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        c, s = rotary.lane_tables(cos, sin)
+
+        def rotate(q, k, c, s):
+            return (rotary.rope_rotate(q, c, s, d, _q_prescale(cfg)),
+                    rotary.rope_rotate(k, c, s, d))
+
+        if ctx.mesh is not None:
+            rotate = batch_shard_map(rotate, ctx.mesh, (3, 3, None, None),
+                                     (3, 3))
+        return rotate(q, k, c, s)
 
 
-def _attention(q, k, v, cfg, mesh, window=None):
+def _q_prescale(cfg):
+    """What the rotation's kernel multiplies q by on its way out: the flash
+    kernels' pre-scale where they are the attention (they then take q as it
+    is, ``q_prescaled``), else nothing."""
+    if cfg.attn_impl != "flash":
+        return None
+    return attention.q_prescale(_sm_scale(cfg), cfg.dtype)
+
+
+def _attention(q, k, v, cfg, mesh, window=None, q_prescaled=False):
     """Dispatch to the configured attention impl; ring / ulysses manage the
     'sp' axis themselves.  ``window``: the keys a query sees, where fewer
-    than all before it (flash and the reference alone take one)."""
+    than all before it (flash and the reference alone take one).
+    ``q_prescaled``: q comes times the flash kernels' pre-scale
+    (``_q_prescale``: flash alone)."""
     impl, scale = cfg.attn_impl, _sm_scale(cfg)
     if mesh is None:
         # Ring/ulysses degenerate to plain attention on one device.
         if impl == "flash":
             return flash_attention(q, k, v, causal=True, sm_scale=scale,
-                                   window=window)
+                                   window=window, q_prescaled=q_prescaled)
         k, v = repeat_kv_heads(q, k, v)
         return mha_reference(q, k, v, causal=True, sm_scale=scale,
                              window=window)
@@ -192,8 +223,9 @@ def _attention(q, k, v, cfg, mesh, window=None):
         k, v = repeat_kv_heads(q, k, v)
     spec = P(BATCH_AXES, None, AXIS_TP, None)
     fn = manual_shard_map(
-        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True,
-                                           sm_scale=scale, window=window),
+        lambda q_, k_, v_: flash_attention(
+            q_, k_, v_, causal=True, sm_scale=scale, window=window,
+            q_prescaled=q_prescaled),
         set(mesh.axis_names), in_specs=(spec, spec, spec),
         out_specs=spec, mesh=mesh)
     return fn(q, k, v)
@@ -229,12 +261,12 @@ def _window_stats(cfg, sq: int, sk: int, d: int, window):
 
 
 def _attend(ctx: Ctx, x, aux, q, k, v, lp, residual: bool, gate=None,
-            windowed: bool = False):
+            windowed: bool = False, q_prescaled: bool = False):
     """What every softmax mixer ends in: the attention itself (scope
-    ``attention``; ``windowed``: under the model's ``sliding_window``),
-    then the heads' outputs side by side — times ``sigmoid(gate)`` where
-    the mixer has an output gate — through ``wo`` and onto the stream
-    (scope ``attn_out``)."""
+    ``attention``; ``windowed``: under the model's ``sliding_window``;
+    ``q_prescaled``: see ``_attention``), then the heads' outputs side by
+    side — times ``sigmoid(gate)`` where the mixer has an output gate —
+    through ``wo`` and onto the stream (scope ``attn_out``)."""
     cfg = ctx.cfg
     with jax.named_scope("attention"):
         if windowed:
@@ -242,14 +274,14 @@ def _attend(ctx: Ctx, x, aux, q, k, v, lp, residual: bool, gate=None,
                 raise NotImplementedError(
                     "a window inside a region that is manual over 'sp'")
             window = attention.live_window(cfg.sliding_window, k.shape[1])
-            o = _attention(q, k, v, cfg, ctx.mesh, window)
+            o = _attention(q, k, v, cfg, ctx.mesh, window, q_prescaled)
             aux = fold(aux, _window_stats(
                 cfg, q.shape[1], k.shape[1], max(q.shape[-1], v.shape[-1]),
                 window), WINDOW_STATS)
         elif ctx.sp_manual:
             o = _attention_sp_manual(q, k, v, cfg)
         else:
-            o = _attention(q, k, v, cfg, ctx.mesh)
+            o = _attention(q, k, v, cfg, ctx.mesh, None, q_prescaled)
     with jax.named_scope("attn_out"):
         o = o.reshape(*x.shape[:2], -1)
         if gate is not None:
@@ -270,7 +302,7 @@ def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
         if cfg.qk_norm:
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-        rotate, flat = cfg.rotary(windowed), _rotates_flat(ctx)
+        rotate, flat = cfg.rotary(windowed), _rotates_flat(ctx, s)
         if rotate and flat:
             q, k = _rotated(ctx, windowed, q, k)
         q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
@@ -286,7 +318,9 @@ def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
             q, k = _rotated(ctx, windowed, q, k)
         q = cst(q, ("batch", "seq", "heads", "head_dim"))
         k = cst(k, ("batch", "seq", "kv_heads", "head_dim"))
-    return _attend(ctx, x, aux, q, k, v, lp, residual, gate, windowed)
+    return _attend(ctx, x, aux, q, k, v, lp, residual, gate, windowed,
+                   q_prescaled=rotate and flat
+                   and _q_prescale(cfg) is not None)
 
 
 def _latent_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):

@@ -73,6 +73,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -702,25 +703,38 @@ def _leave(x, heads: int):
     return jnp.transpose(x, (0, 2, 1, 3))
 
 
-def _forward(q, k, v, sm_scale, causal, tiles, interpret, window):
+def q_prescale(sm_scale: float, dtype) -> float:
+    """What the kernels take q multiplied by (the softmax runs in the log2
+    domain), as the number of q's type the multiply sees."""
+    return float(np.asarray(sm_scale * _LOG2E, jnp.dtype(dtype)))
+
+
+def _forward(q, k, v, sm_scale, causal, tiles, interpret, window,
+             prescaled=False):
     """``flash_fwd`` on the model's q, k, v: (q, k, v as the kernels took
     them — the residuals of the backward pass —, o and lse as the kernel
     wrote them)."""
     in_place = _in_place(q, v)
     heads = (q.shape[2], k.shape[2]) if in_place else None
-    # the pre-scale is XLA's: it rides in the fusion that writes q (RoPE's)
-    qs = (q * (sm_scale * _LOG2E)).astype(q.dtype)
+    # The pre-scale is XLA's: it rides in the fusion that writes q (a norm's,
+    # the 4-D RoPE's).  A custom call that writes q has no fusion for it to
+    # ride in — the multiply would be a pass of its own over q —, so the
+    # RoPE kernel applies it on its way out and hands q in ``prescaled``.
+    qs = q if prescaled else (q * (sm_scale * _LOG2E)).astype(q.dtype)
     qt, kt, vt = (_enter(x, in_place) for x in (qs, k, v))
     ot, lse = _fwd_call(qt, kt, vt, causal, tiles, interpret, window, heads)
     return (qt, kt, vt), ot, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, sm_scale, causal, tiles, interpret, window=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, sm_scale, causal, tiles, interpret, window=None,
+           prescaled=False):
     """``tiles`` = (block_q, block_k, sub_q, sub_k): each sub divides its
     block, each block its sequence.  k and v come with their OWN head
-    count, a divisor of q's."""
-    _, ot, _ = _forward(q, k, v, sm_scale, causal, tiles, interpret, window)
+    count, a divisor of q's.  ``prescaled``: q is already times
+    ``q_prescale`` (and its gradient is the gradient to THAT q)."""
+    _, ot, _ = _forward(q, k, v, sm_scale, causal, tiles, interpret, window,
+                        prescaled)
     return _leave(ot, q.shape[2])
 
 
@@ -730,9 +744,10 @@ def _flash(q, k, v, sm_scale, causal, tiles, interpret, window=None):
 SAVED_RESIDUALS = ("flash_out", "flash_lse")
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret, window=None):
+def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret, window=None,
+               prescaled=False):
     operands, ot, lse = _forward(q, k, v, sm_scale, causal, tiles, interpret,
-                                 window)
+                                 window, prescaled)
     ot = checkpoint_name(ot, "flash_out")
     # One lane of the 128 the kernel writes: a float a row is what is
     # worth holding; flash_dq gets the lanes back, flash_dkv takes rows.
@@ -740,13 +755,17 @@ def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret, window=None):
     return _leave(ot, q.shape[2]), (*operands, ot, lse)
 
 
-def _flash_bwd(sm_scale, causal, tiles, interpret, window, res, do):
+def _flash_bwd(sm_scale, causal, tiles, interpret, window, prescaled, res,
+               do):
     qt, kt, vt, ot, lse = res
     in_place = qt.ndim == 3     # the residuals' own shapes say how they stand
     h = do.shape[2]
     h_kv = kt.shape[2] * h // qt.shape[2] if in_place else kt.shape[1]
+    # flash_dq ends in ``ds @ k`` times this: d scores / d q as it came in
+    dq_scale = (sm_scale / q_prescale(sm_scale, qt.dtype) if prescaled
+                else sm_scale)
     dqt, dkt, dvt = _bwd_call(qt, kt, vt, ot, lse, _enter(do, in_place),
-                              sm_scale, causal, tiles, interpret, window,
+                              dq_scale, causal, tiles, interpret, window,
                               (h, h_kv) if in_place else None)
     return _leave(dqt, h), _leave(dkt, h_kv), _leave(dvt, h_kv)
 
@@ -758,10 +777,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, sm_scale: Optional[float] = None,
                     block_q: int = MAX_BLOCK, block_k: int = MAX_BLOCK,
                     interpret: Optional[bool] = None,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    q_prescaled: bool = False) -> jax.Array:
     """Memory-efficient MHA.  q: (b, sq, h, d); k: (b, sk, h_kv, d); v:
     (b, sk, h_kv, dv), the output (b, sq, h, dv): v's head size may differ
     from q's and k's (a latent-attention mixer's 192 / 128).
+
+    ``q_prescaled``: q comes times ``q_prescale(sm_scale, q.dtype)`` — from
+    a kernel that wrote it and applied the kernels' pre-scale on its way out
+    (``ops/rotary.py``), where XLA's own multiply would be a pass over q —
+    and the gradient returned is the gradient to q as it came.
 
     ``window`` (causal only): query ``i`` sees key ``j`` iff ``0 <= i - j <
     window``; the kernels then neither fetch nor compute a tile beyond
@@ -791,9 +816,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # correct, at O(S^2) memory.
         from ray_tpu.ops.layers import repeat_kv_heads
         k, v = repeat_kv_heads(q, k, v)
+        if q_prescaled:
+            sm_scale = sm_scale / q_prescale(sm_scale, q.dtype)
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                              window=window)
-    return _flash(q, k, v, sm_scale, causal, tiles, interpret, window)
+    return _flash(q, k, v, sm_scale, causal, tiles, interpret, window,
+                  q_prescaled)
 
 
 def live_window(window: Optional[int], sk: int, causal: bool = True

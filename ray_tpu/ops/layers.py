@@ -108,47 +108,16 @@ def scaled_rope(seq_len: int, head_dim: int, theta: float, scaling=(),
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x: (b, s, h, d); cos/sin: (s, d/2).  Rotate-half formulation."""
+    """x: (b, s, h, d); cos/sin: (s, d/2).  Rotate-half formulation.  Where
+    the heads lie side by side, ``(b, s, h x d)``, and a head fills whole
+    lane blocks the same sums are a kernel's (``ops/rotary.py``): in plain
+    XLA they cost tables as wide as x and slices off the lane tiles."""
     d2 = x.shape[-1] // 2
     x1, x2 = x[..., :d2], x[..., d2:]
     cos = cos[None, :, None, :]
     sin = sin[None, :, None, :]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
-
-
-def _along_the_lanes(table: jax.Array, heads: int) -> jax.Array:
-    """``table (s, d)`` once a head along the lanes, ``(s, heads * d)`` —
-    by way of ``(s / 8, 8, heads, d)``, a float32 tile's eight rows split
-    off, so that ONE fusion writes it in the tiled layout (``jnp.tile``: a
-    broadcast and a copy, 0.7 GB more in a step of 16384 positions)."""
-    s, d = table.shape
-    r = math.gcd(s, 8)
-    return jnp.broadcast_to(table.reshape(s // r, r, 1, d),
-                            (s // r, r, heads, d)).reshape(s, heads * d)
-
-
-def apply_rope_flat(x: jax.Array, cos: jax.Array, sin: jax.Array,
-                    head_dim: int) -> jax.Array:
-    """``apply_rope`` on the heads side by side: x ``(b, s, n * head_dim)``
-    as a projection leaves it, cos/sin ``(s, head_dim / 2)``; op by op
-    equal to ``apply_rope`` of the 4-D view, values and gradient (the same
-    products in the same precision; ``a + (-b)`` is ``a - b`` — inside one
-    compiled program the compiler's fusion decides the last place of
-    either form).  A lane's partner is ``head_dim / 2`` lanes up in a
-    head's first half and as far down in its second — two rolls of the
-    whole lane axis and a select, so no op sees a head as a dimension and
-    XLA can keep q and k in the tiled layout of the arrays on both sides of
-    the rotation.  Its price: XLA fuses no repeat along the lanes into the
-    products, so ``[cos|cos]`` and ``[-sin|sin]`` stand as float32 tables
-    as wide as x, 4 / rows times a bfloat16 x's bytes, made anew a layer
-    and pass (PERF.md §6, PR 56: made once a step they cost more)."""
-    d2, heads = head_dim // 2, x.shape[-1] // head_dim
-    first_half = jnp.arange(x.shape[-1]) % head_dim < d2
-    other = jnp.where(first_half, jnp.roll(x, -d2, -1), jnp.roll(x, d2, -1))
-    cos = _along_the_lanes(jnp.concatenate([cos, cos], -1), heads)
-    sin = _along_the_lanes(jnp.concatenate([-sin, sin], -1), heads)
-    return (x * cos + other * sin).astype(x.dtype)
 
 
 def sinkhorn(logits: jax.Array, iters: int, eps: float) -> jax.Array:

@@ -290,42 +290,62 @@ def test_one_rule_a_model_goes_through_the_same_helper(mixer):
 
 def test_the_view_rope_works_on_follows_the_norm_and_the_mesh_not_the_sums(
         monkeypatch):
-    """The softmax mixers rotate ``(b, s, heads x d)`` unless a per-head
-    norm holds q and k to four dimensions or 'tp' shards the lanes — one
-    row or two alike, so a cell's one-row check runs what its step runs;
-    both kinds of layer, the logits are those of the 4-D view to float32's
-    last places."""
-    from ray_tpu.parallel import MeshConfig, make_mesh
+    """Where a head fills whole lane blocks the softmax mixers rotate ``(b,
+    s, heads x d)`` by the rotation's kernel (``ops/rotary.py``) — one row
+    or two alike, so a cell's one-row check runs what its step runs —
+    unless a per-head norm holds q and k to four dimensions, 'tp' shards
+    the lanes or the region is manual over 'sp'; then, and at a narrower
+    head, ``apply_rope`` on the 4-D view.  Both kinds of layer, the logits
+    of the two routes agree to float32's last places."""
+    from ray_tpu.parallel import MeshConfig, make_mesh, use_mesh
 
-    cfg = tiny()
+    cfg = tiny(head_dim=128)
 
-    def flat(cfg, mesh=None):
+    def flat(cfg, mesh=None, sp_manual=False):
         return attention_block._rotates_flat(
-            attention_block.Ctx(cfg, mesh, lambda a, _: a, False))
+            attention_block.Ctx(cfg, mesh, lambda a, _: a, sp_manual), SEQ)
 
     devices = jax.devices()[:4]
     assert flat(cfg)
+    assert not flat(tiny())                     # a head of 16 lanes
     assert not flat(dataclasses.replace(cfg, qk_head_norm=True))
     assert flat(cfg, make_mesh(MeshConfig(fsdp=2), devices=devices[:2]))
     assert not flat(cfg, make_mesh(MeshConfig(fsdp=2, tp=2), devices=devices))
+    assert not flat(cfg, sp_manual=True)
     params, inputs = seeded(cfg), (TOKENS[:, :-1], TOKENS[:1, :-1])
 
-    def programs():
-        return [str(jax.make_jaxpr(lambda p, t: forward(p, t, cfg)[0])(
-            params, t)) for t in inputs]
+    def routes(cfg, params=params):
+        """(the kernel, ``apply_rope``'s halves of a head) in the program
+        of two rows and of one."""
+        found = []
+        for t in inputs:
+            text = str(jax.make_jaxpr(
+                lambda p, t: forward(p, t, cfg)[0])(params, t))
+            half_a_head = (f"f32[{t.shape[0]},{SEQ},{cfg.num_heads},"
+                           f"{cfg.head_dim // 2}]")
+            found.append(("rope_fwd" in text, half_a_head in text))
+        return found
 
-    def of_two_rolls(text, rows):   # a lane's partner, on the flat q
-        return f"f32[{rows},{SEQ},{cfg.qkv_dim}] = select_n" in text
-
-    assert [of_two_rolls(t, r) for t, r in zip(programs(), (2, 1))] == [
-        True, True]
+    assert routes(cfg) == [(True, False)] * 2
+    by_norm = dataclasses.replace(cfg, qk_head_norm=True)
+    assert routes(by_norm, seeded(by_norm)) == [(False, True)] * 2
+    assert routes(tiny(), seeded(tiny())) == [(False, True)] * 2
     # a program a call: what is traced follows the patch below
     logits = lambda t: jax.jit(  # noqa: E731
         lambda p: forward(p, t, cfg)[0])(params)
     by_rows = [logits(t) for t in inputs]
-    monkeypatch.setattr(attention_block, "_rotates_flat", lambda ctx: False)
-    assert [of_two_rolls(t, r) for t, r in zip(programs(), (2, 1))] == [
-        False, False]
+    # under a mesh that leaves the lanes whole: per shard of the batch,
+    # and into the flash kernels with their pre-scale on q already
+    mesh = make_mesh(MeshConfig(fsdp=2), devices=devices[:2])
+    flash = dataclasses.replace(cfg, attn_impl="flash")
+    with use_mesh(mesh):
+        sharded = jax.jit(
+            lambda p: forward(p, inputs[0], flash, mesh=mesh)[0])(params)
+    assert float(jnp.max(jnp.abs(sharded - by_rows[0]))) < 1e-4 * float(
+        jnp.max(jnp.abs(by_rows[0])))
+    monkeypatch.setattr(attention_block, "_rotates_flat",
+                        lambda ctx, s: False)
+    assert routes(cfg) == [(False, True)] * 2
     for got, tokens in zip(by_rows, inputs):
         want = logits(tokens)
         assert float(jnp.max(jnp.abs(got - want))) < 2e-5 * float(

@@ -16,8 +16,9 @@ from ray_tpu.ops import (
     flash_attention, mha_reference, ring_attention, ulysses_attention,
     rms_norm, rope, apply_rope,
 )
+from ray_tpu.ops import attention, rotary
 from ray_tpu.ops.attention import causal_tile_counts, choose_tiles
-from ray_tpu.ops.layers import apply_rope_flat, scaled_rope
+from ray_tpu.ops.layers import scaled_rope
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.parallel import MeshConfig, make_mesh, use_mesh
 
@@ -590,50 +591,87 @@ _YARN = {"rope_type": "yarn", "factor": 16.0,
          "original_max_position_embeddings": 8}
 
 
+@pytest.mark.parametrize("scale", [None, 128 ** -0.5 * math.log2(math.e)],
+                         ids=["plain", "prescale"])
 @pytest.mark.parametrize("tables", ["offset", "yarn"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("heads", [1, 4, 8])
-@pytest.mark.parametrize("d", [16, 64, 128])
-def test_apply_rope_flat_is_apply_rope_bit_for_bit(d, heads, dtype, tables):
-    """RoPE on ``(b, s, heads x d)`` is RoPE on ``(b, s, heads, d)``, values
-    and the gradient w.r.t. x, to the BIT (-0.0 is not 0.0 here): the same
-    products and sums in the same precision, under tables that start at an
-    'sp' rank's offset and under YaRN's frequencies times its factor.  Op
-    by op — inside ONE jitted program the CPU's compiler contracts a
-    product and a sum into a fused multiply-add, another one in each form,
-    and float32 then differs in the last place: held to that below."""
-    b, s = 2, 24
+@pytest.mark.parametrize("rows,d", [(1, 128), (3, 128), (1, 256), (2, 256)])
+def test_the_rope_kernel_is_apply_rope_bit_for_bit(rows, d, heads, dtype,
+                                                   tables, scale):
+    """The rotation's kernel on ``(b, s, heads x d)`` (interpreted here) is
+    ``apply_rope`` on ``(b, s, heads, d)`` — then times the flash kernels'
+    pre-scale, rounded as ``ops/attention.py::_forward`` rounds it —, values
+    and the gradient w.r.t. x, to the BIT (-0.0 is not 0.0 here), each side
+    ONE compiled program: the same products and sums in the same precision
+    — the backward pass's too, where autodiff rounds each product to x's
+    type before it adds them —, under tables that start at an 'sp' rank's
+    offset and under YaRN's frequencies times its factor.  The CPU's compiler
+    contracts a product and a sum into a fused multiply-add, another one in
+    each program, and float32 would then differ in the last place: the
+    float32 cases draw x, g, the scale and the tables from bfloat16's
+    numbers, whose products are exact, so fused or not rounds alike."""
+    s = 24
     cos, sin = (rope(s, d, 1e4, offset=40) if tables == "offset"
                 else scaled_rope(s, d, 1e4, _YARN))
     assert tables == "offset" or float(jnp.max(jnp.abs(cos))) > 1.0
-    x, g = (jax.random.normal(k, (b, s, heads * d), jnp.float32).astype(dtype)
+    if scale is not None:
+        scale = attention.q_prescale(scale / math.log2(math.e), jnp.bfloat16)
+    if dtype == jnp.float32:
+        cos, sin = (t.astype(jnp.bfloat16).astype(dtype) for t in (cos, sin))
+    x, g = (jax.random.normal(k, (rows, s, heads * d), jnp.float32
+                              ).astype(jnp.bfloat16).astype(dtype)
             for k in jax.random.split(jax.random.PRNGKey(d + heads)))
+    assert rotary.fits(s, d)
 
     def by_head(x):
-        return apply_rope(x.reshape(b, s, heads, d), cos, sin).reshape(x.shape)
+        y = apply_rope(x.reshape(rows, s, heads, d), cos, sin).reshape(x.shape)
+        return y if scale is None else (y * scale).astype(y.dtype)
 
-    def flat(x):
-        return apply_rope_flat(x, cos, sin, d)
+    def kernel(x):
+        return rotary.rope_rotate(x, *rotary.lane_tables(cos, sin), d, scale)
+
+    def both(fn):
+        def weighed(x):
+            y = fn(x)
+            return jnp.sum((y * g).astype(jnp.float32)), y
+        (_, y), dx = jax.jit(jax.value_and_grad(weighed, has_aux=True))(x)
+        return y, dx
 
     def bits(a):
         return jax.lax.bitcast_convert_type(
             a, jnp.uint16 if dtype == jnp.bfloat16 else jnp.uint32)
 
-    want, want_vjp = jax.vjp(by_head, x)
-    got, got_vjp = jax.vjp(flat, x)
+    (want, dx_want), (got, dx_got) = both(by_head), both(kernel)
     assert got.dtype == dtype and jnp.array_equal(bits(got), bits(want))
     assert not jnp.array_equal(got, x)          # it does rotate
-    (dx_want,), (dx_got,) = want_vjp(g), got_vjp(g)
     assert dx_got.dtype == dtype
     assert jnp.array_equal(bits(dx_got), bits(dx_want))
-    last_place = 2.0 ** (-7 if dtype == jnp.bfloat16 else -22)
-    for a, w in ((jax.jit(flat)(x), want),
-                 (jax.jit(jax.grad(lambda x: jnp.sum(
-                     (flat(x) * g).astype(jnp.float32))))(x), dx_want)):
-        assert float(jnp.max(jnp.abs(
-            a.astype(jnp.float32) - w.astype(jnp.float32)))) <= (
-                last_place * float(jnp.max(jnp.abs(w))))
+
+
+@pytest.mark.parametrize("s", [64, 7], ids=["kernels", "no-tile"])
+def test_flash_attention_takes_q_prescaled_with_its_own_gradient(s):
+    """``q_prescaled``: q comes times ``q_prescale`` (the rotation's kernel
+    applied it on its way out) and the kernels multiply nothing; output and
+    the gradients to k and v are those of the plain call on q, the gradient
+    to the SCALED q that of the plain call's to q over the scale — through
+    the kernels and, where no block tiles the sequence, the XLA form."""
+    q, k, v, g = (jax.random.normal(key, (2, s, 4, 32)) for key in
+                  jax.random.split(jax.random.PRNGKey(s), 4))
+    c = attention.q_prescale(32 ** -0.5, q.dtype)
+
+    def run(q, prescaled):
+        def weighed(q, k, v):
+            return jnp.sum(g * flash_attention(q, k, v,
+                                               q_prescaled=prescaled))
+        return jax.jit(jax.value_and_grad(weighed, (0, 1, 2)))(q, k, v)
+
+    want, (dq, dk, dv) = run(q, False)
+    got, (dqs, dk_got, dv_got) = run(q * c, True)
+    assert jnp.allclose(got, want, rtol=1e-5)
+    for a, b in ((dqs * c, dq), (dk_got, dk), (dv_got, dv)):
+        assert jnp.max(jnp.abs(a - b)) < 1e-5 * jnp.max(jnp.abs(b))
 
 
 def test_moe_routing_mass_conservation():

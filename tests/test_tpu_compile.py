@@ -14,6 +14,7 @@ its own process.
 """
 
 import functools
+import math
 import os
 import re
 
@@ -782,6 +783,29 @@ def _ops_of(text, opcode, scopes):
     return found
 
 
+@pytest.mark.parametrize("shape,d,dtype", [
+    ((1, 16384, 4096), 128, jnp.bfloat16),   # Mellum2's q: one row
+    ((1, 16384, 512), 128, jnp.bfloat16),    # ... and its k: 4 KV heads
+    ((4, 4096, 2048), 256, jnp.bfloat16),    # a head of two lane blocks
+    ((1, 8, 128), 128, jnp.float32),         # one sublane tile of one head
+], ids=["q-one-row", "k-one-row", "d256", "smallest"])
+def test_the_rope_kernel_compiles(one_chip, as_on_chip, shape, d, dtype):
+    """``rope_fwd`` with the pre-scale epilogue and ``rope_bwd`` at the
+    widths a cell runs and at the edges of ``rotary.fits``: the lane rotate
+    of a head's block, the tables' block beside x's."""
+    from ray_tpu.ops import rotary
+
+    assert rotary.fits(shape[1], d)
+    tables = (_shape((shape[1], d), jnp.float32, one_chip),) * 2
+    text = jax.jit(jax.grad(lambda x, c, s: rotary.rope_rotate(
+        x, c, s, d, 0.125).astype(jnp.float32).sum())).lower(
+            _shape(shape, dtype, one_chip), *tables).compile().as_text()
+    assert "rope_fwd" not in text and "rope_bwd" in text   # a linear rule
+    assert _has_kernel(jax.jit(lambda x, c, s: rotary.rope_rotate(
+        x, c, s, d, 0.125)).lower(
+            _shape(shape, dtype, one_chip), *tables).compile())
+
+
 @pytest.mark.parametrize("config,rows,seq,heads,kv_heads", [
     ("mistral-7b-v0.1-d4", 4, 4096, 32, 8),       # grouped KV, no norm
     ("olmoe-1b-7b-0125-1chip", 4, 4096, 16, 16),  # MHA, a norm over all of q
@@ -791,11 +815,16 @@ def test_rope_on_the_flat_arrays_leaves_no_copy_of_q_or_k(
         one_chip, as_on_chip, config, rows, seq, heads, kv_heads):
     """Two layers (the scan stays a loop) of a cell whose mixer rotates
     ``(b, s, heads x 128)`` before the reshape, the vocabulary cut, as the
-    one-chip train step: between the projections and ``flash_fwd`` /
+    one-chip train step: the rotation is the kernel's (``rope_fwd``,
+    ``rope_bwd``: custom calls) and under ``rope`` stands no float32 array
+    wider than the tables' ``(s, 128)`` (the XLA form's stood as wide as
+    q: PERF.md §6, PR 58); between the projections and ``flash_fwd`` /
     ``flash_dq`` / ``flash_dkv`` XLA copies neither q nor k (on the 4-D
     view it laid RoPE's fusion out with the SEQUENCE on the lanes,
     ``{1,3,2,0}``, and copied both into the kernels' ``{2,1,0}`` every
-    layer and pass: PERF.md §6, PR 54)."""
+    layer and pass: PERF.md §6, PR 54); and the flash kernels' pre-scale of
+    q is the rotation's epilogue: under ``attention`` no multiply is left
+    in the forward pass or its rerun (the backward pass keeps ``o * do``)."""
     import dataclasses
 
     cfg = _benchmark_cfg(config)
@@ -809,6 +838,14 @@ def test_rope_on_the_flat_arrays_leaves_no_copy_of_q_or_k(
     ).compile().as_text()
     assert all(name in text
                for name in ("flash_fwd", "flash_dq", "flash_dkv"))
+    for kernel, calls in (("rope_fwd", 4), ("rope_bwd", 2)):  # q's and k's
+        assert len(re.findall(
+            rf"custom-call\(.*/rope/jit\(_call\)/{kernel}/pallas_call\"",
+            text)) == calls
+    widths = [math.prod(map(int, m.group(1).split(",")))
+              for m in re.finditer(
+                  r"= f32\[([\d,]+)\]\S* \S+\(.*op_name=\"[^\"]*/rope/", text)]
+    assert widths and max(widths) <= seq * 128
     mixer = ("rope", "attn_qkv", "attention")
     q_or_k = re.compile(
         rf"bf16\[{rows},{seq},(?:{heads * 128}|{kv_heads * 128}"
@@ -817,15 +854,21 @@ def test_rope_on_the_flat_arrays_leaves_no_copy_of_q_or_k(
                 if q_or_k.match(c[0])]
     assert not [f for f in _ops_of(text, "fusion", mixer) + _ops_of(
         text, "copy", mixer) if "{1,3,2,0" in f[0]]
+    scaled = [name for _, name in _ops_of(text, "multiply", ("attention",))
+              if name.endswith("/attention/mul")]
+    assert scaled and all("transpose(jvp())" in name
+                          and "rematted_computation" not in name
+                          for name in scaled)
 
 
 def test_rope_stays_on_the_4d_view_after_a_per_head_norm():
     """What the mixer can see decides the view RoPE works on: a layer with
     ``qk_head_norm`` already holds q and k to ``(b, s, heads, d)`` and
-    keeps ``apply_rope`` (rotate-half by slices of a HEAD: the flat form
-    after the norm compiled to float32 copies of q, PERF.md §6, PR 54); the
-    same layer without it rotates ``(b, s, heads x d)``, one row or two,
-    with no op that has a head for a dimension."""
+    keeps ``apply_rope`` (rotate-half by slices of a HEAD: a flat form
+    after the norm compiled to float32 copies of q, PERF.md §6, PR 54), as
+    does a head narrower than the lanes; the same layer at a head of 128
+    lanes without the norm rotates ``(b, s, heads x d)`` by the kernel, one
+    row or two, with no op that has a head for a dimension."""
     from ray_tpu.models import llama
 
     def rope_ops(rows, **kw):
@@ -836,9 +879,9 @@ def test_rope_stays_on_the_4d_view_after_a_per_head_norm():
             lambda p, t: llama.loss_fn(p, {"tokens": t}, cfg)[0])(
                 params, jax.ShapeDtypeStruct((rows, 17), jnp.int32)))
         half_a_head = f"f32[{rows},16,{cfg.num_heads},{cfg.head_dim // 2}]"
-        of_two_rolls = f"f32[{rows},16,{cfg.qkv_dim}] = select_n"
-        return half_a_head in text, of_two_rolls in text
+        return half_a_head in text, "rope_fwd" in text
 
-    assert rope_ops(2, qk_head_norm=True) == (True, False)
-    assert rope_ops(1) == (False, True)
-    assert rope_ops(2) == (False, True)
+    assert rope_ops(2, head_dim=128, qk_head_norm=True) == (True, False)
+    assert rope_ops(2) == (True, False)
+    assert rope_ops(1, head_dim=128) == (False, True)
+    assert rope_ops(2, head_dim=128) == (False, True)
