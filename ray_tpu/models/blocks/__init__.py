@@ -15,7 +15,7 @@ tree names a mixer.
 """
 
 from ray_tpu.models.blocks import (
-    attention, base, conv, delta, ffn, mamba, residual)
+    attention, base, conv, delta, ffn, kda, mamba, residual)
 
 MIXERS = {
     "attention": attention.SOFTMAX,
@@ -25,6 +25,7 @@ MIXERS = {
     "latent": attention.LATENT,
     "mamba": mamba.BLOCK,
     "linear_attention": delta.BLOCK,
+    "kda": kda.BLOCK,      # ... its decay a vector over the key channels
     "conv": conv.BLOCK,
     "none": base.EMPTY_MIXER,
 }

@@ -86,17 +86,21 @@ def _attention_shapes(cfg):
 
 def _latent_shapes(cfg):
     """``wq_a``/``wq_b`` take q down to ``q_lora_rank`` and up to heads x
-    [nope | rope]; ``wkv_a`` gives [the latent c_kv | the one rotary k every
-    head shares], ``wkv_b`` takes the normed latent up to heads x [k_nope |
+    [nope | rope] — or, in a model without a q rank (the public
+    ``q_lora_rank`` null), ONE matrix ``wq`` gives it; ``wkv_a`` gives
+    [the latent c_kv | the one rotary k every head shares], ``wkv_b`` takes the normed latent up to heads x [k_nope |
     v] (the published layouts of ``kv_a_proj_with_mqa`` and
     ``kv_b_proj``)."""
     d, heads, qk = cfg.embed_dim, cfg.num_heads, cfg.latent_qk_dim
+    q = {"wq": Param((d, heads * qk), ("layer", "kernel_in", "heads"))}
+    if cfg.q_lora_rank:
+        q = {"wq_a": Param((d, cfg.q_lora_rank), ("layer", "kernel_in", None)),
+             "q_a_norm": Param((cfg.q_lora_rank,), ("layer", None), ones),
+             "wq_b": Param((cfg.q_lora_rank, heads * qk),
+                           ("layer", None, "heads"))}
     return {
         **norm_shapes(cfg, "attn"),
-        "wq_a": Param((d, cfg.q_lora_rank), ("layer", "kernel_in", None)),
-        "q_a_norm": Param((cfg.q_lora_rank,), ("layer", None), ones),
-        "wq_b": Param((cfg.q_lora_rank, heads * qk),
-                      ("layer", None, "heads")),
+        **q,
         "wkv_a": Param((d, cfg.kv_lora_rank + cfg.qk_rope_dim),
                        ("layer", "kernel_in", None)),
         "kv_a_norm": Param((cfg.kv_lora_rank,), ("layer", None), ones),
@@ -325,29 +329,39 @@ def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
 
 def _latent_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
     """Latent attention on the residual stream: ``attn_qkv`` holds both
-    down-projections, their norms, both up-projections and RoPE.  A
-    head's q and k are [no-position part | rotary part] — the k's rotary
-    part is ONE head, shared by all and laid beside each head's own part
-    in the one k the kernel reads — and its v is narrower; the softmax
-    scale is over the whole q/k head."""
+    down-projections, their norms, both up-projections and RoPE (q is ONE
+    projection in a model without a q rank).  A head's q and k are
+    [no-position part | rotary part] — the k's rotary part is ONE head,
+    shared by all and laid beside each head's own part in the one k the
+    kernel reads — and its v is narrower; the softmax scale is over the
+    whole q/k head.  Whether the rotary part is rotated is the
+    configuration's to say (``cfg.rotary``: under ``nope`` it is 64 more
+    columns without a position)."""
     cfg, cst = ctx.cfg, ctx.cst
     b, s = x.shape[0], x.shape[1]
     heads, nope, rot = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
     with jax.named_scope("attn_qkv"):
         h = block_in(x, lp["attn_norm"], cfg)
-        q = (rms_norm(h @ lp["wq_a"].astype(cfg.dtype), lp["q_a_norm"],
-                      cfg.norm_eps) @ lp["wq_b"].astype(cfg.dtype)).reshape(
-                          b, s, heads, nope + rot)
+        if cfg.q_lora_rank:
+            q = (rms_norm(h @ lp["wq_a"].astype(cfg.dtype), lp["q_a_norm"],
+                          cfg.norm_eps) @ lp["wq_b"].astype(cfg.dtype)
+                 ).reshape(b, s, heads, nope + rot)
+        else:
+            q = (h @ lp["wq"].astype(cfg.dtype)).reshape(
+                b, s, heads, nope + rot)
         c_kv, k_rot = jnp.split(h @ lp["wkv_a"].astype(cfg.dtype),
                                 [cfg.kv_lora_rank], -1)
         kv = (rms_norm(c_kv, lp["kv_a_norm"], cfg.norm_eps)
               @ lp["wkv_b"].astype(cfg.dtype)).reshape(
                   b, s, heads, nope + cfg.v_head_dim)
-        with jax.named_scope("rope"):
-            cos, sin = _rope_tables(ctx, False, s, rot)
-            q = jnp.concatenate(
-                [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], -1)
-            k_rot = apply_rope(k_rot[:, :, None, :], cos, sin)
+        if cfg.rotary(False):
+            with jax.named_scope("rope"):
+                cos, sin = _rope_tables(ctx, False, s, rot)
+                q = jnp.concatenate(
+                    [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], -1)
+                k_rot = apply_rope(k_rot[:, :, None, :], cos, sin)
+        else:
+            k_rot = k_rot[:, :, None, :]
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rot, (b, s, heads, rot))],
             -1)

@@ -106,7 +106,7 @@ class Block:
     returns ``(x, aux)``, an FFN ``(x, aux, what the layer hands out of the
     scan or None)``.  ``saved``: the ``checkpoint_name``s it makes, which
     the layer checkpoint keeps.  ``scopes``: the ``jax.named_scope``s it
-    opens, in order.  ``stats(cfg) -> {name: "sum" | "max" | "mean"}``: the
+    opens, in order.  ``stats(cfg) -> {name: "sum" | "max" | "min" | "mean"}``: the
     float32 scalars it folds into ``aux`` and how layers combine each; they
     come back as step metrics under these names."""
     shapes: Callable[[Any], Dict[str, Param]]
@@ -130,7 +130,9 @@ EMPTY_FFN = Block(lambda cfg: {}, lambda *a, **kw: (*_hand_on(*a, **kw), None))
 
 def fold(aux, seen, how):
     """``aux`` with what one layer ``seen`` of the statistics ``how`` names:
-    the larger for a ``max``, else the sum (the decoder divides a ``mean``
-    by its layers at the end)."""
-    return {k: (jnp.maximum if how[k] == "max" else jnp.add)(v, seen[k])
+    the larger for a ``max``, the smaller for a ``min`` (of numbers that are
+    never positive: the scan starts every statistic at 0), else the sum
+    (the decoder divides a ``mean`` by its layers at the end)."""
+    join = {"max": jnp.maximum, "min": jnp.minimum}
+    return {k: join.get(how[k], jnp.add)(v, seen[k])
             if k in how else v for k, v in aux.items()}

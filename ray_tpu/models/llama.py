@@ -4,15 +4,17 @@ under the layer checkpoint, head, predicted-ahead module, loss, pipeline
 entry points.  What a layer is made of lives in ``models/blocks/``: a MIXER
 (``blocks.MIXERS``: softmax attention, over every earlier token or over a
 window of them | latent attention | a Mamba-2
-state-space mixer | a gated delta-rule linear-attention mixer | a gated
-short convolution) followed by an FFN (``blocks.FFNS``: dense, SwiGLU or
+state-space mixer | a gated delta-rule linear-attention mixer, its decay
+a number a head or a vector over the key channels | a gated short
+convolution) followed by an FFN (``blocks.FFNS``: dense, SwiGLU or
 ungated relu^2 | dropless experts with or without a shared expert) —
 either of the two may be the empty block (``none``), so a layer can be a
 mixer OR an FFN alone —, each on a RESIDUAL
 (``blocks/residual.py``: one stream | ``hc_mult`` streams mixed round every
 block by learned doubly stochastic maps).  A model is a pattern of such
-layers (``layer_types``, ``leading_dense``; or ``layer_pattern``, a
-character a layer), with or without a predicted-ahead module behind them.
+layers (``layer_types``, ``leading_dense``; ``layer_pattern``, a character
+a layer; or ``linear_attn_config``, the layers of each mixer by number),
+with or without a predicted-ahead module behind them.
 Each block declares its own tensors, initialisers, saved residuals, scopes
 and step statistics (``blocks.base.Block``); the decoder reads those and
 names no mixer.
@@ -106,9 +108,9 @@ class LlamaConfig:
     qk_head_norm: bool = False        # ... over EACH head's q and k instead
     remat: bool = True
     # The mixer of each layer, "attention" (or "full_attention") |
-    # "sliding_attention" | "mamba" | "linear_attention" | "conv"; only the
-    # first ``num_layers`` entries are the model, empty = attention
-    # everywhere.
+    # "sliding_attention" | "mamba" | "linear_attention" | "kda" | "conv";
+    # only the first ``num_layers`` entries are the model, empty =
+    # attention everywhere.
     layer_types: Tuple[str, ...] = ()
     # What a "sliding_attention" layer's query sees: itself and the
     # sliding_window - 1 tokens before it.
@@ -136,7 +138,7 @@ class LlamaConfig:
     # Latent attention: kv_lora_rank > 0 makes "latent" the mixer of a
     # model without layer_types.  A head's q and k are qk_nope_dim +
     # qk_rope_dim wide, its v and output v_head_dim.
-    q_lora_rank: int = 0
+    q_lora_rank: Optional[int] = 0    # 0 or None: q is ONE matrix, ``wq``
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
@@ -175,6 +177,15 @@ class LlamaConfig:
     gdn_value_dim: int = 128
     gdn_conv: int = 4                 # width of the causal depthwise conv
     gdn_neg_eigval: bool = False      # beta in (0, 2): eigenvalues (-1, 1)
+    # The public file's group of a model whose linear-attention layers
+    # have a decay PER KEY CHANNEL (mixer ``kda``) beside latent-attention
+    # ones: {"kda_layers": [...], "full_attn_layers": [...]} (layers counted
+    # from 1; entries past ``num_layers`` name layers that are not run),
+    # "num_heads", "head_dim" (keys and values alike),
+    # "short_conv_kernel_size".  It names every layer's mixer where
+    # ``layer_types`` names none (with ``layer_types`` the sizes alone are
+    # read).
+    linear_attn_config: Any = None
     sconv_width: int = 3              # taps of the gated short convolution
     # Where a block's RMSNorm sits: "input", x + f(norm(x)); "output",
     # x + norm(f(x)) with the same weight on what the block adds; or
@@ -207,6 +218,10 @@ class LlamaConfig:
         if isinstance(self.rope_scaling, dict):
             object.__setattr__(self, "rope_scaling",
                                tuple(sorted(self.rope_scaling.items())))
+        if isinstance(self.linear_attn_config, dict):
+            object.__setattr__(self, "linear_attn_config", tuple(sorted(
+                (k, tuple(v) if isinstance(v, list) else v)
+                for k, v in self.linear_attn_config.items())))
         if isinstance(self.rope_parameters, dict):
             object.__setattr__(self, "rope_parameters", tuple(sorted(
                 (kind, tuple(sorted(group.items())))
@@ -268,6 +283,22 @@ class LlamaConfig:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"num_layers is {self.num_layers}")
+        if self.linear_attn_config and not self.layer_types:
+            group = self.linear_group
+            named = sorted(group.get("kda_layers", ())
+                           + group.get("full_attn_layers", ()))
+            if (self.layer_pattern or not self.kv_lora_rank
+                    or named[:self.num_layers] != list(
+                        range(1, self.num_layers + 1))):
+                raise ValueError(
+                    "linear_attn_config names every layer's mixer once, "
+                    "counted from 1, 'kda' or latent attention "
+                    "(kv_lora_rank), in place of layer_types and "
+                    f"layer_pattern: {group}")
+        if "kda" in {m for m, _ in self.layer_kinds} and not self.kda_heads:
+            raise ValueError(
+                "a 'kda' layer takes its heads from linear_attn_config "
+                "(num_heads, head_dim, short_conv_kernel_size)")
         if self.position_embedding == ROPE_BY_KIND:
             groups = dict(self.rope_parameters or ())
             for mixer in {m for m, _ in self.layer_kinds} & set(ROTARY_MIXERS):
@@ -315,6 +346,33 @@ class LlamaConfig:
     def gdn_conv_dim(self) -> int:
         """What the convolution runs over: q, k and v side by side."""
         return 2 * self.gdn_key_inner + self.gdn_value_inner
+
+    @property
+    def linear_group(self) -> Dict[str, Any]:
+        """``linear_attn_config`` as a dict ({} of a model without one)."""
+        return dict(self.linear_attn_config or ())
+
+    @property
+    def kda_heads(self) -> int:
+        return self.linear_group.get("num_heads", 0)
+
+    @property
+    def kda_head_dim(self) -> int:
+        """A head's keys and values alike."""
+        return self.linear_group.get("head_dim", 128)
+
+    @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def kda_rank(self) -> int:
+        """The width the decay and the output gate come up from: a head's."""
+        return self.kda_head_dim
+
+    @property
+    def kda_conv(self) -> int:
+        return self.linear_group.get("short_conv_kernel_size", 4)
 
     @property
     def latent_qk_dim(self) -> int:
@@ -369,8 +427,9 @@ class LlamaConfig:
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         """(mixer, FFN) of every layer: the mixer ``layer_types`` names
         (latent attention for a model with a ``kv_lora_rank``, else
-        attention), a dense FFN in the ``leading_dense`` first layers and
-        in a model without experts, the expert layer elsewhere; or, of a
+        attention) or ``linear_attn_config`` lists, a dense FFN in the
+        ``leading_dense`` first layers and in a model without experts,
+        the expert layer elsewhere; or, of a
         model with a ``layer_pattern``, the pair each character stands
         for (``LAYER_PATTERN``)."""
         if self.layer_pattern:
@@ -379,6 +438,10 @@ class LlamaConfig:
         mixers = self.layer_types[:self.num_layers] or (
             ("latent" if self.kv_lora_rank else "attention",)
             * self.num_layers)
+        if self.linear_attn_config and not self.layer_types:
+            kda = self.linear_group.get("kda_layers", ())
+            mixers = tuple("kda" if i + 1 in kda else "latent"
+                           for i in range(self.num_layers))
         return tuple(
             (mixer, "moe" if self.num_experts and i >= self.leading_dense
              else "dense") for i, mixer in enumerate(mixers))

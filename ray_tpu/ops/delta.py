@@ -662,3 +662,589 @@ def delta_kernels(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     return (_tokens_minor(jnp.transpose(o, (0, 3, 1, 2))[:, :s]),
             jnp.swapaxes(h_last, -1, -2),
             jnp.max(jax.lax.stop_gradient(peak)))
+
+
+
+# ------------------------------------- a decay PER KEY CHANNEL (the KDA rule)
+#
+# Kimi Delta Attention (Kimi Linear, arXiv:2510.26692): the same rule with
+# the decay a VECTOR over the key channels, ``alpha_t = exp(g_t)`` in
+# ``(key_dim,)``.  With ``H = S^T`` (keys x values):
+#
+#     H_t = (I - beta_t k_t k_t^T) Diag(alpha_t) H_(t-1) + beta_t k_t v_t^T
+#     o_t = H_t^T q_t
+#
+# The chunked algebra is the scalar rule's with every ``exp(G_t - G_j)``
+# moved INSIDE the sum over channels: ``A[t, j] = beta_t sum_c k_tc k_jc
+# exp(G_tc - G_jc)`` (j < t), the masked ``q k^T`` likewise, ``exp(G) k``
+# and ``exp(G_last - G) k`` channel by channel, and the state decays by rows
+# (``Diag(exp(G_last)) H``).  ``sum_c x_tc y_jc exp(G_tc - G_jc)`` cannot be
+# factored as ``(x exp(G)) (y exp(-G))^T`` over a chunk — a channel's
+# cumulative log-decay passes -88 inside 64 tokens, and ``exp(-G)``
+# overflows — so ``decayed_dots`` splits the pairs (t, j) by the LEVEL at
+# which they part: at level ``b`` (1, 2, 4, ... chunk / 2) the chunk is cut
+# into blocks of ``b`` tokens, and a pair whose ``t`` lies in an odd block
+# and ``j`` in the even block before it is computed against that odd block's
+# first token ``r``: ``(x_t exp(G_t - G_r)) . (y_j exp(G_r - G_j))``, both
+# exponents at most 0 whatever the decay (the paper's sub-chunks, taken all
+# the way down: its diagonal blocks are levels 1-8 here).  Every pair j < t
+# parts at exactly one level; a level is one product of the chunk's rows.
+
+
+def kda_reference(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                  beta: jax.Array, state: Optional[jax.Array] = None
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence above, a token at a time in float32: ``delta_reference``
+    with ``g (batch, s, heads, key_dim)``, a log-decay a key channel.  With
+    ``g`` equal over a head's channels it IS ``delta_reference``."""
+    batch, _, heads, dk = q.shape
+    if state is None:
+        state = jnp.zeros((batch, heads, v.shape[-1], dk), _F32)
+
+    def token(s, at):
+        q_t, k_t, v_t, g_t, beta_t = at
+        s = s * jnp.exp(g_t)[..., None, :]                 # S Diag(alpha)
+        held = jnp.einsum("zhvk,zhk->zhv", s, k_t, precision=_HIGHEST)
+        s = s + beta_t[..., None, None] * (
+            (v_t - held)[..., :, None] * k_t[..., None, :])
+        return s, jnp.einsum("zhvk,zhk->zhv", s, q_t, precision=_HIGHEST)
+
+    state, o = jax.lax.scan(
+        token, state.astype(_F32),
+        tuple(jnp.moveaxis(t.astype(_F32), 1, 0) for t in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1), state
+
+
+def _levels(c: int):
+    """The levels of a chunk of ``c`` tokens (any number: a sequence shorter
+    than a chunk is one), each ``(the token a row's decay is measured from:
+    its own block's first, the token a column's is measured to: the next
+    block's first, the pairs (t, j) that part at this level)``, as numpy
+    constants."""
+    import numpy as np
+
+    tok, out, b = np.arange(c), [], 1
+    while b < c:
+        blk = tok // b
+        out.append((blk * b, np.minimum((blk + 1) * b, c - 1),
+                    (blk[:, None] % 2 == 1)
+                    & (blk[:, None] - 1 == blk[None, :])))
+        b *= 2
+    return out
+
+
+def _level_scales(cum, c):
+    """A level at a time: ``(exp(G_t - G_r(t))`` for the rows,
+    ``exp(G_r'(j) - G_j)`` for the columns, both ``(..., c, d)`` float32 and
+    at most 1, the level's mask ``(c, c))``."""
+    for row_ref, col_ref, mask in _levels(c):
+        yield (jnp.exp(cum - jnp.take(cum, row_ref, axis=-2)),
+               jnp.exp(jnp.take(cum, col_ref, axis=-2) - cum), mask)
+
+
+def _mm(a, b, spec, dtype):
+    """An einsum of operands rounded to ``dtype``, float32 out (float32
+    operands at full precision)."""
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=_F32,
+                      precision=_HIGHEST if dtype == _F32 else None)
+
+
+@jax.custom_vjp
+def decayed_dots(x: jax.Array, y: jax.Array, cum: jax.Array) -> jax.Array:
+    """``P[t, j] = sum_c x_tc y_jc exp(G_tc - G_jc)`` for ``j < t``, 0
+    elsewhere: ``x``, ``y`` ``(..., c, d)`` in the dtype the products take
+    their operands in, ``cum (..., c, d)`` float32 the cumulative log-decay
+    (never rising along ``c``); float32 out ``(..., c, c)``.  Level by level
+    (above): no exponent is ever positive.  The backward pass is written out
+    and keeps ``x``, ``y`` and ``cum`` alone: ``dx_t = sum_j dP[t, j] y_j
+    exp(G_t - G_j)`` and ``dy_j = sum_t dP[t, j] x_t exp(G_t - G_j)`` by the
+    same levels, ``dG = x dx - y dy``."""
+    c, dtype = x.shape[-2], x.dtype
+    xf, yf = x.astype(_F32), y.astype(_F32)
+    out = 0.0
+    for rows, cols, mask in _level_scales(cum, c):
+        out = out + jnp.where(mask, _mm(xf * rows, yf * cols,
+                                        "...td,...jd->...tj", dtype), 0.0)
+    return out
+
+
+def _dots_fwd(x, y, cum):
+    return decayed_dots(x, y, cum), (x, y, cum)
+
+
+def _dots_bwd(res, dp):
+    x, y, cum = res
+    c, dtype = x.shape[-2], x.dtype
+    xf, yf = x.astype(_F32), y.astype(_F32)
+    dx = dy = 0.0
+    for rows, cols, mask in _level_scales(cum, c):
+        d = jnp.where(mask, dp, 0.0)
+        dx = dx + rows * _mm(d, yf * cols, "...tj,...jd->...td", dtype)
+        dy = dy + cols * _mm(d, xf * rows, "...tj,...td->...jd", dtype)
+    return dx.astype(x.dtype), dy.astype(y.dtype), xf * dx - yf * dy
+
+
+decayed_dots.defvjp(_dots_fwd, _dots_bwd)
+
+
+def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                beta: jax.Array, state: Optional[jax.Array] = None, *,
+                chunk: int = 64
+                ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """``kda_reference`` in chunks of ``chunk`` tokens:
+    ``delta_chunked`` with ``g (batch, s, heads, key_dim)`` float32.
+    Returns ``(o`` like ``v``, the state after the last token ``(batch,
+    heads, value_dim, key_dim)`` float32, ``state_absmax`` (the largest
+    ``|S|`` at any chunk's end) and ``chunk_decay_min`` (the most negative
+    cumulative log-decay inside a chunk: under -88 the factored form would
+    have overflowed), no gradient through the last two``)``.  Padding as
+    ``delta_chunked``'s.
+
+    The Pallas kernels where the shapes are the published ones
+    (``kda_kernels_fit``), the XLA form elsewhere: one algorithm, the same
+    values to rounding."""
+    form = kda_kernels if kda_kernels_fit(
+        q.shape[3], v.shape[3], min(chunk, q.shape[1])) else kda_xla
+    return form(q, k, v, g, beta, state, chunk=chunk)
+
+
+def kda_kernels_fit(key_dim: int, value_dim: int, chunk: int) -> bool:
+    """Whether a call's shapes tile the chip for ``kda_kernels``: keys and
+    values of ONE lane block each (the published 128 / 128), chunks of 64.
+    Any number of heads, any batch, any sequence of a chunk or more."""
+    return chunk == _CHUNK and key_dim == value_dim == _LANES
+
+
+def kda_xla(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+            beta: jax.Array, state: Optional[jax.Array] = None, *,
+            chunk: int = 64
+            ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """``kda_chunked`` as plain XLA: ``delta_xla``'s structure (everything
+    but the state for all chunks at once, one ``lax.scan`` across chunks,
+    autodiff but for the inverse and the decayed products)."""
+    batch, s, heads, dk = q.shape
+    dv, dtype = v.shape[-1], q.dtype
+    c = min(chunk, s)
+    q, k, v, g, beta = pad_to_multiple(c, q, k, v, g, beta)
+    n = q.shape[1] // c
+
+    def chunks(t):   # (z, s, h, ...) -> (z, chunk, h, token, ...)
+        return jnp.moveaxis(t.reshape(batch, n, c, *t.shape[2:]), 3, 2)
+
+    q, k, v = chunks(q), chunks(k), chunks(v)              # (z, c, h, t, d)
+    beta = chunks(beta.astype(_F32))                       # (z, c, h, t)
+    cum = jnp.cumsum(chunks(g.astype(_F32)), axis=-2)      # (z, c, h, t, dk)
+    kf, qf = k.astype(_F32), q.astype(_F32)
+
+    a = beta[..., :, None] * decayed_dots(k, k, cum)
+    inv = unit_lower_inverse(a).astype(dtype)
+    grown = jnp.exp(cum)                                   # exp(G_t)
+    u0 = _mm(inv, beta[..., None] * v.astype(_F32), "zchtj,zchjv->zchtv",
+             dtype)
+    w = _mm(inv, beta[..., None] * grown * kf, "zchtj,zchjk->zchtk",
+            dtype).astype(dtype)
+    last = cum[..., -1:, :]
+    k_end = (kf * jnp.exp(last - cum)).astype(dtype)       # exp(G_last - G_j)
+    whole = jnp.exp(last[..., 0, :])                       # (z, c, h, dk)
+
+    def one_chunk(carry, at):
+        h, peak = carry                                    # (z, h, k, v) f32
+        u0_c, w_c, k_end_c, whole_c = at
+        u = (u0_c - _mm(w_c, h, "zhtk,zhkv->zhtv", dtype)).astype(dtype)
+        left = whole_c[..., None] * h + _mm(k_end_c, u, "zhtk,zhtv->zhkv",
+                                            dtype)
+        peak = jnp.maximum(peak, jnp.max(jnp.abs(
+            jax.lax.stop_gradient(left))))
+        return (left, peak), (h.astype(dtype), u)
+
+    h0 = (jnp.zeros((batch, heads, dk, dv), _F32) if state is None
+          else jnp.swapaxes(state.astype(_F32), -1, -2))
+    (h_last, peak), (entering, u) = jax.lax.scan(
+        one_chunk, (h0, jnp.zeros((), _F32)),
+        tuple(jnp.moveaxis(t, 1, 0) for t in (u0, w, k_end, whole)))
+    entering, u = jnp.moveaxis(entering, 0, 1), jnp.moveaxis(u, 0, 1)
+
+    # the masked q k^T: the pairs j < t by their levels, j = t undecayed
+    m = decayed_dots(q, k, cum) + jnp.sum(qf * kf, -1)[..., None] * jnp.eye(
+        c, dtype=_F32)
+    o = _mm(m, u, "zchtj,zchjv->zchtv", dtype)
+    o = o + _mm(qf * grown, entering, "zchtk,zchkv->zchtv", dtype)
+    o = jnp.moveaxis(o, 2, 3).reshape(batch, n * c, heads, dv)[:, :s]
+    return (o.astype(v.dtype), jnp.swapaxes(h_last, -1, -2), peak,
+            jnp.min(jax.lax.stop_gradient(cum)))
+
+
+# ------------------------------------- the Pallas form of the KDA rule
+#
+# ``kdarule_fwd`` / ``kdarule_bwd``: the scalar rule's kernels (above) with
+# the decay a ``(tokens, 128)`` tile.  What changes: a token's raw
+# log-decays come in as a tile like k's (float32), and EVERY sum of them the
+# pair needs — the cumulative log-decay of a chunk, and at each of the six
+# levels the decay from a row's block start and to a column's next block
+# start — is one product of a 0/1 matrix of the pair's tokens with that
+# tile, so every exponent is a sum of numbers that are never positive (no
+# difference of two large cumulative sums is ever taken).  A 0/1 matrix is
+# exact in bfloat16, and the tile goes in as its three bfloat16 parts side
+# by side (``_split3``): three MXU passes, float32 to the last bit.  The
+# gradient to the raw log-decays is the lower-triangular 0/1 matrix,
+# transposed, times the gradient to the cumulative sums, which for the
+# decayed products is ``x dx - y dy`` (``decayed_dots``).
+
+
+def _split3(x):
+    """float32 ``x (n, 128)`` as three bfloat16 parts side by side ``(n,
+    384)``: their sum is ``x`` to 2^-24."""
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(_F32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(_F32)).astype(jnp.bfloat16)
+    return jnp.concatenate([hi, mid, lo], axis=1)
+
+
+def _sum01(sel, parts, contract=(1, 0)):
+    """The 0/1 matrix ``sel`` (bool) times the float32 tile whose
+    ``_split3`` is ``parts``: exact sums of the tile's rows."""
+    n = parts.shape[1] // 3
+    out = _dot(sel.astype(jnp.bfloat16), parts, contract)
+    return out[:, :n] + out[:, n:2 * n] + out[:, 2 * n:]
+
+
+_KDA_LEVELS = 6      # blocks of 1, 2, ... 32 tokens inside a chunk of 64
+
+
+def _kda_selectors(row, col):
+    """For a pair's tokens ``t`` (rows) and ``i`` (columns): ``lower`` (i
+    in t's chunk, at or before t) and, a level, ``(rows: i after t's block
+    start up to t; cols: i after t up to the first token of the block after
+    t's, inside the chunk; the pairs (t, j = i) that part at this level)``;
+    first ``same``: i in t's chunk.
+    (One product a selection: all eleven stacked into one product of 1408
+    rows, made once before the loop over pairs, read 10 % SLOWER on the
+    v5e, PR 59.)"""
+    same = (row >> 6) == (col >> 6)
+    levels = []
+    for level in range(_KDA_LEVELS):
+        start = (row >> level) << level
+        nxt = jnp.minimum(start + (1 << level), ((row >> 6) << 6) + _CHUNK - 1)
+        levels.append((
+            same & (col > start) & (col <= row),
+            same & (col > row) & (col <= nxt),
+            (((row >> level) & 1) == 1)
+            & ((row >> level) - 1 == (col >> level))))
+    return same, same & (col <= row), levels
+
+
+def _kda_pair_terms(q, k, v, g, beta_r):
+    """What forward and backward both make of one pair from its ``q``,
+    ``k``, ``v (128, 128)``, raw log-decays ``g (128, 128)`` float32 and
+    the row of betas ``(1, 128)``, before anything reads the state."""
+    dtype = q.dtype
+    big = _HIGHEST if dtype == _F32 else None
+    row, col = _iota((_PAIR, _PAIR), 0), _iota((_PAIR, _PAIR), 1)
+    eye = row == col
+    same, lower, levels = _kda_selectors(row, col)
+    parts = _split3(g)
+    cum = _sum01(lower, parts)                              # G_t, by channel
+    beta = jnp.sum(jnp.where(eye, beta_r, 0.0), axis=1, keepdims=True)
+    tok = _iota((_PAIR, 1), 0)
+    lasts = [cum[_CHUNK - 1:_CHUNK, :], cum[_PAIR - 1:_PAIR, :]]  # (1, 128)
+    grown = jnp.exp(cum)
+    to_end = jnp.exp(jnp.where(tok < _CHUNK, lasts[0], lasts[1]) - cum)
+    kf, qf = k.astype(_F32), q.astype(_F32)
+
+    def scaled():
+        """A level at a time: the rows' and the columns' decays, the
+        decayed operands as the products take them, the level's pairs."""
+        for level, (rows, cols, mask) in enumerate(levels):
+            er = jnp.exp(_sum01(rows, parts)) if level else None
+            ec = jnp.exp(_sum01(cols, parts))
+            x = kf if er is None else kf * er
+            qx = qf if er is None else qf * er
+            yield (er, ec, x.astype(dtype), qx.astype(dtype),
+                   (kf * ec).astype(dtype), mask)
+
+    kk = qk = jnp.zeros((_PAIR, _PAIR), _F32)
+    for _, _, x, qx, y, mask in scaled():
+        both = _dot(jnp.concatenate([x, qx], axis=0), y, (1, 1), big)
+        kk = kk + jnp.where(mask, both[:_PAIR], 0.0)
+        qk = qk + jnp.where(mask, both[_PAIR:], 0.0)
+    on_diag = jnp.sum(qf * kf, axis=1, keepdims=True)       # j = t: no decay
+    return dict(
+        big=big, eye=eye, below=same & (col < row), upto=same & (col <= row),
+        row=row, col=col, tok=tok, lower=lower, scaled=scaled, cum=cum,
+        beta=beta, grown=grown, to_end=to_end, lasts=lasts, kf=kf, qf=qf,
+        kk=kk, qk=qk + jnp.where(eye, on_diag, 0.0), a=beta * kk,
+        vb=(beta * v.astype(_F32)).astype(dtype),
+        kb=(beta * grown * kf).astype(dtype),
+        qg=(grown * qf).astype(dtype), k_end=(to_end * kf).astype(dtype))
+
+
+def _column(eye, r):   # (1, 128) -> (128, 1)
+    return jnp.sum(jnp.where(eye, r, 0.0), axis=1, keepdims=True)
+
+
+def _row(eye, c):      # (128, 1) -> (1, 128)
+    return jnp.sum(jnp.where(eye, c, 0.0), axis=0, keepdims=True)
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref,
+                    o_ref, states_ref, tb_ref, hlast_ref, peak_ref, low_ref,
+                    h_scr, *, pairs):
+    """``_fwd_kernel`` with a decay a key channel: the state ``(keys,
+    values)`` decays by ROWS.  Beside the state's largest entry it reports
+    the largest ``-G`` inside a chunk."""
+    dtype = q_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_step():
+        h_scr[...] = h0_ref[0, 0]
+        peak_ref[...] = jnp.zeros_like(peak_ref)
+        low_ref[...] = jnp.zeros_like(low_ref)
+
+    def pair(p, carry):
+        peak, low = carry
+        at = pl.ds(pl.multiple_of(p * _PAIR, _PAIR), _PAIR)
+        t = _kda_pair_terms(
+            q_ref[0, 0, :, at].T, k_ref[0, 0, :, at].T, v_ref[0, 0, :, at].T,
+            g_ref[0, 0, :, at].T, beta_ref[0, 0, :, at])
+        big = t["big"]
+        tb = _pair_inverse(t["a"], t["row"], t["col"]).astype(dtype)
+        tb_ref[0, 0, at, :] = tb
+        u0 = _dot(tb, t["vb"], (1, 0), big)
+        w = _dot(tb, t["kb"], (1, 0), big).astype(dtype)
+        h, us, from_state = h_scr[...], [], []
+        for i, rows in enumerate(_HALVES):
+            states_ref[0, 0, 2 * p + i] = h
+            hb = h.astype(dtype)
+            u = (u0[rows] - _dot(w[rows], hb, (1, 0), big)).astype(dtype)
+            from_state.append(_dot(t["qg"][rows], hb, (1, 0), big))
+            h = _column(t["eye"], jnp.exp(t["lasts"][i])) * h + _dot(
+                t["k_end"][rows], u, (0, 0), big)
+            peak = jnp.maximum(peak, jnp.max(jnp.abs(h)))
+            us.append(u)
+        h_scr[...] = h
+        o = _dot(t["qk"].astype(dtype), _halves(us), (1, 0), big) + _halves(
+            from_state)
+        o_ref[0, 0, :, at] = o.astype(o_ref.dtype).T
+        return peak, jnp.maximum(low, jnp.max(-t["cum"]))
+
+    zero = jnp.zeros((), _F32)
+    peak, low = jax.lax.fori_loop(0, pairs, pair, (zero, zero))
+    peak_ref[...] = jnp.maximum(peak_ref[...], peak)
+    low_ref[...] = jnp.maximum(low_ref[...], low)
+    hlast_ref[0, 0] = h_scr[...]
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, tb_ref,
+                    do_ref, dhl_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                    dbeta_ref, dh0_ref, dh_scr, *, pairs):
+    """``_bwd_kernel`` with a decay a key channel.  The gradient to a
+    token's cumulative log-decays is a ``(tokens, 128)`` tile: of the
+    decayed products ``x dx - y dy`` (the gradients of their operands, the
+    levels summed), of ``exp(G)`` and ``exp(G_last - G)`` what their
+    products send back, channel by channel; a chunk's last token collects
+    what ``G_last`` gets.  The raw log-decays' gradient is the 0/1
+    lower-triangular matrix, transposed, times that tile."""
+    dtype = q_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_step():
+        dh_scr[...] = dhl_ref[0, 0]
+
+    def pair(j, carry):
+        p = pairs - 1 - j
+        at = pl.ds(pl.multiple_of(p * _PAIR, _PAIR), _PAIR)
+        q, k, v = (ref[0, 0, :, at].T for ref in (q_ref, k_ref, v_ref))
+        t = _kda_pair_terms(q, k, v, g_ref[0, 0, :, at].T,
+                            beta_ref[0, 0, :, at])
+        big, tok, beta, grown = t["big"], t["tok"], t["beta"], t["grown"]
+        eye, kf, qf = t["eye"], t["kf"], t["qf"]
+        tb, do = tb_ref[0, 0, at, :], do_ref[0, 0, :, at].T
+        hs = [states_ref[0, 0, 2 * p + i] for i in range(2)]
+        hbs = [h.astype(dtype) for h in hs]
+
+        u0 = _dot(tb, t["vb"], (1, 0), big)
+        w = _dot(tb, t["kb"], (1, 0), big).astype(dtype)
+        u = (u0 - _halves([_dot(w[r], hb, (1, 0), big)
+                           for r, hb in zip(_HALVES, hbs)])).astype(dtype)
+        # o = (masked decayed q k^T) u + (exp(G) q) H
+        dm = jnp.where(t["upto"], _dot(do, u, (1, 1), big), 0.0)
+        du_o = _dot(t["qk"].astype(dtype), do, (0, 0), big)
+        dqg = _halves([_dot(do[r], hb, (1, 1), big)
+                       for r, hb in zip(_HALVES, hbs)])
+
+        dh, walked = dh_scr[...], {}
+        for i in (1, 0):   # du, dw, d k_end, and what <dH', H> gives G_last
+            r, hb = _HALVES[i], hbs[i]
+            whole = _column(eye, jnp.exp(t["lasts"][i]))        # (keys, 1)
+            dhb = dh.astype(dtype)
+            du = du_o[r] + _dot(t["k_end"][r], dhb, (1, 0), big)
+            dub = du.astype(dtype)
+            walked[i] = (du, -_dot(dub, hb, (1, 1), big),
+                         _dot(u[r], dhb, (1, 1), big),
+                         _row(eye, whole * jnp.sum(dh * hs[i], axis=1,
+                                                   keepdims=True)))
+            dh = (whole * dh + _dot(t["qg"][r], do[r], (0, 0), big)
+                  - _dot(w[r], dub, (0, 0), big))
+        dh_scr[...] = dh
+        du, dw, dk_end = (_halves([walked[i][n] for i in range(2)])
+                          for n in range(3))
+
+        dvb = _dot(tb, du.astype(dtype), (0, 0), big)      # T^T du0
+        dkb = _dot(tb, dw.astype(dtype), (0, 0), big)      # T^T dw
+        da = jnp.where(t["below"], -(
+            _dot(dvb.astype(dtype), u0.astype(dtype), (1, 1), big)
+            + _dot(dkb.astype(dtype), w, (1, 1), big)), 0.0)
+        dkk = beta * da
+        # the decayed products, level by level: what their operands get
+        dk_rows = dq_rows = dk_cols = jnp.zeros((_PAIR, _PAIR), _F32)
+        for er, ec, x, qx, y, mask in t["scaled"]():
+            d = jnp.concatenate([jnp.where(mask, dkk, 0.0),
+                                 jnp.where(mask, dm, 0.0)], axis=0
+                                ).astype(dtype)                 # (256, 128)
+            dxs = _dot(d, y, (1, 0), big)
+            dy = _dot(d, jnp.concatenate([x, qx], axis=0), (0, 0), big)
+            dx, dqx = dxs[:_PAIR], dxs[_PAIR:]
+            dk_rows = dk_rows + (dx if er is None else er * dx)
+            dq_rows = dq_rows + (dqx if er is None else er * dqx)
+            dk_cols = dk_cols + ec * dy
+        on_diag = jnp.sum(jnp.where(eye, dm, 0.0), axis=1, keepdims=True)
+        by_kb = beta * grown * dkb                          # d(k) through kb
+        to_ends = t["to_end"] * dk_end                      # ... through k_end
+        dcum = (kf * (dk_rows - dk_cols) + qf * dq_rows
+                + kf * by_kb + grown * dqg * qf - kf * to_ends)
+        for i in range(2):   # what a chunk's last token collects
+            mine = (tok >= i * _CHUNK) & (tok < (i + 1) * _CHUNK)
+            dcum = dcum + jnp.where(
+                tok == i * _CHUNK + _CHUNK - 1,
+                jnp.sum(jnp.where(mine, kf * to_ends, 0.0), axis=0,
+                        keepdims=True) + walked[i][3], 0.0)
+        dbeta = (jnp.sum(da * t["kk"], axis=1, keepdims=True)
+                 + jnp.sum(dkb * grown * kf, axis=1, keepdims=True)
+                 + jnp.sum(dvb * v.astype(_F32), axis=1, keepdims=True))
+        dg_ref[0, 0, :, at] = _sum01(t["lower"], _split3(dcum), (0, 0)).T
+        dbeta_ref[0, 0, :, at] = _row(eye, dbeta)
+        dq_ref[0, 0, :, at] = (dq_rows + on_diag * kf
+                               + grown * dqg).astype(dq_ref.dtype).T
+        dk_ref[0, 0, :, at] = (dk_rows + dk_cols + on_diag * qf + by_kb
+                               + to_ends).astype(dk_ref.dtype).T
+        dv_ref[0, 0, :, at] = (beta * dvb).astype(dv_ref.dtype).T
+        return carry
+
+    jax.lax.fori_loop(0, pairs, pair, 0)
+    dh0_ref[0, 0] = dh_scr[...]
+
+
+def _kda_plan(q, reverse):
+    sp = _plan(q, q, reverse)
+    steps, tokens = sp["grid"][2], sp["pairs"] * _PAIR
+    sp["beta"] = pl.BlockSpec(
+        (1, 1, 1, tokens), lambda b_, h_, c_: (
+            b_, h_, 0, steps - 1 - c_ if reverse else c_))
+    return sp
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kda_fwd_call(q, k, v, g, beta, h0, *, interpret):
+    """``q``, ``k``, ``v`` ``(b, heads, 128, s)``, ``g`` likewise float32
+    (a token's RAW log-decay a key channel), ``beta (b, heads, 1, s)``
+    float32, ``h0 (b, heads, 128, 128)`` float32; ``s`` a multiple of 128.
+    Returns ``_fwd_call``'s five and the largest ``-G`` inside a chunk
+    ``(b, heads, 8, 128)``."""
+    sp = _kda_plan(q, reverse=False)
+    batch, heads, keys, s = q.shape
+    stat = jax.ShapeDtypeStruct((batch, heads, 8, _LANES), _F32)
+    return pl.pallas_call(
+        functools.partial(_kda_fwd_kernel, pairs=sp["pairs"]),
+        grid=sp["grid"],
+        in_specs=[sp["qk"], sp["qk"], sp["qk"], sp["qk"], sp["beta"],
+                  sp["state"]],
+        out_specs=[sp["qk"], sp["states"], sp["tb"], sp["state"],
+                   sp["peak"], sp["peak"]],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(
+                       (batch, heads, s // _CHUNK, keys, keys), _F32),
+                   jax.ShapeDtypeStruct((batch, heads, s, _PAIR), q.dtype),
+                   jax.ShapeDtypeStruct(h0.shape, _F32), stat, stat],
+        scratch_shapes=[sp["carry"]],
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+        name="kdarule_fwd",
+    )(q, k, v, g, beta, h0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kda_bwd_call(q, k, v, g, beta, states, tb, do, dh_last, *, interpret):
+    """Gradients to ``q``, ``k``, ``v``, ``g``, ``beta`` and ``h0``, each
+    like its argument."""
+    sp = _kda_plan(q, reverse=True)
+    like = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype)
+    return pl.pallas_call(
+        functools.partial(_kda_bwd_kernel, pairs=sp["pairs"]),
+        grid=sp["grid"],
+        in_specs=[sp["qk"], sp["qk"], sp["qk"], sp["qk"], sp["beta"],
+                  sp["states"], sp["tb"], sp["qk"], sp["state"]],
+        out_specs=[sp["qk"], sp["qk"], sp["qk"], sp["qk"], sp["beta"],
+                   sp["state"]],
+        out_shape=[like(q), like(k), like(v), like(g), like(beta),
+                   like(dh_last)],
+        scratch_shapes=[sp["carry"]],
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+        name="kdarule_bwd",
+    )(q, k, v, g, beta, states, tb, do, dh_last)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _kda_rule(q, k, v, g, beta, h0, interpret):
+    o, _, _, h_last, peak, low = _kda_fwd_call(q, k, v, g, beta, h0,
+                                               interpret=interpret)
+    return o, h_last, peak, low
+
+
+def _kda_rule_fwd(q, k, v, g, beta, h0, interpret):
+    o, states, tb, h_last, peak, low = _kda_fwd_call(
+        q, k, v, g, beta, h0, interpret=interpret)
+    return (o, h_last, peak, low), (q, k, v, g, beta, states, tb)
+
+
+def _kda_rule_bwd(interpret, res, cts):
+    do, dh_last, _, _ = cts
+    return _kda_bwd_call(*res, do, dh_last, interpret=interpret)
+
+
+_kda_rule.defvjp(_kda_rule_fwd, _kda_rule_bwd)
+
+
+def kda_kernels(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                beta: jax.Array, state: Optional[jax.Array] = None, *,
+                chunk: int = 64
+                ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """``kda_chunked`` through the Pallas kernels (keys and values of 128,
+    chunks of 64; compiled on the TPU, interpreted elsewhere).  XLA pads
+    the sequence to whole pairs of chunks (tokens that neither decay nor
+    write) and hands q, k, v and the RAW log-decays over a head at a time
+    with the sequence as the minor dimension, as ``delta_kernels`` does; the
+    cumulative sums are the kernels' own."""
+    batch, s, heads, dk = q.shape
+    if not kda_kernels_fit(dk, v.shape[-1], min(chunk, s)):
+        raise ValueError("kda_kernels takes keys and values of "
+                         f"{_LANES} at chunks of {_CHUNK}")
+    q, k, v, g, beta = pad_to_multiple(
+        _PAIR, *(_tokens_minor(t) for t in (
+            q, k, v, g.astype(_F32), beta.astype(_F32))))
+
+    def by_head(t):   # (b, s, h, d) -> (b, h, d, s)
+        return jnp.transpose(t, (0, 2, 3, 1))
+
+    h0 = (jnp.zeros((batch, heads, dk, dk), _F32) if state is None
+          else jnp.swapaxes(state.astype(_F32), -1, -2))
+    o, h_last, peak, low = _kda_rule(
+        by_head(q), by_head(k), by_head(v), by_head(g),
+        jnp.transpose(beta, (0, 2, 1))[:, :, None, :], h0,
+        attention._interpret_default())
+    return (_tokens_minor(jnp.transpose(o, (0, 3, 1, 2))[:, :s]),
+            jnp.swapaxes(h_last, -1, -2),
+            jnp.max(jax.lax.stop_gradient(peak)),
+            -jnp.max(jax.lax.stop_gradient(low)))

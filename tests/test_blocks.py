@@ -37,6 +37,8 @@ FIELDS = {
     "mamba": dict(ssm_heads=8, ssm_head_dim=16, ssm_state=8, ssm_groups=2,
                   ssm_chunk=8),
     "linear_attention": dict(gdn_heads=4, gdn_key_dim=8, gdn_value_dim=16),
+    "kda": dict(linear_attn_config={"num_heads": 4, "head_dim": 16,
+                                    "short_conv_kernel_size": 4}),
     "conv": {},
     "none": {},   # the empty block, a mixer and an FFN
     "dense": {},
@@ -140,7 +142,7 @@ def test_a_step_opens_the_scopes_a_block_declares_and_no_other(role, name):
 def test_every_statistic_a_block_declares_is_a_metric(role, name):
     cfg, _, (mixer, ffn) = _model(role, name)
     declared = {**mixer.stats(cfg), **ffn.stats(cfg)}
-    assert set(declared.values()) <= {"sum", "max", "mean"}
+    assert set(declared.values()) <= {"sum", "max", "min", "mean"}
     params = init_params(jax.random.PRNGKey(0), cfg)
     _, (metrics, _) = jax.jit(lambda p: loss_and_counts(
         p, {"tokens": TOKENS}, cfg))(params)
@@ -150,13 +152,14 @@ def test_every_statistic_a_block_declares_is_a_metric(role, name):
 
 # -- (b) the step's scopes, as they were --------------------------------------
 
-def test_step_scopes_are_the_27_names_in_their_order():
+def test_step_scopes_are_the_31_names_in_their_order():
     assert STEP_SCOPES == (
         "embed", "attn_qkv", "attention", "attn_out", "ffn",
         "moe_route", "moe_exchange", "moe_dispatch", "moe_experts",
         "moe_combine",
         "ssm_in", "ssm_conv", "ssm_scan", "ssm_out",
         "gdn_in", "gdn_conv", "gdn_scan", "gdn_out",
+        "kda_in", "kda_conv", "kda_scan", "kda_out",
         "sconv_in", "sconv_gate", "sconv_out",
         "hc_map", "hc_mix", "mtp_in",
         "lm_head", "loss", "optimizer")

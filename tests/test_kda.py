@@ -1,0 +1,275 @@
+"""The delta rule with a decay PER KEY CHANNEL (``ops/delta.py``: the
+recurrence ``kda_reference``, the chunked XLA form ``kda_xla`` with its
+level-wise ``decayed_dots``, the Pallas pair ``kdarule_fwd`` /
+``kdarule_bwd`` interpreted), on the CPU at small sizes in float32.  The
+model built on it is ``tests/test_kimi_linear.py``'s."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models.blocks import kda
+from ray_tpu.ops import delta
+from ray_tpu.ops.delta import (
+    decayed_dots, delta_chunked, delta_reference, kda_chunked,
+    kda_kernels_fit, kda_reference)
+from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+
+HIGHEST = jax.default_matmul_precision("highest")
+
+
+# -- (a) the rule: chunks against the recurrence -------------------------------
+
+def _rule_inputs(seq, strength, seed=0, batch=2, heads=3, dk=16, dv=24,
+                 dtype=jnp.float32):
+    """q and k as the mixer hands them over (unit length a head, q times
+    ``dk ** -0.5``), a log-decay a key CHANNEL of about ``strength`` a
+    token (8: a chunk of 64 passes -88 in every channel, several times
+    over), beta in (0, 1), and a state that is carried in."""
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+    q, k = unit(f(batch, seq, heads, dk)) * dk ** -0.5, unit(
+        f(batch, seq, heads, dk))
+    g = -strength * jax.nn.softplus(f(batch, seq, heads, dk))
+    return (q.astype(dtype), k.astype(dtype), f(batch, seq, heads, dv).astype(
+        dtype), g, jax.nn.sigmoid(f(batch, seq, heads)),
+        f(batch, heads, dv, dk))
+
+
+def _value_and_grads(form, args, weight):
+    def scalar(*t):
+        out = form(*t)
+        o, state = out[:2]
+        return jnp.sum(o * weight) + 0.1 * jnp.sum(jnp.square(state)), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        scalar, argnums=range(6), has_aux=True))(*args)
+    return out, grads
+
+
+@pytest.mark.parametrize("seq,chunk,strength", [
+    (100, 16, 0.3), (100, 16, 8.0), (128, 64, 0.3), (150, 64, 8.0),
+    (24, 64, 2.0)],
+    ids=["ragged-16-mild", "ragged-16-past-88", "two-chunks-64-mild",
+         "ragged-64-past-88", "shorter-than-a-chunk"])
+def test_kda_chunked_equals_the_recurrence(seq, chunk, strength):
+    """Values, the state handed on and the gradient of every input — q, k,
+    v, the log-decay of every channel, beta and the state carried in —
+    against the recurrence a token at a time, at two chunk sizes, on
+    sequences no chunk divides, and with decays so strong that a chunk's
+    cumulative log-decay passes -88 (where ``exp(-G)`` of a factored chunk
+    matrix is infinite in float32): no inf, no NaN, the SAME tolerance —
+    float32 against float32 in another order of sums, 2e-5 on values, 2e-4
+    of each gradient's scale."""
+    args = _rule_inputs(seq, strength)
+    weight = jnp.asarray(np.random.default_rng(1).normal(
+        size=args[2].shape), jnp.float32)
+    with HIGHEST:
+        ((o, state, peak, decay), grads), ((want, want_state), want_grads) = (
+            _value_and_grads(f, args, weight) for f in (
+                functools.partial(kda_chunked, chunk=chunk), kda_reference))
+    assert o.shape == want.shape and o.dtype == args[2].dtype
+    np.testing.assert_allclose(o, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(state, want_state, atol=2e-5, rtol=2e-5)
+    assert float(peak) >= float(jnp.max(jnp.abs(want_state))) - 2e-5
+    # the statistic says whether the guarded path was needed
+    assert float(decay) <= float(jnp.min(args[3])) <= 0.0
+    assert (float(decay) < -88.0) == (strength == 8.0), decay
+    for name, g, w in zip("q k v g beta state".split(), grads, want_grads):
+        assert np.all(np.isfinite(g)), name
+        np.testing.assert_allclose(g, w, atol=2e-4 * float(jnp.max(jnp.abs(
+            w))), rtol=2e-4, err_msg=name)
+
+
+def test_a_decay_equal_over_the_channels_is_the_scalar_rule():
+    """With ``g`` the same in every key channel of a head the rule IS the
+    gated delta rule ``ops/delta.py`` has had: the recurrence against
+    ``delta_reference``, the chunks against ``delta_chunked``, values and
+    the state."""
+    q, k, v, g, beta, state = _rule_inputs(96, 0.3, seed=3)
+    scalar = g[..., 0]
+    wide = jnp.broadcast_to(scalar[..., None], g.shape)
+    with HIGHEST:
+        want, want_state = delta_reference(q, k, v, scalar, beta, state)
+        got, got_state = kda_reference(q, k, v, wide, beta, state)
+        chunked, chunked_state, _, _ = kda_chunked(q, k, v, wide, beta, state)
+        old, old_state, _ = delta_chunked(q, k, v, scalar, beta, state)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    np.testing.assert_allclose(got_state, want_state, atol=1e-6)
+    np.testing.assert_allclose(chunked, old, atol=2e-5)
+    np.testing.assert_allclose(chunked_state, old_state, atol=2e-5)
+    # and a decay that DIFFERS over the channels is another function
+    with HIGHEST:
+        other, _ = kda_reference(q, k, v, g, beta, state)
+    assert float(jnp.max(jnp.abs(other - want))) > 1e-2
+
+
+@pytest.mark.parametrize("c", [1, 2, 16, 24, 64])
+def test_every_pair_parts_at_exactly_one_level(c):
+    """The levels' masks tile the strict lower triangle, and at a pair's
+    level the row is measured from a token at or before it and the column
+    to a token at or after it: both exponents are never positive."""
+    levels = delta._levels(c)
+    assert len(levels) == (c - 1).bit_length()
+    covered = sum(mask.astype(int) for _, _, mask in levels) if levels \
+        else np.zeros((c, c), int)
+    np.testing.assert_array_equal(covered, np.tri(c, k=-1, dtype=int))
+    tok = np.arange(c)
+    for row_ref, col_ref, mask in levels:
+        assert np.all(row_ref <= tok) and np.all(col_ref >= tok)
+        t, j = np.nonzero(mask)
+        np.testing.assert_array_equal(row_ref[t], col_ref[j])
+
+
+def test_decayed_dots_is_the_sum_over_channels_with_its_gradient():
+    """Against the sum written out (mild decays, where it can be), forward
+    and the written-out backward against autodiff of the plain sum; and at
+    decays past -88 a chunk, where the plain FACTORED form is infinite, the
+    same function still equals the exact difference form."""
+    rng = np.random.default_rng(2)
+    x, y = (jnp.asarray(rng.normal(size=(2, 3, 32, 8)), jnp.float32)
+            for _ in range(2))
+
+    def plain(x, y, cum):
+        apart = cum[..., :, None, :] - cum[..., None, :, :]
+        lower = jnp.tri(32, k=-1, dtype=bool)[..., None]
+        return jnp.sum(jnp.where(lower, jnp.exp(jnp.where(
+            lower, apart, 0.0)), 0.0) * x[..., :, None, :]
+            * y[..., None, :, :], -1)
+
+    for strength in (0.2, 6.0):
+        cum = jnp.cumsum(-strength * jax.nn.softplus(jnp.asarray(
+            rng.normal(size=(2, 3, 32, 8)), jnp.float32)), axis=-2)
+        weight = jnp.asarray(rng.normal(size=(2, 3, 32, 32)), jnp.float32)
+        with HIGHEST:
+            got, grads = jax.value_and_grad(
+                lambda *a: jnp.sum(decayed_dots(*a) * weight),
+                argnums=(0, 1, 2))(x, y, cum)
+            want, want_grads = jax.value_and_grad(
+                lambda *a: jnp.sum(plain(*a) * weight),
+                argnums=(0, 1, 2))(x, y, cum)
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        for g, w in zip(grads, want_grads):
+            np.testing.assert_allclose(g, w, atol=1e-5 * float(
+                jnp.max(jnp.abs(w))), rtol=1e-4)
+    assert float(jnp.min(cum)) < -88.0
+    assert not np.isfinite(float(jnp.max(jnp.exp(-cum))))   # the factor
+
+
+def test_kda_in_bfloat16_keeps_its_decays_and_state_in_float32():
+    """bfloat16 operands, float32 decays, inverse and state: within 2e-2
+    of the float32 recurrence on the same (rounded) inputs, the state
+    handed on in float32, at decays past -88 too."""
+    for strength in (0.3, 8.0):
+        args = _rule_inputs(128, strength, seed=4, dtype=jnp.bfloat16)
+        f32 = tuple(a.astype(jnp.float32) for a in args)
+        with HIGHEST:
+            want, want_state = kda_reference(*f32)
+        o, state, peak, _ = jax.jit(kda_chunked)(*args)
+        assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+        scale = float(jnp.max(jnp.abs(want)))
+        assert float(jnp.max(jnp.abs(o.astype(jnp.float32) - want))) \
+            < 2e-2 * scale
+        np.testing.assert_allclose(state, want_state, atol=3e-2 * float(
+            jnp.max(jnp.abs(want_state))))
+        assert np.isfinite(float(peak))
+
+
+def _takes_the_kernels(form, args):
+    """Whether ``form``'s program holds the forward kernel: the dispatch
+    as it can be observed."""
+    return "kdarule_fwd" in str(jax.make_jaxpr(form)(*args))
+
+
+PUBLISHED = dict(batch=1, heads=2, dk=128, dv=128)     # Kimi-Linear's heads
+
+
+@pytest.mark.parametrize("seq,strength,dtype,tol", [
+    (200, 0.3, jnp.float32, 2e-5), (128, 4.0, jnp.float32, 2e-5),
+    (64, 0.3, jnp.float32, 2e-5), (256, 4.0, jnp.bfloat16, 2e-2)],
+    ids=["ragged-mild", "one-pair-past-88", "one-chunk", "bfloat16-past-88"])
+def test_the_kernels_equal_the_xla_form_and_the_recurrence(seq, strength,
+                                                           dtype, tol):
+    """The Pallas pair (interpreted here) at the published 128 / 128: on a
+    sequence that is no multiple of the chunk, on one pair of chunks whose
+    cumulative log-decay passes -88 (-277 at strength 4), on ONE chunk (the
+    other half of its pair is padding); values, the state handed on, both
+    statistics and the gradient of every input against the XLA form — and,
+    in float32, against the recurrence a token at a time.  In bfloat16 the
+    two forms round the same operands and differ by the order of their
+    sums; nothing but the shapes chooses the form."""
+    args = _rule_inputs(seq, strength, seed=5, dtype=dtype, **PUBLISHED)
+    weight = jnp.asarray(np.random.default_rng(1).normal(
+        size=args[2].shape), jnp.float32)
+    assert _takes_the_kernels(kda_chunked, args)
+    assert not _takes_the_kernels(delta.kda_xla, args)
+    with HIGHEST:
+        (got, grads), (want, want_grads) = (
+            _value_and_grads(f, args, weight)
+            for f in (kda_chunked, delta.kda_xla))
+        exact = _value_and_grads(kda_reference, tuple(
+            a.astype(jnp.float32) for a in args), weight)
+    f32 = lambda t: np.asarray(t, np.float32)
+    scale = float(jnp.max(jnp.abs(f32(want[0]))))
+    assert got[0].dtype == args[2].dtype and got[1].dtype == jnp.float32
+    np.testing.assert_allclose(f32(got[0]), f32(want[0]), atol=tol * scale)
+    np.testing.assert_allclose(got[1], want[1], atol=tol * float(
+        jnp.max(jnp.abs(want[1]))))
+    np.testing.assert_allclose(got[2], want[2], rtol=max(tol, 1e-4))
+    np.testing.assert_allclose(got[3], want[3], rtol=1e-5)
+    assert (float(got[3]) < -88.0) == (strength == 4.0)
+    for name, g, w, e in zip("q k v g beta state".split(), grads,
+                             want_grads, exact[1]):
+        assert np.all(np.isfinite(f32(g))), name
+        top = float(jnp.max(jnp.abs(f32(w))))
+        np.testing.assert_allclose(f32(g), f32(w), atol=10 * tol * top,
+                                   err_msg=name)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, e, atol=2e-4 * float(
+                jnp.max(jnp.abs(e))), rtol=2e-4, err_msg=name)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got[0], exact[0][0], atol=2e-5)
+
+
+def test_kernels_fit_says_what_the_kernels_were_written_for():
+    """The published 128 / 128 at chunks of 64, from shapes alone; every
+    other shape runs the XLA form and its program holds no Mosaic call."""
+    assert kda_kernels_fit(128, 128, 64)
+    assert not any(kda_kernels_fit(*s) for s in (
+        (96, 192, 64), (128, 128, 16), (64, 128, 64), (16, 24, 64),
+        (128, 256, 64)))
+    small = _rule_inputs(128, 0.3)
+    assert "pallas_call" not in str(jax.make_jaxpr(kda_chunked)(*small))
+    with pytest.raises(ValueError, match="keys and values of 128"):
+        delta.kda_kernels(*small)
+
+
+def test_on_a_mesh_the_kernels_run_per_shard_of_the_batch():
+    """fsdp=2 x tp=2 with the published heads: inside the manual region
+    (``parallel.sharding.batch_shard_map``, as the block calls it) each
+    shard of the batch runs ``kdarule_fwd`` / ``kdarule_bwd`` on its own
+    rows, and the outputs, both statistics and the five gradients are one
+    device's to the last bit."""
+    from ray_tpu.parallel.sharding import batch_shard_map
+
+    args = _rule_inputs(128, 2.0, seed=6, batch=4, heads=1, dk=128,
+                        dv=128)[:5]
+    mesh = make_mesh(MeshConfig(fsdp=2, tp=2), devices=jax.devices()[:4])
+    one, many = kda.shard_rule, batch_shard_map(
+        kda.shard_rule, mesh, (4, 4, 4, 4, 3), (4, None, None),
+        reduce=jax.lax.pmax)
+
+    def both(rule):
+        def scalar(*t):
+            o, peak, low = rule(*t)
+            return jnp.sum(jnp.sin(o)), (o, peak, low)
+        return jax.jit(jax.value_and_grad(scalar, argnums=range(5),
+                                          has_aux=True))(*args)
+
+    ((_, want), want_grads), ((_, got), grads) = both(one), both(many)
+    for a, b in zip((*got, *grads), (*want, *want_grads)):
+        np.testing.assert_array_equal(a, b)

@@ -378,6 +378,8 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
     want -= {"ssm_conv", "ssm_scan"} if hybrid else ssm_scopes
     # no delta-rule layer (tests/test_delta.py has a model that opens these)
     want -= {"gdn_in", "gdn_conv", "gdn_scan", "gdn_out"}
+    # ... nor one whose decay is a vector (tests/test_kimi_linear.py has one)
+    want -= {"kda_in", "kda_conv", "kda_scan", "kda_out"}
     # no short-convolution layer (tests/test_lfm2.py has such a model)
     want -= {"sconv_in", "sconv_gate", "sconv_out"}
     want -= {"loss"} if mesh is None or moe else set()  # tp splits it

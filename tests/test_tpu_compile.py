@@ -740,6 +740,70 @@ def test_olmo_hybrid_programs_lower_the_delta_kernels_once_a_use(
     assert body.count("Precision.HIGHEST") == 2 * 10
 
 
+def _kimi_linear_cfg(num_layers):
+    """Kimi-Linear-48B-A3B as ``kimilinear-train-s8192`` runs it (the
+    benchmark's configuration file: hidden 2304, 32 KDA heads of 128 / 128,
+    latent attention at 192 / 128 with no q rank), the vocabulary cut, the
+    first ``num_layers`` of its lists."""
+    import dataclasses
+
+    cfg = _benchmark_cfg("kimi-linear-48b-a3b-1of16")
+    assert (cfg.embed_dim, cfg.kda_heads, cfg.kda_head_dim, cfg.q_lora_rank,
+            cfg.position_embedding) == (2304, 32, 128, None, "nope")
+    return dataclasses.replace(cfg, vocab_size=4096, num_layers=num_layers)
+
+
+_KDA_KERNELS = ("kdarule_fwd", "kdarule_bwd")
+
+
+def test_the_kda_kernels_compile_at_the_published_heads(one_chip, as_on_chip):
+    """``ops/delta.py``'s KDA pair at 128 / 128 and 8192 tokens, forward
+    and backward: what Mosaic could refuse — tiles of (128, 128) with the
+    tokens in the lanes stood up in VMEM, twelve 0/1 products of a
+    three-part bfloat16 split a pair, concatenations of two operands'
+    rows, ten float32 products at full precision, two statistics."""
+    from ray_tpu.ops import delta
+
+    qkv = _shape((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+
+    def summed(q, k, v, g, beta):
+        o, _, peak, low = delta.kda_kernels(q, k, v, g, beta)
+        return o.astype(jnp.float32).sum() + peak + low
+
+    lowered = jax.jit(jax.grad(summed, argnums=range(5))).lower(
+        qkv, qkv, qkv, _shape((1, 8192, 32, 128), jnp.float32, one_chip),
+        _shape((1, 8192, 32), jnp.float32, one_chip))
+    text = lowered.as_text()
+    assert [text.count(f'kernel_name = "{n}"') for n in _KDA_KERNELS] == [
+        1, 1]
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_kimi_linear_kda_layer_train_step_compiles(one_chip, as_on_chip):
+    """The first layer of Kimi-Linear (a KDA mixer and the dense FFN of
+    9216) at 8192 positions as a train step, WITH the ``kdarule_*``
+    kernels, under the layer checkpoint: the step holds the forward kernel
+    twice (the rematerialised run hands the backward its entering states
+    and inverses) and the backward once, the ``kda_*`` scopes are on its
+    ops, and what the chip's compiler makes of it fits beside a layer's
+    state."""
+    cfg = _kimi_linear_cfg(1)
+    assert cfg.kind_runs == ((("kda", "dense"), 1),)
+    opt = default_optimizer()
+    lowered = make_train_step(cfg, opt).lower(
+        _state_shapes(cfg, opt, one_chip),
+        {"tokens": _shape((1, 8193), jnp.int32, one_chip)})
+    text = lowered.as_text()
+    assert [text.count(f'kernel_name = "{n}"') for n in _KDA_KERNELS] == [
+        2, 1]
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert all(name in hlo for name in _KDA_KERNELS) and "kda_scan" in hlo
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9
+
+
 def test_lfm2_conv_layer_train_step_compiles(one_chip, as_on_chip):
     """One gated short-convolution layer with its expert FFN of
     LFM2-8B-A1B as ``lfm2moe-train-s8192`` runs it (the benchmark's

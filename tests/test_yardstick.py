@@ -12,13 +12,15 @@ no longer collected here: ``test_mellum.py``'s
 holds the same fields in the form an appended cell leaves true.  Likewise
 ``test_flash_xla_ms.py``'s first case pins its entry as the LAST of
 ``per_layer``, behind which this PR's two entries now stand:
-``test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries``."""
+``test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries``.
+The Kimi-Linear cell's (PR 59) likewise, by name."""
 
 import pytest
 
 pytest.register_assert_rewrite("benchmark.tests.test_trinity",
                                "benchmark.tests.test_mellum",
-                               "benchmark.tests.test_flash_xla_ms")
+                               "benchmark.tests.test_flash_xla_ms",
+                               "benchmark.tests.test_kimi_linear")
 
 from benchmark.tests.tier1_cases import *  # noqa: E402,F401,F403
 from benchmark.tests.test_trinity import (  # noqa: E402,F401
@@ -41,3 +43,15 @@ from benchmark.tests.test_flash_xla_ms import (  # noqa: E402,F401
     test_nothing_left_reads_zero_and_no_scope_reads_none,
     test_on_a_mesh_the_slowest_chip_is_read,
     test_the_scope_less_the_flash_kernels_whatever_their_names)
+from benchmark.tests.test_kimi_linear import (  # noqa: E402,F401
+    test_each_floor_and_each_width_violated_in_turn as
+    test_kimi_linear_each_floor_and_each_width,
+    test_flops_count_the_recurrence_the_held_rows_and_the_two_latent_layers,
+    test_on_a_program_without_the_rule_the_readers_return_nothing,
+    test_rope_kernel_ms_stands_as_it_was_before_this_cells_five_entries,
+    test_the_cell_its_job_and_its_metrics as
+    test_kimi_linear_cell_job_and_metrics,
+    test_the_file_is_the_catalog_row_cut_to_one_chip_of_sixteen,
+    test_the_five_readers_on_synthetic_planes,
+    test_the_parameter_count_is_init_params as
+    test_kimi_linear_parameter_count)

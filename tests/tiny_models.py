@@ -19,8 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark.reference import (
-    afmoe, granite_hybrid, joyai_flash, lfm2_moe, mellum, nemotron_h,
-    olmo_hybrid, olmoe, xing4)
+    afmoe, granite_hybrid, joyai_flash, kimi_linear, lfm2_moe, mellum,
+    nemotron_h, olmo_hybrid, olmoe, xing4)
 from ray_tpu.models.llama import (
     ROPE_BY_KIND, LlamaConfig, forward, init_params, loss_fn)
 
@@ -106,6 +106,10 @@ MELLUM_YARN = {"rope_type": "yarn", "rope_theta": 100, "factor": 4,
                "beta_slow": 1, "attention_factor": 0.1 * math.log(4) + 1}
 MELLUM_GROUPS = {F: MELLUM_YARN, S: {"rope_type": "default",
                                      "rope_theta": 100}}
+# Kimi-Linear's lists in small, counted from 1 and longer than the model:
+# layers 1-5 are run, K K K F K, the first with the dense FFN
+KIMI_LINEAR = {"kda_layers": [1, 2, 3, 5, 6, 7], "full_attn_layers": [4, 8],
+               "num_heads": 4, "head_dim": 16, "short_conv_kernel_size": 4}
 NEMOTRON_PATTERN = "MEM*EMEME"  # longer than the model: the first 5 are run
 
 ROWS: Dict[str, Row] = {
@@ -257,6 +261,25 @@ ROWS: Dict[str, Row] = {
              sliding_window=MELLUM_WINDOW, rope_parameters=MELLUM_GROUPS,
              num_experts_per_tok=4, norm_topk_prob=True, first_expert=4,
              router_aux_loss_coef=0.001)),
+    # the published pattern in small: 1 dense layer then expert layers, KDA
+    # x3 to one latent layer WITHOUT a q rank or a rotation; 16 experts of
+    # which this chip holds 4..7, 4 a token, a shared expert; 96 positions:
+    # a chunk of the rule's 64 and a ragged second one
+    "kimi": Row(
+        dict(_SMALL, num_layers=5, num_kv_heads=4, dense_mlp_dim=96,
+             norm_eps=1e-5, linear_attn_config=KIMI_LINEAR,
+             **dict(_LATENT, q_lora_rank=None), position_embedding="nope",
+             num_experts=16, num_selected=4, experts_held=4, first_expert=4,
+             shared_experts=1, routed_scaling_factor=2.446, leading_dense=1,
+             max_seq_len=128, **_SIGMOID),
+        _jax_tokens(2, 97), kimi_linear,
+        dict(linear_attn_config=KIMI_LINEAR, num_hidden_layers=5,
+             first_k_dense_replace=1, q_lora_rank=None, mla_use_nope=True,
+             num_expert_group=1, num_attention_heads=4, qk_nope_head_dim=16,
+             qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=16,
+             rms_norm_eps=1e-5, num_experts_per_token=4,
+             routed_scaling_factor=2.446, first_expert=4),
+        precision="highest"),
 }
 
 
