@@ -1,11 +1,12 @@
-"""Seconds of ``setup_s`` under no span of the program: set-up's self
-time.  ``setup_s`` as the loop's ``end_to_end`` computes it (process start
-of ``run.py`` -> the first measured step), less the length of the UNION,
+"""Seconds of set-up's WALL under no span of the program: set-up's self
+time.  The wall (process start of ``run.py`` -> the first measured step;
+``setup_s`` until PR 62, which since then is this LESS the TPU runtime's
+start and is no total of these books), less the length of the UNION,
 clipped to ``[process_start, window_start]``, of the ``recent`` intervals
 of every name in ``Result.metrics["_spans"]`` but the containers
 (``train.fit``, ``train.run``, ``train.loop``: they hold the others and
 say nothing about where the time went).  Overlapping and nested spans
-count once, so ``covered(run)[0] + read(run)`` is ``setup_s`` of the run.
+count once, so ``covered(run)[0] + read(run)`` is the wall of the run.
 What is left: the driver before ``fit()``, the program's imports, what
 the device executes in set-up (state, check, warm-up) and the waits
 between spans.
@@ -20,7 +21,7 @@ CONTAINERS = ("train.fit", "train.run", "train.loop")
 
 
 def covered(run):
-    """(seconds of set-up under some span, ``setup_s``); None as ``read``."""
+    """(seconds of set-up under some span, the wall); None as ``read``."""
     w = run["worker"]
     spans = w.get("_spans") or {}
     if "device.bring_up" not in spans:
@@ -48,5 +49,5 @@ def read(run):
     got = covered(run)
     if got is None:
         return None
-    union, setup_s = got
-    return setup_s - union
+    union, wall = got
+    return wall - union

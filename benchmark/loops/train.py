@@ -415,15 +415,53 @@ def correct(run: Dict[str, Any]) -> bool:
                 and all(row["ok"] for row in compared(run)))
 
 
+def runtime_start_s(run: Dict[str, Any]) -> float:
+    """Driver side.  Seconds of set-up in which the worker waited for the
+    TPU runtime to start the chips it was granted (``jax.local_devices()``:
+    libtpu, 7-16 s by the machine's draw, PERF.md section 5): the program's
+    span ``jax.backend_init`` (``ray_tpu/train/backend.py::bring_up``, whose
+    body ``benchmark/tests/test_setup_reading.py`` holds to that ONE call),
+    off ``Result.metrics["_spans"]``, which every run carries; one process
+    starts all its chips under the one span, so it opens once, inside
+    set-up.  A program that leaves the bring-up to the loop has no such
+    span: the loop's own marks round ``jax.devices()`` time the same call.
+    Neither, or a span that opened twice or outside set-up: the run fails
+    by message, never a silent 0."""
+    w = run["worker"]
+    span = (w.get("_spans") or {}).get("jax.backend_init")
+    if span is not None:
+        inside = (run["process_start"] <= span["first_start"]
+                  and span["last_end"] <= w["window_start"])
+        if span["count"] != 1 or not inside:
+            raise RuntimeError(
+                "setup_s: the span jax.backend_init opened "
+                f"{span['count']} time(s), {span['first_start']:.3f} to "
+                f"{span['last_end']:.3f}; the reading takes ONE, between the "
+                f"process start {run['process_start']:.3f} and the window's "
+                f"{w['window_start']:.3f}")
+        return span["total_s"]
+    marks = w.get("setup_marks") or {}
+    if "import_jax" in marks and "devices" in marks:
+        return marks["devices"] - marks["import_jax"]
+    raise RuntimeError(
+        "setup_s: the worker reported neither the span jax.backend_init nor "
+        "the loop's marks import_jax and devices: the runtime's start is "
+        "not known, and set-up is not read without it")
+
+
 def end_to_end(run: Dict[str, Any]) -> Dict[str, float]:
     """Driver side.  Tokens of all steps completed in the window over the
     time from the first measured step's start to the last one's
     ``block_until_ready`` (worker's host clock; for the cell as a whole,
-    not per chip), and process start to the first measured step."""
+    not per chip); and the PROGRAM's set-up: process start to the first
+    measured step, LESS ``runtime_start_s`` (since PR 62; PERF.md section 2
+    has why).  ``setup_s + runtime_start_s`` is the wall of the run, which
+    the per-layer readers keep reading."""
     w = run["worker"]
+    wall = w["window_start"] - run["process_start"]
     return {
         "train_tokens_per_s": w["window"]["tokens"] / w["window"]["elapsed_s"],
-        "setup_s": w["window_start"] - run["process_start"],
+        "setup_s": wall - runtime_start_s(run),
     }
 
 

@@ -848,16 +848,20 @@ def test_step_shares_of_the_recorded_trace_make_a_hundred():
 # ------------------------------------------------------------ the loop --
 
 def _rehearsal_loop(config):
-    """Test-only entry: the train loop without the chip requirement."""
+    """Test-only entry: the train loop without the chip requirement, with
+    the marks ``loop`` sets round the bring-up it makes itself."""
     import time
 
+    marks = {"loop_start": time.time()}
     import jax
 
     from benchmark.loops import train
     from ray_tpu.air import session
 
-    session.report(train.measure(config, jax.devices(),
-                                 {"loop_start": time.time()}))
+    marks["import_jax"] = time.time()
+    devs = jax.devices()
+    marks["devices"] = time.time()
+    session.report(train.measure(config, devs, marks))
 
 
 BF16 = {"param_dtype": {"value": "bfloat16"}, "dtype": {"value": "bfloat16"}}
@@ -927,6 +931,13 @@ def test_train_loop_rehearsal_on_cpu_worker(conf, mesh, check_rows):
         assert _reader("moe.load_max_over_mean").read({"worker": w}) is None
     run = {"worker": w, "process_start": w["loop_start"] - 1.0}
     assert train.end_to_end(run)["train_tokens_per_s"] > 0
+    # a CPU worker is granted no chip and opens no ``jax.backend_init``:
+    # the runtime's start is the loop's own marks, and the identity holds
+    assert "jax.backend_init" not in w["_spans"]
+    start = train.runtime_start_s(run)
+    assert start == w["setup_marks"]["devices"] - w["setup_marks"]["import_jax"]
+    assert train.end_to_end(run)["setup_s"] + start == pytest.approx(
+        w["window_start"] - run["process_start"])
     assert train.end_to_end(run)["setup_s"] > 1.0
 
     # correct(): a chip reports its memory, and bfloat16 at this size is

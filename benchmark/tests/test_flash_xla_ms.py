@@ -26,11 +26,13 @@ def _run(kernels, scopes, step_s=(0.5, 0.5)):
 
 def test_the_entry_is_the_kernels_own_in_every_cell():
     """Beside ``flash.fwd_ms``: the same layer, the same end-to-end metric,
-    no list of cells (every cell's step has the scope), the last entry."""
+    no list of cells (every cell's step has the scope); found by its NAME
+    (PR 62: later PRs' entries stand behind it)."""
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
         bench = json.load(f)
     fwd, = [m for m in bench["per_layer"] if m["name"] == "flash.fwd_ms"]
-    assert bench["per_layer"][-1] == {**fwd, "name": NAME}
+    entry, = [m for m in bench["per_layer"] if m["name"] == NAME]
+    assert entry == {**fwd, "name": NAME}
     assert "workloads" not in fwd
 
 

@@ -126,8 +126,10 @@ def test_the_cell_its_job_and_its_metrics():
             job["check_rows"], job["warmup_steps"], job["traced_steps"]) == (
                 "train", 1, 8192, None, 1, 2, 4)
     per_layer = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [CELL]] == METRICS
+    # the cell's MEMBERSHIP in its three lists, which it opened and later
+    # windowed cells append to (PR 55's did; PR 62 holds it so)
+    for name in METRICS:
+        assert per_layer[name]["workloads"][0] == CELL
     names = [m["name"] for m in bench["per_layer"]]
     first = names.index(METRICS[0])
     assert names[first:first + len(METRICS)] == METRICS
@@ -138,12 +140,13 @@ def test_the_cell_its_job_and_its_metrics():
             "source": ("program_counter" if "executed" in name
                        else "device_trace"),
             "layer": per_layer["flash_roofline"]["layer"],
-            "moves": "train_tokens_per_s", "workloads": [CELL]}
+            "moves": "train_tokens_per_s",
+            "workloads": [CELL] + per_layer[name]["workloads"][1:]}
     for name in APPENDED_TO:
         assert CELL in per_layer[name]["workloads"]
-    assert sorted(m["name"] for m in bench["per_layer"]
-                  if CELL in m.get("workloads", ())) == sorted(
-                      METRICS + APPENDED_TO)
+    # at least these: a later PR may list the cell on an entry of its own
+    assert {m["name"] for m in bench["per_layer"]
+            if CELL in m.get("workloads", ())} >= set(METRICS + APPENDED_TO)
     # both four-chip places were taken: this one is a one-chip cell
     upto = bench["workloads"][:[c["name"] for c in bench["workloads"]
                                 ].index(CELL) + 1]
