@@ -882,14 +882,24 @@ def kda_xla(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 # log-decays come in as a tile like k's (float32), and EVERY sum of them the
 # pair needs — the cumulative log-decay of a chunk, and at each of the six
 # levels the decay from a row's block start and to a column's next block
-# start — is one product of a 0/1 matrix of the pair's tokens with that
-# tile, so every exponent is a sum of numbers that are never positive (no
-# difference of two large cumulative sums is ever taken).  A 0/1 matrix is
-# exact in bfloat16, and the tile goes in as its three bfloat16 parts side
-# by side (``_split3``): three MXU passes, float32 to the last bit.  The
+# start — comes out of ONE doubling scan of that tile along the tokens (the
+# sublanes) on the vector unit, ``_kda_decay_sums``: ``decayed_dots``'
+# levels ARE the stages of a doubling prefix sum, so a level's sums are the
+# level below's plus, in every other block, one row of the neighbouring
+# block.  Every exponent stays a sum of numbers that are never positive,
+# made by a tree of float32 additions (exact to a few 2^-24 OF ITSELF); no
+# difference of two large cumulative sums is ever taken.  Until PR 63 each
+# of the twelve sums was a 0/1 matrix of the pair's tokens times the tile's
+# three bfloat16 parts: 36 of a pair's about 115 MXU passes.  Stacking the
+# selections into one product of 1408 rows kept every pass and read 10 %
+# SLOWER (PR 59); the scan takes them out: ``kda.kernel_ms`` 152.98 ->
+# 123.96 and ``train_tokens_per_s`` 14133 -> 14877 in Kimi-Linear's cell
+# (ledger, PR 60: this change, refused on another cell's ``setup_s``).  The
 # gradient to the raw log-decays is the lower-triangular 0/1 matrix,
 # transposed, times the gradient to the cumulative sums, which for the
-# decayed products is ``x dx - y dy`` (``decayed_dots``).
+# decayed products is ``x dx - y dy`` (``decayed_dots``): the ONE product of
+# a three-part split left (``_split3``, ``_sum01``: a 0/1 matrix is exact in
+# bfloat16, three MXU passes, float32 to the last bit).
 
 
 def _split3(x):
@@ -914,25 +924,93 @@ _KDA_LEVELS = 6      # blocks of 1, 2, ... 32 tokens inside a chunk of 64
 
 
 def _kda_selectors(row, col):
-    """For a pair's tokens ``t`` (rows) and ``i`` (columns): ``lower`` (i
-    in t's chunk, at or before t) and, a level, ``(rows: i after t's block
-    start up to t; cols: i after t up to the first token of the block after
-    t's, inside the chunk; the pairs (t, j = i) that part at this level)``;
-    first ``same``: i in t's chunk.
-    (One product a selection: all eleven stacked into one product of 1408
-    rows, made once before the loop over pairs, read 10 % SLOWER on the
-    v5e, PR 59.)"""
+    """For a pair's tokens ``t`` (rows) and ``i`` (columns): ``same`` (i in
+    t's chunk), ``lower`` (and at or before t) and, a level, the pairs
+    ``(t, j = i)`` that part there: t in an odd block, j in the even block
+    before it."""
     same = (row >> 6) == (col >> 6)
-    levels = []
-    for level in range(_KDA_LEVELS):
-        start = (row >> level) << level
-        nxt = jnp.minimum(start + (1 << level), ((row >> 6) << 6) + _CHUNK - 1)
-        levels.append((
-            same & (col > start) & (col <= row),
-            same & (col > row) & (col <= nxt),
-            (((row >> level) & 1) == 1)
-            & ((row >> level) - 1 == (col >> level))))
-    return same, same & (col <= row), levels
+    return same, same & (col <= row), [
+        (((row >> level) & 1) == 1) & ((row >> level) - 1 == (col >> level))
+        for level in range(_KDA_LEVELS)]
+
+
+def _tile_roll(x, shift):
+    """``x (128, 128)`` float32 with the rows of every ``(8, 128)`` tile
+    rolled by ``shift``: row ``t`` takes row ``t - shift`` OF ITS OWN TILE."""
+    return pltpu.roll(x.reshape(_PAIR // 8, 8, _LANES), shift % 8, 1
+                      ).reshape(_PAIR, _LANES)
+
+
+def _kda_decay_sums(g, row):
+    """Every sum of the raw log-decays ``g (128 tokens, 128 channels)``
+    float32 a pair needs, by one doubling scan along the tokens; ``row
+    (128, 128)`` is the tokens' index.  A block of ``2b = 2^(l+1)`` tokens
+    is two HALVES, blocks of ``b``; ``start_l(t)`` is the first token of
+    ``t``'s block of ``b``.  Returns ``(rows, cols, cum)``:
+
+    ``rows[l][t]``, the sum over ``start_l(t) < i <= t`` (``None`` at level
+    0, where it is empty): ``R_(l+1) = R_l`` plus, in a second half, that
+    half's first log-decay and the whole first half (``R_l`` at the first
+    half's last row);
+    ``cols[l][t]``, the sum over ``t < i <= start_l(t) + b`` — FOR ``t`` IN
+    A FIRST HALF, the columns the level's mask keeps; in a second half some
+    other sum of log-decays.  With ``D_l`` the sum after ``t`` to the end of
+    its block of ``b``: ``C_l = D_l`` plus the second half's first
+    log-decay, and ``D_(l+1) = C_l + D_l`` at that row;
+    ``cum[t]``, the chunk's cumulative sum: ``R_6`` plus the chunk's first
+    ``g``.
+
+    Halves of 8 tokens and more are whole ``(8, 128)`` tiles: a stage is one
+    row's sublane broadcast and an add on half the tiles.  Under that a row
+    is fetched by rolls inside the tiles and a select on a bit of the row
+    index; what a roll wraps round a tile lands only where no level's mask
+    keeps it, and is a sum of log-decays like any other.  So every value is
+    a sum of numbers that are never positive, by float32 additions no deeper
+    than twelve: no ``exp`` of any entry passes 1."""
+    in_tile = 3                                # blocks of 1, 2, 4 tokens
+    odd = [((row >> level) & 1) == 1 for level in range(in_tile)]
+
+    def from_first(x, level):   # a block's first row, down the block
+        for i in range(level):
+            x = jnp.where(odd[i], _tile_roll(x, 1 << i), x)
+        return x
+
+    def from_last(x, level):    # a block's last row, up the block
+        for i in range(level):
+            x = jnp.where(odd[i], x, _tile_roll(x, -(1 << i)))
+        return x
+
+    after = _tile_roll(g, -1)                  # the log-decay after t's
+    rows, cols = [None], [after]
+    r, d = jnp.where(odd[0], g, 0.0), jnp.where(odd[0], 0.0, after)
+    for level in range(1, in_tile):
+        # ... after t's block: the second half's first, seen from the first
+        after = jnp.where(odd[level - 1], after,
+                          _tile_roll(after, -(1 << (level - 1))))
+        rows.append(r)
+        cols.append(d + after)
+        r = r + jnp.where(odd[level],
+                          from_first(g + _tile_roll(r, 1), level), 0.0)
+        d = d + jnp.where(odd[level], 0.0,
+                          from_last(_tile_roll(g + d, -1), level))
+
+    def halves(x, level):       # (blocks of 2b, 2b, 128)
+        return x.reshape(_PAIR >> (level + 1), 2 << level, _LANES)
+
+    def whole(first, second):
+        return jnp.concatenate([first, second], axis=1).reshape(_PAIR, _LANES)
+
+    for level in range(in_tile, _KDA_LEVELS):
+        b = 1 << level
+        r2, d2, g2 = halves(r, level), halves(d, level), halves(g, level)
+        c = d2[:, :b] + g2[:, b:b + 1]
+        rows.append(r)
+        cols.append(whole(c, d2[:, b:]))
+        r = whole(r2[:, :b], r2[:, b:] + (g2[:, b:b + 1] + r2[:, b - 1:b]))
+        d = whole(c + d2[:, b:b + 1], d2[:, b:])   # (the last is not read)
+    g2 = halves(g, _KDA_LEVELS - 1)
+    return rows, cols, r + jnp.broadcast_to(g2[:, :1], g2.shape).reshape(
+        _PAIR, _LANES)
 
 
 def _kda_pair_terms(q, k, v, g, beta_r):
@@ -943,9 +1021,8 @@ def _kda_pair_terms(q, k, v, g, beta_r):
     big = _HIGHEST if dtype == _F32 else None
     row, col = _iota((_PAIR, _PAIR), 0), _iota((_PAIR, _PAIR), 1)
     eye = row == col
-    same, lower, levels = _kda_selectors(row, col)
-    parts = _split3(g)
-    cum = _sum01(lower, parts)                              # G_t, by channel
+    same, lower, masks = _kda_selectors(row, col)
+    rows, cols, cum = _kda_decay_sums(g, row)               # cum: G_t
     beta = jnp.sum(jnp.where(eye, beta_r, 0.0), axis=1, keepdims=True)
     tok = _iota((_PAIR, 1), 0)
     lasts = [cum[_CHUNK - 1:_CHUNK, :], cum[_PAIR - 1:_PAIR, :]]  # (1, 128)
@@ -956,9 +1033,8 @@ def _kda_pair_terms(q, k, v, g, beta_r):
     def scaled():
         """A level at a time: the rows' and the columns' decays, the
         decayed operands as the products take them, the level's pairs."""
-        for level, (rows, cols, mask) in enumerate(levels):
-            er = jnp.exp(_sum01(rows, parts)) if level else None
-            ec = jnp.exp(_sum01(cols, parts))
+        for r, c, mask in zip(rows, cols, masks):
+            er, ec = None if r is None else jnp.exp(r), jnp.exp(c)
             x = kf if er is None else kf * er
             qx = qf if er is None else qf * er
             yield (er, ec, x.astype(dtype), qx.astype(dtype),

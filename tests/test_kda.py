@@ -235,6 +235,85 @@ def test_the_kernels_equal_the_xla_form_and_the_recurrence(seq, strength,
         np.testing.assert_allclose(got[0], exact[0][0], atol=2e-5)
 
 
+# -- the kernels' sums of log-decays: one doubling scan along the tokens -------
+
+@functools.lru_cache(maxsize=None)
+def _scanned(strength):
+    """``_kda_decay_sums`` as the kernels run it (inside a Pallas body,
+    interpreted here) on one head's pair of chunks of the kernels' test:
+    ``(g (128, 128), {"rows": six, "columns": six}, cum)`` as numpy."""
+    from jax.experimental import pallas as pl
+
+    g = _rule_inputs(128, strength, seed=5, **PUBLISHED)[3][0, :, 0]
+
+    def body(g_ref, out_ref):
+        rows, cols, cum = delta._kda_decay_sums(
+            g_ref[...], delta._iota((128, 128), 0))
+        assert rows[0] is None and len(rows) == len(cols) == 6
+        for i, t in enumerate(rows[1:] + cols + [cum]):
+            out_ref[i] = t
+
+    out = np.asarray(pl.pallas_call(body, out_shape=jax.ShapeDtypeStruct(
+        (12, 128, 128), jnp.float32), interpret=True)(g))
+    return np.asarray(g), {"rows": (None, *out[:5]), "columns": out[5:11]
+                           }, out[11]
+
+
+def _selection(side, level):
+    """The 0/1 matrix ``(t, i)`` the kernels multiplied the log-decays by
+    before the scan, and the tokens whose sum the level's mask keeps: a row
+    ``t`` in an odd block, a column ``j`` in an even one."""
+    t, i = np.arange(128)[:, None], np.arange(128)[None, :]
+    same, start = (t >> 6) == (i >> 6), (t >> level) << level
+    if side == "rows":
+        return same & (start < i) & (i <= t), ((t >> level) & 1)[:, 0] == 1
+    nxt = np.minimum(start + (1 << level), ((t >> 6) << 6) + 63)
+    return same & (t < i) & (i <= nxt), ((t >> level) & 1)[:, 0] == 0
+
+
+@pytest.mark.parametrize("strength", [0.3, 4.0])
+@pytest.mark.parametrize("level", range(6))
+@pytest.mark.parametrize("side", ["rows", "columns"])
+def test_the_scan_gives_a_levels_sums_of_log_decays(side, level, strength):
+    """A level's row sums (after a block's first token up to ``t``) and
+    column sums (after ``t`` up to the next block's first token) out of the
+    one scan: NOWHERE a positive value, wherever a roll carried it from (no
+    ``exp`` passes 1); on the tokens the level's mask keeps each sum within
+    1e-6 OF ITSELF of the float64 sum and its ``exp`` within 1e-6 of what
+    the 0/1 product of the three-part split gave — at a chunk whose
+    cumulative sum passes -88 too."""
+    g, sums, _ = _scanned(strength)
+    got = sums[side][level]
+    if got is None:             # rows at level 0: empty, operands undecayed
+        assert (side, level) == ("rows", 0)
+        return
+    sel, kept = _selection(side, level)
+    assert kept.sum() == 64 and np.all(got <= 0.0)
+    want = sel.astype(np.float64) @ g.astype(np.float64)
+    np.testing.assert_allclose(got[kept], want[kept], rtol=1e-6, atol=0)
+    product = np.asarray(delta._sum01(jnp.asarray(sel), delta._split3(
+        jnp.asarray(g))))
+    np.testing.assert_allclose(np.exp(got[kept]), np.exp(product[kept]),
+                               atol=1e-6, rtol=0)
+
+
+@pytest.mark.parametrize("strength", [0.3, 4.0])
+def test_the_scan_gives_a_chunks_cumulative_log_decay(strength):
+    """The rows' last stage plus the chunk's first log-decay: each chunk of
+    the pair on its own, within 1e-6 of itself of the float64 sum and of the
+    0/1 product it replaces, never rising along the tokens."""
+    g, _, cum = _scanned(strength)
+    chunks = g.astype(np.float64).reshape(2, 64, 128)
+    np.testing.assert_allclose(cum, np.cumsum(chunks, axis=1).reshape(
+        128, 128), rtol=1e-6, atol=0)
+    t, i = np.arange(128)[:, None], np.arange(128)[None, :]
+    product = delta._sum01(jnp.asarray(((t >> 6) == (i >> 6)) & (i <= t)),
+                           delta._split3(jnp.asarray(g)))
+    np.testing.assert_allclose(cum, product, rtol=1e-6, atol=0)
+    assert np.all(np.diff(cum.reshape(2, 64, 128), axis=1) <= 0.0)
+    assert (cum.min() < -88.0) == (strength == 4.0)
+
+
 def test_kernels_fit_says_what_the_kernels_were_written_for():
     """The published 128 / 128 at chunks of 64, from shapes alone; every
     other shape runs the XLA form and its program holds no Mosaic call."""

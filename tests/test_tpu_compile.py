@@ -759,9 +759,18 @@ _KDA_KERNELS = ("kdarule_fwd", "kdarule_bwd")
 def test_the_kda_kernels_compile_at_the_published_heads(one_chip, as_on_chip):
     """``ops/delta.py``'s KDA pair at 128 / 128 and 8192 tokens, forward
     and backward: what Mosaic could refuse — tiles of (128, 128) with the
-    tokens in the lanes stood up in VMEM, twelve 0/1 products of a
-    three-part bfloat16 split a pair, concatenations of two operands'
-    rows, ten float32 products at full precision, two statistics."""
+    tokens in the lanes stood up in VMEM, the doubling scan of the
+    log-decays along the sublanes (rolls inside the (8, 128) tiles with
+    selects on a bit of the row index, a block's row broadcast down whole
+    tiles), concatenations of two operands' rows, ten float32 products at
+    full precision, two statistics.  And the static count of what the scan
+    took off the MXU: a 0/1 sum of a three-part bfloat16 split is a product
+    384 lanes wide, NONE in the forward's body and ONE in the backward's
+    (``dg``) where PR 63's parent held 12 and 24 (13 apart: the backward
+    makes the levels' operands twice); the inverse's ten float32 products
+    stand as the parent's bodies hold them, once in the forward's (a product
+    prints its precision twice) and not in the backward's, which reads the
+    inverse."""
     from ray_tpu.ops import delta
 
     qkv = _shape((1, 8192, 32, 128), jnp.bfloat16, one_chip)
@@ -778,6 +787,21 @@ def test_the_kda_kernels_compile_at_the_published_heads(one_chip, as_on_chip):
         1, 1]
     compiled = lowered.compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2
+    head = jax.ShapeDtypeStruct((1, 32, 128, 8192), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct(head.shape, jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, 32, 1, 8192), jnp.float32)
+    h0 = jax.ShapeDtypeStruct((1, 32, 128, 128), jnp.float32)
+    fwd = str(jax.make_jaxpr(functools.partial(
+        delta._kda_fwd_call, interpret=False))(head, head, head, g, beta, h0))
+    bwd = str(jax.make_jaxpr(functools.partial(
+        delta._kda_bwd_call, interpret=False))(
+            head, head, head, g, beta,
+            jax.ShapeDtypeStruct((1, 32, 128, 128, 128), jnp.float32),
+            jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16), head, h0))
+    sum01 = "f32[128,384] = dot_general"
+    assert (fwd.count(sum01), bwd.count(sum01)) == (0, 1)
+    assert (fwd.count("Precision.HIGHEST"),
+            bwd.count("Precision.HIGHEST")) == (2 * 10, 0)
 
 
 def test_kimi_linear_kda_layer_train_step_compiles(one_chip, as_on_chip):

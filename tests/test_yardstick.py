@@ -4,16 +4,14 @@ rot of ``benchmark/tests`` shows in the driver's run: every case
 and no compile), the Trinity cell's, the Mellum2 cell's and the reader
 ``flash.xla_ms``'s, by name.  No assertion lives here.
 
-Trinity's ``test_the_cell_its_job_and_its_metrics`` pins the ``workloads``
-of its three ``flash.window_*`` entries to its own cell alone; PR 55 was
-asked to append a cell to them, and may not edit that file, so its case is
-no longer collected here: ``test_mellum.py``'s
-``test_trinitys_window_entries_stand_as_they_were_with_this_cell_appended``
-holds the same fields in the form an appended cell leaves true.  Likewise
-``test_flash_xla_ms.py``'s first case pins its entry as the LAST of
-``per_layer``, behind which this PR's two entries now stand:
-``test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries``.
-The Kimi-Linear cell's (PR 59) likewise, by name."""
+Trinity's ``test_the_cell_its_job_and_its_metrics`` held three entries'
+``workloads`` to ONE cell and ``test_flash_xla_ms.py``'s first case its
+entry to the LAST place of ``per_layer``; PR 62 repaired both to name and
+membership, and they are collected again, through ``tier1_cases.py``.  The cases ``test_mellum.py``
+wrote while they were out
+(``test_trinitys_window_entries_stand_as_they_were_with_this_cell_appended``,
+``test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries``) stay
+beside them, as does the Kimi-Linear cell's (PR 59), by name."""
 
 import pytest
 
