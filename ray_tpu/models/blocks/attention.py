@@ -214,6 +214,7 @@ def _attention(q, k, v, cfg, mesh, window=None, q_prescaled=False):
         return ulysses_attention(q, k, v, causal=True, sm_scale=scale,
                                  mesh=mesh)
     if impl == "reference":
+        k, v = repeat_kv_heads(q, k, v)
         return mha_reference(q, k, v, causal=True, sm_scale=scale,
                              window=window)
     # flash under a mesh: pallas has no SPMD partitioning rule, so run the

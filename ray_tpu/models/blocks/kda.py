@@ -1,23 +1,28 @@
 """A delta-rule linear-attention mixer whose decay is a VECTOR over the key
 channels (Kimi Delta Attention, arXiv:2510.26692; ``ops/delta.py``'s
 ``kda_chunked``), the mixer ``kda`` of a model whose ``linear_attn_config``
-lists its layers (``LlamaConfig.layer_kinds``), beside latent-attention
-ones.  Scopes: ``kda_in`` (the block's norm and the ONE input projection,
+lists its layers, or whose ``gqa_layers`` leaves them over
+(``LlamaConfig.layer_kinds``), beside latent- or softmax-attention ones.
+Scopes: ``kda_in`` (the block's norm and the ONE input projection,
 [q | k | v | the decay's down-projection | the gate's | b] side by side),
 ``kda_conv`` (the convolution over q, k, v with its SiLU, the L2 norm of
 each head's q and k — q then times ``head_dim ** -0.5`` —, ``beta =
-sigmoid(b)``, and the log-decay a key channel ``g = -exp(A_log_head)
-softplus(up(down) + dt_bias)``), ``kda_scan`` (the chunked rule, per shard
-of the batch under a mesh), ``kda_out`` (each head's output through ONE
-RMSNorm weight of its size, times the SIGMOID of the low-rank gate; the
-output projection; the add).
+sigmoid(b)``, TWICE that under ``cfg.kda_neg_eigval`` (the public files'
+``kda_allow_neg_eigval``: a write strength in (0, 2), the transition's
+eigenvalues in (-1, 1)), and the log-decay a key channel ``g =
+-exp(A_log_head) softplus(up(down) + dt_bias)``), ``kda_scan`` (the chunked
+rule, per shard of the batch under a mesh), ``kda_out`` (each head's output
+through ONE RMSNorm weight of its size, times the SIGMOID of the low-rank
+gate; the output projection; the add).
 
 The layer checkpoint keeps the input projection's output (``kda_proj``:
 bf16, 206 MB a layer at 8192 tokens of the published 12576 columns) and
 nothing of the rule.  The step reports ``kda_state_absmax``, the largest
 state any layer saw at a chunk's end, and ``kda_chunk_decay_min``, the most
 negative cumulative log-decay inside a chunk (under -88 a factored chunk
-matrix would have overflowed: the rule's levels are what keeps it exact).
+matrix would have overflowed: the rule's levels are what keeps it exact),
+and a model with ``kda_neg_eigval`` ``kda_beta_max`` too, the largest write
+strength of the step (over 1: the negative-eigenvalue path ran).
 """
 
 import jax
@@ -35,6 +40,7 @@ from ray_tpu.parallel.sharding import batch_shard_map
 SAVED = ("kda_proj",)
 KDA_STATE_ABSMAX = "kda_state_absmax"
 KDA_CHUNK_DECAY_MIN = "kda_chunk_decay_min"
+KDA_BETA_MAX = "kda_beta_max"
 STATS = {KDA_STATE_ABSMAX: "max", KDA_CHUNK_DECAY_MIN: "min"}
 L2_EPS = 1e-6
 _beta = jax.nn.sigmoid      # the write strength, in (0, 1)
@@ -63,6 +69,12 @@ def _shapes(cfg):
         "kda_out": Param((inner, d), ("layer", "kda_inner", "kernel_in"),
                          residual_out(cfg)),
     }
+
+
+def _stats(cfg):
+    """The statistics a layer folds: ``kda_beta_max`` only where the write
+    strength can pass 1."""
+    return {**STATS, KDA_BETA_MAX: "max"} if cfg.kda_neg_eigval else STATS
 
 
 def shard_rule(q, k, v, g, beta):
@@ -96,6 +108,10 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
         q = (unit(q) * dh ** -0.5).astype(cfg.dtype)
         k = unit(k).astype(cfg.dtype)
         beta = _beta(bt.astype(f32))
+        seen = {}
+        if cfg.kda_neg_eigval:
+            beta = 2.0 * beta
+            seen[KDA_BETA_MAX] = jnp.max(beta)
         g = (f @ lp["kda_f_up"].astype(cfg.dtype)).astype(f32)
         g = -jnp.exp(lp["kda_A_log"].astype(f32))[:, None] * jax.nn.softplus(
             (g + lp["kda_dt_bias"].astype(f32)).reshape(b, s, heads, dh))
@@ -112,10 +128,10 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
              * _gate(gate.astype(f32))).astype(cfg.dtype)
         return add(ctx, x, o.reshape(b, s, inner) @ lp["kda_out"].astype(
             cfg.dtype), residual, out_norm(lp, "kda", cfg)), fold(
-                aux, {KDA_STATE_ABSMAX: peak, KDA_CHUNK_DECAY_MIN: -decay},
-                STATS)
+                aux, {**seen, KDA_STATE_ABSMAX: peak,
+                      KDA_CHUNK_DECAY_MIN: -decay}, _stats(cfg))
 
 
 BLOCK = Block(_shapes, _apply, saved=SAVED,
               scopes=("kda_in", "kda_conv", "kda_scan", "kda_out"),
-              stats=lambda cfg: STATS)
+              stats=_stats)

@@ -13,7 +13,8 @@ mixer OR an FFN alone —, each on a RESIDUAL
 (``blocks/residual.py``: one stream | ``hc_mult`` streams mixed round every
 block by learned doubly stochastic maps).  A model is a pattern of such
 layers (``layer_types``, ``leading_dense``; ``layer_pattern``, a character
-a layer; or ``linear_attn_config``, the layers of each mixer by number),
+a layer; ``linear_attn_config``, the layers of each mixer by number; or
+``gqa_layers``, the softmax layers by number among ``kda`` ones),
 with or without a predicted-ahead module behind them.
 Each block declares its own tensors, initialisers, saved residuals, scopes
 and step statistics (``blocks.base.Block``); the decoder reads those and
@@ -186,6 +187,12 @@ class LlamaConfig:
     # ``layer_types`` names none (with ``layer_types`` the sizes alone are
     # read).
     linear_attn_config: Any = None
+    # Of a model whose public file lists its SOFTMAX layers instead, counted
+    # from 0 (``gqa_layers``; entries past ``num_layers`` name layers that
+    # are not run): those are ``attention``, every other layer ``kda`` at
+    # ``linear_attn_config``'s sizes.
+    gqa_layers: Tuple[int, ...] = ()
+    kda_neg_eigval: bool = False      # beta in (0, 2): eigenvalues (-1, 1)
     sconv_width: int = 3              # taps of the gated short convolution
     # Where a block's RMSNorm sits: "input", x + f(norm(x)); "output",
     # x + norm(f(x)) with the same weight on what the block adds; or
@@ -215,6 +222,7 @@ class LlamaConfig:
     def __post_init__(self):
         # a configuration file hands a list: keep the config hashable
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "gqa_layers", tuple(self.gqa_layers))
         if isinstance(self.rope_scaling, dict):
             object.__setattr__(self, "rope_scaling",
                                tuple(sorted(self.rope_scaling.items())))
@@ -283,7 +291,19 @@ class LlamaConfig:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"num_layers is {self.num_layers}")
-        if self.linear_attn_config and not self.layer_types:
+        if self.gqa_layers and (
+                self.layer_types or self.layer_pattern or self.kv_lora_rank
+                or set(self.linear_group) & {"kda_layers", "full_attn_layers"}
+                or self.linear_group.get("num_kv_heads") not in (
+                    None, self.kda_heads)):
+            raise ValueError(
+                "gqa_layers names the softmax layers, counted from 0, of a "
+                "model whose other layers are 'kda' at linear_attn_config's "
+                "sizes (as many value heads as key heads), in place of "
+                "layer_types, layer_pattern, latent attention and "
+                f"linear_attn_config's own lists: {self.gqa_layers}")
+        if (self.linear_attn_config and not self.layer_types
+                and not self.gqa_layers):
             group = self.linear_group
             named = sorted(group.get("kda_layers", ())
                            + group.get("full_attn_layers", ()))
@@ -427,9 +447,9 @@ class LlamaConfig:
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         """(mixer, FFN) of every layer: the mixer ``layer_types`` names
         (latent attention for a model with a ``kv_lora_rank``, else
-        attention) or ``linear_attn_config`` lists, a dense FFN in the
-        ``leading_dense`` first layers and in a model without experts,
-        the expert layer elsewhere; or, of a
+        attention), ``linear_attn_config`` lists or ``gqa_layers`` leaves
+        to ``kda``, a dense FFN in the ``leading_dense`` first layers and
+        in a model without experts, the expert layer elsewhere; or, of a
         model with a ``layer_pattern``, the pair each character stands
         for (``LAYER_PATTERN``)."""
         if self.layer_pattern:
@@ -438,7 +458,10 @@ class LlamaConfig:
         mixers = self.layer_types[:self.num_layers] or (
             ("latent" if self.kv_lora_rank else "attention",)
             * self.num_layers)
-        if self.linear_attn_config and not self.layer_types:
+        if self.gqa_layers:
+            mixers = tuple("attention" if i in self.gqa_layers else "kda"
+                           for i in range(self.num_layers))
+        elif self.linear_attn_config and not self.layer_types:
             kda = self.linear_group.get("kda_layers", ())
             mixers = tuple("kda" if i + 1 in kda else "latent"
                            for i in range(self.num_layers))

@@ -235,6 +235,59 @@ def test_the_kernels_equal_the_xla_form_and_the_recurrence(seq, strength,
         np.testing.assert_allclose(got[0], exact[0][0], atol=2e-5)
 
 
+def _overshooting_inputs(seq, dk, dv, chunk, heads=2, spread=0.05, seed=7):
+    """The conditioning ``kda_neg_eigval`` worsens: a write strength drawn
+    in (1, 2), keys that all but coincide inside every chunk (one direction
+    a chunk and head, ``spread`` of noise on it) and a decay so mild that a
+    chunk's matrix ``I + tril(beta k k^T decayed)`` holds entries up to 2 —
+    at ``beta`` 2 on equal keys without decay its inverse's entries neither
+    grow nor shrink along the chunk (the transition's eigenvalue -1)."""
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+    chunks = -(-seq // chunk)
+    base = jnp.repeat(f(1, chunks, heads, dk), chunk, axis=1)[:, :seq]
+    k = unit(base + spread * f(1, seq, heads, dk))
+    q = unit(f(1, seq, heads, dk)) * dk ** -0.5
+    g = -0.01 * jax.nn.softplus(f(1, seq, heads, dk))
+    beta = 1.0 + jax.nn.sigmoid(f(1, seq, heads))
+    return q, k, f(1, seq, heads, dv), g, beta, f(1, heads, dv, dk)
+
+
+@pytest.mark.parametrize("form,seq,dk,dv,chunk", [
+    ("xla", 100, 16, 24, 16), ("xla", 150, 16, 24, 64),
+    ("kernels", 200, 128, 128, 64)],
+    ids=["xla-chunks-of-16", "xla-chunks-of-64", "kernels-128"])
+def test_a_write_strength_over_one_on_keys_that_nearly_coincide(
+        form, seq, dk, dv, chunk):
+    """``beta`` in (1, 2) — a model with ``kda_neg_eigval`` — on keys that
+    nearly coincide inside a chunk: the chunked XLA form at two chunk sizes
+    and the Pallas pair (interpreted) against the recurrence a token at a
+    time, values, the state handed on and every gradient, at the SAME
+    tolerance as ``beta`` under 1 on spread keys (2e-5 on values, 2e-4 of
+    each gradient's scale; float32 reads 1e-6 to 5e-6): ``beta`` up to 2
+    is in ``unit_lower_inverse``'s range, the inverse is exact, and the
+    factor costs float32 nothing that shows."""
+    args = _overshooting_inputs(seq, dk, dv, chunk)
+    assert 1.0 < float(args[4].min()) and float(args[4].max()) < 2.0
+    k = args[1][0, :chunk, 0]
+    assert float(jnp.min(k @ k.T)) > 0.9       # one chunk's keys: one line
+    weight = jnp.asarray(np.random.default_rng(1).normal(
+        size=args[2].shape), jnp.float32)
+    rule = functools.partial(kda_chunked, chunk=chunk)
+    assert _takes_the_kernels(rule, args) == (form == "kernels")
+    with HIGHEST:
+        ((o, state, peak, _), grads), ((want, want_state), want_grads) = (
+            _value_and_grads(f, args, weight) for f in (rule, kda_reference))
+    np.testing.assert_allclose(o, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(state, want_state, atol=2e-5, rtol=2e-5)
+    assert float(peak) >= float(jnp.max(jnp.abs(want_state))) - 2e-5
+    for name, g, w in zip("q k v g beta state".split(), grads, want_grads):
+        assert np.all(np.isfinite(g)), name
+        np.testing.assert_allclose(g, w, atol=2e-4 * float(jnp.max(jnp.abs(
+            w))), rtol=2e-4, err_msg=name)
+
+
 # -- the kernels' sums of log-decays: one doubling scan along the tokens -------
 
 @functools.lru_cache(maxsize=None)

@@ -24,12 +24,13 @@ from ray_tpu.models.blocks import attention, kda
 from ray_tpu.models.blocks.kda import KDA_CHUNK_DECAY_MIN, KDA_STATE_ABSMAX
 from ray_tpu.models.llama import LlamaConfig, init_params, loss_fn
 from ray_tpu.ops.delta import kda_kernels_fit
-from ray_tpu.ops.moe import moe_block
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.train.core import STEP_SCOPES, init_train_state, make_train_step
 from ray_tpu.util.tracing import scope_and_phase
 import tiny_models
-from tiny_models import KIMI_LINEAR, against_the_reference, program, reference
+from tiny_models import (
+    KIMI_LINEAR, against_the_reference, expert_layer, program, reference,
+    share)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "kimi-linear-48b-a3b-1of16"
@@ -182,40 +183,13 @@ def test_the_lists_name_the_layers_from_one():
 
 # -- (d) the shares add up -----------------------------------------------------
 
-def _expert_layer(tokens=96, d=64, m=32, experts=32, seed=3):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 10)
-    normal = jax.random.normal
-    return dict(
-        x=normal(keys[0], (tokens, d)),
-        mlp_norm=1.0 + 0.3 * normal(keys[1], (d,)),
-        router=normal(keys[2], (d, experts)) * d ** -0.5,
-        router_bias=0.05 * normal(keys[3], (experts,)),
-        w_gate=normal(keys[4], (experts, d, m)) * d ** -0.5,
-        w_up=normal(keys[5], (experts, d, m)) * d ** -0.5,
-        w_down=normal(keys[6], (experts, m, d)) * m ** -0.5,
-        shared_gate=normal(keys[7], (d, m)) * d ** -0.5,
-        shared_up=normal(keys[8], (d, m)) * d ** -0.5,
-        shared_down=normal(keys[9], (m, d)) * m ** -0.5)
-
-
-@functools.partial(jax.jit, static_argnums=2)
-def _share(p, first, held):
-    return moe_block(
-        p["x"], p["mlp_norm"], p["router"], *(
-            jax.lax.dynamic_slice_in_dim(p[w], first, held)
-            for w in ("w_gate", "w_up", "w_down")),
-        num_selected=4, norm_topk_prob=True, scoring="sigmoid",
-        select_bias=p["router_bias"], gate_scale=2.446, first_expert=first,
-        residual=False)
-
-
 def test_the_sixteen_shares_add_up_to_the_uncut_layer():
     """16 chips with 2 of 32 experts each (the file's 16 chips a layer):
     their routed parts, and the shared expert ONCE, are the whole layer as
     the reference has it; every share routes over all 32 and counts the
     same assignments; the held shares sum to 1."""
-    p = _expert_layer()
-    parts = [_share(p, first, 2) for first in range(0, 32, 2)]
+    p = expert_layer()
+    parts = [share(p, first, 2, 4, 2.446) for first in range(0, 32, 2)]
     routed = sum(y for y, _ in parts)
     n = xing4.rms_norm(p["x"], p["mlp_norm"], 1e-6)
     shared = xing4.swiglu(n, p["shared_gate"], p["shared_up"],

@@ -828,6 +828,35 @@ def test_kimi_linear_kda_layer_train_step_compiles(one_chip, as_on_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9
 
 
+def test_the_solar_open2_cells_step_program_fits_the_chip(one_chip,
+                                                           as_on_chip):
+    """``solaropen2-train-s4096``'s WHOLE step as the cell runs it — the
+    benchmark's configuration file (G K K K at hidden 4096, three KDA
+    layers of 64 heads x 128 with ``beta`` to 2, 10 of 320 experts held,
+    24576 rows) at 1 x 4096 — compiled for the described chip: the peak of
+    live bytes the compiler itself reports (``peak_memory_in_bytes``; NOT
+    arguments + temporaries, which doubles the arena's packing and reads
+    18.20 GB) stays inside the chip's 16.91 GB — 15.12 at PR 64 —, so a
+    later change that pushes the fullest KDA cell over the chip fails here
+    before the driver's run; the softmax layer runs the flash kernels, the
+    KDA layers ``kdarule_*`` and the experts ``moe_gmm*``."""
+    cfg = _benchmark_cfg("solar-open2-250b-1of32")
+    assert cfg.kind_runs == ((("attention", "moe"), 1), (("kda", "moe"), 3))
+    assert (cfg.embed_dim, cfg.kda_inner, cfg.kda_neg_eigval,
+            cfg.local_experts, cfg.vocab_size) == (4096, 8192, True, 10,
+                                                   24576)
+    opt = default_optimizer()
+    compiled = make_train_step(cfg, opt).lower(
+        _state_shapes(cfg, opt, one_chip),
+        {"tokens": _shape((1, 4097), jnp.int32, one_chip)}).compile()
+    hlo = compiled.as_text()
+    for kernel in (*_KDA_KERNELS, "flash_fwd", "flash_dkv", "moe_gmm_swiglu"):
+        assert kernel in hlo, kernel
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == pytest.approx(8.526e9, rel=1e-3)
+    assert 0.25 * 16.909e9 < mem.peak_memory_in_bytes < 16.909e9
+
+
 def test_lfm2_conv_layer_train_step_compiles(one_chip, as_on_chip):
     """One gated short-convolution layer with its expert FFN of
     LFM2-8B-A1B as ``lfm2moe-train-s8192`` runs it (the benchmark's

@@ -20,9 +20,10 @@ import numpy as np
 
 from benchmark.reference import (
     afmoe, granite_hybrid, joyai_flash, kimi_linear, lfm2_moe, mellum,
-    nemotron_h, olmo_hybrid, olmoe, xing4)
+    nemotron_h, olmo_hybrid, olmoe, solar_open2, xing4)
 from ray_tpu.models.llama import (
     ROPE_BY_KIND, LlamaConfig, forward, init_params, loss_fn)
+from ray_tpu.ops.moe import moe_block
 
 S, F = "sliding_attention", "full_attention"
 
@@ -110,6 +111,12 @@ MELLUM_GROUPS = {F: MELLUM_YARN, S: {"rope_type": "default",
 # layers 1-5 are run, K K K F K, the first with the dense FFN
 KIMI_LINEAR = {"kda_layers": [1, 2, 3, 5, 6, 7], "full_attn_layers": [4, 8],
                "num_heads": 4, "head_dim": 16, "short_conv_kernel_size": 4}
+# Solar-Open-2's keys in small: the SOFTMAX layers counted from 0 and past
+# the model's depth (layers 0-3 are run, G K K K), the KDA sizes alone in
+# the group, as many value heads as key heads
+SOLAR_GQA = (0, 4, 8)
+SOLAR_LINEAR = {"num_heads": 4, "head_dim": 16, "num_kv_heads": None,
+                "short_conv_kernel_size": 4}
 NEMOTRON_PATTERN = "MEM*EMEME"  # longer than the model: the first 5 are run
 
 ROWS: Dict[str, Row] = {
@@ -280,6 +287,26 @@ ROWS: Dict[str, Row] = {
              rms_norm_eps=1e-5, num_experts_per_token=4,
              routed_scaling_factor=2.446, first_expert=4),
         precision="highest"),
+    # one published period, G K K K: a gated NoPE softmax layer of 4 query
+    # / 2 KV heads, then three KDA layers whose write strength reaches 2,
+    # their inner width (4 x 16) TWICE the hidden size as published; every
+    # layer an expert layer, 16 experts of which this chip holds 4..7, 4 a
+    # token, a shared expert; 96 positions as Kimi-Linear's row
+    "solar": Row(
+        dict(_SMALL, embed_dim=32, num_layers=4, num_kv_heads=2,
+             norm_eps=1e-5, gqa_layers=SOLAR_GQA,
+             linear_attn_config=SOLAR_LINEAR, kda_neg_eigval=True,
+             attn_output_gate=True, position_embedding="nope",
+             num_experts=16, num_selected=4, experts_held=4, first_expert=4,
+             shared_experts=1, max_seq_len=128, **_SIGMOID),
+        _jax_tokens(2, 97), solar_open2,
+        dict(gqa_layers=list(SOLAR_GQA), linear_attn_config=SOLAR_LINEAR,
+             num_hidden_layers=4, first_k_dense_replace=0, use_rope=False,
+             use_gqa_gate=True, kda_use_full_proj=False,
+             kda_allow_neg_eigval=True, num_attention_heads=4,
+             num_key_value_heads=2, rms_norm_eps=1e-5,
+             num_experts_per_tok=4, routed_scaling_factor=1, first_expert=4),
+        precision="highest"),
 }
 
 
@@ -407,3 +434,38 @@ def against_the_reference(name: str, *, parts=("loss",), rtol=2e-5,
     worst = apart(grads, want_grads)
     assert max(jax.tree.leaves(worst)) < grad_rtol, worst
     return total, got, want, grads
+
+
+# -- one expert layer and the shares of it (the models' share tests) ----------
+
+def expert_layer(tokens=96, d=64, m=32, experts=32, seed=3):
+    """The tensors of ONE sigmoid-routed expert layer with a selection bias
+    and a shared expert, uncut, and ``tokens`` rows ``x`` to run it on."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 10)
+    normal = jax.random.normal
+    return dict(
+        x=normal(keys[0], (tokens, d)),
+        mlp_norm=1.0 + 0.3 * normal(keys[1], (d,)),
+        router=normal(keys[2], (d, experts)) * d ** -0.5,
+        router_bias=0.05 * normal(keys[3], (experts,)),
+        w_gate=normal(keys[4], (experts, d, m)) * d ** -0.5,
+        w_up=normal(keys[5], (experts, d, m)) * d ** -0.5,
+        w_down=normal(keys[6], (experts, m, d)) * m ** -0.5,
+        shared_gate=normal(keys[7], (d, m)) * d ** -0.5,
+        shared_up=normal(keys[8], (d, m)) * d ** -0.5,
+        shared_down=normal(keys[9], (m, d)) * m ** -0.5)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def share(p, first, held, k, scale):
+    """What the chip that holds experts ``first .. first + held - 1`` of
+    ``expert_layer``'s adds for its tokens (the routed part alone), and the
+    layer's statistics: ``moe_block`` routing over ALL the experts, ``k`` a
+    token, gates renormalised over the chosen times ``scale``."""
+    return moe_block(
+        p["x"], p["mlp_norm"], p["router"], *(
+            jax.lax.dynamic_slice_in_dim(p[w], first, held)
+            for w in ("w_gate", "w_up", "w_down")),
+        num_selected=k, norm_topk_prob=True, scoring="sigmoid",
+        select_bias=p["router_bias"], gate_scale=scale, first_expert=first,
+        residual=False)
