@@ -16,8 +16,14 @@ through ONE RMSNorm weight of its size, times the SIGMOID of the low-rank
 gate; the output projection; the add).
 
 The layer checkpoint keeps the input projection's output (``kda_proj``:
-bf16, 206 MB a layer at 8192 tokens of the published 12576 columns) and
-nothing of the rule.  The step reports ``kda_state_absmax``, the largest
+bf16, 206 MB a layer at 8192 tokens of the published 12576 columns) and,
+where the rule runs as its Pallas pair, what ``kdarule_bwd`` and ``kda_out``
+read of the forward pass (``ops.delta.KDA_SAVED_RESIDUALS``, named inside
+the rule's ``custom_vjp``: the kernel's output and each pair's inverse,
+bf16, 67 MB a layer each at 32 heads x 8192 tokens, and the float32 state
+entering each grid step of 8 chunks, 33.5 MB — 168 MB a layer), so that the
+rematerialised pass holds no second ``kdarule_fwd``; the XLA form makes no
+such name and keeps nothing of the rule.  The step reports ``kda_state_absmax``, the largest
 state any layer saw at a chunk's end, and ``kda_chunk_decay_min``, the most
 negative cumulative log-decay inside a chunk (under -88 a factored chunk
 matrix would have overflowed: the rule's levels are what keeps it exact),
@@ -32,12 +38,13 @@ from jax.ad_checkpoint import checkpoint_name
 from ray_tpu.models.blocks.base import (
     Block, Ctx, Param, a_log, conv, dt_bias, fold, ones, residual_out)
 from ray_tpu.models.blocks.residual import add, block_in, out_norm
-from ray_tpu.ops.delta import kda_chunked
+from ray_tpu.ops.delta import KDA_SAVED_RESIDUALS, kda_chunked
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.ops.ssm import causal_conv1d
 from ray_tpu.parallel.sharding import batch_shard_map
 
-SAVED = ("kda_proj",)
+KDA_PROJ = "kda_proj"
+SAVED = (KDA_PROJ, *KDA_SAVED_RESIDUALS)
 KDA_STATE_ABSMAX = "kda_state_absmax"
 KDA_CHUNK_DECAY_MIN = "kda_chunk_decay_min"
 KDA_BETA_MAX = "kda_beta_max"
@@ -93,7 +100,7 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
     f32 = jnp.float32
     with jax.named_scope("kda_in"):
         h = block_in(x, lp["kda_norm"], cfg)
-        proj = checkpoint_name(h @ lp["kda_in"].astype(cfg.dtype), *SAVED)
+        proj = checkpoint_name(h @ lp["kda_in"].astype(cfg.dtype), KDA_PROJ)
         qkv, f, gate, bt = jnp.split(
             proj, [3 * inner, 3 * inner + rank, 3 * inner + 2 * rank], -1)
     with jax.named_scope("kda_conv"):

@@ -61,12 +61,12 @@ also writes what ``delta_bwd`` needs beside the inputs: the state ENTERING
 each chunk (float32, 142 MB a layer at the published sizes) and each
 pair's inverse as the products read it (``q.dtype``, 31 MB); under a layer
 checkpoint the forward kernel runs again in the backward pass and neither
-reaches the checkpoint's stack.  With the entering states saved, the
-backward kernel remakes a pair's ``u`` for both chunks at once and only the
-state's gradient walks; the inverse's gradient needs no float32 product of
-128 rows (``dA = -(T^T du0) (T vb)^T - (T^T dw) (T kb)^T``: two big
-products of what the pair has).  The cumulative log-decays of a chunk are
-made by XLA round the call, which also differentiates them.
+reaches the checkpoint's stack (true of THIS rule's pair only since PR 65:
+the per-channel rule's, below, keeps one state a grid step by name).  With
+the states saved ``delta_bwd`` remakes a pair's ``u`` for both chunks at
+once and only the state's gradient walks; the inverse's gradient needs no
+float32 product of 128 rows (``dA = -(T^T du0) (T vb)^T - (T^T dw) (T
+kb)^T``).  XLA makes a chunk's cumulative log-decays and differentiates them.
 
 Precision: log-decays, their cumulative sums, every ``exp``, ``A``, the
 inverse and the carried state are float32 (the inverse's products at
@@ -877,6 +877,11 @@ def kda_xla(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 
 # ------------------------------------- the Pallas form of the KDA rule
 #
+# (its one import of its own stands HERE, with the code behind the scalar
+# rule's, so that ``delta_fwd`` / ``delta_bwd``, whose lowered text embeds
+# their line numbers, keep their compile-cache keys)
+from jax.ad_checkpoint import checkpoint_name  # noqa: E402
+#
 # ``kdarule_fwd`` / ``kdarule_bwd``: the scalar rule's kernels (above) with
 # the decay a ``(tokens, 128)`` tile.  What changes: a token's raw
 # log-decays come in as a tile like k's (float32), and EVERY sum of them the
@@ -900,6 +905,28 @@ def kda_xla(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 # decayed products is ``x dx - y dy`` (``decayed_dots``): the ONE product of
 # a three-part split left (``_split3``, ``_sum01``: a 0/1 matrix is exact in
 # bfloat16, three MXU passes, float32 to the last bit).
+#
+# WHAT THE PAIR HANDS FROM FORWARD TO BACKWARD (PR 65): the scalar rule's
+# ``delta_fwd`` writes the float32 state entering EVERY chunk, which at 128
+# x 128 is 268 MB a layer of 32 heads x 8192 tokens — too dear for a layer
+# checkpoint to hold, so until PR 65 the checkpoint's backward pass ran the
+# whole of ``kdarule_fwd`` again (decayed products, inverse and all) to get
+# ``states``, ``tb`` and ``o`` back: 44.6 ms of Kimi-Linear's 551 ms step.
+# ``kdarule_fwd`` now writes the state entering each GRID STEP (``_plan``:
+# up to ``_STEP_PAIRS`` pairs = 8 chunks; 33.5 MB a layer), the pairs'
+# inverses ``tb`` (bf16, 67 MB) and ``o`` (67 MB), ``_kda_rule_fwd`` names
+# the three (``KDA_SAVED_RESIDUALS``) and the checkpoint keeps them: no
+# second ``kdarule_fwd``.  ``kdarule_bwd`` takes a grid step from the end
+# and first walks the step's pairs FORWARD from the state that entered it
+# (``_kda_sweep``): with ``tb`` at hand a chunk's state costs ``u0 = T vb``,
+# ``w = T kb``, ``u = u0 - w H`` and ``H' = exp(G_last) H + k_end^T u`` — the
+# first three the backward made anyway — and none of the decayed products or
+# the inverse's ten float32 products.  Forward kernel and sweep advance the
+# state through ONE function, ``_kda_chunk`` (the same operands, casts and
+# order), so the rebuilt states are the forward's to the bit
+# (``tests/test_kda.py``) and the gradient is what it was.  The sweep's
+# states and each pair's ``u0``, ``w``, ``u`` stay in VMEM scratch for the
+# reverse walk (512 + 384 KB at four pairs a step).
 
 
 def _split3(x):
@@ -1013,27 +1040,47 @@ def _kda_decay_sums(g, row):
         _PAIR, _LANES)
 
 
-def _kda_pair_terms(q, k, v, g, beta_r):
-    """What forward and backward both make of one pair from its ``q``,
-    ``k``, ``v (128, 128)``, raw log-decays ``g (128, 128)`` float32 and
-    the row of betas ``(1, 128)``, before anything reads the state."""
-    dtype = q.dtype
+def _kda_state_terms(k, v, g, beta_r):
+    """The part of a pair's terms that the STATE's walk through its two
+    chunks takes — ``k``, ``v (128, 128)``, raw log-decays ``g (128, 128)``
+    float32, the row of betas ``(1, 128)`` —: the decay sums, ``exp(G)``,
+    ``exp(G_last - G)``, ``beta v``, ``beta exp(G) k`` and ``exp(G_last -
+    G) k``.  No decayed product, no inverse, nothing of ``q``: what the
+    backward kernel's sweep stops at."""
+    dtype = k.dtype
     big = _HIGHEST if dtype == _F32 else None
     row, col = _iota((_PAIR, _PAIR), 0), _iota((_PAIR, _PAIR), 1)
     eye = row == col
-    same, lower, masks = _kda_selectors(row, col)
     rows, cols, cum = _kda_decay_sums(g, row)               # cum: G_t
     beta = jnp.sum(jnp.where(eye, beta_r, 0.0), axis=1, keepdims=True)
     tok = _iota((_PAIR, 1), 0)
     lasts = [cum[_CHUNK - 1:_CHUNK, :], cum[_PAIR - 1:_PAIR, :]]  # (1, 128)
     grown = jnp.exp(cum)
     to_end = jnp.exp(jnp.where(tok < _CHUNK, lasts[0], lasts[1]) - cum)
-    kf, qf = k.astype(_F32), q.astype(_F32)
+    kf = k.astype(_F32)
+    return dict(
+        big=big, eye=eye, row=row, col=col, tok=tok, rows=rows, cols=cols,
+        cum=cum, beta=beta, grown=grown, to_end=to_end, lasts=lasts, kf=kf,
+        vb=(beta * v.astype(_F32)).astype(dtype),
+        kb=(beta * grown * kf).astype(dtype),
+        k_end=(to_end * kf).astype(dtype))
+
+
+def _kda_pair_terms(q, k, v, g, beta_r):
+    """What forward and backward both make of one pair from its ``q``,
+    ``k``, ``v (128, 128)``, raw log-decays ``g (128, 128)`` float32 and
+    the row of betas ``(1, 128)``, before anything reads the state:
+    ``_kda_state_terms`` and, on top, the decayed products level by level
+    (``kk``, ``qk``) and ``exp(G) q``."""
+    t = _kda_state_terms(k, v, g, beta_r)
+    dtype, big, row, col, eye = q.dtype, t["big"], t["row"], t["col"], t["eye"]
+    same, lower, masks = _kda_selectors(row, col)
+    kf, qf = t["kf"], q.astype(_F32)
 
     def scaled():
         """A level at a time: the rows' and the columns' decays, the
         decayed operands as the products take them, the level's pairs."""
-        for r, c, mask in zip(rows, cols, masks):
+        for r, c, mask in zip(t["rows"], t["cols"], masks):
             er, ec = None if r is None else jnp.exp(r), jnp.exp(c)
             x = kf if er is None else kf * er
             qx = qf if er is None else qf * er
@@ -1047,13 +1094,9 @@ def _kda_pair_terms(q, k, v, g, beta_r):
         qk = qk + jnp.where(mask, both[_PAIR:], 0.0)
     on_diag = jnp.sum(qf * kf, axis=1, keepdims=True)       # j = t: no decay
     return dict(
-        big=big, eye=eye, below=same & (col < row), upto=same & (col <= row),
-        row=row, col=col, tok=tok, lower=lower, scaled=scaled, cum=cum,
-        beta=beta, grown=grown, to_end=to_end, lasts=lasts, kf=kf, qf=qf,
-        kk=kk, qk=qk + jnp.where(eye, on_diag, 0.0), a=beta * kk,
-        vb=(beta * v.astype(_F32)).astype(dtype),
-        kb=(beta * grown * kf).astype(dtype),
-        qg=(grown * qf).astype(dtype), k_end=(to_end * kf).astype(dtype))
+        t, below=same & (col < row), upto=same & (col <= row), lower=lower,
+        scaled=scaled, qf=qf, kk=kk, qk=qk + jnp.where(eye, on_diag, 0.0),
+        a=t["beta"] * kk, qg=(t["grown"] * qf).astype(dtype))
 
 
 def _column(eye, r):   # (1, 128) -> (128, 1)
@@ -1064,12 +1107,29 @@ def _row(eye, c):      # (128, 1) -> (1, 128)
     return jnp.sum(jnp.where(eye, c, 0.0), axis=0, keepdims=True)
 
 
+def _kda_chunk(t, i, h, u0, w):
+    """Chunk ``i`` of a pair from the state ``h (keys, values)`` float32
+    that enters it: ``(h`` as the products read it, the chunk's new values
+    ``u``, the state it leaves``)``.  The forward kernel and the backward
+    kernel's sweep both walk the state through THIS, so the states the
+    sweep rebuilds are the forward's to the bit."""
+    rows, big = _HALVES[i], t["big"]
+    hb = h.astype(w.dtype)
+    u = (u0[rows] - _dot(w[rows], hb, (1, 0), big)).astype(w.dtype)
+    return hb, u, _column(t["eye"], jnp.exp(t["lasts"][i])) * h + _dot(
+        t["k_end"][rows], u, (0, 0), big)
+
+
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref,
-                    o_ref, states_ref, tb_ref, hlast_ref, peak_ref, low_ref,
+                    o_ref, entering_ref, tb_ref, hlast_ref, peak_ref, low_ref,
                     h_scr, *, pairs):
     """``_fwd_kernel`` with a decay a key channel: the state ``(keys,
-    values)`` decays by ROWS.  Beside the state's largest entry it reports
-    the largest ``-G`` inside a chunk."""
+    values)`` decays by ROWS.  Beside ``o`` it writes what the backward
+    kernel cannot cheaply remake, and no more: each pair's inverse as the
+    products read it and the state entering the GRID STEP (not every
+    chunk: ``kdarule_bwd`` walks the step's chunks forward again from it).
+    Beside the state's largest entry it reports the largest ``-G`` inside
+    a chunk."""
     dtype = q_ref.dtype
 
     @pl.when(pl.program_id(2) == 0)
@@ -1077,6 +1137,8 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref,
         h_scr[...] = h0_ref[0, 0]
         peak_ref[...] = jnp.zeros_like(peak_ref)
         low_ref[...] = jnp.zeros_like(low_ref)
+
+    entering_ref[0, 0, 0] = h_scr[...]
 
     def pair(p, carry):
         peak, low = carry
@@ -1091,12 +1153,8 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref,
         w = _dot(tb, t["kb"], (1, 0), big).astype(dtype)
         h, us, from_state = h_scr[...], [], []
         for i, rows in enumerate(_HALVES):
-            states_ref[0, 0, 2 * p + i] = h
-            hb = h.astype(dtype)
-            u = (u0[rows] - _dot(w[rows], hb, (1, 0), big)).astype(dtype)
+            hb, u, h = _kda_chunk(t, i, h, u0, w)
             from_state.append(_dot(t["qg"][rows], hb, (1, 0), big))
-            h = _column(t["eye"], jnp.exp(t["lasts"][i])) * h + _dot(
-                t["k_end"][rows], u, (0, 0), big)
             peak = jnp.maximum(peak, jnp.max(jnp.abs(h)))
             us.append(u)
         h_scr[...] = h
@@ -1112,21 +1170,62 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref,
     hlast_ref[0, 0] = h_scr[...]
 
 
-def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, tb_ref,
-                    do_ref, dhl_ref, dq_ref, dk_ref, dv_ref, dg_ref,
-                    dbeta_ref, dh0_ref, dh_scr, *, pairs):
-    """``_bwd_kernel`` with a decay a key channel.  The gradient to a
-    token's cumulative log-decays is a ``(tokens, 128)`` tile: of the
-    decayed products ``x dx - y dy`` (the gradients of their operands, the
-    levels summed), of ``exp(G)`` and ``exp(G_last - G)`` what their
-    products send back, channel by channel; a chunk's last token collects
-    what ``G_last`` gets.  The raw log-decays' gradient is the 0/1
-    lower-triangular matrix, transposed, times that tile."""
+def _kda_sweep(k_ref, v_ref, g_ref, beta_ref, entering_ref, tb_ref, hs_scr,
+               u0_scr, w_scr, u_scr, pairs):
+    """A grid step's pairs FORWARD from the state that entered the step,
+    for the backward kernel: the state entering each chunk into ``hs_scr
+    (2 pairs, keys, values)`` float32, each pair's ``u0 = T vb``, ``w = T
+    kb`` and ``u = u0 - w H`` into ``u0_scr``, ``w_scr``, ``u_scr (pairs,
+    128, 128)`` as the products read them.  ``_kda_state_terms`` and
+    ``_kda_chunk`` alone: four products a pair."""
+    dtype = k_ref.dtype
+
+    def pair(p, h):
+        at = pl.ds(pl.multiple_of(p * _PAIR, _PAIR), _PAIR)
+        t = _kda_state_terms(k_ref[0, 0, :, at].T, v_ref[0, 0, :, at].T,
+                             g_ref[0, 0, :, at].T, beta_ref[0, 0, :, at])
+        tb = tb_ref[0, 0, at, :]
+        u0 = _dot(tb, t["vb"], (1, 0), t["big"])
+        w = _dot(tb, t["kb"], (1, 0), t["big"]).astype(dtype)
+        us = []
+        for i in range(2):
+            hs_scr[2 * p + i] = h
+            _, u, h = _kda_chunk(t, i, h, u0, w)
+            us.append(u)
+        u0_scr[p], w_scr[p], u_scr[p] = u0.astype(dtype), w, _halves(us)
+        return h
+
+    jax.lax.fori_loop(0, pairs, pair, entering_ref[0, 0, 0])
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, entering_ref,
+                    tb_ref, do_ref, dhl_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                    dbeta_ref, dh0_ref, dh_scr, hs_scr, u0_scr, w_scr, u_scr,
+                    *, pairs):
+    """``_bwd_kernel`` with a decay a key channel, and WITHOUT the forward's
+    per-chunk states: a grid step (taken from the end) first SWEEPS its
+    pairs forward from the state that entered the step — the decay sums,
+    ``u0 = T vb``, ``w = T kb`` and, a chunk at a time, ``u = u0 - w H``,
+    ``H' = exp(G_last) H + k_end^T u`` (``_kda_chunk``: the forward
+    kernel's sums in the forward kernel's order, none of its decayed
+    products, no inverse) — into VMEM: the state entering each chunk
+    (``hs_scr``, float32) and each pair's ``u0``, ``w``, ``u`` as the
+    products read them; then it walks the pairs in reverse and makes none
+    of them again.  The gradient to a token's cumulative log-decays is a
+    ``(tokens, 128)`` tile: of the decayed products ``x dx - y dy`` (the
+    gradients of their operands, the levels summed), of ``exp(G)`` and
+    ``exp(G_last - G)`` what their products send back, channel by channel;
+    a chunk's last token collects what ``G_last`` gets.  The raw
+    log-decays' gradient is the 0/1 lower-triangular matrix, transposed,
+    times that tile."""
     dtype = q_ref.dtype
 
     @pl.when(pl.program_id(2) == 0)
     def _first_step():
         dh_scr[...] = dhl_ref[0, 0]
+
+    _kda_sweep(k_ref, v_ref, g_ref, beta_ref, entering_ref, tb_ref, hs_scr,
+               u0_scr, w_scr, u_scr, pairs)
 
     def pair(j, carry):
         p = pairs - 1 - j
@@ -1137,13 +1236,9 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, tb_ref,
         big, tok, beta, grown = t["big"], t["tok"], t["beta"], t["grown"]
         eye, kf, qf = t["eye"], t["kf"], t["qf"]
         tb, do = tb_ref[0, 0, at, :], do_ref[0, 0, :, at].T
-        hs = [states_ref[0, 0, 2 * p + i] for i in range(2)]
+        hs = [hs_scr[2 * p + i] for i in range(2)]
         hbs = [h.astype(dtype) for h in hs]
-
-        u0 = _dot(tb, t["vb"], (1, 0), big)
-        w = _dot(tb, t["kb"], (1, 0), big).astype(dtype)
-        u = (u0 - _halves([_dot(w[r], hb, (1, 0), big)
-                           for r, hb in zip(_HALVES, hbs)])).astype(dtype)
+        u0, w, u = u0_scr[p], w_scr[p], u_scr[p]
         # o = (masked decayed q k^T) u + (exp(G) q) H
         dm = jnp.where(t["upto"], _dot(do, u, (1, 1), big), 0.0)
         du_o = _dot(t["qk"].astype(dtype), do, (0, 0), big)
@@ -1170,7 +1265,7 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, tb_ref,
         dvb = _dot(tb, du.astype(dtype), (0, 0), big)      # T^T du0
         dkb = _dot(tb, dw.astype(dtype), (0, 0), big)      # T^T dw
         da = jnp.where(t["below"], -(
-            _dot(dvb.astype(dtype), u0.astype(dtype), (1, 1), big)
+            _dot(dvb.astype(dtype), u0, (1, 1), big)
             + _dot(dkb.astype(dtype), w, (1, 1), big)), 0.0)
         dkk = beta * da
         # the decayed products, level by level: what their operands get
@@ -1213,11 +1308,18 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, tb_ref,
 
 
 def _kda_plan(q, reverse):
+    """``_plan`` for the KDA pair, with the row of betas and ``entering``,
+    ONE state a grid step."""
     sp = _plan(q, q, reverse)
-    steps, tokens = sp["grid"][2], sp["pairs"] * _PAIR
+    steps, tokens, keys = sp["grid"][2], sp["pairs"] * _PAIR, q.shape[2]
+
+    def step(c_):
+        return steps - 1 - c_ if reverse else c_
+
     sp["beta"] = pl.BlockSpec(
-        (1, 1, 1, tokens), lambda b_, h_, c_: (
-            b_, h_, 0, steps - 1 - c_ if reverse else c_))
+        (1, 1, 1, tokens), lambda b_, h_, c_: (b_, h_, 0, step(c_)))
+    sp["entering"] = pl.BlockSpec(
+        (1, 1, 1, keys, keys), lambda b_, h_, c_: (b_, h_, step(c_), 0, 0))
     return sp
 
 
@@ -1226,8 +1328,12 @@ def _kda_fwd_call(q, k, v, g, beta, h0, *, interpret):
     """``q``, ``k``, ``v`` ``(b, heads, 128, s)``, ``g`` likewise float32
     (a token's RAW log-decay a key channel), ``beta (b, heads, 1, s)``
     float32, ``h0 (b, heads, 128, 128)`` float32; ``s`` a multiple of 128.
-    Returns ``_fwd_call``'s five and the largest ``-G`` inside a chunk
-    ``(b, heads, 8, 128)``."""
+    Returns ``o`` like ``v``, the state entering every GRID STEP ``(b,
+    heads, steps, 128, 128)`` float32 (``_plan``: a step is up to
+    ``_STEP_PAIRS`` pairs of chunks), every pair's inverse ``(b, heads, s,
+    128)`` in ``q.dtype``, the last state like ``h0``, its largest entry
+    at any chunk's end and the largest ``-G`` inside a chunk, both ``(b,
+    heads, 8, 128)``."""
     sp = _kda_plan(q, reverse=False)
     batch, heads, keys, s = q.shape
     stat = jax.ShapeDtypeStruct((batch, heads, 8, _LANES), _F32)
@@ -1236,11 +1342,11 @@ def _kda_fwd_call(q, k, v, g, beta, h0, *, interpret):
         grid=sp["grid"],
         in_specs=[sp["qk"], sp["qk"], sp["qk"], sp["qk"], sp["beta"],
                   sp["state"]],
-        out_specs=[sp["qk"], sp["states"], sp["tb"], sp["state"],
+        out_specs=[sp["qk"], sp["entering"], sp["tb"], sp["state"],
                    sp["peak"], sp["peak"]],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(
-                       (batch, heads, s // _CHUNK, keys, keys), _F32),
+                       (batch, heads, sp["grid"][2], keys, keys), _F32),
                    jax.ShapeDtypeStruct((batch, heads, s, _PAIR), q.dtype),
                    jax.ShapeDtypeStruct(h0.shape, _F32), stat, stat],
         scratch_shapes=[sp["carry"]],
@@ -1251,25 +1357,42 @@ def _kda_fwd_call(q, k, v, g, beta, h0, *, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _kda_bwd_call(q, k, v, g, beta, states, tb, do, dh_last, *, interpret):
+def _kda_bwd_call(q, k, v, g, beta, entering, tb, do, dh_last, *, interpret):
     """Gradients to ``q``, ``k``, ``v``, ``g``, ``beta`` and ``h0``, each
-    like its argument."""
+    like its argument, from the forward's ``entering`` and ``tb``.  VMEM
+    beside the state's gradient: a step's chunk states (512 KB at four
+    pairs) and its pairs' ``u0``, ``w``, ``u`` (96 KB a pair)."""
     sp = _kda_plan(q, reverse=True)
+    pairs, keys = sp["pairs"], q.shape[2]
     like = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype)
+    of_pair = pltpu.VMEM((pairs, _PAIR, keys), q.dtype)
     return pl.pallas_call(
-        functools.partial(_kda_bwd_kernel, pairs=sp["pairs"]),
+        functools.partial(_kda_bwd_kernel, pairs=pairs),
         grid=sp["grid"],
         in_specs=[sp["qk"], sp["qk"], sp["qk"], sp["qk"], sp["beta"],
-                  sp["states"], sp["tb"], sp["qk"], sp["state"]],
+                  sp["entering"], sp["tb"], sp["qk"], sp["state"]],
         out_specs=[sp["qk"], sp["qk"], sp["qk"], sp["qk"], sp["beta"],
                    sp["state"]],
         out_shape=[like(q), like(k), like(v), like(g), like(beta),
                    like(dh_last)],
-        scratch_shapes=[sp["carry"]],
+        scratch_shapes=[sp["carry"],
+                        pltpu.VMEM((2 * pairs, keys, keys), _F32),
+                        of_pair, of_pair, of_pair],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
         name="kdarule_bwd",
-    )(q, k, v, g, beta, states, tb, do, dh_last)
+    )(q, k, v, g, beta, entering, tb, do, dh_last)
+
+
+# What a layer checkpoint keeps of the rule (``models/blocks/kda.py`` hands
+# these names to ``models/llama.py``'s policy), named where they are made as
+# ``ops/attention.py`` names ``flash_out`` and ``flash_lse``: with the
+# kernel's output (``kda_out`` reads it), each pair's inverse and the state
+# entering each grid step held, the rematerialised pass needs no second
+# ``kdarule_fwd``.  At 8192 tokens of 32 heads (4096 of 64): 67 + 67 + 33.5
+# MB a layer, where the float32 state entering every CHUNK was 268 MB.
+KDA_SAVED_RESIDUALS = ("kda_rule_out", "kda_rule_inverse",
+                       "kda_rule_entering")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
@@ -1280,9 +1403,11 @@ def _kda_rule(q, k, v, g, beta, h0, interpret):
 
 
 def _kda_rule_fwd(q, k, v, g, beta, h0, interpret):
-    o, states, tb, h_last, peak, low = _kda_fwd_call(
+    o, entering, tb, h_last, peak, low = _kda_fwd_call(
         q, k, v, g, beta, h0, interpret=interpret)
-    return (o, h_last, peak, low), (q, k, v, g, beta, states, tb)
+    o, tb, entering = (checkpoint_name(t, name) for t, name in zip(
+        (o, tb, entering), KDA_SAVED_RESIDUALS))
+    return (o, h_last, peak, low), (q, k, v, g, beta, entering, tb)
 
 
 def _kda_rule_bwd(interpret, res, cts):

@@ -37,7 +37,9 @@ FIELDS = {
     "mamba": dict(ssm_heads=8, ssm_head_dim=16, ssm_state=8, ssm_groups=2,
                   ssm_chunk=8),
     "linear_attention": dict(gdn_heads=4, gdn_key_dim=8, gdn_value_dim=16),
-    "kda": dict(linear_attn_config={"num_heads": 4, "head_dim": 16,
+    # the published head of 128 / 128, so that the Pallas pair runs
+    # (interpreted) and ITS residuals are made
+    "kda": dict(linear_attn_config={"num_heads": 1, "head_dim": 128,
                                     "short_conv_kernel_size": 4}),
     "conv": {},
     "none": {},   # the empty block, a mixer and an FFN

@@ -190,8 +190,12 @@ PUBLISHED = dict(batch=1, heads=2, dk=128, dv=128)     # Kimi-Linear's heads
 
 @pytest.mark.parametrize("seq,strength,dtype,tol", [
     (200, 0.3, jnp.float32, 2e-5), (128, 4.0, jnp.float32, 2e-5),
-    (64, 0.3, jnp.float32, 2e-5), (256, 4.0, jnp.bfloat16, 2e-2)],
-    ids=["ragged-mild", "one-pair-past-88", "one-chunk", "bfloat16-past-88"])
+    (64, 0.3, jnp.float32, 2e-5), (256, 4.0, jnp.bfloat16, 2e-2),
+    (512, 0.3, jnp.float32, 2e-5), (1000, 4.0, jnp.float32, 2e-5),
+    (1060, 0.3, jnp.float32, 2e-5), (1024, 4.0, jnp.bfloat16, 2e-2)],
+    ids=["ragged-mild", "one-pair-past-88", "one-chunk", "bfloat16-past-88",
+         "one-whole-grid-step", "two-grid-steps-ragged-past-88",
+         "nine-grid-steps-of-a-pair", "bfloat16-two-grid-steps"])
 def test_the_kernels_equal_the_xla_form_and_the_recurrence(seq, strength,
                                                            dtype, tol):
     """The Pallas pair (interpreted here) at the published 128 / 128: on a
@@ -201,7 +205,13 @@ def test_the_kernels_equal_the_xla_form_and_the_recurrence(seq, strength,
     statistics and the gradient of every input against the XLA form — and,
     in float32, against the recurrence a token at a time.  In bfloat16 the
     two forms round the same operands and differ by the order of their
-    sums; nothing but the shapes chooses the form."""
+    sums; nothing but the shapes chooses the form.  Since PR 65 the forward
+    kernel hands the backward ONE state a grid step and the backward kernel
+    sweeps the step's chunks forward again (``_kda_sweep``): 128 tokens are
+    a step of one pair, 512 exactly one step of four, 1000 (padded to 1024)
+    two steps of four with a ragged tail, 1060 (to 1152, nine pairs) nine
+    steps of one pair — a state enters the first step in every case, so
+    each boundary the sweep starts from is on the gradients' path."""
     args = _rule_inputs(seq, strength, seed=5, dtype=dtype, **PUBLISHED)
     weight = jnp.asarray(np.random.default_rng(1).normal(
         size=args[2].shape), jnp.float32)
@@ -256,8 +266,9 @@ def _overshooting_inputs(seq, dk, dv, chunk, heads=2, spread=0.05, seed=7):
 
 @pytest.mark.parametrize("form,seq,dk,dv,chunk", [
     ("xla", 100, 16, 24, 16), ("xla", 150, 16, 24, 64),
-    ("kernels", 200, 128, 128, 64)],
-    ids=["xla-chunks-of-16", "xla-chunks-of-64", "kernels-128"])
+    ("kernels", 200, 128, 128, 64), ("kernels", 1000, 128, 128, 64)],
+    ids=["xla-chunks-of-16", "xla-chunks-of-64", "kernels-128",
+         "kernels-128-two-grid-steps"])
 def test_a_write_strength_over_one_on_keys_that_nearly_coincide(
         form, seq, dk, dv, chunk):
     """``beta`` in (1, 2) — a model with ``kda_neg_eigval`` — on keys that
@@ -286,6 +297,114 @@ def test_a_write_strength_over_one_on_keys_that_nearly_coincide(
         assert np.all(np.isfinite(g)), name
         np.testing.assert_allclose(g, w, atol=2e-4 * float(jnp.max(jnp.abs(
             w))), rtol=2e-4, err_msg=name)
+
+
+# -- what the backward kernel rebuilds: the state entering every chunk ----------
+
+def _per_chunk_states(q, k, v, g, beta, h0, pairs):
+    """The forward kernel's state loop AS IT STOOD UNTIL PR 65, kept here
+    for the comparison alone: a grid step writes the float32 state
+    ENTERING each of its chunks, ``(b, heads, s / 64, 128, 128)``.  The
+    arguments are ``_kda_fwd_call``'s."""
+    from jax.experimental import pallas as pl
+
+    batch, heads, keys, s = q.shape
+    sp = delta._kda_plan(q, reverse=False)
+    assert sp["pairs"] == pairs
+
+    def body(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref, states_ref, h_scr):
+        @pl.when(pl.program_id(2) == 0)
+        def _first_step():
+            h_scr[...] = h0_ref[0, 0]
+
+        def pair(p, carry):
+            at = pl.ds(pl.multiple_of(p * 128, 128), 128)
+            t = delta._kda_pair_terms(
+                q_ref[0, 0, :, at].T, k_ref[0, 0, :, at].T,
+                v_ref[0, 0, :, at].T, g_ref[0, 0, :, at].T,
+                beta_ref[0, 0, :, at])
+            big, dtype = t["big"], q_ref.dtype
+            tb = delta._pair_inverse(t["a"], t["row"], t["col"]).astype(dtype)
+            u0 = delta._dot(tb, t["vb"], (1, 0), big)
+            w = delta._dot(tb, t["kb"], (1, 0), big).astype(dtype)
+            h = h_scr[...]
+            for i, rows in enumerate(delta._HALVES):
+                states_ref[0, 0, 2 * p + i] = h
+                hb = h.astype(dtype)
+                u = (u0[rows] - delta._dot(w[rows], hb, (1, 0), big)
+                     ).astype(dtype)
+                h = delta._column(t["eye"], jnp.exp(t["lasts"][i])) * h \
+                    + delta._dot(t["k_end"][rows], u, (0, 0), big)
+            h_scr[...] = h
+            return carry
+
+        jax.lax.fori_loop(0, pairs, pair, 0)
+
+    return pl.pallas_call(
+        body, grid=sp["grid"],
+        in_specs=[sp["qk"]] * 4 + [sp["beta"], sp["state"]],
+        out_specs=sp["states"],
+        out_shape=jax.ShapeDtypeStruct((batch, heads, s // 64, keys, keys),
+                                       jnp.float32),
+        scratch_shapes=[sp["carry"]], interpret=True)(q, k, v, g, beta, h0)
+
+
+def _swept_states(k, v, g, beta, entering, tb, pairs):
+    """``_kda_sweep`` — the product's, as ``kdarule_bwd`` runs it at the
+    head of a grid step — in a call of its own that copies out what it
+    left in VMEM: the state entering every chunk, like
+    ``_per_chunk_states``' output."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, heads, keys, s = k.shape
+    sp = delta._kda_plan(k, reverse=True)      # from the end, as the walk
+
+    def body(k_ref, v_ref, g_ref, beta_ref, entering_ref, tb_ref, out_ref,
+             hs_scr, u0_scr, w_scr, u_scr):
+        delta._kda_sweep(k_ref, v_ref, g_ref, beta_ref, entering_ref, tb_ref,
+                         hs_scr, u0_scr, w_scr, u_scr, pairs)
+        out_ref[0, 0] = hs_scr[...]
+
+    of_pair = pltpu.VMEM((pairs, 128, keys), k.dtype)
+    return pl.pallas_call(
+        body, grid=sp["grid"],
+        in_specs=[sp["qk"]] * 3 + [sp["beta"], sp["entering"], sp["tb"]],
+        out_specs=sp["states"],
+        out_shape=jax.ShapeDtypeStruct((batch, heads, s // 64, keys, keys),
+                                       jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2 * pairs, keys, keys), jnp.float32),
+                        of_pair, of_pair, of_pair],
+        interpret=True)(k, v, g, beta, entering, tb)
+
+
+@pytest.mark.parametrize("seq,pairs,strength,dtype", [
+    (128, 1, 4.0, jnp.float32), (512, 4, 0.3, jnp.float32),
+    (1024, 4, 4.0, jnp.bfloat16), (1152, 1, 0.3, jnp.bfloat16)],
+    ids=["one-pair", "one-step-of-four", "two-steps-of-four-bfloat16",
+         "nine-steps-of-one-bfloat16"])
+def test_the_backward_sweep_rebuilds_the_forwards_chunk_states_to_the_bit(
+        seq, pairs, strength, dtype):
+    """``kdarule_fwd`` keeps the state entering each GRID STEP, an eighth
+    of the per-chunk states at four pairs a step; ``kdarule_bwd`` makes the
+    others again (``_kda_sweep``: the same operands, casts and order as the
+    forward's ``_kda_chunk``).  What it makes IS what the forward kernel
+    held until PR 65, bit for bit — in float32 and, as the cells run it,
+    in bfloat16 —, so the gradient changed by nothing."""
+    q, k, v, g, beta, state = _rule_inputs(seq, strength, seed=8, dtype=dtype,
+                                           **PUBLISHED)
+    by_head = lambda t: jnp.transpose(t, (0, 2, 3, 1))
+    args = (by_head(q), by_head(k), by_head(v), by_head(g),
+            jnp.transpose(beta, (0, 2, 1))[:, :, None, :],
+            jnp.swapaxes(state, -1, -2))
+    want = np.asarray(_per_chunk_states(*args, pairs))
+    _, entering, tb, *_ = delta._kda_fwd_call(*args, interpret=True)
+    assert entering.shape == (1, 2, seq // 128 // pairs, 128, 128)
+    assert entering.dtype == jnp.float32 and tb.dtype == dtype
+    np.testing.assert_array_equal(entering, want[:, :, ::2 * pairs])
+    np.testing.assert_array_equal(
+        _swept_states(*args[1:5], entering, tb, pairs), want)
+    assert np.any(want[:, :, 1:] != want[:, :, :-1])     # the states move
 
 
 # -- the kernels' sums of log-decays: one doubling scan along the tokens -------

@@ -219,14 +219,17 @@ def test_train_step_reports_the_rule_and_names_its_scopes():
     and the four ``kda_*`` scopes — members of ``STEP_SCOPES`` — are on the
     compiled program's ops in the forward, the rematerialised and the
     backward pass, except ``kda_in``'s rematerialised matmul: the
-    checkpoint keeps the projection by name and nothing of the rule."""
+    checkpoint keeps the projection by name (and, where the Pallas pair
+    runs — not at this row's heads of 16 —, the kernel's output, the pairs'
+    inverses and a state a grid step: ``tests/test_blocks.py``)."""
     from ray_tpu.util.tracing import KERNEL_NAMES
 
     assert set(KDA_SCOPES) <= set(STEP_SCOPES)
     # the kernels' prefix is a row of ``step-breakdown`` and no scope's name
     assert "kdarule_" in KERNEL_NAMES and not any(
         s.startswith("kdarule_") for s in STEP_SCOPES)
-    assert kda.BLOCK.saved == ("kda_proj",)
+    assert kda.BLOCK.saved == ("kda_proj", "kda_rule_out",
+                               "kda_rule_inverse", "kda_rule_entering")
     cfg = tiny(attn_impl="flash", remat=True)
     opt = optax.adam(1e-2)
     state = init_train_state(jax.random.PRNGKey(0), cfg, opt)

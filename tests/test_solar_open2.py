@@ -43,7 +43,11 @@ tiny = functools.partial(tiny_models.tiny, "solar")
 
 # -- (a) the whole model against the reference ---------------------------------
 
-@pytest.mark.parametrize("impl", ["reference", "flash"])
+# the KDA sizes at ONE head of the published 128 / 128: the Pallas pair runs
+PUBLISHED_HEAD = tiny_models.Frozen(SOLAR_LINEAR, num_heads=1, head_dim=128)
+
+
+@pytest.mark.parametrize("impl", ["reference", "flash", "kda-kernels"])
 def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
     """G K K K through ``loss_fn`` — with the XLA attention, and with the
     flash kernels (4 query heads on 2 KV heads, interpreted) under the
@@ -53,13 +57,24 @@ def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
     gradient leaf within 5e-4 of its scale (float32 against float32 in
     another order of sums: the chunk's inverse, whose entries the factor 2
     doubles, and the levels' products).  The write strength passed 1 and
-    stayed under 2."""
+    stayed under 2.  ``kda-kernels``: the same with the KDA layers at one
+    head of 128 / 128, so that ``kdarule_fwd`` / ``kdarule_bwd`` run
+    (interpreted) UNDER THE LAYER CHECKPOINT, which since PR 65 keeps the
+    kernel's output, the pairs' inverses and a state a grid step by name:
+    the backward kernel gets them from the checkpoint's stack and the
+    gradients are still the reference's."""
     kw = {} if impl == "reference" else dict(attn_impl="flash", remat=True)
+    conf = None
+    if impl == "kda-kernels":
+        kw["linear_attn_config"] = PUBLISHED_HEAD
+        conf = {"linear_attn_config": PUBLISHED_HEAD}
+        cfg = program("solar", **kw).cfg
+        assert kda_kernels_fit(cfg.kda_head_dim, cfg.kda_head_dim, 64)
     assert program("solar", **kw).cfg.kind_runs == (
         (("attention", "moe"), 1), (("kda", "moe"), 3))
     _, parts, _, ours = against_the_reference(
         "solar", parts=("loss", "moe_held_share"), rtol=2e-6, nll_atol=3e-5,
-        grad_rtol=5e-4, **kw)
+        grad_rtol=5e-4, conf=conf, **kw)
     assert float(parts["moe_dropped"]) == 0.0
     assert 1.0 < float(parts[KDA_BETA_MAX]) < 2.0
     assert 0.05 < float(parts[KDA_STATE_ABSMAX]) < 100.0

@@ -770,7 +770,12 @@ def test_the_kda_kernels_compile_at_the_published_heads(one_chip, as_on_chip):
     makes the levels' operands twice); the inverse's ten float32 products
     stand as the parent's bodies hold them, once in the forward's (a product
     prints its precision twice) and not in the backward's, which reads the
-    inverse."""
+    inverse.  SINCE PR 65 the backward's body starts with a sweep of the
+    grid step's chunk states (``_kda_sweep``): it holds the doubling scan
+    twice (28 rolls for the forward's 14) and TWO products more than it
+    held (43 for 41: the two halves of ``k_end^T u``; ``T vb``, ``T kb``
+    and ``w H`` moved from the walk into the sweep), none of them at full
+    precision or of a split; the forward's 25 products stand."""
     from ray_tpu.ops import delta
 
     qkv = _shape((1, 8192, 32, 128), jnp.bfloat16, one_chip)
@@ -796,22 +801,26 @@ def test_the_kda_kernels_compile_at_the_published_heads(one_chip, as_on_chip):
     bwd = str(jax.make_jaxpr(functools.partial(
         delta._kda_bwd_call, interpret=False))(
             head, head, head, g, beta,
-            jax.ShapeDtypeStruct((1, 32, 128, 128, 128), jnp.float32),
+            jax.ShapeDtypeStruct((1, 32, 16, 128, 128), jnp.float32),
             jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16), head, h0))
     sum01 = "f32[128,384] = dot_general"
     assert (fwd.count(sum01), bwd.count(sum01)) == (0, 1)
     assert (fwd.count("Precision.HIGHEST"),
             bwd.count("Precision.HIGHEST")) == (2 * 10, 0)
+    assert (fwd.count("= dot_general"), bwd.count("= dot_general")) == (
+        25, 43)
+    assert (fwd.count("roll"), bwd.count("roll")) == (14, 28)
 
 
 def test_kimi_linear_kda_layer_train_step_compiles(one_chip, as_on_chip):
     """The first layer of Kimi-Linear (a KDA mixer and the dense FFN of
     9216) at 8192 positions as a train step, WITH the ``kdarule_*``
     kernels, under the layer checkpoint: the step holds the forward kernel
-    twice (the rematerialised run hands the backward its entering states
-    and inverses) and the backward once, the ``kda_*`` scopes are on its
-    ops, and what the chip's compiler makes of it fits beside a layer's
-    state."""
+    ONCE and the backward once (since PR 65 the checkpoint keeps the
+    kernel's output, the pairs' inverses and a state a grid step by name,
+    so the rematerialised pass runs no ``kdarule_fwd``), the ``kda_*``
+    scopes are on its ops, and what the chip's compiler makes of it fits
+    beside a layer's state."""
     cfg = _kimi_linear_cfg(1)
     assert cfg.kind_runs == ((("kda", "dense"), 1),)
     opt = default_optimizer()
@@ -820,7 +829,7 @@ def test_kimi_linear_kda_layer_train_step_compiles(one_chip, as_on_chip):
         {"tokens": _shape((1, 8193), jnp.int32, one_chip)})
     text = lowered.as_text()
     assert [text.count(f'kernel_name = "{n}"') for n in _KDA_KERNELS] == [
-        2, 1]
+        1, 1]
     compiled = lowered.compile()
     hlo = compiled.as_text()
     assert all(name in hlo for name in _KDA_KERNELS) and "kda_scan" in hlo

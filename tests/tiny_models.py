@@ -310,6 +310,14 @@ ROWS: Dict[str, Row] = {
 }
 
 
+class Frozen(dict):
+    """A dict that ``program`` / ``reference`` can take among their
+    (hashed) overrides: a public file's nested group, never changed."""
+
+    def __hash__(self):
+        return hash(tuple(sorted(self.items())))
+
+
 def tiny(name: str, **kw) -> LlamaConfig:
     """The row's configuration with ``kw`` in place of its fields."""
     return LlamaConfig.tiny(**{**ROWS[name].fields, **kw})
@@ -415,13 +423,16 @@ def reference(name: str, conf=(), **kw) -> Wanted:
 
 
 def against_the_reference(name: str, *, parts=("loss",), rtol=2e-5,
-                          nll_atol=3e-5, grad_rtol=1e-4, **kw):
+                          nll_atol=3e-5, grad_rtol=1e-4, conf=None, **kw):
     """The test every model repeats: the program's total, the named parts,
     each token's loss and every gradient leaf beside the reference's, on
     the row's parameters.  ``kw`` as ``program`` takes it (the flash
-    kernels, the checkpoint).  Returns ``(total, parts, want, gradients)``
-    for what a model asserts beyond."""
-    ours, (want, want_grads) = program(name, **kw), reference(name)
+    kernels, the checkpoint); ``conf``: the reference's keys that follow a
+    ``kw`` which changes the parameters' SHAPES (the reference then runs on
+    that program's draw).  Returns ``(total, parts, want, gradients)`` for
+    what a model asserts beyond."""
+    ours, (want, want_grads) = program(name, **kw), (
+        reference(name, conf, **kw) if conf else reference(name))
     params = ours.params
     (total, got), grads = ours.value_and_grad(params)
     np.testing.assert_allclose(total, want["total"], rtol=rtol)
