@@ -2,7 +2,14 @@
 (``ops/moe.py``, under its scopes ``moe_route`` / ``moe_dispatch`` /
 ``moe_experts`` / ``moe_combine`` in place of ``ffn``, and ``moe_exchange``
 where its experts are spread over an ``ep`` axis) with or without a
-shared expert, which every token meets (scope ``ffn``).  Every FFN of a
+shared expert, which every token meets (scope ``ffn``), and with or
+without a LATENT the routed experts work in (``cfg.moe_latent``: ``w_latent_in
+(d, l)`` projects the normed stream down before the dispatch — and before
+the exchange, which then carries ``l``-wide rows —, the held experts'
+matrices have ``l`` rows in place of ``d``, and ``w_latent_out (l, d)`` brings
+each token's summed parts back up after the combine; both under the scope
+``moe_latent``; the router and the shared expert read the normed stream,
+never the latent).  Every FFN of a
 model — dense, routed, shared — has the model's one activation
 (``cfg.ffn_act``): SwiGLU over a gate and an up matrix, or the ungated
 ``relu(h W_up) ** 2 W_down`` of two matrices.  Expert tensors are
@@ -24,7 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.blocks.base import (
-    Block, Ctx, Param, fold, residual_out)
+    Block, Ctx, Param, fold, normal, residual_out)
 from ray_tpu.models.blocks.residual import (
     add, block_in, norm_shapes, out_norm)
 from ray_tpu.ops import moe
@@ -38,14 +45,16 @@ def _gated(cfg) -> bool:
     return cfg.ffn_act == "swiglu"
 
 
-def _ffn_shapes(cfg, m: int, prefix: str = "w_", *held):
+def _ffn_shapes(cfg, m: int, prefix: str = "w_", *held, latent: int = 0):
     """An FFN of width ``m`` (``held`` experts of it): gate, up and down,
-    or up and down alone where the activation has no gate."""
-    d = cfg.embed_dim
+    or up and down alone where the activation has no gate.  In a ``latent``
+    it reads and writes that width, and its down matrix — which then does
+    not write to the residual — is drawn as any other."""
+    d = latent or cfg.embed_dim
     expert = ("expert",) * len(held)
     up = Param((*held, d, m), ("layer", *expert, "kernel_in", "mlp"))
     down = Param((*held, m, d), ("layer", *expert, "mlp", "kernel_in"),
-                 residual_out(cfg))
+                 normal if latent else residual_out(cfg))
     gate = {prefix + "gate": up} if _gated(cfg) else {}
     return {**gate, prefix + "up": up, prefix + "down": down}
 
@@ -64,13 +73,22 @@ def _moe_shapes(cfg):
     """The router over ALL the experts, the tensors of those held here
     (``mlp_dim`` is an expert's width), the selection bias (float32
     whatever the parameters': it moves by thousandths) and the shared
-    expert where the model has them."""
-    d, e = cfg.embed_dim, cfg.num_experts
+    expert where the model has them; round the held experts the latent
+    pair where they work in one (``w_latent_out`` is the one that writes to
+    the residual: the initialiser's scale is its, not the experts')."""
+    d, e, latent = cfg.embed_dim, cfg.num_experts, cfg.moe_latent
     shapes = {
         **norm_shapes(cfg, "mlp"),
         "router": Param((d, e), ("layer", "kernel_in", None)),
-        **_ffn_shapes(cfg, cfg.mlp_dim, "w_", cfg.local_experts),
+        **_ffn_shapes(cfg, cfg.mlp_dim, "w_", cfg.local_experts,
+                      latent=latent),
     }
+    if latent:
+        shapes["w_latent_in"] = Param((d, latent),
+                                      ("layer", "kernel_in", None))
+        shapes["w_latent_out"] = Param((latent, d),
+                                       ("layer", None, "kernel_in"),
+                                       residual_out(cfg))
     if cfg.select_bias:
         shapes["router_bias"] = Param(
             (e,), ("layer", None), _select_bias(cfg.select_bias_init),
@@ -122,10 +140,12 @@ def _moe(ctx: Ctx, x, lp, residual: bool = True):
     names = ("w_gate", "w_up", "w_down")[not _gated(cfg):]
 
     no_gate = (None,) * (not _gated(cfg))
+    latent = ("w_latent_in", "w_latent_out") if cfg.moe_latent else ()
 
     def moe_block(x, norm_w, router_w, *rest, **axes):  # the region's name
+        n = len(rest) - len(latent)
         return moe.moe_block(
-            x, norm_w, router_w, *no_gate, *rest,
+            x, norm_w, router_w, *no_gate, *rest[:n], latent=rest[n:] or None,
             num_selected=cfg.num_selected, norm_eps=cfg.norm_eps,
             norm_topk_prob=cfg.norm_topk_prob,
             topk_norm_eps=cfg.topk_norm_eps, scoring=cfg.router_scoring,
@@ -134,7 +154,8 @@ def _moe(ctx: Ctx, x, lp, residual: bool = True):
 
     bias = (lp["router_bias"],) if cfg.select_bias else ()
     args = (x, lp["mlp_norm"], lp["router"],
-            *(lp[name] for name in names)) + bias
+            *(lp[name] for name in names)) + bias + tuple(
+                lp[name] for name in latent)
     if mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
         return moe_block(*args)
     # The parameters as the region takes them, laid out under the scope
@@ -154,6 +175,9 @@ def _moe(ctx: Ctx, x, lp, residual: bool = True):
         args = (x,) + small + tuple(
             laid_out(name, *(down_axes if name == "w_down" else up_axes))
             for name in names) + bias
+    if latent:
+        with jax.named_scope("moe_latent"):
+            args += tuple(laid_out(name, None, None) for name in latent)
     x_spec = P(BATCH_AXES, AXIS_SP, None)
     up_spec = P(AXIS_EP, None, AXIS_TP)
     fn = manual_shard_map(
@@ -161,7 +185,7 @@ def _moe(ctx: Ctx, x, lp, residual: bool = True):
                           expert_axis=AXIS_EP, sum_axes=(AXIS_TP,)),
         set(mesh.axis_names),
         in_specs=(x_spec, P(), P()) + (up_spec,) * (len(names) - 1)
-        + (P(AXIS_EP, AXIS_TP, None),) + (P(),) * len(bias),
+        + (P(AXIS_EP, AXIS_TP, None),) + (P(),) * len(bias + latent),
         out_specs=(x_spec, P()), mesh=mesh)
     return fn(*args)
 
@@ -193,5 +217,5 @@ def _moe_ffn(ctx: Ctx, x, aux, lp, residual: bool = True):
 DENSE = Block(_dense_shapes, _dense_ffn, scopes=("ffn",))
 MOE = Block(_moe_shapes, _moe_ffn, saved=moe.SAVED_RESIDUALS,
             scopes=("moe_route", "moe_exchange", "moe_dispatch",
-                    "moe_experts", "moe_combine", "ffn"),
+                    "moe_experts", "moe_combine", "moe_latent", "ffn"),
             stats=_moe_stats)

@@ -15,7 +15,10 @@ block by learned doubly stochastic maps).  A model is a pattern of such
 layers (``layer_types``, ``leading_dense``; ``layer_pattern``, a character
 a layer; ``linear_attn_config``, the layers of each mixer by number; or
 ``gqa_layers``, the softmax layers by number among ``kda`` ones),
-with or without a predicted-ahead module behind them.
+with or without a predicted-ahead module behind them — one more layer of
+the model's last kind, or, behind a ``layer_pattern`` model, a pattern of
+its own (``mtp_pattern``: a character a layer, each ONE sub-block on the
+residual as the model's are, so the module is as many scans as it has runs).
 Each block declares its own tensors, initialisers, saved residuals, scopes
 and step statistics (``blocks.base.Block``); the decoder reads those and
 names no mixer.
@@ -158,6 +161,10 @@ class LlamaConfig:
     experts_held: int = 0             # of num_experts, here (0: all)
     first_expert: int = 0             # the first one held
     shared_experts: int = 0           # experts every token meets
+    # The width the ROUTED experts work in (the public files'
+    # ``moe_latent_size``), between a projection down before the dispatch
+    # and one up after the combine; 0: the model's own, no projection.
+    moe_latent: int = 0
     router_scoring: str = "softmax"   # softmax | sigmoid
     topk_method: str = "greedy"       # noaux_tc: a selection bias
     router_groups: int = 1            # group-limited routing: 1 = none
@@ -171,6 +178,11 @@ class LlamaConfig:
     hc_clamp_max: float = 30.0
     num_nextn: int = 0                # predicted-ahead modules (0 | 1)
     mtp_loss_coef: float = 0.3
+    # The module's own layers where the public file spells them
+    # (``mtp_hybrid_override_pattern``; ``LAYER_PATTERN``, every character
+    # is run): what a ``layer_pattern`` model's module is made of.  Empty:
+    # one layer of the model's last mixer and its expert (or dense) FFN.
+    mtp_pattern: str = ""
     # The gated delta-rule mixer: gdn_heads heads whose q and k are
     # gdn_key_dim wide and whose v and output gdn_value_dim.
     gdn_heads: int = 0
@@ -270,19 +282,26 @@ class LlamaConfig:
                              "qk_head_norm over each head: one of the two")
         if self.ffn_act not in ("swiglu", "relu2"):
             raise ValueError(f"ffn_act {self.ffn_act!r}")
-        bad = sorted(set(self.layer_pattern) - set(LAYER_PATTERN))
-        if bad:
-            raise ValueError(
-                f"layer_pattern holds {bad}: a layer is one of "
-                f"{sorted(LAYER_PATTERN)}")
+        for field in ("layer_pattern", "mtp_pattern"):
+            bad = sorted(set(getattr(self, field)) - set(LAYER_PATTERN))
+            if bad:
+                raise ValueError(
+                    f"{field} holds {bad}: a layer is one of "
+                    f"{sorted(LAYER_PATTERN)}")
         if self.layer_pattern and (
                 len(self.layer_pattern) < self.num_layers or self.layer_types
-                or self.hc_mult > 1 or self.num_nextn or self.leading_dense):
+                or self.hc_mult > 1 or self.leading_dense
+                or bool(self.num_nextn) != bool(self.mtp_pattern)):
             raise ValueError(
                 "layer_pattern names every layer's one sub-block, "
                 f"num_layers ({self.num_layers}) of them or more, in place "
-                "of layer_types and leading_dense, on the plain residual "
-                "without a predicted-ahead module")
+                "of layer_types and leading_dense, on the plain residual; "
+                "a predicted-ahead module behind it (num_nextn) is spelled "
+                "the same way, by mtp_pattern, and not without one")
+        if self.mtp_pattern and not self.layer_pattern:
+            raise ValueError(
+                "mtp_pattern spells the predicted-ahead module of a "
+                f"layer_pattern model: {self.mtp_pattern!r}")
         unknown = set(self.layer_types) - set(MIXERS)
         if unknown:
             raise ValueError(
@@ -473,13 +492,7 @@ class LlamaConfig:
     def kind_runs(self) -> Tuple[Tuple[Tuple[str, str], int], ...]:
         """The model as maximal runs of one kind of layer:
         (((mixer, FFN), layers), ...)."""
-        runs = []
-        for kind in self.layer_kinds:
-            if runs and runs[-1][0] == kind:
-                runs[-1][1] += 1
-            else:
-                runs.append([kind, 1])
-        return tuple((kind, n) for kind, n in runs)
+        return _as_runs(self.layer_kinds)
 
     @property
     def layer_runs(self) -> Tuple[Tuple[str, int], ...]:
@@ -488,8 +501,12 @@ class LlamaConfig:
 
     @property
     def mtp_runs(self):
-        """The predicted-ahead module's block: one expert layer (a dense
-        one in a model without experts)."""
+        """The predicted-ahead module's block: the layers ``mtp_pattern``
+        spells, as maximal runs of one kind; without one, one expert layer
+        (a dense one in a model without experts) of the model's last
+        mixer."""
+        if self.mtp_pattern:
+            return _as_runs(LAYER_PATTERN[c] for c in self.mtp_pattern)
         mixer = self.layer_kinds[-1][0]
         return (((mixer, "moe" if self.num_experts else "dense"), 1),)
 
@@ -506,6 +523,18 @@ class LlamaConfig:
                         attn_impl="reference")
         defaults.update(kw)
         return LlamaConfig(**defaults)
+
+
+def _as_runs(kinds) -> Tuple[Tuple[Tuple[str, str], int], ...]:
+    """Layers' kinds in order as maximal runs: (((mixer, FFN), layers),
+    ...)."""
+    runs = []
+    for kind in kinds:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1])
+    return tuple((kind, n) for kind, n in runs)
 
 
 def _layer_shapes(cfg: LlamaConfig, kind=("attention", "dense")
@@ -868,8 +897,9 @@ def _predicted_ahead(params, h, next_tokens, aux, cfg: LlamaConfig, mesh,
     """The predicted-ahead module (arXiv:2412.19437 §2.2) on the model's
     ``h (b, s, d)`` (the summed streams before the last norm) and the
     tokens that FOLLOW each position: the two are normed, laid side by
-    side and projected back to d (scope ``mtp_in``), go through one more
-    layer of the module's own and its own last norm, and meet the model's
+    side and projected back to d (scope ``mtp_in``), go through the
+    module's own layers (``cfg.mtp_runs``: one more layer, or the ones its
+    pattern spells) and its own last norm, and meet the model's
     head.  Position t then predicts token t + 2.  Returns ``(logits, aux,
     counts)`` as ``_hidden`` and the head do."""
     mp, cst = params["mtp"], _make_cst(mesh, rules)

@@ -11,9 +11,14 @@ imbalance: nothing has a capacity and nothing is dropped.  The layer
   one, which reaches the choice and not the gate — (renormalised only
   where the model says so, then scaled), the load-balancing loss over ALL
   the choices and the router z-loss;
+- ``moe_latent``, where the experts work in a latent (``moe_block``'s
+  ``latent``): the normed tokens projected down to ``l`` columns before
+  anything moves, and each token's summed parts projected back up at the
+  end; every row between the two is ``l`` wide, not ``d``;
 - ``moe_dispatch``: a stable sort of the ``T * k`` (token, choice) pairs
   by expert that carries each pair's gate along, its inverse by a second
-  sort, a gather to ``(T * k, d)`` rows;
+  sort, a gather to ``(T * k, w)`` rows, ``w`` the model's ``d`` or the
+  latent's ``l``;
 - ``moe_experts``: the experts' FFN over the ragged groups (row ``r``
   meets the weights of the group it lies in) as ONE rule, ``expert_ffn``:
   the gate and up products with SwiGLU in their kernel's epilogue, the
@@ -53,7 +58,13 @@ masked, in every output.
 
 The row buffer is static, ``T * k`` rows however few are held, and the
 work on it ends at the LIVE rows, the groups' sum (a value the device
-has; nothing has a capacity).  The rows past it — the padding, and the
+has; nothing has a capacity).  (A token's choices are distinct experts,
+so at most ``T * min(k, E')`` rows can ever be live: where a chip holds
+FEWER experts than a token has choices — 8 held at 22 a token — the
+buffer is wider than any routing can fill.  The exact bound was weighed
+and left: the work follows the live rows either way, and the one program
+that meets the case fits its chip with the buffer as it is, PERF.md §4,
+PR 66.)  The rows past it — the padding, and the
 rows of experts that are not held here: other ranks' under expert
 parallelism, other chips' where this chip holds its share of a layer
 (``first_expert`` and the leading dimension of the expert tensors say
@@ -783,6 +794,7 @@ def update_selection_bias(bias: jax.Array, counts: jax.Array,
 def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
               w_gate: Optional[jax.Array], w_up: jax.Array, w_down: jax.Array,
               select_bias: Optional[jax.Array] = None, *,
+              latent: Optional[Tuple[jax.Array, jax.Array]] = None,
               num_selected: int, norm_eps: float = 1e-6,
               norm_topk_prob: bool = False, topk_norm_eps: float = 0.0,
               tile: Optional[int] = None,
@@ -822,7 +834,15 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
     reaches it (``update_selection_bias`` moves it); the gates, renormalised
     where ``norm_topk_prob`` (over their sum plus ``topk_norm_eps``, which
     a model that guards the division states), are multiplied by
-    ``gate_scale``."""
+    ``gate_scale``.
+    ``latent``: ``(w_in (d, l), w_out (l, d))`` of experts that work in a
+    latent of ``l`` (their matrices ``(E', l, m')`` and ``(E', m', l)``):
+    the normed tokens are projected down BEFORE the exchange and the
+    dispatch, which both move ``l``-wide rows, and each token's summed parts
+    up AFTER the combine, the exchange's scatter-sum and the sum over
+    ``sum_axes`` (``w_out`` is linear: a partial sum's product is that
+    share's part); both products under the scope ``moe_latent``.  The
+    router reads the normed tokens, never the latent."""
     shape, d = x.shape, x.shape[-1]
     x = x.reshape(-1, d)
     t, e, k = x.shape[0], router_w.shape[1], num_selected
@@ -865,6 +885,10 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
         z = _psum(jnp.sum(jnp.square(
             jax.nn.logsumexp(logits, axis=-1))), shards) / tokens
         load = jnp.max(counts).astype(jnp.float32) * e / (tokens * k)
+
+    if latent is not None:
+        with jax.named_scope("moe_latent"):
+            h = h @ latent[0].astype(h.dtype)
 
     if expert_axis is not None:
         with jax.named_scope("moe_exchange"):
@@ -928,6 +952,10 @@ def moe_block(x: jax.Array, norm_w: jax.Array, router_w: jax.Array,
                                      tiled=True)
     with jax.named_scope("moe_combine"):
         y = _psum(y, sum_axes)
+    if latent is not None:
+        with jax.named_scope("moe_latent"):
+            y = y @ latent[1].astype(y.dtype)
+    with jax.named_scope("moe_combine"):
         out = ((x + y) if residual else y).reshape(shape)
     return out, {"aux_loss": aux, "z_loss": z, "load_max_over_mean": load,
                  "dropped": dropped, "held_share": reached / (tokens * k),

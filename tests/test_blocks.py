@@ -44,8 +44,10 @@ FIELDS = {
     "conv": {},
     "none": {},   # the empty block, a mixer and an FFN
     "dense": {},
+    # the routed experts in a latent, so that its scope opens
     "moe": dict(num_experts=4, num_selected=2, shared_experts=1,
-                experts_held=2, first_expert=2, z_loss_coef=0.001),
+                experts_held=2, first_expert=2, z_loss_coef=0.001,
+                moe_latent=16),
 }
 ENTRIES = [("mixer", name) for name in MIXERS] + [
     ("ffn", name) for name in FFNS]
@@ -154,11 +156,11 @@ def test_every_statistic_a_block_declares_is_a_metric(role, name):
 
 # -- (b) the step's scopes, as they were --------------------------------------
 
-def test_step_scopes_are_the_31_names_in_their_order():
+def test_step_scopes_are_the_32_names_in_their_order():
     assert STEP_SCOPES == (
         "embed", "attn_qkv", "attention", "attn_out", "ffn",
         "moe_route", "moe_exchange", "moe_dispatch", "moe_experts",
-        "moe_combine",
+        "moe_combine", "moe_latent",
         "ssm_in", "ssm_conv", "ssm_scan", "ssm_out",
         "gdn_in", "gdn_conv", "gdn_scan", "gdn_out",
         "kda_in", "kda_conv", "kda_scan", "kda_out",

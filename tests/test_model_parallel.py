@@ -382,6 +382,8 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
     want -= {"kda_in", "kda_conv", "kda_scan", "kda_out"}
     # no short-convolution layer (tests/test_lfm2.py has such a model)
     want -= {"sconv_in", "sconv_gate", "sconv_out"}
+    # no latent round the routed experts (tests/test_nemotron3.py has one)
+    want -= {"moe_latent"}
     want -= {"loss"} if mesh is None or moe else set()  # tp splits it
     # the embedding takes the rows its tokens name: a gather, never a
     # matmul.  On one device the scope shows nothing here; under a mesh

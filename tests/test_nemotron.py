@@ -258,7 +258,9 @@ def _scopes_of_a_step(cfg):
 @pytest.mark.parametrize("pattern,opened", [
     ("MM", MIXERS["mamba"].scopes),
     ("**", MIXERS["attention"].scopes),
-    ("EE", set(FFNS["moe"].scopes) - {"moe_exchange"}),
+    # (the exchange over an ``ep`` axis alone; the latent's pair where the
+    # experts work in one: tests/test_nemotron3.py)
+    ("EE", set(FFNS["moe"].scopes) - {"moe_exchange", "moe_latent"}),
     ("--", FFNS["dense"].scopes)], ids=["M", "attention", "E", "dense"])
 def test_a_layer_opens_its_own_sub_blocks_scopes_alone(pattern, opened):
     """A model of ``M`` layers has no time under ``ffn`` or ``attn_*``, one

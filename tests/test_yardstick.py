@@ -11,8 +11,8 @@ membership, and they are collected again, through ``tier1_cases.py``.  The cases
 wrote while they were out
 (``test_trinitys_window_entries_stand_as_they_were_with_this_cell_appended``,
 ``test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries``) stay
-beside them, as do the Kimi-Linear cell's (PR 59) and the Solar-Open-2
-cell's (PR 64), by name."""
+beside them, as do the Kimi-Linear cell's (PR 59), the Solar-Open-2
+cell's (PR 64) and the Nemotron-3-Super cell's (PR 66), by name."""
 
 import pytest
 
@@ -20,7 +20,8 @@ pytest.register_assert_rewrite("benchmark.tests.test_trinity",
                                "benchmark.tests.test_mellum",
                                "benchmark.tests.test_flash_xla_ms",
                                "benchmark.tests.test_kimi_linear",
-                               "benchmark.tests.test_solar_open2")
+                               "benchmark.tests.test_solar_open2",
+                               "benchmark.tests.test_nemotron3")
 
 from benchmark.tests.tier1_cases import *  # noqa: E402,F401,F403
 from benchmark.tests.test_trinity import (  # noqa: E402,F401
@@ -67,3 +68,14 @@ from benchmark.tests.test_solar_open2 import (  # noqa: E402,F401
     test_the_five_readers_on_a_made_up_run,
     test_the_parameter_count_is_init_params as
     test_solar_open2_parameter_count)
+from benchmark.tests.test_nemotron3 import (  # noqa: E402,F401
+    test_each_floor_and_each_width_violated_in_turn as
+    test_nemotron3_each_floor_and_each_width,
+    test_flops_count_the_latent_the_module_and_both_heads,
+    test_on_a_program_without_the_latent_the_readers_return_nothing,
+    test_the_cell_its_job_and_its_metrics as
+    test_nemotron3_cell_job_and_metrics,
+    test_the_file_is_the_catalog_row_cut_to_one_chip_of_sixty_four,
+    test_the_five_readers_on_synthetic_planes,
+    test_the_parameter_count_is_init_params as
+    test_nemotron3_parameter_count)

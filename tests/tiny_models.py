@@ -20,7 +20,7 @@ import numpy as np
 
 from benchmark.reference import (
     afmoe, granite_hybrid, joyai_flash, kimi_linear, lfm2_moe, mellum,
-    nemotron_h, olmo_hybrid, olmoe, solar_open2, xing4)
+    nemotron3, nemotron_h, olmo_hybrid, olmoe, solar_open2, xing4)
 from ray_tpu.models.llama import (
     ROPE_BY_KIND, LlamaConfig, forward, init_params, loss_fn)
 from ray_tpu.ops.moe import moe_block
@@ -118,6 +118,25 @@ SOLAR_GQA = (0, 4, 8)
 SOLAR_LINEAR = {"num_heads": 4, "head_dim": 16, "num_kv_heads": None,
                 "short_conv_kernel_size": 4}
 NEMOTRON_PATTERN = "MEM*EMEME"  # longer than the model: the first 5 are run
+NEMOTRON3_MODULE = "*E"         # the predicted-ahead module's own pattern
+
+# The Nemotron-H layers in small, M, E, M, *, E: 2 groups of 4 heads; 16
+# experts of width 32, the held ones from 4 on; a shared expert of width 48;
+# 8 query heads over 2 KV heads.  A row adds how many are held, the choices
+# a token and the gates' scale.
+_NEMOTRON = dict(
+    vocab_size=128, embed_dim=64, num_layers=5,
+    layer_pattern=NEMOTRON_PATTERN, num_heads=8, num_kv_heads=2, head_dim=16,
+    position_embedding="nope", norm_eps=1e-5, max_seq_len=64,
+    dtype=jnp.float32, remat=False, attn_impl="reference", ssm_heads=8,
+    ssm_head_dim=16, ssm_state=8, ssm_groups=2, ssm_conv=4, ssm_chunk=8,
+    ffn_act="relu2", mlp_dim=32, shared_experts=1, shared_mlp_dim=48,
+    num_experts=16, first_expert=4, topk_norm_eps=1e-20, **_SIGMOID)
+_NEMOTRON_CONF = dict(
+    hybrid_override_pattern=NEMOTRON_PATTERN, num_hidden_layers=5,
+    layer_norm_epsilon=1e-5, num_attention_heads=8, num_key_value_heads=2,
+    mamba_num_heads=8, mamba_head_dim=16, ssm_state_size=8, n_groups=2,
+    first_expert=4)
 
 ROWS: Dict[str, Row] = {
     # ``LlamaConfig.tiny`` bare and with experts: no reference of their own
@@ -221,22 +240,24 @@ ROWS: Dict[str, Row] = {
     # experts of width 32 of which this chip holds 4..11, 3 a token; a
     # shared expert of width 48; 8 query heads over 2 KV heads
     "nemotron": Row(
-        dict(vocab_size=128, embed_dim=64, num_layers=5,
-             layer_pattern=NEMOTRON_PATTERN, num_heads=8, num_kv_heads=2,
-             head_dim=16, position_embedding="nope", norm_eps=1e-5,
-             max_seq_len=64, dtype=jnp.float32, remat=False,
-             attn_impl="reference", ssm_heads=8, ssm_head_dim=16,
-             ssm_state=8, ssm_groups=2, ssm_conv=4, ssm_chunk=8,
-             ffn_act="relu2", mlp_dim=32, shared_experts=1,
-             shared_mlp_dim=48, num_experts=16, experts_held=8,
-             first_expert=4, num_selected=3, topk_norm_eps=1e-20,
-             routed_scaling_factor=2.5, **_SIGMOID),
+        dict(_NEMOTRON, experts_held=8, num_selected=3,
+             routed_scaling_factor=2.5),
         _jax_tokens(4, 33), nemotron_h,
-        dict(hybrid_override_pattern=NEMOTRON_PATTERN, num_hidden_layers=5,
-             layer_norm_epsilon=1e-5, num_attention_heads=8,
-             num_key_value_heads=2, mamba_num_heads=8, mamba_head_dim=16,
-             ssm_state_size=8, n_groups=2, num_experts_per_tok=3,
-             routed_scaling_factor=2.5, first_expert=4),
+        dict(_NEMOTRON_CONF, num_experts_per_tok=3,
+             routed_scaling_factor=2.5),
+        params=functools.partial(seeded, also=("D",)), precision="highest"),
+    # the same layers with what Nemotron-3 adds: the routed experts in a
+    # latent of 16 (a quarter of the hidden size, as published), MORE
+    # choices a token (6 of 16) than experts held (4..7), and behind the
+    # stack a predicted-ahead module that is a pattern of its own, * then E
+    "nemotron3": Row(
+        dict(_NEMOTRON, experts_held=4, num_selected=6,
+             routed_scaling_factor=5.0, moe_latent=16, num_nextn=1,
+             mtp_pattern=NEMOTRON3_MODULE, mtp_loss_coef=0.1),
+        _jax_tokens(4, 33), nemotron3,
+        dict(_NEMOTRON_CONF, num_experts_per_tok=6, routed_scaling_factor=5,
+             mtp_hybrid_override_pattern=NEMOTRON3_MODULE,
+             mtp_loss_coef=0.1),
         params=functools.partial(seeded, also=("D",)), precision="highest"),
     "trinity": Row(
         dict(_SMALL, num_layers=5, num_kv_heads=2, dense_mlp_dim=96,
