@@ -2,7 +2,9 @@
 ``linear_attention``; ``ops/delta.py``, arXiv:2412.06464), as in
 Olmo-Hybrid's layers beside ``full_attention`` ones.  Scopes: ``gdn_in``
 (the block's norm where it norms its input, the one projection, its
-split), ``gdn_conv`` (the convolution over q, k, v with its SiLU, the L2
+split), ``gdn_conv`` (the convolution over q, k, v with its SiLU — the
+kernels ``causal_conv_fwd`` / ``causal_conv_bwd`` where
+``ssm.conv_kernels_fit``, per shard of the batch under a mesh —, the L2
 norm of each head's q and k — q then times ``key_dim ** -0.5`` —, ``beta =
 sigmoid(b)``, twice that where the rule may have negative eigenvalues, and
 the log-decay ``g = -exp(A_log) softplus(a + dt_bias)``), ``gdn_scan`` (the
@@ -16,6 +18,8 @@ bf16, 142 MB a layer at 4096 tokens of the published 17340 columns).  The
 step reports ``gdn_state_absmax``, the largest state any layer saw at a
 chunk's end (under a mesh the largest over the shards).
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +79,11 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
             proj, [cfg.gdn_conv_dim, cfg.gdn_conv_dim + values,
                    cfg.gdn_conv_dim + values + heads], -1)
     with jax.named_scope("gdn_conv"):
-        qkv = causal_conv1d(qkv, lp["gdn_conv_w"])
+        # the rule reads (b, heads, d, s): the mixer stands tokens-last
+        conv = functools.partial(causal_conv1d, tokens_last=True)
+        if mesh is not None and not ctx.sp_manual:
+            conv = batch_shard_map(conv, mesh, (3, None), 3)
+        qkv = conv(qkv, lp["gdn_conv_w"])
         q, k, v = jnp.split(qkv, [keys, 2 * keys], -1)
 
         def unit(t):  # each head's vector at length 1, float32

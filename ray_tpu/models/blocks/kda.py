@@ -5,7 +5,9 @@ lists its layers, or whose ``gqa_layers`` leaves them over
 (``LlamaConfig.layer_kinds``), beside latent- or softmax-attention ones.
 Scopes: ``kda_in`` (the block's norm and the ONE input projection,
 [q | k | v | the decay's down-projection | the gate's | b] side by side),
-``kda_conv`` (the convolution over q, k, v with its SiLU, the L2 norm of
+``kda_conv`` (the convolution over q, k, v with its SiLU — the kernels
+``causal_conv_fwd`` / ``causal_conv_bwd`` where ``ssm.conv_kernels_fit``,
+per shard of the batch under a mesh —, the L2 norm of
 each head's q and k — q then times ``head_dim ** -0.5`` —, ``beta =
 sigmoid(b)``, TWICE that under ``cfg.kda_neg_eigval`` (the public files'
 ``kda_allow_neg_eigval``: a write strength in (0, 2), the transition's
@@ -30,6 +32,8 @@ matrix would have overflowed: the rule's levels are what keeps it exact),
 and a model with ``kda_neg_eigval`` ``kda_beta_max`` too, the largest write
 strength of the step (over 1: the negative-eigenvalue path ran).
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -104,7 +108,11 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
         qkv, f, gate, bt = jnp.split(
             proj, [3 * inner, 3 * inner + rank, 3 * inner + 2 * rank], -1)
     with jax.named_scope("kda_conv"):
-        qkv = causal_conv1d(qkv, lp["kda_conv_w"])
+        # the rule reads (b, heads, d, s): the mixer stands tokens-last
+        conv = functools.partial(causal_conv1d, tokens_last=True)
+        if mesh is not None and not ctx.sp_manual:
+            conv = batch_shard_map(conv, mesh, (3, None), 3)
+        qkv = conv(qkv, lp["kda_conv_w"])
         q, k, v = jnp.split(qkv, 3, -1)
 
         def unit(t):  # each head's vector at length 1, float32

@@ -1,7 +1,9 @@
 """A Mamba-2 state-space mixer (``layer_types``: ``mamba``;
 ``ops/ssm.py``), as in granite-4.0-h's layers beside an attention layer
 every tenth.  Scopes: ``ssm_in`` (norm, the one input projection, its
-split), ``ssm_conv`` (the convolution over x, B, C with its SiLU; dt's
+split), ``ssm_conv`` (the convolution over x, B, C with its SiLU — the
+kernels ``causal_conv_fwd`` / ``causal_conv_bwd`` where
+``ssm.conv_kernels_fit``, per shard of the batch under a mesh —; dt's
 softplus), ``ssm_scan`` (the chunked scan, ``D x`` included: Pallas kernels
 where ``ssm.kernels_fit`` — heads that fill whole lane blocks inside each
 of the ``ssm_groups`` groups, whose B and C go in side by side as the
@@ -73,7 +75,10 @@ def _apply(ctx: Ctx, x, aux, lp, residual: bool = True):
         zxbcdt = checkpoint_name(h @ lp["ssm_in"].astype(cfg.dtype), *SAVED)
         z, xbc, dt = jnp.split(zxbcdt, [inner, inner + cfg.ssm_conv_dim], -1)
     with jax.named_scope("ssm_conv"):
-        xbc = causal_conv1d(xbc, lp["conv_w"], lp["conv_b"])
+        conv = causal_conv1d
+        if per_shard:
+            conv = batch_shard_map(conv, mesh, (3, None, None), 3)
+        xbc = conv(xbc, lp["conv_w"], lp["conv_b"])
         dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
         xs, bm, cm = jnp.split(xbc, [inner, inner + gn], -1)
     with jax.named_scope("ssm_scan"):

@@ -51,11 +51,16 @@ gradient to the cumulative sums (row sums less column sums of ``dM o M``,
 what the decays to and from the chunk's ends collect, and ``<dH, H>`` at
 a chunk's last token).
 
-Beside them the two short causal convolutions of the recurrent mixers:
-``causal_conv1d`` (depthwise, SiLU fused: Mamba-2's and the delta rule's)
-and ``gated_short_conv`` (``C * conv(B * x)``, no activation: LFM2's whole
-mixer between its two projections), plain XLA with their backward passes
-written out over one shared pair of helpers; and Mamba-2's output norm,
+Beside them the two short causal convolutions of the recurrent mixers,
+their backward passes written out: ``causal_conv1d`` (depthwise, SiLU
+fused: Mamba-2's and both delta rules') — where the channels fill lane
+tiles and the rows sublane tiles (``conv_kernels_fit``: every published
+width) the Pallas pair ``causal_conv_fwd`` / ``causal_conv_bwd`` over
+``(rows, lanes)`` tiles of ``(b, s, c)`` as it stands, which makes a
+tile's ``pre``, sigmoid and ``dpre`` once in VMEM, elsewhere ``conv_xla``,
+plain XLA — and ``gated_short_conv`` (``C * conv(B * x)``, no activation:
+LFM2's whole mixer between its two projections), plain XLA over the
+helpers it shares with ``conv_xla``; and Mamba-2's output norm,
 ``gated_rms_norm``: one group is plain XLA, several are one rule with a
 written-out backward pass — the kernels ``gated_norm_fwd`` /
 ``gated_norm_bwd`` over the ``(tokens, inner)`` arrays as they stand where
@@ -109,9 +114,9 @@ def _conv_pre(x, weight, bias):
     return taps if lead is None else lead + taps
 
 
-@jax.custom_vjp
 def causal_conv1d(x: jax.Array, weight: jax.Array,
-                  bias: Optional[jax.Array] = None) -> jax.Array:
+                  bias: Optional[jax.Array] = None, *,
+                  tokens_last: bool = False) -> jax.Array:
     """SiLU of the causal depthwise convolution of ``x (b, s, c)`` along
     ``s``: ``y[t] = bias + sum_i weight[i] * x[t - (k-1) + i]`` with
     ``weight (k, c)`` and zeros before the sequence — ``weight[k-1]``
@@ -119,14 +124,34 @@ def causal_conv1d(x: jax.Array, weight: jax.Array,
     cut to ``s`` outputs has it; ``bias`` None is a convolution without
     one.  Float32 inside, ``x.dtype`` out.
 
-    Its backward pass is written out (autodiff of pad-and-slice writes one
-    float32 ``(b, s, c)`` array a tap and sums them in a second pass): the
-    gradient to ``x`` is the same convolution run against time."""
+    Two forms of one rule, chosen by what the call's shapes show
+    (``conv_kernels_fit``): where the array tiles the chip,
+    ``conv_kernels`` — the Pallas pair ``causal_conv_fwd`` /
+    ``causal_conv_bwd`` over 2-D tiles of the array as it stands,
+    interpreted off the chip; elsewhere ``conv_xla``, which is also the
+    tests' oracle.  ``tokens_last`` says HOW it stands, which the caller
+    knows and the shapes do not show: a mixer whose rule reads ``(b,
+    heads, d, s)`` (the delta rules) is laid out by XLA with the tokens
+    along the lanes, and the pair then walks the transposed view — the
+    values are the same either way.  Either way the backward pass is
+    written out and keeps nothing but the arguments: the gradient to ``x``
+    is the same convolution run against time.  A Pallas kernel has no
+    partitioning rule: under a mesh a block calls this per shard of the
+    batch."""
+    if conv_kernels_fit(x.shape[2], weight.shape[0], x.shape[1],
+                        tokens_last):
+        return conv_kernels(x, weight, bias, tokens_last)
+    return conv_xla(x, weight, bias)
+
+
+@jax.custom_vjp
+def conv_xla(x, weight, bias):
+    """``causal_conv1d`` as plain XLA, for any shapes.  Its backward pass
+    is written out (autodiff of pad-and-slice writes one float32 ``(b, s,
+    c)`` array a tap and sums them in a second pass) as ONE expression
+    that XLA fuses into its consumers: every element of ``dx`` and of
+    ``dw`` remakes ``pre``, its sigmoid and ``dpre`` at each tap."""
     return jax.nn.silu(_conv_pre(x, weight, bias)).astype(x.dtype)
-
-
-def _conv_fwd(x, weight, bias):
-    return causal_conv1d(x, weight, bias), (x, weight, bias)
 
 
 def _conv_grads(dpre, x, weight):
@@ -143,18 +168,393 @@ def _conv_grads(dpre, x, weight):
     return dx, dw
 
 
-def _conv_bwd(res, dy):
+def _dsilu(pre, dy):
+    """The gradient to ``pre`` of ``silu(pre)`` under ``dy``, float32."""
+    sig = jax.nn.sigmoid(pre)
+    return dy * sig * (1.0 + pre * (1.0 - sig))
+
+
+def _conv_xla_bwd(res, dy):
     x, weight, bias = res
     pre = _conv_pre(x, weight, bias)          # cheap to run again
-    sig = jax.nn.sigmoid(pre)
-    dpre = dy.astype(_F32) * sig * (1.0 + pre * (1.0 - sig))
+    dpre = _dsilu(pre, dy.astype(_F32))
     dx, dw = _conv_grads(dpre, x, weight)
     return (dx.astype(x.dtype), dw.astype(weight.dtype),
             None if bias is None
             else jnp.sum(dpre, (0, 1)).astype(bias.dtype))
 
 
-causal_conv1d.defvjp(_conv_fwd, _conv_bwd)
+conv_xla.defvjp(lambda *args: (conv_xla(*args), args), _conv_xla_bwd)
+
+
+# ------------------------------------- the convolution's Pallas pair
+#
+# A grid step is one 2-D tile of the array AS IT STANDS in memory, and it
+# stands one of two ways.  A Mamba-2 mixer keeps ``(b, s, c)`` with the
+# tokens down the sublanes and the channels along the lanes (the scan's
+# kernels read it so).  The delta rules' kernels read ``(b, heads, d, s)``,
+# tokens along the LANES, and XLA lays their whole mixer out that way from
+# the input projection on: there (``tokens_last``) the pair walks the
+# transposed view ``(b, c, s)``, which costs no copy, where a pair fixed to
+# ``(b, s, c)`` made XLA turn the array round before and after every call
+# (PERF.md §6, PR 67).  ``axis`` below is the tokens' axis of a tile: 0 or
+# 1.  The ``k - 1`` tokens before a tile (backward: after it too) come from
+# the same array through a second BlockSpec one hardware tile long, zeros
+# at a sequence's ends, so a tile never reads across two rows of the batch.
+# A grid step works through its tile in windows (forward with time,
+# backward against it, a window's edge riding to the next in registers): a
+# window is widened to float32 once, its shifts are rotates whose wrapped
+# tokens are put right from the neighbour's, and ``pre``, its sigmoid and
+# ``dpre`` are made once and never reach HBM.
+
+_CONV_EDGE = (8, _LANES)      # a float32 tile's extent along either axis
+_CONV_HALO = (16, _LANES)     # a bfloat16 tile's: the halo blocks' extent
+# A tile's (tokens, channels), at most, and those of the windows a grid
+# step works through it in, by the tokens' axis: the least forward x 2 +
+# backward of a sweep on the v5e (PERF.md §6, PR 67; a layer of 100.7 M
+# elements, ms forward / backward).  Tokens down the sublanes, windows of
+# sixteen float32 registers whose chain of values never leaves them: 0.73 /
+# 1.36 (the tile in one piece 1.17 / 2.27).  Tokens along the lanes a
+# window's rotates cross as many registers as it is long, a short or a thin
+# window loses (512 x 16: 2.09 / 4.81) and one as long as the tile, 64
+# channels at a time, wins: 0.95 / 2.05 (the tile in one piece 1.06 / 2.20).
+_CONV_TILE = ((2048, 512), (2048, 256))
+_CONV_WINDOW = ((64, 256), (2048, 64))
+
+
+def conv_kernels_fit(channels: int, taps: int, rows: int,
+                     tokens_last: bool = False) -> bool:
+    """Whether ``causal_conv1d``'s call tiles the chip: whole lane tiles
+    along the lanes' axis (the channels; the tokens where ``tokens_last``),
+    whole sublane tiles (bfloat16's 16) along the other, no more taps than
+    a float32 sublane tile has rows."""
+    lanes, sublanes = (rows, channels) if tokens_last else (channels, rows)
+    return (lanes % _LANES == 0 and sublanes % _CONV_HALO[0] == 0
+            and 1 <= taps <= _CONV_EDGE[0])
+
+
+def _largest_tile(size, unit, most):
+    """The largest multiple of ``unit`` that divides ``size``, ``most`` at
+    most."""
+    return max(t for t in range(unit, min(size, most) + 1, unit)
+               if size % t == 0)
+
+
+def _conv_static(x, tokens_last):
+    """What the two calls are compiled for, beside their shapes: the
+    tokens' axis of a tile, the tile's ``(tokens, channels)`` and those of
+    the windows a grid step works through, chosen from the shapes."""
+    axis = int(tokens_last)
+    units = _CONV_HALO[axis], _CONV_HALO[1 - axis]
+    tile = tuple(map(_largest_tile, x.shape[1:], units, _CONV_TILE[axis]))
+    return dict(
+        axis=axis, tile=tile,
+        sub=tuple(map(_largest_tile, tile, units, _CONV_WINDOW[axis])),
+        interpret=attention._interpret_default())
+
+
+def _laid(tokens, channels, axis):
+    """``(tokens, channels)`` in the order a tile has them: the tokens
+    along ``axis``."""
+    return (channels, tokens) if axis else (tokens, channels)
+
+
+def _span(t, start, stop, axis):
+    """``t[start:stop]`` along ``axis`` of a tile (a value or a ref)."""
+    return t[_laid(slice(start, stop), slice(None), axis)]
+
+
+def _behind(tile, before, d, axis):
+    """``tile[t - d]`` along the tokens' ``axis`` of a float32 tile, its
+    first ``d`` tokens the last of ``before`` (one hardware tile long)."""
+    if d == 0:
+        return tile
+    n, e = tile.shape[axis], before.shape[axis]
+    rolled = pltpu.roll(tile, d, axis)
+    head = jnp.where(_iota(before.shape, axis) < d,
+                     pltpu.roll(before, d, axis), _span(rolled, 0, e, axis))
+    if n == e:
+        return head
+    return jnp.concatenate([head, _span(rolled, e, n, axis)], axis=axis)
+
+
+def _ahead(tile, after, d, axis):
+    """``tile[t + d]``, its last ``d`` tokens the first of ``after``."""
+    if d == 0:
+        return tile
+    n, e = tile.shape[axis], after.shape[axis]
+    rolled = pltpu.roll(tile, n - d, axis)
+    tail = jnp.where(_iota(after.shape, axis) >= e - d,
+                     pltpu.roll(after, e - d, axis),
+                     _span(rolled, n - e, n, axis))
+    if n == e:
+        return tail
+    return jnp.concatenate([_span(rolled, 0, n - e, axis), tail], axis=axis)
+
+
+def _add_all(terms):
+    return functools.reduce(lambda a, b: a + b, terms)
+
+
+def _tile_pre(shifted, w, lead, axis):
+    """``_conv_pre`` of a tile from its ``k`` shifted copies (tap ``i``
+    meets ``x[t - (k-1) + i]``), the taps in the XLA form's order."""
+    taps = _add_all([t * _span(w, i, i + 1, axis)
+                     for i, t in enumerate(shifted)])
+    return taps if lead is None else lead + taps
+
+
+def _shifts(x, before, k, axis):
+    return [_behind(x, before, k - 1 - i, axis) for i in range(k)]
+
+
+def _at(tokens, channels, axis):
+    """The index of a window of a grid step's block."""
+    return (0,) + _laid(tokens, channels, axis)
+
+
+def _edge(t, axis, last):
+    """The float32 tile at the start (``last``: the end) of ``t`` along
+    the tokens' axis."""
+    t, e = t.astype(_F32), _CONV_EDGE[axis]
+    n = t.shape[axis]
+    return _span(t, n - e, n, axis) if last else _span(t, 0, e, axis)
+
+
+def _fold(v, axis):
+    """``v`` summed along the tokens' axis down to ONE hardware tile of
+    tokens: whole-register adds, no reduction inside a register."""
+    e = _CONV_EDGE[axis]
+    return _add_all([_span(v, i, i + e, axis)
+                     for i in range(0, v.shape[axis], e)])
+
+
+def _window(i, size):
+    return pl.ds(pl.multiple_of(i * size, size), size)
+
+
+def _channel_window(w_ref, bias_ref, j, size, axis):
+    """Channel window ``j`` of a grid step: its slice and its float32
+    taps and bias (None without one)."""
+    ch = _window(j, size)
+    lanes = _laid(slice(None), ch, axis)
+    return ch, w_ref[lanes].astype(_F32), (
+        None if bias_ref is None else bias_ref[lanes].astype(_F32))
+
+
+def _conv_fwd_kernel(w_ref, *refs, biased, axis, sub):
+    """A tile in windows of ``sub`` ``(tokens, channels)`` — a few
+    registers, so that a window's ``pre`` and its sigmoid never leave them
+    —, the tokens in order: a window's last tokens ride to the next."""
+    x_ref, before_ref, y_ref = refs[biased:]
+    k, (ts, cs) = w_ref.shape[axis], sub
+    first = pl.program_id(2) == 0
+
+    def channels(j, _):
+        ch, w, lead = _channel_window(
+            w_ref, refs[0] if biased else None, j, cs, axis)
+
+        def tokens(i, before):
+            at = _at(_window(i, ts), ch, axis)
+            x = x_ref[at].astype(_F32)
+            pre = _tile_pre(_shifts(x, before, k, axis), w, lead, axis)
+            y_ref[at] = jax.nn.silu(pre).astype(y_ref.dtype)
+            return _edge(x, axis, last=True)
+
+        before = _edge(before_ref[_at(slice(None), ch, axis)],
+                       axis, last=True)
+        jax.lax.fori_loop(0, x_ref.shape[1 + axis] // ts, tokens,
+                          jnp.where(first, 0.0, before))
+        return 0
+
+    jax.lax.fori_loop(0, x_ref.shape[2 - axis] // cs, channels, 0)
+
+
+def _conv_bwd_kernel(w_ref, *refs, biased, axis, sub):
+    """The windows of a tile against time: a window's first ``dpre`` ride
+    to the one before it, as do the weight's and the bias's sums of the
+    tile, one hardware tile of tokens each, until the tile's last window.
+    ``dw_ref`` (and ``db_ref``) stay in VMEM over a channel block's tiles
+    — the grid's two inner axes — and collect the tiles' sums in
+    float32."""
+    x_ref, before_ref, after_ref, dy_ref, dy_after_ref, dx_ref, dw_ref = (
+        refs[biased:biased + 7])
+    k, (ts, cs) = w_ref.shape[axis], sub
+    halo = _CONV_HALO[axis]
+    tile, tiles = pl.program_id(2), pl.num_programs(2)
+    n = x_ref.shape[1 + axis]
+    windows = n // ts
+
+    @pl.when((pl.program_id(1) == 0) & (tile == 0))
+    def _channel_blocks_first_tile():
+        for ref in refs[biased + 6:]:
+            ref[...] = jnp.zeros_like(ref)
+
+    def channels(j, _):
+        ch, w, lead = _channel_window(
+            w_ref, refs[0] if biased else None, j, cs, axis)
+
+        def pre_of(x, before):
+            shifted = _shifts(x, before, k, axis)
+            return shifted, _tile_pre(shifted, w, lead, axis)
+
+        def halo_of(ref, start=None):
+            tokens = slice(None) if start is None else pl.ds(start, halo)
+            return ref[_at(tokens, ch, axis)]
+
+        def window(ii, carry):
+            dpre_after, sums = carry
+            i = windows - 1 - ii
+            at = _at(_window(i, ts), ch, axis)
+            x, dy = x_ref[at].astype(_F32), dy_ref[at].astype(_F32)
+            behind = halo_of(x_ref, pl.multiple_of(
+                jnp.maximum(i * ts - halo, 0), halo))
+            before = jnp.where(i == 0, tile_before,
+                               _edge(behind, axis, last=True))
+            shifted, pre = pre_of(x, before)
+            dpre = _dsilu(pre, dy)
+            dx_ref[at] = _add_all([
+                _ahead(dpre, dpre_after, k - 1 - t, axis)
+                * _span(w, t, t + 1, axis) for t in range(k)]
+            ).astype(dx_ref.dtype)
+            sums = tuple(acc + _fold(dpre if t is None else dpre * t, axis)
+                         for acc, t in zip(sums, shifted + [None]))
+            return _edge(dpre, axis, last=False), sums
+
+        tile_before = jnp.where(tile == 0, 0.0,
+                                _edge(halo_of(before_ref), axis, last=True))
+        # the tokens after the tile: their ``pre`` reads the tile's last
+        # ones, and past the sequence's end there is no gradient
+        _, pre_after = pre_of(
+            _edge(halo_of(after_ref), axis, last=False),
+            _edge(halo_of(x_ref, n - halo), axis, last=True))
+        dy_after = jnp.where(tile == tiles - 1, 0.0,
+                             _edge(halo_of(dy_after_ref), axis, last=False))
+        zero = jnp.zeros_like(dy_after)
+        _, sums = jax.lax.fori_loop(
+            0, windows, window,
+            (_dsilu(pre_after, dy_after), (zero,) * (k + biased)))
+        for t, acc in enumerate(sums):
+            ref, tap = (dw_ref, t) if t < k else (refs[biased + 7], 0)
+            at = _laid(slice(tap, tap + 1), ch, axis)
+            ref[at] += jnp.sum(acc, axis=axis, keepdims=True)
+        return 0
+
+    jax.lax.fori_loop(0, x_ref.shape[2 - axis] // cs, channels, 0)
+
+
+def _conv_plan(x, weight, bias, axis, tile, interpret, carried):
+    """What the two calls share, ``x`` being ``(b, s, c)`` (``axis`` 0) or
+    its transposed view ``(b, c, s)`` (1): the grid ``(channel block,
+    batch row, token tile)``; the BlockSpecs of a tile, of the hardware
+    tile of tokens before it and after it (clamped at the sequence's ends,
+    where the kernels put zeros), of the weight's and the bias's channel
+    block; the two leading operands, the channels along the axis they
+    have in a tile.  ``carried``: the inner axes run in order (the
+    backward's sums ride over them)."""
+    t, c = tile
+    tokens, channels = x.shape[1 + axis], x.shape[2 - axis]
+    halo = _CONV_HALO[axis]
+    halos, last = t // halo, tokens // halo - 1
+
+    def spec(tokens_block, at):
+        """A block ``tokens_block`` tokens long of the grid step's channel
+        block, at the token block ``at(i)``."""
+        block = _laid(tokens_block, c, axis)
+        return pl.BlockSpec((1,) + block, lambda j, b, i: (
+            (b,) + _laid(at(i), j, axis)))
+
+    def lead(extent):  # the weight's taps or the bias, a channel block
+        block = _laid(extent, c, axis)
+        return pl.BlockSpec(block, lambda j, b, i: _laid(0, j, axis))
+
+    operands = (weight,) if bias is None else (weight, bias[None])
+    inner = "arbitrary" if carried else "parallel"
+    return dict(
+        grid=(channels // c, x.shape[0], tokens // t),
+        tile=spec(t, lambda i: i),
+        before=spec(halo, lambda i: jnp.maximum(i * halos - 1, 0)),
+        after=spec(halo, lambda i: jnp.minimum((i + 1) * halos, last)),
+        lead=[lead(weight.shape[0])] + ([] if bias is None else [lead(1)]),
+        operands=operands if axis == 0 else tuple(t.T for t in operands),
+        params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", inner, inner),
+            vmem_limit_bytes=64 * 1024 * 1024))
+
+
+_CONV_STATIC = ("axis", "tile", "sub", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_CONV_STATIC)
+def _conv_fwd_call(x, weight, bias, *, axis, tile, sub, interpret):
+    """``x (b, s, c)`` — ``axis`` 1: ``(b, c, s)`` —, ``weight (k, c)``,
+    ``bias (c,)`` or None: the SiLU of the convolution, like ``x``."""
+    sp = _conv_plan(x, weight, bias, axis, tile, interpret, carried=False)
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, biased=bias is not None,
+                          axis=axis, sub=sub),
+        grid=sp["grid"],
+        in_specs=sp["lead"] + [sp["tile"], sp["before"]],
+        out_specs=sp["tile"],
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=sp["params"],
+        interpret=interpret,
+        name="causal_conv_fwd",
+    )(*sp["operands"], x, x)
+
+
+@functools.partial(jax.jit, static_argnames=_CONV_STATIC)
+def _conv_bwd_call(x, weight, bias, dy, *, axis, tile, sub, interpret):
+    """Gradients to ``x`` (like it), to ``weight`` ``(k, c)`` float32 and,
+    where there is a bias, to it ``(c,)`` float32 (else None)."""
+    sp = _conv_plan(x, weight, bias, axis, tile, interpret, carried=True)
+    sums = [jax.ShapeDtypeStruct(t.shape, _F32) for t in sp["operands"]]
+    dx, *sums = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, biased=bias is not None,
+                          axis=axis, sub=sub),
+        grid=sp["grid"],
+        in_specs=sp["lead"] + [sp["tile"], sp["before"], sp["after"],
+                               sp["tile"], sp["after"]],
+        out_specs=[sp["tile"]] + sp["lead"],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)] + sums,
+        compiler_params=sp["params"],
+        interpret=interpret,
+        name="causal_conv_bwd",
+    )(*sp["operands"], x, x, x, dy, dy)
+    dw, *db = sums if axis == 0 else [t.T for t in sums]
+    return dx, dw, db[0][0] if db else None
+
+
+def _tokens_axis(tokens_last):
+    """``(b, s, c)`` to the view the pair walks, and back."""
+    return (lambda t: jnp.swapaxes(t, 1, 2)) if tokens_last else (lambda t: t)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def conv_kernels(x, weight, bias, tokens_last=False):
+    """``causal_conv1d`` through the Pallas pair (compiled on the TPU,
+    interpreted elsewhere): the backward kernel makes a tile's ``pre``,
+    sigmoid and ``dpre`` once in VMEM and sums the weight's and the bias's
+    gradients in float32; nothing but the arguments is kept."""
+    view = _tokens_axis(tokens_last)
+    return view(_conv_fwd_call(view(x), weight, bias,
+                               **_conv_static(x, tokens_last)))
+
+
+def _conv_kernels_fwd(x, weight, bias, tokens_last):
+    return conv_kernels(x, weight, bias, tokens_last), (x, weight, bias)
+
+
+def _conv_kernels_bwd(tokens_last, res, dy):
+    x, weight, bias = res
+    view = _tokens_axis(tokens_last)
+    dx, dw, db = _conv_bwd_call(view(x), weight, bias, view(dy),
+                                **_conv_static(x, tokens_last))
+    return (view(dx), dw.astype(weight.dtype),
+            None if bias is None else db.astype(bias.dtype))
+
+
+conv_kernels.defvjp(_conv_kernels_fwd, _conv_kernels_bwd)
 
 
 def _gate_split(bcx):
@@ -893,3 +1293,4 @@ def ssd_reference(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         token, jnp.zeros((batch, heads, p, n), _F32),
         tuple(jnp.moveaxis(t, 1, 0) for t in (x, dt, b, c)))
     return jnp.moveaxis(y, 0, 1)
+

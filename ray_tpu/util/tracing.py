@@ -487,7 +487,7 @@ SCAN = "scan"
 # kernel rows of ``step_breakdown``.  A step scope that starts the same way
 # (``hc_map``, ``hc_mix``) is no kernel's name.
 KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm", "ssd_", "gated_norm_", "hc_",
-                "delta_", "rope_", "kdarule_")
+                "delta_", "rope_", "kdarule_", "causal_conv_")
 _SCOPE_TOKENS = re.compile(r"[^/()]+")
 
 

@@ -866,6 +866,90 @@ def test_the_solar_open2_cells_step_program_fits_the_chip(one_chip,
     assert 0.25 * 16.909e9 < mem.peak_memory_in_bytes < 16.909e9
 
 
+@pytest.mark.parametrize("shape,biased,tokens_last", [
+    ((1, 8192, 12288), False, True),   # Kimi-Linear's q, k, v
+    ((1, 4096, 24576), False, True),   # Solar-Open2's
+    ((1, 4096, 11520), False, True),   # Olmo-Hybrid's: 480 channels a tile
+    ((2, 8192, 6144), True, False),    # Nemotron-H's x, B, C
+    ((1, 8192, 4352), True, False),    # granite's: 34 lane tiles
+    ((1, 4096, 10240), True, False),   # Nemotron-3 Super's
+], ids=["kimilinear", "solaropen2", "olmohybrid", "nemotronh", "granite4h",
+        "nemotron3super"])
+def test_the_convolutions_pair_compiles_at_the_cells_shapes(
+        one_chip, as_on_chip, shape, biased, tokens_last):
+    """``causal_conv1d`` at the six cells' ``(b, s, c)``, value and the
+    gradients to x, weight and bias, the array standing as each cell's
+    mixer has it (the delta rules' tokens-last): the kernels
+    ``causal_conv_fwd`` and ``causal_conv_bwd`` — rotates of a float32
+    tile along its sublanes or its lanes, a second block of the same
+    array one hardware tile long before and after a tile at a clamped
+    index, the weight's gradient in an output block (a column a tap where
+    the tokens are along the lanes) that stays over the grid's two inner
+    axes: what Mosaic could refuse."""
+    from ray_tpu.ops.ssm import causal_conv1d
+
+    def f(x, w, bias, dy):
+        return (causal_conv1d(x, w, bias, tokens_last=tokens_last
+                              ).astype(jnp.float32) * dy).sum()
+
+    x = _shape(shape, jnp.bfloat16, one_chip)
+    bias = _shape(shape[2:], jnp.float32, one_chip) if biased else None
+    text = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2) if biased else (0, 1))).lower(
+            x, _shape((4, shape[2]), jnp.float32, one_chip), bias, x
+        ).compile().as_text()
+    assert "causal_conv_fwd" in text and "causal_conv_bwd" in text
+
+
+def _written(text):
+    """The instructions of a compiled text that write an array to memory:
+    every line outside the fused computations, whose ops live in
+    registers."""
+    fused = False
+    for line in text.splitlines():
+        start = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if start:
+            fused = start.group(1).startswith("fused_computation")
+        elif not fused and " = " in line:
+            yield line
+
+
+@pytest.mark.parametrize("mixer", ["kda", "mamba"])
+def test_the_mixers_convolution_is_the_pair_and_writes_no_float32_copy(
+        one_chip, as_on_chip, mixer):
+    """Kimi-Linear's first layer (a KDA mixer; ONE layer: the two-layer
+    step compiles for 50 s and this file is the suite's longest) and two
+    layers of granite (Mamba-2) at their published widths and 8192
+    positions, as the one-chip train step, compiled: each scanned run of
+    layers holds the convolution's forward
+    kernel twice (the pass and its rerun under the layer checkpoint) and
+    the backward kernel once, and under ``kda_conv`` / ``ssm_conv`` no op
+    writes a float32 ``(b, s, c)`` array — the XLA form's backward pass
+    read two (``dpre``'s operands) and remade ``pre``, its sigmoid and
+    ``dpre`` in float32 at every tap of every element (PERF.md §6,
+    PR 67)."""
+    if mixer == "kda":
+        cfg, scope = _kimi_linear_cfg(1), "kda_conv"
+        channels = 3 * cfg.kda_inner
+    else:
+        cfg, scope = _granite_cfg(("mamba", "mamba")), "ssm_conv"
+        channels = cfg.ssm_conv_dim
+    assert {kind[0] for kind, _ in cfg.kind_runs} == {mixer}
+    runs, opt = len(cfg.kind_runs), default_optimizer()
+    text = make_train_step(cfg, opt).lower(
+        _state_shapes(cfg, opt, one_chip),
+        {"tokens": _shape((1, 8193), jnp.int32, one_chip)}
+    ).compile().as_text()
+    for kernel, calls in (("causal_conv_fwd", 2), ("causal_conv_bwd", 1)):
+        assert len(re.findall(
+            rf"custom-call\(.*/{scope}/.*/{kernel}/pallas_call\"", text)
+        ) == calls * runs, kernel
+    wide = [line for line in _written(text)
+            if re.search(rf"= f32\[1,8192,{channels}\]", line)
+            and f"/{scope}/" in line]
+    assert not wide, wide[:3]
+
+
 def test_lfm2_conv_layer_train_step_compiles(one_chip, as_on_chip):
     """One gated short-convolution layer with its expert FFN of
     LFM2-8B-A1B as ``lfm2moe-train-s8192`` runs it (the benchmark's

@@ -900,6 +900,54 @@ def test_step_breakdown_counts_the_state_space_kernels(tmp_path):
     assert "flash_fwd  executed/causal 1.0622  x 1 a step" in text
 
 
+@pytest.mark.parametrize("scope", ["ssm_conv", "gdn_conv", "kda_conv"])
+def test_step_breakdown_counts_the_convolutions_kernels(tmp_path, scope):
+    """The mixers' causal convolution (``ops/ssm.py::causal_conv1d``):
+    kernel rows ``causal_conv_fwd`` (the pass, and its rerun under the
+    layer checkpoint) and ``causal_conv_bwd`` with their calls a step,
+    their time the conv scope's with the XLA ops round them.  The prefix
+    ``causal_conv_`` starts no step scope, so a scope is no kernel's
+    name."""
+    from ray_tpu.train.core import STEP_SCOPES
+    from ray_tpu.util.tracing import (
+        KERNEL_NAMES, format_breakdown, step_breakdown)
+
+    assert "causal_conv_" in KERNEL_NAMES and scope in STEP_SCOPES
+    assert not [s for s in STEP_SCOPES if s.startswith("causal_conv_")
+                or "causal_conv_fwd".startswith(s)]
+    fwd, bwd = _FWD, _BWD
+    call = ', custom_call_target=\\"tpu_custom_call\\"'
+    conv = scope + "/jit(_conv_fwd_call)/causal_conv_fwd/pallas_call"
+    ops = [("%lead = f32[] add()", "jit(step)/optimizer/add", 500, 100)]
+    at = 2000
+    for layer in range(3):
+        ops += [
+            (f"%causal_conv_fwd.{layer} = bf16[] custom-call()" + call,
+             fwd + conv, at, 9),
+            (f"%norm.{layer} = f32[] fusion()", fwd + scope + "/mul",
+             at + 9, 5),
+            (f"%causal_conv_fwd.{layer + 3} = bf16[] custom-call()" + call,
+             bwd + "rematted_computation/" + conv, at + 14, 10),
+            (f"%causal_conv_bwd.{layer} = bf16[] custom-call()" + call,
+             bwd + scope + "/jit(_conv_bwd_call)/causal_conv_bwd/pallas_call",
+             at + 24, 20),
+        ]
+        at += 200
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace(ops, modules=[(0, 1000), (2000, 1000)]))
+    b = step_breakdown(str(path), "jit_step")
+    ns = lambda s: round(s * 1e9)  # noqa: E731
+    assert {k: ns(t) for k, t in b["kernels"].items()} == {
+        "causal_conv_fwd": 27, "causal_conv_fwd.remat": 30,
+        "causal_conv_bwd": 60}
+    assert set(b["kernel_calls"].values()) == {3}
+    assert {p: ns(t) for p, t in b["scopes"][scope].items()} == {
+        "forward": 42, "remat": 30, "backward": 60}
+    text = format_breakdown(b)
+    assert "causal_conv_fwd.remat  x 3 a step" in text
+    assert "causal_conv_bwd  x 3 a step" in text
+
+
 def test_step_breakdown_counts_the_delta_rule_kernels(tmp_path):
     """A gated delta-rule layer's rule (``ops/delta.py``): kernel rows
     ``delta_fwd`` (forward pass, and rematerialised: its entering states
