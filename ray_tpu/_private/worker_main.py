@@ -1967,25 +1967,32 @@ def worker_entry(conn, worker_id_hex: str, session: str, shm_dir: str,
         spill_info={"node": node_id_hex})
     rt.direct_addr = direct_server.address
 
-    def decref_flusher():
-        import time as _time
+    # The periodic thread's work, in order.  flush_results bounds
+    # result-batch latency when a long task follows buffered short-task
+    # results; failure detection (the heartbeat floor + the stalled-head
+    # watchdog) rides the same thread.
+    periodic = (rt.flush_decrefs, rt.flush_results, rt.flush_spans,
+                rt._pull_registry.sweep, rt.flush_xfer_stats,
+                rt.heartbeat_and_watchdog, direct_server.flush_replies)
 
+    def decref_flusher():
         while True:
-            _time.sleep(0.25)
-            try:
-                rt.flush_decrefs()
-                # Bounds result-batch latency when a long task follows
-                # buffered short-task results.
-                rt.flush_results()
-                rt.flush_spans()
-                rt._pull_registry.sweep()
-                rt.flush_xfer_stats()
-                # Failure detection: the heartbeat floor + the stalled-
-                # head watchdog ride the same periodic thread.
-                rt.heartbeat_and_watchdog()
-                direct_server.flush_replies()
-            except Exception:
-                return  # conn gone; reader exits the process
+            time.sleep(0.25)
+            # It runs under the interpreter's lock in the process that
+            # owns the chips: an iteration over a millisecond is the
+            # process-wide span ``worker.flush``, its longest call named.
+            with tracing.span("worker.flush", process_wide=True,
+                              min_s=tracing.GC_PAUSE_MIN_S) as s:
+                longest = 0.0
+                try:
+                    for call in periodic:
+                        t0 = time.perf_counter()
+                        call()
+                        took = time.perf_counter() - t0
+                        if took > longest:
+                            longest, s.args["slowest"] = took, call.__name__
+                except Exception:
+                    return  # conn gone; reader exits the process
 
     threading.Thread(target=decref_flusher, daemon=True,
                      name="ray_tpu-decref").start()

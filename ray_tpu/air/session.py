@@ -43,7 +43,10 @@ class _TrainSession:
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None):
-        with tracing.span("session.report") as s:
+        # clock=True: the loop thread's CPU clocks and scheduler counters
+        # at every report, so a late one says whose fault it was
+        # (``_spans["session.report"]["clock"]``).
+        with tracing.span("session.report", clock=True) as s:
             self._report(metrics, checkpoint, s.start)
 
     def _report(self, metrics: Dict[str, Any],

@@ -85,10 +85,16 @@ class DataParallelTrainer(BaseTrainer):
         ``train.loop``: ``train/backend.py::bring_up``; from there on,
         or where a CPU worker's loop builds train steps, JAX's
         ``jax.trace``, ``jax.lower``, ``jax.compile``, ``jax.cache_load``,
-        ``jax.cache_miss`` of every program, and the collector's
-        ``gc.pause``: ``tracing.watch_process``)
+        ``jax.cache_miss`` of every program, and the process-wide
+        ``gc.pause`` and ``host.lag``: ``tracing.watch_process``; the
+        worker's periodic thread where an iteration took over a
+        millisecond, ``worker.flush``)
         ``count``, ``total_s``, ``max_s``, ``first_start``, ``last_end``
-        and ``recent``, the ``(start, end)`` of its last 256 spans.  Every
+        and ``recent``, the ``(start, end)`` of its last 256 spans;
+        ``session.report`` also ``clock``, the loop thread's CPU clocks
+        and context switches at each of those (``tracing.thread_clock``:
+        which step came late, and whether the worker was stopped, busy or
+        waiting, is ``benchmark/late_steps.py``'s to say).  Every
         entry of ``metrics_history`` carries ``_timestamp`` (the start of
         its ``session.report``) and ``_time_this_iter_s`` (since the
         report before it) beside ``_training_iteration``."""

@@ -14,11 +14,18 @@ a worker's buffer, the periodic ``spans`` message, the head's deque,
 ``record(name, start, end)`` is the same for an interval that is already
 over; ``watch_process()`` turns what JAX reports of its compile pipeline
 (``jax.trace``, ``jax.lower``, ``jax.compile``, ``jax.cache_load``,
-``jax.cache_miss``) and the garbage collector's pauses (``gc.pause``)
-into such spans.  A train worker that was granted chips opens them under
-``device.bring_up`` (``chips=``) > ``jax.import``, ``jax.backend_init``
-before the user's loop (``train/backend.py::bring_up``) and is watched
-from between the two: from the first program it makes.
+``jax.cache_miss``) into such spans, and the garbage collector's pauses
+(``gc.pause``) and the stretches in which no Python thread of the process
+could run (``host.lag``) into PROCESS-WIDE ones: a span marked so says
+something of every thread, and goes to every collector open in the process
+whichever thread opened it (``_emit``; the worker's periodic thread,
+``worker.flush``, is the third).  A span made with ``clock=True`` samples
+the CPU clocks and the scheduler's counters of its thread as it opens
+(``session.report`` does: ``_Collected``).  A train worker that was
+granted chips opens them under ``device.bring_up`` (``chips=``) >
+``jax.import``, ``jax.backend_init`` before the user's loop
+(``train/backend.py::bring_up``) and is watched from between the two:
+from the first program it makes.
 
 The shared clock with the chip: when ``jax`` is ALREADY imported in the
 process, a span also enters ``jax.profiler.TraceAnnotation(name)``, so under
@@ -43,6 +50,7 @@ import itertools
 import json
 import os
 import re
+import resource
 import sys
 import threading
 import time
@@ -53,10 +61,14 @@ from ray_tpu._private.api_internal import get_runtime, require_runtime
 _PROCESS = os.urandom(4).hex()  # span ids are unique across processes
 _ids = itertools.count(1)
 _local = threading.local()  # .stack: open span ids; .collectors: _Collected
-# Every collector open in the process, whichever thread opened it: what a
-# ``gc.pause`` is added to, since a collection stops every thread.
+# Every collector open in the process, whichever thread opened it: where a
+# process-wide span goes (``_emit``).
 _open_collectors: List["_Collected"] = []
+# Process-wide spans the store has not seen yet: ``record_span``'s tuples
+# less the task id (``_emit``).
+_unstored: collections.deque = collections.deque(maxlen=1024)
 RECENT = 256  # (start, end) pairs a collector keeps per name
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)  # Linux
 
 
 def new_id() -> str:
@@ -87,19 +99,49 @@ def stamp(spec: dict) -> None:
             got.causes.add(cause)
 
 
+def thread_clock() -> Tuple[float, float, int, int, int]:
+    """What a clocked span keeps of the calling thread as it opens, all
+    cumulative: CPU seconds THIS thread has run, CPU seconds of all the
+    process's threads (``time.process_time``), and the thread's voluntary
+    context switches, involuntary ones and major page faults.  All but
+    the second from ONE ``getrusage(RUSAGE_THREAD)`` — its ``ru_utime +
+    ru_stime`` is ``time.thread_time()`` to the microsecond on Linux —
+    because each is a system call: a microsecond the two on a plain
+    Linux, 6 us EACH on the sandboxed host of the benchmark's chips,
+    whose kernel also ticks CPU time in 10 ms and counts no switches
+    (``PERF.md`` §6, PR 68).  Without ``RUSAGE_THREAD``: the two clocks
+    and zeros."""
+    if _RUSAGE_THREAD is None:
+        return time.thread_time(), time.process_time(), 0, 0, 0
+    ru = resource.getrusage(_RUSAGE_THREAD)
+    return (ru.ru_utime + ru.ru_stime, time.process_time(), ru.ru_nvcsw,
+            ru.ru_nivcsw, ru.ru_majflt)
+
+
 class span:
-    """``with span("train.backend_start", workers=4): ...``"""
+    """``with span("train.backend_start", workers=4): ...``
+
+    ``clock=True``: the span keeps ``thread_clock()`` of its start beside
+    its ``(start, end)``.  ``process_wide=True``: it says something of
+    every thread of the process and goes to every open collector.
+    ``min_s``: a shorter one is dropped as it closes (its
+    ``TraceAnnotation`` stands)."""
 
     __slots__ = ("name", "args", "id", "parent", "submitted", "task_id",
-                 "kind", "start", "_annotation")
+                 "kind", "start", "_annotation", "clock", "process_wide",
+                 "min_s")
 
-    def __init__(self, name: str, **args):
+    def __init__(self, name: str, *, clock: bool = False,
+                 process_wide: bool = False, min_s: float = 0.0, **args):
         self.name = name
         self.args = args
         self.id = new_id()
         self.parent = self.submitted = self._annotation = None
         self.task_id = b""
         self.kind = "span"
+        self.clock = clock
+        self.process_wide = process_wide
+        self.min_s = min_s
 
     def __enter__(self):
         stack = _stack()
@@ -111,6 +153,7 @@ class span:
         if profiler is not None:
             self._annotation = profiler.TraceAnnotation(self.name)
             self._annotation.__enter__()
+        self.clock = thread_clock() if self.clock else None
         self.start = time.time()
         return self
 
@@ -119,40 +162,54 @@ class span:
         if self._annotation is not None:
             self._annotation.__exit__(*exc_info)
         _stack().pop()
-        _emit(self.name, self.start, end, self.id, self.parent, self.args,
-              self.task_id, self.kind, self.submitted)
+        if end - self.start >= self.min_s:
+            _emit(self.name, self.start, end, self.id, self.parent,
+                  self.args, self.task_id, self.kind, self.submitted,
+                  self.clock, self.process_wide)
         return False
 
 
 def _emit(name: str, start: float, end: float, sid: str,
           parent: Optional[str], args: Optional[dict],
           task_id: bytes = b"", kind: str = "span",
-          submitted: Optional[float] = None) -> None:
-    """A closed span goes to this thread's collectors and to the
-    runtime's store (a worker files it under the task it is running)."""
-    for got in getattr(_local, "collectors", ()):
-        got.add(name, start, end)
+          submitted: Optional[float] = None,
+          clock: Optional[tuple] = None, process_wide: bool = False,
+          store_later: bool = False) -> None:
+    """A closed span goes to this thread's collectors — a process-wide
+    one to EVERY open collector, the one place where a collector is not
+    thread-local — and to the runtime's store (a worker files it under
+    the task it is running).  ``store_later``: the caller may hold the
+    store's lock (``_gc_phase``), so the span waits in ``_unstored`` for
+    the next one this process closes."""
+    for got in (list(_open_collectors) if process_wide
+                else getattr(_local, "collectors", ())):
+        got.add(name, start, end, clock)
+    rec = (name, start, end, kind, sid, parent, submitted, args or None)
+    if store_later:
+        _unstored.append(rec)
+        return
     rt = get_runtime()
     if rt is not None:
         if not task_id and rt.is_worker() \
                 and rt.current_task_id is not None:
             task_id = rt.current_task_id.binary()
-        while _gc_pauses:  # see _gc_phase: filed with the next span
-            began, ended, generation = _gc_pauses.popleft()
-            rt.record_span((task_id, "gc.pause", began, ended, "span",
-                            new_id(), None, None,
-                            {"generation": generation}))
-        rt.record_span((task_id, name, start, end, kind, sid, parent,
-                        submitted, args or None))
+        while _unstored:
+            try:
+                rt.record_span((task_id, *_unstored.popleft()))
+            except IndexError:  # another thread filed it
+                break
+        rt.record_span((task_id, *rec))
 
 
-def record(name: str, start: float, end: float, **args) -> None:
+def record(name: str, start: float, end: float, *,
+           process_wide: bool = False, **args) -> None:
     """A span reported after the fact: ``start`` and ``end`` are known, on
     ``time.time()``.  The same record as a ``with span(...)`` block makes
     (own id, this thread's innermost open span as cause, the task id,
-    collectors, the runtime's store), but no ``TraceAnnotation``: it is
-    over."""
-    _emit(name, start, end, new_id(), current_span(), args)
+    collectors — every open one for a ``process_wide`` span —, the
+    runtime's store), but no ``TraceAnnotation``: it is over."""
+    _emit(name, start, end, new_id(), current_span(), args,
+          process_wide=process_wide)
 
 
 def task_span(task: dict) -> span:
@@ -182,7 +239,12 @@ def span_record(rec: tuple, worker_id: str, node_id: str) -> Dict[str, Any]:
 
 class _Collected:
     """Per-name totals of the spans a thread closed while collecting, and
-    the ``(start, end)`` of each name's last ``RECENT`` spans."""
+    the ``(start, end)`` of each name's last ``RECENT`` spans (``recent``).
+    A name whose spans are clocked (``span(..., clock=True)``: all of a
+    name's are, or none) also keeps ``clock``: ``thread_clock()`` at each
+    of those spans' starts, one tuple a span, as long as ``recent``, in its
+    order and under its bound — cumulative values, so a reader takes the
+    differences of neighbours."""
 
     def __init__(self):
         # Ids of this thread's spans under which a task or an actor was
@@ -191,7 +253,7 @@ class _Collected:
         self._names: Dict[str, Dict[str, Any]] = {}
 
     def _fold(self, name: str, count: int, total_s: float, max_s: float,
-              first_start: float, last_end: float) -> collections.deque:
+              first_start: float, last_end: float) -> Dict[str, Any]:
         s = self._names.get(name)
         if s is None:
             s = self._names[name] = {
@@ -204,18 +266,33 @@ class _Collected:
             s["max_s"] = max(s["max_s"], max_s)
             s["first_start"] = min(s["first_start"], first_start)
             s["last_end"] = max(s["last_end"], last_end)
-        return s["recent"]
+        return s
 
-    def add(self, name: str, start: float, end: float):
+    def add(self, name: str, start: float, end: float,
+            clock: Optional[tuple] = None):
         dur = end - start
-        self._fold(name, 1, dur, dur, start, end).append((start, end))
+        s = self._fold(name, 1, dur, dur, start, end)
+        s["recent"].append((start, end))
+        if clock is not None:
+            s.setdefault("clock", collections.deque(maxlen=RECENT)).append(
+                clock)
 
     def merge(self, summary: Optional[Dict[str, Dict[str, Any]]]):
         """Fold in another summary (a worker session's)."""
         for name, s in (summary or {}).items():
-            recent = self._fold(name, s["count"], s["total_s"], s["max_s"],
-                                s["first_start"], s["last_end"])
-            both = sorted([*recent, *map(tuple, s.get("recent", ()))])
+            mine = self._fold(name, s["count"], s["total_s"], s["max_s"],
+                              s["first_start"], s["last_end"])
+            recent = mine["recent"]
+            both = [*recent, *map(tuple, s.get("recent", ()))]
+            if "clock" in s:  # sorted with the spans they belong to
+                clock = mine.setdefault(
+                    "clock", collections.deque(maxlen=RECENT))
+                pairs = sorted(zip(both, [*clock, *map(tuple, s["clock"])]))
+                both = [p[0] for p in pairs]
+                clock.clear()
+                clock.extend(p[1] for p in pairs)
+            else:
+                both.sort()
             recent.clear()
             recent.extend(both)  # the deque keeps the newest RECENT
 
@@ -223,8 +300,9 @@ class _Collected:
     def summary(self) -> Dict[str, Dict[str, Any]]:
         """name -> ``count``, ``total_s``, ``max_s``, ``first_start``,
         ``last_end`` and ``recent``, a list of ``(start, end)``, oldest
-        first."""
-        return {name: dict(s, recent=list(s["recent"]))
+        first; a clocked name's ``clock`` beside it."""
+        return {name: {k: list(v) if isinstance(v, collections.deque) else v
+                       for k, v in s.items()}
                 for name, s in list(self._names.items())}
 
     def add_caused(self):
@@ -242,7 +320,7 @@ class _Collected:
 @contextlib.contextmanager
 def collect():
     """Summarise every span this thread closes inside the block (and
-    every ``gc.pause`` of the process: it stops this thread too)."""
+    every process-wide span of any thread: it is about this one too)."""
     got = _Collected()
     active = _local.__dict__.setdefault("collectors", [])
     active.append(got)
@@ -265,6 +343,8 @@ _JAX_SPANS = {
 _JAX_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
 _JAX_CACHE_MISS = "/jax/compilation_cache/cache_misses"
 GC_PAUSE_MIN_S = 1e-3  # a younger generation's collection is a span from here
+LAG_SLEEP_S = 10e-3  # the lag meter sleeps this long at a time
+LAG_MIN_S = 20e-3  # woken later than this, it records a ``host.lag``
 
 
 def _jax_open(event: str, start: float, fun_name: str = "", **_):
@@ -333,15 +413,14 @@ def _jax_event(event: str, **_):
 # Collections are serialised and both callbacks of one run on the
 # collecting thread, so one slot does.
 _gc_started: Tuple[Any, float] = (None, 0.0)
-# Pauses the store has not seen yet: (start, end, generation).
-_gc_pauses: collections.deque = collections.deque(maxlen=1024)
 
 
 def _gc_phase(phase: str, info: Dict[str, int]):
     """``gc.callbacks`` entry.  It may run at any allocation, inside code
-    that holds the runtime's locks: so it takes none.  A pause goes to
-    the collectors here and to the store with the next span this process
-    closes (``_emit``)."""
+    that holds the runtime's locks — ``record_span``'s own among them: so
+    it takes none.  A pause stopped every thread: it is a process-wide
+    span, stored with the next span this process closes (``_emit``'s
+    ``store_later``, the one user)."""
     global _gc_started
     if phase == "start":
         profiler = getattr(sys.modules.get("jax"), "profiler", None)
@@ -358,11 +437,36 @@ def _gc_phase(phase: str, info: Dict[str, int]):
         annotation.__exit__(None, None, None)
     if info["generation"] < 2 and end - start < GC_PAUSE_MIN_S:
         return
-    # The pause stopped every thread: every open collector takes it, the
-    # one place where a collector is not thread-local.
-    for got in list(_open_collectors):
-        got.add("gc.pause", start, end)
-    _gc_pauses.append((start, end, info["generation"]))
+    _emit("gc.pause", start, end, new_id(), None,
+          {"generation": info["generation"]}, process_wide=True,
+          store_later=True)
+
+
+def _lag_meter():
+    """The lag meter's thread: sleep ``LAG_SLEEP_S`` at a time and, woken
+    over ``LAG_MIN_S`` late, record the process-wide span ``host.lag``
+    from the moment the sleep should have ended to the moment this thread
+    ran again.  For that long NO Python thread of the process could be
+    scheduled and take the interpreter: the process was descheduled or
+    stopped, or a thread held the interpreter's lock through a call that
+    does not release it.  (Python threads that only take turns are no
+    lag: a waiting thread is handed the lock within
+    ``sys.getswitchinterval()``, 5 ms.)  An interval that is over cannot be
+    handed to the profiler, so under a trace the lag is a mark on
+    ``/host:CPU`` where it ENDS, its length in the stat ``lag_ms``."""
+    while True:
+        due = time.monotonic() + LAG_SLEEP_S
+        time.sleep(LAG_SLEEP_S)
+        late = time.monotonic() - due
+        if late <= LAG_MIN_S:
+            continue
+        end = time.time()
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            with profiler.TraceAnnotation("host.lag",
+                                          lag_ms=round(1e3 * late, 3)):
+                pass
+        record("host.lag", end - late, end, process_wide=True)
 
 
 def watch_process() -> None:
@@ -371,9 +475,10 @@ def watch_process() -> None:
     (``fun=``; only the outermost of its kind on a thread, and no trace
     that a lowering makes, so the totals add up), ``jax.cache_load``
     inside the ``jax.compile`` that read the persistent cache,
-    ``jax.cache_miss`` (no length) — and ``gc.pause`` (``generation=``)
-    for every collection of generation 2 and any that took over
-    ``GC_PAUSE_MIN_S``.  Idempotent.  For a process that has imported JAX
+    ``jax.cache_miss`` (no length) — and, process-wide, ``gc.pause``
+    (``generation=``) for every collection of generation 2 and any that
+    took over ``GC_PAUSE_MIN_S``, and ``host.lag`` (``_lag_meter``, one
+    daemon thread).  Idempotent.  For a process that has imported JAX
     already: a worker that was granted chips calls it as it opens them
     (``train/backend.py::bring_up``), any other process that builds train
     steps where ``train/core.py`` is imported; a driver that must stay
@@ -387,6 +492,8 @@ def watch_process() -> None:
     monitoring.register_event_duration_secs_listener(_jax_duration)
     monitoring.register_event_listener(_jax_event)
     gc.callbacks.append(_gc_phase)
+    threading.Thread(target=_lag_meter, daemon=True,
+                     name="ray_tpu-lag").start()
 
 
 # ------------------------------------------------------- head-side reads --

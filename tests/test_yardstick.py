@@ -12,7 +12,9 @@ wrote while they were out
 (``test_trinitys_window_entries_stand_as_they_were_with_this_cell_appended``,
 ``test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries``) stay
 beside them, as do the Kimi-Linear cell's (PR 59), the Solar-Open-2
-cell's (PR 64) and the Nemotron-3-Super cell's (PR 66), by name."""
+cell's (PR 64) and the Nemotron-3-Super cell's (PR 66), by name; and
+``test_late_steps.py``'s (PR 68: the readers of a window's lost time on
+canned spans), whole."""
 
 import pytest
 
@@ -21,7 +23,8 @@ pytest.register_assert_rewrite("benchmark.tests.test_trinity",
                                "benchmark.tests.test_flash_xla_ms",
                                "benchmark.tests.test_kimi_linear",
                                "benchmark.tests.test_solar_open2",
-                               "benchmark.tests.test_nemotron3")
+                               "benchmark.tests.test_nemotron3",
+                               "benchmark.tests.test_late_steps")
 
 from benchmark.tests.tier1_cases import *  # noqa: E402,F401,F403
 from benchmark.tests.test_trinity import (  # noqa: E402,F401
@@ -79,3 +82,13 @@ from benchmark.tests.test_nemotron3 import (  # noqa: E402,F401
     test_the_five_readers_on_synthetic_planes,
     test_the_parameter_count_is_init_params as
     test_nemotron3_parameter_count)
+from benchmark.tests.test_late_steps import (  # noqa: E402,F401
+    test_a_stall_is_split_into_stopped_running_and_waiting,
+    test_a_steady_window_reads_zero_everywhere,
+    test_a_stop_longer_than_the_interval_is_late_by_counts_for_no_more,
+    test_setup_lag_gives_no_number_where_lags_were_lost,
+    test_the_parents_spans_read_nothing as
+    test_late_steps_parents_spans_read_nothing,
+    test_the_seven_entries_in_benchmark_json,
+    test_the_three_parts_make_late_ms_to_the_float,
+    test_the_tool_prints_the_readers_numbers_and_a_row_a_late_interval)
