@@ -11,19 +11,19 @@ Design (standard memory-efficient attention, mapped to the TPU grid model):
   heads x d)``, the free reshape of the ``(b, s, h, d)`` the mixer holds: a
   head is the lane block ``(rows, d)`` at ``(b_, tile, head)``, 256 B a row
   of 128 bf16 lanes, rows ``heads x d`` elements apart; Mosaic's DMA hides
-  the stride (PERF.md §6, PR 54: the three kernels' sum within 1.4 % of
-  the same calls on contiguous heads, and under the parent's).  Else (a head of 64 lanes, a latent mixer's
+  the stride (PERF.md §6, PR 54: the kernels' sum within 1.4 % of the same
+  calls on contiguous heads, and under the parent's).  Else (a head of 64 lanes, a latent mixer's
   192 / 128, the tests' tiny heads) the operands are turned round to
   ``(b, heads, s, d)`` at the call's boundary.  XLA does NOT fuse those
   transposes into its neighbours: the traces read 16-22 ms a step of
   copies round the calls in the cells of 128-lane heads, which is why the
   first addressing exists.  The per-row stats are the kernels' own in both.
 - Grouped queries: k and v come with their own ``h_kv`` heads and nothing
-  repeats them.  ``flash_fwd`` and ``flash_dq`` read KV head ``h // rep``
-  by the index map; ``flash_dkv`` runs over the KV heads, its sequential
-  axis walking the ``rep`` q heads of the group and each head's q tiles,
-  so ``dk`` / ``dv`` are summed over the group in float32 in VMEM and
-  leave the kernel once a KV head.
+  repeats them.  ``flash_fwd`` reads KV head ``h // rep`` by the index
+  map; the backward kernel runs over the KV heads, its third axis walking
+  the ``rep`` q heads of the group and each head's q tiles, so ``dk`` /
+  ``dv`` are summed over the group in float32 in VMEM and leave the
+  kernel once a KV head.
 - Forward: grid ``(batch, heads, q_blocks, kv_blocks)``.  The last grid
   dimension is sequential on TPU, so softmax running stats ``(m, l)`` and the
   output accumulator live in VMEM scratch that persists across kv iterations;
@@ -31,15 +31,29 @@ Design (standard memory-efficient attention, mapped to the TPU grid model):
 - The forward writes its logsumexp lane-replicated ``(b, h, s, LANES)``:
   per-row stats of a ``(q rows, k columns)`` tile are COLUMNS, and a
   column rides in full vector registers (the layout jax's own TPU
-  flash-attention kernel uses for its ``l``/``m`` outputs).
-- Backward: two kernels (the classic split): one accumulates ``dk, dv`` with
-  grid ``(b, h_kv, kv_blocks, rep x q_blocks)``, one accumulates ``dq``
-  with grid ``(b, h, q_blocks, kv_blocks)``; both recompute ``p = exp(s -
-  lse)`` from the saved per-row logsumexp instead of materializing the S x
-  S matrix.
-  The dk/dv kernel keeps its scores TRANSPOSED, ``s^T = k q^T`` with the q
-  rows along the lanes, so its two gradient products are plain ones and
-  its per-row stats are rows ``(b, h, 1, sq)``, not lane-replicated.
+  flash-attention kernel uses for its ``l``/``m`` outputs).  ONE lane of
+  it, a float a row, is what the checkpoint keeps and the backward takes.
+- Backward: ONE kernel (named ``flash_dkv``: it is that kernel with a
+  third output), grid ``(b, h_kv, rep x q_blocks, kv_blocks)``.  For each
+  live sub-tile it recomputes ``p = exp2(s - lse)`` from the saved per-row
+  logsumexp instead of materializing the S x S matrix, makes ``dP`` and
+  ``ds = p (dP - delta)`` ONCE, and accumulates all three gradients from
+  them: ``dv += p^T do``, ``dk += ds^T q``, ``dq += ds k`` — five products
+  a pair where the classic split (a dk/dv kernel and a dq kernel, each
+  with its own scores and ``dP``) runs seven.  One grid cannot visit both
+  a q tile's and a kv tile's accumulator consecutively: ``dq`` is what
+  the LAST axis gathers (one q tile of float32 scratch, written out once
+  a q tile), and ``dk`` / ``dv`` stay in VMEM for a KV head's WHOLE
+  sequence, ``sk x (d + dv)`` float32 whatever the group's size, and are
+  written out once a KV head (``_check_resident`` is the one limit this
+  adds).
+  The kernel keeps its scores TRANSPOSED, ``s^T = k q^T`` with the q rows
+  along the lanes, so ``dv``'s and ``dk``'s products are plain ones, its
+  per-row stats are rows ``(b, h, 1, sq)`` — nothing is broadcast to 128
+  lanes for it — and ``dq``'s is the one product that contracts the
+  tile's FIRST dimension.  It gathers turned round, ``dq^T += k^T ds^T``
+  as ``(d, block_q)``, so what Mosaic turns is a strip of k and not the
+  tile, and is turned back once a q tile on its way out.
 - Causal schedule: a grid step FETCHES a large tile and the kernel walks
   it in COMPUTE sub-tiles, each dead (no code runs), interior (no mask) or
   on an edge (masked); grid steps whose whole tile is dead name the block
@@ -51,11 +65,11 @@ Design (standard memory-efficient attention, mapped to the TPU grid model):
   - j < w``) gives the schedule a second, FAR edge beside the diagonal: a
   sub-tile wholly older than the window is dead too, one that straddles
   the far edge takes a mask of its own, and each q tile has a first live
-  kv tile as it has a last one (each kv tile a last live q tile).  The
-  windowed calls are named ``flash_fwd_win`` / ``flash_dq_win`` /
-  ``flash_dkv_win``; without a window nothing of this is traced and the
-  kernels are what they were.  At s=8192, w=4096 they compute 1.06 times
-  the 25.17 M pairs the window leaves of the 33.56 M causal ones.
+  kv tile as it has a last one.  The windowed calls are named
+  ``flash_fwd_win`` / ``flash_dkv_win``; without a window nothing of this
+  is traced and the kernels are what they were.  At s=8192, w=4096 they
+  compute 1.06 times the 25.17 M pairs the window leaves of the 33.56 M
+  causal ones.
 - Accumulation is f32 regardless of input dtype (bf16 inputs hit the MXU).
 
 The reference framework has no counterpart (Ray core has no tensor ops —
@@ -94,9 +108,9 @@ _LN2 = 0.6931471805599453
 # contractions, and the sub-tile walk keeps what is computed above the
 # diagonal small whatever the tile.  Without a mask the tile stays what the
 # kernels always used.  What a large tile costs is CODE: one call over a
-# whole 2048 x 2048 tile is straight-line code, and the dk/dv kernel's four
+# whole 2048 x 2048 tile is straight-line code, and a dk/dv kernel's four
 # products at 192 / 128 ran at half speed until its interior tile became a
-# loop over strips (``_dkdv_kernel``).
+# loop over strips (``_bwd_kernel``, which has five).
 MAX_BLOCK = 2048                   # rows of a causal fetch tile, q and kv
 UNMASKED_BLOCK = (512, 1024)       # (q, kv) rows of a non-causal one
 _BLOCK_BYTES = 2 * 1024 * 1024     # one operand's block: bounds rows by d
@@ -108,14 +122,25 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _compiler_params(interpret):
+_VMEM_LIMIT = 100 * 1024 * 1024
+# What a KV head's whole-sequence dk / dv may take of it in the backward
+# kernel; the rest is the fetched blocks, dq and a strip's temporaries
+# (compiled for a v5e: 80 MiB fits at 128-wide bf16 and f32 heads, not at
+# 256-wide f32 ones, whose blocks are 2 MB each).
+_RESIDENT_BYTES = 64 * 1024 * 1024
+
+
+def _compiler_params(interpret, sequential=1):
     if interpret:
         return None
-    # First three grid dims are embarrassingly parallel; the innermost
-    # carries the running softmax state and must stay sequential.
+    # The leading grid dims are embarrassingly parallel; the last
+    # ``sequential`` carry state in VMEM scratch (the forward's running
+    # softmax over the kv tiles; the backward's dk / dv over a KV head's
+    # q tiles as well) and must stay in order.
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=100 * 1024 * 1024)
+        dimension_semantics=("parallel",) * (4 - sequential)
+        + ("arbitrary",) * sequential,
+        vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -184,21 +209,10 @@ def _last_live_k(i, block_q, block_k):
     return (i * block_q + block_q - 1) // block_k
 
 
-def _first_live_q(i, block_q, block_k):
-    """First q tile that holds a row seeing kv tile ``i``."""
-    return (i * block_k) // block_q
-
-
 def _first_live_k(i, block_q, block_k, window):
     """First kv tile that q tile ``i`` sees any column of: the one that
     holds the oldest key of its first row's window."""
     return jnp.maximum(i * block_q - (window - 1), 0) // block_k
-
-
-def _last_live_q(i, block_q, block_k, window):
-    """Last q tile that holds a row seeing kv tile ``i``: the one whose
-    window still reaches the tile's last column."""
-    return (i * block_k + block_k + window - 2) // block_q
 
 
 def causal_tile_counts(sq: int, sk: int, block_q: int, block_k: int,
@@ -403,10 +417,12 @@ def _dims(qt, kt, vt, heads):
 
 def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
     """``(nq, nk)`` tiles of the call and the BlockSpecs of the q-side and
-    kv-side operands for both grid orders: ``q_i, k_j, row_i`` for grids
-    ``(b, h, q, kv)`` and ``q_j, k_i, stat_j`` for ``(b, h_kv, kv, rep x
-    q)`` (``row``: per-row stats lane-replicated ``(b, h, sq, LANES)``;
-    ``stat``: the same stats as rows ``(b, h, 1, sq)``).
+    kv-side operands for both grids: ``q_i, k_j, row_i`` for the forward's
+    ``(b, h, q, kv)`` and ``q_t, k_t, stat_t, k_all`` for the backward's
+    ``(b, h_kv, rep x q, kv)`` (``row``: per-row stats lane-replicated
+    ``(b, h, sq, LANES)``, the forward's output; ``stat``: a float a row as
+    rows ``(b, h, 1, sq)``; ``all``: a KV head's whole sequence, the block
+    of ``dk`` / ``dv``, which no grid step of the head moves).
 
     TWO ADDRESSINGS of q, k, v, o and their gradients, one body a kernel
     (every leading dimension of a block is squeezed: a ref is ``(rows,
@@ -418,17 +434,18 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
     stats are the kernels' own in both.
 
     A KV head serves ``rep = h // h_kv`` q heads BY THE INDEX MAP: the
-    q-ordered grids read kv head ``h_ // rep``; the kv-ordered grid runs
-    over the KV heads, and its sequential axis ``t`` walks the group's q
-    heads and each head's q tiles (q head ``g x rep + t // nq``, q tile
-    ``t % nq``), so ``dk`` and ``dv`` leave the kernel once a KV head.  At
-    ``rep == 1`` the maps are what they were.
+    forward's grid reads kv head ``h_ // rep``; the backward's runs over
+    the KV heads, and its axis ``t`` walks the group's q heads and each
+    head's q tiles (q head ``g x rep + t // nq``, q tile ``t % nq``) while
+    the last axis walks that q tile's kv tiles, so ``dq`` leaves the
+    kernel once a ``t`` and ``dk`` and ``dv`` once a KV head.  At ``rep ==
+    1`` the two grids' q and kv maps are the same.
 
     q and k have one head size, v (and with it o and do: ``o_i``, ``v_j``,
-    ``v_i``, ``o_j``) may have another; where the two are equal the specs
-    are.  Under the mask a dead grid step names the block its nearest live
-    step holds, which Pallas does not copy again: past the diagonal and,
-    under a ``window``, before the far edge."""
+    ``o_t``, ``v_t``, ``v_all``) may have another; where the two are equal
+    the specs are.  Under the mask a dead grid step names the block its
+    nearest live step holds, which Pallas does not copy again: past the
+    diagonal and, under a ``window``, before the far edge."""
     block_q, block_k = tiles[:2]
     _, h, h_kv, sq, sk, d, dv = _dims(qt, kt, vt, heads)
     nq, nk = sq // block_q, sk // block_k
@@ -439,18 +456,11 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
             if window is None:
                 return j
             return jnp.maximum(j, _first_live_k(i, block_q, block_k, window))
-
-        def inner_q(i, j):
-            j = jnp.minimum(
-                jnp.maximum(j, _first_live_q(i, block_q, block_k)), nq - 1)
-            if window is None:
-                return j
-            return jnp.minimum(j, _last_live_q(i, block_q, block_k, window))
     else:
-        inner_k = inner_q = lambda i, j: j
+        inner_k = lambda i, j: j
 
-    # (head, tile) a grid step names, by operand and grid order: (h_, i, j)
-    # of the q-ordered grids, (g, i, t) of the kv-ordered one
+    # (head, tile) a grid step names, by operand and grid: (h_, i, j) of the
+    # forward's, (g, t, j) of the backward's
     if rep == 1:
         kv_head = lambda h_: h_
         walk = lambda g, t: (g, t)
@@ -459,10 +469,10 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
         walk = lambda g, t: (g * rep + t // nq, t % nq)
     outer = lambda h_, i, j: (h_, i)
     k_inner = lambda h_, i, j: (kv_head(h_), inner_k(i, j))
-
-    def q_inner(g, i, t):
-        head, j = walk(g, t)
-        return head, inner_q(i, j)
+    q_walk = lambda g, t, j: walk(g, t)
+    k_walk = lambda g, t, j: (g, inner_k(walk(g, t)[1], j))
+    whole = lambda g, t, j: (g, 0)
+    stat_at = lambda g, t, j: (walk(g, t)[0], 0, walk(g, t)[1])
 
     def spec(block, width, at):
         if heads is None:
@@ -473,21 +483,18 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
             (None, block, width),
             lambda b_, h_, i, j: (b_, *at(h_, i, j)[::-1]))
 
-    def stat_at(g, i, t):
-        head, tile = q_inner(g, i, t)
-        return head, 0, tile
-
     return (nq, nk), {
         "q_i": spec(block_q, d, outer), "o_i": spec(block_q, dv, outer),
         "k_j": spec(block_k, d, k_inner), "v_j": spec(block_k, dv, k_inner),
-        "k_i": spec(block_k, d, outer), "v_i": spec(block_k, dv, outer),
-        "q_j": spec(block_q, d, q_inner), "o_j": spec(block_q, dv, q_inner),
         "row_i": pl.BlockSpec(
             (None, None, block_q, _LANES),
             lambda b_, h_, i, j: (b_, h_, i, 0)),
-        "stat_j": pl.BlockSpec(
+        "q_t": spec(block_q, d, q_walk), "o_t": spec(block_q, dv, q_walk),
+        "k_t": spec(block_k, d, k_walk), "v_t": spec(block_k, dv, k_walk),
+        "k_all": spec(sk, d, whole), "v_all": spec(sk, dv, whole),
+        "stat_t": pl.BlockSpec(
             (None, None, 1, block_q),
-            lambda b_, h_, i, j: (b_, *stat_at(h_, i, j))),
+            lambda b_, g, t, j: (b_, *stat_at(g, t, j))),
     }
 
 
@@ -532,105 +539,129 @@ def _fwd_call(qt, kt, vt, causal, tiles, interpret, window=None, heads=None):
 
 # ---------------------------------------------------------------- backward
 
-def _p_and_ds(q, k, v, do, lse, delta, mask, transposed=False):
+def _p_and_ds(q, k, v, do, lse, delta, mask):
     """Recomputed probabilities and score gradients of one strip, both
-    f32: ``p = exp2(s - lse)``, ``ds = p * (dp - delta)``.  ``(sq, sk)``
-    from lane-replicated stats ``(sq, LANES)``; if ``transposed``
-    ``(sk, sq)`` from stats that are rows ``(1, sq)``."""
-    if transposed:
-        do, v = v, do
-    else:
-        lse, delta = lse[:, :1], delta[:, :1]
-    p = jnp.exp2(_scores(q, k, mask, transposed) - lse)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+    f32 and both TRANSPOSED, ``(sk, sq)``: ``p^T = exp2(s^T - lse)``,
+    ``ds^T = p^T * (dp^T - delta)``, from stats that are rows ``(1, sq)``."""
+    p = jnp.exp2(_scores(q, k, mask, transposed=True) - lse)
+    dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     return p, p * (dp - delta)
 
 
-def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_ref, dv_ref, dk_scr, dv_scr, *, causal, tiles, grid_qk,
-                 window=None, rep=1):
-    # The sequential axis walks the ``rep`` q heads of this KV head, each
-    # head's q tiles in turn: dk and dv gather the whole group's parts.
-    ki, t = pl.program_id(2), pl.program_id(3)
-    qi = t if rep == 1 else t % grid_qk[0]
-    sub_k = tiles[3]
+def _rows(ref, n, body):
+    """``body(rows)`` over ``ref``'s rows, ``n`` at a time, in a loop that
+    is not unrolled: a whole sequence's accumulator is too much
+    straight-line code for one statement."""
+    def step(i, carry):
+        body(pl.ds(pl.multiple_of(i * n, n), n))
+        return carry
 
-    @pl.when(t == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
+    jax.lax.fori_loop(0, ref.shape[0] // n, step, None)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, dq_scale,
+                causal, tiles, grid_qk, window=None, rep=1):
+    # Axis 2 walks the ``rep`` q heads of this KV head, each head's q tiles
+    # in turn, axis 3 a q tile's kv tiles: dq gathers over axis 3 and
+    # leaves once a q tile; dk and dv gather over BOTH, a whole sequence of
+    # float32 in VMEM, and leave once a KV head.
+    t, ki = pl.program_id(2), pl.program_id(3)
+    last_k = ki == pl.num_programs(3) - 1
+    qi = t if rep == 1 else t % grid_qk[0]
+    block_k, sub_k = tiles[1], tiles[3]
+
+    @pl.when((t == 0) & (ki == 0))
+    def _init_kv():
+        def zero(rows):
+            dk_scr[rows] = jnp.zeros((block_k, dk_scr.shape[1]), jnp.float32)
+            dv_scr[rows] = jnp.zeros((block_k, dv_scr.shape[1]), jnp.float32)
+
+        _rows(dk_scr, block_k, zero)
+
+    @pl.when(ki == 0)
+    def _init_q():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
     def update(qs, ks, mask):
         if ks.size > sub_k:
             # An interior tile comes whole.  Its strips run in a loop that
-            # is NOT unrolled: as one call the tile's four products are
+            # is NOT unrolled: as one call the tile's five products are
             # straight-line code, and past a size that code runs at half
-            # speed (q/k head 192: 21.1 ms a call for 10.4; PERF.md §6).
+            # speed (a dk/dv kernel's four at q/k head 192: 21.1 ms a call
+            # for 10.4; PERF.md §6, PR 35).
             def strip(i, carry):
                 update(qs, pl.ds(pl.multiple_of(ks.start + i * sub_k, sub_k),
                                  sub_k), mask)
                 return carry
 
             return jax.lax.fori_loop(0, ks.size // sub_k, strip, None)
-        q, do = q_ref[qs], do_ref[qs]
-        # Transposed, (sk, sq): p^T and ds^T are what the two gradient
-        # products take on the left, so no matrix is turned round.
-        p, ds = _p_and_ds(q, k_ref[ks], v_ref[ks], do, lse_ref[:, qs],
-                          delta_ref[:, qs], mask, transposed=True)
+        q, do, k = q_ref[qs], do_ref[qs], k_ref[ks]
+        # Transposed, (sk, sq): p^T and ds^T are what dv's and dk's
+        # products take on the left, so no tile is turned round.
+        p, ds = _p_and_ds(q, k, v_ref[ks], do, lse_ref[:, qs],
+                          delta_ref[:, qs], mask)
         # Grad matmuls in the INPUT dtype (bf16 on TPU): the MXU runs
         # bf16 natively; f32 operands would force multi-pass matmuls.
-        dv_scr[ks] += jnp.dot(p.astype(do.dtype), do,
-                              preferred_element_type=jnp.float32)  # (sk, dv)
-        dk_scr[ks] += jnp.dot(ds.astype(q.dtype), q,
-                              preferred_element_type=jnp.float32)  # (sk, d)
+        ds = ds.astype(q.dtype)
+        # this strip's rows of the whole-sequence accumulators
+        rows = pl.ds(pl.multiple_of(ki * block_k + ks.start, sub_k), ks.size)
+        dv_scr[rows] += jnp.dot(p.astype(do.dtype), do,
+                                preferred_element_type=jnp.float32)
+        dk_scr[rows] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        # dq = ds k contracts the tile's FIRST dimension.  It gathers
+        # turned round, dq^T = k^T ds^T (d, sq): the operand Mosaic turns is
+        # the strip of k, not the tile, and the product's lanes are the q
+        # rows, whole lane blocks whatever d (at d = 192 and 64 the plain
+        # form pays for 256 and 128: 5 and 9 % of a call; PERF.md §6, PR 69).
+        dq_scr[:, qs] += jax.lax.dot_general(
+            k, ds, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
                update, strips="k", window=window)
 
-    @pl.when(t == pl.num_programs(3) - 1)
-    def _finalize():
-        # q arrives pre-scaled by c = sm_scale*log2e; the true gradient
-        # is sm_scale * ds^T @ q_unscaled = ln2 * ds^T @ (q*c).
-        dk_ref[...] = (dk_scr[...] * _LN2).astype(dk_ref.dtype)
-        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+    @pl.when(last_k)
+    def _finalize_q():
+        # dL/dq as it came in = dq_scale * ds @ k, applied once here.
+        dq_ref[...] = (dq_scr[...].T * dq_scale).astype(dq_ref.dtype)
+
+    @pl.when((t == pl.num_programs(2) - 1) & last_k)
+    def _finalize_kv():
+        def leave(rows):
+            # q arrives pre-scaled by c = sm_scale*log2e; the true gradient
+            # is sm_scale * ds^T @ q_unscaled = ln2 * ds^T @ (q*c).
+            dk_ref[rows] = (dk_scr[rows] * _LN2).astype(dk_ref.dtype)
+            dv_ref[rows] = dv_scr[rows].astype(dv_ref.dtype)
+
+        _rows(dk_scr, block_k, leave)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *, sm_scale, causal, tiles, grid_qk,
-               window=None):
-    # sm_scale is applied once at finalize: dL/dq_orig = sm_scale * ds@k.
-    qi, ki = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    def update(qs, ks, mask):
-        k = k_ref[ks]
-        _, ds = _p_and_ds(q_ref[qs], k, v_ref[ks], do_ref[qs], lse_ref[qs],
-                          delta_ref[qs], mask)
-        dq_scr[qs] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
-               update, strips="q", window=window)
-
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        dq_ref[...] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
+def _check_resident(sk, d, dv, dtype):
+    """Refuse a backward pass whose KV head's dk and dv (float32 scratch
+    and the block they leave in, rows padded to whole lane blocks) do not
+    fit the kernel's VMEM: at 128-wide bf16 heads past 43690 keys."""
+    lanes = sum(-(-width // _LANES) * _LANES for width in (d, dv))
+    resident = sk * lanes * (4 + jnp.dtype(dtype).itemsize)
+    if resident > _RESIDENT_BYTES:
+        raise ValueError(
+            f"flash attention's backward keeps a KV head's whole dk and dv "
+            f"in VMEM: {sk} keys x ({d} + {dv}) take {resident} B of the "
+            f"{_RESIDENT_BYTES} B it may (_RESIDENT_BYTES); split the "
+            f"sequence over devices (ops.ring_attention) or call it a "
+            f"segment of keys at a time")
 
 
-def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret,
+def _bwd_call(qt, kt, vt, ot, lse, dot, dq_scale, causal, tiles, interpret,
               window=None, heads=None):
     """qt, kt, vt, ot, dot in either addressing (``_grid_and_specs``); lse
     (b, h, sq), a float a row.  Returns (dqt, dkt, dvt), each addressed as
     its operand: dkt and dvt at k's and v's OWN head count, summed over
-    each KV head's group of q heads inside ``flash_dkv``."""
-    b, h, h_kv, sq, _, d, dv = _dims(qt, kt, vt, heads)
-    block_q, block_k = tiles[:2]
+    each KV head's group of q heads inside the kernel."""
+    b, h, h_kv, sq, sk, d, dv = _dims(qt, kt, vt, heads)
+    _check_resident(sk, d, dv, kt.dtype)
+    block_q = tiles[0]
     if heads is None:
         delta = jnp.sum(ot.astype(jnp.float32) * dot.astype(jnp.float32),
                         axis=-1)                             # (b, h, sq)
@@ -639,43 +670,25 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, sm_scale, causal, tiles, interpret,
                          ).reshape(b, sq, h, dv), axis=-1).transpose(0, 2, 1)
     (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window,
                                       heads)
-    q_i, k_j, row_i = specs["q_i"], specs["k_j"], specs["row_i"]
-    q_j, k_i, stat_j = specs["q_j"], specs["k_i"], specs["stat_j"]
-    o_i, v_j, o_j, v_i = (specs[n] for n in ("o_i", "v_j", "o_j", "v_i"))
-
+    q_t, o_t, k_t, v_t, k_all, v_all, stat_t = (specs[n] for n in (
+        "q_t", "o_t", "k_t", "v_t", "k_all", "v_all", "stat_t"))
     rep = h // h_kv
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkdv_kernel, causal=causal, tiles=tiles,
-                          grid_qk=(nq, nk), window=window, rep=rep),
-        grid=(b, h_kv, nk, rep * nq),
-        in_specs=[q_j, k_i, v_i, o_j, stat_j, stat_j],
-        out_specs=[k_i, v_i],
-        out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
-                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, dv), jnp.float32)],
-        compiler_params=_compiler_params(interpret),
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, dq_scale=dq_scale, causal=causal,
+                          tiles=tiles, grid_qk=(nq, nk), window=window,
+                          rep=rep),
+        grid=(b, h_kv, rep * nq, nk),
+        in_specs=[q_t, k_t, v_t, o_t, stat_t, stat_t],
+        out_specs=[q_t, k_all, v_all],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (qt, kt, vt)],
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32),
+                        pltpu.VMEM((sk, d), jnp.float32),
+                        pltpu.VMEM((sk, dv), jnp.float32)],
+        compiler_params=_compiler_params(interpret, sequential=2),
         interpret=interpret,
         name=_kernel_name("flash_dkv", window),
     )(qt, kt, vt, dot, lse[:, :, None], delta[:, :, None])
-
-    # flash_dq walks "q" strips: its stats stay columns, lane-replicated.
-    lse, delta = (jnp.broadcast_to(x[..., None], (b, h, sq, _LANES))
-                  for x in (lse, delta))
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          tiles=tiles, grid_qk=(nq, nk),
-                          window=window),
-        grid=(b, h, nq, nk),
-        in_specs=[q_i, k_j, v_j, o_i, row_i, row_i],
-        out_specs=q_i,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-        name=_kernel_name("flash_dq", window),
-    )(qt, kt, vt, dot, lse, delta)
-    return dq, dk, dv
 
 
 # ----------------------------------------------------------------- public
@@ -750,7 +763,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret, window=None,
                                  window, prescaled)
     ot = checkpoint_name(ot, "flash_out")
     # One lane of the 128 the kernel writes: a float a row is what is
-    # worth holding; flash_dq gets the lanes back, flash_dkv takes rows.
+    # worth holding, and what the backward kernel takes (as rows).
     lse = checkpoint_name(lse[..., 0], "flash_lse")
     return _leave(ot, q.shape[2]), (*operands, ot, lse)
 
@@ -761,7 +774,7 @@ def _flash_bwd(sm_scale, causal, tiles, interpret, window, prescaled, res,
     in_place = qt.ndim == 3     # the residuals' own shapes say how they stand
     h = do.shape[2]
     h_kv = kt.shape[2] * h // qt.shape[2] if in_place else kt.shape[1]
-    # flash_dq ends in ``ds @ k`` times this: d scores / d q as it came in
+    # dq leaves as ``ds @ k`` times this: d scores / d q as it came in
     dq_scale = (sm_scale / q_prescale(sm_scale, qt.dtype) if prescaled
                 else sm_scale)
     dqt, dkt, dvt = _bwd_call(qt, kt, vt, ot, lse, _enter(do, in_place),

@@ -697,8 +697,9 @@ def op_names(xplane: bytes) -> Dict[str, Dict[str, str]]:
 # ``dtype[b,h,sq,d]..., dtype[b,h_kv,sk,d]``.  Where the model leaves them
 # (a head of whole lane blocks, ``ops/attention.py``): ``dtype[b,sq,h x
 # d]..., dtype[b,sk,h_kv x d]``, and the q heads' count is that of the
-# call's float32 stats, ``f32[b,h,sq,128]`` or ``f32[b,h,1,sq]`` (a result
-# of ``flash_fwd``, operands of the other two).
+# call's float32 stats: ``f32[b,h,sq,128]``, a result of ``flash_fwd``, or
+# ``f32[b,h,1,sq]``, operands of the one backward kernel (``flash_dkv``,
+# whose three results are dq, dk, dv).
 _FLASH_OPERANDS = re.compile(
     r"operand_layout_constraints=\{(\w+)\[\d+,\d+,(\d+),(\d+)\]\{[^}]*\}, "
     r"\w+\[\d+,\d+,(\d+),\d+\]")

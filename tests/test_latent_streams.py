@@ -122,7 +122,7 @@ def _qkv(seq, d, dv, heads=2, rows=1, dtype=jnp.float32):
 
 
 HEADS = [(192, 128), (128, 128), (64, 64), (24, 16), (16, 32)]
-# How a "k" strip of the dk/dv kernel can come: (seq, causal, tiles).
+# How a "k" strip of the backward kernel can come: (seq, causal, tiles).
 # "chosen" goes through ``flash_attention`` and ``choose_tiles``.
 STRIPS = {
     "chosen": None,
@@ -141,7 +141,7 @@ _FLASH_CASES = (
     [(d, dv, which, "chosen") for d, dv in HEADS
      for which in ("forward", "dq", "dk", "dv")]
     + [(d, dv, which, strips) for d, dv in HEADS for strips in STRIPS
-       if strips != "chosen" for which in ("dk", "dv")])
+       if strips != "chosen" for which in ("dq", "dk", "dv")])
 
 
 def _value_and_grads(fn, q, k, v):
@@ -183,9 +183,9 @@ def _flash_grads(d, dv, strips):
     ids=[f"{d}x{dv}-{which}-{strips}" for d, dv, which, strips in _FLASH_CASES])
 def test_flash_takes_a_v_head_apart_from_the_qk_head(d, dv, which, strips):
     """Value and gradients against ``mha_reference`` at head sizes equal
-    and apart, whole and fractions of a lane block; dk and dv (the kernel
-    whose scores are transposed and whose interior tile is a loop) on
-    every kind of part a "k" strip has."""
+    and apart, whole and fractions of a lane block; dq, dk and dv (ONE
+    kernel, whose scores are transposed and whose interior tile is a loop)
+    on every kind of part a "k" strip has."""
     got, want = _flash_grads(d, dv, strips)
     arg = ("forward", "dq", "dk", "dv").index(which)
     if which == "forward":
@@ -194,30 +194,41 @@ def test_flash_takes_a_v_head_apart_from_the_qk_head(d, dv, which, strips):
 
 
 def test_flash_dkv_is_one_loop_over_an_interior_tile():
-    """ONE body for every shape.  On a grid with an interior tile the
-    dk/dv kernel holds the one loop of the backward pass (the tile's
-    strips, not unrolled); on a one-tile grid the strips are static and
-    nothing loops.  Its per-row stats are rows ``(b, h, 1, sq)`` where
-    ``flash_dq`` takes lane-replicated columns."""
+    """ONE body for every shape, and ONE kernel for the three gradients.
+    On a grid with an interior tile it holds that tile's loop (the strips,
+    not unrolled) beside the two over its whole-sequence dk / dv (zeroed,
+    written out); on a one-tile grid the strips are static.  Its per-row
+    stats are rows ``(b, h, 1, sq)``: the forward's lane-replicated ``(b,
+    h, sq, 128)`` is an output of ``flash_fwd`` and an operand of nothing."""
     q, k, v = _qkv(256, 24, 16)
 
     def backward(tiles):
-        return str(jax.make_jaxpr(jax.grad(lambda q, k, v: attention._flash(
-            q, k, v, 1.0, True, tiles, True).sum(), (0, 1, 2)))(q, k, v))
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: attention._flash(
+            q, k, v, 1.0, True, tiles, True).sum(), (0, 1, 2)))(q, k, v)
+        calls = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        return str(jaxpr), calls
 
     loops = lambda text: text.count("scan[") + text.count("while[")
-    gridded = backward((128, 128, 32, 32))
-    assert loops(gridded) == 1 and loops(backward((256, 256, 32, 32))) == 0
-    assert "f32[1,2,1,256]" in gridded and "f32[1,2,256,128]" in gridded
+    gridded, calls = backward((128, 128, 32, 32))
+    assert loops(gridded) == 3 and loops(backward((256, 256, 32, 32))[0]) == 2
+    assert [e.params["name"] for e in calls] == ["flash_fwd", "flash_dkv"]
+    fwd, bwd = calls
+    assert (1, 2, 256, 128) in [v.aval.shape for v in fwd.outvars]
+    operands = [v.aval.shape for v in bwd.invars]
+    assert operands.count((1, 2, 1, 256)) == 2      # lse and delta
+    assert (1, 2, 256, 128) not in operands
+    assert [v.aval.shape for v in bwd.outvars] == [
+        (1, 2, 256, 24), (1, 2, 256, 24), (1, 2, 256, 16)]
 
 
 def test_equal_head_sizes_give_the_kernels_the_operands_they_had():
     """With v as wide as q and k, every v-side block is the k-side block
-    (and o's q's): the three calls are the calls they were."""
+    (and o's q's): the calls are what equal heads always gave them."""
     q = jnp.zeros((1, 2, 256, 128), jnp.bfloat16)
     _, specs = attention._grid_and_specs(q, q, q, True, (128, 128, 128, 128))
-    for ours, theirs in (("v_j", "k_j"), ("v_i", "k_i"), ("o_i", "q_i"),
-                         ("o_j", "q_j")):
+    for ours, theirs in (("v_j", "k_j"), ("v_t", "k_t"), ("o_i", "q_i"),
+                         ("o_t", "q_t"), ("v_all", "k_all")):
         assert specs[ours].block_shape == specs[theirs].block_shape
         for at in ((0, 1, 0, 1), (0, 0, 1, 0)):
             assert specs[ours].index_map(*at) == specs[theirs].index_map(*at)

@@ -491,10 +491,11 @@ def test_the_train_step_runs_both_kinds_of_kernel_and_reports():
     step = make_train_step(cfg, opt, donate=False)
     lowered = step.lower(state, {"tokens": TOKENS})   # traced once
     text = lowered.as_text(debug_info=True)
-    for name in ("flash_fwd_win", "flash_dq_win", "flash_dkv_win",
-                 "flash_fwd", "attn_qkv/rope/", "attn_out/", "moe_experts/",
+    for name in ("flash_fwd_win", "flash_dkv_win", "flash_fwd", "flash_dkv/",
+                 "attn_qkv/rope/", "attn_out/", "moe_experts/",
                  "moe_combine/"):
         assert name in text, name
+    assert "flash_dq" not in text   # ONE backward kernel, windowed or not
     # the rotary ops sit INSIDE attn_qkv: no name stack starts at ``rope``
     assert "/rope/" in text and "jit(step)/rope" not in text
     state, metrics = lowered.compile()(state, {"tokens": TOKENS})

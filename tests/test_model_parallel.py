@@ -332,9 +332,10 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
 
     traced = step.trace(state, batch)       # one trace for both readings
     jaxpr = str(traced.jaxpr)
-    for kernel in ("flash_fwd", "flash_dkv", "flash_dq") + (
+    for kernel in ("flash_fwd", "flash_dkv") + (
             ("moe_gmm", "moe_tgmm") if moe else ()):
         assert re.search(rf"\bname={kernel}\b", jaxpr), kernel
+    assert "flash_dq" not in jaxpr      # the backward is ONE kernel
 
     # As compiled (CPU): every matmul, the partitioner's collectives,
     # and the interpret-mode kernels' own dots under attention/<kernel>.

@@ -271,13 +271,15 @@ def test_without_a_live_window_the_call_is_the_plain_one_bit_for_bit(
     text = jax.jit(jax.grad(loss(windowed), (0, 1, 2))).lower(
         q, k, v).as_text(debug_info=True)
     assert "flash_fwd" in text and "flash_dkv" in text
+    assert "flash_dq" not in text   # ONE backward kernel writes all three
     assert not any(name + "_win" in text
-                   for name in ("flash_fwd", "flash_dq", "flash_dkv"))
+                   for name in ("flash_fwd", "flash_dkv"))
     live = jax.jit(jax.grad(loss(lambda *a: flash_attention(
         *a, causal=True, window=64)), (0, 1, 2))).lower(q, k, v).as_text(
             debug_info=True)
-    for name in ("flash_fwd_win", "flash_dq_win", "flash_dkv_win"):
+    for name in ("flash_fwd_win", "flash_dkv_win"):
         assert name in live
+    assert "flash_dq" not in live
     with pytest.raises(ValueError):
         flash_attention(q, k, v, causal=False, window=64)
     with pytest.raises(ValueError):
@@ -328,10 +330,11 @@ def test_tile_kinds_and_counts_under_a_window_against_a_brute_count(sq, sk):
 
 
 def test_the_windowed_grid_fetches_no_tile_beyond_either_edge():
-    """The kv blocks a q tile's grid steps name (and the q blocks a kv
-    tile's) are its live tiles and no other: a dead step names its nearest
-    live neighbour's block, which Pallas does not copy again.  At the
-    cell's shape: 8192 positions under 4096 in 2048 x 2048 tiles."""
+    """The kv blocks a q tile's grid steps name — the forward's and the
+    backward's, whose q tile is step ``t`` of a KV head — are its live
+    tiles and no other: a dead step names its nearest live neighbour's
+    block, which Pallas does not copy again.  At the cell's shape: 8192
+    positions under 4096 in 2048 x 2048 tiles."""
     from ray_tpu.ops.attention import _grid_and_specs
 
     for s, window, bq, bk in ((8192, 4096, 2048, 2048), (512, 100, 64, 128),
@@ -348,20 +351,20 @@ def test_the_windowed_grid_fetches_no_tile_beyond_either_edge():
                         for k in (j * bk, j * bk + bk - 1))
                  for j in range(nk)] for i in range(nq)]
         for i in range(nq):
-            named = {int(specs["k_j"].index_map(0, 0, i, j)[2])
-                     for j in range(nk)}
-            assert named == {j for j in range(nk) if live[i][j]}, (s, i)
-        for j in range(nk):
-            named = {int(specs["q_j"].index_map(0, 0, j, i)[2])
-                     for i in range(nq)}
-            assert named == {i for i in range(nq) if live[i][j]}, (s, j)
-            stats = {int(specs["stat_j"].index_map(0, 0, j, i)[3])
-                     for i in range(nq)}
-            assert stats == named
+            for name in ("k_j", "v_j", "k_t", "v_t"):
+                named = {int(specs[name].index_map(0, 0, i, j)[2])
+                         for j in range(nk)}
+                assert named == {j for j in range(nk) if live[i][j]}, (s, i)
+            # the q side of the backward stays put while the kv tiles pass
+            for name in ("q_t", "o_t", "stat_t"):
+                at = 3 if name == "stat_t" else 2
+                assert {int(specs[name].index_map(0, 0, i, j)[at])
+                        for j in range(nk)} == {i}
     # the plain grid names what it named
     (nq, nk), specs = _grid_and_specs(qt, qt, qt, True, (64, 64, 8, 8))
-    assert {int(specs["k_j"].index_map(0, 0, 1, j)[2])
-            for j in range(nk)} == {0, 1}
+    for name in ("k_j", "k_t"):
+        assert {int(specs[name].index_map(0, 0, 1, j)[2])
+                for j in range(nk)} == {0, 1}
 
 
 def test_causal_tile_counts_at_the_windowed_cells_shape():
@@ -390,8 +393,8 @@ def test_causal_tile_counts_at_the_windowed_cells_shape():
 # -- heads where the model leaves them, a KV head by the index map -----------
 
 # (rep, q/k head, v head, mode) on 128 positions in 64 x 64 tiles (a 2 x 2
-# grid with a dead tile under the mask: ``flash_dkv``'s composite axis of
-# ``rep x nq`` steps crosses both a head and a dead tile wherever rep > 1).
+# grid with a dead tile under the mask: the backward kernel's composite axis
+# of ``rep x nq`` steps crosses both a head and a dead tile wherever rep > 1).
 # Head sizes 128 and 256 fill whole lane blocks and are read IN PLACE out
 # of ``(b, s, heads x d)``; 64 and a latent mixer's 192 / 128 are turned
 # round to ``(b, heads, s, d)`` as always.  Every rep meets every head
@@ -413,15 +416,8 @@ def _heads_qkv(rep, d, dv, sq=128, sk=128, h_kv=2):
     return q, k, v[..., :dv]
 
 
-@pytest.mark.parametrize("rep,d,dv,mode", HEAD_CASES,
-                         ids=lambda x: str(x))
-def test_flash_reads_heads_where_they_stand(rep, d, dv, mode):
-    """Value AND the three gradients against ``mha_reference`` on k, v
-    repeated by hand: the kernels read KV head ``h // rep``, and dk, dv
-    come back at the KV heads' own count, each the sum over its group."""
-    q, k, v = _heads_qkv(rep, d, dv)
-    window = {"window": 40 if rep % 4 else 64}.get(mode)
-    kw = dict(causal=mode != "full", window=window)
+def _check_heads(rep, d, dv, sq, sk, kw):
+    q, k, v = _heads_qkv(rep, d, dv, sq, sk)
     flash = lambda q, k, v: flash_attention(q, k, v, block_q=64, block_k=64,
                                             **kw)
     ref = lambda q, k, v: mha_reference(
@@ -429,12 +425,70 @@ def test_flash_reads_heads_where_they_stand(rep, d, dv, mode):
     loss = lambda out: jnp.sum(jnp.sin(out))
     out, grads = _value_and_grads(flash, loss, q, k, v)
     want, ref_grads = _value_and_grads(ref, loss, q, k, v)
-    assert out.shape == (1, 128, 2 * rep, dv)
+    assert out.shape == (1, sq, 2 * rep, dv)
     assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
     assert jnp.max(jnp.abs(out - want)) < 1e-4
     for a, w in zip(grads, ref_grads):
         assert jnp.max(jnp.abs(a - w)) < 1e-4 * max(1.0, float(
             jnp.max(jnp.abs(w))))
+
+
+@pytest.mark.parametrize("rep,d,dv,mode", HEAD_CASES,
+                         ids=lambda x: str(x))
+def test_flash_reads_heads_where_they_stand(rep, d, dv, mode):
+    """Value AND the three gradients against ``mha_reference`` on k, v
+    repeated by hand: the kernels read KV head ``h // rep``, and dk, dv
+    come back at the KV heads' own count, each the sum over its group."""
+    window = {"window": 40 if rep % 4 else 64}.get(mode)
+    _check_heads(rep, d, dv, 128, 128,
+                 dict(causal=mode != "full", window=window))
+
+
+# (d, dv, sq, sk, causal, window) at rep 8 in 64 x 64 tiles: FOUR kv tiles,
+# so the one backward kernel comes back to a block of its whole-sequence
+# dk / dv from 8 heads x up to 4 q tiles, each visit after the kv axis has
+# passed over the other blocks; both addressings; a window wider than a
+# fetch tile and one narrower; sq != sk both ways; no mask.
+REVISIT_CASES = [
+    (128, 128, 256, 256, True, None), (64, 64, 256, 256, True, 100),
+    (128, 128, 256, 256, True, 40), (192, 128, 128, 256, True, None),
+    (64, 64, 256, 128, True, None), (128, 128, 128, 256, False, None)]
+
+
+@pytest.mark.parametrize("d,dv,sq,sk,causal,window", REVISIT_CASES,
+                         ids=lambda x: str(x))
+def test_the_backward_kernel_revisits_dk_and_dv_across_its_outer_axis(
+        d, dv, sq, sk, causal, window):
+    """dq, dk and dv of ONE kernel against the reference where a KV head's
+    gradient block is written to from many grid steps that are not
+    consecutive."""
+    _check_heads(8, d, dv, sq, sk, dict(causal=causal, window=window))
+
+
+def test_a_backward_whose_dk_and_dv_do_not_fit_vmem_is_refused_by_name():
+    """The one limit the single backward kernel adds: a KV head's whole dk
+    and dv stay in VMEM, so past 43690 keys of 128-wide bf16 heads the
+    GRADIENT is refused while it is traced, with the bound and the way out
+    in the message; the forward pass has no such limit, and Mellum2's
+    16384 keys are far inside it."""
+    from ray_tpu.ops import attention
+
+    def grad_shapes(s, dtype, d=128):
+        q = jax.ShapeDtypeStruct((1, s, 2, d), dtype)
+        k = jax.ShapeDtypeStruct((1, s, 1, d), dtype)
+        return jax.eval_shape(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v).astype(jnp.float32).sum(), (0, 1, 2)), q, k, k)
+
+    assert grad_shapes(40960, jnp.bfloat16)[1].shape == (1, 40960, 1, 128)
+    assert grad_shapes(16384, jnp.float32)[1].shape == (1, 16384, 1, 128)
+    for s, dtype, d in ((49152, jnp.bfloat16, 128), (40960, jnp.float32, 128),
+                        (32768, jnp.bfloat16, 192)):   # 192 pads to 256
+        with pytest.raises(ValueError, match="_RESIDENT_BYTES.*ring_attention"):
+            grad_shapes(s, dtype, d)
+    big = jax.ShapeDtypeStruct((1, 65536, 1, 128), jnp.bfloat16)
+    assert jax.eval_shape(lambda q: flash_attention(q, q, q), big).shape == (
+        1, 65536, 1, 128)
+    assert 4 * attention._RESIDENT_BYTES < 3 * attention._VMEM_LIMIT
 
 
 def _eqns_outside_kernels(jaxpr):
@@ -462,7 +516,7 @@ def test_round_the_in_place_calls_nothing_is_transposed_or_repeated(
     grads = jax.grad(lambda *a: flash_attention(
         *a, causal=False, block_q=64, block_k=64).sum(), (0, 1, 2))
     eqns = list(_eqns_outside_kernels(jax.make_jaxpr(grads)(q, k, v).jaxpr))
-    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 3
+    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 2
     turned = [e.outvars[0].aval.shape for e in eqns
               if e.primitive.name == "transpose"]
     if in_place:
@@ -477,11 +531,13 @@ def test_round_the_in_place_calls_nothing_is_transposed_or_repeated(
                 e.primitive.name, shape)
 
 
-def test_the_kv_ordered_grid_walks_a_groups_heads_and_their_tiles():
-    """``flash_dkv``'s maps at rep 6, 4 x 4 tiles under a window, in both
-    addressings: step ``t`` of KV head ``g`` names q head ``6 g + t // 4``
-    and the live q tile nearest ``t % 4``; k, v, dk, dv name head ``g``
-    whatever ``t``; the q-ordered grids name KV head ``h // 6``."""
+def test_the_backward_grid_walks_a_groups_heads_and_their_tiles():
+    """The backward kernel's maps at rep 6, 4 x 4 tiles under a window, in
+    both addressings: step ``t`` of KV head ``g`` names q head ``6 g + t //
+    4`` and q tile ``t % 4`` whatever the kv step ``j``; k and v name head
+    ``g`` and the live kv tile nearest ``j``; dk and dv name head ``g``'s
+    WHOLE sequence whatever ``t`` and ``j`` (they stay in VMEM and leave
+    once a KV head); the forward's grid names KV head ``h // 6``."""
     from ray_tpu.ops.attention import _grid_and_specs
 
     rep, h_kv, s, block, d = 6, 2, 512, 128, 128
@@ -499,19 +555,22 @@ def test_the_kv_ordered_grid_walks_a_groups_heads_and_their_tiles():
         assert (nq, nk) == (4, 4)
         at = lambda spec, *idx: tuple(int(x) for x in spec.index_map(*idx))
         for g in range(h_kv):
-            for i in range(nk):
-                for t in range(rep * nq):
-                    head = g * rep + t // nq
-                    tile = at(plain["q_j"], 0, 0, i, t % nq)[2]
-                    for name in ("q_j", "o_j"):
-                        assert at(old[name], 0, g, i, t) == (0, head, tile, 0)
-                        assert at(new[name], 0, g, i, t) == (0, tile, head)
+            for t in range(rep * nq):
+                head, i = g * rep + t // nq, t % nq
+                for j in range(nk):
+                    tile = at(plain["k_j"], 0, 0, i, j)[2]
+                    for name in ("q_t", "o_t"):
+                        assert at(old[name], 0, g, t, j) == (0, head, i, 0)
+                        assert at(new[name], 0, g, t, j) == (0, i, head)
                     for spec in (old, new):
-                        assert at(spec["stat_j"], 0, g, i, t) == (
-                            0, head, 0, tile)
-                    for name in ("k_i", "v_i"):
-                        assert at(old[name], 0, g, i, t) == (0, g, i, 0)
-                        assert at(new[name], 0, g, i, t) == (0, i, g)
+                        assert at(spec["stat_t"], 0, g, t, j) == (
+                            0, head, 0, i)
+                    for name in ("k_t", "v_t"):
+                        assert at(old[name], 0, g, t, j) == (0, g, tile, 0)
+                        assert at(new[name], 0, g, t, j) == (0, tile, g)
+                    for name in ("k_all", "v_all"):
+                        assert at(old[name], 0, g, t, j) == (0, g, 0, 0)
+                        assert at(new[name], 0, g, t, j) == (0, 0, g)
         for h_ in range(rep * h_kv):
             for i in range(nq):
                 for j in range(nk):
@@ -525,6 +584,14 @@ def test_the_kv_ordered_grid_walks_a_groups_heads_and_their_tiles():
                     assert at(new["row_i"], 0, h_, i, j) == (0, h_, i, 0)
         assert new["q_i"].block_shape == (None, block, d)
         assert old["q_i"].block_shape == (None, None, block, d)
+        assert new["k_all"].block_shape == (None, s, d)
+        assert old["k_all"].block_shape == (None, None, s, d)
+        # at one q head a KV head the two grids are one
+        for name, twin in (("q_i", "q_t"), ("k_j", "k_t"), ("o_i", "o_t")):
+            for i in range(nq):
+                for j in range(nk):
+                    assert at(plain[name], 0, 3, i, j) == at(
+                        plain[twin], 0, 3, i, j)
 
 
 def test_an_untileable_gqa_call_repeats_for_the_reference_alone():

@@ -84,6 +84,7 @@ def _counts(grad, args, cfg):
                if e.primitive.name == "pallas_call"]
     return {"flash_fwd": kernels.count("flash_fwd"),
             "flash_dkv": kernels.count("flash_dkv"),
+            "flash_dq": kernels.count("flash_dq"),
             "sorts": sum(e.primitive.name == "sort" for e in eqns),
             "row_gathers": sum(
                 e.primitive.name == "gather"
@@ -122,13 +123,13 @@ def test_backward_runs_no_second_flash_fwd_and_no_second_dispatch(
     moe = bool(cfg.num_experts)
     grad, args = _grad_fn(cfg, MESHES[mesh_name])
     kept = _counts(grad, args, cfg)
-    assert kept == {"flash_fwd": 1, "flash_dkv": 1, "sorts": 3 * moe,
-                    "row_gathers": 3 * moe}
+    assert kept == {"flash_fwd": 1, "flash_dkv": 1, "flash_dq": 0,
+                    "sorts": 3 * moe, "row_gathers": 3 * moe}
     # The same count sees the second copies under a bare checkpoint.
     monkeypatch.setattr(llama, "_checkpoint", jax.checkpoint)
     bare, _ = _grad_fn(cfg, MESHES[mesh_name])
     assert _counts(bare, args, cfg) == {
-        "flash_fwd": 2, "flash_dkv": 1, "sorts": 5 * moe,
+        "flash_fwd": 2, "flash_dkv": 1, "flash_dq": 0, "sorts": 5 * moe,
         "row_gathers": 3 * moe}
 
 

@@ -127,8 +127,9 @@ def _lowered_flash_grads(shape, dv, sharding):
 ], ids=lambda s: "x".join(map(str, s)) if isinstance(s, tuple) else f"v{s}")
 def test_flash_attention_compiles(one_chip, shape, dv, grad):
     """Sub-tile slices, the masked part's concatenation, the clamped
-    index maps, the dk/dv kernel's row stats, transposed mask and strip
-    loop are what Mosaic could refuse."""
+    index maps, the backward kernel's row stats, transposed mask, strip
+    loop, whole-sequence dk / dv and dq's product over the tile's FIRST
+    dimension are what Mosaic could refuse."""
     lowered = (_lowered_flash_grads(shape, dv, one_chip) if grad
                else jax.jit(_flash_sum).lower(
                    *_flash_shapes(shape, dv, one_chip)))
@@ -136,29 +137,40 @@ def test_flash_attention_compiles(one_chip, shape, dv, grad):
 
 
 def test_flash_backward_lowers_each_kernel_once(one_chip):
-    """A backward pass at Xing4's head sizes holds ONE dk/dv kernel (its
-    interior tile is a loop inside the kernel, not a second call), one dq
-    kernel, and the forward that makes the residuals."""
+    """A backward pass at Xing4's head sizes holds ONE backward kernel
+    (``flash_dkv``, which writes dq too; its interior tile is a loop
+    inside the kernel, not a second call), no ``flash_dq``, and the
+    forward that makes the residuals; of the forward's lane-replicated
+    ``(b, h, sq, 128)`` log-sum-exp one lane a row reaches the backward."""
     text = _lowered_flash_grads(*XING4_HEADS, one_chip).as_text()
     assert [text.count(f'kernel_name = "{name}"')
-            for name in ("flash_fwd", "flash_dkv", "flash_dq")] == [1, 1, 1]
+            for name in ("flash_fwd", "flash_dkv", "flash_dq")] == [1, 1, 0]
+    (call,) = [line for line in text.splitlines()
+               if 'kernel_name = "flash_dkv"' in line]
+    operands, results = call.rsplit(" : (", 1)[1].split(") -> (")
+    (b, s, h, _), _ = XING4_HEADS
+    assert operands.count(f"tensor<{b}x{h}x1x{s}xf32>") == 2    # lse, delta
+    assert operands.count("xf32>") == 2 and results.count("tensor<") == 3
 
 
-@pytest.mark.parametrize("b,h,h_kv,window", [
-    (1, 48, 8, None),    # trinity-train-s8192's full layer: groups of 6
-    (1, 48, 8, 4096),    # ... and its four windowed ones
-    (2, 32, 2, None),    # nemotronh-train-s8192: groups of 16
-    (4, 32, 8, None),    # mistral7b-train-s4096's heads, at 8192
-    (1, 32, 4, None),    # mellum2-train-s16384's full layer: groups of 8
-    (1, 32, 4, 1024),    # ... and its three windowed ones, at 8192
+@pytest.mark.parametrize("b,h,h_kv,window,s", [
+    (1, 48, 8, None, 8192),   # trinity-train-s8192's full layer: groups of 6
+    (1, 48, 8, 4096, 8192),   # ... and its four windowed ones
+    (2, 32, 2, None, 8192),   # nemotronh-train-s8192: groups of 16
+    (4, 32, 8, None, 8192),   # mistral7b-train-s4096's heads, at 8192
+    (1, 32, 4, None, 8192),   # mellum2-train-s16384's full layer at 8192
+    (1, 32, 4, 1024, 8192),   # ... and its three windowed ones, at 8192
+    # ... at its own length: 16.8 MB of float32 dk / dv a KV head in VMEM
+    (1, 32, 4, None, 16384),
 ], ids=lambda x: str(x))
-def test_flash_reads_lane_block_heads_in_place(one_chip, b, h, h_kv, window):
-    """At a head of 128 lanes the three kernels take q ``(b, s, h x 128)``
+def test_flash_reads_lane_block_heads_in_place(one_chip, b, h, h_kv, window,
+                                               s):
+    """At a head of 128 lanes the two kernels take q ``(b, s, h x 128)``
     and k, v ``(b, s, h_kv x 128)`` as the model leaves them: the lowered
     gradient holds each kernel once, nothing is transposed but ``delta``'s
     float a row, and no k, v, dk or dv stands at q's head count — Mosaic
     takes the strided blocks and the composite ``rep x nq`` axis."""
-    s, d = 8192, 128
+    d = 128
     q = _shape((b, s, h, d), jnp.bfloat16, one_chip)
     kv = _shape((b, s, h_kv, d), jnp.bfloat16, one_chip)
 
@@ -171,7 +183,7 @@ def test_flash_reads_lane_block_heads_in_place(one_chip, b, h, h_kv, window):
     text = lowered.as_text()
     names = [n + ("_win" if window else "")
              for n in ("flash_fwd", "flash_dkv", "flash_dq")]
-    assert [text.count(f'kernel_name = "{n}"') for n in names] == [1, 1, 1]
+    assert [text.count(f'kernel_name = "{n}"') for n in names] == [1, 1, 0]
     turned = [line for line in text.splitlines()
               if "stablehlo.transpose" in line]
     assert len(turned) == 1 and f"tensor<{b}x{s}x{h}xf32>" in turned[0]
@@ -183,7 +195,7 @@ def test_flash_reads_lane_block_heads_in_place(one_chip, b, h, h_kv, window):
     assert not [line for line in hlo.splitlines()
                 if " transpose(" in line and "bf16[" in line]
     calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
-    assert len(calls) == 3
+    assert len(calls) == 2
     for line in calls:      # q-side operands at h heads, kv-side at h_kv
         widths = {int(w) for w in re.findall(
             rf"bf16\[{b},{s},(\d+)\]", line)}
@@ -201,7 +213,7 @@ def test_windowed_flash_attention_compiles_under_its_own_names(
         one_chip, shape, window):
     """The far edge's masks (a segment with an upper bound, one with both),
     the index maps clamped from both sides and the second straddling branch
-    are what Mosaic could refuse; the three windowed kernels are named
+    are what Mosaic could refuse; the two windowed kernels are named
     apart from the plain ones."""
     grads = jax.grad(lambda q, k, v: flash_attention(
         q, k, v, causal=True, window=window, interpret=False).astype(
@@ -210,7 +222,7 @@ def test_windowed_flash_attention_compiles_under_its_own_names(
     text = lowered.as_text()
     assert [text.count(f'kernel_name = "{name}"') for name in (
         "flash_fwd_win", "flash_dkv_win", "flash_dq_win", "flash_fwd",
-        "flash_dkv", "flash_dq")] == [1, 1, 1, 0, 0, 0]
+        "flash_dkv", "flash_dq")] == [1, 1, 0, 0, 0, 0]
     assert _has_kernel(lowered.compile())
 
 
@@ -1029,7 +1041,7 @@ def test_rope_on_the_flat_arrays_leaves_no_copy_of_q_or_k(
     ``rope_bwd``: custom calls) and under ``rope`` stands no float32 array
     wider than the tables' ``(s, 128)`` (the XLA form's stood as wide as
     q: PERF.md §6, PR 58); between the projections and ``flash_fwd`` /
-    ``flash_dq`` / ``flash_dkv`` XLA copies neither q nor k (on the 4-D
+    ``flash_dkv`` XLA copies neither q nor k (on the 4-D
     view it laid RoPE's fusion out with the SEQUENCE on the lanes,
     ``{1,3,2,0}``, and copied both into the kernels' ``{2,1,0}`` every
     layer and pass: PERF.md §6, PR 54); and the flash kernels' pre-scale of
@@ -1046,8 +1058,8 @@ def test_rope_on_the_flat_arrays_leaves_no_copy_of_q_or_k(
         _state_shapes(cfg, opt, one_chip),
         {"tokens": _shape((rows, seq + 1), jnp.int32, one_chip)}
     ).compile().as_text()
-    assert all(name in text
-               for name in ("flash_fwd", "flash_dq", "flash_dkv"))
+    assert all(name in text for name in ("flash_fwd", "flash_dkv"))
+    assert "flash_dq" not in text
     for kernel, calls in (("rope_fwd", 4), ("rope_bwd", 2)):  # q's and k's
         assert len(re.findall(
             rf"custom-call\(.*/rope/jit\(_call\)/{kernel}/pallas_call\"",

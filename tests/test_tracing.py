@@ -1224,10 +1224,11 @@ def test_step_breakdown_on_a_synthetic_trace(tmp_path):
     assert scope_and_phase("", STEP_SCOPES) == (None, "forward")
 
 
-# The three calls as a compiled step holds them where the kernels read q,
+# The two calls as a compiled step holds them where the kernels read q,
 # k, v in the model's own (b, s, heads x d) — mistral7b-train-s4096's
 # shapes, 32 q heads over 8 KV heads of 128: the q heads' count stands in
-# the stats alone (a result of flash_fwd, operands of the other two).
+# the stats alone (a result of flash_fwd, lane-replicated; operands of the
+# ONE backward kernel, a float a row, whose results are dq, dk and dv).
 _IN_PLACE = ('operand_layout_constraints={bf16[4,4096,4096]{2,1,0}, '
              'bf16[4,4096,1024]{2,1,0}, bf16[4,4096,1024]{2,1,0}')
 _IN_PLACE_CALLS = {
@@ -1235,11 +1236,8 @@ _IN_PLACE_CALLS = {
                   "f32[4,32,4096,128]{3,2,1,0:T(8,128)}) custom-call(%a, %b, "
                   '%c), custom_call_target=\\"tpu_custom_call\\", '
                   + _IN_PLACE + "}"),
-    "flash_dq": ("%flash_dq.1 = bf16[4,4096,4096]{2,1,0:T(8,128)(2,1)} "
-                 'custom-call(%a), custom_call_target=\\"tpu_custom_call\\", '
-                 + _IN_PLACE + ", bf16[4,4096,4096]{2,1,0}, "
-                 "f32[4,32,4096,128]{3,2,1,0}, f32[4,32,4096,128]{3,2,1,0}}"),
-    "flash_dkv": ("%flash_dkv.1 = (bf16[4,4096,1024]{2,1,0:T(8,128)(2,1)}, "
+    "flash_dkv": ("%flash_dkv.1 = (bf16[4,4096,4096]{2,1,0:T(8,128)(2,1)}, "
+                  "bf16[4,4096,1024]{2,1,0:T(8,128)(2,1)}, "
                   "bf16[4,4096,1024]{2,1,0:T(8,128)(2,1)}) custom-call(%a), "
                   'custom_call_target=\\"tpu_custom_call\\", '
                   + _IN_PLACE + ", bf16[4,4096,4096]{2,1,0}, "
@@ -1274,7 +1272,7 @@ def test_executed_over_causal_reads_operands_where_the_model_leaves_them(
     # no stats to take the heads from, a dtype no kernel takes: nothing
     assert flash_executed_over_causal(_IN_PLACE + "}") is None
     assert flash_executed_over_causal(
-        _IN_PLACE_CALLS["flash_dq"].replace("bf16", "s8")) is None
+        _IN_PLACE_CALLS["flash_dkv"].replace("bf16", "s8")) is None
     ops = [("%lead = f32[] add()", "jit(step)/optimizer/add", 500, 100)] + [
         (text, (_FWD if name == "flash_fwd" else _BWD)
          + f"attention/{name}/pallas_call", 2000 + 100 * i, 100)
@@ -1299,16 +1297,16 @@ def test_step_breakdown_without_a_rematerialised_kernel(tmp_path):
          fwd + "attention/flash_fwd/pallas_call", 2000, 100),
         ("%copy.1 = f32[] copy()",
          bwd + "rematted_computation/attention/transpose", 2100, 30),
-        ("%flash_dq.1 = f32[] custom-call()" + kernel,
-         bwd + "attention/flash_dq/pallas_call", 2200, 120),
+        ("%flash_dkv.1 = f32[] custom-call()" + kernel,
+         bwd + "attention/flash_dkv/pallas_call", 2200, 120),
     ]
     path = tmp_path / "t.xplane.pb"
     path.write_bytes(_xspace(ops, modules=[(0, 1000), (2000, 1000)]))
     b = step_breakdown(str(path), "jit_step")
     ns = lambda s: round(s * 1e9)  # noqa: E731
     assert {k: ns(t) for k, t in b["kernels"].items()} == {
-        "flash_fwd": 100, "flash_dq": 120}
-    assert set(b["kernel_pairs"]) == {"flash_fwd", "flash_dq"}
+        "flash_fwd": 100, "flash_dkv": 120}
+    assert set(b["kernel_pairs"]) == {"flash_fwd", "flash_dkv"}
     assert {p: ns(t) for p, t in b["scopes"]["attention"].items()} == {
         "forward": 100, "remat": 30, "backward": 120}
     text = format_breakdown(b)

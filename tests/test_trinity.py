@@ -279,10 +279,11 @@ def test_the_train_step_runs_the_windowed_kernels_and_reports():
     step = make_train_step(cfg, opt, donate=False)
     lowered = step.lower(state, {"tokens": TOKENS})   # traced once
     text = lowered.as_text(debug_info=True)
-    for name in ("flash_fwd_win", "flash_dq_win", "flash_dkv_win",
-                 "flash_fwd", "attn_qkv/", "attn_out/", "moe_experts/",
-                 "moe_combine/", "ffn/"):
+    for name in ("flash_fwd_win", "flash_dkv_win", "flash_fwd", "flash_dkv/",
+                 "attn_qkv/", "attn_out/", "moe_experts/", "moe_combine/",
+                 "ffn/"):
         assert name in text, name
+    assert "flash_dq" not in text   # ONE backward kernel, windowed or not
     state, metrics = lowered.compile()(state, {"tokens": TOKENS})
     assert {"attn_window_executed_share", "moe_held_share", "moe_dropped",
             "moe_rows_visited_share", "moe_load_max_over_mean"
