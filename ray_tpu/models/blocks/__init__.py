@@ -5,7 +5,9 @@
 sub-block each.  A mixer or FFN is ONE module that ends in ONE
 ``base.Block`` (the softmax mixer is registered three times: ``attention``
 and ``full_attention`` see every earlier token, ``sliding_attention`` is the
-same module under the model's ``sliding_window``).  To add one: write the
+same module under the model's ``sliding_window``; ``indexed``, what a model
+with an ``sa_config`` runs for ``attention``, is its q, k, v and output under
+a learned selection of keys).  To add one: write the
 module, register its ``Block`` below
 under the name ``layer_types`` gives it, and list its scopes in the
 ``"scopes"`` of the benchmark configuration that uses it; its
@@ -23,6 +25,9 @@ MIXERS = {
     # ... the same mixer under the model's ``sliding_window``
     "sliding_attention": attention.SLIDING,
     "latent": attention.LATENT,
+    # softmax attention over the keys a learned indexer picks: what a model
+    # with an ``sa_config`` runs for ``attention``
+    "indexed": attention.INDEXED,
     "mamba": mamba.BLOCK,
     "linear_attention": delta.BLOCK,
     "kda": kda.BLOCK,      # ... its decay a vector over the key channels

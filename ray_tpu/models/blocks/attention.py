@@ -10,7 +10,13 @@ projections, a head's q and k are [no-position part | rotary part, the k's
 shared by all heads] and wider than its v; the flash kernels take the two
 head sizes).  The attention itself is pluggable (``cfg.attn_impl``): pallas
 flash (``ops/attention.py``), ring over 'sp', Ulysses all-to-all, or the
-XLA reference — all numerically interchangeable (tested).
+XLA reference — all numerically interchangeable (tested).  ``indexed`` is
+the softmax mixer of a model with an ``sa_config``: the same q, k and v, and
+beside them an INDEXER on the same normed input, detached, whose scores pick
+the ``topk`` keys a query reads (``ops/sparse_attention.py``: scopes
+``dsa_index``, ``dsa_select``, the softmax over the picked keys under
+``attention``, the indexer's own loss under ``dsa_loss``; ``INDEX_STATS``
+ride out of the scan).
 
 All open the scopes ``attn_qkv`` (norm, projections, RoPE), ``attention``
 and ``attn_out`` (``wo`` and the add), and the layer checkpoint keeps the
@@ -40,10 +46,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.blocks.base import (
-    Block, Ctx, Param, fold, ones, residual_out)
+    Block, Ctx, Param, constant, fold, ones, residual_out)
 from ray_tpu.models.blocks.residual import (
     add, block_in, norm_shapes, out_norm)
-from ray_tpu.ops import attention, rotary
+from ray_tpu.ops import attention, rotary, sparse_attention
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.layers import (
     apply_rope, repeat_kv_heads, rms_norm, scaled_rope, yarn_mscale)
@@ -61,6 +67,17 @@ SCOPES = ("attn_qkv", "attention", "attn_out")
 WINDOW_EXECUTED = "attn_window_executed_share"
 WINDOW_MASKED = "attn_window_masked_tile_share"
 WINDOW_STATS = {WINDOW_EXECUTED: "max", WINDOW_MASKED: "max"}
+# An indexed layer's: the indexer's own loss (the layers' mean joins the
+# step's), the (q, k) pairs its selection holds over the causal ones,
+# counted from the mask it made, and by how many pairs that count is off
+# what ``topk`` keys a query give (0: neither more nor fewer).
+INDEX_SCOPES = ("attn_qkv", "dsa_index", "dsa_select", "attention",
+                "dsa_loss", "attn_out")
+INDEX_LOSS = "idx_loss"
+SELECTED_SHARE = "dsa_selected_share"
+SELECTED_OFF = "dsa_selected_off"
+INDEX_STATS = {INDEX_LOSS: "mean", SELECTED_SHARE: "mean",
+               SELECTED_OFF: "sum"}
 
 
 def _attention_shapes(cfg):
@@ -287,17 +304,27 @@ def _attend(ctx: Ctx, x, aux, q, k, v, lp, residual: bool, gate=None,
             o = _attention_sp_manual(q, k, v, cfg)
         else:
             o = _attention(q, k, v, cfg, ctx.mesh, None, q_prescaled)
+    return _out(ctx, x, o, lp, residual, gate), aux
+
+
+def _out(ctx: Ctx, x, o, lp, residual: bool, gate):
+    """Scope ``attn_out``: the heads' outputs ``o (b, s, h, dv)`` side by
+    side, gated where the mixer has a gate, through ``wo`` onto the
+    stream."""
+    cfg = ctx.cfg
     with jax.named_scope("attn_out"):
         o = o.reshape(*x.shape[:2], -1)
         if gate is not None:
             o = (o.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(cfg.dtype)
         return add(ctx, x, o @ lp["wo"].astype(cfg.dtype), residual,
-                   out_norm(lp, "attn", cfg)), aux
+                   out_norm(lp, "attn", cfg))
 
 
-def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
-                     windowed: bool = False):
+def _qkv(ctx: Ctx, x, lp, windowed: bool):
+    """Scope ``attn_qkv`` of a softmax mixer: ``(q, k, v, the output gate
+    or None, whether q comes times the flash kernels' pre-scale, the normed
+    input)``."""
     cfg, cst = ctx.cfg, ctx.cst
     b, s = x.shape[0], x.shape[1]
     with jax.named_scope("attn_qkv"):
@@ -323,9 +350,118 @@ def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
             q, k = _rotated(ctx, windowed, q, k)
         q = cst(q, ("batch", "seq", "heads", "head_dim"))
         k = cst(k, ("batch", "seq", "kv_heads", "head_dim"))
+    return (q, k, v, gate,
+            rotate and flat and _q_prescale(cfg) is not None, h)
+
+
+def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
+                     windowed: bool = False):
+    q, k, v, gate, prescaled, _ = _qkv(ctx, x, lp, windowed)
     return _attend(ctx, x, aux, q, k, v, lp, residual, gate, windowed,
-                   q_prescaled=rotate and flat
-                   and _q_prescale(cfg) is not None)
+                   q_prescaled=prescaled)
+
+
+def _indexed_shapes(cfg):
+    """The softmax mixer's tensors and the indexer's: its queries' and its
+    one key's projections, the heads' weights, a LayerNorm over the key."""
+    d, heads, di = cfg.embed_dim, cfg.index_heads, cfg.index_dim
+    return {
+        **_attention_shapes(cfg),
+        "wq_idx": Param((d, heads * di), ("layer", "kernel_in", None)),
+        "wk_idx": Param((d, di), ("layer", "kernel_in", None)),
+        "w_idx": Param((d, heads), ("layer", "kernel_in", None)),
+        "k_idx_norm": Param((di,), ("layer", None), ones),
+        "k_idx_bias": Param((di,), ("layer", None), constant(0.0)),
+    }
+
+
+def _layer_norm(x, weight, bias, eps: float):
+    """LayerNorm over the last dimension, statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
+    return ((x32 - mean) * jax.lax.rsqrt(var + eps)
+            * weight.astype(jnp.float32) + bias.astype(jnp.float32)
+            ).astype(x.dtype)
+
+
+def _indexer(ctx: Ctx, h, lp):
+    """The indexer's operands from the DETACHED normed input ``h (b, s,
+    d)``: its heads' queries ``(b, s, H, di)`` and the one key ``(b, s,
+    di)`` (a LayerNorm over it), both rotated by the layer's rule over
+    their own ``di`` channels, and the heads' weights ``(b, s, H)`` float32
+    times ``H ** -0.5 di ** -0.5``."""
+    cfg = ctx.cfg
+    b, s = h.shape[:2]
+    heads, di = cfg.index_heads, cfg.index_dim
+    h = jax.lax.stop_gradient(h)
+    q = (h @ lp["wq_idx"].astype(cfg.dtype)).reshape(b, s, heads, di)
+    k = _layer_norm(h @ lp["wk_idx"].astype(cfg.dtype), lp["k_idx_norm"],
+                    lp["k_idx_bias"], cfg.norm_eps)[:, :, None, :]
+    if cfg.rotary(False):
+        with jax.named_scope("rope"):
+            cos, sin = _rope_tables(ctx, False, s, di)
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    w = (h @ lp["w_idx"].astype(cfg.dtype)).astype(jnp.float32) * (
+        heads ** -0.5 * di ** -0.5)
+    return q, k[:, :, 0, :], w
+
+
+def _selected_attention(cfg, prescaled: bool, q, k, v, q_idx, k_idx, w):
+    """What an indexed layer runs on one shard of the batch, each part
+    under its scope: the index scores (``dsa_index``), the selection
+    (``dsa_select``), the softmax over it (``attention``), the indexer's KL
+    a row (``dsa_loss``).  Returns ``(o (b, s, h, d), kl (b, s), live pairs
+    (b,))``."""
+    scale = _sm_scale(cfg)
+    with jax.named_scope("dsa_index"):
+        scores = sparse_attention.index_scores(
+            q_idx, k_idx, w, kernels=cfg.attn_impl == "flash")
+    with jax.named_scope("dsa_select"):
+        sel = sparse_attention.selection(scores, *sparse_attention.select(
+            scores, cfg.index_topk, kernels=cfg.attn_impl == "flash"))
+        live = sparse_attention.selected_pairs(sel)
+    with jax.named_scope("attention"):
+        o, lse2 = sparse_attention.attend(
+            q, k, v, sel, sm_scale=scale, flash=cfg.attn_impl == "flash",
+            q_prescaled=prescaled)
+    with jax.named_scope("dsa_loss"):
+        kl = sparse_attention.indexer_kl(
+            scores, sel, q, k, lse2, sm_scale=scale,
+            flash=cfg.attn_impl == "flash", q_prescaled=prescaled)
+    return o, kl, live
+
+
+def _indexed_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
+    """Softmax attention over the keys a learned indexer picks
+    (``ops/sparse_attention.py``): q, k, v as the softmax mixer makes them;
+    the indexer's operands from the same normed input, detached; then, per
+    shard of the batch under a mesh, scores, selection, attention and the
+    indexer's loss.  The loss and the selection's counters ride out of the
+    scan as ``INDEX_STATS``."""
+    cfg = ctx.cfg
+    if ctx.sp_manual:
+        raise NotImplementedError(
+            "a learned selection inside a region that is manual over 'sp'")
+    b, s = x.shape[:2]
+    q, k, v, gate, prescaled, h = _qkv(ctx, x, lp, False)
+    with jax.named_scope("dsa_index"):
+        q_idx, k_idx, w = _indexer(ctx, h, lp)
+    run = functools.partial(_selected_attention, cfg, prescaled)
+    if ctx.mesh is not None:
+        run = batch_shard_map(run, ctx.mesh, (4, 4, 4, 4, 3, 3), (4, 2, 1))
+    o, kl, live = run(q, k, v, q_idx, k_idx, w)
+    with jax.named_scope("dsa_loss"):
+        causal = b * (s * (s + 1) // 2)
+        topk = min(cfg.index_topk, s)
+        wanted = b * (topk * (topk + 1) // 2 + (s - topk) * topk)
+        live = jnp.sum(live)
+        aux = fold(aux, {
+            INDEX_LOSS: jnp.mean(kl),
+            SELECTED_SHARE: live.astype(jnp.float32) / causal,
+            SELECTED_OFF: jnp.abs(live - wanted).astype(jnp.float32),
+        }, INDEX_STATS)
+    return _out(ctx, x, o, lp, residual, gate), aux
 
 
 def _latent_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
@@ -380,3 +516,7 @@ SLIDING = Block(_attention_shapes,
                 stats=lambda cfg: WINDOW_STATS)
 LATENT = Block(_latent_shapes, _latent_mixer,
                saved=attention.SAVED_RESIDUALS, scopes=SCOPES)
+INDEXED = Block(_indexed_shapes, _indexed_mixer,
+                saved=(*attention.SAVED_RESIDUALS,
+                       *sparse_attention.SAVED_RESIDUALS),
+                scopes=INDEX_SCOPES, stats=lambda cfg: INDEX_STATS)

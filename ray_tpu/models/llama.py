@@ -2,8 +2,9 @@
 the DECODER: configuration, parameter tree, embedding, the scan over layers
 under the layer checkpoint, head, predicted-ahead module, loss, pipeline
 entry points.  What a layer is made of lives in ``models/blocks/``: a MIXER
-(``blocks.MIXERS``: softmax attention, over every earlier token or over a
-window of them | latent attention | a Mamba-2
+(``blocks.MIXERS``: softmax attention, over every earlier token, over a
+window of them or over the keys a learned indexer picks | latent attention |
+a Mamba-2
 state-space mixer | a gated delta-rule linear-attention mixer, its decay
 a number a head or a vector over the key channels | a gated short
 convolution) followed by an FFN (``blocks.FFNS``: dense, SwiGLU or
@@ -84,7 +85,8 @@ LAYER_PATTERN = {"M": ("mamba", "none"), "E": ("none", "moe"),
 # or with (True) a window reads; and the mixers that rotate at all.
 ROPE_BY_KIND = "rope_by_layer_type"
 ROPE_KINDS = {False: "full_attention", True: "sliding_attention"}
-ROTARY_MIXERS = ("attention", "full_attention", "sliding_attention", "latent")
+ROTARY_MIXERS = ("attention", "full_attention", "sliding_attention", "latent",
+                 "indexed")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -206,6 +208,17 @@ class LlamaConfig:
     gqa_layers: Tuple[int, ...] = ()
     kda_neg_eigval: bool = False      # beta in (0, 2): eigenvalues (-1, 1)
     sconv_width: int = 3              # taps of the gated short convolution
+    # Learned sparse attention, the public file's group: {"indexer_num_heads",
+    # "indexer_head_dim", "indexer_num_kv_heads" (1: the index heads share
+    # ONE key), "topk": the keys a query reads; "q_chunk_size" and
+    # "kv_chunk_size", a release kernel's tiles, change no value and are not
+    # read}.  With it every "attention" layer is the mixer ``indexed``: an
+    # indexer scores the causal pairs, the softmax runs over a query's
+    # ``topk`` highest alone, and the indexer's own loss (the KL from the
+    # heads' mean attention over the selection to the softmax of its scores
+    # there) enters the step's at ``idx_loss_coef``.
+    sa_config: Any = None
+    idx_loss_coef: float = 1.0
     # Where a block's RMSNorm sits: "input", x + f(norm(x)); "output",
     # x + norm(f(x)) with the same weight on what the block adds; or
     # "sandwich", x + post_norm(f(norm(x))): two norms a block.
@@ -242,6 +255,19 @@ class LlamaConfig:
             object.__setattr__(self, "linear_attn_config", tuple(sorted(
                 (k, tuple(v) if isinstance(v, list) else v)
                 for k, v in self.linear_attn_config.items())))
+        if isinstance(self.sa_config, dict):
+            object.__setattr__(self, "sa_config",
+                               tuple(sorted(self.sa_config.items())))
+        if self.sa_config and (
+                self.index_group.get("indexer_num_kv_heads", 1) != 1
+                or min(self.index_heads, self.index_dim, self.index_topk) < 1
+                or self.index_dim % 2 or self.kv_lora_rank
+                or self.hc_mult > 1):
+            raise NotImplementedError(
+                "sa_config: an indexer of indexer_num_heads x "
+                "indexer_head_dim (even) against ONE key head "
+                "(indexer_num_kv_heads 1) that picks topk keys a query, on "
+                f"softmax attention over one stream: {self.index_group}")
         if isinstance(self.rope_parameters, dict):
             object.__setattr__(self, "rope_parameters", tuple(sorted(
                 (kind, tuple(sorted(group.items())))
@@ -392,6 +418,24 @@ class LlamaConfig:
         return dict(self.linear_attn_config or ())
 
     @property
+    def index_group(self) -> Dict[str, Any]:
+        """``sa_config`` as a dict ({} of a model without one)."""
+        return dict(self.sa_config or ())
+
+    @property
+    def index_heads(self) -> int:
+        return self.index_group.get("indexer_num_heads", 0)
+
+    @property
+    def index_dim(self) -> int:
+        return self.index_group.get("indexer_head_dim", 0)
+
+    @property
+    def index_topk(self) -> int:
+        """The keys a query reads (0: a model without an indexer)."""
+        return self.index_group.get("topk", 0)
+
+    @property
     def kda_heads(self) -> int:
         return self.linear_group.get("num_heads", 0)
 
@@ -466,9 +510,10 @@ class LlamaConfig:
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         """(mixer, FFN) of every layer: the mixer ``layer_types`` names
         (latent attention for a model with a ``kv_lora_rank``, else
-        attention), ``linear_attn_config`` lists or ``gqa_layers`` leaves
-        to ``kda``, a dense FFN in the ``leading_dense`` first layers and
-        in a model without experts, the expert layer elsewhere; or, of a
+        attention; ``indexed`` for attention in a model with an
+        ``sa_config``), ``linear_attn_config`` lists or ``gqa_layers``
+        leaves to ``kda``, a dense FFN in the ``leading_dense`` first layers
+        and in a model without experts, the expert layer elsewhere; or, of a
         model with a ``layer_pattern``, the pair each character stands
         for (``LAYER_PATTERN``)."""
         if self.layer_pattern:
@@ -484,6 +529,9 @@ class LlamaConfig:
             kda = self.linear_group.get("kda_layers", ())
             mixers = tuple("kda" if i + 1 in kda else "latent"
                            for i in range(self.num_layers))
+        if self.sa_config:
+            mixers = tuple("indexed" if m in ("attention", "full_attention")
+                           else m for m in mixers)
         return tuple(
             (mixer, "moe" if self.num_experts and i >= self.leading_dense
              else "dense") for i, mixer in enumerate(mixers))
@@ -1044,6 +1092,8 @@ def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
         total = loss + cfg.aux_loss_coef * stats["aux_loss"]
         if "z_loss" in stats:
             total = total + cfg.z_loss_coef * stats["z_loss"]
+        if "idx_loss" in stats:     # the indexers' own, the layers' mean
+            total = total + cfg.idx_loss_coef * stats["idx_loss"]
         metrics = {"loss": loss, **stats}
         if ahead is not None:
             # position t's target is token t + 2: the last has none

@@ -368,7 +368,9 @@ def _tile_offset(causal, qi, ki, tiles, grid_qk):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, causal, tiles, grid_qk,
-                window=None):
+                window=None, sel_ref=None):
+    # ``sel_ref``: a mask that is DATA (``ops/sparse_attention.py``), the
+    # tile's ``(q rows, k columns)`` of it, nonzero where the pair is live
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -380,6 +382,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     def update(qs, ks, mask):
         s = _scores(q_ref[qs], k_ref[ks], mask)   # f32
+        if sel_ref is not None:
+            s = jnp.where(sel_ref[qs, ks] != 0, s, NEG_INF)
         v = v_ref[ks]
         m_prev = m_scr[qs]                           # (sq, LANES) replicated
         m_cur = jnp.max(s, axis=-1, keepdims=True)   # (sq, 1)
@@ -495,31 +499,55 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
         "stat_t": pl.BlockSpec(
             (None, None, 1, block_q),
             lambda b_, g, t, j: (b_, *stat_at(g, t, j))),
+        # a data mask ``(b, sq, sk)`` in the forward's grid, and turned
+        # round, ``(b, sk, sq)``, in the backward's
+        "sel_i": pl.BlockSpec(
+            (None, block_q, block_k),
+            lambda b_, h_, i, j: (b_, i, inner_k(i, j))),
+        "sel_t": pl.BlockSpec(
+            (None, block_k, block_q),
+            lambda b_, g, t, j: (b_, k_walk(g, t, j)[1], walk(g, t)[1])),
     }
 
 
-def _kernel_name(name: str, window) -> str:
-    """The windowed calls carry names of their own that START with the
-    plain ones, so what sums ``flash_*`` holds them and a reader can tell
-    them apart."""
+def _kernel_name(name: str, window, sel=None) -> str:
+    """The windowed calls, and those under a mask that is data, carry names
+    of their own that START with the plain ones, so what sums ``flash_*``
+    holds them and a reader can tell them apart."""
+    if sel is not None:
+        return name + "_dsa"
     return name if window is None else name + "_win"
 
 
-def _fwd_call(qt, kt, vt, causal, tiles, interpret, window=None, heads=None):
+def _with_sel(kernel, at: int):
+    """``kernel`` for a call whose operand ``at`` is a data mask: the ref
+    goes in by its name."""
+    def call(*refs, **kw):
+        return kernel(*refs[:at], *refs[at + 1:], sel_ref=refs[at], **kw)
+    return call
+
+
+def _fwd_call(qt, kt, vt, causal, tiles, interpret, window=None, heads=None,
+              sel=None):
     """qt, kt, vt in either addressing (``_grid_and_specs``); qt PRE-SCALED
     by sm_scale*log2e.  Returns (o_t, lse) with o_t addressed as qt, v's
     head size wide, and lse (b, h, sq, LANES) lane-replicated f32 in the
-    log2 domain."""
+    log2 domain.  ``sel (b, sq, sk)`` int8: a mask that is data, nonzero
+    where the pair is live (the call is then ``flash_fwd_dsa``)."""
     b, h, _, sq, _, _, dv = _dims(qt, kt, vt, heads)
     block_q = tiles[0]
     (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window,
                                       heads)
     o_shape = (b, h, sq, dv) if heads is None else (b, sq, h * dv)
+    kernel, masks = _fwd_kernel, ()
+    if sel is not None:
+        kernel, masks = _with_sel(_fwd_kernel, 3), (sel,)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal, tiles=tiles,
+        functools.partial(kernel, causal=causal, tiles=tiles,
                           grid_qk=(nq, nk), window=window),
         grid=(b, h, nq, nk),
-        in_specs=[specs["q_i"], specs["k_j"], specs["v_j"]],
+        in_specs=[specs["q_i"], specs["k_j"], specs["v_j"],
+                  *(specs["sel_i"] for _ in masks)],
         out_specs=[specs["o_i"], specs["row_i"]],
         out_shape=[
             jax.ShapeDtypeStruct(o_shape, qt.dtype),
@@ -532,18 +560,22 @@ def _fwd_call(qt, kt, vt, causal, tiles, interpret, window=None, heads=None):
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window),
-    )(qt, kt, vt)
+        name=_kernel_name("flash_fwd", window, sel),
+    )(qt, kt, vt, *masks)
     return o, lse
 
 
 # ---------------------------------------------------------------- backward
 
-def _p_and_ds(q, k, v, do, lse, delta, mask):
+def _p_and_ds(q, k, v, do, lse, delta, mask, sel=None):
     """Recomputed probabilities and score gradients of one strip, both
     f32 and both TRANSPOSED, ``(sk, sq)``: ``p^T = exp2(s^T - lse)``,
-    ``ds^T = p^T * (dp^T - delta)``, from stats that are rows ``(1, sq)``."""
-    p = jnp.exp2(_scores(q, k, mask, transposed=True) - lse)
+    ``ds^T = p^T * (dp^T - delta)``, from stats that are rows ``(1, sq)``.
+    ``sel``: the strip of a data mask, turned round as the scores are."""
+    s = _scores(q, k, mask, transposed=True)
+    if sel is not None:
+        s = jnp.where(sel != 0, s, NEG_INF)
+    p = jnp.exp2(s - lse)
     dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     return p, p * (dp - delta)
@@ -562,7 +594,7 @@ def _rows(ref, n, body):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, dq_scale,
-                causal, tiles, grid_qk, window=None, rep=1):
+                causal, tiles, grid_qk, window=None, rep=1, sel_ref=None):
     # Axis 2 walks the ``rep`` q heads of this KV head, each head's q tiles
     # in turn, axis 3 a q tile's kv tiles: dq gathers over axis 3 and
     # leaves once a q tile; dk and dv gather over BOTH, a whole sequence of
@@ -601,7 +633,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # Transposed, (sk, sq): p^T and ds^T are what dv's and dk's
         # products take on the left, so no tile is turned round.
         p, ds = _p_and_ds(q, k, v_ref[ks], do, lse_ref[:, qs],
-                          delta_ref[:, qs], mask)
+                          delta_ref[:, qs], mask,
+                          None if sel_ref is None else sel_ref[ks, qs])
         # Grad matmuls in the INPUT dtype (bf16 on TPU): the MXU runs
         # bf16 natively; f32 operands would force multi-pass matmuls.
         ds = ds.astype(q.dtype)
@@ -654,11 +687,12 @@ def _check_resident(sk, d, dv, dtype):
 
 
 def _bwd_call(qt, kt, vt, ot, lse, dot, dq_scale, causal, tiles, interpret,
-              window=None, heads=None):
+              window=None, heads=None, sel_t=None):
     """qt, kt, vt, ot, dot in either addressing (``_grid_and_specs``); lse
     (b, h, sq), a float a row.  Returns (dqt, dkt, dvt), each addressed as
     its operand: dkt and dvt at k's and v's OWN head count, summed over
-    each KV head's group of q heads inside the kernel."""
+    each KV head's group of q heads inside the kernel.  ``sel_t (b, sk,
+    sq)``: the forward's data mask turned round (``flash_dkv_dsa``)."""
     b, h, h_kv, sq, sk, d, dv = _dims(qt, kt, vt, heads)
     _check_resident(sk, d, dv, kt.dtype)
     block_q = tiles[0]
@@ -673,12 +707,16 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, dq_scale, causal, tiles, interpret,
     q_t, o_t, k_t, v_t, k_all, v_all, stat_t = (specs[n] for n in (
         "q_t", "o_t", "k_t", "v_t", "k_all", "v_all", "stat_t"))
     rep = h // h_kv
+    kernel, masks = _bwd_kernel, ()
+    if sel_t is not None:
+        kernel, masks = _with_sel(_bwd_kernel, 6), (sel_t,)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, dq_scale=dq_scale, causal=causal,
+        functools.partial(kernel, dq_scale=dq_scale, causal=causal,
                           tiles=tiles, grid_qk=(nq, nk), window=window,
                           rep=rep),
         grid=(b, h_kv, rep * nq, nk),
-        in_specs=[q_t, k_t, v_t, o_t, stat_t, stat_t],
+        in_specs=[q_t, k_t, v_t, o_t, stat_t, stat_t,
+                  *(specs["sel_t"] for _ in masks)],
         out_specs=[q_t, k_all, v_all],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (qt, kt, vt)],
@@ -687,8 +725,8 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, dq_scale, causal, tiles, interpret,
                         pltpu.VMEM((sk, dv), jnp.float32)],
         compiler_params=_compiler_params(interpret, sequential=2),
         interpret=interpret,
-        name=_kernel_name("flash_dkv", window),
-    )(qt, kt, vt, dot, lse[:, :, None], delta[:, :, None])
+        name=_kernel_name("flash_dkv", window, sel_t),
+    )(qt, kt, vt, dot, lse[:, :, None], delta[:, :, None], *masks)
 
 
 # ----------------------------------------------------------------- public

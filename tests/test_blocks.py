@@ -34,6 +34,9 @@ FIELDS = {
                               position_embedding="rope_windowed"),
     "latent": dict(q_lora_rank=24, kv_lora_rank=16, qk_nope_dim=16,
                    qk_rope_dim=8, v_head_dim=16),
+    # 16 keys a query of the 64
+    "indexed": dict(sa_config={"indexer_num_heads": 2, "indexer_head_dim": 8,
+                               "indexer_num_kv_heads": 1, "topk": 16}),
     "mamba": dict(ssm_heads=8, ssm_head_dim=16, ssm_state=8, ssm_groups=2,
                   ssm_chunk=8),
     "linear_attention": dict(gdn_heads=4, gdn_key_dim=8, gdn_value_dim=16),
@@ -156,11 +159,12 @@ def test_every_statistic_a_block_declares_is_a_metric(role, name):
 
 # -- (b) the step's scopes, as they were --------------------------------------
 
-def test_step_scopes_are_the_32_names_in_their_order():
+def test_step_scopes_are_the_35_names_in_their_order():
     assert STEP_SCOPES == (
         "embed", "attn_qkv", "attention", "attn_out", "ffn",
         "moe_route", "moe_exchange", "moe_dispatch", "moe_experts",
         "moe_combine", "moe_latent",
+        "dsa_index", "dsa_select", "dsa_loss",
         "ssm_in", "ssm_conv", "ssm_scan", "ssm_out",
         "gdn_in", "gdn_conv", "gdn_scan", "gdn_out",
         "kda_in", "kda_conv", "kda_scan", "kda_out",

@@ -1,0 +1,350 @@
+"""Keye-VL-2.0-30B-A3B's language model at CPU size, float32: softmax
+attention over the keys a learned indexer picks (``ops/sparse_attention.py``,
+the mixer ``indexed``) against the plain reference
+``benchmark/reference/keye_sparse.py`` — loss, the indexer's own loss, each
+token's loss and every gradient; which loss reaches which parameter; the
+selection's rule of ties and its exact size; a selection of everything
+against the dense mixer; the kernels against their XLA forms on several
+tiles; the eight shares of the expert layer; a mesh; the train step; the
+configuration file.  The tiny model is ``tests/tiny_models.py``'s row
+``keye``: 16 keys a query of 128."""
+
+import functools
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.loops import train
+from benchmark.reference import keye_sparse, mellum
+from ray_tpu.models.blocks import attention as attention_block
+from ray_tpu.models.llama import loss_fn
+from ray_tpu.ops import sparse_attention as sa
+from ray_tpu.ops.moe import moe_block
+from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+from ray_tpu.train.core import (
+    default_optimizer, init_train_state, make_train_step)
+
+import tiny_models
+from tiny_models import against_the_reference, program, side_of
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+NAME = "keye-vl-2.0-30b-a3b-1of8"
+ROW = tiny_models.ROWS["keye"]
+TOKENS = ROW.tokens
+tiny = functools.partial(tiny_models.tiny, "keye")
+HIGHEST = jax.default_matmul_precision("highest")
+INDEXER = ("wq_idx", "wk_idx", "w_idx", "k_idx_norm", "k_idx_bias")
+
+
+# -- (a) against the plain reference ------------------------------------------
+
+@pytest.mark.parametrize("impl", ["reference", "flash-under-the-checkpoint"])
+def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
+    """The XLA forms, and the kernels (``sparse_scores``, ``sparse_select``,
+    ``flash_*_dsa``; the KL in XLA at a head of 16) under the layer
+    checkpoint: total, next-token loss, the indexers' loss, each token's loss
+    and every gradient leaf; the selection holds exactly ``topk`` keys a
+    query, and the share the program counts is the reference's."""
+    kw = {} if impl == "reference" else dict(attn_impl="flash", remat=True)
+    _, got, want, _ = against_the_reference(
+        "keye", parts=("loss", "idx_loss"), **kw)
+    assert float(got["idx_loss"]) > 0.05        # a loss that is there
+    assert float(got["dsa_selected_off"]) == 0.0
+    np.testing.assert_allclose(got["dsa_selected_share"],
+                               want["dsa_selected_share"], rtol=1e-6)
+    s, k = 128, 16
+    assert float(got["dsa_selected_share"]) == pytest.approx(
+        (k * (k + 1) // 2 + (s - k) * k) / (s * (s + 1) // 2), rel=1e-6)
+
+
+def test_each_loss_reaches_its_own_parameters_and_no_other():
+    """The indexer's parameters have ZERO gradient from the next-token loss
+    (the selection passes none) and one from their own loss; the stream has
+    none from the indexers' loss: every other gradient is the same with the
+    loss in or out."""
+    side = program("keye")
+    (_, parts), with_idx = side.value_and_grad(side.params)
+    alone = side_of("keye", tiny(idx_loss_coef=0.0), side.params)
+    (total, _), without = alone.value_and_grad(side.params)
+    np.testing.assert_allclose(total, parts["loss"], rtol=1e-6)
+    for name, g in without["layers"].items():
+        if name in INDEXER:
+            assert float(jnp.max(jnp.abs(g))) == 0.0, name
+            assert float(jnp.max(jnp.abs(with_idx["layers"][name]))) > 1e-4, \
+                name
+        else:
+            np.testing.assert_allclose(g, with_idx["layers"][name],
+                                       atol=1e-7, err_msg=name)
+    for name in ("embed", "lm_head", "final_norm"):
+        np.testing.assert_allclose(without[name], with_idx[name], atol=1e-7)
+
+
+@pytest.mark.parametrize("change", [
+    "relu left out", "head weights left out", "topk halved",
+    "the key's norm left out", "the loss aimed at an un-detached target"])
+def test_a_changed_part_stands_apart_from_the_reference(change, monkeypatch):
+    """What the chip's check is asked to see (the configuration's
+    ``check.why``), in float32 where nothing hides it."""
+    want = tiny_models.reference("keye").parts
+    want_grads = tiny_models.reference("keye").grads
+    cfg = tiny()
+    if change == "relu left out":
+        monkeypatch.setattr(jax.nn, "relu", lambda x: x)
+    elif change == "head weights left out":
+        indexer = attention_block._indexer
+        monkeypatch.setattr(
+            attention_block, "_indexer",
+            lambda *a: (lambda q, k, w: (q, k, jnp.ones_like(w) / 32))(
+                *indexer(*a)))
+    elif change == "topk halved":
+        cfg = tiny(sa_config=tiny_models.Frozen(
+            tiny_models.KEYE_INDEXER, topk=8))
+    elif change == "the key's norm left out":
+        monkeypatch.setattr(attention_block, "_layer_norm",
+                            lambda x, w, b, eps: x)
+    else:
+        monkeypatch.setattr(sa.jax.lax, "stop_gradient", lambda x: x)
+    side = side_of("keye", cfg, program("keye").params)
+    if change.startswith("the loss aimed"):
+        # values stand; the model's gradients take the indexers' loss in
+        (_, _), grads = side.value_and_grad(side.params)
+        worst = tiny_models.apart(grads["layers"]["wq"],
+                                  want_grads["layers"]["wq"])
+        assert worst > 1e-3
+        return
+    total, parts = side.loss(side.params)
+    assert abs(float(parts["idx_loss"]) - float(want["idx_loss"])) > 2e-3 \
+        or abs(float(parts["loss"]) - float(want["loss"])) > 1e-4
+
+
+# -- (b) the selection --------------------------------------------------------
+
+def _scores(b=2, s=256, ties=True, seed=0):
+    x = jax.random.normal(jax.random.PRNGKey(seed), (b, s, s))
+    if ties:    # a quarter apart: dozens of equal scores a row, 0.0 and -0.0
+        x = jnp.round(x * 4) / 4
+    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+    return jnp.where(causal, x + 0.0, sa.NEG_INF), causal
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("ties", [True, False])
+def test_the_selection_is_top_ks_own_ties_to_the_lower_key(kernel, ties):
+    """Exactly ``topk`` keys a row (every causal key before), and among
+    equal scores at the threshold the LOWER keys: the set ``lax.top_k``
+    takes, which breaks ties so — by ``lax.top_k`` itself and by the kernel
+    that counts bits and sorts nothing."""
+    b, s, topk = 2, 256, 40
+    scores, causal = _scores(b, s, ties)
+    mask = sa.selection(scores, *sa.select(scores, topk, kernels=kernel))
+    _, keys = jax.lax.top_k(scores, topk)
+    want = jnp.zeros((b, s, s), bool).at[
+        jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
+        keys].set(True) & causal
+    np.testing.assert_array_equal(mask != 0, want)
+    rows = np.minimum(np.arange(s) + 1, topk)
+    np.testing.assert_array_equal(np.asarray(mask).sum(-1),
+                                  np.broadcast_to(rows, (b, s)))
+    if ties:    # the rule did bind: somewhere an equal score was left out
+        tau = jnp.take_along_axis(scores, keys[..., -1:], -1)
+        assert int(jnp.sum((scores == tau) & causal & (mask == 0))) > 0
+    np.testing.assert_array_equal(sa.selected_pairs(mask),
+                                  [rows.sum()] * b)
+
+
+def test_a_selection_of_everything_is_the_dense_mixer():
+    """``topk >= s``: nothing is selected away, and the model's next-token
+    loss and every token's are the plain softmax mixer's on the same
+    tensors (Mellum2's full layer at this model's sizes)."""
+    params = program("keye").params
+    everything = side_of("keye", tiny(sa_config=tiny_models.Frozen(
+        tiny_models.KEYE_INDEXER, topk=128)), params)
+    dense_params = dict(params, layers={
+        k: v for k, v in params["layers"].items() if k not in INDEXER})
+    dense = side_of("keye", tiny(sa_config=None), dense_params)
+    _, got = everything.loss(params)
+    _, want = dense.loss(dense_params)
+    np.testing.assert_allclose(got["loss"], want["loss"], rtol=2e-6)
+    np.testing.assert_allclose(everything.token_nll(params),
+                               dense.token_nll(dense_params), atol=3e-5)
+    assert float(got["dsa_selected_share"]) == 1.0
+    assert float(got["dsa_selected_off"]) == 0.0
+
+
+# -- (c) the kernels against their XLA forms, several tiles -------------------
+
+def _operands(b=1, s=1024, h=4, hk=2, d=128, heads=4, di=16, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    n = jax.random.normal
+    return (n(ks[0], (b, s, h, d)), n(ks[1], (b, s, hk, d)),
+            n(ks[2], (b, s, hk, d)), n(ks[3], (b, s, heads, di)),
+            n(ks[4], (b, s, di)), n(ks[5], (b, s, heads)))
+
+
+def test_the_kernels_are_their_xla_forms_over_several_tiles():
+    """1024 tokens: 2 x 2 tiles of the index kernels, 4 x 2 of the loss's,
+    32 blocks of the selection's; heads of 128 lanes read in place, a group
+    of two.  Values and every gradient."""
+    operands = _operands()
+    topk = 96
+
+    def run(kernels):
+        def f(q, k, v, q_idx, k_idx, w):
+            scores = sa.index_scores(q_idx, k_idx, w, kernels=kernels)
+            sel = sa.selection(scores, *sa.select(scores, topk,
+                                                  kernels=kernels))
+            o, lse2 = sa.attend(q, k, v, sel, sm_scale=128 ** -0.5,
+                                flash=kernels)
+            kl = sa.indexer_kl(scores, sel, q, k, lse2,
+                               sm_scale=128 ** -0.5, flash=kernels)
+            return 0.01 * jnp.sum(o * o) + jnp.mean(kl), (
+                jnp.mean(kl), sa.selected_pairs(sel))
+        return jax.jit(jax.value_and_grad(
+            f, argnums=tuple(range(6)), has_aux=True))(*operands)
+
+    with HIGHEST:
+        (want, (want_kl, want_pairs)), want_grads = run(False)
+        (got, (got_kl, got_pairs)), got_grads = run(True)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    np.testing.assert_allclose(got_kl, want_kl, rtol=1e-5)
+    np.testing.assert_array_equal(got_pairs, want_pairs)
+    for a, b in zip(got_grads, want_grads):
+        assert float(jnp.max(jnp.abs(a - b))) <= 2e-5 * float(
+            jnp.max(jnp.abs(b)))
+
+
+# -- (d) the shares -----------------------------------------------------------
+
+def _expert_layer(seed=5, tokens=96, d=32, m=16, experts=64):
+    rng = np.random.default_rng(seed)
+    n = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa
+    return {"x": n(tokens, d), "mlp_norm": 1.0 + 0.1 * n(d),
+            "router": n(d, experts) * d ** -0.5,
+            "w_gate": n(experts, d, m) * d ** -0.5,
+            "w_up": n(experts, d, m) * d ** -0.5,
+            "w_down": n(experts, m, d) * m ** -0.5}
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _share(p, first, held):
+    return moe_block(
+        p["x"], p["mlp_norm"], p["router"], *(
+            jax.lax.dynamic_slice_in_dim(p[w], first, held)
+            for w in ("w_gate", "w_up", "w_down")),
+        num_selected=8, norm_eps=1e-6, norm_topk_prob=True,
+        scoring="softmax", first_expert=first, residual=False)
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """8 chips with 8 of 64 experts each (the file's 8 chips a layer), no
+    shared expert: their parts, summed, are the whole layer as the reference
+    has it.  Attention and the indexer are whole on every chip (data
+    parallel): counted ONCE, they are the reference's own mixer, which test
+    (a) holds; nothing of them is divided."""
+    p = _expert_layer()
+    parts = [_share(p, first, 8) for first in range(0, 64, 8)]
+    h = mellum.rms_norm(p["x"], p["mlp_norm"], 1e-6)
+    whole, chosen, balance = keye_sparse.expert_ffn(
+        h[None], p, k=8, renormalise=True, first=0)
+    np.testing.assert_allclose(sum(part for part, _ in parts), whole[0],
+                               atol=2e-5)
+    stats = [s for _, s in parts]
+    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
+    assert all(float(s["dropped"]) == 0.0 for s in stats)
+    for s in stats:     # every share routes over all 64
+        np.testing.assert_array_equal(
+            s["counts"], np.bincount(np.asarray(chosen).ravel(),
+                                     minlength=64))
+    alone, _, _ = keye_sparse.expert_ffn(
+        h[None], {**p, **{w: p[w][16:24] for w in ("w_gate", "w_up",
+                                                   "w_down")}},
+        k=8, renormalise=True, first=16)
+    np.testing.assert_allclose(parts[2][0], alone[0], atol=2e-5)
+
+
+# -- (e) a mesh, the train step, the configuration file -----------------------
+
+@pytest.mark.parametrize("impl", ["reference", "flash"])
+def test_on_a_mesh_the_model_is_one_devices(impl):
+    """fsdp=2 x tp=2: scores, selection, attention and the indexer's loss
+    run per shard of the batch (whole over ``tp``), in XLA and by the
+    kernels (interpreted) inside the manual region; the loss, the indexers'
+    and the counters equal one device's."""
+    cfg = tiny(attn_impl=impl)
+    params = program("keye").params
+    mesh = make_mesh(MeshConfig(fsdp=2, tp=2), devices=jax.devices()[:4])
+    with HIGHEST:
+        want, want_m = jax.jit(lambda p: loss_fn(
+            p, {"tokens": TOKENS}, cfg))(params)
+        got, got_m = jax.jit(lambda p: loss_fn(
+            p, {"tokens": TOKENS}, cfg, mesh=mesh))(params)
+    assert abs(float(got) - float(want)) <= 1e-5 * float(want)
+    np.testing.assert_allclose(got_m["idx_loss"], want_m["idx_loss"],
+                               rtol=1e-5)
+    assert float(got_m["dsa_selected_off"]) == 0.0
+    np.testing.assert_allclose(got_m["dsa_selected_share"],
+                               want_m["dsa_selected_share"], rtol=1e-6)
+
+
+def test_the_train_step_runs_the_kernels_under_their_scopes_and_learns():
+    cfg = tiny(attn_impl="flash", remat=True, num_layers=2)
+    opt = default_optimizer()
+    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
+    step = make_train_step(cfg, opt, donate=False)
+    lowered = step.lower(state, {"tokens": TOKENS})
+    text = lowered.as_text(debug_info=True)
+    for name in ("dsa_index/", "sparse_scores", "sparse_scores_bwd",
+                 "dsa_select/", "sparse_select", "attention/",
+                 "flash_fwd_dsa", "flash_dkv_dsa", "dsa_loss/", "attn_out/",
+                 "moe_experts/"):
+        assert name in text, name
+    # under the checkpoint the backward pass selects nothing again and
+    # runs no second forward kernel: the selection's two numbers a row
+    # and the kernel's output are kept
+    again = [n for n in re.findall(r'loc\("([^"]*)"', text)
+             if "rematted_computation" in n]
+    assert any("sparse_scores" in n for n in again)     # remade, as q and k
+    assert not any("sparse_select" in n or "flash_fwd_dsa" in n
+                   for n in again)
+    compiled = lowered.compile()
+    losses = []
+    for _ in range(3):
+        state, metrics = compiled(state, {"tokens": TOKENS})
+        losses.append(float(metrics["loss"]))
+    assert losses[2] < losses[0]
+    assert set(keye_sparse.STEP_METRICS) <= set(metrics)
+    assert float(metrics["moe_dropped"]) == 0.0
+    assert float(metrics["dsa_selected_off"]) == 0.0
+    assert np.isfinite(float(metrics["idx_loss"]))
+
+
+def test_the_files_fields_reach_the_program_and_its_traffic_stays_in_the_slice():
+    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
+        conf = json.load(f)
+    cfg = train.program_config(conf)
+    assert (cfg.vocab_size, cfg.num_experts, cfg.experts_held,
+            cfg.first_expert, cfg.leading_dense, cfg.num_layers) == (
+                18992, 128, 16, 0, 0, 8)
+    assert (cfg.embed_dim, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+            cfg.mlp_dim, cfg.num_selected, cfg.norm_topk_prob,
+            cfg.router_scoring, cfg.shared_experts, cfg.select_bias,
+            cfg.tie_embeddings, cfg.norm_eps, cfg.rope_theta) == (
+                2048, 32, 4, 128, 768, 8, True, "softmax", 0, False, False,
+                1e-6, 10000000)
+    assert (cfg.index_heads, cfg.index_dim, cfg.index_topk,
+            cfg.idx_loss_coef, cfg.qk_head_norm, cfg.qk_norm,
+            cfg.aux_loss_coef, cfg.sliding_window) == (
+                16, 64, 2048, 1.0, True, False, 0.0, 0)
+    assert cfg.kind_runs == ((("indexed", "moe"), 8),)
+    kw = keye_sparse.layer_kwargs(conf)
+    assert (kw["heads"], kw["kv_heads"], kw["index_heads"], kw["topk"],
+            kw["k"], kw["first"]) == (32, 4, 16, 2048, 8, 0)
+    drawn = train.draw_tokens(np.random.default_rng([2**31 + 5, 0]), cfg, 1,
+                              16384)
+    assert drawn.shape == (1, 16385) and drawn.dtype == np.int32
+    assert 0 <= drawn.min() and 18000 < drawn.max() < 18992

@@ -385,6 +385,8 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
     want -= {"sconv_in", "sconv_gate", "sconv_out"}
     # no latent round the routed experts (tests/test_nemotron3.py has one)
     want -= {"moe_latent"}
+    # no indexer that picks a query's keys (tests/test_keye.py has one)
+    want -= {"dsa_index", "dsa_select", "dsa_loss"}
     want -= {"loss"} if mesh is None or moe else set()  # tp splits it
     # the embedding takes the rows its tokens name: a gather, never a
     # matmul.  On one device the scope shows nothing here; under a mesh

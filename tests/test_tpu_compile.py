@@ -13,6 +13,7 @@ loads the TPU library (one process at a time may), and it compiles in
 its own process.
 """
 
+import dataclasses
 import functools
 import math
 import os
@@ -876,6 +877,40 @@ def test_the_solar_open2_cells_step_program_fits_the_chip(one_chip,
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == pytest.approx(8.526e9, rel=1e-3)
     assert 0.25 * 16.909e9 < mem.peak_memory_in_bytes < 16.909e9
+
+
+def test_the_selection_kernels_compile_at_the_published_widths(one_chip,
+                                                               as_on_chip):
+    """What an ``indexed`` layer of ``keyevl2-train-s16384`` runs between its
+    projections (``blocks/attention.py::_selected_attention``: 32 / 4 heads
+    x 128, a 16 x 64 indexer against one key), forward and backward, at
+    4096 tokens and 512 keys a query so that the compile stays short: Mosaic
+    takes every kernel of the selection — the index scores and their
+    gradient, the bit-counting top-k, the flash kernels under an int8 mask
+    tile and its transpose, the indexer's KL.  The WHOLE step at 1 x 16384
+    (a minute and a half to compile: 5.12 + 10.47 GB, peak 12.57 of the
+    chip's 16.91, PR 70) is ``benchmark/rehearse_compile.py``'s by hand."""
+    from ray_tpu.models.blocks import attention as block
+
+    cfg = dataclasses.replace(
+        _benchmark_cfg("keye-vl-2.0-30b-a3b-1of8"), sa_config={
+            "indexer_num_heads": 16, "indexer_head_dim": 64,
+            "indexer_num_kv_heads": 1, "topk": 512})
+    s, bf = 4096, jnp.bfloat16
+    shapes = [(1, s, 32, 128), (1, s, 4, 128), (1, s, 4, 128),
+              (1, s, 16, 64), (1, s, 64), (1, s, 16)]
+    operands = [_shape(shape, jnp.float32 if i == 5 else bf, one_chip)
+                for i, shape in enumerate(shapes)]
+
+    def loss(*args):
+        o, kl, live = block._selected_attention(cfg, False, *args)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(kl)
+
+    hlo = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *operands).compile().as_text()
+    for kernel in ("sparse_scores", "sparse_scores_bwd", "sparse_select",
+                   "flash_fwd_dsa", "flash_dkv_dsa", "sparse_loss"):
+        assert kernel in hlo, kernel
 
 
 @pytest.mark.parametrize("shape,biased,tokens_last", [

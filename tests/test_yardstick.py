@@ -12,9 +12,9 @@ wrote while they were out
 (``test_trinitys_window_entries_stand_as_they_were_with_this_cell_appended``,
 ``test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries``) stay
 beside them, as do the Kimi-Linear cell's (PR 59), the Solar-Open-2
-cell's (PR 64) and the Nemotron-3-Super cell's (PR 66), by name; and
-``test_late_steps.py``'s (PR 68: the readers of a window's lost time on
-canned spans), whole."""
+cell's (PR 64), the Nemotron-3-Super cell's (PR 66) and the Keye-VL-2.0
+cell's (PR 70), by name; and ``test_late_steps.py``'s (PR 68: the readers
+of a window's lost time on canned spans), whole."""
 
 import pytest
 
@@ -24,6 +24,7 @@ pytest.register_assert_rewrite("benchmark.tests.test_trinity",
                                "benchmark.tests.test_kimi_linear",
                                "benchmark.tests.test_solar_open2",
                                "benchmark.tests.test_nemotron3",
+                               "benchmark.tests.test_keye",
                                "benchmark.tests.test_late_steps")
 
 from benchmark.tests.tier1_cases import *  # noqa: E402,F401,F403
@@ -82,6 +83,20 @@ from benchmark.tests.test_nemotron3 import (  # noqa: E402,F401
     test_the_five_readers_on_synthetic_planes,
     test_the_parameter_count_is_init_params as
     test_nemotron3_parameter_count)
+from benchmark.tests.test_keye import (  # noqa: E402,F401
+    test_both_expert_keys_cut_is_a_complaint,
+    test_each_floor_and_each_width_violated_in_turn as
+    test_keye_each_floor_and_each_width,
+    test_flash_dq_ms_lists_the_cells_that_were_there_and_not_this_one,
+    test_flops_count_the_selected_pairs_and_the_indexer,
+    test_on_a_program_without_the_indexer_the_readers_return_nothing,
+    test_the_cell_its_job_and_its_metrics as
+    test_keye_cell_job_and_metrics,
+    test_the_eight_readers_on_synthetic_planes,
+    test_the_file_is_the_catalog_row_cut_to_one_chip_of_eight,
+    test_the_parameter_count_is_init_params as
+    test_keye_parameter_count)
+from benchmark.tests.test_keye import as_accepted  # noqa: E402
 from benchmark.tests.test_late_steps import (  # noqa: E402,F401
     test_a_stall_is_split_into_stopped_running_and_waiting,
     test_a_steady_window_reads_zero_everywhere,
@@ -92,3 +107,20 @@ from benchmark.tests.test_late_steps import (  # noqa: E402,F401
     test_the_seven_entries_in_benchmark_json,
     test_the_three_parts_make_late_ms_to_the_float,
     test_the_tool_prints_the_readers_numbers_and_a_row_a_late_interval)
+
+# Six older cases hold "the entries that list my cell" as a closed set; the
+# list PR 70 had to give ``flash.dq_ms`` (null in every cell since PR 69)
+# names their cells.  Their files are the benchmark's: each runs here on
+# the file as its PR knew that one entry (``test_keye.as_accepted``).
+test_nemotron_h_cell_job_and_metrics = as_accepted(  # noqa: F405
+    test_nemotron_h_cell_job_and_metrics)  # noqa: F405
+test_the_file_is_the_catalog_row_cut_to_one_chip_of_two = as_accepted(
+    test_the_file_is_the_catalog_row_cut_to_one_chip_of_two)  # noqa: F405
+test_mellum_cell_job_and_metrics = as_accepted(
+    test_mellum_cell_job_and_metrics)
+test_kimi_linear_cell_job_and_metrics = as_accepted(
+    test_kimi_linear_cell_job_and_metrics)
+test_solar_open2_cell_job_and_metrics = as_accepted(
+    test_solar_open2_cell_job_and_metrics)
+test_nemotron3_cell_job_and_metrics = as_accepted(
+    test_nemotron3_cell_job_and_metrics)

@@ -19,8 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark.reference import (
-    afmoe, granite_hybrid, joyai_flash, kimi_linear, lfm2_moe, mellum,
-    nemotron3, nemotron_h, olmo_hybrid, olmoe, solar_open2, xing4)
+    afmoe, granite_hybrid, joyai_flash, keye_sparse, kimi_linear, lfm2_moe,
+    mellum, nemotron3, nemotron_h, olmo_hybrid, olmoe, solar_open2, xing4)
 from ray_tpu.models.llama import (
     ROPE_BY_KIND, LlamaConfig, forward, init_params, loss_fn)
 from ray_tpu.ops.moe import moe_block
@@ -60,6 +60,16 @@ def _olmoe_params(cfg):
                                       0.5, 1.5).astype(a.dtype)
                if name.endswith("norm") else a)
         for name, a in params["layers"].items()}
+    return params
+
+
+def _keye_params(cfg):
+    """``seeded``, and the indexer's LayerNorm bias (drawn at 0) away from
+    it, so that leaving it out shows."""
+    params = seeded(cfg)
+    bias = params["layers"]["k_idx_bias"]
+    params["layers"]["k_idx_bias"] = 0.2 * jax.random.normal(
+        jax.random.PRNGKey(11), bias.shape, bias.dtype)
     return params
 
 
@@ -117,6 +127,12 @@ KIMI_LINEAR = {"kda_layers": [1, 2, 3, 5, 6, 7], "full_attn_layers": [4, 8],
 SOLAR_GQA = (0, 4, 8)
 SOLAR_LINEAR = {"num_heads": 4, "head_dim": 16, "num_kv_heads": None,
                 "short_conv_kernel_size": 4}
+# Keye-VL-2.0's group in small: 4 index heads of 16 against one key, 16 keys
+# a query of the sample's 128 (an eighth of the last row's: neither empty
+# nor everything)
+KEYE_INDEXER = {"indexer_num_heads": 4, "indexer_head_dim": 16,
+                "indexer_num_kv_heads": 1, "topk": 16, "q_chunk_size": 512,
+                "kv_chunk_size": 512}
 NEMOTRON_PATTERN = "MEM*EMEME"  # longer than the model: the first 5 are run
 NEMOTRON3_MODULE = "*E"         # the predicted-ahead module's own pattern
 
@@ -289,6 +305,22 @@ ROWS: Dict[str, Row] = {
              sliding_window=MELLUM_WINDOW, rope_parameters=MELLUM_GROUPS,
              num_experts_per_tok=4, norm_topk_prob=True, first_expert=4,
              router_aux_loss_coef=0.001)),
+    # three layers of one kind: GQA 4/2 with a norm over each head's q and
+    # k, an indexer that picks 16 keys a query, every layer an expert layer
+    # (16 experts of which this chip holds 4..7, 4 a token, renormalised)
+    "keye": Row(
+        dict(_SMALL, num_layers=3, num_kv_heads=2, norm_eps=1e-6,
+             qk_head_norm=True, rope_theta=1e4, sa_config=KEYE_INDEXER,
+             num_experts=16, num_selected=4, norm_topk_prob=True,
+             experts_held=4, first_expert=4, aux_loss_coef=0.0,
+             max_seq_len=128),
+        _jax_tokens(2, 129), keye_sparse,
+        dict(num_hidden_layers=3, num_attention_heads=4,
+             num_key_value_heads=2, rope_theta=10000, rms_norm_eps=1e-6,
+             sa_config=KEYE_INDEXER, num_experts_per_tok=4,
+             norm_topk_prob=True, first_expert=4, idx_loss_coef=1.0,
+             router_aux_loss_coef=0.0),
+        params=_keye_params, precision="highest"),
     # the published pattern in small: 1 dense layer then expert layers, KDA
     # x3 to one latent layer WITHOUT a q rank or a rotation; 16 experts of
     # which this chip holds 4..7, 4 a token, a shared expert; 96 positions:
