@@ -409,26 +409,27 @@ def _indexer(ctx: Ctx, h, lp):
 
 def _selected_attention(cfg, prescaled: bool, q, k, v, q_idx, k_idx, w):
     """What an indexed layer runs on one shard of the batch, each part
-    under its scope: the index scores (``dsa_index``), the selection
-    (``dsa_select``), the softmax over it (``attention``), the indexer's KL
-    a row (``dsa_loss``).  Returns ``(o (b, s, h, d), kl (b, s), live pairs
-    (b,))``."""
-    scale = _sm_scale(cfg)
+    under its scope: the index scores (``dsa_index``), the selection and
+    all the rest of the layer takes from the scores — the mask both ways
+    round, its log-sum-exp and counts a row — (``dsa_select``), the softmax
+    over it (``attention``), the indexer's KL a row (``dsa_loss``).
+    Returns ``(o (b, s, h, d), kl (b, s), live pairs (b,))``."""
+    scale, flash = _sm_scale(cfg), cfg.attn_impl == "flash"
     with jax.named_scope("dsa_index"):
-        scores = sparse_attention.index_scores(
-            q_idx, k_idx, w, kernels=cfg.attn_impl == "flash")
+        scores = sparse_attention.index_scores(q_idx, k_idx, w, kernels=flash)
     with jax.named_scope("dsa_select"):
-        sel = sparse_attention.selection(scores, *sparse_attention.select(
-            scores, cfg.index_topk, kernels=cfg.attn_impl == "flash"))
-        live = sparse_attention.selected_pairs(sel)
+        tau, tie = sparse_attention.select(scores, cfg.index_topk,
+                                           kernels=flash)
+        sel, sel_t, lse_i, live = sparse_attention.masks(
+            scores, tau, tie, kernels=flash)
     with jax.named_scope("attention"):
         o, lse2 = sparse_attention.attend(
-            q, k, v, sel, sm_scale=scale, flash=cfg.attn_impl == "flash",
+            q, k, v, sel, sel_t, sm_scale=scale, flash=flash,
             q_prescaled=prescaled)
     with jax.named_scope("dsa_loss"):
         kl = sparse_attention.indexer_kl(
-            scores, sel, q, k, lse2, sm_scale=scale,
-            flash=cfg.attn_impl == "flash", q_prescaled=prescaled)
+            scores, sel, q, k, lse2, lse_i, sm_scale=scale, flash=flash,
+            q_prescaled=prescaled)
     return o, kl, live
 
 

@@ -24,8 +24,19 @@ opens the scopes):
   index, so the selection is ``I > tau`` or ``I == tau and s <= tie`` —
   exactly ``topk`` keys a row past the first ``topk`` rows, every causal key
   before.  Two numbers a row are what the layer checkpoint keeps
-  (``SAVED_RESIDUALS``), so the backward pass selects nothing again.
-- ``selection``: the mask itself, int8 ``(b, s, s)``.
+  (``SAVED_RESIDUALS``), and ALL it keeps of a selection: the backward pass
+  selects nothing again.
+- ``masks`` (scope ``dsa_select`` too): everything else the layer takes
+  from the scores, by ONE kernel pass over their live tiles
+  (``sparse_mask``) — the mask, int8 ``(b, s, s)``; the mask with the keys
+  first, what the flash backward reads; a row's log-sum-exp over its
+  selected scores, what the KL's kernel takes; a row's count of selected
+  keys.  It runs in both phases, after ``select`` in the forward pass and
+  from the checkpoint's ``tau`` and ``tie`` in the rematerialised one.  A
+  tile past the diagonal is read by nobody and written as ZEROS, so either
+  mask read whole is ``selection``'s: the XLA form, the tests' oracle and
+  what runs where no kernel does (``attend`` then turns the mask round
+  itself, ``indexer_kl`` makes its own log-sum-exp).
 - ``attend`` (scope ``attention``): softmax attention over the selected
   pairs alone: the flash kernels of ``ops/attention.py`` under the mask
   (``flash_fwd_dsa`` / ``flash_dkv_dsa``: the causal tile walk as it is,
@@ -426,13 +437,118 @@ def select(scores, topk: int, *, kernels: bool = True,
     return (checkpoint_name(tau, "dsa_tau"), checkpoint_name(tie, "dsa_tie"))
 
 
+def _selected(scores, tau, tie, keys):
+    """The rule of a selection on operands that broadcast against each
+    other: above ``tau``, or at it and no later than ``tie``; and causal."""
+    live = (scores > tau) | ((scores == tau) & (keys <= tie))
+    return live & (scores > NEG_INF)
+
+
 def selection(scores, tau, tie):
     """The mask, int8 ``(b, s, s)``: 1 where key ``j`` is among query
-    ``i``'s selected (and so at or before it)."""
+    ``i``'s selected (and so at or before it), 0 everywhere else — past
+    the diagonal too.  The XLA form: ``masks`` is the layer's."""
     keys = jnp.arange(scores.shape[-1], dtype=jnp.int32)
-    tau, tie = tau[..., None], tie[..., None]
-    live = (scores > tau) | ((scores == tau) & (keys <= tie))
-    return (live & (scores > NEG_INF)).astype(jnp.int8)
+    return _selected(scores, tau[..., None], tie[..., None], keys
+                     ).astype(jnp.int8)
+
+
+# ``masks``' kernel: a grid step holds a (queries, keys) tile of the scores.
+# The turned mask's tile is the float32 tile transposed in VMEM and compared
+# against ``tau`` / ``tie`` laid as a ROW (Mosaic turns 32-bit tiles round,
+# not int8 ones); a row's log-sum-exp is gathered over the key tiles as
+# ``_fwd_kernel`` keeps ``m`` and ``l``, its count beside it.
+def _mask_kernel(x_ref, tau_ref, tie_ref, tau_t_ref, tie_t_ref,
+                 sel_ref, sel_t_ref, lse_ref, n_ref, m_scr, l_scr, n_scr,
+                 *, tile):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    bq, bk = tile
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        n_scr[...] = jnp.zeros_like(n_scr)
+
+    live_tile = ki * bk - qi * bq < bq
+
+    @pl.when(live_tile)
+    def _tile():
+        x = x_ref[...]
+        first = ki * bk
+        live = _selected(
+            x, tau_ref[...], tie_ref[...],
+            first + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+        sel_ref[...] = live.astype(jnp.int8)
+        sel_t_ref[...] = _selected(
+            x.T, tau_t_ref[...], tie_t_ref[...],
+            first + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+        ).astype(jnp.int8)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(jnp.where(live, x, NEG_INF), axis=-1,
+                                       keepdims=True))
+        p = jnp.where(live, jnp.exp(x - m_new[:, :1]), 0.0)
+        l_scr[...] = l_scr[...] * jnp.exp(m - m_new) + jnp.sum(
+            p, axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        n_scr[...] += jnp.sum(live.astype(jnp.float32), axis=-1,
+                              keepdims=True)
+
+    @pl.when(jnp.logical_not(live_tile))
+    def _dead():
+        sel_ref[...] = jnp.zeros_like(sel_ref)
+        sel_t_ref[...] = jnp.zeros_like(sel_t_ref)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _leave():
+        lse_ref[...] = m_scr[...] + jnp.log(l_scr[...])
+        n_ref[...] = n_scr[...].astype(jnp.int32)
+
+
+def _mask_call(scores, tau, tie, interpret):
+    b, s, _ = scores.shape
+    bq, bk = _tile(s, INDEX_TILE)
+    live = _live_k(bq, bk)
+    column = pl.BlockSpec((None, bq, 1), lambda b_, i, j: (b_, i, 0))
+    row = pl.BlockSpec((None, 1, bq), lambda b_, i, j: (b_, 0, i))
+    lanes = pl.BlockSpec((None, bq, _LANES), lambda b_, i, j: (b_, i, 0))
+    sel, sel_t, lse, n = pl.pallas_call(
+        functools.partial(_mask_kernel, tile=(bq, bk)),
+        grid=(b, s // bq, s // bk),
+        in_specs=[pl.BlockSpec((None, bq, bk),
+                               lambda b_, i, j: (b_, i, live(i, j))),
+                  column, column, row, row],
+        out_specs=[pl.BlockSpec((None, bq, bk), lambda b_, i, j: (b_, i, j)),
+                   pl.BlockSpec((None, bk, bq), lambda b_, i, j: (b_, j, i)),
+                   lanes, lanes],
+        out_shape=[jax.ShapeDtypeStruct((b, s, s), jnp.int8)] * 2 + [
+            jax.ShapeDtypeStruct((b, s, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, s, _LANES), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((bq, _LANES), jnp.float32)] * 3,
+        compiler_params=_params(interpret,
+                                ("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name="sparse_mask",
+    )(scores, tau[..., None], tie[..., None], tau[:, None], tie[:, None])
+    return sel, sel_t, lse[..., 0], n[..., 0]
+
+
+def masks(scores, tau, tie, *, kernels: bool = True,
+          interpret: Optional[bool] = None):
+    """What the rest of a layer takes from ``scores (b, s, s)`` under
+    ``select``'s ``tau``, ``tie``: ``(sel, sel_t, lse_i, pairs)`` — the mask
+    (``selection``'s, bit for bit), the mask with the keys first, each
+    row's log-sum-exp over its selected scores ``(b, s)`` and the live pairs
+    a sequence ``(b,)``.  By the kernel ``sparse_mask`` where ``kernels``
+    and the tiles divide the sequence; else the mask in XLA, and ``None``
+    for ``sel_t`` and ``lse_i``: ``attend`` and ``indexer_kl`` then make
+    their own (``swapaxes``, ``logsumexp``)."""
+    scores = jax.lax.stop_gradient(scores)
+    if kernels and _fits(scores.shape[1], INDEX_TILE):
+        sel, sel_t, lse_i, n = _mask_call(scores, tau, tie,
+                                          _interpret(interpret))
+        return sel, sel_t, lse_i, jnp.sum(n, axis=1)
+    sel = selection(scores, tau, tie)
+    return sel, None, None, selected_pairs(sel)
 
 
 # ---------------------------------------------------------------- attention
@@ -475,9 +591,11 @@ def _attend_xla(qs, k, v, sel):
             checkpoint_name(jnp.moveaxis(lse2, 1, 2), "flash_lse"))
 
 
-def _flash_call(q, k, v, sel, sm_scale, tiles, interpret, prescaled):
+def _flash_call(q, k, v, sel, sel_t, sm_scale, tiles, interpret, prescaled):
     """``flash_fwd_dsa`` on the model's q, k, v: the output where it stands,
-    the log-sum-exp a row, and the backward pass's residuals."""
+    the log-sum-exp a row, and the backward pass's residuals (``sel_t``,
+    the mask with the keys first, among them: ``None`` where the backward
+    is to turn ``sel`` round itself)."""
     in_place = attention._in_place(q, v)
     heads = (q.shape[2], k.shape[2]) if in_place else None
     qt, kt, vt = (attention._enter(x, in_place) for x in (
@@ -486,24 +604,25 @@ def _flash_call(q, k, v, sel, sm_scale, tiles, interpret, prescaled):
                                   heads, sel)
     ot = checkpoint_name(ot, "flash_out")
     lse = checkpoint_name(lse[..., 0], "flash_lse")
-    return attention._leave(ot, q.shape[2]), lse, (qt, kt, vt, ot, lse, sel)
+    return (attention._leave(ot, q.shape[2]), lse,
+            (qt, kt, vt, ot, lse, sel if sel_t is None else None, sel_t))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, sel, sm_scale, tiles, interpret, prescaled):
-    return _flash_call(q, k, v, sel, sm_scale, tiles, interpret,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, sel, sel_t, sm_scale, tiles, interpret, prescaled):
+    return _flash_call(q, k, v, sel, sel_t, sm_scale, tiles, interpret,
                        prescaled)[:2]
 
 
-def _flash_fwd(q, k, v, sel, sm_scale, tiles, interpret, prescaled):
-    o, lse, res = _flash_call(q, k, v, sel, sm_scale, tiles, interpret,
-                              prescaled)
+def _flash_fwd(q, k, v, sel, sel_t, sm_scale, tiles, interpret, prescaled):
+    o, lse, res = _flash_call(q, k, v, sel, sel_t, sm_scale, tiles,
+                              interpret, prescaled)
     return (o, lse), res
 
 
 def _flash_bwd(sm_scale, tiles, interpret, prescaled, res, cts):
     do, _ = cts        # the log-sum-exp goes to the detached target alone
-    qt, kt, vt, ot, lse, sel = res
+    qt, kt, vt, ot, lse, sel, sel_t = res
     in_place = qt.ndim == 3
     h = do.shape[2]
     h_kv = kt.shape[2] * h // qt.shape[2] if in_place else kt.shape[1]
@@ -512,21 +631,23 @@ def _flash_bwd(sm_scale, tiles, interpret, prescaled, res, cts):
     dqt, dkt, dvt = attention._bwd_call(
         qt, kt, vt, ot, lse, attention._enter(do, in_place), dq_scale, True,
         tiles, interpret, None, (h, h_kv) if in_place else None,
-        jnp.swapaxes(sel, 1, 2))
+        jnp.swapaxes(sel, 1, 2) if sel_t is None else sel_t)
     return (attention._leave(dqt, h), attention._leave(dkt, h_kv),
-            attention._leave(dvt, h_kv), None)
+            attention._leave(dvt, h_kv), None, None)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def attend(q, k, v, sel, *, sm_scale: float, flash: bool = True,
+def attend(q, k, v, sel, sel_t=None, *, sm_scale: float, flash: bool = True,
            q_prescaled: bool = False, interpret: Optional[bool] = None):
     """Softmax attention over the pairs ``sel (b, s, s)`` marks.  q ``(b, s,
     h, d)``, k ``(b, s, h_kv, d)``, v ``(b, s, h_kv, dv)`` -> ``(o (b, s,
     h, dv), lse2 (b, h, s))``, the log-sum-exp of the scores TIMES
-    ``log2(e)``.  ``q_prescaled``: q comes times the flash kernels'
-    pre-scale (``attention.q_prescale``)."""
+    ``log2(e)``.  ``sel_t``: the mask with the keys first (``masks``'), what
+    the flash backward reads; without it the backward turns ``sel`` round.
+    ``q_prescaled``: q comes times the flash kernels' pre-scale
+    (``attention.q_prescale``)."""
     s = q.shape[1]
     tiles = attention.choose_tiles(
         s, s, True, max(q.shape[-1], v.shape[-1]), q.dtype) if flash else None
@@ -534,8 +655,8 @@ def attend(q, k, v, sel, *, sm_scale: float, flash: bool = True,
         tiles = None    # an int8 tile is (32, 128): the kernels take whole
     if tiles is None:
         return _attend_xla(_log2_scaled(q, sm_scale, q_prescaled), k, v, sel)
-    return _flash(q, k, v, sel, sm_scale, tiles, _interpret(interpret),
-                  q_prescaled)
+    return _flash(q, k, v, sel, sel_t, sm_scale, tiles,
+                  _interpret(interpret), q_prescaled)
 
 
 # --------------------------------------------------------- the indexer's loss
@@ -629,13 +750,16 @@ def _loss_kernel(q_ref, k_ref, lse_ref, idx_ref, sel_ref, lse_i_ref,
         kl_ref[...] = kl_scr[...]
 
 
-def _loss_call(qs, k, lse2, scores, sel, interpret):
+def _loss_call(qs, k, lse2, scores, sel, lse_i, interpret):
     """``(kl (b, s), the KL's gradient to the scores (b, s, s))`` by the
-    kernel ``sparse_loss``; qs ``(b, s, h, d)`` in the log2 domain."""
+    kernel ``sparse_loss``; qs ``(b, s, h, d)`` in the log2 domain, ``lse_i
+    (b, s)`` the selected scores' log-sum-exp a row (``None``: made here)."""
     b, s, heads, d = qs.shape
     kv_heads = k.shape[2]
     bq, bk = _tile(s, LOSS_TILE)
-    lse_i = jax.nn.logsumexp(jnp.where(sel != 0, scores, NEG_INF), axis=-1)
+    if lse_i is None:
+        lse_i = jax.nn.logsumexp(jnp.where(sel != 0, scores, NEG_INF),
+                                 axis=-1)
     live = _live_k(bq, bk)
     at_k = lambda b_, i, j: (b_, live(i, j), 0)
     at_qk = lambda b_, i, j: (b_, i, live(i, j))
@@ -663,34 +787,37 @@ def _loss_call(qs, k, lse2, scores, sel, interpret):
     return kl[..., 0], g
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kl_kernel(scores, sel, qs, k, lse2, interpret):
-    return _loss_call(qs, k, lse2, scores, sel, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _kl_kernel(scores, sel, qs, k, lse2, lse_i, interpret):
+    return _loss_call(qs, k, lse2, scores, sel, lse_i, interpret)[0]
 
 
-def _kl_kernel_fwd(scores, sel, qs, k, lse2, interpret):
-    return _loss_call(qs, k, lse2, scores, sel, interpret)
+def _kl_kernel_fwd(scores, sel, qs, k, lse2, lse_i, interpret):
+    return _loss_call(qs, k, lse2, scores, sel, lse_i, interpret)
 
 
 def _kl_kernel_bwd(interpret, grad, g):
-    return (grad * g[..., None], None, None, None, None)
+    return (grad * g[..., None], None, None, None, None, None)
 
 
 _kl_kernel.defvjp(_kl_kernel_fwd, _kl_kernel_bwd)
 
 
-def indexer_kl(scores, sel, q, k, lse2, *, sm_scale: float,
+def indexer_kl(scores, sel, q, k, lse2, lse_i=None, *, sm_scale: float,
                flash: bool = True, q_prescaled: bool = False,
                interpret: Optional[bool] = None):
     """The indexer's loss a row, ``(b, s)``: ``KL(p_t || softmax over S_t of
     I[t, .])`` with ``p_t`` detached; its gradient reaches ``scores``
     alone.  By the kernel ``sparse_loss`` where the flash kernels are the
-    attention and a head fills whole lane blocks, else in XLA."""
+    attention and a head fills whole lane blocks (``lse_i``: ``masks``'
+    log-sum-exp of a row's selected scores; without it the kernel's call
+    makes it in XLA), else in XLA."""
     s, d = q.shape[1], q.shape[-1]
     qs, k, lse2 = jax.lax.stop_gradient(
         (_log2_scaled(q, sm_scale, q_prescaled), k, lse2))
     if flash and d % _LANES == 0 and _fits(s, LOSS_TILE):
-        return _kl_kernel(scores, sel, qs, k, lse2, _interpret(interpret))
+        return _kl_kernel(scores, sel, qs, k, lse2, lse_i,
+                          _interpret(interpret))
     return _kl_rows(scores, sel, mean_probabilities(qs, k, lse2, sel))
 
 

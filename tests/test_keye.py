@@ -218,6 +218,46 @@ def test_the_kernels_are_their_xla_forms_over_several_tiles():
             jnp.max(jnp.abs(b)))
 
 
+
+@pytest.mark.parametrize("b,s,topk,levels", [
+    (1, 1024, 96, None),    # 2 x 2 tiles, tile (0, 1) wholly past the diagonal
+    (2, 1024, 96, 7),       # seven values: ties at tau on both sides of tie
+    (1, 1536, 700, 5),      # 3 x 3; rows of fewer than topk keys past a tile
+    (1, 1024, 1024, 7),     # topk >= s: nothing is selected away
+    (1, 1024, 4096, None),
+    (1, 384, 64, 3),        # one tile, smaller than INDEX_TILE
+], ids=["plain", "ties", "short-rows", "topk-is-s", "topk-over-s",
+        "one-tile"])
+def test_sparse_mask_is_the_xla_forms(b, s, topk, levels):
+    """The kernel ``sparse_mask`` (interpret mode) against what the layer
+    made in XLA before it: ``selection``, its ``swapaxes``, ``logsumexp``
+    over the selected scores and ``selected_pairs`` — the masks bit for
+    bit (zeros past the diagonal, whole dead tiles among them), the counts
+    exactly, the log-sum-exp to the order of a float32 sum."""
+    scores = jax.random.normal(jax.random.PRNGKey(s + topk), (b, s, s))
+    if levels:
+        # + 0.0: never -0.0, as the index scores are not
+        scores = jnp.round(scores * (levels / 4)) * 0.5 + 0.0
+    keys = jnp.arange(s)
+    scores = jnp.where(keys[:, None] >= keys, scores, sa.NEG_INF)
+    tau, tie = sa.select(scores, topk, kernels=False)
+    want = sa.selection(scores, tau, tie)
+    if levels and topk < s:
+        at_tau = np.asarray(scores == tau[..., None])
+        assert (at_tau & np.asarray(keys > tie[..., None])).any()
+        assert (at_tau & np.asarray(keys < tie[..., None])).any()
+    assert sa.masks(scores, tau, tie, kernels=False)[1:3] == (None, None)
+    sel, sel_t, lse_i, pairs = jax.jit(sa.masks)(scores, tau, tie)
+    np.testing.assert_array_equal(sel, want)
+    np.testing.assert_array_equal(sel_t, jnp.swapaxes(want, 1, 2))
+    assert sel.dtype == sel_t.dtype == jnp.int8
+    np.testing.assert_array_equal(pairs, sa.selected_pairs(want))
+    rows = min(topk, s)
+    assert int(pairs[0]) == rows * (rows + 1) // 2 + (s - rows) * rows
+    np.testing.assert_allclose(lse_i, jax.nn.logsumexp(
+        jnp.where(want != 0, scores, sa.NEG_INF), axis=-1), rtol=1e-6)
+
+
 # -- (d) the shares -----------------------------------------------------------
 
 def _expert_layer(seed=5, tokens=96, d=32, m=16, experts=64):
@@ -299,7 +339,7 @@ def test_the_train_step_runs_the_kernels_under_their_scopes_and_learns():
     lowered = step.lower(state, {"tokens": TOKENS})
     text = lowered.as_text(debug_info=True)
     for name in ("dsa_index/", "sparse_scores", "sparse_scores_bwd",
-                 "dsa_select/", "sparse_select", "attention/",
+                 "dsa_select/", "sparse_select", "sparse_mask", "attention/",
                  "flash_fwd_dsa", "flash_dkv_dsa", "dsa_loss/", "attn_out/",
                  "moe_experts/"):
         assert name in text, name
@@ -309,6 +349,7 @@ def test_the_train_step_runs_the_kernels_under_their_scopes_and_learns():
     again = [n for n in re.findall(r'loc\("([^"]*)"', text)
              if "rematted_computation" in n]
     assert any("sparse_scores" in n for n in again)     # remade, as q and k
+    assert any("sparse_mask" in n for n in again)   # from the kept tau, tie
     assert not any("sparse_select" in n or "flash_fwd_dsa" in n
                    for n in again)
     compiled = lowered.compile()

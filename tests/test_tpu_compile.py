@@ -886,8 +886,12 @@ def test_the_selection_kernels_compile_at_the_published_widths(one_chip,
     x 128, a 16 x 64 indexer against one key), forward and backward, at
     4096 tokens and 512 keys a query so that the compile stays short: Mosaic
     takes every kernel of the selection — the index scores and their
-    gradient, the bit-counting top-k, the flash kernels under an int8 mask
-    tile and its transpose, the indexer's KL.  The WHOLE step at 1 x 16384
+    gradient, the bit-counting top-k, ``sparse_mask`` (a compare stored as
+    an int8 tile, a float32 tile turned round in VMEM), the flash kernels
+    under an int8 mask tile and its transpose, the indexer's KL — and
+    between the top-k and the flash kernels XLA touches no ``(s, s)`` array:
+    the compiled text holds no ``transpose`` and nothing outside the kernels
+    writes an int8 ``(1, s, s)``.  The WHOLE step at 1 x 16384
     (a minute and a half to compile: 5.12 + 10.47 GB, peak 12.57 of the
     chip's 16.91, PR 70) is ``benchmark/rehearse_compile.py``'s by hand."""
     from ray_tpu.models.blocks import attention as block
@@ -909,8 +913,15 @@ def test_the_selection_kernels_compile_at_the_published_widths(one_chip,
     hlo = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
         *operands).compile().as_text()
     for kernel in ("sparse_scores", "sparse_scores_bwd", "sparse_select",
-                   "flash_fwd_dsa", "flash_dkv_dsa", "sparse_loss"):
+                   "sparse_mask", "flash_fwd_dsa", "flash_dkv_dsa",
+                   "sparse_loss"):
         assert kernel in hlo, kernel
+    assert not re.search(r"[}\]] transpose\(", hlo)
+    masks = [line.split(", metadata=")[0][:400] for line in hlo.splitlines()
+             if re.search(rf" = \(?s8\[1,{s},{s}\]", line)]
+    assert masks and all(re.search(
+        r"custom-call\(|get-tuple-element\(%sparse_mask", line)
+        for line in masks), masks
 
 
 @pytest.mark.parametrize("shape,biased,tokens_last", [
