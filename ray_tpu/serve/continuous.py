@@ -28,8 +28,8 @@ CALLERS, not batch occupancy.  With ``RAY_TPU_CONTINUOUS_BATCHING=0``
 (config ``continuous_batching``) the same decorator degrades to
 one-shot driving of the step function — a fixed batch is admitted,
 stepped until EVERY slot finishes, and only then is the next batch
-admitted — which is the measured A/B baseline for the bench row and
-the byte-identical-behavior escape hatch.
+admitted — which is the A/B baseline and the byte-identical-behavior
+escape hatch.
 
 PREFILL-ONLY SLOTS (disaggregated serving): a prefill-pool replica
 rides this same scheduler — its requests carry ``_prefill_only`` and
@@ -112,7 +112,7 @@ class _ContinuousBatcher:
     ``continuous=False`` keeps the admission/step/retire machinery but
     admits only into an EMPTY batch and never refills mid-flight — the
     legacy one-shot window semantics expressed over the same step
-    function (the bench/acceptance A/B baseline).
+    function (the acceptance A/B baseline).
     """
 
     # Follower backstop cadence: how often a waiting caller re-checks

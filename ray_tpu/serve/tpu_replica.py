@@ -77,15 +77,11 @@ stays zero.
 Request format: ``{"prompt": int | [int, ...], "tokens": int}`` → list
 of ``tokens`` greedily decoded token ids (the dense path takes the
 ``int`` form only; decode continues from the LAST prompt token).
-Requests carrying ``"_timing": True`` (+ a client ``"_t0"`` wall
-clock) finish with ``{"tokens": [...], "ttft": seconds}`` instead —
-the bench's time-to-first-token probe.
 """
 
 from __future__ import annotations
 
 import threading
-import time
 from typing import Any, Dict, List, Optional
 
 from ray_tpu.serve.batching import batch
@@ -100,9 +96,7 @@ class MeshShardedDecoder:
                  paged: Optional[bool] = None, kv_blocks: int = 32,
                  kv_block_size: int = 8, max_slots: int = 16,
                  speculative_k: Optional[int] = None,
-                 prefix_caching: Optional[bool] = None,
-                 use_kernel: bool = True,
-                 prefill_ms_per_token: float = 0.0):
+                 prefix_caching: Optional[bool] = None):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -162,13 +156,6 @@ class MeshShardedDecoder:
         self._paged = _CFG.paged_kv if paged is None else paged
         self._spec_k = max(0, (_CFG.speculative_k if speculative_k is None
                                else speculative_k))
-        self._use_kernel = use_kernel
-        # Synthetic prefill cost (seconds per 1000 prompt tokens written
-        # by RECOMPUTED prefill — imported chains pay nothing, their
-        # cost was paid on the prefill replica).  0 = off; the bench
-        # turns it on to make the monolithic interleave stall
-        # measurable.
-        self._prefill_ms = max(0.0, float(prefill_ms_per_token))
         # Disaggregated-serving bookkeeping: the pool tag the controller
         # assigned, a cached ingest descriptor, and handoff fallback
         # counters.  The lock is a documented LEAF (pinned in
@@ -266,8 +253,7 @@ class MeshShardedDecoder:
         bitwise the stored row (= emb[last token])."""
         import jax.numpy as jnp
 
-        from ray_tpu.ops.paged_attention import (
-            paged_attention, paged_attention_reference)
+        from ray_tpu.ops.paged_attention import paged_attention
         np = self._np
         eng = self._kv_engine
         tables = [eng.block_table(s) for s in live]
@@ -277,10 +263,8 @@ class MeshShardedDecoder:
             bt[i, : len(t)] = t
         cl = np.asarray([s.state["pos"] for s in live], np.int32)
         q = np.zeros((len(live), 1, self._embed), np.float32)
-        fn = paged_attention if self._use_kernel \
-            else paged_attention_reference
-        out = fn(jnp.asarray(q), self._kv_cache, self._kv_cache,
-                 jnp.asarray(bt), jnp.asarray(cl), window=1)
+        out = paged_attention(jnp.asarray(q), self._kv_cache, self._kv_cache,
+                              jnp.asarray(bt), jnp.asarray(cl), window=1)
         return np.asarray(out)[:, 0, :]
 
     def _paged_step(self, slots):
@@ -297,7 +281,6 @@ class MeshShardedDecoder:
         # prompt scatters into this request's (fresh or CoW'd) blocks.
         cow, wb, wo, wv = [], [], [], []
         joiners = []
-        n_prefill_toks = 0
         for s in slots:
             if s.state is not None:
                 continue
@@ -330,13 +313,7 @@ class MeshShardedDecoder:
                         wb.append(blk)
                         wo.append(off)
                         wv.append(self._emb_host[tok])
-                    n_prefill_toks += len(kvp.prompt) - lo
             joiners.append(s)
-        if n_prefill_toks and self._prefill_ms:
-            # Synthetic prefill compute: the whole step stalls behind it
-            # — exactly the monolithic interleave cost the split moves
-            # off the decode replicas.
-            time.sleep(self._prefill_ms * n_prefill_toks / 1000.0)
         self._apply_cache_writes(cow, wb, wo, wv)
         for s in joiners:
             # Publish AFTER the prefill scatter: a prefix-cache entry
@@ -392,21 +369,12 @@ class MeshShardedDecoder:
                 wb.append(blk)
                 wo.append(off)
                 wv.append(self._emb_host[tok])
-            if not st["out"] and (s.request or {}).get("_timing"):
-                st["t_first"] = time.time()
             st["out"] += emit
             st["pos"] += len(emit)
             st["last"] = emit[-1]
             eng.note_tokens(len(emit))
             if len(st["out"]) >= st["need"]:
-                toks = list(st["out"][: st["need"]])
-                if (s.request or {}).get("_timing"):
-                    t0 = float((s.request or {}).get(
-                        "_t0", st.get("t_first", 0.0)))
-                    s.finish({"tokens": toks,
-                              "ttft": st.get("t_first", t0) - t0})
-                else:
-                    s.finish(toks)
+                s.finish(list(st["out"][: st["need"]]))
         self._apply_cache_writes(cow, wb, wo, wv)
 
     @batch(mode="continuous", max_batch_size=MAX_BATCH,

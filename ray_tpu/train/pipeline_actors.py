@@ -451,7 +451,7 @@ class PipelineTrainer:
     ``schedule="fill_drain"`` instead drives synchronous wave barriers
     (all M forwards of stage s complete before stage s+1 starts — the
     GPipe fill/drain shape with transfers ON the critical path): the
-    measured A/B baseline for the bench's bubble/overlap comparison.
+    baseline 1F1B's gradients are held bitwise equal to.
 
     Falls back to the byte-identical single-host path (same micro-batch
     loss/grad accumulation in one jitted program) when
@@ -563,8 +563,8 @@ class PipelineTrainer:
 
     def _submit_fill_drain(self, x_mbs, t_mbs):
         """Synchronous GPipe fill/drain: per-stage wave barriers, so
-        every activation transfer sits on the critical path (the bench
-        baseline 1F1B is measured against)."""
+        every activation transfer sits on the critical path (the
+        baseline 1F1B is compared with)."""
         pp, M = self._pp, self._M
         prev = None
         for s in range(pp):

@@ -188,7 +188,7 @@ def test_ring_mean(ray8):
 # coverage via the sub-second ring_allreduce/allgather/reducescatter/
 # mean tests above — this row only re-measures the speedup.
 def test_ring_beats_star_bench(ray8):
-    """VERDICT #4 'done': big allreduce through the ring vs the star.
+    """A big allreduce through the ring vs the star.
     On multi-core hardware the ring wins >2x (every link busy vs one
     actor's GIL); on a 1-core CI box we only record the numbers."""
     import os

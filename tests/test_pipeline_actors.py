@@ -254,7 +254,7 @@ def test_chaos_mid_epoch_kill_bounded_replay(ray_start_regular):
 
 
 def test_fill_drain_schedule_matches_1f1b(ray_start_regular):
-    """The bench baseline computes the same step: fill/drain wave
+    """The baseline computes the same step: fill/drain wave
     barriers produce bitwise-identical grads to 1F1B."""
     w, x, t = _int_data(seed=3)
     tr = _sgd_trainer(w)

@@ -231,7 +231,7 @@ def test_rolling_update_changes_version(ray8):
 
 
 def test_push_propagation_on_downscale(ray8):
-    """VERDICT #8 'done': after a downscale, no request lands on a
+    """After a downscale, no request lands on a
     retired replica — the handle learns by PUSH (long-poll), not TTL."""
     @serve.deployment(num_replicas=3)
     class Who:

@@ -342,7 +342,7 @@ def test_knob_off_dense_engine_zero_counters_pin():
         ray.shutdown()
 
 
-# -- the perf A/B (bench-shaped; slow tier) ---------------------------------
+# -- the perf A/B (slow tier) -----------------------------------------------
 
 @pytest.mark.slow
 def test_acceptance_paged_1_5x_req_s_at_equal_hbm():

@@ -111,7 +111,7 @@ def test_worker_owned_puts_spill(small_store):
 
 
 def test_remote_node_task_returns_overflow():
-    """VERDICT #3 'done' criterion: a REMOTE (agent) node overfills its
+    """A REMOTE (agent) node overfills its
     store during task returns and the job still completes — returns
     spill on that node and the driver restores them through the
     transfer path."""
