@@ -69,15 +69,18 @@ WINDOW_MASKED = "attn_window_masked_tile_share"
 WINDOW_STATS = {WINDOW_EXECUTED: "max", WINDOW_MASKED: "max"}
 # An indexed layer's: the indexer's own loss (the layers' mean joins the
 # step's), the (q, k) pairs its selection holds over the causal ones,
-# counted from the mask it made, and by how many pairs that count is off
-# what ``topk`` keys a query give (0: neither more nor fewer).
+# counted from the mask it made, by how many pairs that count is off
+# what ``topk`` keys a query give (0: neither more nor fewer), and the share
+# of the selection kernel's row blocks in which a tie at the threshold bound
+# and was walked (0 where no kernel runs).
 INDEX_SCOPES = ("attn_qkv", "dsa_index", "dsa_select", "attention",
                 "dsa_loss", "attn_out")
 INDEX_LOSS = "idx_loss"
 SELECTED_SHARE = "dsa_selected_share"
 SELECTED_OFF = "dsa_selected_off"
+TIE_WALK_SHARE = "dsa_tie_walk_share"
 INDEX_STATS = {INDEX_LOSS: "mean", SELECTED_SHARE: "mean",
-               SELECTED_OFF: "sum"}
+               SELECTED_OFF: "sum", TIE_WALK_SHARE: "mean"}
 
 
 def _attention_shapes(cfg):
@@ -413,13 +416,14 @@ def _selected_attention(cfg, prescaled: bool, q, k, v, q_idx, k_idx, w):
     all the rest of the layer takes from the scores — the mask both ways
     round, its log-sum-exp and counts a row — (``dsa_select``), the softmax
     over it (``attention``), the indexer's KL a row (``dsa_loss``).
-    Returns ``(o (b, s, h, d), kl (b, s), live pairs (b,))``."""
+    Returns ``(o (b, s, h, d), kl (b, s), live pairs (b,), the share of
+    the selection's row blocks that walked a tie (b,))``."""
     scale, flash = _sm_scale(cfg), cfg.attn_impl == "flash"
     with jax.named_scope("dsa_index"):
         scores = sparse_attention.index_scores(q_idx, k_idx, w, kernels=flash)
     with jax.named_scope("dsa_select"):
-        tau, tie = sparse_attention.select(scores, cfg.index_topk,
-                                           kernels=flash)
+        tau, tie, walked = sparse_attention.select(scores, cfg.index_topk,
+                                                   kernels=flash)
         sel, sel_t, lse_i, live = sparse_attention.masks(
             scores, tau, tie, kernels=flash)
     with jax.named_scope("attention"):
@@ -430,7 +434,7 @@ def _selected_attention(cfg, prescaled: bool, q, k, v, q_idx, k_idx, w):
         kl = sparse_attention.indexer_kl(
             scores, sel, q, k, lse2, lse_i, sm_scale=scale, flash=flash,
             q_prescaled=prescaled)
-    return o, kl, live
+    return o, kl, live, walked
 
 
 def _indexed_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
@@ -450,8 +454,9 @@ def _indexed_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
         q_idx, k_idx, w = _indexer(ctx, h, lp)
     run = functools.partial(_selected_attention, cfg, prescaled)
     if ctx.mesh is not None:
-        run = batch_shard_map(run, ctx.mesh, (4, 4, 4, 4, 3, 3), (4, 2, 1))
-    o, kl, live = run(q, k, v, q_idx, k_idx, w)
+        run = batch_shard_map(run, ctx.mesh, (4, 4, 4, 4, 3, 3),
+                              (4, 2, 1, 1))
+    o, kl, live, walked = run(q, k, v, q_idx, k_idx, w)
     with jax.named_scope("dsa_loss"):
         causal = b * (s * (s + 1) // 2)
         topk = min(cfg.index_topk, s)
@@ -461,6 +466,7 @@ def _indexed_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
             INDEX_LOSS: jnp.mean(kl),
             SELECTED_SHARE: live.astype(jnp.float32) / causal,
             SELECTED_OFF: jnp.abs(live - wanted).astype(jnp.float32),
+            TIE_WALK_SHARE: jnp.mean(walked),
         }, INDEX_STATS)
     return _out(ctx, x, o, lp, residual, gate), aux
 

@@ -25,7 +25,14 @@ opens the scopes):
   exactly ``topk`` keys a row past the first ``topk`` rows, every causal key
   before.  Two numbers a row are what the layer checkpoint keeps
   (``SAVED_RESIDUALS``), and ALL it keeps of a selection: the backward pass
-  selects nothing again.
+  selects nothing again.  The kernel (``sparse_select``) counts a block of
+  rows over the columns those rows can select from — up to the block's last
+  row, in whole chunks, never fewer than ``topk`` — and over no other: the
+  rest of the square is ``NEG_INF`` by construction.  It walks the tie's
+  key by its bits only in a block where a tie BINDS (a row with more keys
+  at ``tau`` than fit); elsewhere ``tie`` is the last key at ``tau``, one
+  pass.  The share of blocks that walked is ``select``'s third output, the
+  step statistic ``dsa_tie_walk_share``.
 - ``masks`` (scope ``dsa_select`` too): everything else the layer takes
   from the scores, by ONE kernel pass over their live tiles
   (``sparse_mask``) — the mask, int8 ``(b, s, s)``; the mask with the keys
@@ -359,9 +366,32 @@ def _select_xla(scores, topk: int):
 # and the ``topk``-th highest value is found bit by bit — a float's bits,
 # the sign folded in, order as whole numbers, and ``count(key >= v) >=
 # topk`` says whether the next bit of ``v`` is set: 32 counts a row, exact.
-# Then, among the keys AT that value, the one the tie rule admits last: the
-# ``c``-th from the left where ``c`` of them fit, found by its bits as well.
+# A count runs over the columns a row of the block CAN select from and no
+# other: ``[0, W)``, ``W`` the block's last row + 1 rounded up to whole
+# chunks of ``SELECT_CHUNK`` columns and never under ``topk`` (a row of
+# fewer than ``topk`` causal keys counts its ``NEG_INF`` columns up to
+# there, as ``lax.top_k`` takes them); every column past ``W`` is
+# ``NEG_INF`` in every row of the block and is never read.  Then the rule of
+# ties.  Where ``count(key >= v) == topk`` in every row of the block — all
+# but one or two blocks in a hundred on float32 scores nobody rounded
+# (``PERF.md`` §6, PR 73) — every key AT the value is admitted and the last
+# of them is ONE pass (the
+# highest column with ``key == v``).  Only a block with a row whose tie
+# BINDS (more keys at the value than fit) walks: the ``c``-th key at the
+# value from the left, ``c`` of them fitting, found by its bits, 15 counts
+# more at 16384 columns.  The data decide, block by block; the kernel says
+# which blocks walked in the spare lanes of ``tie`` (``select``'s third).
 SELECT_ROWS = 32
+SELECT_CHUNK = 2048
+
+
+def _select_chunk(s: int) -> int:
+    """Columns a trip of a count's loop: the largest halving of
+    ``SELECT_CHUNK`` that divides ``s`` (a multiple of the lane width)."""
+    c = SELECT_CHUNK
+    while s % c:
+        c //= 2
+    return c
 
 
 def _ordered(x):
@@ -370,71 +400,124 @@ def _ordered(x):
     return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
 
 
-def _select_kernel(x_ref, key_ref, tie_ref, *, topk):
-    key = _ordered(x_ref[...])
-    rows, s = key.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, s), 1)
+def _select_kernel(x_ref, key_ref, tie_ref, key_scr, *, topk, chunk):
+    rows, s = x_ref.shape
+    last = (pl.program_id(1) + 1) * rows        # the block's last row + 1
+    trips = jnp.maximum(pl.cdiv(last, chunk), pl.cdiv(topk, chunk))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+
+    def order(c, _):
+        at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        key_scr[:, at] = _ordered(x_ref[:, at])
+
+    jax.lax.fori_loop(0, trips, order, None)
+
+    def sweep(step, acc):
+        """``acc = step(acc, keys, columns)`` over the counted columns, a
+        lane block ``(rows, _LANES)`` at a time, left to right."""
+        def trip(c, acc):
+            def block(j, acc):
+                first = pl.multiple_of(c * chunk + j * _LANES, _LANES)
+                return step(acc, key_scr[:, pl.ds(first, _LANES)],
+                            first + lane)
+
+            # straight-line code, traced once
+            return jax.lax.fori_loop(0, chunk // _LANES, block, acc,
+                                     unroll=True)
+
+        return jax.lax.fori_loop(0, trips, trip, acc)
 
     def count(hit):
-        return jnp.sum(hit.astype(jnp.float32), axis=-1, keepdims=True)
+        """``(rows, 1)`` float32: the counted columns ``hit(keys, columns)``
+        marks.  Gathered as whole numbers a lane block, reduced across the
+        lanes ONCE, as floats (under 2**24: exact; the whole-number reduce
+        reads 2 % slower a call, ``PERF.md`` §6, PR 73)."""
+        acc = sweep(lambda acc, k, col: acc + hit(k, col).astype(jnp.int32),
+                    jnp.zeros((rows, _LANES), jnp.int32))
+        return jnp.sum(acc.astype(jnp.float32), axis=-1, keepdims=True)
 
-    def value_bit(i, v):
+    def value_bit(i, carry):
+        v, n_ge = carry     # n_ge = count(key >= v), lane-replicated as v
         cand = jnp.where(i == 0, jnp.zeros_like(v),
                          v | jnp.left_shift(jnp.int32(1), 31 - i))
-        return jnp.where(count(key >= cand) >= topk, cand, v)
+        n = count(lambda k, _: k >= cand)
+        take = n >= topk
+        return jnp.where(take, cand, v), jnp.where(take, n, n_ge)
 
-    v = jax.lax.fori_loop(
-        0, 32, value_bit, jnp.full((rows, 1), -2 ** 31, jnp.int32))
-    equal = key == v
-    fit = topk - count(key > v)      # how many of the equal ones are taken
+    v, n_ge = jax.lax.fori_loop(0, 32, value_bit, (
+        jnp.full((rows, _LANES), -2 ** 31, jnp.int32),
+        jnp.full((rows, 1), (trips * chunk).astype(jnp.float32))))
+    key_ref[...] = v
+    binds = jnp.sum(jnp.abs(n_ge - topk)) > 0.0     # in some row of the block
 
-    def key_bit(i, m):
-        cand = m | jnp.left_shift(jnp.int32(1), (s - 1).bit_length() - 1 - i)
-        return jnp.where(count(equal & (col < cand)) < fit, cand, m)
+    @pl.when(jnp.logical_not(binds))
+    def _every_key_at_v():
+        m = sweep(lambda acc, k, col: jnp.maximum(
+            acc, jnp.where(k == v, col, -1)),
+            jnp.full((rows, _LANES), -1, jnp.int32))
+        m = jnp.max(m.astype(jnp.float32), axis=-1, keepdims=True)
+        tie_ref[...] = jnp.where(lane == 0, m.astype(jnp.int32), 0)
 
-    m = jax.lax.fori_loop(0, (s - 1).bit_length(), key_bit,
-                          jnp.zeros((rows, 1), jnp.int32))
-    key_ref[...] = jnp.broadcast_to(v, key_ref.shape)
-    tie_ref[...] = jnp.broadcast_to(m, tie_ref.shape)
+    @pl.when(binds)
+    def _walk():
+        fit = topk - count(lambda k, _: k > v)  # of the keys at v, taken
+
+        def key_bit(i, m):
+            cand = m | jnp.left_shift(
+                jnp.int32(1), (s - 1).bit_length() - 1 - i)
+            below = count(lambda k, col: (k == v) & (col < cand))
+            return jnp.where(below < fit, cand, m)
+
+        m = jax.lax.fori_loop(0, (s - 1).bit_length(), key_bit,
+                              jnp.zeros((rows, _LANES), jnp.int32))
+        tie_ref[...] = jnp.where(lane == 0, m, 1)
 
 
 def _select_call(scores, topk: int, interpret):
+    """``(tau, tie, the share of a sequence's row blocks that walked the
+    tie (b,))``."""
     b, s, _ = scores.shape
     rows = min(SELECT_ROWS, s)
     lanes = pl.BlockSpec((None, rows, _LANES), lambda b_, i: (b_, i, 0))
     key, tie = pl.pallas_call(
-        functools.partial(_select_kernel, topk=topk),
+        functools.partial(_select_kernel, topk=topk, chunk=_select_chunk(s)),
         grid=(b, s // rows),
         in_specs=[pl.BlockSpec((None, rows, s), lambda b_, i: (b_, i, 0))],
         out_specs=[lanes, lanes],
         out_shape=[jax.ShapeDtypeStruct((b, s, _LANES), jnp.int32)] * 2,
+        scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32)],
         compiler_params=_params(interpret, ("parallel", "parallel")),
         interpret=interpret, name="sparse_select",
     )(scores)
     key = key[..., 0]
     bits = jnp.where(key < 0, key ^ jnp.int32(0x7FFFFFFF), key)
-    return jax.lax.bitcast_convert_type(bits, jnp.float32), tie[..., 0]
+    walked = jnp.mean(tie[:, ::rows, 1].astype(jnp.float32), axis=1)
+    return (jax.lax.bitcast_convert_type(bits, jnp.float32), tie[..., 0],
+            walked)
 
 
 def select(scores, topk: int, *, kernels: bool = True,
            interpret: Optional[bool] = None):
-    """``(tau (b, s) float32, tie (b, s) int32)`` of ``scores (b, s, s)``
-    (``index_scores``'): the ``topk``-th highest of a row and the highest
-    key admitted at that value, ties to the lower key.  A row of fewer than
-    ``topk`` causal keys reads ``NEG_INF``: every causal key is above it
-    (all rows where ``topk >= s``: nothing is selected away).  By the
-    kernel ``sparse_select`` where ``kernels`` and the rows tile, else by
-    ``lax.top_k``."""
+    """``(tau (b, s) float32, tie (b, s) int32, walked (b,) float32)`` of
+    ``scores (b, s, s)`` (``index_scores``'): the ``topk``-th highest of a
+    row and the highest key admitted at that value, ties to the lower key.
+    A row of fewer than ``topk`` causal keys reads ``NEG_INF``: every causal
+    key is above it (all rows where ``topk >= s``: nothing is selected
+    away).  By the kernel ``sparse_select`` where ``kernels`` and the rows
+    tile, else by ``lax.top_k``.  ``walked``: the share of the kernel's row
+    blocks in which a tie bound and was walked (0 where no kernel ran)."""
     scores = jax.lax.stop_gradient(scores)
     b, s, _ = scores.shape
+    walked = jnp.zeros((b,), jnp.float32)
     if topk >= s:
         tau = jnp.full((b, s), NEG_INF, jnp.float32)
         tie = jnp.full((b, s), s - 1, jnp.int32)
     elif kernels and s % _LANES == 0:
-        tau, tie = _select_call(scores, topk, _interpret(interpret))
+        tau, tie, walked = _select_call(scores, topk, _interpret(interpret))
     else:
         tau, tie = _select_xla(scores, topk)
-    return (checkpoint_name(tau, "dsa_tau"), checkpoint_name(tie, "dsa_tie"))
+    return (checkpoint_name(tau, "dsa_tau"), checkpoint_name(tie, "dsa_tie"),
+            walked)
 
 
 def _selected(scores, tau, tie, keys):

@@ -60,6 +60,11 @@ def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
     s, k = 128, 16
     assert float(got["dsa_selected_share"]) == pytest.approx(
         (k * (k + 1) // 2 + (s - k) * k) / (s * (s + 1) // 2), rel=1e-6)
+    # the kernel's first block of four holds the rows of fewer than 16 keys,
+    # which walk at a width of 128, and where every head's ReLU is shut
+    # scores tie at 0.0; ``lax.top_k`` walks nothing
+    walked = float(got["dsa_tie_walk_share"])
+    assert walked == 0.0 if impl == "reference" else 0.25 <= walked <= 1.0
 
 
 def test_each_loss_reaches_its_own_parameters_and_no_other():
@@ -141,7 +146,7 @@ def test_the_selection_is_top_ks_own_ties_to_the_lower_key(kernel, ties):
     that counts bits and sorts nothing."""
     b, s, topk = 2, 256, 40
     scores, causal = _scores(b, s, ties)
-    mask = sa.selection(scores, *sa.select(scores, topk, kernels=kernel))
+    mask = sa.selection(scores, *sa.select(scores, topk, kernels=kernel)[:2])
     _, keys = jax.lax.top_k(scores, topk)
     want = jnp.zeros((b, s, s), bool).at[
         jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
@@ -155,6 +160,90 @@ def test_the_selection_is_top_ks_own_ties_to_the_lower_key(kernel, ties):
         assert int(jnp.sum((scores == tau) & causal & (mask == 0))) > 0
     np.testing.assert_array_equal(sa.selected_pairs(mask),
                                   [rows.sum()] * b)
+
+
+def _both_selects(scores, topk):
+    """``sparse_select`` (interpret mode) and ``lax.top_k`` on the same
+    scores: ``((tau, tie, walked), (tau, tie))``."""
+    got = jax.jit(functools.partial(sa.select, topk=topk))(scores)
+    return got, sa.select(scores, topk, kernels=False)[:2]
+
+
+@pytest.mark.parametrize("s,topk,ties", [
+    (640, 100, False),      # 5 chunks of 128 columns; topk under a chunk
+    (640, 128, False),      # a chunk exactly: no short row walks
+    (640, 200, False),      # over a chunk: the least width is two
+    (640, 100, True),       # ties at tau in every chunk a row can see
+    (640, 200, True),
+    (256, 40, True),        # one chunk; short rows across block 0's edge
+    (1536, 700, True),      # chunks of 512; 22 blocks of short rows
+    (4096, 2048, False),    # the cell's chunk, 2048, and topk = the chunk
+    (4096, 512, True),
+], ids=["under-a-chunk", "a-chunk", "over-a-chunk", "ties-under", "ties-over",
+        "one-chunk", "chunks-of-512", "the-cells-chunk", "the-cells-chunk-ties"])
+def test_the_selection_kernel_is_top_ks_two_numbers_in_every_row(
+        s, topk, ties):
+    """``tau`` AND ``tie`` of the kernel equal ``lax.top_k``'s in every row,
+    not only the mask they make — over several chunks of counted columns
+    (``sa._select_chunk``: 128 at 640 keys, 2048 at 4096) with ``topk``
+    under, at and over a chunk's width; rows of fewer than ``topk`` keys in
+    the first block, across its edge and across a chunk's (they read
+    ``NEG_INF`` and ``topk - 1``, the ``NEG_INF`` columns counted up to the
+    least width); with ties, a row whose keys AT ``tau`` straddle a chunk's
+    edge, admitted on one side and left out on the other."""
+    scores, causal = _scores(1, s, ties, seed=s + topk)
+    (tau, tie, walked), (want_tau, want_tie) = _both_selects(scores, topk)
+    np.testing.assert_array_equal(tau, want_tau)
+    np.testing.assert_array_equal(tie, want_tie)
+    short = np.arange(s) + 1 < topk
+    assert (np.asarray(tau)[0, short] == sa.NEG_INF).all()
+    assert (np.asarray(tie)[0, short] == topk - 1).all()
+    chunk = sa._select_chunk(s)
+    assert s // chunk > 1 or s == 256
+    if ties and s // chunk > 1:
+        at_tau = np.asarray((scores == tau[..., None]) & causal)[0]
+        keys, last = np.arange(s), np.asarray(tie)[0][:, None]
+        assert any(
+            ((at_tau & (keys < edge) & (keys <= last)).any(-1)
+             & (at_tau & (keys >= edge) & (keys > last)).any(-1)
+             & ~short).any() for edge in range(chunk, s, chunk))
+        assert float(walked[0]) > 0.5
+    if not ties and topk % chunk == 0:
+        assert float(walked[0]) == 0.0      # nothing binds, nothing walks
+
+
+def test_a_tie_is_walked_only_in_the_block_where_it_binds():
+    """The counter, and both paths on the same block.  Tie-free scores at
+    ``topk`` = a chunk: no block walks (``walked`` 0.0) and every row's
+    ``tie`` is the one pass's.  ONE score copied over a lower one in ONE
+    row, so that two keys sit at that row's ``tau`` and one fits: its block
+    of 32 rows walks and no other (1 of 20), the row's numbers are
+    ``lax.top_k``'s, and the thirty-one rows beside it — whose tie does not
+    bind — read from the walk the same two numbers the one pass gave them.
+    On ``_scores(ties=True)`` nearly every block walks."""
+    s, topk, row = 640, 128, 403
+    scores, _ = _scores(1, s, ties=False, seed=7)
+    (tau0, tie0, walked0), want0 = _both_selects(scores, topk)
+    assert float(walked0[0]) == 0.0
+    np.testing.assert_array_equal(tau0, want0[0])
+    np.testing.assert_array_equal(tie0, want0[1])
+    # a second key at the row's tau, in another chunk, where a lower stood
+    lower = np.flatnonzero(np.asarray(scores[0, row, :row]) < tau0[0, row])
+    to = int(lower[lower // 128 != int(tie0[0, row]) // 128][0])
+    tied = scores.at[0, row, to].set(tau0[0, row])
+    (tau1, tie1, walked1), want1 = _both_selects(tied, topk)
+    assert float(walked1[0]) == pytest.approx(1 / (s // sa.SELECT_ROWS))
+    np.testing.assert_array_equal(tau1, want1[0])
+    np.testing.assert_array_equal(tie1, want1[1])
+    assert float(tau1[0, row]) == float(tau0[0, row])
+    assert int(tie1[0, row]) == min(to, int(tie0[0, row]))  # the lower key
+    others = np.arange(s) != row
+    np.testing.assert_array_equal(np.asarray(tau1)[0, others],
+                                  np.asarray(tau0)[0, others])
+    np.testing.assert_array_equal(np.asarray(tie1)[0, others],
+                                  np.asarray(tie0)[0, others])
+    often = _both_selects(_scores(1, s, ties=True)[0], topk)[0][2]
+    assert float(often[0]) > 0.5
 
 
 def test_a_selection_of_everything_is_the_dense_mixer():
@@ -174,6 +263,9 @@ def test_a_selection_of_everything_is_the_dense_mixer():
                                dense.token_nll(dense_params), atol=3e-5)
     assert float(got["dsa_selected_share"]) == 1.0
     assert float(got["dsa_selected_off"]) == 0.0
+    # an indexed layer's statistic, 0.0 where no kernel selects; no other's
+    assert float(got["dsa_tie_walk_share"]) == 0.0
+    assert not [k for k in want if k.startswith("dsa_")]
 
 
 # -- (c) the kernels against their XLA forms, several tiles -------------------
@@ -197,7 +289,7 @@ def test_the_kernels_are_their_xla_forms_over_several_tiles():
         def f(q, k, v, q_idx, k_idx, w):
             scores = sa.index_scores(q_idx, k_idx, w, kernels=kernels)
             sel = sa.selection(scores, *sa.select(scores, topk,
-                                                  kernels=kernels))
+                                                  kernels=kernels)[:2])
             o, lse2 = sa.attend(q, k, v, sel, sm_scale=128 ** -0.5,
                                 flash=kernels)
             kl = sa.indexer_kl(scores, sel, q, k, lse2,
@@ -240,7 +332,7 @@ def test_sparse_mask_is_the_xla_forms(b, s, topk, levels):
         scores = jnp.round(scores * (levels / 4)) * 0.5 + 0.0
     keys = jnp.arange(s)
     scores = jnp.where(keys[:, None] >= keys, scores, sa.NEG_INF)
-    tau, tie = sa.select(scores, topk, kernels=False)
+    tau, tie, _ = sa.select(scores, topk, kernels=False)
     want = sa.selection(scores, tau, tie)
     if levels and topk < s:
         at_tau = np.asarray(scores == tau[..., None])
@@ -329,6 +421,9 @@ def test_on_a_mesh_the_model_is_one_devices(impl):
     assert float(got_m["dsa_selected_off"]) == 0.0
     np.testing.assert_allclose(got_m["dsa_selected_share"],
                                want_m["dsa_selected_share"], rtol=1e-6)
+    assert float(got_m["dsa_tie_walk_share"]) == float(
+        want_m["dsa_tie_walk_share"])
+    assert (float(got_m["dsa_tie_walk_share"]) > 0.0) == (impl == "flash")
 
 
 def test_the_train_step_runs_the_kernels_under_their_scopes_and_learns():
@@ -361,6 +456,7 @@ def test_the_train_step_runs_the_kernels_under_their_scopes_and_learns():
     assert set(keye_sparse.STEP_METRICS) <= set(metrics)
     assert float(metrics["moe_dropped"]) == 0.0
     assert float(metrics["dsa_selected_off"]) == 0.0
+    assert 0.25 <= float(metrics["dsa_tie_walk_share"]) <= 1.0
     assert np.isfinite(float(metrics["idx_loss"]))
 
 

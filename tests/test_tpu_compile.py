@@ -891,7 +891,12 @@ def test_the_selection_kernels_compile_at_the_published_widths(one_chip,
     under an int8 mask tile and its transpose, the indexer's KL — and
     between the top-k and the flash kernels XLA touches no ``(s, s)`` array:
     the compiled text holds no ``transpose`` and nothing outside the kernels
-    writes an int8 ``(1, s, s)``.  The WHOLE step at 1 x 16384
+    writes an int8 ``(1, s, s)``.  ``sparse_select`` ALONE also at the
+    cell's own ``(1, 16384, 16384)``, 2048 keys a query: a count's loop over
+    the chunks of 2048 columns a block of rows can select from, its trip
+    count off the grid step, the ordered keys in 2 MB of VMEM scratch, the
+    tie's walk under a predicate reduced from the block's counts.  The
+    WHOLE step at 1 x 16384
     (a minute and a half to compile: 5.12 + 10.47 GB, peak 12.57 of the
     chip's 16.91, PR 70) is ``benchmark/rehearse_compile.py``'s by hand."""
     from ray_tpu.models.blocks import attention as block
@@ -907,7 +912,7 @@ def test_the_selection_kernels_compile_at_the_published_widths(one_chip,
                 for i, shape in enumerate(shapes)]
 
     def loss(*args):
-        o, kl, live = block._selected_attention(cfg, False, *args)
+        o, kl, _, _ = block._selected_attention(cfg, False, *args)
         return jnp.sum(o.astype(jnp.float32)) + jnp.sum(kl)
 
     hlo = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
@@ -922,6 +927,13 @@ def test_the_selection_kernels_compile_at_the_published_widths(one_chip,
     assert masks and all(re.search(
         r"custom-call\(|get-tuple-element\(%sparse_mask", line)
         for line in masks), masks
+    from ray_tpu.ops import sparse_attention
+
+    assert sparse_attention._select_chunk(16384) == 2048
+    alone = jax.jit(functools.partial(
+        sparse_attention.select, topk=2048, interpret=False)).lower(
+            _shape((1, 16384, 16384), jnp.float32, one_chip)).compile()
+    assert "sparse_select" in alone.as_text()
 
 
 @pytest.mark.parametrize("shape,biased,tokens_last", [
