@@ -1,0 +1,10 @@
+"""``diffattn.combine_ms`` as a share of the traced steps' device time: the
+scope ``attn_diff``'s term of the sum to 100 (with ``s6.time_share_pct``,
+``gmu.time_share_pct`` and the shared ``step.*_pct``).  None where the trace
+has no such scope."""
+
+from benchmark import trace_scopes
+
+
+def read(run):
+    return trace_scopes.step_share_pct(run, ("attn_diff",))

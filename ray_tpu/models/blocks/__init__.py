@@ -7,7 +7,9 @@ sub-block each.  A mixer or FFN is ONE module that ends in ONE
 and ``full_attention`` see every earlier token, ``sliding_attention`` is the
 same module under the model's ``sliding_window``; ``indexed``, what a model
 with an ``sa_config`` runs for ``attention``, is its q, k, v and output under
-a learned selection of keys).  To add one: write the
+a learned selection of keys; differential attention three times likewise:
+under the window, in full — the layer whose keys and values later layers
+read — and ``diff_cross``, which reads them).  To add one: write the
 module, register its ``Block`` below
 under the name ``layer_types`` gives it, and list its scopes in the
 ``"scopes"`` of the benchmark configuration that uses it; its
@@ -17,7 +19,7 @@ tree names a mixer.
 """
 
 from ray_tpu.models.blocks import (
-    attention, base, conv, delta, ffn, kda, mamba, residual)
+    attention, base, conv, delta, ffn, gmu, kda, mamba, mamba1, residual)
 
 MIXERS = {
     "attention": attention.SOFTMAX,
@@ -32,6 +34,16 @@ MIXERS = {
     "linear_attention": delta.BLOCK,
     "kda": kda.BLOCK,      # ... its decay a vector over the key channels
     "conv": conv.BLOCK,
+    # a SambaY decoder's five (``LlamaConfig.mb_per_layer``): a selective
+    # scan; differential attention under the model's ``sliding_window``, over
+    # every earlier token (its keys and values are what it PUBLISHES), and
+    # from later layers onto those keys and values; a gated memory unit that
+    # READS the nearest earlier scan's output
+    "mamba1": mamba1.BLOCK,
+    "diff_sliding": attention.DIFF_SLIDING,
+    "diff_full": attention.DIFF_FULL,
+    "diff_cross": attention.DIFF_CROSS,
+    "gmu": gmu.BLOCK,
     "none": base.EMPTY_MIXER,
 }
 FFNS = {"dense": ffn.DENSE, "moe": ffn.MOE, "none": base.EMPTY_FFN}

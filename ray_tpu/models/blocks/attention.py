@@ -37,6 +37,13 @@ kernels' pre-scale of q is its epilogue), else on the 4-D view by
 (scope ``attn_qkv``) gates the heads' outputs, ``o * sigmoid(g)``, before
 ``wo`` (scope ``attn_out``); the checkpoint keeps nothing of it: the
 rematerialised forward computes ``g`` with q, k and v.
+
+DIFFERENTIAL attention (arXiv:2410.05258; ``diff_sliding``, ``diff_full``,
+``diff_cross``: the attention layers of a SambaY decoder, arXiv:2507.06607)
+stands behind them: heads in pairs, two softmaxes over one doubled value
+head, their difference under a learned lambda (``_diff_mixer``, scope
+``attn_diff``).  ``diff_full`` PUBLISHES its keys and values; ``diff_cross``
+holds no key or value projection and READS them.
 """
 
 import functools
@@ -46,13 +53,14 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.blocks.base import (
-    Block, Ctx, Param, constant, fold, ones, residual_out)
+    BIAS_STD, Block, Ctx, Param, constant, fold, ones, residual_out, small)
 from ray_tpu.models.blocks.residual import (
     add, block_in, norm_shapes, out_norm)
 from ray_tpu.ops import attention, rotary, sparse_attention
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.layers import (
-    apply_rope, repeat_kv_heads, rms_norm, scaled_rope, yarn_mscale)
+    apply_rope, layer_norm as _layer_norm, repeat_kv_heads, rms_norm,
+    scaled_rope, yarn_mscale)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.ulysses import ulysses_attention
 from ray_tpu.parallel.mesh import AXIS_SP, AXIS_TP
@@ -378,16 +386,6 @@ def _indexed_shapes(cfg):
     }
 
 
-def _layer_norm(x, weight, bias, eps: float):
-    """LayerNorm over the last dimension, statistics in float32."""
-    x32 = x.astype(jnp.float32)
-    mean = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
-    return ((x32 - mean) * jax.lax.rsqrt(var + eps)
-            * weight.astype(jnp.float32) + bias.astype(jnp.float32)
-            ).astype(x.dtype)
-
-
 def _indexer(ctx: Ctx, h, lp):
     """The indexer's operands from the DETACHED normed input ``h (b, s,
     d)``: its heads' queries ``(b, s, H, di)`` and the one key ``(b, s,
@@ -527,3 +525,167 @@ INDEXED = Block(_indexed_shapes, _indexed_mixer,
                 saved=(*attention.SAVED_RESIDUALS,
                        *sparse_attention.SAVED_RESIDUALS),
                 scopes=INDEX_SCOPES, stats=lambda cfg: INDEX_STATS)
+
+
+# ---- differential attention -------------------------------------------------
+# Heads in interleaved pairs: q pair p is heads (2p, 2p + 1), KV pair j heads
+# (2j, 2j + 1) of k and the two heads of v side by side, one value head of
+# 2 x head_dim; KV pair j serves the q pairs of its group as a KV head serves
+# its q heads.  With ``a1``, ``a2`` the softmax attention of a pair's first
+# and second q head over the pair's first and second k head and the ONE
+# doubled value head:
+#
+#     o_p = RMSNorm(a1 - lambda a2; diff_norm) (1 - lambda_init)
+#     lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init
+#     lambda_init = 0.8 - 0.6 exp(-0.3 i), i the layer's index
+#
+# The projections are made with their columns in the order [every pair's
+# first head | every pair's second]: a permutation of the MATRICES' columns
+# (``_halves_first``), so q, k come out as the flash kernels want them — ONE
+# grouped-query call of ``heads`` on ``kv_heads`` heads at head_dim / 2 x
+# head_dim, the values given once per half, whose output's two halves are a1
+# and a2 — and no activation is turned.  The softmaxes, lambda and the
+# norm's statistics are float32.
+
+DIFF_KEYS, DIFF_VALUES = "diff_keys", "diff_values"
+DIFF_LAMBDA = "diff_lambda"
+DIFF_SCOPES = ("attn_qkv", "attention", "attn_diff", "attn_out")
+LAMBDA_STD = 0.1
+
+
+def _diff_shapes(cfg, cross: bool = False):
+    """q and o as the softmax mixer's; k and v but for a ``cross`` layer;
+    the projections' biases where the model has them (``attn_bias``); four
+    vectors of a head's size that make lambda (float32 whatever the
+    parameters': lambda moves by hundredths); the weight of the norm over a
+    pair's doubled head."""
+    d, h, kvd, dh = cfg.embed_dim, cfg.qkv_dim, cfg.kv_dim, cfg.head_dim
+    widths = {"q": h} if cross else {"q": h, "k": kvd, "v": kvd}
+    axes = {"q": "heads", "k": "kv_heads", "v": "kv_heads"}
+    shapes = {**norm_shapes(cfg, "attn")}
+    for name, width in widths.items():
+        shapes["w" + name] = Param((d, width),
+                                   ("layer", "kernel_in", axes[name]))
+    shapes["wo"] = Param((h, d), ("layer", "heads", "kernel_in"),
+                         residual_out(cfg))
+    if cfg.attn_bias:
+        for name, width in widths.items():
+            shapes["b" + name] = Param((width,), ("layer", axes[name]),
+                                       small(BIAS_STD))
+        shapes["bo"] = Param((d,), ("layer", "embed"), small(BIAS_STD))
+    for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+        shapes[name] = Param((dh,), ("layer", None), small(LAMBDA_STD),
+                             jnp.float32)
+    shapes["diff_norm"] = Param((2 * dh,), ("layer", None), ones)
+    return shapes
+
+
+def _halves_first(w, head_dim: int):
+    """``w (..., heads x head_dim)`` with its columns in the order [the
+    pairs' first heads | the pairs' second heads]."""
+    lead = w.shape[:-1]
+    return jnp.swapaxes(w.reshape(*lead, -1, 2, head_dim), -2, -3).reshape(
+        *lead, -1)
+
+
+def _diff_projection(h, lp, name: str, cfg, halves: bool = True):
+    w = lp["w" + name].astype(cfg.dtype)
+    turn = (lambda t: _halves_first(t, cfg.head_dim)) if halves else (
+        lambda t: t)
+    y = h @ turn(w)
+    if cfg.attn_bias:
+        y = y + turn(lp["b" + name].astype(cfg.dtype))
+    return y
+
+
+def lambda_init(layer_index):
+    """arXiv:2410.05258 §3.1, by the layer's index counted from 0."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * layer_index)
+
+
+def learned_lambda(lp, start):
+    """``exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``, float32."""
+    return (jnp.exp(jnp.sum(lp["lambda_q1"] * lp["lambda_k1"]))
+            - jnp.exp(jnp.sum(lp["lambda_q2"] * lp["lambda_k2"])) + start)
+
+
+def _diff_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
+                windowed: bool = False, publishes: bool = False,
+                shared=None):
+    """-> ``(the stream, aux)``, and from the layer that ``publishes`` a
+    third, ``{diff_keys, diff_values}`` (the decoder drops it where no
+    later layer reads); with ``shared`` the layer reads those and projects
+    q alone."""
+    cfg, cst = ctx.cfg, ctx.cst
+    b, s = x.shape[:2]
+    dh, f32 = cfg.head_dim, jnp.float32
+    with jax.named_scope("attn_qkv"):
+        h = block_in(x, lp["attn_norm"], cfg, lp.get("attn_norm_bias"))
+        q = cst(_diff_projection(h, lp, "q", cfg).reshape(
+            b, s, cfg.num_heads, dh), ("batch", "seq", "heads", "head_dim"))
+        if shared is None:
+            k = cst(_diff_projection(h, lp, "k", cfg).reshape(
+                b, s, cfg.num_kv_heads, dh),
+                ("batch", "seq", "kv_heads", "head_dim"))
+            v = _diff_projection(h, lp, "v", cfg, halves=False).reshape(
+                b, s, cfg.num_kv_heads // 2, 2 * dh)
+        else:
+            k, v = shared[DIFF_KEYS], shared[DIFF_VALUES]
+    window, aux = _diff_window(ctx, aux, q, k, windowed)
+    with jax.named_scope("attention"):
+        both = _attention(q, k, jnp.concatenate([v, v], axis=2), cfg,
+                          ctx.mesh, window)
+    with jax.named_scope("attn_diff"):
+        first, second = jnp.split(both.astype(f32), 2, axis=2)
+        start = lambda_init(lp["layer_index"])
+        lam = learned_lambda(lp, start)
+        o = rms_norm(first - lam * second, lp["diff_norm"], cfg.norm_eps)
+        o = (o * (1.0 - start)).astype(cfg.dtype).reshape(b, s, -1)
+        aux = fold(aux, {DIFF_LAMBDA: lam}, {DIFF_LAMBDA: "mean"})
+    with jax.named_scope("attn_out"):
+        y = o @ lp["wo"].astype(cfg.dtype)
+        if cfg.attn_bias:
+            y = y + lp["bo"].astype(cfg.dtype)
+        out = add(ctx, x, y, residual)
+    if publishes:
+        return out, aux, {DIFF_KEYS: k, DIFF_VALUES: v}
+    return out, aux
+
+
+def _cross_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *, shared):
+    return _diff_mixer(ctx, x, aux, lp, residual, shared=shared)
+
+
+def _diff_window(ctx: Ctx, aux, q, k, windowed: bool):
+    """``(the window that cuts, or None; aux with a windowed layer's
+    WINDOW_STATS)``, as ``_attend`` has them."""
+    cfg = ctx.cfg
+    if ctx.sp_manual:
+        raise NotImplementedError(
+            "differential attention inside a region that is manual over "
+            "'sp'")
+    if not windowed:
+        return None, aux
+    window = attention.live_window(cfg.sliding_window, k.shape[1])
+    return window, fold(aux, _window_stats(
+        cfg, q.shape[1], k.shape[1], 2 * cfg.head_dim, window), WINDOW_STATS)
+
+
+def _diff_stats(windowed: bool):
+    return lambda cfg: {DIFF_LAMBDA: "mean",
+                        **(WINDOW_STATS if windowed else {})}
+
+
+DIFF_SLIDING = Block(_diff_shapes,
+                     functools.partial(_diff_mixer, windowed=True),
+                     saved=attention.SAVED_RESIDUALS, scopes=DIFF_SCOPES,
+                     stats=_diff_stats(True), indexed=True)
+DIFF_FULL = Block(_diff_shapes,
+                  functools.partial(_diff_mixer, publishes=True),
+                  saved=attention.SAVED_RESIDUALS, scopes=DIFF_SCOPES,
+                  stats=_diff_stats(False), indexed=True,
+                  publishes=(DIFF_KEYS, DIFF_VALUES))
+DIFF_CROSS = Block(functools.partial(_diff_shapes, cross=True), _cross_mixer,
+                   saved=attention.SAVED_RESIDUALS, scopes=DIFF_SCOPES,
+                   stats=_diff_stats(False), indexed=True,
+                   reads=(DIFF_KEYS, DIFF_VALUES))

@@ -72,6 +72,23 @@ def a_log(key, shape):
     return jnp.log(1.0 + 15.0 * jax.random.uniform(key, shape, jnp.float32))
 
 
+def s4d_a_log(key, shape):
+    """Mamba-1's: ``A[c, n] = n + 1`` in every channel, kept as its log."""
+    return jnp.broadcast_to(
+        jnp.log(jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)), shape)
+
+
+# What a bias is drawn at: off zero, so that a comparison with a reference
+# can see it (a trained one starts at 0).
+BIAS_STD = 0.02
+
+
+def small(std: float):
+    """Normal at ``std``, whatever the shape: a bias, a vector a layer."""
+    return lambda key, shape: std * jax.random.normal(key, shape,
+                                                      jnp.float32)
+
+
 class Param(NamedTuple):
     """One tensor of a layer: its shape WITHOUT the stacked layer dim, its
     logical axes WITH it (``("layer", ...)``), its initialiser, and its
@@ -108,12 +125,23 @@ class Block:
     the layer checkpoint keeps.  ``scopes``: the ``jax.named_scope``s it
     opens, in order.  ``stats(cfg) -> {name: "sum" | "max" | "min" | "mean"}``: the
     float32 scalars it folds into ``aux`` and how layers combine each; they
-    come back as step metrics under these names."""
+    come back as step metrics under these names.  ``publishes``: the
+    arrays a mixer makes for LATER layers, by name: its ``apply`` then
+    returns ``(x, aux, {name: array})``.  ``reads``: the names a mixer
+    takes, ``apply(..., shared={name: array})``, each the nearest earlier
+    layer's publication; the decoder carries them from the one to the other
+    (``models/llama.py::_scan_layers``) and refuses a model in which a
+    reader has no publisher before it.  ``indexed``: its ``lp`` also holds
+    ``layer_index``, the layer's place in the model counted from 0, a
+    float32 scalar (a rule that depends on the depth reads it)."""
     shapes: Callable[[Any], Dict[str, Param]]
     apply: Callable
     saved: Tuple[str, ...] = ()
     scopes: Tuple[str, ...] = ()
     stats: Callable[[Any], Dict[str, str]] = lambda cfg: {}
+    publishes: Tuple[str, ...] = ()
+    reads: Tuple[str, ...] = ()
+    indexed: bool = False
 
 
 def _hand_on(ctx, x, aux, lp, residual: bool = True):

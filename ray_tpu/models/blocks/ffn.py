@@ -121,7 +121,7 @@ def _dense_ffn(ctx: Ctx, x, aux, lp, residual: bool = True):
     """-> (the stream, aux, nothing handed out of the scan)."""
     cfg = ctx.cfg
     with jax.named_scope("ffn"):
-        h = block_in(x, lp["mlp_norm"], cfg)
+        h = block_in(x, lp["mlp_norm"], cfg, lp.get("mlp_norm_bias"))
         return add(ctx, x, _ffn(h, lp, cfg), residual,
                    out_norm(lp, "mlp", cfg)), aux, None
 

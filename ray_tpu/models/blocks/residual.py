@@ -17,9 +17,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.blocks.base import (
-    Ctx, Param, constant, keyed_ones, ones)
+    BIAS_STD, Ctx, Param, constant, keyed_ones, ones, small)
 from ray_tpu.ops import streams
-from ray_tpu.ops.layers import rms_norm
+from ray_tpu.ops.layers import layer_norm, rms_norm
 
 # The scopes the n-stream wrapper opens round a block.
 SCOPES = ("hc_map", "hc_mix")
@@ -31,24 +31,36 @@ def scaled(x, multiplier: float):
 
 
 def norm_shapes(cfg, name: str):
-    """A block's norm ``<name>_norm`` and, in a model that norms both what
-    a block reads and what it adds (``block_norm="sandwich"``), the second
-    one, ``<name>_post_norm``, whose gain starts at ``cfg.post_norm_init``."""
+    """A block's norm ``<name>_norm`` — with its bias ``<name>_norm_bias``
+    where the model's norms are LayerNorms (``norm_type``) — and, in a
+    model that norms both what a block reads and what it adds
+    (``block_norm="sandwich"``), the second one, ``<name>_post_norm``,
+    whose gain starts at ``cfg.post_norm_init``."""
     axes = ("layer", "embed")
     shapes = {name + "_norm": Param((cfg.embed_dim,), axes, ones)}
+    if cfg.norm_type == "layernorm":
+        shapes[name + "_norm_bias"] = Param((cfg.embed_dim,), axes,
+                                            small(BIAS_STD))
     if cfg.block_norm == "sandwich":
         shapes[name + "_post_norm"] = Param(
             (cfg.embed_dim,), axes, constant(cfg.post_norm_init))
     return shapes
 
 
-def block_in(x, weight, cfg):
-    """What a block reads: the stream through the block's norm, or, in a
-    model that norms ONLY what a block adds (``add``), the stream as it
-    is."""
+def block_in(x, weight, cfg, bias=None):
+    """What a block reads: the stream through the block's norm (a
+    LayerNorm where the model gives it a ``bias``), or, in a model that
+    norms ONLY what a block adds (``add``), the stream as it is."""
     if cfg.block_norm == "output":
         return x
-    return rms_norm(x, weight, cfg.norm_eps)
+    return norm(x, weight, bias, cfg.norm_eps)
+
+
+def norm(x, weight, bias, eps: float):
+    """The model's norm: RMSNorm, or with a ``bias`` LayerNorm."""
+    if bias is None:
+        return rms_norm(x, weight, eps)
+    return layer_norm(x, weight, bias, eps)
 
 
 def out_norm(lp, name: str, cfg):

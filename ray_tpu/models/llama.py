@@ -54,6 +54,7 @@ for parity, not design.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -61,7 +62,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.models.blocks import FFNS, MIXERS, residual
-from ray_tpu.models.blocks.base import Ctx, Param, normal, ones
+from ray_tpu.models.blocks.base import (
+    BIAS_STD, Ctx, Param, normal, ones, small)
 from ray_tpu.models.blocks.residual import (
     from_streams, hc_block, scaled, to_streams)
 from ray_tpu.ops.layers import rms_norm, rope_type
@@ -243,6 +245,23 @@ class LlamaConfig:
     rescale_prenorm_residual: bool = False
     published_layers: int = 0
     select_bias_init: float = 0.02    # std the selection bias is drawn at
+    # A SambaY decoder (arXiv:2507.06607; the public ``mb_per_layer``, 2:
+    # every second layer a recurrent one).  With it the mixers follow from
+    # the depth L alone (``sambay_mixers``): even layers up to L/2 are
+    # Mamba-1 (``mamba1``), the odd ones below L/2 differential attention
+    # under ``sliding_window``, layer L/2 + 1 in full — the layer whose keys
+    # and values the odd layers after it attend over (``diff_cross``) — and
+    # the even layers after it gated memory units (``gmu``) on the scan
+    # output of layer L/2.
+    mb_per_layer: int = 0
+    s6_state: int = 16                # Mamba-1: state numbers a channel
+    s6_conv: int = 4                  # width of its causal depthwise conv
+    s6_expand: int = 2                # inner width over the model's
+    s6_dt_rank: Any = "auto"          # "auto": ceil(embed_dim / 16)
+    # rmsnorm | layernorm (every block's norm and the last one with a
+    # bias, ``norm_eps`` its epsilon)
+    norm_type: str = "rmsnorm"
+    attn_bias: bool = False           # biases on q, k, v and the output
 
     def __post_init__(self):
         # a configuration file hands a list: keep the config hashable
@@ -328,6 +347,19 @@ class LlamaConfig:
             raise ValueError(
                 "mtp_pattern spells the predicted-ahead module of a "
                 f"layer_pattern model: {self.mtp_pattern!r}")
+        if self.mb_per_layer and (
+                self.mb_per_layer != 2 or self.num_layers % 4
+                or self.layer_types or self.layer_pattern or self.gqa_layers
+                or self.linear_attn_config or self.kv_lora_rank
+                or self.sa_config or self.num_heads % 2
+                or self.num_kv_heads % 2 or self.sliding_window < 1):
+            raise NotImplementedError(
+                "mb_per_layer: a SambaY decoder alternates recurrent and "
+                "attention layers (mb_per_layer 2) over a depth that is a "
+                "multiple of 4, names no layer's mixer any other way, pairs "
+                "its q and its KV heads and states its sliding_window")
+        if self.norm_type not in ("rmsnorm", "layernorm"):
+            raise ValueError(f"norm_type {self.norm_type!r}")
         unknown = set(self.layer_types) - set(MIXERS)
         if unknown:
             raise ValueError(
@@ -336,6 +368,23 @@ class LlamaConfig:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"num_layers is {self.num_layers}")
+        if (self.norm_type == "layernorm" or self.attn_bias) and (
+                not {m for m, _ in self.layer_kinds} <= SAMBAY_MIXERS
+                or self.num_experts or self.hc_mult > 1 or self.num_nextn
+                or self.block_norm != "input"):
+            raise NotImplementedError(
+                "LayerNorm with a bias as the blocks' norm and biases on "
+                "the attention projections are implemented for the mixers "
+                f"of a SambaY decoder ({sorted(SAMBAY_MIXERS)}) and the "
+                "dense FFN, on one stream, the norm on a block's input")
+        if (self.hc_mult > 1 or self.num_nextn) and any(
+                MIXERS[m].publishes or MIXERS[m].reads or MIXERS[m].indexed
+                for m, _ in self.layer_kinds):
+            raise NotImplementedError(
+                "a mixer that publishes, reads or takes its layer's index "
+                "on several residual streams or before a predicted-ahead "
+                "module")
+        _published(self.layer_runs)     # a reader has a publisher before it
         if self.gqa_layers and (
                 self.layer_types or self.layer_pattern or self.kv_lora_rank
                 or set(self.linear_group) & {"kda_layers", "full_attn_layers"}
@@ -398,6 +447,17 @@ class LlamaConfig:
     def ssm_conv_dim(self) -> int:
         """What the convolution runs over: x, B and C side by side."""
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def s6_inner(self) -> int:
+        return self.s6_expand * self.embed_dim
+
+    @property
+    def s6_rank(self) -> int:
+        """The width dt comes up from."""
+        if self.s6_dt_rank == "auto":
+            return -(-self.embed_dim // 16)
+        return self.s6_dt_rank
 
     @property
     def gdn_key_inner(self) -> int:
@@ -519,6 +579,8 @@ class LlamaConfig:
         if self.layer_pattern:
             return tuple(LAYER_PATTERN[c]
                          for c in self.layer_pattern[:self.num_layers])
+        if self.mb_per_layer:
+            return tuple((m, "dense") for m in sambay_mixers(self.num_layers))
         mixers = self.layer_types[:self.num_layers] or (
             ("latent" if self.kv_lora_rank else "attention",)
             * self.num_layers)
@@ -571,6 +633,23 @@ class LlamaConfig:
                         attn_impl="reference")
         defaults.update(kw)
         return LlamaConfig(**defaults)
+
+
+def sambay_mixers(depth: int) -> Tuple[str, ...]:
+    """The mixers of a SambaY decoder of ``depth`` layers (a multiple of
+    4), as ``LlamaConfig.mb_per_layer`` describes them: at 32, M W x 8, M F,
+    then G C x 7."""
+    half = depth // 2
+    return tuple(
+        ("mamba1" if i <= half else "gmu") if i % 2 == 0
+        else "diff_sliding" if i < half
+        else "diff_full" if i == half + 1 else "diff_cross"
+        for i in range(depth))
+
+
+# Those mixers (depth 8 holds every kind): the ones that take LayerNorms
+# and projection biases.
+SAMBAY_MIXERS = frozenset(sambay_mixers(8))
 
 
 def _as_runs(kinds) -> Tuple[Tuple[Tuple[str, str], int], ...]:
@@ -631,6 +710,8 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         "layers": stacks(cfg.kind_runs),
         "final_norm": ("embed",),
     }
+    if cfg.norm_type == "layernorm":
+        axes["final_norm_bias"] = ("embed",)
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("kernel_in", "vocab")
     if cfg.num_nextn:
@@ -650,7 +731,7 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     main = run_shapes(cfg.kind_runs)
     mtp = run_shapes(cfg.mtp_runs) if cfg.num_nextn else []
     n_tensors = sum(len(shapes) for _, shapes in main + mtp) + 3 + (
-        len(_mtp_shapes(cfg)) if mtp else 0)
+        len(_mtp_shapes(cfg)) if mtp else 0) + (cfg.norm_type == "layernorm")
     keys = iter(jax.random.split(key, n_tensors))
 
     def drawn(p: Param, *stacked):
@@ -673,6 +754,9 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
         "layers": layers,
         "final_norm": jnp.ones((cfg.embed_dim,), cfg.param_dtype),
     }
+    if cfg.norm_type == "layernorm":
+        params["final_norm_bias"] = drawn(
+            Param((cfg.embed_dim,), ("embed",), small(BIAS_STD)))
     if not cfg.tie_embeddings:
         params["lm_head"] = matrix((cfg.embed_dim, cfg.vocab_size),
                                    cfg.embed_dim)
@@ -756,17 +840,81 @@ def _scan_layers(layers, x, cfg: LlamaConfig, mesh, rules,
     ``cfg.kind_runs``), over that run's stacked parameters, each under the
     layer checkpoint.  Returns ``(x, aux, counts)``: ``counts`` holds, a
     run, what its layers hand out of the scan — the experts' assignments
-    ``(layers, E)`` of a run whose router has a selection bias, else None."""
+    ``(layers, E)`` of a run whose router has a selection bias, else None.
+    What a mixer PUBLISHES for later layers (``Block.publishes``) leaves its
+    run's scan beside that and enters the scans of the runs that READ it
+    (``_published`` says which run hands on what)."""
     carry = (x, _zero_aux(cfg) if aux is None else aux)
-    counts = []
-    for kind, stacked in _runs(layers, cfg.kind_runs if runs is None
-                               else runs):
-        layer_fn = _make_layer_fn(cfg, mesh, rules, sp_manual, kind)
-        if cfg.remat:
-            layer_fn = _checkpoint(layer_fn)
-        carry, out = jax.lax.scan(layer_fn, carry, stacked)
+    runs = cfg.kind_runs if runs is None else runs
+    wanted = _published(tuple((mixer, n) for (mixer, _), n in runs))
+    counts, shared, first = [], {}, 0
+    for (kind, stacked), (_, n), publish in zip(_runs(layers, runs), runs,
+                                                wanted):
+        scan = functools.partial(_scan_run, cfg, mesh, rules, sp_manual,
+                                 kind, shared)
+        start = first
+        if publish and n > 1:
+            # the run's LAST layer is the one later layers read: the others
+            # go first, in a scan that hands nothing out
+            carry, _ = scan(carry, jax.tree.map(lambda a: a[:-1], stacked),
+                            start)
+            stacked, start = (jax.tree.map(lambda a: a[-1:], stacked),
+                              first + n - 1)
+        carry, out = scan(carry, stacked, start, publish)
+        if publish:
+            out, made = out
+            shared.update(jax.tree.map(lambda a: a[0], made))
         counts.append(out)
+        first += n
     return (*carry, counts)
+
+
+def _scan_run(cfg: LlamaConfig, mesh, rules, sp_manual, kind, shared, carry,
+              stacked, first: int, publish=()):
+    """One ``lax.scan`` over the ``stacked`` layers of ``kind``, the first
+    of them layer ``first`` of the model.  A mixer that reads takes its
+    arrays out of ``shared`` — constants of this scan, held ONCE however
+    many layers read them, their gradient summed over the readers —; one
+    that is ``indexed`` its layer's number beside its tensors; ``publish``:
+    the names the scan hands out beside the layers' own output (stacked
+    over the layers as every output of a scan: the caller scans ONE
+    publishing layer)."""
+    mixer = MIXERS[kind[0]]
+    if mixer.indexed:
+        layers = jax.tree.leaves(stacked)[0].shape[0]
+        stacked = dict(stacked, layer_index=first + jnp.arange(
+            layers, dtype=jnp.float32))
+    layer_fn = _make_layer_fn(
+        cfg, mesh, rules, sp_manual, kind, publish=publish,
+        shared={name: shared[name] for name in mixer.reads})
+    if cfg.remat:
+        layer_fn = _checkpoint(layer_fn)
+    return jax.lax.scan(layer_fn, carry, stacked)
+
+
+def _published(layer_runs) -> Tuple[Tuple[str, ...], ...]:
+    """For each run of ``layer_runs`` (``((mixer, layers), ...)``) the names
+    it has to hand on: those of its mixer's ``publishes`` that a LATER run
+    reads before another run publishes them again (a reader takes the
+    nearest earlier publication; what nobody reads is never handed out of
+    a scan).  A reader without a publisher before it is refused."""
+    out, held = [], set()
+    for i, (mixer, _) in enumerate(layer_runs):
+        missing = set(MIXERS[mixer].reads) - held
+        if missing:
+            raise ValueError(
+                f"layer run {i} ({mixer!r}) reads {sorted(missing)}, which "
+                "no earlier layer publishes")
+        held |= set(MIXERS[mixer].publishes)
+        names = []
+        for name in MIXERS[mixer].publishes:
+            later = next((m for m, _ in layer_runs[i + 1:]
+                          if name in MIXERS[m].publishes + MIXERS[m].reads),
+                         None)
+            if later is not None and name in MIXERS[later].reads:
+                names.append(name)
+        out.append(tuple(names))
+    return tuple(out)
 
 
 def _checkpoint(layer_fn):
@@ -825,7 +973,8 @@ def _mean_aux(aux, cfg: LlamaConfig, runs):
 def _lm_head(params, x, cfg: LlamaConfig, cst):
     """Final norm and head product -> f32 logits (scope ``lm_head``)."""
     with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = residual.norm(x, params["final_norm"],
+                          params.get("final_norm_bias"), cfg.norm_eps)
         if cfg.tie_embeddings:  # one table, read twice: its gradient is
             # the sum of both uses
             logits = jnp.einsum("bsd,vd->bsv", x,
@@ -845,7 +994,7 @@ def _make_cst(mesh, rules):
 
 
 def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
-                   kind=("attention", "dense")):
+                   kind=("attention", "dense"), publish=(), shared=None):
     """One layer of ``kind`` (mixer, FFN) as a scan body over stacked
     layer params: a mixer (``blocks.MIXERS``) then an FFN
     (``blocks.FFNS``), each adding to the residual stream — or, in a model
@@ -857,14 +1006,24 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
     ``sp_manual``: the body runs inside a shard_map that is manual over
     'sp' (the pipeline path — jax/shardy cannot nest manual regions); what
     that means to a block is at ``blocks.base.Ctx``.
+
+    ``shared``: what a mixer that READS takes, by name — arrays earlier
+    layers published, constants of the scan this body runs in, so that a
+    run of readers holds each ONCE and its gradient is summed over them.
+    ``publish``: of what a mixer that PUBLISHES makes, the names a later
+    layer reads: the body hands ``(out, {name: array})`` out of the scan.
     """
     ctx = Ctx(cfg, mesh, _make_cst(mesh, rules), sp_manual)
     mix, ffn = MIXERS[kind[0]].apply, FFNS[kind[1]].apply
+    if shared:
+        mix = functools.partial(mix, shared=shared)
 
     def layer_fn(carry, lp):
         x, aux = carry
-        x, aux = mix(ctx, x, aux, lp)
+        x, aux, *made = mix(ctx, x, aux, lp)
         x, aux, out = ffn(ctx, x, aux, lp)
+        if publish:
+            out = (out, {k: made[0][k] for k in publish})
         return (x, aux), out
 
     def streams_layer_fn(carry, lp):

@@ -24,6 +24,17 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (x * weight.astype(jnp.float32)).astype(dtype)
 
 
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+               eps: float) -> jax.Array:
+    """LayerNorm over the last dimension, statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
+    return ((x32 - mean) * jax.lax.rsqrt(var + eps)
+            * weight.astype(jnp.float32) + bias.astype(jnp.float32)
+            ).astype(x.dtype)
+
+
 def yarn_inv_freq(head_dim: int, theta: float, *, factor: float,
                   original: int, beta_fast: float = 32.0,
                   beta_slow: float = 1.0) -> jax.Array:

@@ -1294,3 +1294,389 @@ def ssd_reference(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         tuple(jnp.moveaxis(t, 1, 0) for t in (x, dt, b, c)))
     return jnp.moveaxis(y, 0, 1)
 
+
+
+# ---- the selective scan (Mamba-1, "S6": Gu & Dao 2023, arXiv:2312.00752) ----
+# A state of ``n`` numbers a CHANNEL, whose decay differs by channel, state
+# index and token:
+#
+#     H_t[c, n] = exp(dt_t[c] A[c, n]) H_(t-1)[c, n] + dt_t[c] B_t[n] x_t[c]
+#     m_t[c]    = sum_n C_t[n] H_t[c, n] + D[c] x_t[c]
+#
+# No chunk of it is a matrix product (``ssd_chunked`` rests on ONE decay a
+# head): it is an elementwise recurrence, work for the vector unit, and the
+# ``(tokens, channels, n)`` array of its states is never written.  Two forms
+# of the one rule, by what a call's shapes show (``selscan_kernels_fit``):
+#
+# - ``selscan_kernels``: the Pallas pair ``selscan_fwd`` / ``selscan_bwd``,
+#   TOKEN-SERIAL.  x, dt and m are turned to ``(b, s, c / 128, 128)``, so
+#   that ONE token's 1024 channels are one ``(8, 128)`` vector register, and
+#   a grid step ``(batch, chunk of _SEL_CHUNK tokens, block of 1024
+#   channels)`` walks its chunk a token at a time with the block's ``n``
+#   state registers as the loop's carry; whatever is indexed by ``n`` alone
+#   — ``B_t[n]``, ``C_t[n]`` — is a SCALAR read from SMEM and splat, so no
+#   lane is ever moved.  The forward writes the state LEAVING each chunk
+#   (float32, ``s / 128 x n x c``: 42 MB a layer at 16384 x 5120 x 16), all
+#   the backward needs beside the inputs: it walks the chunks in reverse,
+#   makes a chunk's states again into VMEM, and runs the adjoint recurrence
+#   back through them.  The gradients to B and C are sums over CHANNELS, a
+#   scalar a (token, n): the step adds each product into a VMEM array over
+#   the channel blocks (the grid's innermost axis) and at the last block
+#   folds the 8 sublanes and writes ``(tokens, n, 128)`` partial sums, which
+#   XLA finishes.
+# - ``selscan_xla``: chunks of ``chunk`` tokens under ``lax.scan``, inside a
+#   chunk an associative scan over ``(decay, input)`` pairs — products of
+#   numbers in (0, 1], so no exponent ever grows — each chunk under a
+#   checkpoint, so that a pass holds ``(chunk, n, channels)`` and the
+#   backward keeps one state a chunk.  For any shapes; the tests' second
+#   oracle beside ``selscan_reference``, the recurrence itself.
+#
+# Both return ``(m, the largest |H| at a chunk's end)``, the second a step
+# statistic without a gradient.
+
+from jax.ad_checkpoint import checkpoint_name  # noqa: E402 (the lines above keep their numbers: the kernels' bodies embed them)
+
+_SEL_CHUNK = 128                 # tokens a grid step of the kernels walks
+_SEL_BLOCK = 8 * _LANES          # channels of a block: one float32 register
+
+
+def selective_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+                   c: jax.Array, d: jax.Array, *, chunk: int = 64):
+    """The recurrence above.  ``x (batch, s, channels)``; ``dt`` like ``x``,
+    float32, positive (after its softplus); ``a (channels, n)`` float32,
+    negative; ``b``, ``c`` ``(batch, s, n)``; ``d (channels,)``.  Returns
+    ``m`` like ``x`` and the largest ``|H|`` at a chunk's end (float32, no
+    gradient).  ``H`` starts at 0, is float32 throughout and exact: no
+    state is truncated."""
+    if selscan_kernels_fit(x.shape[2]):
+        return selscan_kernels(x, dt, a, b, c, d)
+    return selscan_xla(x, dt, a, b, c, d, chunk=chunk)
+
+
+def selscan_kernels_fit(channels: int) -> bool:
+    """Whether a call's channels fill whole ``(8, 128)`` registers."""
+    return channels % _SEL_BLOCK == 0
+
+
+def selscan_reference(x, dt, a, b, c, d):
+    """The recurrence one token at a time, float32 (arguments as
+    ``selective_scan``'s): ``lax.scan`` over positions carrying ``H (batch,
+    channels, n)``.  Returns ``(m, the largest |H| of any token)``."""
+    x, dt, b, c = (t.astype(_F32) for t in (x, dt, b, c))
+    a, d = a.astype(_F32), d.astype(_F32)
+
+    def token(carry, at):
+        h, peak = carry
+        x_t, dt_t, b_t, c_t = at
+        h = (jnp.exp(dt_t[..., None] * a) * h
+             + (dt_t * x_t)[..., None] * b_t[:, None, :])
+        m_t = jnp.sum(h * c_t[:, None, :], -1) + d * x_t
+        return (h, jnp.maximum(peak, jnp.max(jnp.abs(h)))), m_t
+
+    zero = jnp.zeros((x.shape[0], x.shape[2], a.shape[1]), _F32)
+    (_, peak), m = jax.lax.scan(
+        token, (zero, jnp.zeros((), _F32)),
+        tuple(jnp.moveaxis(t, 1, 0) for t in (x, dt, b, c)))
+    return jnp.moveaxis(m, 0, 1), peak
+
+
+def selscan_xla(x, dt, a, b, c, d, *, chunk: int = 64):
+    """``selective_scan`` as plain XLA, differentiated by autodiff."""
+    batch, s, channels = x.shape
+    q = min(chunk, s)
+    xs, dts, bs, cs = pad_to_multiple(q, x, dt.astype(_F32), b, c)
+    a_t, d32 = a.astype(_F32).T, d.astype(_F32)     # (n, channels)
+
+    def by_chunk(t):
+        return jnp.moveaxis(t.reshape(batch, -1, q, t.shape[-1]), 1, 0)
+
+    def combine(early, late):
+        return early[0] * late[0], late[0] * early[1] + late[1]
+
+    @jax.checkpoint
+    def one_chunk(h, at):
+        x_c, dt_c, b_c, c_c = (t.astype(_F32) for t in at)
+        decay = jnp.exp(dt_c[:, :, None, :] * a_t)
+        fed = (dt_c * x_c)[:, :, None, :] * b_c[..., None]
+        kept, added = jax.lax.associative_scan(combine, (decay, fed), axis=1)
+        states = kept * h[:, None] + added          # (batch, q, n, channels)
+        m = jnp.sum(states * c_c[..., None], axis=2) + d32 * x_c
+        return states[:, -1], (m.astype(x.dtype), jnp.max(jnp.abs(
+            jax.lax.stop_gradient(states[:, -1]))))
+
+    _, (m, peaks) = jax.lax.scan(
+        one_chunk, jnp.zeros((batch, a.shape[1], channels), _F32),
+        tuple(map(by_chunk, (xs, dts, bs, cs))))
+    m = jnp.moveaxis(m, 0, 1).reshape(batch, -1, channels)[:, :s]
+    return m, jnp.max(peaks)
+
+
+def _sel_state(a_ref, b_ref, t, i, n, dt_t, fed, h):
+    """State index ``i`` of a block after token ``t``: ``exp(dt A) H + B dt
+    x`` (``fed`` = ``dt x``; ``B_t[i]`` a scalar out of SMEM)."""
+    return jnp.exp(dt_t * a_ref[i]) * h + b_ref[0, t * n + i] * fed
+
+
+def _sel_fwd_kernel(b_ref, c_ref, x_ref, dt_ref, a_ref, d_ref,
+                    m_ref, states_ref, carry, *, n, q):
+    """One chunk of one channel block, a token at a time.  ``b_ref``,
+    ``c_ref``: the chunk's ``(1, q x n)`` scalars in SMEM; ``x_ref``,
+    ``dt_ref``, ``m_ref`` ``(q, 8, 128)``; ``a_ref (n, 8, 128)``; ``d_ref
+    (8, 128)``; ``states_ref (n, 8, 128)``: the state LEAVING the chunk;
+    ``carry (blocks, n, 8, 128)``: every block's state from chunk to
+    chunk."""
+    k, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _start():
+        carry[j] = jnp.zeros(carry.shape[1:], _F32)
+
+    entering = carry[j]
+    d = d_ref[...]
+
+    def token(t, hs):
+        x_t, dt_t = x_ref[t], dt_ref[t]
+        fed, m_t, out = dt_t * x_t, d * x_t, []
+        for i in range(n):
+            h = _sel_state(a_ref, b_ref, t, i, n, dt_t, fed, hs[i])
+            m_t = m_t + c_ref[0, t * n + i] * h
+            out.append(h)
+        m_ref[t] = m_t
+        return tuple(out)
+
+    hs = jax.lax.fori_loop(0, q, token, tuple(entering[i] for i in range(n)))
+    for i in range(n):
+        carry[j, i] = hs[i]
+        states_ref[i] = hs[i]
+
+
+def _sel_bwd_kernel(b_ref, c_ref, x_ref, dt_ref, a_ref, d_ref, states_ref,
+                    dm_ref, dx_ref, ddt_ref, da_ref, dd_ref, db_ref, dc_ref,
+                    carry, hist, pb, pc, *, n, q, blocks, chunks):
+    """The adjoint of ``_sel_fwd_kernel``'s chunk, the chunks in reverse
+    (``states_ref``: what the chunk BEFORE this one left; the first chunk
+    starts from 0):
+    the chunk's states made again into ``hist (q + 1, n, 8, 128)`` (its
+    first entry the state that entered), then back through the tokens with
+    ``G[n] = dA_(t+1) dH_(t+1)`` as the loop's carry (``carry``: every
+    block's, from chunk to chunk).  ``da_ref (n, 8, 128)`` and ``dd_ref (8,
+    128)`` are the chunk's own sums (XLA adds the chunks'); ``db_ref``,
+    ``dc_ref`` ``(q, n, 128)`` the chunk's ``dH dt x`` and ``dm H`` summed
+    over the channel blocks (``pb``, ``pc``: ``(q, n, 8, 128)``) and the 8
+    sublanes, written at the last block."""
+    k, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _start():
+        carry[j] = jnp.zeros(carry.shape[1:], _F32)
+
+    @pl.when(j == 0)
+    def _first_block():
+        pb[...] = jnp.zeros(pb.shape, _F32)
+        pc[...] = jnp.zeros(pc.shape, _F32)
+
+    entering = jnp.where(k == chunks - 1, 0.0, states_ref[...])
+    hist[0] = entering
+
+    def forward(t, hs):
+        x_t, dt_t = x_ref[t], dt_ref[t]
+        fed, out = dt_t * x_t, []
+        for i in range(n):
+            h = _sel_state(a_ref, b_ref, t, i, n, dt_t, fed, hs[i])
+            hist[t + 1, i] = h
+            out.append(h)
+        return tuple(out)
+
+    jax.lax.fori_loop(0, q, forward, tuple(entering[i] for i in range(n)))
+    d, zero = d_ref[...], jnp.zeros((8, _LANES), _F32)
+
+    def backward(step, carried):
+        gs, das, dd = carried
+        t = q - 1 - step
+        x_t, dt_t, dm_t = x_ref[t], dt_ref[t], dm_ref[t]
+        fed, dfed, ddt_t, new_gs, new_das = dt_t * x_t, zero, zero, [], []
+        for i in range(n):
+            a_i = a_ref[i]
+            decay = jnp.exp(dt_t * a_i)
+            dh = gs[i] + c_ref[0, t * n + i] * dm_t
+            pc[t, i] += dm_t * hist[t + 1, i]
+            pb[t, i] += dh * fed
+            dfed = dfed + b_ref[0, t * n + i] * dh
+            grown = dh * hist[t, i] * decay     # to the exponent dt A
+            ddt_t = ddt_t + grown * a_i
+            new_das.append(das[i] + grown * dt_t)
+            new_gs.append(dh * decay)
+        dx_ref[t] = dfed * dt_t + d * dm_t
+        ddt_ref[t] = ddt_t + dfed * x_t
+        return tuple(new_gs), tuple(new_das), dd + dm_t * x_t
+
+    coming = carry[j]
+    gs, das, dd = jax.lax.fori_loop(
+        0, q, backward, (tuple(coming[i] for i in range(n)),
+                         (zero,) * n, zero))
+    for i in range(n):
+        carry[j, i] = gs[i]
+        da_ref[i] = das[i]
+    dd_ref[...] = dd
+
+    @pl.when(j == blocks - 1)
+    def _last_block():
+        def fold(t, _):
+            for i in range(n):
+                db_ref[t, pl.ds(i, 1), :] = jnp.sum(pb[t, i], axis=0,
+                                                    keepdims=True)
+                dc_ref[t, pl.ds(i, 1), :] = jnp.sum(pc[t, i], axis=0,
+                                                    keepdims=True)
+            return 0
+
+        jax.lax.fori_loop(0, q, fold, 0)
+
+
+def _sel_plan(batch, s, channels, n, reverse):
+    """What the two calls share: the grid ``(batch, chunk, channel
+    block)`` — the chunks in reverse for the backward —, the BlockSpecs
+    over ``(b, s, c / 128, 128)`` arrays, the scalars' SMEM blocks and the
+    scratch that carries a state a block."""
+    q, rows = _SEL_CHUNK, _SEL_BLOCK // _LANES
+    nc, blocks = s // q, channels // _SEL_BLOCK
+
+    def spec(block, index, **kw):
+        return pl.BlockSpec(block, lambda b_, k_, j_: index(
+            b_, nc - 1 - k_ if reverse else k_, j_), **kw)
+
+    return dict(
+        grid=(batch, nc, blocks), blocks=blocks, chunks=nc,
+        tokens=spec((None, q, rows, _LANES),
+                    lambda b_, k_, j_: (b_, k_, j_, 0)),
+        scalars=spec((None, None, 1, q * n),
+                     lambda b_, k_, j_: (b_, k_, 0, 0),
+                     memory_space=pltpu.SMEM),
+        a=spec((n, rows, _LANES), lambda b_, k_, j_: (0, j_, 0)),
+        d=spec((rows, _LANES), lambda b_, k_, j_: (j_, 0)),
+        states=spec((None, None, n, rows, _LANES),
+                    lambda b_, k_, j_: (b_, k_, 0, j_, 0)),
+        before=spec((None, None, n, rows, _LANES),
+                    lambda b_, k_, j_: (b_, jnp.maximum(k_ - 1, 0), 0, j_, 0)),
+        per_chunk=spec((None, None, rows, _LANES),
+                       lambda b_, k_, j_: (b_, k_, j_, 0)),
+        folded=spec((None, q, n, _LANES), lambda b_, k_, j_: (b_, k_, 0, 0)),
+        carry=pltpu.VMEM((blocks, n, rows, _LANES), _F32))
+
+
+def _sel_params(interpret):
+    if interpret:
+        return None
+    # the chunks carry the state; the blocks share a chunk's dB and dC sums
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=64 * 1024 * 1024)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _sel_fwd_call(x4, dt4, a3, b2, c2, d2, *, interpret):
+    """``x4``, ``dt4 (b, s, c / 128, 128)`` float32, ``a3 (n, c / 128,
+    128)``, ``b2``, ``c2`` ``(b, s / q, 1, q x n)`` float32, ``d2 (c / 128,
+    128)``.  Returns ``m`` like ``x4`` and the state LEAVING every chunk
+    ``(b, s / q, n, c / 128, 128)``."""
+    batch, s, lanes_rows, _ = x4.shape
+    n = a3.shape[0]
+    sp = _sel_plan(batch, s, lanes_rows * _LANES, n, reverse=False)
+    return pl.pallas_call(
+        functools.partial(_sel_fwd_kernel, n=n, q=_SEL_CHUNK),
+        grid=sp["grid"],
+        in_specs=[sp["scalars"], sp["scalars"], sp["tokens"], sp["tokens"],
+                  sp["a"], sp["d"]],
+        out_specs=[sp["tokens"], sp["states"]],
+        out_shape=[jax.ShapeDtypeStruct(x4.shape, _F32),
+                   jax.ShapeDtypeStruct(
+                       (batch, s // _SEL_CHUNK, n, lanes_rows, _LANES),
+                       _F32)],
+        scratch_shapes=[sp["carry"]],
+        compiler_params=_sel_params(interpret), interpret=interpret,
+        name="selscan_fwd",
+    )(b2, c2, x4, dt4, a3, d2)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _sel_bwd_call(x4, dt4, a3, b2, c2, d2, states, dm4, *, interpret):
+    """Gradients to ``x4`` and ``dt4`` (like them), to ``a3`` and ``d2`` a
+    chunk ``(b, s / q, ...)``, and to ``b2``, ``c2`` as partial sums ``(b,
+    s, n, 128)`` over the channels that share a lane."""
+    batch, s, lanes_rows, _ = x4.shape
+    n, q = a3.shape[0], _SEL_CHUNK
+    sp = _sel_plan(batch, s, lanes_rows * _LANES, n, reverse=True)
+    rows = _SEL_BLOCK // _LANES
+    like = jax.ShapeDtypeStruct(x4.shape, _F32)
+    folded = jax.ShapeDtypeStruct((batch, s, n, _LANES), _F32)
+    return pl.pallas_call(
+        functools.partial(_sel_bwd_kernel, n=n, q=q, blocks=sp["blocks"],
+                          chunks=sp["chunks"]),
+        grid=sp["grid"],
+        in_specs=[sp["scalars"], sp["scalars"], sp["tokens"], sp["tokens"],
+                  sp["a"], sp["d"], sp["before"], sp["tokens"]],
+        out_specs=[sp["tokens"], sp["tokens"], sp["states"],
+                   sp["per_chunk"], sp["folded"], sp["folded"]],
+        out_shape=[like, like,
+                   jax.ShapeDtypeStruct(states.shape, _F32),
+                   jax.ShapeDtypeStruct(
+                       (batch, s // q, lanes_rows, _LANES), _F32),
+                   folded, folded],
+        scratch_shapes=[sp["carry"],
+                        pltpu.VMEM((q + 1, n, rows, _LANES), _F32),
+                        pltpu.VMEM((q, n, rows, _LANES), _F32),
+                        pltpu.VMEM((q, n, rows, _LANES), _F32)],
+        compiler_params=_sel_params(interpret), interpret=interpret,
+        name="selscan_bwd",
+    )(b2, c2, x4, dt4, a3, d2, states, dm4)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _selscan(x4, dt4, a3, b2, c2, d2, interpret):
+    return _sel_fwd_call(x4, dt4, a3, b2, c2, d2, interpret=interpret)
+
+
+# What a layer checkpoint keeps of the kernels (``models/blocks/mamba1.py``
+# hands the name to its policy): the state leaving each chunk, all the
+# backward kernel needs beside the inputs.  With it and the scan's output
+# held (the block names that), no second ``selscan_fwd`` runs.
+SELSCAN_SAVED = ("selscan_states",)
+
+
+def _selscan_fwd(x4, dt4, a3, b2, c2, d2, interpret):
+    m4, states = _sel_fwd_call(x4, dt4, a3, b2, c2, d2, interpret=interpret)
+    states = checkpoint_name(states, *SELSCAN_SAVED)
+    return (m4, states), (x4, dt4, a3, b2, c2, d2, states)
+
+
+def _selscan_bwd(interpret, res, cotangents):
+    x4, dt4, a3, b2, c2, d2, states = res
+    dx4, ddt4, da, dd, db, dc = _sel_bwd_call(
+        x4, dt4, a3, b2, c2, d2, states, cotangents[0], interpret=interpret)
+    scalars = lambda t: jnp.sum(t, -1).reshape(b2.shape)  # noqa: E731
+    return (dx4, ddt4, jnp.sum(da, (0, 1)), scalars(db), scalars(dc),
+            jnp.sum(dd, (0, 1)))
+
+
+_selscan.defvjp(_selscan_fwd, _selscan_bwd)
+
+
+def selscan_kernels(x, dt, a, b, c, d):
+    """``selective_scan`` through the Pallas pair (compiled on the TPU,
+    interpreted elsewhere).  XLA pads the sequence to whole chunks (tokens
+    whose ``dt`` is 0: no decay, no input), turns x and dt to ``(b, s, c /
+    128, 128)`` float32 and B and C to a chunk's scalars, and turns ``m``
+    back; the states the forward kernel writes (the one LEAVING each chunk)
+    give the statistic."""
+    batch, s, channels = x.shape
+    n = a.shape[1]
+    xs, dts, bs, cs = pad_to_multiple(_SEL_CHUNK, x, dt.astype(_F32), b, c)
+    padded = xs.shape[1]
+    lanes = lambda t: t.astype(_F32).reshape(  # noqa: E731
+        *t.shape[:-1], channels // _LANES, _LANES)
+    chunks = lambda t: t.astype(_F32).reshape(  # noqa: E731
+        batch, padded // _SEL_CHUNK, 1, _SEL_CHUNK * n)
+    m4, states = _selscan(
+        lanes(xs), lanes(dts), lanes(a.astype(_F32).T), chunks(bs),
+        chunks(cs), lanes(d), attention._interpret_default())
+    peak = jnp.max(jnp.abs(jax.lax.stop_gradient(states)))
+    return m4.reshape(batch, padded, channels)[:, :s].astype(x.dtype), peak

@@ -591,11 +591,12 @@ PHASES = ("forward", "remat", "backward", "optimizer")
 SCAN = "scan"
 # How the ``name=`` of the program's Pallas kernels start (ops/attention.py,
 # ops/moe.py, ops/ssm.py, ops/streams.py, ops/delta.py, ops/rotary.py,
-# ops/sparse_attention.py): the
+# ops/sparse_attention.py; ``selscan_``: ops/ssm.py's selective scan): the
 # kernel rows of ``step_breakdown``.  A step scope that starts the same way
 # (``hc_map``, ``hc_mix``) is no kernel's name.
 KERNEL_NAMES = ("flash_", "moe_gmm", "moe_tgmm", "ssd_", "gated_norm_", "hc_",
-                "delta_", "rope_", "kdarule_", "causal_conv_", "sparse_")
+                "delta_", "rope_", "kdarule_", "causal_conv_", "sparse_",
+                "selscan_")
 _SCOPE_TOKENS = re.compile(r"[^/()]+")
 
 

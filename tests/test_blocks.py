@@ -45,6 +45,14 @@ FIELDS = {
     "kda": dict(linear_attn_config={"num_heads": 1, "head_dim": 128,
                                     "short_conv_kernel_size": 4}),
     "conv": {},
+    # an inner width of 1024 (16 x 64), so that the Pallas pair runs
+    # (interpreted) and ITS residual is made
+    "mamba1": dict(s6_expand=16, s6_state=4),
+    "diff_sliding": dict(sliding_window=24, attn_bias=True),
+    "diff_full": {},
+    # the two readers stand behind a layer that publishes what they read
+    "diff_cross": dict(after="diff_full"),
+    "gmu": dict(after="mamba1", s6_state=4),
     "none": {},   # the empty block, a mixer and an FFN
     "dense": {},
     # the routed experts in a latent, so that its scope opens
@@ -62,13 +70,24 @@ def _model(role, name):
     FFN): ``(cfg, kind, the two blocks)``.  A layer without an FFN is
     spelled by a pattern string, as the public files of such models do."""
     mixer, ffn = (name, "dense") if role == "mixer" else ("attention", name)
+    fields = dict(FIELDS[name])
+    before = fields.pop("after", None)   # a reader's publisher: one layer
     layers = (dict(layer_pattern="**") if ffn == "none" else dict(
         layer_types=() if mixer == "latent" else (mixer,) * 2))
+    if before:
+        layers = dict(layer_types=(before, mixer, mixer), num_layers=3)
     cfg = LlamaConfig.tiny(
-        attn_impl="flash", remat=True, max_seq_len=SEQ, **layers,
-        **FIELDS[name])
-    assert cfg.kind_runs == (((mixer, ffn), 2),)
+        attn_impl="flash", remat=True, max_seq_len=SEQ, **layers, **fields)
+    assert cfg.kind_runs[-1] == ((mixer, ffn), 2)
+    assert cfg.kind_runs[:-1] == (((before, "dense"), 1),) * bool(before)
     return cfg, (mixer, ffn), (MIXERS[mixer], FFNS[ffn])
+
+
+def _publisher(cfg):
+    """The block ``_model`` put before a reader's two layers, or the empty
+    one: what it opens and reports stands beside the reader's."""
+    return MIXERS[cfg.layer_kinds[0][0]] if len(cfg.kind_runs) > 1 else (
+        MIXERS["none"])
 
 
 # -- (a) the contract, every registered block --------------------------------
@@ -79,6 +98,8 @@ def test_a_model_holds_exactly_the_tensors_a_block_declares(role, name):
     declared = {**mixer.shapes(cfg), **ffn.shapes(cfg)}
     stack = init_params(jax.random.PRNGKey(0), cfg)["layers"]
     axes = param_logical_axes(cfg)["layers"]
+    if len(cfg.kind_runs) > 1:  # behind a publisher: the block's own run
+        stack, axes = stack[-1], axes[-1]
     assert list(stack) == list(declared) == list(axes)
     for key, p in declared.items():
         assert isinstance(p, Param)
@@ -103,12 +124,15 @@ def _eqns(jaxpr):
 def _names_made(cfg, kind):
     """The ``checkpoint_name``s in the gradient's program of one layer of
     ``kind`` under the layer checkpoint."""
-    layer_fn = llama._checkpoint(llama._make_layer_fn(cfg, None, None,
-                                                      kind=kind))
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, SEQ, cfg.embed_dim))
+    reads = _published_by(cfg, MIXERS[kind[0]], x)
+    layer_fn = llama._checkpoint(llama._make_layer_fn(
+        cfg, None, None, kind=kind, shared=reads))
     stacks = init_params(jax.random.PRNGKey(0), cfg)["layers"]
     lp = jax.tree.map(lambda a: a[0],
                       dict(llama._runs(stacks, cfg.kind_runs))[kind])
-    x = jax.random.normal(jax.random.PRNGKey(2), (2, SEQ, cfg.embed_dim))
+    if MIXERS[kind[0]].indexed:
+        lp = dict(lp, layer_index=jnp.float32(1.0))
 
     def out(x, lp):
         (y, _), _ = layer_fn((x, llama._zero_aux(cfg)), lp)
@@ -117,6 +141,54 @@ def _names_made(cfg, kind):
     jaxpr = jax.make_jaxpr(jax.grad(out, argnums=(0, 1)))(x, lp).jaxpr
     return {e.params["name"] for e in _eqns(jaxpr)
             if e.primitive.name == "name"}
+
+
+def _published_by(cfg, reader, x):
+    """What ``reader`` reads, made by the layer before it (``_model`` puts
+    its publisher there), from the stream ``x``: {} for a block that reads
+    nothing."""
+    if not reader.reads:
+        return {}
+    kind = cfg.layer_kinds[0]
+    stack = init_params(jax.random.PRNGKey(0), cfg)["layers"][0]
+    lp = jax.tree.map(lambda a: a[0], stack)
+    if MIXERS[kind[0]].indexed:
+        lp = dict(lp, layer_index=jnp.float32(0.0))
+    made = llama._make_layer_fn(
+        cfg, None, None, kind=kind, publish=reader.reads)(
+            (x, llama._zero_aux(cfg)), lp)[1][1]
+    assert set(made) == set(reader.reads) <= set(MIXERS[kind[0]].publishes)
+    return made
+
+
+@pytest.mark.parametrize("role,name", ENTRIES)
+def test_a_block_publishes_and_reads_what_it_declares(role, name):
+    """A mixer's ``apply`` returns a third value, the arrays it
+    ``publishes`` by name, exactly where it declares any; one that
+    ``reads`` takes them as ``shared`` and fails without; FFNs do neither;
+    ``indexed`` is a mixer's that reads ``lp["layer_index"]``."""
+    cfg, kind, (mixer, ffn) = _model(role, name)
+    assert not (ffn.publishes or ffn.reads or ffn.indexed)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, SEQ, cfg.embed_dim))
+    ctx = llama.Ctx(cfg, None, lambda x, ax: x, False)
+    lp = jax.tree.map(lambda a: a[0], dict(llama._runs(
+        init_params(jax.random.PRNGKey(0), cfg)["layers"],
+        cfg.kind_runs))[kind])
+    shared = _published_by(cfg, mixer, x)
+    kw = dict(shared=shared) if mixer.reads else {}
+    aux = llama._zero_aux(cfg)
+    if mixer.indexed:
+        with pytest.raises(KeyError, match="layer_index"):
+            mixer.apply(ctx, x, aux, lp, **kw)
+        lp = dict(lp, layer_index=jnp.float32(1.0))
+    out = mixer.apply(ctx, x, aux, lp, **kw)
+    assert len(out) == (3 if mixer.publishes else 2)
+    if mixer.publishes:
+        assert set(out[2]) == set(mixer.publishes)
+        assert all(a.shape[:2] == x.shape[:2] for a in out[2].values())
+    if mixer.reads:
+        with pytest.raises(TypeError, match="shared"):
+            mixer.apply(ctx, x, aux, lp)
 
 
 @pytest.mark.parametrize("role,name", ENTRIES)
@@ -140,15 +212,16 @@ def test_a_step_opens_the_scopes_a_block_declares_and_no_other(role, name):
     # on one device: the exchange opens its scope only over an ``ep`` axis
     # (tests/test_moe.py::test_tokens_are_split_over_ep_outside_the_experts)
     assert seen - {None, "scan"} == {
-        "embed", *mixer.scopes, *ffn.scopes, "lm_head", "loss",
-        "optimizer"} - {"moe_exchange"}
+        "embed", *mixer.scopes, *ffn.scopes, *_publisher(cfg).scopes,
+        "lm_head", "loss", "optimizer"} - {"moe_exchange"}
     assert set(mixer.scopes) | set(ffn.scopes) <= set(STEP_SCOPES)
 
 
 @pytest.mark.parametrize("role,name", ENTRIES)
 def test_every_statistic_a_block_declares_is_a_metric(role, name):
     cfg, _, (mixer, ffn) = _model(role, name)
-    declared = {**mixer.stats(cfg), **ffn.stats(cfg)}
+    declared = {**mixer.stats(cfg), **ffn.stats(cfg),
+                **_publisher(cfg).stats(cfg)}
     assert set(declared.values()) <= {"sum", "max", "min", "mean"}
     params = init_params(jax.random.PRNGKey(0), cfg)
     _, (metrics, _) = jax.jit(lambda p: loss_and_counts(
@@ -159,7 +232,7 @@ def test_every_statistic_a_block_declares_is_a_metric(role, name):
 
 # -- (b) the step's scopes, as they were --------------------------------------
 
-def test_step_scopes_are_the_35_names_in_their_order():
+def test_step_scopes_are_the_41_names_in_their_order():
     assert STEP_SCOPES == (
         "embed", "attn_qkv", "attention", "attn_out", "ffn",
         "moe_route", "moe_exchange", "moe_dispatch", "moe_experts",
@@ -169,6 +242,7 @@ def test_step_scopes_are_the_35_names_in_their_order():
         "gdn_in", "gdn_conv", "gdn_scan", "gdn_out",
         "kda_in", "kda_conv", "kda_scan", "kda_out",
         "sconv_in", "sconv_gate", "sconv_out",
+        "s6_in", "s6_conv", "s6_scan", "s6_out", "attn_diff", "gmu",
         "hc_map", "hc_mix", "mtp_in",
         "lm_head", "loss", "optimizer")
 

@@ -387,6 +387,9 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
     want -= {"moe_latent"}
     # no indexer that picks a query's keys (tests/test_keye.py has one)
     want -= {"dsa_index", "dsa_select", "dsa_loss"}
+    # no Mamba-1, differential-attention or memory-unit layer
+    # (tests/test_phi4flash.py has a model that opens these)
+    want -= {"s6_in", "s6_conv", "s6_scan", "s6_out", "attn_diff", "gmu"}
     want -= {"loss"} if mesh is None or moe else set()  # tp splits it
     # the embedding takes the rows its tokens name: a gather, never a
     # matmul.  On one device the scope shows nothing here; under a mesh

@@ -1165,3 +1165,29 @@ def test_rope_stays_on_the_4d_view_after_a_per_head_norm():
     assert rope_ops(2) == (True, False)
     assert rope_ops(1, head_dim=128) == (False, True)
     assert rope_ops(2, head_dim=128) == (False, True)
+
+
+def test_the_selective_scans_kernels_compile_at_the_published_widths(
+        one_chip):
+    """``selscan_fwd`` / ``selscan_bwd`` (``ops/ssm.py``) at what a Mamba-1
+    layer of ``phi4flash-train-s16384`` hands them: 16384 tokens of 5120
+    channels as ``(b, s, 40, 128)`` float32, 16 state numbers a channel, the
+    chunks' scalars in SMEM; the backward's three ``(chunk, n, 8, 128)``
+    arrays in VMEM under its limit."""
+    from ray_tpu.ops import ssm
+
+    batch, s, channels, n = 1, 16384, 5120, 16
+    rows, chunks = channels // 128, s // ssm._SEL_CHUNK
+    f32 = functools.partial(_shape, dtype=jnp.float32, sharding=one_chip)
+    tokens, a3, d2 = f32((batch, s, rows, 128)), f32((n, rows, 128)), f32(
+        (rows, 128))
+    scalars = f32((batch, chunks, 1, ssm._SEL_CHUNK * n))
+    states = f32((batch, chunks, n, rows, 128))
+    fwd = jax.jit(functools.partial(ssm._sel_fwd_call, interpret=False)
+                  ).lower(tokens, tokens, a3, scalars, scalars, d2).compile()
+    bwd = jax.jit(functools.partial(ssm._sel_bwd_call, interpret=False)
+                  ).lower(tokens, tokens, a3, scalars, scalars, d2, states,
+                          tokens).compile()
+    assert "selscan_fwd" in fwd.as_text() and _has_kernel(fwd)
+    assert "selscan_bwd" in bwd.as_text() and _has_kernel(bwd)
+    assert ssm.selscan_kernels_fit(channels)
