@@ -81,6 +81,23 @@ which is exact on garbage.  Where every expert is held (``E' == E``)
 every row is live by construction, and the gathers are the single ones,
 unmasked.
 
+A row buffer is BORN IN THE LAYER THAT FILLS IT (``_row_buffer``): the
+kernel that writes nothing takes one operand it never reads, an array the
+layer has just made.  Without one the call depends on nothing, and under
+``jax.grad`` of a ``lax.scan`` over several layers what depends on nothing
+the loop carries is moved out of the forward loop (JAX's partial evaluation
+of the scan: the compiled ``op_name`` loses its ``while/body``) and handed
+in as a constant; a constant of the outer loop may not be written by the
+inner one, so every layer copied two whole static buffers before its loops
+over the live rows wrote a quarter of them (``copy.264`` / ``copy.270`` of
+``bf16[131072, 2304]``, 5.4 ms a step each, in Mellum2's cell;
+``copy.1439`` / ``copy.1445`` in JoyAI's, ``copy.455`` / ``copy.461`` in
+Keye-VL's, ``copy.664`` / ``copy.670`` in Xing4's).  Each site's operand
+DIFFERS, because two calls equal in operand, shape and dtype are one call
+to XLA's common-subexpression pass, and one buffer under two loops is
+copied again (PERF.md §6, PR 75; ``tests/test_moe_share.py`` holds both
+properties on a scan's jaxpr).
+
 Inside a ``shard_map`` (a Pallas kernel has no partitioning rule) the
 layer takes the names of the mesh axes: tokens are split over
 ``token_axes`` AND over ``expert_axis``, whose ranks are data parallel
@@ -570,16 +587,33 @@ def _row_index(flat, gates, rows):
             jnp.pad(row_gate, (0, rows - n)))
 
 
-def _row_buffer(shape, dtype):
+def _row_buffer(shape, dtype, after):
     """A buffer nobody has written: what a loop over the live rows fills
     as far as it goes.  A kernel that writes nothing, because a
     ``jnp.zeros`` of ``(32768, 3584)`` is a pass of 0.29 ms on a v5e (three
     a layer) and 0.4 GB of the benchmark's fullest program (PERF.md §6,
-    PR 39)."""
+    PR 39).
+
+    ``after`` is an operand the kernel never reads (``pl.ANY``: no DMA, no
+    byte moved): it makes the buffer the LAYER's, where a call that depends
+    on nothing is lifted out of the forward loop over a run of layers and
+    then copied whole by every layer (the module docstring; PERF.md §6, PR
+    75).  Two calls with the same operand, shape and dtype are ONE call to
+    the compiler's common-subexpression pass, whose one buffer two loops
+    write and one of them copies, so each site hands over a DIFFERENT
+    array, one its layer has made in HBM and its loop reads anyway (no
+    value is materialised for the call's sake): the tokens in
+    ``_dispatch``, the rows to sum in ``_live_token_sum`` (the experts'
+    output forward, the rows' gradient backward), the tokens' cotangent in
+    ``_combine_bwd`` — not ``y_rows`` there, which a layer whose backward
+    reruns ``_combine`` hands to the rerun's sum as well."""
     return pl.pallas_call(
-        lambda out_ref: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        lambda after_ref, out_ref: None,
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        interpret=attention._interpret_default(), name="moe_row_buffer")()
+        interpret=attention._interpret_default(), name="moe_row_buffer")(
+            after)
 
 
 def _live_trips(live, rows):
@@ -675,7 +709,7 @@ def _live_token_sum(rows, slot_row, weights, live, traced_under):
             out, total.astype(rows.dtype), start, 0)
 
     runs = _over_live_rows(live, n, runs_of,
-                           _row_buffer((n, rows.shape[1]), rows.dtype))
+                           _row_buffer((n, rows.shape[1]), rows.dtype, rows))
     count = jnp.sum(below, axis=1, dtype=jnp.int32)
     end = jnp.maximum(jnp.cumsum(count) - 1, 0)
     return jnp.where((count > 0)[:, None], _take(runs, end),
@@ -708,7 +742,7 @@ def _dispatch(x, row_token, slot_row, live):
             out, _take(x, index), start, 0)
 
     return _over_live_rows(live, rows, gather,
-                           _row_buffer((rows, x.shape[1]), x.dtype))
+                           _row_buffer((rows, x.shape[1]), x.dtype, x))
 
 
 def _dispatch_fwd(x, row_token, slot_row, live):
@@ -766,8 +800,9 @@ def _combine_bwd(res, d_out):
 
         # a row that is not live moves no gate: its gradient is 0
         d_rows, d_row_gate = _over_live_rows(
-            live, rows, chunk_of, (_row_buffer(y_rows.shape, y_rows.dtype),
-                                   jnp.zeros((rows,), jnp.float32)))
+            live, rows, chunk_of,
+            (_row_buffer(y_rows.shape, y_rows.dtype, d_out),
+             jnp.zeros((rows,), jnp.float32)))
     d_gates = _place(d_row_gate[:n], row_slot[:n]).reshape(gates.shape)
     return d_rows, d_gates.astype(gates.dtype), None, None, None, None, None
 

@@ -92,8 +92,8 @@ def poisoned(monkeypatch):
     from before the poison may serve."""
     jax.clear_caches()
     monkeypatch.setattr(
-        moe, "_row_buffer", lambda shape, dtype: jnp.full(shape, jnp.nan,
-                                                          dtype))
+        moe, "_row_buffer", lambda shape, dtype, after: jnp.full(
+            shape, jnp.nan, dtype))
     call = moe._gmm_call
 
     def call_with_poisoned_tails(form, operands, sched, *rest, **kw):

@@ -3,9 +3,13 @@ E``) with every row past the live ones poisoned, the token-side sum of a
 share and the counter of the rows it reads.  CPU; the Pallas kernels run in
 interpret mode."""
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import Literal
 
 from moe_layer import (
     D, E, K, T, against_the_loop, layer_inputs, per_token_loop, poisoned,
@@ -149,3 +153,159 @@ def test_token_rows_read_share_reads_what_the_index_says(
         -(-live // trip) * (trip + K - 1) + T) / (T * K)
     assert float(stats["token_rows_read_share"]) == pytest.approx(want)
     assert float(stats["held_share"]) == pytest.approx(live / (T * K))
+
+
+# Two expert layers as ONE ``lax.scan`` under the layer checkpoint, a share
+# of the experts held, and a nonlinearity behind the block, so that the
+# backward pass reruns the combine as well (a layer of several residual
+# streams does): every call site of ``_row_buffer`` in both loops.
+_SCAN_FIRST, _SCAN_HELD = 2, 3
+
+
+def _two_layer_scan(share):
+    """``(loss, arguments)``: the scan's ``sum(out ** 2)`` as a function of
+    the tokens and the two layers' stacked tensors — of the chip's share
+    (``first_expert``, ``live`` the device's), or every expert held
+    (``live`` None) with the absent experts' down weights at zero, which
+    adds what a choice nobody computes adds."""
+    x = layer_inputs(0)[0]
+    stacked = tuple(jnp.stack(pair) for pair in zip(layer_inputs(0)[1:],
+                                                    layer_inputs(1)[1:]))
+    held = slice(_SCAN_FIRST, _SCAN_FIRST + _SCAN_HELD)
+    here = jnp.zeros((E, 1, 1)).at[held].set(1.0)
+
+    @functools.partial(
+        jax.checkpoint, policy=jax.checkpoint_policies.save_only_these_names(
+            *moe.SAVED_RESIDUALS))
+    def layer(x, tensors):
+        norm, router, w_gate, w_up, w_down = tensors
+        if share:
+            experts = (w_gate[held], w_up[held], w_down[held])
+        else:
+            experts = (w_gate, w_up, w_down * here)
+        out, _ = moe.moe_block(x, norm, router, *experts, num_selected=K,
+                               tile=16,
+                               first_expert=_SCAN_FIRST if share else 0)
+        return jnp.tanh(out), None
+
+    def loss(x, *stacked):
+        return jnp.sum(jax.lax.scan(layer, x, stacked)[0] ** 2)
+
+    return loss, (x, *stacked)
+
+
+# Who asked for a buffer, by the scope its call stands under and whether it
+# is the layer's own pass (a ``custom_vjp_call``: forward, or rerun under
+# the checkpoint) or a gradient rule's equations.
+_SITE_OF = {("moe_dispatch", True): "_dispatch",
+            ("moe_combine", True): "_live_token_sum",
+            ("moe_combine", False): "_combine_bwd",
+            ("moe_dispatch", False): "_live_token_sum"}
+
+
+def _row_buffer_calls(jaxpr):
+    """The ``moe_row_buffer`` calls under each ``scan`` of ``jaxpr`` that
+    has any, a list a scan: ``(site, operand, shape, dtype)`` with ``site``
+    the function that asked for the buffer (``_SITE_OF``) and ``operand``
+    the variable of the scan's BODY the call's operand is (followed out of
+    the calls in between), or ``const`` / ``carry`` / ``xs`` where it is
+    none the body made."""
+    scans = []
+
+    def inner_jaxprs(eqn):
+        for value in eqn.params.values():
+            value = getattr(value, "jaxpr", value)
+            if hasattr(value, "eqns") and hasattr(value, "invars"):
+                yield value
+
+    def walk(jaxpr, made, calls, scopes="", own_pass=False):
+        for var in jaxpr.constvars:
+            made[var] = "const"
+        for eqn in jaxpr.eqns:
+            operands = ["const" if isinstance(v, Literal)
+                        else made.get(v, "const") for v in eqn.invars]
+            under = f"{scopes}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "scan":
+                body = eqn.params["jaxpr"].jaxpr
+                consts, carry = (eqn.params["num_consts"],
+                                 eqn.params["num_carry"])
+                kinds = (["const"] * consts + ["carry"] * carry
+                         + ["xs"] * (len(body.invars) - consts - carry))
+                found = walk(body, dict(zip(body.invars, kinds)), [])
+                if found:
+                    scans.append(found)
+            elif (eqn.primitive.name == "pallas_call"
+                  and eqn.params["name"] == "moe_row_buffer"):
+                scope, = (s for s in ("moe_dispatch", "moe_combine")
+                          if s in under)
+                out = eqn.outvars[0].aval
+                calls.append((_SITE_OF[scope, own_pass],
+                              operands[0] if operands else "const",
+                              out.shape, out.dtype))
+            else:
+                for sub in inner_jaxprs(eqn):
+                    # a call hands its operands in one for one; whatever
+                    # else (a loop's body) makes its own
+                    known = (dict(zip(sub.invars, operands))
+                             if len(sub.invars) == len(operands) else {})
+                    walk(sub, {v: known.get(v, v) for v in sub.invars},
+                         calls, under,
+                         own_pass or eqn.primitive.name == "custom_vjp_call")
+            for var in eqn.outvars:
+                made[var] = var
+        return calls
+
+    assert not walk(jaxpr, {}, []), "a row buffer outside every scan"
+    return scans
+
+
+@functools.lru_cache(maxsize=None)
+def _scanned_row_buffers():
+    loss, args = _two_layer_scan(share=True)
+    return _row_buffer_calls(jax.make_jaxpr(jax.value_and_grad(
+        loss, argnums=range(6)))(*args).jaxpr)
+
+
+@pytest.mark.parametrize(
+    "site", ["_dispatch", "_live_token_sum", "_combine_bwd"])
+def test_row_buffer_is_made_by_the_layer_that_fills_it(site):
+    """The two properties the copies of a whole row buffer turned on
+    (PERF.md §6, PR 75), held on the jaxpr of a differentiated scan of two
+    layers: every ``moe_row_buffer`` call takes an operand that an equation
+    of the scan's BODY made — a call without one depends on nothing, the
+    scan's partial evaluation moves it out of the forward loop and hands
+    the buffer in as a constant, and each layer copies it whole before
+    writing into it — and no two calls of a body take the same operand at
+    the same shape and dtype, which the compiler's common-subexpression
+    pass would make ONE buffer under two loops.  The forward loop holds the
+    dispatch's and the combine's sum; the backward loop their reruns, the
+    combine's gradient and the dispatch's gradient's sum."""
+    forward, backward = _scanned_row_buffers()
+    assert sorted(c[0] for c in forward) == [
+        "_dispatch", "_live_token_sum"]
+    assert sorted(c[0] for c in backward) == [
+        "_combine_bwd", "_dispatch", "_live_token_sum", "_live_token_sum"]
+    for calls in (forward, backward):
+        for called_by, operand, shape, _ in calls:
+            if called_by == site:
+                assert not isinstance(operand, str), (site, operand)
+                assert shape == (T * K, D)
+        keys = [(id(operand), shape, str(dtype))
+                for _, operand, shape, dtype in calls]
+        assert len(set(keys)) == len(keys)
+
+
+def test_scan_of_two_share_layers_equals_the_scan_with_every_expert_held():
+    """The same scan, value and every gradient, against the form in which
+    every expert is held (``live`` None: no buffer, no loop over live rows)
+    and the absent experts' down weights are zero: the buffers' real
+    kernel, its unread operand included, in interpret mode."""
+    (share, args), (whole, _) = (_two_layer_scan(s) for s in (True, False))
+    got, want = (jax.jit(jax.value_and_grad(f, argnums=range(6)))(*args)
+                 for f in (share, whole))
+    assert float(abs(got[0] - want[0])) < 1e-5 * float(want[0])
+    for name, g, r in zip(("x", "norm", "router", "w_gate", "w_up",
+                           "w_down"), got[1], want[1]):
+        assert bool(jnp.isfinite(g).all()), name
+        assert float(jnp.abs(g - r).max()) < 1e-5 * float(
+            jnp.abs(r).max()) + 1e-6, name

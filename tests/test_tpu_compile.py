@@ -850,6 +850,19 @@ def test_kimi_linear_kda_layer_train_step_compiles(one_chip, as_on_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9
 
 
+@functools.lru_cache(maxsize=None)
+def _solar_open2_step(one_chip):
+    """``(cfg, compiled step)`` of ``solaropen2-train-s4096`` as the cell
+    runs it: the benchmark's configuration file at 1 x 4096, for the
+    described chip (a minute and a half, once a process: two tests read
+    it)."""
+    cfg = _benchmark_cfg("solar-open2-250b-1of32")
+    opt = default_optimizer()
+    return cfg, make_train_step(cfg, opt).lower(
+        _state_shapes(cfg, opt, one_chip),
+        {"tokens": _shape((1, 4097), jnp.int32, one_chip)}).compile()
+
+
 def test_the_solar_open2_cells_step_program_fits_the_chip(one_chip,
                                                            as_on_chip):
     """``solaropen2-train-s4096``'s WHOLE step as the cell runs it — the
@@ -862,21 +875,47 @@ def test_the_solar_open2_cells_step_program_fits_the_chip(one_chip,
     later change that pushes the fullest KDA cell over the chip fails here
     before the driver's run; the softmax layer runs the flash kernels, the
     KDA layers ``kdarule_*`` and the experts ``moe_gmm*``."""
-    cfg = _benchmark_cfg("solar-open2-250b-1of32")
+    cfg, compiled = _solar_open2_step(one_chip)
     assert cfg.kind_runs == ((("attention", "moe"), 1), (("kda", "moe"), 3))
     assert (cfg.embed_dim, cfg.kda_inner, cfg.kda_neg_eigval,
             cfg.local_experts, cfg.vocab_size) == (4096, 8192, True, 10,
                                                    24576)
-    opt = default_optimizer()
-    compiled = make_train_step(cfg, opt).lower(
-        _state_shapes(cfg, opt, one_chip),
-        {"tokens": _shape((1, 4097), jnp.int32, one_chip)}).compile()
     hlo = compiled.as_text()
     for kernel in (*_KDA_KERNELS, "flash_fwd", "flash_dkv", "moe_gmm_swiglu"):
         assert kernel in hlo, kernel
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == pytest.approx(8.526e9, rel=1e-3)
     assert 0.25 * 16.909e9 < mem.peak_memory_in_bytes < 16.909e9
+
+
+def test_a_share_layers_row_buffers_are_made_in_the_layer_loop_and_not_copied(
+        one_chip, as_on_chip):
+    """The same compiled step: the static row buffers of a share's expert
+    layers (``ops/moe.py::_row_buffer``, 32768 rows x 4096 here, 268 MB)
+    are made by the layer that fills them.  Every ``moe_row_buffer`` call
+    takes an operand and keeps the layer loop in its ``op_name`` — a call
+    that depends on nothing was lifted out of the forward loop of the run
+    of three layers when the scan was differentiated (``jit(step)/
+    moe_dispatch/...``, no ``while/body``) —, the run's forward and
+    backward loops hold their own calls, and NO ``copy`` of the buffer's
+    shape is left: a lifted buffer rode through the loop as a constant and
+    every layer copied it whole before writing a share of it (PERF.md §6,
+    PR 75)."""
+    hlo = _solar_open2_step(one_chip)[1].as_text()
+    buffer = "bf16[32768,4096]"
+    calls = [line for line in hlo.splitlines()
+             if re.match(r"\s*%?moe_row_buffer[\w.]* = ", line)]
+    # forward 2 a layer; backward the dispatch's rerun, the combine's
+    # gradient and the dispatch's gradient's sum: inlined for the run of
+    # one layer, once each in the loops of the run of three
+    assert len(calls) == 2 * (2 + 3)
+    for line in calls:
+        assert f" {buffer}" in line
+        assert re.search(r"custom-call\(%[\w.\-]+\)", line), line[:200]
+        assert "while/body" in re.search(r'op_name="([^"]*)"', line).group(1)
+    assert not [line[:200] for line in hlo.splitlines()
+                if re.match(rf"\s*%?[\w.\-]+ = {re.escape(buffer)}\S* copy\(",
+                            line)]
 
 
 def test_the_selection_kernels_compile_at_the_published_widths(one_chip,
