@@ -30,6 +30,9 @@ MIXERS = {
     # softmax attention over the keys a learned indexer picks: what a model
     # with an ``sa_config`` runs for ``attention``
     "indexed": attention.INDEXED,
+    # ... under the block rule, over two streams of one sequence: what a
+    # model with a ``block_diffusion`` group runs for ``attention``
+    "block_attention": attention.BLOCK_RULE,
     "mamba": mamba.BLOCK,
     "linear_attention": delta.BLOCK,
     "kda": kda.BLOCK,      # ... its decay a vector over the key channels

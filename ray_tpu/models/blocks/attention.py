@@ -89,6 +89,13 @@ SELECTED_OFF = "dsa_selected_off"
 TIE_WALK_SHARE = "dsa_tie_walk_share"
 INDEX_STATS = {INDEX_LOSS: "mean", SELECTED_SHARE: "mean",
                SELECTED_OFF: "sum", TIE_WALK_SHARE: "mean"}
+# A block-diffusion layer's: the (q, k) pairs its attention computes over
+# the pairs the block rule needs (the whole square of both streams where no
+# flash kernel runs), and the pairs on which the kernels' schedule and the
+# rule's four cases disagree over one strip of rows (0: none).
+BLOCK_EXECUTED = "attn_bd_executed_share"
+BLOCK_MASK_OFF = "bd_mask_off"
+BLOCK_STATS = {BLOCK_EXECUTED: "max", BLOCK_MASK_OFF: "sum"}
 
 
 def _attention_shapes(cfg):
@@ -163,6 +170,11 @@ def _rope_tables(ctx: Ctx, windowed: bool, s: int, dim: int):
     and the latent one alike (each under the scope ``rope``, inside
     ``attn_qkv``, with the rotations)."""
     offset = jax.lax.axis_index(AXIS_SP) * s if ctx.sp_manual else 0
+    if ctx.cfg.block_diffusion:
+        # two streams of one sequence: a noised token and its clean copy
+        # share a position, so each half is rotated by arange(s / 2)
+        return tuple(jnp.concatenate([t, t]) for t in scaled_rope(
+            s // 2, dim, *ctx.cfg.rope_rule(windowed)))
     return scaled_rope(s, dim, *ctx.cfg.rope_rule(windowed), offset=offset)
 
 
@@ -217,21 +229,24 @@ def _q_prescale(cfg):
     return attention.q_prescale(_sm_scale(cfg), cfg.dtype)
 
 
-def _attention(q, k, v, cfg, mesh, window=None, q_prescaled=False):
+def _attention(q, k, v, cfg, mesh, window=None, q_prescaled=False,
+               block=None):
     """Dispatch to the configured attention impl; ring / ulysses manage the
     'sp' axis themselves.  ``window``: the keys a query sees, where fewer
     than all before it (flash and the reference alone take one).
     ``q_prescaled``: q comes times the flash kernels' pre-scale
-    (``_q_prescale``: flash alone)."""
+    (``_q_prescale``: flash alone).  ``block``: the rows are two streams
+    under the block rule (flash and the reference alone)."""
     impl, scale = cfg.attn_impl, _sm_scale(cfg)
     if mesh is None:
         # Ring/ulysses degenerate to plain attention on one device.
         if impl == "flash":
             return flash_attention(q, k, v, causal=True, sm_scale=scale,
-                                   window=window, q_prescaled=q_prescaled)
+                                   window=window, q_prescaled=q_prescaled,
+                                   block=block)
         k, v = repeat_kv_heads(q, k, v)
         return mha_reference(q, k, v, causal=True, sm_scale=scale,
-                             window=window)
+                             window=window, block=block)
     if window is not None and impl in ("ring", "ulysses"):
         raise NotImplementedError(
             "a window over a sequence split over 'sp' (ring, ulysses): each "
@@ -244,7 +259,7 @@ def _attention(q, k, v, cfg, mesh, window=None, q_prescaled=False):
     if impl == "reference":
         k, v = repeat_kv_heads(q, k, v)
         return mha_reference(q, k, v, causal=True, sm_scale=scale,
-                             window=window)
+                             window=window, block=block)
     # flash under a mesh: pallas has no SPMD partitioning rule, so run the
     # kernel per-shard: batch over (dp,fsdp,ep), heads over tp, seq replicated.
     # Manual over EVERY mesh axis — the TPU lowering refuses a Mosaic
@@ -258,7 +273,7 @@ def _attention(q, k, v, cfg, mesh, window=None, q_prescaled=False):
     fn = manual_shard_map(
         lambda q_, k_, v_: flash_attention(
             q_, k_, v_, causal=True, sm_scale=scale, window=window,
-            q_prescaled=q_prescaled),
+            q_prescaled=q_prescaled, block=block),
         set(mesh.axis_names), in_specs=(spec, spec, spec),
         out_specs=spec, mesh=mesh)
     return fn(q, k, v)
@@ -370,6 +385,41 @@ def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
     q, k, v, gate, prescaled, _ = _qkv(ctx, x, lp, windowed)
     return _attend(ctx, x, aux, q, k, v, lp, residual, gate, windowed,
                    q_prescaled=prescaled)
+
+
+def _block_stats(cfg, length: int, d: int):
+    """``BLOCK_STATS`` of one call over two streams of ``length``
+    positions, from shapes alone (``attention.block_tile_counts``,
+    ``attention.block_schedule_off``)."""
+    block = cfg.bd_block
+    tiles = attention.block_tiles(
+        length, block, d, cfg.dtype) if cfg.attn_impl == "flash" else None
+    needed = attention.block_needed_pairs(length, block)
+    if tiles is None:
+        return {BLOCK_EXECUTED: jnp.float32(4 * length * length / needed),
+                BLOCK_MASK_OFF: jnp.float32(0.0)}
+    executed = attention.block_tile_counts(length, tiles)["executed_pairs"]
+    return {BLOCK_EXECUTED: jnp.float32(executed / needed),
+            BLOCK_MASK_OFF: attention.block_schedule_off(
+                length, block, tiles).astype(jnp.float32)}
+
+
+def _block_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
+    """The softmax mixer of a block-diffusion model: ``x (b, 2 L, d)`` is
+    ``[noised ; clean]`` of one sequence, q and k rotated by ``arange(L)``
+    in each half (``_rope_tables``), attention under the block rule
+    (``ops.attention.block_mask``: ``flash_*_bd`` or the reference)."""
+    cfg = ctx.cfg
+    if ctx.sp_manual:
+        raise NotImplementedError(
+            "the block rule inside a region that is manual over 'sp'")
+    q, k, v, gate, prescaled, _ = _qkv(ctx, x, lp, False)
+    with jax.named_scope("attention"):
+        o = _attention(q, k, v, cfg, ctx.mesh, None, prescaled,
+                       block=cfg.bd_block)
+        aux = fold(aux, _block_stats(
+            cfg, q.shape[1] // 2, max(q.shape[-1], v.shape[-1])), BLOCK_STATS)
+    return _out(ctx, x, o, lp, residual, gate), aux
 
 
 def _indexed_shapes(cfg):
@@ -521,6 +571,9 @@ SLIDING = Block(_attention_shapes,
                 stats=lambda cfg: WINDOW_STATS)
 LATENT = Block(_latent_shapes, _latent_mixer,
                saved=attention.SAVED_RESIDUALS, scopes=SCOPES)
+BLOCK_RULE = Block(_attention_shapes, _block_mixer,
+                   saved=attention.SAVED_RESIDUALS, scopes=SCOPES,
+                   stats=lambda cfg: BLOCK_STATS)
 INDEXED = Block(_indexed_shapes, _indexed_mixer,
                 saved=(*attention.SAVED_RESIDUALS,
                        *sparse_attention.SAVED_RESIDUALS),

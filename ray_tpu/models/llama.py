@@ -221,6 +221,18 @@ class LlamaConfig:
     # there) enters the step's at ``idx_loss_coef``.
     sa_config: Any = None
     idx_loss_coef: float = 1.0
+    # A model trained by BLOCK DIFFUSION (arXiv:2503.09573), the group:
+    # {"block_length": B, "mask_token_id", "eps", "noise_seed"}.  With it the
+    # objective is denoising, not next-token (``_denoising_streams``): a
+    # sequence's tokens are replaced by the mask token with probability
+    # ``p = (1 - eps) t + eps``, ``t ~ U(0, 1)`` a sequence, the model runs
+    # ONE pass over ``[noised ; clean]`` (2 L rows a sequence, both halves at
+    # positions 0..L-1) in which every "attention" layer is the mixer
+    # ``block_attention`` — bidirectional inside a block of B positions,
+    # causal across blocks, the noised stream reading the clean past — and
+    # the loss is the masked positions' OWN tokens' cross-entropy over
+    # ``p``, read off the noised half.  ``forward`` is that pass at step 0.
+    block_diffusion: Any = None
     # Where a block's RMSNorm sits: "input", x + f(norm(x)); "output",
     # x + norm(f(x)) with the same weight on what the block adds; or
     # "sandwich", x + post_norm(f(norm(x))): two norms a block.
@@ -287,6 +299,11 @@ class LlamaConfig:
                 "indexer_head_dim (even) against ONE key head "
                 "(indexer_num_kv_heads 1) that picks topk keys a query, on "
                 f"softmax attention over one stream: {self.index_group}")
+        if isinstance(self.block_diffusion, dict):
+            object.__setattr__(self, "block_diffusion",
+                               tuple(sorted(self.block_diffusion.items())))
+        if self.block_diffusion:
+            self._check_block_diffusion()
         if isinstance(self.rope_parameters, dict):
             object.__setattr__(self, "rope_parameters", tuple(sorted(
                 (kind, tuple(sorted(group.items())))
@@ -430,6 +447,46 @@ class LlamaConfig:
                     "('default') and YaRN's are implemented, and a model "
                     "is never trained with plain frequencies in place of "
                     "the ones its file states")
+
+    def _check_block_diffusion(self):
+        """What is built of the denoising objective, and a refusal by
+        message of what is not: never a silent next-token run."""
+        group = self.bd_group
+        missing = {"block_length", "mask_token_id", "eps",
+                   "noise_seed"} - set(group)
+        if missing or group["block_length"] < 1 or not (
+                0 <= group["mask_token_id"] < self.vocab_size
+                and 0.0 < group["eps"] < 1.0):
+            raise ValueError(
+                "block_diffusion: {block_length >= 1, mask_token_id below "
+                "vocab_size, eps in (0, 1), noise_seed}: "
+                f"{group}")
+        if self.attn_impl in ("ring", "ulysses"):
+            raise NotImplementedError(
+                f"block_diffusion with attn_impl {self.attn_impl!r}: the "
+                "block rule is built into the flash kernels and the "
+                "reference, not into a sequence split over 'sp'")
+        if (self.num_nextn or self.sa_config or self.sliding_window
+                or self.kv_lora_rank or self.hc_mult > 1
+                or self.layer_pattern or self.mb_per_layer
+                or self.gqa_layers or self.linear_attn_config
+                or set(self.layer_types) - {"attention", "full_attention",
+                                            "block_attention"}):
+            raise NotImplementedError(
+                "block_diffusion is built for a model whose every mixer is "
+                "plain softmax attention on one residual stream: not with a "
+                "predicted-ahead module (num_nextn), an indexer (sa_config), "
+                "a sliding window, latent attention or any other mixer — "
+                "none of them knows the two streams")
+
+    @property
+    def bd_group(self) -> Dict[str, Any]:
+        return dict(self.block_diffusion or ())
+
+    @property
+    def bd_block(self) -> int:
+        """The block length of a block-diffusion model, 0 for any other."""
+        return self.bd_group.get("block_length", 0)
 
     @property
     def qkv_dim(self) -> int:
@@ -594,6 +651,8 @@ class LlamaConfig:
         if self.sa_config:
             mixers = tuple("indexed" if m in ("attention", "full_attention")
                            else m for m in mixers)
+        if self.block_diffusion:
+            mixers = ("block_attention",) * self.num_layers
         return tuple(
             (mixer, "moe" if self.num_experts and i >= self.leading_dense
              else "dense") for i, mixer in enumerate(mixers))
@@ -778,9 +837,53 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig, *,
     constraints steer XLA's partitioner.  (The pipeline-parallel path is
     ``parallel.pipeline.forward_pipelined`` — manual SPMD.)
     """
+    if cfg.block_diffusion:
+        # the denoising pass with step 0's noise: the noised stream's logits
+        # (b, s, vocab), the ones ``loss_fn`` scores
+        (logits, _, _), aux, _ = _denoising_pass(params, tokens, cfg, mesh,
+                                                 rules, 0)
+        return logits, _mean_aux(aux, cfg, cfg.kind_runs)
     h, aux, _ = _hidden(params, tokens, cfg, mesh, rules)
     return (_lm_head(params, h, cfg, _make_cst(mesh, rules)),
             _mean_aux(aux, cfg, cfg.kind_runs))
+
+
+BD_MASKED_SHARE = "bd_masked_share"
+
+
+def _denoising_streams(tokens, cfg: LlamaConfig, step):
+    """The block-diffusion corruption of ``tokens (b, L)`` (scope
+    ``bd_noise``): ``([xt ; x0] (b, 2 L), m (b, L), the loss weights m / p
+    (b, L) float32)``.  The key is ``fold_in(PRNGKey(noise_seed), step)``:
+    a step's draws follow from its number, so every step draws fresh noise
+    and a resumed job repeats its own.  Split in two: one ``t ~ U(0, 1)`` a
+    sequence, ``p = (1 - eps) t + eps``; one uniform a position, masked
+    where it lies under ``p``.  WHICH positions are masked is ``m``, never
+    ``xt == mask`` (a data token may equal the mask id)."""
+    group = cfg.bd_group
+    with jax.named_scope("bd_noise"):
+        key_t, key_m = jax.random.split(jax.random.fold_in(
+            jax.random.PRNGKey(group["noise_seed"]), step))
+        t = jax.random.uniform(key_t, (tokens.shape[0], 1), jnp.float32)
+        p = (1.0 - group["eps"]) * t + group["eps"]
+        m = jax.random.uniform(key_m, tokens.shape, jnp.float32) < p
+        noised = jnp.where(m, jnp.asarray(group["mask_token_id"],
+                                          tokens.dtype), tokens)
+        return (jnp.concatenate([noised, tokens], axis=1), m,
+                m.astype(jnp.float32) / p)
+
+
+def _denoising_pass(params, tokens, cfg: LlamaConfig, mesh, rules, step):
+    """One pass over ``[xt ; x0]`` of ``tokens (b, L)``: ``((the noised
+    half's logits (b, L, vocab), m, the loss weights), aux, the layers'
+    counts)``.  The layers see ``b`` sequences of 2 L rows (the mixer
+    ``block_attention`` knows they are two streams); the head runs on the
+    noised half's L rows."""
+    streams, m, weights = _denoising_streams(tokens, cfg, step)
+    h, aux, counts = _hidden(params, streams, cfg, mesh, rules)
+    logits = _lm_head(params, h[:, :tokens.shape[1]], cfg,
+                      _make_cst(mesh, rules))
+    return (logits, m, weights), aux, counts
 
 
 def _embed(params, tokens, cfg: LlamaConfig, mesh, rules):
@@ -1042,6 +1145,11 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
 
 
 def _one_kind(cfg: LlamaConfig, what: str) -> None:
+    if cfg.block_diffusion:
+        raise NotImplementedError(
+            f"{what} runs the next-token objective on one stream; a "
+            "block_diffusion model's denoising pass over two streams is "
+            "built on the normal path: make_train_step(pipelined=False)")
     if len(cfg.kind_runs) > 1 or cfg.hc_mult > 1 or cfg.num_nextn:
         raise NotImplementedError(
             f"{what} splits ONE stack of layers into stages; this model's "
@@ -1216,18 +1324,32 @@ def make_pipeline_loss_fn(cfg: LlamaConfig):
 def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
                     cfg: LlamaConfig, *, mesh: Optional[Mesh] = None,
                     rules: Optional[LogicalAxisRules] = None,
-                    forward_fn=None):
+                    forward_fn=None, step=0):
     """``loss_fn`` and, beside its metrics, what the train step needs and
     no metric can carry: ``(loss, (metrics, counts))``, ``counts`` the
     experts' assignments of every layer whose router has a selection bias
     (``{"layers": a run, "mtp": ...}``; ``update_router_bias`` reads it),
-    None for a model without one."""
+    None for a model without one.  ``step``: the step's number, which a
+    block-diffusion model's noise is drawn from (nothing else reads it)."""
     if "tokens" in batch:
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     else:
         inputs, targets = batch["inputs"], batch["targets"]
-    counts = ahead = None
-    if forward_fn is None:
+    counts = ahead = weights = None
+    if cfg.block_diffusion:
+        if forward_fn is not None:
+            raise NotImplementedError(
+                "block_diffusion with a replaced forward pass (the pipelined "
+                "path): it would run the next-token objective")
+        # the denoising objective: position i's target is ITS OWN token
+        targets = inputs
+        (logits, masked, weights), aux, layer_counts = _denoising_pass(
+            params, inputs, cfg, mesh, rules, step)
+        if cfg.select_bias:
+            counts = {"layers": layer_counts, "mtp": None}
+        aux = dict(_mean_aux(aux, cfg, _all_runs(cfg)),
+                   **{BD_MASKED_SHARE: jnp.mean(masked.astype(jnp.float32))})
+    elif forward_fn is None:
         h, aux, layer_counts = _hidden(params, inputs, cfg, mesh, rules)
         logits = _lm_head(params, h, cfg, _make_cst(mesh, rules))
         if cfg.num_nextn:
@@ -1244,7 +1366,8 @@ def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
     else:
         logits, aux = forward_fn(params, inputs)
     with jax.named_scope("loss"):
-        loss = _mean_nll(logits, targets)
+        loss = (_mean_nll(logits, targets) if weights is None
+                else _weighted_nll(logits, targets, weights))
         # the blocks' statistics are metrics under their own names; the
         # two that are losses also weigh in
         stats = aux if isinstance(aux, dict) else {"aux_loss": aux}
@@ -1294,10 +1417,24 @@ def _mean_nll(logits, targets, weights=None):
         # them and 5 GB more of temporaries at 8192 x 100352 (PERF.md §6,
         # PR 30).  Batches of several rows compile as they always have.
         logits, targets = logits[0], targets[0]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    nll = _row_nll(logits, targets)
     if weights is None:
         return jnp.mean(nll)
     return jnp.sum(nll * weights) / (jnp.sum(weights) * nll.size
                                      / weights.size)
 
+
+
+def _weighted_nll(logits, targets, weights):
+    """``sum(weights x nll) / positions``: the denoising loss, ``weights (b,
+    s)`` the masked positions' ``1 / p`` and 0 elsewhere."""
+    if logits.shape[0] == 1:    # as ``_mean_nll``: no flat scatter
+        logits, targets, weights = logits[0], targets[0], weights[0]
+    nll = _row_nll(logits, targets)
+    return jnp.sum(nll * weights) / nll.size
+
+
+def _row_nll(logits, targets):
+    """Each position's ``-log softmax(logits)[target]``."""
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]

@@ -146,7 +146,8 @@ def _compiler_params(interpret, sequential=1):
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, sm_scale: Optional[float] = None,
                   q_offset: int = 0, kv_offset: int = 0,
-                  window: Optional[int] = None) -> jax.Array:
+                  window: Optional[int] = None,
+                  block: Optional[int] = None) -> jax.Array:
     """Pure-XLA multi-head attention, the numerics oracle for every kernel.
 
     Under ``causal`` query ``i`` sees key ``j`` iff ``j <= i`` (the near
@@ -154,6 +155,8 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (the far edge: the query itself and the ``window - 1`` keys before it).
     ``q_offset``/``kv_offset`` are global positions of element 0 of the q/kv
     chunks — used by ring attention where each device holds a seq slice.
+    Under ``block`` the rows are TWO STREAMS of one sequence (``block_mask``,
+    from the definition, dense).
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -161,13 +164,41 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
         raise ValueError("a window is the causal mask's far edge")
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
-    if causal:
+    if block is not None:
+        _check_block(block, causal, window, q.shape[1], k.shape[1])
+        s = jnp.where(block_mask(q.shape[1] // 2, block), s, NEG_INF)
+    elif causal:
         qi = q_offset + jnp.arange(q.shape[1])[:, None]
         ki = kv_offset + jnp.arange(k.shape[1])[None, :]
         seen = qi >= ki if window is None else (qi >= ki) & (qi - ki < window)
         s = jnp.where(seen, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+
+
+def block_mask(length: int, block: int, first: int = 0,
+               rows: Optional[int] = None) -> jax.Array:
+    """The block-diffusion rule, dense, from its four cases: ``(2 length, 2
+    length)`` booleans over the rows ``[noised ; clean]`` of ONE sequence
+    of ``length`` positions in blocks of ``block`` (position ``i`` lies in
+    block ``i // block``; a noised row and its clean copy share a position)
+    — or its ``rows`` rows from ``first`` on.  A clean row sees the clean
+    keys of its own block and of every earlier one; a noised row the clean
+    keys of STRICTLY earlier blocks and the noised keys of its own block,
+    both directions; no clean row sees a noised key."""
+    at = jnp.arange(2 * length)
+    clean, b = at >= length, (at % length) // block
+    here = slice(first, None if rows is None else first + rows)
+    (rc, cc), (rb, cb) = ((x[here, None], x[None, :]) for x in (clean, b))
+    return jnp.where(rc, cc & (cb <= rb), jnp.where(cc, cb < rb, cb == rb))
+
+
+def _check_block(block, causal, window, sq, sk):
+    if not causal or window is not None or sq != sk or sq % (2 * block):
+        raise ValueError(
+            f"block {block}: the block rule is over two streams of one "
+            f"sequence, [noised ; clean], q and k alike ({sq}, {sk} rows), "
+            "each a whole number of blocks, under no window")
 
 
 # ------------------------------------------------------ causal tile schedule
@@ -327,12 +358,16 @@ def _walk_tile(causal, off, tiles, body, strips, window=None):
         pl.when(off == o)(functools.partial(walk, o))
 
 
-def _scores(q, k, mask, transposed=False):
+def _scores(q, k, mask, transposed=False, rule=None):
     """f32 ``q @ k^T`` of one strip, or ``k @ q^T`` (the q rows along the
     lanes) if ``transposed``.  ``mask`` = (axis, segments) as ``_walk_tile``
     hands it: the k columns (axis 1) or the q rows (axis 0) in segments
     ``(size, lo, hi)``, row r of one seeing its column c iff ``lo <= r - c
-    <= hi``."""
+    <= hi``.  ``rule`` = (shift, strict), the BLOCK rule: a segment's rows
+    and columns count in blocks of ``2 ** shift`` (every ``lo`` is a whole
+    number of them) and row r sees column c iff ``(r >> shift) - (c >>
+    shift) >= (lo >> shift) + strict`` — the whole own block with ``strict``
+    0, strictly earlier blocks with 1 (a traced scalar will do)."""
     a, b = (k, q) if transposed else (q, k)
     s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -348,12 +383,33 @@ def _scores(q, k, mask, transposed=False):
         if lo is None and hi is None:
             continue
         part = parts[i]
-        diff = (jax.lax.broadcasted_iota(jnp.int32, part.shape, rows)
-                - jax.lax.broadcasted_iota(jnp.int32, part.shape, 1 - rows))
+        if rule is None:
+            diff = _block_diff(part.shape, rows, 0)
+        else:
+            diff, lo = _block_diff(part.shape, rows, rule[0]), (
+                lo >> rule[0]) + rule[1]
         seen = (diff >= lo if hi is None else diff <= hi if lo is None
                 else (diff >= lo) & (diff <= hi))
         parts[i] = jnp.where(seen, part, NEG_INF)
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, cut_axis)
+
+
+def _block_diff(shape, rows: int, shift: int):
+    """Row less column of every element of a tile of ``shape`` whose q rows
+    lie along axis ``rows``, each counted in blocks of ``2 ** shift``."""
+    r = jax.lax.broadcasted_iota(jnp.int32, shape, rows)
+    c = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rows)
+    if shift:
+        r, c = (jax.lax.shift_right_arithmetic(x, jnp.int32(shift))
+                for x in (r, c))
+    return r - c
+
+
+def _own_block(s, shift: int):
+    """``s``, the scores of a run of rows against THEIR OWN positions' keys
+    (a square on the diagonal), less every pair of two different blocks of
+    ``2 ** shift``."""
+    return jnp.where(_block_diff(s.shape, 0, shift) == 0, s, NEG_INF)
 
 
 def _tile_offset(causal, qi, ki, tiles, grid_qk):
@@ -366,13 +422,28 @@ def _tile_offset(causal, qi, ki, tiles, grid_qk):
 
 # ---------------------------------------------------------------- forward
 
+def _streams(bd, qi, nq):
+    """Under the block rule a kernel's q-tile axis walks the NOISED stream's
+    ``nq`` tiles, then the clean stream's: ``(the tile within its stream,
+    whether it is the noised one's, the rule _scores takes)``; without it
+    the axis as it is."""
+    if bd is None:
+        return qi, None, None
+    noised = qi < nq
+    return jax.lax.rem(qi, nq), noised, (bd[0], noised.astype(jnp.int32))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, causal, tiles, grid_qk,
-                window=None, sel_ref=None):
+                window=None, sel_ref=None, bd=None):
     # ``sel_ref``: a mask that is DATA (``ops/sparse_attention.py``), the
-    # tile's ``(q rows, k columns)`` of it, nonzero where the pair is live
+    # tile's ``(q rows, k columns)`` of it, nonzero where the pair is live.
+    # ``bd`` = (shift, kn_ref, vn_ref): the block rule (``flash_fwd_bd``);
+    # k_ref / v_ref walk the CLEAN keys, kn_ref / vn_ref hold the noised
+    # stream's keys and values at this q tile's own positions.
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
+    qi, noised, rule = _streams(bd, qi, grid_qk[0])
 
     @pl.when(ki == 0)
     def _init():
@@ -380,11 +451,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def update(qs, ks, mask):
-        s = _scores(q_ref[qs], k_ref[ks], mask)   # f32
-        if sel_ref is not None:
-            s = jnp.where(sel_ref[qs, ks] != 0, s, NEG_INF)
-        v = v_ref[ks]
+    def softmax_step(qs, s, v):
         m_prev = m_scr[qs]                           # (sq, LANES) replicated
         m_cur = jnp.max(s, axis=-1, keepdims=True)   # (sq, 1)
         m_next = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
@@ -396,6 +463,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[qs] = m_next
+
+    def update(qs, ks, mask):
+        s = _scores(q_ref[qs], k_ref[ks], mask, rule=rule)   # f32
+        if sel_ref is not None:
+            s = jnp.where(sel_ref[qs, ks] != 0, s, NEG_INF)
+        softmax_step(qs, s, v_ref[ks])
+
+    if bd is not None:
+        @pl.when((ki == 0) & noised)
+        def _own_blocks():
+            # a noised row's own block, both directions: the noised keys at
+            # its strip's own positions, before any clean key (so no row's
+            # running maximum is ever that of nothing)
+            for s0 in range(0, tiles[0], tiles[2]):
+                qs = pl.ds(s0, tiles[2])
+                softmax_step(qs, _own_block(_scores(
+                    q_ref[qs], bd[1][qs], None), bd[0]), bd[2][qs])
 
     _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
                update, strips="q", window=window)
@@ -419,7 +503,8 @@ def _dims(qt, kt, vt, heads):
     return b, h, h_kv, sq, sk, width // h, vt.shape[2] // h_kv
 
 
-def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
+def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None,
+                    bd=False):
     """``(nq, nk)`` tiles of the call and the BlockSpecs of the q-side and
     kv-side operands for both grids: ``q_i, k_j, row_i`` for the forward's
     ``(b, h, q, kv)`` and ``q_t, k_t, stat_t, k_all`` for the backward's
@@ -449,12 +534,27 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
     ``o_t``, ``v_t``, ``v_all``) may have another; where the two are equal
     the specs are.  Under the mask a dead grid step names the block its
     nearest live step holds, which Pallas does not copy again: past the
-    diagonal and, under a ``window``, before the far edge."""
+    diagonal and, under a ``window``, before the far edge.
+
+    ``bd``: the BLOCK rule.  The rows are two streams, ``[noised ; clean]``,
+    and ``(nq, nk)`` count ONE stream's tiles: a grid's q axis is ``2 nq``
+    long, the noised stream's tiles then the clean one's; its kv axis walks
+    the CLEAN keys (the second half's tiles, up to the q tile's diagonal,
+    for either stream), and ``kn_*`` / ``vn_*`` name the noised keys and
+    values at a noised q tile's own positions (``block_q`` rows; the clean
+    stream's steps keep naming the last one, which is then not copied)."""
     block_q, block_k = tiles[:2]
     _, h, h_kv, sq, sk, d, dv = _dims(qt, kt, vt, heads)
     nq, nk = sq // block_q, sk // block_k
     rep = h // h_kv
-    if causal:
+    q_tiles = nq
+    if bd:
+        nq, nk = nq // 2, nk // 2
+
+        def inner_k(i, j):
+            return nk + jnp.minimum(
+                j, _last_live_k(jax.lax.rem(i, nq), block_q, block_k))
+    elif causal:
         def inner_k(i, j):
             j = jnp.minimum(j, _last_live_k(i, block_q, block_k))
             if window is None:
@@ -470,12 +570,15 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
         walk = lambda g, t: (g, t)
     else:
         kv_head = lambda h_: h_ // rep
-        walk = lambda g, t: (g * rep + t // nq, t % nq)
+        walk = lambda g, t: (g * rep + t // q_tiles, t % q_tiles)
     outer = lambda h_, i, j: (h_, i)
     k_inner = lambda h_, i, j: (kv_head(h_), inner_k(i, j))
     q_walk = lambda g, t, j: walk(g, t)
     k_walk = lambda g, t, j: (g, inner_k(walk(g, t)[1], j))
     whole = lambda g, t, j: (g, 0)
+    own = lambda i: jnp.minimum(i, nq - 1)
+    own_i = lambda h_, i, j: (kv_head(h_), own(i))
+    own_t = lambda g, t, j: (g, own(walk(g, t)[1]))
     stat_at = lambda g, t, j: (walk(g, t)[0], 0, walk(g, t)[1])
 
     def spec(block, width, at):
@@ -496,6 +599,8 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
         "q_t": spec(block_q, d, q_walk), "o_t": spec(block_q, dv, q_walk),
         "k_t": spec(block_k, d, k_walk), "v_t": spec(block_k, dv, k_walk),
         "k_all": spec(sk, d, whole), "v_all": spec(sk, dv, whole),
+        "kn_i": spec(block_q, d, own_i), "vn_i": spec(block_q, dv, own_i),
+        "kn_t": spec(block_q, d, own_t), "vn_t": spec(block_q, dv, own_t),
         "stat_t": pl.BlockSpec(
             (None, None, 1, block_q),
             lambda b_, g, t, j: (b_, *stat_at(g, t, j))),
@@ -510,12 +615,14 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None):
     }
 
 
-def _kernel_name(name: str, window, sel=None) -> str:
-    """The windowed calls, and those under a mask that is data, carry names
-    of their own that START with the plain ones, so what sums ``flash_*``
-    holds them and a reader can tell them apart."""
+def _kernel_name(name: str, window, sel=None, block=None) -> str:
+    """The windowed calls, those under a mask that is data and those under
+    the block rule carry names of their own that START with the plain ones,
+    so what sums ``flash_*`` holds them and a reader can tell them apart."""
     if sel is not None:
         return name + "_dsa"
+    if block is not None:
+        return name + "_bd"
     return name if window is None else name + "_win"
 
 
@@ -527,27 +634,40 @@ def _with_sel(kernel, at: int):
     return call
 
 
+def _with_own(kernel, at: int, shift: int):
+    """``kernel`` for a call under the block rule, whose operands ``at``
+    and ``at + 1`` are the noised stream's own keys and values."""
+    def call(*refs, **kw):
+        return kernel(*refs[:at], *refs[at + 2:],
+                      bd=(shift, *refs[at:at + 2]), **kw)
+    return call
+
+
 def _fwd_call(qt, kt, vt, causal, tiles, interpret, window=None, heads=None,
-              sel=None):
+              sel=None, block=None):
     """qt, kt, vt in either addressing (``_grid_and_specs``); qt PRE-SCALED
     by sm_scale*log2e.  Returns (o_t, lse) with o_t addressed as qt, v's
     head size wide, and lse (b, h, sq, LANES) lane-replicated f32 in the
     log2 domain.  ``sel (b, sq, sk)`` int8: a mask that is data, nonzero
-    where the pair is live (the call is then ``flash_fwd_dsa``)."""
+    where the pair is live (the call is then ``flash_fwd_dsa``).  ``block``:
+    the rows are two streams under the block rule (``flash_fwd_bd``)."""
     b, h, _, sq, _, _, dv = _dims(qt, kt, vt, heads)
     block_q = tiles[0]
     (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window,
-                                      heads)
+                                      heads, block is not None)
     o_shape = (b, h, sq, dv) if heads is None else (b, sq, h * dv)
-    kernel, masks = _fwd_kernel, ()
+    kernel, masks, own = _fwd_kernel, (), ()
     if sel is not None:
         kernel, masks = _with_sel(_fwd_kernel, 3), (sel,)
+    if block is not None:
+        kernel, own = _with_own(_fwd_kernel, 3, _shift(block)), (kt, vt)
     o, lse = pl.pallas_call(
         functools.partial(kernel, causal=causal, tiles=tiles,
                           grid_qk=(nq, nk), window=window),
-        grid=(b, h, nq, nk),
+        grid=(b, h, sq // block_q, nk),
         in_specs=[specs["q_i"], specs["k_j"], specs["v_j"],
-                  *(specs["sel_i"] for _ in masks)],
+                  *(specs["sel_i"] for _ in masks),
+                  *(specs[n] for n in ("kn_i", "vn_i")[:len(own)])],
         out_specs=[specs["o_i"], specs["row_i"]],
         out_shape=[
             jax.ShapeDtypeStruct(o_shape, qt.dtype),
@@ -560,21 +680,31 @@ def _fwd_call(qt, kt, vt, causal, tiles, interpret, window=None, heads=None,
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window, sel),
-    )(qt, kt, vt, *masks)
+        name=_kernel_name("flash_fwd", window, sel, block),
+    )(qt, kt, vt, *masks, *own)
     return o, lse
+
+
+def _shift(block: int) -> int:
+    """log2 of a block length (a power of two: it divides a sub-tile)."""
+    return block.bit_length() - 1
 
 
 # ---------------------------------------------------------------- backward
 
-def _p_and_ds(q, k, v, do, lse, delta, mask, sel=None):
+def _p_and_ds(q, k, v, do, lse, delta, mask, sel=None, rule=None,
+              own=None):
     """Recomputed probabilities and score gradients of one strip, both
     f32 and both TRANSPOSED, ``(sk, sq)``: ``p^T = exp2(s^T - lse)``,
     ``ds^T = p^T * (dp^T - delta)``, from stats that are rows ``(1, sq)``.
-    ``sel``: the strip of a data mask, turned round as the scores are."""
-    s = _scores(q, k, mask, transposed=True)
+    ``sel``: the strip of a data mask, turned round as the scores are;
+    ``rule``: the block rule's (``_scores``); ``own``: the strip is a run of
+    rows against their own positions' keys, in blocks of ``2 ** own``."""
+    s = _scores(q, k, mask, transposed=True, rule=rule)
     if sel is not None:
         s = jnp.where(sel != 0, s, NEG_INF)
+    if own is not None:
+        s = _own_block(s, own)
     p = jnp.exp2(s - lse)
     dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -594,15 +724,21 @@ def _rows(ref, n, body):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, dq_scale,
-                causal, tiles, grid_qk, window=None, rep=1, sel_ref=None):
+                causal, tiles, grid_qk, window=None, rep=1, sel_ref=None,
+                bd=None):
     # Axis 2 walks the ``rep`` q heads of this KV head, each head's q tiles
     # in turn, axis 3 a q tile's kv tiles: dq gathers over axis 3 and
     # leaves once a q tile; dk and dv gather over BOTH, a whole sequence of
-    # float32 in VMEM, and leave once a KV head.
+    # float32 in VMEM, and leave once a KV head.  ``bd`` as the forward's
+    # (``flash_dkv_bd``): a head's q tiles are the noised stream's, then the
+    # clean one's; the clean keys' dk and dv, the accumulators' second half,
+    # gather over both streams' queries.
     t, ki = pl.program_id(2), pl.program_id(3)
     last_k = ki == pl.num_programs(3) - 1
-    qi = t if rep == 1 else t % grid_qk[0]
-    block_k, sub_k = tiles[1], tiles[3]
+    q_tiles = grid_qk[0] * (1 if bd is None else 2)
+    qi, noised, rule = _streams(bd, t if rep == 1 else t % q_tiles,
+                                grid_qk[0])
+    block_q, block_k, sub_q, sub_k = tiles
 
     @pl.when((t == 0) & (ki == 0))
     def _init_kv():
@@ -615,6 +751,34 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(ki == 0)
     def _init_q():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    def gather(qs, ks, keys, values, first_row, mask, own=None):
+        """One strip's part of the three gradients: queries ``qs`` of the
+        tile against the rows ``ks`` of the fetched ``keys`` and ``values``,
+        which are the accumulators' rows from ``first_row()`` on."""
+        q, do, k = q_ref[qs], do_ref[qs], keys[ks]
+        # Transposed, (sk, sq): p^T and ds^T are what dv's and dk's
+        # products take on the left, so no tile is turned round.
+        p, ds = _p_and_ds(q, k, values[ks], do, lse_ref[:, qs],
+                          delta_ref[:, qs], mask,
+                          None if sel_ref is None else sel_ref[ks, qs],
+                          rule, own)
+        # Grad matmuls in the INPUT dtype (bf16 on TPU): the MXU runs
+        # bf16 natively; f32 operands would force multi-pass matmuls.
+        ds = ds.astype(q.dtype)
+        # this strip's rows of the whole-sequence accumulators
+        rows = pl.ds(pl.multiple_of(first_row(), ks.size), ks.size)
+        dv_scr[rows] += jnp.dot(p.astype(do.dtype), do,
+                                preferred_element_type=jnp.float32)
+        dk_scr[rows] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        # dq = ds k contracts the tile's FIRST dimension.  It gathers
+        # turned round, dq^T = k^T ds^T (d, sq): the operand Mosaic turns is
+        # the strip of k, not the tile, and the product's lanes are the q
+        # rows, whole lane blocks whatever d (at d = 192 and 64 the plain
+        # form pays for 256 and 128: 5 and 9 % of a call; PERF.md §6, PR 69).
+        dq_scr[:, qs] += jax.lax.dot_general(
+            k, ds, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     def update(qs, ks, mask):
         if ks.size > sub_k:
@@ -629,28 +793,22 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 return carry
 
             return jax.lax.fori_loop(0, ks.size // sub_k, strip, None)
-        q, do, k = q_ref[qs], do_ref[qs], k_ref[ks]
-        # Transposed, (sk, sq): p^T and ds^T are what dv's and dk's
-        # products take on the left, so no tile is turned round.
-        p, ds = _p_and_ds(q, k, v_ref[ks], do, lse_ref[:, qs],
-                          delta_ref[:, qs], mask,
-                          None if sel_ref is None else sel_ref[ks, qs])
-        # Grad matmuls in the INPUT dtype (bf16 on TPU): the MXU runs
-        # bf16 natively; f32 operands would force multi-pass matmuls.
-        ds = ds.astype(q.dtype)
-        # this strip's rows of the whole-sequence accumulators
-        rows = pl.ds(pl.multiple_of(ki * block_k + ks.start, sub_k), ks.size)
-        dv_scr[rows] += jnp.dot(p.astype(do.dtype), do,
-                                preferred_element_type=jnp.float32)
-        dk_scr[rows] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
-        # dq = ds k contracts the tile's FIRST dimension.  It gathers
-        # turned round, dq^T = k^T ds^T (d, sq): the operand Mosaic turns is
-        # the strip of k, not the tile, and the product's lanes are the q
-        # rows, whole lane blocks whatever d (at d = 192 and 64 the plain
-        # form pays for 256 and 128: 5 and 9 % of a call; PERF.md §6, PR 69).
-        dq_scr[:, qs] += jax.lax.dot_general(
-            k, ds, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        if bd is None:
+            first_row = lambda: ki * block_k + ks.start     # noqa: E731
+        else:   # the clean keys: the accumulators' second half
+            first_row = lambda: (    # noqa: E731
+                ki * block_k + ks.start + grid_qk[1] * block_k)
+        gather(qs, ks, k_ref, v_ref, first_row, mask)
+
+    if bd is not None:
+        @pl.when((ki == 0) & noised)
+        def _own_blocks():
+            # the noised keys at this q tile's own positions: the
+            # accumulators' first half
+            for s0 in range(0, block_q, sub_q):
+                qs = pl.ds(s0, sub_q)
+                gather(qs, qs, bd[1], bd[2], lambda: qi * block_q + s0,
+                       None, own=bd[0])
 
     _walk_tile(causal, _tile_offset(causal, qi, ki, tiles, grid_qk), tiles,
                update, strips="k", window=window)
@@ -687,12 +845,13 @@ def _check_resident(sk, d, dv, dtype):
 
 
 def _bwd_call(qt, kt, vt, ot, lse, dot, dq_scale, causal, tiles, interpret,
-              window=None, heads=None, sel_t=None):
+              window=None, heads=None, sel_t=None, block=None):
     """qt, kt, vt, ot, dot in either addressing (``_grid_and_specs``); lse
     (b, h, sq), a float a row.  Returns (dqt, dkt, dvt), each addressed as
     its operand: dkt and dvt at k's and v's OWN head count, summed over
     each KV head's group of q heads inside the kernel.  ``sel_t (b, sk,
-    sq)``: the forward's data mask turned round (``flash_dkv_dsa``)."""
+    sq)``: the forward's data mask turned round (``flash_dkv_dsa``);
+    ``block``: two streams under the block rule (``flash_dkv_bd``)."""
     b, h, h_kv, sq, sk, d, dv = _dims(qt, kt, vt, heads)
     _check_resident(sk, d, dv, kt.dtype)
     block_q = tiles[0]
@@ -703,20 +862,23 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, dq_scale, causal, tiles, interpret,
         delta = jnp.sum((ot.astype(jnp.float32) * dot.astype(jnp.float32)
                          ).reshape(b, sq, h, dv), axis=-1).transpose(0, 2, 1)
     (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window,
-                                      heads)
+                                      heads, block is not None)
     q_t, o_t, k_t, v_t, k_all, v_all, stat_t = (specs[n] for n in (
         "q_t", "o_t", "k_t", "v_t", "k_all", "v_all", "stat_t"))
     rep = h // h_kv
-    kernel, masks = _bwd_kernel, ()
+    kernel, masks, own = _bwd_kernel, (), ()
     if sel_t is not None:
         kernel, masks = _with_sel(_bwd_kernel, 6), (sel_t,)
+    if block is not None:
+        kernel, own = _with_own(_bwd_kernel, 6, _shift(block)), (kt, vt)
     return pl.pallas_call(
         functools.partial(kernel, dq_scale=dq_scale, causal=causal,
                           tiles=tiles, grid_qk=(nq, nk), window=window,
                           rep=rep),
-        grid=(b, h_kv, rep * nq, nk),
+        grid=(b, h_kv, rep * (sq // block_q), nk),
         in_specs=[q_t, k_t, v_t, o_t, stat_t, stat_t,
-                  *(specs["sel_t"] for _ in masks)],
+                  *(specs["sel_t"] for _ in masks),
+                  *(specs[n] for n in ("kn_t", "vn_t")[:len(own)])],
         out_specs=[q_t, k_all, v_all],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (qt, kt, vt)],
@@ -725,8 +887,8 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, dq_scale, causal, tiles, interpret,
                         pltpu.VMEM((sk, dv), jnp.float32)],
         compiler_params=_compiler_params(interpret, sequential=2),
         interpret=interpret,
-        name=_kernel_name("flash_dkv", window, sel_t),
-    )(qt, kt, vt, dot, lse[:, :, None], delta[:, :, None], *masks)
+        name=_kernel_name("flash_dkv", window, sel_t, block),
+    )(qt, kt, vt, dot, lse[:, :, None], delta[:, :, None], *masks, *own)
 
 
 # ----------------------------------------------------------------- public
@@ -761,7 +923,7 @@ def q_prescale(sm_scale: float, dtype) -> float:
 
 
 def _forward(q, k, v, sm_scale, causal, tiles, interpret, window,
-             prescaled=False):
+             prescaled=False, block=None):
     """``flash_fwd`` on the model's q, k, v: (q, k, v as the kernels took
     them — the residuals of the backward pass —, o and lse as the kernel
     wrote them)."""
@@ -773,19 +935,21 @@ def _forward(q, k, v, sm_scale, causal, tiles, interpret, window,
     # RoPE kernel applies it on its way out and hands q in ``prescaled``.
     qs = q if prescaled else (q * (sm_scale * _LOG2E)).astype(q.dtype)
     qt, kt, vt = (_enter(x, in_place) for x in (qs, k, v))
-    ot, lse = _fwd_call(qt, kt, vt, causal, tiles, interpret, window, heads)
+    ot, lse = _fwd_call(qt, kt, vt, causal, tiles, interpret, window, heads,
+                        block=block)
     return (qt, kt, vt), ot, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, sm_scale, causal, tiles, interpret, window=None,
-           prescaled=False):
+           prescaled=False, block=None):
     """``tiles`` = (block_q, block_k, sub_q, sub_k): each sub divides its
-    block, each block its sequence.  k and v come with their OWN head
-    count, a divisor of q's.  ``prescaled``: q is already times
+    block, each block its sequence (under ``block``, the block rule, ONE
+    stream's of q, k, v ``[noised ; clean]``).  k and v come with their OWN
+    head count, a divisor of q's.  ``prescaled``: q is already times
     ``q_prescale`` (and its gradient is the gradient to THAT q)."""
     _, ot, _ = _forward(q, k, v, sm_scale, causal, tiles, interpret, window,
-                        prescaled)
+                        prescaled, block)
     return _leave(ot, q.shape[2])
 
 
@@ -796,9 +960,9 @@ SAVED_RESIDUALS = ("flash_out", "flash_lse")
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret, window=None,
-               prescaled=False):
+               prescaled=False, block=None):
     operands, ot, lse = _forward(q, k, v, sm_scale, causal, tiles, interpret,
-                                 window, prescaled)
+                                 window, prescaled, block)
     ot = checkpoint_name(ot, "flash_out")
     # One lane of the 128 the kernel writes: a float a row is what is
     # worth holding, and what the backward kernel takes (as rows).
@@ -806,8 +970,8 @@ def _flash_fwd(q, k, v, sm_scale, causal, tiles, interpret, window=None,
     return _leave(ot, q.shape[2]), (*operands, ot, lse)
 
 
-def _flash_bwd(sm_scale, causal, tiles, interpret, window, prescaled, res,
-               do):
+def _flash_bwd(sm_scale, causal, tiles, interpret, window, prescaled, block,
+               res, do):
     qt, kt, vt, ot, lse = res
     in_place = qt.ndim == 3     # the residuals' own shapes say how they stand
     h = do.shape[2]
@@ -817,7 +981,7 @@ def _flash_bwd(sm_scale, causal, tiles, interpret, window, prescaled, res,
                 else sm_scale)
     dqt, dkt, dvt = _bwd_call(qt, kt, vt, ot, lse, _enter(do, in_place),
                               dq_scale, causal, tiles, interpret, window,
-                              (h, h_kv) if in_place else None)
+                              (h, h_kv) if in_place else None, block=block)
     return _leave(dqt, h), _leave(dkt, h_kv), _leave(dvt, h_kv)
 
 
@@ -829,7 +993,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: int = MAX_BLOCK, block_k: int = MAX_BLOCK,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None,
-                    q_prescaled: bool = False) -> jax.Array:
+                    q_prescaled: bool = False,
+                    block: Optional[int] = None) -> jax.Array:
     """Memory-efficient MHA.  q: (b, sq, h, d); k: (b, sk, h_kv, d); v:
     (b, sk, h_kv, dv), the output (b, sq, h, dv): v's head size may differ
     from q's and k's (a latent-attention mixer's 192 / 128).
@@ -843,6 +1008,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     window``; the kernels then neither fetch nor compute a tile beyond
     either edge (``flash_*_win``).  A window that reaches every key the
     diagonal leaves (``window >= sk``) cuts nothing: the plain kernels run.
+
+    ``block`` (causal, no window): the BLOCK-DIFFUSION rule, a third edge.
+    The rows of q, k and v are TWO STREAMS of one sequence of ``sq / 2``
+    positions, ``[noised ; clean]``, and a row sees a column by the blocks
+    of ``block`` positions the two lie in and the streams they come from
+    (``block_mask`` has the four cases).  The kernels (``flash_*_bd``) run
+    each stream's q tiles over the clean keys up to the tile's diagonal —
+    whole sub-tiles below it, the test on ``row // block`` and ``column //
+    block`` on it, the clean stream's ``<=``, the noised one's ``<`` — and
+    the noised stream's besides over its OWN keys, the diagonal squares
+    alone: nothing runs for the clean rows against noised keys or for two
+    different blocks of noised ones, and no mask array exists.  For a
+    ``block`` that divides the compute sub-tile (a power of two); else the
+    XLA reference, the dense mask.
 
     Grouped-query attention: k and v may have fewer heads than q, ``h %
     h_kv == 0``.  Nothing repeats them: the kernels find a q head's KV
@@ -859,9 +1038,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         raise ValueError(f"q heads {q.shape[2]} not a multiple of kv heads "
                          f"{k.shape[2]}")
     window = live_window(window, k.shape[1], causal)
-    tiles = choose_tiles(q.shape[1], k.shape[1], causal,
-                         max(q.shape[-1], v.shape[-1]), q.dtype, block_q,
-                         block_k, window)
+    if block is not None:
+        _check_block(block, causal, window, q.shape[1], k.shape[1])
+        tiles = block_tiles(q.shape[1] // 2, block,
+                            max(q.shape[-1], v.shape[-1]), q.dtype, block_q,
+                            block_k)
+    else:
+        tiles = choose_tiles(q.shape[1], k.shape[1], causal,
+                             max(q.shape[-1], v.shape[-1]), q.dtype, block_q,
+                             block_k, window)
     if tiles is None:
         # No block >= 8 tiles the sequence exactly: the XLA reference is
         # correct, at O(S^2) memory.
@@ -870,9 +1055,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         if q_prescaled:
             sm_scale = sm_scale / q_prescale(sm_scale, q.dtype)
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
-                             window=window)
+                             window=window, block=block)
     return _flash(q, k, v, sm_scale, causal, tiles, interpret, window,
-                  q_prescaled)
+                  q_prescaled, block)
 
 
 def live_window(window: Optional[int], sk: int, causal: bool = True
@@ -925,3 +1110,77 @@ def choose_tiles(sq: int, sk: int, causal: bool, d: int, dtype,
         if n["executed_pairs"] <= MAX_EXECUTED * n["causal_pairs"]:
             break
     return tiles
+
+
+# ------------------------------------------------------------ the block rule
+
+def block_tiles(length: int, block: int, d: int, dtype,
+                block_q: int = MAX_BLOCK, block_k: int = MAX_BLOCK):
+    """``choose_tiles`` for ONE stream of ``length`` positions under the
+    block rule (the streams tile alike; off the diagonal a sub-tile is whole
+    or dead exactly as under the causal edge, so the causal choice is the
+    choice), or None where ``block`` does not divide the compute sub-tile:
+    the test on the diagonal counts rows and columns in whole blocks from a
+    sub-tile's corner."""
+    tiles = choose_tiles(length, length, True, d, dtype, block_q, block_k)
+    if tiles is None or block < 2 or block & (block - 1) or any(
+            t % block for t in tiles[2:]):
+        return None
+    return tiles
+
+
+def block_needed_pairs(length: int, block: int) -> int:
+    """(q, k) pairs the block rule asks for, a head: row ``r`` of either
+    stream reads ``(r // block + 1) block`` keys — the clean one its own
+    block and the earlier ones, the noised one the earlier clean blocks and
+    its own noised block — so ``length (length + block)`` together."""
+    return length * (length + block)
+
+
+def block_tile_counts(length: int, tiles) -> dict:
+    """What the ``flash_*_bd`` schedule executes for one (batch, head):
+    both streams' live sub-tiles on the clean keys (``causal_tile_counts``
+    of one stream, twice) and the noised stream's diagonal squares
+    (``sub_q`` rows on their own ``sub_q`` keys)."""
+    n = causal_tile_counts(length, length, *tiles)
+    sub_q = min(tiles[2], tiles[0])
+    return {"executed_pairs": 2 * n["executed_pairs"] + length * sub_q,
+            "diagonal": 2 * n["diagonal"] + length // sub_q,
+            "interior": 2 * n["interior"]}
+
+
+def block_schedule_off(length: int, block: int, tiles) -> jax.Array:
+    """The pairs on which the kernels' schedule and ``block_mask`` DISAGREE,
+    over one strip of ``sub_q`` rows of each stream (the first strip of the
+    middle q tile: it has whole tiles before it, a diagonal and dead tiles
+    after): the strip's row of kv tiles walked by ``_walk_tile`` and tested
+    by ``_scores`` as the forward kernel does it, on scores of 0, then the
+    own-block square; an int32 scalar, 0 for a sound schedule.  A few
+    ``(sub_q, block_k)`` integer tiles of constants: it costs a step nothing
+    to speak of."""
+    block_q, block_k, sub_q, _ = tiles
+    shift, qi = _shift(block), (length // block_q) // 2
+    zeros = lambda n: jnp.zeros((n, 1), jnp.float32)   # noqa: E731
+    first, off = qi * block_q, 0
+    for strict in (1, 0):                    # the noised stream, the clean
+        clean = jnp.zeros((sub_q, length), bool)
+
+        def body(ki, qs, ks, mask):
+            nonlocal clean
+            if qs.start == 0:       # the strip: what the kernel's test sees
+                at = ki * block_k + ks.start
+                clean = clean.at[:, at:at + ks.size].set(_scores(
+                    zeros(qs.size), zeros(ks.size), mask,
+                    rule=(shift, strict)) > NEG_INF / 2)
+
+        for ki in range(length // block_k):
+            _walk_tile(True, ki * block_k - first, tiles,
+                       functools.partial(body, ki), "q")
+        noised = jnp.zeros_like(clean)
+        if strict:
+            noised = noised.at[:, first:first + sub_q].set(_own_block(
+                jnp.zeros((sub_q, sub_q), jnp.float32), shift) > NEG_INF / 2)
+        want = block_mask(length, block, (0 if strict else length) + first,
+                          sub_q)
+        off = off + jnp.sum(jnp.concatenate([noised, clean], 1) != want)
+    return off.astype(jnp.int32)

@@ -43,7 +43,7 @@ tracing.watch_process()
 # ``rematted_computation`` the rematerialised forward (of all but the
 # residuals a block keeps by name), ``transpose(jvp(..))`` the backward
 # pass (``util.tracing.step_breakdown``).
-STEP_SCOPES = ("embed", *layer_scopes(), "mtp_in",
+STEP_SCOPES = ("embed", *layer_scopes(), "mtp_in", "bd_noise",
                "lm_head", "loss", "optimizer")
 
 
@@ -116,18 +116,20 @@ def make_train_step(cfg: LlamaConfig,
                                   Tuple[TrainState, Dict[str, jax.Array]]]:
     """Build the jitted train step.  Batch: {"tokens": (b, s+1) int32}."""
 
-    def compute_loss(params, batch):
+    def compute_loss(params, batch, step):
         forward_fn = None
         if pipelined:
             forward_fn = lambda p, t: forward_pipelined(
                 p, t, cfg, mesh=mesh, num_microbatches=num_microbatches,
                 rules=rules)
         return loss_and_counts(params, batch, cfg, mesh=mesh, rules=rules,
-                               forward_fn=forward_fn)
+                               forward_fn=forward_fn, step=step)
 
     def step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
+        # the step's number: what a block-diffusion model's noise is drawn
+        # from (fresh every step, the same again in a resumed job)
         (_, (metrics, counts)), grads = jax.value_and_grad(
-            compute_loss, has_aux=True)(state.params, batch)
+            compute_loss, has_aux=True)(state.params, batch, state.step)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(grads, state.opt_state,
                                                   state.params)

@@ -37,6 +37,10 @@ FIELDS = {
     # 16 keys a query of the 64
     "indexed": dict(sa_config={"indexer_num_heads": 2, "indexer_head_dim": 8,
                                "indexer_num_kv_heads": 1, "topk": 16}),
+    # blocks of 4 of the 64 positions, over [noised ; clean]
+    "block_attention": dict(block_diffusion={
+        "block_length": 4, "mask_token_id": 255, "eps": 1e-3,
+        "noise_seed": 0}),
     "mamba": dict(ssm_heads=8, ssm_head_dim=16, ssm_state=8, ssm_groups=2,
                   ssm_chunk=8),
     "linear_attention": dict(gdn_heads=4, gdn_key_dim=8, gdn_value_dim=16),
@@ -211,8 +215,11 @@ def test_a_step_opens_the_scopes_a_block_declares_and_no_other(role, name):
             for n in re.findall(r'loc\("([^"]*)"', text)}
     # on one device: the exchange opens its scope only over an ``ep`` axis
     # (tests/test_moe.py::test_tokens_are_split_over_ep_outside_the_experts)
+    # ... and the denoising objective's noise is the decoder's scope, not
+    # a block's (a model with a ``block_diffusion`` group opens it)
     assert seen - {None, "scan"} == {
         "embed", *mixer.scopes, *ffn.scopes, *_publisher(cfg).scopes,
+        *(["bd_noise"] if cfg.block_diffusion else []),
         "lm_head", "loss", "optimizer"} - {"moe_exchange"}
     assert set(mixer.scopes) | set(ffn.scopes) <= set(STEP_SCOPES)
 
@@ -226,13 +233,15 @@ def test_every_statistic_a_block_declares_is_a_metric(role, name):
     params = init_params(jax.random.PRNGKey(0), cfg)
     _, (metrics, _) = jax.jit(lambda p: loss_and_counts(
         p, {"tokens": TOKENS}, cfg))(params)
-    assert set(metrics) == {"loss", "aux_loss", "perplexity", *declared}
+    assert set(metrics) == {
+        "loss", "aux_loss", "perplexity", *declared,
+        *([llama.BD_MASKED_SHARE] if cfg.block_diffusion else [])}
     assert all(np.isfinite(float(v)) for v in metrics.values())
 
 
 # -- (b) the step's scopes, as they were --------------------------------------
 
-def test_step_scopes_are_the_41_names_in_their_order():
+def test_step_scopes_are_the_42_names_in_their_order():
     assert STEP_SCOPES == (
         "embed", "attn_qkv", "attention", "attn_out", "ffn",
         "moe_route", "moe_exchange", "moe_dispatch", "moe_experts",
@@ -243,7 +252,7 @@ def test_step_scopes_are_the_41_names_in_their_order():
         "kda_in", "kda_conv", "kda_scan", "kda_out",
         "sconv_in", "sconv_gate", "sconv_out",
         "s6_in", "s6_conv", "s6_scan", "s6_out", "attn_diff", "gmu",
-        "hc_map", "hc_mix", "mtp_in",
+        "hc_map", "hc_mix", "mtp_in", "bd_noise",
         "lm_head", "loss", "optimizer")
 
 

@@ -309,3 +309,59 @@ def test_scan_of_two_share_layers_equals_the_scan_with_every_expert_held():
         assert bool(jnp.isfinite(g).all()), name
         assert float(jnp.abs(g - r).max()) < 1e-5 * float(
             jnp.abs(r).max()) + 1e-6, name
+
+
+# -- the shares of a softmax-routed layer add up to the uncut layer ------------
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _softmax_share(p, first, held, k):
+    """What the chip that holds experts ``first .. first + held - 1`` adds
+    for its tokens: ``moe_block`` routing over ALL the experts, ``k`` a
+    token, gates renormalised over the chosen, no shared expert."""
+    return moe.moe_block(
+        p["x"], p["mlp_norm"], p["router"], *(
+            jax.lax.dynamic_slice_in_dim(p[w], first, held)
+            for w in ("w_gate", "w_up", "w_down")),
+        num_selected=k, norm_eps=1e-6, norm_topk_prob=True,
+        scoring="softmax", first_expert=first, residual=False)
+
+
+@pytest.mark.parametrize("experts,k,chips", [(128, 8, 8), (16, 4, 4)],
+                         ids=["sdar-128-top8-of-8-chips", "16-top4-of-4"])
+def test_the_shares_of_a_softmax_router_add_up_to_the_uncut_layer(
+        experts, k, chips):
+    """At a configuration's router (SDAR's: 128 wide, 8 a token,
+    renormalised, no shared expert, 16 experts a chip of 8): the parts all
+    the chips give, summed, are the plain reference's uncut layer; every
+    share routes over all the experts and drops nothing; one share alone is
+    the reference's share."""
+    from benchmark.reference import sdar_block_diffusion as plain
+    from benchmark.reference.decoder import rms_norm
+
+    rng = np.random.default_rng(experts)
+    n = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa
+    d, m, held = 32, 16, experts // chips
+    p = {"x": n(96, d), "mlp_norm": 1.0 + 0.1 * n(d),
+         "router": n(d, experts) * d ** -0.5,
+         "w_gate": n(experts, d, m) * d ** -0.5,
+         "w_up": n(experts, d, m) * d ** -0.5,
+         "w_down": n(experts, m, d) * m ** -0.5}
+    parts = [_softmax_share(p, first, held, k)
+             for first in range(0, experts, held)]
+    h = rms_norm(p["x"], p["mlp_norm"], 1e-6)
+    whole, chosen, _ = plain.expert_ffn(h[None], p, k=k, renormalise=True,
+                                        first=0)
+    np.testing.assert_allclose(sum(part for part, _ in parts), whole[0],
+                               atol=2e-5)
+    stats = [s for _, s in parts]
+    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
+    assert all(float(s["dropped"]) == 0.0 for s in stats)
+    for s in stats:     # every share routes over all the experts
+        np.testing.assert_array_equal(
+            s["counts"], np.bincount(np.asarray(chosen).ravel(),
+                                     minlength=experts))
+    alone, _, _ = plain.expert_ffn(
+        h[None], {**p, **{w: p[w][held:2 * held]
+                          for w in ("w_gate", "w_up", "w_down")}},
+        k=k, renormalise=True, first=held)
+    np.testing.assert_allclose(parts[1][0], alone[0], atol=2e-5)

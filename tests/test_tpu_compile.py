@@ -227,6 +227,26 @@ def test_windowed_flash_attention_compiles_under_its_own_names(
     assert _has_kernel(lowered.compile())
 
 
+def test_block_rule_flash_attention_compiles_under_its_own_names(one_chip):
+    """SDAR's cell: two streams of 8192 positions, 32 heads on 4 KV heads of
+    128, blocks of 4 — the shifts of the diagonal's test, the q axis over
+    both streams, the noised stream's own keys as two more operands and a
+    KV head's dk / dv of 16384 rows in VMEM are what Mosaic could refuse."""
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    grads = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, block=4, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    lowered = jax.jit(grads).lower(q, kv, kv)
+    text = lowered.as_text()
+    assert [text.count(f'kernel_name = "{name}"') for name in (
+        "flash_fwd_bd", "flash_dkv_bd", "flash_fwd", "flash_dkv")] == [
+            1, 1, 0, 0]
+    assert _has_kernel(lowered.compile())
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)],
                          ids=["gate_up", "down"])

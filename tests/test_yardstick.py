@@ -13,7 +13,8 @@ wrote while they were out
 ``test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries``) stay
 beside them, as do the Kimi-Linear cell's (PR 59), the Solar-Open-2
 cell's (PR 64), the Nemotron-3-Super cell's (PR 66) and the Keye-VL-2.0
-cell's (PR 70) and the Phi-4-mini-flash cell's (PR 74), by name; and ``test_late_steps.py``'s (PR 68: the readers
+cell's (PR 70), the Phi-4-mini-flash cell's (PR 74) and the SDAR
+block-diffusion cell's (PR 76), by name; and ``test_late_steps.py``'s (PR 68: the readers
 of a window's lost time on canned spans), whole."""
 
 import pytest
@@ -26,6 +27,7 @@ pytest.register_assert_rewrite("benchmark.tests.test_trinity",
                                "benchmark.tests.test_nemotron3",
                                "benchmark.tests.test_keye",
                                "benchmark.tests.test_phi4flash",
+                               "benchmark.tests.test_sdar",
                                "benchmark.tests.test_late_steps")
 
 from benchmark.tests.tier1_cases import *  # noqa: E402,F401,F403
@@ -110,6 +112,21 @@ from benchmark.tests.test_phi4flash import (  # noqa: E402,F401
     test_the_file_is_the_catalog_row_cut_to_the_rule_at_depth_eight,
     test_the_parameter_count_is_init_params as
     test_phi4flash_parameter_count)
+from benchmark.tests.test_sdar import (  # noqa: E402,F401
+    test_each_floor_and_each_width_violated_in_turn as
+    test_sdar_each_floor_and_each_width,
+    test_flops_count_two_rows_a_token_one_through_the_head_and_the_needed_pairs,
+    test_on_a_program_without_the_objective_the_readers_return_nothing,
+    test_the_cell_its_job_and_its_metrics as
+    test_sdar_cell_job_and_metrics,
+    test_the_file_is_the_catalog_row_cut_to_one_chip_of_eight as
+    test_sdar_file_is_the_catalog_row_cut_to_one_chip_of_eight,
+    test_the_parameter_count_is_init_params as
+    test_sdar_parameter_count,
+    test_the_readers_and_the_flop_module_import_no_jax as
+    test_sdar_readers_and_flop_module_import_no_jax,
+    test_the_roofline_cannot_pass_100_unless_the_count_is_wrong,
+    test_the_six_readers_on_a_made_up_run)
 from benchmark.tests.test_late_steps import (  # noqa: E402,F401
     test_a_stall_is_split_into_stopped_running_and_waiting,
     test_a_steady_window_reads_zero_everywhere,

@@ -20,8 +20,8 @@ import numpy as np
 
 from benchmark.reference import (
     afmoe, granite_hybrid, joyai_flash, keye_sparse, kimi_linear, lfm2_moe,
-    mellum, nemotron3, nemotron_h, olmo_hybrid, olmoe, phi4flash, solar_open2,
-    xing4)
+    mellum, nemotron3, nemotron_h, olmo_hybrid, olmoe, phi4flash,
+    sdar_block_diffusion, solar_open2, xing4)
 from ray_tpu.models.llama import (
     ROPE_BY_KIND, LlamaConfig, forward, init_params, loss_fn)
 from ray_tpu.ops.moe import moe_block
@@ -134,6 +134,11 @@ SOLAR_LINEAR = {"num_heads": 4, "head_dim": 16, "num_kv_heads": None,
 KEYE_INDEXER = {"indexer_num_heads": 4, "indexer_head_dim": 16,
                 "indexer_num_kv_heads": 1, "topk": 16, "q_chunk_size": 512,
                 "kv_chunk_size": 512}
+# SDAR's objective in small, as hashable pairs (a row's fields and a
+# reference's keys are cached by value): blocks of 4 of the sample's 64
+# positions, the mask token the vocabulary's last, a seed of its own
+SDAR_NOISE = (("block_length", 4), ("eps", 1e-3), ("mask_token_id", 127),
+              ("noise_seed", 5))
 NEMOTRON_PATTERN = "MEM*EMEME"  # longer than the model: the first 5 are run
 NEMOTRON3_MODULE = "*E"         # the predicted-ahead module's own pattern
 
@@ -322,6 +327,23 @@ ROWS: Dict[str, Row] = {
              norm_topk_prob=True, first_expert=4, idx_loss_coef=1.0,
              router_aux_loss_coef=0.0),
         params=_keye_params, precision="highest"),
+    # two layers of one kind under the DENOISING objective: GQA 4/2 with a
+    # norm over each head's q and k under the block rule over [noised ;
+    # clean] (128 rows of 64 positions), every layer an expert layer (16
+    # experts of which this chip holds 4..7, 4 a token, renormalised)
+    "sdar": Row(
+        dict(_SMALL, num_layers=2, num_kv_heads=2, norm_eps=1e-6,
+             qk_head_norm=True, rope_theta=1e4, block_diffusion=SDAR_NOISE,
+             num_experts=16, num_selected=4, norm_topk_prob=True,
+             experts_held=4, first_expert=4, aux_loss_coef=0.001,
+             max_seq_len=128),
+        _jax_tokens(2, 65), sdar_block_diffusion,
+        dict(num_hidden_layers=2, num_attention_heads=4,
+             num_key_value_heads=2, rope_theta=10000, rms_norm_eps=1e-6,
+             block_diffusion=SDAR_NOISE, num_experts_per_tok=4,
+             norm_topk_prob=True, first_expert=4,
+             router_aux_loss_coef=0.001),
+        precision="highest"),
     # the published pattern in small: 1 dense layer then expert layers, KDA
     # x3 to one latent layer WITHOUT a q rank or a rotation; 16 experts of
     # which this chip holds 4..7, 4 a token, a shared expert; 96 positions:
