@@ -34,11 +34,18 @@ Design (standard memory-efficient attention, mapped to the TPU grid model):
   flash-attention kernel uses for its ``l``/``m`` outputs).  ONE lane of
   it, a float a row, is what the checkpoint keeps and the backward takes.
 - Backward: ONE kernel (named ``flash_dkv``: it is that kernel with a
-  third output), grid ``(b, h_kv, rep x q_blocks, kv_blocks)``.  For each
-  live sub-tile it recomputes ``p = exp2(s - lse)`` from the saved per-row
-  logsumexp instead of materializing the S x S matrix, makes ``dP`` and
-  ``ds = p (dP - delta)`` ONCE, and accumulates all three gradients from
-  them: ``dv += p^T do``, ``dk += ds^T q``, ``dq += ds k`` — five products
+  third output), grid ``(b, h_kv, rep x q_blocks, kv_blocks)``.  What
+  crosses the call is q, k, v, o, do and ``lse`` ALONE, a float a row:
+  ``delta``, the float a query row ``sum_d o do``, is made inside the
+  kernel once a q tile from the ``o`` and ``do`` tiles it holds (summed in
+  float32 on the vector unit), so no array of it and no float32 ``o x do``
+  exists outside (XLA's own ``delta`` over ONE row of 16384 tokens read in
+  place was four passes over a float32 array the size of two q's: PERF.md
+  §6, PR 77).  For each live sub-tile it recomputes ``p = exp2(s - lse)``
+  from the saved per-row logsumexp instead of materializing the S x S
+  matrix, makes ``dP`` and ``ds = p (dP - delta)`` ONCE, and accumulates
+  all three gradients from them: ``dv += p^T do``, ``dk += ds^T q``,
+  ``dq += ds k`` — five products
   a pair where the classic split (a dk/dv kernel and a dq kernel, each
   with its own scores and ``dP``) runs seven.  One grid cannot visit both
   a q tile's and a kv tile's accumulator consecutively: ``dq`` is what
@@ -49,9 +56,10 @@ Design (standard memory-efficient attention, mapped to the TPU grid model):
   adds).
   The kernel keeps its scores TRANSPOSED, ``s^T = k q^T`` with the q rows
   along the lanes, so ``dv``'s and ``dk``'s products are plain ones, its
-  per-row stats are rows ``(b, h, 1, sq)`` — nothing is broadcast to 128
-  lanes for it — and ``dq``'s is the one product that contracts the
-  tile's FIRST dimension.  It gathers turned round, ``dq^T += k^T ds^T``
+  per-row stats are rows — ``lse`` comes in as ``(b, h, 1, sq)``, ``delta``
+  is a row of scratch; nothing is broadcast to 128 lanes for it — and
+  ``dq``'s is the one product that contracts the tile's FIRST dimension.
+  It gathers turned round, ``dq^T += k^T ds^T``
   as ``(d, block_q)``, so what Mosaic turns is a strip of k and not the
   tile, and is turned back once a q tile on its way out.
 - Causal schedule: a grid step FETCHES a large tile and the kernel walks
@@ -510,8 +518,10 @@ def _grid_and_specs(qt, kt, vt, causal, tiles, window=None, heads=None,
     ``(b, h, q, kv)`` and ``q_t, k_t, stat_t, k_all`` for the backward's
     ``(b, h_kv, rep x q, kv)`` (``row``: per-row stats lane-replicated
     ``(b, h, sq, LANES)``, the forward's output; ``stat``: a float a row as
-    rows ``(b, h, 1, sq)``; ``all``: a KV head's whole sequence, the block
-    of ``dk`` / ``dv``, which no grid step of the head moves).
+    rows ``(b, h, 1, sq)`` — the log-sum-exp, the one statistic that
+    crosses the backward call: ``delta`` is made in the kernel from the
+    ``o_t`` blocks of o and do; ``all``: a KV head's whole sequence, the
+    block of ``dk`` / ``dv``, which no grid step of the head moves).
 
     TWO ADDRESSINGS of q, k, v, o and their gradients, one body a kernel
     (every leading dimension of a block is squeezed: a ref is ``(rows,
@@ -722,10 +732,10 @@ def _rows(ref, n, body):
     jax.lax.fori_loop(0, ref.shape[0] // n, step, None)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, dq_scale,
-                causal, tiles, grid_qk, window=None, rep=1, sel_ref=None,
-                bd=None):
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, delta_scr, *,
+                dq_scale, causal, tiles, grid_qk, window=None, rep=1,
+                sel_ref=None, bd=None):
     # Axis 2 walks the ``rep`` q heads of this KV head, each head's q tiles
     # in turn, axis 3 a q tile's kv tiles: dq gathers over axis 3 and
     # leaves once a q tile; dk and dv gather over BOTH, a whole sequence of
@@ -751,6 +761,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(ki == 0)
     def _init_q():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        # delta, the float a query row ``sum_d o[r, d] do[r, d]``, once a q
+        # tile and as a ROW, which is how the transposed scores take it: the
+        # float32 products turned round (as dq is on its way out) and summed
+        # down the sublanes.  On the vector unit and in float32 alone: ``ds
+        # = p (dp - delta)`` cancels, and a product on the MXU would round.
+        for s0 in range(0, block_q, sub_q):
+            qs = pl.ds(s0, sub_q)
+            prod = o_ref[qs].astype(jnp.float32) * do_ref[qs].astype(
+                jnp.float32)
+            delta_scr[:, qs] = jnp.broadcast_to(
+                jnp.sum(prod.T, axis=0, keepdims=True), (8, sub_q))
 
     def gather(qs, ks, keys, values, first_row, mask, own=None):
         """One strip's part of the three gradients: queries ``qs`` of the
@@ -760,7 +781,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # Transposed, (sk, sq): p^T and ds^T are what dv's and dk's
         # products take on the left, so no tile is turned round.
         p, ds = _p_and_ds(q, k, values[ks], do, lse_ref[:, qs],
-                          delta_ref[:, qs], mask,
+                          delta_scr[:1, qs], mask,
                           None if sel_ref is None else sel_ref[ks, qs],
                           rule, own)
         # Grad matmuls in the INPUT dtype (bf16 on TPU): the MXU runs
@@ -851,16 +872,17 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, dq_scale, causal, tiles, interpret,
     its operand: dkt and dvt at k's and v's OWN head count, summed over
     each KV head's group of q heads inside the kernel.  ``sel_t (b, sk,
     sq)``: the forward's data mask turned round (``flash_dkv_dsa``);
-    ``block``: two streams under the block rule (``flash_dkv_bd``)."""
+    ``block``: two streams under the block rule (``flash_dkv_bd``).
+
+    ``ot`` rides ``dot``'s spec: a block's index does not depend on the kv
+    axis, so it is fetched once a q tile — ``block_q x dv`` of o's type,
+    0.5 MB at 2048 x 128 bfloat16, twice buffered — and ``delta``'s row is
+    ``(8, block_q)`` float32 of scratch, 64 KB: 1.06 MB of the ``_VMEM_LIMIT``
+    100 MiB beside the 64 MiB ``_check_resident`` leaves dk and dv, where
+    the ``(1, block_q)`` block of an XLA-made ``delta`` took 0.13."""
     b, h, h_kv, sq, sk, d, dv = _dims(qt, kt, vt, heads)
     _check_resident(sk, d, dv, kt.dtype)
     block_q = tiles[0]
-    if heads is None:
-        delta = jnp.sum(ot.astype(jnp.float32) * dot.astype(jnp.float32),
-                        axis=-1)                             # (b, h, sq)
-    else:   # summed where o stands: what is turned round is a float a row
-        delta = jnp.sum((ot.astype(jnp.float32) * dot.astype(jnp.float32)
-                         ).reshape(b, sq, h, dv), axis=-1).transpose(0, 2, 1)
     (nq, nk), specs = _grid_and_specs(qt, kt, vt, causal, tiles, window,
                                       heads, block is not None)
     q_t, o_t, k_t, v_t, k_all, v_all, stat_t = (specs[n] for n in (
@@ -876,7 +898,7 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, dq_scale, causal, tiles, interpret,
                           tiles=tiles, grid_qk=(nq, nk), window=window,
                           rep=rep),
         grid=(b, h_kv, rep * (sq // block_q), nk),
-        in_specs=[q_t, k_t, v_t, o_t, stat_t, stat_t,
+        in_specs=[q_t, k_t, v_t, o_t, o_t, stat_t,
                   *(specs["sel_t"] for _ in masks),
                   *(specs[n] for n in ("kn_t", "vn_t")[:len(own)])],
         out_specs=[q_t, k_all, v_all],
@@ -884,11 +906,12 @@ def _bwd_call(qt, kt, vt, ot, lse, dot, dq_scale, causal, tiles, interpret,
                    for x in (qt, kt, vt)],
         scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32),
                         pltpu.VMEM((sk, d), jnp.float32),
-                        pltpu.VMEM((sk, dv), jnp.float32)],
+                        pltpu.VMEM((sk, dv), jnp.float32),
+                        pltpu.VMEM((8, block_q), jnp.float32)],
         compiler_params=_compiler_params(interpret, sequential=2),
         interpret=interpret,
         name=_kernel_name("flash_dkv", window, sel_t, block),
-    )(qt, kt, vt, dot, lse[:, :, None], delta[:, :, None], *masks, *own)
+    )(qt, kt, vt, ot, dot, lse[:, :, None], *masks, *own)
 
 
 # ----------------------------------------------------------------- public

@@ -198,8 +198,9 @@ def test_flash_dkv_is_one_loop_over_an_interior_tile():
     On a grid with an interior tile it holds that tile's loop (the strips,
     not unrolled) beside the two over its whole-sequence dk / dv (zeroed,
     written out); on a one-tile grid the strips are static.  Its per-row
-    stats are rows ``(b, h, 1, sq)``: the forward's lane-replicated ``(b,
-    h, sq, 128)`` is an output of ``flash_fwd`` and an operand of nothing."""
+    statistic, the log-sum-exp alone, is rows ``(b, h, 1, sq)``: the
+    forward's lane-replicated ``(b, h, sq, 128)`` is an output of
+    ``flash_fwd`` and an operand of nothing."""
     q, k, v = _qkv(256, 24, 16)
 
     def backward(tiles):
@@ -216,7 +217,7 @@ def test_flash_dkv_is_one_loop_over_an_interior_tile():
     fwd, bwd = calls
     assert (1, 2, 256, 128) in [v.aval.shape for v in fwd.outvars]
     operands = [v.aval.shape for v in bwd.invars]
-    assert operands.count((1, 2, 1, 256)) == 2      # lse and delta
+    assert operands.count((1, 2, 1, 256)) == 1      # lse; no delta
     assert (1, 2, 256, 128) not in operands
     assert [v.aval.shape for v in bwd.outvars] == [
         (1, 2, 256, 24), (1, 2, 256, 24), (1, 2, 256, 16)]

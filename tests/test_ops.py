@@ -416,6 +416,15 @@ def _heads_qkv(rep, d, dv, sq=128, sk=128, h_kv=2):
     return q, k, v[..., :dv]
 
 
+def _assert_close(out, want, grads, ref_grads, tol=1e-4):
+    """The value within ``tol``, each gradient within ``tol`` of its
+    reference's largest entry (of 1 at least)."""
+    assert jnp.max(jnp.abs(out - want)) < tol
+    for a, w in zip(grads, ref_grads):
+        assert jnp.max(jnp.abs(a - w)) < tol * max(1.0, float(
+            jnp.max(jnp.abs(w))))
+
+
 def _check_heads(rep, d, dv, sq, sk, kw):
     q, k, v = _heads_qkv(rep, d, dv, sq, sk)
     flash = lambda q, k, v: flash_attention(q, k, v, block_q=64, block_k=64,
@@ -427,10 +436,7 @@ def _check_heads(rep, d, dv, sq, sk, kw):
     want, ref_grads = _value_and_grads(ref, loss, q, k, v)
     assert out.shape == (1, sq, 2 * rep, dv)
     assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
-    assert jnp.max(jnp.abs(out - want)) < 1e-4
-    for a, w in zip(grads, ref_grads):
-        assert jnp.max(jnp.abs(a - w)) < 1e-4 * max(1.0, float(
-            jnp.max(jnp.abs(w))))
+    _assert_close(out, want, grads, ref_grads)
 
 
 @pytest.mark.parametrize("rep,d,dv,mode", HEAD_CASES,
@@ -506,29 +512,129 @@ def _eqns_outside_kernels(jaxpr):
                          ids=["128-in-place", "64-turned-round"])
 def test_round_the_in_place_calls_nothing_is_transposed_or_repeated(
         d, in_place):
-    """At a head of whole lane blocks the program round the three kernels
-    holds ONE transpose, of ``delta``'s float a row, and no k, v, dk or dv
-    at q's head count; a head of 64 lanes keeps the transposes, and loses
-    the repeat all the same.  (sk != sq, so a kv-side array is known by
-    its length.)"""
+    """At a head of whole lane blocks the program round the two kernels
+    holds NO transpose (``delta``, the float a row that was turned round
+    here until PR 77, is made inside the backward kernel) and no k, v, dk or
+    dv at q's head count; a head of 64 lanes keeps the transposes, and loses
+    the repeat all the same.  In neither is a float32 array of o's size
+    left outside the kernels: no ``o x do``.  (sk != sq, so a kv-side array
+    is known by its length; bfloat16, so a float32 array is one the program
+    made.)"""
     rep, h_kv, sq, sk = 4, 2, 128, 256
-    q, k, v = _heads_qkv(rep, d, d, sq, sk, h_kv)
-    grads = jax.grad(lambda *a: flash_attention(
-        *a, causal=False, block_q=64, block_k=64).sum(), (0, 1, 2))
-    eqns = list(_eqns_outside_kernels(jax.make_jaxpr(grads)(q, k, v).jaxpr))
+    q, k, v = (x.astype(jnp.bfloat16)
+               for x in _heads_qkv(rep, d, d, sq, sk, h_kv))
+    # the cotangent comes in as data: no loss whose own arrays would count
+    grads = lambda q, k, v, do: jax.vjp(lambda *a: flash_attention(
+        *a, causal=False, block_q=64, block_k=64), q, k, v)[1](do)
+    eqns = list(_eqns_outside_kernels(jax.make_jaxpr(grads)(q, k, v, q).jaxpr))
     assert sum(e.primitive.name == "pallas_call" for e in eqns) == 2
     turned = [e.outvars[0].aval.shape for e in eqns
               if e.primitive.name == "transpose"]
     if in_place:
-        assert turned == [(1, rep * h_kv, sq)]
+        assert turned == []
     else:       # q, k, v in; o out; do in; dq, dk, dv out
         assert len(turned) == 8 and all(len(t) == 4 for t in turned)
     repeated = rep * h_kv * sk * d      # a k at q's head count
+    as_wide_as_o = math.prod(q.shape)   # b x sq x h x dv
     for e in eqns:
         for var in (*e.invars, *e.outvars):
             shape = getattr(var.aval, "shape", ())
             assert not (sk in shape and math.prod(shape) == repeated), (
                 e.primitive.name, shape)
+        if e.primitive.name == "pallas_call":
+            continue    # the forward's log-sum-exp, 128 lanes wide: its own
+        for var in e.outvars:
+            assert not (var.aval.dtype == jnp.float32
+                        and math.prod(var.aval.shape) >= as_wide_as_o), (
+                e.primitive.name, var.aval)
+
+
+# -- delta, the float a row ``sum_d o do``, made inside the backward kernel --
+
+# The four kinds of call the one backward kernel serves, on 256 query rows:
+# ``(what flash_attention takes, what mha_reference takes)``.  The data mask
+# IS the window's, so that the reference can say what it should give.
+_DELTA_WINDOW = 100
+_DELTA_KINDS = {
+    "plain": (dict(block_q=128, block_k=128), {}),
+    "window": (dict(window=_DELTA_WINDOW, block_q=128, block_k=128),
+               dict(window=_DELTA_WINDOW)),
+    "data-mask": (None, dict(window=_DELTA_WINDOW)),
+    "block-rule": (dict(block=4, block_q=64, block_k=64), dict(block=4)),
+}
+
+
+def _delta_call(kind, d):
+    """``kind``'s attention as ``f(q, k, v) -> o`` through the kernels."""
+    kw = _DELTA_KINDS[kind][0]
+    if kw is not None:
+        return lambda q, k, v: flash_attention(q, k, v, **kw)
+    from ray_tpu.ops import sparse_attention
+
+    at = jnp.arange(256)
+    seen = (at[:, None] >= at) & (at[:, None] - at < _DELTA_WINDOW)
+    sel = seen.astype(jnp.int8)[None]
+    return lambda q, k, v: sparse_attention.attend(
+        q, k, v, sel, sm_scale=d ** -0.5)[0]
+
+
+@pytest.mark.parametrize("rep", [1, 4])
+@pytest.mark.parametrize("d", [128, 64], ids=["in-place", "turned-round"])
+@pytest.mark.parametrize("kind", _DELTA_KINDS)
+def test_delta_made_in_the_backward_kernel_survives_the_cancellation(
+        kind, d, rep):
+    """dq, dk and dv of every kind of call, in both addressings, one q head
+    a KV head and four, against ``mha_reference``'s where ``ds = p (dp -
+    delta)`` CANCELS: v and the cotangent stand round 4, so ``delta`` and
+    every ``dp`` are near ``16 d`` (2048 at 128 lanes) and their difference
+    is of order 10 — a ``delta`` rounded to bfloat16 on its way (8 bits: off
+    by up to 8 there) misses dq and dk by whole units, a float32 one by the
+    sum's last bits."""
+    h_kv, s = 2, 256
+    keys = jax.random.split(jax.random.PRNGKey(d + rep), 4)
+    q, w = (jax.random.normal(key, (1, s, rep * h_kv, d)) for key in keys[:2])
+    k, v = (jax.random.normal(key, (1, s, h_kv, d)) for key in keys[2:])
+    v, w = v + 4.0, w + 4.0
+    flash = _delta_call(kind, d)
+    ref = lambda q, k, v: mha_reference(
+        q, jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2),
+        **_DELTA_KINDS[kind][1])
+    loss = lambda out: jnp.sum(w * out)
+    out, grads = _value_and_grads(flash, loss, q, k, v)
+    want, ref_grads = _value_and_grads(ref, loss, q, k, v)
+    _assert_close(out, want, grads, ref_grads)
+
+
+@pytest.mark.parametrize("d", [128, 64], ids=["in-place", "turned-round"])
+@pytest.mark.parametrize("kind", _DELTA_KINDS)
+def test_the_backward_call_takes_o_and_do_and_one_statistic_a_row(kind, d):
+    """What crosses the backward ``pallas_call``, from the jaxpr: q, k, v,
+    o and do as the addressing has them, ONE float32 statistic a row as rows
+    ``(b, h, 1, sq)`` — the log-sum-exp; no ``delta`` — and then the data
+    mask with the keys first, or the noised stream's keys and values."""
+    rep, h_kv, s = 4, 2, 256
+    h = rep * h_kv
+    q = jnp.zeros((1, s, h, d), jnp.bfloat16)
+    k = jnp.zeros((1, s, h_kv, d), jnp.bfloat16)
+    flash = _delta_call(kind, d)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, do: jax.vjp(flash, q, k, v)[1](
+        do))(q, k, k, q).jaxpr
+    calls = [e for e in _eqns_outside_kernels(jaxpr)
+             if e.primitive.name == "pallas_call"
+             and e.params["name"].startswith("flash_dkv")]
+    assert len(calls) == 1
+    suffix = {"plain": "", "window": "_win", "data-mask": "_dsa",
+              "block-rule": "_bd"}[kind]
+    assert calls[0].params["name"] == "flash_dkv" + suffix
+    got = [(v.aval.shape, v.aval.dtype) for v in calls[0].invars]
+    bf16 = jnp.bfloat16
+    wide, narrow = (((1, s, h * d), (1, s, h_kv * d)) if d == 128 else
+                    ((1, h, s, d), (1, h_kv, s, d)))
+    want = [(wide, bf16), (narrow, bf16), (narrow, bf16), (wide, bf16),
+            (wide, bf16), ((1, h, 1, s), jnp.float32)]
+    want += {"data-mask": [((1, s, s), jnp.int8)],
+             "block-rule": [(narrow, bf16), (narrow, bf16)]}.get(kind, [])
+    assert got == want
 
 
 def test_the_backward_grid_walks_a_groups_heads_and_their_tiles():
