@@ -50,6 +50,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.blocks.base import (
@@ -458,14 +459,70 @@ def _indexer(ctx: Ctx, h, lp):
     return q, k[:, :, 0, :], w
 
 
+# The indexer's loss carries its gradient out of the forward pass.  The KL
+# reaches nothing but the index scores, and through them q_idx, k_idx and w:
+# with the loss ONE number a sequence its cotangent is a scalar a sequence,
+# and ``d(operand) = cotangent x (the operand's gradient at cotangent one)``
+# exactly — for k_idx too, whose gradient sums over the queries.  So the
+# forward rule makes the unit gradients where scores, mask and log-sum-exp
+# stand anyway (``sparse_loss``, then ``sparse_scores_bwd`` on its ``(s, s)``
+# gradient, each under its scope), the layer checkpoint keeps the three
+# (``sparse_attention.UNIT_GRADIENTS``), and the backward rule is three
+# multiplies: neither kernel runs again under the checkpoint, no ``(s, s)``
+# array is read after the forward pass for the loss's sake, and a program
+# that takes no gradient holds ``sparse_loss`` alone.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _indexer_loss(scale, flash, prescaled, q_idx, k_idx, w, scores, sel, q,
+                  k, lse2, lse_i):
+    """The indexer's KL, a sequence's rows summed ``(b,)``, as a function of
+    the indexer's operands alone: everything else comes detached (its
+    cotangent is none, and ``index_scores``' own backward is never
+    reached)."""
+    with jax.named_scope("dsa_loss"):
+        kl, _ = sparse_attention.kl_and_gradient(
+            scores, sel, q, k, lse2, lse_i, sm_scale=scale, flash=flash,
+            q_prescaled=prescaled)
+        return jnp.sum(kl, axis=1)
+
+
+def _indexer_loss_fwd(scale, flash, prescaled, q_idx, k_idx, w, scores, sel,
+                      q, k, lse2, lse_i):
+    with jax.named_scope("dsa_loss"):
+        kl, g = sparse_attention.kl_and_gradient(
+            scores, sel, q, k, lse2, lse_i, sm_scale=scale, flash=flash,
+            q_prescaled=prescaled)
+    with jax.named_scope("dsa_index"):
+        unit = sparse_attention.index_grads(q_idx, k_idx, w, g, kernels=flash)
+    # kept as ``(b, s, H x d)``: a stack of float32 ``(..., H, 64)`` is laid
+    # 128 lanes wide, twice its bytes
+    unit = tuple(checkpoint_name(x.reshape(*x.shape[:2], -1), name)
+                 for x, name in zip(unit, sparse_attention.UNIT_GRADIENTS))
+    # the operands for their shapes and types: the backward reads no value
+    return jnp.sum(kl, axis=1), (unit, (q_idx, k_idx, w))
+
+
+def _indexer_loss_bwd(scale, flash, prescaled, res, ct):
+    unit, operands = res
+    with jax.named_scope("dsa_loss"):
+        # float32 times float32, ONE rounding to the operand's type
+        return (*((ct[:, None, None] * u).astype(x.dtype).reshape(x.shape)
+                  for u, x in zip(unit, operands)), *(None,) * 6)
+
+
+_indexer_loss.defvjp(_indexer_loss_fwd, _indexer_loss_bwd)
+
+
 def _selected_attention(cfg, prescaled: bool, q, k, v, q_idx, k_idx, w):
     """What an indexed layer runs on one shard of the batch, each part
     under its scope: the index scores (``dsa_index``), the selection and
     all the rest of the layer takes from the scores — the mask both ways
     round, its log-sum-exp and counts a row — (``dsa_select``), the softmax
-    over it (``attention``), the indexer's KL a row (``dsa_loss``).
-    Returns ``(o (b, s, h, d), kl (b, s), live pairs (b,), the share of
-    the selection's row blocks that walked a tie (b,))``."""
+    over it (``attention``), the indexer's KL (``dsa_loss``; under a
+    gradient ``_indexer_loss``'s forward rule, which also runs
+    ``index_scores``' backward kernel under ``dsa_index``, in the FORWARD
+    pass).  Returns ``(o (b, s, h, d), kl (b,) the rows' sum, live pairs
+    (b,), the share of the selection's row blocks that walked a tie
+    (b,))``."""
     scale, flash = _sm_scale(cfg), cfg.attn_impl == "flash"
     with jax.named_scope("dsa_index"):
         scores = sparse_attention.index_scores(q_idx, k_idx, w, kernels=flash)
@@ -478,10 +535,8 @@ def _selected_attention(cfg, prescaled: bool, q, k, v, q_idx, k_idx, w):
         o, lse2 = sparse_attention.attend(
             q, k, v, sel, sel_t, sm_scale=scale, flash=flash,
             q_prescaled=prescaled)
-    with jax.named_scope("dsa_loss"):
-        kl = sparse_attention.indexer_kl(
-            scores, sel, q, k, lse2, lse_i, sm_scale=scale, flash=flash,
-            q_prescaled=prescaled)
+    kl = _indexer_loss(scale, flash, prescaled, q_idx, k_idx, w,
+                       *jax.lax.stop_gradient((scores, sel, q, k, lse2, lse_i)))
     return o, kl, live, walked
 
 
@@ -503,7 +558,7 @@ def _indexed_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
     run = functools.partial(_selected_attention, cfg, prescaled)
     if ctx.mesh is not None:
         run = batch_shard_map(run, ctx.mesh, (4, 4, 4, 4, 3, 3),
-                              (4, 2, 1, 1))
+                              (4, 1, 1, 1))
     o, kl, live, walked = run(q, k, v, q_idx, k_idx, w)
     with jax.named_scope("dsa_loss"):
         causal = b * (s * (s + 1) // 2)
@@ -511,7 +566,7 @@ def _indexed_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
         wanted = b * (topk * (topk + 1) // 2 + (s - topk) * topk)
         live = jnp.sum(live)
         aux = fold(aux, {
-            INDEX_LOSS: jnp.mean(kl),
+            INDEX_LOSS: jnp.sum(kl) / (b * s),
             SELECTED_SHARE: live.astype(jnp.float32) / causal,
             SELECTED_OFF: jnp.abs(live - wanted).astype(jnp.float32),
             TIE_WALK_SHARE: jnp.mean(walked),

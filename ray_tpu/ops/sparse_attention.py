@@ -16,23 +16,24 @@ opens the scopes):
 
 - ``index_scores`` (scope ``dsa_index``): the ``(b, s, s)`` float32 matrix,
   ``NEG_INF`` above the diagonal, ``q_chunk`` queries at a time so that
-  the ``H`` heads' products never stand whole; its gradient (``custom_vjp``)
+  the ``H`` heads' products never stand whole; its gradient (``custom_vjp``;
+  ``index_grads``, float32, where a layer asks for it in the forward pass)
   remakes a chunk's products from q, k and w and keeps nothing of them.
 - ``select`` (scope ``dsa_select``): a row's ``topk``-th highest score
   ``tau`` and, for the rule of TIES (the lower key wins), the highest key
   ``tie`` admitted AT ``tau``: ``lax.top_k`` breaks ties towards the lower
   index, so the selection is ``I > tau`` or ``I == tau and s <= tie`` —
   exactly ``topk`` keys a row past the first ``topk`` rows, every causal key
-  before.  Two numbers a row are what the layer checkpoint keeps
-  (``SAVED_RESIDUALS``), and ALL it keeps of a selection: the backward pass
-  selects nothing again.  The kernel (``sparse_select``) counts a block of
-  rows over the columns those rows can select from — up to the block's last
-  row, in whole chunks, never fewer than ``topk`` — and over no other: the
-  rest of the square is ``NEG_INF`` by construction.  It walks the tie's
-  key by its bits only in a block where a tie BINDS (a row with more keys
-  at ``tau`` than fit); elsewhere ``tie`` is the last key at ``tau``, one
-  pass.  The share of blocks that walked is ``select``'s third output, the
-  step statistic ``dsa_tie_walk_share``.
+  before.  Two numbers a row are ALL the layer checkpoint keeps of a
+  selection (``SAVED_RESIDUALS``, beside the loss's three unit gradients):
+  the backward pass selects nothing again.  The kernel (``sparse_select``)
+  counts a block of rows over the columns those rows can select from — up
+  to the block's last row, in whole chunks, never fewer than ``topk`` — and
+  over no other: the rest of the square is ``NEG_INF`` by construction.  It
+  walks the tie's key by its bits only in a block where a tie BINDS (a row
+  with more keys at ``tau`` than fit); elsewhere ``tie`` is the last key at
+  ``tau``, one pass.  The share of blocks that walked is ``select``'s third
+  output, the step statistic ``dsa_tie_walk_share``.
 - ``masks`` (scope ``dsa_select`` too): everything else the layer takes
   from the scores, by ONE kernel pass over their live tiles
   (``sparse_mask``) — the mask, int8 ``(b, s, s)``; the mask with the keys
@@ -43,7 +44,7 @@ opens the scopes):
   tile past the diagonal is read by nobody and written as ZEROS, so either
   mask read whole is ``selection``'s: the XLA form, the tests' oracle and
   what runs where no kernel does (``attend`` then turns the mask round
-  itself, ``indexer_kl`` makes its own log-sum-exp).
+  itself, ``kl_and_gradient`` makes its own log-sum-exp).
 - ``attend`` (scope ``attention``): softmax attention over the selected
   pairs alone: the flash kernels of ``ops/attention.py`` under the mask
   (``flash_fwd_dsa`` / ``flash_dkv_dsa``: the causal tile walk as it is,
@@ -51,10 +52,19 @@ opens the scopes):
   which the selection needs ``selected / causal``), or in XLA where no
   kernel runs (``attn_impl`` other than ``flash``, a sequence no block
   tiles).  Returns the output and each row's log-sum-exp, base 2.
-- ``indexer_kl`` (scope ``dsa_loss``): the heads' probabilities remade from
-  q, k and the log-sum-exp, ``q_chunk`` queries at a time, their mean, the
-  KL a row; its gradient reaches the index scores alone, ``softmax_S(I) -
-  p`` on the selection.
+- ``kl_and_gradient`` (scope ``dsa_loss``): the heads' probabilities remade
+  from q, k and the log-sum-exp, ``q_chunk`` queries at a time, their mean,
+  the KL a row AND its gradient, which reaches the index scores alone:
+  ``softmax_S(I) - p`` on the selection, ``(b, s, s)`` float32.  Both are
+  values of the forward pass.  ``index_grads`` (scope ``dsa_index``) turns
+  that gradient, there and then, into what the step wants — the UNIT
+  gradients to q_idx, k_idx and w, float32 — and the layer ties them to the
+  loss, ONE number a sequence (``models/blocks/attention.py::
+  _indexer_loss``, whose forward rule calls the two): they are all the loss
+  hands its backward pass and all the checkpoint keeps of it, the backward
+  rule is the cotangent (a scalar a sequence, so exact for k_idx too) times
+  each, and neither kernel runs a second time under the checkpoint.
+  ``indexer_kl`` is the plain row form under autodiff, the tests' oracle.
 
 Nothing outside ``S_t`` is read by the softmax or any gradient.
 """
@@ -73,8 +83,12 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import NEG_INF, _LANES, _LN2, _LOG2E
 
-# What the layer checkpoint keeps of a selection: two numbers a row.
-SAVED_RESIDUALS = ("dsa_tau", "dsa_tie")
+# What the layer checkpoint keeps of a selection, two numbers a row, and of
+# the indexer's loss, its UNIT gradients to q_idx, k_idx and w, float32
+# (named by the layer; 72 MB a layer at 16384 tokens of 16 x 64, where the
+# KL's ``(s, s)`` gradient they are made from is 1.07 GB).
+UNIT_GRADIENTS = ("dsa_dq_idx", "dsa_dk_idx", "dsa_dw")
+SAVED_RESIDUALS = ("dsa_tau", "dsa_tie", *UNIT_GRADIENTS)
 # A chunk's products, all heads, may take this much (float32 bytes).
 _CHUNK_BYTES = 256 * 1024 * 1024
 
@@ -139,7 +153,8 @@ def _index_scores_xla(q_idx, k_idx, w):
 
 
 def _index_grads_xla(q_idx, k_idx, w, g):
-    """A chunk's products are made again; dk gathers over the chunks."""
+    """A chunk's products are made again; dk gathers over the chunks.  All
+    three float32."""
     b, s, heads, d = q_idx.shape
     c = _chunk(s, 4 * b * heads * s)
 
@@ -153,12 +168,12 @@ def _index_grads_xla(q_idx, k_idx, w, g):
                         preferred_element_type=jnp.float32)
         dk = dk + jnp.einsum("bchs,bchd->bsd", ds, q,
                              preferred_element_type=jnp.float32)
-        return dk, (dq.astype(q.dtype), dw)
+        return dk, (dq, dw)
 
     dk, (dq, dw) = jax.lax.scan(
         one, jnp.zeros((b, s, d), jnp.float32),
         tuple(_split(x, c) for x in (q_idx, w, g)))
-    return _join(dq), dk.astype(k_idx.dtype), _join(dw)
+    return _join(dq), dk, _join(dw)
 
 
 # The same as kernels: a grid step holds a (queries, keys) tile and walks
@@ -284,12 +299,12 @@ def _grads_kernel(q_ref, k_ref, w_ref, g_ref, dq_ref, dk_ref, dw_ref,
 
     @pl.when(ki == nk - 1)
     def _leave_q():
-        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[...] = dq_scr[...]
         dw_ref[...] = dw_scr[...]
 
     @pl.when((qi == nq - 1) & (ki == nk - 1))
     def _leave_k():
-        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dk_ref[...] = dk_scr[...]
 
 
 def _grads_call(q_idx, k_idx, w, g, interpret):
@@ -297,12 +312,16 @@ def _grads_call(q_idx, k_idx, w, g, interpret):
     (bq, bk), q, k, w_, _, g_at = _index_specs(s, heads, d)
     whole = pl.BlockSpec((None, s, d), lambda b_, i, j: (b_, 0, 0))
     lanes = pl.BlockSpec((None, bq, _LANES), lambda b_, i, j: (b_, i, 0))
-    dq, dk, dw = pl.pallas_call(
+    # the barrier: where the three are a layer's residuals, XLA would have
+    # the kernel write ``dk`` straight into the layer scan's stack, in a
+    # fusion whose scoped VMEM is XLA's 16 MB and not ``_params``' limit
+    # (the kernel holds 34 MB at 16384 tokens: the compile fails)
+    dq, dk, dw = jax.lax.optimization_barrier(pl.pallas_call(
         functools.partial(_grads_kernel, heads=heads, tile=(bq, bk)),
         grid=(b, s // bq, s // bk), in_specs=[q, k, w_, g_at],
         out_specs=[q, whole, lanes],
-        out_shape=[jax.ShapeDtypeStruct((b, heads, s, d), q_idx.dtype),
-                   jax.ShapeDtypeStruct((b, s, d), k_idx.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, heads, s, d), jnp.float32),
+                   jax.ShapeDtypeStruct((b, s, d), jnp.float32),
                    jax.ShapeDtypeStruct((b, s, _LANES), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((heads, bq, d), jnp.float32),
                         pltpu.VMEM((s, d), jnp.float32),
@@ -310,7 +329,7 @@ def _grads_call(q_idx, k_idx, w, g, interpret):
         compiler_params=_params(interpret,
                                 ("parallel", "arbitrary", "arbitrary")),
         interpret=interpret, name="sparse_scores_bwd",
-    )(jnp.moveaxis(q_idx, 2, 1), k_idx, w, g)
+    )(jnp.moveaxis(q_idx, 2, 1), k_idx, w, g))
     return jnp.moveaxis(dq, 1, 2), dk, dw[..., :heads]
 
 
@@ -325,15 +344,26 @@ def _index_scores_fwd(q_idx, k_idx, w, kernel):
     return _index_scores(q_idx, k_idx, w, kernel), (q_idx, k_idx, w)
 
 
-def _index_scores_bwd(kernel, res, g):
+def _index_grads(q_idx, k_idx, w, g, kernel):
     """``g (b, s, s)``: what reaches the scores (0 wherever a pair is not
-    selected)."""
+    selected).  The three gradients in float32."""
     if kernel is None:
-        return _index_grads_xla(*res, g)
-    return _grads_call(*res, g, kernel)
+        return _index_grads_xla(q_idx, k_idx, w, g)
+    return _grads_call(q_idx, k_idx, w, g, kernel)
+
+
+def _index_scores_bwd(kernel, res, g):
+    return tuple(d.astype(x.dtype) for d, x in zip(
+        _index_grads(*res, g, kernel), res))
 
 
 _index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)
+
+
+def _index_kernel(s: int, kernels: bool, interpret: Optional[bool]):
+    """The index kernels' ``interpret``, or None where XLA runs."""
+    return (_interpret(interpret)
+            if kernels and _fits(s, INDEX_TILE) else None)
 
 
 def index_scores(q_idx, k_idx, w, *, kernels: bool = True,
@@ -342,9 +372,21 @@ def index_scores(q_idx, k_idx, w, *, kernels: bool = True,
     -> ``I (b, s, s)`` float32, ``NEG_INF`` where the key is after the
     query.  By the kernels ``sparse_scores`` / ``sparse_scores_bwd`` where
     ``kernels`` and the tiles divide the sequence, else in XLA."""
-    kernel = (_interpret(interpret)
-              if kernels and _fits(q_idx.shape[1], INDEX_TILE) else None)
-    return _index_scores(q_idx, k_idx, w.astype(jnp.float32), kernel)
+    return _index_scores(q_idx, k_idx, w.astype(jnp.float32), _index_kernel(
+        q_idx.shape[1], kernels, interpret))
+
+
+def index_grads(q_idx, k_idx, w, g, *, kernels: bool = True,
+                interpret: Optional[bool] = None):
+    """What ``g (b, s, s)`` reaching ``index_scores``' output sends to its
+    three operands, ``(dq_idx, dk_idx, dw)`` in FLOAT32 whatever the
+    operands' types (the kernel's accumulators as they stand), on detached
+    operands: a value of the forward pass, by ``sparse_scores_bwd`` where
+    ``index_scores`` is ``sparse_scores``."""
+    q_idx, k_idx, w, g = jax.lax.stop_gradient(
+        (q_idx, k_idx, w.astype(jnp.float32), g))
+    return _index_grads(q_idx, k_idx, w, g, _index_kernel(
+        q_idx.shape[1], kernels, interpret))
 
 
 # --------------------------------------------------------------- selection
@@ -870,38 +912,34 @@ def _loss_call(qs, k, lse2, scores, sel, lse_i, interpret):
     return kl[..., 0], g
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _kl_kernel(scores, sel, qs, k, lse2, lse_i, interpret):
-    return _loss_call(qs, k, lse2, scores, sel, lse_i, interpret)[0]
-
-
-def _kl_kernel_fwd(scores, sel, qs, k, lse2, lse_i, interpret):
-    return _loss_call(qs, k, lse2, scores, sel, lse_i, interpret)
-
-
-def _kl_kernel_bwd(interpret, grad, g):
-    return (grad * g[..., None], None, None, None, None, None)
-
-
-_kl_kernel.defvjp(_kl_kernel_fwd, _kl_kernel_bwd)
-
-
-def indexer_kl(scores, sel, q, k, lse2, lse_i=None, *, sm_scale: float,
-               flash: bool = True, q_prescaled: bool = False,
-               interpret: Optional[bool] = None):
+def indexer_kl(scores, sel, q, k, lse2, *, sm_scale: float,
+               q_prescaled: bool = False):
     """The indexer's loss a row, ``(b, s)``: ``KL(p_t || softmax over S_t of
     I[t, .])`` with ``p_t`` detached; its gradient reaches ``scores``
-    alone.  By the kernel ``sparse_loss`` where the flash kernels are the
-    attention and a head fills whole lane blocks (``lse_i``: ``masks``'
-    log-sum-exp of a row's selected scores; without it the kernel's call
-    makes it in XLA), else in XLA."""
-    s, d = q.shape[1], q.shape[-1]
+    alone.  The plain form, in XLA, under autodiff: what ``kl_and_gradient``
+    and the layer's rule are held to (``tests/test_keye.py``)."""
     qs, k, lse2 = jax.lax.stop_gradient(
         (_log2_scaled(q, sm_scale, q_prescaled), k, lse2))
-    if flash and d % _LANES == 0 and _fits(s, LOSS_TILE):
-        return _kl_kernel(scores, sel, qs, k, lse2, lse_i,
-                          _interpret(interpret))
     return _kl_rows(scores, sel, mean_probabilities(qs, k, lse2, sel))
+
+
+def kl_and_gradient(scores, sel, q, k, lse2, lse_i=None, *, sm_scale: float,
+                    flash: bool = True, q_prescaled: bool = False,
+                    interpret: Optional[bool] = None):
+    """``indexer_kl``'s rows ``(b, s)`` AND their sum's gradient to the
+    scores ``(b, s, s)`` float32 (``softmax_S(I) - p`` on the selection, 0
+    off it), both values of the forward pass on detached operands.  By the
+    kernel ``sparse_loss`` where the flash kernels are the attention and a
+    head fills whole lane blocks (``lse_i``: ``masks``' log-sum-exp of a
+    row's selected scores; without it the kernel's call makes it in XLA),
+    else in XLA."""
+    s, d = q.shape[1], q.shape[-1]
+    scores, qs, k, lse2 = jax.lax.stop_gradient(
+        (scores, _log2_scaled(q, sm_scale, q_prescaled), k, lse2))
+    if flash and d % _LANES == 0 and _fits(s, LOSS_TILE):
+        return _loss_call(qs, k, lse2, scores, sel, lse_i,
+                          _interpret(interpret))
+    return _kl_rows_fwd(scores, sel, mean_probabilities(qs, k, lse2, sel))
 
 
 def selected_pairs(sel):

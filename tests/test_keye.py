@@ -21,6 +21,7 @@ import pytest
 
 from benchmark.loops import train
 from benchmark.reference import keye_sparse, mellum
+from ray_tpu.models import llama
 from ray_tpu.models.blocks import attention as attention_block
 from ray_tpu.models.llama import loss_fn
 from ray_tpu.ops import sparse_attention as sa
@@ -292,10 +293,11 @@ def test_the_kernels_are_their_xla_forms_over_several_tiles():
                                                   kernels=kernels)[:2])
             o, lse2 = sa.attend(q, k, v, sel, sm_scale=128 ** -0.5,
                                 flash=kernels)
-            kl = sa.indexer_kl(scores, sel, q, k, lse2,
-                               sm_scale=128 ** -0.5, flash=kernels)
-            return 0.01 * jnp.sum(o * o) + jnp.mean(kl), (
-                jnp.mean(kl), sa.selected_pairs(sel))
+            kl = jnp.sum(attention_block._indexer_loss(
+                128 ** -0.5, kernels, False, q_idx, k_idx, w,
+                *jax.lax.stop_gradient((scores, sel, q, k, lse2, None)))
+            ) / sel.shape[1]
+            return 0.01 * jnp.sum(o * o) + kl, (kl, sa.selected_pairs(sel))
         return jax.jit(jax.value_and_grad(
             f, argnums=tuple(range(6)), has_aux=True))(*operands)
 
@@ -309,6 +311,84 @@ def test_the_kernels_are_their_xla_forms_over_several_tiles():
         assert float(jnp.max(jnp.abs(a - b))) <= 2e-5 * float(
             jnp.max(jnp.abs(b)))
 
+
+
+def _locs(text):
+    """The locations a lowered text names."""
+    return re.findall(r'loc\("([^"]*)"', text)
+
+
+def _late(locs):
+    """Those of ``locs`` that the backward pass runs: the rematerialised
+    forward's, the transposed ones and, in the layer scan's backward body,
+    those under the layer's ``checkpoint``."""
+    return [n for n in locs
+            if "rematted_computation" in n or "transpose(" in n
+            or n.startswith("checkpoint/")]
+
+
+@pytest.mark.parametrize("checkpointed", [False, True],
+                         ids=["plain", "under-the-checkpoint"])
+def test_the_loss_carries_its_gradient_out_of_the_forward_pass(checkpointed):
+    """The layer's rule ``_indexer_loss`` (``kl_and_gradient`` ->
+    ``index_grads`` in its forward rule, the cotangent times each in its
+    backward) by the kernels (``sparse_loss`` at a head of 128,
+    ``sparse_scores_bwd``; interpret mode) against the plain form under
+    autodiff — ``indexer_kl``'s
+    rows summed, differentiated through ``index_scores``' XLA form — for a
+    cotangent that is NOT one and another in each of two sequences: the
+    value a sequence and the gradients to q_idx, k_idx (which sums over the
+    queries) and w.  Under ``jax.checkpoint`` with the model's policy the
+    same numbers, and the backward pass holds neither kernel: both run once,
+    in the forward pass, and their three float32 outputs are what is kept."""
+    q, k, v, q_idx, k_idx, w = _operands(b=2, s=512)
+    topk, scale = 96, 128 ** -0.5
+    ct = jnp.asarray([0.37, -1.9])
+
+    def plain(q_idx, k_idx, w):
+        scores = sa.index_scores(q_idx, k_idx, w, kernels=False)
+        sel = sa.selection(scores, *sa.select(scores, topk,
+                                              kernels=False)[:2])
+        _, lse2 = sa.attend(q, k, v, sel, sm_scale=scale, flash=False)
+        kl = jnp.sum(sa.indexer_kl(scores, sel, q, k, lse2, sm_scale=scale),
+                     axis=1)
+        return jnp.sum(ct * kl), kl
+
+    def carried(q_idx, k_idx, w):
+        scores = sa.index_scores(q_idx, k_idx, w)
+        sel, sel_t, lse_i, _ = sa.masks(scores, *sa.select(scores, topk)[:2])
+        _, lse2 = sa.attend(q, k, v, sel, sel_t, sm_scale=scale)
+        kl = attention_block._indexer_loss(
+            scale, True, False, q_idx, k_idx, w,
+            *jax.lax.stop_gradient((scores, sel, q, k, lse2, lse_i)))
+        return jnp.sum(ct * kl), kl
+
+    if checkpointed:
+        carried = jax.checkpoint(
+            carried, policy=jax.checkpoint_policies.save_only_these_names(
+                *llama._saved_names()))
+    grad = lambda f: jax.jit(jax.value_and_grad(  # noqa: E731
+        f, argnums=(0, 1, 2), has_aux=True))
+    # the indexer's operands in the model's type: the cast is the rule's
+    low = (q_idx.astype(jnp.bfloat16), k_idx.astype(jnp.bfloat16), w)
+    with HIGHEST:
+        (_, want_kl), want = grad(plain)(q_idx, k_idx, w)
+        (_, got_kl), got = grad(carried)(q_idx, k_idx, w)
+        _, got_low = grad(carried)(*low)
+    assert got_kl.shape == (2,)
+    np.testing.assert_allclose(got_kl, want_kl, rtol=1e-5)
+    for a, b in zip(got, want):
+        assert float(jnp.max(jnp.abs(a - b))) <= 2e-5 * float(
+            jnp.max(jnp.abs(b)))
+    assert [x.dtype for x in got_low] == [x.dtype for x in low]
+    unit = jax.eval_shape(sa.index_grads, *low, jax.ShapeDtypeStruct(
+        (2, 512, 512), jnp.float32))
+    assert {x.dtype for x in unit} == {jnp.dtype(jnp.float32)}
+    text = grad(carried).lower(q_idx, k_idx, w).as_text(debug_info=True)
+    late = _late(_locs(text))
+    for kernel in ("sparse_loss", "sparse_scores_bwd"):
+        assert kernel in text
+        assert not [n for n in late if kernel in n], kernel
 
 
 @pytest.mark.parametrize("b,s,topk,levels", [
@@ -441,12 +521,23 @@ def test_the_train_step_runs_the_kernels_under_their_scopes_and_learns():
     # under the checkpoint the backward pass selects nothing again and
     # runs no second forward kernel: the selection's two numbers a row
     # and the kernel's output are kept
-    again = [n for n in re.findall(r'loc\("([^"]*)"', text)
-             if "rematted_computation" in n]
+    locs = _locs(text)
+    again = [n for n in locs if "rematted_computation" in n]
     assert any("sparse_scores" in n for n in again)     # remade, as q and k
     assert any("sparse_mask" in n for n in again)   # from the kept tau, tie
     assert not any("sparse_select" in n or "flash_fwd_dsa" in n
                    for n in again)
+    # the indexer's loss hands its backward three unit gradients and no
+    # more: ``sparse_scores_bwd`` runs in the FORWARD pass, under its scope,
+    # and the KL (in XLA at a head of 16) with its ``(s, s)`` gradient is
+    # made once — the rematerialised pass holds nothing of ``dsa_loss``,
+    # the backward pass three multiplies and no ``(s, s)`` array
+    late = _late(locs)
+    assert any(n.startswith("dsa_index/sparse_scores_bwd") for n in locs)
+    assert not [n for n in late if "sparse_scores_bwd" in n]
+    assert any(n.startswith("dsa_loss/exp") for n in locs)
+    assert {n.rsplit("/", 1)[-1] for n in late if "dsa_loss/" in n} == {
+        "mul", "div", "broadcast_in_dim", "reshape"}
     compiled = lowered.compile()
     losses = []
     for _ in range(3):
