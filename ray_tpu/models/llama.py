@@ -233,6 +233,15 @@ class LlamaConfig:
     # the loss is the masked positions' OWN tokens' cross-entropy over
     # ``p``, read off the noised half.  ``forward`` is that pass at step 0.
     block_diffusion: Any = None
+    # A LOOPED model (arXiv:2510.25741), the group {"passes": T,
+    # "entropy_coef": beta}: the WHOLE stack runs T times over the same
+    # weights, the model's last norm at the end of EVERY pass (what it hands
+    # on is what the next pass starts from), and after every pass an exit
+    # gate (one number a token) and the head are read.  The loss is the exit
+    # distribution's expected next-token loss less ``beta`` times its
+    # entropy (``_exit_mixture``); ``forward`` returns the LAST pass's
+    # logits.  None: the stack runs once and nothing here is traced.
+    looped: Any = None
     # Where a block's RMSNorm sits: "input", x + f(norm(x)); "output",
     # x + norm(f(x)) with the same weight on what the block adds; or
     # "sandwich", x + post_norm(f(norm(x))): two norms a block.
@@ -304,6 +313,9 @@ class LlamaConfig:
                                tuple(sorted(self.block_diffusion.items())))
         if self.block_diffusion:
             self._check_block_diffusion()
+        if isinstance(self.looped, dict):
+            object.__setattr__(self, "looped",
+                               tuple(sorted(self.looped.items())))
         if isinstance(self.rope_parameters, dict):
             object.__setattr__(self, "rope_parameters", tuple(sorted(
                 (kind, tuple(sorted(group.items())))
@@ -447,6 +459,35 @@ class LlamaConfig:
                     "('default') and YaRN's are implemented, and a model "
                     "is never trained with plain frequencies in place of "
                     "the ones its file states")
+        if self.looped:
+            self._check_looped()
+
+    def _check_looped(self):
+        """What is built of a looped model, and a refusal by message of
+        what is not: never a silent single pass."""
+        group = self.loop_group
+        if set(group) != {"passes", "entropy_coef"} or not (
+                isinstance(group["passes"], int) and group["passes"] >= 1
+                and group["entropy_coef"] >= 0.0):
+            raise ValueError(
+                "looped: {passes >= 1 (a whole number), entropy_coef >= 0}: "
+                f"{group}")
+        if self.attn_impl in ("ring", "ulysses"):
+            raise NotImplementedError(
+                f"looped with attn_impl {self.attn_impl!r}: the passes are "
+                "not built over a sequence split over 'sp'")
+        if (self.num_nextn or self.block_diffusion or self.hc_mult > 1
+                or self.select_bias and self.num_experts
+                or any(MIXERS[m].publishes or MIXERS[m].reads
+                       for m, _ in self.layer_kinds)):
+            raise NotImplementedError(
+                "looped is built for a stack on one residual stream under "
+                "the next-token loss of every pass: not with a "
+                "predicted-ahead module (num_nextn), block_diffusion, "
+                "several residual streams (hc_mult), a router's selection "
+                "bias (its counts are a step's, not a pass's) or a mixer "
+                "that publishes or reads across layers — what one pass "
+                "published the next would have to read")
 
     def _check_block_diffusion(self):
         """What is built of the denoising objective, and a refusal by
@@ -482,6 +523,15 @@ class LlamaConfig:
     @property
     def bd_group(self) -> Dict[str, Any]:
         return dict(self.block_diffusion or ())
+
+    @property
+    def loop_group(self) -> Dict[str, Any]:
+        return dict(self.looped or ())
+
+    @property
+    def passes(self) -> int:
+        """The times the stack is run: a looped model's T, 1 for any other."""
+        return self.loop_group.get("passes", 1)
 
     @property
     def bd_block(self) -> int:
@@ -773,6 +823,8 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         axes["final_norm_bias"] = ("embed",)
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("kernel_in", "vocab")
+    if cfg.looped:
+        axes["exit_gate"], axes["exit_gate_bias"] = ("embed",), ()
     if cfg.num_nextn:
         axes["mtp"] = {**{k: p.axes for k, p in _mtp_shapes(cfg).items()},
                        "layers": stacks(cfg.mtp_runs)}
@@ -790,7 +842,8 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
     main = run_shapes(cfg.kind_runs)
     mtp = run_shapes(cfg.mtp_runs) if cfg.num_nextn else []
     n_tensors = sum(len(shapes) for _, shapes in main + mtp) + 3 + (
-        len(_mtp_shapes(cfg)) if mtp else 0) + (cfg.norm_type == "layernorm")
+        len(_mtp_shapes(cfg)) if mtp else 0) + (
+            cfg.norm_type == "layernorm") + bool(cfg.looped)
     keys = iter(jax.random.split(key, n_tensors))
 
     def drawn(p: Param, *stacked):
@@ -824,6 +877,10 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict[str, Any]:
                          for name, p in _mtp_shapes(cfg).items()}
         params["mtp"]["layers"] = _per_run(
             [stack(n, shapes) for n, shapes in mtp])
+    if cfg.looped:
+        # the exit gate: a projection to ONE number a token, its bias at 0
+        params["exit_gate"] = matrix((cfg.embed_dim,), cfg.embed_dim)
+        params["exit_gate_bias"] = jnp.zeros((), cfg.param_dtype)
     return params
 
 
@@ -843,6 +900,13 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig, *,
         (logits, _, _), aux, _ = _denoising_pass(params, tokens, cfg, mesh,
                                                  rules, 0)
         return logits, _mean_aux(aux, cfg, cfg.kind_runs)
+    if cfg.looped:
+        # no exit is taken early: the last pass's logits (its stream is
+        # normed already)
+        h, aux, _ = _looped(params, tokens, None, cfg, mesh, rules)
+        with jax.named_scope("lm_head"):
+            logits = _head_product(params, h, cfg, _make_cst(mesh, rules))
+        return logits, _mean_aux(aux, cfg, cfg.kind_runs * cfg.passes)
     h, aux, _ = _hidden(params, tokens, cfg, mesh, rules)
     return (_lm_head(params, h, cfg, _make_cst(mesh, rules)),
             _mean_aux(aux, cfg, cfg.kind_runs))
@@ -1064,7 +1128,7 @@ def _mean_aux(aux, cfg: LlamaConfig, runs):
     """What the scan carried over ``runs``: a ``mean`` divided by the
     layers that added to it, a ``sum`` and a ``max`` as they are."""
     if not isinstance(aux, dict):
-        return aux / cfg.num_layers
+        return aux / (cfg.num_layers * cfg.passes)
     how, layers = {"aux_loss": "mean"}, dict.fromkeys(aux, 0)
     for kind, n in runs:
         for k, h in _layer_stats(cfg, kind).items():
@@ -1076,17 +1140,115 @@ def _mean_aux(aux, cfg: LlamaConfig, runs):
 def _lm_head(params, x, cfg: LlamaConfig, cst):
     """Final norm and head product -> f32 logits (scope ``lm_head``)."""
     with jax.named_scope("lm_head"):
-        x = residual.norm(x, params["final_norm"],
-                          params.get("final_norm_bias"), cfg.norm_eps)
-        if cfg.tie_embeddings:  # one table, read twice: its gradient is
-            # the sum of both uses
-            logits = jnp.einsum("bsd,vd->bsv", x,
-                                params["embed"].astype(cfg.dtype))
-        else:
-            logits = x @ params["lm_head"].astype(cfg.dtype)
-        logits = scaled(logits.astype(jnp.float32),
-                         1.0 / cfg.logits_scaling)
-        return cst(logits, ("batch", "seq", "vocab"))
+        return _head_product(params, _final_norm(params, x, cfg), cfg, cst)
+
+
+def _final_norm(params, x, cfg: LlamaConfig):
+    return residual.norm(x, params["final_norm"],
+                         params.get("final_norm_bias"), cfg.norm_eps)
+
+
+def _head_product(params, x, cfg: LlamaConfig, cst):
+    """The normed ``x`` through the head -> f32 logits (inside the scope
+    ``lm_head``)."""
+    if cfg.tie_embeddings:  # one table, read twice: its gradient is
+        # the sum of both uses
+        logits = jnp.einsum("bsd,vd->bsv", x,
+                            params["embed"].astype(cfg.dtype))
+    else:
+        logits = x @ params["lm_head"].astype(cfg.dtype)
+    logits = scaled(logits.astype(jnp.float32), 1.0 / cfg.logits_scaling)
+    return cst(logits, ("batch", "seq", "vocab"))
+
+
+def _ut_pass(params, layers, x, aux, cfg: LlamaConfig, mesh, rules):
+    """One pass of a looped model: the whole stack ``layers`` over ``x``
+    (the layer checkpoint as on every path), then the model's ONE last norm
+    -> ``(h, aux)``; ``h`` is what the exit gate and the head read AND what
+    the next pass starts from."""
+    x, aux, _ = _scan_layers(layers, x, cfg, mesh, rules, aux=aux)
+    # keeps the pass's output, not the norm's float32 copies of it
+    norm = _inputs_kept(lambda params, x: _final_norm(params, x, cfg), cfg)
+    with jax.named_scope("lm_head"):
+        return norm(params, x), aux
+
+
+def _inputs_kept(fn, cfg: LlamaConfig):
+    """``fn`` under a checkpoint (``cfg.remat``) that keeps what it reads of
+    its inputs and nothing it makes.  For the body of the passes' scan,
+    where nothing is merged across the checkpoint's edge anyway."""
+    return jax.checkpoint(fn, prevent_cse=False) if cfg.remat else fn
+
+
+def _exit_reading(params, h, targets, cfg: LlamaConfig, cst):
+    """What the objective reads of a pass's ``h (b, s, d)``: the exit
+    gate's logit and each position's next-token loss, ``(b, s)`` float32
+    both.  The head and its loss run under a checkpoint of their own
+    (``_inputs_kept``) that keeps ``h`` and nothing else: the backward pass
+    makes the pass's float32 logits again, so ONE pass's logits and their
+    gradient are alive at a time, forward and backward."""
+    with jax.named_scope("ut_exit"):
+        gate = jnp.einsum(
+            "bsd,d->bs", h, params["exit_gate"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32) \
+            + params["exit_gate_bias"].astype(jnp.float32)
+
+    def head_nll(params, h):
+        with jax.named_scope("lm_head"):
+            logits = _head_product(params, h, cfg, cst)
+        with jax.named_scope("loss"):
+            return _token_nll(logits, targets)
+
+    return gate, _inputs_kept(head_nll, cfg)(params, h)
+
+
+def _looped(params, tokens, targets, cfg: LlamaConfig, mesh, rules):
+    """A looped model's passes over ``tokens (b, s)``: ``(the last pass's
+    h, aux, (gate logits, next-token losses) (T, b, s) or None without
+    ``targets``)``.  ONE ``lax.scan`` over the passes whose constants are
+    the parameters: the program holds one layer body a run and one
+    norm-gate-head-loss body whatever T is, a shared tensor's gradient is
+    the scan's sum over its T uses, and the backward pass of pass t reruns
+    pass t's layers alone, from what the layer checkpoint kept of them."""
+    cst = _make_cst(mesh, rules)
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, cfg, mesh, rules)
+
+    def one_pass(carry, _):
+        h, aux = _ut_pass(params, params["layers"], *carry, cfg, mesh, rules)
+        return (h, aux), None if targets is None else _exit_reading(
+            params, h, targets, cfg, cst)
+
+    (h, aux), read = jax.lax.scan(one_pass, (x, _zero_aux(cfg)), None,
+                                  length=cfg.passes)
+    return h, aux, read
+
+
+def _exit_mixture(gates, nll, cfg: LlamaConfig):
+    """The looped objective from every pass's gate logit and per-token loss
+    ``(T, b, s)`` (scope ``ut_exit``): with ``lambda_t = sigmoid(g_t)`` a
+    token exits after pass t < T with ``p_t = lambda_t prod_(j<t) (1 -
+    lambda_j)`` and after the last with what is left (``g_T`` is not read),
+    and ``loss = mean_i (sum_t p_t nll_t - beta H(p))``; nothing is
+    detached.  Carried in logs (``log (1 - sigmoid(g)) = log_sigmoid(-g)``).
+    Returns ``(loss, its step statistics)``."""
+    with jax.named_scope("ut_exit"):
+        passes = gates.shape[0]
+        one = jnp.zeros_like(gates[:1])             # log 1
+        survived = jnp.concatenate(                 # log S_(t-1), t = 1..T
+            [one, jnp.cumsum(jax.nn.log_sigmoid(-gates[:-1]), axis=0)])
+        log_p = survived + jnp.concatenate(
+            [jax.nn.log_sigmoid(gates[:-1]), one])
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, axis=0)
+        loss = jnp.mean(jnp.sum(p * nll, axis=0)
+                        - cfg.loop_group["entropy_coef"] * entropy)
+        steps = jnp.arange(1, passes + 1, dtype=jnp.float32)
+        return loss, {
+            **{f"ut_nll_{t + 1}": jnp.mean(nll[t]) for t in range(passes)},
+            "ut_exit_entropy": jnp.mean(entropy),
+            "ut_expected_steps": jnp.mean(jnp.einsum("t,t...->...", steps, p)),
+            "ut_steps": jnp.float32(passes)}
 
 
 def _make_cst(mesh, rules):
@@ -1145,6 +1307,11 @@ def _make_layer_fn(cfg: LlamaConfig, mesh, rules, sp_manual: bool = False,
 
 
 def _one_kind(cfg: LlamaConfig, what: str) -> None:
+    if cfg.looped:
+        raise NotImplementedError(
+            f"{what} runs a stage's layers ONCE a micro-batch; a looped "
+            "model's passes over one stack, its exit gate and its objective "
+            "are built on the normal path: make_train_step(pipelined=False)")
     if cfg.block_diffusion:
         raise NotImplementedError(
             f"{what} runs the next-token objective on one stream; a "
@@ -1335,8 +1502,16 @@ def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     else:
         inputs, targets = batch["inputs"], batch["targets"]
-    counts = ahead = weights = None
-    if cfg.block_diffusion:
+    counts = ahead = weights = exits = None
+    if cfg.looped:
+        if forward_fn is not None:
+            raise NotImplementedError(
+                "looped with a replaced forward pass (the pipelined path): "
+                "it would run ONE pass under the plain next-token loss")
+        _, aux, read = _looped(params, inputs, targets, cfg, mesh, rules)
+        aux = _mean_aux(aux, cfg, cfg.kind_runs * cfg.passes)
+        loss, exits = _exit_mixture(*read, cfg)
+    elif cfg.block_diffusion:
         if forward_fn is not None:
             raise NotImplementedError(
                 "block_diffusion with a replaced forward pass (the pipelined "
@@ -1366,8 +1541,9 @@ def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
     else:
         logits, aux = forward_fn(params, inputs)
     with jax.named_scope("loss"):
-        loss = (_mean_nll(logits, targets) if weights is None
-                else _weighted_nll(logits, targets, weights))
+        if exits is None:
+            loss = (_mean_nll(logits, targets) if weights is None
+                    else _weighted_nll(logits, targets, weights))
         # the blocks' statistics are metrics under their own names; the
         # two that are losses also weigh in
         stats = aux if isinstance(aux, dict) else {"aux_loss": aux}
@@ -1386,7 +1562,11 @@ def loss_and_counts(params: Dict[str, Any], batch: Dict[str, jax.Array],
                 (jnp.arange(seq) < seq - 1).astype(jnp.float32))
             total = total + cfg.mtp_loss_coef * mtp_loss
             metrics["mtp_loss"] = mtp_loss
-        metrics["perplexity"] = jnp.exp(loss)
+        if exits is None:
+            metrics["perplexity"] = jnp.exp(loss)
+        else:   # ``loss`` is the looped objective, no log-likelihood: its
+            # exponential would be no perplexity
+            metrics.update(exits)
         return total, (metrics, counts)
 
 
@@ -1423,6 +1603,14 @@ def _mean_nll(logits, targets, weights=None):
     return jnp.sum(nll * weights) / (jnp.sum(weights) * nll.size
                                      / weights.size)
 
+
+
+def _token_nll(logits, targets):
+    """Each position's next-token loss ``(b, s)``; one row without its
+    dimension, as ``_mean_nll`` (no flat scatter)."""
+    if logits.shape[0] == 1:
+        return _row_nll(logits[0], targets[0])[None]
+    return _row_nll(logits, targets)
 
 
 def _weighted_nll(logits, targets, weights):

@@ -43,7 +43,7 @@ tracing.watch_process()
 # ``rematted_computation`` the rematerialised forward (of all but the
 # residuals a block keeps by name), ``transpose(jvp(..))`` the backward
 # pass (``util.tracing.step_breakdown``).
-STEP_SCOPES = ("embed", *layer_scopes(), "mtp_in", "bd_noise",
+STEP_SCOPES = ("embed", *layer_scopes(), "mtp_in", "bd_noise", "ut_exit",
                "lm_head", "loss", "optimizer")
 
 

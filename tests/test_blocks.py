@@ -241,7 +241,7 @@ def test_every_statistic_a_block_declares_is_a_metric(role, name):
 
 # -- (b) the step's scopes, as they were --------------------------------------
 
-def test_step_scopes_are_the_42_names_in_their_order():
+def test_step_scopes_are_the_43_names_in_their_order():
     assert STEP_SCOPES == (
         "embed", "attn_qkv", "attention", "attn_out", "ffn",
         "moe_route", "moe_exchange", "moe_dispatch", "moe_experts",
@@ -252,7 +252,7 @@ def test_step_scopes_are_the_42_names_in_their_order():
         "kda_in", "kda_conv", "kda_scan", "kda_out",
         "sconv_in", "sconv_gate", "sconv_out",
         "s6_in", "s6_conv", "s6_scan", "s6_out", "attn_diff", "gmu",
-        "hc_map", "hc_mix", "mtp_in", "bd_noise",
+        "hc_map", "hc_mix", "mtp_in", "bd_noise", "ut_exit",
         "lm_head", "loss", "optimizer")
 
 

@@ -392,6 +392,8 @@ def test_step_program_is_named_by_scope(mesh_kw, moe, hybrid):
     want -= {"s6_in", "s6_conv", "s6_scan", "s6_out", "attn_diff", "gmu"}
     # ... nor a denoising objective's noise (tests/test_sdar.py)
     want -= {"bd_noise"}
+    # ... nor a looped model's exit gate and objective (tests/test_ouro.py)
+    want -= {"ut_exit"}
     want -= {"loss"} if mesh is None or moe else set()  # tp splits it
     # the embedding takes the rows its tokens name: a gather, never a
     # matmul.  On one device the scope shows nothing here; under a mesh

@@ -13,8 +13,9 @@ wrote while they were out
 ``test_flash_xla_ms_stands_as_it_was_before_this_cells_two_entries``) stay
 beside them, as do the Kimi-Linear cell's (PR 59), the Solar-Open-2
 cell's (PR 64), the Nemotron-3-Super cell's (PR 66) and the Keye-VL-2.0
-cell's (PR 70), the Phi-4-mini-flash cell's (PR 74) and the SDAR
-block-diffusion cell's (PR 76), by name; and ``test_late_steps.py``'s (PR 68: the readers
+cell's (PR 70), the Phi-4-mini-flash cell's (PR 74), the SDAR
+block-diffusion cell's (PR 76) and the Ouro looped-stack cell's (PR 79), by
+name; and ``test_late_steps.py``'s (PR 68: the readers
 of a window's lost time on canned spans), whole."""
 
 import pytest
@@ -28,6 +29,7 @@ pytest.register_assert_rewrite("benchmark.tests.test_trinity",
                                "benchmark.tests.test_keye",
                                "benchmark.tests.test_phi4flash",
                                "benchmark.tests.test_sdar",
+                               "benchmark.tests.test_ouro",
                                "benchmark.tests.test_late_steps")
 
 from benchmark.tests.tier1_cases import *  # noqa: E402,F401,F403
@@ -127,6 +129,20 @@ from benchmark.tests.test_sdar import (  # noqa: E402,F401
     test_sdar_readers_and_flop_module_import_no_jax,
     test_the_roofline_cannot_pass_100_unless_the_count_is_wrong,
     test_the_six_readers_on_a_made_up_run)
+from benchmark.tests.test_ouro import (  # noqa: E402,F401
+    test_each_width_and_the_passes_changed_in_turn_is_a_complaint,
+    test_flops_count_a_layer_and_the_head_once_a_pass,
+    test_on_a_program_without_the_passes_the_readers_return_nothing,
+    test_the_cell_its_job_and_its_metrics as
+    test_ouro_cell_job_and_metrics,
+    test_the_file_is_the_catalog_row_cut_in_depth_alone,
+    test_the_five_readers_on_a_made_up_run,
+    test_the_parameter_count_is_init_params as
+    test_ouro_parameter_count,
+    test_the_readers_and_the_flop_module_import_no_jax as
+    test_ouro_readers_and_flop_module_import_no_jax,
+    test_the_roofline_cannot_pass_100_unless_the_count_is_wrong as
+    test_ouro_roofline_cannot_pass_100_unless_the_count_is_wrong)
 from benchmark.tests.test_late_steps import (  # noqa: E402,F401
     test_a_stall_is_split_into_stopped_running_and_waiting,
     test_a_steady_window_reads_zero_everywhere,

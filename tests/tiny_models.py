@@ -20,7 +20,7 @@ import numpy as np
 
 from benchmark.reference import (
     afmoe, granite_hybrid, joyai_flash, keye_sparse, kimi_linear, lfm2_moe,
-    mellum, nemotron3, nemotron_h, olmo_hybrid, olmoe, phi4flash,
+    mellum, nemotron3, nemotron_h, olmo_hybrid, olmoe, ouro_looped, phi4flash,
     sdar_block_diffusion, solar_open2, xing4)
 from ray_tpu.models.llama import (
     ROPE_BY_KIND, LlamaConfig, forward, init_params, loss_fn)
@@ -72,6 +72,18 @@ def _keye_params(cfg):
     params["layers"]["k_idx_bias"] = 0.2 * jax.random.normal(
         jax.random.PRNGKey(11), bias.shape, bias.dtype)
     return params
+
+
+def _ouro_params(cfg):
+    """``seeded``, with the exit gate four times its draw and its bias
+    (drawn at 0) away from it: the exits then stand far from uniform, and a
+    fault in how the passes are weighted shows (a model without the group
+    has no gate: ``seeded``)."""
+    params = seeded(cfg)
+    if not cfg.looped:
+        return params
+    return dict(params, exit_gate=4.0 * params["exit_gate"],
+                exit_gate_bias=params["exit_gate_bias"] + 0.3)
 
 
 def _jax_tokens(rows, positions, vocab=128):
@@ -139,6 +151,9 @@ KEYE_INDEXER = {"indexer_num_heads": 4, "indexer_head_dim": 16,
 # positions, the mask token the vocabulary's last, a seed of its own
 SDAR_NOISE = (("block_length", 4), ("eps", 1e-3), ("mask_token_id", 127),
               ("noise_seed", 5))
+# Ouro's group in small, as hashable pairs; ``looped(T)`` is the pair of a
+# row's field and its reference's keys at another count of passes
+OURO_LOOP = (("entropy_coef", 0.05), ("passes", 4))
 NEMOTRON_PATTERN = "MEM*EMEME"  # longer than the model: the first 5 are run
 NEMOTRON3_MODULE = "*E"         # the predicted-ahead module's own pattern
 
@@ -401,6 +416,17 @@ ROWS: Dict[str, Row] = {
              layer_norm_eps=1e-5, mb_per_layer=2),
         params=functools.partial(seeded, also=("s6_D",)),
         precision="highest"),
+    # ONE stack of two layers run four times over the same weights, four
+    # norms a layer, the last norm after every pass, an exit gate and the
+    # head read after every pass
+    "ouro": Row(
+        dict(_SMALL, num_layers=2, num_kv_heads=4, norm_eps=1e-6,
+             rope_theta=1e4, block_norm="sandwich", looped=OURO_LOOP),
+        _jax_tokens(2, 65), ouro_looped,
+        dict(num_hidden_layers=2, num_attention_heads=4,
+             num_key_value_heads=4, rope_theta=10000, rms_norm_eps=1e-6,
+             total_ut_steps=4, looped=dict(OURO_LOOP)),
+        params=_ouro_params, precision="highest"),
 }
 
 
@@ -410,6 +436,14 @@ class Frozen(dict):
 
     def __hash__(self):
         return hash(tuple(sorted(self.items())))
+
+
+def looped(passes: int, entropy_coef: float = 0.05):
+    """``(the program's field, the reference's keys)`` of an Ouro row at
+    ``passes`` passes, both hashable."""
+    group = {"passes": passes, "entropy_coef": entropy_coef}
+    return tuple(sorted(group.items())), dict(
+        total_ut_steps=passes, looped=Frozen(group))
 
 
 def tiny(name: str, **kw) -> LlamaConfig:
