@@ -41,17 +41,18 @@ arrays in memory, autodiff but for the inverse.  ``delta_xla`` is also the
 tests' second oracle.  Both take a state that enters.  ``T`` is the inverse
 of a unit lower triangular matrix of ``chunk`` rows
 (``unit_lower_inverse``): the inverses of its diagonal blocks of two rows,
-merged by doubling, ten products of ``chunk`` rows at 64 in place of a
-substitution of ``chunk`` dependent steps; its backward pass is written out
-(``dA = -T^T dT T^T``).
+merged by doubling, ten products of ``chunk`` rows at 64 (in the kernels:
+of a pair's 128) in place of a substitution of ``chunk`` dependent steps;
+its backward pass is written out (``dA = -T^T dT T^T``).
 
 The kernels (``delta_fwd``, ``delta_bwd``): grid ``(batch, head, step)``,
-a step being a few PAIRS of chunks worked through by a loop; the step axis
-is sequential and the head's ``(keys, values)`` float32 state rides in a
-VMEM scratch from one chunk to the next (backward: its gradient, chunks in
-reverse).  A pair's two chunks sit on the block diagonal of ONE ``(128,
-128)`` matrix (a ``(64, 64)`` float32 tile fills half the lanes; the zeros
-add nothing, so it stays exact): ``A``, ``T``, ``T (beta v)``, ``T (beta
+a step being a few PAIRS of chunks worked through in turn (written out, not
+looped: ``_each_pair``); the step axis is sequential and the head's
+``(keys, values)`` float32 state rides in a VMEM scratch from one chunk to
+the next (backward: its gradient, chunks in reverse).  A pair's two chunks
+sit on the block diagonal of ONE ``(128, 128)`` matrix (a ``(64, 64)``
+float32 tile fills half the lanes; the zeros add nothing, so it stays
+exact): ``A``, ``T``, ``T (beta v)``, ``T (beta
 decay k)``, the masked ``q k^T`` and the state never leave VMEM.  q, k, v
 and o are read and written a head at a time with the SEQUENCE as the minor
 dimension, ``(batch, heads, d, s)`` — how XLA lays the mixer's arrays out
@@ -69,10 +70,12 @@ float32 product of 128 rows (``dA = -(T^T du0) (T vb)^T - (T^T dw) (T
 kb)^T``).  XLA makes a chunk's cumulative log-decays and differentiates them.
 
 Precision: log-decays, their cumulative sums, every ``exp``, ``A``, the
-inverse and the carried state are float32 (the inverse's products at
-``Precision.HIGHEST``); the operands of the big products (``k k^T``, ``q
-k^T``, ``T`` times values and keys, everything times the state) are in
-``q.dtype`` with float32 accumulation.  ``state_absmax`` — the largest
+inverse and the carried state are float32 (the inverse's ten products at
+``Precision.HIGHEST``, six passes of the MXU each, all six cross terms of
+the operands' bfloat16 parts: ``_pair_inverse`` has what was tried in their
+place); the operands of the big products (``k k^T``, ``q k^T``, ``T`` times
+values and keys, everything times the state) are in ``q.dtype`` with
+float32 accumulation.  ``state_absmax`` — the largest
 ``|S|`` at a chunk's end — is the first number to read when a comparison
 with the recurrence drifts.
 """
@@ -309,6 +312,20 @@ def _iota(shape, axis):
     return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 
 
+def _each_pair(pairs, body, init):
+    """``body(p, carry)`` for a grid step's pairs in turn, WRITTEN OUT: the
+    step's few pairs one after the other in one block of code, no loop.  A
+    pair is two chains of products that wait on each other — the inverse's
+    ten, then the state's walk through its two chunks — and a product of
+    128 rows answers after some 130 cycles: inside a loop nothing of the
+    next pair starts before this one ends (and each turn of the loop stalls
+    about 0.5 us on the chip beside what its schedule says), written out
+    the next pair's decays, products and inverse run beside this pair's
+    walk.  The sums are the loop's, in the loop's order: results to the
+    bit."""
+    return jax.lax.fori_loop(0, pairs, body, init, unroll=True)
+
+
 def _halves(parts):
     """Two ``(64, n)`` results of a pair's chunks, one under the other."""
     return jnp.concatenate(parts, axis=0)
@@ -317,7 +334,20 @@ def _halves(parts):
 def _pair_inverse(a, row, col):
     """``unit_lower_inverse`` of a pair's ``a (128, 128)`` — zero but
     below the diagonal of its two blocks —: the same doubling, its five
-    levels on the whole matrix, float32 at full precision."""
+    levels on the whole matrix, float32 at full precision.  Ten float32
+    products at ``Precision.HIGHEST``, which the chip's compiler makes of
+    six passes of the MXU each (the operands' three bfloat16 parts cut off
+    by masks, the cross terms ``hi.hi, hi.mid, mid.hi, hi.lo, lo.hi,
+    mid.mid``, float32 accumulation): sixty of a pair's about eighty.  They
+    are a CHAIN, each waiting on the one before, and that is what they
+    cost: PR 80 wrote them as three passes each (the two chunks are the
+    blocks of a block-diagonal operand, so a second part fits where the
+    other chunk's zeros are; the same six terms) and the inverse alone read
+    1.99 us a pair either way, the forward kernels 3.68 for 3.67 — a lane
+    roll that packs an operand answers after 114 cycles, a product after
+    131, and the packing's selects and roundings cost the vector unit what
+    the passes had cost the MXU (``PERF.md`` section 6, PR 80).  What pays
+    is work of ANOTHER pair beside the chain: ``_each_pair``."""
     def together(rows):   # rows a power of two: one diagonal block of them
         shift = rows.bit_length() - 1
         return (row >> shift) == (col >> shift)
@@ -406,7 +436,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, sc_ref, h0_ref,
         o_ref[0, 0, :, at] = o.astype(o_ref.dtype).T
         return peak
 
-    peak = jax.lax.fori_loop(0, pairs, pair, jnp.zeros((), _F32))
+    peak = _each_pair(pairs, pair, jnp.zeros((), _F32))
     peak_ref[...] = jnp.maximum(peak_ref[...], peak)
     hlast_ref[0, 0] = h_scr[...]
 
@@ -504,7 +534,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, sc_ref, states_ref, tb_ref, do_ref,
         dv_ref[0, 0, :, at] = (beta * dvb).astype(dv_ref.dtype).T
         return carry
 
-    jax.lax.fori_loop(0, pairs, pair, 0)
+    _each_pair(pairs, pair, 0)
     dh0_ref[0, 0] = dh_scr[...]
 
 
@@ -921,9 +951,10 @@ from jax.ad_checkpoint import checkpoint_name  # noqa: E402
 # (``_kda_sweep``): with ``tb`` at hand a chunk's state costs ``u0 = T vb``,
 # ``w = T kb``, ``u = u0 - w H`` and ``H' = exp(G_last) H + k_end^T u`` — the
 # first three the backward made anyway — and none of the decayed products or
-# the inverse's ten float32 products.  Forward kernel and sweep advance the
-# state through ONE function, ``_kda_chunk`` (the same operands, casts and
-# order), so the rebuilt states are the forward's to the bit
+# the inverse's ten float32 products (``_pair_inverse``, sixty MXU passes).
+# Forward kernel and sweep advance the state through ONE function,
+# ``_kda_chunk`` (the same operands, casts and order), so the rebuilt states
+# are the forward's to the bit
 # (``tests/test_kda.py``) and the gradient is what it was.  The sweep's
 # states and each pair's ``u0``, ``w``, ``u`` stay in VMEM scratch for the
 # reverse walk (512 + 384 KB at four pairs a step).
@@ -1164,7 +1195,7 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref,
         return peak, jnp.maximum(low, jnp.max(-t["cum"]))
 
     zero = jnp.zeros((), _F32)
-    peak, low = jax.lax.fori_loop(0, pairs, pair, (zero, zero))
+    peak, low = _each_pair(pairs, pair, (zero, zero))
     peak_ref[...] = jnp.maximum(peak_ref[...], peak)
     low_ref[...] = jnp.maximum(low_ref[...], low)
     hlast_ref[0, 0] = h_scr[...]
@@ -1195,7 +1226,7 @@ def _kda_sweep(k_ref, v_ref, g_ref, beta_ref, entering_ref, tb_ref, hs_scr,
         u0_scr[p], w_scr[p], u_scr[p] = u0.astype(dtype), w, _halves(us)
         return h
 
-    jax.lax.fori_loop(0, pairs, pair, entering_ref[0, 0, 0])
+    _each_pair(pairs, pair, entering_ref[0, 0, 0])
 
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, entering_ref,
@@ -1303,7 +1334,7 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, entering_ref,
         dv_ref[0, 0, :, at] = (beta * dvb).astype(dv_ref.dtype).T
         return carry
 
-    jax.lax.fori_loop(0, pairs, pair, 0)
+    _each_pair(pairs, pair, 0)
     dh0_ref[0, 0] = dh_scr[...]
 
 

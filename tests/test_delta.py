@@ -21,6 +21,7 @@ from ray_tpu.models.blocks import delta
 from ray_tpu.models.blocks.delta import GDN_STATE_ABSMAX
 from ray_tpu.models.llama import (
     LlamaConfig, init_params, loss_fn, param_logical_axes)
+from ray_tpu.ops import delta as delta_ops
 from ray_tpu.ops.delta import (
     delta_chunked, delta_kernels, delta_reference, delta_xla, kernels_fit,
     unit_lower_inverse)
@@ -231,6 +232,183 @@ def test_the_inverse_holds_where_the_plain_series_loses_it():
             lost = np.asarray(series(jnp.asarray(a, jnp.float32)))
         np.testing.assert_allclose(got, want, atol=atol)
         assert not np.abs(lost - want).max() < 100.0
+
+
+# -- the kernels' pair: its inverse, its MXU passes, its pairs written out ----
+
+def _pairs_of(first, second):
+    """Chunks' ``(64, 64)`` matrices as pairs: ``first[i]``, ``second[i]``
+    on the block diagonal of ``(128, 128)``."""
+    a = np.zeros((len(first), 128, 128))
+    a[:, :64, :64], a[:, 64:, 64:] = first, second
+    return a
+
+
+def _leaning_pairs():
+    """``test_the_inverse_holds_where_the_plain_series_loses_it``'s two
+    matrices (entries near 0.5 and near 0.9), each beside the other."""
+    rng = np.random.default_rng(0)
+    low, high = (np.tril(level + 0.05 * rng.normal(size=(64, 64)), -1)
+                 for level in (0.5, 0.9))
+    return _pairs_of([low, high], [high, low])
+
+
+def _coinciding_pairs():
+    """Chunks as ``tests/test_kda.py::test_a_write_strength_over_one_on_
+    keys_that_nearly_coincide`` draws them: one direction a chunk with 0.05
+    of noise on it, ``beta`` in (1, 2), a decay of 0.01 a token — ``beta k
+    k^T`` decayed, entries up to 2."""
+    rng = np.random.default_rng(7)
+
+    def chunk():
+        k = rng.normal(size=(1, 128)) + 0.05 * rng.normal(size=(64, 128))
+        k /= np.linalg.norm(k, axis=-1, keepdims=True)
+        beta = 1.0 + 1.0 / (1.0 + np.exp(-rng.normal(size=(64, 1))))
+        cum = np.cumsum(-0.01 * np.log1p(np.exp(rng.normal(size=64))))
+        return np.tril(beta * (k @ k.T) * np.exp(cum[:, None] - cum), -1)
+
+    return _pairs_of([chunk() for _ in range(4)], [chunk() for _ in range(4)])
+
+
+def _in_a_kernel(inverse, a):
+    """``inverse(a[i], row, col)`` for every pair of ``a (n, 128, 128)``
+    inside a Pallas body (interpreted), as the forward kernels call it."""
+    from jax.experimental import pallas as pl
+
+    def body(a_ref, out_ref):
+        row, col = (delta_ops._iota((128, 128), axis) for axis in (0, 1))
+        out_ref[0] = inverse(a_ref[0], row, col)
+
+    one = pl.BlockSpec((1, 128, 128), lambda i: (i, 0, 0))
+    return pl.pallas_call(
+        body, grid=(a.shape[0],), in_specs=[one], out_specs=one,
+        out_shape=jax.ShapeDtypeStruct(a.shape, jnp.float32),
+        interpret=True)(a)
+
+
+@pytest.mark.parametrize("pairs", [_leaning_pairs, _coinciding_pairs],
+                         ids=["keys-that-lean", "keys-that-coincide"])
+def test_the_pair_inverse_is_the_inverse_of_either_chunk(pairs):
+    """``_pair_inverse`` — the doubling on a PAIR's block-diagonal ``(128,
+    128)``, as both forward kernels call it — on the matrices that break
+    the plain series and on ``beta`` up to 2 over keys that nearly
+    coincide: against numpy's inverse in float64 its largest error is held
+    to TWICE what ``unit_lower_inverse`` reads on the same chunks (the same
+    ten products at ``Precision.HIGHEST``: 2.3e-7 and 1.5e-6 here), not to
+    a tolerance — the bar for any other way of making those products (PR
+    80 measured one: three packed bfloat16 passes a product read 1.6e-7
+    and 2.4e-6, and no faster).  Nothing is left outside the two blocks or
+    above their diagonals, and the diagonal is 1."""
+    a = jnp.asarray(pairs(), jnp.float32)
+    want = np.linalg.inv(np.eye(128) + np.asarray(a, np.float64))
+    assert np.abs(want).max() < 2.0
+    got = np.asarray(_in_a_kernel(delta_ops._pair_inverse, a))
+    halves = (slice(0, 64), slice(64, 128))
+    with HIGHEST:
+        plain = [np.asarray(unit_lower_inverse(a[:, half, half]))
+                 for half in halves]
+    held_to = 2.0 * max(np.abs(t - want[:, half, half]).max()
+                        for t, half in zip(plain, halves))
+    assert held_to < 1e-5
+    assert np.abs(got - want).max() <= held_to
+    for t, half in zip(plain, halves):
+        np.testing.assert_allclose(got[:, half, half], t, atol=held_to)
+    assert not np.any(got[:, :64, 64:]) and not np.any(got[:, 64:, :64])
+    assert not np.any(np.triu(got, 1))
+    assert np.all(np.diagonal(got, axis1=1, axis2=2) == 1.0)
+
+
+def _kernel_jaxpr(kernel, keys, values, s=512):
+    """The jaxpr of one of the four kernels' calls at bfloat16 heads of
+    ``keys`` and ``values``, two heads, ``s`` tokens (one grid step of four
+    pairs)."""
+    f32 = jnp.float32
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        (1, 2, *dims), dtype)
+    if kernel.startswith("delta"):     # scalar rows; a state a CHUNK saved
+        args = (shape(keys, s), shape(keys, s), shape(values, s),
+                shape(2, s, dtype=f32))
+        saved = shape(s // 64, keys, values, dtype=f32)
+    else:                              # decays a channel; a state a STEP
+        args = (*[shape(keys, s)] * 3, shape(keys, s, dtype=f32),
+                shape(1, s, dtype=f32))
+        saved = shape(1, keys, values, dtype=f32)
+    state = shape(keys, values, dtype=f32)
+    if kernel.endswith("bwd"):         # + the inverses, do, dh_last
+        args += (saved, shape(s, 128), shape(values, s))
+    call = {"delta_fwd": delta_ops._fwd_call, "delta_bwd": delta_ops._bwd_call,
+            "kdarule_fwd": delta_ops._kda_fwd_call,
+            "kdarule_bwd": delta_ops._kda_bwd_call}[kernel]
+    jaxpr = jax.make_jaxpr(functools.partial(call, interpret=True))(
+        *args, state)
+    assert kernel in str(jaxpr)
+    return jaxpr.jaxpr
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of what it calls, a loop's body
+    once."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _mxu_passes(jaxpr):
+    """``(passes, float32 products at HIGHEST)`` of ``jaxpr``'s
+    ``dot_general``s: ``(m, k) x (k, n)`` is ``ceil(m / 128) ceil(k / 128)
+    ceil(n / 128)`` passes of the MXU, six times that for float32 operands
+    at ``Precision.HIGHEST``."""
+    passes = highest = 0
+    for eqn in _eqns(jaxpr):
+        if eqn.primitive.name != "dot_general":
+            continue
+        lhs, rhs = (v.aval for v in eqn.invars)
+        (lc, rc), (lb, rb) = eqn.params["dimension_numbers"]
+        m = np.prod([d for i, d in enumerate(lhs.shape)
+                     if i not in (*lc, *lb)], dtype=int)
+        n = np.prod([d for i, d in enumerate(rhs.shape)
+                     if i not in (*rc, *rb)], dtype=int)
+        k = np.prod([lhs.shape[i] for i in lc], dtype=int)
+        tiles = int(np.prod([-(-d // 128) for d in (m, k, n)]))
+        precision = eqn.params["precision"]
+        full = lhs.dtype == jnp.float32 and jax.lax.Precision.HIGHEST in (
+            precision if isinstance(precision, tuple) else (precision,))
+        passes, highest = passes + tiles * (6 if full else 1), highest + full
+    return passes, highest
+
+
+@pytest.mark.parametrize("kernel,keys,values,passes", [
+    ("delta_fwd", 96, 192, 79), ("kdarule_fwd", 128, 128, 81)])
+def test_sixty_of_a_pairs_mxu_passes_are_the_inverses(
+        kernel, keys, values, passes):
+    """The MXU passes a PAIR of chunks costs a forward kernel, counted
+    from its jaxpr at the published head sizes in bfloat16 (the pair
+    loop's body counts once): 79 and 81, of which the inverse's ten
+    float32 products at ``Precision.HIGHEST`` are sixty and the only
+    float32 products.  (PR 80 built them as thirty packed bfloat16 passes
+    — 49 and 51 a pair — and the kernels ran no faster: a pair is a chain
+    of products that wait on each other, not a count of passes.  Whoever
+    moves these numbers measures the kernels alone first.)"""
+    assert _mxu_passes(_kernel_jaxpr(kernel, keys, values)) == (passes, 10)
+
+
+@pytest.mark.parametrize("kernel,keys,values,loops", [
+    ("delta_fwd", 96, 192, 1), ("delta_bwd", 96, 192, 1),
+    ("kdarule_fwd", 128, 128, 1), ("kdarule_bwd", 128, 128, 2)])
+def test_a_grid_steps_pairs_are_written_out(kernel, keys, values, loops):
+    """Every loop over a grid step's pairs in the four kernels (the
+    per-channel backward has two: its forward sweep, then the walk in
+    reverse) is written out in full (``_each_pair``): the next pair's
+    decays, products and inverse have nothing to wait for in this pair's
+    walk through the state, and inside a loop they waited all the same —
+    8.49 -> 7.28 us a pair forward + backward at Kimi-Linear's layer shape
+    on the chip, every result to the bit (PR 80)."""
+    scans = [eqn for eqn in _eqns(_kernel_jaxpr(kernel, keys, values))
+             if eqn.primitive.name in ("scan", "while")]
+    assert len(scans) == loops
+    assert all(eqn.primitive.name == "scan" and eqn.params["length"] == 4
+               and eqn.params["unroll"] == 4 for eqn in scans)
 
 
 def test_delta_chunked_in_bfloat16_keeps_its_decays_and_state_in_float32():
