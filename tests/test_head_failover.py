@@ -199,6 +199,31 @@ def test_head_failover_acceptance_live_cluster():
         c.shutdown()
 
 
+def _wait_for_snapshot_of(c, name, state, timeout=30.0):
+    """Until the head's snapshot FILE (what a restarted head reads) holds
+    the named actor with a ``__ray_save__`` checkpoint of ``state``: the
+    checkpoint rides the worker's connection behind the call's result and
+    the snapshot loop writes on its own clock, so neither has landed for
+    sure when the last ``get`` returns."""
+    from ray_tpu._private import protocol, serialization
+
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        try:
+            with open(c._head_cfg["gcs_snapshot_path"], "rb") as f:
+                actors = serialization.loads_inline(f.read())["actors"]
+        except OSError:     # not written yet (the head renames it in)
+            actors = []
+        for row in actors:
+            descr = row["checkpoint"]
+            if row["name"] == name and descr is not None and (
+                    descr[0] != protocol.INLINE
+                    or serialization.loads_inline(descr[1]) == state):
+                return
+        time.sleep(0.05)
+    raise AssertionError(f"no snapshot of {name!r} at {state!r} in {timeout}s")
+
+
 def test_cold_restore_named_actor_from_checkpoint():
     """An actor whose worker DIES WITH THE HEAD (killed alongside it,
     so nothing re-claims the incarnation) is re-created by the restarted
@@ -211,7 +236,7 @@ def test_cold_restore_named_actor_from_checkpoint():
         assert ray.get([cnt.incr.remote() for _ in range(3)],
                        timeout=60) == [1, 2, 3]
         actor_pid = ray.get(cnt.pid.remote(), timeout=60)
-        time.sleep(0.8)  # checkpoint + snapshot both land
+        _wait_for_snapshot_of(c, "ck", 3)  # checkpoint + snapshot both land
         c.kill_head()
         os.kill(actor_pid, 9)
         c.restart_head()

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from benchmark.reference import joyai_flash, xing4
+from benchmark.reference import xing4
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.parallel.sharding import named_sharding
@@ -25,7 +25,9 @@ from ray_tpu.train.core import (
     train_state_shardings)
 from ray_tpu.util.tracing import scope_and_phase
 import tiny_models
-from tiny_models import against_the_reference, program, reference
+from tiny_models import (
+    against_the_reference, expert_layer, fault_ids, program, share,
+    shares_add_up, stands_apart)
 
 tiny = functools.partial(tiny_models.tiny, "joyai")
 
@@ -43,52 +45,21 @@ def test_loss_per_token_loss_and_gradients_equal_the_plain_reference():
     assert not np.any(np.asarray(ours["layers"][1]["router_bias"]))
 
 
-@pytest.mark.parametrize("change", [
-    dict(routed_scaling_factor=1.0), dict(shared_experts=0),
-    dict(norm_topk_prob=False), dict(mtp_loss_coef=0.0),
-    dict(rope_theta=1e4), dict(first_expert=8)],
-    ids=["gate_scale", "shared_expert", "renormalised", "mtp_weight",
-         "rope_theta", "the_other_host"])
-def test_a_changed_part_stands_apart_from_the_reference(change):
-    """What each part is worth to the loss: the program with the part
-    changed stands apart from the reference by more than the check's
-    tolerance, or the check could not see that part."""
-    params = program("joyai").params
-    want = float(reference("joyai").parts["total"])
-    got = float(program("joyai", **change).loss(params)[0])
-    assert abs(got - want) / want > joyai_flash.LOSS_RTOL, (got, want)
+@pytest.mark.parametrize("fault", fault_ids("joyai"))
+def test_a_changed_part_stands_apart_from_the_reference(fault):
+    """What each part is worth to the loss (the row's ``faults``)."""
+    stands_apart("joyai", fault)
 
 
 # -- (c) the two hosts' shares add up -----------------------------------------
 
-def _expert_layer(tokens=96, d=64, m=32, experts=16, seed=3):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 10)
-    normal = jax.random.normal
-    return dict(
-        x=normal(keys[0], (tokens, d)),
-        mlp_norm=1.0 + 0.3 * normal(keys[1], (d,)),
-        router=normal(keys[2], (d, experts)) * d ** -0.5,
-        router_bias=0.05 * normal(keys[3], (experts,)),
-        w_gate=normal(keys[4], (experts, d, m)) * d ** -0.5,
-        w_up=normal(keys[5], (experts, d, m)) * d ** -0.5,
-        w_down=normal(keys[6], (experts, m, d)) * m ** -0.5,
-        shared_gate=normal(keys[7], (d, m)) * d ** -0.5,
-        shared_up=normal(keys[8], (d, m)) * d ** -0.5,
-        shared_down=normal(keys[9], (m, d)) * m ** -0.5)
+_expert_layer = functools.partial(expert_layer, experts=16)
 
 
-@functools.partial(jax.jit, static_argnums=2)
 def _block(p, first, held):
     """The routed part alone of the host that holds ``held`` experts from
-    ``first`` on, its step counters beside it; one program, ``first``
-    traced."""
-    return moe_block(
-        p["x"], p["mlp_norm"], p["router"], *(
-            jax.lax.dynamic_slice_in_dim(p[w], first, held)
-            for w in ("w_gate", "w_up", "w_down")),
-        num_selected=4, norm_topk_prob=True, scoring="sigmoid",
-        select_bias=p["router_bias"], gate_scale=2.5, first_expert=first,
-        residual=False)
+    ``first`` on, its step counters beside it."""
+    return share(p, first, held, 4, 2.5)
 
 
 def test_the_two_hosts_shares_add_up_to_the_uncut_layer():
@@ -96,19 +67,12 @@ def test_the_two_hosts_shares_add_up_to_the_uncut_layer():
     and the shared expert ONCE, are the whole layer as the reference has
     it."""
     p = _expert_layer()
-    parts = [_block(p, first, 8) for first in (0, 8)]
-    routed = sum(y for y, _ in parts)
     n = xing4.rms_norm(p["x"], p["mlp_norm"], 1e-6)
     shared = xing4.swiglu(n, p["shared_gate"], p["shared_up"],
                           p["shared_down"])
     whole, _ = xing4.expert_ffn(p["x"][None], p, k=4, factor=2.5, first=0,
                                 eps=1e-6)
-    np.testing.assert_allclose(routed + shared, whole[0], atol=2e-5)
-    stats = [s for _, s in parts]
-    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
-    assert all(float(s["dropped"]) == 0.0 for s in stats)
-    np.testing.assert_array_equal(stats[0]["counts"], stats[1]["counts"])
-    assert int(jnp.sum(stats[0]["counts"])) == 96 * 4
+    shares_add_up("joyai", p, _block, whole[0], k=4, shared=shared)
 
 
 # -- (e) the straggler's statistic --------------------------------------------

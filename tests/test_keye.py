@@ -27,11 +27,11 @@ from ray_tpu.models.llama import loss_fn
 from ray_tpu.ops import sparse_attention as sa
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
-from ray_tpu.train.core import (
-    default_optimizer, init_train_state, make_train_step)
 
 import tiny_models
-from tiny_models import against_the_reference, program, side_of
+from tiny_models import (
+    against_the_reference, fault_ids, program, shares_add_up, side_of,
+    stands_apart, train_step_reports)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "keye-vl-2.0-30b-a3b-1of8"
@@ -90,42 +90,12 @@ def test_each_loss_reaches_its_own_parameters_and_no_other():
         np.testing.assert_allclose(without[name], with_idx[name], atol=1e-7)
 
 
-@pytest.mark.parametrize("change", [
-    "relu left out", "head weights left out", "topk halved",
-    "the key's norm left out", "the loss aimed at an un-detached target"])
-def test_a_changed_part_stands_apart_from_the_reference(change, monkeypatch):
+@pytest.mark.parametrize("fault", fault_ids("keye"))
+def test_a_changed_part_stands_apart_from_the_reference(fault):
     """What the chip's check is asked to see (the configuration's
-    ``check.why``), in float32 where nothing hides it."""
-    want = tiny_models.reference("keye").parts
-    want_grads = tiny_models.reference("keye").grads
-    cfg = tiny()
-    if change == "relu left out":
-        monkeypatch.setattr(jax.nn, "relu", lambda x: x)
-    elif change == "head weights left out":
-        indexer = attention_block._indexer
-        monkeypatch.setattr(
-            attention_block, "_indexer",
-            lambda *a: (lambda q, k, w: (q, k, jnp.ones_like(w) / 32))(
-                *indexer(*a)))
-    elif change == "topk halved":
-        cfg = tiny(sa_config=tiny_models.Frozen(
-            tiny_models.KEYE_INDEXER, topk=8))
-    elif change == "the key's norm left out":
-        monkeypatch.setattr(attention_block, "_layer_norm",
-                            lambda x, w, b, eps: x)
-    else:
-        monkeypatch.setattr(sa.jax.lax, "stop_gradient", lambda x: x)
-    side = side_of("keye", cfg, program("keye").params)
-    if change.startswith("the loss aimed"):
-        # values stand; the model's gradients take the indexers' loss in
-        (_, _), grads = side.value_and_grad(side.params)
-        worst = tiny_models.apart(grads["layers"]["wq"],
-                                  want_grads["layers"]["wq"])
-        assert worst > 1e-3
-        return
-    total, parts = side.loss(side.params)
-    assert abs(float(parts["idx_loss"]) - float(want["idx_loss"])) > 2e-3 \
-        or abs(float(parts["loss"]) - float(want["loss"])) > 1e-4
+    ``check.why``; the row's ``faults``), in float32 where nothing hides
+    it."""
+    stands_apart("keye", fault)
 
 
 # -- (b) the selection --------------------------------------------------------
@@ -459,19 +429,10 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     parallel): counted ONCE, they are the reference's own mixer, which test
     (a) holds; nothing of them is divided."""
     p = _expert_layer()
-    parts = [_share(p, first, 8) for first in range(0, 64, 8)]
     h = mellum.rms_norm(p["x"], p["mlp_norm"], 1e-6)
     whole, chosen, balance = keye_sparse.expert_ffn(
         h[None], p, k=8, renormalise=True, first=0)
-    np.testing.assert_allclose(sum(part for part, _ in parts), whole[0],
-                               atol=2e-5)
-    stats = [s for _, s in parts]
-    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
-    assert all(float(s["dropped"]) == 0.0 for s in stats)
-    for s in stats:     # every share routes over all 64
-        np.testing.assert_array_equal(
-            s["counts"], np.bincount(np.asarray(chosen).ravel(),
-                                     minlength=64))
+    parts = shares_add_up("keye", p, _share, whole[0], chosen, k=8)
     alone, _, _ = keye_sparse.expert_ffn(
         h[None], {**p, **{w: p[w][16:24] for w in ("w_gate", "w_up",
                                                    "w_down")}},
@@ -507,21 +468,11 @@ def test_on_a_mesh_the_model_is_one_devices(impl):
 
 
 def test_the_train_step_runs_the_kernels_under_their_scopes_and_learns():
-    cfg = tiny(attn_impl="flash", remat=True, num_layers=2)
-    opt = default_optimizer()
-    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    step = make_train_step(cfg, opt, donate=False)
-    lowered = step.lower(state, {"tokens": TOKENS})
-    text = lowered.as_text(debug_info=True)
-    for name in ("dsa_index/", "sparse_scores", "sparse_scores_bwd",
-                 "dsa_select/", "sparse_select", "sparse_mask", "attention/",
-                 "flash_fwd_dsa", "flash_dkv_dsa", "dsa_loss/", "attn_out/",
-                 "moe_experts/"):
-        assert name in text, name
+    stepped = train_step_reports("keye")
     # under the checkpoint the backward pass selects nothing again and
     # runs no second forward kernel: the selection's two numbers a row
     # and the kernel's output are kept
-    locs = _locs(text)
+    locs = _locs(stepped.text)
     again = [n for n in locs if "rematted_computation" in n]
     assert any("sparse_scores" in n for n in again)     # remade, as q and k
     assert any("sparse_mask" in n for n in again)   # from the kept tau, tie
@@ -538,13 +489,7 @@ def test_the_train_step_runs_the_kernels_under_their_scopes_and_learns():
     assert any(n.startswith("dsa_loss/exp") for n in locs)
     assert {n.rsplit("/", 1)[-1] for n in late if "dsa_loss/" in n} == {
         "mul", "div", "broadcast_in_dim", "reshape"}
-    compiled = lowered.compile()
-    losses = []
-    for _ in range(3):
-        state, metrics = compiled(state, {"tokens": TOKENS})
-        losses.append(float(metrics["loss"]))
-    assert losses[2] < losses[0]
-    assert set(keye_sparse.STEP_METRICS) <= set(metrics)
+    metrics = stepped.metrics
     assert float(metrics["moe_dropped"]) == 0.0
     assert float(metrics["dsa_selected_off"]) == 0.0
     assert 0.25 <= float(metrics["dsa_tie_walk_share"]) <= 1.0

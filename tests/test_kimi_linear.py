@@ -13,9 +13,7 @@ import os
 import re
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 from benchmark.loops import train
@@ -25,17 +23,16 @@ from ray_tpu.models.blocks.kda import KDA_CHUNK_DECAY_MIN, KDA_STATE_ABSMAX
 from ray_tpu.models.llama import LlamaConfig, init_params, loss_fn
 from ray_tpu.ops.delta import kda_kernels_fit
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
-from ray_tpu.train.core import STEP_SCOPES, init_train_state, make_train_step
+from ray_tpu.train.core import STEP_SCOPES
 from ray_tpu.util.tracing import scope_and_phase
 import tiny_models
 from tiny_models import (
-    KIMI_LINEAR, against_the_reference, expert_layer, program, reference,
-    share)
+    KDA_SCOPES, against_the_reference, expert_layer, fault_ids, program,
+    share, shares_add_up, stands_apart, train_step_reports)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "kimi-linear-48b-a3b-1of16"
 HIGHEST = jax.default_matmul_precision("highest")
-KDA_SCOPES = ("kda_in", "kda_conv", "kda_scan", "kda_out")
 TOKENS = tiny_models.ROWS["kimi"].tokens
 tiny = functools.partial(tiny_models.tiny, "kimi")
 
@@ -66,58 +63,15 @@ def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
     assert not np.any(np.asarray(ours["layers"][1]["router_bias"]))
 
 
-@pytest.mark.parametrize("change", [
-    dict(position_embedding="rope"), dict(routed_scaling_factor=1.0),
-    dict(shared_experts=0), dict(norm_topk_prob=False),
-    dict(first_expert=8), dict(leading_dense=0, dense_mlp_dim=0),
-    "silu gate", "beta twice", "decay a head", "latent in a kda layer"],
-    ids=lambda c: c if isinstance(c, str) else "-".join(c))
-def test_a_changed_part_stands_apart_from_the_reference(change, monkeypatch):
-    """What each part is worth to the loss: the program with the part
-    changed stands apart from the reference by more than the check's
-    tolerance, or the check could not see that part.  A rotation of the 64
-    shared columns, each part of the gates, the other chip's experts; and,
-    inside the KDA mixer, a SiLU where the output gate's sigmoid is, beta
-    in (0, 2), ONE decay a head (the mean over its channels: the rule
-    Olmo-Hybrid has), a latent layer where the lists name a KDA one.  THE
-    ROTATION moves the MEAN of 192 positions by 1.3e-4 only, under its
-    tolerance (8 of 24 columns in one layer of five; signed differences
-    cancel): it is the PER-TOKEN comparison that sees it, 0.127 nats RMS
-    where the sound program reads 8e-7."""
-    params = program("kimi").params
-    want = float(reference("kimi").parts["total"])
-    if change == dict(position_embedding="rope"):
-        apart = program("kimi", **change).token_nll(params) - reference(
-            "kimi").parts["token_nll"]
-        assert float(jnp.sqrt(jnp.mean(jnp.square(apart)))) > 0.05
-        return
-    if isinstance(change, dict):
-        if "leading_dense" in change:   # other shapes: its own parameters
-            cfg = tiny(**change)
-            got = float(loss_fn(tiny_models.seeded(cfg), {"tokens": TOKENS},
-                                cfg)[0])
-        else:
-            got = float(program("kimi", **change).loss(params)[0])
-    else:
-        cfg = tiny()
-        if change == "silu gate":
-            monkeypatch.setattr(kda, "_gate", jax.nn.silu)
-        elif change == "beta twice":
-            monkeypatch.setattr(kda, "_beta",
-                                lambda b: 2.0 * jax.nn.sigmoid(b))
-        elif change == "decay a head":
-            rule = kda.kda_chunked
-            monkeypatch.setattr(kda, "kda_chunked", lambda q, k, v, g, b: rule(
-                q, k, v, jnp.broadcast_to(
-                    jnp.mean(g, -1, keepdims=True), g.shape), b))
-        else:
-            lists = dict(KIMI_LINEAR, kda_layers=[1, 2, 5],
-                         full_attn_layers=[3, 4])
-            cfg = tiny(linear_attn_config=lists)
-            params = tiny_models.seeded(cfg)
-        with HIGHEST:
-            got = float(loss_fn(params, {"tokens": TOKENS}, cfg)[0])
-    assert abs(got - want) / want > kimi_linear.LOSS_RTOL, (got, want)
+@pytest.mark.parametrize("fault", fault_ids("kimi"))
+def test_a_changed_part_stands_apart_from_the_reference(fault):
+    """What each part is worth to the loss (the row's ``faults``): a
+    rotation of the 64 shared columns, each part of the gates, the other
+    chip's experts; and, inside the KDA mixer, a SiLU where the output
+    gate's sigmoid is, beta in (0, 2), ONE decay a head (the mean over its
+    channels: the rule Olmo-Hybrid has), a latent layer where the lists
+    name a KDA one."""
+    stands_apart("kimi", fault)
 
 
 # -- (c) the latent mixer without a q rank; the older trees as they were -------
@@ -189,20 +143,14 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
     the reference has it; every share routes over all 32 and counts the
     same assignments; the held shares sum to 1."""
     p = expert_layer()
-    parts = [share(p, first, 2, 4, 2.446) for first in range(0, 32, 2)]
-    routed = sum(y for y, _ in parts)
     n = xing4.rms_norm(p["x"], p["mlp_norm"], 1e-6)
     shared = xing4.swiglu(n, p["shared_gate"], p["shared_up"],
                           p["shared_down"])
     whole, chosen = xing4.expert_ffn(p["x"][None], p, k=4, factor=2.446,
                                      first=0, eps=1e-6)
-    np.testing.assert_allclose(routed + shared, whole[0], atol=2e-5)
-    stats = [s for _, s in parts]
-    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
-    assert all(float(s["dropped"]) == 0.0 for s in stats)
-    for s in stats:
-        np.testing.assert_array_equal(s["counts"], np.bincount(
-            np.asarray(chosen).ravel(), minlength=32))
+    parts = shares_add_up(
+        "kimi", p, lambda p, first, held: share(p, first, held, 4, 2.446),
+        whole[0], chosen, k=4, shared=shared)
     # one share alone is the reference's with the same experts held
     alone, _ = xing4.expert_ffn(
         p["x"][None], {**p, **{w: p[w][6:8] for w in (
@@ -230,27 +178,12 @@ def test_train_step_reports_the_rule_and_names_its_scopes():
         s.startswith("kdarule_") for s in STEP_SCOPES)
     assert kda.BLOCK.saved == ("kda_proj", "kda_rule_out",
                                "kda_rule_inverse", "kda_rule_entering")
-    cfg = tiny(attn_impl="flash", remat=True)
-    opt = optax.adam(1e-2)
-    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    batch = {"tokens": TOKENS}
-    step = make_train_step(cfg, opt, donate=False).lower(
-        state, batch).compile()
-    losses = []
-    for _ in range(3):
-        state, metrics = step(state, batch)
-        losses.append(float(metrics["loss"]))
-    assert losses[2] < losses[0]
-    assert np.isfinite(float(metrics[KDA_STATE_ABSMAX]))
-    assert float(metrics[KDA_CHUNK_DECAY_MIN]) < 0.0
-    assert float(metrics["moe_dropped"]) == 0.0
-    text = step.as_text()
-    names = re.findall(r'op_name="([^"]*)"', text)
-    seen = {scope_and_phase(n, STEP_SCOPES) for n in names}
-    assert {(s, p) for s in KDA_SCOPES
-            for p in ("forward", "remat", "backward")} <= seen
+    stepped = train_step_reports("kimi")
+    assert np.isfinite(float(stepped.metrics[KDA_STATE_ABSMAX]))
+    assert float(stepped.metrics[KDA_CHUNK_DECAY_MIN]) < 0.0
+    assert float(stepped.metrics["moe_dropped"]) == 0.0
     dots = {scope_and_phase(n, STEP_SCOPES) for n in re.findall(
-        r'dot\([^\n]*op_name="([^"]*)"', text)}
+        r'dot\([^\n]*op_name="([^"]*)"', stepped.compiled.as_text())}
     assert ("kda_in", "forward") in dots and ("kda_in", "backward") in dots
     assert ("kda_in", "remat") not in dots
 

@@ -4,7 +4,6 @@ selection bias, a shared expert and a held share, the predicted-ahead
 module — the program (``ray_tpu/models/llama.py`` and its ops) against the
 plain reference (``benchmark/reference/xing4.py``) on seeded weights."""
 
-import dataclasses
 import functools
 import json
 import os
@@ -21,13 +20,13 @@ from ray_tpu.models.llama import (
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.layers import sinkhorn, yarn_inv_freq, yarn_mscale
-from ray_tpu.ops.moe import moe_block, update_selection_bias
-from ray_tpu.train.core import (
-    STEP_SCOPES, default_optimizer, init_train_state, make_train_step)
+from ray_tpu.ops.moe import update_selection_bias
+from ray_tpu.train.core import STEP_SCOPES
 import tiny_models
 from tiny_models import (
-    ROWS, XING4_SCALING as SCALING, against_the_reference, program,
-    reference, side_of)
+    ROWS, XING4_SCALING as SCALING, against_the_reference, expert_layer,
+    fault_ids, program, share, shares_add_up, stands_apart,
+    train_step_reports)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "xing4.0-29b-a4b-1of8"
@@ -79,37 +78,17 @@ def test_flash_kernels_and_the_checkpoint_give_the_same_loss():
     np.testing.assert_allclose(kernels, plain, rtol=1e-5)
 
 
-# what each part of the model is worth to the loss: the program with the
-# part changed must stand apart from the reference by far more than the
-# check's tolerance (1e-4), or the check could not see that part
-@pytest.mark.parametrize("change", [
-    dict(rope_scaling=None),                      # 1b: YaRN and its scale
-    dict(routed_scaling_factor=1.0),              # 1c: the gates' scale
-    dict(router_scoring="softmax"),
-    dict(norm_topk_prob=False),
-    dict(first_expert=0),                         # another chip's experts
-    dict(hc_sinkhorn_iters=0),                    # 1d: exp alone
-    dict(hc_clamp_max=0.0),
-    dict(mtp_loss_coef=0.0),                      # 1e
-], ids=lambda c: "-".join(c))
-def test_a_changed_part_stands_apart_from_the_reference(change):
-    cfg, params = program("xing4").cfg, program("xing4").params
-    want = float(reference("xing4").parts["total"])
-    got = float(side_of("xing4", dataclasses.replace(cfg, **change),
-                        params).loss(params)[0])
-    assert abs(got - want) / want > 3e-4
+# what each part of the model is worth to the loss (the row's ``faults``):
+# the program with the part changed must stand apart from the reference by
+# far more than the check's tolerance (1e-4), or the check could not see it
+@pytest.mark.parametrize("fault", fault_ids("xing4"))
+def test_a_changed_part_stands_apart_from_the_reference(fault):
+    stands_apart("xing4", fault)
 
 
-@pytest.mark.parametrize("leaf", [
-    "router_bias", "shared_down", "hc_attn_bias", "hc_ffn_scale", "wkv_b"])
+@pytest.mark.parametrize("leaf", fault_ids("xing4", "leaf"))
 def test_a_zeroed_leaf_stands_apart_from_the_reference(leaf):
-    params = program("xing4").params
-    want = float(reference("xing4").parts["total"])
-    dense, moe = params["layers"]
-    changed = dict(params, layers=(dense, dict(moe, **{
-        leaf: jnp.zeros_like(moe[leaf])})))
-    got = float(program("xing4").loss(changed)[0])
-    assert abs(got - want) / want > 2e-4
+    stands_apart("xing4", leaf)
 
 
 # -- 1b: the flash kernels at two head sizes -----------------------------------
@@ -261,54 +240,25 @@ def test_yarn_frequencies_and_scale():
 
 # -- 1c: the expert layer ------------------------------------------------------
 
-def _expert_layer(seed=3, tokens=96, d=32, m=16, experts=16):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 9)
-    normal = jax.random.normal
-    return dict(
-        x=normal(keys[0], (tokens, d)),
-        mlp_norm=1.0 + 0.3 * normal(keys[1], (d,)),
-        router=normal(keys[2], (d, experts)) * d ** -0.5,
-        router_bias=0.05 * normal(keys[3], (experts,)),
-        w_gate=normal(keys[4], (experts, d, m)) * d ** -0.5,
-        w_up=normal(keys[5], (experts, d, m)) * d ** -0.5,
-        w_down=normal(keys[6], (experts, m, d)) * m ** -0.5,
-        shared_gate=normal(keys[7], (d, m)) * d ** -0.5,
-        shared_up=normal(keys[8], (d, m)) * d ** -0.5,
-        shared_down=normal(keys[0], (m, d)) * m ** -0.5)
+_expert_layer = functools.partial(expert_layer, d=32, m=16, experts=16)
 
 
-@functools.partial(jax.jit, static_argnums=2)
 def _share(p, first, held):
     """What the chip that holds ``held`` experts from ``first`` on adds:
-    the routed part alone, its step counters beside it; one program,
-    ``first`` traced."""
-    return moe_block(
-        p["x"], p["mlp_norm"], p["router"], *(
-            jax.lax.dynamic_slice_in_dim(p[w], first, held)
-            for w in ("w_gate", "w_up", "w_down")),
-        num_selected=4, norm_topk_prob=True, scoring="sigmoid",
-        select_bias=p["router_bias"], gate_scale=2.0, first_expert=first,
-        residual=False)
+    the routed part alone, its step counters beside it."""
+    return share(p, first, held, 4, 2.0)
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
     """8 chips with 2 of 16 experts each: their routed parts, and the
     shared expert ONCE, are the whole layer as the reference has it."""
     p = _expert_layer()
-    parts = [_share(p, first, 2) for first in range(0, 16, 2)]
-    routed = sum(y for y, _ in parts)
     n = xing4.rms_norm(p["x"], p["mlp_norm"], 1e-6)
     shared = xing4.swiglu(n, p["shared_gate"], p["shared_up"],
                           p["shared_down"])
     whole, _ = xing4.expert_ffn(p["x"][None], p, k=4, factor=2.0, first=0,
                                 eps=1e-6)
-    np.testing.assert_allclose(routed + shared, whole[0], atol=2e-5)
-    stats = [s for _, s in parts]
-    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
-    assert all(float(s["dropped"]) == 0.0 for s in stats)
-    for s in stats:     # every chip counts ALL the experts, and alike
-        np.testing.assert_array_equal(s["counts"], stats[0]["counts"])
-    assert int(jnp.sum(stats[0]["counts"])) == 96 * 4
+    parts = shares_add_up("xing4", p, _share, whole[0], k=4, shared=shared)
     # one share alone is the reference's with the same experts held
     alone, _ = xing4.expert_ffn(
         p["x"][None], {**p, **{w: p[w][6:8] for w in (
@@ -347,19 +297,13 @@ def test_selection_bias_update_sign_and_size():
 
 
 def test_the_train_step_moves_the_bias_by_its_rule_and_nothing_else_does():
-    cfg = tiny()
-    opt = default_optimizer()
-    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    before = jax.tree.map(np.asarray, state.params)
-    state, metrics = make_train_step(cfg, opt, donate=False)(
-        state, {"tokens": TOKENS})
+    _, _, before, _, _, state, metrics = train_step_reports("xing4")
     for old, new in ((before["layers"][1], state.params["layers"][1]),
                      (before["mtp"]["layers"], state.params["mtp"]["layers"])):
         step = np.asarray(new["router_bias"]) - old["router_bias"]
         assert np.all((step == 0) | np.isclose(np.abs(step), 0.001,
                                                atol=1e-6))
         assert np.any(step > 0) and np.any(step < 0)
-    assert {"mtp_loss", "moe_held_share", "moe_dropped"} <= set(metrics)
     assert 0.0 < float(metrics["moe_held_share"]) < 1.0
     # a model without such a leaf hands the step no counts
     from ray_tpu.models.llama import loss_and_counts

@@ -7,7 +7,6 @@ against the benchmark's plain reference (``benchmark/reference/
 lfm2_moe.py``: nothing shared with the code under test) on seeded
 weights."""
 
-import dataclasses
 import functools
 import json
 import os
@@ -28,12 +27,12 @@ from ray_tpu.train.core import (
     STEP_SCOPES, default_optimizer, init_train_state, make_train_step)
 import tiny_models
 from tiny_models import (
-    LFM2_PATTERN as PATTERN, ROWS, against_the_reference, apart as _apart,
-    program, reference, side_of)
+    LFM2_PATTERN as PATTERN, ROWS, SCONV_SCOPES, against_the_reference,
+    apart as _apart, fault_ids, program, shares_add_up, stands_apart,
+    train_step_reports)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "lfm2-8b-a1b-1of2"
-SCONV_SCOPES = ("sconv_in", "sconv_gate", "sconv_out")
 TOKENS = ROWS["lfm2"].tokens
 tiny = functools.partial(tiny_models.tiny, "lfm2")
 # half the depth where the pattern itself is not what is tested: four runs
@@ -203,80 +202,14 @@ def test_the_checkpoint_and_the_flash_kernels_give_the_same_loss():
     assert max(jax.tree.leaves(_apart(g_remat, g_plain))) < 1e-5
 
 
-def _sound():
-    """The tiny model, its seeded parameters and the reference's loss on
-    them, compiled once for every case below."""
-    sound = program("lfm2")
-    return sound.cfg, sound.params, float(reference("lfm2").parts["total"])
-
-
-def _changed(params, run, name, fn):
-    layers = list(params["layers"])
-    layers[run] = dict(layers[run], **{name: fn(layers[run][name])})
-    return dict(params, layers=tuple(layers))
-
-
-@pytest.mark.parametrize("change", [
-    "no-head-norm", "whole-projection-norm", "no-topk-eps", "softmax-scores",
-    "no-bias", "silu-in-the-conv", "gates-swapped", "taps-reversed",
-    "untied-head"])
-def test_a_changed_part_stands_apart_from_the_reference(change):
+@pytest.mark.parametrize("fault", fault_ids("lfm2"))
+def test_a_changed_part_stands_apart_from_the_reference(fault):
     """Each structural point of the configuration, got wrong in the
-    program, stands apart from the reference by more than the chip
-    check's tolerance of the mean (3e-4), but for the ``1e-6`` of the
-    renormalisation, which moves a gate by a millionth and which no check
-    can see: the test says so rather than claim it."""
-    cfg, params, want = _sound()
-    if change == "no-head-norm":
-        wrong, p = dataclasses.replace(cfg, qk_head_norm=False), params
-    elif change == "whole-projection-norm":
-        wrong = dataclasses.replace(cfg, qk_head_norm=False, qk_norm=True)
-        p = params
-        for run in (1, 3):
-            p = _changed(p, run, "q_norm", lambda a: jnp.tile(a, (1, 4)))
-            p = _changed(p, run, "k_norm", lambda a: jnp.tile(a, (1, 2)))
-    elif change == "no-topk-eps":
-        wrong, p = dataclasses.replace(cfg, topk_norm_eps=0.0), params
-    elif change == "softmax-scores":
-        wrong, p = dataclasses.replace(cfg, router_scoring="softmax"), params
-    elif change == "no-bias":
-        wrong = dataclasses.replace(cfg, topk_method="greedy")
-        p = dict(params, layers=tuple(
-            {k: v for k, v in lp.items() if k != "router_bias"}
-            for lp in params["layers"]))
-    elif change == "untied-head":
-        wrong = dataclasses.replace(cfg, tie_embeddings=False)
-        p = dict(params, lm_head=init_params(
-            jax.random.PRNGKey(5), wrong)["lm_head"])
-    else:
-        wrong, p = cfg, params
-        for run in (0, 2, 4):
-            if change == "taps-reversed":
-                p = _changed(p, run, "sconv_w", lambda a: a[:, ::-1])
-            elif change == "gates-swapped":   # [B | C | x] read as [x | C | B]
-                # is the same function; [C | B | x] is not
-                p = _changed(p, run, "sconv_in", lambda a: jnp.concatenate(
-                    [a[..., 64:128], a[..., :64], a[..., 128:]], -1))
-    if change == "silu-in-the-conv":
-        from ray_tpu.models.blocks import conv
-        plain = conv.gated_short_conv
-
-        def with_silu(bcx, w):
-            gate_in, gate_out, x = jnp.split(bcx, 3, -1)
-            return gate_out * causal_conv1d(gate_in * x, w)
-
-        conv.gated_short_conv = with_silu
-        try:
-            got, _ = side_of("lfm2", wrong, p).loss(p)
-        finally:
-            conv.gated_short_conv = plain
-    else:
-        got, _ = side_of("lfm2", wrong, p).loss(p)
-    apart = abs(float(got) - want) / want
-    if change == "no-topk-eps":
-        assert apart < 2e-5       # below anything a check resolves
-    else:
-        assert apart > lfm2_moe.LOSS_RTOL, apart
+    program (the row's ``faults``), stands apart from the reference by more
+    than the chip check's tolerance of the mean (3e-4), but for the
+    ``1e-6`` of the renormalisation, which moves a gate by a millionth and
+    which no check can see: the row says so rather than claim it."""
+    stands_apart("lfm2", fault)
 
 
 # -- the share -----------------------------------------------------------------
@@ -312,19 +245,9 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
     their parts are the whole layer as the reference has it — no shared
     expert to count once."""
     p = _expert_layer()
-    parts = [_share(p, first, 4) for first in (0, 4)]
     h = lfm2_moe.rms_norm(p["x"], p["mlp_norm"], 1e-5)
     whole, chosen = lfm2_moe.expert_ffn(h[None], p, k=4, factor=1.0, first=0)
-    np.testing.assert_allclose(parts[0][0] + parts[1][0], whole[0],
-                               atol=2e-5)
-    stats = [s for _, s in parts]
-    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
-    assert all(float(s["dropped"]) == 0.0 for s in stats)
-    np.testing.assert_array_equal(stats[0]["counts"], stats[1]["counts"])
-    assert int(jnp.sum(stats[0]["counts"])) == 96 * 4
-    np.testing.assert_array_equal(
-        stats[0]["counts"], np.bincount(np.asarray(chosen).ravel(),
-                                        minlength=8))
+    parts = shares_add_up("lfm2", p, _share, whole[0], chosen, k=4)
     # one share alone is the reference's with the same experts held
     alone, _ = lfm2_moe.expert_ffn(
         h[None], {**p, **{w: p[w][4:] for w in ("w_gate", "w_up", "w_down")}},
@@ -362,23 +285,11 @@ def test_update_router_bias_moves_the_bias_of_every_expert_run():
 
 def test_the_train_step_reports_the_scopes_and_the_counters():
     assert set(SCONV_SCOPES) <= set(STEP_SCOPES)
-    cfg = tiny(remat=True)
-    opt = default_optimizer()
-    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    before = jax.tree.map(np.asarray, state.params)
-    step = make_train_step(cfg, opt, donate=False)
-    lowered = step.lower(state, {"tokens": TOKENS})   # traced once
-    text = lowered.as_text(debug_info=True)
-    for scope in SCONV_SCOPES + ("attn_qkv", "moe_experts", "ffn"):
-        assert f"{scope}/" in text, scope
-    state, metrics = lowered.compile()(state, {"tokens": TOKENS})
-    assert {"moe_held_share", "moe_dropped", "moe_rows_visited_share",
-            "moe_load_max_over_mean"} <= set(metrics)
-    assert float(metrics["moe_dropped"]) == 0.0
-    assert np.isfinite(float(metrics["loss"]))
+    stepped = train_step_reports("lfm2")
+    assert float(stepped.metrics["moe_dropped"]) == 0.0
     for run in (1, 2, 3, 4):
-        moved = np.asarray(state.params["layers"][run]["router_bias"]) \
-            - before["layers"][run]["router_bias"]
+        moved = np.asarray(stepped.state.params["layers"][run][
+            "router_bias"]) - stepped.before["layers"][run]["router_bias"]
         assert np.all((moved == 0) | np.isclose(np.abs(moved), 0.001,
                                                 atol=1e-6))
 

@@ -21,19 +21,17 @@ import pytest
 from benchmark.loops import train
 from benchmark.reference import mellum
 from ray_tpu.models.blocks import attention as attention_block
-from ray_tpu.models.llama import (
-    ROPE_BY_KIND, forward, loss_and_counts, loss_fn)
+from ray_tpu.models.llama import ROPE_BY_KIND, forward, loss_and_counts
 from ray_tpu.ops.attention import (
     causal_tile_counts, choose_tiles, flash_attention, mha_reference)
 from ray_tpu.ops.layers import (
     repeat_kv_heads, rope, scaled_rope, yarn_inv_freq)
 from ray_tpu.ops.moe import moe_block
-from ray_tpu.train.core import (
-    default_optimizer, init_train_state, make_train_step)
 import tiny_models
 from tiny_models import (
     F, MELLUM_GROUPS, MELLUM_WINDOW, MELLUM_YARN, ROWS, S,
-    against_the_reference, program, reference, seeded, side_of)
+    against_the_reference, fault_ids, program, seeded, shares_add_up,
+    stands_apart, train_step_reports)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "mellum2-12b-a2.5b-1of4"
@@ -46,10 +44,6 @@ tiny = functools.partial(tiny_models.tiny, "mellum")
 PUBLISHED = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
              "original_max_position_embeddings": 8192, "beta_fast": 32,
              "beta_slow": 1, "attention_factor": 1.2772588722239782}
-
-
-def _token_nll(cfg, params):
-    return side_of("mellum", cfg, params).token_nll(params)
 
 
 # -- the model against the reference ------------------------------------------
@@ -83,85 +77,18 @@ def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
         assert all(np.any(np.asarray(g)) for g in run.values())
 
 
-def _sound():
-    """One period of the tiny model, its seeded parameters, the reference's
-    per-token losses on them (which the sound program stands 5e-5 off) and
-    its total: each compiled once for every case below."""
-    sound = program("mellum", num_layers=4)
-    want = reference("mellum", dict(num_hidden_layers=4),
-                     num_layers=4).parts
-    np.testing.assert_allclose(sound.token_nll(sound.params),
-                               want["token_nll"], atol=5e-5)
-    return sound.cfg, sound.params, want["token_nll"], want["total"]
-
-
-def _groups(**kinds):
-    return {k: dict(GROUPS[k], **change) if isinstance(change, dict)
-            else GROUPS[change] for k, change in kinds.items()}
-
-
-@pytest.mark.parametrize("change", [
-    "yarn-dropped", "attention-factor-dropped", "tables-swapped",
-    "yarn-in-the-windowed-layers-too", "ramp-not-truncated",
-    "no-rope-in-the-full-layers", "no-window", "window-one-short",
-    "window-four-times", "gates-not-renormalised", "another-chips-experts",
-    "no-balance-loss"])
-def test_a_changed_part_stands_apart_from_the_reference(change):
-    """Each structural point of the configuration, got wrong in the
-    program, moves a token's loss by more than a thousandth of a nat (the
-    sound program stands 5e-5 off at most) — or, of the load-balancing
-    term, the total by more than its tolerance: the full layers' tables
-    plain, YaRN's factor left off them, the two kinds' tables swapped, YaRN
-    in every layer, the ramp between untruncated bounds, the window dropped,
-    a key short or four times as wide, the gates not renormalised, the
-    wrong quarter of the experts."""
-    cfg, params, want, sound = _sound()
-    wrong = {
-        "yarn-dropped": dict(rope_parameters=_groups(**{F: S, S: S})),
-        "attention-factor-dropped": dict(rope_parameters=_groups(
-            **{F: {"attention_factor": 1.0}, S: S})),
-        "tables-swapped": dict(rope_parameters=_groups(**{F: S, S: F})),
-        "yarn-in-the-windowed-layers-too": dict(
-            rope_parameters=_groups(**{F: F, S: F})),
-        "no-rope-in-the-full-layers": dict(position_embedding="rope_windowed",
-                                           rope_theta=100.0),
-        "no-window": dict(sliding_window=SEQ),
-        "window-one-short": dict(sliding_window=WINDOW - 1),
-        "window-four-times": dict(sliding_window=4 * WINDOW),
-        "gates-not-renormalised": dict(norm_topk_prob=False),
-        "another-chips-experts": dict(first_expert=0),
-        "no-balance-loss": dict(aux_loss_coef=0.0),
-    }.get(change)
-    if change == "ramp-not-truncated":
-        # the upper bound left a fraction (c(1) = 1.62 where the rule says
-        # ceil: 2) moves the ramp's one step inside, 0.5 to 0.62
-        def untruncated(head_dim, theta, *, factor, original, beta_fast=32.0,
-                        beta_slow=1.0):
-            def c(n):
-                return (head_dim * math.log(original / (n * 2 * math.pi))
-                        / (2 * math.log(theta)))
-
-            low, high = max(c(beta_fast), 0.0), c(beta_slow)
-            plain = 1.0 / theta ** (jnp.arange(0, head_dim, 2) / head_dim)
-            ramp = jnp.clip((jnp.arange(head_dim // 2) - low) / (high - low),
-                            0.0, 1.0)
-            return plain / factor * ramp + plain * (1.0 - ramp)
-
-        with pytest.MonkeyPatch.context() as patch:
-            from ray_tpu.ops import layers
-            patch.setattr(layers, "yarn_inv_freq", untruncated)
-            got = _token_nll(cfg, params)
-    elif change == "no-balance-loss":
-        total = jax.jit(lambda p: loss_fn(
-            p, {"tokens": TOKENS}, dataclasses.replace(cfg, **wrong))[0])(
-                params)
-        # what the chip's mean-loss row sees: 0.001 x E-ish of ln(vocab)
-        assert abs(float(total) - float(sound)) / float(sound) > \
-            2 * mellum.LOSS_RTOL
-        return
-    else:
-        got = _token_nll(dataclasses.replace(cfg, **wrong), params)
-    assert float(jnp.max(jnp.abs(got - want))) > 1e-3
+@pytest.mark.parametrize("fault", fault_ids("mellum"))
+def test_a_changed_part_stands_apart_from_the_reference(fault):
+    """Each structural point of the configuration, got wrong in ONE PERIOD
+    of the program (the row's ``faults`` and ``sound``), moves a token's
+    loss by more than a thousandth of a nat (the sound program stands 5e-5
+    off at most) — or, of the load-balancing term, the total by more than
+    its tolerance: the full layers' tables plain, YaRN's factor left off
+    them, the two kinds' tables swapped, YaRN in every layer, the ramp
+    between untruncated bounds, the window dropped, a key short or four
+    times as wide, the gates not renormalised, the wrong quarter of the
+    experts."""
+    stands_apart("mellum", fault)
 
 
 # -- the rotary rule -----------------------------------------------------------
@@ -450,21 +377,12 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     reference has it — the gates renormalised over the 8 CHOSEN, wherever
     they live, so a share's gates do not sum to 1."""
     p = _expert_layer()
-    parts = [_share(p, first, 16) for first in range(0, 64, 16)]
     h = mellum.rms_norm(p["x"], p["mlp_norm"], 1e-6)
     whole, chosen, balance = mellum.expert_ffn(
         h[None], p, k=8, renormalise=True, first=0)
-    np.testing.assert_allclose(sum(part for part, _ in parts), whole[0],
-                               atol=2e-5)
-    stats = [s for _, s in parts]
-    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
-    assert all(0.1 < float(s["held_share"]) < 0.4 for s in stats)
-    assert all(float(s["dropped"]) == 0.0 for s in stats)
-    # every share routes over all 64 and reports the same loss over them
-    for s in stats:
-        np.testing.assert_array_equal(
-            s["counts"], np.bincount(np.asarray(chosen).ravel(),
-                                     minlength=64))
+    parts = shares_add_up("mellum", p, _share, whole[0], chosen, k=8)
+    for _, s in parts:  # ... and reports the same loss over all 64
+        assert 0.1 < float(s["held_share"]) < 0.4
         np.testing.assert_allclose(s["aux_loss"], balance, rtol=1e-5)
     # one share alone is the reference's with the same experts held
     alone, _, _ = mellum.expert_ffn(
@@ -485,23 +403,12 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
 # -- the train step and the configuration file ---------------------------------
 
 def test_the_train_step_runs_both_kinds_of_kernel_and_reports():
-    cfg = tiny(attn_impl="flash", remat=True, num_layers=4)
-    opt = default_optimizer()
-    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    step = make_train_step(cfg, opt, donate=False)
-    lowered = step.lower(state, {"tokens": TOKENS})   # traced once
-    text = lowered.as_text(debug_info=True)
-    for name in ("flash_fwd_win", "flash_dkv_win", "flash_fwd", "flash_dkv/",
-                 "attn_qkv/rope/", "attn_out/", "moe_experts/",
-                 "moe_combine/"):
-        assert name in text, name
+    stepped = train_step_reports("mellum")
+    text = stepped.text
     assert "flash_dq" not in text   # ONE backward kernel, windowed or not
     # the rotary ops sit INSIDE attn_qkv: no name stack starts at ``rope``
     assert "/rope/" in text and "jit(step)/rope" not in text
-    state, metrics = lowered.compile()(state, {"tokens": TOKENS})
-    assert set(mellum.STEP_METRICS) <= set(metrics)
-    assert float(metrics["moe_dropped"]) == 0.0
-    assert np.isfinite(float(metrics["loss"]))
+    assert float(stepped.metrics["moe_dropped"]) == 0.0
 
 
 def test_the_files_fields_reach_the_program_and_its_traffic_stays_in_the_slice():

@@ -18,15 +18,16 @@ import optax
 import pytest
 
 from benchmark.reference import nemotron_h
-from ray_tpu.models.blocks import FFNS, MIXERS, mamba
+from ray_tpu.models.blocks import FFNS, MIXERS
 from ray_tpu.models.blocks.base import Ctx
-from ray_tpu.models.llama import LAYER_PATTERN, forward, init_params
+from ray_tpu.models.llama import LAYER_PATTERN, init_params
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.train.core import STEP_SCOPES, init_train_state, make_train_step
 from ray_tpu.util.tracing import scope_and_phase
 import tiny_models
 from tiny_models import (
-    ROWS, against_the_reference, apart as _apart, program, reference)
+    ROWS, against_the_reference, apart as _apart, fault_ids, program,
+    shares_add_up, stands_apart)
 
 TOKENS = ROWS["nemotron"].tokens
 tiny = functools.partial(tiny_models.tiny, "nemotron")
@@ -130,67 +131,14 @@ def test_the_kernels_under_the_checkpoint_give_the_same_loss_and_gradients():
     assert max(jax.tree.leaves(apart)) < 1e-4, apart
 
 
-def _gate_in_place_of_relu2(cfg, params):
-    """The same weights read as a SwiGLU model whose gate is its up."""
-    gated = dataclasses.replace(cfg, ffn_act="swiglu")
-    return gated, dict(params, layers=tuple(
-        dict(s, w_gate=s["w_up"], shared_gate=s["shared_up"])
-        if "w_up" in s else s for s in params["layers"]))
-
-
-def _one_groups_b_and_c(monkeypatch):
-    scan = mamba.ssd_chunked
-    monkeypatch.setattr(
-        mamba, "ssd_chunked", lambda x, dt, a, b, c, d, chunk: scan(
-            x, dt, a, jnp.repeat(b[:, :, :1], b.shape[2], 2),
-            jnp.repeat(c[:, :, :1], c.shape[2], 2), d, chunk=chunk))
-
-
-@pytest.mark.parametrize("fault", [
-    "gate_in_place_of_relu2", "norm_over_the_whole_width",
-    "one_groups_b_and_c_for_all_heads", "selection_bias_zeroed",
-    "shared_expert_at_the_experts_width", "rope_switched_on",
-    "gate_scale_left_out", "the_next_chips_experts"])
-def test_a_changed_part_stands_apart_from_the_reference(fault, monkeypatch):
-    """The faults the chip check is shown to catch (PERF.md section 6), at
-    CPU size and in float32: the per-token losses of the program with the
-    part changed stand apart from the reference's by a hundred times what
-    the sound program's do (3e-5 at most, the test above)."""
-    cfg = tiny()
-    params = program_params = program("nemotron").params
-    # before any patch: the reference's answer is kept for the process
-    want = reference("nemotron").parts["token_nll"]
-    if fault == "gate_in_place_of_relu2":
-        cfg, program_params = _gate_in_place_of_relu2(cfg, params)
-    elif fault == "norm_over_the_whole_width":
-        norm = mamba.gated_rms_norm
-        monkeypatch.setattr(mamba, "gated_rms_norm",
-                            lambda y, z, w, eps, groups: norm(y, z, w, eps))
-    elif fault == "one_groups_b_and_c_for_all_heads":
-        _one_groups_b_and_c(monkeypatch)
-    elif fault == "selection_bias_zeroed":
-        program_params = dict(params, layers=tuple(
-            dict(s, router_bias=jnp.zeros_like(s["router_bias"]))
-            if "router_bias" in s else s for s in params["layers"]))
-    elif fault == "shared_expert_at_the_experts_width":
-        cfg = dataclasses.replace(cfg, shared_mlp_dim=0)
-        program_params = dict(params, layers=tuple(
-            dict(s, shared_up=s["shared_up"][..., :32],
-                 shared_down=s["shared_down"][:, :32])
-            if "shared_up" in s else s for s in params["layers"]))
-    elif fault == "rope_switched_on":
-        cfg = dataclasses.replace(cfg, position_embedding="rope")
-    elif fault == "gate_scale_left_out":
-        cfg = dataclasses.replace(cfg, routed_scaling_factor=1.0)
-    else:
-        cfg = dataclasses.replace(cfg, first_expert=12)
-    with HIGHEST:   # a program of its own: traced under the patch
-        logits, _ = jax.jit(lambda p: forward(p, TOKENS[:, :-1], cfg))(
-            program_params)
-    got = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
-                               TOKENS[:, 1:, None], -1)[..., 0]
-    apart = float(jnp.sqrt(jnp.mean(jnp.square(got - want))))
-    assert apart > 3e-3, apart
+@pytest.mark.parametrize("fault", fault_ids("nemotron"))
+def test_a_changed_part_stands_apart_from_the_reference(fault):
+    """The faults the chip check is shown to catch (PERF.md section 6; the
+    row's ``faults``), at CPU size and in float32: the per-token losses of
+    the program with the part changed stand apart from the reference's by
+    a hundred times what the sound program's do (3e-5 at most, the test
+    above)."""
+    stands_apart("nemotron", fault)
 
 
 # -- (c) the eight shares add up ----------------------------------------------
@@ -227,20 +175,13 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     """Chips 0..7 with two experts each: their routed parts, and the shared
     expert ONCE, are the whole layer as the reference has it."""
     p = _expert_layer()
-    parts = [_share(p, first, 2) for first in range(0, 16, 2)]
-    routed = sum(y for y, _ in parts)
     h = nemotron_h.rms_norm(p["x"], p["mlp_norm"], 1e-6)
     shared = nemotron_h.relu2(h, p["shared_up"], p["shared_down"])
     with HIGHEST:
         whole, _ = nemotron_h.expert_ffn(h[None], p, k=3, factor=2.5,
                                          first=0)
-    np.testing.assert_allclose(routed + shared, whole[0], atol=3e-5)
-    stats = [s for _, s in parts]
-    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
-    assert all(float(s["dropped"]) == 0.0 for s in stats)
-    for s in stats[1:]:
-        np.testing.assert_array_equal(stats[0]["counts"], s["counts"])
-    assert int(jnp.sum(stats[0]["counts"])) == 96 * 3
+    shares_add_up("nemotron", p, _share, whole[0], k=3, shared=shared,
+                  atol=3e-5)
 
 
 # -- (d) the scopes of a layer that is one sub-block --------------------------

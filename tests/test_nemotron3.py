@@ -6,7 +6,6 @@ then ``E``) — the program (``ray_tpu/models/llama.py``, ``blocks/ffn.py``,
 ``ops/moe.py``) against the plain reference
 (``benchmark/reference/nemotron3.py``) on seeded weights in float32."""
 
-import dataclasses
 import functools
 import re
 
@@ -17,12 +16,12 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from benchmark.reference import nemotron3
-from ray_tpu.models.blocks import ffn, mamba
 from ray_tpu.models.llama import LlamaConfig, init_params
 from ray_tpu.ops.moe import moe_block
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 import tiny_models
-from tiny_models import against_the_reference, program, reference
+from tiny_models import (
+    against_the_reference, fault_ids, program, shares_add_up, stands_apart)
 
 tiny = functools.partial(tiny_models.tiny, "nemotron3")
 HIGHEST = jax.default_matmul_precision("highest")
@@ -120,67 +119,18 @@ def test_the_kernels_under_the_checkpoint_give_the_same_loss_and_gradients():
     assert max(jax.tree.leaves(apart)) < 1e-4, apart
 
 
-def _experts(params, change):
-    """``params`` with ``change(stack)`` in place of every expert stack,
-    the stack's and the module's."""
-    swap = lambda stacks: tuple(change(s) if "router" in s else s
-                                for s in stacks)
-    return dict(params, layers=swap(params["layers"]), mtp=dict(
-        params["mtp"], layers=swap(params["mtp"]["layers"])))
-
-
-@pytest.mark.parametrize("fault", [
-    "gate_scale_left_out", "top_21_in_place_of_top_22",
-    "the_latent_pair_crossed", "shared_expert_fed_the_latents_round_trip",
-    "the_modules_e_before_its_star", "norm_over_the_whole_width"])
-def test_a_changed_part_stands_apart_from_the_reference(fault, monkeypatch):
-    """The six faults the chip check is shown to catch (PERF.md section 6),
-    at CPU size and in float32: with the part changed each token's loss —
-    or, where the fault is in the module and the stack is sound, the
-    module's loss — stands apart from the reference's by a hundred times
-    what the sound program's does (3e-5 nats and 2e-5 relative at most, the
-    test above).
-    Three of them are a published key READ WRONG (``routed_scaling_factor``,
-    ``num_experts_per_tok``, ``mtp_hybrid_override_pattern``), two the
-    latent's wiring (``moe_latent_size``)."""
-    cfg = tiny()
-    params = program_params = program("nemotron3").params
-    want = reference("nemotron3").parts   # before any patch
-    if fault == "gate_scale_left_out":
-        cfg = dataclasses.replace(cfg, routed_scaling_factor=1.0)
-    elif fault == "top_21_in_place_of_top_22":    # one choice fewer: 5 of 6
-        cfg = dataclasses.replace(cfg, num_selected=cfg.num_selected - 1)
-    elif fault == "the_latent_pair_crossed":   # u = h W_out^T, out = y W_in^T
-        program_params = _experts(params, lambda s: dict(
-            s, w_latent_in=s["w_latent_out"].swapaxes(1, 2),
-            w_latent_out=s["w_latent_in"].swapaxes(1, 2)))
-    elif fault == "shared_expert_fed_the_latents_round_trip":
-        dense = ffn._ffn
-
-        def fed(h, lp, cfg, prefix="w_"):
-            if prefix == "shared_":
-                h = (h @ lp["w_latent_in"]) @ lp["w_latent_out"]
-            return dense(h, lp, cfg, prefix)
-
-        monkeypatch.setattr(ffn, "_ffn", fed)
-    elif fault == "the_modules_e_before_its_star":
-        cfg = dataclasses.replace(cfg, mtp_pattern="E*")
-        program_params = dict(params, mtp=dict(
-            params["mtp"], layers=params["mtp"]["layers"][::-1]))
-    else:
-        norm = mamba.gated_rms_norm
-        monkeypatch.setattr(mamba, "gated_rms_norm",
-                            lambda y, z, w, eps, groups: norm(y, z, w, eps))
-    with HIGHEST:   # a program of its own: traced under the patch
-        side = tiny_models.side_of("nemotron3", cfg, program_params)
-        _, parts = side.loss(program_params)
-        got = side.token_nll(program_params)
-    apart = float(jnp.sqrt(jnp.mean(jnp.square(got - want["token_nll"]))))
-    ahead_apart = abs(float(parts["mtp_loss"]) / float(want["mtp_loss"]) - 1)
+@pytest.mark.parametrize("fault", fault_ids("nemotron3"))
+def test_a_changed_part_stands_apart_from_the_reference(fault):
+    """The six faults the chip check is shown to catch (PERF.md section 6;
+    the row's ``faults``), at CPU size and in float32: with the part
+    changed each token's loss — or, where the fault is in the module and
+    the stack is sound, the module's loss — stands apart from the
+    reference's by a hundred times what the sound program's does (3e-5
+    nats and 2e-5 relative at most, the test above)."""
+    read = stands_apart("nemotron3", fault)
     if fault == "the_modules_e_before_its_star":   # the stack is sound
-        assert apart < 3e-5 and ahead_apart > 2e-3, (apart, ahead_apart)
-    else:
-        assert apart > 3e-3, apart
+        assert tiny_models.token_rms(read.side, read.params,
+                                     read.want) < 3e-5
 
 
 # -- (c) the shares add up ----------------------------------------------------
@@ -225,18 +175,13 @@ def test_the_shares_add_up_to_the_uncut_layer():
     sum, and the shared expert ONCE, are the whole layer as the reference
     has it."""
     p = _latent_layer()
-    parts = [_share(p, first, 4) for first in range(0, 16, 4)]
-    routed = sum(y for y, _ in parts)
     h = nemotron3.rms_norm(p["x"], p["mlp_norm"], 1e-6)
     shared = nemotron3.relu2(h, p["shared_up"], p["shared_down"])
     with HIGHEST:
         whole, _ = nemotron3.latent_expert_ffn(h[None], p, k=6, factor=5.0,
                                                first=0)
-    np.testing.assert_allclose(routed + shared, whole[0], atol=5e-5)
-    stats = [s for _, s in parts]
-    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
-    assert all(float(s["dropped"]) == 0.0 for s in stats)
-    assert int(jnp.sum(stats[0]["counts"])) == 96 * 6
+    shares_add_up("nemotron3", p, _share, whole[0], k=6, shared=shared,
+                  atol=5e-5)
 
 
 # -- (d) over an ``ep`` axis the exchange carries the latent ------------------

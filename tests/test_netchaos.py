@@ -315,7 +315,16 @@ def _netchaos_fanout(n_tasks=40):
         t0 = time.monotonic()
         out = ray.get(s2, timeout=120)
         elapsed = time.monotonic() - t0
+        # The counters ride the workers' periodic delta stream: wait for
+        # the fan-out's own stall, and the reconstruction that followed
+        # it, to be counted — a ``get`` that has returned says nothing of
+        # when its workers last flushed.
+        deadline = time.monotonic() + 15
         stats = c.rt.transfer_stats()
+        while time.monotonic() < deadline and not (
+                stats["stall_timeouts"] and stats["reconstructions"]):
+            time.sleep(0.1)
+            stats = c.rt.transfer_stats()
         proc = c._agents.get(n2)
         alive = proc is not None and proc.poll() is None
         return out, stats, elapsed, alive

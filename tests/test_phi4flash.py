@@ -9,7 +9,6 @@ plain mixer; which layer is what; what a layer hands to later ones; a mesh;
 the train step; the configuration file.  The tiny model is
 ``tests/tiny_models.py``'s row ``phi4flash``."""
 
-import dataclasses
 import functools
 import json
 import os
@@ -22,8 +21,7 @@ import pytest
 from benchmark.loops import train
 from benchmark.reference import phi4flash
 from ray_tpu.models import llama
-from ray_tpu.models.blocks import (
-    MIXERS, attention as attention_block, mamba1)
+from ray_tpu.models.blocks import MIXERS, attention as attention_block
 from ray_tpu.models.blocks.base import Ctx
 from ray_tpu.models.llama import (
     LlamaConfig, init_params, loss_fn, sambay_mixers)
@@ -31,11 +29,11 @@ from ray_tpu.ops import ssm
 from ray_tpu.ops.attention import mha_reference
 from ray_tpu.ops.layers import rms_norm
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
-from ray_tpu.train.core import (
-    default_optimizer, init_train_state, make_train_step)
 
 import tiny_models
-from tiny_models import against_the_reference, program, reference, side_of
+from tiny_models import (
+    against_the_reference, fault_ids, program, stands_apart,
+    train_step_reports)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "phi-4-mini-flash-reasoning-1of8"
@@ -92,68 +90,13 @@ def test_the_reported_lambda_is_the_attention_layers_mean():
                                rtol=1e-6)
 
 
-def _with(block, **fields):
-    return dataclasses.replace(block, **fields)
-
-
-def _memory_fault(monkeypatch, remade):
-    """The Mamba-1 block publishing ``remade(m, what the real block
-    publishes with D at 0, the gate's silu(z))`` in place of ``m``."""
-    real = mamba1.BLOCK.apply
-
-    def apply(ctx, x, aux, lp, residual=True):
-        out, aux_out, made = real(ctx, x, aux, lp, residual)
-        _, _, bare = real(ctx, x, aux, dict(
-            lp, s6_D=jnp.zeros_like(lp["s6_D"])), residual)
-        h = mamba1.block_in(x, lp["s6_norm"], ctx.cfg, lp["s6_norm_bias"])
-        z = (h @ lp["s6_in"])[..., ctx.cfg.s6_inner:]
-        return out, aux_out, {mamba1.MEMORY: remade(
-            made[mamba1.MEMORY], bare[mamba1.MEMORY], jax.nn.silu(z))}
-
-    monkeypatch.setitem(MIXERS, "mamba1", _with(mamba1.BLOCK, apply=apply))
-
-
-@pytest.mark.parametrize("change", [
-    "no-lambda", "no-sub-norm", "one-lambda-init", "window-one-longer",
-    "cross-reads-the-windowed-layer", "unit-reads-after-the-gate",
-    "memory-without-d-x", "no-dt-bias"])
-def test_a_changed_part_stands_apart_from_the_reference(change, monkeypatch):
+@pytest.mark.parametrize("fault", fault_ids("phi4flash"))
+def test_a_changed_part_stands_apart_from_the_reference(fault):
     """Each fault the chip check is held to (the configuration file's
-    ``check.why``), put into the PROGRAM at CPU size: the per-token losses
-    part from the reference's by far more than rounding (a sound program's
-    are within 3e-5)."""
-    want = reference("phi4flash").parts["token_nll"]
-    cfg, params = tiny(), program("phi4flash").params
-    if change == "no-lambda":       # a1 - a2
-        monkeypatch.setattr(attention_block, "learned_lambda",
-                            lambda lp, start: 1.0)
-    elif change == "no-sub-norm":
-        monkeypatch.setattr(attention_block, "rms_norm", lambda x, w, eps: x)
-    elif change == "one-lambda-init":   # layer 0's in every layer
-        monkeypatch.setattr(attention_block, "lambda_init",
-                            lambda i: 0.2 + 0.0 * i)
-    elif change == "window-one-longer":
-        cfg = tiny(sliding_window=9)
-    elif change == "cross-reads-the-windowed-layer":
-        monkeypatch.setitem(MIXERS, "diff_full", _with(
-            attention_block.DIFF_FULL, publishes=()))
-        monkeypatch.setitem(MIXERS, "diff_sliding", _with(
-            attention_block.DIFF_SLIDING,
-            publishes=attention_block.DIFF_FULL.publishes,
-            apply=functools.partial(attention_block._diff_mixer,
-                                    windowed=True, publishes=True)))
-        assert llama._published(cfg.layer_runs)[3] == (
-            "diff_keys", "diff_values")
-    elif change == "unit-reads-after-the-gate":
-        _memory_fault(monkeypatch, lambda m, bare, gate: m * gate)
-    elif change == "memory-without-d-x":
-        _memory_fault(monkeypatch, lambda m, bare, gate: bare)
-    elif change == "no-dt-bias":
-        params = dict(params, layers=tuple(
-            {k: (jnp.zeros_like(v) if k == "s6_dt_bias" else v)
-             for k, v in lp.items()} for lp in params["layers"]))
-    got = side_of("phi4flash", cfg, params).token_nll(params)
-    assert float(jnp.sqrt(jnp.mean(jnp.square(got - want)))) > 1e-3, change
+    ``check.why``; the row's ``faults``), put into the PROGRAM at CPU size:
+    the per-token losses part from the reference's by far more than
+    rounding (a sound program's are within 3e-5)."""
+    stands_apart("phi4flash", fault)
 
 
 # -- (b) the selective scan ---------------------------------------------------
@@ -397,23 +340,7 @@ def test_on_a_mesh_the_model_is_one_devices(impl):
 
 
 def test_the_train_step_opens_the_scopes_and_learns():
-    cfg = tiny(attn_impl="flash", remat=True)
-    opt = default_optimizer()
-    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    step = make_train_step(cfg, opt, donate=False)
-    lowered = step.lower(state, {"tokens": TOKENS})
-    text = lowered.as_text(debug_info=True)
-    for name in ("s6_in/", "s6_conv/", "s6_scan/", "s6_out/", "gmu/",
-                 "attn_qkv/", "attention/", "flash_fwd_win", "flash_dkv_win",
-                 "flash_fwd", "attn_diff/", "attn_out/", "ffn/"):
-        assert name in text, name
-    compiled = lowered.compile()
-    losses = []
-    for _ in range(3):
-        state, metrics = compiled(state, {"tokens": TOKENS})
-        losses.append(float(metrics["loss"]))
-    assert losses[2] < losses[0]
-    assert set(phi4flash.STEP_METRICS) <= set(metrics)
+    train_step_reports("phi4flash")
 
 
 def test_the_files_fields_reach_the_program_and_its_traffic_stays_in_the_slice():

@@ -26,11 +26,11 @@ from ray_tpu.models.blocks.base import Ctx
 from ray_tpu.models.llama import LlamaConfig, forward, loss_fn
 from ray_tpu.ops import attention
 from ray_tpu.ops.layers import repeat_kv_heads
-from ray_tpu.train.core import (
-    STEP_SCOPES, default_optimizer, init_train_state, make_train_step)
+from ray_tpu.train.core import STEP_SCOPES
 
 import tiny_models
-from tiny_models import SDAR_NOISE, against_the_reference, program
+from tiny_models import (
+    SDAR_NOISE, against_the_reference, program, train_step_reports)
 
 ROW = tiny_models.ROWS["sdar"]
 TOKENS = ROW.tokens
@@ -242,17 +242,9 @@ def test_the_train_step_draws_by_its_step_and_learns():
     same step the same again (a resumed job repeats its draws); the step
     runs the block rule's kernels under the scope ``attention``, the noise
     under ``bd_noise``, and learns."""
-    cfg = tiny(attn_impl="flash", remat=True)
-    opt = default_optimizer()
-    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    step = make_train_step(cfg, opt, donate=False)
-    lowered = step.lower(state, {"tokens": TOKENS})
-    text = lowered.as_text(debug_info=True)
-    for name in ("(bd_noise)/", "attention/", "flash_fwd_bd", "flash_dkv_bd",
-                 "moe_experts/"):
-        assert name in text, name
     assert "bd_noise" in STEP_SCOPES
-    compiled = lowered.compile()
+    stepped = train_step_reports("sdar")
+    cfg, state, compiled = stepped.cfg, stepped.initial, stepped.compiled
     at = lambda n: dataclasses.replace(  # noqa: E731
         state, step=jnp.asarray(n, jnp.int32))
     batch = {"tokens": TOKENS}
@@ -269,11 +261,7 @@ def test_the_train_step_draws_by_its_step_and_learns():
     assert set(sdar_block_diffusion.STEP_METRICS) <= set(first)
     assert float(first["bd_mask_off"]) == 0.0
     assert float(first["moe_dropped"]) == 0.0
-    losses = []
-    for _ in range(3):
-        state, metrics = compiled(state, batch)
-        losses.append(float(metrics["loss"]))
-    assert int(state.step) == 3 and np.isfinite(losses).all()
+    assert int(stepped.state.step) == 3
 
 
 # -- (e) what is not built refuses by message ------------------------------------

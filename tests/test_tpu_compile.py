@@ -32,6 +32,7 @@ from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.train.core import (
     TrainState, default_optimizer, init_train_state, make_train_step,
     train_state_shardings)
+from conftest import compiled_to_run
 
 
 @pytest.fixture(scope="module")
@@ -46,11 +47,14 @@ def topo():
     except Exception as e:  # noqa: BLE001 — any failure means: skip
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     # A compile for a described chip is written to the persistent cache
-    # but cannot be read back without one: keep it off around these.
+    # but cannot be read back without one: keep it off around these.  And
+    # these cases read what the OPTIMISING compiler makes (its text, its
+    # memory analysis): tier-1's compile-to-check setting is set aside too.
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield t
+    with compiled_to_run():
+        yield t
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 
@@ -339,21 +343,45 @@ def _smoke_cfg(**kw):
     return LlamaConfig.llama2_7b(**model)
 
 
-def _batch(sharding):
+@functools.lru_cache(maxsize=None)
+def _lowered_step(cfg, rows, positions, where):
+    """The train step of ``cfg`` on ``rows`` x ``positions`` tokens under
+    the default optimizer, lowered for ``where`` — the described chip
+    (``one_chip``), or a mesh of the described chips (built with ``mesh=``
+    and lowered with NO mesh context: how the smoke's loop calls it) —
+    once a process."""
+    opt = default_optimizer()
+    if isinstance(where, jax.sharding.Mesh):
+        step = make_train_step(cfg, opt, mesh=where)
+        state = train_state_shardings(cfg, opt, where)
+        where = NamedSharding(where, P(("dp", "fsdp")))
+    else:
+        step, state = make_train_step(cfg, opt), where
+    return step.lower(
+        _state_shapes(cfg, opt, state),
+        {"tokens": _shape((rows, positions + 1), jnp.int32, where)})
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_step(cfg, rows, positions, where):
+    """``_lowered_step`` compiled, ONCE a process: every case that reads a
+    step's compiled text or its memory analysis takes it from here (a
+    whole cell's step is a minute and a half, a layer's half a minute)."""
+    return _lowered_step(cfg, rows, positions, where).compile()
+
+
+def _smoke_step(where):
+    """The smoke's config (7B widths, batch 8 x 2048), depth cut to 1
+    layer to keep the compile short — the scanned layer body is the same
+    program at any depth."""
     import chip_smoke
 
-    return {"tokens": _shape(
-        (chip_smoke.TRAIN_BATCH, chip_smoke.TRAIN_SEQ + 1), jnp.int32,
-        sharding)}
+    return _compiled_step(_smoke_cfg(num_layers=1), chip_smoke.TRAIN_BATCH,
+                          chip_smoke.TRAIN_SEQ, where)
 
 
 def test_one_chip_train_step_compiles(one_chip, as_on_chip):
-    """The smoke's config (7B widths, batch 8 x 2048), depth cut to 1
-    layer to keep the compile short — the scanned layer body is the
-    same program at any depth."""
-    cfg, opt = _smoke_cfg(num_layers=1), default_optimizer()
-    compiled = make_train_step(cfg, opt).lower(
-        _state_shapes(cfg, opt, one_chip), _batch(one_chip)).compile()
+    compiled = _smoke_step(one_chip)
     assert _has_kernel(compiled)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
@@ -368,9 +396,7 @@ def test_fsdp2_tp2_train_step_compiles(mesh4, as_on_chip):
     mu = shardings.opt_state[1][0].mu
     assert mu["layers"]["wq"] == shardings.params["layers"]["wq"]
     assert mu["lm_head"].spec == P("fsdp", "tp")
-    compiled = make_train_step(cfg, opt, mesh=mesh4).lower(
-        _state_shapes(cfg, opt, shardings),
-        _batch(NamedSharding(mesh4, P(("dp", "fsdp"))))).compile()
+    compiled = _smoke_step(mesh4)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # fsdp gathers parameters and scatters gradients; tp reduces partial
@@ -456,13 +482,7 @@ def test_expert_parallel_train_step_compiles(mesh4, as_on_chip):
         num_kv_heads=16, head_dim=128, mlp_dim=1024, num_experts=8,
         num_selected=2, qk_norm=True, norm_eps=1e-5, z_loss_coef=0.001,
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
-    opt = default_optimizer()
-    shardings = train_state_shardings(cfg, opt, mesh)
-    batch = {"tokens": _shape((4, 2049), jnp.int32, NamedSharding(
-        mesh, P(("dp", "fsdp"), None)))}
-    compiled = make_train_step(cfg, opt, mesh=mesh).lower(
-        _state_shapes(cfg, opt, shardings), batch).compile()
-    text = compiled.as_text()
+    text = _compiled_step(cfg, 4, 2048, mesh).as_text()
     assert "moe_gmm" in text and "moe_tgmm" in text
 
 
@@ -492,10 +512,7 @@ def test_granite_hybrid_layer_train_step_compiles(one_chip, as_on_chip,
     refuse) and fits; the attention layer's flash kernels take head size
     64 (half of Mosaic's minor dimension), 32 query heads on 8 KV heads,
     a softmax scale that is a given number, and no RoPE."""
-    cfg, opt = _granite_cfg(layer_types), default_optimizer()
-    compiled = make_train_step(cfg, opt).lower(
-        _state_shapes(cfg, opt, one_chip),
-        {"tokens": _shape((1, 8193), jnp.int32, one_chip)}).compile()
+    compiled = _compiled_step(_granite_cfg(layer_types), 1, 8192, one_chip)
     text = compiled.as_text()
     assert _has_kernel(compiled)
     assert ("ssd_fwd" in text and "ssd_bwd" in text) is (
@@ -522,8 +539,7 @@ def test_granite_hybrid_programs_lower_the_scan_kernels_once_a_use(
     opt = default_optimizer()
     state = _state_shapes(cfg, opt, one_chip)
     tokens = _shape((1, 8193), jnp.int32, one_chip)
-    step = make_train_step(cfg, opt).lower(state, {"tokens": tokens}
-                                           ).as_text()
+    step = _lowered_step(cfg, 1, tokens.shape[1] - 1, one_chip).as_text()
     check = jax.jit(train.program_check(cfg, None)).lower(
         state.params, tokens).as_text()
 
@@ -634,10 +650,7 @@ def test_xing4_layer_train_step_compiles_with_the_stream_kernels(
     a scratch: what Mosaic could refuse — and it fits."""
     cfg = _xing4_cfg(num_layers=1, leading_dense=1)
     assert cfg.kind_runs == ((("latent", "dense"), 1),)
-    opt = default_optimizer()
-    compiled = make_train_step(cfg, opt).lower(
-        _state_shapes(cfg, opt, one_chip),
-        {"tokens": _shape((1, 8193), jnp.int32, one_chip)}).compile()
+    compiled = _compiled_step(cfg, 1, 8192, one_chip)
     text = compiled.as_text()
     assert all(name in text for name in _HC_KERNELS)
     mem = compiled.memory_analysis()
@@ -665,8 +678,7 @@ def test_xing4_programs_lower_the_stream_kernels_once_a_use(one_chip,
     opt = default_optimizer()
     state = _state_shapes(cfg, opt, one_chip)
     tokens = _shape((1, 8193), jnp.int32, one_chip)
-    step = make_train_step(cfg, opt).lower(state, {"tokens": tokens}
-                                           ).as_text()
+    step = _lowered_step(cfg, 1, tokens.shape[1] - 1, one_chip).as_text()
     check = jax.jit(train.program_check(cfg, None)).lower(
         state.params, tokens).as_text()
 
@@ -719,10 +731,7 @@ def test_olmo_hybrid_linear_layer_train_step_compiles(one_chip, as_on_chip):
     of the step must fit beside a layer's state."""
     cfg = _olmo_hybrid_cfg(1)
     assert cfg.layer_runs == (("linear_attention", 1),)
-    opt = default_optimizer()
-    compiled = make_train_step(cfg, opt).lower(
-        _state_shapes(cfg, opt, one_chip),
-        {"tokens": _shape((1, 4097), jnp.int32, one_chip)}).compile()
+    compiled = _compiled_step(cfg, 1, 4096, one_chip)
     text = compiled.as_text()
     assert all(name in text for name in _DELTA_KERNELS)
     assert "gdn_scan" in text
@@ -750,8 +759,7 @@ def test_olmo_hybrid_programs_lower_the_delta_kernels_once_a_use(
     opt = default_optimizer()
     state = _state_shapes(cfg, opt, one_chip)
     tokens = _shape((1, 4097), jnp.int32, one_chip)
-    step = make_train_step(cfg, opt).lower(state, {"tokens": tokens}
-                                           ).as_text()
+    step = _lowered_step(cfg, 1, tokens.shape[1] - 1, one_chip).as_text()
     check = jax.jit(train.program_check(cfg, None)).lower(
         state.params, tokens).as_text()
 
@@ -856,31 +864,23 @@ def test_kimi_linear_kda_layer_train_step_compiles(one_chip, as_on_chip):
     beside a layer's state."""
     cfg = _kimi_linear_cfg(1)
     assert cfg.kind_runs == ((("kda", "dense"), 1),)
-    opt = default_optimizer()
-    lowered = make_train_step(cfg, opt).lower(
-        _state_shapes(cfg, opt, one_chip),
-        {"tokens": _shape((1, 8193), jnp.int32, one_chip)})
-    text = lowered.as_text()
+    text = _lowered_step(cfg, 1, 8192, one_chip).as_text()
     assert [text.count(f'kernel_name = "{n}"') for n in _KDA_KERNELS] == [
         1, 1]
-    compiled = lowered.compile()
+    compiled = _compiled_step(cfg, 1, 8192, one_chip)   # the pair's case too
     hlo = compiled.as_text()
     assert all(name in hlo for name in _KDA_KERNELS) and "kda_scan" in hlo
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6e9
 
 
-@functools.lru_cache(maxsize=None)
 def _solar_open2_step(one_chip):
     """``(cfg, compiled step)`` of ``solaropen2-train-s4096`` as the cell
     runs it: the benchmark's configuration file at 1 x 4096, for the
     described chip (a minute and a half, once a process: two tests read
     it)."""
     cfg = _benchmark_cfg("solar-open2-250b-1of32")
-    opt = default_optimizer()
-    return cfg, make_train_step(cfg, opt).lower(
-        _state_shapes(cfg, opt, one_chip),
-        {"tokens": _shape((1, 4097), jnp.int32, one_chip)}).compile()
+    return cfg, _compiled_step(cfg, 1, 4096, one_chip)
 
 
 def test_the_solar_open2_cells_step_program_fits_the_chip(one_chip,
@@ -1046,8 +1046,9 @@ def _written(text):
 @pytest.mark.parametrize("mixer", ["kda", "mamba"])
 def test_the_mixers_convolution_is_the_pair_and_writes_no_float32_copy(
         one_chip, as_on_chip, mixer):
-    """Kimi-Linear's first layer (a KDA mixer; ONE layer: the two-layer
-    step compiles for 50 s and this file is the suite's longest) and two
+    """Kimi-Linear's first layer (a KDA mixer; ONE layer, the step
+    ``test_kimi_linear_kda_layer_train_step_compiles`` compiled: the
+    two-layer step compiles for 50 s and this file is the suite's longest) and two
     layers of granite (Mamba-2) at their published widths and 8192
     positions, as the one-chip train step, compiled: each scanned run of
     layers holds the convolution's forward
@@ -1064,11 +1065,8 @@ def test_the_mixers_convolution_is_the_pair_and_writes_no_float32_copy(
         cfg, scope = _granite_cfg(("mamba", "mamba")), "ssm_conv"
         channels = cfg.ssm_conv_dim
     assert {kind[0] for kind, _ in cfg.kind_runs} == {mixer}
-    runs, opt = len(cfg.kind_runs), default_optimizer()
-    text = make_train_step(cfg, opt).lower(
-        _state_shapes(cfg, opt, one_chip),
-        {"tokens": _shape((1, 8193), jnp.int32, one_chip)}
-    ).compile().as_text()
+    runs = len(cfg.kind_runs)
+    text = _compiled_step(cfg, 1, 8192, one_chip).as_text()
     for kernel, calls in (("causal_conv_fwd", 2), ("causal_conv_bwd", 1)):
         assert len(re.findall(
             rf"custom-call\(.*/{scope}/.*/{kernel}/pallas_call\"", text)
@@ -1098,10 +1096,7 @@ def test_lfm2_conv_layer_train_step_compiles(one_chip, as_on_chip):
     cfg = dataclasses.replace(cfg, vocab_size=4096, num_layers=1,
                               leading_dense=0, layer_types=("conv",))
     assert cfg.kind_runs == ((("conv", "moe"), 1),)
-    opt = default_optimizer()
-    compiled = make_train_step(cfg, opt).lower(
-        _state_shapes(cfg, opt, one_chip),
-        {"tokens": _shape((1, 8193), jnp.int32, one_chip)}).compile()
+    compiled = _compiled_step(cfg, 1, 8192, one_chip)
     assert _has_kernel(compiled)
     text = compiled.as_text()
     assert "sconv_gate" in text and "moe_experts" in text
@@ -1171,11 +1166,7 @@ def test_rope_on_the_flat_arrays_leaves_no_copy_of_q_or_k(
     assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
             cfg.qk_head_norm) == (heads, kv_heads, 128, False)
     cfg = dataclasses.replace(cfg, num_layers=2, vocab_size=4096)
-    opt = default_optimizer()
-    text = make_train_step(cfg, opt).lower(
-        _state_shapes(cfg, opt, one_chip),
-        {"tokens": _shape((rows, seq + 1), jnp.int32, one_chip)}
-    ).compile().as_text()
+    text = _compiled_step(cfg, rows, seq, one_chip).as_text()
     assert all(name in text for name in ("flash_fwd", "flash_dkv"))
     assert "flash_dq" not in text
     for kernel, calls in (("rope_fwd", 4), ("rope_bwd", 2)):  # q's and k's

@@ -20,16 +20,13 @@ import pytest
 from benchmark.loops import train
 from benchmark.reference import afmoe
 from ray_tpu.models.blocks import MIXERS, attention as attention_block
-from ray_tpu.models.llama import (
-    LlamaConfig, init_params, loss_and_counts)
+from ray_tpu.models.llama import init_params, loss_and_counts
 from ray_tpu.ops.attention import causal_tile_counts, choose_tiles
 from ray_tpu.ops.moe import moe_block
-from ray_tpu.train.core import (
-    default_optimizer, init_train_state, make_train_step)
 import tiny_models
 from tiny_models import (
-    F, ROWS, S, TRINITY_WINDOW, against_the_reference,
-    program, reference, side_of)
+    F, ROWS, S, TRINITY_WINDOW, against_the_reference, fault_ids, program,
+    shares_add_up, stands_apart, train_step_reports)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "trinity-large-preview-1of32"
@@ -90,53 +87,15 @@ def test_loss_token_losses_and_gradients_equal_the_plain_reference(impl):
             assert np.any(np.asarray(g)) == (name != "router_bias"), name
 
 
-@pytest.mark.parametrize("change", [
-    "rope-in-the-full-layer-too", "no-rope-in-the-windowed-layers",
-    "rope-in-the-full-layer-alone", "no-window", "window-one-short",
-    "no-output-gate", "no-post-norms", "no-head-norm", "no-embedding-scale",
-    "no-bias", "no-route-scale", "no-shared-expert"])
-def test_a_changed_part_stands_apart_from_the_reference(change):
+@pytest.mark.parametrize("fault", fault_ids("trinity"))
+def test_a_changed_part_stands_apart_from_the_reference(fault):
     """Each structural point of the configuration, got wrong in the
-    program, moves a token's loss by more than a thousandth of a nat (the
-    sound program stands 3e-5 off at most): the position signal flipped in
-    EITHER kind of layer, the window dropped or a key short, the gate, the
-    second norms, the per-head norm, muP's factor, the selection bias,
-    ``route_scale``, the shared expert."""
-    sound = program("trinity")
-    cfg, params = sound.cfg, sound.params
-    want = reference("trinity").parts["token_nll"]
-    wrong, p = cfg, params
-    if change == "rope-in-the-full-layer-too":
-        wrong = dataclasses.replace(cfg, position_embedding="rope")
-    elif change == "no-rope-in-the-windowed-layers":
-        wrong = dataclasses.replace(cfg, position_embedding="nope")
-    elif change == "rope-in-the-full-layer-alone":
-        class Flipped(LlamaConfig):
-            def rotary(self, windowed):
-                return not windowed
-        wrong = Flipped(**{f.name: getattr(cfg, f.name)
-                           for f in dataclasses.fields(cfg)})
-    elif change == "no-window":
-        wrong = dataclasses.replace(cfg, sliding_window=SEQ)
-    elif change == "window-one-short":
-        wrong = dataclasses.replace(cfg, sliding_window=WINDOW - 1)
-    elif change == "no-output-gate":
-        wrong = dataclasses.replace(cfg, attn_output_gate=False)
-    elif change == "no-post-norms":
-        wrong = dataclasses.replace(cfg, block_norm="input")
-    elif change == "no-head-norm":
-        wrong = dataclasses.replace(cfg, qk_head_norm=False)
-    elif change == "no-embedding-scale":
-        wrong = dataclasses.replace(cfg, embedding_multiplier=1.0)
-    elif change == "no-bias":
-        wrong = dataclasses.replace(cfg, topk_method="greedy")
-    elif change == "no-route-scale":
-        wrong = dataclasses.replace(cfg, routed_scaling_factor=1.0)
-    elif change == "no-shared-expert":
-        wrong = dataclasses.replace(cfg, shared_experts=0)
-    np.testing.assert_allclose(sound.token_nll(params), want, atol=3e-5)
-    got = side_of("trinity", wrong, p).token_nll(p)
-    assert float(jnp.max(jnp.abs(got - want))) > 1e-3
+    program (the row's ``faults``), moves a token's loss by more than a
+    thousandth of a nat (the sound program stands 3e-5 off at most): the
+    position signal flipped in EITHER kind of layer, the window dropped or
+    a key short, the gate, the second norms, the per-head norm, muP's
+    factor, the selection bias, ``route_scale``, the shared expert."""
+    stands_apart("trinity", fault)
 
 
 def test_rotary_positions_follow_the_kind_of_layer():
@@ -192,24 +151,17 @@ def test_the_32_shares_add_up_to_the_uncut_layer_before_its_last_norm():
     ``n_post_mlp`` — a norm is not linear: the normed parts do not add up
     — are the whole layer as the reference has it."""
     p = _expert_layer()
-    parts = [_share(p, first, 1) for first in range(32)]
     h = afmoe.rms_norm(p["x"], p["mlp_norm"], 1e-5)
     whole, chosen = afmoe.expert_ffn(h[None], p, k=4, scale=2.448, first=0)
     shared = afmoe.swiglu(h, p["shared_gate"], p["shared_up"],
                           p["shared_down"])
+    parts = shares_add_up("trinity", p, _share, whole[0], chosen, k=4,
+                          shared=shared)
     summed = sum(part for part, _ in parts) + shared
-    np.testing.assert_allclose(summed, whole[0], atol=2e-5)
     post = lambda y: afmoe.rms_norm(y, p["mlp_post_norm"], 1e-5)  # noqa: E731
     np.testing.assert_allclose(post(summed), post(whole[0]), atol=2e-5)
     normed_parts = sum(post(part) for part, _ in parts) + post(shared)
     assert float(jnp.max(jnp.abs(normed_parts - post(whole[0])))) > 1.0
-    stats = [s for _, s in parts]
-    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
-    assert all(float(s["dropped"]) == 0.0 for s in stats)
-    assert int(jnp.sum(stats[0]["counts"])) == 96 * 4
-    np.testing.assert_array_equal(
-        stats[0]["counts"], np.bincount(np.asarray(chosen).ravel(),
-                                        minlength=32))
     # one share alone is the reference's with the same expert held, less
     # the shared expert the reference adds
     alone, _ = afmoe.expert_ffn(
@@ -272,29 +224,15 @@ def test_the_window_statistic_is_the_schedules_count():
 
 
 def test_the_train_step_runs_the_windowed_kernels_and_reports():
-    cfg = tiny(attn_impl="flash", remat=True)
-    opt = default_optimizer()
-    state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    before = jax.tree.map(np.asarray, state.params)
-    step = make_train_step(cfg, opt, donate=False)
-    lowered = step.lower(state, {"tokens": TOKENS})   # traced once
-    text = lowered.as_text(debug_info=True)
-    for name in ("flash_fwd_win", "flash_dkv_win", "flash_fwd", "flash_dkv/",
-                 "attn_qkv/", "attn_out/", "moe_experts/", "moe_combine/",
-                 "ffn/"):
-        assert name in text, name
-    assert "flash_dq" not in text   # ONE backward kernel, windowed or not
-    state, metrics = lowered.compile()(state, {"tokens": TOKENS})
-    assert {"attn_window_executed_share", "moe_held_share", "moe_dropped",
-            "moe_rows_visited_share", "moe_load_max_over_mean"
-            } <= set(metrics)
-    assert float(metrics["moe_dropped"]) == 0.0
-    assert np.isfinite(float(metrics["loss"]))
+    stepped = train_step_reports("trinity")
+    # ONE backward kernel, windowed or not
+    assert "flash_dq" not in stepped.text
+    assert float(stepped.metrics["moe_dropped"]) == 0.0
     for run in (1, 2, 3):
-        moved = np.asarray(state.params["layers"][run]["router_bias"]) \
-            - before["layers"][run]["router_bias"]
+        moved = np.asarray(stepped.state.params["layers"][run][
+            "router_bias"]) - stepped.before["layers"][run]["router_bias"]
         assert np.all((moved == 0) | np.isclose(
-            np.abs(moved), cfg.bias_update_speed, atol=1e-7))
+            np.abs(moved), stepped.cfg.bias_update_speed, atol=1e-7))
 
 
 def test_a_window_is_refused_where_no_kernel_takes_one():

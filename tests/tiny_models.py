@@ -4,27 +4,51 @@ with its configuration (the public key names), the tokens, how the
 parameters are drawn — and ONE cached maker a process of a row's program
 (``program``: configuration, parameters, and its loss, gradients and
 per-token losses as compiled functions) and of what its reference says of
-the same parameters (``reference``).  A new model is a row here, its
-reference, and a file of tests of what is new in it.  Not collected by
-pytest (no ``test_`` in its name); ``tests/test_blocks.py`` holds the table
-to every registered block."""
+the same parameters (``reference``).
+
+The tests every model repeats are ONE function each here, and a model's
+file calls them in three lines: ``against_the_reference`` (loss, per-token
+losses and gradients equal the reference's), ``stands_apart`` (a row's
+``faults``: a part got wrong moves the loss by more than the check's
+tolerance), ``shares_add_up`` (the row's ``shares`` chips' parts of one
+expert layer are the uncut layer) and ``train_step_reports`` (the row's
+``step``: the train step opens its scopes, reports its counters and
+learns).  A new model is a row here — with its faults, its share count and
+its step —, its reference, and a file of tests of what is NEW in it.  Not
+collected by pytest (no ``test_`` in its name); ``tests/test_blocks.py``
+holds the table to every registered block."""
 
 import contextlib
+import dataclasses
 import functools
 import math
+import re
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
+import pytest
 
 from benchmark.reference import (
     afmoe, granite_hybrid, joyai_flash, keye_sparse, kimi_linear, lfm2_moe,
     mellum, nemotron3, nemotron_h, olmo_hybrid, olmoe, ouro_looped, phi4flash,
     sdar_block_diffusion, solar_open2, xing4)
+from ray_tpu.models import llama
+from ray_tpu.models.blocks import (
+    MIXERS, attention as attention_block, conv, ffn, kda, mamba, mamba1)
+from ray_tpu.models.blocks.delta import GDN_STATE_ABSMAX
+from ray_tpu.models.blocks.kda import (
+    KDA_BETA_MAX, KDA_CHUNK_DECAY_MIN, KDA_STATE_ABSMAX)
 from ray_tpu.models.llama import (
     ROPE_BY_KIND, LlamaConfig, forward, init_params, loss_fn)
+from ray_tpu.ops import layers, sparse_attention
 from ray_tpu.ops.moe import moe_block
+from ray_tpu.ops.ssm import causal_conv1d
+from ray_tpu.train.core import (
+    STEP_SCOPES, default_optimizer, init_train_state, make_train_step)
+from ray_tpu.util.tracing import scope_and_phase
 
 S, F = "sliding_attention", "full_attention"
 
@@ -103,6 +127,14 @@ class Row(NamedTuple):
     conf: Optional[Dict] = None     # its configuration, public key names
     params: Callable = seeded       # cfg -> parameters
     precision: Optional[str] = None  # matmul precision of BOTH sides
+    faults: Tuple = ()              # ``Fault``s: the model got wrong
+    sees: Tuple = ()                # (distance, over): how a fault shows
+    sound: Tuple = ({}, {})         # (fields, the reference's keys) the
+    #                                 faults are put into, over the row's
+    sound_within: Optional[float] = None   # ... and how far off, a token's
+    #                                 loss at most, the sound program stands
+    shares: int = 0                 # chips an expert layer is cut over
+    step: Any = None                # ``Step``: the train step's test
 
 
 _SMALL = dict(vocab_size=128, embed_dim=64, num_heads=4, head_dim=16,
@@ -175,6 +207,439 @@ _NEMOTRON_CONF = dict(
     mamba_num_heads=8, mamba_head_dim=16, ssm_state_size=8, n_groups=2,
     first_expert=4)
 
+# -- the faults: a part of a model got wrong -----------------------------------
+
+class Frozen(dict):
+    """A dict that ``program`` / ``reference`` can take among their
+    (hashed) overrides: a public file's nested group, never changed."""
+
+    def __hash__(self):
+        return hash(tuple(sorted(self.items())))
+
+
+def mean_apart(side, params, want):
+    """The total loss's distance from the reference's, relative."""
+    total = float(want.parts["total"])
+    return abs(float(side.loss(params)[0]) - total) / total
+
+
+def _token_apart(side, params, want):
+    return side.token_nll(params) - want.parts["token_nll"]
+
+
+def token_rms(side, params, want):
+    """The per-token losses' distance from the reference's, RMS (nats)."""
+    return float(jnp.sqrt(jnp.mean(jnp.square(
+        _token_apart(side, params, want)))))
+
+
+def token_max(side, params, want):
+    """The per-token losses' distance from the reference's, at most."""
+    return float(jnp.max(jnp.abs(_token_apart(side, params, want))))
+
+
+def part_apart(part):
+    """One named part's distance from the reference's, relative."""
+    def distance(side, params, want):
+        got = side.loss(params)[1][part]
+        return abs(float(got) / float(want.parts[part]) - 1)
+
+    return distance
+
+
+class Fault(NamedTuple):
+    """One way to get a row's model wrong, put into the PROGRAM: overrides
+    of the row's fields, ONE function of the program replaced (``patch``,
+    handed a ``pytest.MonkeyPatch``: the changed program is then traced
+    under it and nothing of it is kept), the sound tensors as the wrong
+    program reads them (``reread(params, cfg)``), or — where the fields
+    change the tensors' SHAPES — the changed program on its own draw.
+    ``distance`` / ``over`` in place of the row's ``sees``; ``under``: a
+    fault NO check can see, which the test says rather than claim."""
+    id: str
+    fields: Dict[str, Any] = {}
+    patch: Optional[Callable] = None
+    reread: Optional[Callable] = None
+    own_draw: bool = False
+    distance: Optional[Callable] = None
+    over: Optional[float] = None
+    under: Optional[float] = None
+    group: str = "part"
+
+
+def _in_stacks(change, where=lambda stack: True):
+    """``reread``: ``change(stack)`` in place of every stack of layers that
+    ``where`` picks."""
+    def reread(params, cfg):
+        return dict(params, layers=tuple(
+            change(s) if where(s) else s for s in params["layers"]))
+
+    return reread
+
+
+def _tensors(runs=None, **changes):
+    """``reread``: ``changes[name](tensor)`` in place of the named tensors
+    of the runs of layers ``runs`` (of every run that holds them all)."""
+    def reread(params, cfg):
+        return dict(params, layers=tuple(
+            dict(s, **{name: fn(s[name]) for name, fn in changes.items()})
+            if (all(name in s for name in changes) if runs is None
+                else i in runs) else s
+            for i, s in enumerate(params["layers"])))
+
+    return reread
+
+
+def _whole_width_norm(patch):
+    norm = mamba.gated_rms_norm
+    patch.setattr(mamba, "gated_rms_norm",
+                  lambda y, z, w, eps, groups: norm(y, z, w, eps))
+
+
+_JOYAI_FAULTS = (
+    Fault("gate_scale", dict(routed_scaling_factor=1.0)),
+    Fault("shared_expert", dict(shared_experts=0)),
+    Fault("renormalised", dict(norm_topk_prob=False)),
+    Fault("mtp_weight", dict(mtp_loss_coef=0.0)),
+    Fault("rope_theta", dict(rope_theta=1e4)),
+    Fault("the_other_host", dict(first_expert=8)))
+
+
+
+
+_XING4_FAULTS = (
+    Fault("rope_scaling", dict(rope_scaling=None)),     # YaRN and its scale
+    Fault("routed_scaling_factor", dict(routed_scaling_factor=1.0)),
+    Fault("router_scoring", dict(router_scoring="softmax")),
+    Fault("norm_topk_prob", dict(norm_topk_prob=False)),
+    Fault("first_expert", dict(first_expert=0)),    # another chip's experts
+    Fault("hc_sinkhorn_iters", dict(hc_sinkhorn_iters=0)),      # exp alone
+    Fault("hc_clamp_max", dict(hc_clamp_max=0.0)),
+    Fault("mtp_loss_coef", dict(mtp_loss_coef=0.0)),
+    # the expert stack with one tensor at 0
+    *(Fault(leaf, reread=_tensors((1,), **{leaf: jnp.zeros_like}), over=2e-4,
+            group="leaf")
+      for leaf in ("router_bias", "shared_down", "hc_attn_bias",
+                   "hc_ffn_scale", "wkv_b")))
+
+
+def _decay_a_head(patch):   # the mean over its channels: Olmo-Hybrid's rule
+    rule = kda.kda_chunked
+    patch.setattr(kda, "kda_chunked", lambda q, k, v, g, b: rule(
+        q, k, v, jnp.broadcast_to(jnp.mean(g, -1, keepdims=True), g.shape),
+        b))
+
+
+# THE ROTATION moves the MEAN of 192 positions by 1.3e-4 only, under its
+# tolerance (8 of 24 columns in one layer of five; signed differences
+# cancel): it is the PER-TOKEN comparison that sees it, 0.127 nats RMS where
+# the sound program reads 8e-7
+_KIMI_FAULTS = (
+    Fault("position_embedding", dict(position_embedding="rope"),
+          distance=token_rms, over=0.05),
+    Fault("routed_scaling_factor", dict(routed_scaling_factor=1.0)),
+    Fault("shared_experts", dict(shared_experts=0)),
+    Fault("norm_topk_prob", dict(norm_topk_prob=False)),
+    Fault("first_expert", dict(first_expert=8)),
+    Fault("leading_dense-dense_mlp_dim",
+          dict(leading_dense=0, dense_mlp_dim=0), own_draw=True),
+    Fault("silu gate", patch=lambda patch: patch.setattr(
+        kda, "_gate", jax.nn.silu)),
+    Fault("beta twice", patch=lambda patch: patch.setattr(
+        kda, "_beta", lambda b: 2.0 * jax.nn.sigmoid(b))),
+    Fault("decay a head", patch=_decay_a_head),
+    Fault("latent in a kda layer", dict(linear_attn_config=dict(
+        KIMI_LINEAR, kda_layers=[1, 2, 5], full_attn_layers=[3, 4])),
+        own_draw=True))
+
+_SOLAR_FAULTS = (
+    Fault("kda_neg_eigval=False", dict(kda_neg_eigval=False)),
+    Fault("attn_output_gate=False", dict(attn_output_gate=False)),
+    Fault("position_embedding=rope", dict(position_embedding="rope"),
+          distance=token_rms, over=0.01),
+    Fault("routed_scaling_factor=2.0", dict(routed_scaling_factor=2.0)),
+    Fault("shared_experts=0", dict(shared_experts=0)),
+    Fault("norm_topk_prob=False", dict(norm_topk_prob=False)),
+    Fault("first_expert=8", dict(first_expert=8)),
+    Fault("num_kv_heads=4", dict(num_kv_heads=4), own_draw=True),
+    Fault("gqa_layers=(1, 5)", dict(gqa_layers=(1, 5)), own_draw=True))
+
+
+
+
+def _silu_in_the_conv(patch):
+    def with_silu(bcx, w):
+        gate_in, gate_out, x = jnp.split(bcx, 3, -1)
+        return gate_out * causal_conv1d(gate_in * x, w)
+
+    patch.setattr(conv, "gated_short_conv", with_silu)
+
+
+_LFM2_FAULTS = (
+    Fault("no-head-norm", dict(qk_head_norm=False)),
+    Fault("whole-projection-norm", dict(qk_head_norm=False, qk_norm=True),
+          reread=_tensors((1, 3), q_norm=lambda a: jnp.tile(a, (1, 4)),
+                          k_norm=lambda a: jnp.tile(a, (1, 2)))),
+    # the renormalisation's 1e-6 moves a gate by a millionth: below
+    # anything a check resolves
+    Fault("no-topk-eps", dict(topk_norm_eps=0.0), under=2e-5),
+    Fault("softmax-scores", dict(router_scoring="softmax")),
+    Fault("no-bias", dict(topk_method="greedy"), reread=_in_stacks(
+        lambda s: {k: v for k, v in s.items() if k != "router_bias"})),
+    Fault("silu-in-the-conv", patch=_silu_in_the_conv),
+    # [B | C | x] read as [x | C | B] is the same function; [C | B | x] not
+    Fault("gates-swapped", reread=_tensors(
+        (0, 2, 4), sconv_in=lambda a: jnp.concatenate(
+            [a[..., 64:128], a[..., :64], a[..., 128:]], -1))),
+    Fault("taps-reversed", reread=_tensors(
+        (0, 2, 4), sconv_w=lambda a: a[:, ::-1])),
+    Fault("untied-head", dict(tie_embeddings=False),
+          reread=lambda params, cfg: dict(params, lm_head=init_params(
+              jax.random.PRNGKey(5), cfg)["lm_head"])))
+
+
+def _mellum_groups(**kinds):
+    """Mellum's two groups of rotary keys with a kind's changed (a dict)
+    or taken from the other kind (its name)."""
+    return {k: dict(MELLUM_GROUPS[k], **change) if isinstance(change, dict)
+            else MELLUM_GROUPS[change] for k, change in kinds.items()}
+
+
+def _ramp_not_truncated(patch):
+    """The upper bound left a fraction (c(1) = 1.62 where the rule says
+    ceil: 2) moves the ramp's one step inside, 0.5 to 0.62."""
+    def untruncated(head_dim, theta, *, factor, original, beta_fast=32.0,
+                    beta_slow=1.0):
+        def c(n):
+            return (head_dim * math.log(original / (n * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low, high = max(c(beta_fast), 0.0), c(beta_slow)
+        plain = 1.0 / theta ** (jnp.arange(0, head_dim, 2) / head_dim)
+        ramp = jnp.clip((jnp.arange(head_dim // 2) - low) / (high - low),
+                        0.0, 1.0)
+        return plain / factor * ramp + plain * (1.0 - ramp)
+
+    patch.setattr(layers, "yarn_inv_freq", untruncated)
+
+
+_MELLUM_FAULTS = (
+    Fault("yarn-dropped", dict(rope_parameters=_mellum_groups(**{F: S, S: S}))),
+    Fault("attention-factor-dropped", dict(rope_parameters=_mellum_groups(
+        **{F: {"attention_factor": 1.0}, S: S}))),
+    Fault("tables-swapped",
+          dict(rope_parameters=_mellum_groups(**{F: S, S: F}))),
+    Fault("yarn-in-the-windowed-layers-too",
+          dict(rope_parameters=_mellum_groups(**{F: F, S: F}))),
+    Fault("ramp-not-truncated", patch=_ramp_not_truncated),
+    Fault("no-rope-in-the-full-layers",
+          dict(position_embedding="rope_windowed", rope_theta=100.0)),
+    Fault("no-window", dict(sliding_window=48)),    # the sample's positions
+    Fault("window-one-short", dict(sliding_window=MELLUM_WINDOW - 1)),
+    Fault("window-four-times", dict(sliding_window=4 * MELLUM_WINDOW)),
+    Fault("gates-not-renormalised", dict(norm_topk_prob=False)),
+    Fault("another-chips-experts", dict(first_expert=0)),
+    # what the chip's mean-loss row sees: 0.001 x E-ish of ln(vocab)
+    Fault("no-balance-loss", dict(aux_loss_coef=0.0), distance=mean_apart,
+          over=2 * mellum.LOSS_RTOL))
+
+_TRINITY_FAULTS = (
+    Fault("rope-in-the-full-layer-too", dict(position_embedding="rope")),
+    Fault("no-rope-in-the-windowed-layers", dict(position_embedding="nope")),
+    Fault("rope-in-the-full-layer-alone", patch=lambda patch: patch.setattr(
+        LlamaConfig, "rotary", lambda self, windowed: not windowed)),
+    Fault("no-window", dict(sliding_window=48)),    # the sample's positions
+    Fault("window-one-short", dict(sliding_window=TRINITY_WINDOW - 1)),
+    Fault("no-output-gate", dict(attn_output_gate=False)),
+    Fault("no-post-norms", dict(block_norm="input")),
+    Fault("no-head-norm", dict(qk_head_norm=False)),
+    Fault("no-embedding-scale", dict(embedding_multiplier=1.0)),
+    Fault("no-bias", dict(topk_method="greedy")),
+    Fault("no-route-scale", dict(routed_scaling_factor=1.0)),
+    Fault("no-shared-expert", dict(shared_experts=0)))
+
+
+def _one_groups_b_and_c(patch):
+    scan = mamba.ssd_chunked
+    patch.setattr(mamba, "ssd_chunked", lambda x, dt, a, b, c, d, chunk: scan(
+        x, dt, a, jnp.repeat(b[:, :, :1], b.shape[2], 2),
+        jnp.repeat(c[:, :, :1], c.shape[2], 2), d, chunk=chunk))
+
+
+_NEMOTRON_FAULTS = (
+    # the same weights read as a SwiGLU model whose gate is its up
+    Fault("gate_in_place_of_relu2", dict(ffn_act="swiglu"),
+          reread=_in_stacks(lambda s: dict(
+              s, w_gate=s["w_up"], shared_gate=s["shared_up"]),
+              lambda s: "w_up" in s)),
+    Fault("norm_over_the_whole_width", patch=_whole_width_norm),
+    Fault("one_groups_b_and_c_for_all_heads", patch=_one_groups_b_and_c),
+    Fault("selection_bias_zeroed",
+          reread=_tensors(router_bias=jnp.zeros_like)),
+    Fault("shared_expert_at_the_experts_width", dict(shared_mlp_dim=0),
+          reread=_tensors(shared_up=lambda a: a[..., :32],
+                          shared_down=lambda a: a[:, :32])),
+    Fault("rope_switched_on", dict(position_embedding="rope")),
+    Fault("gate_scale_left_out", dict(routed_scaling_factor=1.0)),
+    Fault("the_next_chips_experts", dict(first_expert=12)))
+
+
+def _in_expert_stacks(change):
+    """``reread``: ``change(stack)`` in place of every expert stack, the
+    stack's and the predicted-ahead module's."""
+    def reread(params, cfg):
+        swap = lambda stacks: tuple(  # noqa: E731
+            change(s) if "router" in s else s for s in stacks)
+        return dict(params, layers=swap(params["layers"]), mtp=dict(
+            params["mtp"], layers=swap(params["mtp"]["layers"])))
+
+    return reread
+
+
+def _shared_expert_fed_the_round_trip(patch):
+    dense = ffn._ffn
+
+    def fed(h, lp, cfg, prefix="w_"):
+        if prefix == "shared_":
+            h = (h @ lp["w_latent_in"]) @ lp["w_latent_out"]
+        return dense(h, lp, cfg, prefix)
+
+    patch.setattr(ffn, "_ffn", fed)
+
+
+# three of them are a published key READ WRONG (``routed_scaling_factor``,
+# ``num_experts_per_tok``, ``mtp_hybrid_override_pattern``), two the
+# latent's wiring (``moe_latent_size``)
+_NEMOTRON3_FAULTS = (
+    Fault("gate_scale_left_out", dict(routed_scaling_factor=1.0)),
+    Fault("top_21_in_place_of_top_22", dict(num_selected=5)),  # 5 of 6
+    # u = h W_out^T, out = y W_in^T
+    Fault("the_latent_pair_crossed", reread=_in_expert_stacks(lambda s: dict(
+        s, w_latent_in=s["w_latent_out"].swapaxes(1, 2),
+        w_latent_out=s["w_latent_in"].swapaxes(1, 2)))),
+    Fault("shared_expert_fed_the_latents_round_trip",
+          patch=_shared_expert_fed_the_round_trip),
+    # the stack is sound: it is the module's loss that parts
+    Fault("the_modules_e_before_its_star", dict(mtp_pattern="E*"),
+          reread=lambda params, cfg: dict(params, mtp=dict(
+              params["mtp"], layers=params["mtp"]["layers"][::-1])),
+          distance=part_apart("mtp_loss"), over=2e-3),
+    Fault("norm_over_the_whole_width", patch=_whole_width_norm))
+
+
+_with = dataclasses.replace
+
+
+def _memory_fault(remade):
+    """The Mamba-1 block publishing ``remade(m, what the real block
+    publishes with D at 0, the gate's silu(z))`` in place of ``m``."""
+    def fault(patch):
+        real = mamba1.BLOCK.apply
+
+        def apply(ctx, x, aux, lp, residual=True):
+            out, aux_out, made = real(ctx, x, aux, lp, residual)
+            _, _, bare = real(ctx, x, aux, dict(
+                lp, s6_D=jnp.zeros_like(lp["s6_D"])), residual)
+            h = mamba1.block_in(x, lp["s6_norm"], ctx.cfg,
+                                lp["s6_norm_bias"])
+            z = (h @ lp["s6_in"])[..., ctx.cfg.s6_inner:]
+            return out, aux_out, {mamba1.MEMORY: remade(
+                made[mamba1.MEMORY], bare[mamba1.MEMORY], jax.nn.silu(z))}
+
+        patch.setitem(MIXERS, "mamba1", _with(mamba1.BLOCK, apply=apply))
+
+    return fault
+
+
+def _cross_reads_the_windowed_layer(patch):
+    patch.setitem(MIXERS, "diff_full", _with(
+        attention_block.DIFF_FULL, publishes=()))
+    patch.setitem(MIXERS, "diff_sliding", _with(
+        attention_block.DIFF_SLIDING,
+        publishes=attention_block.DIFF_FULL.publishes,
+        apply=functools.partial(attention_block._diff_mixer, windowed=True,
+                                publishes=True)))
+    assert llama._published(tiny("phi4flash").layer_runs)[3] == (
+        "diff_keys", "diff_values")
+
+
+_PHI4FLASH_FAULTS = (
+    Fault("no-lambda", patch=lambda patch: patch.setattr(     # a1 - a2
+        attention_block, "learned_lambda", lambda lp, start: 1.0)),
+    Fault("no-sub-norm", patch=lambda patch: patch.setattr(
+        attention_block, "rms_norm", lambda x, w, eps: x)),
+    Fault("one-lambda-init", patch=lambda patch: patch.setattr(
+        attention_block, "lambda_init", lambda i: 0.2 + 0.0 * i)),
+    Fault("window-one-longer", dict(sliding_window=9)),
+    Fault("cross-reads-the-windowed-layer",
+          patch=_cross_reads_the_windowed_layer),
+    Fault("unit-reads-after-the-gate",
+          patch=_memory_fault(lambda m, bare, gate: m * gate)),
+    Fault("memory-without-d-x",
+          patch=_memory_fault(lambda m, bare, gate: bare)),
+    Fault("no-dt-bias", reread=_tensors(s6_dt_bias=jnp.zeros_like)))
+
+
+def _keye_losses_apart(side, params, want):
+    """The indexers' loss by 2e-3 or the next-token loss by 1e-4: the
+    larger of the two in units of its bound."""
+    _, parts = side.loss(params)
+    return max(abs(float(parts[part]) - float(want.parts[part])) / bound
+               for part, bound in (("idx_loss", 2e-3), ("loss", 1e-4)))
+
+
+def _keye_wq_gradient_apart(side, params, want):
+    """Values stand; the model's gradients take the indexers' loss in."""
+    _, grads = side.value_and_grad(params)
+    return apart(grads["layers"]["wq"], want.grads["layers"]["wq"])
+
+
+def _head_weights_left_out(patch):
+    indexer = attention_block._indexer
+    patch.setattr(attention_block, "_indexer", lambda *a: (
+        lambda q, k, w: (q, k, jnp.ones_like(w) / 32))(*indexer(*a)))
+
+
+_KEYE_FAULTS = (
+    Fault("relu left out", patch=lambda patch: patch.setattr(
+        jax.nn, "relu", lambda x: x)),
+    Fault("head weights left out", patch=_head_weights_left_out),
+    Fault("topk halved", dict(sa_config=Frozen(KEYE_INDEXER, topk=8))),
+    Fault("the key's norm left out", patch=lambda patch: patch.setattr(
+        attention_block, "_layer_norm", lambda x, w, b, eps: x)),
+    Fault("the loss aimed at an un-detached target",
+          patch=lambda patch: patch.setattr(
+              sparse_attention.jax.lax, "stop_gradient", lambda x: x),
+          distance=_keye_wq_gradient_apart, over=1e-3))
+
+
+# -- the train steps: ``train_step_reports``'s arguments, a row each ----------
+
+class Step(NamedTuple):
+    """The train step of ``tiny(name, **fields)`` under ``optimizer()``:
+    ``steps`` steps of it, the names its lowered text must hold (scopes as
+    ``"scope/"``, kernels by name), the scopes its compiled ops must carry
+    in all three phases, the counters among its metrics, and whether the
+    loss must fall over the steps."""
+    fields: Dict[str, Any] = {}
+    named: Tuple[str, ...] = ()
+    phased: Tuple[str, ...] = ()
+    counters: Tuple[str, ...] = ()
+    steps: int = 3
+    learns: bool = True
+    optimizer: Callable = default_optimizer
+
+
+_KERNELS = dict(attn_impl="flash", remat=True)   # as a chip runs the model
+_ADAM = functools.partial(optax.adam, 1e-2)
+_MOE_COUNTERS = ("moe_held_share", "moe_dropped", "moe_rows_visited_share",
+                 "moe_load_max_over_mean")
+_WINDOWED = ("flash_fwd_win", "flash_dkv_win", "flash_fwd", "flash_dkv/",
+             "attn_out/", "moe_experts/", "moe_combine/")
+GDN_SCOPES = ("gdn_in", "gdn_conv", "gdn_scan", "gdn_out")
+KDA_SCOPES = ("kda_in", "kda_conv", "kda_scan", "kda_out")
+SCONV_SCOPES = ("sconv_in", "sconv_gate", "sconv_out")
+
 ROWS: Dict[str, Row] = {
     # ``LlamaConfig.tiny`` bare and with experts: no reference of their own
     "dense": Row({}, _numpy_tokens(2, 33)),
@@ -231,7 +696,9 @@ ROWS: Dict[str, Row] = {
          "linear_key_head_dim": 8, "linear_value_head_dim": 16,
          "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
          "rms_norm_eps": 1e-6},
-        params=functools.partial(seeded, draw=5), precision="highest"),
+        params=functools.partial(seeded, draw=5), precision="highest",
+        step=Step(optimizer=_ADAM, counters=(GDN_STATE_ABSMAX,),
+                  phased=GDN_SCOPES)),
     "lfm2": Row(
         dict(_SMALL, num_layers=8, num_kv_heads=2, dense_mlp_dim=96,
              rope_theta=1e6, norm_eps=1e-5, layer_types=LFM2_PATTERN,
@@ -244,7 +711,11 @@ ROWS: Dict[str, Row] = {
              num_hidden_layers=8, num_dense_layers=2, num_attention_heads=4,
              num_key_value_heads=2, rope_theta=1000000, norm_eps=1e-5,
              num_experts_per_tok=4, routed_scaling_factor=1,
-             first_expert=4)),
+             first_expert=4),
+        faults=_LFM2_FAULTS, sees=(mean_apart, lfm2_moe.LOSS_RTOL), shares=2,
+        step=Step(fields=dict(remat=True), steps=1, counters=_MOE_COUNTERS,
+                  named=tuple(f"{scope}/" for scope in SCONV_SCOPES + (
+                      "attn_qkv", "moe_experts", "ffn")))),
     "xing4": Row(
         dict(_SMALL, num_layers=4, num_kv_heads=4, dense_mlp_dim=96,
              rope_scaling=XING4_SCALING, num_experts=16, num_selected=4,
@@ -258,7 +729,10 @@ ROWS: Dict[str, Row] = {
              rope_scaling=XING4_SCALING, num_experts_per_tok=4,
              routed_scaling_factor=2, first_expert=4, hc_sinkhorn_iters=20,
              hc_eps=1e-6, mhc_h_res_clamp_min=-30, mhc_h_res_clamp_max=30,
-             mtp_loss_coef=0.3)),
+             mtp_loss_coef=0.3),
+        faults=_XING4_FAULTS, sees=(mean_apart, 3e-4), shares=8,
+        step=Step(steps=1, counters=("mtp_loss", "moe_held_share",
+                                     "moe_dropped"))),
     # the published pattern in small: 1 dense layer then expert layers, 16
     # experts of which this host holds the first 8, 4 a token
     "joyai": Row(
@@ -272,7 +746,9 @@ ROWS: Dict[str, Row] = {
              qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
              kv_lora_rank=16, rope_theta=32e6, rms_norm_eps=1e-6,
              num_experts_per_tok=4, routed_scaling_factor=2.5,
-             first_expert=0, mtp_loss_coef=0.3)),
+             first_expert=0, mtp_loss_coef=0.3),
+        faults=_JOYAI_FAULTS, sees=(mean_apart, joyai_flash.LOSS_RTOL),
+        shares=2),
     # the published layers in small: M, E, M, *, E; 2 groups of 4 heads; 16
     # experts of width 32 of which this chip holds 4..11, 3 a token; a
     # shared expert of width 48; 8 query heads over 2 KV heads
@@ -282,7 +758,8 @@ ROWS: Dict[str, Row] = {
         _jax_tokens(4, 33), nemotron_h,
         dict(_NEMOTRON_CONF, num_experts_per_tok=3,
              routed_scaling_factor=2.5),
-        params=functools.partial(seeded, also=("D",)), precision="highest"),
+        params=functools.partial(seeded, also=("D",)), precision="highest",
+        faults=_NEMOTRON_FAULTS, sees=(token_rms, 3e-3), shares=8),
     # the same layers with what Nemotron-3 adds: the routed experts in a
     # latent of 16 (a quarter of the hidden size, as published), MORE
     # choices a token (6 of 16) than experts held (4..7), and behind the
@@ -295,7 +772,8 @@ ROWS: Dict[str, Row] = {
         dict(_NEMOTRON_CONF, num_experts_per_tok=6, routed_scaling_factor=5,
              mtp_hybrid_override_pattern=NEMOTRON3_MODULE,
              mtp_loss_coef=0.1),
-        params=functools.partial(seeded, also=("D",)), precision="highest"),
+        params=functools.partial(seeded, also=("D",)), precision="highest",
+        faults=_NEMOTRON3_FAULTS, sees=(token_rms, 3e-3), shares=4),
     "trinity": Row(
         dict(_SMALL, num_layers=5, num_kv_heads=2, dense_mlp_dim=96,
              rope_theta=1e4, norm_eps=1e-5, layer_types=TRINITY_PATTERN,
@@ -312,7 +790,12 @@ ROWS: Dict[str, Row] = {
              num_key_value_heads=2, hidden_size=64, rope_theta=10000,
              rms_norm_eps=1e-5, sliding_window=TRINITY_WINDOW,
              num_experts_per_tok=2, route_scale=2.448, first_expert=4,
-             mup_enabled=True)),
+             mup_enabled=True),
+        faults=_TRINITY_FAULTS, sees=(token_max, 1e-3), sound_within=3e-5,
+        shares=32,
+        step=Step(fields=_KERNELS, steps=1, named=_WINDOWED + (
+            "attn_qkv/", "ffn/"), counters=_MOE_COUNTERS + (
+                "attn_window_executed_share",))),
     "mellum": Row(
         dict(_SMALL, num_layers=8, num_kv_heads=2, norm_eps=1e-6,
              layer_types=MELLUM_PATTERN, sliding_window=MELLUM_WINDOW,
@@ -325,7 +808,13 @@ ROWS: Dict[str, Row] = {
              num_key_value_heads=2, hidden_size=64, rms_norm_eps=1e-6,
              sliding_window=MELLUM_WINDOW, rope_parameters=MELLUM_GROUPS,
              num_experts_per_tok=4, norm_topk_prob=True, first_expert=4,
-             router_aux_loss_coef=0.001)),
+             router_aux_loss_coef=0.001),
+        faults=_MELLUM_FAULTS, sees=(token_max, 1e-3),
+        sound=(dict(num_layers=4), dict(num_hidden_layers=4)),
+        sound_within=5e-5, shares=4,
+        step=Step(fields=dict(_KERNELS, num_layers=4), steps=1,
+                  named=_WINDOWED + ("attn_qkv/rope/",),
+                  counters=tuple(mellum.STEP_METRICS))),
     # three layers of one kind: GQA 4/2 with a norm over each head's q and
     # k, an indexer that picks 16 keys a query, every layer an expert layer
     # (16 experts of which this chip holds 4..7, 4 a token, renormalised)
@@ -341,7 +830,13 @@ ROWS: Dict[str, Row] = {
              sa_config=KEYE_INDEXER, num_experts_per_tok=4,
              norm_topk_prob=True, first_expert=4, idx_loss_coef=1.0,
              router_aux_loss_coef=0.0),
-        params=_keye_params, precision="highest"),
+        params=_keye_params, precision="highest", faults=_KEYE_FAULTS,
+        sees=(_keye_losses_apart, 1.0), shares=8,
+        step=Step(fields=dict(_KERNELS, num_layers=2), named=(
+            "dsa_index/", "sparse_scores", "sparse_scores_bwd", "dsa_select/",
+            "sparse_select", "sparse_mask", "attention/", "flash_fwd_dsa",
+            "flash_dkv_dsa", "dsa_loss/", "attn_out/", "moe_experts/"),
+            counters=tuple(keye_sparse.STEP_METRICS))),
     # two layers of one kind under the DENOISING objective: GQA 4/2 with a
     # norm over each head's q and k under the block rule over [noised ;
     # clean] (128 rows of 64 positions), every layer an expert layer (16
@@ -358,7 +853,11 @@ ROWS: Dict[str, Row] = {
              block_diffusion=SDAR_NOISE, num_experts_per_tok=4,
              norm_topk_prob=True, first_expert=4,
              router_aux_loss_coef=0.001),
-        precision="highest"),
+        precision="highest",
+        step=Step(fields=_KERNELS, learns=False, named=(
+            "(bd_noise)/", "attention/", "flash_fwd_bd", "flash_dkv_bd",
+            "moe_experts/"),
+            counters=tuple(sdar_block_diffusion.STEP_METRICS))),
     # the published pattern in small: 1 dense layer then expert layers, KDA
     # x3 to one latent layer WITHOUT a q rank or a rotation; 16 experts of
     # which this chip holds 4..7, 4 a token, a shared expert; 96 positions:
@@ -377,7 +876,11 @@ ROWS: Dict[str, Row] = {
              qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=16,
              rms_norm_eps=1e-5, num_experts_per_token=4,
              routed_scaling_factor=2.446, first_expert=4),
-        precision="highest"),
+        precision="highest", faults=_KIMI_FAULTS,
+        sees=(mean_apart, kimi_linear.LOSS_RTOL), shares=16,
+        step=Step(fields=_KERNELS, optimizer=_ADAM, phased=KDA_SCOPES,
+                  counters=(KDA_STATE_ABSMAX, KDA_CHUNK_DECAY_MIN,
+                            "moe_dropped"))),
     # one published period, G K K K: a gated NoPE softmax layer of 4 query
     # / 2 KV heads, then three KDA layers whose write strength reaches 2,
     # their inner width (4 x 16) TWICE the hidden size as published; every
@@ -397,7 +900,10 @@ ROWS: Dict[str, Row] = {
              kda_allow_neg_eigval=True, num_attention_heads=4,
              num_key_value_heads=2, rms_norm_eps=1e-5,
              num_experts_per_tok=4, routed_scaling_factor=1, first_expert=4),
-        precision="highest"),
+        precision="highest", faults=_SOLAR_FAULTS,
+        sees=(mean_apart, solar_open2.LOSS_RTOL), shares=32,
+        step=Step(fields=_KERNELS, optimizer=_ADAM, counters=(
+            KDA_BETA_MAX, *solar_open2.STEP_METRICS))),
     # the SambaY rule at depth 8, M W M W | M F | G C: a state of 4 numbers
     # a channel over 128 channels, 4 query / 2 KV heads (2 pairs on 1), a
     # window of 8 of the 64 tokens, LayerNorms and biases throughout; D away
@@ -415,7 +921,13 @@ ROWS: Dict[str, Row] = {
              num_key_value_heads=2, sliding_window=8, mamba_d_state=4,
              layer_norm_eps=1e-5, mb_per_layer=2),
         params=functools.partial(seeded, also=("s6_D",)),
-        precision="highest"),
+        precision="highest", faults=_PHI4FLASH_FAULTS,
+        sees=(token_rms, 1e-3),
+        step=Step(fields=_KERNELS, named=(
+            "s6_in/", "s6_conv/", "s6_scan/", "s6_out/", "gmu/", "attn_qkv/",
+            "attention/", "flash_fwd_win", "flash_dkv_win", "flash_fwd",
+            "attn_diff/", "attn_out/", "ffn/"),
+            counters=tuple(phi4flash.STEP_METRICS))),
     # ONE stack of two layers run four times over the same weights, four
     # norms a layer, the last norm after every pass, an exit gate and the
     # head read after every pass
@@ -428,14 +940,6 @@ ROWS: Dict[str, Row] = {
              total_ut_steps=4, looped=dict(OURO_LOOP)),
         params=_ouro_params, precision="highest"),
 }
-
-
-class Frozen(dict):
-    """A dict that ``program`` / ``reference`` can take among their
-    (hashed) overrides: a public file's nested group, never changed."""
-
-    def __hash__(self):
-        return hash(tuple(sorted(self.items())))
 
 
 def looped(passes: int, entropy_coef: float = 0.05):
@@ -583,6 +1087,70 @@ def against_the_reference(name: str, *, parts=("loss",), rtol=2e-5,
     return total, got, want, grads
 
 
+# -- a changed part stands apart from the reference ----------------------------
+
+def fault_ids(name: str, group: str = "part"):
+    """The ids of a row's faults, for a model's file to parametrise over."""
+    return [f.id for f in ROWS[name].faults if f.group == group]
+
+
+class Apart(NamedTuple):
+    """What ``stands_apart`` read: the fault's distance, and the changed
+    side, its parameters and the reference's answer for what a model's
+    file asserts beyond (under no patch any more)."""
+    distance: float
+    side: Side
+    params: Any
+    want: Wanted
+
+
+def _hashable(fields) -> bool:
+    try:
+        hash(tuple(fields.values()))
+    except TypeError:
+        return False
+    return True
+
+
+def stands_apart(name: str, fault_id: str) -> Apart:
+    """The test every model repeats over its row's ``faults``: what each
+    part is worth to the loss.  The program with the part got wrong stands
+    apart from the reference — by the fault's ``distance`` or the row's —
+    by more than the check's tolerance (``over``), or the check could not
+    see that part.  The reference's answer is asked for before anything is
+    patched; a fault of fields alone is ``program(name, **fields)``, so two
+    cases of a file that change the same field share a compile; under a
+    patch the changed program is traced anew and nothing of it is kept."""
+    row = ROWS[name]
+    fault = next(f for f in row.faults if f.id == fault_id)
+    base, conf = row.sound
+    sound = program(name, **base)
+    want = reference(name, conf, **base) if base else reference(name)
+    if row.sound_within is not None:
+        assert token_max(sound, sound.params, want) < row.sound_within
+    fields = {**base, **fault.fields}
+    distance = fault.distance or row.sees[0]
+    with pytest.MonkeyPatch.context() as patch:
+        if fault.patch is not None:
+            fault.patch(patch)
+        if fault.patch is None and _hashable(fields):
+            side = program(name, **fields)
+        else:
+            cfg = tiny(name, **fields)
+            side = side_of(name, cfg, sound.params if not fault.own_draw
+                           else row.params(cfg))
+        params = side.params if fault.own_draw else sound.params
+        if fault.reread is not None:
+            params = fault.reread(params, side.cfg)
+        reading = distance(side, params, want)
+    if fault.under is not None:
+        assert reading < fault.under, (fault_id, reading)
+    else:
+        over = row.sees[1] if fault.over is None else fault.over
+        assert reading > over, (fault_id, reading, over)
+    return Apart(reading, side, params, want)
+
+
 # -- one expert layer and the shares of it (the models' share tests) ----------
 
 def expert_layer(tokens=96, d=64, m=32, experts=32, seed=3):
@@ -616,3 +1184,81 @@ def share(p, first, held, k, scale):
         num_selected=k, norm_topk_prob=True, scoring="sigmoid",
         select_bias=p["router_bias"], gate_scale=scale, first_expert=first,
         residual=False)
+
+
+def shares_add_up(name: str, p, share_of, whole, chosen=None, *, k,
+                  shared=0.0, atol=2e-5):
+    """The test every model with a held share repeats: ``ROWS[name].shares``
+    chips with an equal cut of ``p``'s experts each
+    (``share_of(p, first, held) -> (routed part, statistics)``): their
+    routed parts, and ``shared`` (the shared expert, ONCE), are ``whole``,
+    the uncut layer as the reference has it; the held shares sum to 1,
+    nothing is dropped, and every share routes over ALL the experts and
+    counts the same ``k`` assignments a token (the reference's ``chosen``
+    where it hands them out).  Returns the parts for what a model asserts
+    beyond."""
+    experts = p["router"].shape[1]
+    held = experts // ROWS[name].shares
+    parts = [share_of(p, first, held) for first in range(0, experts, held)]
+    assert len(parts) == ROWS[name].shares
+    np.testing.assert_allclose(sum(y for y, _ in parts) + shared, whole,
+                               atol=atol)
+    stats = [s for _, s in parts]
+    assert sum(float(s["held_share"]) for s in stats) == pytest.approx(1.0)
+    assert all(float(s["dropped"]) == 0.0 for s in stats)
+    counts = stats[0]["counts"] if chosen is None else np.bincount(
+        np.asarray(chosen).ravel(), minlength=experts)
+    for s in stats:
+        np.testing.assert_array_equal(s["counts"], counts)
+    assert int(np.sum(counts)) == p["x"].shape[0] * k
+    return parts
+
+
+# -- the train step reports its scopes and counters ----------------------------
+
+class Stepped(NamedTuple):
+    """What ``train_step_reports`` built and ran, for what a model asserts
+    beyond: the configuration, the state before the first step (and its
+    parameters as numpy arrays), the lowered step's text, the compiled
+    step, the state and the metrics after the last step."""
+    cfg: LlamaConfig
+    initial: Any
+    before: Any
+    text: str
+    compiled: Any
+    state: Any
+    metrics: Dict[str, Any]
+
+
+def train_step_reports(name: str) -> Stepped:
+    """The test every model repeats over its row's ``step``: the train step
+    is traced ONCE and compiled once; its lowered text holds every name in
+    ``named``; ``steps`` steps run, every loss is finite and — over several
+    steps, unless ``learns`` says no — the last is under the first; the
+    metrics hold ``counters``; the compiled program's ops carry each scope
+    in ``phased`` in the forward, the rematerialised and the backward
+    pass."""
+    spec = ROWS[name].step
+    cfg, opt = tiny(name, **spec.fields), spec.optimizer()
+    initial = init_train_state(jax.random.PRNGKey(0), cfg, opt)
+    before = jax.tree.map(np.asarray, initial.params)
+    batch = {"tokens": ROWS[name].tokens}
+    lowered = make_train_step(cfg, opt, donate=False).lower(initial, batch)
+    text = lowered.as_text(debug_info=True)
+    for scope in spec.named:
+        assert scope in text, scope
+    compiled = lowered.compile()
+    state, losses = initial, []
+    for _ in range(spec.steps):
+        state, metrics = compiled(state, batch)
+        losses.append(float(metrics["loss"]))
+    assert np.isfinite(losses).all(), losses
+    if spec.steps > 1 and spec.learns:
+        assert losses[-1] < losses[0], losses
+    assert set(spec.counters) <= set(metrics)
+    if spec.phased:
+        seen = {scope_and_phase(n, STEP_SCOPES) for n in re.findall(
+            r'op_name="([^"]*)"', compiled.as_text())}
+        assert {(s, phase) for s in spec.phased for phase in (
+            "forward", "remat", "backward")} <= seen
+    return Stepped(cfg, initial, before, text, compiled, state, metrics)
