@@ -1197,7 +1197,7 @@ def _exit_reading(params, h, targets, cfg: LlamaConfig, cst):
         with jax.named_scope("lm_head"):
             logits = _head_product(params, h, cfg, cst)
         with jax.named_scope("loss"):
-            return _token_nll(logits, targets)
+            return _row_nll(logits, targets)
 
     return gate, _inputs_kept(head_nll, cfg)(params, h)
 
@@ -1590,13 +1590,9 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jax.Array],
 def _mean_nll(logits, targets, weights=None):
     """Mean next-token loss; ``weights (seq,)`` of 0 and 1 leaves the
     positions at 0 out of the mean."""
-    if logits.shape[0] == 1:
-        # One row: drop the degenerate dimension.  With it XLA's TPU
-        # compiler turns the gradient of the gather below into a FLAT
-        # scatter — float32 zeros the size of the logits, a relayout of
-        # them and 5 GB more of temporaries at 8192 x 100352 (PERF.md §6,
-        # PR 30).  Batches of several rows compile as they always have.
-        logits, targets = logits[0], targets[0]
+    # One row or many, one path: ``_row_nll``'s gradient is written out, so
+    # no gather's gradient is left to compile to the flat scatter that a
+    # one-row batch once had to dodge (PERF.md §6, PR 30 and PR 82).
     nll = _row_nll(logits, targets)
     if weights is None:
         return jnp.mean(nll)
@@ -1604,25 +1600,48 @@ def _mean_nll(logits, targets, weights=None):
                                      / weights.size)
 
 
-
-def _token_nll(logits, targets):
-    """Each position's next-token loss ``(b, s)``; one row without its
-    dimension, as ``_mean_nll`` (no flat scatter)."""
-    if logits.shape[0] == 1:
-        return _row_nll(logits[0], targets[0])[None]
-    return _row_nll(logits, targets)
-
-
 def _weighted_nll(logits, targets, weights):
     """``sum(weights x nll) / positions``: the denoising loss, ``weights (b,
     s)`` the masked positions' ``1 / p`` and 0 elsewhere."""
-    if logits.shape[0] == 1:    # as ``_mean_nll``: no flat scatter
-        logits, targets, weights = logits[0], targets[0], weights[0]
     nll = _row_nll(logits, targets)
     return jnp.sum(nll * weights) / nll.size
 
 
+def _is_target(logits, targets):
+    """Where along the vocabulary each position's target sits: a compare
+    against an iota, which XLA fuses into whatever reads it (and
+    partitions over a sharded vocabulary), where a gather is an op of
+    its own and its gradient a scatter."""
+    return jax.lax.broadcasted_iota(
+        targets.dtype, logits.shape, logits.ndim - 1) == targets[..., None]
+
+
+@jax.custom_vjp
 def _row_nll(logits, targets):
-    """Each position's ``-log softmax(logits)[target]``."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    """Each position's ``-log softmax(logits)[target]``, as ``logsumexp -
+    the target's logit``: ONE pass over the vocabulary.  The gradient is
+    written out (``_row_nll_bwd``) because JAX's own, of ``log_softmax``
+    and a gather, keeps a float32 ``log p`` the size of the logits for
+    the backward pass and reads the vocabulary twice more (PR 82)."""
+    return _row_nll_fwd(logits, targets)[0]
+
+
+def _row_nll_fwd(logits, targets):
+    top = jnp.max(logits, axis=-1)
+    lse = top + jnp.log(jnp.sum(jnp.exp(logits - top[..., None]), axis=-1))
+    picked = jnp.sum(
+        jnp.where(_is_target(logits, targets), logits, 0.0), axis=-1)
+    return lse - picked, (logits, lse, targets)
+
+
+def _row_nll_bwd(kept, g):
+    """``(softmax - [target]) x g``: elementwise over the kept logits, so
+    it fuses into the operands of the head's two backward products and no
+    array of the logits' size is written for it."""
+    logits, lse, targets = kept
+    grad = jnp.exp(logits - lse[..., None]) \
+        - _is_target(logits, targets).astype(logits.dtype)
+    return grad * g[..., None], None
+
+
+_row_nll.defvjp(_row_nll_fwd, _row_nll_bwd)

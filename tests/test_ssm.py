@@ -666,10 +666,11 @@ def test_pipelines_refuse_a_mixed_pattern():
 
 
 def test_a_one_row_batch_takes_the_loss_of_the_batched_form():
-    """``_mean_nll`` drops the degenerate dimension of a one-row batch (on
-    the TPU the gather's gradient over it compiles to a flat scatter:
-    5 GB at 8192 x 100352): the same loss and gradients as the same row
-    twice."""
+    """A one-row batch is no case of its own: the same loss and gradients
+    as the same row twice.  (Until PR 82 ``_mean_nll`` dropped the
+    degenerate dimension, because on the TPU the gradient of the loss's
+    gather over it compiled to a flat scatter, 5 GB at 8192 x 100352;
+    ``_row_nll`` gathers nothing now and its gradient is written out.)"""
     cfg = LlamaConfig.tiny()
     params = init_params(jax.random.PRNGKey(2), cfg)
     row = TOKENS[:1, :33]

@@ -411,6 +411,31 @@ def test_fsdp2_tp2_train_step_compiles(mesh4, as_on_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
+@pytest.mark.parametrize("where,shard", [
+    ("one_chip", (8, 2048, 32000)), ("mesh4", (4, 2048, 16000))],
+    ids=["one_chip", "mesh4"])
+def test_the_loss_writes_no_float32_array_of_the_logits_size(
+        request, as_on_chip, where, shard):
+    """The two smoke steps above (the programs they compiled, no new
+    compile): the head writes its bf16 logits and NO op writes a float32
+    array of their shape — a chip's shard of them on the mesh, batch over
+    ``fsdp`` and vocabulary over ``tp`` — for the loss or its gradient.
+    JAX's own gradient of ``log_softmax`` and a gather wrote ``log p``
+    there, 2.1 GB on the one chip (PERF.md §6, PR 82).  And on the mesh no
+    collective moves an array of the logits' rank: the loss's sums over
+    ``tp`` are of one number a position."""
+    text = _smoke_step(request.getfixturevalue(where)).as_text()
+    logits = "[{},{},{}]".format(*shard)
+    # an op's result type: what stands before its operands
+    results = [line.split(" = ", 1)[1].split("(%", 1)[0]
+               for line in _written(text)]
+    assert [r for r in results if "bf16" + logits in r]
+    assert not [r for r in results if "f32" + logits in r]
+    if where == "mesh4":
+        assert not [m for m in _collectives(text)
+                    if "2048,16000" in m[1] or "2048,32000" in m[1]]
+
+
 def _embed_twice_grad(cfg, mesh):
     """The table's gradient of a table read twice — a model's tokens and
     its predicted-ahead module's, one position on — compiled for ``mesh``
