@@ -32,11 +32,21 @@ blocks, no per-head norm, no 'tp' over the lanes), by ONE small kernel
 (``ops/rotary.py``: the tables' ``(rows, d)`` block in VMEM serves every
 head of a row tile, a lane rotate swaps a head's halves, the flash
 kernels' pre-scale of q is its epilogue), else on the 4-D view by
-``apply_rope``, as the latent mixer rotates its 64-wide rotary part.  With
+``apply_rope``, as the latent mixer rotates its 64-wide rotary part.  A
+kind of layer that rotates a SHARE of a head (``cfg.rotary_dim``: the
+first ``r`` of a head's dimensions, the tables reckoned over ``r``, the rest
+passing through untouched) goes by the 4-D view too, under the scope
+``rope_partial`` inside ``rope``.  How many QUERY heads a layer has is its
+kind's as well (``cfg.q_heads``: ``wq``, ``wo`` and the gate of a
+``sliding_attention`` run may be wider or narrower than a full run's; the
+KV heads are the model's).  With
 ``attn_output_gate`` a fourth projection ``wg`` of the block's input
 (scope ``attn_qkv``) gates the heads' outputs, ``o * sigmoid(g)``, before
-``wo`` (scope ``attn_out``); the checkpoint keeps nothing of it: the
-rematerialised forward computes ``g`` with q, k and v.
+``wo`` (scope ``attn_out``) — a gate a head and channel (``wg`` as wide as
+``wq``), or ONE number a head (``"per_head"``: ``wg (d, heads)``, the
+product under the scope ``attn_head_gate`` inside ``attn_out``); the
+checkpoint keeps nothing of it: the rematerialised forward computes ``g``
+with q, k and v.
 
 DIFFERENTIAL attention (arXiv:2410.05258; ``diff_sliding``, ``diff_full``,
 ``diff_cross``: the attention layers of a SambaY decoder, arXiv:2507.06607)
@@ -76,6 +86,15 @@ SCOPES = ("attn_qkv", "attention", "attn_out")
 WINDOW_EXECUTED = "attn_window_executed_share"
 WINDOW_MASKED = "attn_window_masked_tile_share"
 WINDOW_STATS = {WINDOW_EXECUTED: "max", WINDOW_MASKED: "max"}
+# Of a model whose head count follows the layer's kind
+# (``cfg.heads_per_layer``): the query heads a full and a windowed layer
+# ran (read off the q the layer made), the dimensions of a head a full layer
+# rotated (the width ``_rotated`` read) and the keys a windowed layer's
+# query saw at most (the window its attention was handed: the sequence's
+# length where the window cuts nothing off).
+Q_HEADS = {False: "attn_q_heads_full", True: "attn_q_heads_window"}
+ROTARY_WIDTH_FULL = "attn_rotary_width_full"
+WINDOW_KEYS = "attn_window_keys"
 # An indexed layer's: the indexer's own loss (the layers' mean joins the
 # step's), the (q, k) pairs its selection holds over the causal ones,
 # counted from the mask it made, by how many pairs that count is off
@@ -99,8 +118,11 @@ BLOCK_MASK_OFF = "bd_mask_off"
 BLOCK_STATS = {BLOCK_EXECUTED: "max", BLOCK_MASK_OFF: "sum"}
 
 
-def _attention_shapes(cfg):
-    d, h, kvd = cfg.embed_dim, cfg.qkv_dim, cfg.kv_dim
+def _attention_shapes(cfg, windowed: bool = False):
+    """A softmax layer's tensors; the query heads are its KIND's
+    (``cfg.q_heads``)."""
+    heads = cfg.q_heads(windowed)
+    d, h, kvd = cfg.embed_dim, heads * cfg.head_dim, cfg.kv_dim
     shapes = {
         **norm_shapes(cfg, "attn"),
         "wq": Param((d, h), ("layer", "kernel_in", "heads")),
@@ -115,9 +137,22 @@ def _attention_shapes(cfg):
     if cfg.qk_head_norm:  # over each head, ONE weight of a head's size
         head = Param((cfg.head_dim,), ("layer", "head_dim"), ones)
         shapes.update({"q_norm": head, "k_norm": head})
-    if cfg.attn_output_gate:  # a gate a head and channel of the output
-        shapes["wg"] = Param((d, h), ("layer", "kernel_in", "heads"))
+    if cfg.attn_output_gate:  # a gate a head and channel of the output,
+        # or one number a head
+        shapes["wg"] = Param(
+            (d, heads if cfg.attn_output_gate == "per_head" else h),
+            ("layer", "kernel_in", "heads"))
     return shapes
+
+
+def _kind_stats(windowed: bool):
+    """``Block.stats`` of the softmax mixer of this kind: a windowed
+    layer's ``WINDOW_STATS``, and in a model whose head count follows the
+    kind (nothing in any other) the kind's own counters."""
+    own = {Q_HEADS[windowed]: "max",
+           (WINDOW_KEYS if windowed else ROTARY_WIDTH_FULL): "max"}
+    return lambda cfg: {**(WINDOW_STATS if windowed else {}),
+                        **(own if cfg.heads_per_layer else {})}
 
 
 def _latent_shapes(cfg):
@@ -202,11 +237,18 @@ def _rotated(ctx: Ctx, windowed: bool, q, k):
     s, heads, d)`` by ``apply_rope``; ``(b, s, heads x d)`` by the kernel
     (under a mesh per shard of the batch), which hands q on times the
     flash kernels' pre-scale where they are the attention
-    (``_q_prescale``)."""
+    (``_q_prescale``).  A kind that rotates the first ``r`` of a head's
+    ``d`` dimensions comes in the 4-D view: tables over ``r``, the other
+    ``d - r`` columns handed on as they are (scope ``rope_partial``)."""
     cfg = ctx.cfg
-    d = cfg.head_dim
+    d, r = cfg.head_dim, cfg.rotary_dim(windowed)
     with jax.named_scope("rope"):
-        cos, sin = _rope_tables(ctx, windowed, q.shape[1], d)
+        cos, sin = _rope_tables(ctx, windowed, q.shape[1], r)
+        if r < d:
+            with jax.named_scope("rope_partial"):
+                return tuple(jnp.concatenate(
+                    [apply_rope(x[..., :r], cos, sin), x[..., r:]], -1)
+                    for x in (q, k))
         if q.ndim == 4:
             return apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         c, s = rotary.lane_tables(cos, sin)
@@ -327,6 +369,9 @@ def _attend(ctx: Ctx, x, aux, q, k, v, lp, residual: bool, gate=None,
             aux = fold(aux, _window_stats(
                 cfg, q.shape[1], k.shape[1], max(q.shape[-1], v.shape[-1]),
                 window), WINDOW_STATS)
+            if cfg.heads_per_layer:
+                aux = fold(aux, {WINDOW_KEYS: jnp.float32(
+                    window or k.shape[1])}, {WINDOW_KEYS: "max"})
         elif ctx.sp_manual:
             o = _attention_sp_manual(q, k, v, cfg)
         else:
@@ -336,16 +381,36 @@ def _attend(ctx: Ctx, x, aux, q, k, v, lp, residual: bool, gate=None,
 
 def _out(ctx: Ctx, x, o, lp, residual: bool, gate):
     """Scope ``attn_out``: the heads' outputs ``o (b, s, h, dv)`` side by
-    side, gated where the mixer has a gate, through ``wo`` onto the
-    stream."""
+    side, gated where the mixer has a gate — ``gate (b, s, h x dv)``, or
+    ``(b, s, h)``, one number a head (scope ``attn_head_gate``) —, through
+    ``wo`` onto the stream."""
     cfg = ctx.cfg
     with jax.named_scope("attn_out"):
+        if gate is not None and cfg.attn_output_gate == "per_head":
+            with jax.named_scope("attn_head_gate"):
+                o, gate = _head_gated(o, gate, cfg.dtype), None
         o = o.reshape(*x.shape[:2], -1)
         if gate is not None:
             o = (o.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(cfg.dtype)
         return add(ctx, x, o @ lp["wo"].astype(cfg.dtype), residual,
                    out_norm(lp, "attn", cfg))
+
+
+def _head_gated(o, gate, dtype):
+    """``o (b, s, h, dv)`` times ``sigmoid(gate (b, s, h))``, one number a
+    head, side by side as ``(b, s, h x dv)`` — where the flash kernels
+    leave o and ``wo`` reads it.  The head's number is laid over its ``dv``
+    lanes by a 0/1 product on the MXU (exact: one 1 a column): left to a
+    broadcast on the 4-D view, XLA writes a float32 array the size of two
+    o's, re-lays it to the flat one and copies o beside it, 9-12 ms a layer
+    and pass at 64 heads x 16384 tokens (PERF.md §6, PR 83); the product
+    itself is float32, rounded once."""
+    b, s, h, dv = o.shape
+    spread = jnp.repeat(jnp.eye(h, dtype=dtype), dv, axis=1)    # (h, h dv)
+    wide = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dtype) @ spread
+    return (o.reshape(b, s, h * dv).astype(jnp.float32)
+            * wide.astype(jnp.float32)).astype(dtype)
 
 
 def _qkv(ctx: Ctx, x, lp, windowed: bool):
@@ -361,10 +426,14 @@ def _qkv(ctx: Ctx, x, lp, windowed: bool):
         if cfg.qk_norm:
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-        rotate, flat = cfg.rotary(windowed), _rotates_flat(ctx, s)
+        # the kernel swaps the halves of a WHOLE head: a kind that rotates
+        # a share of one goes by the 4-D view
+        rotate, flat = cfg.rotary(windowed), (
+            cfg.rotary_dim(windowed) == cfg.head_dim
+            and _rotates_flat(ctx, s))
         if rotate and flat:
             q, k = _rotated(ctx, windowed, q, k)
-        q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
+        q = q.reshape(b, s, cfg.q_heads(windowed), cfg.head_dim)
         k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
         if cfg.qk_head_norm:
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
@@ -383,7 +452,16 @@ def _qkv(ctx: Ctx, x, lp, windowed: bool):
 
 def _attention_mixer(ctx: Ctx, x, aux, lp, residual: bool = True, *,
                      windowed: bool = False):
+    cfg = ctx.cfg
     q, k, v, gate, prescaled, _ = _qkv(ctx, x, lp, windowed)
+    if cfg.heads_per_layer:
+        # the heads of the q this layer made; the width ``_rotated`` read
+        # (the window's keys join where the attention is handed them)
+        seen = {Q_HEADS[windowed]: jnp.float32(q.shape[2])}
+        if not windowed:
+            seen[ROTARY_WIDTH_FULL] = jnp.float32(
+                cfg.rotary_dim(False) if cfg.rotary(False) else 0)
+        aux = fold(aux, seen, dict.fromkeys(seen, "max"))
     return _attend(ctx, x, aux, q, k, v, lp, residual, gate, windowed,
                    q_prescaled=prescaled)
 
@@ -619,11 +697,12 @@ def _latent_mixer(ctx: Ctx, x, aux, lp, residual: bool = True):
 
 
 SOFTMAX = Block(_attention_shapes, _attention_mixer,
-                saved=attention.SAVED_RESIDUALS, scopes=SCOPES)
-SLIDING = Block(_attention_shapes,
+                saved=attention.SAVED_RESIDUALS, scopes=SCOPES,
+                stats=_kind_stats(False))
+SLIDING = Block(functools.partial(_attention_shapes, windowed=True),
                 functools.partial(_attention_mixer, windowed=True),
                 saved=attention.SAVED_RESIDUALS, scopes=SCOPES,
-                stats=lambda cfg: WINDOW_STATS)
+                stats=_kind_stats(True))
 LATENT = Block(_latent_shapes, _latent_mixer,
                saved=attention.SAVED_RESIDUALS, scopes=SCOPES)
 BLOCK_RULE = Block(_attention_shapes, _block_mixer,

@@ -123,7 +123,15 @@ class LlamaConfig:
     # What a "sliding_attention" layer's query sees: itself and the
     # sliding_window - 1 tokens before it.
     sliding_window: int = 0
-    attn_output_gate: bool = False    # o * sigmoid(h W_g) before W_o
+    # o * sigmoid(h W_g) before W_o: True, a gate a head AND channel (W_g
+    # as wide as W_q); "per_head", ONE number a head (W_g (d, heads))
+    attn_output_gate: Any = False
+    # The public file's ``num_attention_heads_per_layer``: a layer's count
+    # of QUERY heads, one entry a layer and ONE count a KIND of layer
+    # (``q_heads``: a "sliding_attention" layer's, every other's); empty:
+    # ``num_heads`` everywhere.  The KV heads and a head's size are the
+    # model's.
+    heads_per_layer: Tuple[int, ...] = ()
     ssm_heads: int = 0                # Mamba-2: heads x head_dim = inner width
     ssm_head_dim: int = 64
     ssm_state: int = 128              # state size a head (d_state)
@@ -157,10 +165,17 @@ class LlamaConfig:
     # "original_max_position_embeddings", "beta_fast", "beta_slow",
     # "attention_factor"}, "sliding_attention": {...}}.  Read under
     # position_embedding="rope_by_layer_type", in place of ``rope_theta``
-    # and ``rope_scaling``.
+    # and ``rope_scaling``.  A kind's "partial_rotary_factor" is the share
+    # of a head, from its first dimension on, that the kind's layers rotate
+    # (``rotary_dim``; the frequencies are reckoned over that width, the
+    # rest of the head passes through).
     rope_parameters: Any = None
     # Expert layers: mlp_dim is an expert's width.
     leading_dense: int = 0            # layers 0.. with a dense FFN instead
+    # ... or each layer's FFN as a public file spells it, one entry a
+    # layer, "dense" | "sparse" (the expert layer); only the first
+    # ``num_layers`` are the model.  In place of ``leading_dense``.
+    mlp_layer_types: Tuple[str, ...] = ()
     dense_mlp_dim: int = 0            # their width (0: mlp_dim)
     experts_held: int = 0             # of num_experts, here (0: all)
     first_expert: int = 0             # the first one held
@@ -288,6 +303,20 @@ class LlamaConfig:
         # a configuration file hands a list: keep the config hashable
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         object.__setattr__(self, "gqa_layers", tuple(self.gqa_layers))
+        object.__setattr__(self, "heads_per_layer",
+                           tuple(self.heads_per_layer))
+        object.__setattr__(self, "mlp_layer_types",
+                           tuple(self.mlp_layer_types))
+        if self.mlp_layer_types and (
+                set(self.mlp_layer_types) - {"dense", "sparse"}
+                or len(self.mlp_layer_types) < self.num_layers
+                or self.leading_dense or self.layer_pattern
+                or not self.num_experts):
+            raise ValueError(
+                "mlp_layer_types names every layer's FFN, 'dense' or "
+                f"'sparse', num_layers ({self.num_layers}) of them or more, "
+                "of a model with experts, in place of leading_dense and "
+                f"layer_pattern: {self.mlp_layer_types}")
         if isinstance(self.rope_scaling, dict):
             object.__setattr__(self, "rope_scaling",
                                tuple(sorted(self.rope_scaling.items())))
@@ -317,8 +346,11 @@ class LlamaConfig:
             object.__setattr__(self, "looped",
                                tuple(sorted(self.looped.items())))
         if isinstance(self.rope_parameters, dict):
+            # (a public file may repeat a number beside the kinds' groups:
+            # it is kept as it stands and no kind reads it)
             object.__setattr__(self, "rope_parameters", tuple(sorted(
-                (kind, tuple(sorted(group.items())))
+                (kind, tuple(sorted(group.items()))
+                 if isinstance(group, dict) else group)
                 for kind, group in self.rope_parameters.items())))
         if self.router_groups != 1 or self.num_nextn > 1:
             raise NotImplementedError(
@@ -354,6 +386,10 @@ class LlamaConfig:
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm is over the whole projection, "
                              "qk_head_norm over each head: one of the two")
+        if self.attn_output_gate not in (False, True, "per_head"):
+            raise ValueError(
+                f"attn_output_gate {self.attn_output_gate!r}: True (a gate "
+                "a head and channel) or 'per_head' (one number a head)")
         if self.ffn_act not in ("swiglu", "relu2"):
             raise ValueError(f"ffn_act {self.ffn_act!r}")
         for field in ("layer_pattern", "mtp_pattern"):
@@ -451,7 +487,10 @@ class LlamaConfig:
                         f"position_embedding {ROPE_BY_KIND!r}: "
                         "rope_parameters holds no rope_theta for the "
                         f"model's {kind} layers")
+        if self.heads_per_layer:
+            self._check_heads_per_layer()
         for windowed in (False, True):
+            self.rotary_dim(windowed)   # refuses a share it cannot rotate
             kind = rope_type(self.rope_rule(windowed)[1])
             if kind not in ("default", "yarn"):
                 raise NotImplementedError(
@@ -461,6 +500,35 @@ class LlamaConfig:
                     "the ones its file states")
         if self.looped:
             self._check_looped()
+
+    def _check_heads_per_layer(self):
+        """One count of query heads a KIND of layer, each whole groups of
+        the KV heads, on the softmax mixers that read it."""
+        mixers = [m for m, _ in self.layer_kinds]
+        if len(self.heads_per_layer) < self.num_layers:
+            raise ValueError(
+                f"heads_per_layer names {len(self.heads_per_layer)} layers, "
+                f"num_layers is {self.num_layers}")
+        other = sorted(set(mixers) - {"attention", "full_attention",
+                                      "sliding_attention"})
+        if other or self.hc_mult > 1 or self.num_nextn:
+            raise NotImplementedError(
+                "heads_per_layer is read by the softmax mixers (attention, "
+                "full_attention, sliding_attention) of a model on one "
+                f"residual stream without a predicted-ahead module: {other}")
+        for windowed in (False, True):
+            counts = sorted({
+                h for h, m in zip(self.heads_per_layer, mixers)
+                if (m == "sliding_attention") == windowed})
+            if len(counts) > 1:
+                raise ValueError(
+                    f"heads_per_layer gives the {ROPE_KINDS[windowed]} "
+                    f"layers {counts} query heads: ONE count a kind of "
+                    "layer (a run of layers is one stack of tensors)")
+            if counts and (counts[0] < 1 or counts[0] % self.num_kv_heads):
+                raise ValueError(
+                    f"heads_per_layer: {counts[0]} query heads are not "
+                    f"whole groups of the {self.num_kv_heads} KV heads")
 
     def _check_looped(self):
         """What is built of a looped model, and a refusal by message of
@@ -541,6 +609,33 @@ class LlamaConfig:
     @property
     def qkv_dim(self) -> int:
         return self.num_heads * self.head_dim
+
+    def q_heads(self, windowed: bool) -> int:
+        """The query heads of a softmax layer of this kind (``windowed``: a
+        "sliding_attention" layer): the count ``heads_per_layer`` gives the
+        kind's layers, ``num_heads`` in a model without the list (and for a
+        kind the model has no layer of)."""
+        mixers = (m for m, _ in self.layer_kinds)
+        return next((h for h, m in zip(self.heads_per_layer, mixers)
+                     if (m == "sliding_attention") == windowed),
+                    self.num_heads)
+
+    def rotary_dim(self, windowed: bool) -> int:
+        """The dimensions of a head, from its first on, that a softmax
+        layer of this kind rotates: ``head_dim`` times the
+        ``partial_rotary_factor`` of the kind's rotary group, 1 where it
+        states none (latent attention states its rotary part by
+        ``qk_rope_dim`` and takes no share)."""
+        share = dict(self.rope_rule(windowed)[1]).get(
+            "partial_rotary_factor", 1.0)
+        width = self.head_dim * share
+        if (not 0.0 < share <= 1.0 or width != int(width) or int(width) % 2
+                or share != 1.0 and self.kv_lora_rank):
+            raise ValueError(
+                f"partial_rotary_factor {share} of a head of "
+                f"{self.head_dim}: the rotary width {width} is no multiple "
+                "of 2 within a softmax mixer's head")
+        return int(width)
 
     @property
     def kv_dim(self) -> int:
@@ -680,6 +775,7 @@ class LlamaConfig:
         attention; ``indexed`` for attention in a model with an
         ``sa_config``), ``linear_attn_config`` lists or ``gqa_layers``
         leaves to ``kda``, a dense FFN in the ``leading_dense`` first layers
+        (or where ``mlp_layer_types`` says ``dense``)
         and in a model without experts, the expert layer elsewhere; or, of a
         model with a ``layer_pattern``, the pair each character stands
         for (``LAYER_PATTERN``)."""
@@ -703,6 +799,9 @@ class LlamaConfig:
                            else m for m in mixers)
         if self.block_diffusion:
             mixers = ("block_attention",) * self.num_layers
+        if self.mlp_layer_types:
+            return tuple((mixer, "moe" if ffn == "sparse" else "dense")
+                         for mixer, ffn in zip(mixers, self.mlp_layer_types))
         return tuple(
             (mixer, "moe" if self.num_experts and i >= self.leading_dense
              else "dense") for i, mixer in enumerate(mixers))

@@ -933,6 +933,51 @@ def test_the_solar_open2_cells_step_program_fits_the_chip(one_chip,
     assert 0.25 * 16.909e9 < mem.peak_memory_in_bytes < 16.909e9
 
 
+def test_the_laguna_cells_step_program_fits_the_chip(one_chip, as_on_chip):
+    """``laguna-train-s16384``'s WHOLE step as the cell runs it — the
+    benchmark's configuration file (F S S S twice at hidden 2048, 32 of 256
+    experts of 512 held, 12544 rows) at 1 x 16384 — compiled for the
+    described chip: the chip's compiler takes a model whose query heads
+    follow the layer's KIND (flash calls of 48 heads on 8 KV heads in the
+    full layers and of 64 on 8 under the window, each head a lane block
+    read in place), rotates 64 of a head's 128 dimensions in the full
+    layers on the 4-D view (scope ``rope_partial``; no ``rope_*`` kernel
+    call stands under it) and the whole head in the sliding ones by the
+    rotation's kernel, gates a head by one number (``attn_head_gate``),
+    and the peak of live bytes it reports stays inside the chip's 16.91 GB
+    — 14.79 at PR 83 — and over a quarter of it."""
+    cfg = _benchmark_cfg("laguna-xs.2-33b-a3b-1of8")
+    S, F = "sliding_attention", "full_attention"
+    assert cfg.kind_runs == (((F, "dense"), 1), ((S, "moe"), 3),
+                             ((F, "moe"), 1), ((S, "moe"), 3))
+    assert (cfg.q_heads(False), cfg.q_heads(True), cfg.num_kv_heads,
+            cfg.rotary_dim(False), cfg.rotary_dim(True)) == (48, 64, 8, 64,
+                                                             128)
+    compiled = _compiled_step(cfg, 1, 16384, one_chip)
+    hlo = compiled.as_text()
+    for kernel in ("flash_fwd_win", "flash_dkv_win", "flash_fwd", "flash_dkv",
+                   "rope_fwd", "rope_bwd", "moe_gmm_swiglu"):
+        assert kernel in hlo, kernel
+    assert "flash_dq" not in hlo
+    # q as the flash kernels take it in each kind of layer, heads side by
+    # side (a full layer's 6144 columns, a sliding layer's 8192)
+    calls = [line for line in hlo.splitlines()
+             if "custom-call" in line and "/flash_" in line]
+    assert any("bf16[1,16384,6144]" in c for c in calls)
+    assert any("bf16[1,16384,8192]" in c for c in calls)
+    # the partial rotation is XLA's: its ops carry the scope, no kernel does
+    partial = [line for line in hlo.splitlines() if "/rope_partial/" in line]
+    assert partial and not [p for p in partial if " custom-call(" in p]
+    assert "/attn_out/attn_head_gate/" in hlo
+    # the kernel rotates the sliding runs' q and k alone: 2 runs x (q, k)
+    assert len(re.findall(
+        r"custom-call\(.*/rope/jit\(_call\)/rope_fwd/pallas_call\"", hlo)) \
+        == 2 * 2 * 2    # ... forward and under the checkpoint
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == pytest.approx(6.710e9, rel=1e-3)
+    assert 0.25 * 16.909e9 < mem.peak_memory_in_bytes < 16.909e9
+
+
 def test_a_share_layers_row_buffers_are_made_in_the_layer_loop_and_not_copied(
         one_chip, as_on_chip):
     """The same compiled step: the static row buffers of a share's expert

@@ -14,7 +14,10 @@ wrote while they were out
 beside them, as do the Kimi-Linear cell's (PR 59), the Solar-Open-2
 cell's (PR 64), the Nemotron-3-Super cell's (PR 66) and the Keye-VL-2.0
 cell's (PR 70), the Phi-4-mini-flash cell's (PR 74), the SDAR
-block-diffusion cell's (PR 76) and the Ouro looped-stack cell's (PR 79), by
+block-diffusion cell's (PR 76), the Ouro looped-stack cell's (PR 79) and the
+Laguna cell's (PR 83: it brings no entry and JOINS twelve lists, so the
+three older cases that hold such a list closed run through its
+``before_this_cell``), by
 name; and ``test_late_steps.py``'s (PR 68: the readers
 of a window's lost time on canned spans), whole."""
 
@@ -30,6 +33,7 @@ pytest.register_assert_rewrite("benchmark.tests.test_trinity",
                                "benchmark.tests.test_phi4flash",
                                "benchmark.tests.test_sdar",
                                "benchmark.tests.test_ouro",
+                               "benchmark.tests.test_laguna",
                                "benchmark.tests.test_late_steps")
 
 from benchmark.tests.tier1_cases import *  # noqa: E402,F401,F403
@@ -143,6 +147,19 @@ from benchmark.tests.test_ouro import (  # noqa: E402,F401
     test_ouro_readers_and_flop_module_import_no_jax,
     test_the_roofline_cannot_pass_100_unless_the_count_is_wrong as
     test_ouro_roofline_cannot_pass_100_unless_the_count_is_wrong)
+from benchmark.tests.test_laguna import (  # noqa: E402,F401
+    test_each_floor_each_width_and_each_head_count_violated_in_turn,
+    test_flops_count_attention_by_the_layers_kind,
+    test_the_cell_its_job_and_its_metrics as
+    test_laguna_cell_job_and_metrics,
+    test_the_file_is_the_catalog_row_cut_to_one_chip_of_eight as
+    test_laguna_file_is_the_catalog_row_cut_to_one_chip_of_eight,
+    test_the_joined_readers_count_by_kind_through_this_cells_module,
+    test_the_parameter_count_is_init_params as
+    test_laguna_parameter_count,
+    test_the_readers_and_the_flop_module_import_no_jax as
+    test_laguna_readers_and_flop_module_import_no_jax)
+from benchmark.tests.test_laguna import before_this_cell  # noqa: E402
 from benchmark.tests.test_late_steps import (  # noqa: E402,F401
     test_a_stall_is_split_into_stopped_running_and_waiting,
     test_a_steady_window_reads_zero_everywhere,
@@ -162,11 +179,21 @@ test_nemotron_h_cell_job_and_metrics = as_accepted(  # noqa: F405
     test_nemotron_h_cell_job_and_metrics)  # noqa: F405
 test_the_file_is_the_catalog_row_cut_to_one_chip_of_two = as_accepted(
     test_the_file_is_the_catalog_row_cut_to_one_chip_of_two)  # noqa: F405
-test_mellum_cell_job_and_metrics = as_accepted(
-    test_mellum_cell_job_and_metrics)
+test_mellum_cell_job_and_metrics = before_this_cell(as_accepted(
+    test_mellum_cell_job_and_metrics))
 test_kimi_linear_cell_job_and_metrics = as_accepted(
     test_kimi_linear_cell_job_and_metrics)
 test_solar_open2_cell_job_and_metrics = as_accepted(
     test_solar_open2_cell_job_and_metrics)
 test_nemotron3_cell_job_and_metrics = as_accepted(
     test_nemotron3_cell_job_and_metrics)
+
+# Two more hold ``rope.kernel_ms``'s three cells as a closed list, which the
+# Laguna cell joined (its sliding layers rotate by the kernel): each runs on
+# the file as it was before that (``test_laguna.before_this_cell``; what
+# they no longer see, ``test_laguna_cell_job_and_metrics`` holds).
+test_the_entry_is_written_as_the_flash_times_are = before_this_cell(
+    test_the_entry_is_written_as_the_flash_times_are)  # noqa: F405
+test_rope_kernel_ms_stands_as_it_was_before_this_cells_five_entries = \
+    before_this_cell(
+        test_rope_kernel_ms_stands_as_it_was_before_this_cells_five_entries)

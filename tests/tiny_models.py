@@ -32,9 +32,9 @@ import optax
 import pytest
 
 from benchmark.reference import (
-    afmoe, granite_hybrid, joyai_flash, keye_sparse, kimi_linear, lfm2_moe,
-    mellum, nemotron3, nemotron_h, olmo_hybrid, olmoe, ouro_looped, phi4flash,
-    sdar_block_diffusion, solar_open2, xing4)
+    afmoe, granite_hybrid, joyai_flash, keye_sparse, kimi_linear, laguna,
+    lfm2_moe, mellum, nemotron3, nemotron_h, olmo_hybrid, olmoe, ouro_looped,
+    phi4flash, sdar_block_diffusion, solar_open2, xing4)
 from ray_tpu.models import llama
 from ray_tpu.models.blocks import (
     MIXERS, attention as attention_block, conv, ffn, kda, mamba, mamba1)
@@ -162,6 +162,23 @@ MELLUM_YARN = {"rope_type": "yarn", "rope_theta": 100, "factor": 4,
                "beta_slow": 1, "attention_factor": 0.1 * math.log(4) + 1}
 MELLUM_GROUPS = {F: MELLUM_YARN, S: {"rope_type": "default",
                                      "rope_theta": 100}}
+# Laguna's per-layer lists in small, two periods: a FULL layer first, the
+# head count by the kind (2 KV heads: groups of 2 and 3), the dense FFN in
+# layer 0 alone.  The full layers rotate HALF a head (8 of 16 dimensions) by
+# YaRN reckoned over those 8 — c(1) = 0.81, so pair 0 keeps its frequency
+# and pairs 1-3 have it divided by 4 —, the sliding ones the whole head
+LAGUNA_PATTERN = (F, S, S, S) * 2
+LAGUNA_HEADS = (4, 6, 6, 6) * 2
+LAGUNA_FFNS = ("dense",) + ("sparse",) * 7
+LAGUNA_WINDOW = 16
+LAGUNA_YARN = {"rope_type": "yarn", "rope_theta": 100, "factor": 4,
+               "original_max_position_embeddings": 16, "beta_fast": 64,
+               "beta_slow": 1, "attention_factor": 0.1 * math.log(4) + 1,
+               "partial_rotary_factor": 0.5}
+LAGUNA_GROUPS = {F: LAGUNA_YARN,
+                 S: {"rope_type": "default", "rope_theta": 100,
+                     "partial_rotary_factor": 1},
+                 "original_max_position_embeddings": 16}
 # Kimi-Linear's lists in small, counted from 1 and longer than the model:
 # layers 1-5 are run, K K K F K, the first with the dense FFN
 KIMI_LINEAR = {"kda_layers": [1, 2, 3, 5, 6, 7], "full_attn_layers": [4, 8],
@@ -442,6 +459,66 @@ _MELLUM_FAULTS = (
     # what the chip's mean-loss row sees: 0.001 x E-ish of ln(vocab)
     Fault("no-balance-loss", dict(aux_loss_coef=0.0), distance=mean_apart,
           over=2 * mellum.LOSS_RTOL))
+
+def _laguna_groups(**full):
+    """Laguna's rotary groups with the full layers' changed."""
+    return dict(LAGUNA_GROUPS, **{F: dict(LAGUNA_YARN, **full)})
+
+
+def _factor_on_the_unrotated_half(patch):
+    rotated = attention_block._rotated
+
+    def wrong(ctx, windowed, q, k):
+        q, k = rotated(ctx, windowed, q, k)
+        if windowed:
+            return q, k
+        scale = jnp.where(jnp.arange(q.shape[-1]) < ctx.cfg.rotary_dim(False),
+                          1.0, LAGUNA_YARN["attention_factor"])
+        return q * scale, k * scale
+
+    patch.setattr(attention_block, "_rotated", wrong)
+
+
+def _yarn_over_the_whole_head(patch):
+    tables = attention_block._rope_tables
+    patch.setattr(
+        attention_block, "_rope_tables",
+        lambda ctx, windowed, s, dim: tuple(
+            t[:, :dim // 2] for t in tables(ctx, windowed, s,
+                                            ctx.cfg.head_dim)))
+
+
+def _sliding_layers_at_four_heads(stack):
+    """A sliding run's tensors as a program of 4 query heads everywhere
+    reads them: the first 4 of its 6 heads."""
+    if stack["wg"].shape[-1] != 6:
+        return stack
+    return dict(stack, wq=stack["wq"][..., :64], wo=stack["wo"][:, :64],
+                wg=stack["wg"][..., :4])
+
+
+_LAGUNA_FAULTS = (
+    Fault("full-head-count-in-the-sliding-layers",
+          dict(heads_per_layer=(4,) * 8),
+          reread=_in_stacks(_sliding_layers_at_four_heads)),
+    Fault("whole-head-rotated-in-the-full-layer", dict(
+        rope_parameters=_laguna_groups(partial_rotary_factor=1.0))),
+    Fault("factor-on-the-unrotated-half",
+          patch=_factor_on_the_unrotated_half),
+    Fault("yarn-over-the-whole-head", patch=_yarn_over_the_whole_head),
+    Fault("attention-factor-dropped", dict(
+        rope_parameters=_laguna_groups(attention_factor=1.0))),
+    Fault("plain-tables-in-the-full-layer", dict(
+        rope_parameters=_laguna_groups(rope_type="default"))),
+    Fault("no-gate", dict(attn_output_gate=False)),
+    Fault("window-one-short", dict(sliding_window=LAGUNA_WINDOW - 1)),
+    Fault("no-window", dict(sliding_window=48)),    # the sample's positions
+    Fault("softmax-scores", dict(router_scoring="softmax")),
+    Fault("no-bias", dict(topk_method="greedy")),
+    Fault("no-route-scale", dict(routed_scaling_factor=1.0)),
+    Fault("gates-not-renormalised", dict(norm_topk_prob=False)),
+    Fault("no-shared-expert", dict(shared_experts=0)),
+    Fault("another-chips-experts", dict(first_expert=0)))
 
 _TRINITY_FAULTS = (
     Fault("rope-in-the-full-layer-too", dict(position_embedding="rope")),
@@ -815,6 +892,34 @@ ROWS: Dict[str, Row] = {
         step=Step(fields=dict(_KERNELS, num_layers=4), steps=1,
                   named=_WINDOWED + ("attn_qkv/rope/",),
                   counters=tuple(mellum.STEP_METRICS))),
+    # two published periods, F S S S twice: 4 query heads in a full layer
+    # and 6 under the window over 2 KV heads, half a head rotated by YaRN
+    # in the full layers, a gate a head, one dense layer then expert layers
+    # (16 experts of which this chip holds 4..7, 4 a token x 2.5, a shared
+    # expert); two norms a layer
+    "laguna": Row(
+        dict(_SMALL, num_layers=8, num_kv_heads=2, dense_mlp_dim=96,
+             norm_eps=1e-6, layer_types=LAGUNA_PATTERN,
+             heads_per_layer=LAGUNA_HEADS, mlp_layer_types=LAGUNA_FFNS,
+             sliding_window=LAGUNA_WINDOW, position_embedding=ROPE_BY_KIND,
+             rope_parameters=LAGUNA_GROUPS, attn_output_gate="per_head",
+             num_experts=16, num_selected=4, experts_held=4, first_expert=4,
+             shared_experts=1, routed_scaling_factor=2.5, **_SIGMOID),
+        _jax_tokens(2, 49), laguna,
+        dict(layer_types=list(LAGUNA_PATTERN),
+             mlp_layer_types=list(LAGUNA_FFNS),
+             num_attention_heads_per_layer=list(LAGUNA_HEADS),
+             num_hidden_layers=8, num_key_value_heads=2, hidden_size=64,
+             rms_norm_eps=1e-6, sliding_window=LAGUNA_WINDOW,
+             rope_parameters=LAGUNA_GROUPS, num_experts_per_tok=4,
+             moe_routed_scaling_factor=2.5, first_expert=4),
+        faults=_LAGUNA_FAULTS, sees=(token_max, 1e-3),
+        sound=(dict(num_layers=4), dict(num_hidden_layers=4)),
+        sound_within=5e-5, shares=8,
+        step=Step(fields=dict(_KERNELS, num_layers=4), steps=1,
+                  named=_WINDOWED + ("attn_qkv/rope/rope_partial/",
+                                     "attn_out/attn_head_gate/", "ffn/"),
+                  counters=tuple(laguna.STEP_METRICS))),
     # three layers of one kind: GQA 4/2 with a norm over each head's q and
     # k, an indexer that picks 16 keys a query, every layer an expert layer
     # (16 experts of which this chip holds 4..7, 4 a token, renormalised)
